@@ -50,7 +50,8 @@ from symmetry_tpu.engine.disagg.net import (
     link_transport,
     secure_link,
 )
-from symmetry_tpu.protocol.keys import HostOp, LinkOp
+from symmetry_tpu.protocol.keys import HOST_EXIT_NO_CHIP, HostOp, LinkOp
+from symmetry_tpu.provider.backends.base import BackendNoChipError
 from symmetry_tpu.utils.faults import FAULTS
 from symmetry_tpu.utils.logging import logger as log
 
@@ -246,6 +247,8 @@ class PrefillNode:
             line = await self._proc.stdout.readline()
             if not line:
                 rc = await self._proc.wait()
+                if rc == HOST_EXIT_NO_CHIP:
+                    raise BackendNoChipError.for_host("prefill host")
                 raise RuntimeError(
                     f"prefill host died during startup (rc={rc})")
             try:
@@ -504,6 +507,9 @@ class PrefillNode:
                     await self._spawn_host()
                 except Exception as exc:  # noqa: BLE001 — spawn failed
                     self._respawn_failures += 1
+                    if isinstance(exc, BackendNoChipError):
+                        # The next life would get the same platform.
+                        self._respawn_failures = self._max_respawns
                     log.error(f"prefill node: host respawn failed: {exc}")
                     continue
                 self.stats["host_restarts"] += 1

@@ -44,7 +44,15 @@ Protocol: JSON lines.
             bounded device trace on its OWN thread — the serve loop
             and every stream keep flowing — and replies when done)
            {"op": "stats"} | {"op": "shutdown"}
-  stdout → {"op": "ready", "model": …}            (after warmup)
+  stdout → {"op": "ready", "model", "role", "slots", "max_seq_len",
+            "build_s", "warmup_s", "compile_cache",
+            "device": {"platform", "device_kind", "device_count",
+                       "hbm": [{"bytes_in_use", "bytes_limit"}, …]},
+            "attention": {"prefill", "decode"}}
+            (after warmup. `device` is what JAX handed this process and
+            its per-device memory_stats() once every program has
+            compiled; `attention` is "pallas" | "pallas-interpret" |
+            "xla" per program. The stats reply repeats both.)
            {"op": "clock", "t0", "t": our monotonic at receipt}
            {"op": "trace", "clock", "components": [{name, spans,
             counters, clock_offset_s}, …]}   (host + scheduler rings,
@@ -90,6 +98,13 @@ block-boundary flush writes one line under a lock straight from the
 engine thread — there is no latency-sensitive I/O in this process to
 starve.
 
+A host that finds itself on a platform other than `tpu` without the
+CPU having been pinned by name (utils/device.py require_chip) builds
+nothing: it says which platform it got on stderr and exits with
+HOST_EXIT_NO_CHIP, which the backend reports as a failure it must not
+respawn — a chip belongs to one process, so a second host on the same
+chip lands here.
+
 Run: python -m symmetry_tpu.engine.host <config.yaml>
 """
 
@@ -104,8 +119,9 @@ from typing import TYPE_CHECKING, Any
 
 from symmetry_tpu.engine.engine import InferenceEngine, SamplingParams
 from symmetry_tpu.engine.scheduler import GenRequest, Scheduler
-from symmetry_tpu.protocol.keys import HostOp
+from symmetry_tpu.protocol.keys import HOST_EXIT_NO_CHIP, HostOp
 from symmetry_tpu.provider.config import ConfigManager
+from symmetry_tpu.utils.device import NoChipError, device_report
 from symmetry_tpu.utils.faults import FAULTS
 from symmetry_tpu.utils.logging import logger, set_component
 from symmetry_tpu.utils.metrics import METRICS, MetricName
@@ -137,6 +153,10 @@ class EngineHost:
             FAULTS.load(config.get("faults"))
         self._engine: InferenceEngine | None = None
         self._scheduler: Scheduler | None = None
+        # Filled by start(): build/warmup seconds, compile-cache
+        # directory, the device JAX handed this process and the
+        # attention path of each program (READY and stats carry it).
+        self._startup: dict[str, Any] = {}
         self._wlock = threading.Lock()
         self._cancelled: set[str] = set()
         self._reported: dict[str, int] = {}  # id -> tokens already reported
@@ -321,10 +341,9 @@ class EngineHost:
 
         from symmetry_tpu.utils.compile_cache import enable_compile_cache
 
-        # Persistent XLA compile cache (round-3 verdict #4): without it
-        # every host start recompiles the full serving grid (~90 s of the
-        # observed 94 s startup); with it a config-identical restart
-        # compiles ~nothing.
+        # Persistent XLA compile cache: without it every host start
+        # recompiles the full serving grid; with it a config-identical
+        # restart compiles ~nothing.
         cache_dir = enable_compile_cache(self._config.tpu)
         t0 = time.perf_counter()
         self._engine = InferenceEngine.from_tpu_config(self._config.tpu)
@@ -364,17 +383,34 @@ class EngineHost:
         METRICS.enabled = bool(mcfg.get("enabled", True))
         set_component("host")
         self._scheduler.start()
+        # What this process runs on, read once every program has
+        # compiled and the caches are allocated: READY, the log line and
+        # every stats reply carry the same block, so nobody downstream
+        # has to touch JAX (and take the chip) to learn it.
+        self._startup = {
+            "build_s": round(t_build, 1), "warmup_s": round(t_warmup, 1),
+            "compile_cache": cache_dir,
+            "device": device_report(),
+            "attention": self._engine.attention_paths()}
         self._write({"op": HostOp.READY,
                      "model": self._config.model_name,
                      "role": self._role,
                      "slots": self._engine.max_slots,
                      "max_seq_len": self._engine.max_seq_len,
-                     "build_s": round(t_build, 1),
-                     "warmup_s": round(t_warmup, 1)})
+                     **self._startup})
         # Startup breakdown to stderr: a slow start must carry its own
         # explanation in the provider log (round-3 verdict #1).
+        dev, attn = self._startup["device"], self._startup["attention"]
+        hbm = " ".join(f"{h['bytes_in_use'] / 2**30:.2f}/"
+                       f"{h['bytes_limit'] / 2**30:.2f}GiB"
+                       for h in dev["hbm"]) or "n/a"
         logger.info(f"engine host ready: model={self._config.model_name} "
                     f"role={self._role} slots={self._engine.max_slots} "
+                    f"platform={dev['platform']} "
+                    f"device_kind={dev['device_kind']!r} "
+                    f"device_count={dev['device_count']} hbm={hbm} "
+                    f"attention=prefill:{attn['prefill']},"
+                    f"decode:{attn['decode']} "
                     f"build={t_build:.1f}s warmup={t_warmup:.1f}s "
                     f"compile_cache={cache_dir or 'off'}")
 
@@ -419,6 +455,7 @@ class EngineHost:
                 # for a stats read.
                 m["emit"] = dict(self.emit_stats)
                 m["role"] = self._role
+                m["startup"] = self._startup
                 # Per-request emitted-token journal rider: the tokens
                 # each live stream has had WRITTEN to the pipe. The
                 # backend's supervisor keeps the last heartbeat's copy,
@@ -916,7 +953,11 @@ def main() -> int:
               file=sys.stderr)
         return 2
     host = EngineHost(ConfigManager(config_path=sys.argv[1]))
-    return host.serve_forever()
+    try:
+        return host.serve_forever()
+    except NoChipError as exc:
+        logger.error(f"engine host refused to start: {exc}")
+        return HOST_EXIT_NO_CHIP
 
 
 if __name__ == "__main__":

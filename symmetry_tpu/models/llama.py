@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from symmetry_tpu.ops.attention import gqa_attention
+from symmetry_tpu.ops.interpret import interpret_mode
 from symmetry_tpu.ops.norm import rms_norm
 from symmetry_tpu.ops.quant import QuantizedTensor, qmatmul, quantize_tree
 from symmetry_tpu.ops.rope import apply_rope
@@ -305,6 +306,28 @@ def cache_logical_axes(*, quantized: bool = False) -> KVCache:
 # Forward
 
 
+def attention_paths(config: ModelConfig, capacity: int,
+                    tp_mesh=None) -> dict[str, str]:
+    """Which attention implementation the prefill-from-empty and the
+    single-position decode programs take at this cache capacity: "pallas"
+    (ops/flash.py, ops/decode_attention.py), "pallas-interpret" (the same
+    kernels on the CPU backend) or "xla" (ops/attention.py gqa_attention).
+    `_layer` routes by this and the engine reports it, so a kernel giving
+    way to the XLA path is visible rather than quiet. Under a GSPMD mesh
+    the kernels run per shard with KV heads over `model`, which needs the
+    heads to divide; otherwise that build keeps the XLA path."""
+    from symmetry_tpu.ops import decode_attention as da
+
+    kernel = "pallas-interpret" if interpret_mode() else "pallas"
+    if tp_mesh is not None and (
+            config.num_kv_heads % dict(tp_mesh.shape).get("model", 1)):
+        return {"prefill": "xla", "decode": "xla"}
+    return {"prefill": kernel,
+            "decode": (kernel if da.supports(config, capacity,
+                                             jax.default_backend())
+                       else "xla")}
+
+
 def _layer(
     h: jnp.ndarray,             # [B, S, E]
     lp: dict,                   # one layer's params (leading L dim stripped)
@@ -318,6 +341,7 @@ def _layer(
     ring_mesh=None,             # static: Mesh => sequence-parallel prefill
     sp_mode: str = "ring",      # static: "ring" | "ulysses" (SURVEY §5.7)
     kv_append_ok: bool = True,  # static: False for sharded caches (TP/PP)
+    tp_mesh=None,               # static: Mesh the trunk is GSPMD-sharded over
 ) -> tuple[jnp.ndarray, KVCache]:
     B, S, E = h.shape
     D, nq, nkv = config.dim_per_head, config.num_heads, config.num_kv_heads
@@ -390,32 +414,37 @@ def _layer(
             from symmetry_tpu.parallel.ring import ring_attention
 
             attn = ring_attention(q, k, v, seq_lens, ring_mesh)
-    elif prefill_flash:
-        # Prefill-from-empty: attention is over this call's own K/V — the
-        # Pallas kernel streams K/V blocks through VMEM instead of
-        # materializing [H, S, S] scores (ops/flash.py); the cache slice is
-        # never read back. Sliding-window models restrict the kernel's
-        # block range to the window.
-        from symmetry_tpu.ops.flash import flash_prefill
-
-        attn = flash_prefill(q, k, v, seq_lens,
-                             window=config.sliding_window,
-                             interpret=jax.default_backend() != "tpu")
     else:
-        from symmetry_tpu.ops import decode_attention as da
+        paths = attention_paths(config, cache.k.shape[2], tp_mesh)
+        if prefill_flash and paths["prefill"] != "xla":
+            # Prefill-from-empty: attention is over this call's own K/V —
+            # the Pallas kernel streams K/V blocks through VMEM instead of
+            # materializing [H, S, S] scores (ops/flash.py); the cache
+            # slice is never read back. Sliding-window models restrict the
+            # kernel's block range to the window.
+            from symmetry_tpu.ops import flash
 
-        if S == 1 and da.supports(config, cache.k.shape[2],
-                                  jax.default_backend()):
+            kw = dict(window=config.sliding_window,
+                      interpret=interpret_mode())
+            attn = (flash.flash_prefill(q, k, v, seq_lens, **kw)
+                    if tp_mesh is None else
+                    flash.flash_prefill_tp(q, k, v, seq_lens, mesh=tp_mesh,
+                                           **kw))
+        elif S == 1 and paths["decode"] != "xla":
             # Single-position decode on TPU: the Pallas kernel reads only
             # each slot's occupied KV prefix (per-slot block skipping); the
             # full cache is its operand, layer selection happens in the
             # kernel's block addressing (ops/decode_attention.py).
-            attn = da.decode_attention(
-                q[:, 0], cache.k, cache.v, layer, kv_valid,
-                k_scale=cache.k_scale if cache.quantized else None,
-                v_scale=cache.v_scale if cache.quantized else None,
-                window=config.sliding_window,
-                interpret=jax.default_backend() != "tpu")[:, None]
+            from symmetry_tpu.ops import decode_attention as da
+
+            args = (q[:, 0], cache.k, cache.v, layer, kv_valid,
+                    cache.k_scale if cache.quantized else None,
+                    cache.v_scale if cache.quantized else None)
+            kw = dict(window=config.sliding_window,
+                      interpret=interpret_mode())
+            attn = (da.decode_attention(*args, **kw) if tp_mesh is None
+                    else da.decode_attention_tp(*args, mesh=tp_mesh,
+                                                **kw))[:, None]
         else:
             def at_layer(arr):
                 return jax.lax.dynamic_index_in_dim(arr, layer, 0,
@@ -469,6 +498,7 @@ def forward_hidden(
     ring_mesh=None,               # static: context-parallel prefill mesh
     sp_mode: str = "ring",        # static: "ring" | "ulysses"
     kv_append_ok: bool = True,    # static: False when the cache is sharded
+    tp_mesh=None,                 # static: Mesh the arrays are sharded over
 ) -> tuple[jnp.ndarray, KVCache]:
     """Decoder trunk: returns (final-norm hidden states [B, S, E], cache).
 
@@ -522,7 +552,8 @@ def forward_hidden(
     h, new_cache = run_layers(params["layers"], h, cache, positions,
                               kv_valid, seq_lens, config,
                               use_flash=use_flash, use_ring=use_ring,
-                              sp_mode=sp_mode, kv_append_ok=kv_append_ok)
+                              sp_mode=sp_mode, kv_append_ok=kv_append_ok,
+                              tp_mesh=tp_mesh)
     h = rms_norm(h, _norm_w(params["final_norm"], config), config.rms_eps)
     return h, new_cache._replace(lengths=kv_valid)
 
@@ -540,6 +571,7 @@ def run_layers(
     use_ring=None,
     sp_mode: str = "ring",
     kv_append_ok: bool = True,
+    tp_mesh=None,
 ) -> tuple[jnp.ndarray, KVCache]:
     """Scan a stack of decoder layers over `h`. Factored out of
     forward_hidden so pipeline parallelism (parallel/pipeline.py) can run a
@@ -555,7 +587,8 @@ def run_layers(
         lp, l = xs
         h, c = _layer(h, lp, c, l, positions, kv_valid,
                       seq_lens, config, use_flash, ring_mesh=use_ring,
-                      sp_mode=sp_mode, kv_append_ok=kv_append_ok)
+                      sp_mode=sp_mode, kv_append_ok=kv_append_ok,
+                      tp_mesh=tp_mesh)
         return (h, c), None
 
     n_layers = jax.tree.leaves(layers_params)[0].shape[0]

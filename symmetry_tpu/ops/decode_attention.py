@@ -240,3 +240,32 @@ def decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, nq, D), q.dtype),
         interpret=interpret,
     )(*args)
+
+
+def decode_attention_tp(q, k_cache, v_cache, layer, kv_length,
+                        k_scale=None, v_scale=None, *, mesh,
+                        **kw) -> jnp.ndarray:
+    """decode_attention inside a GSPMD program sharded over `mesh`.
+
+    XLA cannot partition a pallas_call: left bare under tensor parallelism
+    it would gather the whole KV cache into every call. One shard_map runs
+    the kernel per shard instead — KV heads over `model` (the cache's own
+    sharding, parallel/sharding.py; a shard's query heads are exactly its
+    KV heads' groups; needs kv_heads % model == 0) and slots over `data`
+    when they divide. No collective: the output stays head-sharded."""
+    from jax.sharding import PartitionSpec as P
+
+    data = dict(mesh.shape).get("data", 1)
+    b = "data" if data > 1 and q.shape[0] % data == 0 else None
+    kv = P(None, b, None, "model", None)
+    scale = P(None, b, "model", None)
+    quantized = k_scale is not None
+    return jax.shard_map(
+        lambda q, k, v, lay, n, *sc: decode_attention(q, k, v, lay, n, *sc,
+                                                      **kw),
+        mesh=mesh,
+        in_specs=(P(b, "model", None), kv, kv, P(), P(b))
+        + ((scale, scale) if quantized else ()),
+        out_specs=P(b, "model", None), check_vma=False,
+    )(q, k_cache, v_cache, layer, kv_length,
+      *((k_scale, v_scale) if quantized else ()))

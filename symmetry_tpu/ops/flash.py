@@ -146,3 +146,24 @@ def flash_prefill(
         interpret=interpret,
     )(seq_lens, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
+
+
+def flash_prefill_tp(q, k, v, seq_lens, *, mesh, **kw) -> jnp.ndarray:
+    """flash_prefill inside a GSPMD program sharded over `mesh`.
+
+    XLA cannot partition a pallas_call: left bare under tensor parallelism
+    it would gather every head onto every device first. One shard_map runs
+    the kernel per shard instead — heads over `model` (a shard's query
+    heads are exactly its KV heads' groups, so the h // group mapping holds
+    shard-locally; needs kv_heads % model == 0) and prompts over `data`
+    when they divide. No collective: the output stays head-sharded, where
+    the row-parallel wo wants it."""
+    from jax.sharding import PartitionSpec as P
+
+    data = dict(mesh.shape).get("data", 1)
+    b = "data" if data > 1 and q.shape[0] % data == 0 else None
+    spec = P(b, None, "model", None)
+    return jax.shard_map(
+        lambda q, k, v, n: flash_prefill(q, k, v, n, **kw), mesh=mesh,
+        in_specs=(spec, spec, spec, P(b)), out_specs=spec,
+        check_vma=False)(q, k, v, seq_lens)

@@ -50,6 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from symmetry_tpu.ops.interpret import interpret_mode
+
 # Tile sizes measured on v5e (tools/probe_s8_mxu.py, M=512): smaller bn
 # keeps more N-blocks for the grid, which generalizes better to narrow
 # layers; (512, 1024) performs comparably at wide shapes.
@@ -294,7 +296,7 @@ def w8a16_apply(x: jnp.ndarray, w_tiles: jnp.ndarray,
         return y.astype(out_dtype)
     out = w8a16_matmul(x.reshape(M, K), w_tiles, w_scale,
                        out_dtype=out_dtype, apply_scale=apply_scale,
-                       interpret=jax.default_backend() != "tpu")
+                       interpret=interpret_mode())
     return out.reshape(*lead, N)
 
 
@@ -317,8 +319,6 @@ def w8a16_apply_sharded(x: jnp.ndarray, w) -> jnp.ndarray:
     program (prefill/chunk/decode/verify) with zero extra plumbing."""
     from jax.sharding import PartitionSpec as P
 
-    from symmetry_tpu.utils.compat import shard_map
-
     mesh, k_ax, n_ax = w.mesh, w.k_axis, w.n_axis
     data = dict(zip(mesh.axis_names, mesh.devices.shape)).get("data", 1)
     # Keep activations batch-sharded through the kernel when they are
@@ -339,6 +339,6 @@ def w8a16_apply_sharded(x: jnp.ndarray, w) -> jnp.ndarray:
         y = jax.lax.psum(part, k_ax)
         return (y * sl).astype(x.dtype)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(x_spec, q_spec, s_spec),
-                     out_specs=o_spec, check_rep=False)(x, w.q, w.scale)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(x_spec, q_spec, s_spec),
+                         out_specs=o_spec, check_vma=False)(x, w.q, w.scale)

@@ -18,6 +18,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from symmetry_tpu.ops.interpret import interpret_mode
+
 
 class QuantizedTensor(NamedTuple):
     q: jnp.ndarray      # int8, same shape as the dense weight
@@ -221,8 +223,8 @@ def _pack_quantized_report(
         # Blocks are chosen against the PER-SHARD dims so the tile grid
         # [K/bk, N/bn] divides evenly across the mesh axes — that is
         # what makes the sharded packed layout equal the per-shard pack.
-        floor_k = qmm._TPU_MIN_BK if jax.default_backend() == "tpu" else 8
-        floor_n = qmm._TPU_MIN_BN if jax.default_backend() == "tpu" else 8
+        floor_k = 8 if interpret_mode() else qmm._TPU_MIN_BK
+        floor_n = 8 if interpret_mode() else qmm._TPU_MIN_BN
         bk = qmm.pick_w8a16_block(K_loc, qmm.W8A16_BLOCK_K, floor=floor_k)
         bn = qmm.pick_w8a16_block(N_loc, qmm.W8A16_BLOCK_N, floor=floor_n)
         if bk is None or bn is None:

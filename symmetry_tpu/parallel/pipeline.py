@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from symmetry_tpu.models.llama import KVCache, ModelConfig, run_layers
 from symmetry_tpu.ops.norm import rms_norm
 from symmetry_tpu.parallel.sharding import DEFAULT_RULES
-from symmetry_tpu.utils.compat import shard_map
 
 # Sharding rules for pipeline mode: layers (params AND cache) over `stage`.
 PIPELINE_RULES = {**DEFAULT_RULES, "layers": "stage"}
@@ -205,7 +204,7 @@ def pipeline_forward_hidden(
 
     fn = functools.partial(_pp_shard_fn, config=config, n_stages=n_stages,
                            n_micro=n_microbatches, use_flash=use_flash)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(param_specs, P(), cache_specs, P()),
         out_specs=(P(), cache_specs),

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 
 from symmetry_tpu.ops.attention import NEG_INF
-from symmetry_tpu.utils.compat import shard_map
 
 
 def _partial_attention(q, k, v, q_pos, kv_pos, seq_lens, m, l, acc):
@@ -109,7 +108,7 @@ def ring_attention(
     fn = functools.partial(_ring_shard_fn, axis=axis, shard_len=shard_len,
                            n_shards=n)
     spec = P(None, axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(spec, spec, spec, P()),
         out_specs=spec,

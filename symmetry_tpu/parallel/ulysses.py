@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 
 from symmetry_tpu.ops.attention import gqa_attention
-from symmetry_tpu.utils.compat import shard_map
 
 
 def _ulysses_shard_fn(q, k, v, seq_lens, *, axis: str):
@@ -90,7 +89,7 @@ def ulysses_attention(
 
     fn = functools.partial(_ulysses_shard_fn, axis=axis)
     spec = P(None, axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(spec, spec, spec, P()),
         out_specs=spec,

@@ -126,6 +126,13 @@ class HostOp:
     HANDOFF = "handoff"     # prefill role: serialized KV prefix frame
 
 
+# Exit code of an engine host that refused to start because JAX gave it
+# a platform other than tpu (utils/device.py require_chip). The backend
+# reports it as a failure it must not respawn; a host that crashes keeps
+# whatever code it died with (fault injection uses 86).
+HOST_EXIT_NO_CHIP = 87
+
+
 HOST_OPS = frozenset(
     v for k, v in vars(HostOp).items()
     if not k.startswith("_") and isinstance(v, str)

@@ -26,6 +26,9 @@ async def run(config_path: str) -> None:
     await stop.wait()
     logger.info("draining and shutting down…")
     await provider.stop()
+    host_rc = getattr(provider.backend, "host_exit_code", None)
+    if host_rc:
+        raise SystemExit(f"engine host exited with code {host_rc}")
 
 
 def run_worker(config_path: str) -> None:

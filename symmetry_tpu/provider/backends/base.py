@@ -218,6 +218,22 @@ class BackendRestartingError(BackendError):
         self.emitted = emitted
 
 
+class BackendNoChipError(BackendError):
+    """An engine process found itself on a platform other than tpu and
+    refused to build (utils/device.py require_chip; a host says so with
+    exit code HOST_EXIT_NO_CHIP). NOT retryable and never respawned: a
+    chip belongs to one process, so the next life would land on the same
+    platform. Topologies that put several engine processes on one chip
+    (a local disagg pair, an inline prefill node, a pool) end here."""
+
+    @classmethod
+    def for_host(cls, what: str) -> "BackendNoChipError":
+        return cls(
+            f"{what} got a platform other than tpu and refused to start "
+            f"(its stderr names the platform): each engine host needs a "
+            f"chip of its own")
+
+
 class BackendDeadlineError(BackendError):
     """The request's end-to-end deadline expired before it was served
     (scheduler admission shed). NOT retryable — by definition nobody is

@@ -19,10 +19,11 @@ from typing import Any, AsyncIterator
 
 from symmetry_tpu.engine.engine import InferenceEngine, SamplingParams
 from symmetry_tpu.engine.scheduler import AsyncSession, Scheduler
-from symmetry_tpu.protocol.keys import HostOp, LinkOp
+from symmetry_tpu.protocol.keys import HOST_EXIT_NO_CHIP, HostOp, LinkOp
 from symmetry_tpu.provider.backends.base import (
     BackendDeadlineError,
     BackendError,
+    BackendNoChipError,
     BackendRestartingError,
     InferenceBackend,
     InferenceRequest,
@@ -109,6 +110,9 @@ class TpuNativeBackend(InferenceBackend):
         self._scheduler: Scheduler | None = None
         self._command_loop = None
         self._proc: asyncio.subprocess.Process | None = None
+        # The primary host's exit code once stop() has shut it down (the
+        # provider CLI exits non-zero when this is).
+        self.host_exit_code: int | None = None
         self._cfg_path: str | None = None
         self._queues: dict[str, asyncio.Queue] = {}
         self._reader: asyncio.Task | None = None
@@ -361,6 +365,7 @@ class TpuNativeBackend(InferenceBackend):
 
     async def _start_inproc(self) -> None:
         from symmetry_tpu.utils.compile_cache import enable_compile_cache
+        from symmetry_tpu.utils.device import NoChipError
 
         tpu_cfg = self._config.tpu
         mh = tpu_cfg.multihost
@@ -369,7 +374,10 @@ class TpuNativeBackend(InferenceBackend):
         def build() -> InferenceEngine:
             return InferenceEngine.from_tpu_config(tpu_cfg)
 
-        self._engine = await asyncio.to_thread(build)
+        try:
+            self._engine = await asyncio.to_thread(build)
+        except NoChipError as exc:
+            raise BackendNoChipError(str(exc)) from exc
         sched_engine = self._engine
         if mh and mh.get("num_processes", 1) > 1:
             # Rank 0 fronts the network; its scheduler drives all ranks in
@@ -433,7 +441,13 @@ class TpuNativeBackend(InferenceBackend):
             # member is a capacity event handled in its own domain.
             await self._start_pool()
             return
-        await self._spawn_host()
+        try:
+            await self._spawn_host()
+        except BaseException:
+            # A pair is spawned together: whichever host failed, the
+            # other must not be left building a model nobody will use.
+            await self._reap_host()
+            raise
         if self._net_mode:
             await self._start_link()
         if self._sup_enabled:
@@ -458,13 +472,18 @@ class TpuNativeBackend(InferenceBackend):
 
     @staticmethod
     async def _await_ready(proc: asyncio.subprocess.Process,
-                           what: str) -> None:
+                           what: str) -> dict:
         """Read frames until the host's ready line (weight loading +
-        warmup happen in the host before it appears)."""
+        warmup happen in the host before it appears); returns that
+        frame — it names the device the host got. A host that refused
+        its platform raises BackendNoChipError, which no caller
+        respawns."""
         while True:
             line = await proc.stdout.readline()
             if not line:
                 rc = await proc.wait()
+                if rc == HOST_EXIT_NO_CHIP:
+                    raise BackendNoChipError.for_host(what)
                 raise BackendError(f"{what} died during startup "
                                    f"(rc={rc})")
             try:
@@ -474,7 +493,7 @@ class TpuNativeBackend(InferenceBackend):
             if not isinstance(msg, dict):
                 continue  # stray scalar on stdout (see _read_events)
             if msg.get("op") == HostOp.READY:
-                return
+                return msg
 
     async def _spawn_host(self) -> None:
         """One host life: spawn, await ready, measure the clock offset,
@@ -489,7 +508,7 @@ class TpuNativeBackend(InferenceBackend):
         if self._local_pair:
             self._prefill_proc = await self._spawn_one(
                 self._prefill_cfg_path)
-        await self._await_ready(
+        ready = await self._await_ready(
             self._proc, "decode host" if self._disagg else "engine host")
         self._clock_offset = await self._clock_handshake(self._proc)
         self._reader = asyncio.get_running_loop().create_task(
@@ -509,9 +528,13 @@ class TpuNativeBackend(InferenceBackend):
                 f"(pid {self._prefill_proc.pid}): clock_offset="
                 f"{self._prefill_clock_offset * 1e6:+.0f}us")
         self._spawned_at = time.monotonic()
+        dev = ready.get("device") or {}
         log.info(f"tpu_native engine host up (pid {self._proc.pid}"
                  f"{', disagg pair' if self._disagg else ''}): "
                  f"model={self._model_name} "
+                 f"platform={dev.get('platform')} "
+                 f"device_kind={dev.get('device_kind')!r} "
+                 f"device_count={dev.get('device_count')} "
                  f"clock_offset={self._clock_offset * 1e6:+.0f}us")
 
     # ------------------------------------------------- handoff link (net)
@@ -902,6 +925,9 @@ class TpuNativeBackend(InferenceBackend):
                     raise
                 except Exception as exc:  # noqa: BLE001 — spawn failed
                     m.respawn_failures += 1
+                    if isinstance(exc, BackendNoChipError):
+                        # The next life would get the same platform.
+                        m.respawn_failures = self._max_respawns
                     if m.proc is not None:
                         if m.proc.returncode is None:
                             with contextlib.suppress(ProcessLookupError):
@@ -1846,6 +1872,10 @@ class TpuNativeBackend(InferenceBackend):
             except asyncio.TimeoutError:
                 self._proc.kill()
                 await self._proc.wait()  # reap — no zombie
+            self.host_exit_code = self._proc.returncode
+            if self.host_exit_code != 0:
+                log.error(f"engine host exited with code "
+                          f"{self.host_exit_code} on shutdown")
             self._proc = None
         if self._reader is not None:
             self._reader.cancel()
@@ -2005,6 +2035,9 @@ class TpuNativeBackend(InferenceBackend):
                 except Exception as exc:  # noqa: BLE001 — any spawn failure
                     self._respawn_failures += 1
                     await self._reap_host()
+                    if isinstance(exc, BackendNoChipError):
+                        # The next life would get the same platform.
+                        self._respawn_failures = self._max_respawns
                     if self._respawn_failures >= self._max_respawns:
                         self._circuit_open = True
                         log.error(

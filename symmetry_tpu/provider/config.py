@@ -273,7 +273,8 @@ class TpuConfig:
     # (engine/weights.py save_warm_cache). SURVEY §5.4 warm restart.
     warm_cache: bool = True
     # Persistent XLA compilation cache (utils/compile_cache.py): True →
-    # ~/.cache/symmetry_tpu/xla, a string → that directory, False → off.
+    # <checkout>/.jax_cache, a string → that directory, False → off;
+    # JAX_COMPILATION_CACHE_DIR, when set, places it instead of either.
     # A config-identical engine restart then compiles ~nothing.
     compile_cache: Any = True
     tokenizer_path: str | None = None   # tokenizer.json; None → byte tokenizer

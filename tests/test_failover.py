@@ -471,8 +471,10 @@ class TestFailover:
                         "mem://server", ident.public_key, "tiny:fo",
                         [{"role": "user", "content": "x"}]):
                     pass
-            # default: one jittered retry round re-tried both providers
-            assert p1.metrics["shed"] + p2.metrics["shed"] == 4
+            # default: one jittered retry round re-tried both providers —
+            # two rounds of two sheds, on top of the first call's two
+            # (`shed` is cumulative).
+            assert p1.metrics["shed"] + p2.metrics["shed"] == 2 + 4
             await p1.stop(drain_timeout_s=1)
             await p2.stop(drain_timeout_s=1)
             await server.stop()

@@ -37,13 +37,11 @@ import os
 import sys
 import time
 
-# CPU pinning + shared compile cache BEFORE any jax import (the engine
-# hosts inherit this environment; the warm cache is what makes the
-# mid-run member spawn affordable).
+# CPU pinning BEFORE any jax import (the engine hosts inherit this
+# environment). Every host resolves the same compile cache
+# (utils/compile_cache.py); warm, it makes the mid-run member spawn
+# affordable.
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/symmetry-tpu-disagg-smoke-cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.3")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -1,0 +1,232 @@
+"""Compile every routed Pallas kernel ON THE CHIP and check it against the
+reference its test file uses — at mistral-7b's real geometry, which the CPU
+suite (interpret mode, tiny shapes) cannot reach: VMEM limits, (8, 128)
+trailing block dims on int8 payloads, and the int8 tile floors are checked
+by the Mosaic compiler only.
+
+  flash_prefill      S 128 and 2048; D 128, 32 Q / 8 KV heads, bf16
+                     vs tests/test_ops.py naive_attention
+  decode_attention   capacity 4096 and 8192, bf16 and int8 KV with
+                     [L, B, K, T] f32 scale planes
+                     vs ops/attention.py gqa_attention
+  w8a16_matmul       M 8 and 128 × the trunk's K×N (wq wk/wv wo wg/wu wd,
+                     LM head), through pack_quantized + w8a16_apply
+                     vs tests/test_qmm.py _reference_qmatmul
+
+Then the one timing question later PRs lean on: does
+`jax.block_until_ready` on this chip wait for completion? One decode block
+of chip_smoke.py's engine is timed under it, under the fetch fence of
+tools/_bench_util.sync, and with no fence at all (the enqueue).
+
+References run in float32 at highest matmul precision; tolerances are set
+from the dtype the kernel returns (bf16: 2 ulp). Writes
+chiprun_out/chip_kernels.json; exits non-zero if any kernel failed to
+compile or disagreed. Needs a TPU: `python tools/chip_kernels.py`.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from _bench_util import sync  # noqa: E402
+from symmetry_tpu.ops.attention import gqa_attention  # noqa: E402
+from symmetry_tpu.ops.decode_attention import decode_attention  # noqa: E402
+from symmetry_tpu.ops.flash import flash_prefill  # noqa: E402
+from symmetry_tpu.ops.interpret import interpret_mode  # noqa: E402
+from symmetry_tpu.ops.qmm import w8a16_apply  # noqa: E402
+from symmetry_tpu.ops.quant import (  # noqa: E402
+    PackedQuantizedTensor, pack_quantized, quantize, quantize_kv)
+from tests.test_ops import naive_attention  # noqa: E402
+from tests.test_qmm import _reference_qmatmul  # noqa: E402
+
+D, NQ, NKV, E, F, VOCAB = 128, 32, 8, 4096, 14336, 32768
+BF16_TOL = 2 * 2.0 ** -8   # two bf16 ulps, relative; absolute on O(1) values
+
+
+def check(name: str, fn, want: np.ndarray, tol: float) -> dict:
+    """Compile + run `fn`, compare. (main() refuses to start off a TPU, so
+    interpret_mode() is False in every call a chip run makes.)"""
+    row: dict = {"kernel": name}
+    t0 = time.perf_counter()
+    try:
+        got = np.asarray(fn(), np.float32)
+    except Exception as exc:  # noqa: BLE001 — the refusal IS the finding
+        row.update(ok=False, error=f"{type(exc).__name__}: {exc}"[:2000])
+        return row
+    row["compile_run_s"] = round(time.perf_counter() - t0, 2)
+    err = np.abs(got - want)
+    bound = tol * (1.0 + np.abs(want))
+    row.update(ok=bool(np.isfinite(got).all() and (err <= bound).all()),
+               max_abs_err=float(err.max()), tol=tol,
+               worst_over_bound=float((err / bound).max()))
+    return row
+
+
+def flash_cases(lengths=(128, 2048)) -> list[dict]:
+    rows = []
+    for S in lengths:
+        # B 2 keeps naive_attention's per-row numpy loops affordable.
+        B = 2
+        rng = np.random.default_rng(S)
+        q = rng.normal(size=(B, S, NQ, D)).astype(np.float32)
+        k = rng.normal(size=(B, S, NKV, D)).astype(np.float32)
+        v = rng.normal(size=(B, S, NKV, D)).astype(np.float32)
+        seq_lens = np.array([S, S // 2 + 3], np.int32)
+        to = lambda a: jnp.asarray(a, jnp.bfloat16)  # noqa: E731
+        # The reference sees the same bf16-rounded inputs, in float32. It
+        # checks a sample of query rows: the full S² loop at 2048 is hours.
+        rows_q = np.unique(np.concatenate(
+            [np.arange(0, S, max(1, S // 16)), [S - 1]]))
+        qs, ks, vs = (np.asarray(to(a), np.float32) for a in (q, k, v))
+        q_pos = np.broadcast_to(rows_q.astype(np.int32), (B, len(rows_q)))
+        want = naive_attention(qs[:, rows_q], ks, vs, q_pos, seq_lens)
+        valid = rows_q[None, :] < seq_lens[:, None]          # [B, rows]
+
+        def run(q=q, k=k, v=v, seq_lens=seq_lens, rows_q=rows_q,
+                valid=valid):
+            out = flash_prefill(to(q), to(k), to(v), jnp.asarray(seq_lens),
+                                interpret=interpret_mode())
+            got = np.asarray(out, np.float32)[:, rows_q]
+            return np.where(valid[..., None, None], got, 0.0)
+
+        rows.append(check(f"flash_prefill S={S} bf16", run,
+                          np.where(valid[..., None, None], want, 0.0),
+                          BF16_TOL))
+    return rows
+
+
+def decode_cases(capacities=(4096, 8192)) -> list[dict]:
+    rows = []
+    for T in capacities:
+        L, B, layer = 2, 8, 1
+        ks = jax.random.split(jax.random.key(T), 3)
+        q = jax.random.normal(ks[0], (B, NQ, D), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (L, B, T, NKV, D), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (L, B, T, NKV, D), jnp.bfloat16)
+        lengths = jnp.asarray(
+            [T - 3, 5, T // 2, 1, 513, 1024, T, 700][:B], jnp.int32)
+
+        def ref(kl, vl, ksc=None, vsc=None):
+            with jax.default_matmul_precision("highest"):
+                out = gqa_attention(
+                    q.astype(jnp.float32)[:, None], kl, vl,
+                    (lengths - 1)[:, None], lengths,
+                    k_scale=ksc, v_scale=vsc)
+            return np.asarray(out[:, 0], np.float32)
+
+        rows.append(check(
+            f"decode_attention T={T} bf16",
+            lambda: decode_attention(q, k, v, jnp.int32(layer), lengths,
+                                     interpret=interpret_mode()),
+            ref(k[layer].astype(jnp.float32), v[layer].astype(jnp.float32)),
+            BF16_TOL))
+        kq, ksc = quantize_kv(k)
+        vq, vsc = quantize_kv(v)
+        ksc, vsc = jnp.moveaxis(ksc, -1, -2), jnp.moveaxis(vsc, -1, -2)
+        rows.append(check(
+            f"decode_attention T={T} int8 KV",
+            lambda: decode_attention(q, kq, vq, jnp.int32(layer), lengths,
+                                     k_scale=ksc, v_scale=vsc,
+                                     interpret=interpret_mode()),
+            ref(kq[layer], vq[layer], ksc[layer], vsc[layer]), BF16_TOL))
+    return rows
+
+
+def matmul_cases() -> list[dict]:
+    rows = []
+    shapes = {"wq/wo": (E, NQ * D), "wk/wv": (E, NKV * D), "wg/wu": (E, F),
+              "wd": (F, E), "lm_head": (E, VOCAB)}
+    for name, (K, N) in shapes.items():
+        kw = jax.random.key(K + N)
+        qt = quantize(jax.random.normal(kw, (K, N), jnp.float32) * 0.05)
+        pt = pack_quantized(qt)
+        if not isinstance(pt, PackedQuantizedTensor):
+            rows.append({"kernel": f"w8a16 {name} {K}x{N}", "ok": False,
+                         "error": "pack_quantized left the leaf flat"})
+            continue
+        for M in (8, 128):
+            x = jax.random.normal(jax.random.key(M), (M, K), jnp.bfloat16)
+            want = _reference_qmatmul(np.asarray(x, np.float32), qt)
+            rows.append(check(
+                f"w8a16 {name} M={M} {K}x{N} tiles{pt.q.shape[-2:]}",
+                lambda x=x, pt=pt: w8a16_apply(x, pt.q, pt.scale),
+                want, BF16_TOL))
+    return rows
+
+
+def fence_timing() -> dict:
+    """One decode block of chip_smoke.py's engine (mistral-7b int8+kv8,
+    8 slots × 4096, block 16), 10 blocks per fence."""
+    import chip_smoke
+    from symmetry_tpu.engine.engine import InferenceEngine
+    from symmetry_tpu.provider.config import TpuConfig
+
+    tpu = TpuConfig.from_dict(
+        chip_smoke.provider_config("mistral-7b", 1)["tpu"])
+    engine = InferenceEngine.from_tpu_config(tpu)
+    for _ in range(3):                       # compile + settle
+        sync(engine.decode_steps_dispatch())
+
+    def timed(fence) -> list[float]:
+        out = []
+        for _ in range(10):
+            t0 = time.perf_counter()
+            toks = engine.decode_steps_dispatch()
+            fence(toks)
+            out.append(time.perf_counter() - t0)
+            sync(toks)                       # drain before the next sample
+        return sorted(out)
+
+    res = {name: timed(fence) for name, fence in (
+        ("enqueue_only", lambda t: None),
+        ("block_until_ready", jax.block_until_ready),
+        ("fetch_fence", sync))}
+    report = {f"{k}_ms": {"p50": round(1e3 * v[len(v) // 2], 3),
+                          "min": round(1e3 * v[0], 3),
+                          "max": round(1e3 * v[-1], 3)}
+              for k, v in res.items()}
+    bur = res["block_until_ready"][len(res["block_until_ready"]) // 2]
+    fetch = res["fetch_fence"][len(res["fetch_fence"]) // 2]
+    report["block_until_ready_over_fetch"] = round(bur / fetch, 4)
+    report["decode_step_ms_p50"] = round(1e3 * fetch / engine.decode_block,
+                                         3)
+    report["attention"] = engine.attention_paths()
+    return report
+
+
+def main() -> int:
+    if interpret_mode() or jax.default_backend() != "tpu":
+        print(f"chip_kernels needs a TPU; JAX gave "
+              f"{jax.default_backend()!r}", file=sys.stderr)
+        return 1
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": jax.device_count()}
+    rows = flash_cases() + decode_cases() + matmul_cases()
+    for r in rows:
+        print(json.dumps(r))
+    out = {"device": device, "jax": jax.__version__, "kernels": rows,
+           "fence": fence_timing()}
+    print(json.dumps(out["fence"]))
+    os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(REPO, "chiprun_out", "chip_kernels.json"),
+              "w") as fh:
+        json.dump(out, fh, indent=1)
+    failed = [r["kernel"] for r in rows if not r["ok"]]
+    print(json.dumps({"ok": not failed, "failed": failed, "device": device}))
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

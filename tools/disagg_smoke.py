@@ -63,13 +63,11 @@ import asyncio
 import os
 import sys
 
-# CPU pinning + shared compile cache BEFORE any jax import (the engine
-# hosts inherit this environment; the cache makes the post-crash respawn
-# a warm start, which is also what keeps this smoke affordable).
+# CPU pinning BEFORE any jax import (the engine hosts inherit this
+# environment). Every host resolves the same compile cache
+# (utils/compile_cache.py), which makes the post-crash respawn a warm
+# start and keeps this smoke affordable.
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/symmetry-tpu-disagg-smoke-cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.3")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -55,6 +55,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _bench_util import timeit  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from symmetry_tpu.ops.interpret import interpret_mode  # noqa: E402
 from symmetry_tpu.ops.qmm import w8a16_matmul  # noqa: E402
 from symmetry_tpu.ops.quant import pack_quantized, quantize  # noqa: E402
 
@@ -84,7 +85,7 @@ def main() -> None:
     K = int(os.environ.get("PROBE_K", 4096))
     N = int(os.environ.get("PROBE_N", 4 * 14336))
     ITERS = int(os.environ.get("PROBE_ITERS", 20))
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     if interpret:
         print("WARNING: no TPU backend — interpret mode measures the "
               "emulator, not the chip; table numbers must come from a "
