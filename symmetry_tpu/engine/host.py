@@ -78,7 +78,11 @@ Protocol: JSON lines.
             prefill_jobs_active, the prefix_cache hit/miss/evict/bytes
             block when the shared-prefix KV cache is enabled, and the
             speculative drafted/accepted/acceptance-rate block when
-            tpu.speculative is on)
+            tpu.speculative is on; `loop_s` / `loop_iters`: the engine
+            thread's seconds per loop phase; `compile`: what JAX traced,
+            lowered and compiled in this process — counts, seconds,
+            `at_ready` as they stood after warm-up, and the last 32
+            as [monotonic t, kind, fun_name, seconds])
 
 The batched `events` frame is the hot path: the scheduler coalesces each
 decode block's per-slot deltas (plus any finishes and admission errors
@@ -122,6 +126,7 @@ from symmetry_tpu.engine.scheduler import GenRequest, Scheduler
 from symmetry_tpu.protocol.keys import HOST_EXIT_NO_CHIP, HostOp
 from symmetry_tpu.provider.config import ConfigManager
 from symmetry_tpu.utils.device import NoChipError, device_report
+from symmetry_tpu.utils.devprof import CompileWatch
 from symmetry_tpu.utils.faults import FAULTS
 from symmetry_tpu.utils.logging import logger, set_component
 from symmetry_tpu.utils.metrics import METRICS, MetricName
@@ -157,6 +162,9 @@ class EngineHost:
         # directory, the device JAX handed this process and the
         # attention path of each program (READY and stats carry it).
         self._startup: dict[str, Any] = {}
+        # What JAX traced, lowered and compiled in this process (stats
+        # `compile` block); start() registers its listeners.
+        self._compile = CompileWatch()
         self._wlock = threading.Lock()
         self._cancelled: set[str] = set()
         self._reported: dict[str, int] = {}  # id -> tokens already reported
@@ -251,7 +259,17 @@ class EngineHost:
         if FAULTS.enabled and FAULTS.point("host.pipe_write"):
             return  # injected drop_frame: the frame is lost on the wire
         line = json.dumps(obj, separators=(",", ":"))
-        t0 = time.monotonic()
+        if events > 0:
+            # Event frames only (one per block): the flush hold is the
+            # "emit" leg of the TTFT chain, worth a span; ready/stats
+            # frames are not emit-path traffic.
+            with self.tracer.phase("host.pipe_flush", ring="pipe_flush",
+                                   events=events, bytes=len(line) + 1):
+                self._write_line(line, events)
+        else:
+            self._write_line(line, events)
+
+    def _write_line(self, line: str, events: int) -> None:
         with self._wlock:
             self.emit_stats["pipe_writes"] += 1
             self.emit_stats["pipe_events"] += events
@@ -266,12 +284,6 @@ class EngineHost:
         self._m_pipe_bytes.inc(len(line) + 1)
         if events:
             self._m_pipe_events.inc(events)
-        if events > 0:
-            # Event frames only (one per block): the flush hold is the
-            # "emit" leg of the TTFT chain, worth a span; ready/stats
-            # frames are not emit-path traffic.
-            self.tracer.record("pipe_flush", t0, time.monotonic() - t0,
-                               events=events, bytes=len(line) + 1)
 
     def _event_dict(self, req_id: str, ev: "TokenEvent") -> dict[str, Any]:
         """One event's wire fields (shared by legacy and batched frames),
@@ -345,6 +357,7 @@ class EngineHost:
         # recompiles the full serving grid; with it a config-identical
         # restart compiles ~nothing.
         cache_dir = enable_compile_cache(self._config.tpu)
+        self._compile.register()
         t0 = time.perf_counter()
         self._engine = InferenceEngine.from_tpu_config(self._config.tpu)
         t_build = time.perf_counter() - t0
@@ -363,6 +376,7 @@ class EngineHost:
         t1 = time.perf_counter()
         sched_engine.warmup()
         t_warmup = time.perf_counter() - t1
+        self._compile.mark_ready()
         self._scheduler = Scheduler(
             sched_engine, emit_batch=self._emit_batch,
             pipeline_depth=int(getattr(self._config.tpu,
@@ -456,6 +470,7 @@ class EngineHost:
                 m["emit"] = dict(self.emit_stats)
                 m["role"] = self._role
                 m["startup"] = self._startup
+                m["compile"] = self._compile.stats()
                 # Per-request emitted-token journal rider: the tokens
                 # each live stream has had WRITTEN to the pipe. The
                 # backend's supervisor keeps the last heartbeat's copy,
@@ -575,9 +590,18 @@ class EngineHost:
     # --------------------------------------------------------------- submit
 
     def _submit(self, msg: dict) -> None:
-        t_recv = time.monotonic()
+        """The pipe_in leg as a span: command read → tokenized →
+        enqueued."""
         req_id = str(msg.get("id", ""))
         trace_id = str(msg.get("trace") or "")
+        with self.tracer.phase("host.host_submit", ring="host_submit",
+                               request_id=req_id,
+                               trace_id=trace_id) as span:
+            self._submit_request(msg, req_id, trace_id, span)
+
+    def _submit_request(self, msg: dict, req_id: str, trace_id: str,
+                        span: dict[str, Any]) -> None:
+        t_recv = time.monotonic()
         s = msg.get("sampling") or {}
         resume = msg.get("resume") if isinstance(msg.get("resume"), dict) \
             else None
@@ -674,11 +698,7 @@ class EngineHost:
             # scheduler's admission check needs no cross-process offset.
             deadline_at=(t_recv + float(deadline)
                          if deadline is not None else None)))
-        # The pipe_in leg as a span: command read → tokenized → enqueued.
-        self.tracer.record("host_submit", t_recv,
-                           time.monotonic() - t_recv,
-                           request_id=req_id, trace_id=trace_id,
-                           prompt_len=len(prompt_ids))
+        span["prompt_len"] = len(prompt_ids)
 
     # -------------------------------------------------------------- disagg
 

@@ -22,6 +22,7 @@ request has a live stream; cache length never exceeds capacity.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import queue
 import threading
 import time
@@ -146,6 +147,15 @@ class _ActiveSlot:
     prompt_len: int = 0
     first_token_at: float | None = None
     stages_sent: bool = False
+
+
+# The phases that partition the engine thread's loop (Scheduler._phase):
+# sync = device→host wait for a block's tokens; process = the rest of block
+# processing; dispatch = decode/verify dispatch; admit = _admit_new; chunks
+# = _advance_prefills; flush = _flush_events; wait = blocked on an empty
+# inbox. stats()["loop_s"] carries the seconds of each.
+LOOP_PHASES = ("sync", "process", "dispatch", "admit", "chunks", "flush",
+               "wait")
 
 
 class Scheduler:
@@ -315,7 +325,11 @@ class Scheduler:
                         # offloaded_s (emit-worker wall), lives in
                         # _wmetrics — the split is the CPU-verifiable
                         # proxy for dispatch_gap_share -> ~0.
-                        "dispatch_thread_s": 0.0}
+                        "dispatch_thread_s": 0.0,
+                        # Iterations of the engine thread's loop; with
+                        # stats()["loop_s"] (seconds per loop phase) the
+                        # per-iteration cost of each phase.
+                        "loop_iters": 0}
         from symmetry_tpu.utils.metrics import METRICS, MetricName
         from symmetry_tpu.utils.trace import Histogram, Tracer
 
@@ -379,6 +393,8 @@ class Scheduler:
         # blocking call inside it. ~10 records per block: noise next to
         # the device sync it sits beside.
         self.tracer = Tracer(capacity=8192)
+        # The loop phase the engine thread is in (see _phase).
+        self._open_phase: Any = None
         # Engine-side latency distributions: TTFT as the scheduler saw it
         # (enqueue → first sampled token), admission dispatch wall, and the
         # interval between consecutive decode-block syncs while streams are
@@ -471,6 +487,12 @@ class Scheduler:
         out: dict[str, Any] = dict(self.metrics)
         out["role"] = self._role
         out["occupancy"] = len(self._slots)
+        # Where the engine thread's wall went, cumulative seconds per loop
+        # phase (_phase): the seven partition the loop, so their sum is
+        # dispatch_thread_s plus the idle wait.
+        out["loop_s"] = {name: round(
+            self.tracer.phase_s.get("sched." + name, 0.0), 6)
+            for name in LOOP_PHASES}
         if self._adopt_hist.count:
             out["adopt_dispatch_s"] = self._adopt_hist.to_dict()
         # Gauges for the two admission backlogs that were invisible in
@@ -584,6 +606,26 @@ class Scheduler:
 
     # ------------------------------------------------------------- the loop
 
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One phase of the engine thread's loop (LOOP_PHASES): a
+        Tracer.phase named `sched.<name>`. Phases partition the thread's
+        time, one level deep: a phase entered inside another (the
+        cold-burst flush inside admission, the device sync inside block
+        processing) suspends the outer one for its length."""
+        outer = self._open_phase
+        if outer is not None:
+            outer.__exit__(None, None, None)
+        phase = self._open_phase = self.tracer.phase("sched." + name)
+        phase.__enter__()
+        try:
+            yield
+        finally:
+            phase.__exit__(None, None, None)
+            self._open_phase = outer
+            if outer is not None:
+                outer.__enter__()
+
     def _run(self) -> None:
         """Thread target: contain crashes so no stream ever hangs open."""
         try:
@@ -658,6 +700,11 @@ class Scheduler:
         the resulting events exactly like the inline _flush_events path:
         one emit_batch call with a sink installed, else per-event
         req.emit. Worker thread; books into _wmetrics only."""
+        with self.tracer.phase("emit.emit_flush",
+                               ring="emit_flush") as span:
+            self._deliver(jobs, span)
+
+    def _deliver(self, jobs: list[tuple], span: dict[str, Any]) -> None:
         t0 = time.monotonic()
         batch: list[tuple[GenRequest, TokenEvent]] = []
         for job in jobs:
@@ -668,6 +715,7 @@ class Scheduler:
                 continue
             if pair is not None:
                 batch.append(pair)
+        span["events"] = len(batch)
         if not batch:
             return
         self._wmetrics["emit_flushes"] += 1
@@ -677,8 +725,6 @@ class Scheduler:
                 self._emit_batch(batch)
             except Exception as exc:  # noqa: BLE001 — must never kill the worker
                 log.error(f"emit batch sink failed: {exc}")
-            self.tracer.record("emit_flush", t0, time.monotonic() - t0,
-                               events=len(batch))
         else:
             for req, ev in batch:
                 try:
@@ -770,6 +816,7 @@ class Scheduler:
         pending: deque[tuple] = deque()
         while True:
             t_iter = time.perf_counter()
+            self.metrics["loop_iters"] += 1
             self._spent_this_block = 0.0
             # Dispatch the next block BEFORE this iteration's admission
             # work: decode blocks sit at the FRONT of the device queue and
@@ -814,17 +861,20 @@ class Scheduler:
                     while pending:
                         self._process_pending(pending.popleft())
                 if self._slots and not pending:
-                    vb = self._maybe_verify_block()
+                    with self._phase("dispatch"):
+                        vb = self._maybe_verify_block()
                     if vb is not None:
                         pending.append(vb)
                         did_dispatch = did_verify = True
             if self._slots and not did_dispatch and len(pending) <= self._depth:
-                pending.append((
-                    "decode_block", self.engine.decode_steps_dispatch(),
-                    dict(self._slots), time.monotonic(), None))
+                with self._phase("dispatch"):
+                    pending.append((
+                        "decode_block", self.engine.decode_steps_dispatch(),
+                        dict(self._slots), time.monotonic(), None))
                 self.metrics["steps"] += self.engine.decode_block
                 did_dispatch = True
-            drained = self._admit_new()
+            with self._phase("admit"):
+                drained = self._admit_new()
             if not self._slots and not pending and not self._prefill_jobs:
                 # Terminal/error events from the admission pass must reach
                 # their consumers BEFORE blocking on an empty inbox.
@@ -845,8 +895,9 @@ class Scheduler:
                 # the distributed runtime's timeout.
                 tick = getattr(self.engine, "idle_tick", None)
                 try:
-                    item = self._inbox.get(
-                        timeout=10.0 if tick is not None else None)
+                    with self._phase("wait"):
+                        item = self._inbox.get(
+                            timeout=10.0 if tick is not None else None)
                 except queue.Empty:
                     tick()
                     continue
@@ -858,7 +909,8 @@ class Scheduler:
                 # would reorder it BEHIND arrivals that raced in while we
                 # were blocked — inverted FIFO for the earliest request).
                 t_iter = time.perf_counter()
-                self._admit_new(carry=item)
+                with self._phase("admit"):
+                    self._admit_new(carry=item)
                 self._flush_events()
                 self.metrics["dispatch_thread_s"] += (
                     time.perf_counter() - t_iter)
@@ -873,7 +925,8 @@ class Scheduler:
             # number of chunk dispatches per block keeps long-prompt
             # admission from stalling active streams for more than ~a
             # chunk's device time.
-            self._advance_prefills()
+            with self._phase("chunks"):
+                self._advance_prefills()
             # Admission-time events (first tokens from placement, chunked-
             # prefill finishes, admission errors) leave NOW, before the
             # device sync below can hold them for up to a whole block —
@@ -906,16 +959,22 @@ class Scheduler:
                 self._check_invariants()
 
     def _process_pending(self, blk: tuple) -> None:
-        """Sync + process one in-flight pipeline entry (FIFO order).
+        """Sync + process one in-flight pipeline entry (FIFO order): the
+        loop's `process` phase, which the device→host waits inside it
+        suspend as `sync`."""
+        with self._phase("process"):
+            self._sync_and_process(blk)
 
-        Verify entries book their speculative accounting HERE, at sync
+    def _sync_and_process(self, blk: tuple) -> None:
+        """Verify entries book their speculative accounting HERE, at sync
         time — the dispatch ran up to `pipeline_depth` iterations ago,
         overlapped with admission and emit work (spec_verify_s is
         therefore dispatch -> sync wall, not pure device time)."""
         kind, toks_dev, snapshot, t0m, extra = blk
         if kind == "verify":
             n_emit_dev, n_draft, proposed = extra
-            n_emit = np.asarray(n_emit_dev)
+            with self._phase("sync"):
+                n_emit = np.asarray(n_emit_dev)
             dt = time.monotonic() - t0m
             accepted = int(np.sum(np.minimum(n_emit - 1, n_draft)))
             self.tracer.record("verify_dispatch", t0m, dt,
@@ -978,9 +1037,10 @@ class Scheduler:
         discarded from the counters too, so the engine-side number sums
         to exactly the bench's tokens_streamed. tokens_generated keeps
         counting the EOS (the budget convention)."""
-        t0 = time.perf_counter()
-        toks = np.asarray(device_toks)  # blocks on THIS block only
-        t1 = time.perf_counter()
+        with self._phase("sync"):
+            t0 = time.perf_counter()
+            toks = np.asarray(device_toks)  # blocks on THIS block only
+            t1 = time.perf_counter()
         self.metrics["block_syncs"] += 1
         self.metrics["sync_s"] += t1 - t0
         # Same-kind-only intervals: a decode_block -> decode_block gap is
@@ -1428,21 +1488,31 @@ class Scheduler:
                         self._free.append(slot)
                         self._deferred.append(req)
                 break
-            t0m = time.monotonic()
+            # Decode tier: a cached-unit dispatch is handoff ADOPTION
+            # (seed copy + suffix), not admission prefill — booked apart
+            # so this host's admit_* wall reads zero and the trace row
+            # names the work. (A p==0 routing-only handoff still
+            # full-prefills here and rightly counts as admit.)
+            adopting = hit is not None and self._role == "decode"
             t0 = time.perf_counter()
             try:
-                if hit is not None:
-                    firsts = self.engine.prefill_and_insert_cached(
-                        [(slot, req.prompt_ids, req.sampling)
-                         for slot, req in sub], hit)
-                elif len(sub) > 1:
-                    firsts = self.engine.prefill_and_insert_many(
-                        [(slot, req.prompt_ids, req.sampling)
-                         for slot, req in sub])
-                else:
-                    slot0, req0 = sub[0]
-                    firsts = [self.engine.prefill_and_insert(
-                        slot0, req0.prompt_ids, req0.sampling)]
+                with self.tracer.phase(
+                        "engine.prefill",
+                        ring="adopt_dispatch" if adopting
+                        else "prefill_dispatch",
+                        n=len(sub), cached=hit is not None):
+                    if hit is not None:
+                        firsts = self.engine.prefill_and_insert_cached(
+                            [(slot, req.prompt_ids, req.sampling)
+                             for slot, req in sub], hit)
+                    elif len(sub) > 1:
+                        firsts = self.engine.prefill_and_insert_many(
+                            [(slot, req.prompt_ids, req.sampling)
+                             for slot, req in sub])
+                    else:
+                        slot0, req0 = sub[0]
+                        firsts = [self.engine.prefill_and_insert(
+                            slot0, req0.prompt_ids, req0.sampling)]
             except Exception as exc:  # noqa: BLE001 — engine errors → stream error
                 n_dispatches += 1  # a failed dispatch still cost time
                 self._spent_this_block += time.perf_counter() - t0
@@ -1457,31 +1527,22 @@ class Scheduler:
             dt = time.perf_counter() - t0
             n_dispatches += 1
             self._spent_this_block += dt
-            if hit is not None and self._role == "decode":
-                # Decode tier: a cached-unit dispatch is handoff ADOPTION
-                # (seed copy + suffix), not admission prefill — book it
-                # apart so this host's admit_* wall reads zero and the
-                # trace row names the work. (A p==0 routing-only handoff
-                # still full-prefills here and rightly counts as admit.)
+            if adopting:
                 self.metrics["adopt_dispatches"] += 1
                 self.metrics["adopt_s"] += dt
                 self._adopt_hist.observe(dt)
-                self.tracer.record("adopt_dispatch", t0m, dt, n=len(sub))
                 self._m_dispatch.observe(dt, kind="adopt")
             else:
                 self.metrics["admit_dispatches"] += 1
                 self.metrics["admit_s"] += dt
                 self._admit_hist.observe(dt)
-                self.tracer.record("prefill_dispatch", t0m, dt, n=len(sub),
-                                   cached=hit is not None)
                 self._m_dispatch.observe(dt, kind="prefill")
             if self.ledger.enabled and dt > 0.0:
                 # Prefill/adopt attribution is EXACT (the dispatch names
                 # its requests): the unit wall splits across members by
                 # suffix length, and a radix hit's avoided prefix is
                 # priced at this very dispatch's per-token rate.
-                phase = ("adopt" if hit is not None
-                         and self._role == "decode" else "prefill")
+                phase = "adopt" if adopting else "prefill"
                 sfx = [max(1, len(req.prompt_ids) - req.reused_tokens)
                        for _s, req in sub]
                 rate = dt / sum(sfx)
@@ -1529,10 +1590,12 @@ class Scheduler:
                     text="", token_id=None, done=True,
                     finish_reason="cancelled"))
                 continue
-            t0m = time.monotonic()
             t0 = time.perf_counter()
             try:
-                first = self.engine.advance_chunked_prefill(job)
+                with self.tracer.phase("engine.chunk", ring="chunk_dispatch",
+                                       request_id=req.id,
+                                       trace_id=req.trace_id):
+                    first = self.engine.advance_chunked_prefill(job)
             except Exception as exc:  # noqa: BLE001 — fail one, not all
                 self._prefill_jobs.pop(0)
                 self._free.append(job.slot)
@@ -1545,8 +1608,6 @@ class Scheduler:
             self.metrics["chunk_dispatches"] += 1
             self.metrics["chunk_s"] += dt
             self._spent_this_block += dt
-            self.tracer.record("chunk_dispatch", t0m, dt,
-                               request_id=req.id, trace_id=req.trace_id)
             self._m_dispatch.observe(dt, kind="chunk")
             if req.ledger is not None:
                 req.ledger.book_device("chunk", dt)
@@ -1756,7 +1817,12 @@ class Scheduler:
         is full — the backpressure that bounds memory under a slow
         pipe). Offload off: deliver everything buffered inline — one
         emit_batch call when a sink is installed (→ one host-pipe frame
-        per block), else per-event req.emit delivery."""
+        per block), else per-event req.emit delivery. The loop's `flush`
+        phase."""
+        with self._phase("flush"):
+            self._flush_pending()
+
+    def _flush_pending(self) -> None:
         if self._emit_offload:
             if self._block_jobs:
                 jobs, self._block_jobs = self._block_jobs, []
@@ -1769,12 +1835,13 @@ class Scheduler:
         self.metrics["emit_events"] += len(batch)
         if self._emit_batch is not None:
             t0 = time.monotonic()
-            try:
-                self._emit_batch(batch)
-            except Exception as exc:  # noqa: BLE001 — must never kill the loop
-                log.error(f"emit batch sink failed: {exc}")
+            with self.tracer.phase("emit.emit_flush", ring="emit_flush",
+                                   events=len(batch)):
+                try:
+                    self._emit_batch(batch)
+                except Exception as exc:  # noqa: BLE001 — must never kill the loop
+                    log.error(f"emit batch sink failed: {exc}")
             dt = time.monotonic() - t0
-            self.tracer.record("emit_flush", t0, dt, events=len(batch))
             if self.ledger.enabled and dt > 0.0:
                 per = dt / len(batch)
                 for req, _ev in batch:

@@ -47,7 +47,13 @@ Results flow three ways, mirroring every other instrument:
 full `jax.profiler` trace (HLO timelines, HBM) for a bounded window,
 triggered by the HostOp.PROFILE pipe op (provider wire op, SIGUSR1, or
 the SLO burn-rate breach hook alongside the flight recorder) and
-dumped as a linkable TensorBoard/Perfetto artifact.
+dumped as a linkable TensorBoard/Perfetto artifact. While it runs, every
+`Tracer.phase` in the process also enters its `sym.*` TraceAnnotation
+(utils/trace.py), so the capture's host plane carries the scheduler-loop
+phases on the device rows' clock.
+
+`CompileWatch` counts what JAX traces, lowers and compiles in the engine
+host, from `jax.monitoring` events — the stats op's `compile` block.
 """
 
 from __future__ import annotations
@@ -55,10 +61,12 @@ from __future__ import annotations
 import os
 import threading
 import time
+from collections import deque
 from typing import Any
 
 from symmetry_tpu.utils.metrics import METRICS, MetricName
-from symmetry_tpu.utils.trace import Histogram, Tracer
+from symmetry_tpu.utils.trace import (
+    Histogram, Tracer, set_capture_active)
 
 # The dispatch kinds the engine wraps. A probe with an unknown kind
 # still records (the set is documentation + the smoke's assertion
@@ -208,6 +216,82 @@ class DeviceProfiler:
         return self.tracer.component(name)
 
 
+# --------------------------------------------------- lowerings and compiles
+
+class CompileWatch:
+    """Counts what JAX traces, lowers and compiles in this process, from
+    the `jax.monitoring` events JAX itself records — the operator's answer
+    to "which step recompiled", and the benchmark's count of lowerings
+    inside a window. Cumulative since `register()`; `mark_ready()` keeps
+    the counts as they stood when warm-up ended, so growth past them is
+    work the serving loop paid for. A listener body is an add and an
+    append under a lock."""
+
+    EVENTS = {
+        "/jax/core/compile/jaxpr_trace_duration": ("traces", "trace_s"),
+        "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            ("lowerings", "lower_s"),
+        "/jax/core/compile/backend_compile_duration":
+            ("backend_compiles", "backend_s"),
+    }
+    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+    RECENT = 32
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._counts: dict[str, float] = {
+            key: 0 for pair in self.EVENTS.values() for key in pair}
+        self._counts["cache_hits"] = 0
+        self._recent: deque[tuple[float, str, str, float]] = deque(
+            maxlen=self.RECENT)
+        self._at_ready: dict[str, float] | None = None
+
+    def register(self) -> None:
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def unregister(self) -> None:
+        from jax import monitoring
+
+        monitoring.unregister_event_duration_listener(self._on_duration)
+        monitoring.unregister_event_listener(self._on_event)
+
+    def _on_duration(self, event: str, duration: float,
+                     **kwargs: Any) -> None:
+        keys = self.EVENTS.get(event)
+        if keys is None:
+            return
+        with self._lock:
+            self._counts[keys[0]] += 1
+            self._counts[keys[1]] += duration
+            self._recent.append((time.monotonic(), keys[0],
+                                 str(kwargs.get("fun_name", "")), duration))
+
+    def _on_event(self, event: str, **kwargs: Any) -> None:
+        if event == self.CACHE_HIT:
+            with self._lock:
+                self._counts["cache_hits"] += 1
+
+    def _snapshot(self) -> dict[str, float]:
+        out = dict(self._counts)
+        out["host_s"] = out["trace_s"] + out["lower_s"] + out["backend_s"]
+        return out
+
+    def mark_ready(self) -> None:
+        with self._lock:
+            self._at_ready = self._snapshot()
+
+    def stats(self) -> dict[str, Any]:
+        with self._lock:
+            out: dict[str, Any] = self._snapshot()
+            out["at_ready"] = self._at_ready
+            out["recent"] = [[round(t, 4), kind, name, round(s, 6)]
+                             for t, kind, name, s in self._recent]
+        return out
+
+
 # ------------------------------------------------------ on-demand capture
 
 # One capture at a time per process: jax.profiler refuses concurrent
@@ -244,8 +328,14 @@ def capture_device_profile(out_dir: str, duration_s: float = 2.0) -> str:
         os.makedirs(path, exist_ok=True)
         jax.profiler.start_trace(path)
         try:
-            time.sleep(max(0.0, float(duration_s)))
+            # From here to stop_trace every Tracer.phase in this process
+            # also enters its `sym.*` annotation; the capture thread names
+            # its own sleep so no reader mistakes it for idle host time.
+            set_capture_active(True)
+            with jax.profiler.TraceAnnotation("sym.capture"):
+                time.sleep(max(0.0, float(duration_s)))
         finally:
+            set_capture_active(False)
             jax.profiler.stop_trace()
         return path
     finally:

@@ -12,9 +12,11 @@ byte counts on the peer object. Here the equivalents are first-class:
     percentile estimates — p50/p99 TTFT is the BASELINE.json north-star
     metric, so it must be computable from a running provider, not from
     offline logs.
-  - device_trace: on-demand jax.profiler capture for the TPU engine (the
-    "trace capture endpoint" of SURVEY §5.1); writes a TensorBoard-loadable
-    trace directory.
+  - Tracer.phase: one timed section that is a ring record, a cumulative
+    seconds-per-label counter and — only while a jax.profiler capture is
+    active in this process (utils/devprof.capture_device_profile) — a
+    `sym.<label>` TraceAnnotation, so the program's own spans sit on the
+    device trace's clock in every capture.
 
 Request-scoped distributed tracing (PR 5) builds on the same rings:
 
@@ -40,7 +42,6 @@ Request-scoped distributed tracing (PR 5) builds on the same rings:
 from __future__ import annotations
 
 import bisect
-import contextlib
 import json
 import math
 import os
@@ -50,7 +51,7 @@ import time
 import uuid
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Iterator
+from typing import Any
 
 
 def _log_buckets(lo: float, hi: float, per_decade: int = 5) -> list[float]:
@@ -195,6 +196,58 @@ class Span:
                 "trace_id": self.trace_id, **self.attrs}
 
 
+# jax.profiler.TraceAnnotation while a capture runs in this process, else
+# None: the one flag a phase tests. utils/devprof.capture_device_profile
+# sets and clears it around start_trace/stop_trace, so a process that is not
+# being captured enters no annotation and constructs nothing.
+_annotation: Any = None
+
+
+def set_capture_active(active: bool) -> None:
+    global _annotation
+    if active:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    else:
+        _annotation = None
+
+
+class _Phase:
+    """One Tracer.phase section. Re-enterable: the scheduler suspends a
+    loop phase around a nested one by exiting and re-entering it."""
+
+    __slots__ = ("_tracer", "_label", "_ring", "_attrs", "_t0", "_ann")
+
+    def __init__(self, tracer: "Tracer", label: str, ring: str,
+                 attrs: dict[str, Any]) -> None:
+        self._tracer = tracer
+        self._label = label
+        self._ring = ring
+        self._attrs = attrs
+        self._ann = None
+
+    def __enter__(self) -> dict[str, Any]:
+        if _annotation is not None:
+            self._ann = _annotation("sym." + self._label, **self._attrs)
+            self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self._attrs
+
+    def __exit__(self, *exc: Any) -> None:
+        dt = time.monotonic() - self._t0
+        if self._ann is not None:
+            # Entered under a capture; exiting after stop_trace is a no-op
+            # inside the profiler.
+            self._ann.__exit__(*exc)
+            self._ann = None
+        tracer = self._tracer
+        with tracer._lock:
+            tracer.phase_s[self._label] = (
+                tracer.phase_s.get(self._label, 0.0) + dt)
+        tracer.record(self._ring, self._t0, dt, **self._attrs)
+
+
 class Tracer:
     """Bounded ring of completed spans + named histograms.
 
@@ -212,22 +265,22 @@ class Tracer:
         self._counters: deque[tuple[float, str, float]] = deque(
             maxlen=capacity)
         self._hists: dict[str, Histogram] = {}
+        # Cumulative seconds per phase label since process start. Plain
+        # counters: they grow whether or not the rings are enabled.
+        self.phase_s: dict[str, float] = {}
         self._lock = threading.Lock()
 
-    @contextlib.contextmanager
-    def span(self, name: str, request_id: str = "", trace_id: str = "",
-             **attrs: Any) -> Iterator[dict[str, Any]]:
-        """Times the enclosed block. Yields the attrs dict so the block can
+    def phase(self, label: str, ring: str | None = None,
+              **attrs: Any) -> _Phase:
+        """A timed section named `<component>.<name>` (`sched.sync`,
+        `engine.prefill`, `host.pipe_flush`). On exit it (a) records the
+        span in the ring as `ring` (default: the label), (b) adds its
+        seconds to `phase_s[label]`, and (c) only while a profiler capture
+        is active in this process, it ran inside
+        `TraceAnnotation("sym.<label>", **attrs)` — the same interval on
+        the device trace's clock. Yields the attrs dict, so the block can
         annotate the span (e.g. token counts) before it closes."""
-        if not self.enabled:
-            yield attrs
-            return
-        t0 = time.monotonic()
-        try:
-            yield attrs
-        finally:
-            self.record(name, t0, time.monotonic() - t0,
-                        request_id=request_id, trace_id=trace_id, **attrs)
+        return _Phase(self, label, ring or label, attrs)
 
     def record(self, name: str, start: float, duration_s: float,
                request_id: str = "", trace_id: str = "",
@@ -281,12 +334,6 @@ class Tracer:
         with self._lock:
             hists = dict(self._hists)
         return {name: h.to_dict() for name, h in hists.items()}
-
-    def clear(self) -> None:
-        with self._lock:
-            self._spans.clear()
-            self._counters.clear()
-            self._hists.clear()
 
 
 # --------------------------------------------------------------- perfetto
@@ -415,19 +462,3 @@ class FlightRecorder:
         with open(path, "w", encoding="utf-8") as fh:
             json.dump(payload, fh)
         return path
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str) -> Iterator[None]:
-    """Capture a jax.profiler device trace for the enclosed block.
-
-    The TPU-era answer to the reference stack's dormant hypertrace hooks:
-    wraps engine work in an XLA/TPU profile (HLO timelines, HBM usage),
-    viewable in TensorBoard or Perfetto."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

@@ -1,0 +1,378 @@
+"""The engine thread's loop phases, their annotations, and the lowering
+counters (PR 25).
+
+  - the partition: on a fake engine with real dispatch and sync walls, the
+    `sched.*` ring records of the running loop never overlap and their
+    cumulative seconds (`stats()["loop_s"]`) account for the thread's wall
+    (`dispatch_thread_s` + the idle wait) to within 5%;
+  - the annotations: no capture → no `TraceAnnotation` is ever constructed
+    and a phase costs microseconds; a CPU capture of a tiny engine that is
+    serving puts `sym.capture`, `sym.sched.sync` and `sym.sched.admit` on
+    the trace's host plane; the flag is cleared when a capture raises;
+  - `CompileWatch`: a new jitted function is one lowering, a repeated call
+    is none, `recent` is bounded;
+  - `stats` keeps every key the benchmark's correctness check reads.
+"""
+
+import glob
+import os
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from symmetry_tpu.engine.engine import SamplingParams
+from symmetry_tpu.engine.scheduler import LOOP_PHASES, GenRequest, Scheduler
+from symmetry_tpu.engine.tokenizer import ByteTokenizer
+from symmetry_tpu.utils import devprof, trace
+from symmetry_tpu.utils.trace import Tracer
+
+WALL = 0.003
+
+
+class LazyBlock:
+    """A block still on the device: np.asarray waits for it."""
+
+    def __init__(self, arr):
+        self.arr = arr
+        self.shape = arr.shape
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(WALL)
+        return self.arr
+
+
+class FakeJob:
+    def __init__(self, slot):
+        self.slot = slot
+        self.chunks = 0
+
+
+class FakeEngine:
+    """The scheduler-facing engine contract with fixed walls: batched and
+    chunked admission, a decode block whose sync blocks."""
+
+    def __init__(self, slots=4, block=4):
+        self.max_slots = slots
+        self.decode_block = block
+        self.slot_capacity = 4096
+        self.tokenizer = ByteTokenizer()
+        self.prefill_buckets = (32, 128)
+
+    def bucket_for(self, n):
+        return 32 if n <= 32 else 128
+
+    def prefill_batches_for(self, bucket):
+        return (4,)
+
+    def wants_chunked(self, n):
+        return n >= 64
+
+    def start_chunked_prefill(self, slot, ids, sampling, hit=None):
+        return FakeJob(slot)
+
+    def advance_chunked_prefill(self, job):
+        time.sleep(WALL)
+        job.chunks += 1
+        return ord("A") if job.chunks >= 2 else None
+
+    def prefill_and_insert(self, slot, ids, sampling):
+        time.sleep(WALL)
+        return ord("A")
+
+    def prefill_and_insert_many(self, group):
+        time.sleep(WALL)
+        return [ord("A")] * len(group)
+
+    def decode_steps_dispatch(self):
+        time.sleep(WALL / 10)
+        return LazyBlock(np.full((self.decode_block, self.max_slots),
+                                 ord("b"), dtype=np.int32))
+
+    def release_slot(self, slot):
+        pass
+
+    def slot_length(self, slot):
+        return 0
+
+
+def drive(sched, n=10, max_new=24):
+    """Start the loop, serve `n` requests (every third one chunked), idle
+    a moment so the wait phase is entered, stop. Returns finished ids."""
+    done, lock = [], threading.Lock()
+
+    def emit_batch(batch):
+        with lock:
+            done.extend(req.id for req, ev in batch if ev.done)
+
+    sched._emit_batch = emit_batch
+    sched.start()
+    for i in range(n):
+        prompt = b"p" * (80 if i % 3 == 0 else 8)
+        sched.submit(GenRequest(
+            prompt_ids=list(prompt), sampling=SamplingParams(),
+            max_new_tokens=max_new, emit=lambda ev: None, id=f"r{i}"))
+        time.sleep(0.004)
+    deadline = time.monotonic() + 20
+    while len(done) < n and time.monotonic() < deadline:
+        time.sleep(0.01)
+    time.sleep(0.05)
+    sched.stop(timeout=10)
+    assert not sched._thread.is_alive()
+    return done
+
+
+class TestPartition:
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_phases_tile_the_engine_thread(self, depth):
+        sched = Scheduler(FakeEngine(), pipeline_depth=depth)
+        done = drive(sched)
+        assert sorted(done) == sorted(f"r{i}" for i in range(10))
+        stats = sched.stats()
+        loop_s = stats["loop_s"]
+        assert tuple(loop_s) == LOOP_PHASES
+        assert stats["loop_iters"] > 10
+        # every phase that this traffic exercises took time
+        for name in ("sync", "process", "dispatch", "admit", "chunks",
+                     "wait"):
+            assert loop_s[name] > 0, (name, loop_s)
+        # the ring's loop-phase records, in start order, never overlap
+        spans = sorted((s["start"], s["start"] + s["duration_s"], s["name"])
+                       for s in sched.tracer.export()
+                       if s["name"].startswith("sched."))
+        assert {n for _, _, n in spans} >= {
+            "sched." + p for p in LOOP_PHASES if p != "flush" or depth == 1}
+        for (s0, e0, n0), (s1, e1, n1) in zip(spans, spans[1:]):
+            assert s1 >= e0 - 1e-6, (n0, e0, n1, s1)
+        # and their seconds account for the thread's wall
+        wall = stats["dispatch_thread_s"] + loop_s["wait"]
+        assert sum(loop_s.values()) == pytest.approx(wall, rel=0.05)
+        assert sum(loop_s.values()) <= wall * 1.001
+
+    def test_child_spans_keep_their_ring_names(self):
+        sched = Scheduler(FakeEngine(), pipeline_depth=2)
+        drive(sched, n=4)
+        names = {s["name"] for s in sched.tracer.export()}
+        assert {"prefill_dispatch", "chunk_dispatch", "emit_flush"} <= names
+        phase_s = sched.tracer.phase_s
+        assert phase_s["engine.prefill"] > 0 and phase_s["engine.chunk"] > 0
+        # a child lies inside its loop phase, which therefore took longer
+        assert phase_s["sched.admit"] >= phase_s["engine.prefill"]
+        assert phase_s["sched.chunks"] >= phase_s["engine.chunk"]
+
+    def test_loop_s_does_not_depend_on_tracing(self):
+        sched = Scheduler(FakeEngine(), pipeline_depth=2)
+        sched.tracer.enabled = False
+        drive(sched, n=3)
+        assert not sched.tracer.export()
+        assert sched.stats()["loop_s"]["sync"] > 0
+
+    def test_stats_keep_what_the_benchmark_checks(self):
+        """`check_correct` compares the wire's tokens with the host's
+        `tokens`; the new blocks sit beside the old keys."""
+        sched = Scheduler(FakeEngine(), pipeline_depth=2)
+        drive(sched, n=5, max_new=24)
+        stats = sched.stats()
+        assert stats["tokens"] == 5 * 24 and stats["requests"] == 5
+        for key in ("admit_s", "admit_dispatches", "chunk_s", "sync_s",
+                    "dispatch_thread_s", "occupancy", "engine_ttft_s",
+                    "block_interval_s", "queue_depth"):
+            assert key in stats, key
+
+
+class TestAnnotations:
+    def test_no_capture_constructs_no_annotation(self, monkeypatch):
+        import jax.profiler
+
+        made = []
+
+        class Sentinel:
+            def __init__(self, name, **kwargs):
+                made.append((name, kwargs))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return None
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Sentinel)
+        assert trace._annotation is None
+        tracer = Tracer()
+        with tracer.phase("sched.sync"):
+            pass
+        sched = Scheduler(FakeEngine(), pipeline_depth=2)
+        drive(sched, n=2)
+        assert made == []
+        # ... and under a capture each phase enters exactly one
+        trace.set_capture_active(True)
+        try:
+            with tracer.phase("engine.prefill", ring="prefill_dispatch",
+                              n=3, cached=False):
+                pass
+        finally:
+            trace.set_capture_active(False)
+        assert made == [("sym.engine.prefill", {"n": 3, "cached": False})]
+        assert trace._annotation is None
+        assert [s["name"] for s in tracer.export()] == [
+            "sched.sync", "prefill_dispatch"]
+
+    def test_phase_overhead_guard(self):
+        """With the rings off and no capture a phase is two clock reads
+        and a dict add: the same bound as the other disabled-mode guards
+        (200k sites under half a second would be 2.5 us each; a phase is
+        allowed 5 us — a loop iteration enters about ten and lasts
+        hundreds of milliseconds on the chip)."""
+        tracer = Tracer()
+        tracer.enabled = False
+        t0 = time.perf_counter()
+        for _ in range(100_000):
+            with tracer.phase("sched.sync"):
+                pass
+        dt = time.perf_counter() - t0
+        assert dt < 0.5, f"{dt:.3f}s for 100k phases"
+        assert tracer.phase_s["sched.sync"] > 0 and not tracer.export()
+
+    def test_flag_cleared_when_a_capture_raises(self, monkeypatch, tmp_path):
+        import jax.profiler
+
+        seen = []
+        monkeypatch.setattr(jax.profiler, "start_trace", lambda path: None)
+        monkeypatch.setattr(jax.profiler, "stop_trace",
+                            lambda: seen.append(trace._annotation))
+
+        def boom(_s):
+            seen.append(trace._annotation)
+            raise RuntimeError("interrupted")
+
+        monkeypatch.setattr(devprof.time, "sleep", boom)
+        with pytest.raises(RuntimeError, match="interrupted"):
+            devprof.capture_device_profile(str(tmp_path), 0.1)
+        # active during the sleep, cleared before stop_trace and after
+        assert seen[0] is jax.profiler.TraceAnnotation and seen[1] is None
+        assert trace._annotation is None
+        assert devprof._capture_busy is False
+
+    def test_cpu_capture_of_a_serving_engine_holds_the_spans(self, tmp_path):
+        import jax
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        from symmetry_tpu.engine.engine import InferenceEngine
+        from symmetry_tpu.models import init_params, preset
+
+        cfg = preset("tiny")
+        engine = InferenceEngine(
+            cfg, init_params(cfg, jax.random.key(0), jnp.float32),
+            ByteTokenizer(), max_slots=2, max_seq_len=64,
+            prefill_buckets=(16,), cache_dtype=jnp.float32, decode_block=2)
+        engine.warmup()
+        sched = Scheduler(engine, pipeline_depth=2)
+        sched.start()
+        stop = threading.Event()
+
+        def traffic():
+            i = 0
+            while not stop.is_set():
+                sched.submit(GenRequest(
+                    prompt_ids=list(b"hello"), sampling=SamplingParams(),
+                    max_new_tokens=12, emit=lambda ev: None, id=f"c{i}"))
+                i += 1
+                time.sleep(0.02)
+
+        feeder = threading.Thread(target=traffic)
+        feeder.start()
+        try:
+            path = devprof.capture_device_profile(str(tmp_path), 0.4)
+        finally:
+            stop.set()
+            feeder.join(timeout=10)
+            sched.stop(timeout=30)
+        assert not feeder.is_alive() and not sched._thread.is_alive()
+        assert trace._annotation is None
+        (pb,) = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                          recursive=True)
+        lines = {}
+        for plane in ProfileData.from_file(pb).planes:
+            if plane.name.startswith("/host"):
+                for i, line in enumerate(plane.lines):
+                    names = {ev.name for ev in line.events
+                             if ev.name.startswith("sym.")}
+                    if names:
+                        lines[i] = names
+        everything = set().union(*lines.values())
+        assert {"sym.capture", "sym.sched.sync", "sym.sched.admit",
+                "sym.engine.prefill", "sym.emit.emit_flush"} <= everything
+        # the loop phases share one line (the engine thread's); the
+        # capture's own span is on another
+        sched_lines = [i for i, names in lines.items()
+                       if any(n.startswith("sym.sched.") for n in names)]
+        assert len(sched_lines) == 1
+        assert "sym.capture" not in lines[sched_lines[0]]
+
+
+class TestCompileWatch:
+    def test_counts_lowerings_and_bounds_recent(self):
+        import jax
+
+        watch = devprof.CompileWatch()
+        watch.register()
+        try:
+            def fresh(x):
+                return x * 3 + 1
+
+            f = jax.jit(fresh)
+            before = watch.stats()
+            f(np.ones((3,), np.float32)).block_until_ready()
+            first = watch.stats()
+            assert first["lowerings"] == before["lowerings"] + 1
+            assert first["backend_compiles"] == before["backend_compiles"] + 1
+            assert first["traces"] >= before["traces"] + 1
+            assert first["host_s"] > before["host_s"]
+            assert first["host_s"] == pytest.approx(
+                first["trace_s"] + first["lower_s"] + first["backend_s"])
+            assert any(kind == "lowerings" and "fresh" in name
+                       for _t, kind, name, _s in first["recent"])
+            f(np.ones((3,), np.float32)).block_until_ready()
+            again = watch.stats()
+            assert again["lowerings"] == first["lowerings"]
+            assert again["traces"] == first["traces"]
+            assert first["at_ready"] is None
+            watch.mark_ready()
+            for n in range(4, 20):          # a new shape lowers again
+                f(np.ones((n,), np.float32)).block_until_ready()
+            last = watch.stats()
+            assert last["lowerings"] == first["lowerings"] + 16
+            assert last["at_ready"]["lowerings"] == first["lowerings"]
+            assert len(last["recent"]) == devprof.CompileWatch.RECENT == 32
+        finally:
+            watch.unregister()
+        f(np.ones((40,), np.float32)).block_until_ready()
+        assert watch.stats()["lowerings"] == last["lowerings"]
+
+    def test_host_stats_carry_the_compile_block(self, capsys):
+        """The host's stats reply gains `compile` and the scheduler's
+        `loop_s`; `tokens` and the other counters are where they were."""
+        import io
+        import json
+        import sys
+
+        from symmetry_tpu.engine.host import EngineHost
+
+        host = EngineHost(config=None)
+        sched = Scheduler(FakeEngine(), pipeline_depth=2)
+        host._scheduler = sched
+        host.start = lambda: None
+        stdin, sys.stdin = sys.stdin, io.StringIO('{"op":"stats"}\n')
+        try:
+            assert host.serve_forever() == 0
+        finally:
+            sys.stdin = stdin
+        reply = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert reply["op"] == "stats" and reply["tokens"] == 0
+        assert set(reply["compile"]) == {
+            "traces", "trace_s", "lowerings", "lower_s", "backend_compiles",
+            "backend_s", "cache_hits", "host_s", "at_ready", "recent"}
+        assert tuple(reply["loop_s"]) == LOOP_PHASES
+        assert reply["loop_iters"] == 0

@@ -47,11 +47,11 @@ class TestHistogram:
 
 
 class TestTracer:
-    def test_span_records_and_aggregates(self):
+    def test_phase_records_and_aggregates(self):
         tr = Tracer()
-        with tr.span("prefill", request_id="r1", bucket=128):
+        with tr.phase("prefill", request_id="r1", bucket=128):
             time.sleep(0.01)
-        with tr.span("prefill", request_id="r2", bucket=512):
+        with tr.phase("prefill", request_id="r2", bucket=512):
             pass
         spans = tr.export()
         assert len(spans) == 2
@@ -60,11 +60,12 @@ class TestTracer:
         assert spans[0]["duration_s"] >= 0.01
         assert tr.export(request_id="r2")[0]["bucket"] == 512
         assert tr.stats()["prefill_s"]["count"] == 2
+        assert tr.phase_s["prefill"] >= 0.01
 
     def test_disabled_is_noop(self):
         tr = Tracer()
         tr.enabled = False
-        with tr.span("x"):
+        with tr.phase("x"):
             pass
         tr.record("y", 0.0, 1.0)
         assert tr.export() == []
@@ -78,8 +79,8 @@ class TestTracer:
         assert len(spans) == 8
         assert spans[0]["request_id"] == "12"  # oldest retained
 
-    def test_annotate_inside_span(self):
+    def test_annotate_inside_phase(self):
         tr = Tracer()
-        with tr.span("gen") as attrs:
+        with tr.phase("gen") as attrs:
             attrs["tokens"] = 42
         assert tr.export()[0]["tokens"] == 42
