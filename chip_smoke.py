@@ -17,8 +17,9 @@ tokens, then one lone greedy request sent twice.
 
 It exits 0 only when every phase passed on a TPU, and then prints two JSON
 lines: the run's report (device, shape, start-up seconds, compile-cache
-entries, HBM, attention paths, burst TTFT and tok/s — reported, not judged)
-and, as the LAST line of stdout, the verdict with exactly these keys:
+entries, HBM, attention paths, the sampler's top-k route, burst TTFT and
+tok/s — reported, not judged) and, as the LAST line of stdout, the verdict
+with exactly these keys:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
@@ -299,6 +300,7 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
         "shape": {k: v for k, v in cfg["tpu"].items()
                   if k != "model_preset"},
         "attention": attention,
+        "sampling": startup.get("sampling"),
         "startup_s": round(startup_s, 1),
         "build_s": startup.get("build_s"),
         "warmup_s": startup.get("warmup_s"),

@@ -42,7 +42,8 @@ from symmetry_tpu.models.llama import (
 )
 
 
-from symmetry_tpu.ops.sampling import sample_tokens, verify_tokens
+from symmetry_tpu.ops.sampling import (
+    sample_tokens, top_k_route, verify_tokens)
 from symmetry_tpu.parallel.mesh import MeshSpec, build_mesh
 from symmetry_tpu.parallel.sharding import shardings_for
 from symmetry_tpu.engine.prefix_cache import BlockPool, RadixHit, RadixIndex
@@ -1843,6 +1844,12 @@ class InferenceEngine:
         return attention_paths(
             self.config, self.max_seq_len,
             None if self.pipeline else self.mesh)
+
+    def sampling_route(self) -> dict:
+        """How every sampling call of the served programs selects its
+        top-`cap` window (ops/sampling.py top_k_route: the routing
+        itself, asked with this model's vocabulary)."""
+        return top_k_route(self.config.vocab_size)
 
     def weight_stream_bytes(self) -> int:
         """Bytes of parameter data one decode step must stream from HBM:

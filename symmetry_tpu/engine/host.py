@@ -48,11 +48,14 @@ Protocol: JSON lines.
             "build_s", "warmup_s", "compile_cache",
             "device": {"platform", "device_kind", "device_count",
                        "hbm": [{"bytes_in_use", "bytes_limit"}, …]},
-            "attention": {"prefill", "decode"}}
+            "attention": {"prefill", "decode"},
+            "sampling": {"top_k", "groups"?, "width"?, "cap"?}}
             (after warmup. `device` is what JAX handed this process and
             its per-device memory_stats() once every program has
             compiled; `attention` is "pallas" | "pallas-interpret" |
-            "xla" per program. The stats reply repeats both.)
+            "xla" per program; `sampling.top_k` is "grouped" (with its
+            geometry) | "direct", ops/sampling.py top_k_route. The
+            stats reply repeats all three.)
            {"op": "clock", "t0", "t": our monotonic at receipt}
            {"op": "trace", "clock", "components": [{name, spans,
             counters, clock_offset_s}, …]}   (host + scheduler rings,
@@ -159,8 +162,9 @@ class EngineHost:
         self._engine: InferenceEngine | None = None
         self._scheduler: Scheduler | None = None
         # Filled by start(): build/warmup seconds, compile-cache
-        # directory, the device JAX handed this process and the
-        # attention path of each program (READY and stats carry it).
+        # directory, the device JAX handed this process, the attention
+        # path of each program and the sampler's top-k route (READY and
+        # stats carry it).
         self._startup: dict[str, Any] = {}
         # What JAX traced, lowered and compiled in this process (stats
         # `compile` block); start() registers its listeners.
@@ -405,7 +409,8 @@ class EngineHost:
             "build_s": round(t_build, 1), "warmup_s": round(t_warmup, 1),
             "compile_cache": cache_dir,
             "device": device_report(),
-            "attention": self._engine.attention_paths()}
+            "attention": self._engine.attention_paths(),
+            "sampling": self._engine.sampling_route()}
         self._write({"op": HostOp.READY,
                      "model": self._config.model_name,
                      "role": self._role,
@@ -415,6 +420,8 @@ class EngineHost:
         # Startup breakdown to stderr: a slow start must carry its own
         # explanation in the provider log (round-3 verdict #1).
         dev, attn = self._startup["device"], self._startup["attention"]
+        samp = ",".join(f"{k}:{v}"
+                        for k, v in self._startup["sampling"].items())
         hbm = " ".join(f"{h['bytes_in_use'] / 2**30:.2f}/"
                        f"{h['bytes_limit'] / 2**30:.2f}GiB"
                        for h in dev["hbm"]) or "n/a"
@@ -425,6 +432,7 @@ class EngineHost:
                     f"device_count={dev['device_count']} hbm={hbm} "
                     f"attention=prefill:{attn['prefill']},"
                     f"decode:{attn['decode']} "
+                    f"sampling={samp} "
                     f"build={t_build:.1f}s warmup={t_warmup:.1f}s "
                     f"compile_cache={cache_dir or 'off'}")
 

@@ -7,10 +7,22 @@ selects greedy via masking rather than control flow — no recompiles, no
 data-dependent branching under jit.
 
 Perf note: a full [B, V] sort at V=128k costs more than the decode matmuls
-for small models, so sampling is restricted to the top `cap` logits via
-`lax.top_k` (top-k at small k is a cheap partial reduction on TPU). Greedy
-and any top_k <= cap are exact; top-p loses only the probability mass beyond
-the top `cap` tokens (< 1e-3 for typical LM distributions at cap=64).
+for small models, so sampling is restricted to the top `cap` logits. One
+`lax.top_k` over the whole vocabulary is not cheap on a TPU either: its
+time is proportional to V and to nothing else — 3.5 ms a decode step on
+[128, 152064], four times the LM head that produced the logits, 0.75 ms
+on [128, 32768] (PERF_LEDGER.jsonl PR 25; PERF.md §6, PR 26). So where the
+vocabulary is wide enough (`top_k_route`: from 16,257 entries at cap 64) the
+window is selected in two stages (`_top_k`): the maxima of groups of
+TOP_K_GROUP_WIDTH consecutive entries choose the `cap` groups that can hold
+the top `cap`, and only those are ranked — 0.75 ms a step at 152,064. Exact,
+ties included: `lax.top_k` is stable (lowest index first), so an entry
+left out sits behind `cap` groups whose maxima outrank it — `cap` entries
+ahead of it; and the chosen groups are gathered in ascending order, so
+candidate order is vocabulary order and ties break as in the single call.
+Greedy and any top_k <= cap are exact; top-p loses only the probability
+mass beyond the top `cap` tokens (< 1e-3 for typical LM distributions at
+cap=64).
 """
 
 from __future__ import annotations
@@ -21,6 +33,52 @@ import jax.numpy as jnp
 from symmetry_tpu.ops.attention import NEG_INF
 
 SAMPLING_TOP_CAP = 64
+# Width of a vocabulary group in the two-stage selection, and how many
+# groups per kept entry make it worth taking: fixed once from a sweep of
+# W in {128, 256, 512} at V = 152064 and 32768 on a v5e (PERF.md §6, PR 26).
+TOP_K_GROUP_WIDTH = 128
+TOP_K_MIN_GROUPS_PER_CAP = 2
+
+
+def top_k_route(vocab: int, cap: int = SAMPLING_TOP_CAP) -> dict:
+    """How `_top_k` selects the top `cap` of `vocab` logits — decided by
+    the two static sizes alone, so every call of a served program takes
+    the same route and the engine can report it (startup.sampling)."""
+    cap = min(cap, vocab)
+    groups = -(-vocab // TOP_K_GROUP_WIDTH)
+    if groups < TOP_K_MIN_GROUPS_PER_CAP * cap:
+        return {"top_k": "direct"}
+    return {"top_k": "grouped", "groups": groups,
+            "width": TOP_K_GROUP_WIDTH, "cap": cap}
+
+
+def _grouped_top_k(x: jnp.ndarray, cap: int,
+                   width: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`lax.top_k(x, cap)` over the last axis, values and indices equal,
+    ranking groups + cap*width entries instead of all of them (module
+    docstring has why it is exact). Needs at least `cap` groups."""
+    *lead, vocab = x.shape
+    groups = -(-vocab // width)
+    pad = groups * width - vocab
+    if pad:  # -inf at the highest indices: behind every real entry
+        x = jnp.pad(x, [(0, 0)] * len(lead) + [(0, pad)],
+                    constant_values=-jnp.inf)
+    grouped = x.reshape(*lead, groups, width)
+    _, chosen = jax.lax.top_k(grouped.max(-1), cap)
+    chosen = jnp.sort(chosen, axis=-1)  # candidates in vocabulary order
+    candidates = jnp.take_along_axis(grouped, chosen[..., None], axis=-2)
+    values, pos = jax.lax.top_k(candidates.reshape(*lead, cap * width), cap)
+    group = jnp.take_along_axis(chosen, pos // width, axis=-1)
+    return values, group * width + pos % width
+
+
+def _top_k(x: jnp.ndarray, cap: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The top `cap` of the last axis, descending, with their indices:
+    `lax.top_k`'s result by the route `top_k_route` names."""
+    route = top_k_route(x.shape[-1], cap)
+    if route["top_k"] == "direct":
+        return jax.lax.top_k(x, cap)
+    return _grouped_top_k(x, cap, route["width"])
 
 
 def _masked_top_logits(
@@ -44,7 +102,7 @@ def _masked_top_logits(
     scaled = logits / safe_t[ctl + (None,)]
 
     # Partial sort: [..., cap] descending, with original vocab indices.
-    top_logits, top_idx = jax.lax.top_k(scaled, cap)
+    top_logits, top_idx = _top_k(scaled, cap)
 
     ranks = jnp.arange(cap, dtype=jnp.int32)
     # top-k: keep ranks < k (0 disables; anything beyond cap acts as cap).

@@ -115,6 +115,9 @@ class TestEngineHost:
             # the backend is not a TPU, so decode takes the XLA path.
             assert block["attention"] == {"prefill": "pallas-interpret",
                                           "decode": "xla"}
+            # tiny's 512 logits are 4 groups of 128, below the two-stage
+            # selection's threshold (ops/sampling.py top_k_route).
+            assert block["sampling"] == {"top_k": "direct"}
             assert block["compile_cache"] == compile_cache.cache_dir()
             assert block["build_s"] >= 0 and block["warmup_s"] >= 0
 

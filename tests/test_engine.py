@@ -419,6 +419,31 @@ class TestSeededReproducibility:
 
         assert gen(1) != gen(2)
 
+    @pytest.mark.parametrize("sp", [
+        SamplingParams(),
+        SamplingParams(temperature=0.9, top_p=0.95, seed=123),
+        SamplingParams(temperature=1.2, top_k=40, seed=7),
+    ], ids=["greedy", "top_p", "top_k"])
+    def test_grouped_top_k_programs_emit_the_same_tokens(self, setup, sp,
+                                                         monkeypatch):
+        """The served programs (prefill, chunk_final, decode) with the
+        sampler's two-stage selection engaged — tiny's 512 logits as 256
+        groups of 2 — emit the tokens the single lax.top_k gives."""
+        from symmetry_tpu.ops import sampling
+        cfg, params = setup
+        prompt = list(b"a prompt long enough to be chunked in two")
+
+        def generate(chunk):
+            engine = make_engine(cfg, params, buckets=(16, 32, 64),
+                                 prefill_chunk=chunk)
+            toks = [engine.prefill_and_insert(0, prompt, sp)]
+            return toks + [int(engine.decode_step()[0]) for _ in range(8)]
+
+        want = [generate(256), generate(16)]
+        monkeypatch.setattr(sampling, "TOP_K_GROUP_WIDTH", 2)
+        assert sampling.top_k_route(cfg.vocab_size)["top_k"] == "grouped"
+        assert [generate(256), generate(16)] == want
+
 
 class TestCoalescedPrefill:
     def test_prefill_many_matches_sequential(self, setup):

@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from symmetry_tpu.ops import apply_rope, gqa_attention, rms_norm, sample_tokens
+from symmetry_tpu.ops import (
+    apply_rope, gqa_attention, rms_norm, sample_tokens, sampling)
+from symmetry_tpu.ops.attention import NEG_INF
 
 
 class TestRope:
@@ -136,3 +138,158 @@ class TestSampling:
                             top_p=jnp.ones(2), top_k=jnp.zeros(2, jnp.int32))
         assert int(out[0]) == 1
         assert 0 <= int(out[1]) < 3
+
+
+# Vocabularies of the two-stage selection's test: qwen2-7b, mistral-7b,
+# OLMoE, one that TOP_K_GROUP_WIDTH does not divide (padded last group),
+# one small enough that the groups do not reach the threshold, and tiny's.
+TOP_K_VOCABS = (152064, 32768, 50304, 100000, 1000, 512)
+
+
+def top_k_case(kind: str, vocab: int, cap: int = 64,
+               width: int = sampling.TOP_K_GROUP_WIDTH) -> np.ndarray:
+    """[3, vocab] float32 rows (or [2, 3, vocab]) built to stress one
+    property of the selection; seeded by (kind, vocab)."""
+    rng = np.random.default_rng([len(kind), vocab])
+    groups = -(-vocab // width)
+    if kind == "gaussian":
+        return rng.normal(size=(3, vocab)).astype(np.float32) * 3
+    if kind == "bf16_ties":  # what the sampler sees: bf16 logits / 0.7
+        x = jnp.asarray(rng.normal(size=(3, vocab)), jnp.bfloat16)
+        return np.asarray(x.astype(jnp.float32) / 0.7)
+    if kind == "one_group":  # the whole top window inside a single group
+        x = rng.normal(size=(3, vocab)).astype(np.float32)
+        g = int(rng.integers(groups - 1))
+        x[:, g * width:g * width + min(width, cap + 8)] += 100.0
+        return x
+    if kind == "one_per_group":  # one winner in each of `cap` groups;
+        x = np.ones((3, vocab), np.float32)  # every other maximum ties
+        for row in x:
+            chosen = rng.choice(groups - 1, size=min(cap, groups - 1),
+                                replace=False)
+            row[chosen * width + rng.integers(width, size=chosen.size)] = 2.0
+        return x
+    if kind == "ties_across_groups":  # the window's tail is decided by
+        x = np.zeros((3, vocab), np.float32)  # index order among equals
+        x[:, rng.choice(vocab, size=vocab // 3, replace=False)] = 1.0
+        x[:, rng.choice(vocab, size=cap // 2, replace=False)] = 2.0
+        return x
+    if kind == "constant":
+        return np.full((3, vocab), 0.25, np.float32)
+    if kind == "neg_inf":  # masked vocabularies: fewer finite than cap
+        x = np.full((3, vocab), -np.inf, np.float32)
+        x[1] = NEG_INF
+        x[:, rng.choice(vocab, size=cap // 2, replace=False)] = \
+            rng.normal(size=cap // 2).astype(np.float32)
+        x[2] = -np.inf
+        return x
+    if kind == "batch_seq":  # verify_tokens' [B, S, V]
+        return rng.normal(size=(2, 3, vocab)).astype(np.float32)
+    raise ValueError(kind)
+
+
+TOP_K_KINDS = ("gaussian", "bf16_ties", "one_group", "one_per_group",
+               "ties_across_groups", "constant", "neg_inf", "batch_seq")
+
+
+class TestTwoStageTopK:
+    """ops/sampling.py _top_k against the single lax.top_k it replaces:
+    values AND indices equal, ties included."""
+
+    @pytest.mark.parametrize("vocab", TOP_K_VOCABS)
+    @pytest.mark.parametrize("kind", TOP_K_KINDS)
+    def test_equals_single_call(self, kind, vocab):
+        x = jnp.asarray(top_k_case(kind, vocab))
+        want_v, want_i = jax.lax.top_k(x, 64)
+        got_v, got_i = jax.jit(sampling._top_k, static_argnums=1)(x, 64)
+        np.testing.assert_array_equal(got_v, want_v)
+        np.testing.assert_array_equal(got_i, want_i)
+
+    @pytest.mark.parametrize("kind", TOP_K_KINDS)
+    def test_padded_last_group_at_any_width(self, kind):
+        # The grouped form itself, below the route's threshold: 1000 is
+        # 125 groups of 8 (divides) or 77 of 13 (a padded last group).
+        for width in (8, 13):
+            x = jnp.asarray(top_k_case(kind, 1000, width=width))
+            want_v, want_i = jax.lax.top_k(x, 64)
+            got_v, got_i = sampling._grouped_top_k(x, 64, width)
+            np.testing.assert_array_equal(got_v, want_v)
+            np.testing.assert_array_equal(got_i, want_i)
+
+    @pytest.mark.parametrize("vocab,route", [
+        (152064, {"top_k": "grouped", "groups": 1188, "width": 128,
+                  "cap": 64}),
+        (32768, {"top_k": "grouped", "groups": 256, "width": 128,
+                 "cap": 64}),
+        (100000, {"top_k": "grouped", "groups": 782, "width": 128,
+                  "cap": 64}),
+        (1000, {"top_k": "direct"}),
+        (512, {"top_k": "direct"}),
+        (32, {"top_k": "direct"}),  # cap clamps to the vocabulary
+    ])
+    def test_route_follows_the_shape(self, vocab, route):
+        assert sampling.top_k_route(vocab) == route
+
+    def test_benchmark_presets_take_the_grouped_route(self):
+        from symmetry_tpu.models.llama import preset
+        for name in ("qwen2-7b", "mistral-7b"):
+            assert sampling.top_k_route(
+                preset(name).vocab_size)["top_k"] == "grouped"
+        assert sampling.top_k_route(
+            preset("tiny").vocab_size) == {"top_k": "direct"}
+
+    @pytest.mark.parametrize("vocab", (152064, 32768))
+    def test_sample_tokens_identical_to_single_call(self, vocab,
+                                                    monkeypatch):
+        B = 6
+        logits = jnp.asarray(
+            np.random.default_rng(vocab).normal(size=(B, vocab)),
+            jnp.bfloat16).astype(jnp.float32)
+        args = (logits, jax.random.split(jax.random.key(7), B),
+                jnp.asarray([0.0, 0.7, 0.7, 1.0, 1.3, 0.7], jnp.float32),
+                jnp.asarray([1.0, 1.0, 0.9, 0.5, 1.0, 1.0], jnp.float32),
+                jnp.asarray([0, 0, 0, 40, 5, 64], jnp.int32))
+        got = sample_tokens(*args)
+        monkeypatch.setattr(sampling, "_top_k", jax.lax.top_k)
+        np.testing.assert_array_equal(got, sample_tokens(*args))
+
+    @pytest.mark.parametrize("vocab", (152064, 32768))
+    def test_verify_tokens_identical_to_single_call(self, vocab,
+                                                    monkeypatch):
+        B, k = 4, 3
+        rng = np.random.default_rng(vocab + 1)
+        logits = jnp.asarray(rng.normal(size=(B, 1 + k, vocab)),
+                             jnp.bfloat16).astype(jnp.float32)
+        # Proposals the target likes (its own argmax) and ones it does
+        # not, so acceptance and both bonus draws are exercised.
+        draft = np.array(jnp.argmax(logits[:, :k], -1), np.int32)
+        draft[1, 1] = 17
+        draft[3, 0] = 23
+        args = (logits, jnp.asarray(draft),
+                jnp.asarray([3, 3, 2, 3], jnp.int32),
+                jax.random.split(jax.random.key(11), B),
+                jnp.asarray([0.0, 0.7, 1.0, 0.7], jnp.float32),
+                jnp.asarray([1.0, 1.0, 0.9, 1.0], jnp.float32),
+                jnp.asarray([0, 0, 0, 8], jnp.int32))
+        got = sampling.verify_tokens(*args)
+        monkeypatch.setattr(sampling, "_top_k", jax.lax.top_k)
+        want = sampling.verify_tokens(*args)
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+
+    @pytest.mark.parametrize("vocab", (32768, 100000))
+    def test_vocab_sharded_logits_under_a_mesh(self, vocab):
+        # The tensor-parallel build hands the sampler logits sharded over
+        # `model` on the vocabulary axis (parallel/sharding.py): the
+        # [.., V] -> [.., G, W] view must stay legal under GSPMD, also
+        # where a shard's slice is not a whole number of groups (100000).
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from symmetry_tpu.parallel.mesh import MeshSpec, build_mesh
+        mesh = build_mesh(MeshSpec(data=2, model=4))
+        x = jnp.asarray(top_k_case("bf16_ties", vocab)[:2])
+        want_v, want_i = jax.lax.top_k(x, 64)
+        sharded = jax.device_put(x, NamedSharding(mesh, P("data", "model")))
+        got_v, got_i = jax.jit(sampling._top_k, static_argnums=1)(sharded, 64)
+        np.testing.assert_array_equal(got_v, want_v)
+        np.testing.assert_array_equal(got_i, want_i)
