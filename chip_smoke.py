@@ -37,6 +37,11 @@ contends for the chip its child needs.
 
     python chip_smoke.py                       # the chip run
     python chip_smoke.py --mesh-model 4        # one host, four chips, TP
+    python chip_smoke.py --preset mixtral-8x7b --mesh-model 4 --parity
+        # the 47 B expert model at full widths over four chips (the report
+        # carries `moe`: layout, route, the quantised-leaf route), then —
+        # once the provider has drained and the chips are free — the
+        # depth-2 full-width logits parity of tools/moe_parity.py
     JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny
         # CPU dry run of every phase; ends non-zero: "platform is cpu"
 """
@@ -301,6 +306,7 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
                   if k != "model_preset"},
         "attention": attention,
         "sampling": startup.get("sampling"),
+        **({"moe": startup["moe"]} if startup.get("moe") else {}),
         "startup_s": round(startup_s, 1),
         "build_s": startup.get("build_s"),
         "warmup_s": startup.get("warmup_s"),
@@ -336,6 +342,10 @@ def main() -> int:
                          "chips of one host (default: one chip)")
     ap.add_argument("--preset", default="mistral-7b",
                     help="model preset; `tiny` is the CPU dry run")
+    ap.add_argument("--parity", action="store_true",
+                    help="after the smoke, run tools/moe_parity.py on the "
+                         "same mesh (expert models: full widths, depth 2, "
+                         "logits against the float32 reference)")
     args = ap.parse_args()
 
     if os.environ.get("JAX_PLATFORMS") == "cpu" and args.preset != "tiny":
@@ -368,6 +378,20 @@ def main() -> int:
             if "engine host ready" in line:  # the host's own account
                 print(line.rstrip(), file=sys.stderr)
     os.unlink(log_path)
+    if args.parity:
+        # Its own process, now that the provider's host has let go of the
+        # chips; its JSON line joins the report, its exit code the verdict.
+        parity = subprocess.run(
+            [sys.executable, os.path.join(REPO, "tools", "moe_parity.py"),
+             "--preset", args.preset, "--mesh-model", str(args.mesh_model)],
+            cwd=REPO, capture_output=True, text=True)
+        lines = parity.stdout.strip().splitlines()
+        if parity.returncode != 0 or not lines:
+            print(f"chip_smoke: FAIL: the parity check exited with code "
+                  f"{parity.returncode}: {lines[-1:] or ''}\n"
+                  f"{parity.stderr[-3000:]}", file=sys.stderr)
+            return 1
+        result["parity"] = json.loads(lines[-1])
     print(json.dumps(result))
     print(json.dumps(verdict(result)), flush=True)
     return 0

@@ -583,9 +583,11 @@ class ProviderSession:
             try:
                 # Budget the capture window PLUS the profiler's cold
                 # init (the process's first capture can take tens of
-                # seconds) and the provider's own probe margin.
+                # seconds) and the provider's own probe margin, which
+                # grows with the host's chips (90 s each): the provider
+                # answers inside its margin, this only outlasts it.
                 data = await asyncio.wait_for(self._profile_q.get(),
-                                              duration_s + 150.0)
+                                              duration_s + 750.0)
             except asyncio.TimeoutError:
                 raise ProviderGoneError(
                     "no profile reply within the capture window") from None

@@ -21,6 +21,7 @@ concurrency in one event loop (SURVEY §5.2).
 
 from __future__ import annotations
 
+import collections
 import os
 import time
 from dataclasses import dataclass
@@ -313,6 +314,15 @@ class InferenceEngine:
         self.devprof = DeviceProfiler(profile_sample)
 
         c = config
+        # MoE models count the valid (token, expert) pairs each forward
+        # computed, per expert, in the caches' `expert_pairs` (models/
+        # llama.py KVCache). The pipeline schedule rebuilds its caches
+        # without it.
+        self._count_experts = (bool(getattr(c, "num_experts", 0))
+                               and not self.pipeline)
+        self.expert_pairs = [0] * getattr(c, "num_experts", 0)
+        self._pairs_pending: collections.deque = collections.deque()
+        self._moe_report: dict | None = None
 
         if mesh is not None:
             rules = self._rules
@@ -328,6 +338,7 @@ class InferenceEngine:
                 # batch-sharded slot may live on another host.
                 lengths=rep,
                 k_scale=sc, v_scale=sc,
+                expert_pairs=rep if self._count_experts else None,
             )
             self._state_shardings = DecodeState(
                 cache=self._cache_shardings, last_token=rep, temperature=rep,
@@ -339,7 +350,8 @@ class InferenceEngine:
         def _init_state() -> DecodeState:
             return DecodeState(
                 cache=init_cache(c, max_slots, max_seq_len, cache_dtype,
-                                 quantized=kv_quant),
+                                 quantized=kv_quant,
+                                 count_experts=self._count_experts),
                 last_token=jnp.zeros((max_slots,), jnp.int32),
                 temperature=jnp.zeros((max_slots,), jnp.float32),
                 top_p=jnp.ones((max_slots,), jnp.float32),
@@ -461,7 +473,8 @@ class InferenceEngine:
 
         def make():
             return init_cache(c, slots, self.prefix_block,
-                              self.cache_dtype, quantized=self.kv_quant)
+                              self.cache_dtype, quantized=self.kv_quant,
+                              count_experts=self._count_experts)
 
         if self.mesh is not None:
             return jax.jit(make, out_shardings=self._prefix_shard)()
@@ -517,6 +530,11 @@ class InferenceEngine:
             contract first."""
             cache = scratch._replace(
                 lengths=jnp.zeros_like(scratch.lengths))
+            if cache.expert_pairs is not None:
+                # insert_all adds a prefix's count to the decode state's:
+                # a reused buffer must not bring its last use's along.
+                cache = cache._replace(
+                    expert_pairs=jnp.zeros_like(cache.expert_pairs))
             h, cache = trunk(params, tokens, cache,
                              seq_lens=true_len, prefill_flash=True)
             # Project ONLY the last valid position through the LM head —
@@ -578,7 +596,12 @@ class InferenceEngine:
                 return insert(st, prefix, i, slots[i], true_len,
                               first_token, temp, top_p, top_k, rng)
 
-            return jax.lax.fori_loop(0, slots.shape[0], body, state)
+            state = jax.lax.fori_loop(0, slots.shape[0], body, state)
+            if state.cache.expert_pairs is not None:
+                state = state._replace(cache=state.cache._replace(
+                    expert_pairs=(state.cache.expert_pairs
+                                  + prefix.expert_pairs)))
+            return state
 
         def insert_from_blocks(scratch: KVCache, pool: KVCache, ids, p):
             """Seed a donated (batch, bucket) working prefix buffer from
@@ -664,6 +687,9 @@ class InferenceEngine:
                 lengths=jnp.full((1,), p, jnp.int32),
                 k_scale=take(prefix.k_scale) if self.kv_quant else None,
                 v_scale=take(prefix.v_scale) if self.kv_quant else None,
+                # an entry seeds later prefills: it starts their count at 0
+                expert_pairs=(None if prefix.expert_pairs is None else
+                              jnp.zeros_like(prefix.expert_pairs)),
             )
 
         def chunk_step(params, tokens, cache, seq_len):
@@ -701,10 +727,19 @@ class InferenceEngine:
         def decode_block(params, state: DecodeState):
             """K decode steps in ONE dispatch: the per-dispatch host cost
             is paid once per K tokens (SURVEY §7 hard-part 3: streaming
-            latency discipline). Returns (state, tokens [K, B])."""
-            return jax.lax.scan(
+            latency discipline). Returns (state, tokens [K, B], pairs):
+            `pairs` is the cache's `expert_pairs` since the last block —
+            this block's steps and every prefill inserted in between —
+            handed out and zeroed, so the int32 counter never wraps; [0]
+            for a model that does not count."""
+            state, toks = jax.lax.scan(
                 lambda s, _: decode_one(s, params), state, None,
                 length=self.decode_block)
+            pairs = state.cache.expert_pairs
+            if pairs is None:
+                return state, toks, jnp.zeros((0,), jnp.int32)
+            return state._replace(cache=state.cache._replace(
+                expert_pairs=jnp.zeros_like(pairs))), toks, pairs
 
         def verify_block(params, state: DecodeState, draft, n_draft):
             """Speculative verify: ONE batched forward over [B, 1+k_draft]
@@ -768,12 +803,13 @@ class InferenceEngine:
                 v=shardings_for(cax.v, self.mesh, prefix_rules),
                 lengths=rep,
                 k_scale=psc, v_scale=psc,
+                expert_pairs=rep if self._count_experts else None,
             )
             self._prefix_shard = prefix_shard
             self._prefill = jax.jit(prefill, donate_argnums=(7,),
                                     out_shardings=(rep, prefix_shard))
             self._decode = jax.jit(decode_block, donate_argnums=(1,),
-                                   out_shardings=(state_shard, rep))
+                                   out_shardings=(state_shard, rep, rep))
             if self.spec is not None:
                 self._verify = jax.jit(
                     verify_block, donate_argnums=(1,),
@@ -1499,7 +1535,8 @@ class InferenceEngine:
 
         def make():
             return init_cache(c, batch, capacity, self.cache_dtype,
-                              quantized=self.kv_quant)
+                              quantized=self.kv_quant,
+                              count_experts=self._count_experts)
 
         if self.mesh is not None:
             return jax.jit(make, out_shardings=self._prefix_shard)()
@@ -1564,7 +1601,7 @@ class InferenceEngine:
         # recovery under load never pays a fresh XLA compile.
         self._rng_resume(jax.random.key(0), 0)
         if decode_side:
-            self.state, _ = self._decode(self.params, self.state)
+            self.state, _, _ = self._decode(self.params, self.state)
         for bucket in self.prefill_buckets:
             for batch in self.prefill_batches_for(bucket):
                 if batch > self.max_slots:
@@ -1751,12 +1788,12 @@ class InferenceEngine:
                         self.state = _settle_insert(self.state, batch,
                                                     bucket)
                         # steady decode between admissions
-                        self.state, _ = self._decode(self.params, self.state)
+                        self.state, _, _ = self._decode(self.params, self.state)
                         self.state = _settle_insert(self.state, batch,
                                                     bucket)
                     # consecutive decode blocks (no admission between)
-                    self.state, _ = self._decode(self.params, self.state)
-                    self.state, _ = self._decode(self.params, self.state)
+                    self.state, _, _ = self._decode(self.params, self.state)
+                    self.state, _, _ = self._decode(self.params, self.state)
                 if self.spec is not None:
                     self.verify_step(
                         np.zeros((self.max_slots, self.spec.k_draft),
@@ -1814,14 +1851,64 @@ class InferenceEngine:
         1-in-N cadence bounds the serialization cost."""
         dp = self.devprof
         t_dp = dp.begin() if dp.enabled else 0.0
-        self.state, toks = self._decode(self.params, self.state)
+        self.state, toks, pairs = self._decode(self.params, self.state)
+        if self._count_experts:
+            # Start the [experts] count's copy to the host now: by the
+            # time this block's tokens are synced it has arrived, and
+            # collect_expert_pairs reads it without touching the device.
+            pairs.copy_to_host_async()
+            self._pairs_pending.append(pairs)
         if dp.enabled:
             dp.probe("decode_block", toks, t_dp)
         return toks
 
     def decode_steps(self) -> np.ndarray:
         """decode_block tokens for every slot; host gets [K, B] int32."""
-        return np.asarray(self.decode_steps_dispatch())
+        toks = np.asarray(self.decode_steps_dispatch())
+        self.collect_expert_pairs()
+        return toks
+
+    def collect_expert_pairs(self) -> None:
+        """Add the per-expert pair counts of every decode block that has
+        finished to `self.expert_pairs`. Call it after a block's tokens
+        were synced: its count is an output of the same program and its
+        copy to the host began at dispatch, so the read waits for
+        nothing."""
+        while self._pairs_pending and self._pairs_pending[0].is_ready():
+            block = np.asarray(self._pairs_pending.popleft())
+            self.expert_pairs = [a + int(b) for a, b in
+                                 zip(self.expert_pairs, block)]
+
+    def moe_report(self) -> dict | None:
+        """`startup.moe`: where the expert weights live and which form
+        each program kind's expert FFN takes (models/moe.py); None for a
+        dense model. Built once: nothing in it changes after start."""
+        c = self.config
+        if not getattr(c, "num_experts", 0):
+            return None
+        if self._moe_report is not None:
+            return self._moe_report
+        from symmetry_tpu.models.moe import moe_layout, moe_route
+        from symmetry_tpu.ops.quant import QuantizedTensor
+
+        wg = self.params["layers"]["wg"]
+        self._moe_report = {
+            "experts": c.num_experts, "top_k": c.num_experts_per_tok,
+            "layout": moe_layout(None if self.pipeline else self.mesh,
+                                 c.intermediate_size),
+            # by tokens a dispatch: decode is one per slot; a prefill is
+            # batch x bucket for every shape warm-up compiles
+            "route": {"decode": moe_route(self.max_slots),
+                      "prefill": {str(t): moe_route(t) for t in sorted({
+                          b * bucket for bucket in self.prefill_buckets
+                          for b in self.prefill_batches_for(bucket)})}},
+            "quantized_leaf_route": (
+                "expert_stack: int8 [L, X, K, N] stays flat (the packed "
+                "W8A16 layout has no expert grid dim) and is the ragged "
+                "dot's operand, scales on the accumulator"
+                if isinstance(wg, QuantizedTensor) else "not quantized"),
+        }
+        return self._moe_report
 
     def decode_step(self) -> np.ndarray:
         """One decode step [B] (requires decode_block == 1; tests/bench)."""
@@ -2008,7 +2095,8 @@ class InferenceEngine:
                 shardings = shardings_for(axes, mesh, rules)
                 params = jax.jit(
                     lambda: init_params(config, jax.random.key(0), dtype,
-                                        quantize=quant),
+                                        quantize=quant,
+                                        shardings=shardings),
                     out_shardings=shardings)()
             else:
                 params = init_params(config, jax.random.key(0), dtype,

@@ -49,8 +49,11 @@ Protocol: JSON lines.
             "device": {"platform", "device_kind", "device_count",
                        "hbm": [{"bytes_in_use", "bytes_limit"}, …]},
             "attention": {"prefill", "decode"},
-            "sampling": {"top_k", "groups"?, "width"?, "cap"?}}
-            (after warmup. `device` is what JAX handed this process and
+            "sampling": {"top_k", "groups"?, "width"?, "cap"?},
+            "moe"?: {"experts", "top_k", "layout", "route": {"decode",
+                     "prefill"}, "quantized_leaf_route"}}
+            (after warmup. `moe` only for an expert model: engine.py
+            moe_report. `device` is what JAX handed this process and
             its per-device memory_stats() once every program has
             compiled; `attention` is "pallas" | "pallas-interpret" |
             "xla" per program; `sampling.top_k` is "grouped" (with its
@@ -411,6 +414,9 @@ class EngineHost:
             "device": device_report(),
             "attention": self._engine.attention_paths(),
             "sampling": self._engine.sampling_route()}
+        moe = self._engine.moe_report()
+        if moe is not None:
+            self._startup["moe"] = moe
         self._write({"op": HostOp.READY,
                      "model": self._config.model_name,
                      "role": self._role,
@@ -433,6 +439,7 @@ class EngineHost:
                     f"attention=prefill:{attn['prefill']},"
                     f"decode:{attn['decode']} "
                     f"sampling={samp} "
+                    + (f"moe={json.dumps(moe)} " if moe else "") +
                     f"build={t_build:.1f}s warmup={t_warmup:.1f}s "
                     f"compile_cache={cache_dir or 'off'}")
 
@@ -582,13 +589,15 @@ class EngineHost:
             tempfile.gettempdir(), "symmetry_tpu_profiles")
 
         def run() -> None:
+            t0 = time.monotonic()
             try:
                 path = capture_device_profile(out_dir, duration_s)
             except Exception as exc:  # noqa: BLE001 — reply, never crash
                 self._write({"op": HostOp.PROFILE, "error": str(exc)})
                 return
             logger.info(f"device profile captured → {path} "
-                        f"({duration_s:.1f}s window)")
+                        f"({duration_s:.1f}s window, "
+                        f"{time.monotonic() - t0:.1f}s in all)")
             self._write({"op": HostOp.PROFILE, "path": path,
                          "duration_s": duration_s})
 

@@ -495,6 +495,13 @@ class Scheduler:
             for name in LOOP_PHASES}
         if self._adopt_hist.count:
             out["adopt_dispatch_s"] = self._adopt_hist.to_dict()
+        if getattr(self.engine, "expert_pairs", None):
+            # Valid (token, expert) pairs computed since start, per
+            # expert and in all, as of the last synced decode block; the
+            # form each program kind's expert FFN takes (models/moe.py).
+            counts = list(self.engine.expert_pairs)
+            out["moe"] = {"pairs": sum(counts), "expert_pairs": counts,
+                          "route": self.engine.moe_report()["route"]}
         # Gauges for the two admission backlogs that were invisible in
         # host→provider stats: the budget-deferred deque and the
         # chunked-prefill jobs still building their prefixes.
@@ -1040,6 +1047,11 @@ class Scheduler:
         with self._phase("sync"):
             t0 = time.perf_counter()
             toks = np.asarray(device_toks)  # blocks on THIS block only
+            # MoE: the block's per-expert pair counts came out of the
+            # same program, so they are ready — a read, not a wait.
+            collect = getattr(self.engine, "collect_expert_pairs", None)
+            if collect is not None:
+                collect()
             t1 = time.perf_counter()
         self.metrics["block_syncs"] += 1
         self.metrics["sync_s"] += t1 - t0

@@ -76,9 +76,6 @@ class MoEConfig(ModelConfig):
 
     num_experts: int = 8
     num_experts_per_tok: int = 2
-    # Prefill token-dispatch capacity (models/moe.py); None = the module
-    # default. Set >= num_experts / num_experts_per_tok for zero drops.
-    moe_capacity_factor: float | None = None
 
 
 # Named presets; sizes from the public HF configs of each model family.
@@ -116,6 +113,13 @@ PRESETS: dict[str, ModelConfig] = {
         vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
         num_kv_heads=2, intermediate_size=128, rope_theta=10000.0,
         max_position=512, num_experts=4, num_experts_per_tok=2,
+    ),
+    # mixtral's routing shape (8 experts, top 2) with heads that divide a
+    # `model: 4` mesh: the four-device rehearsals of the sharded path
+    "tiny-moe8": MoEConfig(
+        vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
+        num_kv_heads=4, intermediate_size=128, rope_theta=10000.0,
+        max_position=512, num_experts=8, num_experts_per_tok=2,
     ),
     "mixtral-8x7b": MoEConfig(
         vocab_size=32000, hidden_size=4096, num_layers=32, num_heads=32,
@@ -176,6 +180,10 @@ class KVCache(NamedTuple):
     lengths: jnp.ndarray  # [batch] int32: valid entries per slot
     k_scale: jnp.ndarray | None = None
     v_scale: jnp.ndarray | None = None
+    # MoE only, and only where the caller asked init_cache to count: valid
+    # (token, expert) pairs computed per expert, summed over the layers of
+    # every forward through this cache ([experts] int32; models/moe.py).
+    expert_pairs: jnp.ndarray | None = None
 
     @property
     def quantized(self) -> bool:
@@ -184,10 +192,12 @@ class KVCache(NamedTuple):
 
 def init_cache(
     config: ModelConfig, batch: int, capacity: int, dtype=jnp.bfloat16,
-    *, quantized: bool = False,
+    *, quantized: bool = False, count_experts: bool = False,
 ) -> KVCache:
     shape = (config.num_layers, batch, capacity, config.num_kv_heads,
              config.dim_per_head)
+    pairs = (jnp.zeros((config.num_experts,), jnp.int32)
+             if count_experts else None)
     if quantized:
         scale_shape = (config.num_layers, batch, config.num_kv_heads,
                        capacity)
@@ -197,11 +207,13 @@ def init_cache(
             lengths=jnp.zeros((batch,), jnp.int32),
             k_scale=jnp.zeros(scale_shape, jnp.float32),
             v_scale=jnp.zeros(scale_shape, jnp.float32),
+            expert_pairs=pairs,
         )
     return KVCache(
         k=jnp.zeros(shape, dtype),
         v=jnp.zeros(shape, dtype),
         lengths=jnp.zeros((batch,), jnp.int32),
+        expert_pairs=pairs,
     )
 
 
@@ -210,7 +222,8 @@ def init_cache(
 
 
 def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
-                *, quantize: bool = False) -> dict:
+                *, quantize: bool = False, shardings: dict | None = None,
+                slice_above: int | None = None) -> dict:
     """Random init (scaled normal). Real serving loads HF weights instead.
 
     quantize=True materializes QUANT_KEYS leaves as int8 directly — the
@@ -218,16 +231,34 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     compiled program (ops/quant.py make_leaf), so no full-precision copy of
     a leaf ever lands in HBM beyond that program's fused temporaries. That
     is what lets an 8B-parameter model initialize on a 16 GB chip.
+
+    A leaf whose per-device share in `dtype` is over `slice_above` bytes
+    (default: a quarter of the device's memory, ops/quant.py
+    default_leaf_limit) is built a layer at a time instead
+    (make_leaf_sliced) — mixtral-8x7b's expert stacks. `shardings` is the
+    params' sharding tree when the caller jits this with out_shardings: it
+    gives the per-device share and places each slice.
     """
     c = config
     keys = iter(jax.random.split(key, 16))
 
-    from symmetry_tpu.ops.quant import make_leaf
+    from symmetry_tpu.ops.quant import (
+        default_leaf_limit, leaf_is_sliced, make_leaf, make_leaf_sliced)
+
+    if slice_above is None:
+        slice_above = default_leaf_limit()
 
     def dense(k, shape, scale=None, name=None):
         scale = scale if scale is not None else shape[0] ** -0.5
-        return make_leaf(k, shape, scale, dtype,
-                         quantized=quantize and name in QUANT_KEYS)
+        quantized = quantize and name in QUANT_KEYS
+        # Only the stacked per-layer leaves have a layers axis to slice.
+        where = (shardings or {}).get("layers", {}).get(name)
+        q_where = where.q if isinstance(where, QuantizedTensor) else where
+        if name in STACKED_KEYS and leaf_is_sliced(shape, dtype, q_where,
+                                                   slice_above):
+            return make_leaf_sliced(k, shape, scale, dtype,
+                                    quantized=quantized, sharding=where)
+        return make_leaf(k, shape, scale, dtype, quantized=quantized)
 
     L, E, F = c.num_layers, c.hidden_size, c.intermediate_size
     n_exp = getattr(c, "num_experts", 0)
@@ -296,10 +327,12 @@ def param_logical_axes(config: ModelConfig) -> dict:
     return axes
 
 
-def cache_logical_axes(*, quantized: bool = False) -> KVCache:
+def cache_logical_axes(*, quantized: bool = False,
+                       count_experts: bool = False) -> KVCache:
     kv = ("layers", "batch", "cache_seq", "kv_heads", "head_dim")
     sc = ("layers", "batch", "kv_heads", "cache_seq") if quantized else None
-    return KVCache(k=kv, v=kv, lengths=("batch",), k_scale=sc, v_scale=sc)
+    return KVCache(k=kv, v=kv, lengths=("batch",), k_scale=sc, v_scale=sc,
+                   expert_pairs=(None,) if count_experts else None)
 
 
 # ---------------------------------------------------------------------------
@@ -367,6 +400,17 @@ def _layer(
     # overwritten later. Quantized caches write int8 payload + f32 scales.
     b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
     l_idx = jnp.full((B, S), layer, jnp.int32)
+    row = (l_idx, b_idx, positions)  # each write is one [K, D] row
+    if tp_mesh is not None:
+        # A cache sharded by KV head may hold 2 heads a chip, and XLA then
+        # keeps it in HBM as [L, B, K, T, D] (a second-minor dim of 2
+        # would pad 2x). A scatter whose window is the [K, D] row needs K
+        # next to D, so the program copied the whole cache into the padded
+        # layout around every decode block (+4.3 GB a chip at 64 x 2048:
+        # it did not fit). Indexing the head too leaves a window of D
+        # alone, which either layout serves in place.
+        row = tuple(i[..., None] for i in row) + (
+            jnp.arange(nkv, dtype=jnp.int32)[None, None, :],)
     if cache.quantized:
         from symmetry_tpu.ops import kv_append as kva
         from symmetry_tpu.ops.quant import quantize_kv
@@ -390,15 +434,15 @@ def _layer(
             # the mixed advanced/slice index puts the advanced dims (B, S)
             # in front, matching the [B, S, K] scale values.
             cache = cache._replace(
-                k=cache.k.at[l_idx, b_idx, positions].set(kq),
-                v=cache.v.at[l_idx, b_idx, positions].set(vq),
+                k=cache.k.at[row].set(kq),
+                v=cache.v.at[row].set(vq),
                 k_scale=cache.k_scale.at[l_idx, b_idx, :, positions].set(ks),
                 v_scale=cache.v_scale.at[l_idx, b_idx, :, positions].set(vs),
             )
     else:
         cache = cache._replace(
-            k=cache.k.at[l_idx, b_idx, positions].set(k.astype(cache.k.dtype)),
-            v=cache.v.at[l_idx, b_idx, positions].set(v.astype(cache.v.dtype)),
+            k=cache.k.at[row].set(k.astype(cache.k.dtype)),
+            v=cache.v.at[row].set(v.astype(cache.v.dtype)),
         )
 
     if ring_mesh is not None:
@@ -461,7 +505,10 @@ def _layer(
     if "router" in lp:
         from symmetry_tpu.models.moe import moe_mlp
 
-        h = h + moe_mlp(x, lp, config)
+        y, pairs = moe_mlp(x, lp, config, seq_lens, tp_mesh)
+        h = h + y
+        if cache.expert_pairs is not None:
+            cache = cache._replace(expert_pairs=cache.expert_pairs + pairs)
     else:
         h = h + qmatmul(_act(qmatmul(x, lp["wg"]), config)
                         * qmatmul(x, lp["wu"]), lp["wd"])
@@ -608,6 +655,8 @@ def logits_from_hidden(params: dict, config: ModelConfig,
 # Weights eligible for int8 quantization (all the large matmuls; the
 # embedding stays dense — it is gathered, not contracted).
 QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "lm_head")
+# Those of them stacked along a leading layers axis.
+STACKED_KEYS = QUANT_KEYS[:-1]
 
 
 def quantize_params(params: dict) -> dict:

@@ -1,172 +1,229 @@
-"""Mixture-of-experts MLP (mixtral family) — dense-mixture, TPU-first.
+"""Mixture-of-experts FFN (mixtral family): two lossless forms, chosen by
+token count.
 
-The reference has no model code at all (SURVEY §0); MoE enters through the
-framework's model-family coverage (mixtral-8x7b preset, llama.py) and the
-`expert` mesh axis (SURVEY §2.3: expert parallelism "only if MoE models
-are added" — they are).
+The block is HF `MixtralSparseMoeBlock`: router logits in float32, top-k
+experts per token, softmax over the k selected logits, and
 
-Design: DENSE mixture. Every expert processes every token; the top-k
-router gates (zeros outside the selected experts) weight the combine. Why
-this is the TPU-right shape for serving:
+    y = sum_e gate_e * wd_e(silu(wg_e x) * wu_e x)      over the k selected.
 
-  - A serving batch of B slots × top-2 routing touches essentially every
-    expert every step, so all expert weights stream from HBM regardless —
-    the decode step stays bandwidth-bound and skipping compute for
-    unselected (token, expert) pairs saves no HBM traffic.
-  - The expert dim becomes a leading batch dim of ONE big dot_general per
-    projection — the MXU sees [experts] × [tokens, embed] @ [embed, ffn]
-    batched matmuls, no gathers, no ragged dispatch, no recompiles.
-  - Sharding: experts map to the `expert` mesh axis and each expert's ffn
-    dim to `model` (parallel/sharding.py rules); XLA derives the combine
-    all-reduce from the shardings, exactly like the dense-MLP TP path.
+Neither form drops a (token, expert) pair, and there is no capacity:
 
-PREFILL is the exception: it is compute-bound (S large), and the dense
-mixture pays num_experts/top_k extra FLOPs (4x for mixtral-8x7b). There
-moe_mlp routes through capacity-factor token DISPATCH (moe_mlp_dispatch):
-tokens are gathered into a static [experts, capacity, embed] buffer (rank
-computed with a one-hot cumsum — no ragged shapes, no recompiles), each
-expert runs one batched matmul over just its tokens, and a scatter-add
-combines the gated results. Under an `expert` mesh axis the gather/
-scatter become XLA-inserted all-to-alls along it, exactly the GShard/
-Switch dispatch pattern. Tokens past an expert's capacity are dropped
-(standard switch semantics); capacity_factor trades that tail loss
-against the FLOP saving.
+- ROUTED (`_routed_ffn`; programs of `ROUTED_MIN_TOKENS` tokens or more —
+  the larger prefill dispatches). The T*k pairs are sorted by expert, so
+  each expert's rows are one contiguous group; the three expert matmuls run
+  over the groups as `jax.lax.ragged_dot` (XLA:TPU's native grouped matmul:
+  T*k rows whatever the routing — static shapes, FLOPs of k experts and not
+  of all of them); the rows are un-sorted by a gather and combined with the
+  gates in float32.
+- DENSE MIXTURE (`_dense_mixture`; decode and the smaller prefills). Every
+  expert computes every token as one batched matmul and the gates, zero
+  outside the top k, weight the combine: X/k times the FLOPs, the same
+  weight bytes. A decode step of B slots x k pairs hits every expert anyway,
+  so all expert weights stream from HBM either way.
+
+Which form where is a measurement, not a taste (`tools/moe_decode_ab.py`;
+PERF.md, PR 28; one chip's share of mixtral-8x7b under `model: 4`, ms a
+layer, routed / dense): 64 tokens 1.35 / 0.49 (the weight stream's floor is
+0.43), 256 tokens 2.85 / 1.04, 768 tokens 3.69 / 3.24, 1,024 tokens 4.00 /
+3.93, 1,280 tokens 4.42 / 4.91, 2,048 tokens 5.98 / 7.93, 4,096 tokens 10.7
+/ 15.8. The mixed int8 dot runs the dense form at ~90% of the MXU peak,
+`ragged_dot` with an int8 operand reaches ~35%, so routing pays only once
+it saves more than it wastes: they cross at about 1,050 tokens.
+`moe_route(T)` is that choice, from the shape alone; `startup.moe` reports
+it per program.
+
+int8 expert stacks stay int8 in HBM in both forms: the int8 payload is the
+dot's operand, the per-(expert, column) scale is applied to the float32
+accumulator (row by row in the routed form: each row knows its expert).
+
+Sharding (`tp_mesh` with `model` > 1): every expert's FFN width is split
+over `model` (all experts on every chip) and the FFN runs per shard inside
+one `shard_map` — each chip routes identically (the router and the
+activations are replicated over `model`), computes its slice of every
+expert, combines, and one `psum` over `model` adds the partial [T, D] rows:
+the dense-TP collective pattern, no all-to-all, no straggler when routing is
+uneven. Without `tp_mesh`, or with an `expert` axis > 1, the same function
+is traced bare and GSPMD partitions it from the shardings (exact; not
+measured on a chip — PERF.md).
+
+Counting: `moe_mlp` also returns the per-expert number of VALID pairs it
+computed ([experts] int32; padded prompt positions are left out), which
+`models/llama.py _layer` adds to `KVCache.expert_pairs` where the cache
+carries that counter (the engine's does; `stats.engine.moe`).
 """
 
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 
 from symmetry_tpu.ops.quant import QuantizedTensor
 
-# Per-expert buffer = ceil(T * top_k / X * CAPACITY_FACTOR) tokens.
-# Capacity-factor dispatch is LOSSY under routing imbalance: (token,
-# expert) pairs past an expert's capacity contribute nothing (standard
-# switch semantics, no renormalization). The default of 2.0 keeps the
-# drop tail negligible for mixtral-like routing while still saving
-# X / (k * cf) = 2x prefill FLOPs; set `moe_capacity_factor` to
-# num_experts / num_experts_per_tok for guaranteed-lossless dispatch
-# (which also forfeits the FLOP saving — capacity then covers the
-# worst case), or lower for more speed at more drop risk.
-CAPACITY_FACTOR = 2.0
-# Below this many tokens the dense mixture is used even at S > 1: the
-# dispatch bookkeeping outweighs the matmul saving for tiny prefills.
-MIN_DISPATCH_TOKENS = 64
+
+# Programs of fewer tokens take the dense mixture (module docstring: the
+# two forms tie at 1,024 tokens a dispatch and routing wins above).
+ROUTED_MIN_TOKENS = 1024
 
 
-def qmatmul_experts(x: jnp.ndarray, w) -> jnp.ndarray:
-    """[B, S, D] @ per-expert [X, D, F] -> [B, S, X, F].
+def moe_route(n_tokens: int) -> str:
+    """The form a program of `n_tokens` tokens takes."""
+    return "routed" if n_tokens >= ROUTED_MIN_TOKENS else "dense-mixture"
 
-    QuantizedTensor experts keep the int8 payload as the dot operand (no
-    bf16 materialization — same rule as ops/quant.py qmatmul); per-column
-    scales [X, F] apply to the f32 accumulator."""
+
+def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """[T, D] tokens -> (gates [T, k] float32, experts [T, k] int32).
+    Logits accumulate and come out in float32; the softmax is over the k
+    selected logits (mixtral: normalise AFTER selection)."""
+    logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+    top_vals, top_idx = jax.lax.top_k(logits, k)
+    return jax.nn.softmax(top_vals, axis=-1), top_idx.astype(jnp.int32)
+
+
+def _grouped_matmul(rows: jnp.ndarray, w, group_sizes: jnp.ndarray,
+                    row_expert: jnp.ndarray) -> jnp.ndarray:
+    """rows [R, A] sorted by expert @ per-expert [X, A, F] -> [R, F] in
+    float32. A QuantizedTensor keeps its int8 payload as the operand; its
+    [X, F] scales are gathered per row onto the accumulator."""
     if isinstance(w, QuantizedTensor):
-        y = jax.lax.dot_general(
-            x, w.q,
-            dimension_numbers=(((x.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [B, S, X, F]
+        y = jax.lax.ragged_dot(rows, w.q, group_sizes,
+                               preferred_element_type=jnp.float32)
+        return y * jnp.take(w.scale, row_expert, axis=0)
+    return jax.lax.ragged_dot(rows, w, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _routed_ffn(x, valid, router, wg, wu, wd, k: int):
+    """x [T, D], valid [T] bool -> (y [T, D] float32, pairs [X] int32).
+    Under shard_map this is one shard's program: wg/wu hold a slice of the
+    FFN width, wd the matching rows, and y is that slice's partial sum."""
+    T, _ = x.shape
+    X = router.shape[-1]
+    gates, experts = route_top_k(x, router, k)            # [T, k]
+    flat_expert = experts.reshape(-1)                     # [T*k]
+    # Stable sort: pairs of one expert keep token order, so the result
+    # does not depend on how the sort breaks ties.
+    order = jnp.argsort(flat_expert, stable=True)         # sorted -> pair
+    row_expert = jnp.take(flat_expert, order)
+    onehot = flat_expert[:, None] == jnp.arange(X, dtype=jnp.int32)
+    group_sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)
+    pairs = jnp.sum(onehot & jnp.repeat(valid, k)[:, None], axis=0,
+                    dtype=jnp.int32)
+
+    rows = jnp.take(x, order // k, axis=0)                # [T*k, D]
+    h = (jax.nn.silu(_grouped_matmul(rows, wg, group_sizes, row_expert))
+         * _grouped_matmul(rows, wu, group_sizes, row_expert))
+    y = _grouped_matmul(h.astype(x.dtype), wd, group_sizes, row_expert)
+
+    # Un-sort by a gather (the inverse permutation), then the gated sum
+    # over each token's k rows in float32: no scatter-add, so the sum has
+    # one order.
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * k, dtype=order.dtype))
+    y = jnp.take(y, inverse, axis=0).reshape(T, k, -1)
+    return jnp.einsum("tkd,tk->td", y, gates), pairs
+
+
+def _experts_dot(x: jnp.ndarray, w) -> jnp.ndarray:
+    """[T, A] @ per-expert [X, A, F] -> [T, X, F], every expert at once."""
+    if isinstance(w, QuantizedTensor):
+        y = jax.lax.dot_general(x, w.q, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
         return (y * w.scale).astype(x.dtype)
-    return jnp.einsum("bsd,xdf->bsxf", x, w)
+    return jnp.einsum("ta,xaf->txf", x, w)
 
 
-def route_top_k(logits: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Router logits [B, S, X] -> dense gates [B, S, X]: softmax over the
-    top-k logits (mixtral semantics: normalize AFTER selection), zeros
-    elsewhere. Static-shape: one_hot scatter, no gathers."""
-    top_vals, top_idx = jax.lax.top_k(logits, k)          # [B, S, k]
-    probs = jax.nn.softmax(top_vals, axis=-1)
-    onehot = jax.nn.one_hot(top_idx, logits.shape[-1],
-                            dtype=probs.dtype)            # [B, S, k, X]
-    return jnp.einsum("bsk,bskx->bsx", probs, onehot)
-
-
-def moe_mlp(x: jnp.ndarray, lp: dict, config) -> jnp.ndarray:
-    """MoE FFN: [B, S, E] -> [B, S, E]. Dense mixture at decode
-    (bandwidth-bound), capacity-factor dispatch at prefill
-    (compute-bound) — see module docstring."""
-    B, S, _ = x.shape
-    if S > 1 and B * S >= MIN_DISPATCH_TOKENS:
-        return moe_mlp_dispatch(x, lp, config)
-    gates = route_top_k(
-        jnp.asarray(x @ lp["router"], jnp.float32),
-        config.num_experts_per_tok).astype(x.dtype)       # [B, S, X]
-    h = jax.nn.silu(qmatmul_experts(x, lp["wg"])) * qmatmul_experts(
-        x, lp["wu"])                                      # [B, S, X, F]
-    # Per-expert down-projection then gated combine over experts.
-    wd = lp["wd"]
+def _dense_mixture(x, valid, router, wg, wu, wd, k: int):
+    """Same contract as `_routed_ffn`; every expert computes every token."""
+    X = router.shape[-1]
+    gates, experts = route_top_k(x, router, k)            # [T, k]
+    onehot = experts[..., None] == jnp.arange(X, dtype=jnp.int32)
+    dense_gates = jnp.sum(jnp.where(onehot, gates[..., None], 0.0), axis=1)
+    pairs = jnp.sum(onehot & valid[:, None, None], axis=(0, 1),
+                    dtype=jnp.int32)
+    h = jax.nn.silu(_experts_dot(x, wg)) * _experts_dot(x, wu)  # [T, X, F]
     if isinstance(wd, QuantizedTensor):
-        y = jax.lax.dot_general(
-            h, wd.q,
-            dimension_numbers=(((3,), (1,)), ((2,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # batch over experts: [X, B, S, E]
-        y = (y * wd.scale[:, None, None, :]).astype(x.dtype)
-        y = jnp.moveaxis(y, 0, 2)                         # [B, S, X, E]
+        y = jax.lax.dot_general(h, wd.q, (((2,), (1,)), ((1,), (0,))),
+                                preferred_element_type=jnp.float32)
+        y = y * wd.scale[:, None, :]                      # [X, T, D]
     else:
-        y = jnp.einsum("bsxf,xfe->bsxe", h, wd)
-    return jnp.einsum("bsxe,bsx->bse", y, gates)
+        y = jnp.einsum("txf,xfd->xtd", h, wd,
+                       preferred_element_type=jnp.float32)
+    return jnp.einsum("xtd,tx->td", y, dense_gates), pairs
 
 
-def _expert_matmul(xg: jnp.ndarray, w) -> jnp.ndarray:
-    """Per-expert batched matmul: [X, C, A] @ [X, A, F] -> [X, C, F]."""
-    if isinstance(w, QuantizedTensor):
-        y = jax.lax.dot_general(
-            xg, w.q,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        return (y * w.scale[:, None, :]).astype(xg.dtype)
-    return jnp.einsum("xca,xaf->xcf", xg, w)
+def _expert_ffn(x, valid, router, wg, wu, wd, k: int):
+    """x [T, D] -> (y [T, D] float32, valid pairs [X]) by the form this
+    token count takes."""
+    form = (_routed_ffn if moe_route(x.shape[0]) == "routed"
+            else _dense_mixture)
+    return form(x, valid, router, wg, wu, wd, k)
 
 
-def moe_mlp_dispatch(x: jnp.ndarray, lp: dict, config) -> jnp.ndarray:
-    """Capacity-factor token dispatch (GShard/Switch shape, static sizes).
+def _model_shards(tp_mesh, ffn_width: int) -> int:
+    """How many ways `model` splits the FFN width inside a shard_map, or 1
+    when the expert FFN is traced bare (no mesh, an `expert` axis, or a
+    width that does not divide)."""
+    if tp_mesh is None:
+        return 1
+    shape = dict(tp_mesh.shape)
+    n = shape.get("model", 1)
+    if shape.get("expert", 1) > 1 or ffn_width % n:
+        return 1
+    return n
 
-    Each (token, choice) pair is ranked within its expert by a one-hot
-    cumsum; pairs past the expert's capacity are dropped. Experts compute
-    ONE batched matmul over their gathered tokens — FLOPs scale with
-    top_k * capacity_factor instead of num_experts — and a scatter-add
-    puts the gated outputs back in token order.
-    """
-    B, S, E = x.shape
-    X = config.num_experts
+
+def moe_layout(tp_mesh, ffn_width: int) -> str:
+    """One line for `startup.moe`: where the expert weights live."""
+    n = _model_shards(tp_mesh, ffn_width)
+    if n > 1:
+        return (f"every expert's FFN width split {n} ways over `model` "
+                f"({ffn_width // n} columns a chip), all experts on every "
+                f"chip, one psum after the combine (shard_map)")
+    if tp_mesh is None:
+        return "one device holds every expert whole"
+    return "GSPMD partitions the expert FFN from the parameter shardings"
+
+
+def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
+            tp_mesh=None) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """MoE FFN: [B, S, D] -> ([B, S, D], valid pairs per expert [X]).
+    `seq_lens` [B] says how many of each row's S positions are real."""
+    B, S, D = x.shape
     k = config.num_experts_per_tok
-    cf = getattr(config, "moe_capacity_factor", None) or CAPACITY_FACTOR
-    T = B * S
-    C = min(T, math.ceil(T * k / X * cf))
+    if seq_lens is None:
+        valid = jnp.ones((B * S,), bool)
+    else:
+        valid = (jnp.arange(S, dtype=jnp.int32)[None, :]
+                 < seq_lens[:, None]).reshape(B * S)
+    xf = x.reshape(B * S, D)
+    args = (xf, valid, lp["router"], lp["wg"], lp["wu"], lp["wd"])
 
-    xf = x.reshape(T, E)
-    logits = jnp.asarray(xf @ lp["router"], jnp.float32)      # [T, X]
-    top_vals, top_idx = jax.lax.top_k(logits, k)              # [T, k]
-    probs = jax.nn.softmax(top_vals, axis=-1)                 # mixtral renorm
+    n = _model_shards(tp_mesh, config.intermediate_size)
+    if n == 1:
+        y, pairs = _expert_ffn(*args, k)
+    else:
+        from jax.sharding import PartitionSpec as P
 
-    flat_expert = top_idx.reshape(-1)                         # [T*k]
-    flat_token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
-    flat_gate = probs.reshape(-1).astype(x.dtype)
+        data = dict(tp_mesh.shape).get("data", 1)
+        b = "data" if data > 1 and (B * S) % data == 0 else None
 
-    # Rank of each pair within its expert = how many earlier pairs chose
-    # the same expert (one-hot cumsum: static shapes, no sort).
-    onehot = jax.nn.one_hot(flat_expert, X, dtype=jnp.int32)  # [T*k, X]
-    before = jnp.cumsum(onehot, axis=0) - onehot
-    rank = jnp.take_along_axis(before, flat_expert[:, None], 1)[:, 0]
-    keep = rank < C
-    # Slot in the [X * C] dispatch buffer; dropped pairs target a trash
-    # slot (index X*C) so every scatter stays in bounds and static.
-    slot = jnp.where(keep, flat_expert * C + rank, X * C)
+        def spec(w, q_spec, scale_spec):
+            return (QuantizedTensor(q=q_spec, scale=scale_spec)
+                    if isinstance(w, QuantizedTensor) else q_spec)
 
-    token_for_slot = jnp.zeros((X * C + 1,), jnp.int32).at[slot].set(
-        flat_token)
-    gate_for_slot = jnp.zeros((X * C + 1,), x.dtype).at[slot].set(
-        jnp.where(keep, flat_gate, 0).astype(x.dtype))
+        col = spec(lp["wg"], P(None, None, "model"), P(None, "model"))
+        row = spec(lp["wd"], P(None, "model", None), P())
 
-    xg = jnp.take(xf, token_for_slot[:X * C], axis=0).reshape(X, C, E)
-    h = jax.nn.silu(_expert_matmul(xg, lp["wg"])) * _expert_matmul(
-        xg, lp["wu"])                                         # [X, C, F]
-    y = _expert_matmul(h, lp["wd"])                           # [X, C, E]
+        def shard(*a):
+            y, pairs = _expert_ffn(*a, k)
+            y = jax.lax.psum(y, "model")
+            if b is not None:
+                pairs = jax.lax.psum(pairs, b)
+            return y, pairs
 
-    weighted = y.reshape(X * C, E) * gate_for_slot[:X * C, None]
-    out = jnp.zeros((T, E), x.dtype).at[token_for_slot[:X * C]].add(weighted)
-    return out.reshape(B, S, E)
+        y, pairs = jax.shard_map(
+            shard, mesh=tp_mesh,
+            in_specs=(P(b, None), P(b), P(), col, col, row),
+            out_specs=(P(b, None), P()), check_vma=False)(*args)
+    return y.astype(x.dtype).reshape(B, S, D), pairs

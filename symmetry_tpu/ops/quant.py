@@ -13,6 +13,7 @@ shard with NamedShardings, and donate exactly like dense ones.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -345,3 +346,55 @@ def make_leaf(key, shape: tuple[int, ...], scale: float, dtype,
     what makes 8B-scale quantized init fit on one chip."""
     w = (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
     return quantize(w) if quantized else w
+
+
+def leaf_is_sliced(shape: tuple[int, ...], dtype, sharding=None,
+                   limit_bytes: int | None = None) -> bool:
+    """Whether init builds this leaf a leading slice at a time
+    (make_leaf_sliced) instead of in one piece (make_leaf): when one
+    device's share of the leaf in `dtype` — the full-precision temporary a
+    one-piece init holds beside its output — is over `limit_bytes`. The
+    two forms draw DIFFERENT random values, so a leaf that fits keeps the
+    one-piece form and its values (mistral-7b and qwen2-7b on one chip:
+    3.76 / 3.80 GB against a quarter of 16.9 GB). `sharding` is the
+    leaf's NamedSharding (of `q` for a quantized leaf) or None."""
+    if limit_bytes is None:
+        return False
+    local = sharding.shard_shape(tuple(shape)) if sharding is not None \
+        else shape
+    return math.prod(local) * jnp.dtype(dtype).itemsize > limit_bytes
+
+
+def default_leaf_limit() -> int | None:
+    """A quarter of the first device's memory, or None where the backend
+    reports none (the CPU): then nothing is sliced."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return limit // 4 if limit else None
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "scale", "dtype",
+                                             "quantized", "sharding"))
+def make_leaf_sliced(key, shape: tuple[int, ...], scale: float, dtype,
+                     quantized: bool = False, sharding=None):
+    """make_leaf for a stacked leaf too large to hold in full precision:
+    one slice of the leading (layers) axis at a time under `lax.map`, each
+    from its own key, so the full-precision temporary is one slice — what
+    lets mixtral-8x7b's [32, 8, 4096, 14336] expert stacks (7.5 GB a chip
+    in bf16 under `model: 4`) be made beside 11.7 GB of int8 weights.
+    `sharding` (NamedSharding, or a QuantizedTensor of them) is the
+    stack's placement; each slice is constrained to it minus the leading
+    axis, so the temporary is sharded like the output."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    def one(k):
+        w = (jax.random.normal(k, shape[1:], jnp.float32) * scale
+             ).astype(dtype)
+        out = quantize(w) if quantized else w
+        if sharding is None:
+            return out
+        return jax.lax.with_sharding_constraint(
+            out, jax.tree.map(
+                lambda s: NamedSharding(s.mesh, P(*s.spec[1:])), sharding))
+
+    return jax.lax.map(one, jax.random.split(key, shape[0]))

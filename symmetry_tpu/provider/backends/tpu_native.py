@@ -234,6 +234,7 @@ class TpuNativeBackend(InferenceBackend):
         # instead of tripping the breaker.
         self._min_stable_s = float(sup.get("min_stable_s", 5.0))
         self._spawned_at: float | None = None
+        self._device_count = 1  # chips the engine host reported at READY
         self._supervisor: asyncio.Task | None = None
         self._host_down: asyncio.Event | None = None  # set by reader EOF
         self._down_reason = "crash"
@@ -529,6 +530,7 @@ class TpuNativeBackend(InferenceBackend):
                 f"{self._prefill_clock_offset * 1e6:+.0f}us")
         self._spawned_at = time.monotonic()
         dev = ready.get("device") or {}
+        self._device_count = int(dev.get("device_count") or 1)
         log.info(f"tpu_native engine host up (pid {self._proc.pid}"
                  f"{', disagg pair' if self._disagg else ''}): "
                  f"model={self._model_name} "
@@ -2237,8 +2239,10 @@ class TpuNativeBackend(InferenceBackend):
         payload = {"duration_s": float(duration_s),
                    **({"dir": out_dir} if out_dir else {})}
         # Generous beyond the window: the process's FIRST capture pays
-        # the profiler's cold init (tens of seconds on a loaded host).
-        timeout = float(duration_s) + 90.0
+        # the profiler's cold init (tens of seconds on a loaded host),
+        # and stopping a capture collects every chip's events (four
+        # chips under load did not answer within 90 s; PERF.md, PR 28).
+        timeout = float(duration_s) + 90.0 * max(1, self._device_count)
         if self._process_mode and self._pool_mode:
             m0 = next((m for m in self._decode_members.values()
                        if m.alive), None)

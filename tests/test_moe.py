@@ -18,18 +18,21 @@ from symmetry_tpu.models.moe import route_top_k
 
 class TestRouting:
     def test_gates_topk_normalized(self):
-        logits = jax.random.normal(jax.random.key(0), (2, 3, 8))
-        gates = np.asarray(route_top_k(logits, 2))
-        # exactly k nonzero per token, summing to 1
-        nonzero = (gates > 0).sum(-1)
-        np.testing.assert_array_equal(nonzero, np.full((2, 3), 2))
-        np.testing.assert_allclose(gates.sum(-1), 1.0, rtol=1e-5)
+        x = jax.random.normal(jax.random.key(0), (6, 16))
+        router = jax.random.normal(jax.random.key(1), (16, 8))
+        gates, experts = route_top_k(x, router, 2)
+        assert gates.shape == experts.shape == (6, 2)
+        assert gates.dtype == jnp.float32
+        # k distinct experts per token, gates summing to 1
+        assert (np.asarray(experts[:, 0]) != np.asarray(experts[:, 1])).all()
+        np.testing.assert_allclose(np.asarray(gates).sum(-1), 1.0, rtol=1e-5)
 
     def test_gates_pick_largest(self):
-        logits = jnp.asarray([[[1.0, 5.0, 3.0, -2.0]]])
-        gates = np.asarray(route_top_k(logits, 2))[0, 0]
-        assert gates[1] > gates[2] > 0
-        assert gates[0] == 0 and gates[3] == 0
+        # identity router: the logits are the token itself
+        x = jnp.asarray([[1.0, 5.0, 3.0, -2.0]])
+        gates, experts = route_top_k(x, jnp.eye(4), 2)
+        assert np.asarray(experts).tolist() == [[1, 2]]
+        assert gates[0, 0] > gates[0, 1] > 0
 
 
 class TestMoEForward:
@@ -177,94 +180,3 @@ class TestMoECheckpoint:
         np.testing.assert_allclose(
             converted["layers"]["wd"],
             np.asarray(params["layers"]["wd"], np.float32), rtol=1e-6)
-
-
-class TestDispatchPrefill:
-    """Capacity-factor token dispatch (moe_mlp_dispatch): prefill computes
-    top_k*cf/num_experts of the dense-mixture FLOPs; with capacity high
-    enough for zero drops it must match the dense mixture EXACTLY."""
-
-    def _layer_params(self, cfg, key):
-        from symmetry_tpu.models.llama import init_params
-
-        params = init_params(cfg, key, jnp.float32)
-        lp = {k: v[0] for k, v in params["layers"].items()}
-        return lp
-
-    def test_no_drop_dispatch_matches_dense(self):
-        import dataclasses
-
-        from symmetry_tpu.models.moe import moe_mlp, moe_mlp_dispatch
-
-        cfg = preset("tiny-moe")
-        lp = self._layer_params(cfg, jax.random.key(0))
-        x = jax.random.normal(jax.random.key(1), (4, 32, cfg.hidden_size),
-                              jnp.float32)
-        # capacity X/k => C = T: nothing can drop
-        full = dataclasses.replace(
-            cfg, moe_capacity_factor=cfg.num_experts
-            / cfg.num_experts_per_tok)
-        got = moe_mlp_dispatch(x, lp, full)
-        # dense path: call with S=1 slices to force the dense branch
-        dense = moe_mlp(x[:, :1], lp, cfg)
-        np.testing.assert_allclose(np.asarray(got[:, :1]),
-                                   np.asarray(dense), rtol=2e-4, atol=2e-4)
-        # and over the full sequence against a manual dense reference
-        from symmetry_tpu.models.moe import qmatmul_experts, route_top_k
-
-        gates = route_top_k(
-            jnp.asarray(x @ lp["router"], jnp.float32),
-            cfg.num_experts_per_tok).astype(x.dtype)
-        h = jax.nn.silu(qmatmul_experts(x, lp["wg"])) * qmatmul_experts(
-            x, lp["wu"])
-        y = jnp.einsum("bsxf,xfe->bsxe", h, lp["wd"])
-        want = jnp.einsum("bsxe,bsx->bse", y, gates)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_low_capacity_drops_but_stays_finite(self):
-        import dataclasses
-
-        from symmetry_tpu.models.moe import moe_mlp_dispatch
-
-        cfg = dataclasses.replace(preset("tiny-moe"),
-                                  moe_capacity_factor=0.5)
-        lp = self._layer_params(cfg, jax.random.key(2))
-        x = jax.random.normal(jax.random.key(3), (2, 64, cfg.hidden_size),
-                              jnp.float32)
-        out = moe_mlp_dispatch(x, lp, cfg)
-        assert out.shape == x.shape
-        assert bool(jnp.isfinite(out).all())
-
-    def test_prefill_routes_through_dispatch_and_decode_stays_dense(self):
-        """forward() at S>64 tokens uses the dispatch path; greedy decode
-        continuations still match the dense engine reference (decode is
-        S=1 => dense mixture, and the prefill numerics stay exact with
-        no-drop capacity)."""
-        import dataclasses
-
-        cfg = dataclasses.replace(
-            preset("tiny-moe"),
-            moe_capacity_factor=(preset("tiny-moe").num_experts
-                                 / preset("tiny-moe").num_experts_per_tok))
-        params = init_params(cfg, jax.random.key(4), jnp.float32)
-        prompt = list(range(1, 97))  # 96 tokens >= MIN_DISPATCH_TOKENS
-
-        cache = init_cache(cfg, 1, 128, jnp.float32)
-        logits, cache = forward(params, cfg,
-                                jnp.asarray([prompt], jnp.int32), cache)
-        toks = [int(jnp.argmax(logits[0, -1]))]
-        for _ in range(4):
-            logits, cache = forward(
-                params, cfg, jnp.asarray([[toks[-1]]], jnp.int32), cache)
-            toks.append(int(jnp.argmax(logits[0, 0])))
-
-        # engine path (bucketed prefill + slot decode) agrees
-        engine = InferenceEngine(
-            cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=128,
-            prefill_buckets=(128,), cache_dtype=jnp.float32)
-        first = engine.prefill_and_insert(0, prompt, SamplingParams())
-        got = [first]
-        for _ in range(4):
-            got.append(int(engine.decode_step()[0]))
-        assert got == toks
