@@ -63,6 +63,25 @@ def _stage_rules(mesh):
     return None
 
 
+def _park(state: "DecodeState", park: jnp.ndarray) -> "DecodeState":
+    """The head of every decode program: the lanes in `park` ([B] bool:
+    released since the last one and not reused, InferenceEngine
+    .release_slot) go to length 0. Decode attention reads each lane up to
+    its length; a finished request's lane would otherwise be read at its
+    whole context, growing a token a step, for nobody."""
+    lengths = state.cache.lengths
+    return state._replace(cache=state.cache._replace(
+        lengths=jnp.where(park, 0, lengths).astype(lengths.dtype)))
+
+
+def _stay_parked(before: jnp.ndarray, after: jnp.ndarray) -> jnp.ndarray:
+    """Per-lane cache lengths after a decode step: a lane at length 0 is
+    parked (fresh, or released and not yet reused; a live lane holds its
+    prompt) and stays at 0 — it decodes garbage like every idle lane, at
+    one position, and never grows into context nobody reads."""
+    return jnp.where(before == 0, 0, after)
+
+
 class EngineError(RuntimeError):
     pass
 
@@ -284,6 +303,9 @@ class InferenceEngine:
                 "axis > 1 — the setting would otherwise be silently inert")
         self.pipeline_microbatches = pipeline_microbatches
         self.max_slots = max_slots
+        # lanes released since the last decode dispatch and not reused:
+        # that dispatch parks them (release_slot)
+        self._park = np.zeros((max_slots,), bool)
         self.max_seq_len = max_seq_len
         self.prefill_buckets = tuple(sorted(b for b in prefill_buckets
                                             if b <= max_seq_len))
@@ -714,6 +736,8 @@ class InferenceEngine:
         def decode_one(state: DecodeState, params):
             """Advance every slot one token."""
             h, cache = trunk(params, state.last_token[:, None], state.cache)
+            cache = cache._replace(lengths=_stay_parked(
+                state.cache.lengths, cache.lengths))
             logits = logits_from_hidden(params, cfg, h)
             split = jax.vmap(lambda k: jax.random.split(k, 2))(state.rng)
             rng, step_key = split[:, 0], split[:, 1]
@@ -724,24 +748,25 @@ class InferenceEngine:
                 top_p=state.top_p, top_k=state.top_k, rng=rng,
             ), toks
 
-        def decode_block(params, state: DecodeState):
+        def decode_block(params, state: DecodeState, park):
             """K decode steps in ONE dispatch: the per-dispatch host cost
             is paid once per K tokens (SURVEY §7 hard-part 3: streaming
-            latency discipline). Returns (state, tokens [K, B], pairs):
+            latency discipline); `park` first (`_park`). Returns (state,
+            tokens [K, B], pairs):
             `pairs` is the cache's `expert_pairs` since the last block —
             this block's steps and every prefill inserted in between —
             handed out and zeroed, so the int32 counter never wraps; [0]
             for a model that does not count."""
             state, toks = jax.lax.scan(
-                lambda s, _: decode_one(s, params), state, None,
-                length=self.decode_block)
+                lambda s, _: decode_one(s, params), _park(state, park),
+                None, length=self.decode_block)
             pairs = state.cache.expert_pairs
             if pairs is None:
                 return state, toks, jnp.zeros((0,), jnp.int32)
             return state._replace(cache=state.cache._replace(
                 expert_pairs=jnp.zeros_like(pairs))), toks, pairs
 
-        def verify_block(params, state: DecodeState, draft, n_draft):
+        def verify_block(params, state: DecodeState, draft, n_draft, park):
             """Speculative verify: ONE batched forward over [B, 1+k_draft]
             positions — the pending last_token plus every slot's drafted
             continuation — then per-position acceptance (ops/sampling.py
@@ -756,6 +781,7 @@ class InferenceEngine:
             exclude and later writes overwrite — the rollback itself is
             one lengths update, no data movement. A slot with n_draft 0
             advances exactly one token, like a plain decode step."""
+            state = _park(state, park)
             tokens = jnp.concatenate([state.last_token[:, None], draft],
                                      axis=1)               # [B, 1+k]
             seq_lens = 1 + n_draft
@@ -773,7 +799,8 @@ class InferenceEngine:
                                        axis=1)[:, 0]
             # Roll back: only the accepted prefix (and the pending bonus
             # token's future write position) stays valid.
-            cache = cache._replace(lengths=old_lengths + n_emit)
+            cache = cache._replace(lengths=_stay_parked(
+                old_lengths, old_lengths + n_emit))
             return DecodeState(
                 cache=cache, last_token=last, temperature=state.temperature,
                 top_p=state.top_p, top_k=state.top_k, rng=rng,
@@ -996,9 +1023,8 @@ class InferenceEngine:
             self._prefill_scratch_for(batch, bucket))
         # One dispatch installs every row; pad rows re-write the last
         # real slot with bit-identical data (same prompt AND keys above).
-        self.state = self._insert_all(
-            self.state, prefix, jnp.asarray(slots_arr), lens_arr,
-            toks, temps_arr, top_ps_arr, top_ks_arr, decode_keys_arr)
+        self._insert(prefix, slots_arr, lens_arr, toks, temps_arr,
+                     top_ps_arr, top_ks_arr, decode_keys_arr)
         if dp.enabled:
             # The probe covers the prefill + insert chain (device order
             # is FIFO, so last_token ready implies both executed).
@@ -1137,10 +1163,8 @@ class InferenceEngine:
                 self.params, jnp.asarray(suffix), scratch, sfx_arr,
                 sfx_arr - 1, temps_arr, top_ps_arr, top_ks_arr,
                 jnp.stack(prefill_keys))
-            self.state = self._insert_all(
-                self.state, prefix, jnp.asarray(slots_arr),
-                jnp.asarray(full_lens), toks, temps_arr, top_ps_arr,
-                top_ks_arr, decode_keys_arr)
+            self._insert(prefix, slots_arr, jnp.asarray(full_lens), toks,
+                         temps_arr, top_ps_arr, top_ks_arr, decode_keys_arr)
             if dp.enabled:
                 # The cached-hit suffix dispatch is still a prefill on
                 # the device (chunk_final + insert over the seeded rows).
@@ -1498,10 +1522,9 @@ class InferenceEngine:
         job.cache = None  # old buffer was donated to chunk_final; poison reuse
         # same (batch=1, bucket) insert program the prefill warmup grid
         # compiled — no chunk-specific insert compile
-        self.state = self._insert_all(
-            self.state, cache, jnp.asarray([job.slot], jnp.int32),
-            jnp.asarray([job.true_len], jnp.int32), toks,
-            job.temp, job.top_p, job.top_k, job.decode_key)
+        self._insert(cache, np.asarray([job.slot], np.int32),
+                     jnp.asarray([job.true_len], jnp.int32), toks,
+                     job.temp, job.top_p, job.top_k, job.decode_key)
         if dp.enabled:
             dp.probe("chunk", self.state.last_token, t_dp)
         # The finished buffer holds the FULL prompt's KV — scatter its
@@ -1576,8 +1599,30 @@ class InferenceEngine:
 
     def release_slot(self, slot: int) -> None:
         """A finished slot's cache lane is garbage until reuse (insert
-        resets it); nothing to do device-side — the hook exists so the
-        scheduler's slot lifecycle has a single engine-visible seam."""
+        resets it). Nothing is dispatched: the lane is noted, and the
+        next decode program parks it — sets its length to 0, where the
+        programs leave it (`_park`, `_stay_parked`) — unless an insert
+        reuses it first. One chip or a mesh alike; across hosts the
+        release is a command of its own (parallel/multihost.py), so every
+        process passes the same lanes."""
+        self._park[slot] = True
+
+    def _take_park(self) -> np.ndarray:
+        park, self._park = self._park, np.zeros_like(self._park)
+        return park
+
+    def _dispatch_decode(self):
+        """Dispatch one decode block from the current state; the caller
+        keeps the state it returns: (state, tokens [K, B], pairs)."""
+        return self._decode(self.params, self.state, self._take_park())
+
+    def _insert(self, prefix, slots: np.ndarray, *rows) -> None:
+        """Install the rows of a prefilled prefix into decode lanes
+        `slots` (every insert goes through here: a lane that is reused
+        before the next decode program is no longer one to park)."""
+        self._park[np.asarray(slots)] = False
+        self.state = self._insert_all(self.state, prefix,
+                                      jnp.asarray(slots), *rows)
 
     def warmup(self) -> None:
         """Compile every serving program before traffic: decode, and the
@@ -1601,7 +1646,7 @@ class InferenceEngine:
         # recovery under load never pays a fresh XLA compile.
         self._rng_resume(jax.random.key(0), 0)
         if decode_side:
-            self.state, _, _ = self._decode(self.params, self.state)
+            self.state, _, _ = self._dispatch_decode()
         for bucket in self.prefill_buckets:
             for batch in self.prefill_batches_for(bucket):
                 if batch > self.max_slots:
@@ -1640,7 +1685,7 @@ class InferenceEngine:
         for bucket in (self.prefill_buckets if decode_side else ()):
             widest = max(b for b in self.prefill_batches_for(bucket)
                          if b <= self.max_slots)
-            pending = self._decode(self.params, self.state)
+            pending = self._dispatch_decode()
             self.state = pending[0]
             toks, prefix = self._prefill(
                 self.params,
@@ -1788,12 +1833,12 @@ class InferenceEngine:
                         self.state = _settle_insert(self.state, batch,
                                                     bucket)
                         # steady decode between admissions
-                        self.state, _, _ = self._decode(self.params, self.state)
+                        self.state, _, _ = self._dispatch_decode()
                         self.state = _settle_insert(self.state, batch,
                                                     bucket)
                     # consecutive decode blocks (no admission between)
-                    self.state, _, _ = self._decode(self.params, self.state)
-                    self.state, _, _ = self._decode(self.params, self.state)
+                    self.state, _, _ = self._dispatch_decode()
+                    self.state, _, _ = self._dispatch_decode()
                 if self.spec is not None:
                     self.verify_step(
                         np.zeros((self.max_slots, self.spec.k_draft),
@@ -1824,7 +1869,7 @@ class InferenceEngine:
         t_dp = dp.begin() if dp.enabled else 0.0
         self.state, toks, n_emit = self._verify(
             self.params, self.state, jnp.asarray(draft, jnp.int32),
-            jnp.asarray(n_draft, jnp.int32))
+            jnp.asarray(n_draft, jnp.int32), self._take_park())
         if dp.enabled:
             dp.probe("verify", toks, t_dp)
         return toks, n_emit
@@ -1851,7 +1896,7 @@ class InferenceEngine:
         1-in-N cadence bounds the serialization cost."""
         dp = self.devprof
         t_dp = dp.begin() if dp.enabled else 0.0
-        self.state, toks, pairs = self._decode(self.params, self.state)
+        self.state, toks, pairs = self._dispatch_decode()
         if self._count_experts:
             # Start the [experts] count's copy to the host now: by the
             # time this block's tokens are synced it has arrived, and
@@ -1930,7 +1975,9 @@ class InferenceEngine:
 
         return attention_paths(
             self.config, self.max_seq_len,
-            None if self.pipeline else self.mesh)
+            None if self.pipeline else self.mesh, batch=self.max_slots,
+            kv_bytes=jnp.dtype(jnp.int8 if self.kv_quant
+                               else self.cache_dtype).itemsize)
 
     def sampling_route(self) -> dict:
         """How every sampling call of the served programs selects its

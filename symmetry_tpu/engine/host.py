@@ -436,8 +436,7 @@ class EngineHost:
                     f"platform={dev['platform']} "
                     f"device_kind={dev['device_kind']!r} "
                     f"device_count={dev['device_count']} hbm={hbm} "
-                    f"attention=prefill:{attn['prefill']},"
-                    f"decode:{attn['decode']} "
+                    f"attention={','.join(f'{k}:{v}' for k, v in attn.items())} "
                     f"sampling={samp} "
                     + (f"moe={json.dumps(moe)} " if moe else "") +
                     f"build={t_build:.1f}s warmup={t_warmup:.1f}s "

@@ -339,26 +339,42 @@ def cache_logical_axes(*, quantized: bool = False,
 # Forward
 
 
-def attention_paths(config: ModelConfig, capacity: int,
-                    tp_mesh=None) -> dict[str, str]:
+def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
+                    batch: int, kv_bytes: int) -> dict:
     """Which attention implementation the prefill-from-empty and the
-    single-position decode programs take at this cache capacity: "pallas"
-    (ops/flash.py, ops/decode_attention.py), "pallas-interpret" (the same
-    kernels on the CPU backend) or "xla" (ops/attention.py gqa_attention).
-    `_layer` routes by this and the engine reports it, so a kernel giving
-    way to the XLA path is visible rather than quiet. Under a GSPMD mesh
-    the kernels run per shard with KV heads over `model`, which needs the
-    heads to divide; otherwise that build keeps the XLA path."""
+    single-position decode programs take at this cache shape (`batch`
+    slots of `capacity` entries of `kv_bytes`): "pallas" (ops/flash.py,
+    ops/decode_attention.py), "pallas-interpret" (the same kernels on the
+    CPU backend) or "xla" (ops/attention.py gqa_attention). `_layer`
+    routes by this and the engine reports it, so a kernel giving way to
+    the XLA path is visible rather than quiet. One route per observable
+    case, decided by shapes and the mesh alone:
+
+      - decode takes the kernel wherever it has a geometry for the cache
+        as it lies on the chip (ops/decode_attention.py geometry: the one
+        shape gate), and the reply names the `decode_slot_tile` and
+        `decode_block_t` it compiles with;
+      - a GSPMD mesh: the kernels run per shard with KV heads over
+        `model`, which needs the heads to divide — otherwise that build
+        keeps the XLA path — and decode takes the kernel only from
+        TP_MIN_CAPACITY up: below it a sharded trunk stays on the XLA
+        path (2 KV heads a chip lie head-major there and no slice is
+        staged: PERF.md PR 28) until a four-chip A/B says otherwise."""
     from symmetry_tpu.ops import decode_attention as da
 
     kernel = "pallas-interpret" if interpret_mode() else "pallas"
-    if tp_mesh is not None and (
-            config.num_kv_heads % dict(tp_mesh.shape).get("model", 1)):
+    model = 1 if tp_mesh is None else dict(tp_mesh.shape).get("model", 1)
+    if config.num_kv_heads % model:
         return {"prefill": "xla", "decode": "xla"}
-    return {"prefill": kernel,
-            "decode": (kernel if da.supports(config, capacity,
-                                             jax.default_backend())
-                       else "xla")}
+    data = 1 if tp_mesh is None else dict(tp_mesh.shape).get("data", 1)
+    tiles = da.geometry(batch // data if batch % data == 0 else batch,
+                        capacity, config.num_kv_heads // model,
+                        config.dim_per_head, kv_bytes)
+    if tiles is None or (tp_mesh is not None
+                         and capacity < da.TP_MIN_CAPACITY):
+        return {"prefill": kernel, "decode": "xla"}
+    return {"prefill": kernel, "decode": kernel,
+            "decode_slot_tile": tiles[0], "decode_block_t": tiles[1]}
 
 
 def _layer(
@@ -459,7 +475,9 @@ def _layer(
 
             attn = ring_attention(q, k, v, seq_lens, ring_mesh)
     else:
-        paths = attention_paths(config, cache.k.shape[2], tp_mesh)
+        paths = attention_paths(config, cache.k.shape[2], tp_mesh,
+                                batch=cache.k.shape[1],
+                                kv_bytes=cache.k.dtype.itemsize)
         if prefill_flash and paths["prefill"] != "xla":
             # Prefill-from-empty: attention is over this call's own K/V —
             # the Pallas kernel streams K/V blocks through VMEM instead of
@@ -475,10 +493,10 @@ def _layer(
                     flash.flash_prefill_tp(q, k, v, seq_lens, mesh=tp_mesh,
                                            **kw))
         elif S == 1 and paths["decode"] != "xla":
-            # Single-position decode on TPU: the Pallas kernel reads only
-            # each slot's occupied KV prefix (per-slot block skipping); the
-            # full cache is its operand, layer selection happens in the
-            # kernel's block addressing (ops/decode_attention.py).
+            # Single-position decode: the Pallas kernel reads only each
+            # slot's occupied KV prefix; the full cache is its operand and
+            # stays where it lies, layer and slot are DMA addressing
+            # (ops/decode_attention.py).
             from symmetry_tpu.ops import decode_attention as da
 
             args = (q[:, 0], cache.k, cache.v, layer, kv_valid,

@@ -1,42 +1,65 @@
-"""Pallas ragged decode attention: per-slot length-aware KV block skipping.
+"""Pallas ragged decode attention: the cache is read where it lies, and
+each slot only up to (a block past) its own length.
 
 The decode step is HBM-bound and the KV cache is its second-largest stream
-(after the weights). The XLA einsum path must read the FULL [T] cache
-capacity for every slot — masking discards the values but not the traffic —
-and slicing the read at the XLA level measured slower than the full read
-(it defeats the int8-dequant/matmul fusion; see the round-2 bench log).
-This kernel reads only the occupied prefix of each slot's cache:
+(after the weights). The XLA path reads the FULL [T] capacity of every slot
+— `dynamic_index_in_dim(cache.k, layer)` is staged as one whole
+`[B, T, K, D]` slice per layer for K and again for V (a copy into the
+compiler's fast memory that the score / output fusions then read), and
+masking discards the dead positions' values but not their traffic. This
+kernel is the single-position attention of every one-chip decode program
+(and, a call a shard, of a sharded trunk from TP_MIN_CAPACITY up):
 
-  - grid = (batch, T/block_t), T innermost; the k/v BlockSpec index_map
-    CLAMPS the block index at the slot's last occupied block, so Pallas's
-    revisit rule (a block whose index equals the previous iteration's is
-    not re-fetched) skips the DMA for every unoccupied tail block. A slot
-    at length 600 of an 8192-capacity cache streams 2 × 512-entry blocks,
-    not 16 — fully dynamic, zero recompiles, per-slot.
-  - The FULL [L, B, T, K, D] cache (native layout — reshaping it outside
-    would force a relaid-out copy) is the kernel operand and the layer is
-    a scalar-prefetch arg consumed by the index_map: layer selection is
-    pure block addressing, never a materialized slice.
-  - GQA without a head loop: ALL query heads contract against ALL kv heads
-    in ONE [nq, K*block_t] MXU matmul; wrong-pair scores are masked to
-    -inf BEFORE the online softmax, so they exp to exactly 0 and the
-    output matmul [nq, K*block_t] @ [K*block_t, D] needs no selection —
-    the zeros kill every cross-head term. 8x redundant MXU FLOPs, but the
-    step is bandwidth-bound and this removes the per-head scalar work
-    that otherwise dominates small grids.
-  - Online softmax (running max/sum) accumulates in VMEM scratch across
-    the T grid dimension; output is written on the final T iteration.
+  - The FULL [L, B, T, K, D] cache stays in HBM (pinned there: left
+    free, XLA stages small operands whole in its fast memory) and is
+    seen as it lies there, so the view is a bitcast (`_lanes`): as a
+    rule the K heads of a position are rows of one memory tile and a
+    slot is one lane, [T * K, D]; 2 int8 heads (a shard of 8 over
+    model: 4) XLA keeps head-major, [L, B, K, T, D] physically, and every
+    (slot, head) is a lane of its own, [T, D], with one KV head. The
+    layer is a scalar-prefetch argument: layer and lane selection are
+    DMA addressing, never a materialised slice.
+  - Work is lists of (slot, block) items the kernel writes into SMEM at
+    the top of each grid step: for each slot only the `block_t`-entry
+    blocks under its length (and, with a sliding window, not below the
+    window's floor) — per slot, so a short slot costs nothing for sharing
+    a tile with a long one. The slots are dealt to WAYS lists (each to
+    the shortest so far) and one loop walks the lists side by side: an
+    item's chain of MXU and cross-lane latencies is long and serial, WAYS
+    independent chains in one straight line of code overlap. Per way the
+    copies of the next item (K, V and the two scale blocks) are in flight
+    while this one is computed, slot boundaries included. An empty slot
+    still walks one (fully masked) block, so every output row is written,
+    none NaN.
+  - The grid is (B / slot_tile,): q and the output move in tiles of
+    `slot_tile` slots (bounded so the work lists fit SMEM at any
+    capacity); everything ragged happens inside a grid step.
+  - GQA without a head loop or a relayout: ALL query heads contract
+    against the block's rows — (position, head) interleaved, as they lie
+    — in one [nq, block_t * K] MXU product with the block as stationary
+    operand; wrong-pair scores are masked to -inf BEFORE the online
+    softmax, so they exp to exactly 0 and the output product needs no
+    selection. K-fold redundant MXU and VPU work (bf16 operands, one
+    pass), which measured far cheaper than pulling each head's rows out
+    of the interleave (PERF.md, PR 29).
+  - Query rows are ordered (group, head) inside the kernel, so rows
+    r, r + K, ... share a KV head and a `slab` of max(K, 8) rows is a
+    whole sublane tile with the same head pattern in every slab.
   - int8 caches (ops/quant.py quantize_kv): payload is read at 1 byte and
-    dequantized in VMEM — k scales multiply the scores, v scales the
-    probabilities, exactly like the XLA fallback (ops/attention.py).
+    widened in VMEM to the query's dtype (exact). The [K, block_t] scale
+    planes are position-minor; the MXU lays them out in the scores'
+    (position, head) lane order — a product with a 0/1 `spread` matrix,
+    exact because the f32 scales go in as three bf16 terms and every
+    output has one non-zero addend. k scales multiply the scores, v
+    scales the probabilities, the probabilities are cast to the query's
+    dtype before the output product — `gqa_attention`'s algebra.
+  - Online softmax in f32; running max / sum / accumulator are the loop
+    carry of each way, reset at a slot's first item. A masked position
+    contributes exp(-inf - m) = 0 exactly, so a slot's result depends on
+    nothing but its own rows.
 
 Masking is by absolute position (kv_pos < kv_length), identical semantics
 to ops/attention.py gqa_attention at decode (q position == length - 1).
-
-Regime: the kernel wins when capacity is large relative to typical
-occupancy (long-context serving — at 32k capacity the full-read einsum is
-unserveable); at small capacities the einsum's fusion wins. supports()
-encodes the measured crossover.
 """
 
 from __future__ import annotations
@@ -49,113 +72,286 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0**30
-DEFAULT_BLOCK_T = 512
-# Below this cache capacity the XLA full-read einsum path measured faster
-# than the kernel (grid overhead > saved bandwidth at 1-2k capacities).
-MIN_CAPACITY = 4096
+LANES = 128          # scale planes are position-minor: blocks are lane tiles
+SUBLANES = 8
+BLOCK_ROWS = 1024    # (position, head) rows of one item: 128 KB of int8
+WAYS = 4             # independent lane lists walked side by side
+NBUF = 2             # buffers a way: one item computed, the next in flight
+MAX_TILE_LANES = 128  # q / output lanes resident in VMEM per grid step
+MAX_TILE_ITEMS = 1024  # a work list's entries in SMEM: 32 KB for them all
+MAX_ITEM_BYTES = 2**19  # of K (and of V), WAYS x NBUF buffers each: 8 MB of
+                        # the 16 MB of VMEM a kernel may take
 
 
-def _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, scale: float, block_t: int,
-            n_kv: int, group: int, quantized: bool,
-            window: int | None = None,
-            ks_ref=None, vs_ref=None):
-    del layer_ref  # consumed by the index_maps
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-    length = len_ref[b]
-    n_blocks = (length + block_t - 1) // block_t
-    # Sliding window: keys below (length - window) are dead — blocks fully
-    # below it are skipped (their DMA too, via the index_map clamp; for
-    # t < first the fetched block belongs to `first` and must not be
-    # processed under this t, hence the compute gate below).
-    first = (jnp.maximum(length - window, 0) // block_t
-             if window is not None else 0)
-    nq, D = q_ref.shape
-    KB = n_kv * block_t
+def _lanes(n_kv: int, kv_bytes: int) -> tuple[int, int] | None:
+    """(heads, n_kv): how a slot's KV heads (those on this chip) lie in
+    the cache — `heads` lanes of `n_kv` interleaved heads each — or None
+    for a count the kernel has no view of. What XLA does with the
+    [L, B, T, K, D] cache of a program decides it (tests/test_chip_compile
+    .py holds it to that): 4, 8 or a multiple of 8 heads are row tiles of
+    the array as written; 2 heads of int8 would fill a sixteenth of a
+    tile and XLA keeps that cache head-major ([L, B, K, T, D] physically),
+    while 2 heads of bf16 or f32 get a 2-row tile and stay interleaved."""
+    if n_kv == 2 and kv_bytes == 1:
+        return 2, 1
+    if n_kv in (1, 2, 4) or (n_kv and n_kv % SUBLANES == 0):
+        return 1, n_kv
+    return None
 
-    @pl.when(t == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when((t >= first) & (t < n_blocks))
-    def _():
-        q = q_ref[:].astype(jnp.float32) * scale          # [nq, D]
-        # Dequant scales multiply the K/V blocks in 3-D BEFORE flattening
-        # (same algebra as scaling scores/probs; Mosaic cannot shape-cast
-        # a per-position scale vector onto the flattened score lanes).
-        # Scale blocks arrive [K, block_t] (position-minor layout).
-        kb = k_ref[:].astype(jnp.float32)                 # [block_t, K, D]
+def geometry(batch: int, capacity: int, n_kv: int, head_dim: int = LANES,
+             kv_bytes: int = 1) -> tuple[int, int] | None:
+    """(slot_tile, block_t) the kernel compiles with at this cache shape
+    (`n_kv`: the KV heads on the chip; `kv_bytes`: of a cache entry), or
+    None where it has none: the one gate — the caller keeps the XLA path
+    there and says so. By shape alone, at every capacity and on either
+    backend (the CPU interprets it); the >= 4,096 floor this replaced
+    priced the old one-slot-one-block grid (BASELINE.md rounds 3-4), not
+    the idea.
+
+    block_t: the positions that make BLOCK_ROWS rows of a lane — 128 at
+    8 interleaved KV heads, 256 at 4, 1,024 for a head-major lane — so an
+    item is the same 128 KB copy and the same [nq, 1024] scores whatever
+    the model; never under a lane tile, never over the capacity. A slot
+    is read at most one block past its length: the block is the
+    granularity of what "live" means. A capacity that the block does not
+    divide (640 = 2.5 x 256) ends in a block that starts early and masks
+    what the one before it covered. A capacity that is no multiple of 128
+    has no block (the scale planes are position-minor and a partial lane
+    tile would be a masked copy), a head size that is no lane tile none
+    either, nor heads so many or wide that a lane tile of positions
+    overruns MAX_ITEM_BYTES (gemma-7b's 16 heads of 256 in bf16).
+    slot_tile: the largest divisor of the batch whose lanes (slots x
+    head-major heads) are at most MAX_TILE_LANES and whose blocks fit a
+    work list of MAX_TILE_ITEMS (128 slots at 640, 16 at 8,192)."""
+    lanes = _lanes(n_kv, kv_bytes)
+    if lanes is None or capacity % LANES or head_dim % LANES:
+        return None
+    heads, n_kv = lanes
+    block_t = min(capacity, max(LANES, BLOCK_ROWS // n_kv // LANES * LANES))
+    if block_t * n_kv * head_dim * kv_bytes > MAX_ITEM_BYTES:
+        return None
+    most = max(1, min(MAX_TILE_LANES, MAX_TILE_ITEMS
+                      // -(-capacity // block_t)) // heads)
+    return next(t for t in range(min(batch, most), 0, -1)
+                if batch % t == 0), block_t
+
+
+def _three_bf16(x):
+    """f32 x as three bf16 terms whose f32 sum is x exactly."""
+    a = x.astype(jnp.bfloat16)
+    r = x - a.astype(jnp.float32)
+    b = r.astype(jnp.bfloat16)
+    c = (r - b.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.concatenate([a, b, c], axis=0)
+
+
+def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
+            scale: float, block_t: int, capacity: int, heads: int,
+            n_kv: int, slab: int, ways: int, quantized: bool,
+            window: int | None, compute_dtype):
+    if quantized:
+        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, scbuf, *spread, islot, iblk,
+         sem) = rest
+    else:
+        o_ref, kbuf, vbuf, islot, iblk, sem = rest
+    tile, nq, D = q_ref.shape
+    base = pl.program_id(0) * tile
+    layer = layer_ref[0]
+    rows = block_t * n_kv               # a block's (position, head) rows
+    chunk = min(block_t, LANES)         # positions one spread product lays
+    n_t = -(-capacity // block_t)       # out
+    # the last block of a capacity the block does not divide starts early
+    align = block_t if capacity % block_t == 0 else LANES
+    # named, not None: a process-wide default (the tests' "highest") must
+    # not turn the bf16 products into f32 ones Mosaic refuses
+    prec = (jax.lax.Precision.HIGHEST if compute_dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
+    # The work lists, one per way: (lane, block) items, lane-major, never
+    # empty for a lane; each lane goes to the way with the fewest items.
+    # A lane is a slot, or one head-major head of a slot.
+    def list_slot(i, counts):
+        length = len_ref[(base + i) // heads]
+        hi = jnp.clip((length + block_t - 1) // block_t, 1, n_t)
+        # decode q position == length - 1: the window's floor is
+        # length - window, and blocks wholly under it are never read
+        lo = 0 if window is None else jnp.minimum(
+            jnp.maximum(length - window, 0) // block_t, hi - 1)
+        w, least = jnp.int32(0), counts[0]
+        for c in range(1, ways):
+            fewer = counts[c] < least
+            w = jnp.where(fewer, c, w)
+            least = jnp.where(fewer, counts[c], least)
+
+        def put(j, n):
+            islot[w, n] = i
+            iblk[w, n] = j
+            return n + 1
+
+        n = jax.lax.fori_loop(lo, hi, put, least)
+        return tuple(jnp.where(w == c, n, counts[c]) for c in range(ways))
+
+    counts = jax.lax.fori_loop(0, tile, list_slot,
+                               tuple(jnp.int32(0) for _ in range(ways)))
+
+    def block_start(blk):
+        return pl.multiple_of(
+            jnp.minimum(blk * block_t, capacity - block_t), align)
+
+    def copies(w, item, buf):
+        b = base + islot[w, item]
+        t0 = block_start(iblk[w, item])
+        at = pl.ds(pl.multiple_of(t0 * n_kv, align * n_kv), rows)
+        out = [pltpu.make_async_copy(k_hbm.at[layer, b, at],
+                                     kbuf.at[w, buf], sem.at[0, w, buf]),
+               pltpu.make_async_copy(v_hbm.at[layer, b, at],
+                                     vbuf.at[w, buf], sem.at[1, w, buf])]
         if quantized:
-            kb = kb * ks_ref[:].T[:, :, None]
-        # [block_t, K, D] -> [block_t*K, D]: leading-dim merge, layout-free.
-        # Flat row j holds (t_in_block = j // K, head = j % K).
-        s = jax.lax.dot_general(
-            q, kb.reshape(KB, D),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [nq, K*block_t]
-        col = jax.lax.broadcasted_iota(jnp.int32, (nq, KB), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (nq, KB), 0)
-        kv_pos = t * block_t + col // n_kv
-        # own-head (query row h ↔ kv head h // group) AND in-length
-        keep = ((col % n_kv) == (row // group)) & (kv_pos < length)
-        if window is not None:
-            # decode q position == length - 1: window floor is length - w
-            keep &= kv_pos >= length - window
-        s = jnp.where(keep, s, NEG_INF)
+            for p, plane in enumerate((ks_hbm, vs_hbm)):
+                out.append(pltpu.make_async_copy(
+                    plane.at[layer, b // heads, :, pl.ds(t0, block_t)],
+                    scbuf.at[w, buf, p], sem.at[2 + p, w, buf]))
+        return out
 
-        m_old = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                            # 0 at masked cols
-        corr = jnp.exp(m_old - m_new)
-        l_scr[:, 0:1] = l_scr[:, 0:1] * corr + jnp.sum(p, -1, keepdims=True)
-        m_scr[:, 0:1] = m_new
-        vb = v_ref[:].astype(jnp.float32)                 # [block_t, K, D]
-        if quantized:
-            vb = vb * vs_ref[:].T[:, :, None]
-        acc_scr[:, :D] = acc_scr[:, :D] * corr + jax.lax.dot_general(
-            p, vb.reshape(KB, D),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    for w in range(ways):
+        for d in range(NBUF - 1):
+            @pl.when(d < counts[w])
+            def _(w=w, d=d):
+                for c in copies(w, d, d):
+                    c.start()
 
-    @pl.when(t == pl.num_programs(1) - 1)
-    def _():
-        # Empty / fully-masked rows have l == 0: guard the divide (their
-        # output is garbage by contract, but must not be NaN).
-        o_ref[:] = (acc_scr[:, :D]
-                    / jnp.maximum(l_scr[:, 0:1], 1e-30)).astype(o_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+    t_of_lane = lane // n_kv
+    row = jax.lax.broadcasted_iota(jnp.int32, (nq, 1), 0)
+    # own-head: query row r (head r % K) against lane j (head j % K)
+    bias = jnp.where((row % n_kv) == (lane % n_kv), 0.0,
+                     NEG_INF).astype(jnp.float32)
+    if quantized and n_kv > 1:
+        # spread[t, j] = 1 where lane j holds position t: one MXU product
+        # lays a [slab, chunk] scale plane out in the scores' lane order.
+        spread, = spread
+        spread[...] = (
+            jax.lax.broadcasted_iota(jnp.int32, spread.shape, 0)
+            == jax.lax.broadcasted_iota(jnp.int32, spread.shape, 1) // n_kv
+        ).astype(jnp.bfloat16)
+
+    def per_slab(x, planes):
+        # x [nq, rows] times planes [slab, rows], the same for every slab
+        # (one row for a head-major lane: its own head's)
+        if planes.shape[0] == 1:
+            return x * planes
+        return (x.reshape(nq // slab, slab, rows) * planes[None]
+                ).reshape(nq, rows)
+
+    def body(step, carry):
+        # Control first — each way's copies started and awaited — so that
+        # the ways' arithmetic below is one straight line the scheduler
+        # can interleave: the chains are independent.
+        meta = []
+        for w in range(ways):
+            live = step < counts[w]
+            item = jnp.minimum(step, counts[w] - 1)
+            buf = step % NBUF
+            i = islot[w, item]
+            first = (item == 0) | (islot[w, jnp.maximum(item - 1, 0)] != i)
+
+            @pl.when(step + NBUF - 1 < counts[w])
+            def _(w=w):
+                for c in copies(w, step + NBUF - 1, (step + NBUF - 1) % NBUF):
+                    c.start()
+
+            @pl.when(live)
+            def _(w=w, item=item, buf=buf):
+                for c in copies(w, item, buf):
+                    c.wait()
+
+            meta.append((live, i, iblk[w, item], buf, first))
+
+        out = []
+        for w in range(ways):
+            live, i, blk, buf, first = meta[w]
+            m_in, l_in, acc_in = carry[w]
+            length = len_ref[(base + i) // heads]
+            m_old = jnp.where(first, NEG_INF, m_in)
+            l_old = jnp.where(first, 0.0, l_in)
+            acc = jnp.where(first, 0.0, acc_in)
+
+            s = jax.lax.dot_general(
+                q_ref[i], kbuf[w, buf].astype(compute_dtype),
+                dimension_numbers=(((1,), (1,)), ((), ())), precision=prec,
+                preferred_element_type=jnp.float32)       # [nq, rows]
+            if quantized and n_kv == 1:
+                # the lane's own row of the [heads, block_t] planes
+                head = (base + i) % heads
+                k_plane, v_plane = (
+                    functools.reduce(
+                        lambda row, h: jnp.where(
+                            head == h, scbuf[w, buf, p, h:h + 1], row),
+                        range(1, heads), scbuf[w, buf, p, 0:1])
+                    for p in range(2))
+                s = per_slab(s, k_plane)
+            elif quantized:
+                planes = []
+                for c in range(block_t // chunk):
+                    at = slice(c * chunk, (c + 1) * chunk)
+                    # K < 8 heads fill a slab by repeating their rows
+                    e = jax.lax.dot_general(
+                        _three_bf16(jnp.concatenate(
+                            [scbuf[w, buf, 0, :, at]] * (slab // n_kv)
+                            + [scbuf[w, buf, 1, :, at]] * (slab // n_kv),
+                            axis=0)),
+                        spread[...], (((1,), (0,)), ((), ())),
+                        precision=jax.lax.Precision.DEFAULT,
+                        preferred_element_type=jnp.float32)
+                    planes.append(e[:2 * slab] + e[2 * slab:4 * slab]
+                                  + e[4 * slab:])
+                planes = jnp.concatenate(planes, axis=1)  # [2 * slab, rows]
+                k_plane, v_plane = planes[:slab], planes[slab:]
+                s = per_slab(s, k_plane)
+            s = s * scale + bias
+            pos = t_of_lane + block_start(blk)
+            keep = (pos < length) & (pos >= blk * block_t)
+            if window is not None:
+                keep &= pos >= length - window
+            s = jnp.where(keep, s, NEG_INF)
+
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)                        # 0 where masked
+            corr = jnp.exp(m_old - m_new)
+            l_new = l_old * corr + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                p = per_slab(p, v_plane)
+            acc = acc * corr + jax.lax.dot_general(
+                p.astype(compute_dtype), vbuf[w, buf].astype(compute_dtype),
+                dimension_numbers=(((1,), (0,)), ((), ())), precision=prec,
+                preferred_element_type=jnp.float32)       # [nq, D]
+            # A way whose list has run out keeps its last slot's result
+            # (what its buffers hold then is stale, and dropped here).
+            out.append((jnp.where(live, m_new, m_in),
+                        jnp.where(live, l_new, l_in),
+                        jnp.where(live, acc, acc_in)))
+
+        # Every item stores its slot's running result and the slot's last
+        # item wins: no branch after the arithmetic. A slot with nothing
+        # valid sums exp(0) over a masked block — garbage by contract,
+        # finite by construction.
+        for w in range(ways):
+            _, l_new, acc = out[w]
+            o_ref[meta[w][1]] = (acc / l_new).astype(o_ref.dtype)
+        return tuple(out)
+
+    steps = counts[0]
+    for c in range(1, ways):
+        steps = jnp.maximum(steps, counts[c])
+    jax.lax.fori_loop(
+        0, steps, body,
+        tuple((jnp.full((nq, 1), NEG_INF, jnp.float32),
+               jnp.ones((nq, 1), jnp.float32),
+               jnp.zeros((nq, D), jnp.float32)) for _ in range(ways)))
 
 
-def _quant_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, m_scr, l_scr, acc_scr, **kw):
-    _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, quantized=True,
-            ks_ref=ks_ref, vs_ref=vs_ref, **kw)
-
-
-def supports(config, cache_capacity: int, backend: str) -> bool:
-    """Static gate for routing decode attention through the kernel.
-
-    Long-context capacities only: below MIN_CAPACITY the XLA einsum path
-    measured as fast or faster (round-3 re-measure with fetch-fenced
-    timing: kernel 33.6 vs einsum 32.6 ms full-trunk at 640 — the step
-    there is convert-throughput-bound, not KV-traffic-bound, so block
-    skipping buys nothing). Sliding-window models route through the
-    kernel too: the window bounds the block range per slot (mistral at
-    8k capacity / 4k window reads half the blocks)."""
-    D = config.dim_per_head
-    return (D % 128 == 0
-            and backend == "tpu"
-            and cache_capacity >= MIN_CAPACITY
-            # decode_attention auto-picks a block from (512, 256, 128, 64),
-            # so any 64-multiple capacity tiles.
-            and cache_capacity % 64 == 0)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_t", "window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def decode_attention(
     q: jnp.ndarray,           # [B, n_q_heads, D] (single decode position)
     k_cache: jnp.ndarray,     # [L, B, T, K, D] FULL cache (bf16/f32 or int8)
@@ -165,7 +361,6 @@ def decode_attention(
     k_scale: jnp.ndarray | None = None,  # [L, B, K, T] f32 (int8 caches;
     v_scale: jnp.ndarray | None = None,  # position minor — tile-friendly)
     *,
-    block_t: int = DEFAULT_BLOCK_T,
     window: int | None = None,  # sliding-window span (mistral); bounds the
                                 # per-slot block range below AND above
     interpret: bool = False,
@@ -174,72 +369,88 @@ def decode_attention(
     L, B, T, K, D = k_cache.shape
     nq = q.shape[1]
     group = nq // K
-    block_t = min(block_t, T)
-    if T % block_t:
-        # Auto-pick the largest standard block that tiles the capacity
-        # (e.g. 640 → 128); callers then never need capacity-aware sizing.
-        for cand in (256, 128, 64):
-            if cand < block_t and T % cand == 0:
-                block_t = cand
-                break
-        else:
-            raise ValueError(f"cache capacity {T} has no usable block size")
-    n_t = T // block_t
-    scale = D ** -0.5
+    kv_bytes = k_cache.dtype.itemsize
+    tiles = geometry(B, T, K, D, kv_bytes)
+    if tiles is None:
+        raise ValueError(f"no decode-attention geometry for a {B} x {T} "
+                         f"cache of {K} KV heads of {D}")
+    slot_tile, block_t = tiles
+    heads, n_kv = _lanes(K, kv_bytes)
+    lanes, tile = B * heads, slot_tile * heads
+    slab = max(n_kv, SUBLANES)
     quantized = k_scale is not None
+    compute_dtype = (q.dtype if quantized
+                     else jnp.promote_types(q.dtype, k_cache.dtype))
+    n_t = -(-T // block_t)
+    rows = block_t * n_kv
+    ways = min(WAYS, tile)
 
-    layer_arr = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    # A lane's query rows ordered (group, head) and padded to whole slabs:
+    # row r of the kernel's q and output belongs to the lane's head
+    # r % n_kv.
+    nql = group * n_kv
+    nqp = -(-nql // slab) * slab
+    qk = jnp.swapaxes(q.reshape(lanes, n_kv, group, D), 1, 2)
+    qk = jnp.pad(qk.reshape(lanes, nql, D).astype(compute_dtype),
+                 ((0, 0), (0, nqp - nql), (0, 0)))
+    if heads > 1:  # head-major, as XLA lays such a cache out: a bitcast
+        k_cache, v_cache = (jnp.swapaxes(x, 2, 3) for x in (k_cache, v_cache))
 
-    def clamp_t(b, t, len_ref, layer_ref):
-        # Clamp into the live block range for this slot: above the last
-        # occupied block, and (windowed models) below the first block the
-        # window can still see. Out-of-range iterations repeat a boundary
-        # index, so Pallas's revisit rule skips their DMAs; the kernel's
-        # compute gate skips their math.
-        last = jnp.maximum((len_ref[b] + block_t - 1) // block_t - 1, 0)
-        t_eff = jnp.minimum(t, last)
-        if window is not None:
-            first = jnp.maximum(len_ref[b] - window, 0) // block_t
-            t_eff = jnp.maximum(t_eff, first)
-        return layer_ref[0], b, t_eff, 0, 0
-
-    q_spec = pl.BlockSpec((None, nq, D), lambda b, t, lr, yr: (b, 0, 0))
-    kv_spec = pl.BlockSpec((None, None, block_t, K, D), clamp_t)
-    out_spec = pl.BlockSpec((None, nq, D), lambda b, t, lr, yr: (b, 0, 0))
-    scratch = [
-        pltpu.VMEM((nq, 128), jnp.float32),  # running max (col 0)
-        pltpu.VMEM((nq, 128), jnp.float32),  # running denom (col 0)
-        pltpu.VMEM((nq, max(D, 128)), jnp.float32),  # output accumulator
-    ]
-    common = dict(scale=scale, block_t=block_t, n_kv=K, group=group,
-                  window=window)
-
+    tile_spec = pl.BlockSpec((tile, nqp, D),
+                             lambda i, lens, lay: (i, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    args = [kv_length.astype(jnp.int32),
+            jnp.reshape(layer, (1,)).astype(jnp.int32), qk,
+            k_cache.reshape(L, lanes, T * n_kv, D),
+            v_cache.reshape(L, lanes, T * n_kv, D)]
+    in_specs = [tile_spec, hbm, hbm]
+    scratch = [pltpu.VMEM((ways, NBUF, rows, D), k_cache.dtype),
+               pltpu.VMEM((ways, NBUF, rows, D), v_cache.dtype)]
+    n_sem = 2
     if quantized:
-        def clamp_t_scale(b, t, len_ref, layer_ref):
-            lay, bb, tt, _, _ = clamp_t(b, t, len_ref, layer_ref)
-            return lay, bb, 0, tt
+        chunk = min(block_t, LANES)
+        args += [k_scale, v_scale]
+        in_specs += [hbm, hbm]
+        scratch += [pltpu.VMEM((ways, NBUF, 2, K, block_t), jnp.float32)]
+        if n_kv > 1:
+            scratch += [pltpu.VMEM((chunk, chunk * n_kv), jnp.bfloat16)]
+        n_sem += 2
+    scratch += [pltpu.SMEM((ways, tile * n_t), jnp.int32),
+                pltpu.SMEM((ways, tile * n_t), jnp.int32),
+                pltpu.SemaphoreType.DMA((n_sem, ways, NBUF))]
 
-        sc_spec = pl.BlockSpec((None, None, K, block_t), clamp_t_scale)
-        kernel = functools.partial(_quant_kernel, **common)
-        in_specs = [q_spec, kv_spec, kv_spec, sc_spec, sc_spec]
-        args = (kv_length, layer_arr, q, k_cache, v_cache, k_scale, v_scale)
-    else:
-        kernel = functools.partial(_kernel, quantized=False, **common)
-        in_specs = [q_spec, kv_spec, kv_spec]
-        args = (kv_length, layer_arr, q, k_cache, v_cache)
-
-    return pl.pallas_call(
-        kernel,
+    # Left free, XLA's memory-space assignment stages a small enough
+    # operand WHOLE in its fast memory around every call (qwen2-7b's 9 MB
+    # scale arrays: three 9 MB copies a layer, for blocks the kernel
+    # copies itself). The cache stays in HBM. (The interpreter has no
+    # memory spaces.)
+    if not interpret:
+        args[3:] = [pltpu.with_memory_space_constraint(x, pltpu.HBM)
+                    for x in args[3:]]
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=D ** -0.5, block_t=block_t,
+                          capacity=T, heads=heads, n_kv=n_kv, slab=slab,
+                          ways=ways,
+                          quantized=quantized, window=window,
+                          compute_dtype=compute_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # kv_length, layer
-            grid=(B, n_t),
+            grid=(B // slot_tile,),
             in_specs=in_specs,
-            out_specs=out_spec,
+            out_specs=tile_spec,
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, nq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((lanes, nqp, D), q.dtype),
         interpret=interpret,
     )(*args)
+    out = out[:, :nql].reshape(lanes, group, n_kv, D)
+    return jnp.swapaxes(out, 1, 2).reshape(B, nq, D)
+
+
+# A sharded trunk takes the kernel only from here up: the gate the old
+# grid had, kept for the mesh alone until the per-shard kernel has a
+# four-chip A/B under it (models/llama.py attention_paths).
+TP_MIN_CAPACITY = 4096
 
 
 def decode_attention_tp(q, k_cache, v_cache, layer, kv_length,

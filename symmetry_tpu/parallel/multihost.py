@@ -42,6 +42,7 @@ CMD_PREFILL = 1   # prefill + insert one request
 CMD_DECODE = 2    # advance all slots one decode block
 CMD_STOP = 3      # shut down the loop
 CMD_WARMUP = 4    # precompile the decode program (pre-traffic)
+CMD_RELEASE = 5   # a finished slot: the next decode program parks its lane
 
 # Vector layout: [kind, slot, true_len, bucket, temp_milli, top_p_milli,
 #                 top_k, seed_or_-1, tokens...(max_bucket)]
@@ -178,6 +179,8 @@ class CommandLoop:
             return self.engine.decode_steps()
         if cmd.kind == CMD_WARMUP:
             return self.engine.warmup()
+        if cmd.kind == CMD_RELEASE:
+            return self.engine.release_slot(cmd.slot)
         return None
 
     def _broadcast(self, vec: np.ndarray) -> np.ndarray:
@@ -272,8 +275,9 @@ class MultihostEngine:
         return self.decode_steps()
 
     def release_slot(self, slot: int) -> None:
-        """Host-side no-op (engine.release_slot); nothing to broadcast."""
-        self._loop.engine.release_slot(slot)
+        """Every process notes the lane: the lanes to park are an input
+        of the next decode program and must be the same everywhere."""
+        self._loop.lead(Command(kind=CMD_RELEASE, slot=slot))
 
     def warmup(self) -> None:
         self._loop.lead(Command(kind=CMD_WARMUP))

@@ -111,8 +111,8 @@ class TestEngineHost:
             assert block["device"]["device_kind"]
             assert block["device"]["device_count"] == jax.device_count()
             assert block["device"]["hbm"] == []  # CPU reports no HBM
-            # tiny on CPU: flash runs interpreted; 64 < MIN_CAPACITY and
-            # the backend is not a TPU, so decode takes the XLA path.
+            # tiny on CPU: flash runs interpreted; head size 16 is no
+            # lane tile, so decode takes the XLA path and says so.
             assert block["attention"] == {"prefill": "pallas-interpret",
                                           "decode": "xla"}
             # tiny's 512 logits are 4 groups of 128, below the two-stage
@@ -307,8 +307,10 @@ class TestAttentionKernelsUnderTensorParallelism:
         np.testing.assert_allclose(np.asarray(got1), np.asarray(want[:1]),
                                    rtol=1e-6, atol=1e-6)
 
-    @pytest.mark.parametrize("quantized", [False, True])
-    def test_decode_attention_tp_matches_unsharded(self, mesh, quantized):
+    @pytest.mark.parametrize("K", [4, 8])  # 1 KV head a chip, and 2: an
+    @pytest.mark.parametrize("quantized", [False, True])  # int8 pair lies
+    def test_decode_attention_tp_matches_unsharded(self, mesh, quantized,
+                                                   K):  # head-major
         import jax.numpy as jnp
         import numpy as np
 
@@ -317,7 +319,7 @@ class TestAttentionKernelsUnderTensorParallelism:
         from symmetry_tpu.ops.quant import quantize_kv
 
         ks = jax.random.split(jax.random.key(1), 3)
-        L, B, T, K, G, D = 2, 4, 64, 4, 2, 128
+        L, B, T, G, D = 2, 4, 256, 2, 128
         q = jax.random.normal(ks[0], (B, K * G, D), jnp.float32)
         k = jax.random.normal(ks[1], (L, B, T, K, D), jnp.float32)
         v = jax.random.normal(ks[2], (L, B, T, K, D), jnp.float32)
@@ -327,7 +329,7 @@ class TestAttentionKernelsUnderTensorParallelism:
             k, ksc = quantize_kv(k)
             v, vsc = quantize_kv(v)
             scales = (jnp.moveaxis(ksc, -1, -2), jnp.moveaxis(vsc, -1, -2))
-        kw = dict(block_t=32, interpret=True)
+        kw = dict(interpret=True)
         args = (q, k, v, jnp.int32(1), lengths, *scales)
         want = decode_attention(*args, **kw)
         got = jax.jit(lambda *a: decode_attention_tp(*a, mesh=mesh, **kw))(
@@ -335,11 +337,80 @@ class TestAttentionKernelsUnderTensorParallelism:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6, atol=1e-6)
 
+    def test_startup_attention_names_route_tile_and_block(self, mesh):
+        """What `stats.engine.startup.attention` carries: a one-chip build
+        decodes through the kernel (interpreted here) and names the slot
+        tile and block it compiled with; a sharded trunk under
+        TP_MIN_CAPACITY keeps the XLA path and names no geometry."""
+        from symmetry_tpu.models.llama import attention_paths, preset
+        from symmetry_tpu.ops.decode_attention import TP_MIN_CAPACITY
+        from symmetry_tpu.parallel.mesh import MeshSpec, build_mesh
+
+        # the dense cells: 128 slots x 640
+        for name, block_t in (("mistral-7b", 128), ("qwen2-7b", 256)):
+            assert attention_paths(preset(name), 640, None, batch=128,
+                                   kv_bytes=1) == {
+                "prefill": "pallas-interpret", "decode": "pallas-interpret",
+                "decode_slot_tile": 128, "decode_block_t": block_t}
+        # mixtral-8x7b.rag-closed: 64 slots x 2,048 over model: 4
+        sharded = preset("mistral-7b")  # mixtral's attention, head for head
+        assert attention_paths(sharded, 2048, mesh, batch=64,
+                               kv_bytes=1) == {
+            "prefill": "pallas-interpret", "decode": "xla"}
+        # ... and from TP_MIN_CAPACITY up takes the per-shard kernel, as
+        # it did before this gate was the mesh's alone: 2 int8 KV heads a
+        # chip lie head-major, each a lane of 1,024-position blocks
+        # (chip_smoke.py --mesh-model 4: 8 slots over data: 2)
+        assert attention_paths(sharded, TP_MIN_CAPACITY, mesh, batch=8,
+                               kv_bytes=1) == {
+            "prefill": "pallas-interpret", "decode": "pallas-interpret",
+            "decode_slot_tile": 4, "decode_block_t": 1024}
+        two = build_mesh(MeshSpec(data=4, model=2))
+        assert attention_paths(sharded, TP_MIN_CAPACITY, two, batch=8,
+                               kv_bytes=2) == {
+            "prefill": "pallas-interpret", "decode": "pallas-interpret",
+            "decode_slot_tile": 2, "decode_block_t": 256}
+
+    def test_engine_reports_the_decode_geometry(self):
+        """The engine asks with its own slots and capacity, and what it
+        reports is what `_layer` traces: a head-size-128 model at capacity
+        128 decodes through the interpreted kernel."""
+        import jax.numpy as jnp
+
+        from symmetry_tpu.engine.engine import InferenceEngine
+        from symmetry_tpu.engine.tokenizer import ByteTokenizer
+        from symmetry_tpu.models import ModelConfig, init_params
+
+        cfg = ModelConfig(vocab_size=512, hidden_size=128, num_layers=2,
+                          num_heads=4, num_kv_heads=4, intermediate_size=128,
+                          head_dim=128, rope_theta=10000.0, max_position=256)
+        engine = InferenceEngine(
+            cfg, init_params(cfg, jax.random.key(0), jnp.float32),
+            ByteTokenizer(), max_slots=4, max_seq_len=128,
+            prefill_buckets=(16,),
+            cache_dtype=jnp.float32)
+        assert engine.attention_paths() == {
+            "prefill": "pallas-interpret", "decode": "pallas-interpret",
+            "decode_slot_tile": 4, "decode_block_t": 128}
+        # ... and decodes through it: two identical greedy prompts in
+        # different lanes, beside different neighbours, agree
+        from symmetry_tpu.engine.engine import SamplingParams
+
+        prompt = list(b"the same prompt")
+        a = [engine.prefill_and_insert(0, prompt, SamplingParams())]
+        engine.prefill_and_insert(1, list(b"another"), SamplingParams())
+        b = [engine.prefill_and_insert(3, prompt, SamplingParams())]
+        for _ in range(4):
+            toks = engine.decode_steps()[0]
+            a.append(int(toks[0]))
+            b.append(int(toks[3]))
+        assert a[1:] == b[1:] and a[0] == b[0]
+
     def test_heads_that_do_not_divide_keep_the_xla_path(self, mesh):
         from symmetry_tpu.models.llama import attention_paths, preset
 
         tiny = preset("tiny")  # 2 KV heads over model=4
-        assert attention_paths(tiny, 4096, mesh) == {"prefill": "xla",
-                                                     "decode": "xla"}
-        assert attention_paths(tiny, 4096, None)["prefill"] == \
-            "pallas-interpret"
+        assert attention_paths(tiny, 4096, mesh, batch=8, kv_bytes=1) == {
+            "prefill": "xla", "decode": "xla"}
+        assert attention_paths(tiny, 4096, None, batch=8,
+                               kv_bytes=1)["prefill"] == "pallas-interpret"

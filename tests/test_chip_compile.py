@@ -1,0 +1,169 @@
+"""Compile the decode-attention kernel for a v5e that is described, not
+attached: what the Mosaic and XLA TPU compilers refuse or rearrange at the
+benchmark cells' real shapes, which interpret mode at tiny shapes cannot
+show (tiling of the int8 payload and the 4-row scale planes, VMEM, and
+whether XLA stages an operand of the call in its fast memory). Nothing
+runs. One file, one fixture: only the worker given this file loads the
+TPU's compiler library (on-chip-measurement guide, section 2).
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from symmetry_tpu.ops.decode_attention import (
+    decode_attention, decode_attention_tp)
+
+
+@pytest.fixture(scope="module")
+def host():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(host):
+    return SingleDeviceSharding(host.devices[0])
+
+
+@pytest.fixture()
+def no_cache():
+    # an executable compiled for a described chip cannot be read back
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+CASES = {
+    # layers, slots, capacity, KV heads, query heads, cache dtype, window,
+    # head size
+    "mistral-7b cell": (32, 128, 640, 8, 32, jnp.int8, None, 128),
+    "qwen2-7b cell": (28, 128, 640, 4, 28, jnp.int8, None, 128),
+    "8 x 4096 bf16": (2, 8, 4096, 8, 32, jnp.bfloat16, None, 128),
+    "8 x 8192 int8 window": (2, 8, 8192, 8, 32, jnp.int8, 4096, 128),
+    "16 KV heads": (2, 8, 1024, 16, 32, jnp.int8, None, 128),
+    # many slots of a long capacity: the work lists have to fit SMEM
+    "128 x 8192 int8 window": (2, 128, 8192, 8, 32, jnp.int8, 4096, 128),
+    "128 x 32768 int8": (1, 128, 32768, 8, 32, jnp.int8, None, 128),
+    # 2 KV heads a chip (a shard of mistral-7b over model: 4): XLA keeps
+    # the int8 cache head-major, the bf16 one interleaved — neither view
+    # the kernel takes may be a copy
+    "2 KV heads int8": (32, 8, 4096, 2, 8, jnp.int8, 4096, 128),
+    "2 KV heads bf16": (32, 8, 4096, 2, 8, jnp.bfloat16, None, 128),
+    "2 KV heads at 640": (32, 128, 640, 2, 8, jnp.int8, None, 128),
+    "gemma-2b: 1 KV head of 256": (18, 8, 8192, 1, 8, jnp.int8, None, 256),
+    "gemma-7b: 16 KV heads of 256": (2, 8, 4096, 16, 16, jnp.int8, None,
+                                     256),
+}
+
+
+def whole_cache_copies(text: str) -> list[str]:
+    """Lines of a compiled program that copy or relay out a five-axis
+    array of int8 or bf16 — the cache — or a four-axis f32 one, its
+    scales."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(r"= (s8|bf16)\[\d+,\d+,\d+,\d+,\d+\]\S* "
+                         r"(copy|transpose|fusion)\(", line)
+            or re.search(r"= f32\[\d+,\d+,\d+,\d+\]\S* "
+                         r"(copy|transpose|reshape|fusion)\(", line)]
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_compiles_at_served_shapes(one_chip, no_cache, case):
+    L, B, T, K, nq, dtype, window, D = CASES[case]
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    q = shape((B, nq, D), jnp.bfloat16)
+    kv = shape((L, B, T, K, D), dtype)
+    scale = shape((L, B, K, T), jnp.float32) if dtype == jnp.int8 else None
+    compiled = jax.jit(
+        lambda q, k, v, layer, n, ks, vs: decode_attention(
+            q, k, v, layer, n, ks, vs, window=window)
+    ).lower(q, kv, kv, shape((), jnp.int32), shape((B,), jnp.int32),
+            scale, scale).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    # the cache is the call's operand as it lies: viewed, never copied
+    assert not re.search(r" copy\(%[kv]\.", text)
+    assert not whole_cache_copies(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
+@pytest.mark.parametrize("dtype", [jnp.int8, jnp.bfloat16],
+                         ids=["int8", "bf16"])
+def test_sharded_kernel_reads_each_shard_in_place(host, no_cache, dtype):
+    """chip_smoke.py --mesh-model 4: mistral-7b over model: 4 at 8 x
+    4,096, 2 KV heads a chip — one kernel call a shard, its share of the
+    cache neither gathered nor copied."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(host.devices).reshape(1, 4), ("data", "model"))
+
+    def shape(dims, dt, *spec):
+        return jax.ShapeDtypeStruct(dims, dt,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    L, B, T, K, nq = 32, 8, 4096, 8, 32
+    q = shape((B, nq, 128), jnp.bfloat16, None, "model", None)
+    kv = shape((L, B, T, K, 128), dtype, None, None, None, "model", None)
+    scale = (shape((L, B, K, T), jnp.float32, None, None, "model", None)
+             if dtype == jnp.int8 else None)
+    compiled = jax.jit(
+        lambda q, k, v, layer, n, ks, vs: decode_attention_tp(
+            q, k, v, layer, n, ks, vs, mesh=mesh, window=4096)
+    ).lower(q, kv, kv, shape((), jnp.int32), shape((B,), jnp.int32),
+            scale, scale).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "all-gather" not in text
+    assert not whole_cache_copies(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
+def test_trunk_reads_the_cache_in_place(one_chip, no_cache, monkeypatch):
+    """The whole decode trunk at qwen2-7b's cell: one kernel call in the
+    layer loop, no per-layer slice of the int8 cache, and none of the
+    cache's arrays staged whole in XLA's fast memory around the call (its
+    9 MB scale arrays were: three copies a layer until the operands were
+    pinned to HBM)."""
+    from symmetry_tpu.models import llama
+
+    monkeypatch.setattr(llama, "interpret_mode", lambda: False)
+    cfg = llama.preset("qwen2-7b")
+    B, T = 128, 640
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True))
+    cache = shaped(lambda: llama.init_cache(cfg, B, T, jnp.bfloat16,
+                                            quantized=True))
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    text = jax.jit(
+        lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
+        donate_argnums=(2,)).lower(params, tok, cache).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "s8[1,128,640,4,128]" not in text
+    staged = [line for line in text.splitlines()
+              if "copy-start" in line and "[28,128," in line]
+    assert not staged, staged[0][:200]

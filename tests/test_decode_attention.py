@@ -5,12 +5,31 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import symmetry_tpu.ops.decode_attention as da
 from symmetry_tpu.ops.attention import gqa_attention
-from symmetry_tpu.ops.decode_attention import decode_attention, supports
+from symmetry_tpu.ops.decode_attention import (
+    TP_MIN_CAPACITY, decode_attention, geometry)
 from symmetry_tpu.ops.quant import quantize_kv
 
 
-def make_case(B=3, T=64, K=2, G=4, D=128, L=2, seed=0, dtype=jnp.float32):
+@pytest.fixture()
+def tiled(monkeypatch):
+    """decode_attention with the router's constants made small, so a
+    handful of slots on the CPU walk every tiling the chip sees at 128
+    and more: `tiled(lanes=2)` holds a grid step to 2 lanes,
+    `tiled(block_rows=512)` a block to 512 rows. Its own jit each time:
+    the constants are read when a shape is first traced."""
+    def make(lanes=None, block_rows=None):
+        if lanes is not None:
+            monkeypatch.setattr(da, "MAX_TILE_LANES", lanes)
+        if block_rows is not None:
+            monkeypatch.setattr(da, "BLOCK_ROWS", block_rows)
+        return jax.jit(decode_attention.__wrapped__,
+                       static_argnames=("window", "interpret"))
+    return make
+
+
+def make_case(B=3, T=384, K=2, G=4, D=128, L=2, seed=0, dtype=jnp.float32):
     ks = jax.random.split(jax.random.key(seed), 3)
     nq = K * G
     q = jax.random.normal(ks[0], (B, nq, D), dtype)
@@ -36,11 +55,11 @@ def to_minor(scale):
 
 class TestDecodeAttentionKernel:
     @pytest.mark.parametrize("layer", [0, 1])
-    @pytest.mark.parametrize("block_t", [16, 32, 64])
-    def test_matches_xla_reference(self, layer, block_t):
-        q, k, v, lengths = make_case()
+    @pytest.mark.parametrize("K", [2, 4, 8])  # blocks of 384, 256, 128
+    def test_matches_xla_reference(self, layer, K):
+        q, k, v, lengths = make_case(K=K)
         got = decode_attention(q, k, v, jnp.int32(layer), lengths,
-                               block_t=block_t, interpret=True)
+                               interpret=True)
         want = reference(q, k[layer], v[layer], lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
@@ -51,8 +70,7 @@ class TestDecodeAttentionKernel:
         vq, vsc = quantize_kv(v)
         ksc, vsc = to_minor(ksc), to_minor(vsc)
         got = decode_attention(q, kq, vq, jnp.int32(1), lengths,
-                               k_scale=ksc, v_scale=vsc,
-                               block_t=32, interpret=True)
+                               k_scale=ksc, v_scale=vsc, interpret=True)
         want = reference(q, kq[1], vq[1], lengths,
                          k_scale=ksc[1], v_scale=vsc[1])
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -62,7 +80,7 @@ class TestDecodeAttentionKernel:
         q, k, v, lengths = make_case()
         lengths = lengths.at[1].set(0)  # empty slot: garbage out, not NaN
         got = decode_attention(q, k, v, jnp.int32(0), lengths,
-                               block_t=32, interpret=True)
+                               interpret=True)
         assert np.isfinite(np.asarray(got)).all()
         want = reference(q, k[0], v[0], jnp.maximum(lengths, 1))
         np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
@@ -71,34 +89,249 @@ class TestDecodeAttentionKernel:
                                    rtol=2e-5, atol=2e-5)
 
     def test_single_block(self):
-        q, k, v, lengths = make_case(T=32)
+        q, k, v, lengths = make_case(T=128)  # the block is clamped to T
         got = decode_attention(q, k, v, jnp.int32(0), lengths,
-                               block_t=256, interpret=True)  # clamped to T
+                               interpret=True)
         want = reference(q, k[0], v[0], lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
-    def test_supports_gate(self):
+    @pytest.mark.parametrize("name, capacity, want", [
+        ("llama3-8b", 8192, True),
+        ("llama3-8b", 640, True),        # the dense cells' capacity
+        ("llama3-8b", 2048, True),       # no capacity floor on one chip
+        ("tiny", 8192, False),           # D = 16: no lane tile
+        ("mistral-7b", 128, True),       # one block
+        ("llama3-8b", 4096 + 640, True),
+        ("llama3-8b", 672, False),       # not a multiple of 128: no
+        ("qwen2-7b", 640, True),         # 2.5 blocks: the last starts early
+        ("tiny-mha", 640, False),
+        ("mixtral-8x7b", 2048, True),
+        ("gemma-2b", 8192, True),        # one KV head of 256
+    ])
+    def test_supports_gate(self, name, capacity, want):
+        """geometry() is the one gate: None is the XLA path."""
+        from symmetry_tpu.models import preset
+
+        cfg = preset(name)
+        assert (geometry(128, capacity, cfg.num_kv_heads,
+                         cfg.dim_per_head) is not None) is want
+
+    def test_two_heads_a_chip_are_served_as_they_lie(self):
+        """2 KV heads a chip (mistral, llama3 or mixtral over model: 4):
+        XLA keeps an int8 cache head-major and each (slot, head) is a
+        lane of its own; a bf16 one stays interleaved
+        (tests/test_chip_compile.py shows neither view is a copy)."""
+        # gemma-7b: 128 positions of 16 bf16 heads of 256 are 1 MB a buffer
+        assert geometry(8, 4096, 16, 256, kv_bytes=1) == (8, 128)
+        assert geometry(8, 4096, 16, 256, kv_bytes=2) is None
+        assert geometry(8, 4096, 2, kv_bytes=1) == (8, 1024)
+        assert geometry(8, 4096, 2, kv_bytes=2) == (8, 512)
+        assert da._lanes(2, 1) == (2, 1) and da._lanes(2, 2) == (1, 2)
+
+    @pytest.mark.parametrize("batch, capacity, n_kv, want", [
+        (128, 640, 8, (128, 128)),       # mistral-7b's cell
+        (128, 640, 4, (128, 256)),       # qwen2-7b's: 2.5 blocks a slot
+        (64, 2048, 2, (64, 1024)),       # head-major lanes: 128 a grid step
+        (128, 640, 2, (64, 640)),
+        (8, 4096, 8, (8, 128)),
+        (8, 4096, 1, (8, 1024)),
+        (8, 256, 2, (8, 256)),           # never over the capacity
+        (192, 640, 16, (96, 128)),       # never under a lane tile;
+        (7, 1024, 8, (7, 128)),          # two grid steps of 96 slots
+        (128, 672, 8, None),
+        (128, 8192, 8, (16, 128)),       # the work lists stay in SMEM:
+        (128, 32768, 8, (4, 128)),       # 1,024 (slot, block) items a list
+        (8, 4096, 3, None),              # heads that tile nothing
+        (8, 4096, 12, None),
+        (8, 4096, 16, (8, 128)),
+    ])
+    def test_geometry_follows_the_cache_shape(self, batch, capacity, n_kv,
+                                              want):
+        assert geometry(batch, capacity, n_kv) == want
+
+    def test_routes_by_shape_and_mesh(self):
+        """One route per observable case, each named in the reply."""
         import dataclasses
 
         from symmetry_tpu.models import preset
+        from symmetry_tpu.models.llama import attention_paths
 
-        assert supports(preset("llama3-8b"), 8192, "tpu")
-        assert not supports(preset("llama3-8b"), 8192, "cpu")
-        assert not supports(preset("llama3-8b"), 2048, "tpu")  # below crossover
-        assert not supports(preset("tiny"), 8192, "tpu")       # D=16
-        # windowed models now route through the kernel (window-bounded
-        # block range); the capacity floor still applies
-        sliding = dataclasses.replace(preset("mistral-7b"), sliding_window=4096)
-        assert supports(sliding, 8192, "tpu")
-        assert not supports(sliding, 2048, "tpu")
-        assert supports(preset("llama3-8b"), 4096 + 640, "tpu")  # 64-mult
+        class Mesh:  # attention_paths reads nothing but the shape
+            shape = {"model": 4}
+
+        def paths(cfg, capacity, mesh=None, batch=128, kv_bytes=1):
+            return attention_paths(cfg, capacity, mesh, batch=batch,
+                                   kv_bytes=kv_bytes)
+
+        cfg = preset("mistral-7b")
+        assert paths(cfg, 640) == {
+            "prefill": "pallas-interpret", "decode": "pallas-interpret",
+            "decode_slot_tile": 128, "decode_block_t": 128}
+        assert paths(preset("qwen2-7b"), 640)["decode_block_t"] == 256
+        assert paths(cfg, 672) == {
+            "prefill": "pallas-interpret", "decode": "xla"}
+        # a sharded trunk keeps the XLA path under TP_MIN_CAPACITY and
+        # the per-shard kernel from there up, 2 KV heads a chip or 4
+        assert paths(cfg, 2048, Mesh(), batch=64) == {
+            "prefill": "pallas-interpret", "decode": "xla"}
+        assert paths(cfg, TP_MIN_CAPACITY, Mesh(), batch=8) == {
+            "prefill": "pallas-interpret", "decode": "pallas-interpret",
+            "decode_slot_tile": 8, "decode_block_t": 1024}
+
+        class Mesh2:
+            shape = {"model": 2}
+
+        assert paths(cfg, 2048, Mesh2())["decode"] == "xla"
+        assert paths(cfg, TP_MIN_CAPACITY, Mesh2())["decode"] == \
+            "pallas-interpret"
+        sliding = dataclasses.replace(cfg, sliding_window=4096)
+        assert paths(sliding, 8192)["decode"] == "pallas-interpret"
+        # one chip, 2 KV heads or 1 at a long capacity: the kernel too
+        for name in ("gemma-2b", "tiny-qwen"):
+            small = dataclasses.replace(preset(name), head_dim=128)
+            assert paths(small, 4096, batch=8)["decode"] == \
+                "pallas-interpret"
+
+
+LENGTHS_640 = [0, 1, 127, 128, 129, 640]
+
+
+def case_640(K, G, quantized, dtype=jnp.bfloat16, seed=0, B=6, T=640):
+    """One layer pair of a 6-slot x 640 cache at the dense cells' head
+    shapes, lengths mixed in the batch; scales position-minor."""
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (B, K * G, 128), dtype)
+    k = jax.random.normal(ks[1], (2, B, T, K, 128), jnp.float32)
+    v = jax.random.normal(ks[2], (2, B, T, K, 128), jnp.float32)
+    if not quantized:
+        return q, k.astype(dtype), v.astype(dtype), ()
+    kq, ksc = quantize_kv(k)
+    vq, vsc = quantize_kv(v)
+    return q, kq, vq, (to_minor(ksc), to_minor(vsc))
+
+
+class TestCellShapes:
+    """Capacity 640 with mistral-7b's (8 KV x 4) and qwen2-7b's (4 KV x 7)
+    heads: the shapes the benchmark's dense cells decode at."""
+
+    @pytest.mark.parametrize("slot_tile", [1, 2, 3, 6])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("K, G", [(8, 4), (4, 7)])
+    def test_matches_gqa_attention(self, tiled, K, G, quantized, slot_tile):
+        q, k, v, scales = case_640(K, G, quantized)
+        lengths = jnp.asarray(LENGTHS_640, jnp.int32)
+        assert geometry(6, 640, K)[0] == 6
+        got = tiled(lanes=slot_tile)(q, k, v, jnp.int32(1), lengths,
+                                     *scales, interpret=True)
+        assert got.shape == q.shape and got.dtype == q.dtype
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        want = reference(q, k[1], v[1], jnp.maximum(lengths, 1),
+                         *(s[1] for s in scales))
+        live = np.asarray(lengths) > 0   # an empty slot's row is garbage
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[live],
+            np.asarray(want, np.float32)[live], rtol=2e-2, atol=2e-2)
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("K, G", [(8, 4), (4, 7), (2, 4)])
+    def test_a_slot_does_not_see_its_neighbours(self, tiled, K, G,
+                                                quantized):
+        """check_correct compares two identical greedy requests that land
+        in different slots beside different neighbours: a slot's result
+        must be bit-identical whatever the others' lengths, wherever the
+        tile boundaries fall."""
+        q, k, v, scales = case_640(K, G, quantized, seed=2)
+        mine, slot = 300, 2
+        outs = []
+        for others, slot_tile in (([640, 1, 0, 129, 513], 6),
+                                  ([0, 0, 0, 0, 0], 6),
+                                  ([128, 640, 640, 640, 127], 3),
+                                  ([5, 257, 384, 1, 640], 1)):
+            lengths = others[:slot] + [mine] + others[slot:]
+            got = tiled(lanes=slot_tile)(
+                q, k, v, jnp.int32(0), jnp.asarray(lengths, jnp.int32),
+                *scales, interpret=True)
+            outs.append(np.asarray(got[slot], np.float32))
+        for other in outs[1:]:
+            np.testing.assert_array_equal(outs[0], other)
+
+    @pytest.mark.parametrize("K, G, quantized", [
+        (2, 4, True),    # head-major lanes, as XLA lays 2 int8 heads out
+        (1, 8, True),    # MQA (gemma-2b)
+        (2, 4, False),   # 2 bf16 heads stay interleaved: blocks of 512
+        (1, 4, False),
+    ])
+    def test_few_heads_at_a_long_capacity(self, tiled, K, G, quantized):
+        """1 or 2 KV heads a chip (a trunk sharded over model: 4, MQA) at
+        a capacity of several 1,024-position blocks: lengths mixed, the
+        batch split over grid steps."""
+        q, k, v, scales = case_640(K, G, quantized, seed=6, T=2304)
+        lengths = jnp.asarray([0, 1, 1023, 1025, 2304, 2049], jnp.int32)
+        got = tiled(lanes=4)(q, k, v, jnp.int32(1), lengths, *scales,
+                             interpret=True)
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        want = reference(q, k[1], v[1], jnp.maximum(lengths, 1),
+                         *(s[1] for s in scales))
+        np.testing.assert_allclose(np.asarray(got, np.float32)[1:],
+                                   np.asarray(want, np.float32)[1:],
+                                   rtol=2e-2, atol=2e-2)
+
+    @pytest.mark.parametrize("block_t", [128, 256, 384, 640])
+    def test_every_block_size_reads_the_same(self, tiled, block_t):
+        """640 = 2.5 x 256: the last block starts early and masks what the
+        one before it covered; whatever the block, the same result."""
+        q, k, v, scales = case_640(4, 7, True, seed=5)
+        lengths = jnp.asarray([640, 513, 512, 257, 256, 3], jnp.int32)
+        got = tiled(block_rows=4 * block_t)(
+            q, k, v, jnp.int32(1), lengths, *scales, window=300,
+            interpret=True)
+        want = gqa_attention(q[:, None], k[1], v[1], (lengths - 1)[:, None],
+                             lengths, sliding_window=300,
+                             k_scale=scales[0][1], v_scale=scales[1][1])[:, 0]
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+    def test_blocks_of_two_lane_tiles(self, tiled):
+        """Blocks over 128 positions: the scale planes are spread one
+        128-position chunk at a time."""
+        q, k, v, scales = case_640(8, 4, True, seed=4, B=3, T=512)
+        lengths = jnp.asarray([512, 257, 3], jnp.int32)
+        got = tiled(block_rows=8 * 256)(q, k, v, jnp.int32(0), lengths,
+                                        *scales, interpret=True)
+        want = reference(q, k[0], v[0], lengths, *(s[0] for s in scales))
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+    def test_heads_that_tile_no_sublanes_are_refused(self):
+        assert geometry(2, 128, 3) is None
+        q, k, v, _ = case_640(3, 2, False, B=2, T=128)
+        with pytest.raises(ValueError, match="geometry"):
+            decode_attention(q, k, v, jnp.int32(0),
+                             jnp.asarray([5, 9], jnp.int32), interpret=True)
+
+    def test_window_at_640(self):
+        q, k, v, scales = case_640(8, 4, True, seed=3)
+        lengths = jnp.asarray([640, 400, 257, 256, 130, 7], jnp.int32)
+        got = decode_attention(q, k, v, jnp.int32(1), lengths, *scales,
+                               window=256, interpret=True)
+        want = gqa_attention(q[:, None], k[1], v[1], (lengths - 1)[:, None],
+                             lengths, sliding_window=256,
+                             k_scale=scales[0][1], v_scale=scales[1][1])[:, 0]
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
 
 
 class TestModelIntegration:
     def test_forward_decode_uses_kernel_and_matches(self, monkeypatch):
-        """Full model decode with the kernel path force-enabled (interpret)
-        must reproduce the XLA path token-for-token."""
+        """Full model decode through the kernel (capacity 128: routed by
+        shape, interpreted here) must reproduce the XLA path
+        token-for-token."""
         import symmetry_tpu.ops.decode_attention as da
         from symmetry_tpu.models import ModelConfig, forward, init_cache, init_params
 
@@ -110,11 +343,9 @@ class TestModelIntegration:
             np.random.default_rng(0).integers(0, 256, (2, 8)), jnp.int32)
 
         def decode(force_kernel):
-            if force_kernel:
-                monkeypatch.setattr(da, "supports", lambda *a: True)
-            else:
-                monkeypatch.setattr(da, "supports", lambda *a: False)
-            cache = init_cache(cfg, 2, 32, jnp.float32)
+            if not force_kernel:
+                monkeypatch.setattr(da, "geometry", lambda *a: None)
+            cache = init_cache(cfg, 2, 128, jnp.float32)
             logits, cache = forward(params, cfg, prompt, cache)
             last = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
             toks = [np.asarray(last)]
@@ -138,8 +369,9 @@ class TestModelIntegration:
             np.random.default_rng(1).integers(0, 256, (1, 6)), jnp.int32)
 
         def decode(force_kernel):
-            monkeypatch.setattr(da, "supports", lambda *a: force_kernel)
-            cache = init_cache(cfg, 1, 32, jnp.float32, quantized=True)
+            if not force_kernel:
+                monkeypatch.setattr(da, "geometry", lambda *a: None)
+            cache = init_cache(cfg, 1, 128, jnp.float32, quantized=True)
             logits, cache = forward(params, cfg, prompt, cache)
             last = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
             outs = [np.asarray(logits[:, -1])]
@@ -158,12 +390,11 @@ class TestSlidingWindow:
     gqa_attention's sliding_window semantics exactly."""
 
     @pytest.mark.parametrize("window", [8, 24, 48, 200])
-    @pytest.mark.parametrize("block_t", [16, 32])
-    def test_matches_xla_sliding_reference(self, window, block_t):
-        q, k, v, lengths = make_case(seed=3)
+    @pytest.mark.parametrize("K", [2, 8])  # one block of 384, three of 128
+    def test_matches_xla_sliding_reference(self, window, K):
+        q, k, v, lengths = make_case(seed=3, K=K)
         got = decode_attention(q, k, v, jnp.int32(0), lengths,
-                               block_t=block_t, window=window,
-                               interpret=True)
+                               window=window, interpret=True)
         positions = (lengths - 1)[:, None]
         want = gqa_attention(q[:, None], k[0], v[0], positions, lengths,
                              sliding_window=window)[:, 0]
@@ -171,16 +402,18 @@ class TestSlidingWindow:
                                    rtol=2e-5, atol=2e-5)
 
     def test_quantized_sliding(self):
-        q, k, v, lengths = make_case(seed=4)
+        # 2 int8 KV heads: head-major lanes of 1,024-position blocks, the
+        # window's floor two blocks up for the longest slot
+        q, k, v, lengths = make_case(seed=4, T=3200)
         kq, ksc = quantize_kv(k)
         vq, vsc = quantize_kv(v)
         ksc, vsc = to_minor(ksc), to_minor(vsc)
         got = decode_attention(q, kq, vq, jnp.int32(1), lengths,
                                k_scale=ksc, v_scale=vsc,
-                               block_t=16, window=24, interpret=True)
+                               window=1100, interpret=True)
         positions = (lengths - 1)[:, None]
         want = gqa_attention(q[:, None], kq[1], vq[1], positions, lengths,
-                             sliding_window=24,
+                             sliding_window=1100,
                              k_scale=ksc[1], v_scale=vsc[1])[:, 0]
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
@@ -188,7 +421,7 @@ class TestSlidingWindow:
     def test_window_larger_than_length_is_full_attention(self):
         q, k, v, lengths = make_case(seed=5)
         got = decode_attention(q, k, v, jnp.int32(0), lengths,
-                               block_t=16, window=10_000, interpret=True)
+                               window=10_000, interpret=True)
         want = reference(q, k[0], v[0], lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)

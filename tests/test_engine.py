@@ -107,6 +107,74 @@ class TestEnginePrimitives:
         assert got_a == want_a
         assert got_b == want_b
 
+    def test_released_and_fresh_lanes_stay_parked(self, setup):
+        """Decode attention reads each lane up to its length, so a lane
+        nobody owns must not keep one: a fresh lane stays at 0 through
+        decode steps, a released lane returns to 0 and stays, and neither
+        disturbs the live lane's greedy tokens."""
+        cfg, params = setup
+        engine = make_engine(cfg, params, slots=3)
+        pa, pb = list(b"first prompt"), list(b"second, quite different")
+        want_a = reference_greedy(cfg, params, pa, 7)
+
+        got_a = [engine.prefill_and_insert(0, pa, SamplingParams())]
+        engine.prefill_and_insert(1, pb, SamplingParams())
+        got_a.append(int(engine.decode_step()[0]))
+        assert [engine.slot_length(s) for s in range(3)] == [
+            len(pa) + 1, len(pb) + 1, 0]
+        engine.release_slot(1)
+        for _ in range(5):
+            got_a.append(int(engine.decode_step()[0]))
+        assert [engine.slot_length(s) for s in range(3)] == [
+            len(pa) + 6, 0, 0]
+        assert got_a == want_a
+        # the lane is reusable, and its request decodes like any other
+        want_b = reference_greedy(cfg, params, pb, 3)
+        got_b = [engine.prefill_and_insert(1, pb, SamplingParams())]
+        for _ in range(2):
+            got_b.append(int(engine.decode_step()[1]))
+        assert got_b == want_b
+
+    def test_a_lane_reused_before_the_next_decode_is_not_parked(self, setup):
+        """Parking rides the next decode dispatch; a closed loop refills
+        a finished lane before that dispatch, and the new request must
+        keep its prompt."""
+        cfg, params = setup
+        engine = make_engine(cfg, params, slots=2)
+        pa, pb = list(b"first prompt"), list(b"second, quite different")
+        engine.prefill_and_insert(0, pa, SamplingParams())
+        engine.decode_step()
+        engine.release_slot(0)
+        want_b = reference_greedy(cfg, params, pb, 4)
+        got_b = [engine.prefill_and_insert(0, pb, SamplingParams())]
+        for _ in range(3):
+            got_b.append(int(engine.decode_step()[0]))
+        assert got_b == want_b
+        assert engine.slot_length(0) == len(pb) + 3
+
+    def test_a_release_is_a_command_every_process_follows(self):
+        """Across hosts the lanes to park are an input of the next decode
+        program: a follower has to note the same release as the leader
+        (the wire form and what a follower does with it; the two-process
+        run is tests/test_multihost.py)."""
+        from symmetry_tpu.parallel.multihost import (
+            CMD_RELEASE, Command, CommandLoop)
+
+        class Follower:
+            prefill_buckets = (16,)
+
+            def __init__(self):
+                self.released = []
+
+            def release_slot(self, slot):
+                self.released.append(slot)
+
+        engine = Follower()
+        loop = CommandLoop(engine, is_coordinator=False)
+        wire = Command(kind=CMD_RELEASE, slot=5).encode(loop.max_bucket)
+        loop._execute(Command.decode(wire))
+        assert engine.released == [5]
+
     def test_prompt_too_long_raises(self, setup):
         cfg, params = setup
         engine = make_engine(cfg, params, buckets=(16,))

@@ -1,41 +1,135 @@
-"""Does the ragged decode-attention kernel win at 640 capacity under
-PARTIAL occupancy (the serving regime), not just full (round-3's gate
-measurement)? Times the trunk at several occupancies, kernel vs einsum."""
-import os, sys
+"""Decode attention at the dense cells' shape, kernel against the XLA path.
+
+For each of mistral-7b and qwen2-7b (int8 weights + int8 KV, 128 slots x
+640, the benchmark's serving shape): first the kernel's result against
+`gqa_attention` on one layer of random cache with lengths {0, 1, 127, 128,
+129, 640} mixed in the batch, then the decode trunk (`forward_hidden`, one
+position a slot) timed with every slot at 64 / 173 / 320 / 620 of 640 and
+at the closed cells' mix (107 live slots spread over 32..600, 21 empty) —
+once through ops/decode_attention.py as `_layer` routes it, once with the
+route forced to "xla" (the staged per-layer slice the kernel replaced).
+The forcing lives here, not in the program: the served path has no switch.
+
+Needs a TPU: `python tools/ab_ragged_640.py [preset ...]`. Writes
+chiprun_out/ab_ragged_640.json.
+"""
+import json
+import os
+import sys
+import time
+
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import time
-import jax, jax.numpy as jnp
+import jax
+import jax.numpy as jnp
+import numpy as np
 from _bench_util import sync
 from symmetry_tpu.models import llama
 from symmetry_tpu.ops import decode_attention as da
+from symmetry_tpu.ops.attention import gqa_attention
+from symmetry_tpu.ops.interpret import interpret_mode
 
-cfg = llama.preset("llama3-8b")
 B, T = 128, 640
-params = llama.init_params(cfg, jax.random.key(0), jnp.bfloat16, quantize=True)
+OCCUPANCIES = (64, 173, 320, 620)
 
-def time_at(occ, use_kernel, n=15):
-    real = da.supports
-    da.supports = (lambda *a, **k: True) if use_kernel else (lambda *a, **k: False)
+
+def cell_mix() -> np.ndarray:
+    """The closed cells' steady state: 107 of 128 slots live, lengths
+    spread over prompt 32..160 + output 0..448, the rest empty."""
+    rng = np.random.default_rng(0)
+    lengths = np.zeros(B, np.int32)
+    live = rng.permutation(B)[:107]
+    lengths[live] = rng.integers(32, 600, size=live.size)
+    return lengths
+
+
+def parity(cfg) -> dict:
+    """Worst |kernel - gqa_attention| on one layer, lengths mixed."""
+    K, nq, D = cfg.num_kv_heads, cfg.num_heads, cfg.dim_per_head
+    ks = jax.random.split(jax.random.key(1), 5)
+    q = jax.random.normal(ks[0], (B, nq, D), jnp.bfloat16)
+    k = jax.random.randint(ks[1], (2, B, T, K, D), -127, 128, jnp.int8)
+    v = jax.random.randint(ks[2], (2, B, T, K, D), -127, 128, jnp.int8)
+    ksc = jax.random.uniform(ks[3], (2, B, K, T), jnp.float32, 0.005, 0.02)
+    vsc = jax.random.uniform(ks[4], (2, B, K, T), jnp.float32, 0.005, 0.02)
+    lengths = jnp.asarray(np.resize([0, 1, 127, 128, 129, 640], B), jnp.int32)
+    got = da.decode_attention(q, k, v, jnp.int32(1), lengths, ksc, vsc,
+                              window=cfg.sliding_window,
+                              interpret=interpret_mode())
+    want = gqa_attention(q[:, None], k[1], v[1],
+                         jnp.maximum(lengths - 1, 0)[:, None], lengths,
+                         sliding_window=cfg.sliding_window,
+                         k_scale=ksc[1], v_scale=vsc[1])[:, 0]
+    live = np.asarray(lengths) > 0
+    err = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
+    return {"max_abs_err": float(err[live].max()),
+            "finite": bool(np.isfinite(np.asarray(got, np.float32)).all()),
+            "ref_scale": float(np.abs(np.asarray(want, np.float32)[live]).max())}
+
+
+def make_trunk(cfg, params, use_kernel: bool):
+    """The decode trunk, compiled once per route; lengths are an argument,
+    so every occupancy runs the same executable."""
+    real = da.geometry
+    if not use_kernel:
+        da.geometry = lambda *a, **k: None
     try:
+        def step(p, t, c, n_):
+            # every call decodes at the SAME lengths: the occupancy under
+            # test does not drift over the timed iterations
+            return llama.forward_hidden(p, cfg, t, c._replace(lengths=n_))
+
+        trunk = jax.jit(step, donate_argnums=(2,))
         cache = llama.init_cache(cfg, B, T, jnp.bfloat16, quantized=True)
-        cache = cache._replace(lengths=jnp.full((B,), occ, jnp.int32))
         tok = jnp.ones((B, 1), jnp.int32)
-        trunk = jax.jit(lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
-                        donate_argnums=(2,))
+        h, cache = trunk(params, tok, cache, jnp.zeros((B,), jnp.int32))
+        sync(h)
+    finally:
+        da.geometry = real
+
+    def timed(lengths, n: int = 20) -> float:
+        nonlocal cache
+        lengths = jnp.asarray(lengths, jnp.int32)
         for _ in range(3):
-            h, cache = trunk(params, tok, cache)
+            h, cache = trunk(params, tok, cache, lengths)
         sync(h)
         t0 = time.perf_counter()
         for _ in range(n):
-            h, cache = trunk(params, tok, cache)
+            h, cache = trunk(params, tok, cache, lengths)
         sync(h)
         return (time.perf_counter() - t0) / n * 1e3
-    finally:
-        da.supports = real
 
-for occ in (128, 320, 512, 620):
-    ein = time_at(occ, False)
-    ker = time_at(occ, True)
-    print(f"occ {occ:4d}/640: einsum {ein:6.2f} ms  kernel {ker:6.2f} ms  "
-          f"({ein - ker:+.2f})", flush=True)
+    return timed
+
+
+def main() -> None:
+    report = {"device": jax.devices()[0].device_kind, "shape": [B, T]}
+    for name in sys.argv[1:] or ("mistral-7b", "qwen2-7b"):
+        cfg = llama.preset(name)
+        row = {"geometry": da.geometry(B, T, cfg.num_kv_heads),
+               "parity": parity(cfg),
+               "trunk_ms": {}}
+        print(name, "parity", row["parity"], flush=True)
+        params = llama.init_params(cfg, jax.random.key(0), jnp.bfloat16,
+                                   quantize=True)
+        cases = {str(o): np.full(B, o, np.int32) for o in OCCUPANCIES}
+        cases["cell-mix"] = cell_mix()
+        for route in ("xla", "kernel"):  # one cache on the chip at a time
+            timed = make_trunk(cfg, params, route == "kernel")
+            for label, lengths in cases.items():
+                row["trunk_ms"].setdefault(label, {})[route] = round(
+                    timed(lengths), 2)
+            del timed
+        for label, ms in row["trunk_ms"].items():
+            print(f"{name} {label:>8} of 640: xla {ms['xla']:6.2f} ms  "
+                  f"kernel {ms['kernel']:6.2f} ms  "
+                  f"({ms['xla'] - ms['kernel']:+.2f})", flush=True)
+        report[name] = row
+        del params
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/ab_ragged_640.json", "w") as fh:
+            json.dump(report, fh, indent=1)
+
+
+if __name__ == "__main__":
+    main()

@@ -33,24 +33,18 @@ def main():
                          "upcast-materialized by XLA, int8 would not beat "
                          "bf16 here")
     ap.add_argument("--trunk-only", action="store_true")
-    force = ap.add_mutually_exclusive_group()
-    force.add_argument("--force-kernel", action="store_true",
-                       help="route decode attention through the Pallas "
-                            "ragged kernel regardless of capacity")
-    force.add_argument("--force-einsum", action="store_true",
-                       help="disable the Pallas decode kernel (A/B at "
-                            "capacities where it is the default)")
+    ap.add_argument("--force-einsum", action="store_true",
+                    help="time the XLA attention path instead of the "
+                         "Pallas decode kernel the program routes to "
+                         "(forced here: the served path has no switch)")
     ap.add_argument("--occupancy", type=int, default=None,
                     help="per-slot cache occupancy for the trunk timing "
                          "(default: near capacity)")
     args = ap.parse_args()
 
-    if args.force_kernel:
-        from symmetry_tpu.ops import decode_attention as _da
-        _da.MIN_CAPACITY = 0
     if args.force_einsum:
         from symmetry_tpu.ops import decode_attention as _da
-        _da.MIN_CAPACITY = 10**9
+        _da.geometry = lambda *a, **k: None
 
     from symmetry_tpu.models.llama import (
         forward_hidden, init_cache, init_params, logits_from_hidden, preset)
@@ -87,7 +81,6 @@ def main():
     L = cfg.num_layers
     print(f"trunk (all {L} layers):   {ms_trunk:8.2f} ms  "
           f"(B={B} T={T} occ={occ} kv={'int8' if kvq else 'bf16'}"
-          f"{' kernel' if args.force_kernel else ''}"
           f"{' einsum' if args.force_einsum else ''})", flush=True)
     if args.trunk_only:
         return
@@ -156,22 +149,19 @@ def main():
     except Exception as exc:  # noqa: BLE001
         print(f"cache scatter x1 (k):     failed: {exc}", flush=True)
 
-    # Pallas ragged decode kernel at this capacity (if divisible)
+    # Pallas ragged decode kernel at this capacity (block and slot tile by
+    # its own geometry)
     from symmetry_tpu.ops import decode_attention as da
-    for bt in (512, 256, 128):
-        if T % bt == 0 and bt <= T:
-            q3 = jnp.ones((B, nq, D), jnp.bfloat16)
-            pal = jax.jit(lambda q3, k, v, ks, vs, kl: da.decode_attention(
-                q3, k, v, jnp.int32(0), kl,
-                k_scale=ks, v_scale=vs, block_t=bt))
-            try:
-                ms_pallas1 = timeit(pal, q3, cache.k, cache.v,
-                                    cache.k_scale, cache.v_scale, kl)
-                print(f"attention x1 (pallas):    {ms_pallas1:8.2f} ms  "
-                      f"(x{L} = {ms_pallas1*L:.1f})", flush=True)
-            except Exception as exc:  # noqa: BLE001
-                print(f"attention x1 (pallas):    failed: {exc}", flush=True)
-            break
+    q3 = jnp.ones((B, nq, D), jnp.bfloat16)
+    pal = jax.jit(lambda q3, k, v, ks, vs, kl: da.decode_attention(
+        q3, k, v, jnp.int32(0), kl, k_scale=ks, v_scale=vs))
+    try:
+        ms_pallas1 = timeit(pal, q3, cache.k, cache.v,
+                            cache.k_scale, cache.v_scale, kl)
+        print(f"attention x1 (pallas):    {ms_pallas1:8.2f} ms  "
+              f"(x{L} = {ms_pallas1*L:.1f})", flush=True)
+    except Exception as exc:  # noqa: BLE001
+        print(f"attention x1 (pallas):    failed: {exc}", flush=True)
 
     # bandwidth sanity: weight bytes + kv bytes
     wb = sum(np.prod(x.shape) * x.dtype.itemsize
