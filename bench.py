@@ -201,7 +201,7 @@ def run_bench(preset_name: str, *, slots: int, steps: int, prompt_len: int,
               max_seq: int, dtype_name: str, mesh_model: int,
               block: int = 1, quant: str | None = None,
               kv_quant: bool = False, fused_dequant: bool = False,
-              profile_sample: int = 0, pipeline_depth: int = 1) -> dict:
+              pipeline_depth: int = 1) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -236,7 +236,7 @@ def run_bench(preset_name: str, *, slots: int, steps: int, prompt_len: int,
         config, params, ByteTokenizer(), mesh=mesh, max_slots=slots,
         max_seq_len=max_seq, prefill_buckets=(prompt_len,),
         cache_dtype=dtype, decode_block=block, kv_quant=kv_quant,
-        fused_dequant=fused_dequant, profile_sample=profile_sample)
+        fused_dequant=fused_dequant)
 
     # Compile the decode program BEFORE inserting real requests (warmup's
     # garbage device writes are only harmless pre-insert).
@@ -289,17 +289,6 @@ def run_bench(preset_name: str, *, slots: int, steps: int, prompt_len: int,
 
     done_steps = n_disp * block
     tok_s = slots * done_steps / dt
-    # symprof block (tpu.profile_sample): per-dispatch-kind DEVICE
-    # duration p50s + the dispatch-gap share — the engine-only bench
-    # exercises prefill + decode_block; the serving bench covers the
-    # full kind set through the scheduler.
-    devprof_block = None
-    if profile_sample:
-        dstats = engine.devprof.stats()
-        devprof_block = dict(dstats)
-        devprof_block["device_p50_ms"] = {
-            kind: _rnd(1e3 * h["p50"], 3) if h.get("p50") else None
-            for kind, h in (dstats.get("device_s") or {}).items()}
     dtype_label = f"{dtype_name}+{quant}" if quant else dtype_name
     if kv_quant:
         dtype_label += "+kv8"
@@ -336,7 +325,6 @@ def run_bench(preset_name: str, *, slots: int, steps: int, prompt_len: int,
             weight_bytes_dev / step_s / 1e9, 1),
         "pipeline_depth": depth,
         "dispatch_thread_block_s": disp_wall,
-        **({"devprof": devprof_block} if devprof_block else {}),
     }
 
 
@@ -666,7 +654,7 @@ def run_autoscale(preset_name: str, *, clients: int, slots: int,
     The autoscaled arm runs the real closed loop: a SloMonitor observes
     the same traffic (the bench performs the provider's exact observe
     calls — TTFT on first delta, inter-chunk gaps as they arrive), the
-    pool heartbeat feeds burn rates + queue gauges + symprof busy-time
+    pool heartbeat feeds burn rates + queue gauges + the ledger's busy-time
     into PoolAutoscaler (engine/disagg/autoscale.py), and its decisions
     spawn/drain real members mid-trace. The verdict the capture
     records: does the autoscaled arm meet the SLOs with fewer
@@ -944,7 +932,6 @@ def run_e2e(preset_name: str, *, clients: int, slots: int, max_new: int,
             disagg_pool: tuple[int, int] | None = None,
             multi_turn: int = 1,
             metrics_out: str | None = None,
-            profile_sample: int = 0,
             pipeline_depth: int | None = None,
             arrival: str | None = None,
             arrival_duration_s: float = 45.0,
@@ -1057,11 +1044,6 @@ def run_e2e(preset_name: str, *, clients: int, slots: int, max_new: int,
                 # under 1% of greedy decode tok/s (--no-trace vs default
                 # at otherwise identical settings).
                 **({"tracing": False} if not tracing else {}),
-                # symprof (utils/devprof.py): 1-in-N completion probes
-                # per dispatch kind — per-kind device durations + the
-                # dispatch-gap share land in the engine.devprof block.
-                **({"profile_sample": profile_sample}
-                   if profile_sample else {}),
             },
         }
         # Provider log is ALWAYS captured (round-3 verdict #1: a 6-line
@@ -2427,17 +2409,6 @@ def main() -> None:
                          "(tpu.tracing=false). The tracing-overhead A/B "
                          "is this flag on vs off at otherwise identical "
                          "settings; acceptance: within 1%% tok/s")
-    ap.add_argument("--profile-sample", type=int, default=0, metavar="N",
-                    help="symprof device-time attribution "
-                         "(tpu.profile_sample): completion-probe every "
-                         "Nth engine dispatch of each kind — per-kind "
-                         "DEVICE duration p50s and the dispatch-gap "
-                         "share land in the JSON's devprof block (and "
-                         "the Perfetto export gains the device track). "
-                         "0 = off. Probes serialize 1 dispatch in N; "
-                         "the overhead A/B (BASELINE.md Round 15) is "
-                         "this flag vs 0 at otherwise identical "
-                         "settings")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the provider's final metrics-registry "
                          "snapshot (tier-labeled JSON, utils/metrics.py "
@@ -2568,8 +2539,7 @@ def main() -> None:
                 "dtype": dtype, "block": block, "mesh_model": mesh_model,
                 "quant": quant, "kv_quant": kv_quant,
                 "fused_dequant": fused_dequant,
-                "pipeline_depth": pipeline_depth,
-                "profile_sample": args.profile_sample}
+                "pipeline_depth": pipeline_depth}
 
     if mode == "smoke":
         fp_cfg = engine_fp("tiny", 2, 8, 16, 64, "float32", 2, 1,
@@ -2627,7 +2597,6 @@ def main() -> None:
             "max_queue": args.max_queue, "max_ttft": args.max_ttft,
             "client_procs": args.client_procs,
             "tracing": not args.no_trace,
-            "profile_sample": args.profile_sample,
         }
     if args.smoke:
         # Smoke mode must not touch a TPU: pin the CPU backend by name
@@ -2637,7 +2606,7 @@ def main() -> None:
         jax.config.update("jax_platforms", "cpu")
         result = run_bench("tiny", slots=2, steps=8, prompt_len=16,
                            max_seq=64, dtype_name="float32", mesh_model=1,
-                           block=2, profile_sample=args.profile_sample,
+                           block=2,
                            pipeline_depth=args.pipeline_depth or 1)
     elif args.chaos:
         result = run_chaos(
@@ -2674,7 +2643,6 @@ def main() -> None:
             quant=None if args.quant == "none" else args.quant,
             kv_quant=args.kv_quant == "int8",
             fused_dequant=args.fused_dequant,
-            profile_sample=args.profile_sample,
             pipeline_depth=args.pipeline_depth or 1)
     elif args.proxy:
         result = run_proxy(clients=args.clients, max_new=args.max_new,
@@ -2714,7 +2682,6 @@ def main() -> None:
             disagg_pool=pool_mn,
             multi_turn=args.multi_turn,
             metrics_out=args.metrics_out,
-            profile_sample=args.profile_sample,
             pipeline_depth=args.pipeline_depth,
             arrival=args.arrival,
             arrival_duration_s=args.arrival_duration,

@@ -2,8 +2,8 @@
 
 Every piece existed before this module and nothing connected them: PR 11
 gave the pools their actuators (join / drain / leave, per-member
-respawn), PR 10 gave SLO burn rates and queue gauges, PR 15 gave
-symprof's measured device-seconds per tier — yet the M×N tier shape
+respawn), PR 10 gave SLO burn rates and queue gauges, the request
+ledger books device-seconds per tier — yet the M×N tier shape
 stayed a hand-picked constant. This module is the controller in the
 middle, shaped after DistServe's goodput objective and Splitwise's
 phase-pool sizing (PAPERS.md): maximize SLO-attaining tokens per
@@ -12,7 +12,7 @@ chip-second, where chip-seconds = Σ member-alive time.
     SloMonitor.burn_rates() ──ttft──────────▶ prefill pressure
                             ──inter_chunk──▶ decode pressure
     PoolRouter gauges ──in-flight + queue_depth──▶ per-tier load
-    symprof device_s_total ──per-tier busy deltas─▶ measured M:N ratio
+    ledger device_total_s ──per-tier busy deltas─▶ measured M:N ratio
                                 │
                                 ▼  PoolAutoscaler.tick()  (one per pool
                                 │  heartbeat; pure state, injectable
@@ -210,7 +210,7 @@ class PoolAutoscaler:
              applying: bool = False) -> dict[str, Any]:
         """One control step. Inputs: per-SLO fast-window burns
         (SloMonitor.burn_rates()), per-tier device-busy-second deltas
-        since the last tick (symprof's measured ratio signal), the
+        since the last tick (the ledger's measured ratio signal), the
         cumulative SLO-ATTAINING token count (the goodput numerator —
         the ledger's per-request attainment fold; ROADMAP item 5 and
         DistServe define goodput over tokens that met their SLO, not
@@ -392,7 +392,7 @@ class PoolAutoscaler:
                 f"load {avg_load[best_tier]:.2f} over threshold"), {
                 "tier": best_tier}
 
-        # --- rebalance: symprof's measured per-tier device cost says
+        # --- rebalance: the ledger's per-tier device cost says
         # the M:N split is wrong. desired_prefill = total × share of
         # busy time the prefill tier actually consumed, clamped to
         # keep both tiers ≥ 1. Only moves when the shrinking tier is

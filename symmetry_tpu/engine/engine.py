@@ -52,17 +52,6 @@ from symmetry_tpu.engine.spec import SpecConfig
 from symmetry_tpu.engine.tokenizer import Tokenizer, get_tokenizer
 
 
-def _stage_rules(mesh):
-    """PIPELINE_RULES when the mesh has an active stage axis, else None —
-    the ONE place pipeline-mode detection lives (constructor, jit builder,
-    and from_tpu_config all route through it)."""
-    if mesh is not None and dict(mesh.shape).get("stage", 1) > 1:
-        from symmetry_tpu.parallel.pipeline import PIPELINE_RULES
-
-        return PIPELINE_RULES
-    return None
-
-
 def _park(state: "DecodeState", park: jnp.ndarray) -> "DecodeState":
     """The head of every decode program: the lanes in `park` ([B] bool:
     released since the last one and not reused, InferenceEngine
@@ -187,7 +176,6 @@ class InferenceEngine:
         cache_dtype=jnp.bfloat16,
         decode_block: int = 1,
         kv_quant: bool = False,
-        pipeline_microbatches: int = 1,
         prefill_chunk: int | None = 256,
         prefill_token_budget: int | None = None,
         prefix_cache_bytes: int = 0,
@@ -197,12 +185,19 @@ class InferenceEngine:
         speculative: SpecConfig | None = None,
         fused_dequant: bool = False,
         role: str = "unified",
-        profile_sample: int = 0,
     ) -> None:
         self.config = config
         self.params = params
         self.tokenizer = tokenizer
         self.mesh = mesh
+        stages = 1 if mesh is None else dict(mesh.shape).get("stage", 1)
+        if stages > 1:
+            # The axis has no schedule behind it (it leaves parallel/
+            # mesh.py AXIS_ORDER with the next mesh change): refused, not
+            # served as replication.
+            raise EngineError(
+                f"mesh axis 'stage' is {stages}: no stage schedule — "
+                f"shard over 'model' (and 'data') instead")
         # Disaggregated prefill/decode (engine/disagg/): "unified" is
         # today's engine — prefill AND decode on this chip. "prefill"
         # builds prompt KV and hands it off (never decodes; warmup skips
@@ -259,49 +254,24 @@ class InferenceEngine:
                 MetricName.QMM_FALLBACK,
                 "int8 leaves kept on the mixed dot at load",
                 labels=("reason",))
-            if _stage_rules(mesh) is not None:
-                # Pipeline stages run the trunk inside their own
-                # shard_map collectives; the fused kernel's per-shard
-                # dispatch cannot nest there. Degrade the whole tree —
-                # the engine serves unfused, and says so.
+            degrades: list[tuple[str, str]] = []
+            self.params = params = pack_params(
+                params, config=config, mesh=mesh, report=degrades)
+            for path, reason in degrades:
                 logger.warning(
-                    "tpu.fused_dequant: pipeline (stage axis > 1) keeps "
-                    "every int8 leaf on the mixed dot (reason: "
-                    "stage_axis)")
-                fallback.inc(reason="stage_axis")
-            else:
-                degrades: list[tuple[str, str]] = []
-                self.params = params = pack_params(
-                    params, config=config, mesh=mesh, report=degrades)
-                for path, reason in degrades:
-                    logger.warning(
-                        f"tpu.fused_dequant: {path} stays on the mixed "
-                        f"dot (reason: {reason})")
-                    fallback.inc(reason=reason)
+                    f"tpu.fused_dequant: {path} stays on the mixed "
+                    f"dot (reason: {reason})")
+                fallback.inc(reason=reason)
 
-                def is_packed(leaf):
-                    return isinstance(leaf, PackedQuantizedTensor)
+            def is_packed(leaf):
+                return isinstance(leaf, PackedQuantizedTensor)
 
-                if not any(is_packed(leaf) for leaf in
-                           jax.tree.leaves(params, is_leaf=is_packed)):
-                    logger.warning(
-                        "tpu.fused_dequant: no int8 leaf packed on this "
-                        "mesh/backend — the engine runs entirely on the "
-                        "mixed dot (see the degrade reasons above)")
-        # Pipeline-parallel serving (parallel/pipeline.py): a stage axis of
-        # size > 1 routes prefill AND decode through the staged microbatch
-        # schedule; params/cache must be stage-sharded (PIPELINE_RULES).
-        self._rules = _stage_rules(mesh)
-        self.pipeline = self._rules is not None
-        if self.pipeline and max_slots % pipeline_microbatches:
-            raise EngineError(
-                f"max_slots {max_slots} must divide into "
-                f"{pipeline_microbatches} pipeline microbatches")
-        if pipeline_microbatches > 1 and not self.pipeline:
-            raise EngineError(
-                "pipeline_microbatches > 1 requires a mesh with a stage "
-                "axis > 1 — the setting would otherwise be silently inert")
-        self.pipeline_microbatches = pipeline_microbatches
+            if not any(is_packed(leaf) for leaf in
+                       jax.tree.leaves(params, is_leaf=is_packed)):
+                logger.warning(
+                    "tpu.fused_dequant: no int8 leaf packed on this "
+                    "mesh/backend — the engine runs entirely on the "
+                    "mixed dot (see the degrade reasons above)")
         self.max_slots = max_slots
         # lanes released since the last decode dispatch and not reused:
         # that dispatch parks them (release_slot)
@@ -327,34 +297,22 @@ class InferenceEngine:
                                      else self.PREFILL_TOKEN_BUDGET)
         if self.prefill_token_budget < 1:
             raise EngineError("prefill_token_budget must be >= 1")
-        # symprof (utils/devprof.py, tpu.profile_sample): sampling
-        # completion probes around every dispatch kind below — per-kind
-        # DEVICE durations + the dispatch-gap series. Off (0) = one
-        # branch per dispatch: every hook is guarded by `dp.enabled`.
-        from symmetry_tpu.utils.devprof import DeviceProfiler
-
-        self.devprof = DeviceProfiler(profile_sample)
-
         c = config
         # MoE models count the valid (token, expert) pairs each forward
         # computed, per expert, in the caches' `expert_pairs` (models/
-        # llama.py KVCache). The pipeline schedule rebuilds its caches
-        # without it.
-        self._count_experts = (bool(getattr(c, "num_experts", 0))
-                               and not self.pipeline)
+        # llama.py KVCache).
+        self._count_experts = bool(getattr(c, "num_experts", 0))
         self.expert_pairs = [0] * getattr(c, "num_experts", 0)
         self._pairs_pending: collections.deque = collections.deque()
         self._moe_report: dict | None = None
 
         if mesh is not None:
-            rules = self._rules
             cax = cache_logical_axes(quantized=kv_quant)
             rep = jax.NamedSharding(mesh, jax.sharding.PartitionSpec())
-            sc = (shardings_for(cax.k_scale, mesh, rules)
-                  if kv_quant else None)
+            sc = shardings_for(cax.k_scale, mesh) if kv_quant else None
             self._cache_shardings = KVCache(
-                k=shardings_for(cax.k, mesh, rules),
-                v=shardings_for(cax.v, mesh, rules),
+                k=shardings_for(cax.k, mesh),
+                v=shardings_for(cax.v, mesh),
                 # lengths stays REPLICATED (O(slots) int32): the host reads
                 # individual slots, and on a multi-process data axis a
                 # batch-sharded slot may live on another host.
@@ -506,29 +464,7 @@ class InferenceEngine:
     # Jitted primitives
 
     def _build_jits(self) -> None:
-        cfg = self.config
-
-        def trunk(params, tokens, cache, seq_lens=None, prefill_flash=False):
-            """forward_hidden, routed through the pipeline schedule when a
-            stage axis is active (params/cache are stage-sharded then)."""
-            if self.pipeline:
-                from symmetry_tpu.parallel.pipeline import (
-                    pipeline_forward_hidden)
-
-                n_micro = (self.pipeline_microbatches
-                           if tokens.shape[0] == self.max_slots else 1)
-                return pipeline_forward_hidden(
-                    params, cfg, tokens, cache, self.mesh,
-                    seq_lens=seq_lens, n_microbatches=n_micro,
-                    prefill_flash=prefill_flash)
-            return forward_hidden(params, cfg, tokens, cache,
-                                  seq_lens=seq_lens,
-                                  prefill_flash=prefill_flash,
-                                  # The fused Pallas KV append has no
-                                  # GSPMD partitioning rule; sharded
-                                  # caches keep the XLA scatter path.
-                                  kv_append_ok=self.mesh is None,
-                                  tp_mesh=self.mesh)
+        cfg, mesh = self.config, self.mesh
 
         def prefill(params, tokens, true_len, temp, top_p, top_k, rng,
                     scratch):
@@ -557,8 +493,9 @@ class InferenceEngine:
                 # a reused buffer must not bring its last use's along.
                 cache = cache._replace(
                     expert_pairs=jnp.zeros_like(cache.expert_pairs))
-            h, cache = trunk(params, tokens, cache,
-                             seq_lens=true_len, prefill_flash=True)
+            h, cache = forward_hidden(params, cfg, tokens, cache,
+                                      seq_lens=true_len, prefill_flash=True,
+                                      tp_mesh=mesh)
             # Project ONLY the last valid position through the LM head —
             # head cost is per-position × vocab, and padded positions are
             # garbage anyway.
@@ -719,14 +656,16 @@ class InferenceEngine:
             runs the continuation path (absolute-position masking against
             the cache written by earlier chunks) — prefill_flash's
             empty-cache contract doesn't hold past chunk 0."""
-            _, cache = trunk(params, tokens, cache, seq_lens=seq_len)
+            _, cache = forward_hidden(params, cfg, tokens, cache,
+                                      seq_lens=seq_len, tp_mesh=mesh)
             return cache
 
         def chunk_final(params, tokens, cache, seq_len, last_idx,
                         temp, top_p, top_k, rng):
             """Last chunk: also project the final valid position and sample
             the first token (mirrors `prefill`'s tail)."""
-            h, cache = trunk(params, tokens, cache, seq_lens=seq_len)
+            h, cache = forward_hidden(params, cfg, tokens, cache,
+                                      seq_lens=seq_len, tp_mesh=mesh)
             h_last = jnp.take_along_axis(
                 h, last_idx[:, None, None].astype(jnp.int32), axis=1)
             last = logits_from_hidden(params, cfg, h_last)[:, 0]
@@ -735,7 +674,8 @@ class InferenceEngine:
 
         def decode_one(state: DecodeState, params):
             """Advance every slot one token."""
-            h, cache = trunk(params, state.last_token[:, None], state.cache)
+            h, cache = forward_hidden(params, cfg, state.last_token[:, None],
+                                      state.cache, tp_mesh=mesh)
             cache = cache._replace(lengths=_stay_parked(
                 state.cache.lengths, cache.lengths))
             logits = logits_from_hidden(params, cfg, h)
@@ -786,7 +726,8 @@ class InferenceEngine:
                                      axis=1)               # [B, 1+k]
             seq_lens = 1 + n_draft
             old_lengths = state.cache.lengths
-            h, cache = trunk(params, tokens, state.cache, seq_lens=seq_lens)
+            h, cache = forward_hidden(params, cfg, tokens, state.cache,
+                                      seq_lens=seq_lens, tp_mesh=mesh)
             # Head over all 1+k positions: unlike prefill's bucket-wide
             # pad, every lane here is a candidate token — and 1+k is tiny.
             logits = logits_from_hidden(params, cfg, h)    # [B, 1+k, V]
@@ -820,9 +761,8 @@ class InferenceEngine:
             # the layouts can't silently diverge (parallel/sharding.py).
             from symmetry_tpu.parallel.sharding import DEFAULT_RULES
 
-            base_rules = self._rules or DEFAULT_RULES
             cax = cache_logical_axes(quantized=self.kv_quant)
-            prefix_rules = {**base_rules, "batch": None}
+            prefix_rules = {**DEFAULT_RULES, "batch": None}
             psc = (shardings_for(cax.k_scale, self.mesh, prefix_rules)
                    if self.kv_quant else None)
             prefix_shard = KVCache(
@@ -1015,8 +955,6 @@ class InferenceEngine:
         top_ps_arr = jnp.asarray(top_ps)
         top_ks_arr = jnp.asarray(top_ks)
         decode_keys_arr = jnp.stack(decode_keys)
-        dp = self.devprof
-        t_dp = dp.begin() if dp.enabled else 0.0
         toks, prefix = self._prefill(
             self.params, jnp.asarray(padded), lens_arr, temps_arr,
             top_ps_arr, top_ks_arr, jnp.stack(prefill_keys),
@@ -1025,10 +963,6 @@ class InferenceEngine:
         # real slot with bit-identical data (same prompt AND keys above).
         self._insert(prefix, slots_arr, lens_arr, toks, temps_arr,
                      top_ps_arr, top_ks_arr, decode_keys_arr)
-        if dp.enabled:
-            # The probe covers the prefill + insert chain (device order
-            # is FIFO, so last_token ready implies both executed).
-            dp.probe("prefill", self.state.last_token, t_dp)
         # Populate the prefix cache from this batch BEFORE the buffer goes
         # back to the pool (the extract reads it; the next same-shape
         # prefill would overwrite it).
@@ -1141,14 +1075,10 @@ class InferenceEngine:
                 prefill_keys.append(pk)
                 decode_keys.append(dk)
 
-            dp = self.devprof
-            t_dp = dp.begin() if dp.enabled else 0.0
             scratch = self._prefill_scratch_for(batch, bucket)
             scratch = self._insert_from_blocks(
                 scratch, self._pool_kv, self._bucket_ids(bucket, hit.blocks),
                 jnp.int32(p))
-            if dp.enabled:
-                dp.probe("seed_gather", scratch.lengths, t_dp)
             # The gather out of the pool is dispatched (device order is
             # FIFO, so any later scatter into a since-freed block runs
             # after this read): safe to unpin now.
@@ -1158,17 +1088,12 @@ class InferenceEngine:
             top_ps_arr = jnp.asarray(top_ps)
             top_ks_arr = jnp.asarray(top_ks)
             decode_keys_arr = jnp.stack(decode_keys)
-            t_dp = dp.begin() if dp.enabled else 0.0
             toks, prefix = self._chunk_final(
                 self.params, jnp.asarray(suffix), scratch, sfx_arr,
                 sfx_arr - 1, temps_arr, top_ps_arr, top_ks_arr,
                 jnp.stack(prefill_keys))
             self._insert(prefix, slots_arr, jnp.asarray(full_lens), toks,
                          temps_arr, top_ps_arr, top_ks_arr, decode_keys_arr)
-            if dp.enabled:
-                # The cached-hit suffix dispatch is still a prefill on
-                # the device (chunk_final + insert over the seeded rows).
-                dp.probe("prefill", self.state.last_token, t_dp)
             # The finished rows hold prefix + suffix KV: extend the tree
             # with the new tail blocks BEFORE the buffer goes back to
             # the scratch pool — this is what makes turn N+1 of a
@@ -1195,23 +1120,13 @@ class InferenceEngine:
             p = PB * (len(ids) // PB)
             if p < PB:
                 continue
-            dp = self.devprof
-            t_dp = 0.0
             plan = self.prefix_index.plan_insert(ids[:p])
             if plan is None:
                 continue  # fully resident, or rejected even after LRU
             try:
                 # Inside the try: a device failure in the extract (or
                 # anywhere before commit) must abort the plan, or its
-                # pinned prefix and allocated blocks leak forever. The
-                # probe's begin() sits here too — only a path that
-                # actually dispatches may close a pending dispatch gap
-                # (a plan-None early-out closing it at a bookkeeping
-                # moment would bias gap_share low), and an exception in
-                # it must abort the plan like any other pre-commit
-                # failure.
-                if dp.enabled:
-                    t_dp = dp.begin()
+                # pinned prefix and allocated blocks leak forever.
                 row_cache = self._extract_prefix_row(
                     prefix, jnp.int32(row), jnp.int32(p))
                 bucket = row_cache.k.shape[2]
@@ -1223,8 +1138,6 @@ class InferenceEngine:
                 plan.abort()
                 raise
             plan.commit()
-            if dp.enabled:
-                dp.probe("scatter", self._pool_kv.lengths, t_dp)
             return
 
     def prefix_cache_stats(self) -> dict | None:
@@ -1361,8 +1274,6 @@ class InferenceEngine:
         p_eff = PB * (min(cov, p) // PB)
         if p_eff <= 0:
             return False
-        dp = self.devprof
-        t_dp = 0.0
         plan = self.prefix_index.plan_insert(tokens[:p_eff])
         if plan is None:
             # Fully resident (adoption by reference — the sender skipped
@@ -1376,13 +1287,8 @@ class InferenceEngine:
         # try: a failure anywhere between plan and commit (no bucket
         # fits, a frame missing its scale planes, a device transfer
         # error) must abort the plan, or its pinned matched prefix and
-        # allocated blocks leak forever. The probe's begin() sits inside
-        # for the same two reasons as _maybe_store_prefix: only a path
-        # that dispatches may close a pending dispatch gap, and an
-        # exception in it must abort the plan.
+        # allocated blocks leak forever.
         try:
-            if dp.enabled:
-                t_dp = dp.begin()
             capacity = self.bucket_for(p_eff)
             m = plan.matched_len
             k_row = np.zeros((c.num_layers, 1, capacity, c.num_kv_heads,
@@ -1423,10 +1329,6 @@ class InferenceEngine:
             plan.abort()
             raise
         plan.commit()
-        if dp.enabled:
-            # Adoption's device work: host→device row transfer + the
-            # one-dispatch pool scatter.
-            dp.probe("adopt", self._pool_kv.lengths, t_dp)
         return True
 
     # ------------------------------------------------------------------
@@ -1471,13 +1373,9 @@ class InferenceEngine:
 
             cache = self._new_prefix_cache(bucket)
             if hit is not None:
-                dp = self.devprof
-                t_dp = dp.begin() if dp.enabled else 0.0
                 cache = self._insert_from_blocks(
                     cache, self._pool_kv,
                     self._bucket_ids(bucket, hit.blocks), jnp.int32(start))
-                if dp.enabled:
-                    dp.probe("seed_gather", cache.lengths, t_dp)
                 hit.release()  # gather dispatched; blocks free to evict
                 self.prefix_index.note_reuse(1, start)
             elif self.prefix_index is not None:
@@ -1505,14 +1403,10 @@ class InferenceEngine:
         chunk = jnp.asarray(job.ids[:, c0:c0 + C])
         valid = jnp.asarray([min(C, job.suffix_len - c0)], jnp.int32)
         last = job.done_chunks == job.n_chunks - 1
-        dp = self.devprof
-        t_dp = dp.begin() if dp.enabled else 0.0
         if not last:
             job.cache = self._chunk_step(self.params, chunk, job.cache,
                                          valid)
             job.done_chunks += 1
-            if dp.enabled:
-                dp.probe("chunk", job.cache.lengths, t_dp)
             return None
         last_idx = jnp.asarray([job.suffix_len - 1 - c0], jnp.int32)
         toks, cache = self._chunk_final(
@@ -1525,8 +1419,6 @@ class InferenceEngine:
         self._insert(cache, np.asarray([job.slot], np.int32),
                      jnp.asarray([job.true_len], jnp.int32), toks,
                      job.temp, job.top_p, job.top_k, job.decode_key)
-        if dp.enabled:
-            dp.probe("chunk", self.state.last_token, t_dp)
         # The finished buffer holds the FULL prompt's KV — scatter its
         # unresident whole blocks into the pool before it is dropped.
         # Completed chunked prefills are exactly the long shared
@@ -1865,13 +1757,9 @@ class InferenceEngine:
         if draft.shape != (self.max_slots, k):
             raise EngineError(
                 f"draft shape {draft.shape} != {(self.max_slots, k)}")
-        dp = self.devprof
-        t_dp = dp.begin() if dp.enabled else 0.0
         self.state, toks, n_emit = self._verify(
             self.params, self.state, jnp.asarray(draft, jnp.int32),
             jnp.asarray(n_draft, jnp.int32), self._take_park())
-        if dp.enabled:
-            dp.probe("verify", toks, t_dp)
         return toks, n_emit
 
     def verify_step(self, draft: np.ndarray, n_draft: np.ndarray
@@ -1888,14 +1776,7 @@ class InferenceEngine:
         enqueue block N+1 and only then block on block N's tokens, so the
         host-side work (transfer, detokenize, emit) overlaps block N+1's
         device execution (SURVEY §7 hard-part 3: double-buffered token
-        fetch).
-
-        A firing symprof probe (tpu.profile_sample) deliberately syncs
-        THIS dispatch before returning — draining the pipeline is what
-        makes the following dispatch gap a true device-idle sample; the
-        1-in-N cadence bounds the serialization cost."""
-        dp = self.devprof
-        t_dp = dp.begin() if dp.enabled else 0.0
+        fetch)."""
         self.state, toks, pairs = self._dispatch_decode()
         if self._count_experts:
             # Start the [experts] count's copy to the host now: by the
@@ -1903,8 +1784,6 @@ class InferenceEngine:
             # collect_expert_pairs reads it without touching the device.
             pairs.copy_to_host_async()
             self._pairs_pending.append(pairs)
-        if dp.enabled:
-            dp.probe("decode_block", toks, t_dp)
         return toks
 
     def decode_steps(self) -> np.ndarray:
@@ -1939,8 +1818,7 @@ class InferenceEngine:
         wg = self.params["layers"]["wg"]
         self._moe_report = {
             "experts": c.num_experts, "top_k": c.num_experts_per_tok,
-            "layout": moe_layout(None if self.pipeline else self.mesh,
-                                 c.intermediate_size),
+            "layout": moe_layout(self.mesh, c.intermediate_size),
             # by tokens a dispatch: decode is one per slot; a prefill is
             # batch x bucket for every shape warm-up compiles
             "route": {"decode": moe_route(self.max_slots),
@@ -1974,8 +1852,8 @@ class InferenceEngine:
         from symmetry_tpu.models.llama import attention_paths
 
         return attention_paths(
-            self.config, self.max_seq_len,
-            None if self.pipeline else self.mesh, batch=self.max_slots,
+            self.config, self.max_seq_len, self.mesh,
+            batch=self.max_slots,
             kv_bytes=jnp.dtype(jnp.int8 if self.kv_quant
                                else self.cache_dtype).itemsize)
 
@@ -2081,10 +1959,6 @@ class InferenceEngine:
                 f"unsupported tpu.kv_quantization {tpu_cfg.kv_quantization!r}")
         quant = tpu_cfg.quantization == "int8"
 
-        # Pipeline mode (mesh stage > 1): params shard their layer dim over
-        # the stage axis instead of replicating it.
-        rules = _stage_rules(mesh)
-
         if tpu_cfg.checkpoint_path:
             from symmetry_tpu.engine.weights import (
                 load_checkpoint, load_warm_cache, save_warm_cache)
@@ -2104,7 +1978,7 @@ class InferenceEngine:
                 try:
                     warm = load_warm_cache(
                         tpu_cfg.checkpoint_path, dtype=dtype,
-                        quantize=quant, mesh=mesh, rules=rules)
+                        quantize=quant, mesh=mesh)
                 except Exception as exc:  # noqa: BLE001 — cache is advisory
                     logger.warning(f"warm cache unreadable, cold load: {exc}")
             if warm is not None:
@@ -2112,8 +1986,7 @@ class InferenceEngine:
                 logger.info("weights loaded from warm cache")
             else:
                 params, config = load_checkpoint(
-                    tpu_cfg.checkpoint_path, mesh=mesh, rules=rules,
-                    dtype=dtype)
+                    tpu_cfg.checkpoint_path, mesh=mesh, dtype=dtype)
                 if quant:
                     from symmetry_tpu.models.llama import quantize_params
 
@@ -2139,7 +2012,7 @@ class InferenceEngine:
                         quantized_logical_axes)
 
                     axes = quantized_logical_axes(axes)
-                shardings = shardings_for(axes, mesh, rules)
+                shardings = shardings_for(axes, mesh)
                 params = jax.jit(
                     lambda: init_params(config, jax.random.key(0), dtype,
                                         quantize=quant,
@@ -2160,7 +2033,6 @@ class InferenceEngine:
             cache_dtype=dtype,
             decode_block=getattr(tpu_cfg, "decode_block", 1),
             kv_quant=tpu_cfg.kv_quantization == "int8",
-            pipeline_microbatches=tpu_cfg.pipeline_microbatches,
             prefill_chunk=getattr(tpu_cfg, "prefill_chunk", 256),
             prefill_token_budget=getattr(tpu_cfg, "prefill_token_budget",
                                          None),
@@ -2178,8 +2050,6 @@ class InferenceEngine:
             speculative=SpecConfig.from_knob(
                 getattr(tpu_cfg, "speculative", None)),
             fused_dequant=bool(getattr(tpu_cfg, "fused_dequant", False)),
-            profile_sample=int(
-                getattr(tpu_cfg, "profile_sample", 0) or 0),
             # "disagg" is the BACKEND's role (it spawns a prefill and a
             # decode host, each of which sees its own tier role here);
             # an engine can only be one tier or unified.

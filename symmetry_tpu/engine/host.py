@@ -556,16 +556,11 @@ class EngineHost:
     def _handle_trace(self) -> None:
         """Span-ring snapshot: this process's host + scheduler rings,
         stamps on this process's clock (the provider adds its measured
-        offset when merging), plus the symprof DEVICE track (probed
-        per-kind device spans + dispatch gaps) when tpu.profile_sample
-        is on — the device row that renders beside the request spans."""
+        offset when merging)."""
         comps = [self.tracer.component("host")]
         trace_export = getattr(self._scheduler, "trace_export", None)
         if trace_export is not None:
             comps.append(trace_export())
-        devprof = getattr(self._engine, "devprof", None)
-        if devprof is not None and devprof.enabled:
-            comps.append(devprof.component("device"))
         self._write({"op": HostOp.TRACE, "clock": time.monotonic(),
                      "components": comps})
 

@@ -1,10 +1,10 @@
 """symledger: per-request device-time attribution and waste accounting.
 
-symprof (utils/devprof.py) prices device time per dispatch KIND; this
-module prices it per REQUEST. The scheduler apportions every dispatch's
-measured wall to the slots it served — prefill/chunk dispatches exactly
-(each dispatch names its requests), decode/verify block syncs split by
-active-slot occupancy — and each request accumulates:
+This module prices device time per REQUEST. The scheduler apportions
+every dispatch's measured wall to the slots it served — prefill/chunk
+dispatches exactly (each dispatch names its requests), decode/verify
+block syncs split by active-slot occupancy — and each request
+accumulates:
 
   device_s{phase}   attributed device seconds per phase
                     (prefill / chunk / decode / verify / adopt)
@@ -22,10 +22,9 @@ active-slot occupancy — and each request accumulates:
   saved_s           prefill seconds a radix hit avoided, priced at the
                     admitting dispatch's own per-token rate
 
-Attribution source is flagged, never guessed: "probed" when symprof
-sampling is armed (probe syncs make the dispatch walls device-true),
-"blocked" otherwise (dispatch-thread block time — an upper bound that
-includes host-side dispatch overhead). Echo backends stamp "estimated".
+Attribution source is flagged, never guessed: "blocked" — dispatch-
+thread block time, an upper bound that includes host-side dispatch
+overhead. Echo backends stamp "estimated".
 
 Threading: the engine thread opens/books/finishes entries, the emit
 worker books emit shares, and the host pipe thread reads stats() — one
@@ -215,10 +214,9 @@ class RequestLedger:
     bounded ring of finished cost blocks, and cumulative aggregates
     (per finish reason + per phase) for the host STATS rider."""
 
-    def __init__(self, *, enabled: bool = True, ring: int = 128,
-                 measured: bool = False) -> None:
+    def __init__(self, *, enabled: bool = True, ring: int = 128) -> None:
         self.enabled = bool(enabled)
-        self.source = "probed" if measured else "blocked"
+        self.source = "blocked"
         self._lock = threading.Lock()
         self._ring: deque[dict[str, Any]] = deque(maxlen=max(1, int(ring)))
         # Cumulative fleet totals: the conservation test's right-hand

@@ -420,15 +420,11 @@ class Scheduler:
         self._last_sync_done: float | None = None
         self._last_sync_kind: str | None = None
         # symledger (engine/ledger.py, tpu.ledger): per-request device-
-        # time attribution. Source flag: symprof sampling armed makes
-        # the dispatch walls probe-synced ("probed"); otherwise they are
-        # dispatch-thread block time ("blocked"). Disabled cost is one
-        # guarded branch per dispatch — track() returns None, and every
-        # booking site checks `req.ledger is not None` / ledger.enabled.
-        dp0 = getattr(engine, "devprof", None)
-        self.ledger = RequestLedger(
-            enabled=ledger_enabled,
-            measured=dp0 is not None and dp0.enabled)
+        # time attribution from the dispatch walls (dispatch-thread
+        # block time). Disabled cost is one guarded branch per dispatch
+        # — track() returns None, and every booking site checks
+        # `req.ledger is not None` / ledger.enabled.
+        self.ledger = RequestLedger(enabled=ledger_enabled)
 
     # ------------------------------------------------------------- lifecycle
 
@@ -560,19 +556,6 @@ class Scheduler:
                 if wsbd is not None:
                     out["weight_stream_gbs_per_device"] = round(
                         int(wsbd()) / step_s / 1e9, 1)
-        # symprof device-time attribution (utils/devprof.py,
-        # tpu.profile_sample): per-dispatch-kind DEVICE-duration
-        # percentiles + the dispatch-gap distribution/share, riding the
-        # same host stats op → provider engine block → bench JSON.
-        # Annotated with the pipeline depth: a probe's sync serializes
-        # behind every in-flight block, so at depth >= 2 the next gap
-        # sample measures the post-drain refill — gap_share is then an
-        # UPPER bound on true device idle, and consumers must read it
-        # against this depth (the documented accounting rule).
-        dp = getattr(self.engine, "devprof", None)
-        if dp is not None and dp.enabled:
-            out["devprof"] = dict(dp.stats())
-            out["devprof"]["pipeline_depth"] = self._depth
         # Shared-prefix KV cache counters (hit/miss/evict/bytes) ride the
         # same host stats op so they surface provider- and bench-side.
         pc_stats = getattr(self.engine, "prefix_cache_stats", None)

@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from symmetry_tpu.ops.attention import gqa_attention
 from symmetry_tpu.ops.interpret import interpret_mode
 from symmetry_tpu.ops.norm import rms_norm
-from symmetry_tpu.ops.quant import QuantizedTensor, qmatmul, quantize_tree
+from symmetry_tpu.ops.quant import (
+    QuantizedTensor, qmatmul, quantize_kv, quantize_tree)
 from symmetry_tpu.ops.rope import apply_rope
 
 
@@ -217,6 +218,47 @@ def init_cache(
     )
 
 
+def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
+             k: jnp.ndarray, v: jnp.ndarray, *, by_head: bool) -> KVCache:
+    """Scatter this call's K/V ([B, S, K, D], roped) straight into the full
+    cache at (layer, slot, position) — an in-place row write on the layer
+    scan's carry; a per-layer slice-out/slice-in would stream the whole
+    layer slice through HBM. `positions` is [B, S]; a position at or past
+    the capacity is dropped. Padded tail tokens write garbage past the
+    slot's valid length — never read, overwritten later. A quantized cache
+    takes the int8 payload plus the f32 scales (ops/quant.py quantize_kv).
+
+    `by_head` (the trunk is sharded over a mesh): a cache sharded by KV
+    head may hold 2 heads a chip, and XLA then keeps it in HBM as
+    [L, B, K, T, D] (a second-minor dim of 2 would pad 2x). A scatter
+    whose window is the [K, D] row needs K next to D, so the program
+    copied the whole cache into the padded layout around every decode
+    block (+4.3 GB a chip at 64 x 2048: it did not fit). Indexing the head
+    too leaves a window of D alone, which either layout serves in place.
+    """
+    B, S, nkv, _ = k.shape
+    b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
+    l_idx = jnp.full((B, S), layer, jnp.int32)
+    row = (l_idx, b_idx, positions)  # each write is one [K, D] row
+    if by_head:
+        row = tuple(i[..., None] for i in row) + (
+            jnp.arange(nkv, dtype=jnp.int32)[None, None, :],)
+    if not cache.quantized:
+        return cache._replace(
+            k=cache.k.at[row].set(k.astype(cache.k.dtype)),
+            v=cache.v.at[row].set(v.astype(cache.v.dtype)))
+    kq, ks = quantize_kv(k)  # ks [B, S, K]
+    vq, vs = quantize_kv(v)
+    # Scale planes are [L, B, K, T] (position minor, see KVCache): the
+    # mixed advanced/slice index puts the advanced dims (B, S) in front,
+    # matching the [B, S, K] scale values.
+    return cache._replace(
+        k=cache.k.at[row].set(kq),
+        v=cache.v.at[row].set(vq),
+        k_scale=cache.k_scale.at[l_idx, b_idx, :, positions].set(ks),
+        v_scale=cache.v_scale.at[l_idx, b_idx, :, positions].set(vs))
+
+
 # ---------------------------------------------------------------------------
 # Parameters
 
@@ -389,7 +431,6 @@ def _layer(
     prefill_flash: bool,        # static: flash self-attention (fresh cache)
     ring_mesh=None,             # static: Mesh => sequence-parallel prefill
     sp_mode: str = "ring",      # static: "ring" | "ulysses" (SURVEY §5.7)
-    kv_append_ok: bool = True,  # static: False for sharded caches (TP/PP)
     tp_mesh=None,               # static: Mesh the trunk is GSPMD-sharded over
 ) -> tuple[jnp.ndarray, KVCache]:
     B, S, E = h.shape
@@ -409,57 +450,8 @@ def _layer(
     q = apply_rope(q, positions, config.rope_theta)
     k = apply_rope(k, positions, config.rope_theta)
 
-    # Scatter the new K/V straight into the full cache at (layer, batch,
-    # position) — an in-place row write on the scan carry; a per-layer
-    # slice-out/slice-in would stream the whole layer slice through HBM.
-    # Padded tail tokens write garbage past kv_valid — never read,
-    # overwritten later. Quantized caches write int8 payload + f32 scales.
-    b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
-    l_idx = jnp.full((B, S), layer, jnp.int32)
-    row = (l_idx, b_idx, positions)  # each write is one [K, D] row
-    if tp_mesh is not None:
-        # A cache sharded by KV head may hold 2 heads a chip, and XLA then
-        # keeps it in HBM as [L, B, K, T, D] (a second-minor dim of 2
-        # would pad 2x). A scatter whose window is the [K, D] row needs K
-        # next to D, so the program copied the whole cache into the padded
-        # layout around every decode block (+4.3 GB a chip at 64 x 2048:
-        # it did not fit). Indexing the head too leaves a window of D
-        # alone, which either layout serves in place.
-        row = tuple(i[..., None] for i in row) + (
-            jnp.arange(nkv, dtype=jnp.int32)[None, None, :],)
-    if cache.quantized:
-        from symmetry_tpu.ops import kv_append as kva
-        from symmetry_tpu.ops.quant import quantize_kv
-
-        if (S == 1 and kv_append_ok
-                and kva.supports(cache.k.shape[2], D,
-                                 jax.default_backend(),
-                                 sharded=False)):
-            # Decode: one fused Pallas call quantizes and writes the row
-            # in place — the XLA path below costs ~14 kernels/layer incl.
-            # a full-plane select on the position-minor scale planes
-            # (ops/kv_append.py; round-4 decode-floor work).
-            ck, cv, ks_, vs_ = kva.kv_append(
-                cache.k, cache.v, cache.k_scale, cache.v_scale,
-                k[:, 0], v[:, 0], layer, positions[:, 0])
-            cache = cache._replace(k=ck, v=cv, k_scale=ks_, v_scale=vs_)
-        else:
-            kq, ks = quantize_kv(k)  # ks [B, S, K]
-            vq, vs = quantize_kv(v)
-            # Scale planes are [L, B, K, T] (position minor, see KVCache):
-            # the mixed advanced/slice index puts the advanced dims (B, S)
-            # in front, matching the [B, S, K] scale values.
-            cache = cache._replace(
-                k=cache.k.at[row].set(kq),
-                v=cache.v.at[row].set(vq),
-                k_scale=cache.k_scale.at[l_idx, b_idx, :, positions].set(ks),
-                v_scale=cache.v_scale.at[l_idx, b_idx, :, positions].set(vs),
-            )
-    else:
-        cache = cache._replace(
-            k=cache.k.at[row].set(k.astype(cache.k.dtype)),
-            v=cache.v.at[row].set(v.astype(cache.v.dtype)),
-        )
+    cache = write_kv(cache, layer, positions, k, v,
+                     by_head=tp_mesh is not None)
 
     if ring_mesh is not None:
         # Long-context prefill: sequence sharded over the `context` mesh
@@ -562,7 +554,6 @@ def forward_hidden(
     prefill_flash: bool = False,  # static: caller guarantees cache is empty
     ring_mesh=None,               # static: context-parallel prefill mesh
     sp_mode: str = "ring",        # static: "ring" | "ulysses"
-    kv_append_ok: bool = True,    # static: False when the cache is sharded
     tp_mesh=None,                 # static: Mesh the arrays are sharded over
 ) -> tuple[jnp.ndarray, KVCache]:
     """Decoder trunk: returns (final-norm hidden states [B, S, E], cache).
@@ -617,8 +608,7 @@ def forward_hidden(
     h, new_cache = run_layers(params["layers"], h, cache, positions,
                               kv_valid, seq_lens, config,
                               use_flash=use_flash, use_ring=use_ring,
-                              sp_mode=sp_mode, kv_append_ok=kv_append_ok,
-                              tp_mesh=tp_mesh)
+                              sp_mode=sp_mode, tp_mesh=tp_mesh)
     h = rms_norm(h, _norm_w(params["final_norm"], config), config.rms_eps)
     return h, new_cache._replace(lengths=kv_valid)
 
@@ -635,14 +625,10 @@ def run_layers(
     use_flash: bool = False,
     use_ring=None,
     sp_mode: str = "ring",
-    kv_append_ok: bool = True,
     tp_mesh=None,
 ) -> tuple[jnp.ndarray, KVCache]:
-    """Scan a stack of decoder layers over `h`. Factored out of
-    forward_hidden so pipeline parallelism (parallel/pipeline.py) can run a
-    STAGE'S local slice of layers against its local cache shard — layer
-    indices inside are local to the stack passed in, which is exactly what
-    the per-stage cache expects."""
+    """Scan a stack of decoder layers over `h`; layer indices inside are
+    local to the stack passed in, whose leading dim is the cache's."""
 
     def body(carry, xs):
         # The cache rides the CARRY, scatter-updated in place: scan xs/ys
@@ -652,8 +638,7 @@ def run_layers(
         lp, l = xs
         h, c = _layer(h, lp, c, l, positions, kv_valid,
                       seq_lens, config, use_flash, ring_mesh=use_ring,
-                      sp_mode=sp_mode, kv_append_ok=kv_append_ok,
-                      tp_mesh=tp_mesh)
+                      sp_mode=sp_mode, tp_mesh=tp_mesh)
         return (h, c), None
 
     n_layers = jax.tree.leaves(layers_params)[0].shape[0]

@@ -1,44 +1,26 @@
-"""Quantized-weight Pallas matmuls: W8A8 (measured, not routed) and the
-W8A16 fused-dequant kernel (`tpu.fused_dequant`, off by default).
+"""W8A16 fused-dequant Pallas matmul (`tpu.fused_dequant`, off by default).
 
-The regime matters (all numbers measured on this v5e, fetch-fenced,
-carry-dependent loops — tools/probe_s8_mxu.py, tools/bisect_decode.py):
+The regime (measured on this v5e before the benchmark existed; BASELINE.md
+rounds 3-4): DECODE (M ≈ slot count, ~128 rows) is bandwidth-bound, and
+the floor is the int8→bf16 CONVERT, not HBM — XLA's mixed dot
+materializes a full bf16 copy of every int8 weight before each dot
+(~480 GB/s effective vs the 740-860 a pure bf16 matmul streams).
 
-  - DECODE (M ≈ slot count, ~128 rows): bandwidth-bound, and the floor is
-    the int8→bf16 CONVERT, not HBM: XLA's mixed dot materializes a full
-    bf16 copy of every int8 weight before each dot (~480 GB/s effective
-    vs the 740-860 a pure bf16 matmul streams).
-  - W8A8 (this file's first kernel): every int8 form is convert-
-    throughput-limited; the s8×s8 kernel measured ~50% SLOWER than the
-    XLA mixed dot in the full trunk (48.5 vs 32.1 ms). Decode stays on
-    ops/quant.qmatmul's mixed dot.
-  - PREFILL (M ≥ ~256 token rows): the s8×s8 MXU tiles measure
-    ~172 TFLOP/s in ISOLATION at M=512, but routed into the real prefill
-    path the end-to-end group time is identical (165.3 vs 167.6 ms) —
-    prefill is not matmul-bound. Since W8A8 adds per-row activation-quant
-    noise for zero measured gain, it is NOT routed.
+`w8a16_matmul` keeps the weights int8 in HBM and dequantizes them TILE BY
+TILE in VMEM — the pallas_call grid pipeline double-buffers each
+weight-tile DMA against the previous tile's MXU work, so the convert
+rides inside the DMA/matmul pipeline instead of materializing a full bf16
+weight tensor per decode step. Activations stay bf16. Weights are
+PRE-PACKED into the kernel's [K/bk, N/bn, bk, bn] tile layout at load
+(ops/quant.py pack_quantized) so each grid step's DMA is one contiguous
+read. Numerics are the mixed dot's exactly: int8 values are exact in
+bf16, products accumulate in f32, the per-output-channel scale is applied
+in the epilogue — `(x @ q_bf16) * scale`, cast to the activation dtype.
 
-W8A16 (`w8a16_matmul`, the round-8 convert-wall lever) is the one form
-the rounds-3/4 study did NOT cover: weights stay int8 in HBM and are
-dequantized TILE BY TILE in VMEM — the pallas_call grid pipeline
-double-buffers each weight-tile DMA against the previous tile's MXU
-work, so the convert rides inside the DMA/matmul pipeline instead of
-materializing a full bf16 weight tensor per decode step. Activations
-stay bf16 (no per-row activation-quant noise — exactly the path the
-W8A8 negative result does not condemn). Weights are PRE-PACKED into the
-kernel's [K/bk, N/bn, bk, bn] tile layout at load (ops/quant.py
-pack_quantized) so each grid step's DMA is one contiguous read.
-Numerics are the mixed dot's exactly: int8 values are exact in bf16,
-products accumulate in f32, the per-output-channel scale is applied in
-the epilogue — `(x @ q_bf16) * scale`, cast to the activation dtype.
-
-The W8A8 kernel is kept as a correct, tested building block
-(tests/test_qmm.py pins the arithmetic against a bit-exact integer
-reference in interpret mode) and as the measurement record — a future
-TPU generation or a genuinely matmul-bound workload may flip the
-verdict. The activation is quantized dynamically per row to int8; the
-s32 tile products are rescaled in the kernel epilogue by (row
-activation scale × per-output-channel weight scale).
+A W8A8 form (activations quantized per row, s8×s8 → s32 MXU tiles) was
+measured and never routed: ~50% slower than the mixed dot in the decode
+trunk (48.5 vs 32.1 ms) and no gain at prefill (165.3 vs 167.6 ms a
+group). The record is BASELINE.md rounds 3-4; the kernel left in PR 30.
 """
 
 from __future__ import annotations
@@ -51,104 +33,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from symmetry_tpu.ops.interpret import interpret_mode
-
-# Tile sizes measured on v5e (tools/probe_s8_mxu.py, M=512): smaller bn
-# keeps more N-blocks for the grid, which generalizes better to narrow
-# layers; (512, 1024) performs comparably at wide shapes.
-BLOCK_N = 256
-BLOCK_K = 512
-MIN_ROWS = 32  # below this the MXU is mostly idle
-
-
-def _kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_scr, *, n_k: int,
-            out_dtype):
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    acc_scr[:] += jax.lax.dot_general(
-        x_ref[:], w_ref[:],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-
-    @pl.when(k == n_k - 1)
-    def _():
-        # epilogue: s32 -> f32, row scale × column scale, cast out
-        o_ref[:] = (acc_scr[:].astype(jnp.float32)
-                    * xs_ref[:] * ws_ref[:]).astype(out_dtype)
-
-
-def _pick_block(dim: int, prefer: int) -> int | None:
-    for cand in (prefer, 512, 256, 128, 64):
-        if cand <= prefer and dim % cand == 0:
-            return cand
-    return None
-
-
-def supports(m: int, k: int, n: int, backend: str) -> bool:
-    """Static gate for the w8a8 kernel (shapes tileable, MXU-worthy M)."""
-    return (backend == "tpu"
-            and m >= MIN_ROWS
-            and _pick_block(k, BLOCK_K) is not None
-            and _pick_block(n, BLOCK_N) is not None)
-
-
-def quantize_rows(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Symmetric per-row int8: x [M, K] -> (q [M, K] s8, scale [M, 1] f32)."""
-    xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
-    return q, scale
-
-
-@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
-def w8a8_matmul(
-    x: jnp.ndarray,        # [M, K] float (bf16/f32)
-    wq: jnp.ndarray,       # [K, N] int8
-    w_scale: jnp.ndarray,  # [N] f32 per-output-channel
-    *,
-    out_dtype=None,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """x @ dequant(wq) with the activation quantized per row to int8 and
-    the product computed as native s8×s8 → s32 MXU tiles."""
-    M, K = x.shape
-    Kw, N = wq.shape
-    assert K == Kw, (K, Kw)
-    out_dtype = out_dtype or x.dtype
-    bk = _pick_block(K, BLOCK_K)
-    bn = _pick_block(N, BLOCK_N)
-    if bk is None or bn is None:
-        raise ValueError(f"untileable w8a8 shape K={K} N={N}")
-    n_k = K // bk
-
-    xq, xs = quantize_rows(x)
-    ws = w_scale.astype(jnp.float32).reshape(1, N)
-
-    return pl.pallas_call(
-        functools.partial(_kernel, n_k=n_k, out_dtype=out_dtype),
-        grid=(N // bn, n_k),
-        in_specs=[
-            pl.BlockSpec((M, bk), lambda n, k: (0, k)),
-            pl.BlockSpec((bk, bn), lambda n, k: (k, n)),
-            pl.BlockSpec((M, 1), lambda n, k: (0, 0)),
-            pl.BlockSpec((1, bn), lambda n, k: (0, n)),
-        ],
-        out_specs=pl.BlockSpec((M, bn), lambda n, k: (0, n)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((M, bn), jnp.int32)],
-        interpret=interpret,
-    )(xq, wq, xs, ws)
-
-
-# ---------------------------------------------------------------------------
-# W8A16 fused-dequant matmul (tpu.fused_dequant): bf16 activations against
-# tile-packed int8 weights, dequantized in VMEM inside the DMA/matmul
-# pipeline. See the module docstring for the regime analysis; the measured
-# on-chip A/B lives in BASELINE.md and tools/probe_w8a16.py.
 
 # Tile defaults: bn/bk are the DMA granularity AND the effective double-
 # buffer depth lever (the pallas grid pipeline keeps the next (bk, bn)

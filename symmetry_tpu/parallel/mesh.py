@@ -4,8 +4,11 @@ Axis convention (ordered outer→inner so the innermost axis maps to the
 fastest interconnect — `model` collectives ride ICI, `data` may span DCN,
 per the two-tier design in SURVEY §5.8):
 
-    stage   — pipeline parallelism (parallel/pipeline.py): layer stages,
-              point-to-point activation transfers only; DCN-safe
+    stage   — no schedule uses it: the engine refuses a mesh with
+              stage > 1 (engine/engine.py). It stays in AXIS_ORDER because
+              the axis names are part of every lowered program under
+              Shardy (`sdy.mesh`); it leaves in the one mesh change that
+              decides `context` too (ROADMAP Design 6)
     data    — batch replication/sharding; DCN-safe (no per-layer collectives)
     context — sequence/ring-attention axis (long context, SURVEY §5.7)
     expert  — MoE expert parallelism (models/moe.py); ICI collectives

@@ -100,8 +100,8 @@ class HostOp:
                             # decode member's shipped-block ledger the
                             # handoff should be keyed against. The same
                             # reply is the autoscaler's sensor feed:
-                            # "queue_depth" and the symprof "devprof"
-                            # block (device_s_total) are differenced
+                            # "queue_depth" and the "ledger" rider's
+                            # device_total_s are differenced
                             # per heartbeat into the per-tier load and
                             # measured-M:N-ratio inputs of
                             # engine/disagg/autoscale.py.

@@ -73,7 +73,7 @@ class StreamChunk:
     tokens: int | None = None
     # symledger cost block (engine/ledger.py), stamped on the done
     # chunk only: device_s{phase}/queue_s/emit_s/wasted_s{reason}/
-    # saved_s as attributed by the scheduler (source "probed"/"blocked")
+    # saved_s as attributed by the scheduler (source "blocked")
     # or estimated by a proxy backend (source "estimated"). None
     # mid-stream, and None everywhere while tpu.ledger is off.
     costs: dict | None = None

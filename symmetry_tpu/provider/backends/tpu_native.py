@@ -1002,9 +1002,9 @@ class TpuNativeBackend(InferenceBackend):
                      if self._slo_monitor is not None else None)
             burn = (max(burns.values(), default=0.0)
                     if burns is not None else None)
-            # symprof's measured per-tier device cost: each member's
-            # devprof.device_s_total rider, differenced per heartbeat —
-            # the autoscaler's M:N ratio signal.
+            # Per-tier device cost: each member's ledger.device_total_s
+            # rider (the dispatch walls the scheduler books), differenced
+            # per heartbeat — the autoscaler's M:N ratio signal.
             busy = {"prefill": 0.0, "decode": 0.0}
             for m, msg in zip(decode, replies[:len(decode)]):
                 if isinstance(msg, dict):
@@ -1046,14 +1046,15 @@ class TpuNativeBackend(InferenceBackend):
 
     def _busy_delta(self, member_id: str, msg: dict) -> float:
         """One member's device-busy seconds since its last heartbeat,
-        from the symprof stats rider (devprof.device_s_total, present
-        when tpu.profile_sample > 0). A counter that went backwards is
-        a host restart — the new life's total IS the delta."""
-        dp = msg.get("devprof")
-        if not isinstance(dp, dict):
+        from the `ledger` rider of its stats reply (device_total_s:
+        the dispatch walls engine/ledger.py books; absent while
+        tpu.ledger is off). A counter that went backwards is a host
+        restart — the new life's total IS the delta."""
+        led = msg.get("ledger")
+        if not isinstance(led, dict):
             return 0.0
         try:
-            total = float(dp.get("device_s_total") or 0.0)
+            total = float(led.get("device_total_s") or 0.0)
         except (TypeError, ValueError):
             return 0.0
         prev = self._prev_busy.get(member_id)

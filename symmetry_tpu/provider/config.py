@@ -141,17 +141,6 @@ class TpuConfig:
     # service time instead of growing with the backlog. 0 disables
     # queueing (shed the moment every slot is busy).
     max_queue: int | None = None
-    # symprof device-time attribution (utils/devprof.py): every Nth
-    # engine dispatch of each kind (prefill/chunk/decode_block/verify/
-    # adopt/seed_gather/scatter) is completion-probed — timestamped
-    # block_until_ready — yielding per-kind DEVICE-duration histograms
-    # and the dispatch-gap series (host idle between device blocks, the
-    # rounds-3/4 steady-wire suspect) in stats/metrics/the Perfetto
-    # device track. 0 (default) disables: one branch per dispatch,
-    # CI-asserted like the metrics registry. Sampling serializes 1
-    # dispatch in N, so keep N large enough that tok/s stays within 1%
-    # (BASELINE.md Round 15 pre-registers the A/B).
-    profile_sample: int = 0
     # Request-scoped tracing (utils/trace.py): bounded span/counter rings
     # in the scheduler and host, read through the host-pipe `trace` op and
     # exported as a Perfetto timeline (provider `trace` op, bench.py
@@ -229,7 +218,7 @@ class TpuConfig:
     disagg: dict[str, Any] | None = None
     # SLO-goodput autoscaler for the elastic disagg pool
     # (engine/disagg/autoscale.py): a controller tick inside the pool
-    # heartbeat turns SLO burn rates + queue gauges + symprof's measured
+    # heartbeat turns SLO burn rates + queue gauges + the ledger's
     # per-tier device cost into real membership ops (spawn / drain /
     # rebalance the M×N shape). None (default) → the pool shape stays
     # whatever `disagg.pool` declared. Keys (all optional):
@@ -266,7 +255,6 @@ class TpuConfig:
     #   spawn_timeout_s: float = 600 respawn must reach ready within this
     #   stop_grace_s: float = 30     shutdown drain before SIGKILL
     supervisor: dict[str, Any] | None = None
-    pipeline_microbatches: int = 1     # GPipe microbatches (mesh stage > 1)
     checkpoint_path: str | None = None  # HF safetensors dir; None → random init
     # Cache the finished (stacked/transposed/quantized) param tree beside
     # the checkpoint on first load; restarts skip the whole conversion

@@ -105,23 +105,11 @@ class MetricName:
     # Overlapped-pipeline split (tpu.pipeline_depth): wall the dispatch
     # thread spends per non-idle loop iteration vs wall the emit worker
     # spends delivering the offloaded per-block work, plus the live
-    # in-flight block count between iterations. dispatch_thread -> ~the
-    # bare dispatch cost is the CPU-verifiable proxy for
-    # sym_dispatch_gap_share -> ~0 on the chip.
+    # in-flight block count between iterations.
     SCHED_DISPATCH_THREAD = "sym_sched_dispatch_thread_s"
     SCHED_OFFLOADED = "sym_sched_offloaded_s"
     SCHED_PIPELINE_DEPTH = "sym_sched_pipeline_depth"
 
-    # --- symprof device-time attribution (utils/devprof.py; lives in
-    #     the host process beside the engine, tier-labeled through the
-    #     HostOp.METRICS probe). Device durations come from sampling
-    #     completion probes (`tpu.profile_sample`); the dispatch gap is
-    #     host idle between a probed device completion and the next
-    #     dispatch — the steady-wire suspect, measured on-device.
-    DEVICE_DISPATCH = "sym_device_dispatch_seconds"          # {kind}
-    DEVICE_PROBES = "sym_device_probes_total"                # {kind}
-    DISPATCH_GAP = "sym_dispatch_gap_seconds"
-    DISPATCH_GAP_SHARE = "sym_dispatch_gap_share"
     # On-demand jax.profiler captures (provider wire op / SIGUSR1 / SLO
     # burn hook → HostOp.PROFILE), booked by the provider per trigger.
     PROFILE_CAPTURES = "sym_profile_captures_total"          # {reason}

@@ -401,6 +401,50 @@ async def _collect_stream(backend, content, max_tokens=4):
     return "".join(text)
 
 
+class TestBusyDelta:
+    """TpuNativeBackend._busy_delta: a member's device-busy seconds since
+    its last heartbeat, from the `ledger` rider of its stats reply — the
+    autoscaler's M:N ratio signal (fed to tick() as busy_delta_s)."""
+
+    def _backend(self):
+        return _autoscale_backend({"prefill": 1, "decode": 1}, {})
+
+    def test_first_reading_is_the_whole_total(self):
+        b = self._backend()
+        assert b._busy_delta("d0", {"ledger": {"device_total_s": 1.5}}) \
+            == 1.5
+
+    def test_growth_is_differenced_per_member(self):
+        b = self._backend()
+        b._busy_delta("d0", {"ledger": {"device_total_s": 1.5}})
+        b._busy_delta("p0", {"ledger": {"device_total_s": 10.0}})
+        assert b._busy_delta(
+            "d0", {"ledger": {"device_total_s": 2.25}}) == 0.75
+        assert b._busy_delta(
+            "p0", {"ledger": {"device_total_s": 10.0}}) == 0.0
+
+    def test_a_counter_that_went_backwards_is_a_new_life(self):
+        b = self._backend()
+        b._busy_delta("d0", {"ledger": {"device_total_s": 8.0}})
+        # the host restarted: the new life's total IS the delta
+        assert b._busy_delta(
+            "d0", {"ledger": {"device_total_s": 0.5}}) == 0.5
+        assert b._busy_delta(
+            "d0", {"ledger": {"device_total_s": 0.75}}) == 0.25
+
+    def test_a_missing_or_malformed_rider_reads_zero(self):
+        b = self._backend()
+        b._busy_delta("d0", {"ledger": {"device_total_s": 3.0}})
+        for msg in ({}, {"ledger": None}, {"ledger": "on"},
+                    {"ledger": {"device_total_s": "n/a"}},
+                    # the rider the signal used to come from
+                    {"devprof": {"device_s_total": 9.0}}):
+            assert b._busy_delta("d0", msg) == 0.0
+        # and the member's last good reading still stands
+        assert b._busy_delta(
+            "d0", {"ledger": {"device_total_s": 3.5}}) == 0.5
+
+
 class TestAutoscaleBackendFake:
     def test_burn_spike_spawns_member_with_zero_sheds(self):
         async def main():

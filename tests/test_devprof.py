@@ -1,20 +1,12 @@
-"""symprof (utils/devprof.py) + benchdiff (tools/benchdiff.py) tests.
+"""benchdiff (tools/benchdiff.py), bench.py's result stamp and the
+on-demand device profile (utils/devprof.py capture_device_profile).
 
-Three layers, matching the PR's contract:
-
-  - DeviceProfiler unit behavior: the 1-in-N cadence, the
-    probed-completion → next-begin gap pairing, stats/gap-share shapes,
-    the Perfetto device component, and the DISABLED-mode overhead guard
-    (one branch per dispatch, same discipline as the metrics registry
-    and the fault injector).
-  - Engine integration: a tiny engine with profile_sample on books
-    per-kind device durations through real dispatches, and the
-    scheduler's stats() carries the devprof block; profile_sample=0
-    books nothing and compiles no extra anything.
   - benchdiff verdict logic: direction/min-effect policies, IQR noise
     bands over a baseline series, the config-fingerprint refusal, exit
     codes, and the markdown table — plus bench.stamp_result fingerprint
     stability (same config → same stamp; any knob change → different).
+  - capture_device_profile writes a real trace directory and refuses a
+    concurrent capture.
 """
 
 import json
@@ -24,182 +16,11 @@ import time
 
 import pytest
 
-from symmetry_tpu.utils.devprof import DISPATCH_KINDS, DeviceProfiler
-from symmetry_tpu.utils.metrics import METRICS
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from tools.benchdiff import compare, flatten, policy_for  # noqa: E402
 from tools.benchdiff import main as benchdiff_main  # noqa: E402
-
-
-class TestDeviceProfiler:
-    def test_disabled_is_inert(self):
-        dp = DeviceProfiler(0)
-        assert not dp.enabled
-        # The engine never calls begin/probe with the knob off (the
-        # `if dp.enabled` guard is the contract), but even direct calls
-        # must not blow up or book anything real.
-        dp.probe("decode_block", None, 0.0)
-        assert dp.stats()["probes"] == {}
-        assert dp.gap_share() is None
-
-    def test_disabled_mode_overhead_guard(self):
-        """The off-mode cost the engine pays per dispatch is ONE
-        attribute load + branch (`if dp.enabled:`). Same bound
-        discipline as the metrics registry's disabled mode: 200k
-        guarded dispatch sites must stay far under the time one real
-        dispatch costs."""
-        dp = DeviceProfiler(0)
-        t0 = time.perf_counter()
-        acc = 0.0
-        for _ in range(200_000):
-            if dp.enabled:  # the exact engine-side guard shape
-                acc += dp.begin()
-        dt = time.perf_counter() - t0
-        assert acc == 0.0
-        assert dt < 0.5, f"disabled-mode: {dt:.3f}s for 200k guards"
-        # ~an engine dispatch is >= 100 us even on CPU; the guard must
-        # be noise beside it (one guard < 0.1% of 100 us).
-        assert (dt / 200_000) < 1e-7 * 100
-
-    def test_cadence_probes_one_in_n_per_kind(self):
-        """The cadence is per KIND: a rare kind interleaved with a
-        frequent one must still get its 1-in-N probes instead of the
-        frequent kind absorbing every slot of a shared counter."""
-        dp = DeviceProfiler(4)
-        for _ in range(12):
-            t0 = dp.begin()
-            dp.probe("decode_block", 1.23, t0)  # plain float: pytree leaf
-        for _ in range(4):
-            t0 = dp.begin()
-            dp.probe("prefill", 1.23, t0)
-        stats = dp.stats()
-        assert stats["dispatches"] == {"decode_block": 12, "prefill": 4}
-        assert stats["probes"] == {"decode_block": 3, "prefill": 1}
-        assert stats["device_s"]["decode_block"]["count"] == 3
-        assert stats["device_s"]["prefill"]["count"] == 1
-
-    def test_gap_pairs_probe_with_next_begin(self):
-        dp = DeviceProfiler(1)
-        t0 = dp.begin()
-        dp.probe("prefill", 0.0, t0)
-        assert dp.stats()["dispatch_gap_s"]["count"] == 0  # not yet
-        time.sleep(0.01)
-        dp.begin()  # closes the pending gap
-        stats = dp.stats()
-        assert stats["dispatch_gap_s"]["count"] == 1
-        assert stats["dispatch_gap_s"]["p50"] >= 0.008
-        share = dp.gap_share()
-        assert share is not None and 0.0 < share <= 1.0
-        # begin() without a pending probe adds NO gap (an unprobed
-        # dispatch's completion time is unknown — no fabricated idle).
-        dp.begin()
-        dp.begin()
-        assert dp.stats()["dispatch_gap_s"]["count"] == 1
-
-    def test_probe_failure_never_raises(self):
-        class Boom:
-            def __jax_array__(self):  # pragma: no cover — never reached
-                raise RuntimeError("nope")
-
-        dp = DeviceProfiler(1)
-        t0 = dp.begin()
-        # block_until_ready on a non-pytree-of-arrays may raise inside
-        # jax; the probe must swallow it — diagnostics never fail work.
-        dp.probe("verify", object(), t0)
-        assert True  # reaching here IS the assertion
-
-    def test_component_is_perfetto_ready(self):
-        from symmetry_tpu.utils.trace import export_perfetto
-
-        dp = DeviceProfiler(1)
-        for kind in ("prefill", "decode_block"):
-            t0 = dp.begin()
-            dp.probe(kind, 7.0, t0)
-        dp.begin()
-        comp = dp.component("device")
-        assert comp["name"] == "device"
-        perfetto = export_perfetto([comp])
-        names = {e["name"] for e in perfetto["traceEvents"]
-                 if e.get("ph") == "X"}
-        assert {"prefill", "decode_block", "dispatch_gap"} <= names
-        assert all(e["ts"] >= 0 for e in perfetto["traceEvents"]
-                   if e.get("ph") == "X")
-
-    def test_metrics_families_emitted(self):
-        from symmetry_tpu.utils.metrics import MetricName
-
-        dp = DeviceProfiler(1)
-        t0 = dp.begin()
-        dp.probe("decode_block", 0.5, t0)
-        dp.begin()
-        snap = METRICS.snapshot(compact=True)["families"]
-        assert MetricName.DEVICE_DISPATCH in snap
-        assert MetricName.DEVICE_PROBES in snap
-        assert MetricName.DISPATCH_GAP in snap
-        assert MetricName.DISPATCH_GAP_SHARE in snap
-        probes = snap[MetricName.DEVICE_PROBES]["series"]
-        assert any(s["labels"].get("kind") == "decode_block"
-                   for s in probes)
-
-    def test_kind_vocabulary_documented(self):
-        # The engine's hook kinds and the documented set must agree —
-        # the smoke asserts per-kind slices by these names.
-        assert set(DISPATCH_KINDS) == {
-            "prefill", "chunk", "decode_block", "verify", "adopt",
-            "seed_gather", "scatter"}
-
-
-class TestEngineIntegration:
-    @pytest.fixture(scope="class")
-    def engine_mod(self):
-        import jax
-        import jax.numpy as jnp
-
-        from symmetry_tpu.engine.engine import InferenceEngine
-        from symmetry_tpu.engine.tokenizer import ByteTokenizer
-        from symmetry_tpu.models import init_params, preset
-
-        cfg = preset("tiny")
-        params = init_params(cfg, jax.random.key(0), jnp.float32)
-        return cfg, params, InferenceEngine, ByteTokenizer, jnp
-
-    def test_probed_engine_books_kinds_and_gaps(self, engine_mod):
-        from symmetry_tpu.engine.engine import SamplingParams
-
-        cfg, params, InferenceEngine, ByteTokenizer, jnp = engine_mod
-        engine = InferenceEngine(
-            cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=64,
-            prefill_buckets=(16,), cache_dtype=jnp.float32,
-            decode_block=2, profile_sample=1)
-        engine.warmup()
-        engine.prefill_and_insert(0, list(b"hello"), SamplingParams())
-        for _ in range(3):
-            engine.decode_steps()
-        stats = engine.devprof.stats()
-        assert stats["probes"].get("prefill", 0) >= 1
-        assert stats["probes"].get("decode_block", 0) >= 3
-        assert stats["device_s"]["decode_block"]["p50"] is not None
-        assert stats["dispatch_gap_s"]["count"] >= 1
-        assert stats["gap_share"] is not None
-
-    def test_scheduler_stats_carry_devprof_block(self, engine_mod):
-        from symmetry_tpu.engine.scheduler import Scheduler
-
-        cfg, params, InferenceEngine, ByteTokenizer, jnp = engine_mod
-        engine = InferenceEngine(
-            cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=64,
-            prefill_buckets=(16,), cache_dtype=jnp.float32,
-            decode_block=2, profile_sample=1)
-        sched = Scheduler(engine)
-        assert "devprof" in sched.stats()
-        off = InferenceEngine(
-            cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=64,
-            prefill_buckets=(16,), cache_dtype=jnp.float32,
-            decode_block=2)
-        assert "devprof" not in Scheduler(off).stats()
 
 
 class TestBenchStamp:
@@ -241,7 +62,6 @@ class TestBenchdiff:
         assert policy_for("value") == ("higher", 0.03)
         assert policy_for("ttft_p50_s")[0] == "lower"
         assert policy_for("engine.decode_step_ms")[0] == "lower"
-        assert policy_for("devprof.gap_share")[0] == "lower"
         assert policy_for("shared_prefix.ttft_p50_cached_s")[0] == "lower"
         assert policy_for("tokens_streamed") is None  # workload-sized
 

@@ -709,3 +709,25 @@ class TestPrefillScratchPool:
             got1.append(int(toks[0]))
             got2.append(int(toks[1]))
         assert got1 == want1 and got2 == want2
+
+
+def test_a_mesh_with_a_stage_axis_is_refused_at_engine_build(setup):
+    """No stage schedule exists: a mesh with stage > 1 is refused with the
+    axis named — by the constructor, which from_tpu_config ends in — not
+    served as replication."""
+    from symmetry_tpu.parallel.mesh import MeshSpec, build_mesh
+    from symmetry_tpu.provider.config import TpuConfig
+
+    cfg, params = setup
+    mesh = build_mesh(MeshSpec(stage=2), jax.devices()[:2])
+    with pytest.raises(EngineError, match="'stage'.*no stage schedule"):
+        InferenceEngine(cfg, params, ByteTokenizer(), mesh=mesh)
+    with pytest.raises(EngineError, match="'stage'.*no stage schedule"):
+        InferenceEngine.from_tpu_config(
+            TpuConfig(model_preset="tiny", mesh={"stage": 2, "model": 1}))
+    # the axis at size 1 is every other mesh: accepted
+    InferenceEngine(cfg, params, ByteTokenizer(), max_slots=2,
+                    max_seq_len=64, prefill_buckets=(16,),
+                    cache_dtype=jnp.float32,
+                    mesh=build_mesh(MeshSpec(stage=1), jax.devices()[:1]))
+

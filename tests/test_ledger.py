@@ -27,6 +27,7 @@ stats().
 import time
 
 import numpy as np
+import pytest
 
 from symmetry_tpu.engine.engine import SamplingParams
 from symmetry_tpu.engine.ledger import LedgerEntry, RequestLedger
@@ -390,9 +391,14 @@ class TestLedgerUnit:
         assert led.stats()["emit_s"] == 0.1
         assert led.stats()["ring"][-1]["device_total_s"] == 0.0
 
-    def test_measured_flag_sets_probed_source(self):
-        assert RequestLedger(measured=True).source == "probed"
-        assert RequestLedger(measured=False).source == "blocked"
+    def test_source_is_the_dispatch_walls(self):
+        """One source: dispatch-thread block time. The rider keeps the
+        field so the wire shape stands."""
+        led = RequestLedger()
+        assert led.source == "blocked"
+        assert led.stats()["source"] == "blocked"
+        with pytest.raises(TypeError):
+            RequestLedger(measured=True)
 
     def test_unattributed_counts_toward_conservation(self):
         led = RequestLedger()

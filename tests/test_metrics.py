@@ -506,10 +506,9 @@ class TestSymtop:
         table = symtop.render_table(rows)
         assert "prov-a" in table and "decode" in table
 
-    def test_gap_and_depth_columns(self):
-        """Tier sub-rows carry the dispatch-gap share (rendered as a
-        percentage) and the live pipeline depth — the two numbers the
-        overlapped scheduler is judged by, readable off the live table."""
+    def test_depth_column(self):
+        """Tier sub-rows carry the live pipeline depth, readable off the
+        live table; the dispatch-gap column left with its gauge."""
         import tools.symtop as symtop
 
         r = MetricsRegistry()
@@ -517,7 +516,6 @@ class TestSymtop:
         r.gauge(MetricName.PROVIDER_UPTIME, "u").set(10.0)
         sched = MetricsRegistry()
         sched.gauge(MetricName.SCHED_OCCUPANCY, "o").set(2)
-        sched.gauge(MetricName.DISPATCH_GAP_SHARE, "g").set(0.07)
         sched.gauge(MetricName.SCHED_PIPELINE_DEPTH, "d").set(2)
         fams = symtop.families_from_snapshots([
             {"snapshot": r.snapshot(compact=True), "labels": {}},
@@ -525,15 +523,14 @@ class TestSymtop:
              "labels": {"tier": "decode"}},
         ])
         rows = symtop.build_rows("prov-a", fams, None, now=0.0)
-        assert rows[0].get("gap") is None       # provider row: engine-only
+        assert rows[0].get("depth") is None     # provider row: engine-only
         tier = rows[1]
-        assert tier["gap"] == "7%"
+        assert "gap" not in tier
         assert tier["depth"] == 2
         rows[0].pop("_sample", None)
         table = symtop.render_table(rows)
         header = table.splitlines()[0]
-        assert "GAP%" in header and "DEPTH" in header
-        assert "7%" in table
+        assert "DEPTH" in header and "GAP%" not in header
 
     def test_rate_from_previous_sample(self):
         import tools.symtop as symtop

@@ -270,7 +270,7 @@ def test_model4_on_four_devices_matches_the_reference_and_unsharded(
                                              count_experts=True)))
     got, got_cache = prefill_then_decode(
         sharded, cfg, tokens, 17, jax.device_put(cache(), cache_shard),
-        kv_append_ok=False, tp_mesh=mesh)
+        tp_mesh=mesh)
     np.testing.assert_allclose(got, plain, atol=2e-4, rtol=0)
     np.testing.assert_array_equal(np.asarray(got_cache.expert_pairs),
                                   np.asarray(plain_cache.expert_pairs))

@@ -86,11 +86,10 @@ POLICIES: list[tuple[re.Pattern, str, float]] = [
     (re.compile(r"inter_chunk_gap_p\d+_s$"), "lower", 0.15),
     (re.compile(r"decode_step_ms$"), "lower", 0.05),
     (re.compile(r"prefill_s_per_slot$"), "lower", 0.10),
-    (re.compile(r"gap_share$"), "lower", 0.15),
     # Dispatch-thread wall per scheduler iteration (the pipelined-
     # scheduler target metric): host time the dispatch thread spends
     # per block after emit/bookkeep moved off-thread. Noisy like any
-    # host-side latency — same band as the gap share it pairs with.
+    # host-side latency.
     (re.compile(r"dispatch_thread_block_s\.(p50|p99)$"), "lower", 0.15),
     (re.compile(r"recovery_[a-z0-9_]*s$"), "lower", 0.15),
     (re.compile(r"wasted_tokens$"), "lower", 0.15),

@@ -126,13 +126,12 @@ def main() -> int:
         h, cache = llama.forward_hidden(
             params, cfg, toks, cache,
             seq_lens=jnp.full((n,), s, jnp.int32), prefill_flash=True,
-            kv_append_ok=mesh is None, tp_mesh=mesh)
+            tp_mesh=mesh)
         return llama.logits_from_hidden(params, cfg,
                                         h[:, s - args.last:]), cache
 
     def step(params, tok, cache):
         h, cache = llama.forward_hidden(params, cfg, tok, cache,
-                                        kv_append_ok=mesh is None,
                                         tp_mesh=mesh)
         return llama.logits_from_hidden(params, cfg, h), cache
 

@@ -49,10 +49,10 @@ from symmetry_tpu.utils.metrics import (  # noqa: E402
 )
 
 COLUMNS = ("PROVIDER", "TIER", "TOK/S", "TTFT p50", "TTFT p99",
-           "QUEUE", "INFL", "OCC", "GAP%", "DEPTH", "SHED", "RESUME",
+           "QUEUE", "INFL", "OCC", "DEPTH", "SHED", "RESUME",
            "WASTED", "REUSED", "DUMPS", "COST", "WASTE%", "GPUT",
            "LINK", "STATE", "SHARE", "HIT", "TARGET", "SCALE")
-WIDTHS = (22, 10, 9, 9, 9, 7, 6, 5, 5, 5, 7, 7, 7, 7, 6, 7, 6, 7, 6,
+WIDTHS = (22, 10, 9, 9, 9, 7, 6, 5, 5, 7, 7, 7, 7, 6, 7, 6, 7, 6,
           9, 6, 6, 9, 6)
 
 # sym_pool_member_state gauge encoding (engine/disagg/pool.py
@@ -323,14 +323,6 @@ def build_rows(name: str, fams: dict,
             "queue": _value(fams, "sym_sched_queue_depth", tier=tier),
             "in_flight": None,
             "occupancy": _value(fams, "sym_sched_occupancy", tier=tier),
-            # Dispatch-gap share (devprof, tier-labeled gauge): fraction
-            # of on-device wall the accelerator sat idle between
-            # dispatches — THE number the pipelined scheduler drives
-            # toward zero. At pipeline depth >= 2 the probe's sync
-            # serializes behind every in-flight block, so this reads as
-            # an UPPER bound (scheduler stats() carries the same note).
-            "gap": _fmt_pct(_value(fams, "sym_dispatch_gap_share",
-                                   tier=tier)),
             # Live pipeline depth (blocks in flight after the last
             # scheduler iteration): 0 = idle tier, steady < configured
             # depth = the pipeline never fills (admission-bound).
@@ -372,7 +364,7 @@ def render_table(rows: list[dict[str, Any]]) -> str:
     for r in rows:
         cells = (r["provider"], r["tier"] or "-", r["tok_s"],
                  r["ttft_p50"], r["ttft_p99"], r["queue"], r["in_flight"],
-                 r["occupancy"], r.get("gap"), r.get("depth"),
+                 r["occupancy"], r.get("depth"),
                  r["shed"], r.get("resume"),
                  r.get("wasted"), r.get("reused"), r.get("dumps"),
                  r.get("cost"), r.get("waste") or "-", r.get("gput"),
