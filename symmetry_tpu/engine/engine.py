@@ -831,6 +831,24 @@ class InferenceEngine:
         # Scalar key program, mesh-independent (keys are replicated).
         self._rng_resume = jax.jit(rng_resume)
 
+        def derive_keys(base_key, counters, seeds, seeded):
+            """What _request_keys does for one request (without a resume
+            skip), for a whole prefill batch in one dispatch: the
+            request's own seed, or its counter folded into the base key,
+            then the split into (prefill, decode)."""
+            def one(counter, seed, is_seeded):
+                data = jnp.where(
+                    is_seeded,
+                    jax.random.key_data(jax.random.key(seed)),
+                    jax.random.key_data(
+                        jax.random.fold_in(base_key, counter)))
+                return jax.random.split(jax.random.wrap_key_data(data))
+
+            pairs = jax.vmap(one)(counters, seeds, seeded)
+            return pairs[:, 0], pairs[:, 1]
+
+        self._derive_keys = jax.jit(derive_keys)
+
     # ------------------------------------------------------------------
     # Host-side API (called by the scheduler's engine thread)
 
@@ -890,9 +908,54 @@ class InferenceEngine:
         pk, dk = jax.random.split(key)
         return pk, dk
 
+    def _group_keys(self, samplings: list[SamplingParams], batch: int
+                    ) -> tuple[jax.Array, jax.Array]:
+        """(prefill keys, decode keys), [batch] each, for one coalesced
+        admission dispatch: row i is _request_keys(samplings[i]), rows
+        past the group replay the last request's (pad rows must be exact
+        overwrites). ONE device dispatch for the whole batch: one at a
+        time each request costs half a dozen tiny programs (key or
+        fold_in, split, two index ops, its share of two stacks), and
+        past a few dozen computations in flight the runtime makes the
+        dispatching thread wait for the program at the head of the
+        queue — the decode block admission is meant not to wait for.
+        Only a resumed seeded request (rng_skip) is walked on its own."""
+        n_req = len(samplings)
+        counters = np.zeros((batch,), np.int32)
+        seeds = np.zeros((batch,), np.int64)
+        seeded = np.zeros((batch,), bool)
+        resumed: dict[int, tuple[Any, Any]] = {}
+        for i, sampling in enumerate(samplings):
+            if sampling.seed is None:
+                self._requests_served += 1
+                counters[i] = self._requests_served
+                continue
+            seeds[i], seeded[i] = sampling.seed, True
+            if sampling.rng_skip:
+                resumed[i] = self._request_keys(sampling)
+        for arr in (counters, seeds, seeded):
+            arr[n_req:] = arr[n_req - 1]
+        # int64 -> int32 wraps like jax.random.key's own conversion of a
+        # Python int does without x64.
+        prefill_keys, decode_keys = self._derive_keys(
+            self._base_key, counters, seeds.astype(np.int32), seeded)
+        for i, (pk, dk) in resumed.items():
+            rows = slice(i, batch if i == n_req - 1 else i + 1)
+            prefill_keys = prefill_keys.at[rows].set(pk)
+            decode_keys = decode_keys.at[rows].set(dk)
+        return prefill_keys, decode_keys
+
+    # Every admission path comes in two forms. The DISPATCH form enqueues
+    # its programs (prefill or final chunk, then insert) and returns the
+    # sampled first tokens as the device array, unread: the scheduler
+    # reads it when it reaches the entry in its in-flight queue, in
+    # device order, so the engine thread never waits inside admission.
+    # The plain form is "dispatch, then read" for every other caller.
+
     def prefill_and_insert(self, slot: int, prompt_ids: list[int],
                            sampling: SamplingParams) -> int:
-        """Prefill a prompt and install it in `slot`; returns first token."""
+        """Prefill a prompt and install it in `slot`; returns first token
+        (waits for the device)."""
         return self.prefill_and_insert_many(
             [(slot, prompt_ids, sampling)])[0]
 
@@ -901,23 +964,41 @@ class InferenceEngine:
     ) -> list[int]:
         """Prefill several prompts in as few device dispatches as the
         bucket's batch budget allows and install each in its slot; returns
-        their first tokens. Coalescing matters because each dispatch pays
-        a host↔device round-trip: admitting a burst of arrivals one-by-one
-        serializes that cost into the last request's TTFT (SURVEY §7
-        hard-part 3). A group wider than the bucket's largest allowed
-        batch is split into consecutive dispatches."""
+        their first tokens (waits for the device). Coalescing matters
+        because each dispatch pays a host↔device round-trip: admitting a
+        burst of arrivals one-by-one serializes that cost into the last
+        request's TTFT (SURVEY §7 hard-part 3). A group wider than the
+        bucket's largest allowed batch is split into consecutive
+        dispatches."""
         if not assignments:
             return []
+        if any(len(ids) == 0 for _, ids, _ in assignments):
+            raise EngineError("empty prompt")
+        bucket = max(self.bucket_for(len(ids)) for _, ids, _ in assignments)
+        cap = self.prefill_batches_for(bucket)[-1]
+        firsts: list[int] = []
+        for start in range(0, len(assignments), cap):
+            part = assignments[start:start + cap]
+            toks = np.asarray(self.prefill_and_insert_many_dispatch(part))
+            firsts.extend(int(tok) for tok in toks[:len(part)])
+        return firsts
+
+    def prefill_and_insert_many_dispatch(
+        self, assignments: list[tuple[int, list[int], SamplingParams]],
+    ) -> jax.Array:
+        """Dispatch ONE coalesced prefill and the insert that installs
+        every row in its slot; returns the first tokens [batch] on the
+        device, unread — row i is assignments[i]'s, rows past the group
+        are padding. The group must fit the bucket's largest batch."""
         if any(len(ids) == 0 for _, ids, _ in assignments):
             raise EngineError("empty prompt")
         n_req = len(assignments)
         bucket = max(self.bucket_for(len(ids)) for _, ids, _ in assignments)
         allowed = self.prefill_batches_for(bucket)
-        if n_req > allowed[-1]:
-            return [tok
-                    for start in range(0, n_req, allowed[-1])
-                    for tok in self.prefill_and_insert_many(
-                        assignments[start:start + allowed[-1]])]
+        if not 0 < n_req <= allowed[-1]:
+            raise EngineError(
+                f"prefill group of {n_req} outside the bucket's batch cap "
+                f"{allowed[-1]} (the caller partitions to cap)")
         batch = next(b for b in allowed if b >= n_req)
 
         padded = np.zeros((batch, bucket), np.int32)
@@ -925,16 +1006,15 @@ class InferenceEngine:
         temps = np.zeros((batch,), np.float32)
         top_ps = np.ones((batch,), np.float32)
         top_ks = np.zeros((batch,), np.int32)
-        prefill_keys, decode_keys = [], []
         slots_arr = np.zeros((batch,), np.int32)
         for i in range(batch):
             # Pad rows replay the last request BIT-IDENTICALLY — same
-            # prompt, same slot, and (below) the same PRNG keys. They are
-            # inserted (insert_all covers every row), so anything short of
-            # an identical overwrite would corrupt the last real slot's
-            # state: a pad row with fresh entropy would sample a DIFFERENT
-            # first token and leave decode conditioned on a token the
-            # client never saw.
+            # prompt, same slot, and (_group_keys) the same PRNG keys.
+            # They are inserted (insert_all covers every row), so anything
+            # short of an identical overwrite would corrupt the last real
+            # slot's state: a pad row with fresh entropy would sample a
+            # DIFFERENT first token and leave decode conditioned on a
+            # token the client never saw.
             slot, ids, sampling = assignments[min(i, n_req - 1)]
             slots_arr[i] = slot
             padded[i, :len(ids)] = ids
@@ -942,22 +1022,16 @@ class InferenceEngine:
             temps[i] = sampling.temperature
             top_ps[i] = sampling.top_p
             top_ks[i] = sampling.top_k
-            if i >= n_req:
-                prefill_keys.append(prefill_keys[n_req - 1])
-                decode_keys.append(decode_keys[n_req - 1])
-                continue
-            pk, dk = self._request_keys(sampling)
-            prefill_keys.append(pk)
-            decode_keys.append(dk)
+        prefill_keys, decode_keys_arr = self._group_keys(
+            [sampling for _, _, sampling in assignments], batch)
 
         lens_arr = jnp.asarray(lens)
         temps_arr = jnp.asarray(temps)
         top_ps_arr = jnp.asarray(top_ps)
         top_ks_arr = jnp.asarray(top_ks)
-        decode_keys_arr = jnp.stack(decode_keys)
         toks, prefix = self._prefill(
             self.params, jnp.asarray(padded), lens_arr, temps_arr,
-            top_ps_arr, top_ks_arr, jnp.stack(prefill_keys),
+            top_ps_arr, top_ks_arr, prefill_keys,
             self._prefill_scratch_for(batch, bucket))
         # One dispatch installs every row; pad rows re-write the last
         # real slot with bit-identical data (same prompt AND keys above).
@@ -973,8 +1047,7 @@ class InferenceEngine:
         # the next same-shape prefill the moment the insert executes —
         # device-order sequencing makes immediate reuse safe.
         self._store_prefill_scratch(batch, bucket, prefix)
-        host_toks = np.asarray(toks)
-        return [int(host_toks[i]) for i in range(n_req)]
+        return toks
 
     # ------------------------------------------------------------------
     # Shared-prefix KV cache (engine side; bookkeeping in prefix_cache.py)
@@ -1013,6 +1086,15 @@ class InferenceEngine:
         self, assignments: list[tuple[int, list[int], SamplingParams]],
         hit: RadixHit,
     ) -> list[int]:
+        """prefill_and_insert_cached_dispatch, then read: the group's
+        first tokens (waits for the device)."""
+        toks = self.prefill_and_insert_cached_dispatch(assignments, hit)
+        return [int(tok) for tok in np.asarray(toks)[:len(assignments)]]
+
+    def prefill_and_insert_cached_dispatch(
+        self, assignments: list[tuple[int, list[int], SamplingParams]],
+        hit: RadixHit,
+    ) -> jax.Array | list:
         """Admit a group of requests that SHARE a cached prefix: one
         block gather seeds every row of the (batch, bucket) working
         buffer straight from the pool, one continuation dispatch
@@ -1022,7 +1104,8 @@ class InferenceEngine:
         group regardless of how long the shared prefix is. The finished
         rows then extend the radix tree with their NEW tail blocks, so
         the next turn of the same session hits at its full history.
-        Releases `hit` in all paths."""
+        Returns the first tokens [batch] on the device, unread (row i is
+        assignments[i]'s). Releases `hit` in all paths."""
         try:
             if not assignments:
                 return []
@@ -1052,7 +1135,6 @@ class InferenceEngine:
             top_ps = np.ones((batch,), np.float32)
             top_ks = np.zeros((batch,), np.int32)
             slots_arr = np.zeros((batch,), np.int32)
-            prefill_keys, decode_keys = [], []
             for i in range(batch):
                 # Pad rows replay the last request bit-identically (same
                 # suffix, slot, and keys) — same contract as the full
@@ -1067,13 +1149,8 @@ class InferenceEngine:
                 top_ps[i] = sampling.top_p
                 top_ks[i] = sampling.top_k
                 slots_arr[i] = slot
-                if i >= n_req:
-                    prefill_keys.append(prefill_keys[n_req - 1])
-                    decode_keys.append(decode_keys[n_req - 1])
-                    continue
-                pk, dk = self._request_keys(sampling)
-                prefill_keys.append(pk)
-                decode_keys.append(dk)
+            prefill_keys, decode_keys_arr = self._group_keys(
+                [sampling for _, _, sampling in assignments], batch)
 
             scratch = self._prefill_scratch_for(batch, bucket)
             scratch = self._insert_from_blocks(
@@ -1087,11 +1164,10 @@ class InferenceEngine:
             temps_arr = jnp.asarray(temps)
             top_ps_arr = jnp.asarray(top_ps)
             top_ks_arr = jnp.asarray(top_ks)
-            decode_keys_arr = jnp.stack(decode_keys)
             toks, prefix = self._chunk_final(
                 self.params, jnp.asarray(suffix), scratch, sfx_arr,
                 sfx_arr - 1, temps_arr, top_ps_arr, top_ks_arr,
-                jnp.stack(prefill_keys))
+                prefill_keys)
             self._insert(prefix, slots_arr, jnp.asarray(full_lens), toks,
                          temps_arr, top_ps_arr, top_ks_arr, decode_keys_arr)
             # The finished rows hold prefix + suffix KV: extend the tree
@@ -1102,8 +1178,7 @@ class InferenceEngine:
             self._maybe_store_prefix(assignments[:n_req], prefix)
             self._store_prefill_scratch(batch, bucket, prefix)
             self.prefix_index.note_reuse(n_req, p)
-            host_toks = np.asarray(toks)
-            return [int(host_toks[i]) for i in range(n_req)]
+            return toks
         finally:
             hit.release()
 
@@ -1395,9 +1470,18 @@ class InferenceEngine:
 
     def advance_chunked_prefill(self, job: ChunkedPrefill) -> int | None:
         """Run ONE chunk; returns the first sampled token when the prompt
-        is complete (the slot is then live), else None. Chunk offsets are
-        relative to the SUFFIX the job carries — with a seeded start_pos
-        the cache lengths already position the writes past the prefix."""
+        is complete (the slot is then live; waits for the device), else
+        None."""
+        toks = self.advance_chunked_prefill_dispatch(job)
+        return None if toks is None else int(np.asarray(toks)[0])
+
+    def advance_chunked_prefill_dispatch(self, job: ChunkedPrefill
+                                         ) -> jax.Array | None:
+        """Dispatch ONE chunk. The final chunk also dispatches the insert
+        and returns the first sampled token [1] on the device, unread;
+        any other returns None. Chunk offsets are relative to the SUFFIX
+        the job carries — with a seeded start_pos the cache lengths
+        already position the writes past the prefix."""
         C = self.prefill_chunk
         c0 = job.done_chunks * C
         chunk = jnp.asarray(job.ids[:, c0:c0 + C])
@@ -1441,7 +1525,7 @@ class InferenceEngine:
                     plan.abort()
                     raise
                 plan.commit()
-        return int(np.asarray(toks)[0])
+        return toks
 
     def _new_prefix_cache(self, capacity: int, batch: int = 1):
         """Fresh batch-N prefix cache, created sharded-in-place (jit with
@@ -1537,6 +1621,10 @@ class InferenceEngine:
         # covers every resume depth): warm it so the first mid-stream
         # recovery under load never pays a fresh XLA compile.
         self._rng_resume(jax.random.key(0), 0)
+        for batch in self.PREFILL_BATCHES:  # the group-key program
+            self._derive_keys(self._base_key, np.zeros((batch,), np.int32),
+                              np.zeros((batch,), np.int32),
+                              np.zeros((batch,), bool))
         if decode_side:
             self.state, _, _ = self._dispatch_decode()
         for bucket in self.prefill_buckets:
@@ -1908,7 +1996,8 @@ class InferenceEngine:
         out: dict[str, int] = {}
         for name in ("_prefill", "_decode", "_verify", "_chunk_step",
                      "_chunk_final", "_insert_all", "_insert_from_blocks",
-                     "_write_blocks", "_extract_prefix_row"):
+                     "_write_blocks", "_extract_prefix_row",
+                     "_derive_keys"):
             fn = getattr(self, name, None)
             if fn is not None and hasattr(fn, "_cache_size"):
                 out[name] = fn._cache_size()

@@ -149,11 +149,39 @@ class _ActiveSlot:
     stages_sent: bool = False
 
 
+@dataclass(slots=True)
+class _Admission:
+    """One admission dispatch in flight: its prefill (or final chunk) and
+    insert are queued on the device, its first tokens not yet read. It
+    sits in the scheduler's in-flight queue among the decode blocks and
+    is read in device order (Scheduler._read_admission)."""
+
+    kind: str                 # "prefill" | "adopt" | "chunk"
+    toks: Any                 # first tokens: device array, or host values
+    # (slot, request, the lane's _ActiveSlot — None on a prefill tier,
+    # whose lanes are handed off instead of decoded)
+    members: list[tuple[int, GenRequest, "_ActiveSlot | None"]]
+    # (kind, batch, bucket, padded tokens computed): the estimate's key
+    shape: tuple[str, int, int, int]
+    dispatched_at: float      # monotonic
+    charged_s: float          # what the block's budget was charged
+    chunks_before: int = 0    # unread chunk dispatches queued ahead of it
+
+
+def _is_ready(toks: Any) -> bool:
+    """True when reading `toks` will not wait: a device array that has
+    been computed, or values a synchronous engine already holds on the
+    host (anything without `is_ready`)."""
+    probe = getattr(toks, "is_ready", None)
+    return True if probe is None else bool(probe())
+
+
 # The phases that partition the engine thread's loop (Scheduler._phase):
-# sync = device→host wait for a block's tokens; process = the rest of block
-# processing; dispatch = decode/verify dispatch; admit = _admit_new; chunks
-# = _advance_prefills; flush = _flush_events; wait = blocked on an empty
-# inbox. stats()["loop_s"] carries the seconds of each.
+# sync = device→host wait for an in-flight entry's tokens (a block's, or an
+# admission's first tokens); process = the rest of reading an entry;
+# dispatch = decode/verify dispatch; admit = _admit_new (dispatch only);
+# chunks = _advance_prefills; flush = _flush_events; wait = blocked on an
+# empty inbox. stats()["loop_s"] carries the seconds of each.
 LOOP_PHASES = ("sync", "process", "dispatch", "admit", "chunks", "flush",
                "wait")
 
@@ -165,7 +193,7 @@ class Scheduler:
                  debug_invariants: bool = False,
                  prefill_chunks_per_block: int = 4,
                  admit_groups_per_block: int = 4,
-                 admit_seconds_per_block: float = 0.65,
+                 admit_seconds_per_block: float = 0.1,
                  pipeline_depth: int = 2,
                  emit_queue_blocks: int = 8,
                  emit_batch: Callable[
@@ -204,12 +232,13 @@ class Scheduler:
         # per-event through req.emit otherwise (AsyncSession, tests).
         self._emit_batch = emit_batch
         self._pending_events: list[tuple[GenRequest, TokenEvent]] = []
-        # Overlapped pipeline (ROADMAP item 2): keep up to `pipeline_depth`
-        # decode blocks dispatched-but-unsynced between iterations, so the
-        # host's per-block work (detokenize, event encode, pipe emit,
-        # bookkeeping) overlaps device execution instead of serializing
-        # with it. Depth 1 reproduces the pre-pipeline double-buffer loop
-        # exactly (the A/B baseline).
+        # Overlapped pipeline (ROADMAP item 2): `pipeline_depth` decode
+        # blocks (at least two: one running, one queued) are in flight
+        # when the thread reads the oldest, so the host's per-block work
+        # (detokenize, event encode, pipe emit, bookkeeping, admission
+        # dispatch) overlaps device execution instead of serializing with
+        # it. Depth 1 runs the same loop with the emit work inline on
+        # the engine thread (the A/B baseline for the offload).
         self._depth = max(1, int(pipeline_depth))
         # Emit/bookkeep offload (depth >= 2): everything that is not a
         # device dispatch — push_many detokenize, TokenEvent construction,
@@ -243,19 +272,43 @@ class Scheduler:
         self._prefill_jobs: list[tuple[Any, GenRequest]] = []
         self._chunks_per_block = prefill_chunks_per_block
         self._admit_groups = admit_groups_per_block
-        # The binding admission bound while streams are active is TIME, not
-        # count, shared by burst admissions and chunked-prefill advances:
-        # stop admitting once the block's admission work exceeds this many
-        # seconds (one dispatch may overshoot — admissions are atomic).
-        # Measured on-chip (round 4): prefill dispatches overlap the
-        # in-flight decode block (async dispatch), so engine-side block
-        # intervals stay <= ~1.6x block time even at 2 wide admissions
-        # per block — while halving the budget to one dispatch per block
-        # only stretched the ramp (TTFT p50 5.0 -> 7.0 s) without moving
-        # the client-observed gap. 0.65 allows ~2 batch-16 prefills per
-        # block; the count caps remain as secondary bounds.
+        # The binding admission bound while streams are active is DEVICE
+        # TIME, not count, shared by burst admissions and chunked-prefill
+        # advances: stop admitting once the admission work queued between
+        # two decode blocks exceeds this many seconds (one dispatch may
+        # overshoot — admissions are atomic). A dispatch returns in
+        # milliseconds, so what it is charged is an estimate of the
+        # seconds the device will need (_charge): the last measured
+        # device interval of its (kind, batch, bucket, tokens) shape, taken where
+        # the entries are read, and before a shape has run its padded
+        # tokens x the slowest per-token rate any shape last showed. A
+        # model whose prefills take 0.4 s and one whose take 0.05 s share
+        # the one constant (0.1, chosen on the chip: PERF.md §6, PR 31):
+        # the first lands one dispatch between two blocks, the second two
+        # or three. The count caps remain as secondary bounds.
         self._admit_budget_s = admit_seconds_per_block
         self._spent_this_block = 0.0
+        self._shape_s: dict[tuple, float] = {}  # shape -> device seconds
+        # The in-flight queue: decode blocks / verify dispatches (tuples)
+        # and admission dispatches (_Admission), in dispatch order, which
+        # is the order the device runs them and the order they are read.
+        self._pending: deque[tuple | _Admission] = deque()
+        self._blocks_in_flight = 0
+        # Non-final chunk dispatches queued since the last entry: they
+        # have nothing to read, so the next chunk entry's measured
+        # interval is theirs too.
+        self._chunks_unread = 0
+        # (monotonic stamp at which the last entry's read returned, and
+        # whether the thread WAITED for it — only then is the stamp the
+        # moment the device finished it.)
+        self._ready_at: tuple[float | None, bool] = (None, False)
+        # stats()["admit"]: that the mechanism engages. device_s = device
+        # seconds of admission work (measured interval per entry, else
+        # its estimate); wait_s = engine-thread wall waiting on first
+        # tokens; ready_at_read / reads = entries whose tokens were
+        # already there when the thread came to read them.
+        self._admit = {"device_s": 0.0, "wait_s": 0.0, "reads": 0,
+                       "ready_at_read": 0}
         self._debug = debug_invariants
         self._thread: threading.Thread | None = None
         self._stopping = threading.Event()
@@ -295,7 +348,11 @@ class Scheduler:
                         # benchmark capture must carry its own explanation):
                         # admission prefill dispatches, chunked-prefill
                         # advances, decode-block syncs — each phase's count
-                        # and cumulative seconds, read via stats().
+                        # and cumulative seconds, read via stats(). admit_s
+                        # and chunk_s are engine-thread wall INSIDE the
+                        # dispatch calls: dispatch cost alone for an engine
+                        # with the dispatch forms, the whole device wait for
+                        # a synchronous one.
                         "admit_dispatches": 0, "admit_s": 0.0,
                         "chunk_dispatches": 0, "chunk_s": 0.0,
                         "block_syncs": 0, "sync_s": 0.0,
@@ -396,7 +453,8 @@ class Scheduler:
         # The loop phase the engine thread is in (see _phase).
         self._open_phase: Any = None
         # Engine-side latency distributions: TTFT as the scheduler saw it
-        # (enqueue → first sampled token), admission dispatch wall, and the
+        # (enqueue → first sampled token), an admission's device seconds
+        # (the interval between ready stamps where it is read), and the
         # interval between consecutive decode-block syncs while streams are
         # active (the engine-side bound on any client's inter-chunk gap —
         # if the client measures seconds and this says milliseconds, the
@@ -489,6 +547,7 @@ class Scheduler:
         out["loop_s"] = {name: round(
             self.tracer.phase_s.get("sched." + name, 0.0), 6)
             for name in LOOP_PHASES}
+        out["admit"] = {k: round(v, 6) for k, v in self._admit.items()}
         if self._adopt_hist.count:
             out["adopt_dispatch_s"] = self._adopt_hist.to_dict()
         if getattr(self.engine, "expert_pairs", None):
@@ -641,6 +700,16 @@ class Scheduler:
                     text="", token_id=None, done=True,
                     finish_reason="error", error=f"engine failure: {exc}"))
             self._prefill_jobs.clear()
+            for entry in self._pending:
+                # Admissions in flight whose lanes are in no slot table
+                # (a prefill tier's): the loop above did not reach them.
+                for _slot, req, active in getattr(entry, "members", ()):
+                    if active is None:
+                        self._emit_cb(req, TokenEvent(
+                            text="", token_id=None, done=True,
+                            finish_reason="error",
+                            error=f"engine failure: {exc}"))
+            self._pending.clear()
             while True:
                 try:
                     item = self._inbox.get_nowait()
@@ -790,62 +859,56 @@ class Scheduler:
         return job[1], job[2]
 
     def _loop_forever(self) -> None:
-        # Pipelined decode (SURVEY §7 hard-part 3, ROADMAP item 2): up to
-        # `pipeline_depth` blocks stay in flight on the device between
-        # iterations while the host processes the oldest one. Each pending
-        # entry is (kind, device tokens, slot snapshot at dispatch,
-        # dispatch stamp, extra) — the snapshot attributes each lane's
-        # tokens to the request that occupied it AT DISPATCH, so a lane
-        # freed-and-reused between dispatch and sync never leaks the old
-        # request's block into the new one (the stale-snapshot check in
-        # _process_block), and a slot freed at block N is never
-        # double-sampled by the already-in-flight block N+1: its lane
-        # tokens there are simply discarded. Depth 1 degenerates to the
-        # pre-pipeline double buffer: one dispatch ahead, processed the
-        # next iteration.
-        pending: deque[tuple] = deque()
+        # One in-flight queue (self._pending) holds everything dispatched
+        # and not yet read, in device order: decode blocks, verify
+        # dispatches and admission dispatches. An iteration is
+        #
+        #   dispatch the next block -> read the oldest block and the
+        #   admissions queued behind it -> admit (dispatch only)
+        #
+        # so the queue cycles through [B(k), P(k)..., B(k+1)]: the thread
+        # waits on B(k) with P(k) and B(k+1) queued behind it, then on
+        # P(k) with B(k+1) behind it, then dispatches P(k+1) behind the
+        # running B(k+1) and B(k+2) behind that. Two invariants:
+        # (a) while a slot is live or an admission is in flight, no device
+        # read returns to an empty device queue — a decode block is
+        # always queued behind whatever the thread waits for, so the host
+        # work after a read (process, flush, the next group's preparation,
+        # a re-lowering) hides behind it; (b) a prefill is queued behind
+        # at most one block that has not started and its first token is
+        # read as soon as the block ahead of it has been processed, so
+        # TTFT does not pay for the overlap. Admission never reads the
+        # device: a lane is registered live AT DISPATCH (so the snapshot
+        # of the block dispatched after its prefill attributes the lane's
+        # tokens to it), and what its first token decides — TTFT, EOS /
+        # budget / capacity finish, the first emit — happens where the
+        # entry is read. A lane that finishes there discards its tokens
+        # of the block already in flight, like any lane freed between
+        # dispatch and read (the stale-snapshot check in _process_block).
+        #
+        # Each block entry is (kind, device tokens, slot snapshot at
+        # dispatch, dispatch stamp, extra): the snapshot attributes each
+        # lane's tokens to the request that occupied it AT DISPATCH, so a
+        # lane freed-and-reused between dispatch and read never leaks the
+        # old request's block into the new one, and a slot freed at block
+        # N is never double-sampled by the in-flight block N+1.
+        #
+        # `pipeline_depth` blocks (at least two: one running, one queued)
+        # are in flight when the thread reads; depth 1 keeps the emit
+        # work inline on this thread (the A/B baseline for the offload).
+        pending = self._pending
+        want = max(2, self._depth)
         while True:
             t_iter = time.perf_counter()
             self.metrics["loop_iters"] += 1
-            self._spent_this_block = 0.0
-            # Dispatch the next block BEFORE this iteration's admission
-            # work: decode blocks sit at the FRONT of the device queue and
-            # admission prefills enqueue behind them, so a burst of
-            # arrivals never delays the block active streams are waiting
-            # on — the prefill lane is fully asynchronous to decode, and
-            # prefix-cache seed gathers/scatters (cached-path admission,
-            # decode-tier adoption) overlap every in-flight block.
-            # (Measured motivation: steady wire throughput stuck at ~70%
-            # of engine-only because prefill dispatches issued ahead of
-            # the block stretched every block interval under continuous
-            # admission — BASELINE.md rounds 3-4.) A slot admitted this
-            # iteration joins the NEXT dispatch — its first token was
-            # already sampled by its prefill dispatch, so TTFT is
-            # untouched; only its second token waits the extra block(s).
-            #
-            # Speculative mode drains the pipeline before proposing: the
-            # drafter extends continuations of the freshest emitted
-            # context. The verify dispatch itself then joins the pipeline
-            # like any block (the satellite fix for the old same-iteration
-            # early sync); at depth 1 it is still synced in-iteration —
-            # the pre-pipeline serial behavior, for the A/B.
             did_dispatch = False
             did_verify = False
-            # Depth >= 2 syncs the OLDEST in-flight block FIRST — the
-            # loop body the tentpole asks for: sync oldest -> sample
-            # next -> dispatch. The pipeline still holds depth-1 newer
-            # blocks through the sync, so the device never idles, and
-            # every host decision below (drafter peek, verify drain,
-            # admission) sees a context only ONE block stale instead of
-            # `depth` — without this, the speculative peek at depth 2
-            # lags the device by two blocks and misfires both ways
-            # (drains that propose nothing, repetition spotted too late
-            # to verify). Depth 1 cannot sync first without a device
-            # bubble (nothing else would be in flight during the sync):
-            # it keeps the pre-pipeline dispatch-then-process double
-            # buffer at the bottom of the loop.
-            if self._depth > 1 and len(pending) >= self._depth:
-                self._process_pending(pending.popleft())
+            # Speculative mode drains the queue before proposing: the
+            # drafter extends continuations of the freshest emitted
+            # context (admission entries drain in order with the blocks;
+            # a lane whose first token is unread proposes nothing). The
+            # verify dispatch itself then joins the queue like any block;
+            # at depth 1 it is read in the same iteration.
             if self._slots and self._drafter is not None:
                 if pending and self._spec_peek():
                     while pending:
@@ -854,21 +917,43 @@ class Scheduler:
                     with self._phase("dispatch"):
                         vb = self._maybe_verify_block()
                     if vb is not None:
-                        pending.append(vb)
+                        self._push_block(vb)
                         did_dispatch = did_verify = True
-            if self._slots and not did_dispatch and len(pending) <= self._depth:
+            if (self._slots and not did_dispatch
+                    and self._blocks_in_flight < want):
                 with self._phase("dispatch"):
-                    pending.append((
+                    self._push_block((
                         "decode_block", self.engine.decode_steps_dispatch(),
                         dict(self._slots), time.monotonic(), None))
                 self.metrics["steps"] += self.engine.decode_block
                 did_dispatch = True
+            self._live_depth = self._blocks_in_flight
+            self._m_pipeline_depth.set(self._blocks_in_flight)
+            # Read the oldest block once the pipeline is full — and when
+            # nothing was dispatched (slots emptied or stopping: the
+            # drain path) — then every admission queued behind it. Each
+            # admission's first tokens leave as soon as they are read:
+            # first-token latency must not pay for the entries behind it
+            # (a cold burst queues many).
+            if pending and (self._blocks_in_flight >= want
+                            or not did_dispatch
+                            or (did_verify and self._depth == 1)):
+                self._read_through_block()
+            self._read_admissions()
+            self._flush_events()
+            self._spent_this_block = 0.0
             with self._phase("admit"):
                 drained = self._admit_new()
+            # Chunked prefills ride between decode dispatches: a bounded
+            # number of chunk dispatches per block keeps long-prompt
+            # admission from stalling active streams for more than ~a
+            # chunk's device time.
+            with self._phase("chunks"):
+                self._advance_prefills()
+            # Terminal events of the admission pass (queued cancels,
+            # sheds, dispatch errors) leave before the next wait.
+            self._flush_events()
             if not self._slots and not pending and not self._prefill_jobs:
-                # Terminal/error events from the admission pass must reach
-                # their consumers BEFORE blocking on an empty inbox.
-                self._flush_events()
                 # Idle boundary: the next block interval would span the
                 # idle wait, which is not a serving stall.
                 self._last_sync_done = None
@@ -898,48 +983,16 @@ class Scheduler:
                 # Hand the popped item straight to admission (re-putting it
                 # would reorder it BEHIND arrivals that raced in while we
                 # were blocked — inverted FIFO for the earliest request).
+                # Its prefill is dispatched here and read at the top of
+                # the next iteration, behind the first decode block.
                 t_iter = time.perf_counter()
+                self._spent_this_block = 0.0
                 with self._phase("admit"):
                     self._admit_new(carry=item)
                 self._flush_events()
                 self.metrics["dispatch_thread_s"] += (
                     time.perf_counter() - t_iter)
                 continue
-
-            # (The next block was dispatched above, before admission;
-            # syncing the oldest in-flight block below then overlaps the
-            # newer blocks' device execution, while the admission
-            # dispatches that just enqueued run after them, never ahead.)
-            #
-            # Chunked prefills ride between decode dispatches: a bounded
-            # number of chunk dispatches per block keeps long-prompt
-            # admission from stalling active streams for more than ~a
-            # chunk's device time.
-            with self._phase("chunks"):
-                self._advance_prefills()
-            # Admission-time events (first tokens from placement, chunked-
-            # prefill finishes, admission errors) leave NOW, before the
-            # device sync below can hold them for up to a whole block —
-            # first-token latency must not pay for block coalescing. One
-            # extra pipe write per block at most: still O(1).
-            self._flush_events()
-            # Depth 1's process point (the pre-pipeline double buffer:
-            # dispatch block N+1 above, sync block N here), and both
-            # depths' drain path when nothing was dispatched (slots
-            # emptied or stopping). A depth-1 verify syncs in the same
-            # iteration — the pre-pipeline serial-verify behavior.
-            # Depth >= 2 already synced its oldest block at the TOP of
-            # the iteration, so len(pending) never exceeds depth here.
-            if pending and (len(pending) > self._depth or not did_dispatch
-                            or (did_verify and self._depth == 1)):
-                self._process_pending(pending.popleft())
-            # Block boundary: everything this iteration produced (block
-            # deltas, finishes) leaves as one batch — the O(1)-writes-
-            # per-block contract (one bounded-queue handoff per flush
-            # point while offload is on).
-            self._flush_events()
-            self._live_depth = len(pending)
-            self._m_pipeline_depth.set(len(pending))
             dt_iter = time.perf_counter() - t_iter
             self.metrics["dispatch_thread_s"] += dt_iter
             if did_dispatch:
@@ -948,12 +1001,38 @@ class Scheduler:
             if self._debug:
                 self._check_invariants()
 
-    def _process_pending(self, blk: tuple) -> None:
-        """Sync + process one in-flight pipeline entry (FIFO order): the
-        loop's `process` phase, which the device→host waits inside it
-        suspend as `sync`."""
+    def _push_block(self, blk: tuple) -> None:
+        self._pending.append(blk)
+        self._blocks_in_flight += 1
+        self._chunks_unread = 0
+
+    def _read_through_block(self) -> None:
+        """Read in-flight entries, oldest first, up to and including the
+        first decode block / verify dispatch."""
+        while self._pending:
+            entry = self._pending.popleft()
+            self._process_pending(entry)
+            if not isinstance(entry, _Admission):
+                return
+
+    def _read_admissions(self) -> None:
+        """Read every admission at the head of the in-flight queue (those
+        queued between the block just read and the next one); each one's
+        first tokens leave at once."""
+        while self._pending and isinstance(self._pending[0], _Admission):
+            self._process_pending(self._pending.popleft())
+            self._flush_events()
+
+    def _process_pending(self, entry: tuple | _Admission) -> None:
+        """Read + process one in-flight entry (FIFO order): the loop's
+        `process` phase, which the device→host waits inside it suspend as
+        `sync`."""
         with self._phase("process"):
-            self._sync_and_process(blk)
+            if isinstance(entry, _Admission):
+                self._read_admission(entry)
+            else:
+                self._blocks_in_flight = max(0, self._blocks_in_flight - 1)
+                self._sync_and_process(entry)
 
     def _sync_and_process(self, blk: tuple) -> None:
         """Verify entries book their speculative accounting HERE, at sync
@@ -1027,6 +1106,7 @@ class Scheduler:
         discarded from the counters too, so the engine-side number sums
         to exactly the bench's tokens_streamed. tokens_generated keeps
         counting the EOS (the budget convention)."""
+        was_ready = _is_ready(device_toks)
         with self._phase("sync"):
             t0 = time.perf_counter()
             toks = np.asarray(device_toks)  # blocks on THIS block only
@@ -1036,6 +1116,7 @@ class Scheduler:
             if collect is not None:
                 collect()
             t1 = time.perf_counter()
+        self._ready_at = (time.monotonic(), not was_ready)
         self.metrics["block_syncs"] += 1
         self.metrics["sync_s"] += t1 - t0
         # Same-kind-only intervals: a decode_block -> decode_block gap is
@@ -1223,17 +1304,22 @@ class Scheduler:
         return ("verify", toks, snapshot, t0m, (n_emit, n_draft, proposed))
 
     def _admit_new(self, carry: GenRequest | None = None) -> bool:
-        """Place queued requests into free slots. Returns True if inbox
-        empty. Concurrent arrivals coalesce into ONE prefill dispatch when
-        the engine supports it (prefill_and_insert_many) — per-dispatch
-        round-trips would otherwise serialize into the tail TTFT. `carry`
-        is an already-popped request admitted ahead of the queue.
+        """Place queued requests into free slots: DISPATCH their prefills
+        behind what is in flight and register their lanes; nothing here
+        reads the device (the first tokens are read in device order,
+        _read_admission). Returns True if inbox empty. Concurrent
+        arrivals coalesce into ONE prefill dispatch when the engine
+        supports it — per-dispatch round-trips would otherwise serialize
+        into the tail TTFT. `carry` is an already-popped request admitted
+        ahead of the queue.
 
         While streams are active, at most `admit_groups_per_block` prefill
-        DEVICE DISPATCHES are spent per call (a group spanning buckets
-        costs one per bucket chunk): an admission burst would otherwise
-        freeze every active stream for the whole burst. With nothing
-        active there is nobody to stall — drain freely."""
+        DEVICE DISPATCHES are queued per call (a group spanning buckets
+        costs one per bucket chunk) and at most `admit_seconds_per_block`
+        estimated device seconds: an admission burst would otherwise
+        sit between two decode blocks and freeze every active stream for
+        the whole burst. With nothing active there is nobody to stall —
+        drain freely."""
         many = getattr(self.engine, "prefill_and_insert_many", None)
         batches_for = getattr(self.engine, "prefill_batches_for", None)
         if many is None:
@@ -1328,12 +1414,17 @@ class Scheduler:
                 groups_left -= max(done, 1)
             else:
                 # Unbudgeted cold-burst drain (nothing was decoding): a
-                # large burst spans many placement groups, so each
-                # group's first tokens leave NOW rather than after the
-                # whole drain — the earliest request's delivered TTFT
-                # must not pay for the rest of the burst's admission.
-                # Still one write per placement group, not per event.
-                self._flush_events()
+                # large burst spans many placement groups. One dispatch
+                # stays queued behind the one the thread waits for — the
+                # device goes from prefill to prefill while the host
+                # prepares the next group — and no more: every queued
+                # program holds its workspace on the device, and the
+                # earliest request's first token must not wait for the
+                # whole burst (it leaves at its read: one write per
+                # entry, not per event).
+                while len(self._pending) > 1:
+                    self._process_pending(self._pending.popleft())
+                    self._flush_events()
         if carry is not None:
             # No free slot took it (all busy): hold it at the deferred
             # tail rather than dropping it — every deferred entry was
@@ -1343,8 +1434,9 @@ class Scheduler:
         return not self._deferred and self._inbox.empty()
 
     def _place_group(self, group: list[tuple[int, GenRequest]]) -> int:
-        """Admit `group`; returns the number of prefill DEVICE DISPATCHES
-        performed (the unit the per-block admission budget counts)."""
+        """Admit `group`: dispatch its prefills and queue them for their
+        read; returns the number of prefill DEVICE DISPATCHES performed
+        (the unit the per-block admission budget counts)."""
         # Requests the engine would reject (e.g. prompt beyond the largest
         # bucket) must fail individually, not poison the whole batch.
         wants_chunked = getattr(self.engine, "wants_chunked", None)
@@ -1437,7 +1529,7 @@ class Scheduler:
                 self.engine.bucket_for(len(req.prompt_ids)), []).append(
                     (slot, req))
         batches_for = getattr(self.engine, "prefill_batches_for", None)
-        # Each unit: (subgroup, prefix hit or None), ordered by the
+        # Each unit: (subgroup, prefix hit or None, bucket), ordered by the
         # EARLIEST arrival among its members — under a tight admission
         # budget the unstarted tail of `units` defers to the next block,
         # so any other order (e.g. cheapest-first) would let a sustained
@@ -1445,7 +1537,7 @@ class Scheduler:
         # miss, the exact FIFO inversion the deferred deque exists to
         # prevent.
         arrival = {id(req): i for i, (_s, req) in enumerate(group)}
-        units: list[tuple[list[tuple[int, GenRequest]], Any]] = []
+        units: list[tuple[list[tuple[int, GenRequest]], Any, int]] = []
         for bucket_key, (hit, subgroup) in hit_units.items():
             cap = (max(batches_for(bucket_key[0]))
                    if batches_for is not None else len(subgroup))
@@ -1453,30 +1545,32 @@ class Scheduler:
                 # Split units share one pinned handle; release() is
                 # idempotent and the handle's entry ref keeps the buffer
                 # alive for the later splits either way.
-                units.append((subgroup[start:start + cap], hit))
+                units.append((subgroup[start:start + cap], hit,
+                              bucket_key[0]))
         for bucket, subgroup in by_bucket.items():
             cap = (max(batches_for(bucket)) if batches_for is not None
                    else len(subgroup))
             for start in range(0, len(subgroup), cap):
-                units.append((subgroup[start:start + cap], None))
+                units.append((subgroup[start:start + cap], None, bucket))
         units.sort(key=lambda u: min(arrival[id(req)] for _s, req in u[0]))
         n_dispatches = 0
-        for unit_idx, (sub, hit) in enumerate(units):
+        for unit_idx, (sub, hit, bucket) in enumerate(units):
             if (unit_idx > 0 and self._slots
                     and self._spent_this_block >= self._admit_budget_s):
-                # The shared per-block time budget ran out mid-group: a
+                # The shared per-block budget ran out mid-group: a
                 # 16-request group spanning the 512 bucket splits into
-                # 4-5 dispatches, and running them all back-to-back would
-                # overshoot the budget several-fold and stall every
-                # active stream. Defer the unstarted subgroups — slots
-                # back to the pool, requests to the deferred queue (NOT
-                # the inbox tail, which would put them behind later
-                # arrivals and invert FIFO order every deferral) — and
-                # let the next block pick them up. (unit_idx > 0
-                # guarantees forward progress: one dispatch always lands.)
-                # A deferred hit re-resolves through prefix_lookup next
-                # block, so its pinned handle is released now.
-                for d_sub, d_hit in units[unit_idx:]:
+                # 4-5 dispatches, and queueing them all between two
+                # decode blocks would overshoot the budget several-fold
+                # and stall every active stream. Defer the unstarted
+                # subgroups — slots back to the pool, requests to the
+                # deferred queue (NOT the inbox tail, which would put
+                # them behind later arrivals and invert FIFO order every
+                # deferral) — and let the next block pick them up.
+                # (unit_idx > 0 guarantees forward progress: one dispatch
+                # always lands.) A deferred hit re-resolves through
+                # prefix_lookup next block, so its pinned handle is
+                # released now.
+                for d_sub, d_hit, _b in units[unit_idx:]:
                     if d_hit is not None:
                         d_hit.release()
                     for slot, req in d_sub:
@@ -1490,24 +1584,14 @@ class Scheduler:
             # full-prefills here and rightly counts as admit.)
             adopting = hit is not None and self._role == "decode"
             t0 = time.perf_counter()
+            t0m = time.monotonic()
             try:
                 with self.tracer.phase(
                         "engine.prefill",
                         ring="adopt_dispatch" if adopting
                         else "prefill_dispatch",
                         n=len(sub), cached=hit is not None):
-                    if hit is not None:
-                        firsts = self.engine.prefill_and_insert_cached(
-                            [(slot, req.prompt_ids, req.sampling)
-                             for slot, req in sub], hit)
-                    elif len(sub) > 1:
-                        firsts = self.engine.prefill_and_insert_many(
-                            [(slot, req.prompt_ids, req.sampling)
-                             for slot, req in sub])
-                    else:
-                        slot0, req0 = sub[0]
-                        firsts = [self.engine.prefill_and_insert(
-                            slot0, req0.prompt_ids, req0.sampling)]
+                    toks = self._dispatch_prefill(sub, hit)
             except Exception as exc:  # noqa: BLE001 — engine errors → stream error
                 n_dispatches += 1  # a failed dispatch still cost time
                 self._spent_this_block += time.perf_counter() - t0
@@ -1521,36 +1605,191 @@ class Scheduler:
                 continue
             dt = time.perf_counter() - t0
             n_dispatches += 1
-            self._spent_this_block += dt
             if adopting:
                 self.metrics["adopt_dispatches"] += 1
-                self.metrics["adopt_s"] += dt
-                self._adopt_hist.observe(dt)
-                self._m_dispatch.observe(dt, kind="adopt")
             else:
                 self.metrics["admit_dispatches"] += 1
                 self.metrics["admit_s"] += dt
-                self._admit_hist.observe(dt)
-                self._m_dispatch.observe(dt, kind="prefill")
-            if self.ledger.enabled and dt > 0.0:
-                # Prefill/adopt attribution is EXACT (the dispatch names
-                # its requests): the unit wall splits across members by
-                # suffix length, and a radix hit's avoided prefix is
-                # priced at this very dispatch's per-token rate.
-                phase = "adopt" if adopting else "prefill"
-                sfx = [max(1, len(req.prompt_ids) - req.reused_tokens)
-                       for _s, req in sub]
-                rate = dt / sum(sfx)
-                for (slot_i, req), n_sfx in zip(sub, sfx):
-                    if req.ledger is not None:
-                        req.ledger.book_device(phase, rate * n_sfx)
-                        if req.reused_tokens:
-                            req.ledger.book_saved(
-                                rate * req.reused_tokens,
-                                req.reused_tokens)
-            for (slot, req), first in zip(sub, firsts):
-                self._activate(slot, req, first)
+            batch = (next((b for b in batches_for(bucket) if b >= len(sub)),
+                          len(sub))
+                     if batches_for is not None else len(sub))
+            self._push_admission(
+                "adopt" if adopting else "prefill", toks, sub,
+                ("cached", batch, bucket, batch * align) if hit is not None
+                else ("prefill", batch, bucket, batch * bucket), t0m, dt)
         return n_dispatches
+
+    def _dispatch_prefill(self, sub: list[tuple[int, GenRequest]],
+                          hit: Any) -> Any:
+        """Enqueue one unit's prefill + insert; returns its first tokens
+        unread (row i is sub[i]'s). An engine with only the synchronous
+        forms (the multi-host lead, test fakes) hands back host values,
+        on which the later read is a no-op."""
+        engine = self.engine
+        group = [(slot, req.prompt_ids, req.sampling) for slot, req in sub]
+        if hit is not None:
+            cached = getattr(engine, "prefill_and_insert_cached_dispatch",
+                             None) or engine.prefill_and_insert_cached
+            return cached(group, hit)
+        many = getattr(engine, "prefill_and_insert_many_dispatch", None)
+        if many is not None:
+            return many(group)
+        if len(group) > 1:
+            return engine.prefill_and_insert_many(group)
+        return [engine.prefill_and_insert(*group[0])]
+
+    def _charge(self, shape: tuple, dt: float, materialised: bool) -> float:
+        """Charge one admission dispatch to the block's budget; returns
+        the seconds charged. A synchronous engine's call held the thread
+        for the device work, so its wall IS the cost. A dispatch that
+        returned at once is charged what the device last needed for the
+        shape, and a shape that has not run its padded tokens at the
+        slowest per-token rate any shape last showed (nothing, before
+        anything was measured: the count caps bound the first blocks)."""
+        if materialised:
+            cost = dt
+        elif shape in self._shape_s:
+            cost = self._shape_s[shape]
+        else:
+            cost = shape[-1] * max(
+                (seconds / measured[-1]
+                 for measured, seconds in self._shape_s.items()),
+                default=0.0)
+        self._spent_this_block += cost
+        return cost
+
+    def _push_admission(self, kind: str, toks: Any,
+                        sub: list[tuple[int, GenRequest]], shape: tuple,
+                        t0m: float, dt: float) -> None:
+        """Queue a dispatched admission behind what is in flight, and
+        register its lanes as live NOW: the decode block dispatched next
+        computes their tokens, and its snapshot must name them."""
+        cost = self._charge(shape, dt,
+                            materialised=not hasattr(toks, "is_ready"))
+        members = []
+        for slot, req in sub:
+            active = None
+            if self._role != "prefill":
+                active = self._slots[slot] = _ActiveSlot(
+                    req=req, decoder=self.engine.tokenizer.stream_decoder(),
+                    prompt_len=len(req.prompt_ids))
+            members.append((slot, req, active))
+        self.metrics["peak_occupancy"] = max(self.metrics["peak_occupancy"],
+                                             len(self._slots))
+        self._pending.append(_Admission(
+            kind, toks, members, shape, t0m, cost, self._chunks_unread))
+        self._chunks_unread = 0
+
+    def _device_interval(self, adm: _Admission, was_ready: bool
+                         ) -> tuple[float, bool]:
+        """Device seconds of the admission just read, from the ready
+        stamps, and whether they are exact: the device ran it from the
+        moment the entry before it was ready until now. Only exact when
+        the thread WAITED for both entries; otherwise a bound — an upper
+        one when the thread came late to this entry or the device was
+        idle when it was dispatched (the interval then starts with the
+        dispatch call, host work and all), a lower one when it came late
+        to the entry before, a blurred one when chunk dispatches of
+        another shape ran in between."""
+        prev, prev_exact = self._ready_at
+        now = time.monotonic()
+        self._ready_at = (now, not was_ready)
+        idle_before = prev is None or adm.dispatched_at >= prev
+        interval = now - (adm.dispatched_at if idle_before else prev)
+        exact = not was_ready and not idle_before and prev_exact
+        if adm.chunks_before:
+            # A job's earlier chunks ran in the same interval: same
+            # shape, so each took its share.
+            if adm.kind == "chunk":
+                interval /= 1 + adm.chunks_before
+            else:
+                exact = False
+        return interval, exact
+
+    def _read_admission(self, adm: _Admission) -> None:
+        """Read one admission's first tokens (in device order) and do
+        what they decide for each lane: fail on a device error, finish a
+        request cancelled since dispatch, else activate."""
+        was_ready = _is_ready(adm.toks)
+        error: Exception | None = None
+        t0 = time.perf_counter()
+        with self._phase("sync"):
+            try:
+                firsts = np.asarray(adm.toks).reshape(-1)
+            except Exception as exc:  # noqa: BLE001 — device errors → stream error
+                error = exc
+        self._admit["wait_s"] += time.perf_counter() - t0
+        self._admit["reads"] += 1
+        self._admit["ready_at_read"] += was_ready
+        device_s, exact = self._device_interval(adm, was_ready)
+        if exact:
+            self._shape_s[adm.shape] = device_s
+        elif adm.charged_s:
+            # The stamps only bound it: what the budget was charged
+            # stands — the shape's last measurement, or a synchronous
+            # engine's wall inside the call.
+            device_s = adm.charged_s
+        self._admit["device_s"] += device_s
+        if adm.kind == "adopt":
+            self.metrics["adopt_s"] += device_s
+            self._adopt_hist.observe(device_s)
+        elif adm.kind == "prefill":
+            self._admit_hist.observe(device_s)
+        self._m_dispatch.observe(device_s, kind=adm.kind)
+        if self.ledger.enabled and device_s > 0.0:
+            self._book_admission(adm, device_s)
+        if error is not None:
+            for slot, req, active in adm.members:
+                log.error(f"prefill failed for request {req.id}: {error}")
+                if active is not None and self._slots.get(slot) is active:
+                    del self._slots[slot]
+                self._free.append(slot)
+                self.engine.release_slot(slot)
+                self._emit_cb(req, TokenEvent(
+                    text="", token_id=None, done=True,
+                    finish_reason="error", error=str(error)))
+            return
+        for (slot, req, active), first in zip(adm.members, firsts):
+            if active is not None and self._slots.get(slot) is not active:
+                continue  # failed open by a dying loop
+            if active is not None and req.cancelled():
+                # Cancelled between dispatch and read: the prefill ran
+                # for nobody. The lane's tokens of the block in flight
+                # go stale with the slot.
+                if req.ledger is not None:
+                    req.ledger.waste_all_device("killed_prefill")
+                self._finish(slot, active, "cancelled", None, ())
+                continue
+            self._activate(slot, req, int(first), active)
+
+    def _book_admission(self, adm: _Admission, device_s: float) -> None:
+        """symledger: an admission's device seconds land on the requests
+        it names — exact attribution, priced where the entry is read."""
+        if adm.kind == "chunk":
+            (_slot, req, _active), = adm.members
+            if req.ledger is not None:
+                req.ledger.book_device("chunk", device_s)
+                if req.reused_tokens:
+                    # Seeded chunked prefill (radix hit with a long
+                    # suffix): the avoided prefix is priced at this
+                    # request's own chunk rate, known only now that the
+                    # chunks have run.
+                    req.ledger.book_saved_at_phase_rate(
+                        "chunk", len(req.prompt_ids) - req.reused_tokens,
+                        req.reused_tokens)
+            return
+        # The unit's seconds split across members by suffix length, and
+        # a radix hit's avoided prefix is priced at this very dispatch's
+        # per-token rate.
+        sfx = [max(1, len(req.prompt_ids) - req.reused_tokens)
+               for _s, req, _a in adm.members]
+        rate = device_s / sum(sfx)
+        for (_slot, req, _active), n_sfx in zip(adm.members, sfx):
+            if req.ledger is not None:
+                req.ledger.book_device(adm.kind, rate * n_sfx)
+                if req.reused_tokens:
+                    req.ledger.book_saved(rate * req.reused_tokens,
+                                          req.reused_tokens)
 
     def _advance_prefills(self) -> None:
         """Run up to `prefill_chunks_per_block` prompt chunks, FIFO (the
@@ -1585,12 +1824,16 @@ class Scheduler:
                     text="", token_id=None, done=True,
                     finish_reason="cancelled"))
                 continue
+            dispatch = getattr(self.engine,
+                               "advance_chunked_prefill_dispatch", None)
             t0 = time.perf_counter()
+            t0m = time.monotonic()
             try:
                 with self.tracer.phase("engine.chunk", ring="chunk_dispatch",
                                        request_id=req.id,
                                        trace_id=req.trace_id):
-                    first = self.engine.advance_chunked_prefill(job)
+                    toks = (dispatch or
+                            self.engine.advance_chunked_prefill)(job)
             except Exception as exc:  # noqa: BLE001 — fail one, not all
                 self._prefill_jobs.pop(0)
                 self._free.append(job.slot)
@@ -1602,26 +1845,33 @@ class Scheduler:
             dt = time.perf_counter() - t0
             self.metrics["chunk_dispatches"] += 1
             self.metrics["chunk_s"] += dt
-            self._spent_this_block += dt
-            self._m_dispatch.observe(dt, kind="chunk")
-            if req.ledger is not None:
-                req.ledger.book_device("chunk", dt)
             progressed += 1
             budget -= 1
-            if first is not None:
+            shape = ("chunk", 1, self.engine.bucket_for(len(req.prompt_ids)),
+                     getattr(self.engine, "prefill_chunk", None) or 1)
+            if toks is not None:
+                # Final chunk: the insert is queued behind it and the
+                # lane is live; its first token is read in device order.
                 self._prefill_jobs.pop(0)
-                if req.ledger is not None and req.reused_tokens:
-                    # Seeded chunked prefill (radix hit with a long
-                    # suffix): the avoided prefix is priced at this
-                    # request's own measured chunk rate, known only now
-                    # that the chunks have run.
-                    req.ledger.book_saved_at_phase_rate(
-                        "chunk",
-                        len(req.prompt_ids) - req.reused_tokens,
-                        req.reused_tokens)
-                self._activate(job.slot, req, first)
+                self._push_admission("chunk", toks, [(job.slot, req)],
+                                     shape, t0m, dt)
+                continue
+            # Any other chunk leaves nothing to read: it is charged (and
+            # booked) its estimate now, and the job's final chunk
+            # measures them all.
+            cost = self._charge(shape, dt, materialised=dispatch is None)
+            if dispatch is not None:
+                self._chunks_unread += 1
+            self._admit["device_s"] += cost
+            self._m_dispatch.observe(cost, kind="chunk")
+            if req.ledger is not None:
+                req.ledger.book_device("chunk", cost)
 
-    def _activate(self, slot: int, req: GenRequest, first: int) -> None:
+    def _activate(self, slot: int, req: GenRequest, first: int,
+                  active: _ActiveSlot | None) -> None:
+        """The lane's first token has been read: stamp TTFT, finish on
+        EOS / budget / capacity, else emit it. `active` is the lane as
+        registered at dispatch (None on a prefill tier: hand off)."""
         if req.resume_offset > 0 and req.reused_tokens > 0:
             # Booked HERE (activation runs exactly once per request, even
             # across budget deferrals that re-resolve the lookup): the
@@ -1637,8 +1887,6 @@ class Scheduler:
             # keys from their seed.)
             self._handoff_request(slot, req, first)
             return
-        active = _ActiveSlot(req=req, decoder=self.engine.tokenizer.stream_decoder(),
-                             prompt_len=len(req.prompt_ids))
         active.first_token_at = time.monotonic()
         self._ttft_hist.observe(active.first_token_at - req.enqueued_at)
         self._m_ttft.observe(active.first_token_at - req.enqueued_at)
@@ -1655,9 +1903,6 @@ class Scheduler:
                                active.first_token_at - picked,
                                request_id=req.id, trace_id=req.trace_id,
                                prompt_len=len(req.prompt_ids))
-        self._slots[slot] = active
-        self.metrics["peak_occupancy"] = max(self.metrics["peak_occupancy"],
-                                             len(self._slots))
         active.generated = 1
         if first in self.engine.tokenizer.eos_ids:
             self._finish(slot, active, "stop", first, ())
@@ -1665,13 +1910,15 @@ class Scheduler:
         active.emitted = 1
         self.metrics["tokens"] += 1
         self._m_tokens.inc()
-        # Finish before the first decode block if (a) the request's token
-        # budget is already spent by the prefill token, or (b) the prompt is
-        # so long the cache can't absorb the TWO dispatches that may land
+        # Finish on the first token if (a) the request's token budget is
+        # already spent by the prefill token, or (b) the prompt is so
+        # long the cache can't absorb the TWO dispatches that may land
         # before this slot's tokens are next examined (one in-flight + one
         # lookahead; each writes up to _max_block_writes positions) —
         # otherwise KV writes land past capacity (silently dropped
-        # scatters) and the client would stream garbage.
+        # scatters) and the client would stream garbage. (A block
+        # dispatched behind the prefill already holds this lane: its
+        # tokens go stale with the slot.)
         if (active.generated >= req.max_new_tokens
                 or active.prompt_len + active.generated
                 + 2 * self._max_block_writes
@@ -1853,6 +2100,11 @@ class Scheduler:
         active = set(self._slots)
         free = set(self._free)
         prefilling = {job.slot for job, _ in self._prefill_jobs}
+        # A prefill tier's lanes between dispatch and handoff (anywhere
+        # else an admitted lane is active from its dispatch on).
+        prefilling |= {slot for entry in self._pending
+                       for slot, _req, lane in getattr(entry, "members", ())
+                       if lane is None}
         assert not (active & free), f"slot in both active and free: {active & free}"
         assert not (active & prefilling), \
             f"slot both active and prefilling: {active & prefilling}"

@@ -231,7 +231,11 @@ class CommandLoop:
 class MultihostEngine:
     """Engine facade for the scheduler on rank 0: every call is led through
     the CommandLoop so worker processes stay in lockstep. Exposes the same
-    surface Scheduler uses (prefill_and_insert / decode_steps / metadata).
+    surface Scheduler uses (prefill_and_insert / decode_steps / metadata),
+    in its synchronous form only: a command round completes before it
+    returns, so an admission's first token is a host value when the
+    scheduler queues it and the later read is a no-op (as for
+    decode_steps_dispatch below).
     """
 
     def __init__(self, loop: CommandLoop) -> None:

@@ -669,6 +669,44 @@ class TestCoalescedPadRows:
             assert int(engine.state.last_token[slot]) == first
 
 
+class TestGroupKeys:
+    """One dispatch derives a whole batch's PRNG keys (PR 31): each row
+    must be bit-identical to the one-request derivation every other
+    admission path uses, pad rows included."""
+
+    SAMPLINGS = [
+        SamplingParams(),
+        SamplingParams(temperature=0.8, seed=7),
+        SamplingParams(temperature=1.0),
+        SamplingParams(temperature=0.5, seed=2**31 + 5),
+        SamplingParams(seed=9, rng_skip=3),
+        SamplingParams(seed=2**40 + 12345),
+        SamplingParams(seed=0),
+        SamplingParams(seed=2**32 - 1),
+    ]
+
+    @pytest.mark.parametrize("rows,batch", [
+        ((0, 1, 2), 4), ((0, 1), 2), ((0, 1, 2, 3, 4), 8), ((2,), 1),
+        ((1, 3), 4), ((0, 2), 2), ((5, 6, 7), 4), ((1, 5, 3, 4), 16),
+        ((4,), 2)])
+    def test_rows_match_request_keys(self, setup, rows, batch):
+        cfg, params = setup
+        engine = make_engine(cfg, params, slots=4)
+        group = [self.SAMPLINGS[i] for i in rows]
+        engine._requests_served = 10
+        want = [engine._request_keys(s) for s in group]
+        served = engine._requests_served
+        engine._requests_served = 10
+        prefill_keys, decode_keys = engine._group_keys(group, batch)
+        assert engine._requests_served == served
+        assert prefill_keys.shape == decode_keys.shape == (batch,)
+        for i in range(batch):
+            pk, dk = want[min(i, len(group) - 1)]
+            for got, ref in ((prefill_keys[i], pk), (decode_keys[i], dk)):
+                assert np.array_equal(jax.random.key_data(got),
+                                      jax.random.key_data(ref)), (i, rows)
+
+
 class TestPrefillScratchPool:
     def test_pool_is_lru_bounded(self, setup):
         """The persistent prefill scratch pool must stay bounded: pinning

@@ -182,6 +182,7 @@ class TestConservation:
                cancelled=lambda: bool(cancel_chunk))
         submit(sched, "rchunk2", b"M" * 64, max_new=5)
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         # Chunked prefills, one chunk per pass (chunks_per_block=1,
         # FIFO head-first): rchunk runs one chunk, is cancelled before
@@ -191,7 +192,8 @@ class TestConservation:
         sched._advance_prefills()          # rchunk chunk 1
         cancel_chunk.append(True)
         sched._advance_prefills()          # rchunk killed; rchunk2 chunk 1
-        sched._advance_prefills()          # rchunk2 chunk 2 -> activates
+        sched._advance_prefills()          # rchunk2 chunk 2 -> lane live
+        sched._read_admissions()           # ... its first token read
         sched._flush_events()
         assert {a.req.id for a in sched._slots.values()} == {
             "r0", "r1", "rhit", "rchunk2"}

@@ -43,6 +43,24 @@ class LazyBlock:
         return self.arr
 
 
+class LazyFirsts(LazyBlock):
+    """An admission's first tokens, still on the device: the read waits
+    once, and `is_ready` says whether it would."""
+
+    def __init__(self, n):
+        super().__init__(np.full((n,), ord("A"), dtype=np.int32))
+        self.waited = False
+
+    def is_ready(self):
+        return self.waited
+
+    def __array__(self, dtype=None, copy=None):
+        if not self.waited:
+            time.sleep(WALL)
+            self.waited = True
+        return self.arr
+
+
 class FakeJob:
     def __init__(self, slot):
         self.slot = slot
@@ -97,6 +115,22 @@ class FakeEngine:
         return 0
 
 
+class DispatchFormEngine(FakeEngine):
+    """The same device with the DISPATCH forms of admission: a dispatch
+    returns at once with lazy first tokens; the wait moves to where the
+    scheduler reads them."""
+
+    def prefill_and_insert_many_dispatch(self, group):
+        return LazyFirsts(len(group))
+
+    def advance_chunked_prefill_dispatch(self, job):
+        job.chunks += 1
+        return LazyFirsts(1) if job.chunks >= 2 else None
+
+
+ENGINES = {"sync_forms": FakeEngine, "dispatch_forms": DispatchFormEngine}
+
+
 def drive(sched, n=10, max_new=24):
     """Start the loop, serve `n` requests (every third one chunked), idle
     a moment so the wait phase is entered, stop. Returns finished ids."""
@@ -124,9 +158,10 @@ def drive(sched, n=10, max_new=24):
 
 
 class TestPartition:
+    @pytest.mark.parametrize("forms", list(ENGINES))
     @pytest.mark.parametrize("depth", [1, 2])
-    def test_phases_tile_the_engine_thread(self, depth):
-        sched = Scheduler(FakeEngine(), pipeline_depth=depth)
+    def test_phases_tile_the_engine_thread(self, depth, forms):
+        sched = Scheduler(ENGINES[forms](), pipeline_depth=depth)
         done = drive(sched)
         assert sorted(done) == sorted(f"r{i}" for i in range(10))
         stats = sched.stats()
@@ -149,6 +184,33 @@ class TestPartition:
         wall = stats["dispatch_thread_s"] + loop_s["wait"]
         assert sum(loop_s.values()) == pytest.approx(wall, rel=0.05)
         assert sum(loop_s.values()) <= wall * 1.001
+
+    def test_admission_waits_where_it_reads(self):
+        """With the dispatch forms the `admit` phase holds dispatch cost
+        alone: the wait for an admission's first tokens is `sync`, and
+        stats()["admit"] says how long it was and how often the thread
+        was there first."""
+        walls = {}
+        for forms, engine in ENGINES.items():
+            sched = Scheduler(engine(), pipeline_depth=2)
+            drive(sched, n=9)
+            st = sched.stats()
+            walls[forms] = st
+            adm = st["admit"]
+            assert set(adm) == {"device_s", "wait_s", "reads",
+                                "ready_at_read"}
+            # every admission is read exactly once: one-dispatch prompts
+            # and the final chunk of each chunked one
+            assert adm["reads"] == st["admit_dispatches"] + 3
+            assert adm["device_s"] > 0
+        sync, disp = walls["sync_forms"], walls["dispatch_forms"]
+        assert sync["admit"]["ready_at_read"] == sync["admit"]["reads"]
+        assert sync["admit"]["wait_s"] < WALL
+        assert disp["admit"]["ready_at_read"] == 0
+        assert disp["admit"]["wait_s"] >= 0.9 * WALL * disp["admit"]["reads"]
+        # admit_s stays "wall inside the dispatch calls"
+        assert disp["admit_s"] < 0.5 * sync["admit_s"]
+        assert disp["loop_s"]["admit"] < sync["loop_s"]["admit"]
 
     def test_child_spans_keep_their_ring_names(self):
         sched = Scheduler(FakeEngine(), pipeline_depth=2)

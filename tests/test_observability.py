@@ -418,6 +418,7 @@ class TestSchedulerSpans:
         sched = self.make()
         self.submit_one(sched)
         sched._admit_new()
+        sched._read_admissions()
         spans = {s["name"]: s for s in sched.tracer.export()}
         assert "prefill_dispatch" in spans
         for name in ("queue", "prefill"):
@@ -431,6 +432,7 @@ class TestSchedulerSpans:
         sched = self.make()
         self.submit_one(sched)
         sched._admit_new()
+        sched._read_admissions()
         toks = np.full((4, 4), ord("x"), dtype=np.int64)
         t_disp = time.monotonic() - 0.01
         sched._process_block(toks, dict(sched._slots),
@@ -449,6 +451,7 @@ class TestSchedulerSpans:
         sched = self.make()
         self.submit_one(sched)
         sched._admit_new()
+        sched._read_admissions()
         eos = sched.engine.tokenizer.EOS
         toks = np.full((4, 4), eos, dtype=np.int64)
         sched._process_block(toks, dict(sched._slots))
@@ -464,6 +467,7 @@ class TestSchedulerSpans:
         sched.tracer.enabled = False
         self.submit_one(sched)
         sched._admit_new()
+        sched._read_admissions()
         toks = np.full((4, 4), ord("x"), dtype=np.int64)
         sched._process_block(toks, dict(sched._slots),
                              dispatched_at=time.monotonic())

@@ -1,8 +1,9 @@
 """Overlapped scheduler pipeline (tpu.pipeline_depth): edge semantics.
 
-The pipelined dispatch loop keeps up to `pipeline_depth` decode blocks
-in flight on the device and moves detokenize/event-build/delivery onto
-a bounded-queue emit worker. These tests pin the seams the overlap
+The pipelined dispatch loop keeps `pipeline_depth` decode blocks (at
+least two) and the admission dispatches between them in flight on the
+device, and moves detokenize/event-build/delivery onto a bounded-queue
+emit worker. These tests pin the seams the overlap
 opens:
 
   - token identity: a real tiny CPU engine must produce byte-identical
@@ -18,6 +19,12 @@ opens:
     every stream open (no hung client); the bounded queue is the
     backpressure contract — a slow sink stalls the dispatch thread
     instead of letting it run unboundedly ahead.
+
+  - admission in flight (PR 31): an admission dispatch joins the same
+    in-flight queue as the decode blocks and its first tokens are read in
+    device order, with the next block already queued behind them; what
+    the first token decides (EOS, budget, cancel, a device error) happens
+    at the read; the per-block budget charges estimated device seconds.
 
 White-box cases drive scheduler internals on a fake engine (no JAX, no
 engine thread) exactly like test_scheduler_emit.py; the threaded cases
@@ -101,6 +108,7 @@ class TestDispatchSyncWindow:
         cancelled: list = []
         submit(sched, b"r0", cancelled=lambda: bool(cancelled))
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         toks = eng.decode_steps_dispatch()
         snapshot = dict(sched._slots)  # the dispatch point
@@ -126,6 +134,7 @@ class TestDispatchSyncWindow:
         sched = Scheduler(eng, emit_batch=batches.append)
         submit(sched, b"r0")
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         snapshot = dict(sched._slots)
         toks_n = eng.decode_steps_dispatch()
@@ -139,6 +148,7 @@ class TestDispatchSyncWindow:
         # The freed slot is re-admitted before block N+1 syncs.
         submit(sched, b"r1")
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         assert 0 in sched._slots and sched._slots[0].req.id == "r1"
         tokens_before = sched.metrics["tokens"]
@@ -163,9 +173,11 @@ class TestDispatchSyncWindow:
         sched = Scheduler(eng, emit_batch=batches.append)
         submit(sched, b"r0")
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         submit(sched, b"late", deadline_at=time.monotonic() - 0.01)
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         (ev,) = events_of(batches, "late")
         assert ev.done and ev.finish_reason == "expired"
@@ -258,6 +270,329 @@ class TestEmitWorkerFaults:
         assert max(lead) <= 2 + 1 + 2, f"dispatch ran ahead: {max(lead)}"
 
 
+class LazyToks:
+    """Something still on the device: `np.asarray` waits for it (and
+    logs the read); `is_ready` says whether it would."""
+
+    def __init__(self, arr, log, name, wall=0.0, error=None):
+        self.arr = np.asarray(arr, dtype=np.int32)
+        self.shape = self.arr.shape
+        self.log, self.name, self.wall, self.error = log, name, wall, error
+        self.read = False
+
+    def is_ready(self):
+        return self.read
+
+    def __array__(self, dtype=None, copy=None):
+        if not self.read and self.wall:
+            time.sleep(self.wall)
+        self.read = True
+        self.log.append(("read", self.name))
+        if self.error is not None:
+            raise self.error
+        return self.arr
+
+
+class AsyncFakeEngine(FakeEngine):
+    """A fake device with the DISPATCH forms of admission: a dispatch
+    returns at once and logs itself; reading its tokens waits."""
+
+    def __init__(self, *, first=ord("A"), prefill_wall=0.0, batch_cap=4,
+                 **kw):
+        super().__init__(**kw)
+        self.log: list[tuple[str, str]] = []
+        self.first = first
+        self.prefill_wall = prefill_wall
+        self.batch_cap = batch_cap
+        self.prefill_error = None
+        self.n_prefills = 0
+        self.prefill_order: list[bytes] = []
+
+    def prefill_batches_for(self, bucket):
+        return (self.batch_cap,)
+
+    def prefill_and_insert_many_dispatch(self, group):
+        name = f"P{self.n_prefills}"
+        self.n_prefills += 1
+        self.prefill_order.extend(bytes(ids) for _s, ids, _p in group)
+        self.log.append(("dispatch", name))
+        return LazyToks([self.first] * len(group), self.log, name,
+                        self.prefill_wall, self.prefill_error)
+
+    def decode_steps_dispatch(self):
+        name = f"B{self.dispatches}"
+        self.log.append(("dispatch", name))
+        return LazyToks(super().decode_steps_dispatch(), self.log, name,
+                        0.002)
+
+
+class SyncOnlyEngine(FakeEngine):
+    """Only the synchronous admission forms (the multi-host lead's
+    surface): results are host values when the call returns."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.log: list[tuple[str, str]] = []
+
+    def prefill_and_insert(self, slot, ids, sampling):
+        self.log.append(("dispatch", "P"))
+        return super().prefill_and_insert(slot, ids, sampling)
+
+    def prefill_and_insert_many(self, group):
+        self.log.append(("dispatch", "P"))
+        return super().prefill_and_insert_many(group)
+
+
+def run_to_completion(sched, prompts, max_new=9):
+    """Start the loop, serve `prompts`, stop; returns {id: [events]}."""
+    got = {p.decode(): [] for p in prompts}
+    done = {p.decode(): threading.Event() for p in prompts}
+
+    def sink(batch):
+        for req, ev in batch:
+            got[req.id].append(ev)
+            if ev.done:
+                done[req.id].set()
+
+    sched._emit_batch = sink
+    sched.start()
+    try:
+        for p in prompts:
+            submit(sched, p, max_new=max_new)
+            time.sleep(0.01)
+        for rid, ev in done.items():
+            assert ev.wait(30), f"{rid} hung"
+    finally:
+        sched.stop(timeout=10)
+    assert not sched._thread.is_alive()
+    return got
+
+
+class TestAdmissionInFlight:
+    """Admission dispatches join the in-flight queue (PR 31)."""
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_block_is_queued_before_the_admission_is_read(self, depth):
+        """Invariant (a): the decode block after an admission is
+        dispatched BEFORE that admission's tokens are read; and entries
+        are read in dispatch order (device order)."""
+        eng = AsyncFakeEngine(slots=4, prefill_wall=0.005)
+        sched = Scheduler(eng, pipeline_depth=depth)
+        got = run_to_completion(
+            sched, [b"r0", b"r1", b"r2", b"r3", b"r4", b"r5"], max_new=30)
+        for rid, evs in got.items():
+            assert evs[-1].finish_reason == "length", rid
+            assert "".join(ev.text for ev in evs) == "A" + "b" * 29
+        log = eng.log
+        dispatched = [n for kind, n in log if kind == "dispatch"]
+        reads = [n for kind, n in log if kind == "read"]
+        assert reads == dispatched[:len(reads)], "read out of device order"
+        assert eng.n_prefills >= 2
+        for i, (kind, name) in enumerate(log):
+            if kind == "read" and name.startswith("P"):
+                at = log.index(("dispatch", name))
+                assert any(k == "dispatch" and n.startswith("B")
+                           for k, n in log[at:i]), (
+                    f"{name} was read with no decode block queued "
+                    f"behind it: {log[at:i + 1]}")
+        st = sched.stats()
+        assert st["admit"]["reads"] == eng.n_prefills
+        assert st["admit"]["ready_at_read"] < st["admit"]["reads"]
+        assert st["admit"]["wait_s"] > 0 and st["admit"]["device_s"] > 0
+        assert st["admit_dispatches"] == eng.n_prefills
+
+    def test_lane_is_live_from_dispatch_and_joins_the_next_block(self):
+        """The slot is registered at dispatch, so the snapshot of the
+        block dispatched behind the prefill names the request; its tokens
+        of that block follow its first token."""
+        eng = AsyncFakeEngine(slots=2)
+        batches: list = []
+        sched = Scheduler(eng, emit_batch=batches.append)
+        submit(sched, b"r0")
+        sched._admit_new()
+        assert 0 in sched._slots and not sched._free.count(0)
+        assert sched._slots[0].first_token_at is None
+        (adm,) = sched._pending
+        assert [(s, r.id) for s, r, _a in adm.members] == [(0, "r0")]
+        snapshot = dict(sched._slots)
+        toks = eng.decode_steps_dispatch()     # queued behind the prefill
+        sched._read_admissions()
+        assert not sched._pending
+        assert sched._slots[0].first_token_at is not None
+        sched._process_pending(
+            ("decode_block", toks, snapshot, time.monotonic(), None))
+        sched._flush_events()
+        text = "".join(ev.text for ev in events_of(batches, "r0"))
+        assert text == "A" + "b" * 4
+
+    def test_sync_only_engine_takes_the_same_path(self):
+        """An engine with only the synchronous forms goes through the
+        same queue: its results are already on the host, the read is a
+        no-op, and its device seconds are its dispatch wall."""
+        eng = SyncOnlyEngine(slots=2)
+        sched = Scheduler(eng)
+        got = run_to_completion(sched, [b"s0", b"s1", b"s2"], max_new=13)
+        for rid, evs in got.items():
+            assert "".join(ev.text for ev in evs) == "A" + "b" * 12, rid
+        st = sched.stats()
+        assert st["admit"]["reads"] == st["admit_dispatches"] >= 2
+        assert st["admit"]["ready_at_read"] == st["admit"]["reads"]
+        assert st["admit"]["device_s"] == pytest.approx(st["admit_s"],
+                                                        abs=1e-5)
+
+    @pytest.mark.parametrize("case", ["eos", "max_new_1", "cancel",
+                                      "device_error"])
+    def test_what_the_first_token_decides_happens_at_the_read(self, case):
+        """First token EOS / budget of one / cancel between dispatch and
+        read / device error at the read: the right terminal event, the
+        slot back in _free, the lane parked, and no token of the block
+        already in flight delivered."""
+        eng = AsyncFakeEngine(
+            slots=1, first=ByteTokenizer.EOS if case == "eos" else ord("A"))
+        if case == "device_error":
+            eng.prefill_error = RuntimeError("HBM parity")
+        batches: list = []
+        sched = Scheduler(eng, emit_batch=batches.append)
+        cancelled: list = []
+        submit(sched, b"r0", max_new=1 if case == "max_new_1" else 100,
+               cancelled=lambda: bool(cancelled))
+        sched._admit_new()
+        sched._flush_events()
+        assert not events_of(batches, "r0")    # nothing decided yet
+        snapshot = dict(sched._slots)
+        assert list(snapshot) == [0]
+        toks = eng.decode_steps_dispatch()     # in flight behind it
+        if case == "cancel":
+            cancelled.append(True)
+        sched._read_admissions()
+        (ev,) = events_of(batches, "r0")
+        want = {"eos": ("stop", ""), "max_new_1": ("length", "A"),
+                "cancel": ("cancelled", ""),
+                "device_error": ("error", "")}[case]
+        assert ev.done and (ev.finish_reason, ev.text) == want
+        if case == "device_error":
+            assert "HBM parity" in ev.error
+        assert not sched._slots and sched._free == [0]
+        assert eng.released == [0]
+        tokens_before = sched.metrics["tokens"]
+        n_batches = len(batches)
+        sched._process_pending(
+            ("decode_block", toks, snapshot, time.monotonic(), None))
+        sched._flush_events()
+        assert len(batches) == n_batches
+        assert sched.metrics["tokens"] == tokens_before
+        # the lane is reusable at once
+        submit(sched, b"r1")
+        sched._admit_new()
+        assert sched._slots[0].req.id == "r1"
+
+
+class TestEstimatedSecondsBudget:
+    """The per-block admission bound charges each dispatch an estimate
+    of its DEVICE seconds (the dispatch itself returns at once)."""
+
+    def _busy(self, measured_s, n=4, batch_cap=1):
+        """A scheduler with one live stream and `n` queued one-request
+        prefills of a shape last measured at `measured_s`."""
+        eng = AsyncFakeEngine(slots=8, batch_cap=batch_cap)
+        sched = Scheduler(eng, admit_seconds_per_block=0.1)
+        submit(sched, b"occ")
+        sched._admit_new()
+        sched._read_admissions()
+        assert len(sched._slots) == 1
+        if measured_s is not None:
+            shape = ("prefill", batch_cap, 16, 16 * batch_cap)
+            sched._shape_s[shape] = measured_s
+        prompts = [b"q%d" % i for i in range(n)]
+        for p in prompts:
+            submit(sched, p)
+        sched._spent_this_block = 0.0
+        return sched, eng, prompts
+
+    def test_a_slow_shape_lands_once_per_block(self):
+        sched, eng, prompts = self._busy(0.4)
+        sched._admit_new()
+        assert eng.prefill_order[1:] == prompts[:1]
+        assert sched._spent_this_block == pytest.approx(0.4)
+        # the next block takes the next one, in arrival order
+        sched._spent_this_block = 0.0
+        sched._admit_new()
+        assert eng.prefill_order[1:] == prompts[:2]
+
+    def test_a_fast_shape_lands_several_times(self):
+        sched, eng, prompts = self._busy(0.04)
+        sched._admit_new()
+        # 0.04 + 0.04 < 0.1 <= 0.12: three dispatches, then the bound
+        assert eng.prefill_order[1:] == prompts[:3]
+        assert sched._spent_this_block == pytest.approx(0.12)
+
+    def test_an_unmeasured_shape_is_charged_by_its_tokens(self):
+        """Before a shape has run: padded tokens x the slowest per-token
+        rate any shape last showed. 16 tokens at 0.01 s a token = 0.16 s:
+        one a block."""
+        sched, eng, prompts = self._busy(None)
+        sched._shape_s.clear()
+        sched._shape_s.update({
+            ("prefill", 4, 64, 256): 0.512,     # 0.002 s a token
+            ("prefill", 1, 32, 32): 0.32})      # 0.01: the slowest
+        sched._admit_new()
+        assert eng.prefill_order[1:] == prompts[:1]
+        assert sched._spent_this_block == pytest.approx(0.16)
+
+    def test_the_read_measures_the_shape(self):
+        """The interval between the ready stamps of an entry and the one
+        before it — both waited for — becomes the shape's next charge;
+        an admission dispatched to an idle device only bounds it."""
+        eng = AsyncFakeEngine(slots=4, batch_cap=1, prefill_wall=0.03)
+        sched = Scheduler(eng, admit_seconds_per_block=0.1)
+        submit(sched, b"m0")
+        sched._admit_new()
+        sched._read_admissions()        # device idle before: not exact
+        assert not sched._shape_s
+        first = sched.stats()["admit"]["device_s"]
+        assert 0.03 <= first < 0.2
+        snapshot = dict(sched._slots)
+        block = eng.decode_steps_dispatch()
+        submit(sched, b"m1")
+        sched._admit_new()              # queued behind the block
+        sched._process_pending(
+            ("decode_block", block, snapshot, time.monotonic(), None))
+        sched._read_admissions()
+        measured = sched._shape_s[("prefill", 1, 16, 16)]
+        assert 0.03 <= measured < 0.1
+        assert sched.stats()["admit"]["device_s"] == pytest.approx(
+            first + measured, abs=1e-5)
+        assert sched.stats()["admit_dispatch_s"]["count"] == 2
+        assert sched.stats()["admit"]["ready_at_read"] == 0
+
+    def test_deferred_units_keep_arrival_order(self):
+        """A group spanning two buckets whose first unit exhausts the
+        budget defers the second — to _deferred, ahead of later
+        arrivals."""
+        eng = AsyncFakeEngine(slots=8, batch_cap=4)
+        sched = Scheduler(eng, admit_seconds_per_block=0.1)
+        submit(sched, b"occ")
+        sched._admit_new()
+        sched._read_admissions()
+        sched._shape_s[("prefill", 4, 16, 64)] = 0.4
+        sched._shape_s[("prefill", 4, 32, 128)] = 0.4
+        short1, short2 = b"r1", b"r3"                       # bucket 16
+        long1, long2, late = (b"x2" + b"x" * 18, b"x4" + b"x" * 18,
+                              b"x5" + b"x" * 18)            # bucket 32
+        for p in (short1, long1, short2, long2, late):
+            submit(sched, p)
+        sched._spent_this_block = 0.0
+        sched._admit_new()
+        assert [bytes(r.prompt_ids) for r in sched._deferred] == [
+            long1, long2]
+        assert eng.prefill_order[1:] == [short1, short2]
+        sched._spent_this_block = 0.0
+        sched._admit_new()
+        order = eng.prefill_order
+        assert order.index(long1) < order.index(long2) < order.index(late)
+        assert not sched._deferred
+
+
 class TestDepthTokenIdentity:
     """Real tiny CPU engine: the A/B invariant the tentpole pins."""
 
@@ -338,3 +673,116 @@ class TestDepthTokenIdentity:
             assert stats["dispatch_thread_block_s"]["p50"] is not None
             assert "pipeline_live_depth" in stats
             assert "emit_queue_depth" in stats
+
+
+class SyncFormsOnly:
+    """The real engine behind only its synchronous admission forms (what
+    the multi-host lead offers): the scheduler's getattr for a dispatch
+    form finds nothing."""
+
+    HIDDEN = ("prefill_and_insert_many_dispatch",
+              "prefill_and_insert_cached_dispatch",
+              "advance_chunked_prefill_dispatch")
+
+    def __init__(self, engine):
+        self._engine = engine
+
+    def __getattr__(self, name):
+        if name in self.HIDDEN:
+            raise AttributeError(name)
+        return getattr(self._engine, name)
+
+
+class TestAdmissionFormIdentity:
+    """Real tiny CPU engine, every admission path — full prefill, chunked
+    prefill, the prefix-cached path and the seeded chunked one: greedy
+    and seeded streams are token-identical whether admission dispatches
+    (and is read in device order) or waits in place."""
+
+    WAVE_1 = [
+        (b"pipeline greedy one", SamplingParams()),          # chunked
+        (b"greedy two", SamplingParams()),                   # one dispatch
+        (b"seeded sampled",
+         SamplingParams(temperature=0.8, top_k=8, seed=7)),
+        (b"pipeline greedy one, with a longer tail",
+         SamplingParams(temperature=0.7, top_k=4, seed=11)),  # chunked
+    ]
+    # The same prompts again now hit the radix cache (short suffix: the
+    # cached path), and a new tail on a cached prefix takes the seeded
+    # chunked path.
+    WAVE_2 = WAVE_1 + [
+        (b"pipeline greedy another tail of twenty-five",
+         SamplingParams(temperature=0.9, top_k=8, seed=3)),
+    ]
+
+    @pytest.fixture(scope="class")
+    def setup(self):
+        import jax
+        import jax.numpy as jnp
+
+        from symmetry_tpu.models import init_params, preset
+
+        cfg = preset("tiny")
+        return cfg, init_params(cfg, jax.random.key(0), jnp.float32)
+
+    def _serve(self, cfg, params, *, sync_only, depth):
+        import jax.numpy as jnp
+
+        from symmetry_tpu.engine.engine import InferenceEngine
+
+        engine = InferenceEngine(
+            cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=96,
+            prefill_buckets=(16, 48), cache_dtype=jnp.float32,
+            decode_block=4, prefill_chunk=16, prefix_cache_bytes=1 << 20,
+            prefix_block_tokens=16)
+        sigs, stats = [], []
+        for wave in (self.WAVE_1, self.WAVE_2):
+            # A scheduler per wave, everything queued before it starts:
+            # the grouping is then the same in every run.
+            sched = Scheduler(
+                SyncFormsOnly(engine) if sync_only else engine,
+                debug_invariants=True, pipeline_depth=depth)
+            results = {i: [] for i in range(len(wave))}
+            done = {i: threading.Event() for i in range(len(wave))}
+            for i, (prompt, sampling) in enumerate(wave):
+                def emit(ev, i=i):
+                    results[i].append(ev)
+                    if ev.done:
+                        done[i].set()
+                sched.submit(GenRequest(
+                    prompt_ids=list(prompt), sampling=sampling,
+                    max_new_tokens=12, emit=emit, id=f"r{i}"))
+            sched.start()
+            try:
+                for i, ev in done.items():
+                    assert ev.wait(120), f"r{i} hung"
+            finally:
+                sched.stop()
+            sigs.append({
+                i: ("".join(ev.text for ev in evs),
+                    [ev.token_id for ev in evs if ev.token_id is not None],
+                    evs[-1].tokens_generated, evs[-1].finish_reason,
+                    evs[0].tokens_reused)
+                for i, evs in results.items()})
+            stats.append(sched.stats())
+        return sigs, stats
+
+    def test_dispatch_and_sync_forms_agree(self, setup):
+        cfg, params = setup
+        ref, ref_stats = self._serve(cfg, params, sync_only=True, depth=2)
+        for depth in (1, 2):
+            got, stats = self._serve(cfg, params, sync_only=False,
+                                     depth=depth)
+            assert got == ref, f"depth {depth}"
+            for st, rst in zip(stats, ref_stats):
+                assert st["tokens"] == rst["tokens"]
+                assert st["admit"]["reads"] == rst["admit"]["reads"] > 0
+                assert st["chunk_dispatches"] == rst["chunk_dispatches"]
+        # every path ran: chunks in wave 1; in wave 2 cache hits through
+        # both the short-suffix and the seeded chunked path
+        assert ref_stats[0]["chunk_dispatches"] >= 4
+        assert ref_stats[1]["prefix_cache"]["hits"] >= 3
+        assert 0 < ref_stats[1]["chunk_dispatches"] < (
+            ref_stats[0]["chunk_dispatches"])
+        reused = [sig[4] for sig in ref[1].values()]
+        assert reused[0] == 16 and reused[3] == 32 and reused[4] == 16

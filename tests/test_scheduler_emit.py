@@ -92,6 +92,7 @@ class TestBatchedBlockEmit:
         for rid in (b"r0", b"r1", b"r2"):
             submit(sched, rid)
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         assert len(batches) == 1  # activation: 3 first tokens, 1 flush
         assert len(batches[0]) == 3
@@ -118,6 +119,7 @@ class TestBatchedBlockEmit:
         submit(sched, b"r0")
         submit(sched, b"r1")
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         toks = np.full((8, 2), ord("b"), dtype=np.int32)
         slot0 = next(s for s, a in sched._slots.items() if a.req.id == "r0")
@@ -143,6 +145,7 @@ class TestBatchedBlockEmit:
         sched, batches = make_scheduler(eng)
         submit(sched, b"r0", max_new=5)  # 1 at prefill + 4 in the block
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         toks = np.full((8, 1), ord("b"), dtype=np.int32)
         sched._process_block(toks, dict(sched._slots))
@@ -160,6 +163,7 @@ class TestBatchedBlockEmit:
         sched, batches = make_scheduler(eng)
         submit(sched, b"r0", max_new=4)  # budget: 3 block tokens
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         toks = np.full((8, 1), ord("b"), dtype=np.int32)
         toks[2, 0] = ByteTokenizer.EOS  # the 3rd = budget-exhausting token
@@ -175,6 +179,7 @@ class TestBatchedBlockEmit:
         cancelled = []
         submit(sched, b"r0", cancelled=lambda: bool(cancelled))
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         tokens_before = sched.metrics["tokens"]
         cancelled.append(True)  # lands between dispatch and processing
@@ -195,6 +200,7 @@ class TestBatchedBlockEmit:
         sched, batches = make_scheduler(eng)
         submit(sched, b"r0")
         sched._admit_new()
+        sched._read_admissions()
         sched._flush_events()
         two = "é".encode()  # 2-byte codepoint
         block1 = np.array([[ord("x")], [two[0]]], dtype=np.int32)
@@ -221,6 +227,7 @@ class TestDeferredAdmissionFifo:
             eng, admit_seconds_per_block=1e-9)
         submit(sched, b"occ")       # occupier engages the admission budget
         sched._admit_new()
+        sched._read_admissions()
         assert len(sched._slots) == 1
 
         short, long = b"r1", b"r3"  # bucket 16
@@ -231,6 +238,7 @@ class TestDeferredAdmissionFifo:
 
         sched._spent_this_block = 0.0
         sched._admit_new()
+        sched._read_admissions()
         # group [r1, l2, r3, l4] split by bucket: unit [r1, r3] dispatched,
         # unit [l2, l4] deferred on the exhausted budget
         assert [bytes(r.prompt_ids) for r in sched._deferred] == [l2, l4]
@@ -238,6 +246,7 @@ class TestDeferredAdmissionFifo:
 
         sched._spent_this_block = 0.0
         sched._admit_new()
+        sched._read_admissions()
         order = eng.prefill_order
         # Deferred l2/l4 admit before the later arrivals l5/l6.
         assert order.index(l2) < order.index(l5)
@@ -252,6 +261,7 @@ class TestDeferredAdmissionFifo:
         sched, _ = make_scheduler(eng, admit_seconds_per_block=1e-9)
         submit(sched, b"occ")
         sched._admit_new()
+        sched._read_admissions()
         submit(sched, b"s1")                 # bucket 16
         submit(sched, b"x" * 20)             # bucket 32 -> second unit
         sched._spent_this_block = 0.0
