@@ -86,6 +86,12 @@ def provider_config(preset: str, mesh_model: int) -> dict:
             "max_batch_size": SLOTS, "max_seq_len": MAX_SEQ,
             "prefill_buckets": [BUCKET], "decode_block": BLOCK,
             **({"mesh": {"model": mesh_model}} if mesh_model > 1 else {}),
+            # No prompt of the smoke is chunked (one bucket of 128 under
+            # the default chunk of 256), so say so: chunked prefill is
+            # REFUSED for a model with recurrent layers (`--preset
+            # granite-4.0-h-small`; models/hybrid.py state_refusals), and
+            # this process may not import jax to ask which preset is one.
+            "prefill_chunk": None,
         },
     }
 
@@ -310,6 +316,7 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
         "attention": attention,
         "sampling": startup.get("sampling"),
         **({"moe": startup["moe"]} if startup.get("moe") else {}),
+        **({"ssm": startup["ssm"]} if startup.get("ssm") else {}),
         "startup_s": round(startup_s, 1),
         "build_s": startup.get("build_s"),
         "warmup_s": startup.get("warmup_s"),

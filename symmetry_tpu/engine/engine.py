@@ -219,6 +219,21 @@ class InferenceEngine:
                 f"(KV handoff snapshots the cache host-side); drop the "
                 f"role or the mesh")
         self.role = role
+        # A model with recurrent layers keeps a state per slot that is not
+        # a row per position (models/hybrid.py): what cannot carry it is
+        # refused here, never served wrong.
+        self._has_state = bool(getattr(config, "layer_types", None))
+        if self._has_state:
+            from symmetry_tpu.models.hybrid import state_refusals
+
+            refused = state_refusals(
+                mesh=mesh is not None, role=role,
+                prefix_cache=prefix_cache_bytes > 0,
+                speculative=speculative is not None,
+                prefill_chunk=prefill_chunk)
+            if refused:
+                raise EngineError(refused[0])
+        self.ssm_counters = {"prefill_tokens": 0, "state_installs": 0}
         # W8A16 fused-dequant routing (tpu.fused_dequant): pack the int8
         # weight leaves into the Pallas kernel's tile layout ONCE, here —
         # the layout is the routing (qmatmul dispatches on the leaf
@@ -511,13 +526,17 @@ class InferenceEngine:
             """Copy row `row` of a batch-N prefilled prefix into decode
             slot `slot` (scalars arrive as [N] arrays, indexed by row)."""
 
-            def place(big, small_batch):
+            def place(big, small_batch, axis=1):
                 # big [L,B,T,...] <- small_batch[:, row] at [:, slot, 0]
-                # (KV payloads are rank 5, scale planes rank 4)
-                sizes = (small_batch.shape[0], 1) + small_batch.shape[2:]
-                src = (0, row) + (0,) * (small_batch.ndim - 2)
+                # (KV payloads are rank 5, scale planes rank 4); `axis` is
+                # where the batch lies (2 in the conv tails)
+                sizes = tuple(1 if d == axis else n
+                              for d, n in enumerate(small_batch.shape))
+                src = tuple(row if d == axis else 0
+                            for d in range(small_batch.ndim))
                 small = jax.lax.dynamic_slice(small_batch, src, sizes)
-                start = (0, slot, 0) + (0,) * (big.ndim - 3)
+                start = tuple(slot if d == axis else 0
+                              for d in range(big.ndim))
                 return jax.lax.dynamic_update_slice(
                     big, small.astype(big.dtype), start)
 
@@ -530,6 +549,11 @@ class InferenceEngine:
                 **({"k_scale": place(state.cache.k_scale, prefix.k_scale),
                     "v_scale": place(state.cache.v_scale, prefix.v_scale)}
                    if self.kv_quant else {}),
+                # The whole recurrent state of the lane is overwritten:
+                # this copy is also the lane's reset (a length of 0 is not).
+                **({"ssm": place(state.cache.ssm, prefix.ssm),
+                    "conv": place(state.cache.conv, prefix.conv, axis=2)}
+                   if state.cache.ssm is not None else {}),
             )
             return DecodeState(
                 cache=cache,
@@ -881,7 +905,27 @@ class InferenceEngine:
         budget = max(self.prefill_token_budget, bucket)
         return tuple(b for b in self.PREFILL_BATCHES
                      if b * bucket <= budget
-                     and (b == 1 or b <= self.max_slots))
+                     and (b == 1 or b <= min(self.max_slots,
+                                             self._state_rows_max())))
+
+    # What one prefill buffer may hold of recurrent state: every ROW of a
+    # buffer carries a slot's whole state whatever its bucket (37.7 MB at
+    # granite-4.0-h-small: 4 rows), and the prefill program holds a second
+    # copy of it in the layout its state einsum writes. Narrow batches also
+    # keep an admission dispatch short beside a decode block: the per-block
+    # admission budget is checked between dispatches, so the longest gap a
+    # stream sees is a block plus one dispatch past the budget (PERF.md,
+    # PR 33: at 8 rows `gap_p99_s` spread 10% over six seeds).
+    STATE_SCRATCH_BYTES = 160 << 20
+
+    def _state_rows_max(self) -> int:
+        """Widest prefill batch a model with recurrent layers takes, from
+        the shape alone; the widest there is for any other model."""
+        per_row = self.state_bytes_per_slot()
+        if not per_row:
+            return self.PREFILL_BATCHES[-1]
+        return max(1, min(self.PREFILL_BATCHES[-1],
+                          self.STATE_SCRATCH_BYTES // per_row))
 
     def _request_keys(self, sampling: SamplingParams) -> tuple[Any, Any]:
         """(prefill key, decode key) for one request: seeded requests
@@ -1025,6 +1069,8 @@ class InferenceEngine:
         prefill_keys, decode_keys_arr = self._group_keys(
             [sampling for _, _, sampling in assignments], batch)
 
+        if self._has_state:
+            self.ssm_counters["prefill_tokens"] += int(lens[:n_req].sum())
         lens_arr = jnp.asarray(lens)
         temps_arr = jnp.asarray(temps)
         top_ps_arr = jnp.asarray(top_ps)
@@ -1245,12 +1291,51 @@ class InferenceEngine:
         plus scale planes when int8-quantized) — sizes handoff frames
         and the decode tier's adoption-budget floor."""
         c = self.config
-        per_plane = c.num_layers * c.num_kv_heads
+        # a model with recurrent layers keeps K/V for its attention layers
+        # alone (state_bytes_per_slot has the rest of a slot)
+        n_layers = (len(c.layers_of("attention")) if self._has_state
+                    else c.num_layers)
+        per_plane = n_layers * c.num_kv_heads
         if self.kv_quant:
             # int8 payload + one f32 scale per (layer, head, position)
             return 2 * per_plane * (c.dim_per_head + 4)
         return 2 * per_plane * c.dim_per_head * jnp.dtype(
             self.cache_dtype).itemsize
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes a slot holds that are not rows per position: the mamba
+        layers' recurrent state and convolution tails; 0 for a model
+        without such layers."""
+        if not self._has_state:
+            return 0
+        from symmetry_tpu.models.hybrid import state_bytes_per_slot
+
+        return sum(state_bytes_per_slot(self.config,
+                                        self.cache_dtype).values())
+
+    def ssm_report(self) -> dict | None:
+        """`startup.ssm`: what the recurrent layers keep and which form
+        each program kind takes; None for a model without them."""
+        if not self._has_state:
+            return None
+        from symmetry_tpu.models.hybrid import state_bytes_per_slot
+
+        c = self.config
+        per_slot = state_bytes_per_slot(c, self.cache_dtype)
+        return {
+            "mamba_layers": len(c.layers_of("mamba")),
+            "attention_layers": len(c.layers_of("attention")),
+            "state_bytes_per_slot": per_slot["ssm"],
+            "conv_bytes_per_slot": per_slot["conv"],
+            "state_bytes": sum(per_slot.values()) * self.max_slots,
+            "state_dtype": str(self.state.cache.ssm.dtype),
+            "conv_dtype": str(self.state.cache.conv.dtype),
+            "prefill": {"form": "chunked (jnp)",
+                        "chunk": c.mamba_chunk_size},
+            "decode": {"form": "step (jnp), state updated in place"},
+            "prefill_rows_max": self._state_rows_max(),
+            "scratch_rows_max": 2 * self._state_rows_max(),
+        }
 
     def extract_slot_kv(self, slot: int, p: int):
         """Batch-1 snapshot of decode-lane `slot`'s KV, lengths pinned to
@@ -1572,6 +1657,18 @@ class InferenceEngine:
                 continue
             self._prefill_scratch.pop(old_key)  # dropped ref frees HBM
             total -= old_key[0] * old_key[1]
+        if self._has_state:
+            # Every ROW of a buffer carries a whole recurrent state
+            # whatever its bucket, so tokens do not bound the pool's
+            # bytes: rows do — twice the widest batch (_state_rows_max),
+            # from the shape alone.
+            rows = sum(b for (b, _) in self._prefill_scratch)
+            for old_key in list(self._prefill_scratch):
+                if rows <= max(2 * self._state_rows_max(), batch):
+                    break
+                if old_key != key:
+                    self._prefill_scratch.pop(old_key)
+                    rows -= old_key[0]
 
     def release_slot(self, slot: int) -> None:
         """A finished slot's cache lane is garbage until reuse (insert
@@ -1597,6 +1694,9 @@ class InferenceEngine:
         `slots` (every insert goes through here: a lane that is reused
         before the next decode program is no longer one to park)."""
         self._park[np.asarray(slots)] = False
+        if self._has_state:
+            self.ssm_counters["state_installs"] += len(set(
+                np.asarray(slots).tolist()))
         self.state = self._insert_all(self.state, prefix,
                                       jnp.asarray(slots), *rows)
 
@@ -1903,14 +2003,19 @@ class InferenceEngine:
         from symmetry_tpu.models.moe import moe_layout, moe_route
         from symmetry_tpu.ops.quant import QuantizedTensor
 
-        wg = self.params["layers"]["wg"]
+        layers = self.params["layers"]
+        wg = layers.get("ffn", layers)["wg"]
+
+        def route(tokens: int) -> str:
+            return moe_route(tokens, c.num_experts, c.num_experts_per_tok)
+
         self._moe_report = {
             "experts": c.num_experts, "top_k": c.num_experts_per_tok,
             "layout": moe_layout(self.mesh, c.intermediate_size),
             # by tokens a dispatch: decode is one per slot; a prefill is
             # batch x bucket for every shape warm-up compiles
-            "route": {"decode": moe_route(self.max_slots),
-                      "prefill": {str(t): moe_route(t) for t in sorted({
+            "route": {"decode": route(self.max_slots),
+                      "prefill": {str(t): route(t) for t in sorted({
                           b * bucket for bucket in self.prefill_buckets
                           for b in self.prefill_batches_for(bucket)})}},
             "quantized_leaf_route": (
@@ -1919,6 +2024,10 @@ class InferenceEngine:
                 "dot's operand, scales on the accumulator"
                 if isinstance(wg, QuantizedTensor) else "not quantized"),
         }
+        if c.shared_intermediate_size:
+            self._moe_report["shared_expert"] = {
+                "width": c.shared_intermediate_size,
+                "form": "dense gated FFN, every token, weight 1"}
         return self._moe_report
 
     def decode_step(self) -> np.ndarray:

@@ -417,6 +417,9 @@ class EngineHost:
         moe = self._engine.moe_report()
         if moe is not None:
             self._startup["moe"] = moe
+        ssm = self._engine.ssm_report()
+        if ssm is not None:
+            self._startup["ssm"] = ssm
         self._write({"op": HostOp.READY,
                      "model": self._config.model_name,
                      "role": self._role,
@@ -484,6 +487,10 @@ class EngineHost:
                 m["emit"] = dict(self.emit_stats)
                 m["role"] = self._role
                 m["startup"] = self._startup
+                if "ssm" in self._startup:
+                    # valid prompt tokens the mamba layers scanned and
+                    # lanes whose state an insert overwrote, since start
+                    m["ssm"] = dict(self._engine.ssm_counters)
                 m["compile"] = self._compile.stats()
                 # Per-request emitted-token journal rider: the tokens
                 # each live stream has had WRITTEN to the pipe. The

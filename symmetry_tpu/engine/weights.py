@@ -53,7 +53,15 @@ class CheckpointError(RuntimeError):
 def convert_hf_state_dict(
     tensors: dict[str, np.ndarray], config: ModelConfig
 ) -> dict:
-    """Convert a full in-memory HF llama/mixtral state dict to our pytree."""
+    """Convert a full in-memory HF llama/mixtral state dict to our pytree
+    (a granitemoehybrid one through models/hybrid.py's name map)."""
+    if getattr(config, "layer_types", None):
+        from symmetry_tpu.models import hybrid
+
+        try:
+            return hybrid.convert_hf_state_dict(tensors, config)
+        except (KeyError, ValueError) as exc:
+            raise CheckpointError(f"granitemoehybrid checkpoint: {exc}")
     n_exp = getattr(config, "num_experts", 0)
     per_layer: dict[str, list] = {
         ours: [None] * config.num_layers
@@ -157,6 +165,10 @@ class _SafetensorsDir:
             self._handles[fpath] = self._open(fpath, framework="np")
         return self._handles[fpath]
 
+    def read(self, name: str) -> np.ndarray:
+        """The whole tensor, in the file's layout."""
+        return self._handle(name).get_tensor(name)
+
     def read_slice(self, name: str, index: tuple[slice, ...],
                    transpose: bool) -> np.ndarray:
         """Read tensor[index] where index refers to the (maybe-transposed)
@@ -208,6 +220,19 @@ def load_checkpoint(
 
     store = _SafetensorsDir(path)
     names = set(store.names())
+    if getattr(config, "layer_types", None):
+        # Two mixer kinds, stacked per kind: whole tensors through the
+        # in-memory converter, one device (no mesh for such a model yet).
+        if mesh is not None:
+            raise CheckpointError("a model with recurrent layers loads on "
+                                  "one device")
+        tensors = {n: store.read(n) for n in sorted(names)}
+        params = convert_hf_state_dict(tensors, config)
+        abstract = jax.eval_shape(
+            lambda: init_params(config, jax.random.key(0), dtype))
+        return jax.tree.map(
+            lambda a, like: jnp.asarray(a, like.dtype), params,
+            abstract), config
     tied = config.tie_embeddings or "lm_head.weight" not in names
 
     axes = param_logical_axes(config)
@@ -304,6 +329,18 @@ def save_checkpoint(path: str, params: dict, config: ModelConfig) -> None:
     from safetensors.numpy import save_file
 
     os.makedirs(path, exist_ok=True)
+    if getattr(config, "layer_types", None):
+        from symmetry_tpu.models import hybrid
+
+        c = config
+        save_file({k: np.ascontiguousarray(v) for k, v in
+                   hybrid.to_hf_state_dict(jax.device_get(params),
+                                           c).items()},
+                  os.path.join(path, "model.safetensors"))
+        with open(os.path.join(path, "config.json"), "w",
+                  encoding="utf-8") as fh:
+            json.dump(hybrid.hf_config(c), fh, indent=2)
+        return
     tensors: dict[str, np.ndarray] = {}
     inv_top = {ours: (hf, t) for hf, (ours, t) in HF_TOP_MAP.items()}
     for ours in ("embed", "final_norm", "lm_head"):

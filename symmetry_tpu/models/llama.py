@@ -77,6 +77,42 @@ class MoEConfig(ModelConfig):
 
     num_experts: int = 8
     num_experts_per_tok: int = 2
+    # width of a shared expert every token passes through beside its
+    # routed ones (0: none); the routed experts' width is intermediate_size
+    shared_intermediate_size: int = 0
+
+
+@dataclass(frozen=True)
+class HybridConfig(MoEConfig):
+    """Layers of two mixer kinds in one model (granitemoehybrid family):
+    `layer_types[i]` is "mamba" (a Mamba-2 mixer with a per-slot recurrent
+    state, models/mamba2.py) or "attention" (GQA, here without rotary
+    embedding), each followed by the routed + shared expert FFN. The
+    forward pass, parameters and cache are models/hybrid.py's; every entry
+    point of this module hands a config with `layer_types` over to it."""
+
+    layer_types: tuple[str, ...] = ()
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None   # None: 1 / sqrt(head_dim)
+    logits_scaling: float = 1.0
+    rope: bool = True                 # False: position_embedding_type nope
+
+    def __post_init__(self):
+        kinds = set(self.layer_types)
+        if (len(self.layer_types) != self.num_layers
+                or not kinds <= {"mamba", "attention"}):
+            raise ValueError(
+                f"layer_types must name {self.num_layers} layers, each "
+                f"'mamba' or 'attention'; got {self.layer_types!r}")
+
+    def layers_of(self, kind: str) -> tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
 
 
 # Named presets; sizes from the public HF configs of each model family.
@@ -126,6 +162,39 @@ PRESETS: dict[str, ModelConfig] = {
         vocab_size=32000, hidden_size=4096, num_layers=32, num_heads=32,
         num_kv_heads=8, intermediate_size=14336, rope_theta=1000000.0,
         num_experts=8, num_experts_per_tok=2,
+    ),
+    # both mixer kinds, 8 routed experts top 3 and a shared one, every
+    # multiplier off 1, a chunk shorter than the test prompts: the CPU's
+    # copy of granite-4.0-h-small's mechanisms
+    "tiny-hybrid": HybridConfig(
+        vocab_size=512, hidden_size=64, num_layers=4, num_heads=4,
+        num_kv_heads=2, intermediate_size=32, head_dim=16,
+        rope_theta=10000.0, max_position=512, tie_embeddings=True,
+        num_experts=8, num_experts_per_tok=3, shared_intermediate_size=48,
+        layer_types=("mamba", "mamba", "attention", "mamba"),
+        mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16,
+        mamba_chunk_size=16, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=1 / 16,
+        logits_scaling=4.0, rope=False,
+    ),
+    # granite-4.0-h-small (32B-A9B) CUT IN DEPTH to the first period of its
+    # layer pattern — layers 0-9 of 40: mamba x 5, attention, mamba x 4 —
+    # at every published width, with all 72 experts and the whole
+    # vocabulary: stage 1 of 4 of a pipeline, what one 16 GB chip holds in
+    # int8 (benchmarks/configs/granite-4.0-h-small.json has the cut).
+    # rope_theta is the published key; no layer uses it (rope=False).
+    "granite-4.0-h-small": HybridConfig(
+        vocab_size=100352, hidden_size=4096, num_layers=10, num_heads=32,
+        num_kv_heads=8, intermediate_size=768, head_dim=128,
+        rope_theta=10000.0, rms_eps=1e-5, tie_embeddings=True,
+        max_position=131072,
+        num_experts=72, num_experts_per_tok=10,
+        shared_intermediate_size=1536,
+        layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+        mamba_n_heads=128, mamba_d_head=64, mamba_d_state=128,
+        mamba_d_conv=4, mamba_chunk_size=256, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.0078125,
+        logits_scaling=16.0, rope=False,
     ),
     "gemma-7b": ModelConfig(
         vocab_size=256000, hidden_size=3072, num_layers=28, num_heads=16,
@@ -185,6 +254,14 @@ class KVCache(NamedTuple):
     # (token, expert) pairs computed per expert, summed over the layers of
     # every forward through this cache ([experts] int32; models/moe.py).
     expert_pairs: jnp.ndarray | None = None
+    # Models with recurrent layers only (models/hybrid.py; None, so no
+    # leaf, for every other): per (mamba layer, slot) the state after the
+    # slot's last token, ssm [n_mamba, B, H, P, N] float32, and its last
+    # d_conv - 1 convolution inputs, conv [n_mamba, d_conv - 1, B, C].
+    # Neither is indexed by position; k / v then hold the ATTENTION layers
+    # alone, in pattern order.
+    ssm: jnp.ndarray | None = None
+    conv: jnp.ndarray | None = None
 
     @property
     def quantized(self) -> bool:
@@ -195,6 +272,12 @@ def init_cache(
     config: ModelConfig, batch: int, capacity: int, dtype=jnp.bfloat16,
     *, quantized: bool = False, count_experts: bool = False,
 ) -> KVCache:
+    if getattr(config, "layer_types", None):
+        from symmetry_tpu.models import hybrid
+
+        return hybrid.init_cache(config, batch, capacity, dtype,
+                                 quantized=quantized,
+                                 count_experts=count_experts)
     shape = (config.num_layers, batch, capacity, config.num_kv_heads,
              config.dim_per_head)
     pairs = (jnp.zeros((config.num_experts,), jnp.int32)
@@ -282,6 +365,11 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     gives the per-device share and places each slice.
     """
     c = config
+    if getattr(c, "layer_types", None):
+        from symmetry_tpu.models import hybrid
+
+        return hybrid.init_params(c, key, dtype, quantize=quantize,
+                                  slice_above=slice_above)
     keys = iter(jax.random.split(key, 16))
 
     from symmetry_tpu.ops.quant import (
@@ -338,6 +426,10 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
 
 def param_logical_axes(config: ModelConfig) -> dict:
     """Pytree of logical-axis tuples, same structure as init_params output."""
+    if getattr(config, "layer_types", None):
+        raise NotImplementedError(
+            "a model with recurrent layers has no sharding rules yet: it "
+            "runs on one device")
     moe = bool(getattr(config, "num_experts", 0))
     ffn = (("layers", "experts", "embed", "mlp") if moe
            else ("layers", "embed", "mlp"))
@@ -419,11 +511,11 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
             "decode_slot_tile": tiles[0], "decode_block_t": tiles[1]}
 
 
-def _layer(
-    h: jnp.ndarray,             # [B, S, E]
+def _attention(
+    x: jnp.ndarray,             # [B, S, E], already normed
     lp: dict,                   # one layer's params (leading L dim stripped)
     cache: KVCache,             # FULL [L, B, T, K, D] cache (lengths unused)
-    layer: jnp.ndarray,         # scalar int32 layer index
+    layer: jnp.ndarray,         # scalar int32 index into the cache's layers
     positions: jnp.ndarray,     # [B, S]
     kv_valid: jnp.ndarray,      # [B] cache length AFTER this call's writes
     seq_lens: jnp.ndarray,      # [B] valid tokens in this call's input
@@ -433,10 +525,11 @@ def _layer(
     sp_mode: str = "ring",      # static: "ring" | "ulysses" (SURVEY §5.7)
     tp_mesh=None,               # static: Mesh the trunk is GSPMD-sharded over
 ) -> tuple[jnp.ndarray, KVCache]:
-    B, S, E = h.shape
+    """The attention mixer: projections, the cache write, attention by the
+    routed path, the output projection -> ([B, S, E], cache)."""
+    B, S, E = x.shape
     D, nq, nkv = config.dim_per_head, config.num_heads, config.num_kv_heads
 
-    x = rms_norm(h, _norm_w(lp["attn_norm"], config), config.rms_eps)
     q = qmatmul(x, lp["wq"])
     k = qmatmul(x, lp["wk"])
     v = qmatmul(x, lp["wv"])
@@ -447,8 +540,14 @@ def _layer(
     q = q.reshape(B, S, nq, D)
     k = k.reshape(B, S, nkv, D)
     v = v.reshape(B, S, nkv, D)
-    q = apply_rope(q, positions, config.rope_theta)
-    k = apply_rope(k, positions, config.rope_theta)
+    if getattr(config, "rope", True):
+        q = apply_rope(q, positions, config.rope_theta)
+        k = apply_rope(k, positions, config.rope_theta)
+    scale = getattr(config, "attention_multiplier", None)
+    if scale is not None:
+        # every attention path scales scores by 1 / sqrt(D): fold the
+        # ratio to the published multiplier into q
+        q = q * jnp.asarray(scale * D ** 0.5, q.dtype)
 
     cache = write_kv(cache, layer, positions, k, v,
                      by_head=tp_mesh is not None)
@@ -509,7 +608,31 @@ def _layer(
                 sliding_window=config.sliding_window,
                 k_scale=at_layer(cache.k_scale) if cache.quantized else None,
                 v_scale=at_layer(cache.v_scale) if cache.quantized else None)
-    h = h + qmatmul(attn.reshape(B, S, nq * D), lp["wo"])
+    return qmatmul(attn.reshape(B, S, nq * D), lp["wo"]), cache
+
+
+def _layer(
+    h: jnp.ndarray,             # [B, S, E]
+    lp: dict,
+    cache: KVCache,
+    layer: jnp.ndarray,
+    positions: jnp.ndarray,
+    kv_valid: jnp.ndarray,
+    seq_lens: jnp.ndarray,
+    config: ModelConfig,
+    prefill_flash: bool,
+    ring_mesh=None,
+    sp_mode: str = "ring",
+    tp_mesh=None,
+) -> tuple[jnp.ndarray, KVCache]:
+    """One decoder layer of the homogeneous stack: attention, then the FFN
+    (dense or routed experts); arguments as `_attention`'s."""
+    x = rms_norm(h, _norm_w(lp["attn_norm"], config), config.rms_eps)
+    attn, cache = _attention(x, lp, cache, layer, positions, kv_valid,
+                             seq_lens, config, prefill_flash,
+                             ring_mesh=ring_mesh, sp_mode=sp_mode,
+                             tp_mesh=tp_mesh)
+    h = h + attn
 
     x = rms_norm(h, _norm_w(lp["mlp_norm"], config), config.rms_eps)
     if "router" in lp:
@@ -576,6 +699,14 @@ def forward_hidden(
     parallel) flash kernel instead — callers needing SP for windowed
     models must shard some other way.
     """
+    if getattr(config, "layer_types", None):
+        from symmetry_tpu.models import hybrid
+
+        if ring_mesh is not None or tp_mesh is not None:
+            raise ValueError("a model with recurrent layers runs on one "
+                             "device: no ring_mesh, no tp_mesh")
+        return hybrid.forward_hidden(params, config, tokens, cache, seq_lens,
+                                     prefill_flash=prefill_flash)
     B, S = tokens.shape
     if seq_lens is None:
         seq_lens = jnp.full((B,), S, jnp.int32)
@@ -652,12 +783,17 @@ def logits_from_hidden(params: dict, config: ModelConfig,
                        h: jnp.ndarray) -> jnp.ndarray:
     """LM head: [B, S, E] hidden -> [B, S, vocab] float32 logits."""
     head = params["embed"].T if config.tie_embeddings else params["lm_head"]
-    return qmatmul(h, head).astype(jnp.float32)
+    logits = qmatmul(h, head).astype(jnp.float32)
+    scaling = getattr(config, "logits_scaling", 1.0)
+    return logits if scaling == 1.0 else logits / scaling
 
 
 # Weights eligible for int8 quantization (all the large matmuls; the
 # embedding stays dense — it is gathered, not contracted).
-QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "lm_head")
+# `in_proj` / `out_proj` are the mamba mixer's, `sg` / `su` / `sd` the shared
+# expert's (models/hybrid.py).
+QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "in_proj",
+              "out_proj", "sg", "su", "sd", "lm_head")
 # Those of them stacked along a leading layers axis.
 STACKED_KEYS = QUANT_KEYS[:-1]
 
@@ -853,6 +989,37 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
     sliding = hf.get("sliding_window")
     if hf.get("use_sliding_window") is False:
         sliding = None
+    if hf.get("model_type") == "granitemoehybrid":
+        types = tuple(hf["layer_types"])
+        if hf.get("mamba_n_groups", 1) != 1:
+            raise ValueError("mamba_n_groups other than 1 is not implemented")
+        return HybridConfig(
+            vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf.get("num_key_value_heads",
+                                hf["num_attention_heads"]),
+            intermediate_size=hf["intermediate_size"],
+            head_dim=hf.get("head_dim"),
+            rope_theta=hf.get("rope_theta", 10000.0),
+            rms_eps=hf.get("rms_norm_eps", 1e-5),
+            tie_embeddings=hf.get("tie_word_embeddings", True),
+            max_position=hf.get("max_position_embeddings", 8192),
+            num_experts=hf["num_local_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            shared_intermediate_size=hf.get("shared_intermediate_size", 0),
+            layer_types=types,
+            mamba_n_heads=hf["mamba_n_heads"],
+            mamba_d_head=hf["mamba_d_head"],
+            mamba_d_state=hf["mamba_d_state"],
+            mamba_d_conv=hf.get("mamba_d_conv", 4),
+            mamba_chunk_size=hf.get("mamba_chunk_size", 256),
+            embedding_multiplier=hf.get("embedding_multiplier", 1.0),
+            residual_multiplier=hf.get("residual_multiplier", 1.0),
+            attention_multiplier=hf.get("attention_multiplier"),
+            logits_scaling=hf.get("logits_scaling", 1.0),
+            rope=hf.get("position_embedding_type", "rope") != "nope",
+        )
     if hf.get("num_local_experts"):
         return MoEConfig(
             vocab_size=hf["vocab_size"],

@@ -1,0 +1,196 @@
+"""Mamba-2 mixer (state-space duality layer): one step for decode, a chunked
+form for prefill, both reading and writing a per-slot recurrent state.
+
+The layer (HF `GraniteMoeHybridMambaLayer` / `Mamba2Mixer`, one group):
+
+    [z | xBC | dt] = u @ in_proj           widths H*P | H*P + 2N | H
+    xBC = silu(causal depthwise conv over K taps, with bias)
+    [x | B | C] = xBC                      x as [H, P]
+    D_t = softplus(dt + dt_bias);  a_t = exp(-D_t * exp(A_log))     per head
+    S_t = a_t * S_{t-1} + D_t * x_t (outer) B_t                     [H, P, N]
+    y_t = S_t C_t + D * x_t
+    y = rms_norm(y * silu(z)) * gate_norm  over all H*P channels, gate first
+    out = y @ out_proj
+
+What a slot keeps between calls (models/llama.py KVCache): `ssm` [B, H, P, N]
+float32 — the state S after the slot's last valid token — and `conv`
+[K-1, B, C] — its last K-1 inputs to the convolution (batch second-minor and
+channels minor: no padded dim on the chip). Neither is indexed by position.
+
+Two forms, one mathematics:
+
+- `step` (S == 1): the recurrence as written. The output is taken from the
+  OLD state — `S_t C = a (S_{t-1} C) + D_t x (B . C)` — so the state update
+  is a pure elementwise pass over the [B, H, P, N] buffer that XLA can run
+  in place, and nothing reads the new state in the same program.
+- `chunked` (S > 1; prefill): per chunk of `chunk` positions the outputs are
+  a masked [Q, Q] matrix product (the "dual" quadratic form) plus the
+  incoming state's decayed read-out, and the state moves a chunk at a time.
+  It starts from a given state and conv tail, and a row stops at its own
+  `seq_len`: positions at or past it get D_t = 0 (a = 1, no input), so the
+  state after the bucket IS the state after the row's last valid token, and
+  the new conv tail is the row's last K-1 VALID inputs. Decays, products of
+  decays and the state stay float32 and the small einsums run at `highest`
+  precision: they are a few GFLOP a layer, and bf16 passes there would cost
+  three digits of the state.
+
+The projections are `ops/quant.py` mixed dots on int8 leaves; `in_proj`'s
+accumulator is kept in float32 up to the split (dt feeds an exponential).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from symmetry_tpu.ops.quant import QuantizedTensor, qmatmul
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def sizes(config) -> dict:
+    h, p, n = (config.mamba_n_heads, config.mamba_d_head,
+               config.mamba_d_state)
+    return {"H": h, "P": p, "N": n, "K": config.mamba_d_conv,
+            "inner": h * p, "conv": h * p + 2 * n,
+            "proj": 2 * h * p + 2 * n + h}
+
+
+def _in_proj(u: jnp.ndarray, w) -> jnp.ndarray:
+    """u @ in_proj with the float32 accumulator kept (no cast back)."""
+    if isinstance(w, QuantizedTensor):
+        y = jax.lax.dot_general(
+            u, w.q, (((u.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return y * w.scale
+    return jnp.dot(u, w, preferred_element_type=jnp.float32)
+
+
+def _split(zxbcdt: jnp.ndarray, z: dict):
+    inner, conv = z["inner"], z["conv"]
+    return (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv],
+            zxbcdt[..., inner + conv:])
+
+
+def _decay(dt: jnp.ndarray, lp: dict):
+    """dt [..., H] raw -> (delta, log a), float32."""
+    delta = jax.nn.softplus(dt + lp["dt_bias"].astype(jnp.float32))
+    return delta, -delta * jnp.exp(lp["A_log"].astype(jnp.float32))
+
+
+def _gate_out(y: jnp.ndarray, gate: jnp.ndarray, lp: dict, eps: float,
+              dtype) -> jnp.ndarray:
+    """y, gate [..., inner] float32 -> the layer's output in `dtype`."""
+    y = y * jax.nn.silu(gate)
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + eps) * lp["gate_norm"].astype(jnp.float32)
+    return qmatmul(y.astype(dtype), lp["out_proj"])
+
+
+def step(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, conv: jnp.ndarray,
+         config) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One token a slot: u [B, E], ssm [B, H, P, N], conv [K-1, B, C] ->
+    (out [B, E], ssm, conv)."""
+    z = sizes(config)
+    B = u.shape[0]
+    gate, xbc, dt = _split(_in_proj(u, lp["in_proj"]), z)
+    window = jnp.concatenate([conv.astype(jnp.float32), xbc[None]], axis=0)
+    xbc = jax.nn.silu(
+        jnp.sum(window * lp["conv_w"].astype(jnp.float32)[:, None, :],
+                axis=0) + lp["conv_b"].astype(jnp.float32))
+    x = xbc[:, :z["inner"]].reshape(B, z["H"], z["P"])
+    b = xbc[:, z["inner"]:z["inner"] + z["N"]]
+    c = xbc[:, z["inner"] + z["N"]:]
+    delta, log_a = _decay(dt, lp)                               # [B, H]
+    a = jnp.exp(log_a)
+    dx = delta[..., None] * x                                   # [B, H, P]
+    # y_t = S_t C = a (S_{t-1} C) + (dt x) (B . C): reads the OLD state
+    y = (a[..., None] * jnp.einsum("bhpn,bn->bhp", ssm, c,
+                                   precision=HIGHEST)
+         + dx * jnp.sum(b * c, axis=-1)[:, None, None]
+         + lp["D"].astype(jnp.float32)[:, None] * x)
+    ssm = (a[..., None, None] * ssm
+           + dx[..., None] * b[:, None, None, :]).astype(ssm.dtype)
+    out = _gate_out(y.reshape(B, z["inner"]), gate, lp, config.rms_eps,
+                    u.dtype)
+    return out, ssm, window[1:].astype(conv.dtype)
+
+
+def _chunk(x, delta, log_a, b, c, state):
+    """One chunk of the dual form. x [B, Q, H, P], delta / log_a [B, Q, H],
+    b / c [B, Q, N], state [B, H, P, N] -> (y [B, Q, H, P], state)."""
+    Q = x.shape[1]
+    cum = jnp.cumsum(log_a, axis=1)                             # inclusive
+    dx = delta[..., None] * x                                   # [B, Q, H, P]
+    # within the chunk: y_t += sum_{s<=t} exp(cum_t - cum_s) (C_t.B_s) dx_s
+    scores = jnp.einsum("btn,bsn->bts", c, b, precision=HIGHEST)
+    cum_h = jnp.moveaxis(cum, 1, 2)                             # [B, H, Q]
+    seg = cum_h[..., :, None] - cum_h[..., None, :]             # [B, H, t, s]
+    # the mask goes on the exponent: above the diagonal seg > 0 can overflow
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), seg,
+                              -jnp.inf))
+    y = jnp.einsum("bhts,bshp->bthp", scores[:, None] * decay, dx,
+                   precision=HIGHEST)
+    # the incoming state, decayed to each position
+    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+        "bhpn,btn->bthp", state, c, precision=HIGHEST)
+    # the state at the chunk's end
+    to_end = jnp.exp(cum[:, -1:, :] - cum)                      # [B, Q, H]
+    state = (jnp.exp(cum[:, -1, :])[..., None, None] * state
+             + jnp.einsum("bshp,bsn->bhpn", to_end[..., None] * dx, b,
+                          precision=HIGHEST))
+    return y, state
+
+
+def chunked(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, conv: jnp.ndarray,
+            seq_lens: jnp.ndarray, config
+            ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """A run of positions a row: u [B, S, E], starting from ssm [B, H, P, N]
+    and conv [K-1, B, C]; row b has seq_lens[b] valid positions ->
+    (out [B, S, E], ssm, conv) with the state and tail as they stand after
+    each row's LAST VALID position."""
+    z = sizes(config)
+    B, S, _ = u.shape
+    K = z["K"]
+    gate, xbc, dt = _split(_in_proj(u, lp["in_proj"]), z)
+    padded = jnp.concatenate(
+        [jnp.moveaxis(conv, 0, 1).astype(jnp.float32), xbc], axis=1)
+    w = lp["conv_w"].astype(jnp.float32)
+    conv_out = lp["conv_b"].astype(jnp.float32) + sum(
+        w[j] * padded[:, j:j + S] for j in range(K))
+    # the row's last K-1 valid inputs: padded[seq_len .. seq_len + K-2]
+    tail_at = seq_lens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
+    tail = jnp.take_along_axis(padded, tail_at[..., None], axis=1)
+    xbc = jax.nn.silu(conv_out)
+    x = xbc[..., :z["inner"]].reshape(B, S, z["H"], z["P"])
+    b = xbc[..., z["inner"]:z["inner"] + z["N"]]
+    c = xbc[..., z["inner"] + z["N"]:]
+    delta, log_a = _decay(dt, lp)                               # [B, S, H]
+    valid = (jnp.arange(S, dtype=jnp.int32)[None, :]
+             < seq_lens[:, None])[..., None]
+    delta = jnp.where(valid, delta, 0.0)                        # a = 1 there
+    log_a = jnp.where(valid, log_a, 0.0)
+
+    Q = min(config.mamba_chunk_size, S)
+    state = ssm.astype(jnp.float32)
+    if S == Q:
+        y, state = _chunk(x, delta, log_a, b, c, state)
+    else:
+        pad = -S % Q
+        parts = [jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                 for t in (x, delta, log_a, b, c)]              # delta 0: inert
+        parts = [jnp.moveaxis(
+            t.reshape((B, (S + pad) // Q, Q) + t.shape[2:]), 1, 0)
+            for t in parts]
+
+        def body(state, xs):
+            y, state = _chunk(*xs, state)
+            return state, y
+
+        state, y = jax.lax.scan(body, state, tuple(parts))
+        y = jnp.moveaxis(y, 0, 1).reshape(B, S + pad, z["H"], z["P"])[:, :S]
+    y = y + lp["D"].astype(jnp.float32)[:, None] * x
+    out = _gate_out(y.reshape(B, S, z["inner"]), gate, lp, config.rms_eps,
+                    u.dtype)
+    return (out, state.astype(ssm.dtype),
+            jnp.moveaxis(tail, 1, 0).astype(conv.dtype))

@@ -29,8 +29,12 @@ layer, routed / dense): 64 tokens 1.35 / 0.49 (the weight stream's floor is
 / 15.8. The mixed int8 dot runs the dense form at ~90% of the MXU peak,
 `ragged_dot` with an int8 operand reaches ~35%, so routing pays only once
 it saves more than it wastes: they cross at about 1,050 tokens.
-`moe_route(T)` is that choice, from the shape alone; `startup.moe` reports
-it per program.
+`moe_route(T, experts, k)` is that choice, from the shape alone (each routing
+shape has its own measured crossing: 72 experts top 10 never route below
+2,560 tokens);
+`startup.moe` reports it per program. A config with
+`shared_intermediate_size` adds a shared expert (`sg`, `su`, `sd`: one dense
+gated FFN every token passes through) to the routed sum.
 
 int8 expert stacks stay int8 in HBM in both forms: the int8 payload is the
 dot's operand, the per-(expert, column) scale is applied to the float32
@@ -57,17 +61,33 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from symmetry_tpu.ops.quant import QuantizedTensor
+from symmetry_tpu.ops.quant import QuantizedTensor, qmatmul
 
 
 # Programs of fewer tokens take the dense mixture (module docstring: the
-# two forms tie at 1,024 tokens a dispatch and routing wins above).
+# two forms tie at 1,024 tokens a dispatch and routing wins above) — at
+# mixtral's 8 experts top 2, where it was measured.
 ROUTED_MIN_TOKENS = 1024
+# Other routing shapes, each from its own reading of tools/moe_decode_ab.py:
+# (experts, k) -> the least tokens a dispatch at which routing pays. 72 top
+# 10 at expert width 768 (granite-4.0-h-small; PERF.md, PR 33; ms a layer,
+# routed / dense): 128 tokens 5.51 / 1.15, 512: 9.09 / 4.34, 1,024: 12.03 /
+# 8.10, 2,048: 18.10 / 16.74 — `ragged_dot` over 72 groups of a width of 768
+# reaches 11% of the MXU peak where the mixture's batched dot reaches 84%,
+# so the 7.2x FLOPs are still the cheaper form up to the largest dispatch
+# (2,048 tokens); the slopes cross near 2,600. (Compiled for a v5e the
+# mixture's [X, T, D] float32 products are never held whole — 0.75 GB of
+# temporaries for a 2,048-token prefill of the ten layers — so it needs no
+# blocking at 72 experts.)
+ROUTED_FROM = {(72, 10): 2560}
 
 
-def moe_route(n_tokens: int) -> str:
-    """The form a program of `n_tokens` tokens takes."""
-    return "routed" if n_tokens >= ROUTED_MIN_TOKENS else "dense-mixture"
+def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
+    """The form a program of `n_tokens` tokens takes, from the shape alone:
+    the measured crossing of this routing shape, or mixtral's where none
+    was measured."""
+    least = ROUTED_FROM.get((experts, k), ROUTED_MIN_TOKENS)
+    return "routed" if n_tokens >= least else "dense-mixture"
 
 
 def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int
@@ -155,7 +175,8 @@ def _dense_mixture(x, valid, router, wg, wu, wd, k: int):
 def _expert_ffn(x, valid, router, wg, wu, wd, k: int):
     """x [T, D] -> (y [T, D] float32, valid pairs [X]) by the form this
     token count takes."""
-    form = (_routed_ffn if moe_route(x.shape[0]) == "routed"
+    form = (_routed_ffn
+            if moe_route(x.shape[0], router.shape[-1], k) == "routed"
             else _dense_mixture)
     return form(x, valid, router, wg, wu, wd, k)
 
@@ -226,4 +247,9 @@ def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
             shard, mesh=tp_mesh,
             in_specs=(P(b, None), P(b), P(), col, col, row),
             out_specs=(P(b, None), P()), check_vma=False)(*args)
+    if "sg" in lp:
+        # the shared expert: the same gated form, every token, weight 1,
+        # added to the routed sum in float32
+        y = y + qmatmul(jax.nn.silu(qmatmul(xf, lp["sg"]))
+                        * qmatmul(xf, lp["su"]), lp["sd"])
     return y.astype(x.dtype).reshape(B, S, D), pairs

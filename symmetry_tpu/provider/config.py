@@ -327,6 +327,34 @@ class ConfigManager:
         ):
             raise ConfigError("maxConnections must be a positive integer")
 
+        if provider == "tpu_native" and self._tpu.model_preset:
+            self._validate_state_model(self._tpu)
+
+    @staticmethod
+    def _validate_state_model(tpu: TpuConfig) -> None:
+        """A preset with recurrent layers keeps a per-slot state that the
+        prefix cache, speculation, chunked prefill, the disagg handoff and
+        a mesh cannot carry: refuse those settings here, by name, before a
+        host is spawned (models/hybrid.py state_refusals; the engine
+        refuses the same for a checkpoint, whose config it learns late)."""
+        import math
+
+        from symmetry_tpu.models.llama import PRESETS
+
+        if not getattr(PRESETS.get(tpu.model_preset), "layer_types", None):
+            return
+        from symmetry_tpu.models.hybrid import state_refusals
+
+        refused = state_refusals(
+            mesh=math.prod((tpu.mesh or {}).values()) > 1,
+            role=tpu.role or "unified",
+            prefix_cache=bool(tpu.prefix_cache_mb),
+            speculative=bool(tpu.speculative),
+            prefill_chunk=tpu.prefill_chunk)
+        if refused:
+            raise ConfigError(f"model_preset {tpu.model_preset!r}: "
+                              + "; ".join(refused))
+
     def get(self, key: str, default: Any = None) -> Any:
         return self._config.get(key, default)
 

@@ -167,3 +167,46 @@ def test_trunk_reads_the_cache_in_place(one_chip, no_cache, monkeypatch):
     staged = [line for line in text.splitlines()
               if "copy-start" in line and "[28,128," in line]
     assert not staged, staged[0][:200]
+
+
+def test_hybrid_decode_step_updates_the_recurrent_state_in_place(
+        one_chip, no_cache, monkeypatch):
+    """granite-4.0-h-small's decode trunk at its cell (128 slots x 640): the
+    4.83 GB recurrent state is donated in, aliased out and updated where it
+    lies — no second copy of it among the program's temporaries, no copy
+    or relayout of the whole array — and the one attention layer still
+    takes the decode kernel. (PR 28 and PR 29 both met XLA copying a cache
+    the source said was updated in place.)"""
+    from symmetry_tpu.models import llama
+
+    monkeypatch.setattr(llama, "interpret_mode", lambda: False)
+    cfg = llama.preset("granite-4.0-h-small")
+    B, T = 128, 640
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+        slice_above=1 << 40))
+    cache = shaped(lambda: llama.init_cache(cfg, B, T, jnp.bfloat16,
+                                            quantized=True))
+    assert cache.ssm.shape == (9, 128, 128, 64, 128)
+    assert cache.k.shape == (1, 128, 640, 8, 128)
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
+        donate_argnums=(2,)).lower(params, tok, cache).compile()
+    memory = compiled.memory_analysis()
+    state_bytes = 9 * 128 * 128 * 64 * 128 * 4
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 9    # under one layer
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    whole = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= f32\[9,128,128,64,128\]\S* (copy|transpose)\(",
+                          line)]
+    assert not whole, whole[0]

@@ -1,0 +1,119 @@
+"""Every benchmark configuration that was CUT (a non-empty `reduced`) is held
+to its contract: the file is the program's preset, `published` holds the
+source's value of every cut key, and `reduced` lists exactly the keys that
+differ from the source — no more, no fewer. For a model in the guide's
+catalog the source is its row there (where the catalog is installed).
+
+`benchmarks/tests/test_manifest.py` compares every file with the preset too,
+but it was written when no configuration was cut and ends in
+`reduced == []`; this file is where a cut is checked (PERF.md, Open
+questions)."""
+
+import json
+import os
+
+import pytest
+
+from symmetry_tpu.models.llama import preset
+
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIGS = os.path.join(CHECKOUT, "benchmarks", "configs")
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+# widths may never be cut (the builder's contract on `reduced`)
+WIDTH_ENDS = ("_size", "_dim", "_rank", "_d_head", "_d_state", "_d_conv",
+              "_expand", "_n_heads", "experts_per_tok")
+
+
+def load(name):
+    with open(os.path.join(CONFIGS, name)) as fh:
+        return json.load(fh)
+
+
+def cut_files():
+    return sorted(f for f in os.listdir(CONFIGS) if load(f).get("reduced"))
+
+
+def test_there_is_a_cut_configuration_to_hold():
+    assert "granite-4.0-h-small.json" in cut_files()
+
+
+@pytest.mark.parametrize("name", cut_files())
+def test_reduced_lists_exactly_the_keys_that_differ_from_published(name):
+    c = load(name)
+    published = c["published"]
+    assert sorted(published) == sorted(c["reduced"])
+    for key in c["reduced"]:
+        assert c[key] != published[key], key
+        assert not key.endswith(WIDTH_ENDS), key
+    manifest = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
+    entry = next(e for e in manifest["configs"]
+                 if e["file"] == f"benchmarks/configs/{name}")
+    assert entry["reduced"] == c["reduced"]
+    assert entry["source"] == c["source"] and len(c["source"]) <= 200
+    for key in ("assumed", "deployment"):
+        assert c[key], key
+
+
+@pytest.mark.parametrize("name", cut_files())
+def test_a_cut_keeps_a_whole_period_of_the_published_pattern(name):
+    c = load(name)
+    if "layer_types" not in c["reduced"]:
+        pytest.skip("no layer pattern was cut")
+    full, kept = c["published"]["layer_types"], c["layer_types"]
+    assert len(kept) == c["num_hidden_layers"] >= 4
+    assert len(full) == c["published"]["num_hidden_layers"]
+    assert full[:len(kept)] == kept                 # the published order
+    period = len(kept)
+    assert len(full) % period == 0
+    # the cut is a whole period: the pattern repeats with the same count of
+    # each kind in every stretch of that length
+    for start in range(0, len(full), period):
+        stretch = full[start:start + period]
+        assert sorted(stretch) == sorted(kept), (start, stretch)
+
+
+@pytest.mark.parametrize("name", cut_files())
+def test_a_cut_file_is_the_programs_preset(name):
+    c = load(name)
+    p = preset(c["tpu"]["model_preset"])
+    assert (p.vocab_size, p.hidden_size, p.num_layers, p.num_heads,
+            p.num_kv_heads, p.intermediate_size, p.dim_per_head) == (
+        c["vocab_size"], c["hidden_size"], c["num_hidden_layers"],
+        c["num_attention_heads"], c["num_key_value_heads"],
+        c["intermediate_size"], c["head_dim"])
+    assert p.rope_theta == c["rope_theta"] and p.rms_eps == c["rms_norm_eps"]
+    assert p.tie_embeddings == c["tie_word_embeddings"]
+    if "layer_types" in c:
+        assert list(p.layer_types) == c["layer_types"]
+        assert (p.num_experts, p.num_experts_per_tok,
+                p.shared_intermediate_size) == (
+            c["num_local_experts"], c["num_experts_per_tok"],
+            c["shared_intermediate_size"])
+        assert (p.mamba_n_heads, p.mamba_d_head, p.mamba_d_state,
+                p.mamba_d_conv, p.mamba_chunk_size) == (
+            c["mamba_n_heads"], c["mamba_d_head"], c["mamba_d_state"],
+            c["mamba_d_conv"], c["mamba_chunk_size"])
+        assert c["mamba_n_groups"] == 1 and c["mamba_conv_bias"] is True
+        assert c["mamba_expand"] * c["hidden_size"] == (
+            p.mamba_n_heads * p.mamba_d_head)
+        assert (p.embedding_multiplier, p.residual_multiplier,
+                p.attention_multiplier, p.logits_scaling) == (
+            c["embedding_multiplier"], c["residual_multiplier"],
+            c["attention_multiplier"], c["logits_scaling"])
+        assert p.rope is (c["position_embedding_type"] != "nope")
+
+
+@pytest.mark.parametrize("name", cut_files())
+def test_every_uncut_key_is_the_catalogs(name):
+    if not os.path.exists(CATALOG):
+        pytest.skip("the guide's catalog is not installed here")
+    c = load(name)
+    rows = [json.loads(line) for line in open(CATALOG)]
+    row = next((r for r in rows if r["source_url"] == c["source"]), None)
+    if row is None:
+        pytest.skip("not a catalog model")
+    for key, value in row["config"].items():
+        if key in c["reduced"]:
+            assert c["published"][key] == value, key
+        else:
+            assert c[key] == value, key

@@ -1,0 +1,129 @@
+"""Lowered text of the engine's served programs, for "did this refactor
+change what an existing configuration runs?".
+
+    JAX_PLATFORMS=cpu python tools/lowered_programs.py OUT_DIR [preset ...]
+
+For each preset (default: mistral-7b, qwen2-7b at the dense cells' shape,
+128 slots x 640, int8 weights + int8 KV, decode_block 16; and tiny-moe8 on
+a `model: 4` mesh of virtual CPU devices) it writes the StableHLO of the
+engine's OWN jits — `decode_block`, `prefill` at (8, 256) and `insert_all`
+— lowered from shapes alone (nothing is built or run), as
+`OUT_DIR/<preset>.<program>.txt` and prints one sha256 a file. The text
+carries no source locations, and on the CPU the Pallas kernels lower through
+the interpreter (no Mosaic bytecode with file paths in it), so the same
+programs give the same bytes on two commits: copy this file into the other
+checkout's `tools/`, run it from each, and `diff -r` the two directories.
+
+It reaches into `InferenceEngine` (an instance made without `__init__`, with
+the attributes `_build_jits` reads) so that a 7B model's state is never
+allocated; the tiny sharded case builds the real engine.
+"""
+
+from __future__ import annotations
+
+import hashlib
+import os
+import sys
+
+os.environ.setdefault("XLA_FLAGS",
+                      "--xla_force_host_platform_device_count=4")
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from symmetry_tpu.engine import engine as eng_mod  # noqa: E402
+from symmetry_tpu.models import llama  # noqa: E402
+
+SLOTS, CAPACITY, BLOCK = 128, 640, 16
+PREFILL = (8, 256)
+
+
+def shapes(fn):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        jax.eval_shape(fn))
+
+
+def bare_engine(cfg):
+    """An engine whose jits exist and whose arrays do not."""
+    e = object.__new__(eng_mod.InferenceEngine)
+    e.config, e.mesh, e.kv_quant, e.decode_block = cfg, None, True, BLOCK
+    e.spec, e.prefix_block, e.cache_dtype = None, 16, jnp.bfloat16
+    e.max_slots, e.max_seq_len = SLOTS, CAPACITY
+    e._state_shardings = e._cache_shardings = None
+    e._count_experts = bool(getattr(cfg, "num_experts", 0))
+    e._build_jits()
+    return e
+
+
+def programs(e, params, state):
+    n, bucket = PREFILL
+    cfg = e.config
+    i32, f32 = jnp.int32, jnp.float32
+
+    def vec(dtype):
+        return jax.ShapeDtypeStruct((n,), dtype)
+
+    keys = shapes(lambda: jax.random.split(jax.random.key(0), n))
+    scratch = shapes(lambda: llama.init_cache(
+        cfg, n, bucket, jnp.bfloat16, quantized=True,
+        count_experts=e._count_experts))
+    yield "decode_block", e._decode.lower(
+        params, state, jax.ShapeDtypeStruct((e.max_slots,), bool))
+    yield "prefill", e._prefill.lower(
+        params, jax.ShapeDtypeStruct((n, bucket), i32), vec(i32), vec(f32),
+        vec(f32), vec(i32), keys, scratch)
+    yield "insert_all", e._insert_all.lower(
+        state, scratch, vec(i32), vec(i32), vec(i32), vec(f32), vec(f32),
+        vec(i32), keys)
+
+
+def main() -> int:
+    out_dir = sys.argv[1]
+    names = sys.argv[2:] or ["mistral-7b", "qwen2-7b", "tiny-moe8"]
+    os.makedirs(out_dir, exist_ok=True)
+    for name in names:
+        cfg = llama.preset(name)
+        if name.startswith("tiny"):
+            from symmetry_tpu.engine.tokenizer import get_tokenizer
+            from symmetry_tpu.parallel.mesh import MeshSpec, build_mesh
+            from symmetry_tpu.parallel.sharding import shardings_for
+
+            mesh = build_mesh(MeshSpec(model=4))
+            sh = shardings_for(llama.quantized_logical_axes(
+                llama.param_logical_axes(cfg)), mesh)
+            params = jax.jit(lambda: llama.init_params(
+                cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+                shardings=sh), out_shardings=sh)()
+            e = eng_mod.InferenceEngine(
+                cfg, params, get_tokenizer(None, vocab_size=cfg.vocab_size),
+                mesh=mesh, max_slots=8, max_seq_len=CAPACITY,
+                prefill_buckets=(PREFILL[1],), decode_block=BLOCK,
+                kv_quant=True, prefill_chunk=None)
+            params, state = e.params, e.state
+        else:
+            e = bare_engine(cfg)
+            params = shapes(lambda: llama.init_params(
+                cfg, jax.random.key(0), jnp.bfloat16, quantize=True))
+            state = shapes(lambda: eng_mod.DecodeState(
+                cache=llama.init_cache(cfg, SLOTS, CAPACITY, jnp.bfloat16,
+                                       quantized=True,
+                                       count_experts=e._count_experts),
+                last_token=jnp.zeros((SLOTS,), jnp.int32),
+                temperature=jnp.zeros((SLOTS,), jnp.float32),
+                top_p=jnp.ones((SLOTS,), jnp.float32),
+                top_k=jnp.zeros((SLOTS,), jnp.int32),
+                rng=jax.random.split(jax.random.key(0), SLOTS)))
+        for prog, lowered in programs(e, params, state):
+            text = lowered.as_text()
+            path = os.path.join(out_dir, f"{name}.{prog}.txt")
+            with open(path, "w") as fh:
+                fh.write(text)
+            print(f"{hashlib.sha256(text.encode()).hexdigest()}  "
+                  f"{os.path.basename(path)}  {len(text)} bytes", flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -13,6 +13,8 @@ Prints one JSON line: ms a layer for each form at each token count — the
 reading `models/moe.py ROUTED_MIN_TOKENS` is set from.
 
     python tools/moe_decode_ab.py            # on the chip
+    python tools/moe_decode_ab.py --shape 72,10,4096,768 --layers 2 \
+        --tokens 128,256,512,1024,2048       # granite-4.0-h-small's layer
     JAX_PLATFORMS=cpu python tools/moe_decode_ab.py --tiny
 """
 
@@ -33,16 +35,21 @@ def main() -> int:
     ap.add_argument("--tokens", default="64,128,256,2048")
     ap.add_argument("--repeats", type=int, default=20)
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--shape", default="8,2,4096,3584",
+                    help="experts,top_k,hidden,ffn_width held here; "
+                         "granite-4.0-h-small whole is 72,10,4096,768")
     args = ap.parse_args()
 
     import jax
     import jax.numpy as jnp
 
-    from symmetry_tpu.models.moe import _dense_mixture, _routed_ffn
+    from symmetry_tpu.models.moe import (
+        _dense_mixture, _routed_ffn, moe_route)
     from symmetry_tpu.ops.quant import make_leaf
 
-    X, k = 8, 2
-    D, F = (64, 32) if args.tiny else (4096, 14336 // 4)
+    X, k, D, F = (int(v) for v in args.shape.split(","))
+    if args.tiny:
+        D, F = 64, 32
     L = args.layers
     keys = jax.random.split(jax.random.key(0), 4)
     wg = make_leaf(keys[0], (L, X, D, F), D ** -0.5, jnp.bfloat16, True)
@@ -85,6 +92,7 @@ def main() -> int:
             y.block_until_ready()
             row[name] = round(
                 1e3 * (time.perf_counter() - t0) / args.repeats / L, 4)
+        row["route"] = moe_route(T, X, k)
         out["ms_per_layer"][str(T)] = row
     weight_bytes = 3 * X * D * F
     out["weight_stream_floor_ms_per_layer"] = round(
