@@ -298,6 +298,10 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
             "pallas", "pallas"):
         failures.append(f"attention did not run compiled Pallas kernels "
                         f"in both programs: {attention}")
+    ssm_decode = (startup.get("ssm") or {}).get("decode")
+    if ssm_decode is not None and ssm_decode.get("form") != "pallas":
+        failures.append(f"the recurrent layers' decode step did not run "
+                        f"the compiled Pallas kernel: {ssm_decode}")
     if device.get("platform") != "tpu":
         failures.append(f"the engine host's platform is "
                         f"{device.get('platform')}, not tpu")

@@ -1314,11 +1314,11 @@ class InferenceEngine:
                                         self.cache_dtype).values())
 
     def ssm_report(self) -> dict | None:
-        """`startup.ssm`: what the recurrent layers keep and which form
-        each program kind takes; None for a model without them."""
+        """`startup.ssm`: the state kept, each program's form; or None."""
         if not self._has_state:
             return None
         from symmetry_tpu.models.hybrid import state_bytes_per_slot
+        from symmetry_tpu.models.mamba2 import step_form
 
         c = self.config
         per_slot = state_bytes_per_slot(c, self.cache_dtype)
@@ -1332,7 +1332,7 @@ class InferenceEngine:
             "conv_dtype": str(self.state.cache.conv.dtype),
             "prefill": {"form": "chunked (jnp)",
                         "chunk": c.mamba_chunk_size},
-            "decode": {"form": "step (jnp), state updated in place"},
+            "decode": step_form(c, self.state.cache.ssm.dtype.itemsize),
             "prefill_rows_max": self._state_rows_max(),
             "scratch_rows_max": 2 * self._state_rows_max(),
         }

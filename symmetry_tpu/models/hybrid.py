@@ -19,8 +19,10 @@ The cache (models/llama.py KVCache) holds K/V for the ATTENTION layers only
 (`k` [n_attention, B, T, K, D], attention layer j at index j of the stack)
 and, beside them, `ssm` [n_mamba, B, H, P, N] float32 and `conv` [n_mamba,
 d_conv - 1, B, C] for the mamba layers. A mamba layer's step reads and
-writes its slice of `ssm` where it lies: `.at[j].set` on the donated buffer,
-which rides every scan's carry.
+writes its slice of `ssm` where it lies — the decode step hands the whole
+stack and the layer's index to one kernel (ops/ssm_step.py), the chunked
+form `.at[j].set`s the donated buffer — and the stack rides every scan's
+carry.
 
 Which form a mamba layer takes follows the call's shape: one position a
 slot is the recurrence step; more is the chunked form, from zeros when the
@@ -234,14 +236,16 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
         if kind == "attention":
             return llama._attention(x, lp, cache, j, positions, kv_valid,
                                     seq_lens, c, prefill_flash and S > 1)
-        ssm, conv = _at(cache.ssm, j), _at(cache.conv, j)
-        if S == 1:
-            out, ssm, conv = mamba2.step(x[:, 0], lp, ssm, conv, c)
-            out = out[:, None]
-        else:
-            if prefill_flash:  # from empty, whatever the buffer holds
-                ssm, conv = jnp.zeros_like(ssm), jnp.zeros_like(conv)
-            out, ssm, conv = mamba2.chunked(x, lp, ssm, conv, seq_lens, c)
+        conv = _at(cache.conv, j)
+        if S == 1:  # the stack as it lies: layer j is the step's address
+            out, ssm, conv = mamba2.step_at(x[:, 0], lp, cache.ssm, j,
+                                            conv, c)
+            return out[:, None], cache._replace(
+                ssm=ssm, conv=cache.conv.at[j].set(conv))
+        ssm = _at(cache.ssm, j)
+        if prefill_flash:  # from empty, whatever the buffer holds
+            ssm, conv = jnp.zeros_like(ssm), jnp.zeros_like(conv)
+        out, ssm, conv = mamba2.chunked(x, lp, ssm, conv, seq_lens, c)
         return out, cache._replace(ssm=cache.ssm.at[j].set(ssm),
                                    conv=cache.conv.at[j].set(conv))
 

@@ -19,10 +19,16 @@ channels minor: no padded dim on the chip). Neither is indexed by position.
 
 Two forms, one mathematics:
 
-- `step` (S == 1): the recurrence as written. The output is taken from the
-  OLD state — `S_t C = a (S_{t-1} C) + D_t x (B . C)` — so the state update
-  is a pure elementwise pass over the [B, H, P, N] buffer that XLA can run
-  in place, and nothing reads the new state in the same program.
+- `step` / `step_at` (S == 1): the recurrence as written, the output taken
+  from the OLD state — `S_t C = a (S_{t-1} C) + D_t x (B . C)` — so one
+  copy of a tile of the state serves both lines. Between the decay and the
+  gate it is ONE Pallas kernel over the whole [n_mamba, B, H, P, N] stack
+  (ops/ssm_step.py: each tile read once, updated where it lies, `S C`
+  reduced in VMEM; the layer is DMA addressing) wherever that kernel has a
+  geometry for the state (`step_form`); `recurrence` is the same two lines
+  in jnp — what the tests hold the kernel to, and what a state the kernel
+  has no geometry for falls back to (XLA makes it two passes over the
+  state: PERF.md, PR 33 / PR 34).
 - `chunked` (S > 1; prefill): per chunk of `chunk` positions the outputs are
   a masked [Q, Q] matrix product (the "dual" quadratic form) plus the
   incoming state's decayed read-out, and the state moves a chunk at a time.
@@ -43,6 +49,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from symmetry_tpu.ops import ssm_step
+from symmetry_tpu.ops.interpret import interpret_mode
 from symmetry_tpu.ops.quant import QuantizedTensor, qmatmul
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -87,10 +95,37 @@ def _gate_out(y: jnp.ndarray, gate: jnp.ndarray, lp: dict, eps: float,
     return qmatmul(y.astype(dtype), lp["out_proj"])
 
 
-def step(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, conv: jnp.ndarray,
-         config) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One token a slot: u [B, E], ssm [B, H, P, N], conv [K-1, B, C] ->
-    (out [B, E], ssm, conv)."""
+def step_form(config, itemsize: int = 4) -> dict:
+    """Which form the single-position recurrence takes for this config's
+    state (what `step_at` routes by and the engine reports): "pallas"
+    with its `head_tile` ("pallas-interpret": the same kernel on the CPU
+    backend), or the jnp recurrence where the kernel has no geometry."""
+    z = sizes(config)
+    tile = ssm_step.head_tile(z["H"], z["P"], z["N"], itemsize,
+                              interpret=interpret_mode())
+    if tile is None:
+        return {"form": "step (jnp), two passes over the state"}
+    return {"form": "pallas-interpret" if interpret_mode() else "pallas",
+            "head_tile": tile}
+
+
+def recurrence(ssm, a, dx, b, c, skip):
+    """One position of the recurrence in jnp: ssm [B, H, P, N], a [B, H],
+    dx / skip [B, H, P], b / c [B, N], float32 -> (y [B, H, P], ssm)."""
+    # y_t = S_t C = a (S_{t-1} C) + (dt x) (B . C): reads the OLD state
+    y = (a[..., None] * jnp.einsum("bhpn,bn->bhp", ssm, c,
+                                   precision=HIGHEST)
+         + dx * jnp.sum(b * c, axis=-1)[:, None, None] + skip)
+    return y, (a[..., None, None] * ssm
+               + dx[..., None] * b[:, None, None, :]).astype(ssm.dtype)
+
+
+def step_at(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, layer,
+            conv: jnp.ndarray, config
+            ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One token a slot, the state where it lies: u [B, E], ssm the WHOLE
+    stack [n_mamba, B, H, P, N] of which layer `layer` (traced) steps,
+    conv [K-1, B, C] that layer's tail -> (out [B, E], the stack, conv)."""
     z = sizes(config)
     B = u.shape[0]
     gate, xbc, dt = _split(_in_proj(u, lp["in_proj"]), z)
@@ -104,16 +139,26 @@ def step(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, conv: jnp.ndarray,
     delta, log_a = _decay(dt, lp)                               # [B, H]
     a = jnp.exp(log_a)
     dx = delta[..., None] * x                                   # [B, H, P]
-    # y_t = S_t C = a (S_{t-1} C) + (dt x) (B . C): reads the OLD state
-    y = (a[..., None] * jnp.einsum("bhpn,bn->bhp", ssm, c,
-                                   precision=HIGHEST)
-         + dx * jnp.sum(b * c, axis=-1)[:, None, None]
-         + lp["D"].astype(jnp.float32)[:, None] * x)
-    ssm = (a[..., None, None] * ssm
-           + dx[..., None] * b[:, None, None, :]).astype(ssm.dtype)
+    skip = lp["D"].astype(jnp.float32)[:, None] * x
+    if "head_tile" in step_form(config, ssm.dtype.itemsize):
+        y, ssm = ssm_step.ssm_step(ssm, layer, a, dx, b, c, skip,
+                                   interpret=interpret_mode())
+    else:
+        y, new = recurrence(
+            jax.lax.dynamic_index_in_dim(ssm, layer, 0, keepdims=False),
+            a, dx, b, c, skip)
+        ssm = ssm.at[layer].set(new)
     out = _gate_out(y.reshape(B, z["inner"]), gate, lp, config.rms_eps,
                     u.dtype)
     return out, ssm, window[1:].astype(conv.dtype)
+
+
+def step(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, conv: jnp.ndarray,
+         config) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """`step_at` for one layer's state alone: u [B, E], ssm [B, H, P, N],
+    conv [K-1, B, C] -> (out [B, E], ssm, conv)."""
+    out, ssm, conv = step_at(u, lp, ssm[None], jnp.int32(0), conv, config)
+    return out, ssm[0], conv
 
 
 def _chunk(x, delta, log_a, b, c, state):

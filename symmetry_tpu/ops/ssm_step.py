@@ -1,0 +1,167 @@
+"""Pallas Mamba-2 decode step: one pass over the recurrent state.
+
+The single-position recurrence of a mamba layer (models/mamba2.py step),
+float32 throughout:
+
+    y[b,h,p]   = a[b,h] * sum_n S[b,h,p,n] * C[b,n]
+                 + dx[b,h,p] * (B[b,.] . C[b,.]) + skip[b,h,p]
+    S[b,h,p,n] = a[b,h] * S[b,h,p,n] + dx[b,h,p] * B[b,n]
+
+The state is nearly all of the bytes (4 MB a slot a layer at granite's
+128 x 64 x 128 against 100 KB of everything else), and the step is bound by
+reading and writing it. XLA compiles the two lines as two fusions — a
+reduction that reads the layer's state for `S C`, then an elementwise pass
+that reads it again and writes it — so the state crosses HBM three times a
+layer. Here each tile of `S` is copied into VMEM once, both lines are
+computed from that copy, and the new tile goes back to where it came from:
+
+  - The WHOLE stack [L, B, H, P, N] is the kernel's operand, aliased input
+    to output, with the layer a scalar-prefetch argument: layer, slot and
+    head tile are DMA addressing (ops/decode_attention.py's lesson: a
+    per-layer slice around a kernel is a 537 MB copy each way). Blocks the
+    grid does not visit are untouched: only layer `layer` changes.
+  - The grid is (B, H / head_tile): one step moves `head_tile` heads of one
+    slot, [head_tile, P, N], in and out through Pallas's double buffers.
+    Every slot steps, idle lanes included: the work is the state of all
+    slots read once and written once (benchmarks/lib/hybrid_bytes.py).
+  - N lies on lanes, so B and C are lane vectors (a stride-0 sublane
+    broadcast), the decay of a head is a scalar (read from SMEM, a free
+    splat) and what is per (h, p) — dx, the reduced `S C` — is a COLUMN.
+    Those come in as [B, P, H] (P on sublanes, heads on lanes, padded to
+    a lane tile): head h's dx is lane h of eight vregs, broadcast along
+    lanes for the update, and the lane reduction's result is selected
+    into lane h of the output. A group of heads is brought to lanes 0..
+    by one dynamic lane rotation, so every slice inside the unrolled
+    group is static.
+  - All arithmetic is float32 on the VPU, the reduction over N on the XLU:
+    no product takes a bf16 pass. The MXU stays idle — its f32-exact forms
+    (three-term splits) cost more passes than the DMA leaves time for.
+  - What it costs (tools/ssm_step_ab.py on a v5e, ms a layer of 537 MB;
+    PERF.md, PR 34): a bare copy through the same pipeline 1.67 at any
+    tile — the chip's rate for a read and a write at once, 640 GB/s — and
+    the kernel 1.68 at 64 heads a step; with the decay as a third lane
+    broadcast it was 1.79 (the XLU, not the DMA, set the pace), at 16
+    heads a step 1.90 (1,024 grid steps a layer).
+
+`head_tile` is the one shape gate: None where the kernel has no geometry
+(the caller keeps the jnp recurrence there and says so).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+SUBLANES = 8
+GROUP = 16             # heads unrolled between two lane rotations
+TILE_BYTES = 2**21     # of state a grid step moves each way
+NAME = "ssm_step"      # the op's name in a device trace
+
+
+def head_tile(n_heads: int, d_head: int, d_state: int, itemsize: int = 4,
+              *, interpret: bool = False) -> int | None:
+    """Heads of one slot a grid step moves at this state shape, or None
+    where the kernel has none: a [P, N] plane of a head has to be whole
+    (8, 128) tiles for Mosaic (any shape interprets). The largest divisor
+    of the head count whose tile is at most TILE_BYTES."""
+    if not interpret and (d_state % LANES or d_head % SUBLANES):
+        return None
+    most = max(1, TILE_BYTES // (d_head * d_state * itemsize))
+    return next(t for t in range(min(n_heads, most), 0, -1)
+                if n_heads % t == 0)
+
+
+def _kernel(layer_ref, b_ref, c_ref, dx_ref, skip_ref, a_ref, s_ref,
+            y_ref, s_out_ref, *, group: int):
+    del layer_ref                                   # addressing only
+    tile, P, N = s_ref.shape[2:]
+    lanes = dx_ref.shape[-1]
+    first = pl.program_id(1) * tile                 # this step's first head
+    b_row, c_row = b_ref[0], c_ref[0]               # [1, N]
+    dx_all = dx_ref[0]                              # [P, lanes]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (P, lanes), 1)
+
+    def heads(g, sc):
+        h0 = first + g * group
+        # bring heads h0 .. h0 + group - 1 to lanes 0 .. group - 1
+        dx_g = pltpu.roll(dx_all, (lanes - h0) % lanes, 1)
+        for j in range(group):
+            at = g * group + j
+            a = a_ref[0, 0, h0 + j]
+            s = s_ref[0, 0, at].astype(jnp.float32)             # [P, N]
+            read = jnp.sum(s * c_row, axis=-1, keepdims=True)   # [P, 1]
+            s_out_ref[0, 0, at] = (
+                a * s + dx_g[:, j:j + 1] * b_row).astype(s_out_ref.dtype)
+            sc = jnp.where(lane == h0 + j, a * read, sc)
+        return sc
+
+    sc = jax.lax.fori_loop(0, tile // group, heads,
+                           jnp.zeros((P, lanes), jnp.float32))
+    mine = (lane >= first) & (lane < first + tile)
+    # the output block stays in VMEM across a slot's head tiles: each
+    # writes its own lanes (what the others' lanes hold until then is
+    # never read as a number)
+    y_ref[0] = jnp.where(mine, sc + skip_ref[0], y_ref[0])
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def ssm_step(
+    ssm: jnp.ndarray,       # [L, B, H, P, N] the FULL stack
+    layer: jnp.ndarray,     # scalar int32: which layer's state steps
+    a: jnp.ndarray,         # [B, H] f32 decay of this position
+    dx: jnp.ndarray,        # [B, H, P] f32 dt * x
+    b: jnp.ndarray,         # [B, N] f32
+    c: jnp.ndarray,         # [B, N] f32
+    skip: jnp.ndarray,      # [B, H, P] f32 D * x
+    *,
+    tile: int | None = None,    # heads a grid step (None: `head_tile`)
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Returns (y [B, H, P] f32, the stack with layer `layer` stepped)."""
+    L, B, H, P, N = ssm.shape
+    if tile is None:
+        tile = head_tile(H, P, N, ssm.dtype.itemsize, interpret=interpret)
+    if tile is None or H % tile:
+        raise ValueError(f"no ssm-step geometry for a state of {H} heads "
+                         f"of {P} x {N} (tile {tile})")
+    group = next(g for g in range(min(GROUP, tile), 0, -1) if tile % g == 0)
+    lanes = -(-H // LANES) * LANES
+
+    def columns(v):     # [B, H, P] -> [B, P, lanes]: heads on lanes
+        return jnp.pad(jnp.swapaxes(v.astype(jnp.float32), 1, 2),
+                       ((0, 0), (0, 0), (0, lanes - H)))
+
+    skip = skip + dx * jnp.sum(b * c, axis=-1)[:, None, None]
+    row = pl.BlockSpec((1, 1, N), lambda i, t, lay: (i, 0, 0))
+    col = pl.BlockSpec((1, P, lanes), lambda i, t, lay: (i, 0, 0))
+    decay = pl.BlockSpec((1, 1, H), lambda i, t, lay: (i, 0, 0),
+                         memory_space=pltpu.SMEM)
+    state = pl.BlockSpec((1, 1, tile, P, N),
+                         lambda i, t, lay: (lay[0], i, t, 0, 0))
+    block = 2 * tile * P * N * 4    # a tile in and a tile out, as float32
+    y, ssm = pl.pallas_call(
+        functools.partial(_kernel, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # layer
+            grid=(B, H // tile),
+            in_specs=[row, row, col, col, decay, state],
+            out_specs=[col, state],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, P, lanes), jnp.float32),
+                   jax.ShapeDtypeStruct(ssm.shape, ssm.dtype)],
+        input_output_aliases={6: 1},    # the stack, counted with `layer`
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            # double buffers of the tile each way, and room for the rest
+            vmem_limit_bytes=max(32 * 2**20, 3 * block)),
+        name=NAME,
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      b[:, None].astype(jnp.float32), c[:, None].astype(jnp.float32),
+      columns(dx), columns(skip), a[:, None].astype(jnp.float32), ssm)
+    return jnp.swapaxes(y[:, :, :H], 1, 2), ssm

@@ -174,12 +174,16 @@ def test_hybrid_decode_step_updates_the_recurrent_state_in_place(
     """granite-4.0-h-small's decode trunk at its cell (128 slots x 640): the
     4.83 GB recurrent state is donated in, aliased out and updated where it
     lies — no second copy of it among the program's temporaries, no copy
-    or relayout of the whole array — and the one attention layer still
-    takes the decode kernel. (PR 28 and PR 29 both met XLA copying a cache
-    the source said was updated in place.)"""
-    from symmetry_tpu.models import llama
+    or relayout of the whole array — by ONE kernel call a run of mamba
+    layers (ops/ssm_step.py, the stack its operand) and no XLA fusion: the
+    two passes XLA made of the step (a reduction for `S C`, then the
+    update) cannot come back unseen. The one attention layer still takes
+    the decode kernel. (PR 28 and PR 29 both met XLA copying a cache the
+    source said was updated in place.)"""
+    from symmetry_tpu.models import hybrid, llama, mamba2
 
     monkeypatch.setattr(llama, "interpret_mode", lambda: False)
+    monkeypatch.setattr(mamba2, "interpret_mode", lambda: False)
     cfg = llama.preset("granite-4.0-h-small")
     B, T = 128, 640
 
@@ -205,8 +209,21 @@ def test_hybrid_decode_step_updates_the_recurrent_state_in_place(
     assert memory.alias_size_in_bytes >= state_bytes
     assert memory.temp_size_in_bytes < state_bytes // 9    # under one layer
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 1
+    mamba_runs = sum(kind == "mamba" for kind, _, _ in hybrid.runs(cfg))
+    assert mamba_runs == 2
+    assert text.count("tpu_custom_call") == 1 + mamba_runs
+    assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == mamba_runs
     whole = [line.strip()[:160] for line in text.splitlines()
              if re.search(r"= f32\[9,128,128,64,128\]\S* (copy|transpose)\(",
                           line)]
     assert not whole, whole[0]
+    # the stack is an operand of the kernel calls (and of the plumbing that
+    # carries it: tuples, loops, bitcasts) and of no fusion, in or out
+    shape = r"f32\[9,128,128,64,128\]"
+    stack = set(re.findall(rf"(%[\w.\-]+)(?: =|:) {shape}", text))
+    fused = [line.strip()[:160] for line in text.splitlines()
+             if " fusion(" in line and (
+                 re.search(shape, line.split(" fusion(")[0])
+                 or stack & set(re.findall(r"%[\w.\-]+",
+                                           line.split(" fusion(")[1])))]
+    assert stack and not fused, fused[:1]
