@@ -1,0 +1,60 @@
+"""The qwen3-next cell's shape rehearsed through `run.py` on the CPU:
+`tiny-gdn` (Gated DeltaNet layers beside a gated-attention layer, 16 experts
+top 4 and a gated shared one), int8 weights and int8 KV, a closed loop, every
+metric file of the real cell. Every phase runs — the matrix state is
+installed, stepped and reused lane after lane by real traffic — every reader
+is walked, and then it REFUSES: non-zero exit, nothing on stdout, because the
+engine host's platform is not tpu."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+from conftest import BENCH, CHECKOUT, TESTS
+
+RUN = os.path.join(BENCH, "run.py")
+ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
+REAL_CELL = "qwen3-next-80b-a3b.batch-closed"
+CELL = "tiny-gdn.tiny-closed"
+
+
+def test_gdn_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
+    data = tmp_path / "data"
+    shutil.copytree(os.path.join(TESTS, "data"), data)
+    real = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
+    mine = [m for m in real["per_layer"]
+            if m.get("workloads") == [REAL_CELL]]
+    assert len(mine) == 12
+    m = json.load(open(data / "BENCHMARK.tiny.json"))
+    m["configs"].append({"name": "tiny-gdn", "source": "test preset",
+                         "file": "configs/tiny-gdn.json", "reduced": [],
+                         "why": "CPU rehearsal of the qwen3_next model"})
+    m["workloads"].append({"name": CELL, "config": "tiny-gdn",
+                           "traffic": "tiny-closed", "chips": 1,
+                           "why": "rehearsal"})
+    m["per_layer"] += [dict(e, workloads=[CELL]) for e in mine]
+    json.dump(m, open(data / "BENCHMARK.tiny.json", "w"))
+    out = subprocess.run(
+        [sys.executable, RUN, "--workload", CELL, "--seed", "3000000035",
+         "--seconds", "3", "--trace", "1", "--manifest",
+         str(data / "BENCHMARK.tiny.json")], cwd=CHECKOUT, env=ENV,
+        capture_output=True, text=True, timeout=900)
+    assert out.returncode != 0 and out.stdout.strip() == "", out.stdout
+    assert "not tpu" in out.stderr, out.stderr[-3000:]
+    lines = [ln for ln in out.stderr.splitlines() if "rehearsal:" in ln]
+    assert lines, out.stderr[-3000:]
+    line = lines[-1]
+    assert "correct=True" in line and "failed=0" in line, line
+    # every reader that needs no device trace found something to read
+    for name in ("gap_p99_s", "setup_s", "lin_prefill_tok_s",
+                 "lin_installs_per_s", "moe_expert_imbalance.lin",
+                 "wire_out_tok_s.lin", "decode_step_ms.lin",
+                 "sched_occupancy.lin", "wire_tpot_p50_ms", "admit_share"):
+        assert f"'{name}'" in line, line
+    # ... and the trace readers found no device plane (nor the CPU a
+    # memory limit), and said nothing
+    for name in ("lin_decode_hbm_share", "lin_prefill_mxu_share",
+                 "lin_state_hbm_share", "hbm_used.lin"):
+        assert f"'{name}'" not in line, line

@@ -42,6 +42,12 @@ contends for the chip its child needs.
         # carries `moe`: layout, route, the quantised-leaf route), then —
         # once the provider has drained and the chips are free — the
         # depth-2 full-width logits parity of tools/moe_parity.py
+    python chip_smoke.py --preset granite-4.0-h-small
+    python chip_smoke.py --preset qwen3-next-80b-a3b
+        # the one-chip hybrid models (a per-slot recurrent state beside an
+        # attention layer; the report carries `ssm`: the kind, its state
+        # and each program's form — Mamba-2's decode step must read
+        # `pallas`, the Gated DeltaNet's is jnp so far — and `moe`)
     JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny
         # CPU dry run of every phase; ends non-zero: "platform is cpu"
 """
@@ -298,8 +304,12 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
             "pallas", "pallas"):
         failures.append(f"attention did not run compiled Pallas kernels "
                         f"in both programs: {attention}")
-    ssm_decode = (startup.get("ssm") or {}).get("decode")
-    if ssm_decode is not None and ssm_decode.get("form") != "pallas":
+    ssm = startup.get("ssm") or {}
+    ssm_decode = ssm.get("decode")
+    # Mamba-2's step has a kernel (ops/ssm_step.py); the Gated DeltaNet's
+    # (`--preset qwen3-next-80b-a3b`) is jnp so far and says so
+    if (ssm_decode is not None and ssm.get("kind", "mamba2") == "mamba2"
+            and ssm_decode.get("form") != "pallas"):
         failures.append(f"the recurrent layers' decode step did not run "
                         f"the compiled Pallas kernel: {ssm_decode}")
     if device.get("platform") != "tpu":

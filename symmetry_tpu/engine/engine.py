@@ -1293,7 +1293,7 @@ class InferenceEngine:
         c = self.config
         # a model with recurrent layers keeps K/V for its attention layers
         # alone (state_bytes_per_slot has the rest of a slot)
-        n_layers = (len(c.layers_of("attention")) if self._has_state
+        n_layers = (len(c.layers_of(c.attention_kind)) if self._has_state
                     else c.num_layers)
         per_plane = n_layers * c.num_kv_heads
         if self.kv_quant:
@@ -1303,8 +1303,8 @@ class InferenceEngine:
             self.cache_dtype).itemsize
 
     def state_bytes_per_slot(self) -> int:
-        """Bytes a slot holds that are not rows per position: the mamba
-        layers' recurrent state and convolution tails; 0 for a model
+        """Bytes a slot holds that are not rows per position: the
+        recurrent layers' state and convolution tails; 0 for a model
         without such layers."""
         if not self._has_state:
             return 0
@@ -1317,22 +1317,31 @@ class InferenceEngine:
         """`startup.ssm`: the state kept, each program's form; or None."""
         if not self._has_state:
             return None
+        from symmetry_tpu.models import gdn, mamba2
         from symmetry_tpu.models.hybrid import state_bytes_per_slot
-        from symmetry_tpu.models.mamba2 import step_form
 
         c = self.config
         per_slot = state_bytes_per_slot(c, self.cache_dtype)
+        itemsize = self.state.cache.ssm.dtype.itemsize
+        if c.recurrent_kind == "mamba":
+            kind = {"kind": "mamba2",
+                    "mamba_layers": len(c.layers_of("mamba"))}
+            chunk, decode = c.mamba_chunk_size, mamba2.step_form(c, itemsize)
+        else:
+            kind = {"kind": "gated_deltanet",
+                    "linear_attention_layers":
+                        len(c.layers_of("linear_attention"))}
+            chunk, decode = c.linear_chunk_size, gdn.step_form(c)
         return {
-            "mamba_layers": len(c.layers_of("mamba")),
-            "attention_layers": len(c.layers_of("attention")),
+            **kind,
+            "attention_layers": len(c.layers_of(c.attention_kind)),
             "state_bytes_per_slot": per_slot["ssm"],
             "conv_bytes_per_slot": per_slot["conv"],
             "state_bytes": sum(per_slot.values()) * self.max_slots,
             "state_dtype": str(self.state.cache.ssm.dtype),
             "conv_dtype": str(self.state.cache.conv.dtype),
-            "prefill": {"form": "chunked (jnp)",
-                        "chunk": c.mamba_chunk_size},
-            "decode": step_form(c, self.state.cache.ssm.dtype.itemsize),
+            "prefill": {"form": "chunked (jnp)", "chunk": chunk},
+            "decode": decode,
             "prefill_rows_max": self._state_rows_max(),
             "scratch_rows_max": 2 * self._state_rows_max(),
         }
@@ -2027,7 +2036,10 @@ class InferenceEngine:
         if c.shared_intermediate_size:
             self._moe_report["shared_expert"] = {
                 "width": c.shared_intermediate_size,
-                "form": "dense gated FFN, every token, weight 1"}
+                "form": ("dense gated FFN, every token, weight "
+                         "sigmoid(x . sgate)"
+                         if getattr(c, "shared_expert_gate", False)
+                         else "dense gated FFN, every token, weight 1")}
         return self._moe_report
 
     def decode_step(self) -> np.ndarray:

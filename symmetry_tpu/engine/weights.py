@@ -54,14 +54,17 @@ def convert_hf_state_dict(
     tensors: dict[str, np.ndarray], config: ModelConfig
 ) -> dict:
     """Convert a full in-memory HF llama/mixtral state dict to our pytree
-    (a granitemoehybrid one through models/hybrid.py's name map)."""
+    (a granitemoehybrid or qwen3_next one through models/hybrid.py's name
+    maps)."""
     if getattr(config, "layer_types", None):
         from symmetry_tpu.models import hybrid
 
         try:
             return hybrid.convert_hf_state_dict(tensors, config)
         except (KeyError, ValueError) as exc:
-            raise CheckpointError(f"granitemoehybrid checkpoint: {exc}")
+            family = ("qwen3_next" if config.recurrent_kind
+                      == "linear_attention" else "granitemoehybrid")
+            raise CheckpointError(f"{family} checkpoint: {exc}")
     n_exp = getattr(config, "num_experts", 0)
     per_layer: dict[str, list] = {
         ours: [None] * config.num_layers

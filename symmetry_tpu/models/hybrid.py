@@ -1,7 +1,9 @@
-"""Decoder whose layers are of two mixer kinds (granitemoehybrid family):
-Mamba-2 layers with a per-slot recurrent state (models/mamba2.py) among
-attention layers (models/llama.py `_attention`), every one followed by the
-routed + shared expert FFN (models/moe.py).
+"""Decoder whose layers are of two mixer kinds: recurrent layers with a
+per-slot state — Mamba-2 (models/mamba2.py; granitemoehybrid family) or
+Gated DeltaNet (models/gdn.py; qwen3_next family, whose `layer_types` say
+"linear_attention" / "full_attention") — among attention layers
+(models/llama.py `_attention`), every one followed by the routed + shared
+expert FFN (models/moe.py).
 
     h = embed[tokens] * embedding_multiplier
     for layer i:   h = h + r * mixer_i(rms_norm(h))
@@ -29,6 +31,14 @@ slot is the recurrence step; more is the chunked form, from zeros when the
 caller says the cache is empty (`prefill_flash`, the engine's prefill: the
 scratch it reuses is dirty) and from the cache's state otherwise.
 
+A qwen3_next model is the same trunk with the other recurrent kind: its
+stack is `layers.gdn`, its state rides the same two cache leaves — `ssm`
+[n_linear, B, Hv, Dk, Dv] float32, a MATRIX a value head, and `conv`
+[n_linear, K - 1, B, 2 Hk Dk + Hv Dv] — its norms are zero-centred
+(`norm_plus_one`), its head untied, its shared expert gated; the forms
+follow the call's shape in the same way (models/gdn.py `step_at`,
+`chunked`).
+
 One device only: there are no sharding rules for the state yet.
 """
 
@@ -39,11 +49,12 @@ import math
 import jax
 import jax.numpy as jnp
 
-from symmetry_tpu.models import llama, mamba2
+from symmetry_tpu.models import gdn, llama, mamba2
 from symmetry_tpu.models.moe import moe_mlp
 from symmetry_tpu.ops.norm import rms_norm
 
-KIND_STACK = {"mamba": "mamba", "attention": "attn"}
+KIND_STACK = {"mamba": "mamba", "attention": "attn",
+              "linear_attention": "gdn", "full_attention": "attn"}
 
 
 def stack_index(config, i: int) -> int:
@@ -51,20 +62,31 @@ def stack_index(config, i: int) -> int:
     return config.layers_of(config.layer_types[i]).index(i)
 
 
+def state_shapes(config, batch: int) -> tuple[tuple, tuple]:
+    """The shapes of the cache's `ssm` and `conv` leaves: a stack over the
+    recurrent layers of this model's kind."""
+    n = len(config.layers_of(config.recurrent_kind))
+    if config.recurrent_kind == "mamba":
+        z = mamba2.sizes(config)
+        return ((n, batch, z["H"], z["P"], z["N"]),
+                (n, z["K"] - 1, batch, z["conv"]))
+    z = gdn.sizes(config)
+    return ((n, batch, z["Hv"], z["Dk"], z["Dv"]),
+            (n, z["K"] - 1, batch, z["conv"]))
+
+
 def state_bytes_per_slot(config, dtype=jnp.bfloat16) -> dict:
     """What one slot holds that is not a row per position."""
-    z = mamba2.sizes(config)
-    n = len(config.layers_of("mamba"))
-    return {"ssm": n * z["H"] * z["P"] * z["N"] * 4,
-            "conv": n * (z["K"] - 1) * z["conv"] * jnp.dtype(dtype).itemsize}
+    ssm, conv = state_shapes(config, 1)
+    return {"ssm": math.prod(ssm) * 4,
+            "conv": math.prod(conv) * jnp.dtype(dtype).itemsize}
 
 
 def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
                quantized: bool = False, count_experts: bool = False
                ) -> llama.KVCache:
-    z = mamba2.sizes(config)
-    n_attn = len(config.layers_of("attention"))
-    n_mamba = len(config.layers_of("mamba"))
+    n_attn = len(config.layers_of(config.attention_kind))
+    ssm, conv = state_shapes(config, batch)
     shape = (n_attn, batch, capacity, config.num_kv_heads,
              config.dim_per_head)
     scale_shape = (n_attn, batch, config.num_kv_heads, capacity)
@@ -76,8 +98,8 @@ def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
         v_scale=jnp.zeros(scale_shape, jnp.float32) if quantized else None,
         expert_pairs=(jnp.zeros((config.num_experts,), jnp.int32)
                       if count_experts else None),
-        ssm=jnp.zeros((n_mamba, batch, z["H"], z["P"], z["N"]), jnp.float32),
-        conv=jnp.zeros((n_mamba, z["K"] - 1, batch, z["conv"]), dtype),
+        ssm=jnp.zeros(ssm, jnp.float32),
+        conv=jnp.zeros(conv, dtype),
     )
 
 
@@ -91,7 +113,6 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
         default_leaf_limit, leaf_is_sliced, make_leaf, make_leaf_sliced)
 
     c = config
-    z = mamba2.sizes(c)
     if slice_above is None:
         slice_above = default_leaf_limit()
     keys = iter(jax.random.split(key, 24))
@@ -104,9 +125,12 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
                 else make_leaf)
         return make(next(keys), shape, scale, dtype, quantized=quantized)
 
+    if c.recurrent_kind == "linear_attention":
+        return _init_qwen3_next(c, keys, dense, dtype)
     L, E, F = c.num_layers, c.hidden_size, c.intermediate_size
     X, Fs = c.num_experts, c.shared_intermediate_size
     Lm, La = len(c.layers_of("mamba")), len(c.layers_of("attention"))
+    z = mamba2.sizes(c)
     H = z["H"]
     # dt in [1e-3, 1e-1] log-uniform through the softplus, A in [1, 16]:
     # the published initialisation's ranges (decays from 0.2 to 0.999)
@@ -147,6 +171,62 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
         },
         "final_norm": jnp.ones((E,), dtype),
     }
+
+
+def _init_qwen3_next(c, keys, dense, dtype) -> dict:
+    """`init_params` for a qwen3_next config: A in (0, 16] and dt in [1e-3,
+    1e-1] as published, every zero-centred norm at its identity (0; the
+    Gated DeltaNet's output norm is a plain weight: 1)."""
+    z = gdn.sizes(c)
+    L, E, F = c.num_layers, c.hidden_size, c.intermediate_size
+    X, Fs, D = c.num_experts, c.shared_intermediate_size, c.dim_per_head
+    Lm = len(c.layers_of("linear_attention"))
+    La = len(c.layers_of("full_attention"))
+    dt = jnp.exp(jax.random.uniform(next(keys), (Lm, z["Hv"]), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    a = jax.random.uniform(next(keys), (Lm, z["Hv"]), jnp.float32, 1e-3,
+                           16.0)
+    params = {
+        "embed": dense((c.vocab_size, E), scale=0.02),
+        "layers": {
+            "gdn": {
+                "norm": jnp.zeros((Lm, E), dtype),
+                "in_proj": dense((Lm, E, z["proj"]), "in_proj"),
+                "in_ba": dense((Lm, E, 2 * z["Hv"])),
+                "conv_w": dense((Lm, z["K"], z["conv"]),
+                                scale=z["K"] ** -0.5),
+                "dt_bias": jnp.log(jnp.expm1(dt)),          # softplus^-1
+                "A_log": jnp.log(a),
+                "gate_norm": jnp.ones((Lm, z["Dv"]), dtype),
+                "out_proj": dense((Lm, z["inner"], E), "out_proj"),
+            },
+            "attn": {
+                "norm": jnp.zeros((La, E), dtype),
+                "wq": dense((La, E, (2 if c.attn_output_gate else 1)
+                             * c.q_dim), "wq"),
+                "wk": dense((La, E, c.kv_dim), "wk"),
+                "wv": dense((La, E, c.kv_dim), "wv"),
+                "wo": dense((La, c.q_dim, E), "wo"),
+                "q_norm": jnp.zeros((La, D), dtype),
+                "k_norm": jnp.zeros((La, D), dtype),
+            },
+            "ffn": {
+                "norm": jnp.zeros((L, E), dtype),
+                "router": dense((L, E, X)),
+                "wg": dense((L, X, E, F), "wg"),
+                "wu": dense((L, X, E, F), "wu"),
+                "wd": dense((L, X, F, E), "wd"),
+                "sg": dense((L, E, Fs), "sg"),
+                "su": dense((L, E, Fs), "su"),
+                "sd": dense((L, Fs, E), "sd"),
+                "sgate": dense((L, E, 1)),
+            },
+        },
+        "final_norm": jnp.zeros((E,), dtype),
+    }
+    if not c.tie_embeddings:
+        params["lm_head"] = dense((E, c.vocab_size), "lm_head", scale=0.02)
+    return params
 
 
 def state_refusals(*, mesh: bool = False, role: str = "unified",
@@ -223,8 +303,8 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
                  + jnp.arange(S, dtype=jnp.int32)[None, :])
     kv_valid = cache.lengths + seq_lens
     layers = params["layers"]
-    for kind, stack in KIND_STACK.items():
-        n = jax.tree.leaves(layers[stack])[0].shape[0]
+    for kind in (c.recurrent_kind, c.attention_kind):
+        n = jax.tree.leaves(layers[KIND_STACK[kind]])[0].shape[0]
         if n != len(c.layers_of(kind)):
             raise ValueError(f"params carry {n} {kind} layers but "
                              f"layer_types has {len(c.layers_of(kind))}")
@@ -232,20 +312,25 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
     h = h * jnp.asarray(c.embedding_multiplier, h.dtype)
     r = jnp.asarray(c.residual_multiplier, h.dtype)
 
+    recurrent = gdn if c.recurrent_kind == "linear_attention" else mamba2
+
+    def norm(h, w):
+        return rms_norm(h, llama._norm_w(w, c), c.rms_eps)
+
     def mixer(kind, x, lp, cache, j):
-        if kind == "attention":
+        if kind == c.attention_kind:
             return llama._attention(x, lp, cache, j, positions, kv_valid,
                                     seq_lens, c, prefill_flash and S > 1)
         conv = _at(cache.conv, j)
         if S == 1:  # the stack as it lies: layer j is the step's address
-            out, ssm, conv = mamba2.step_at(x[:, 0], lp, cache.ssm, j,
-                                            conv, c)
+            out, ssm, conv = recurrent.step_at(x[:, 0], lp, cache.ssm, j,
+                                               conv, c)
             return out[:, None], cache._replace(
                 ssm=ssm, conv=cache.conv.at[j].set(conv))
         ssm = _at(cache.ssm, j)
         if prefill_flash:  # from empty, whatever the buffer holds
             ssm, conv = jnp.zeros_like(ssm), jnp.zeros_like(conv)
-        out, ssm, conv = mamba2.chunked(x, lp, ssm, conv, seq_lens, c)
+        out, ssm, conv = recurrent.chunked(x, lp, ssm, conv, seq_lens, c)
         return out, cache._replace(ssm=cache.ssm.at[j].set(ssm),
                                    conv=cache.conv.at[j].set(conv))
 
@@ -255,12 +340,11 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
         def body(carry, step, kind=kind, first=first, j0=j0):
             h, cache = carry
             lp = _at(layers[KIND_STACK[kind]], j0 + step)
-            out, cache = mixer(kind, rms_norm(h, lp["norm"], c.rms_eps), lp,
-                               cache, j0 + step)
+            out, cache = mixer(kind, norm(h, lp["norm"]), lp, cache,
+                               j0 + step)
             h = h + r * out
             lp = _at(layers["ffn"], first + step)
-            y, pairs = moe_mlp(rms_norm(h, lp["norm"], c.rms_eps), lp, c,
-                               seq_lens)
+            y, pairs = moe_mlp(norm(h, lp["norm"]), lp, c, seq_lens)
             h = h + r * y
             if cache.expert_pairs is not None:
                 cache = cache._replace(
@@ -269,7 +353,7 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
 
         (h, cache), _ = jax.lax.scan(
             body, (h, cache), jnp.arange(length, dtype=jnp.int32))
-    h = rms_norm(h, params["final_norm"], c.rms_eps)
+    h = norm(h, params["final_norm"])
     return h, cache._replace(lengths=kv_valid)
 
 
@@ -307,8 +391,37 @@ HF_TOP = {"model.embed_tokens.weight": "embed",
 def hf_config(config) -> dict:
     """The config as its published `config.json` keys (what
     `models/llama.py config_from_hf` reads back, and what the plain
-    reference `benchmarks/reference/hybrid_decoder.py` is given)."""
+    reference — `benchmarks/reference/hybrid_decoder.py`, or
+    `gdn_moe_decoder.py` for a qwen3_next config — is given)."""
     c = config
+    if c.recurrent_kind == "linear_attention":
+        return {
+            "architectures": ["Qwen3NextForCausalLM"],
+            "model_type": "qwen3_next",
+            "vocab_size": c.vocab_size, "hidden_size": c.hidden_size,
+            "num_hidden_layers": c.num_layers,
+            "layer_types": list(c.layer_types),
+            "full_attention_interval":
+                c.layer_types.index("full_attention") + 1,
+            "num_attention_heads": c.num_heads,
+            "num_key_value_heads": c.num_kv_heads,
+            "head_dim": c.dim_per_head,
+            "moe_intermediate_size": c.intermediate_size,
+            "shared_expert_intermediate_size": c.shared_intermediate_size,
+            "num_experts": c.num_experts,
+            "num_experts_per_tok": c.num_experts_per_tok,
+            "norm_topk_prob": True, "decoder_sparse_step": 1,
+            "mlp_only_layers": [],
+            "linear_num_key_heads": c.linear_num_key_heads,
+            "linear_key_head_dim": c.linear_key_head_dim,
+            "linear_num_value_heads": c.linear_num_value_heads,
+            "linear_value_head_dim": c.linear_value_head_dim,
+            "linear_conv_kernel_dim": c.linear_conv_kernel_dim,
+            "partial_rotary_factor": c.partial_rotary_factor,
+            "rope_theta": c.rope_theta, "rms_norm_eps": c.rms_eps,
+            "tie_word_embeddings": c.tie_embeddings,
+            "max_position_embeddings": c.max_position,
+        }
     return {
         "architectures": ["GraniteMoeHybridForCausalLM"],
         "model_type": "granitemoehybrid",
@@ -351,11 +464,13 @@ def _from_hf(ours: str, arr):
 
 
 def convert_hf_state_dict(tensors: dict, config) -> dict:
-    """A full in-memory HF granitemoehybrid state dict -> our pytree
-    (numpy). Raises KeyError naming the first tensor that is missing and
-    ValueError for one that maps nowhere."""
+    """A full in-memory HF granitemoehybrid (or qwen3_next) state dict ->
+    our pytree (numpy). Raises KeyError naming the first tensor that is
+    missing and ValueError for one that maps nowhere."""
     import numpy as np
 
+    if config.recurrent_kind == "linear_attention":
+        return _qwen3_next_from_hf(tensors, config)
     known = set(HF_TOP)
     stacks: dict = {"mamba": {}, "attn": {}, "ffn": {}}
     for i, kind in enumerate(config.layer_types):
@@ -383,6 +498,9 @@ def to_hf_state_dict(params: dict, config) -> dict:
     """The inverse of `convert_hf_state_dict` (numpy, float32)."""
     import numpy as np
 
+    if config.recurrent_kind == "linear_attention":
+        return _qwen3_next_to_hf(params, config)
+
     def arr(a):
         return np.asarray(a, np.float32)
 
@@ -404,4 +522,151 @@ def to_hf_state_dict(params: dict, config) -> dict:
                      for n in names]
             out[prefix + hf] = np.concatenate(parts, axis=-2) \
                 if len(parts) > 1 else parts[0]
+    return out
+
+
+# ---------------------------------------------------------------------------
+# HF `qwen3_next` checkpoint names. HF fuses the Gated DeltaNet's projections
+# PER KEY-HEAD GROUP: the rows of `in_proj_qkvz` are, for key head g,
+# (q_g [Dk] | k_g [Dk] | v of g's value heads [r Dv] | z of them [r Dv]) with
+# r = Hv / Hk, and those of `in_proj_ba` (b of g's value heads [r] | a [r]);
+# ours are split by role, (q | k | v | z) and (b | a), every head in order.
+# The routed experts are named one by one (`mlp.experts.{e}.gate_proj`, ...).
+
+QWEN_MIXER = {
+    "linear_attention": {
+        "input_layernorm.weight": "norm",
+        "linear_attn.in_proj_qkvz.weight": "in_proj",
+        "linear_attn.in_proj_ba.weight": "in_ba",
+        "linear_attn.conv1d.weight": "conv_w",
+        "linear_attn.dt_bias": "dt_bias", "linear_attn.A_log": "A_log",
+        "linear_attn.norm.weight": "gate_norm",
+        "linear_attn.out_proj.weight": "out_proj"},
+    "full_attention": {
+        "input_layernorm.weight": "norm",
+        "self_attn.q_proj.weight": "wq", "self_attn.k_proj.weight": "wk",
+        "self_attn.v_proj.weight": "wv", "self_attn.o_proj.weight": "wo",
+        "self_attn.q_norm.weight": "q_norm",
+        "self_attn.k_norm.weight": "k_norm"},
+}
+QWEN_FFN = {"post_attention_layernorm.weight": "norm",
+            "mlp.gate.weight": "router",
+            "mlp.shared_expert.gate_proj.weight": "sg",
+            "mlp.shared_expert.up_proj.weight": "su",
+            "mlp.shared_expert.down_proj.weight": "sd",
+            "mlp.shared_expert_gate.weight": "sgate"}
+QWEN_EXPERT = {"gate_proj": "wg", "up_proj": "wu", "down_proj": "wd"}
+QWEN_TOP = {"model.embed_tokens.weight": "embed",
+            "model.norm.weight": "final_norm", "lm_head.weight": "lm_head"}
+
+
+def _group_widths(config) -> tuple[list[int], list[int]]:
+    """Per key-head group, the widths HF fuses in `in_proj_qkvz` and in
+    `in_proj_ba`."""
+    z = gdn.sizes(config)
+    r = z["Hv"] // z["Hk"]
+    return [z["Dk"], z["Dk"], r * z["Dv"], r * z["Dv"]], [r, r]
+
+
+def _ungroup(arr, widths: list[int], n_groups: int):
+    """HF [n_groups * sum(widths), E] rows fused per group -> ours [E,
+    sum over roles], every role's groups in order."""
+    import numpy as np
+
+    per = arr.reshape(n_groups, sum(widths), arr.shape[-1])
+    cuts = np.cumsum([0] + widths)
+    return np.concatenate(
+        [per[:, a:b].reshape(-1, arr.shape[-1])
+         for a, b in zip(cuts[:-1], cuts[1:])], axis=0).T
+
+
+def _regroup(arr, widths: list[int], n_groups: int):
+    """The inverse of `_ungroup`: ours [E, ...] -> HF's fused rows."""
+    import numpy as np
+
+    rows = arr.T
+    cuts = np.cumsum([0] + [w * n_groups for w in widths])
+    roles = [rows[a:b].reshape(n_groups, w, rows.shape[-1])
+             for a, b, w in zip(cuts[:-1], cuts[1:], widths)]
+    return np.concatenate(roles, axis=1).reshape(-1, rows.shape[-1])
+
+
+def _qwen3_next_from_hf(tensors: dict, config) -> dict:
+    import numpy as np
+
+    qkvz, ba = _group_widths(config)
+    n_groups = config.linear_num_key_heads
+    known = set(QWEN_TOP)
+    stacks: dict = {"gdn": {}, "attn": {}, "ffn": {}}
+
+    def ours_of(name, a):
+        if name == "in_proj":
+            return _ungroup(a, qkvz, n_groups)
+        if name == "in_ba":
+            return _ungroup(a, ba, n_groups)
+        if name == "conv_w":
+            return np.ascontiguousarray(a[:, 0, :].T)           # [K, C]
+        return np.swapaxes(a, -1, -2) if a.ndim >= 2 else a
+
+    for i, kind in enumerate(config.layer_types):
+        prefix = f"model.layers.{i}."
+        for hf, name in QWEN_MIXER[kind].items():
+            known.add(prefix + hf)
+            stacks[KIND_STACK[kind]].setdefault(name, []).append(
+                ours_of(name, tensors[prefix + hf]))
+        for hf, name in QWEN_FFN.items():
+            known.add(prefix + hf)
+            stacks["ffn"].setdefault(name, []).append(
+                ours_of(name, tensors[prefix + hf]))
+        for hf, name in QWEN_EXPERT.items():
+            names = [f"{prefix}mlp.experts.{e}.{hf}.weight"
+                     for e in range(config.num_experts)]
+            known.update(names)
+            stacks["ffn"].setdefault(name, []).append(
+                np.stack([tensors[n].T for n in names]))
+    unmapped = sorted(set(tensors) - known)
+    if unmapped:
+        raise ValueError(f"unmapped HF tensors: {unmapped[:4]}")
+    out = {"embed": tensors["model.embed_tokens.weight"],
+           "final_norm": tensors["model.norm.weight"],
+           "layers": {stack: {k: np.stack(v) for k, v in leaves.items()}
+                      for stack, leaves in stacks.items()}}
+    if not config.tie_embeddings:
+        out["lm_head"] = tensors["lm_head.weight"].T
+    return out
+
+
+def _qwen3_next_to_hf(params: dict, config) -> dict:
+    import numpy as np
+
+    def arr(a):
+        return np.asarray(a, np.float32)
+
+    qkvz, ba = _group_widths(config)
+    n_groups = config.linear_num_key_heads
+    lay = params["layers"]
+    out = {"model.embed_tokens.weight": arr(params["embed"]),
+           "model.norm.weight": arr(params["final_norm"])}
+    if not config.tie_embeddings:
+        out["lm_head.weight"] = arr(params["lm_head"]).T
+
+    def hf_of(name, a):
+        if name == "in_proj":
+            return _regroup(a, qkvz, n_groups)
+        if name == "in_ba":
+            return _regroup(a, ba, n_groups)
+        if name == "conv_w":
+            return a.T[:, None, :]
+        return np.swapaxes(a, -1, -2) if a.ndim >= 2 else a
+
+    for i, kind in enumerate(config.layer_types):
+        prefix, j = f"model.layers.{i}.", stack_index(config, i)
+        for hf, name in QWEN_MIXER[kind].items():
+            out[prefix + hf] = hf_of(name, arr(lay[KIND_STACK[kind]][name][j]))
+        for hf, name in QWEN_FFN.items():
+            out[prefix + hf] = hf_of(name, arr(lay["ffn"][name][i]))
+        for hf, name in QWEN_EXPERT.items():
+            for e in range(config.num_experts):
+                out[f"{prefix}mlp.experts.{e}.{hf}.weight"] = arr(
+                    lay["ffn"][name][i][e]).T
     return out

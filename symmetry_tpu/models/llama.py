@@ -84,12 +84,14 @@ class MoEConfig(ModelConfig):
 
 @dataclass(frozen=True)
 class HybridConfig(MoEConfig):
-    """Layers of two mixer kinds in one model (granitemoehybrid family):
-    `layer_types[i]` is "mamba" (a Mamba-2 mixer with a per-slot recurrent
-    state, models/mamba2.py) or "attention" (GQA, here without rotary
-    embedding), each followed by the routed + shared expert FFN. The
-    forward pass, parameters and cache are models/hybrid.py's; every entry
-    point of this module hands a config with `layer_types` over to it."""
+    """Layers of two mixer kinds in one model (granitemoehybrid and
+    qwen3_next families): `layer_types[i]` is "mamba" (a Mamba-2 mixer with
+    a per-slot recurrent state, models/mamba2.py) or "attention" (GQA, here
+    without rotary embedding) — or, qwen3_next's pair, "linear_attention"
+    (Gated DeltaNet, models/gdn.py) or "full_attention" — each followed by
+    the routed + shared expert FFN. The forward pass, parameters and cache
+    are models/hybrid.py's; every entry point of this module hands a config
+    with `layer_types` over to it."""
 
     layer_types: tuple[str, ...] = ()
     mamba_n_heads: int = 0
@@ -102,17 +104,45 @@ class HybridConfig(MoEConfig):
     attention_multiplier: float | None = None   # None: 1 / sqrt(head_dim)
     logits_scaling: float = 1.0
     rope: bool = True                 # False: position_embedding_type nope
+    # qwen3_next family, every field at "absent" for any other model:
+    # `layer_types[i]` is "linear_attention" (a Gated DeltaNet mixer with a
+    # per-slot MATRIX state, models/gdn.py) or "full_attention" (GQA whose
+    # q_proj also emits an output gate, q and k normed per head, only the
+    # leading `partial_rotary_factor` of each head rotated), HF's names.
+    linear_num_key_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_num_value_heads: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_chunk_size: int = 64       # the published kernels' chunk
+    partial_rotary_factor: float = 1.0
+    qk_norm: bool = False             # per-head RMSNorm on q and k
+    attn_output_gate: bool = False    # wq is [E, 2 * q_dim]: (q | gate) a head
+    shared_expert_gate: bool = False  # shared(x) * sigmoid(x . w_sg)
 
     def __post_init__(self):
         kinds = set(self.layer_types)
         if (len(self.layer_types) != self.num_layers
-                or not kinds <= {"mamba", "attention"}):
+                or not (kinds <= {"mamba", "attention"}
+                        or kinds <= {"linear_attention", "full_attention"})):
             raise ValueError(
                 f"layer_types must name {self.num_layers} layers, each "
-                f"'mamba' or 'attention'; got {self.layer_types!r}")
+                f"'mamba' or 'attention', or each 'linear_attention' or "
+                f"'full_attention'; got {self.layer_types!r}")
 
     def layers_of(self, kind: str) -> tuple[int, ...]:
         return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
+
+    @property
+    def recurrent_kind(self) -> str:
+        """The name `layer_types` gives this model's recurrent layers."""
+        return ("linear_attention" if "linear_attention" in self.layer_types
+                or "full_attention" in self.layer_types else "mamba")
+
+    @property
+    def attention_kind(self) -> str:
+        return ("full_attention" if self.recurrent_kind == "linear_attention"
+                else "attention")
 
 
 # Named presets; sizes from the public HF configs of each model family.
@@ -195,6 +225,43 @@ PRESETS: dict[str, ModelConfig] = {
         mamba_d_conv=4, mamba_chunk_size=256, embedding_multiplier=12.0,
         residual_multiplier=0.22, attention_multiplier=0.0078125,
         logits_scaling=16.0, rope=False,
+    ),
+    # the CPU's copy of qwen3-next's mechanisms: the 3:1 pattern, 2 q/k
+    # heads serving 4 value heads, a chunk shorter than the test prompts,
+    # a quarter of each attention head rotated, 16 experts top 4 and a
+    # gated shared one, zero-centred norms, an untied head
+    "tiny-gdn": HybridConfig(
+        vocab_size=512, hidden_size=64, num_layers=4, num_heads=4,
+        num_kv_heads=2, intermediate_size=32, head_dim=16,
+        rope_theta=10000.0, rms_eps=1e-6, max_position=512,
+        norm_plus_one=True, num_experts=16, num_experts_per_tok=4,
+        shared_intermediate_size=32,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        linear_num_key_heads=2, linear_key_head_dim=16,
+        linear_num_value_heads=4, linear_value_head_dim=16,
+        linear_chunk_size=16, partial_rotary_factor=0.25, qk_norm=True,
+        attn_output_gate=True, shared_expert_gate=True,
+    ),
+    # Qwen3-Next-80B-A3B-Instruct CUT IN DEPTH to the first period of its
+    # layer pattern — layers 0-3 of 48: Gated DeltaNet x 3, gated attention
+    # — at every published width, with all 512 experts and the whole
+    # vocabulary: stage 1 of 12 of a pipeline, what one 16 GB chip holds in
+    # int8 (benchmarks/configs/qwen3-next-80b-a3b.json has the cut).
+    # `intermediate_size` is the routed expert's width
+    # (`moe_intermediate_size`); the published dense width 5120 serves no
+    # layer (`mlp_only_layers` is empty).
+    "qwen3-next-80b-a3b": HybridConfig(
+        vocab_size=151936, hidden_size=2048, num_layers=4, num_heads=16,
+        num_kv_heads=2, intermediate_size=512, head_dim=256,
+        rope_theta=10000000.0, rms_eps=1e-6, max_position=262144,
+        norm_plus_one=True, num_experts=512, num_experts_per_tok=10,
+        shared_intermediate_size=512,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        linear_num_key_heads=16, linear_key_head_dim=128,
+        linear_num_value_heads=32, linear_value_head_dim=128,
+        linear_conv_kernel_dim=4, linear_chunk_size=64,
+        partial_rotary_factor=0.25, qk_norm=True, attn_output_gate=True,
+        shared_expert_gate=True,
     ),
     "gemma-7b": ModelConfig(
         vocab_size=256000, hidden_size=3072, num_layers=28, num_heads=16,
@@ -537,12 +604,21 @@ def _attention(
         q = q + lp["bq"]
         k = k + lp["bk"]
         v = v + lp["bv"]
+    gate = None
+    if getattr(config, "attn_output_gate", False):
+        # qwen3_next: q_proj emits, per head, the query then an output gate
+        q = q.reshape(B, S, nq, 2 * D)
+        q, gate = q[..., :D], q[..., D:].reshape(B, S, nq * D)
     q = q.reshape(B, S, nq, D)
     k = k.reshape(B, S, nkv, D)
     v = v.reshape(B, S, nkv, D)
+    if getattr(config, "qk_norm", False):  # per head, over its D channels
+        q = rms_norm(q, _norm_w(lp["q_norm"], config), config.rms_eps)
+        k = rms_norm(k, _norm_w(lp["k_norm"], config), config.rms_eps)
     if getattr(config, "rope", True):
-        q = apply_rope(q, positions, config.rope_theta)
-        k = apply_rope(k, positions, config.rope_theta)
+        rot = int(D * getattr(config, "partial_rotary_factor", 1.0))
+        q = apply_rope(q, positions, config.rope_theta, rot)
+        k = apply_rope(k, positions, config.rope_theta, rot)
     scale = getattr(config, "attention_multiplier", None)
     if scale is not None:
         # every attention path scales scores by 1 / sqrt(D): fold the
@@ -608,7 +684,10 @@ def _attention(
                 sliding_window=config.sliding_window,
                 k_scale=at_layer(cache.k_scale) if cache.quantized else None,
                 v_scale=at_layer(cache.v_scale) if cache.quantized else None)
-    return qmatmul(attn.reshape(B, S, nq * D), lp["wo"]), cache
+    attn = attn.reshape(B, S, nq * D)
+    if gate is not None:
+        attn = attn * jax.nn.sigmoid(gate)
+    return qmatmul(attn, lp["wo"]), cache
 
 
 def _layer(
@@ -790,8 +869,9 @@ def logits_from_hidden(params: dict, config: ModelConfig,
 
 # Weights eligible for int8 quantization (all the large matmuls; the
 # embedding stays dense — it is gathered, not contracted).
-# `in_proj` / `out_proj` are the mamba mixer's, `sg` / `su` / `sd` the shared
-# expert's (models/hybrid.py).
+# `in_proj` / `out_proj` are the recurrent mixer's (mamba's or the Gated
+# DeltaNet's q|k|v|z projection), `sg` / `su` / `sd` the shared expert's
+# (models/hybrid.py).
 QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "in_proj",
               "out_proj", "sg", "su", "sd", "lm_head")
 # Those of them stacked along a leading layers axis.
@@ -989,6 +1069,45 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
     sliding = hf.get("sliding_window")
     if hf.get("use_sliding_window") is False:
         sliding = None
+    if hf.get("model_type") == "qwen3_next":
+        if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1:
+            raise ValueError("qwen3_next with dense-MLP layers "
+                             "(mlp_only_layers, decoder_sparse_step) is not "
+                             "implemented")
+        if not hf.get("norm_topk_prob", True):
+            raise ValueError("qwen3_next with norm_topk_prob false is not "
+                             "implemented")
+        interval = hf.get("full_attention_interval", 4)
+        types = tuple(hf.get("layer_types") or (
+            "full_attention" if (i + 1) % interval == 0
+            else "linear_attention"
+            for i in range(hf["num_hidden_layers"])))
+        return HybridConfig(
+            vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf["num_key_value_heads"],
+            # the routed expert's width; `intermediate_size` (the dense
+            # width) serves no layer when every layer is sparse
+            intermediate_size=hf["moe_intermediate_size"],
+            head_dim=hf.get("head_dim"),
+            rope_theta=hf.get("rope_theta", 10000.0),
+            rms_eps=hf.get("rms_norm_eps", 1e-6),
+            tie_embeddings=hf.get("tie_word_embeddings", False),
+            max_position=hf.get("max_position_embeddings", 8192),
+            norm_plus_one=True,
+            num_experts=hf["num_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            shared_intermediate_size=hf["shared_expert_intermediate_size"],
+            layer_types=types,
+            linear_num_key_heads=hf["linear_num_key_heads"],
+            linear_key_head_dim=hf["linear_key_head_dim"],
+            linear_num_value_heads=hf["linear_num_value_heads"],
+            linear_value_head_dim=hf["linear_value_head_dim"],
+            linear_conv_kernel_dim=hf.get("linear_conv_kernel_dim", 4),
+            partial_rotary_factor=hf.get("partial_rotary_factor", 1.0),
+            qk_norm=True, attn_output_gate=True, shared_expert_gate=True,
+        )
     if hf.get("model_type") == "granitemoehybrid":
         types = tuple(hf["layer_types"])
         if hf.get("mamba_n_groups", 1) != 1:

@@ -31,10 +31,19 @@ layer, routed / dense): 64 tokens 1.35 / 0.49 (the weight stream's floor is
 it saves more than it wastes: they cross at about 1,050 tokens.
 `moe_route(T, experts, k)` is that choice, from the shape alone (each routing
 shape has its own measured crossing: 72 experts top 10 never route below
-2,560 tokens);
+2,560 tokens; 512 experts top 10 route from 1,024);
 `startup.moe` reports it per program. A config with
 `shared_intermediate_size` adds a shared expert (`sg`, `su`, `sd`: one dense
-gated FFN every token passes through) to the routed sum.
+gated FFN every token passes through) to the routed sum, weighted by
+sigmoid(x . sgate) where the layer has that column (qwen3_next).
+
+qwen3_next's router (HF `Qwen3NextSparseMoeBlock`, `norm_topk_prob` true)
+takes the softmax over ALL the router logits, keeps the k largest and
+renormalises them to sum 1. That IS `route_top_k`'s softmax over the k
+selected logits — e^{l_i} / sum_{j in top} e^{l_j} either way: the full
+softmax's denominator cancels, and the k largest probabilities are the k
+largest logits — so the router needs no second form
+(`tests/test_gdn.py` holds the two to each other).
 
 int8 expert stacks stay int8 in HBM in both forms: the int8 payload is the
 dot's operand, the per-(expert, column) scale is applied to the float32
@@ -79,7 +88,18 @@ ROUTED_MIN_TOKENS = 1024
 # mixture's [X, T, D] float32 products are never held whole — 0.75 GB of
 # temporaries for a 2,048-token prefill of the ten layers — so it needs no
 # blocking at 72 experts.)
-ROUTED_FROM = {(72, 10): 2560}
+# 512 top 10 at expert width 512 (qwen3-next-80b-a3b; PERF.md, PR 35;
+# `tools/moe_decode_ab.py --shape 512,10,2048,512`, ms a layer, routed /
+# dense): 64 tokens 7.96 / 2.20, 128: 10.45 / 2.85 (the weight stream's
+# floor is 1.97), 256: 14.76 / 4.93, 512: 15.21 / 9.92, 1,024: 16.51 /
+# 20.11, 2,048: 19.42 / 49.84 — `ragged_dot` over 512 groups costs 8-15 ms
+# before the first useful row and is nearly flat from 256 tokens on (a
+# group of 5-40 rows fills a fraction of an MXU tile), the mixture's 51x
+# FLOPs grow with every token (~82% of the MXU peak at 2,048): they cross
+# between 512 and 1,024 tokens, and no dispatch lies between the two
+# (batches double). Decode (128 tokens) is the mixture at 1.4x its weight
+# floor.
+ROUTED_FROM = {(72, 10): 2560, (512, 10): 1024}
 
 
 def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
@@ -248,8 +268,14 @@ def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
             in_specs=(P(b, None), P(b), P(), col, col, row),
             out_specs=(P(b, None), P()), check_vma=False)(*args)
     if "sg" in lp:
-        # the shared expert: the same gated form, every token, weight 1,
-        # added to the routed sum in float32
-        y = y + qmatmul(jax.nn.silu(qmatmul(xf, lp["sg"]))
-                        * qmatmul(xf, lp["su"]), lp["sd"])
+        # the shared expert: the same gated form, every token, weight 1 —
+        # or, where the layer has a `sgate` column (qwen3_next's
+        # `shared_expert_gate`), sigmoid(x . sgate) — added to the routed
+        # sum in float32
+        shared = qmatmul(jax.nn.silu(qmatmul(xf, lp["sg"]))
+                         * qmatmul(xf, lp["su"]), lp["sd"])
+        if "sgate" in lp:
+            shared = shared * jax.nn.sigmoid(jnp.dot(
+                xf, lp["sgate"], preferred_element_type=jnp.float32))
+        y = y + shared
     return y.astype(x.dtype).reshape(B, S, D), pairs

@@ -36,8 +36,16 @@ def apply_rope(
     x: jnp.ndarray,          # [batch, seq, heads, head_dim]
     positions: jnp.ndarray,  # [batch, seq]
     theta: float = 500000.0,
+    rotary_dim: int | None = None,
 ) -> jnp.ndarray:
-    """Rotate q or k by absolute position; returns x's dtype."""
+    """Rotate q or k by absolute position; returns x's dtype. With
+    `rotary_dim` under the head size (partial rotary: HF
+    `partial_rotary_factor`) only the leading `rotary_dim` channels of each
+    head rotate, as a head of that size would; the rest pass through."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], axis=-1)
     cos, sin = rope_cos_sin(positions, x.shape[-1], theta)
     # Broadcast over the heads axis: [batch, seq, 1, head_dim].
     cos, sin = cos[..., None, :], sin[..., None, :]

@@ -227,3 +227,53 @@ def test_hybrid_decode_step_updates_the_recurrent_state_in_place(
                  or stack & set(re.findall(r"%[\w.\-]+",
                                            line.split(" fusion(")[1])))]
     assert stack and not fused, fused[:1]
+
+
+def test_gdn_decode_step_updates_the_matrix_state_in_place(
+        one_chip, no_cache, monkeypatch):
+    """qwen3-next-80b-a3b's decode trunk at its cell (128 slots x 640): the
+    0.81 GB matrix state is donated in, aliased out and updated where it
+    lies — no copy or relayout of the whole stack, temporaries under one
+    layer's state — by the jnp step's two fusions a layer (one pass that
+    reads the state for both read-outs, one in-place update), and the one
+    gated-attention layer (2 KV heads of 256) takes the decode kernel."""
+    from symmetry_tpu.models import llama, mamba2
+
+    monkeypatch.setattr(llama, "interpret_mode", lambda: False)
+    monkeypatch.setattr(mamba2, "interpret_mode", lambda: False)
+    cfg = llama.preset("qwen3-next-80b-a3b")
+    B, T = 128, 640
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+        slice_above=1 << 40))
+    cache = shaped(lambda: llama.init_cache(cfg, B, T, jnp.bfloat16,
+                                            quantized=True))
+    assert cache.ssm.shape == (3, 128, 32, 128, 128)
+    assert cache.conv.shape == (3, 3, 128, 8192)
+    assert cache.k.shape == (1, 128, 640, 2, 256)
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
+        donate_argnums=(2,)).lower(params, tok, cache).compile()
+    memory = compiled.memory_analysis()
+    state_bytes = 3 * 128 * 32 * 128 * 128 * 4
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 3    # under one layer
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1          # decode attention
+    whole = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= f32\[3,128,32,128,128\]\S* (copy|transpose)\(",
+                          line)]
+    assert not whole, whole[0]
+    # the stack's only writer is an in-place dynamic-update-slice
+    writers = [line for line in text.splitlines()
+               if re.search(r"= f32\[3,128,32,128,128\]\S* "
+                            r"dynamic-update-slice\(", line)]
+    assert writers

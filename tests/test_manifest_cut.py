@@ -35,6 +35,7 @@ def cut_files():
 
 def test_there_is_a_cut_configuration_to_hold():
     assert "granite-4.0-h-small.json" in cut_files()
+    assert "qwen3-next-80b-a3b.json" in cut_files()
 
 
 @pytest.mark.parametrize("name", cut_files())
@@ -57,6 +58,17 @@ def test_reduced_lists_exactly_the_keys_that_differ_from_published(name):
 @pytest.mark.parametrize("name", cut_files())
 def test_a_cut_keeps_a_whole_period_of_the_published_pattern(name):
     c = load(name)
+    if "full_attention_interval" in c:
+        # the pattern is the interval's (qwen3_next): a whole number of
+        # periods, at least one and at least four layers
+        every = c["full_attention_interval"]
+        assert c["num_hidden_layers"] % every == 0
+        assert c["published"]["num_hidden_layers"] % every == 0
+        assert c["num_hidden_layers"] >= max(4, every)
+        assert list(preset(c["tpu"]["model_preset"]).layer_types) == [
+            "full_attention" if (i + 1) % every == 0 else "linear_attention"
+            for i in range(c["num_hidden_layers"])]
+        return
     if "layer_types" not in c["reduced"]:
         pytest.skip("no layer pattern was cut")
     full, kept = c["published"]["layer_types"], c["layer_types"]
@@ -76,13 +88,34 @@ def test_a_cut_keeps_a_whole_period_of_the_published_pattern(name):
 def test_a_cut_file_is_the_programs_preset(name):
     c = load(name)
     p = preset(c["tpu"]["model_preset"])
+    # a qwen3_next file's `intermediate_size` is the dense width no layer
+    # uses; the preset's is the routed expert's
+    width = c.get("moe_intermediate_size", c["intermediate_size"])
     assert (p.vocab_size, p.hidden_size, p.num_layers, p.num_heads,
             p.num_kv_heads, p.intermediate_size, p.dim_per_head) == (
         c["vocab_size"], c["hidden_size"], c["num_hidden_layers"],
         c["num_attention_heads"], c["num_key_value_heads"],
-        c["intermediate_size"], c["head_dim"])
+        width, c["head_dim"])
     assert p.rope_theta == c["rope_theta"] and p.rms_eps == c["rms_norm_eps"]
     assert p.tie_embeddings == c["tie_word_embeddings"]
+    if c.get("model_type") == "qwen3_next":
+        from symmetry_tpu.models.llama import config_from_hf
+
+        # every published key the program reads, through its own reader
+        assert config_from_hf(c) == p
+        assert (p.num_experts, p.num_experts_per_tok,
+                p.shared_intermediate_size) == (
+            c["num_experts"], c["num_experts_per_tok"],
+            c["shared_expert_intermediate_size"]) == (512, 10, 512)
+        assert (p.linear_num_key_heads, p.linear_key_head_dim,
+                p.linear_num_value_heads, p.linear_value_head_dim,
+                p.linear_conv_kernel_dim) == (
+            c["linear_num_key_heads"], c["linear_key_head_dim"],
+            c["linear_num_value_heads"], c["linear_value_head_dim"],
+            c["linear_conv_kernel_dim"])
+        assert p.partial_rotary_factor == c["partial_rotary_factor"]
+        assert p.max_position == c["max_position_embeddings"]
+        assert c["mlp_only_layers"] == [] and c["norm_topk_prob"] is True
     if "layer_types" in c:
         assert list(p.layer_types) == c["layer_types"]
         assert (p.num_experts, p.num_experts_per_tok,
