@@ -47,7 +47,8 @@ contends for the chip its child needs.
         # the one-chip hybrid models (a per-slot recurrent state beside an
         # attention layer; the report carries `ssm`: the kind, its state
         # and each program's form — Mamba-2's decode step must read
-        # `pallas`, the Gated DeltaNet's is jnp so far — and `moe`)
+        # `pallas`, the Gated DeltaNet's is jnp so far — and `moe`, whose
+        # `grouped_matmul` must read `pallas` on one chip: ops/gmm.py)
     JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny
         # CPU dry run of every phase; ends non-zero: "platform is cpu"
 """
@@ -312,6 +313,13 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
             and ssm_decode.get("form") != "pallas"):
         failures.append(f"the recurrent layers' decode step did not run "
                         f"the compiled Pallas kernel: {ssm_decode}")
+    # One chip's int8 expert stacks: the routed form over the compiled
+    # grouped-matmul kernel (ops/gmm.py); under a mesh `lax.ragged_dot`
+    gmm_form = (startup.get("moe") or {}).get("grouped_matmul")
+    if (gmm_form is not None and "mesh" not in cfg["tpu"]
+            and gmm_form.get("form") != "pallas"):
+        failures.append(f"the routed expert FFN's grouped matmul did not "
+                        f"run the compiled Pallas kernel: {gmm_form}")
     if device.get("platform") != "tpu":
         failures.append(f"the engine host's platform is "
                         f"{device.get('platform')}, not tpu")

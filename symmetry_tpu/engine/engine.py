@@ -2009,7 +2009,8 @@ class InferenceEngine:
             return None
         if self._moe_report is not None:
             return self._moe_report
-        from symmetry_tpu.models.moe import moe_layout, moe_route
+        from symmetry_tpu.models.moe import (
+            grouped_matmul_form, moe_layout, moe_route)
         from symmetry_tpu.ops.quant import QuantizedTensor
 
         layers = self.params["layers"]
@@ -2018,19 +2019,24 @@ class InferenceEngine:
         def route(tokens: int) -> str:
             return moe_route(tokens, c.num_experts, c.num_experts_per_tok)
 
+        # by tokens a dispatch: decode is one per slot; a prefill is
+        # batch x bucket for every shape warm-up compiles
+        prefills = sorted({b * bucket for bucket in self.prefill_buckets
+                           for b in self.prefill_batches_for(bucket)})
         self._moe_report = {
             "experts": c.num_experts, "top_k": c.num_experts_per_tok,
             "layout": moe_layout(self.mesh, c.intermediate_size),
-            # by tokens a dispatch: decode is one per slot; a prefill is
-            # batch x bucket for every shape warm-up compiles
             "route": {"decode": route(self.max_slots),
-                      "prefill": {str(t): route(t) for t in sorted({
-                          b * bucket for bucket in self.prefill_buckets
-                          for b in self.prefill_batches_for(bucket)})}},
+                      "prefill": {str(t): route(t) for t in prefills}},
+            # what the routed form's three matmuls run as (the row tile
+            # of the smallest program's rows: the kernel's own from 64)
+            "grouped_matmul": grouped_matmul_form(
+                wg, min(self.max_slots, *prefills) * c.num_experts_per_tok,
+                one_device=self.mesh is None),
             "quantized_leaf_route": (
                 "expert_stack: int8 [L, X, K, N] stays flat (the packed "
-                "W8A16 layout has no expert grid dim) and is the ragged "
-                "dot's operand, scales on the accumulator"
+                "W8A16 layout has no expert grid dim) and is the grouped "
+                "matmul's operand, scales on the accumulator"
                 if isinstance(wg, QuantizedTensor) else "not quantized"),
         }
         if c.shared_intermediate_size:

@@ -24,7 +24,9 @@ d_conv - 1, B, C] for the mamba layers. A mamba layer's step reads and
 writes its slice of `ssm` where it lies — the decode step hands the whole
 stack and the layer's index to one kernel (ops/ssm_step.py), the chunked
 form `.at[j].set`s the donated buffer — and the stack rides every scan's
-carry.
+carry. The expert FFN is handed the `ffn` stacks whole beside the layer's
+index for the same reason: the routed form's kernel (ops/gmm.py) reads an
+expert's int8 tile at (layer, expert) of the stack as it lies.
 
 Which form a mamba layer takes follows the call's shape: one position a
 slot is the recurrence step; more is the chunked form, from zeros when the
@@ -344,7 +346,8 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
                                j0 + step)
             h = h + r * out
             lp = _at(layers["ffn"], first + step)
-            y, pairs = moe_mlp(norm(h, lp["norm"]), lp, c, seq_lens)
+            y, pairs = moe_mlp(norm(h, lp["norm"]), lp, c, seq_lens,
+                               stack=(layers["ffn"], first + step))
             h = h + r * y
             if cache.expert_pairs is not None:
                 cache = cache._replace(

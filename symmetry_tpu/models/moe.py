@@ -8,34 +8,33 @@ experts per token, softmax over the k selected logits, and
 
 Neither form drops a (token, expert) pair, and there is no capacity:
 
-- ROUTED (`_routed_ffn`; programs of `ROUTED_MIN_TOKENS` tokens or more —
-  the larger prefill dispatches). The T*k pairs are sorted by expert, so
-  each expert's rows are one contiguous group; the three expert matmuls run
-  over the groups as `jax.lax.ragged_dot` (XLA:TPU's native grouped matmul:
-  T*k rows whatever the routing — static shapes, FLOPs of k experts and not
-  of all of them); the rows are un-sorted by a gather and combined with the
-  gates in float32.
-- DENSE MIXTURE (`_dense_mixture`; decode and the smaller prefills). Every
+- ROUTED (`_routed_ffn`; programs of at least the routing shape's crossing:
+  `moe_route`). The T*k pairs are sorted by expert, so each expert's rows
+  are one contiguous group; the three expert matmuls run over the groups —
+  T*k rows whatever the routing: static shapes, FLOPs of k experts and not
+  of all of them — the rows are un-sorted by a gather and combined with the
+  gates in float32. The grouped matmul (`_grouped_matmul`) is
+  * the Pallas kernel `moe_gmm` (ops/gmm.py) for an int8 stack where the
+    expert FFN is traced on one device: it reads each HIT expert's int8
+    tile once, where it lies in the layers' stack, widens it in VMEM and
+    multiplies only the row tiles the expert's group touches;
+  * `jax.lax.ragged_dot` (XLA:TPU's native grouped matmul) everywhere else:
+    under a mesh (each shard of mixtral-8x7b on `model: 4`), for bf16 /
+    float32 stacks, for a shape the kernel cannot tile. It is also the
+    kernel's reference in the tests.
+- DENSE MIXTURE (`_dense_mixture`; programs under the crossing). Every
   expert computes every token as one batched matmul and the gates, zero
   outside the top k, weight the combine: X/k times the FLOPs, the same
-  weight bytes. A decode step of B slots x k pairs hits every expert anyway,
-  so all expert weights stream from HBM either way.
+  weight bytes. A decode step of B slots x k pairs hits every expert of a
+  few dozen anyway, so all expert weights stream from HBM either way.
 
-Which form where is a measurement, not a taste (`tools/moe_decode_ab.py`;
-PERF.md, PR 28; one chip's share of mixtral-8x7b under `model: 4`, ms a
-layer, routed / dense): 64 tokens 1.35 / 0.49 (the weight stream's floor is
-0.43), 256 tokens 2.85 / 1.04, 768 tokens 3.69 / 3.24, 1,024 tokens 4.00 /
-3.93, 1,280 tokens 4.42 / 4.91, 2,048 tokens 5.98 / 7.93, 4,096 tokens 10.7
-/ 15.8. The mixed int8 dot runs the dense form at ~90% of the MXU peak,
-`ragged_dot` with an int8 operand reaches ~35%, so routing pays only once
-it saves more than it wastes: they cross at about 1,050 tokens.
-`moe_route(T, experts, k)` is that choice, from the shape alone (each routing
-shape has its own measured crossing: 72 experts top 10 never route below
-2,560 tokens; 512 experts top 10 route from 1,024);
-`startup.moe` reports it per program. A config with
-`shared_intermediate_size` adds a shared expert (`sg`, `su`, `sd`: one dense
-gated FFN every token passes through) to the routed sum, weighted by
-sigmoid(x . sgate) where the layer has that column (qwen3_next).
+Which form where is a measurement, not a taste (`tools/moe_decode_ab.py`:
+`ROUTED_MIN_TOKENS` and `ROUTED_FROM` below hold the readings).
+`moe_route(T, experts, k)` is that choice, from the shape alone;
+`startup.moe` reports it per program, and what the grouped matmul runs as.
+A config with `shared_intermediate_size` adds a shared expert (`sg`, `su`,
+`sd`: one dense gated FFN every token passes through) to the routed sum,
+weighted by sigmoid(x . sgate) where the layer has that column (qwen3_next).
 
 qwen3_next's router (HF `Qwen3NextSparseMoeBlock`, `norm_topk_prob` true)
 takes the softmax over ALL the router logits, keeps the k largest and
@@ -70,36 +69,49 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from symmetry_tpu.ops import gmm
+from symmetry_tpu.ops.interpret import interpret_mode
 from symmetry_tpu.ops.quant import QuantizedTensor, qmatmul
 
 
-# Programs of fewer tokens take the dense mixture (module docstring: the
-# two forms tie at 1,024 tokens a dispatch and routing wins above) — at
-# mixtral's 8 experts top 2, where it was measured.
+# Under a mesh the grouped matmul is `lax.ragged_dot`, and programs of fewer
+# tokens take the dense mixture — mixtral's 8 experts top 2, where it was
+# measured (PERF.md, PR 28; one chip's share of mixtral-8x7b under
+# `model: 4`, ms a layer, routed / dense): 64 tokens 1.35 / 0.49 (the weight
+# stream's floor is 0.43), 256 tokens 2.85 / 1.04, 768 tokens 3.69 / 3.24,
+# 1,024 tokens 4.00 / 3.93, 1,280 tokens 4.42 / 4.91, 2,048 tokens 5.98 /
+# 7.93, 4,096 tokens 10.7 / 15.8. The mixed int8 dot runs the dense form at
+# ~90% of the MXU peak, `ragged_dot` with an int8 operand reaches ~35%, so
+# routing pays only once it saves more than it wastes: they cross at about
+# 1,050 tokens. Any routing shape without a reading of its own takes this.
 ROUTED_MIN_TOKENS = 1024
-# Other routing shapes, each from its own reading of tools/moe_decode_ab.py:
-# (experts, k) -> the least tokens a dispatch at which routing pays. 72 top
-# 10 at expert width 768 (granite-4.0-h-small; PERF.md, PR 33; ms a layer,
-# routed / dense): 128 tokens 5.51 / 1.15, 512: 9.09 / 4.34, 1,024: 12.03 /
-# 8.10, 2,048: 18.10 / 16.74 — `ragged_dot` over 72 groups of a width of 768
-# reaches 11% of the MXU peak where the mixture's batched dot reaches 84%,
-# so the 7.2x FLOPs are still the cheaper form up to the largest dispatch
-# (2,048 tokens); the slopes cross near 2,600. (Compiled for a v5e the
-# mixture's [X, T, D] float32 products are never held whole — 0.75 GB of
-# temporaries for a 2,048-token prefill of the ten layers — so it needs no
-# blocking at 72 experts.)
-# 512 top 10 at expert width 512 (qwen3-next-80b-a3b; PERF.md, PR 35;
-# `tools/moe_decode_ab.py --shape 512,10,2048,512`, ms a layer, routed /
-# dense): 64 tokens 7.96 / 2.20, 128: 10.45 / 2.85 (the weight stream's
-# floor is 1.97), 256: 14.76 / 4.93, 512: 15.21 / 9.92, 1,024: 16.51 /
-# 20.11, 2,048: 19.42 / 49.84 — `ragged_dot` over 512 groups costs 8-15 ms
-# before the first useful row and is nearly flat from 256 tokens on (a
-# group of 5-40 rows fills a fraction of an MXU tile), the mixture's 51x
-# FLOPs grow with every token (~82% of the MXU peak at 2,048): they cross
-# between 512 and 1,024 tokens, and no dispatch lies between the two
-# (batches double). Decode (128 tokens) is the mixture at 1.4x its weight
-# floor.
-ROUTED_FROM = {(72, 10): 2560, (512, 10): 1024}
+# The one-chip routing shapes, each from its own reading of
+# tools/moe_decode_ab.py with the kernel under the routed form (PERF.md,
+# PR 36; a v5e; ms a layer: routed over `ragged_dot` / routed over the
+# kernel / dense mixture): (experts, k) -> the least tokens a dispatch at
+# which routing pays.
+# 72 top 10 at expert width 768 (granite-4.0-h-small; `--shape
+# 72,10,4096,768`; the weight stream's floor is 0.83): 16 tokens 4.03 /
+# 0.89 / 0.96, 64: 4.75 / 1.06 / 0.94, 128: 5.51 / 1.17 / 1.14, 256: 7.67 /
+# 1.38 / 2.14, 512: 9.09 / 1.87 / 4.34, 1,024: 12.03 / 3.35 / 8.10, 2,048:
+# 18.10 / 5.76 / 15.98. At 64-128 tokens every expert is hit and the
+# mixture streams them at 73-88% of the chip's rate; from 256 its 7.2x
+# FLOPs cost more than the kernel's visits: they cross between 128 and 256
+# tokens and no dispatch lies between (batches double), so decode (128
+# slots) keeps the mixture (a tie within 2% there) and a prefill of 256
+# tokens or more is routed. (At 1,024 tokens the kernels are 2.3 ms of the
+# 3.35; the rest is the sort, the row gather and the combine.)
+# 512 top 10 at expert width 512 (qwen3-next-80b-a3b; `--shape
+# 512,10,2048,512`; floor 1.97 for all 512 experts): 16 tokens 5.83 / 0.66
+# / 2.17, 32: 6.61 / 1.12 / 2.18, 64: 7.94 / 1.72 / 2.17, 128: 10.42 / 2.15
+# / 2.81, 256: 14.73 / 2.44 / 4.91, 512: 15.19 / 2.73 / 9.90, 1,024: 16.48
+# / 3.28 / 20.08, 2,048: 19.39 / 5.28 / 49.80. The kernel reads the HIT
+# experts alone (~140 of 512 at 16 tokens, ~470 at 128) where the mixture
+# reads all of them and computes 51x the FLOPs, so it wins at every size,
+# decode's 128 tokens among them (by 24%): the crossing is under the
+# smallest dispatch there is. (`ragged_dot` over 512 groups costs 6-15 ms
+# before its first useful row.)
+ROUTED_FROM = {(72, 10): 256, (512, 10): 1}
 
 
 def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
@@ -120,11 +132,40 @@ def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int
     return jax.nn.softmax(top_vals, axis=-1), top_idx.astype(jnp.int32)
 
 
+def grouped_matmul_form(w, n_rows: int, one_device: bool = True) -> dict:
+    """Which implementation the routed form's matmuls take against the
+    expert leaf `w` ([.., X, A, F]) for `n_rows` rows (what
+    `_grouped_matmul` routes by and `startup.moe` reports): the Pallas
+    kernel (ops/gmm.py) with its row tile for an int8 stack on one device —
+    "pallas-interpret" is the same kernel on the CPU backend — or
+    `lax.ragged_dot` with the reason."""
+    if not one_device:
+        return {"form": "ragged_dot",
+                "why": "the expert FFN is traced under a mesh"}
+    if not isinstance(w, QuantizedTensor):
+        return {"form": "ragged_dot", "why": "the expert stack is not int8"}
+    A, F = w.q.shape[-2:]
+    tiling = gmm.geometry(n_rows, A, F, interpret=interpret_mode())
+    if tiling is None:
+        return {"form": "ragged_dot",
+                "why": f"no kernel geometry for experts of [{A}, {F}]"}
+    return {"form": "pallas-interpret" if interpret_mode() else "pallas",
+            "row_tile": tiling[0]}
+
+
 def _grouped_matmul(rows: jnp.ndarray, w, group_sizes: jnp.ndarray,
-                    row_expert: jnp.ndarray) -> jnp.ndarray:
+                    row_expert: jnp.ndarray, at=None) -> jnp.ndarray:
     """rows [R, A] sorted by expert @ per-expert [X, A, F] -> [R, F] in
     float32. A QuantizedTensor keeps its int8 payload as the operand; its
-    [X, F] scales are gathered per row onto the accumulator."""
+    [X, F] scales go onto the accumulator, row by row. `at` = (the leaf's
+    whole stack [L, X, A, F], this layer's index) where the expert FFN is
+    traced on one device: the Pallas kernel addresses (layer, expert) in
+    the stack as it lies (`w`, the layer's slice, is then not read)."""
+    if at is not None and grouped_matmul_form(
+            at[0], rows.shape[0])["form"] != "ragged_dot":
+        stack, layer = at
+        return gmm.grouped_matmul(rows, stack.q, stack.scale, group_sizes,
+                                  layer, interpret=interpret_mode())
     if isinstance(w, QuantizedTensor):
         y = jax.lax.ragged_dot(rows, w.q, group_sizes,
                                preferred_element_type=jnp.float32)
@@ -133,10 +174,12 @@ def _grouped_matmul(rows: jnp.ndarray, w, group_sizes: jnp.ndarray,
                               preferred_element_type=jnp.float32)
 
 
-def _routed_ffn(x, valid, router, wg, wu, wd, k: int):
+def _routed_ffn(x, valid, router, wg, wu, wd, k: int, at=None):
     """x [T, D], valid [T] bool -> (y [T, D] float32, pairs [X] int32).
     Under shard_map this is one shard's program: wg/wu hold a slice of the
-    FFN width, wd the matching rows, and y is that slice's partial sum."""
+    FFN width, wd the matching rows, and y is that slice's partial sum.
+    `at` = ({"wg", "wu", "wd"}: the whole stacks, this layer's index) on
+    one device (`_grouped_matmul`), else None."""
     T, _ = x.shape
     X = router.shape[-1]
     gates, experts = route_top_k(x, router, k)            # [T, k]
@@ -150,18 +193,40 @@ def _routed_ffn(x, valid, router, wg, wu, wd, k: int):
     pairs = jnp.sum(onehot & jnp.repeat(valid, k)[:, None], axis=0,
                     dtype=jnp.int32)
 
+    def grouped(rows, w, name):
+        return _grouped_matmul(rows, w, group_sizes, row_expert,
+                               at and (at[0][name], at[1]))
+
     rows = jnp.take(x, order // k, axis=0)                # [T*k, D]
-    h = (jax.nn.silu(_grouped_matmul(rows, wg, group_sizes, row_expert))
-         * _grouped_matmul(rows, wu, group_sizes, row_expert))
-    y = _grouped_matmul(h.astype(x.dtype), wd, group_sizes, row_expert)
+    h = (jax.nn.silu(grouped(rows, wg, "wg")) * grouped(rows, wu, "wu"))
+    y = grouped(h.astype(x.dtype), wd, "wd")
 
     # Un-sort by a gather (the inverse permutation), then the gated sum
     # over each token's k rows in float32: no scatter-add, so the sum has
     # one order.
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(T * k, dtype=order.dtype))
-    y = jnp.take(y, inverse, axis=0).reshape(T, k, -1)
-    return jnp.einsum("tkd,tk->td", y, gates), pairs
+    if at is None:
+        y = jnp.take(y, inverse, axis=0).reshape(T, k, -1)
+        return jnp.einsum("tkd,tk->td", y, gates), pairs
+    # On one device the same sum a gather at a time: a token's j-th row
+    # times its gate, added in the order j = 0 .. k - 1, in a loop XLA
+    # cannot flatten — so the un-sorted [T*k, D] float32 copy and its
+    # [T, k, D] relayout (k on sublanes: 10 pads to 16) never exist beside
+    # the kernel's output: a 1,024-token prefill of granite-4.0-h-small
+    # holds 521 MiB of temporaries for it where that form held 746 (the
+    # mixture: 282), beside a chip 85% full whose largest free block is
+    # 1.3 GB (PERF.md, PR 36). (The sharded trunk keeps the form above:
+    # its lowered programs are held byte-identical in this PR.)
+    slot = inverse.reshape(T, k)
+
+    def add(j, out):
+        row = jnp.take(y, jax.lax.dynamic_index_in_dim(slot, j, 1, False),
+                       axis=0)
+        return out + jax.lax.dynamic_index_in_dim(gates, j, 1) * row
+
+    return jax.lax.fori_loop(
+        0, k, add, jnp.zeros((T, y.shape[-1]), jnp.float32)), pairs
 
 
 def _experts_dot(x: jnp.ndarray, w) -> jnp.ndarray:
@@ -192,13 +257,12 @@ def _dense_mixture(x, valid, router, wg, wu, wd, k: int):
     return jnp.einsum("xtd,tx->td", y, dense_gates), pairs
 
 
-def _expert_ffn(x, valid, router, wg, wu, wd, k: int):
+def _expert_ffn(x, valid, router, wg, wu, wd, k: int, at=None):
     """x [T, D] -> (y [T, D] float32, valid pairs [X]) by the form this
-    token count takes."""
-    form = (_routed_ffn
-            if moe_route(x.shape[0], router.shape[-1], k) == "routed"
-            else _dense_mixture)
-    return form(x, valid, router, wg, wu, wd, k)
+    token count takes; `at` as `_routed_ffn`'s."""
+    if moe_route(x.shape[0], router.shape[-1], k) == "routed":
+        return _routed_ffn(x, valid, router, wg, wu, wd, k, at)
+    return _dense_mixture(x, valid, router, wg, wu, wd, k)
 
 
 def _model_shards(tp_mesh, ffn_width: int) -> int:
@@ -226,10 +290,17 @@ def moe_layout(tp_mesh, ffn_width: int) -> str:
     return "GSPMD partitions the expert FFN from the parameter shardings"
 
 
+EXPERT_LEAVES = ("wg", "wu", "wd")
+
+
 def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
-            tp_mesh=None) -> tuple[jnp.ndarray, jnp.ndarray]:
+            tp_mesh=None, stack=None) -> tuple[jnp.ndarray, jnp.ndarray]:
     """MoE FFN: [B, S, D] -> ([B, S, D], valid pairs per expert [X]).
-    `seq_lens` [B] says how many of each row's S positions are real."""
+    `seq_lens` [B] says how many of each row's S positions are real.
+    `stack` = (the FFN layers' stacked leaves, this layer's index) where the
+    caller indexes its stacks itself (models/hybrid.py): the routed form's
+    kernel then reads an expert where it lies in the stack, and `lp`'s
+    slices of the three expert leaves are read by the mixture alone."""
     B, S, D = x.shape
     k = config.num_experts_per_tok
     if seq_lens is None:
@@ -241,7 +312,14 @@ def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
     args = (xf, valid, lp["router"], lp["wg"], lp["wu"], lp["wd"])
 
     n = _model_shards(tp_mesh, config.intermediate_size)
-    if n == 1:
+    if tp_mesh is None:
+        # one device: the kernel's operand is a stack — the caller's, or
+        # this layer alone as a stack of one (a reshape)
+        stacks, layer = stack if stack is not None else (
+            jax.tree.map(lambda a: a[None],
+                         {name: lp[name] for name in EXPERT_LEAVES}), 0)
+        y, pairs = _expert_ffn(*args, k, (stacks, jnp.int32(layer)))
+    elif n == 1:
         y, pairs = _expert_ffn(*args, k)
     else:
         from jax.sharding import PartitionSpec as P

@@ -236,11 +236,13 @@ def test_gdn_decode_step_updates_the_matrix_state_in_place(
     lies — no copy or relayout of the whole stack, temporaries under one
     layer's state — by the jnp step's two fusions a layer (one pass that
     reads the state for both read-outs, one in-place update), and the one
-    gated-attention layer (2 KV heads of 256) takes the decode kernel."""
-    from symmetry_tpu.models import llama, mamba2
+    gated-attention layer (2 KV heads of 256) takes the decode kernel. At
+    512 experts top 10 the step's experts are the routed form (PR 36):
+    three `moe_gmm` calls in the body of each of the two runs' scans."""
+    from symmetry_tpu.models import hybrid, llama, mamba2, moe
 
-    monkeypatch.setattr(llama, "interpret_mode", lambda: False)
-    monkeypatch.setattr(mamba2, "interpret_mode", lambda: False)
+    for module in (llama, mamba2, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
     cfg = llama.preset("qwen3-next-80b-a3b")
     B, T = 128, 640
 
@@ -267,7 +269,9 @@ def test_gdn_decode_step_updates_the_matrix_state_in_place(
     assert memory.alias_size_in_bytes >= state_bytes
     assert memory.temp_size_in_bytes < state_bytes // 3    # under one layer
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 1          # decode attention
+    gmm_calls = len(re.findall(r"%moe_gmm[.\d]* = ", text))
+    assert gmm_calls == 3 * len(hybrid.runs(cfg))
+    assert text.count("tpu_custom_call") == 1 + gmm_calls  # + attention
     whole = [line.strip()[:160] for line in text.splitlines()
              if re.search(r"= f32\[3,128,32,128,128\]\S* (copy|transpose)\(",
                           line)]
@@ -277,3 +281,66 @@ def test_gdn_decode_step_updates_the_matrix_state_in_place(
                if re.search(r"= f32\[3,128,32,128,128\]\S* "
                             r"dynamic-update-slice\(", line)]
     assert writers
+
+
+@pytest.mark.parametrize("preset,rows,bucket,stack", [
+    ("qwen3-next-80b-a3b", 2, 256, r"s8\[4,512,(2048,512|512,2048)\]"),
+    ("granite-4.0-h-small", 4, 128, r"s8\[10,72,(4096,768|768,4096)\]"),
+])
+def test_prefill_reads_the_expert_stacks_where_they_lie(
+        one_chip, no_cache, monkeypatch, preset, rows, bucket, stack):
+    """A 512-token prefill dispatch of each one-chip expert cell: the routed
+    form's three matmuls a layer are `moe_gmm` calls (ops/gmm.py: one call
+    each in the body of a run's scan) whose weight operands are the WHOLE
+    int8 stacks as they lie in HBM — no slice, copy or relayout of a stack
+    or of a layer of it beside a call (PR 29's lesson: a slice around a
+    kernel is a copy; here 0.5 GB a matmul), no stack an operand of an XLA
+    fusion, and no dense mixture left (its [tokens, experts, width]
+    product)."""
+    from symmetry_tpu.models import hybrid, llama, mamba2, moe
+
+    for module in (llama, mamba2, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    cfg = llama.preset(preset)
+    tokens = rows * bucket
+    assert moe.moe_route(tokens, cfg.num_experts,
+                         cfg.num_experts_per_tok) == "routed"
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+        slice_above=1 << 40))
+    cache = shaped(lambda: llama.init_cache(cfg, rows, bucket, jnp.bfloat16,
+                                            quantized=True))
+    tok = jax.ShapeDtypeStruct((rows, bucket), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    # the engine's precision, not the CPU tests' "highest" (conftest.py),
+    # which the flash kernel's bf16 dots cannot take
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(
+            lambda p, t, c, n: llama.forward_hidden(p, cfg, t, c, n,
+                                                    prefill_flash=True),
+            donate_argnums=(2,)).lower(params, tok, cache, lens).compile(
+        ).as_text()
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 3 * len(
+        hybrid.runs(cfg))
+    names = set(re.findall(rf"(%[\w.\-]+)(?: =|:) {stack}", text))
+    assert names
+    touched = [line.strip()[:160] for line in text.splitlines()
+               if re.search(rf"= {stack}\S* (copy|transpose|fusion|"
+                            rf"dynamic-slice)\(", line)
+               or (" fusion(" in line and names & set(re.findall(
+                   r"%[\w.\-]+", line.split(" fusion(")[1])))]
+    assert not touched, touched[0]
+    layer = stack.replace(r"s8\[4,", r"s8\[").replace(r"s8\[10,", r"s8\[")
+    sliced = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= {layer}", line)]
+    assert not sliced, sliced[0]
+    mixture = [line.strip()[:160] for line in text.splitlines()
+               if re.search(rf"\[{tokens},{cfg.num_experts},\d+\]", line)]
+    assert not mixture, mixture[0]

@@ -307,13 +307,16 @@ def test_512_way_routing_and_the_gated_shared_expert_drop_no_pair(
 
 
 def test_moe_route_at_512_experts_is_a_measured_entry():
-    assert (512, 10) in moe.ROUTED_FROM
-    least = moe.ROUTED_FROM[(512, 10)]
-    assert moe.moe_route(least, 512, 10) == "routed"
-    assert moe.moe_route(least - 1, 512, 10) == "dense-mixture"
-    # the other shapes read as before
+    """Over the grouped-matmul kernel the routed form wins at every size
+    measured (16-2,048 tokens: it reads the hit experts alone, the mixture
+    all 512), decode's 128 tokens among them (PR 36)."""
+    assert moe.ROUTED_FROM[(512, 10)] == 1
+    for tokens in (1, 16, 64, 128, 512, 2048):
+        assert moe.moe_route(tokens, 512, 10) == "routed"
+    # the other shapes keep crossings of their own
+    assert moe.moe_route(1023) == "dense-mixture"
     assert moe.moe_route(1024) == "routed"
-    assert moe.moe_route(2048, 72, 10) == "dense-mixture"
+    assert moe.moe_route(128, 72, 10) == "dense-mixture"
 
 
 # ---------------------------------------------------------------- the engine

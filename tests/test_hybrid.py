@@ -201,8 +201,9 @@ def test_72_way_routing_and_the_shared_expert_against_a_per_token_loop(
     forms of the expert FFN, against the sum written out token by token."""
     X, k, D, F, Fs = 72, 10, 32, 16, 24
     cfg = dataclasses.replace(CFG, num_experts=X, num_experts_per_tok=k)
-    if form == "routed":
-        monkeypatch.setitem(moe.ROUTED_FROM, (X, k), 1)
+    # each form at 400 tokens, whichever side of the crossing that is
+    monkeypatch.setitem(moe.ROUTED_FROM, (X, k),
+                        1 if form == "routed" else 401)
     assert moe.moe_route(tokens, X, k) == form
     keys = jax.random.split(jax.random.key(17), 8)
     lp = {"router": jax.random.normal(keys[0], (D, X)),
@@ -240,10 +241,13 @@ def test_moe_route_leaves_mixtrals_choices_where_they_were():
         assert moe.moe_route(tokens) == "routed"
         assert moe.moe_route(tokens, 8, 2) == "routed"
         assert moe.moe_route(tokens, 4, 2) == "routed"
-    # 72 top 10 has its own measured crossing: above every served dispatch
-    for tokens in (128, 512, 2048):
+    # 72 top 10 has its own crossing, measured over the grouped-matmul
+    # kernel (PR 36): decode's 128 tokens keep the mixture, a prefill
+    # dispatch of 256 tokens or more is routed
+    for tokens in (16, 64, 128, 255):
         assert moe.moe_route(tokens, 72, 10) == "dense-mixture"
-    assert moe.moe_route(2560, 72, 10) == "routed"
+    for tokens in (256, 512, 1024, 2048):
+        assert moe.moe_route(tokens, 72, 10) == "routed"
 
 
 # ---------------------------------------------------------------- the engine

@@ -13,7 +13,9 @@ from symmetry_tpu.models.llama import (
     param_logical_axes,
     quantize_params,
 )
+from symmetry_tpu.models import moe
 from symmetry_tpu.models.moe import route_top_k
+from symmetry_tpu.ops.quant import quantize
 
 
 class TestRouting:
@@ -33,6 +35,45 @@ class TestRouting:
         gates, experts = route_top_k(x, jnp.eye(4), 2)
         assert np.asarray(experts).tolist() == [[1, 2]]
         assert gates[0, 0] > gates[0, 1] > 0
+
+
+def int8_layer(X, k, D, F_, key):
+    keys = jax.random.split(key, 4)
+
+    def leaf(key, shape, fan_in):
+        return quantize(jax.random.normal(key, shape) * fan_in ** -0.5)
+
+    return {"router": jax.random.normal(keys[0], (D, X)),
+            "wg": leaf(keys[1], (X, D, F_), D),
+            "wu": leaf(keys[2], (X, D, F_), D),
+            "wd": leaf(keys[3], (X, F_, D), F_)}
+
+
+@pytest.mark.parametrize("X,k,tokens", [(72, 10, 48), (512, 10, 24),
+                                        (8, 2, 40)])
+def test_the_routed_form_over_the_kernel_is_the_dense_mixture(X, k, tokens):
+    """`_routed_ffn` with the kernel under it against `_dense_mixture` and
+    against itself over `lax.ragged_dot`, at the served routing shapes
+    (72 and 512 experts top 10: most of 512 experts have no row)."""
+    D, F_ = 32, 16
+    lp = int8_layer(X, k, D, F_, jax.random.key(X))
+    x = jax.random.normal(jax.random.key(X + 1), (tokens, D))
+    valid = jnp.arange(tokens) < tokens - 5
+    args = (x, valid, lp["router"], lp["wg"], lp["wu"], lp["wd"], k)
+    stacks = jax.tree.map(lambda a: a[None],
+                          {n: lp[n] for n in moe.EXPERT_LEAVES})
+    jaxpr = str(jax.make_jaxpr(
+        lambda: moe._routed_ffn(*args, (stacks, jnp.int32(0))))())
+    assert jaxpr.count("moe_gmm") >= 3 and "ragged_dot" not in jaxpr
+    with jax.default_matmul_precision("highest"):
+        kernel, pairs = moe._routed_ffn(*args, (stacks, jnp.int32(0)))
+        ragged, pairs_ragged = moe._routed_ffn(*args)
+        dense, pairs_dense = moe._dense_mixture(*args)
+    np.testing.assert_allclose(kernel, ragged, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(kernel, dense, rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(pairs, pairs_dense)
+    np.testing.assert_array_equal(pairs, pairs_ragged)
+    assert int(pairs.sum()) == (tokens - 5) * k
 
 
 class TestMoEForward:

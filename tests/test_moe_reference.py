@@ -224,15 +224,22 @@ def test_padded_positions_are_computed_but_not_counted(form):
     assert int(pairs.sum()) == 75 * cfg.num_experts_per_tok
 
 
-def test_the_form_follows_the_token_count():
+@pytest.mark.parametrize("weights,grouped", [("int8", "moe_gmm"),
+                                             ("float32", "ragged_dot")])
+def test_the_form_follows_the_token_count(weights, grouped):
+    """The mixture under the crossing, the routed form from it — over the
+    Pallas grouped matmul for an int8 stack on one device, over
+    `lax.ragged_dot` for any other."""
     cfg = moe_config(8)
-    lp, _ = skewed_layer(cfg, "zipf", "int8")
+    lp, _ = skewed_layer(cfg, "zipf", weights)
     assert moe.moe_route(64) == "dense-mixture"
     assert moe.moe_route(moe.ROUTED_MIN_TOKENS) == "routed"
-    for tokens, ragged in ((64, False), (moe.ROUTED_MIN_TOKENS, True)):
+    for tokens, routed in ((64, False), (moe.ROUTED_MIN_TOKENS, True)):
         x = jnp.zeros((1, tokens, cfg.hidden_size))
         jaxpr = str(jax.make_jaxpr(lambda x: moe_mlp(x, lp, cfg))(x))
-        assert ("ragged_dot" in jaxpr) == ragged
+        assert (grouped in jaxpr) == routed
+        (other,) = {"moe_gmm", "ragged_dot"} - {grouped}
+        assert other not in jaxpr
 
 
 @pytest.mark.parametrize("kv", ["dense", "int8"])
@@ -428,6 +435,9 @@ def test_engine_counts_expert_pairs_and_reports_the_route():
                                            "32": "dense-mixture"}}
     assert report["experts"] == 4 and report["top_k"] == 2
     assert "one device" in report["layout"]
+    # float32 stacks: the routed form would run over lax.ragged_dot
+    assert report["grouped_matmul"] == {
+        "form": "ragged_dot", "why": "the expert stack is not int8"}
 
 
 def test_a_dense_engine_reports_no_moe():

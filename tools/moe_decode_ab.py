@@ -1,26 +1,35 @@
-"""The two forms of the expert FFN (`models/moe.py`) timed against each
-other on one chip's share of a layer.
+"""The forms of the expert FFN (`models/moe.py`) timed against each other
+on one chip's share of a layer: the routed form over `lax.ragged_dot`, the
+routed form over the Pallas grouped matmul (`ops/gmm.py`; int8 stacks on
+one device) and the dense mixture.
 
-At decode (64 slots, one token each) every expert is hit and both forms
-stream the same weight bytes — is the dense mixture (every expert computes
-every token; 4x the FLOPs) faster than the routed form (sort +
-`lax.ragged_dot`)? From how many tokens a dispatch does routing pay?
+At decode (64 slots, one token each) every expert is hit and every form
+streams the same weight bytes — is the dense mixture (every expert computes
+every token; 4x the FLOPs) faster than the routed form? From how many
+tokens a dispatch does routing pay?
 
 One chip holds what one chip of `mesh {model: 4}` holds of mixtral-8x7b: all
 8 experts at a quarter of the FFN width ([8, 4096, 3584] int8 x 3), `--layers`
 layers of them scanned like the model's trunk, random weights and tokens.
-Prints one JSON line: ms a layer for each form at each token count — the
-reading `models/moe.py ROUTED_MIN_TOKENS` is set from.
+Prints one JSON line: ms a layer for each form at each token count, the
+weight stream's floor (every expert read once at 819 GB/s) and each form's
+share of it (the kernel reads the HIT experts alone, so it can read over 1
+where few tokens hit few experts) — the reading `models/moe.py
+ROUTED_MIN_TOKENS` and `ROUTED_FROM` are set from. `--row-tiles` times the
+kernel at other row tiles than `ops/gmm.py geometry` chooses.
 
     python tools/moe_decode_ab.py            # on the chip
     python tools/moe_decode_ab.py --shape 72,10,4096,768 --layers 2 \
         --tokens 128,256,512,1024,2048       # granite-4.0-h-small's layer
+    python tools/moe_decode_ab.py --shape 512,10,2048,512 --layers 2 \
+        --row-tiles 32,64,256                # qwen3-next-80b-a3b's
     JAX_PLATFORMS=cpu python tools/moe_decode_ab.py --tiny
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -35,6 +44,8 @@ def main() -> int:
     ap.add_argument("--tokens", default="64,128,256,2048")
     ap.add_argument("--repeats", type=int, default=20)
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--row-tiles", default="",
+                    help="also time the kernel at these row tiles")
     ap.add_argument("--shape", default="8,2,4096,3584",
                     help="experts,top_k,hidden,ffn_width held here; "
                          "granite-4.0-h-small whole is 72,10,4096,768")
@@ -44,7 +55,8 @@ def main() -> int:
     import jax.numpy as jnp
 
     from symmetry_tpu.models.moe import (
-        _dense_mixture, _routed_ffn, moe_route)
+        _dense_mixture, _routed_ffn, grouped_matmul_form, moe_route)
+    from symmetry_tpu.ops import gmm
     from symmetry_tpu.ops.quant import make_leaf
 
     X, k, D, F = (int(v) for v in args.shape.split(","))
@@ -57,48 +69,77 @@ def main() -> int:
     wd = make_leaf(keys[2], (L, X, F, D), F ** -0.5, jnp.bfloat16, True)
     router = make_leaf(keys[3], (L, D, X), D ** -0.5, jnp.bfloat16)
 
-    def dense_mixture(x, router, wg, wu, wd):
-        y, _ = _dense_mixture(x, jnp.ones((x.shape[0],), bool), router, wg,
-                              wu, wd, k)
-        return y
+    def valid(x):
+        return jnp.ones((x.shape[0],), bool)
 
-    def routed(x, router, wg, wu, wd):
-        y, _ = _routed_ffn(x, jnp.ones((x.shape[0],), bool), router, wg,
-                           wu, wd, k)
-        return y
+    def dense_mixture(x, layer, stacks):
+        return _dense_mixture(x, valid(x), *layer[1:], k)[0]
+
+    def routed(x, layer, stacks):
+        return _routed_ffn(x, valid(x), *layer[1:], k)[0]
+
+    def routed_kernel(x, layer, stacks):
+        # as models/hybrid.py calls it: the stacks whole, the layer's index
+        return _routed_ffn(x, valid(x), *layer[1:], k, (stacks, layer[0]))[0]
 
     def trunk(form):
         def run(x, layers):  # the weights are arguments, never constants
-            def body(h, lp):
-                return h + form(h, *lp).astype(h.dtype), None
+            stacks = dict(zip(("wg", "wu", "wd"), layers[2:]))
+
+            def body(h, layer):
+                return h + form(h, layer, stacks).astype(h.dtype), None
             return jax.lax.scan(body, x, layers)[0]
         return jax.jit(run)
 
-    layers = (router, wg, wu, wd)
+    def ms_a_layer(form, x):
+        fn = trunk(form)
+        fn(x, layers).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(args.repeats):
+            y = fn(x, layers)
+        y.block_until_ready()
+        return round(1e3 * (time.perf_counter() - t0) / args.repeats / L, 4)
+
+    layers = (jnp.arange(L, dtype=jnp.int32), router, wg, wu, wd)
+    forms = {"routed": routed, "routed_kernel": routed_kernel,
+             "dense_mixture": dense_mixture}
+    weight_bytes = 3 * X * D * F
+    floor = None if args.tiny else round(1e3 * weight_bytes / 819e9, 4)
 
     out = {"device": jax.devices()[0].device_kind, "layers": L,
            "shape": {"experts": X, "top_k": k, "hidden": D, "ffn_slice": F},
-           "ms_per_layer": {}}
+           "weight_stream_floor_ms_per_layer": floor, "ms_per_layer": {}}
     for T in [int(t) for t in args.tokens.split(",")]:
         x = jax.random.normal(jax.random.key(T), (T, D), jnp.bfloat16)
-        row = {}
-        for name, form in (("routed", routed), ("dense_mixture",
-                                                 dense_mixture)):
-            fn = trunk(form)
-            fn(x, layers).block_until_ready()
-            t0 = time.perf_counter()
-            for _ in range(args.repeats):
-                y = fn(x, layers)
-            y.block_until_ready()
-            row[name] = round(
-                1e3 * (time.perf_counter() - t0) / args.repeats / L, 4)
+        row = {name: ms_a_layer(form, x) for name, form in forms.items()}
+        row["grouped_matmul"] = grouped_matmul_form(wg, T * k)
+        for tile in [int(t) for t in args.row_tiles.split(",") if t]:
+            with _row_tile(gmm, tile):
+                row[f"routed_kernel@{tile}"] = ms_a_layer(routed_kernel, x)
+        if floor:
+            row["floor_share"] = {name: round(floor / row[name], 3)
+                                  for name in forms}
         row["route"] = moe_route(T, X, k)
         out["ms_per_layer"][str(T)] = row
-    weight_bytes = 3 * X * D * F
-    out["weight_stream_floor_ms_per_layer"] = round(
-        1e3 * weight_bytes / 819e9, 4) if not args.tiny else None
     print(json.dumps(out), flush=True)
     return 0
+
+
+@contextlib.contextmanager
+def _row_tile(gmm, tile: int):
+    """`ops/gmm.py` with another row tile, for the sweep alone (the
+    kernel's own is a constant no caller chooses; a traced kernel keeps
+    the tile it was traced with, hence the cleared caches)."""
+    import jax
+
+    before = gmm.ROW_TILE
+    gmm.ROW_TILE = tile
+    jax.clear_caches()
+    try:
+        yield
+    finally:
+        gmm.ROW_TILE = before
+        jax.clear_caches()
 
 
 if __name__ == "__main__":
