@@ -391,7 +391,8 @@ class EngineHost:
             handoff=(self._handoff_sink if self._role == "prefill"
                      else None),
             ledger_enabled=bool(getattr(self._config.tpu,
-                                        "ledger", True)))
+                                        "ledger", True)),
+            compile_watch=self._compile)
         # tpu.tracing=False empties every ring (the bench A/B knob); the
         # default leaves the bounded always-on recorder running.
         tracing = bool(getattr(self._config.tpu, "tracing", True))

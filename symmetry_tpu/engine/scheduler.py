@@ -24,17 +24,21 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import queue
+import resource
+import statistics
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import numpy as np
 
 from symmetry_tpu.engine.engine import InferenceEngine, SamplingParams
 from symmetry_tpu.engine.ledger import RequestLedger
 from symmetry_tpu.engine.tokenizer import StreamDecoder
+from symmetry_tpu.utils.device import memory_report
+from symmetry_tpu.utils.devprof import CompileWatch, gc_seconds, gc_watch
 from symmetry_tpu.utils.faults import FAULTS, InjectedFault
 from symmetry_tpu.utils.logging import logger as log
 
@@ -166,6 +170,7 @@ class _Admission:
     dispatched_at: float      # monotonic
     charged_s: float          # what the block's budget was charged
     chunks_before: int = 0    # unread chunk dispatches queued ahead of it
+    chunks_s: float = 0.0     # ... and the device seconds they were charged
 
 
 def _is_ready(toks: Any) -> bool:
@@ -185,6 +190,56 @@ def _is_ready(toks: Any) -> bool:
 LOOP_PHASES = ("sync", "process", "dispatch", "admit", "chunks", "flush",
                "wait")
 
+# One record per entry that leaves the in-flight queue (stats()["reads"],
+# Scheduler._end_read), as a list in this order. seq: reads since start;
+# t: monotonic stamp at which the read returned; kind: decode_block |
+# verify | prefill | adopt | chunk; rows, bucket: the program's batch and
+# prefill bucket (a block: lanes in its snapshot, 0); tokens: valid prompt
+# tokens of an admission, lanes x steps of a block; caused_by: for an
+# admission the seq of the decode block it was queued behind, for a block
+# the seq of the block before it (None across an idle boundary); wait_s:
+# wall inside the sync; late: the tokens were ready when the thread came;
+# device_s, exact: _device_interval's — but a wait that ran past the entry
+# (a stall) is no device time: the record then holds what the entry should
+# have taken, inexact; behind: entries still in flight when the read
+# began; host_s, cpu_s, lowerings, gc_s: the engine thread's wall outside
+# sync since the read before returned, its CPU seconds, and the lowerings
+# and collector seconds of that span; chunks: the non-final chunk
+# dispatches that ran unread ahead of it, inside its interval (a chunk
+# entry's device_s is ONE chunk's: the interval over 1 + chunks).
+READ_FIELDS = ("seq", "t", "kind", "rows", "bucket", "tokens", "caused_by",
+               "wait_s", "late", "device_s", "exact", "behind", "host_s",
+               "cpu_s", "lowerings", "gc_s", "chunks")
+# A stall is a read whose wait runs past what its entry should take, or a
+# dispatch call whose wall runs, by more than one median decode-block
+# interval and never less than this (stats()["stalls"]["threshold_s"] is
+# the threshold in force).
+STALL_FLOOR_S = 0.25
+
+
+class _Mark(NamedTuple):
+    """What a span on the engine thread is measured from
+    (Scheduler._mark)."""
+
+    t: float             # monotonic
+    cpu: float           # the thread's CPU seconds
+    process_cpu: float   # every thread's
+    voluntary: int       # the thread's context switches ...
+    involuntary: int
+    major_faults: int    # ... and its major faults (RUSAGE_THREAD; a
+                         # sandbox's kernel may count none: PERF.md §7)
+    gc_s: float          # collector seconds of the process
+    lowerings: int       # CompileWatch's count
+
+
+class _Read(NamedTuple):
+    """What is known of a read before its wait (Scheduler._begin_read)."""
+
+    attrs: dict[str, Any]  # the sync phase's: entry, seq, rows, bucket
+    late: bool             # the tokens were there when the thread came
+    behind: int            # entries in flight behind this one
+    m0: _Mark              # what the wait is measured from
+
 
 class Scheduler:
     """Drives an InferenceEngine from a request queue on its own thread."""
@@ -201,7 +256,8 @@ class Scheduler:
                  | None = None,
                  handoff: Callable[[int, GenRequest, int], None]
                  | None = None,
-                 ledger_enabled: bool = True) -> None:
+                 ledger_enabled: bool = True,
+                 compile_watch: CompileWatch | None = None) -> None:
         self.engine = engine
         # Disaggregated tier role (engine/disagg/): mirrors the engine's.
         # "prefill" replaces slot activation with the handoff sink — a
@@ -298,6 +354,10 @@ class Scheduler:
         # have nothing to read, so the next chunk entry's measured
         # interval is theirs too.
         self._chunks_unread = 0
+        self._chunks_unread_s = 0.0
+        # (count, charged seconds) of the chunk dispatches queued ahead
+        # of each block in flight, in block order.
+        self._block_chunks: deque[tuple[int, float]] = deque()
         # (monotonic stamp at which the last entry's read returned, and
         # whether the thread WAITED for it — only then is the stamp the
         # moment the device finished it.)
@@ -309,6 +369,27 @@ class Scheduler:
         # already there when the thread came to read them.
         self._admit = {"device_s": 0.0, "wait_s": 0.0, "reads": 0,
                        "ready_at_read": 0}
+        # The read records (READ_FIELDS): the count since start and the
+        # last 64, which a sampler of stats() unions by seq.
+        self._compile_watch = compile_watch
+        gc_watch()
+        self._reads_n = 0
+        self._reads: deque[list] = deque(maxlen=64)
+        # (seq, read stamp) of the last decode block read; the seq is
+        # None across an idle boundary.
+        self._last_block: tuple[int | None, float] = (None, 0.0)
+        # _mark() as the last read returned (None: nothing to measure a
+        # host span from — before the first read, across an idle wait).
+        self._host_mark: _Mark | None = None
+        # Running medians over the last 63 decode blocks: the read-to-
+        # read interval, and the exact device seconds.
+        self._recent_intervals: deque[float] = deque(maxlen=63)
+        self._recent_block_s: deque[float] = deque(maxlen=63)
+        self._block_interval_s: float | None = None
+        self._block_device_s: float | None = None
+        self._stalls = {"count": 0, "seconds": 0.0, "longest_s": 0.0,
+                        "by_phase": {}}
+        self._stalls_recent: deque[dict] = deque(maxlen=8)
         self._debug = debug_invariants
         self._thread: threading.Thread | None = None
         self._stopping = threading.Event()
@@ -331,7 +412,7 @@ class Scheduler:
             engine.decode_block,
             (1 + spec.k_draft) if spec is not None else 0)
         self.metrics = {"requests": 0, "tokens": 0, "evictions": 0,
-                        "steps": 0, "peak_occupancy": 0,
+                        "steps": 0,
                         # Requests shed at admission because their
                         # end-to-end deadline had already expired (the
                         # overload-round accounting: prefill work saved).
@@ -460,7 +541,6 @@ class Scheduler:
         # if the client measures seconds and this says milliseconds, the
         # stall is in the relay/wire, not the engine).
         self._ttft_hist = Histogram()
-        self._admit_hist = Histogram()
         self._adopt_hist = Histogram()
         # Block-sync intervals are PER KIND, and an interval is observed
         # only when the previous sync was the SAME kind: a decode_block ->
@@ -548,6 +628,12 @@ class Scheduler:
             self.tracer.phase_s.get("sched." + name, 0.0), 6)
             for name in LOOP_PHASES}
         out["admit"] = {k: round(v, 6) for k, v in self._admit.items()}
+        out["reads"] = {"n": self._reads_n, "fields": list(READ_FIELDS),
+                        "recent": list(self._reads)}
+        out["stalls"] = {**self._stalls,
+                         "by_phase": dict(self._stalls["by_phase"]),
+                         "threshold_s": round(self._stall_threshold(), 6),
+                         "recent": list(self._stalls_recent)}
         if self._adopt_hist.count:
             out["adopt_dispatch_s"] = self._adopt_hist.to_dict()
         if getattr(self.engine, "expert_pairs", None):
@@ -568,8 +654,6 @@ class Scheduler:
         # REAL backlog instead of only its own in-flight counts.
         out["queue_depth"] = self._inbox.qsize() + len(self._deferred)
         out["engine_ttft_s"] = self._ttft_hist.to_dict()
-        out["admit_dispatch_s"] = self._admit_hist.to_dict()
-        out["block_interval_s"] = self._interval_hists["decode_block"].to_dict()
         if self._interval_hists["verify"].count:
             out["verify_interval_s"] = self._interval_hists["verify"].to_dict()
         # The overlap split (the tentpole's CPU-verifiable target): wall
@@ -594,7 +678,7 @@ class Scheduler:
         # decode wall from the block-interval p50 (intervals spanning
         # admissions land in the upper percentiles, so p50 is the
         # steady-state estimate), and the weight bytes that step must
-        # stream — their ratio is the effective weight-stream HBM GB/s.
+        # stream.
         # Intervals are per-kind and same-kind-only, so speculative
         # verify dispatches no longer poison the decode_block histogram —
         # the metrics hold with drafting on (pre-pipeline they had to be
@@ -605,16 +689,7 @@ class Scheduler:
             step_s = iv_p50 / self.engine.decode_block
             out["decode_step_ms"] = round(1e3 * step_s, 3)
             if wsb is not None:
-                nbytes = int(wsb())
-                out["weight_bytes_per_step"] = nbytes
-                out["weight_stream_gbs"] = round(nbytes / step_s / 1e9, 1)
-                # Per-device shard stream (sharded packed layout): the
-                # per-chip HBM roofline number the TP A/B gate reads.
-                wsbd = getattr(self.engine,
-                               "weight_stream_bytes_per_device", None)
-                if wsbd is not None:
-                    out["weight_stream_gbs_per_device"] = round(
-                        int(wsbd()) / step_s / 1e9, 1)
+                out["weight_bytes_per_step"] = int(wsb())
         # Shared-prefix KV cache counters (hit/miss/evict/bytes) ride the
         # same host stats op so they surface provider- and bench-side.
         pc_stats = getattr(self.engine, "prefix_cache_stats", None)
@@ -656,19 +731,22 @@ class Scheduler:
     # ------------------------------------------------------------- the loop
 
     @contextlib.contextmanager
-    def _phase(self, name: str):
+    def _phase(self, name: str, **attrs: Any):
         """One phase of the engine thread's loop (LOOP_PHASES): a
         Tracer.phase named `sched.<name>`. Phases partition the thread's
         time, one level deep: a phase entered inside another (the
         cold-burst flush inside admission, the device sync inside block
-        processing) suspends the outer one for its length."""
+        processing) suspends the outer one for its length. `attrs` go on
+        the capture's `sym.sched.<name>` annotation; the dict yielded is
+        the ring span's, which the block may add to before it closes."""
         outer = self._open_phase
         if outer is not None:
             outer.__exit__(None, None, None)
-        phase = self._open_phase = self.tracer.phase("sched." + name)
-        phase.__enter__()
+        phase = self._open_phase = self.tracer.phase("sched." + name,
+                                                     **attrs)
+        span = phase.__enter__()
         try:
-            yield
+            yield span
         finally:
             phase.__exit__(None, None, None)
             self._open_phase = outer
@@ -922,9 +1000,14 @@ class Scheduler:
             if (self._slots and not did_dispatch
                     and self._blocks_in_flight < want):
                 with self._phase("dispatch"):
-                    self._push_block((
-                        "decode_block", self.engine.decode_steps_dispatch(),
-                        dict(self._slots), time.monotonic(), None))
+                    m0 = self._mark()
+                    toks = self.engine.decode_steps_dispatch()
+                    self._dispatch_returned(
+                        "dispatch", m0, hasattr(toks, "is_ready"),
+                        "decode_block", len(self._slots), 0)
+                    self._push_block(("decode_block", toks,
+                                      dict(self._slots), time.monotonic(),
+                                      None))
                 self.metrics["steps"] += self.engine.decode_block
                 did_dispatch = True
             self._live_depth = self._blocks_in_flight
@@ -958,6 +1041,7 @@ class Scheduler:
                 # idle wait, which is not a serving stall.
                 self._last_sync_done = None
                 self._last_sync_kind = None
+                self._last_block = (None, 0.0)
                 self._live_depth = 0
                 self._m_pipeline_depth.set(0)
                 self.metrics["dispatch_thread_s"] += (
@@ -980,6 +1064,7 @@ class Scheduler:
                     if self._stopping.is_set():
                         return
                     continue
+                self._host_mark = None  # the wait is no host span
                 # Hand the popped item straight to admission (re-putting it
                 # would reorder it BEHIND arrivals that raced in while we
                 # were blocked — inverted FIFO for the earliest request).
@@ -1004,7 +1089,15 @@ class Scheduler:
     def _push_block(self, blk: tuple) -> None:
         self._pending.append(blk)
         self._blocks_in_flight += 1
-        self._chunks_unread = 0
+        self._block_chunks.append(self._take_unread_chunks())
+
+    def _take_unread_chunks(self) -> tuple[int, float]:
+        """(count, charged seconds) of the non-final chunk dispatches
+        queued since the last entry: the entry queued now runs behind
+        them, so its wait and its interval hold them too."""
+        chunks = (self._chunks_unread, self._chunks_unread_s)
+        self._chunks_unread, self._chunks_unread_s = 0, 0.0
+        return chunks
 
     def _read_through_block(self) -> None:
         """Read in-flight entries, oldest first, up to and including the
@@ -1106,8 +1199,10 @@ class Scheduler:
         discarded from the counters too, so the engine-side number sums
         to exactly the bench's tokens_streamed. tokens_generated keeps
         counting the EOS (the budget convention)."""
-        was_ready = _is_ready(device_toks)
-        with self._phase("sync"):
+        read = self._begin_read(kind, device_toks, len(snapshot), 0)
+        chunks = (self._block_chunks.popleft() if self._block_chunks
+                  else (0, 0.0))
+        with self._phase("sync", **read.attrs) as span:
             t0 = time.perf_counter()
             toks = np.asarray(device_toks)  # blocks on THIS block only
             # MoE: the block's per-expert pair counts came out of the
@@ -1116,7 +1211,10 @@ class Scheduler:
             if collect is not None:
                 collect()
             t1 = time.perf_counter()
-        self._ready_at = (time.monotonic(), not was_ready)
+            self._end_read(read, span, wait_s=t1 - t0,
+                           tokens=len(snapshot) * int(toks.shape[0]),
+                           dispatched_at=dispatched_at, chunks=chunks,
+                           expected=self._block_device_s)
         self.metrics["block_syncs"] += 1
         self.metrics["sync_s"] += t1 - t0
         # Same-kind-only intervals: a decode_block -> decode_block gap is
@@ -1125,6 +1223,10 @@ class Scheduler:
         # cadence instead — neither poisons the other's percentiles.
         if self._last_sync_done is not None and self._last_sync_kind == kind:
             self._interval_hists[kind].observe(t1 - self._last_sync_done)
+            if kind == "decode_block":
+                self._recent_intervals.append(t1 - self._last_sync_done)
+                self._block_interval_s = statistics.median(
+                    self._recent_intervals)
         self._last_sync_done = t1
         self._last_sync_kind = kind
         if dispatched_at is not None:
@@ -1591,7 +1693,11 @@ class Scheduler:
                         ring="adopt_dispatch" if adopting
                         else "prefill_dispatch",
                         n=len(sub), cached=hit is not None):
+                    m0 = self._mark()
                     toks = self._dispatch_prefill(sub, hit)
+                    self._dispatch_returned(
+                        "admit", m0, hasattr(toks, "is_ready"),
+                        "adopt" if adopting else "prefill", len(sub), bucket)
             except Exception as exc:  # noqa: BLE001 — engine errors → stream error
                 n_dispatches += 1  # a failed dispatch still cost time
                 self._spent_this_block += time.perf_counter() - t0
@@ -1674,67 +1780,207 @@ class Scheduler:
                     req=req, decoder=self.engine.tokenizer.stream_decoder(),
                     prompt_len=len(req.prompt_ids))
             members.append((slot, req, active))
-        self.metrics["peak_occupancy"] = max(self.metrics["peak_occupancy"],
-                                             len(self._slots))
         self._pending.append(_Admission(
-            kind, toks, members, shape, t0m, cost, self._chunks_unread))
-        self._chunks_unread = 0
+            kind, toks, members, shape, t0m, cost,
+            *self._take_unread_chunks()))
 
-    def _device_interval(self, adm: _Admission, was_ready: bool
-                         ) -> tuple[float, bool]:
-        """Device seconds of the admission just read, from the ready
-        stamps, and whether they are exact: the device ran it from the
-        moment the entry before it was ready until now. Only exact when
-        the thread WAITED for both entries; otherwise a bound — an upper
-        one when the thread came late to this entry or the device was
-        idle when it was dispatched (the interval then starts with the
-        dispatch call, host work and all), a lower one when it came late
-        to the entry before, a blurred one when chunk dispatches of
-        another shape ran in between."""
+    def _device_interval(self, kind: str, dispatched_at: float | None,
+                         chunks_before: int, was_ready: bool
+                         ) -> tuple[float, bool, float]:
+        """Device seconds of the entry just read (an admission or a
+        block), from the ready stamps, whether they are exact, and the
+        stamp: the device ran it from the moment the entry before it was
+        ready until now. Only exact when the thread WAITED for both
+        entries; otherwise a bound — an upper one when the thread came
+        late to this entry or the device was idle when it was dispatched
+        (the interval then starts with the dispatch call, host work and
+        all), a lower one when it came late to the entry before, a
+        blurred one when chunk dispatches of another shape ran in
+        between."""
         prev, prev_exact = self._ready_at
         now = time.monotonic()
         self._ready_at = (now, not was_ready)
-        idle_before = prev is None or adm.dispatched_at >= prev
-        interval = now - (adm.dispatched_at if idle_before else prev)
+        idle_before = prev is None or (dispatched_at is not None
+                                       and dispatched_at >= prev)
+        if idle_before and dispatched_at is None:
+            return 0.0, False, now
+        interval = now - (dispatched_at if idle_before else prev)
         exact = not was_ready and not idle_before and prev_exact
-        if adm.chunks_before:
+        if chunks_before:
             # A job's earlier chunks ran in the same interval: same
             # shape, so each took its share.
-            if adm.kind == "chunk":
-                interval /= 1 + adm.chunks_before
+            if kind == "chunk":
+                interval /= 1 + chunks_before
             else:
                 exact = False
-        return interval, exact
+        return interval, exact, now
+
+    def _mark(self) -> _Mark:
+        """Now, on this thread: what a read's host span, a wait or a
+        dispatch call is measured from."""
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        watch = self._compile_watch
+        return _Mark(time.monotonic(), time.thread_time(),
+                     time.process_time(), ru.ru_nvcsw, ru.ru_nivcsw,
+                     ru.ru_majflt, gc_seconds(),
+                     watch.lowerings if watch is not None else 0)
+
+    def _begin_read(self, kind: str, toks: Any, rows: int,
+                    bucket: int) -> _Read:
+        """What is known of a read before its wait: the `sync` phase's
+        attrs (so a capture's `sym.sched.sync` joins its record by seq),
+        whether the tokens are there already, what is in flight behind
+        the entry, and the mark the wait is measured from."""
+        return _Read({"entry": kind, "seq": self._reads_n, "rows": rows,
+                      "bucket": bucket},
+                     _is_ready(toks), len(self._pending), self._mark())
+
+    def _end_read(self, read: _Read, span: dict[str, Any], *,
+                  wait_s: float, tokens: int, dispatched_at: float | None,
+                  chunks: tuple[int, float], expected: float | None,
+                  charged_s: float = 0.0) -> tuple[float, bool]:
+        """The read returned: price the entry (_device_interval; what the
+        stamps only bound stays at `charged_s`), hold the wait against
+        `expected` device seconds — a wait that ran past them by more
+        than the threshold is a stall (_stalled), not device time: the
+        entry is priced at `expected`, inexact, so the excess is in no
+        `device_s`, no median and no `_shape_s` — and write the record
+        (READ_FIELDS) into stats()["reads"] and onto the `sched.sync`
+        ring span. Returns (device seconds, exact)."""
+        attrs, m0 = read.attrs, read.m0
+        kind, seq = attrs["entry"], attrs["seq"]
+        device_s, exact, now = self._device_interval(
+            kind, dispatched_at, chunks[0], read.late)
+        excess = wait_s - expected - chunks[1] if expected else 0.0
+        stalled = excess > self._stall_threshold()
+        if stalled:
+            # the thread woke late: this stamp is not the moment the
+            # device finished, for this entry or for the next one's start
+            device_s, exact = expected, False
+            self._ready_at = (now, False)
+        if not exact and charged_s:
+            device_s = charged_s
+        last_seq, last_t = self._last_block
+        if kind in ("decode_block", "verify"):
+            caused_by = last_seq
+            self._last_block = (seq, now)
+            if exact and kind == "decode_block":
+                self._recent_block_s.append(device_s)
+                self._block_device_s = statistics.median(
+                    self._recent_block_s)
+        else:
+            # the block it was queued behind was read after its dispatch
+            caused_by = (last_seq if dispatched_at is not None
+                         and last_t >= dispatched_at else None)
+        base = self._host_mark or m0
+        record = [seq, round(now, 6), kind, attrs["rows"], attrs["bucket"],
+                  tokens, caused_by, round(wait_s, 6), read.late,
+                  round(device_s, 6), exact, read.behind,
+                  round(m0.t - base.t, 6), round(m0.cpu - base.cpu, 6),
+                  m0.lowerings - base.lowerings,
+                  round(m0.gc_s - base.gc_s, 6), chunks[0]]
+        self._reads_n += 1
+        self._reads.append(record)
+        span.update(zip(READ_FIELDS, record))
+        m1 = self._host_mark = self._mark()
+        if stalled:
+            self._stalled("sync", m0, m1, seq, kind, attrs["rows"],
+                          attrs["bucket"], excess, read.behind)
+        return device_s, exact
+
+    def _stall_threshold(self) -> float:
+        return max(STALL_FLOOR_S, self._block_interval_s or 0.0)
+
+    def _dispatch_returned(self, phase: str, m0: _Mark, lazy: bool,
+                           kind: str, rows: int, bucket: int) -> None:
+        """A dispatch call that hands back device values should return
+        at once; one that took longer than the threshold is a stall, its
+        whole wall the excess. (A synchronous engine's call IS the device
+        work: not `lazy`, not judged.)"""
+        wall = time.monotonic() - m0.t
+        if lazy and wall > self._stall_threshold():
+            self._stalled(phase, m0, self._mark(),
+                          self._reads_n + len(self._pending), kind, rows,
+                          bucket, wall, len(self._pending))
+
+    def _stalled(self, phase: str, m0: _Mark, m1: _Mark, seq: int,
+                 kind: str, rows: int, bucket: int, excess_s: float,
+                 behind: int) -> None:
+        """Write one stall record (stats()["stalls"]): what the thread,
+        the process, the collector, the compiler and the device's
+        allocator did between the marks `m0` and `m1`. `seq` is the
+        entry's read record (for a dispatch: the one it will get). The
+        totals always count it; what the collector cannot gather (a
+        runtime that refuses `memory_stats()` mid-flight) is left out of
+        the record and logged — a diagnostic never fails the serving
+        path it is called from."""
+        totals = self._stalls
+        totals["count"] += 1
+        totals["seconds"] = round(totals["seconds"] + excess_s, 6)
+        totals["longest_s"] = round(max(totals["longest_s"], excess_s), 6)
+        totals["by_phase"][phase] = totals["by_phase"].get(phase, 0) + 1
+        stall = {
+            "t": round(m1.t, 6), "phase": phase, "seq": seq, "kind": kind,
+            "rows": rows, "bucket": bucket,
+            "wall_s": round(m1.t - m0.t, 6),
+            "excess_s": round(excess_s, 6),
+            "threshold_s": round(self._stall_threshold(), 6),
+            "behind": behind,
+            # CPU against wall: running or blocked; the process's CPU
+            # beside the thread's: another thread holding the GIL
+            "cpu_s": round(m1.cpu - m0.cpu, 6),
+            "process_cpu_s": round(m1.process_cpu - m0.process_cpu, 6),
+            "voluntary_switches": m1.voluntary - m0.voluntary,
+            "involuntary_switches": m1.involuntary - m0.involuntary,
+            "major_faults": m1.major_faults - m0.major_faults,
+            "gc_s": round(m1.gc_s - m0.gc_s, 6)}
+        self._stalls_recent.append(stall)
+        try:
+            watch = self._compile_watch
+            stall["in_flight"] = [
+                e.kind if isinstance(e, _Admission) else e[0]
+                for e in self._pending]
+            stall["compiles"] = (watch.overlapping(m0.t, m1.t)
+                                 if watch is not None else [])
+            stall["memory"] = memory_report()
+        except Exception as exc:  # noqa: BLE001 — diagnostics only
+            log.warning(f"engine stall: record incomplete: {exc!r}")
+        log.warning(f"engine stall: {stall}")
 
     def _read_admission(self, adm: _Admission) -> None:
         """Read one admission's first tokens (in device order) and do
         what they decide for each lane: fail on a device error, finish a
         request cancelled since dispatch, else activate."""
-        was_ready = _is_ready(adm.toks)
+        read = self._begin_read(adm.kind, adm.toks, adm.shape[1],
+                                adm.shape[2])
         error: Exception | None = None
+        tokens = sum(len(req.prompt_ids) - req.reused_tokens
+                     for _slot, req, _active in adm.members)
         t0 = time.perf_counter()
-        with self._phase("sync"):
+        with self._phase("sync", **read.attrs) as span:
             try:
                 firsts = np.asarray(adm.toks).reshape(-1)
             except Exception as exc:  # noqa: BLE001 — device errors → stream error
                 error = exc
-        self._admit["wait_s"] += time.perf_counter() - t0
+            wait_s = time.perf_counter() - t0
+            # What the stamps only bound stays at what the budget was
+            # charged: the shape's last measurement, or a synchronous
+            # engine's wall inside the call.
+            device_s, exact = self._end_read(
+                read, span, wait_s=wait_s, tokens=tokens,
+                dispatched_at=adm.dispatched_at,
+                chunks=(adm.chunks_before, adm.chunks_s),
+                expected=self._shape_s.get(adm.shape) or adm.charged_s,
+                charged_s=adm.charged_s)
+        self._admit["wait_s"] += wait_s
         self._admit["reads"] += 1
-        self._admit["ready_at_read"] += was_ready
-        device_s, exact = self._device_interval(adm, was_ready)
+        self._admit["ready_at_read"] += read.late
         if exact:
             self._shape_s[adm.shape] = device_s
-        elif adm.charged_s:
-            # The stamps only bound it: what the budget was charged
-            # stands — the shape's last measurement, or a synchronous
-            # engine's wall inside the call.
-            device_s = adm.charged_s
         self._admit["device_s"] += device_s
         if adm.kind == "adopt":
             self.metrics["adopt_s"] += device_s
             self._adopt_hist.observe(device_s)
-        elif adm.kind == "prefill":
-            self._admit_hist.observe(device_s)
         self._m_dispatch.observe(device_s, kind=adm.kind)
         if self.ledger.enabled and device_s > 0.0:
             self._book_admission(adm, device_s)
@@ -1826,14 +2072,20 @@ class Scheduler:
                 continue
             dispatch = getattr(self.engine,
                                "advance_chunked_prefill_dispatch", None)
+            shape = ("chunk", 1, self.engine.bucket_for(len(req.prompt_ids)),
+                     getattr(self.engine, "prefill_chunk", None) or 1)
             t0 = time.perf_counter()
             t0m = time.monotonic()
             try:
                 with self.tracer.phase("engine.chunk", ring="chunk_dispatch",
                                        request_id=req.id,
                                        trace_id=req.trace_id):
+                    m0 = self._mark()
                     toks = (dispatch or
                             self.engine.advance_chunked_prefill)(job)
+                    self._dispatch_returned(
+                        "chunks", m0, dispatch is not None, "chunk", 1,
+                        shape[2])
             except Exception as exc:  # noqa: BLE001 — fail one, not all
                 self._prefill_jobs.pop(0)
                 self._free.append(job.slot)
@@ -1847,8 +2099,6 @@ class Scheduler:
             self.metrics["chunk_s"] += dt
             progressed += 1
             budget -= 1
-            shape = ("chunk", 1, self.engine.bucket_for(len(req.prompt_ids)),
-                     getattr(self.engine, "prefill_chunk", None) or 1)
             if toks is not None:
                 # Final chunk: the insert is queued behind it and the
                 # lane is live; its first token is read in device order.
@@ -1862,6 +2112,7 @@ class Scheduler:
             cost = self._charge(shape, dt, materialised=dispatch is None)
             if dispatch is not None:
                 self._chunks_unread += 1
+                self._chunks_unread_s += cost
             self._admit["device_s"] += cost
             self._m_dispatch.observe(cost, kind="chunk")
             if req.ledger is not None:

@@ -246,6 +246,12 @@ class TpuNativeBackend(InferenceBackend):
         # being handled — the provider wires its flight-recorder dump
         # here so every restart leaves a debuggable artifact.
         self.on_host_restart = None
+        # Provider hook, called (the host's `stalls` block) when a
+        # heartbeat finds that the engine thread has recorded a stall
+        # since the last one (engine/scheduler.py _stalled) — the
+        # provider's flight-recorder dump, while the rings hold it.
+        self.on_engine_stall = None
+        self._stalls_seen = 0
         # Measured host-pipe clock offset (host monotonic − provider
         # monotonic), from the startup clock handshake. On Linux both
         # processes read one CLOCK_MONOTONIC so it lands near zero — but
@@ -1953,6 +1959,7 @@ class TpuNativeBackend(InferenceBackend):
                     # NEXT death's sheds stamp counts no staler than one
                     # heartbeat.
                     self._journal.merge(msg.get("journal"))
+                    self._note_stalls(msg.get("stalls"))
                 alive = msg is not None and self._engine_alive
                 if alive and self._local_pair and self._started:
                     # Decode tier answered — the prefill tier must too,
@@ -1984,6 +1991,18 @@ class TpuNativeBackend(InferenceBackend):
             if not self._started or self._circuit_open:
                 return
             await self._respawn_loop()
+
+    def _note_stalls(self, stalls: dict | None) -> None:
+        """Heartbeat rider: tell the provider when the host's stall count
+        has grown (a respawned host counts from zero again)."""
+        count = int((stalls or {}).get("count") or 0)
+        grown, self._stalls_seen = count > self._stalls_seen, count
+        hook = self.on_engine_stall
+        if grown and hook is not None:
+            try:
+                hook(stalls)
+            except Exception as exc:  # noqa: BLE001 — diagnostics only
+                log.warning(f"on_engine_stall hook failed: {exc}")
 
     async def _respawn_loop(self) -> None:
         """Respawn the dead host with exponential backoff; open the

@@ -297,6 +297,8 @@ class SymmetryProvider:
             # crash/wedge the supervisor handles dumps the flight
             # recorder FIRST — the restart must not erase the evidence.
             self.backend.on_host_restart = self._on_backend_restart
+        if hasattr(self.backend, "on_engine_stall"):
+            self.backend.on_engine_stall = self._on_engine_stall
         listen_address = listen_address or (
             f"{self._transport.scheme}://"
             f"{self.config.get('listenHost', '0.0.0.0')}"
@@ -469,6 +471,18 @@ class SymmetryProvider:
         self._m_backend_restarts.inc()
         if self.flight is not None:
             self._spawn(self._flight_dump(f"host_{reason}", force=True))
+
+    def _on_engine_stall(self, stalls: dict) -> None:
+        """Backend heartbeat hook: the engine thread recorded a stall (a
+        read or a dispatch call that ran long). Its record names the
+        phase and the entry; the dump keeps the rings around it."""
+        last = (stalls.get("recent") or [{}])[-1]
+        logger.warning(
+            f"engine stall #{stalls.get('count')}: {last.get('phase')} "
+            f"{last.get('kind')} seq {last.get('seq')}, "
+            f"{last.get('excess_s')}s over")
+        if self.flight is not None:
+            self._spawn(self._flight_dump("engine_stall"))
 
     def _start_puncher(self) -> None:
         """NAT hole punching (network/natpunch.py): keep this provider

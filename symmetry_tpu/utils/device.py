@@ -56,3 +56,13 @@ def device_report() -> dict[str, Any]:
             "device_kind": devices[0].device_kind,
             "device_count": len(devices),
             "hbm": hbm}
+
+
+def memory_report() -> list[dict[str, int]] | None:
+    """Each local device's `memory_stats()` as the runtime gives them
+    (`bytes_in_use`, `peak_bytes_in_use`, `largest_free_block_bytes`,
+    `num_allocs`, ...); None where it gives none (the CPU)."""
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    if not any(stats):
+        return None
+    return [{k: int(v) for k, v in (s or {}).items()} for s in stats]

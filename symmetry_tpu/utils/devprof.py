@@ -12,11 +12,14 @@ benchmark's `--trace 1` reduces that capture to `device_idle`,
 
 `CompileWatch` counts what JAX traces, lowers and compiles in the engine
 host, from `jax.monitoring` events — the stats op's `compile` block
-(`compile_share`, `lowerings_in_window`).
+(`compile_share`, `lowerings_in_window`) — and names the events that
+overlap a span (the scheduler's stall record). `gc_seconds` is the wall the
+garbage collector has held the interpreter, from one `gc.callbacks` pair.
 """
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
@@ -93,6 +96,18 @@ class CompileWatch:
         with self._lock:
             self._at_ready = self._snapshot()
 
+    @property
+    def lowerings(self) -> int:
+        return int(self._counts["lowerings"])
+
+    def overlapping(self, t0: float, t1: float) -> list[list]:
+        """The recent events that ran inside [t0, t1] (monotonic), as
+        [kind, function, seconds]: which step recompiled in that span."""
+        with self._lock:
+            return [[kind, name, round(s, 6)]
+                    for t, kind, name, s in self._recent
+                    if t - s <= t1 and t >= t0]
+
     def stats(self) -> dict[str, Any]:
         with self._lock:
             out: dict[str, Any] = self._snapshot()
@@ -100,6 +115,31 @@ class CompileWatch:
             out["recent"] = [[round(t, 4), kind, name, round(s, 6)]
                              for t, kind, name, s in self._recent]
         return out
+
+
+# ------------------------------------------------------ the collector
+
+# [seconds collecting since gc_watch(), start stamp of the collection in
+# progress]. A collection runs on whichever thread tripped the threshold and
+# holds the GIL throughout, so its wall is every thread's.
+_gc = [0.0, 0.0]
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc[1] = time.perf_counter()
+    else:
+        _gc[0] += time.perf_counter() - _gc[1]
+
+
+def gc_watch() -> None:
+    """Register the callback pair, once per process."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_seconds() -> float:
+    return _gc[0]
 
 
 # ------------------------------------------------------ on-demand capture
@@ -136,7 +176,13 @@ def capture_device_profile(out_dir: str, duration_s: float = 2.0) -> str:
             os.path.expanduser(out_dir),
             f"profile_{int(time.time())}_{uuid.uuid4().hex[:8]}")
         os.makedirs(path, exist_ok=True)
-        jax.profiler.start_trace(path)
+        # The Python tracer stays off: it hooks every Python call of every
+        # thread for the window (a lowering costs 45-87 ms under it, ~10
+        # untraced: PERF.md), and nothing reads its events — the `sym.*`
+        # annotations below are TraceMe events of the host tracer.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(path, profiler_options=options)
         try:
             # From here to stop_trace every Tracer.phase in this process
             # also enters its `sym.*` annotation; the capture thread names

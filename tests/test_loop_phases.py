@@ -239,8 +239,15 @@ class TestPartition:
         assert stats["tokens"] == 5 * 24 and stats["requests"] == 5
         for key in ("admit_s", "admit_dispatches", "chunk_s", "sync_s",
                     "dispatch_thread_s", "occupancy", "engine_ttft_s",
-                    "block_interval_s", "queue_depth"):
+                    "reads", "stalls", "queue_depth"):
             assert key in stats, key
+        # a block interval is read to read: t of one decode-block record
+        # to t of the next (what `block_interval_s` held as a histogram)
+        recs = [dict(zip(stats["reads"]["fields"], r))
+                for r in stats["reads"]["recent"]]
+        blocks = [r for r in recs if r["kind"] == "decode_block"]
+        assert len(blocks) > 2 and stats["reads"]["n"] >= len(recs)
+        assert all(b["t"] > a["t"] for a, b in zip(blocks, blocks[1:]))
 
 
 class TestAnnotations:
@@ -300,7 +307,8 @@ class TestAnnotations:
         import jax.profiler
 
         seen = []
-        monkeypatch.setattr(jax.profiler, "start_trace", lambda path: None)
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda path, **options: None)
         monkeypatch.setattr(jax.profiler, "stop_trace",
                             lambda: seen.append(trace._annotation))
 
@@ -355,15 +363,26 @@ class TestAnnotations:
         assert trace._annotation is None
         (pb,) = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
                           recursive=True)
-        lines = {}
+        lines, syncs = {}, []
         for plane in ProfileData.from_file(pb).planes:
             if plane.name.startswith("/host"):
                 for i, line in enumerate(plane.lines):
-                    names = {ev.name for ev in line.events
+                    names = {ev.name.split("#", 1)[0] for ev in line.events
                              if ev.name.startswith("sym.")}
+                    syncs += [dict(ev.stats) for ev in line.events
+                              if ev.name.startswith("sym.sched.sync")]
                     if names:
                         lines[i] = names
         everything = set().union(*lines.values())
+        # the sync events carry what was known before the wait: the entry's
+        # kind and the seq its read record has (PR 37)
+        attrs = syncs
+        assert attrs and all(set(a) == {"entry", "seq", "rows", "bucket"}
+                             for a in attrs)
+        assert {a["entry"] for a in attrs} == {"decode_block", "prefill"}
+        seqs = sorted(int(a["seq"]) for a in attrs)
+        assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
+        assert seqs[-1] < sched.stats()["reads"]["n"]
         assert {"sym.capture", "sym.sched.sync", "sym.sched.admit",
                 "sym.engine.prefill", "sym.emit.emit_flush"} <= everything
         # the loop phases share one line (the engine thread's); the

@@ -562,7 +562,13 @@ class TestEstimatedSecondsBudget:
         assert 0.03 <= measured < 0.1
         assert sched.stats()["admit"]["device_s"] == pytest.approx(
             first + measured, abs=1e-5)
-        assert sched.stats()["admit_dispatch_s"]["count"] == 2
+        reads = sched.stats()["reads"]
+        recs = [dict(zip(reads["fields"], r)) for r in reads["recent"]]
+        assert [r["kind"] for r in recs] == [
+            "prefill", "decode_block", "prefill"]
+        assert [r["exact"] for r in recs] == [False, False, True]
+        assert recs[2]["device_s"] == pytest.approx(measured, abs=1e-5)
+        assert recs[2]["caused_by"] == recs[1]["seq"] == 1
         assert sched.stats()["admit"]["ready_at_read"] == 0
 
     def test_deferred_units_keep_arrival_order(self):

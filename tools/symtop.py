@@ -35,6 +35,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import math
 import os
 import sys
 import time
@@ -51,9 +52,10 @@ from symmetry_tpu.utils.metrics import (  # noqa: E402
 COLUMNS = ("PROVIDER", "TIER", "TOK/S", "TTFT p50", "TTFT p99",
            "QUEUE", "INFL", "OCC", "DEPTH", "SHED", "RESUME",
            "WASTED", "REUSED", "DUMPS", "COST", "WASTE%", "GPUT",
-           "LINK", "STATE", "SHARE", "HIT", "TARGET", "SCALE")
+           "LINK", "STATE", "SHARE", "HIT", "TARGET", "SCALE",
+           "STALLS", "TAIL")
 WIDTHS = (22, 10, 9, 9, 9, 7, 6, 5, 5, 7, 7, 7, 7, 6, 7, 6, 7, 6,
-          9, 6, 6, 9, 6)
+          9, 6, 6, 9, 6, 9, 6)
 
 # sym_pool_member_state gauge encoding (engine/disagg/pool.py
 # STATE_CODES) rendered back to the membership lifecycle names.
@@ -228,10 +230,35 @@ def _pool_rows(name: str, fams: dict) -> list[dict[str, Any]]:
 # ------------------------------------------------------------- row model
 
 
-def build_rows(name: str, fams: dict,
-               prev: dict | None, now: float) -> list[dict[str, Any]]:
+def read_tail(engine: dict | None) -> tuple[str | None, float | None]:
+    """The engine host's stall and read records (the stats reply's
+    `engine` block; a wire poll has it, a Prometheus scrape does not):
+    STALLS = count / longest excess in seconds; TAIL = 99th percentile of
+    the decode-block read-to-read intervals among the last 64 reads —
+    the engine's side of the clients' inter-chunk gap p99. One reply's
+    `recent` is consecutive reads by construction, so only an idle
+    boundary (`caused_by`) breaks the chain here; the benchmark's
+    `readers/tail.py intervals` unions many replies and checks `seq` too."""
+    engine = engine or {}
+    stalls, reads = engine.get("stalls"), engine.get("reads")
+    stall_cell = (None if not stalls else
+                  f"{stalls['count']}/{stalls['longest_s']:.1f}")
+    if not reads:
+        return stall_cell, None
+    recs = [dict(zip(reads["fields"], row)) for row in reads["recent"]]
+    blocks = [r for r in recs if r["kind"] in ("decode_block", "verify")]
+    ivs = sorted(b["t"] - a["t"] for a, b in zip(blocks, blocks[1:])
+                 if b["caused_by"] == a["seq"])
+    tail = ivs[math.ceil(0.99 * len(ivs)) - 1] if ivs else None
+    return stall_cell, tail
+
+
+def build_rows(name: str, fams: dict, prev: dict | None, now: float,
+               engine: dict | None = None) -> list[dict[str, Any]]:
     """One provider-level row plus one sub-row per engine tier. `prev`
-    is the previous poll's {"t", "tok", "shed"} for rate deltas."""
+    is the previous poll's {"t", "tok", "shed"} for rate deltas;
+    `engine` the stats reply's engine block, where the poll has one."""
+    stall_cell, tail = read_tail(engine)
     tok = _value(fams, "sym_provider_tokens_out_total", 0.0)
     shed = _value(fams, "sym_provider_sheds_total", 0.0)
     cost_total, cost_n = _ledger_cost(fams)
@@ -306,6 +333,7 @@ def build_rows(name: str, fams: dict,
         "link": (None if link is None else ("up" if link else "DOWN")),
         "state": None, "share": None,
         "target": target, "scale": scale_disp,
+        "stalls": stall_cell, "tail": tail,
         "_sample": {"t": now, "tok": tok, "shed": shed or 0.0,
                     "dec": decisions or 0.0},
     }]
@@ -370,7 +398,8 @@ def render_table(rows: list[dict[str, Any]]) -> str:
                  r.get("cost"), r.get("waste") or "-", r.get("gput"),
                  r["link"] or "-",
                  r.get("state") or "-", r.get("share") or "-",
-                 r.get("hit"), r.get("target") or "-", r.get("scale"))
+                 r.get("hit"), r.get("target") or "-", r.get("scale"),
+                 r.get("stalls") or "-", r.get("tail"))
         out.append("  ".join(_fmt_cell(c, w)
                              for c, w in zip(cells, WIDTHS)))
     return "\n".join(out)
@@ -384,9 +413,11 @@ def poll_http(url: str, timeout: float = 5.0) -> dict:
         return parse_prometheus_text(resp.read().decode("utf-8"))
 
 
-async def poll_wire(address: str, key_hex: str | None) -> dict:
+async def poll_wire(address: str, key_hex: str | None
+                    ) -> tuple[dict, dict | None]:
     """One metrics probe over the peer wire (stats + tier-labeled
-    registry snapshots ride the same reply)."""
+    registry snapshots ride the same reply): the families, and the
+    engine host's stats block."""
     from symmetry_tpu.client.client import SymmetryClient
 
     client = SymmetryClient()
@@ -397,7 +428,8 @@ async def poll_wire(address: str, key_hex: str | None) -> dict:
     finally:
         await session.close()
     return families_from_snapshots(
-        (stats.get("metrics") or {}).get("snapshots") or [])
+        (stats.get("metrics") or {}).get("snapshots") or []), stats.get(
+            "engine")
 
 
 # ------------------------------------------------------------------ main
@@ -444,10 +476,10 @@ def main(argv: list[str] | None = None) -> int:
             for kind, where, key in targets:
                 short = where.split("//")[-1]
                 try:
-                    fams = (poll_http(where) if kind == "http"
-                            else loop.run_until_complete(
-                                asyncio.wait_for(poll_wire(where, key),
-                                                 10.0)))
+                    fams, engine = (
+                        (poll_http(where), None) if kind == "http"
+                        else loop.run_until_complete(asyncio.wait_for(
+                            poll_wire(where, key), 10.0)))
                 except Exception as exc:  # noqa: BLE001 — show, keep polling
                     rows.append({"provider": short, "tier": "",
                                  "tok_s": None, "ttft_p50": None,
@@ -456,7 +488,8 @@ def main(argv: list[str] | None = None) -> int:
                                  "shed": None,
                                  "link": f"ERR:{type(exc).__name__}"})
                     continue
-                target_rows = build_rows(short, fams, prev.get(where), now)
+                target_rows = build_rows(short, fams, prev.get(where), now,
+                                         engine)
                 sample = target_rows[0].pop("_sample", None)
                 if sample:
                     prev[where] = sample
