@@ -283,9 +283,11 @@ class Scheduler:
         self._slots: dict[int, _ActiveSlot] = {}
         self._free: list[int] = list(range(engine.max_slots))[::-1]
         # Block-granular emit: events buffer on the engine thread and are
-        # delivered at block boundaries — as ONE emit_batch call when a
-        # sink is installed (the host pipe writes one frame per block), or
-        # per-event through req.emit otherwise (AsyncSession, tests).
+        # delivered when the entry that produced them has been read — as
+        # ONE emit_batch call when a sink is installed (the host pipe
+        # writes one frame per entry read: a block's chunks, an
+        # admission's first tokens), or per-event through req.emit
+        # otherwise (AsyncSession, tests).
         self._emit_batch = emit_batch
         self._pending_events: list[tuple[GenRequest, TokenEvent]] = []
         # Overlapped pipeline (ROADMAP item 2): `pipeline_depth` decode
@@ -369,6 +371,13 @@ class Scheduler:
         # already there when the thread came to read them.
         self._admit = {"device_s": 0.0, "wait_s": 0.0, "reads": 0,
                        "ready_at_read": 0}
+        # stats()["flush_ahead"]: that a block's events leave ahead of
+        # the admissions behind it. blocks = block reads whose events
+        # were handed on while an admission sat unread behind them;
+        # lead_s = the summed wait_s of the first admission read after
+        # each such flush — the seconds the clients no longer wait.
+        self._flush_ahead = {"blocks": 0, "lead_s": 0.0}
+        self._lead_open = False
         # The read records (READ_FIELDS): the count since start and the
         # last 64, which a sampler of stats() unions by seq.
         self._compile_watch = compile_watch
@@ -628,6 +637,8 @@ class Scheduler:
             self.tracer.phase_s.get("sched." + name, 0.0), 6)
             for name in LOOP_PHASES}
         out["admit"] = {k: round(v, 6) for k, v in self._admit.items()}
+        out["flush_ahead"] = {k: round(v, 6)
+                              for k, v in self._flush_ahead.items()}
         out["reads"] = {"n": self._reads_n, "fields": list(READ_FIELDS),
                         "recent": list(self._reads)}
         out["stalls"] = {**self._stalls,
@@ -833,7 +844,7 @@ class Scheduler:
                 log.error(f"emit worker batch failed: {exc}")
 
     def _deliver_jobs(self, jobs: list[tuple]) -> None:
-        """Run one block's jobs (detokenize + event build) and deliver
+        """Run one entry's jobs (detokenize + event build) and deliver
         the resulting events exactly like the inline _flush_events path:
         one emit_batch call with a sink installed, else per-event
         req.emit. Worker thread; books into _wmetrics only."""
@@ -964,6 +975,20 @@ class Scheduler:
         # of the block already in flight, like any lane freed between
         # dispatch and read (the stale-snapshot check in _process_block).
         #
+        # What is read leaves: the events of every entry — decode block,
+        # verify dispatch, admission — are handed on (one emit-queue put,
+        # or the inline emit_batch call at depth 1) as soon as that entry
+        # has been processed, BEFORE the thread waits on the next one
+        # (_process_pending). So a block's chunk does not sit out the
+        # device seconds of the admission behind it — a client's gap is
+        # the block-to-block interval — and an admission's first tokens
+        # do not pay for the entries behind them (a cold burst queues
+        # many); the detokenize and the pipe write overlap the next
+        # entry's wait, and by (a) the device has B(k+1) queued meanwhile.
+        # B(k)'s snapshot holds no lane of P(k) — those lanes join B(k+1)
+        # — so a stream's first-token event still precedes its first
+        # block event.
+        #
         # Each block entry is (kind, device tokens, slot snapshot at
         # dispatch, dispatch stamp, extra): the snapshot attributes each
         # lane's tokens to the request that occupied it AT DISPATCH, so a
@@ -1015,15 +1040,12 @@ class Scheduler:
             # Read the oldest block once the pipeline is full — and when
             # nothing was dispatched (slots emptied or stopping: the
             # drain path) — then every admission queued behind it. Each
-            # admission's first tokens leave as soon as they are read:
-            # first-token latency must not pay for the entries behind it
-            # (a cold burst queues many).
+            # entry's events leave at its read (_process_pending).
             if pending and (self._blocks_in_flight >= want
                             or not did_dispatch
                             or (did_verify and self._depth == 1)):
                 self._read_through_block()
             self._read_admissions()
-            self._flush_events()
             self._spent_this_block = 0.0
             with self._phase("admit"):
                 drained = self._admit_new()
@@ -1110,22 +1132,28 @@ class Scheduler:
 
     def _read_admissions(self) -> None:
         """Read every admission at the head of the in-flight queue (those
-        queued between the block just read and the next one); each one's
-        first tokens leave at once."""
+        queued between the block just read and the next one)."""
         while self._pending and isinstance(self._pending[0], _Admission):
             self._process_pending(self._pending.popleft())
-            self._flush_events()
 
     def _process_pending(self, entry: tuple | _Admission) -> None:
         """Read + process one in-flight entry (FIFO order): the loop's
         `process` phase, which the device→host waits inside it suspend as
-        `sync`."""
+        `sync`. Then hand on what it buffered, before the caller waits on
+        the next entry: what is read leaves."""
         with self._phase("process"):
             if isinstance(entry, _Admission):
                 self._read_admission(entry)
             else:
                 self._blocks_in_flight = max(0, self._blocks_in_flight - 1)
                 self._sync_and_process(entry)
+        flushed = self._flush_events()
+        if (flushed and not isinstance(entry, _Admission) and self._pending
+                and isinstance(self._pending[0], _Admission)):
+            # This block's events left with an admission still unread
+            # behind it: that admission's wait is what they were spared.
+            self._flush_ahead["blocks"] += 1
+            self._lead_open = True
 
     def _sync_and_process(self, blk: tuple) -> None:
         """Verify entries book their speculative accounting HERE, at sync
@@ -1183,8 +1211,8 @@ class Scheduler:
         scan over the whole [K, B] block, then per live slot one
         finish-point computation, one push_many over its token run, and
         one buffered TokenEvent — per-token Python work is gone, and the
-        block boundary flush coalesces every slot's event into a single
-        host-pipe frame.
+        flush that follows the block's read (_process_pending) coalesces
+        every slot's event into a single host-pipe frame.
 
         `n_valid` [B] makes the block RAGGED: slot b produced only
         n_valid[b] tokens this dispatch (>= 1). Plain decode blocks pass
@@ -1526,7 +1554,6 @@ class Scheduler:
                 # entry, not per event).
                 while len(self._pending) > 1:
                     self._process_pending(self._pending.popleft())
-                    self._flush_events()
         if carry is not None:
             # No free slot took it (all busy): hold it at the deferred
             # tail rather than dropping it — every deferred entry was
@@ -1973,6 +2000,9 @@ class Scheduler:
                 expected=self._shape_s.get(adm.shape) or adm.charged_s,
                 charged_s=adm.charged_s)
         self._admit["wait_s"] += wait_s
+        if self._lead_open:
+            self._flush_ahead["lead_s"] += wait_s
+            self._lead_open = False
         self._admit["reads"] += 1
         self._admit["ready_at_read"] += read.late
         if exact:
@@ -2304,25 +2334,27 @@ class Scheduler:
             ev.costs = req.ledger.finish(ev.finish_reason or "error")
         self._submit_job(("raw", req, ev))
 
-    def _flush_events(self) -> None:
-        """Block-boundary flush. Offload on: hand the buffered jobs to
-        the emit worker as ONE bounded-queue put (blocking when the queue
-        is full — the backpressure that bounds memory under a slow
-        pipe). Offload off: deliver everything buffered inline — one
-        emit_batch call when a sink is installed (→ one host-pipe frame
-        per block), else per-event req.emit delivery. The loop's `flush`
-        phase."""
+    def _flush_events(self) -> bool:
+        """Hand on what is buffered (after every entry read, and after
+        the admission pass); True when there was something. Offload on:
+        the buffered jobs go to the emit worker as ONE bounded-queue put
+        (blocking when the queue is full — the backpressure that bounds
+        memory under a slow pipe). Offload off: deliver everything
+        buffered inline — one emit_batch call when a sink is installed
+        (→ one host-pipe frame per entry read), else per-event req.emit
+        delivery. The loop's `flush` phase."""
         with self._phase("flush"):
-            self._flush_pending()
+            return self._flush_pending()
 
-    def _flush_pending(self) -> None:
+    def _flush_pending(self) -> bool:
         if self._emit_offload:
-            if self._block_jobs:
-                jobs, self._block_jobs = self._block_jobs, []
-                self._emit_queue.put(jobs)
-            return
+            if not self._block_jobs:
+                return False
+            jobs, self._block_jobs = self._block_jobs, []
+            self._emit_queue.put(jobs)
+            return True
         if not self._pending_events:
-            return
+            return False
         batch, self._pending_events = self._pending_events, []
         self.metrics["emit_flushes"] += 1
         self.metrics["emit_events"] += len(batch)
@@ -2340,12 +2372,13 @@ class Scheduler:
                 for req, _ev in batch:
                     if req.ledger is not None:
                         req.ledger.book_emit(per)
-            return
+            return True
         for req, ev in batch:
             try:
                 req.emit(ev)
             except Exception as exc:  # noqa: BLE001 — emit must never kill the loop
                 log.error(f"emit callback failed for request {req.id}: {exc}")
+        return True
 
     def _check_invariants(self) -> None:
         active = set(self._slots)

@@ -22,6 +22,7 @@ chip, no model.
 """
 
 import asyncio
+import json
 import threading
 import time
 
@@ -642,7 +643,8 @@ class TestSymtop:
         st = sched.stats()
         if stalls is not None:
             st["stalls"] = stalls
-        return {"reads": st["reads"], "stalls": st["stalls"]}
+        return {"reads": st["reads"], "stalls": st["stalls"],
+                "flush_ahead": st["flush_ahead"]}
 
     def test_columns_from_the_engine_block(self):
         import tools.symtop as symtop
@@ -653,8 +655,21 @@ class TestSymtop:
         assert 0.005 < rows[0]["tail"] < 0.2
         table = symtop.render_table(rows)
         head, first = table.splitlines()[:2]
-        assert head.split()[-2:] == ["STALLS", "TAIL"]
+        assert head.split()[-3:] == ["AHEAD", "STALLS", "TAIL"]
         assert first.split()[-2] == "2/4.7"
+
+    def test_flush_ahead_beside_the_stalls(self):
+        """AHEAD = blocks whose events left ahead of an admission / the
+        seconds of those admissions' waits (stats `flush_ahead`)."""
+        import tools.symtop as symtop
+
+        engine = self._engine()
+        assert engine["flush_ahead"] == {"blocks": 0, "lead_s": 0.0}
+        engine["flush_ahead"] = {"blocks": 212, "lead_s": 9.4312}
+        rows = symtop.build_rows("prov", {}, None, now=0.0, engine=engine)
+        assert rows[0]["ahead"] == "212/9.4"
+        first = symtop.render_table(rows).splitlines()[1]
+        assert first.split()[-3] == "212/9.4"
 
     @pytest.mark.parametrize("engine", [None, {}, {"tokens": 3}])
     def test_a_scrape_or_an_older_host_shows_nothing(self, engine):
@@ -662,5 +677,97 @@ class TestSymtop:
 
         rows = symtop.build_rows("prov", {}, None, now=0.0, engine=engine)
         assert rows[0]["stalls"] is None and rows[0]["tail"] is None
-        assert symtop.render_table(rows).splitlines()[1].split()[-2:] == [
-            "-", "-"]
+        assert rows[0]["ahead"] is None
+        assert symtop.render_table(rows).splitlines()[1].split()[-3:] == [
+            "-", "-", "-"]
+
+
+class TestReadTailListing:
+    """`tools/read_tail.py` on a dump: what the clients' gap p99 is held
+    against. A tree before PR 38 flushed a block's events after the first
+    admission read behind it, so the listing rebuilds those intervals; a
+    dump that carries `flush_ahead` prints the plain interval beside the
+    counter's growth."""
+
+    BLOCK_S = 0.3
+
+    def _dump(self, tmp_path, flush_ahead):
+        """60 blocks of 0.3 s with two prefills of 0.05 s behind each;
+        every tenth block's second prefill takes 0.2 s and the first one
+        behind the block after it 0.25 s. The clients' chunks arrive when
+        the block's events left: at the block's read (`flush_ahead`),
+        else at the first admission's read behind it."""
+        rows, left, t, seq, last = [], [], 100.0, 0, None
+        lead_s = 0.0
+        for i in range(60):
+            t += self.BLOCK_S
+            rows.append([seq, t, "decode_block", 128, 0, 2048, last,
+                         self.BLOCK_S, False, self.BLOCK_S, True, 2,
+                         0.002, 0.002, 0, 0.0, 0])
+            last, seq, t_block = seq, seq + 1, t
+            first = 0.25 if i % 10 == 6 else 0.05
+            for n, p in enumerate((first, 0.2 if i % 10 == 5 else 0.05)):
+                t += p
+                rows.append([seq, t, "prefill", 4, 128, 400, last, p,
+                             False, p, True, 1, 0.002, 0.002, 0, 0.0, 0])
+                seq += 1
+                if n == 0:
+                    lead_s += p
+                    left.append((t_block if flush_ahead else t, i + 1,
+                                 lead_s))
+        w0, w1 = 101.0, 121.0
+
+        def stats_at(now):
+            seen = [r for r in rows if r[1] <= now]
+            engine = {"reads": {"n": len(seen), "fields": list(READ_FIELDS),
+                                "recent": seen[-40:]}}
+            if flush_ahead:
+                gone = [x for x in left if x[0] <= now]
+                engine["flush_ahead"] = {
+                    "blocks": gone[-1][1] if gone else 0,
+                    "lead_s": gone[-1][2] if gone else 0.0}
+            return {"engine": engine}
+
+        samples = [(w0 + i, stats_at(w0 + i)) for i in range(21)]
+        stamps = [[m, 16] for m, _n, _s in left]
+        path = tmp_path / "cell.1.json"
+        path.write_text(json.dumps({
+            "w0": w0, "w1": w1, "samples": samples,
+            "records": [{"stamps": stamps}] * 4}))
+        return path
+
+    @pytest.mark.parametrize("flush_ahead", [False, True],
+                             ids=["older-tree", "flush-ahead"])
+    def test_the_client_p99_beside_what_explains_it(self, tmp_path,
+                                                    flush_ahead):
+        import os
+        import subprocess
+        import sys
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        out = subprocess.run(
+            [sys.executable, os.path.join(root, "tools", "read_tail.py"),
+             str(self._dump(tmp_path, flush_ahead))],
+            capture_output=True, text=True, timeout=120,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
+        assert out.returncode == 0, out.stderr[-2000:]
+        got = json.loads(out.stdout.strip().splitlines()[-1])
+        # an interval is a block + the two prefills before it: 0.4, once
+        # in ten 0.55 (the long second one), then 0.6 (the long first one)
+        assert got["interval_p99_s"] == pytest.approx(0.6, abs=1e-6)
+        if flush_ahead:
+            assert "leave_p99_s" not in got
+            assert got["client_gap_p99_s"] == pytest.approx(0.6, abs=1e-6)
+            assert got["wire_excess_ms"] == pytest.approx(0.0, abs=1e-3)
+            ahead = got["flush_ahead"]
+            assert 43 <= ahead["blocks"] <= 49     # 20 s of 0.4-0.6 s
+            assert ahead["lead_mean_s"] == pytest.approx(0.07, abs=0.01)
+            assert ahead["lead_share"] == pytest.approx(
+                100.0 * ahead["lead_s"] / 20.0, abs=1e-2)
+        else:
+            # block read -> first admission read: the 0.55 interval + the
+            # difference of the two first waits (0.25 - 0.05)
+            assert "flush_ahead" not in got
+            assert got["leave_p99_s"] == pytest.approx(0.75, abs=1e-6)
+            assert got["client_gap_p99_s"] == pytest.approx(0.75, abs=1e-6)
+            assert got["wire_excess_ms"] == pytest.approx(150.0, abs=1e-3)

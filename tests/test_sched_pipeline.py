@@ -274,18 +274,23 @@ class LazyToks:
     """Something still on the device: `np.asarray` waits for it (and
     logs the read); `is_ready` says whether it would."""
 
-    def __init__(self, arr, log, name, wall=0.0, error=None):
+    def __init__(self, arr, log, name, wall=0.0, error=None, on_wait=None):
         self.arr = np.asarray(arr, dtype=np.int32)
         self.shape = self.arr.shape
         self.log, self.name, self.wall, self.error = log, name, wall, error
+        self.on_wait = on_wait
         self.read = False
 
     def is_ready(self):
         return self.read
 
     def __array__(self, dtype=None, copy=None):
-        if not self.read and self.wall:
-            time.sleep(self.wall)
+        if not self.read:
+            self.log.append(("wait", self.name))
+            if self.on_wait is not None:
+                self.on_wait(self.name)
+            if self.wall:
+                time.sleep(self.wall)
         self.read = True
         self.log.append(("read", self.name))
         if self.error is not None:
@@ -298,11 +303,13 @@ class AsyncFakeEngine(FakeEngine):
     returns at once and logs itself; reading its tokens waits."""
 
     def __init__(self, *, first=ord("A"), prefill_wall=0.0, batch_cap=4,
-                 **kw):
+                 block_wall=0.002, **kw):
         super().__init__(**kw)
-        self.log: list[tuple[str, str]] = []
+        self.log: list[tuple] = []
+        self.on_wait = None     # called with an entry's name as its wait begins
         self.first = first
         self.prefill_wall = prefill_wall
+        self.block_wall = block_wall
         self.batch_cap = batch_cap
         self.prefill_error = None
         self.n_prefills = 0
@@ -317,13 +324,13 @@ class AsyncFakeEngine(FakeEngine):
         self.prefill_order.extend(bytes(ids) for _s, ids, _p in group)
         self.log.append(("dispatch", name))
         return LazyToks([self.first] * len(group), self.log, name,
-                        self.prefill_wall, self.prefill_error)
+                        self.prefill_wall, self.prefill_error, self.on_wait)
 
     def decode_steps_dispatch(self):
         name = f"B{self.dispatches}"
         self.log.append(("dispatch", name))
         return LazyToks(super().decode_steps_dispatch(), self.log, name,
-                        0.002)
+                        self.block_wall, on_wait=self.on_wait)
 
 
 class SyncOnlyEngine(FakeEngine):
@@ -485,6 +492,254 @@ class TestAdmissionInFlight:
         submit(sched, b"r1")
         sched._admit_new()
         assert sched._slots[0].req.id == "r1"
+
+
+class SpecFakeEngine(AsyncFakeEngine):
+    """A fake device that also verifies drafts: every lane accepts its
+    whole draft, so the drafter (fed a stream of one repeated token)
+    keeps proposing and the loop keeps draining its queue."""
+
+    K_DRAFT = 2
+
+    def __init__(self, **kw):
+        from symmetry_tpu.engine.spec import SpecConfig
+
+        super().__init__(**kw)
+        self.spec = SpecConfig(k_draft=self.K_DRAFT)
+        self.verifies = 0
+
+    def verify_step_dispatch(self, draft, n_draft):
+        name = f"V{self.verifies}"
+        self.verifies += 1
+        self.log.append(("dispatch", name))
+        toks = np.full((1 + self.K_DRAFT, self.max_slots), ord("b"),
+                       dtype=np.int32)
+        return (LazyToks(toks, self.log, name, self.block_wall,
+                         on_wait=self.on_wait),
+                1 + np.asarray(n_draft))
+
+
+# (pipeline_depth, emit offload): depth 1 never offloads; at depth 2 the
+# un-started scheduler delivers inline and the started one through the
+# worker.
+EMIT_PATHS = [pytest.param(1, False, id="depth1-inline"),
+              pytest.param(2, False, id="depth2-inline"),
+              pytest.param(2, True, id="depth2-offload")]
+
+
+def signature(events):
+    return [(ev.text, ev.tokens_generated, ev.tokens_emitted, ev.done,
+             ev.finish_reason, ev.ttft_s is not None,
+             ev.costs is not None) for ev in events]
+
+
+class TestWhatIsReadLeaves:
+    """The loop's one rule for the flush (PR 38): the events of an entry
+    — decode block, verify dispatch, admission — are handed on when that
+    entry has been read, before the thread waits on the next one. Until
+    then a block's chunk sat out the first admission's device seconds
+    behind it."""
+
+    def _sched(self, eng, depth, offload, **kw):
+        """A scheduler driven white-box whose sink writes into the fake
+        device's log; `offload` runs the emit worker without the loop."""
+        got: dict[str, list] = {}
+
+        def sink(batch):
+            eng.log.append(("sink", tuple(
+                (req.id, ev.text, ev.done) for req, ev in batch)))
+            for req, ev in batch:
+                got.setdefault(req.id, []).append(ev)
+
+        sched = Scheduler(eng, pipeline_depth=depth, emit_batch=sink, **kw)
+        if offload:
+            sched._emit_offload = True
+            sched._emit_thread = threading.Thread(
+                target=sched._emit_worker_run, daemon=True)
+            sched._emit_thread.start()
+        return sched, got
+
+    @staticmethod
+    def _push(sched, eng):
+        sched._push_block(("decode_block", eng.decode_steps_dispatch(),
+                           dict(sched._slots), time.monotonic(), None))
+
+    @staticmethod
+    def _sunk(log, event):
+        """Index of the sink call that carried `event`."""
+        return next(i for i, (kind, what) in enumerate(log)
+                    if kind == "sink" and event in what)
+
+    def _block_with_an_admission_behind(self, depth, offload):
+        """[B0, P1, B1] in flight with r0 live: the queue the loop reads
+        from in steady state. Reads B0, then P1."""
+        eng = AsyncFakeEngine(slots=3, prefill_wall=0.05, block_wall=0.02)
+        sched, got = self._sched(eng, depth, offload)
+        submit(sched, b"r0")
+        sched._admit_new()
+        sched._read_admissions()
+        assert sched.stats()["flush_ahead"] == {"blocks": 0, "lead_s": 0.0}
+        self._push(sched, eng)            # B0
+        submit(sched, b"r1")
+        sched._admit_new()                # P1, behind B0
+        self._push(sched, eng)            # B1, behind P1: invariant (a)
+        sched._read_through_block()
+        sched._read_admissions()
+        return eng, sched, got
+
+    @pytest.mark.parametrize("depth,offload", EMIT_PATHS)
+    def test_a_blocks_events_leave_before_the_admission_behind_it_is_read(
+            self, depth, offload):
+        eng, sched, got = self._block_with_an_admission_behind(
+            depth, offload)
+        sched._stop_emit_worker()
+        log = eng.log
+        chunk = self._sunk(log, ("r0", "bbbb", False))
+        assert log.index(("read", "B0")) < chunk
+        if offload:
+            # handed to the worker before the wait: delivered while the
+            # admission's 50 ms run
+            assert chunk < log.index(("read", "P1"))
+        else:
+            assert chunk < log.index(("wait", "P1"))
+        # ... and the admission's first token at its own read, with B1
+        # still unread behind it
+        first = self._sunk(log, ("r1", "A", False))
+        assert log.index(("read", "P1")) < first
+        assert ("wait", "B1") not in log
+        assert [ev.text for ev in got["r0"]] == ["A", "bbbb"]
+        assert [ev.text for ev in got["r1"]] == ["A"]
+
+    @pytest.mark.parametrize("depth,offload", EMIT_PATHS)
+    def test_flush_ahead_counts_a_block_with_an_admission_behind_it(
+            self, depth, offload):
+        eng, sched, _got = self._block_with_an_admission_behind(
+            depth, offload)
+        st = sched.stats()
+        recs = [dict(zip(st["reads"]["fields"], r))
+                for r in st["reads"]["recent"]]
+        assert [r["kind"] for r in recs] == [
+            "prefill", "decode_block", "prefill"]
+        assert st["flush_ahead"]["blocks"] == 1
+        assert st["flush_ahead"]["lead_s"] == pytest.approx(
+            recs[2]["wait_s"], abs=2e-6)
+        assert recs[2]["wait_s"] >= 0.05
+        # B1 has no admission behind it: read, flushed, not counted; and
+        # an admission read behind no flush-ahead adds no lead
+        sched._read_through_block()
+        submit(sched, b"r2")
+        sched._admit_new()
+        sched._read_admissions()
+        sched._stop_emit_worker()
+        after = sched.stats()
+        assert after["reads"]["n"] == 5
+        assert after["flush_ahead"] == st["flush_ahead"]
+        assert after["admit"]["wait_s"] > st["admit"]["wait_s"]
+
+    def _serve(self, depth, offload, order):
+        """One scripted run of admissions between blocks — finishes at a
+        first token, mid-block, by EOS and by budget — read in the loop's
+        order (`now`) or in the parent's order of calls (`parent`: the
+        block's events buffered until the first admission behind it has
+        been read, then a flush after every further admission)."""
+        eng = AsyncFakeEngine(slots=4, block=4)
+        sched, got = self._sched(eng, depth, offload)
+        pending = sched._pending
+
+        def read():
+            if order == "now":
+                sched._read_through_block()
+                sched._read_admissions()
+                return
+            flush = sched._flush_events
+            sched._flush_events = lambda: False
+            try:
+                sched._read_through_block()
+                while pending and not isinstance(pending[0], tuple):
+                    sched._process_pending(pending.popleft())
+                    flush()
+            finally:
+                sched._flush_events = flush
+            flush()
+
+        arrivals = [
+            [(b"r0", 6), (b"r1", 14)],     # one group of two
+            [(b"r2", 1)],                  # finishes at its first token
+            [(b"r3", 9), (b"r4", 3)],
+            [],
+            [(b"r5", 5)],
+        ]
+        for step in range(8):
+            for rid, max_new in (arrivals[step] if step < len(arrivals)
+                                 else []):
+                submit(sched, rid, max_new=max_new)
+            sched._admit_new()
+            sched._flush_events()
+            if sched._slots:
+                self._push(sched, eng)
+            if sched._blocks_in_flight >= 2 or not sched._slots:
+                read()
+        while pending:
+            read()
+        sched._stop_emit_worker()
+        assert not sched._slots
+        return {rid: signature(evs) for rid, evs in got.items()}
+
+    @pytest.mark.parametrize("depth,offload", EMIT_PATHS)
+    def test_every_stream_is_what_the_parents_order_of_calls_sent(
+            self, depth, offload):
+        now = self._serve(depth, offload, "now")
+        assert now == self._serve(depth, offload, "parent")
+        want = {"r0": 6, "r1": 14, "r2": 1, "r3": 9, "r4": 3, "r5": 5}
+        assert set(now) == set(want)
+        for rid, n in want.items():
+            evs = now[rid]
+            assert "".join(e[0] for e in evs) == "A" + "b" * (n - 1), rid
+            assert [e[3] for e in evs] == [False] * (len(evs) - 1) + [True]
+            assert evs[-1][1:5] == (n, n, True, "length"), rid
+            assert evs[-1][6], f"{rid}: no costs block on the terminal"
+            gens = [e[1] for e in evs]
+            assert gens == sorted(set(gens)), rid
+
+    @pytest.mark.parametrize("engine_cls", [AsyncFakeEngine, SpecFakeEngine],
+                             ids=["plain", "speculative"])
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_nothing_is_buffered_when_a_wait_begins(self, depth, engine_cls):
+        """The real loop: at the start of every wait on an in-flight
+        entry — the block read, the admissions behind it, and each entry
+        of the speculative drain — the engine thread holds no event of
+        the entries before it."""
+        eng = engine_cls(slots=4, prefill_wall=0.005)
+        sched = Scheduler(eng, pipeline_depth=depth)
+        held: list[tuple] = []
+
+        def on_wait(name):
+            n = len(sched._pending_events) + len(sched._block_jobs)
+            if n:
+                held.append((name, n))
+
+        eng.on_wait = on_wait
+        got = run_to_completion(
+            sched, [b"r0", b"r1", b"r2", b"r3", b"r4", b"r5"], max_new=30)
+        assert not held, f"events held across a wait: {held[:5]}"
+        for rid, evs in got.items():
+            assert evs[-1].finish_reason == "length", rid
+            assert "".join(ev.text for ev in evs) == "A" + "b" * 29
+        log = [(kind, name) for kind, name in eng.log if kind != "wait"]
+        st = sched.stats()
+        if engine_cls is SpecFakeEngine:
+            assert st["speculative"]["verify_blocks"] == eng.verifies > 0
+            # the drain ran over more than one entry at least once: two
+            # reads in a row before a verify dispatch
+            assert any(
+                log[i][0] == log[i + 1][0] == "read"
+                and log[i + 2] == ("dispatch", log[i + 2][1])
+                and log[i + 2][1].startswith("V")
+                for i in range(len(log) - 2)), log[:40]
+        else:
+            # steady state reads B(k) with P(k) behind it
+            assert st["flush_ahead"]["blocks"] >= 1
+            assert 0 < st["flush_ahead"]["lead_s"] <= st["admit"]["wait_s"]
 
 
 class TestEstimatedSecondsBudget:

@@ -113,6 +113,35 @@ class TestBatchedBlockEmit:
         # tokens counts EMITTED tokens: 3 activation firsts + 24 block
         assert sched.metrics["tokens"] == 27
 
+    def test_an_entry_read_through_the_queue_leaves_at_its_read(self):
+        """The loop's path (PR 38): `_process_pending` hands on what the
+        entry buffered, so a block read off the in-flight queue is ONE
+        flush with no call of the caller's — and the flush the loop still
+        makes after its admission pass finds nothing and counts nothing.
+        (`test_one_flush_per_block_for_all_slots` drives `_process_block`
+        below that seam, so its count of 2 stands.)"""
+        import time
+
+        eng = FakeEngine(slots=4, block=8)
+        sched, batches = make_scheduler(eng)
+        for rid in (b"r0", b"r1", b"r2"):
+            submit(sched, rid)
+        sched._admit_new()
+        sched._read_admissions()
+        assert len(batches) == 1 and len(batches[0]) == 3
+        assert sched.metrics["emit_flushes"] == 1
+        toks = np.full((8, 4), ord("b"), dtype=np.int32)
+        sched._process_pending(("decode_block", toks, dict(sched._slots),
+                                time.monotonic(), None))
+        assert len(batches) == 2 and len(batches[1]) == 3
+        assert not sched._pending_events
+        sched._flush_events()
+        assert len(batches) == 2
+        assert sched.metrics["emit_flushes"] == 2
+        assert sched.metrics["emit_events"] == 6
+        # no admission sat behind that block: nothing left "ahead"
+        assert sched.stats()["flush_ahead"] == {"blocks": 0, "lead_s": 0.0}
+
     def test_eos_mid_block_finishes_and_discards_remainder(self):
         eng = FakeEngine(slots=2, block=8)
         sched, batches = make_scheduler(eng)

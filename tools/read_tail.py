@@ -5,8 +5,12 @@
 `<dump.json>` is what `benchmarks/run.py --dump <dir>` writes (the window,
 the client records and the `stats` samples, so every read record of the
 window: `benchmarks/readers/tail.py`). Prints one JSON line: the tail
-metrics, the tail's intervals record by record, how many of them `split`
-had to cut, admission entries read per interval (the histogram
+metrics, the clients' gap p99 beside what explains it — the plain interval
+p99 with `flush_ahead`'s growth over the window (blocks whose events left
+ahead of an admission, and the seconds of those admissions' waits) or, for
+a dump of a tree before PR 38, the p99 of the rebuilt "block read -> first
+admission read" intervals — the tail's intervals record by record, how
+many of them `split` had to cut, admission entries read per interval (the histogram
 `tools/per_block` took at dispatch until PR 37; a chunked prompt counts
 once here, at its final chunk), prefill tokens per device second by
 program shape, and every stall record with the harness's own poll gap
@@ -27,9 +31,10 @@ from types import SimpleNamespace
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
+from lib import window  # noqa: E402
 from lib.xplane import DEVICE_PLANE, MODULES_LINE, find_xplane  # noqa: E402
-from readers import tail  # noqa: E402
-from readers.stats import _dig  # noqa: E402
+from readers import client, tail  # noqa: E402
+from readers.stats import _dig, counter_share  # noqa: E402
 
 SYNC = "sym.sched.sync"
 
@@ -117,6 +122,42 @@ def prefill_rates(recs: list[dict], w0: float, w1: float) -> dict[str, dict]:
     return out
 
 
+def leave_p99_s(recs: list[dict], ivs: list[dict]) -> float | None:
+    """99th percentile of the intervals between the moments two successive
+    blocks' events LEFT the engine thread on a tree before PR 38, whose
+    loop flushed a block's events only after the first admission read
+    behind it: that admission's read stamp, or the block's own with none
+    behind it. There this, and not the plain interval, met the clients'
+    gap p99 (PERF.md §6, PR 37)."""
+    by_seq = {r["seq"]: r for r in recs}
+
+    def left(block: dict) -> float:
+        nxt = by_seq.get(block["seq"] + 1)
+        return (nxt["t"] if nxt is not None
+                and nxt["kind"] not in tail.BLOCKS else block["t"])
+
+    spans = [left(iv["block"]) - left(by_seq[iv["block"]["caused_by"]])
+             for iv in ivs]
+    return window.percentile(spans, 99) if spans else None
+
+
+def flush_ahead(ctx) -> dict | None:
+    """Growth of `stats.engine.flush_ahead` over the window's samples
+    (PR 38: blocks whose events left with an admission unread behind
+    them, and the seconds of those admissions' waits; `lead_share` is the
+    `flush_lead_share` metric); None for a dump of an older tree."""
+    (_t0, first), (_t1, last) = ctx.phase.samples[0], ctx.phase.samples[-1]
+    a, b = (_dig(s, "engine.flush_ahead") for s in (first, last))
+    if a is None or b is None:
+        return None
+    blocks = b["blocks"] - a["blocks"]
+    lead_s = b["lead_s"] - a["lead_s"]
+    share = counter_share(ctx, "engine.flush_ahead.lead_s")
+    return {"blocks": blocks, "lead_s": round(lead_s, 6),
+            "lead_mean_s": round(lead_s / blocks, 6) if blocks else None,
+            "lead_share": None if share is None else round(share, 3)}
+
+
 def report(dump: dict, capture: str | None = None) -> dict:
     stats = [s for _t, s in dump["samples"]]
     ctx = SimpleNamespace(phase=SimpleNamespace(
@@ -147,6 +188,16 @@ def report(dump: dict, capture: str | None = None) -> dict:
         return out
     ivs = tail.intervals(recs, dump["w0"], dump["w1"])
     out["intervals"] = len(ivs)
+    # What the clients' gap p99 is held against: since PR 38 a block's
+    # events leave at its read, so the plain interval, beside the waits
+    # they were spared; before, the rebuilt "block read -> first
+    # admission read" intervals.
+    out["client_gap_p99_s"] = client.gap_percentile_s(ctx, 99)
+    ahead = flush_ahead(ctx)
+    if ahead is not None:
+        out["flush_ahead"] = ahead
+    else:
+        out["leave_p99_s"] = leave_p99_s(recs, ivs)
     hist: dict[int, int] = {}
     for iv in ivs:
         n = len(iv["admissions"])

@@ -53,9 +53,9 @@ COLUMNS = ("PROVIDER", "TIER", "TOK/S", "TTFT p50", "TTFT p99",
            "QUEUE", "INFL", "OCC", "DEPTH", "SHED", "RESUME",
            "WASTED", "REUSED", "DUMPS", "COST", "WASTE%", "GPUT",
            "LINK", "STATE", "SHARE", "HIT", "TARGET", "SCALE",
-           "STALLS", "TAIL")
+           "AHEAD", "STALLS", "TAIL")
 WIDTHS = (22, 10, 9, 9, 9, 7, 6, 5, 5, 7, 7, 7, 7, 6, 7, 6, 7, 6,
-          9, 6, 6, 9, 6, 9, 6)
+          9, 6, 6, 9, 6, 11, 9, 6)
 
 # sym_pool_member_state gauge encoding (engine/disagg/pool.py
 # STATE_CODES) rendered back to the membership lifecycle names.
@@ -230,9 +230,13 @@ def _pool_rows(name: str, fams: dict) -> list[dict[str, Any]]:
 # ------------------------------------------------------------- row model
 
 
-def read_tail(engine: dict | None) -> tuple[str | None, float | None]:
-    """The engine host's stall and read records (the stats reply's
-    `engine` block; a wire poll has it, a Prometheus scrape does not):
+def read_tail(engine: dict | None
+              ) -> tuple[str | None, str | None, float | None]:
+    """The engine host's flush-ahead counter, stall and read records (the
+    stats reply's `engine` block; a wire poll has it, a Prometheus scrape
+    does not): AHEAD = decode blocks whose events left with an admission
+    still unread behind them / the seconds of those admissions' waits
+    (what the clients no longer wait; lifetime totals);
     STALLS = count / longest excess in seconds; TAIL = 99th percentile of
     the decode-block read-to-read intervals among the last 64 reads —
     the engine's side of the clients' inter-chunk gap p99. One reply's
@@ -241,16 +245,19 @@ def read_tail(engine: dict | None) -> tuple[str | None, float | None]:
     `readers/tail.py intervals` unions many replies and checks `seq` too."""
     engine = engine or {}
     stalls, reads = engine.get("stalls"), engine.get("reads")
+    ahead = engine.get("flush_ahead")
+    ahead_cell = (None if not ahead else
+                  f"{ahead['blocks']}/{ahead['lead_s']:.1f}")
     stall_cell = (None if not stalls else
                   f"{stalls['count']}/{stalls['longest_s']:.1f}")
     if not reads:
-        return stall_cell, None
+        return ahead_cell, stall_cell, None
     recs = [dict(zip(reads["fields"], row)) for row in reads["recent"]]
     blocks = [r for r in recs if r["kind"] in ("decode_block", "verify")]
     ivs = sorted(b["t"] - a["t"] for a, b in zip(blocks, blocks[1:])
                  if b["caused_by"] == a["seq"])
     tail = ivs[math.ceil(0.99 * len(ivs)) - 1] if ivs else None
-    return stall_cell, tail
+    return ahead_cell, stall_cell, tail
 
 
 def build_rows(name: str, fams: dict, prev: dict | None, now: float,
@@ -258,7 +265,7 @@ def build_rows(name: str, fams: dict, prev: dict | None, now: float,
     """One provider-level row plus one sub-row per engine tier. `prev`
     is the previous poll's {"t", "tok", "shed"} for rate deltas;
     `engine` the stats reply's engine block, where the poll has one."""
-    stall_cell, tail = read_tail(engine)
+    ahead_cell, stall_cell, tail = read_tail(engine)
     tok = _value(fams, "sym_provider_tokens_out_total", 0.0)
     shed = _value(fams, "sym_provider_sheds_total", 0.0)
     cost_total, cost_n = _ledger_cost(fams)
@@ -333,7 +340,7 @@ def build_rows(name: str, fams: dict, prev: dict | None, now: float,
         "link": (None if link is None else ("up" if link else "DOWN")),
         "state": None, "share": None,
         "target": target, "scale": scale_disp,
-        "stalls": stall_cell, "tail": tail,
+        "ahead": ahead_cell, "stalls": stall_cell, "tail": tail,
         "_sample": {"t": now, "tok": tok, "shed": shed or 0.0,
                     "dec": decisions or 0.0},
     }]
@@ -399,7 +406,8 @@ def render_table(rows: list[dict[str, Any]]) -> str:
                  r["link"] or "-",
                  r.get("state") or "-", r.get("share") or "-",
                  r.get("hit"), r.get("target") or "-", r.get("scale"),
-                 r.get("stalls") or "-", r.get("tail"))
+                 r.get("ahead") or "-", r.get("stalls") or "-",
+                 r.get("tail"))
         out.append("  ".join(_fmt_cell(c, w)
                              for c, w in zip(cells, WIDTHS)))
     return "\n".join(out)
