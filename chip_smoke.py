@@ -49,6 +49,11 @@ contends for the chip its child needs.
         # and each program's form — Mamba-2's decode step must read
         # `pallas`, the Gated DeltaNet's is jnp so far — and `moe`, whose
         # `grouped_matmul` must read `pallas` on one chip: ops/gmm.py)
+    python chip_smoke.py --preset keye-vl-2.0-30b-a3b
+        # learned sparse attention on one chip (a lightning indexer with a
+        # key cache of its own; the report's `attention.sparse` carries
+        # topk, the form of each program — the smoke fails without one —
+        # and the index cache's bytes), 128 experts top 8 (`moe`)
     JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny
         # CPU dry run of every phase; ends non-zero: "platform is cpu"
 """
@@ -71,6 +76,11 @@ SLOTS, MAX_SEQ, BUCKET, BLOCK, MAX_NEW = 8, 4096, 128, 16, 64
 START_TIMEOUT_S = 900.0   # build + cold compile of every served program
 SERVE_TIMEOUT_S = 150.0   # all ten requests and the stats read
 DRAIN_TIMEOUT_S = 120.0
+
+
+# presets whose attention runs under a learned selection (by name: this
+# process may not import jax to ask)
+SPARSE_PRESETS = ("keye-vl-2.0-30b-a3b", "tiny-dsa")
 
 
 class SmokeFailure(Exception):
@@ -305,6 +315,11 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
             "pallas", "pallas"):
         failures.append(f"attention did not run compiled Pallas kernels "
                         f"in both programs: {attention}")
+    sparse = attention.get("sparse") or {}
+    if (cfg["tpu"]["model_preset"] in SPARSE_PRESETS
+            and not (sparse.get("form") or {}).get("decode")):
+        failures.append(f"a model with learned sparse attention reported "
+                        f"no sparse form: {attention}")
     ssm = startup.get("ssm") or {}
     ssm_decode = ssm.get("decode")
     # Mamba-2's step has a kernel (ops/ssm_step.py); the Gated DeltaNet's

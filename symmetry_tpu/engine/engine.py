@@ -233,6 +233,23 @@ class InferenceEngine:
                 prefill_chunk=prefill_chunk)
             if refused:
                 raise EngineError(refused[0])
+        # Learned sparse attention keeps an index key a cached position
+        # (models/llama.py KVCache.idx): the same rule.
+        self._sparse = getattr(config, "sparse", None)
+        if self._sparse is not None:
+            from symmetry_tpu.models.llama import sparse_refusals
+
+            refused = sparse_refusals(
+                mesh=mesh is not None, role=role,
+                prefix_cache=prefix_cache_bytes > 0,
+                speculative=speculative is not None,
+                prefill_chunk=prefill_chunk)
+            if refused:
+                raise EngineError(refused[0])
+        # since start, as of the last synced decode block (stats.engine.dsa)
+        self.dsa = (None if self._sparse is None else
+                    {"queries": 0, "candidates": 0, "selected": 0,
+                     "dense_queries": 0})
         self.ssm_counters = {"prefill_tokens": 0, "state_installs": 0}
         # W8A16 fused-dequant routing (tpu.fused_dequant): pack the int8
         # weight leaves into the Pallas kernel's tile layout ONCE, here —
@@ -554,6 +571,9 @@ class InferenceEngine:
                 **({"ssm": place(state.cache.ssm, prefix.ssm),
                     "conv": place(state.cache.conv, prefix.conv, axis=2)}
                    if state.cache.ssm is not None else {}),
+                # a sparse-attention row brings its index keys along
+                **({"idx": place(state.cache.idx, prefix.idx)}
+                   if state.cache.idx is not None else {}),
             )
             return DecodeState(
                 cache=cache,
@@ -1296,11 +1316,21 @@ class InferenceEngine:
         n_layers = (len(c.layers_of(c.attention_kind)) if self._has_state
                     else c.num_layers)
         per_plane = n_layers * c.num_kv_heads
+        # sparse attention: one index key a layer and position, unquantized
+        index = self.index_bytes_per_token()
         if self.kv_quant:
             # int8 payload + one f32 scale per (layer, head, position)
-            return 2 * per_plane * (c.dim_per_head + 4)
+            return 2 * per_plane * (c.dim_per_head + 4) + index
         return 2 * per_plane * c.dim_per_head * jnp.dtype(
-            self.cache_dtype).itemsize
+            self.cache_dtype).itemsize + index
+
+    def index_bytes_per_token(self) -> int:
+        """Bytes of index keys one token position occupies (learned sparse
+        attention; 0 for every other model)."""
+        if self._sparse is None:
+            return 0
+        return (self.config.num_layers * self._sparse.index_head_dim
+                * jnp.dtype(self.cache_dtype).itemsize)
 
     def state_bytes_per_slot(self) -> int:
         """Bytes a slot holds that are not rows per position: the
@@ -1999,6 +2029,12 @@ class InferenceEngine:
             block = np.asarray(self._pairs_pending.popleft())
             self.expert_pairs = [a + int(b) for a, b in
                                  zip(self.expert_pairs, block)]
+            if self._sparse is not None:
+                from symmetry_tpu.ops.sparse_attention import (
+                    N_COUNTS, read_counts)
+
+                for name, n in read_counts(block[-N_COUNTS:]).items():
+                    self.dsa[name] += n
 
     def moe_report(self) -> dict | None:
         """`startup.moe`: where the expert weights live and which form
@@ -2064,13 +2100,25 @@ class InferenceEngine:
         """The attention implementation the served prefill and decode
         programs took (models/llama.py attention_paths: the routing
         itself, asked with this engine's geometry)."""
-        from symmetry_tpu.models.llama import attention_paths
+        from symmetry_tpu.models.llama import attention_paths, sparse_forms
 
-        return attention_paths(
+        paths = attention_paths(
             self.config, self.max_seq_len, self.mesh,
             batch=self.max_slots,
             kv_bytes=jnp.dtype(jnp.int8 if self.kv_quant
                                else self.cache_dtype).itemsize)
+        if self._sparse is not None:
+            # the selection each program's attention runs under, and what
+            # the indexer's own cache costs
+            per_token = self.index_bytes_per_token()
+            paths["sparse"] = {
+                "topk": self._sparse.topk,
+                "index_heads": self._sparse.index_heads,
+                "form": sparse_forms(paths),
+                "index_bytes_per_token": per_token,
+                "index_cache_bytes": (per_token * self.max_slots
+                                      * self.max_seq_len)}
+        return paths
 
     def sampling_route(self) -> dict:
         """How every sampling call of the served programs selects its

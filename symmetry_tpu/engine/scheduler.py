@@ -654,6 +654,10 @@ class Scheduler:
             counts = list(self.engine.expert_pairs)
             out["moe"] = {"pairs": sum(counts), "expert_pairs": counts,
                           "route": self.engine.moe_report()["route"]}
+        if getattr(self.engine, "dsa", None) is not None:
+            # learned sparse attention: queries, the positions they could
+            # select from and those they selected, as of the same block
+            out["dsa"] = dict(self.engine.dsa)
         # Gauges for the two admission backlogs that were invisible in
         # host→provider stats: the budget-deferred deque and the
         # chunked-prefill jobs still building their prefixes.
@@ -1719,7 +1723,7 @@ class Scheduler:
                         "engine.prefill",
                         ring="adopt_dispatch" if adopting
                         else "prefill_dispatch",
-                        n=len(sub), cached=hit is not None):
+                        n=len(sub), cached=hit is not None, bucket=bucket):
                     m0 = self._mark()
                     toks = self._dispatch_prefill(sub, hit)
                     self._dispatch_returned(

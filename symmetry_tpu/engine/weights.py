@@ -31,11 +31,12 @@ import numpy as np
 from symmetry_tpu.models.llama import (
     HF_EXPERT_MAP,
     HF_LAYER_MAP,
-    HF_MOE_ROUTER,
     HF_TOP_MAP,
     ModelConfig,
     config_from_hf,
     hf_expert_name,
+    hf_moe_names,
+    optional_layer_params,
     init_params,
     param_logical_axes,
 )
@@ -66,11 +67,14 @@ def convert_hf_state_dict(
                       == "linear_attention" else "granitemoehybrid")
             raise CheckpointError(f"{family} checkpoint: {exc}")
     n_exp = getattr(config, "num_experts", 0)
+    router_name, experts_module, expert_map = hf_moe_names(config)
+    absent = optional_layer_params(config)
     per_layer: dict[str, list] = {
         ours: [None] * config.num_layers
         for ours, _ in HF_LAYER_MAP.values()
-        # bias params exist only for attention_bias (qwen2) configs
-        if config.attention_bias or ours not in ("bq", "bk", "bv")}
+        # bias params exist only for attention_bias (qwen2) configs, q/k
+        # norms and the indexer only for a config that has them
+        if ours not in absent}
     if n_exp:
         # MoE FFN params come per (layer, expert); stack experts inside
         # each layer. The dense FFN names are absent in mixtral files.
@@ -87,20 +91,21 @@ def convert_hf_state_dict(
             rest = name[len("model.layers."):]
             idx_str, _, sub = rest.partition(".")
             layer = int(idx_str)
-            if n_exp and sub == HF_MOE_ROUTER:
+            if n_exp and sub == router_name:
                 per_layer["router"][layer] = arr.T
-            elif n_exp and sub.startswith("block_sparse_moe.experts."):
+            elif n_exp and sub.startswith(experts_module + "."):
                 parts = sub.split(".")       # experts . <e> . w1 . weight
                 expert, w = int(parts[2]), parts[3]
-                if w not in HF_EXPERT_MAP:
+                if w not in expert_map:
                     raise CheckpointError(f"unmapped HF tensor {name!r}")
-                per_layer[HF_EXPERT_MAP[w]][layer][expert] = arr.T
+                per_layer[expert_map[w]][layer][expert] = arr.T
             elif sub in HF_LAYER_MAP:
                 ours, transpose = HF_LAYER_MAP[sub]
                 if ours not in per_layer:
                     raise CheckpointError(
                         f"checkpoint has {name!r} but the config does not "
-                        f"enable attention_bias")
+                        f"enable " + ("attention_bias" if ours[0] == "b"
+                                      else f"what {ours!r} belongs to"))
                 per_layer[ours][layer] = arr.T if transpose else arr
             else:
                 raise CheckpointError(f"unmapped HF tensor {name!r}")
@@ -265,6 +270,7 @@ def load_checkpoint(
         return read
 
     n_exp = getattr(config, "num_experts", 0)
+    router_name = hf_moe_names(config)[0]
 
     def layer_reader(ours: str) -> Callable:
         if n_exp and ours == "router":
@@ -272,7 +278,7 @@ def load_checkpoint(
                 l_sl, *rest = _norm_index(index, 3)
                 layers = range(*l_sl.indices(config.num_layers))
                 per = [store.read_slice(
-                    f"model.layers.{l}.{HF_MOE_ROUTER}", tuple(rest), True)
+                    f"model.layers.{l}.{router_name}", tuple(rest), True)
                     for l in layers]
                 return np.stack(per).astype(dtype)
 
@@ -284,7 +290,7 @@ def load_checkpoint(
                 layers = range(*l_sl.indices(config.num_layers))
                 experts = range(*x_sl.indices(n_exp))
                 per = [np.stack([store.read_slice(
-                    hf_expert_name(l, e, ours), tuple(rest), True)
+                    hf_expert_name(l, e, ours, config), tuple(rest), True)
                     for e in experts]) for l in layers]
                 return np.stack(per).astype(dtype)
 
@@ -353,17 +359,18 @@ def save_checkpoint(path: str, params: dict, config: ModelConfig) -> None:
         arr = np.asarray(jax.device_get(params[ours]), dtype=np.float32)
         tensors[hf_name] = np.ascontiguousarray(arr.T) if transpose else arr
     n_exp = getattr(config, "num_experts", 0)
+    router_name = hf_moe_names(config)[0]
     for ours, stacked in params["layers"].items():
         host = np.asarray(jax.device_get(stacked), dtype=np.float32)
         if n_exp and ours == "router":
             for l in range(host.shape[0]):
-                tensors[f"model.layers.{l}.{HF_MOE_ROUTER}"] = (
+                tensors[f"model.layers.{l}.{router_name}"] = (
                     np.ascontiguousarray(host[l].T))
             continue
         if n_exp and ours in ("wg", "wu", "wd"):
             for l in range(host.shape[0]):
                 for e in range(host.shape[1]):
-                    tensors[hf_expert_name(l, e, ours)] = (
+                    tensors[hf_expert_name(l, e, ours, config)] = (
                         np.ascontiguousarray(host[l, e].T))
             continue
         hf_sub, transpose = {v[0]: (k, v[1]) for k, v in HF_LAYER_MAP.items()}[ours]
@@ -393,6 +400,10 @@ def save_checkpoint(path: str, params: dict, config: ModelConfig) -> None:
     if n_exp:
         hf_cfg["num_local_experts"] = n_exp
         hf_cfg["num_experts_per_tok"] = config.num_experts_per_tok
+    if getattr(config, "sparse", None) is not None:
+        from symmetry_tpu.models.llama import hf_config_sparse
+
+        hf_cfg = hf_config_sparse(config)
     with open(os.path.join(path, "config.json"), "w", encoding="utf-8") as fh:
         json.dump(hf_cfg, fh, indent=2)
 

@@ -71,6 +71,18 @@ class ModelConfig:
 
 
 @dataclass(frozen=True)
+class SparseAttention:
+    """Learned sparse attention (HF `sa_config`, DeepSeek-Sparse-Attention's
+    lightning indexer; ops/sparse_attention.py): `index_heads` index query
+    heads of `index_head_dim` score every cached position's ONE shared index
+    key, and a query attends over its `topk` best positions."""
+
+    topk: int
+    index_heads: int
+    index_head_dim: int
+
+
+@dataclass(frozen=True)
 class MoEConfig(ModelConfig):
     """Mixture-of-experts variant (mixtral family): the MLP becomes
     num_experts parallel FFNs with top-k routing (models/moe.py)."""
@@ -80,6 +92,25 @@ class MoEConfig(ModelConfig):
     # width of a shared expert every token passes through beside its
     # routed ones (0: none); the routed experts' width is intermediate_size
     shared_intermediate_size: int = 0
+    # KeyeVL2 family, each at "absent" for every other model: per-head
+    # RMSNorm on q and k (the Qwen3-MoE block), the frequency pairs each
+    # component of a multimodal rotary turns (ops/rope.py), and the sparse
+    # attention's sizes (a third cache leaf, `KVCache.idx`, goes with them)
+    qk_norm: bool = False
+    mrope_section: tuple[int, ...] | None = None
+    sparse: SparseAttention | None = None
+    # whose tensor names the expert block has in an HF checkpoint
+    # (`hf_moe_names`): "mixtral" (`block_sparse_moe`) or "qwen3_moe"
+    # (`mlp.gate`, `mlp.experts`)
+    hf_block: str = "mixtral"
+    # random init draws a stacked leaf at fan_in ** -0.5 (models/hybrid.py's
+    # scale). False keeps the older presets' layers ** -0.5 — the leading
+    # axis of a stacked leaf — whose cells' weights may not move; at 4
+    # layers that is 0.5 an entry: router logits of deviation 23, so a
+    # softmax that is one expert, and expert outputs 100x the attention's
+    # (PERF.md PR 40: bfloat16 rounding alone then flips one token in a
+    # hundred to an unrelated logit row)
+    init_fan_in: bool = False
 
 
 @dataclass(frozen=True)
@@ -187,6 +218,36 @@ PRESETS: dict[str, ModelConfig] = {
         vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
         num_kv_heads=4, intermediate_size=128, rope_theta=10000.0,
         max_position=512, num_experts=8, num_experts_per_tok=2,
+    ),
+    # the CPU's copy of Keye-VL-2.0's mechanisms: a lightning indexer whose
+    # topk is SHORTER than the test prompts, GQA with q/k norms, a rotary of
+    # three position components, 8 experts top 2, an untied head
+    "tiny-dsa": MoEConfig(
+        vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
+        num_kv_heads=2, intermediate_size=32, head_dim=16,
+        rope_theta=10000.0, rms_eps=1e-6, max_position=512,
+        num_experts=8, num_experts_per_tok=2, qk_norm=True,
+        mrope_section=(2, 3, 3),
+        sparse=SparseAttention(topk=16, index_heads=4, index_head_dim=8),
+        hf_block="qwen3_moe", init_fan_in=True,
+    ),
+    # Keye-VL-2.0-30B-A3B's language model CUT IN DEPTH to 4 of its 48
+    # identical layers at every published width, with all 128 experts and
+    # the whole vocabulary: stage 1 of 12 of a pipeline, what one 16 GB
+    # chip holds in int8 beside a 64 x 16,384 cache (benchmarks/configs/
+    # keye-vl-2.0-30b-a3b.json has the cut). `intermediate_size` is the
+    # routed expert's width (`moe_intermediate_size`); the published dense
+    # width 6144 serves no layer. The vision tower is left out: the served
+    # path feeds three equal position components.
+    "keye-vl-2.0-30b-a3b": MoEConfig(
+        vocab_size=151936, hidden_size=2048, num_layers=4, num_heads=32,
+        num_kv_heads=4, intermediate_size=768, head_dim=128,
+        rope_theta=10000000.0, rms_eps=1e-6, max_position=262144,
+        num_experts=128, num_experts_per_tok=8, qk_norm=True,
+        mrope_section=(16, 24, 24),
+        sparse=SparseAttention(topk=2048, index_heads=16,
+                               index_head_dim=64),
+        hf_block="qwen3_moe", init_fan_in=True,
     ),
     "mixtral-8x7b": MoEConfig(
         vocab_size=32000, hidden_size=4096, num_layers=32, num_heads=32,
@@ -329,6 +390,16 @@ class KVCache(NamedTuple):
     # alone, in pattern order.
     ssm: jnp.ndarray | None = None
     conv: jnp.ndarray | None = None
+    # Models with learned sparse attention only (config.sparse; None, so no
+    # leaf, for every other): the indexer's roped key of each cached
+    # position, idx [L, B, T, index_head_dim] in the compute dtype. Such a
+    # model's `expert_pairs` ends in ops/sparse_attention.py's N_COUNTS
+    # counters (queries, dense queries, candidates, selected): they are
+    # zeroed, merged at an insert and handed out exactly where the expert
+    # counts are (engine/engine.py: eight sites in five programs), so they
+    # ride that vector instead of a leaf that would twin each site; the
+    # engine alone splits them off (`collect_expert_pairs`).
+    idx: jnp.ndarray | None = None
 
     @property
     def quantized(self) -> bool:
@@ -349,6 +420,15 @@ def init_cache(
              config.dim_per_head)
     pairs = (jnp.zeros((config.num_experts,), jnp.int32)
              if count_experts else None)
+    extra = {}
+    sparse = getattr(config, "sparse", None)
+    if sparse is not None:
+        from symmetry_tpu.ops.sparse_attention import N_COUNTS
+
+        extra["idx"] = jnp.zeros((config.num_layers, batch, capacity,
+                                  sparse.index_head_dim), dtype)
+        if count_experts:
+            pairs = jnp.zeros((config.num_experts + N_COUNTS,), jnp.int32)
     if quantized:
         scale_shape = (config.num_layers, batch, config.num_kv_heads,
                        capacity)
@@ -358,18 +438,19 @@ def init_cache(
             lengths=jnp.zeros((batch,), jnp.int32),
             k_scale=jnp.zeros(scale_shape, jnp.float32),
             v_scale=jnp.zeros(scale_shape, jnp.float32),
-            expert_pairs=pairs,
+            expert_pairs=pairs, **extra,
         )
     return KVCache(
         k=jnp.zeros(shape, dtype),
         v=jnp.zeros(shape, dtype),
         lengths=jnp.zeros((batch,), jnp.int32),
-        expert_pairs=pairs,
+        expert_pairs=pairs, **extra,
     )
 
 
 def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
-             k: jnp.ndarray, v: jnp.ndarray, *, by_head: bool) -> KVCache:
+             k: jnp.ndarray, v: jnp.ndarray, *, by_head: bool,
+             idx: jnp.ndarray | None = None) -> KVCache:
     """Scatter this call's K/V ([B, S, K, D], roped) straight into the full
     cache at (layer, slot, position) — an in-place row write on the layer
     scan's carry; a per-layer slice-out/slice-in would stream the whole
@@ -377,6 +458,8 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     the capacity is dropped. Padded tail tokens write garbage past the
     slot's valid length — never read, overwritten later. A quantized cache
     takes the int8 payload plus the f32 scales (ops/quant.py quantize_kv).
+    `idx` ([B, S, index_head_dim]; sparse attention) goes into `cache.idx`
+    at the same rows.
 
     `by_head` (the trunk is sharded over a mesh): a cache sharded by KV
     head may hold 2 heads a chip, and XLA then keeps it in HBM as
@@ -390,6 +473,9 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
     l_idx = jnp.full((B, S), layer, jnp.int32)
     row = (l_idx, b_idx, positions)  # each write is one [K, D] row
+    if idx is not None:
+        cache = cache._replace(
+            idx=cache.idx.at[row].set(idx.astype(cache.idx.dtype)))
     if by_head:
         row = tuple(i[..., None] for i in row) + (
             jnp.arange(nkv, dtype=jnp.int32)[None, None, :],)
@@ -445,8 +531,10 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     if slice_above is None:
         slice_above = default_leaf_limit()
 
+    fan_axis = -2 if getattr(c, "init_fan_in", False) else 0
+
     def dense(k, shape, scale=None, name=None):
-        scale = scale if scale is not None else shape[0] ** -0.5
+        scale = scale if scale is not None else shape[fan_axis] ** -0.5
         quantized = quantize and name in QUANT_KEYS
         # Only the stacked per-layer leaves have a layers axis to slice.
         where = (shardings or {}).get("layers", {}).get(name)
@@ -488,6 +576,22 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     if not c.tie_embeddings:
         params["lm_head"] = dense(next(keys), (E, c.vocab_size), scale=0.02,
                                   name="lm_head")
+    # (drawn after every key above, so no other model's weights move)
+    if getattr(c, "qk_norm", False):
+        params["layers"]["q_norm"] = jnp.ones((L, c.dim_per_head), dtype)
+        params["layers"]["k_norm"] = jnp.ones((L, c.dim_per_head), dtype)
+    sparse = getattr(c, "sparse", None)
+    if sparse is not None:
+        # the lightning indexer: index queries, the one shared index key,
+        # and the per-head weights (bf16 like the router: 16 columns that
+        # feed a selection)
+        params["layers"]["wqi"] = dense(
+            next(keys), (L, E, sparse.index_heads * sparse.index_head_dim),
+            name="wqi")
+        params["layers"]["wki"] = dense(
+            next(keys), (L, E, sparse.index_head_dim), name="wki")
+        params["layers"]["wwi"] = dense(next(keys),
+                                        (L, E, sparse.index_heads))
     return params
 
 
@@ -525,6 +629,12 @@ def param_logical_axes(config: ModelConfig) -> dict:
         axes["layers"]["bv"] = ("layers", "kv_heads")
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
+    if getattr(config, "qk_norm", False):
+        axes["layers"]["q_norm"] = ("layers", None)
+        axes["layers"]["k_norm"] = ("layers", None)
+    if getattr(config, "sparse", None) is not None:
+        for name in ("wqi", "wki", "wwi"):
+            axes["layers"][name] = ("layers", "embed", None)
     return axes
 
 
@@ -560,7 +670,11 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
         keeps the XLA path — and decode takes the kernel only from
         TP_MIN_CAPACITY up: below it a sharded trunk stays on the XLA
         path (2 KV heads a chip lie head-major there and no slice is
-        staged: PERF.md PR 28) until a four-chip A/B says otherwise."""
+        staged: PERF.md PR 28) until a four-chip A/B says otherwise;
+      - learned sparse attention (`config.sparse`): the decode kernel
+        takes a selection only beside the scale planes of an int8 cache of
+        interleaved heads (ops/decode_attention.py keep_supported); any
+        other cache decodes on the XLA path."""
     from symmetry_tpu.ops import decode_attention as da
 
     kernel = "pallas-interpret" if interpret_mode() else "pallas"
@@ -574,8 +688,61 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
     if tiles is None or (tp_mesh is not None
                          and capacity < da.TP_MIN_CAPACITY):
         return {"prefill": kernel, "decode": "xla"}
+    if getattr(config, "sparse", None) is not None and not da.keep_supported(
+            config.num_kv_heads, kv_bytes, quantized=kv_bytes == 1):
+        return {"prefill": kernel, "decode": "xla"}
     return {"prefill": kernel, "decode": kernel,
             "decode_slot_tile": tiles[0], "decode_block_t": tiles[1]}
+
+
+def sparse_forms(paths: dict) -> dict:
+    """What each program's attention is under a learned selection, from
+    `attention_paths`' routes — every one the lossless MASKED form (each
+    live block is read, what the query left out is set to -inf); the
+    gather form (read the selected rows alone) is not built."""
+    def form(route, kernel):
+        return f"masked ({kernel if route != 'xla' else 'xla'})"
+
+    return {"prefill": form(paths["prefill"], "dsa_flash kernel"),
+            "decode": form(paths["decode"], "decode kernel")}
+
+
+def sparse_refusals(*, mesh: bool = False, role: str = "unified",
+                    prefix_cache: bool = False, speculative: bool = False,
+                    prefill_chunk: int | None = None) -> list[str]:
+    """Why a model with learned sparse attention (an index key a cached
+    position beside its K/V row) cannot be served under these settings:
+    one sentence a setting, empty when it can. The engine raises the first
+    as an EngineError; provider/config.py asks the same of a preset before
+    anything is built and raises it as a ConfigError."""
+    why = []
+    if prefix_cache:
+        why.append(
+            "tpu.prefix_cache_mb: the block pool knows one entry shape (K, "
+            "V and their scales) and would hand a hit back without its "
+            "index keys — leave it unset for a model with sparse attention")
+    if speculative:
+        why.append(
+            "tpu.speculative: the verify program over a selection is not "
+            "shown to pick what the single-position steps pick — leave it "
+            "unset for a model with sparse attention")
+    if prefill_chunk is not None:
+        why.append(
+            f"tpu.prefill_chunk {prefill_chunk}: a chunk over a non-empty "
+            f"cache selects on the XLA path alone ([B, S, T] scores and the "
+            f"threshold's passes through HBM), which no chip run has driven "
+            f"at long contexts — set prefill_chunk: null for a model with "
+            f"sparse attention (prompts prefill whole, up to the largest "
+            f"bucket)")
+    if role != "unified":
+        why.append(
+            f"tpu.role {role!r}: the KV handoff frame has no place for the "
+            f"index keys — a model with sparse attention serves unified")
+    if mesh:
+        why.append(
+            "tpu.mesh: the index cache and the selection have no sharding "
+            "rules yet — a model with sparse attention runs on one device")
+    return why
 
 
 def _attention(
@@ -591,6 +758,7 @@ def _attention(
     ring_mesh=None,             # static: Mesh => sequence-parallel prefill
     sp_mode: str = "ring",      # static: "ring" | "ulysses" (SURVEY §5.7)
     tp_mesh=None,               # static: Mesh the trunk is GSPMD-sharded over
+    rope_positions=None,        # [3, B, S] multimodal rotary components
 ) -> tuple[jnp.ndarray, KVCache]:
     """The attention mixer: projections, the cache write, attention by the
     routed path, the output projection -> ([B, S, E], cache)."""
@@ -615,7 +783,15 @@ def _attention(
     if getattr(config, "qk_norm", False):  # per head, over its D channels
         q = rms_norm(q, _norm_w(lp["q_norm"], config), config.rms_eps)
         k = rms_norm(k, _norm_w(lp["k_norm"], config), config.rms_eps)
-    if getattr(config, "rope", True):
+    if getattr(config, "rope", True) and rope_positions is not None:
+        # a multimodal rotary's three components (equal ones, the served
+        # text path, are `positions`: the branch below)
+        section = config.mrope_section
+        q = apply_rope(q, rope_positions, config.rope_theta,
+                       mrope_section=section)
+        k = apply_rope(k, rope_positions, config.rope_theta,
+                       mrope_section=section)
+    elif getattr(config, "rope", True):
         rot = int(D * getattr(config, "partial_rotary_factor", 1.0))
         q = apply_rope(q, positions, config.rope_theta, rot)
         k = apply_rope(k, positions, config.rope_theta, rot)
@@ -625,8 +801,29 @@ def _attention(
         # ratio to the published multiplier into q
         q = q * jnp.asarray(scale * D ** 0.5, q.dtype)
 
-    cache = write_kv(cache, layer, positions, k, v,
-                     by_head=tp_mesh is not None)
+    sparse = getattr(config, "sparse", None)
+    if sparse is None:
+        cache = write_kv(cache, layer, positions, k, v,
+                         by_head=tp_mesh is not None)
+    else:
+        # the lightning indexer: index queries, this position's index key
+        # (cached beside K and V) and the head weights, from the same
+        # normed input; plain rotary by the temporal component
+        from symmetry_tpu.ops import sparse_attention as sa
+
+        if ring_mesh is not None or tp_mesh is not None:
+            raise ValueError("a model with learned sparse attention runs "
+                             "on one device: no ring_mesh, no tp_mesh")
+        t_pos = positions if rope_positions is None else rope_positions[0]
+        qi = apply_rope(
+            qmatmul(x, lp["wqi"]).reshape(B, S, sparse.index_heads,
+                                          sparse.index_head_dim),
+            t_pos, config.rope_theta)
+        ki = apply_rope(qmatmul(x, lp["wki"])[:, :, None, :], t_pos,
+                        config.rope_theta)[:, :, 0]
+        wi = qmatmul(x, lp["wwi"])
+        cache = write_kv(cache, layer, positions, k, v, by_head=False,
+                         idx=ki)
 
     if ring_mesh is not None:
         # Long-context prefill: sequence sharded over the `context` mesh
@@ -645,7 +842,26 @@ def _attention(
         paths = attention_paths(config, cache.k.shape[2], tp_mesh,
                                 batch=cache.k.shape[1],
                                 kv_bytes=cache.k.dtype.itemsize)
-        if prefill_flash and paths["prefill"] != "xla":
+
+        def at_layer(arr):
+            return jax.lax.dynamic_index_in_dim(arr, layer, 0,
+                                                keepdims=False)
+
+        flash_route = prefill_flash and paths["prefill"] != "xla"
+        keep = None
+        if sparse is not None:
+            # each query's own keep-set: over this call's index keys on
+            # the flash route ([B, S, S] int8), over the cached ones
+            # ([B, S, T] bool) on the two routes that read the cache
+            keep, n_sel = (
+                sa.prefill_keep(qi, ki, wi, seq_lens, sparse.topk)
+                if flash_route else
+                sa.cache_keep(qi, at_layer(cache.idx), wi, positions,
+                              kv_valid, sparse.topk))
+            if cache.expert_pairs is not None:
+                cache = cache._replace(
+                    expert_pairs=sa.add_counts(cache.expert_pairs, n_sel))
+        if flash_route:
             # Prefill-from-empty: attention is over this call's own K/V —
             # the Pallas kernel streams K/V blocks through VMEM instead of
             # materializing [H, S, S] scores (ops/flash.py); the cache
@@ -655,10 +871,15 @@ def _attention(
 
             kw = dict(window=config.sliding_window,
                       interpret=interpret_mode())
-            attn = (flash.flash_prefill(q, k, v, seq_lens, **kw)
-                    if tp_mesh is None else
-                    flash.flash_prefill_tp(q, k, v, seq_lens, mesh=tp_mesh,
-                                           **kw))
+            if keep is not None:
+                # (the keep-set carries causality and the prompt's length)
+                attn = sa.flash_sparse(q, k, v, keep,
+                                       interpret=interpret_mode())
+            elif tp_mesh is None:
+                attn = flash.flash_prefill(q, k, v, seq_lens, **kw)
+            else:
+                attn = flash.flash_prefill_tp(q, k, v, seq_lens,
+                                              mesh=tp_mesh, **kw)
         elif S == 1 and paths["decode"] != "xla":
             # Single-position decode: the Pallas kernel reads only each
             # slot's occupied KV prefix; the full cache is its operand and
@@ -669,21 +890,20 @@ def _attention(
             args = (q[:, 0], cache.k, cache.v, layer, kv_valid,
                     cache.k_scale if cache.quantized else None,
                     cache.v_scale if cache.quantized else None)
+            if keep is not None:
+                args += (keep[:, 0],)
             kw = dict(window=config.sliding_window,
                       interpret=interpret_mode())
             attn = (da.decode_attention(*args, **kw) if tp_mesh is None
                     else da.decode_attention_tp(*args, mesh=tp_mesh,
                                                 **kw))[:, None]
         else:
-            def at_layer(arr):
-                return jax.lax.dynamic_index_in_dim(arr, layer, 0,
-                                                    keepdims=False)
-
             attn = gqa_attention(
                 q, at_layer(cache.k), at_layer(cache.v), positions, kv_valid,
                 sliding_window=config.sliding_window,
                 k_scale=at_layer(cache.k_scale) if cache.quantized else None,
-                v_scale=at_layer(cache.v_scale) if cache.quantized else None)
+                v_scale=at_layer(cache.v_scale) if cache.quantized else None,
+                keep=keep)
     attn = attn.reshape(B, S, nq * D)
     if gate is not None:
         attn = attn * jax.nn.sigmoid(gate)
@@ -703,6 +923,7 @@ def _layer(
     ring_mesh=None,
     sp_mode: str = "ring",
     tp_mesh=None,
+    rope_positions=None,
 ) -> tuple[jnp.ndarray, KVCache]:
     """One decoder layer of the homogeneous stack: attention, then the FFN
     (dense or routed experts); arguments as `_attention`'s."""
@@ -710,7 +931,7 @@ def _layer(
     attn, cache = _attention(x, lp, cache, layer, positions, kv_valid,
                              seq_lens, config, prefill_flash,
                              ring_mesh=ring_mesh, sp_mode=sp_mode,
-                             tp_mesh=tp_mesh)
+                             tp_mesh=tp_mesh, rope_positions=rope_positions)
     h = h + attn
 
     x = rms_norm(h, _norm_w(lp["mlp_norm"], config), config.rms_eps)
@@ -720,7 +941,10 @@ def _layer(
         y, pairs = moe_mlp(x, lp, config, seq_lens, tp_mesh)
         h = h + y
         if cache.expert_pairs is not None:
-            cache = cache._replace(expert_pairs=cache.expert_pairs + pairs)
+            # (a sparse-attention model's vector ends in its own counters)
+            extra = cache.expert_pairs.shape[0] - pairs.shape[0]
+            cache = cache._replace(expert_pairs=cache.expert_pairs + (
+                jnp.pad(pairs, (0, extra)) if extra else pairs))
     else:
         h = h + qmatmul(_act(qmatmul(x, lp["wg"]), config)
                         * qmatmul(x, lp["wu"]), lp["wd"])
@@ -757,8 +981,15 @@ def forward_hidden(
     ring_mesh=None,               # static: context-parallel prefill mesh
     sp_mode: str = "ring",        # static: "ring" | "ulysses"
     tp_mesh=None,                 # static: Mesh the arrays are sharded over
+    rope_positions: jnp.ndarray | None = None,  # [3, B, S] (mrope models)
 ) -> tuple[jnp.ndarray, KVCache]:
     """Decoder trunk: returns (final-norm hidden states [B, S, E], cache).
+
+    `rope_positions` are the three components (temporal, height, width) a
+    model with `mrope_section` turns its rotary by; None means a text
+    token's: all three equal to the position in the sequence, which is the
+    plain rotary every other model runs. Cache rows, causality and lengths
+    go by the position in the sequence either way.
 
     Split from the LM head so prefill can project only the last valid
     position — at 128k vocab the head matmul over a full padded bucket would
@@ -815,10 +1046,15 @@ def forward_hidden(
         # gemma: embeddings scaled by sqrt(hidden) at lookup, normalizer
         # cast to the activation dtype (HF modeling_gemma semantics)
         h = h * jnp.asarray(config.hidden_size ** 0.5, h.dtype)
+    if rope_positions is not None and not getattr(config, "mrope_section",
+                                                  None):
+        raise ValueError("rope_positions are a multimodal rotary's: the "
+                         "config has no mrope_section")
     h, new_cache = run_layers(params["layers"], h, cache, positions,
                               kv_valid, seq_lens, config,
                               use_flash=use_flash, use_ring=use_ring,
-                              sp_mode=sp_mode, tp_mesh=tp_mesh)
+                              sp_mode=sp_mode, tp_mesh=tp_mesh,
+                              rope_positions=rope_positions)
     h = rms_norm(h, _norm_w(params["final_norm"], config), config.rms_eps)
     return h, new_cache._replace(lengths=kv_valid)
 
@@ -836,6 +1072,7 @@ def run_layers(
     use_ring=None,
     sp_mode: str = "ring",
     tp_mesh=None,
+    rope_positions=None,
 ) -> tuple[jnp.ndarray, KVCache]:
     """Scan a stack of decoder layers over `h`; layer indices inside are
     local to the stack passed in, whose leading dim is the cache's."""
@@ -848,7 +1085,8 @@ def run_layers(
         lp, l = xs
         h, c = _layer(h, lp, c, l, positions, kv_valid,
                       seq_lens, config, use_flash, ring_mesh=use_ring,
-                      sp_mode=sp_mode, tp_mesh=tp_mesh)
+                      sp_mode=sp_mode, tp_mesh=tp_mesh,
+                      rope_positions=rope_positions)
         return (h, c), None
 
     n_layers = jax.tree.leaves(layers_params)[0].shape[0]
@@ -872,8 +1110,9 @@ def logits_from_hidden(params: dict, config: ModelConfig,
 # `in_proj` / `out_proj` are the recurrent mixer's (mamba's or the Gated
 # DeltaNet's q|k|v|z projection), `sg` / `su` / `sd` the shared expert's
 # (models/hybrid.py).
+# `wqi` / `wki` are the sparse attention's index projections.
 QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "in_proj",
-              "out_proj", "sg", "su", "sd", "lm_head")
+              "out_proj", "sg", "su", "sd", "wqi", "wki", "lm_head")
 # Those of them stacked along a leading layers axis.
 STACKED_KEYS = QUANT_KEYS[:-1]
 
@@ -1037,7 +1276,27 @@ HF_LAYER_MAP = {
     "mlp.gate_proj.weight": ("wg", True),
     "mlp.up_proj.weight": ("wu", True),
     "mlp.down_proj.weight": ("wd", True),
+    # KeyeVL2 (the Qwen3-MoE block): per-head q/k norms, and the lightning
+    # indexer's three Linears (ASSUMED names, DeepSeek-V3.2's `indexer`
+    # module: the checkpoint is not in the sandbox)
+    "self_attn.q_norm.weight": ("q_norm", False),
+    "self_attn.k_norm.weight": ("k_norm", False),
+    "self_attn.indexer.wq.weight": ("wqi", True),
+    "self_attn.indexer.wk.weight": ("wki", True),
+    "self_attn.indexer.weights_proj.weight": ("wwi", True),
 }
+
+
+def optional_layer_params(config: ModelConfig) -> set[str]:
+    """HF_LAYER_MAP's leaves this config does NOT have."""
+    absent = set()
+    if not config.attention_bias:
+        absent |= {"bq", "bk", "bv"}
+    if not getattr(config, "qk_norm", False):
+        absent |= {"q_norm", "k_norm"}
+    if getattr(config, "sparse", None) is None:
+        absent |= {"wqi", "wki", "wwi"}
+    return absent
 # Mixtral: the MLP block is `block_sparse_moe` — a router (`gate`) plus
 # per-expert w1/w2/w3 Linears (w1=gate_proj, w2=down_proj, w3=up_proj).
 # All are HF [out, in] → transposed; experts stack on our leading dim.
@@ -1045,9 +1304,52 @@ HF_MOE_ROUTER = "block_sparse_moe.gate.weight"            # → router (T)
 HF_EXPERT_MAP = {"w1": "wg", "w3": "wu", "w2": "wd"}      # all transposed
 
 
-def hf_expert_name(layer: int, expert: int, ours: str) -> str:
-    w = {v: k for k, v in HF_EXPERT_MAP.items()}[ours]
-    return f"model.layers.{layer}.block_sparse_moe.experts.{expert}.{w}.weight"
+# The Qwen3-MoE block (KeyeVL2): `mlp.gate` routes, `mlp.experts.<e>` hold
+# gate_proj / up_proj / down_proj.
+HF_QWEN_MOE_ROUTER = "mlp.gate.weight"
+HF_QWEN_EXPERT_MAP = {"gate_proj": "wg", "up_proj": "wu", "down_proj": "wd"}
+
+
+def hf_moe_names(config: ModelConfig | None) -> tuple[str, str, dict]:
+    """(router tensor, experts module, expert Linear -> ours) under a
+    layer, by the family the config is of."""
+    if getattr(config, "hf_block", "mixtral") == "qwen3_moe":
+        return HF_QWEN_MOE_ROUTER, "mlp.experts", HF_QWEN_EXPERT_MAP
+    return HF_MOE_ROUTER, "block_sparse_moe.experts", HF_EXPERT_MAP
+
+
+def hf_expert_name(layer: int, expert: int, ours: str,
+                   config: ModelConfig | None = None) -> str:
+    _, module, names = hf_moe_names(config)
+    w = {v: k for k, v in names.items()}[ours]
+    return f"model.layers.{layer}.{module}.{expert}.{w}.weight"
+
+
+def hf_config_sparse(config: MoEConfig) -> dict:
+    """A sparse-attention config as its published `config.json` keys (what
+    `config_from_hf` reads back, and what the plain reference —
+    `benchmarks/reference/sparse_moe_decoder.py` — is given)."""
+    c = config
+    return {
+        "model_type": "KeyeVL2", "vocab_size": c.vocab_size,
+        "hidden_size": c.hidden_size, "num_hidden_layers": c.num_layers,
+        "num_attention_heads": c.num_heads,
+        "num_key_value_heads": c.num_kv_heads, "head_dim": c.dim_per_head,
+        "moe_intermediate_size": c.intermediate_size,
+        "num_experts": c.num_experts,
+        "num_experts_per_tok": c.num_experts_per_tok,
+        "norm_topk_prob": True, "decoder_sparse_step": 1,
+        "mlp_only_layers": [], "rope_theta": c.rope_theta,
+        "rope_scaling": {"mrope_section": list(c.mrope_section)},
+        "rms_norm_eps": c.rms_eps,
+        "max_position_embeddings": c.max_position,
+        "tie_word_embeddings": c.tie_embeddings,
+        "attention_bias": c.attention_bias, "use_sliding_window": False,
+        "sa_config": {"topk": c.sparse.topk,
+                      "indexer_num_heads": c.sparse.index_heads,
+                      "indexer_head_dim": c.sparse.index_head_dim,
+                      "indexer_num_kv_heads": 1},
+    }
 
 
 def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
@@ -1107,6 +1409,43 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
             linear_conv_kernel_dim=hf.get("linear_conv_kernel_dim", 4),
             partial_rotary_factor=hf.get("partial_rotary_factor", 1.0),
             qk_norm=True, attn_output_gate=True, shared_expert_gate=True,
+        )
+    if hf.get("model_type") == "KeyeVL2":
+        # the LANGUAGE model of the family (the catalog row's keys): the
+        # Qwen3-MoE block under a DeepSeek-Sparse-Attention indexer
+        if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1:
+            raise ValueError("KeyeVL2 with dense-MLP layers (mlp_only_layers,"
+                             " decoder_sparse_step) is not implemented")
+        if not hf.get("norm_topk_prob", True):
+            raise ValueError("KeyeVL2 with norm_topk_prob false is not "
+                             "implemented")
+        sa = hf["sa_config"]
+        if sa.get("indexer_num_kv_heads", 1) != 1:
+            raise ValueError("an indexer with more than one key head is not "
+                             "implemented")
+        return MoEConfig(
+            vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf["num_key_value_heads"],
+            intermediate_size=hf["moe_intermediate_size"],
+            head_dim=hf.get("head_dim"),
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            rms_eps=hf.get("rms_norm_eps", 1e-6),
+            tie_embeddings=hf.get("tie_word_embeddings", False),
+            sliding_window=sliding,
+            attention_bias=hf.get("attention_bias", False),
+            max_position=hf.get("max_position_embeddings", 8192),
+            num_experts=hf["num_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            qk_norm=True,
+            mrope_section=tuple(
+                (hf.get("rope_scaling") or {}).get("mrope_section") or ())
+            or None,
+            sparse=SparseAttention(topk=sa["topk"],
+                                   index_heads=sa["indexer_num_heads"],
+                                   index_head_dim=sa["indexer_head_dim"]),
+            hf_block="qwen3_moe", init_fan_in=True,
         )
     if hf.get("model_type") == "granitemoehybrid":
         types = tuple(hf["layer_types"])

@@ -111,7 +111,17 @@ ROUTED_MIN_TOKENS = 1024
 # decode's 128 tokens among them (by 24%): the crossing is under the
 # smallest dispatch there is. (`ragged_dot` over 512 groups costs 6-15 ms
 # before its first useful row.)
-ROUTED_FROM = {(72, 10): 256, (512, 10): 1}
+# 128 top 8 at expert width 768 (keye-vl-2.0-30b-a3b; `--shape
+# 128,8,2048,768`; PERF.md, PR 40; floor 0.74 for all 128 experts): 32
+# tokens 4.30 / 0.77 / 0.83, 64: 6.15 / 0.88 / 0.83, 128: 6.34 / 0.95 /
+# 1.00, 256: 6.49 / 1.07 / 1.90, 512: 6.77 / 1.31 / 3.83, 2,048: 10.24 /
+# 3.06 / 14.94, 8,192: 23.30 / 11.01 / 59.38. At 64 tokens (decode's 64
+# slots: 512 pairs hit 126 of the 128 experts) the mixture's 16x FLOPs
+# still hide behind the weight stream (89% of the floor) and it wins by
+# 6%; from 128 the kernel does. They cross between 64 and 128 and no
+# dispatch lies between: decode keeps the mixture, every prefill of the
+# long-document cell (1,024 tokens and up) is routed.
+ROUTED_FROM = {(72, 10): 256, (512, 10): 1, (128, 8): 128}
 
 
 def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:

@@ -35,7 +35,8 @@ def gqa_attention(
     sliding_window: int | None = None,  # mistral-style local attention span
     k_scale: jnp.ndarray | None = None,  # [B, n_kv_heads, T] f32: int8 cache
     v_scale: jnp.ndarray | None = None,  # per-token-per-head dequant scales
-) -> jnp.ndarray:
+    keep: jnp.ndarray | None = None,  # [B, S, T] bool: a learned selection
+) -> jnp.ndarray:                     # (ops/sparse_attention.py) to stay in
     """Returns [B, S, n_q_heads, head_dim] in q's dtype. Softmax in f32.
 
     With k_scale/v_scale set, k_cache/v_cache hold int8 payloads
@@ -71,6 +72,8 @@ def gqa_attention(
     )  # [B, S, T]
     if sliding_window is not None:
         mask &= kv_pos[None, None, :] > q_positions[..., None] - sliding_window
+    if keep is not None:
+        mask &= keep
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
 
     probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))

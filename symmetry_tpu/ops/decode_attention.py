@@ -60,6 +60,13 @@ kernel is the single-position attention of every one-chip decode program
 
 Masking is by absolute position (kv_pos < kv_length), identical semantics
 to ops/attention.py gqa_attention at decode (q position == length - 1).
+
+A learned selection (ops/sparse_attention.py: `keep`, a 0/1 float32 plane
+[B, K, T], the slot's set repeated a KV head) rides with the int8 cache's
+scale planes as a third one: copied block by block, laid out in the scores'
+lane order by the same `spread` product, and turned into -inf on the scores
+of what the query did not select — the masked form: every live block is
+still read. Interleaved int8 lanes alone (`keep_supported`).
 """
 
 from __future__ import annotations
@@ -137,6 +144,14 @@ def geometry(batch: int, capacity: int, n_kv: int, head_dim: int = LANES,
                 if batch % t == 0), block_t
 
 
+def keep_supported(n_kv: int, kv_bytes: int, quantized: bool) -> bool:
+    """Whether `decode_attention(keep=)` has a form for this cache: the
+    selection travels as a third scale plane, which only an int8 cache of
+    interleaved heads has."""
+    lanes = _lanes(n_kv, kv_bytes)
+    return quantized and lanes is not None and lanes[1] > 1
+
+
 def _three_bf16(x):
     """f32 x as three bf16 terms whose f32 sum is x exactly."""
     a = x.astype(jnp.bfloat16)
@@ -149,10 +164,10 @@ def _three_bf16(x):
 def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             scale: float, block_t: int, capacity: int, heads: int,
             n_kv: int, slab: int, ways: int, quantized: bool,
-            window: int | None, compute_dtype):
+            window: int | None, compute_dtype, masked: bool):
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, scbuf, *spread, islot, iblk,
-         sem) = rest
+        planes_hbm, rest = rest[:2 + masked], rest[2 + masked:]
+        o_ref, kbuf, vbuf, scbuf, *spread, islot, iblk, sem = rest
     else:
         o_ref, kbuf, vbuf, islot, iblk, sem = rest
     tile, nq, D = q_ref.shape
@@ -208,9 +223,11 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                pltpu.make_async_copy(v_hbm.at[layer, b, at],
                                      vbuf.at[w, buf], sem.at[1, w, buf])]
         if quantized:
-            for p, plane in enumerate((ks_hbm, vs_hbm)):
+            for p, plane in enumerate(planes_hbm):
+                # the keep plane (p == 2) is one layer's: no layer axis
+                at_t = (b // heads, slice(None), pl.ds(t0, block_t))
                 out.append(pltpu.make_async_copy(
-                    plane.at[layer, b // heads, :, pl.ds(t0, block_t)],
+                    plane.at[at_t if p == 2 else (layer,) + at_t],
                     scbuf.at[w, buf, p], sem.at[2 + p, w, buf]))
         return out
 
@@ -296,20 +313,25 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                 for c in range(block_t // chunk):
                     at = slice(c * chunk, (c + 1) * chunk)
                     # K < 8 heads fill a slab by repeating their rows
+                    n_p = (2 + masked) * slab
                     e = jax.lax.dot_general(
                         _three_bf16(jnp.concatenate(
-                            [scbuf[w, buf, 0, :, at]] * (slab // n_kv)
-                            + [scbuf[w, buf, 1, :, at]] * (slab // n_kv),
-                            axis=0)),
+                            sum(([scbuf[w, buf, p, :, at]] * (slab // n_kv)
+                                 for p in range(2 + masked)), []), axis=0)),
                         spread[...], (((1,), (0,)), ((), ())),
                         precision=jax.lax.Precision.DEFAULT,
                         preferred_element_type=jnp.float32)
-                    planes.append(e[:2 * slab] + e[2 * slab:4 * slab]
-                                  + e[4 * slab:])
-                planes = jnp.concatenate(planes, axis=1)  # [2 * slab, rows]
-                k_plane, v_plane = planes[:slab], planes[slab:]
+                    planes.append(e[:n_p] + e[n_p:2 * n_p] + e[2 * n_p:])
+                planes = jnp.concatenate(planes, axis=1)  # [n_p, rows]
+                k_plane, v_plane = planes[:slab], planes[slab:2 * slab]
                 s = per_slab(s, k_plane)
             s = s * scale + bias
+            if masked:
+                # 0 on a selected position, -inf (as the own-head bias's)
+                # on one the query left out
+                s = (s.reshape(nq // slab, slab, rows)
+                     + ((planes[2 * slab:] - 1.0) * -NEG_INF)[None]
+                     ).reshape(nq, rows)
             pos = t_of_lane + block_start(blk)
             keep = (pos < length) & (pos >= blk * block_t)
             if window is not None:
@@ -360,7 +382,8 @@ def decode_attention(
     kv_length: jnp.ndarray,   # [B] int32 valid entries (incl. current token)
     k_scale: jnp.ndarray | None = None,  # [L, B, K, T] f32 (int8 caches;
     v_scale: jnp.ndarray | None = None,  # position minor — tile-friendly)
-    *,
+    keep: jnp.ndarray | None = None,     # [B, T] bool: the positions each
+    *,                                   # slot's query selected
     window: int | None = None,  # sliding-window span (mistral); bounds the
                                 # per-slot block range below AND above
     interpret: bool = False,
@@ -379,6 +402,10 @@ def decode_attention(
     lanes, tile = B * heads, slot_tile * heads
     slab = max(n_kv, SUBLANES)
     quantized = k_scale is not None
+    masked = keep is not None
+    if masked and not keep_supported(K, kv_bytes, quantized):
+        raise ValueError("a selection rides with the scale planes of "
+                         "interleaved int8 lanes alone")
     compute_dtype = (q.dtype if quantized
                      else jnp.promote_types(q.dtype, k_cache.dtype))
     n_t = -(-T // block_t)
@@ -411,10 +438,15 @@ def decode_attention(
         chunk = min(block_t, LANES)
         args += [k_scale, v_scale]
         in_specs += [hbm, hbm]
-        scratch += [pltpu.VMEM((ways, NBUF, 2, K, block_t), jnp.float32)]
+        if masked:
+            args += [jnp.broadcast_to(
+                keep.astype(jnp.float32)[:, None, :], (B, K, T))]
+            in_specs += [hbm]
+        scratch += [pltpu.VMEM((ways, NBUF, 2 + masked, K, block_t),
+                               jnp.float32)]
         if n_kv > 1:
             scratch += [pltpu.VMEM((chunk, chunk * n_kv), jnp.bfloat16)]
-        n_sem += 2
+        n_sem += 2 + masked
     scratch += [pltpu.SMEM((ways, tile * n_t), jnp.int32),
                 pltpu.SMEM((ways, tile * n_t), jnp.int32),
                 pltpu.SemaphoreType.DMA((n_sem, ways, NBUF))]
@@ -432,7 +464,7 @@ def decode_attention(
                           capacity=T, heads=heads, n_kv=n_kv, slab=slab,
                           ways=ways,
                           quantized=quantized, window=window,
-                          compute_dtype=compute_dtype),
+                          compute_dtype=compute_dtype, masked=masked),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # kv_length, layer
             grid=(B // slot_tile,),

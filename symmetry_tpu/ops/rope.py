@@ -32,21 +32,56 @@ def _rotate_half(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([-x2, x1], axis=-1)
 
 
+def mrope_cos_sin(
+    positions: jnp.ndarray,        # [3, batch, seq]: temporal, height, width
+    head_dim: int,
+    theta: float,
+    section: tuple[int, ...],      # frequency pairs per component
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Multimodal rotary (HF `mrope_section`): frequency pair i of the
+    head_dim / 2 is turned by the position component whose section holds
+    it — the first `section[0]` pairs by the temporal one, the next by the
+    height, the rest by the width. Three equal components give
+    `rope_cos_sin`'s values exactly."""
+    if sum(section) != head_dim // 2 or positions.shape[0] != len(section):
+        raise ValueError(f"mrope_section {section} must split the "
+                         f"{head_dim // 2} frequency pairs over the "
+                         f"{positions.shape[0]} position components")
+    inv_freq = 1.0 / (
+        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    of_pair = jnp.repeat(jnp.arange(len(section)), jnp.asarray(section),
+                         total_repeat_length=head_dim // 2)
+    # [B, S, hd/2]: each pair's own component
+    pos = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[..., of_pair]
+    freqs = pos * inv_freq
+    emb = jnp.concatenate([freqs, freqs], axis=-1)
+    return jnp.cos(emb), jnp.sin(emb)
+
+
 def apply_rope(
     x: jnp.ndarray,          # [batch, seq, heads, head_dim]
-    positions: jnp.ndarray,  # [batch, seq]
+    positions: jnp.ndarray,  # [batch, seq], or [3, batch, seq] (mrope)
     theta: float = 500000.0,
     rotary_dim: int | None = None,
+    mrope_section: tuple[int, ...] | None = None,
 ) -> jnp.ndarray:
     """Rotate q or k by absolute position; returns x's dtype. With
     `rotary_dim` under the head size (partial rotary: HF
     `partial_rotary_factor`) only the leading `rotary_dim` channels of each
-    head rotate, as a head of that size would; the rest pass through."""
+    head rotate, as a head of that size would; the rest pass through.
+    3-D `positions` are the components of a multimodal rotary
+    (`mrope_cos_sin`, by `mrope_section`); 2-D ones are three equal
+    components, which is the plain rotary below."""
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
         return jnp.concatenate(
-            [apply_rope(x[..., :rotary_dim], positions, theta),
+            [apply_rope(x[..., :rotary_dim], positions, theta,
+                        mrope_section=mrope_section),
              x[..., rotary_dim:]], axis=-1)
-    cos, sin = rope_cos_sin(positions, x.shape[-1], theta)
+    if positions.ndim == 3:
+        cos, sin = mrope_cos_sin(positions, x.shape[-1], theta,
+                                 tuple(mrope_section))
+    else:
+        cos, sin = rope_cos_sin(positions, x.shape[-1], theta)
     # Broadcast over the heads axis: [batch, seq, 1, head_dim].
     cos, sin = cos[..., None, :], sin[..., None, :]
     xf = x.astype(jnp.float32)

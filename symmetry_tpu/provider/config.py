@@ -336,12 +336,28 @@ class ConfigManager:
         prefix cache, speculation, chunked prefill, the disagg handoff and
         a mesh cannot carry: refuse those settings here, by name, before a
         host is spawned (models/hybrid.py state_refusals; the engine
-        refuses the same for a checkpoint, whose config it learns late)."""
+        refuses the same for a checkpoint, whose config it learns late).
+        A preset with learned sparse attention keeps an index key a cached
+        position: models/llama.py sparse_refusals, the same settings the
+        same way."""
         import math
 
         from symmetry_tpu.models.llama import PRESETS
 
-        if not getattr(PRESETS.get(tpu.model_preset), "layer_types", None):
+        preset = PRESETS.get(tpu.model_preset)
+        if getattr(preset, "sparse", None) is not None:
+            from symmetry_tpu.models.llama import sparse_refusals
+
+            refused = sparse_refusals(
+                mesh=math.prod((tpu.mesh or {}).values()) > 1,
+                role=tpu.role or "unified",
+                prefix_cache=bool(tpu.prefix_cache_mb),
+                speculative=bool(tpu.speculative),
+                prefill_chunk=tpu.prefill_chunk)
+            if refused:
+                raise ConfigError(f"model_preset {tpu.model_preset!r}: "
+                                  + "; ".join(refused))
+        if not getattr(preset, "layer_types", None):
             return
         from symmetry_tpu.models.hybrid import state_refusals
 

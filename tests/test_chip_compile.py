@@ -344,3 +344,50 @@ def test_prefill_reads_the_expert_stacks_where_they_lie(
     mixture = [line.strip()[:160] for line in text.splitlines()
                if re.search(rf"\[{tokens},{cfg.num_experts},\d+\]", line)]
     assert not mixture, mixture[0]
+
+
+def test_decode_kernel_takes_a_selection_at_the_long_document_cell(
+        one_chip, no_cache):
+    """keye-vl-2.0-30b-a3b's cell (64 slots x 16,384, 4 int8 KV heads): the
+    selection rides as a third scale plane; the cache is still read where
+    it lies, and the keep plane is the one array built for the call."""
+    L, B, T, K, nq, D = 4, 64, 16384, 4, 32, 128
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    kv = shape((L, B, T, K, D), jnp.int8)
+    scale = shape((L, B, K, T), jnp.float32)
+    compiled = jax.jit(
+        lambda q, k, v, layer, n, ks, vs, keep: decode_attention(
+            q, k, v, layer, n, ks, vs, keep)
+    ).lower(shape((B, nq, D), jnp.bfloat16), kv, kv, shape((), jnp.int32),
+            shape((B,), jnp.int32), scale, scale,
+            shape((B, T), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r" copy\(%[kv]\.", text)
+    assert not whole_cache_copies(text)
+    # the [B, K, T] float32 keep plane (16 MB) and nothing of the cache
+    assert compiled.memory_analysis().temp_size_in_bytes < 20 * 2**20
+
+
+@pytest.mark.parametrize("bucket", [1024, 8192, 14848])
+def test_sparse_flash_kernel_compiles_at_the_cells_buckets(
+        one_chip, no_cache, bucket):
+    """`dsa_flash` (ops/sparse_attention.py) at keye-vl-2.0-30b-a3b's head
+    shape and the cell's buckets: K and V are blocks of a grid axis, never
+    whole in VMEM, so a 14,848-token prompt compiles as a 1,024-token one
+    does."""
+    from symmetry_tpu.ops import sparse_attention as sa
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    kv = shape((1, bucket, 4, 128), jnp.bfloat16)
+    compiled = jax.jit(sa.flash_sparse).lower(
+        shape((1, bucket, 32, 128), jnp.bfloat16), kv, kv,
+        shape((1, bucket, bucket), jnp.int8)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert sa.NAME in text

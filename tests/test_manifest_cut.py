@@ -36,6 +36,7 @@ def cut_files():
 def test_there_is_a_cut_configuration_to_hold():
     assert "granite-4.0-h-small.json" in cut_files()
     assert "qwen3-next-80b-a3b.json" in cut_files()
+    assert "keye-vl-2.0-30b-a3b.json" in cut_files()
 
 
 @pytest.mark.parametrize("name", cut_files())
@@ -68,6 +69,13 @@ def test_a_cut_keeps_a_whole_period_of_the_published_pattern(name):
         assert list(preset(c["tpu"]["model_preset"]).layer_types) == [
             "full_attention" if (i + 1) % every == 0 else "linear_attention"
             for i in range(c["num_hidden_layers"])]
+        return
+    if c.get("model_type") == "KeyeVL2":
+        # 48 identical layers: a period is one layer, and four is the
+        # floor for the layers kept
+        assert c["num_hidden_layers"] == 4
+        assert c["published"]["num_hidden_layers"] == 48
+        assert c["mlp_only_layers"] == [] and c["decoder_sparse_step"] == 1
         return
     if "layer_types" not in c["reduced"]:
         pytest.skip("no layer pattern was cut")
@@ -116,6 +124,33 @@ def test_a_cut_file_is_the_programs_preset(name):
         assert p.partial_rotary_factor == c["partial_rotary_factor"]
         assert p.max_position == c["max_position_embeddings"]
         assert c["mlp_only_layers"] == [] and c["norm_topk_prob"] is True
+    if c.get("model_type") == "KeyeVL2":
+        from symmetry_tpu.models.llama import config_from_hf
+
+        # every published key the program reads, through its own reader
+        assert config_from_hf(c) == p
+        assert (p.num_experts, p.num_experts_per_tok) == (
+            c["num_experts"], c["num_experts_per_tok"]) == (128, 8)
+        assert c["num_local_experts"] == c["num_experts"]
+        sa = c["sa_config"]
+        assert (p.sparse.topk, p.sparse.index_heads,
+                p.sparse.index_head_dim) == (
+            sa["topk"], sa["indexer_num_heads"], sa["indexer_head_dim"]) == (
+            2048, 16, 64)
+        assert sa["indexer_num_kv_heads"] == 1
+        assert list(p.mrope_section) == c["rope_scaling"]["mrope_section"]
+        assert sum(p.mrope_section) == c["head_dim"] // 2
+        assert p.qk_norm and p.sliding_window is None
+        assert p.max_position == c["max_position_embeddings"]
+        assert c["tpu"]["max_seq_len"] == 16384
+        assert c["tpu"]["prefill_chunk"] is None
+        # every `assumed` item the issue lists is stated
+        text = " ".join(c["assumed"])
+        for word in ("RMSNorm", "normed hidden state", "temporal",
+                     "top_k", "chunk_size", "FP8", "vision tower", "16384",
+                     "byte tokenizer", "6144"):
+            assert word in text, word
+        assert "stage 1 of 12" in c["deployment"]
     if "layer_types" in c:
         assert list(p.layer_types) == c["layer_types"]
         assert (p.num_experts, p.num_experts_per_tok,

@@ -3,10 +3,11 @@ change what an existing configuration runs?".
 
     JAX_PLATFORMS=cpu python tools/lowered_programs.py OUT_DIR [preset ...]
 
-For each preset (default: mistral-7b, qwen2-7b and granite-4.0-h-small at
-the closed cells' shape, 128 slots x 640, int8 weights + int8 KV,
-decode_block 16; and tiny-moe8 on a `model: 4` mesh of virtual CPU devices:
-twelve programs) it writes the StableHLO of the
+For each preset (default: mistral-7b, qwen2-7b, granite-4.0-h-small,
+qwen3-next-80b-a3b and keye-vl-2.0-30b-a3b at the closed cells' shape, 128
+slots x 640, int8 weights + int8 KV, decode_block 16; and tiny-moe8 — the
+stand-in for mixtral-8x7b's sharded programs — on a `model: 4` mesh of
+virtual CPU devices: eighteen programs) it writes the StableHLO of the
 engine's OWN jits — `decode_block`, `prefill` at (8, 256) and `insert_all`
 — lowered from shapes alone (nothing is built or run), as
 `OUT_DIR/<preset>.<program>.txt` and prints one sha256 a file. The text
@@ -83,7 +84,8 @@ def programs(e, params, state):
 def main() -> int:
     out_dir = sys.argv[1]
     names = sys.argv[2:] or ["mistral-7b", "qwen2-7b", "tiny-moe8",
-                             "granite-4.0-h-small"]
+                             "granite-4.0-h-small", "qwen3-next-80b-a3b",
+                             "keye-vl-2.0-30b-a3b"]
     os.makedirs(out_dir, exist_ok=True)
     for name in names:
         cfg = llama.preset(name)

@@ -53,9 +53,9 @@ COLUMNS = ("PROVIDER", "TIER", "TOK/S", "TTFT p50", "TTFT p99",
            "QUEUE", "INFL", "OCC", "DEPTH", "SHED", "RESUME",
            "WASTED", "REUSED", "DUMPS", "COST", "WASTE%", "GPUT",
            "LINK", "STATE", "SHARE", "HIT", "TARGET", "SCALE",
-           "AHEAD", "STALLS", "TAIL")
+           "DSA", "AHEAD", "STALLS", "TAIL")
 WIDTHS = (22, 10, 9, 9, 9, 7, 6, 5, 5, 7, 7, 7, 7, 6, 7, 6, 7, 6,
-          9, 6, 6, 9, 6, 11, 9, 6)
+          9, 6, 6, 9, 6, 11, 11, 9, 6)
 
 # sym_pool_member_state gauge encoding (engine/disagg/pool.py
 # STATE_CODES) rendered back to the membership lifecycle names.
@@ -260,6 +260,21 @@ def read_tail(engine: dict | None
     return ahead_cell, stall_cell, tail
 
 
+def read_dsa(engine: dict | None) -> str | None:
+    """Learned sparse attention (the stats reply's `engine.dsa` counters
+    and `startup.attention.sparse`; None for any other model): DSA =
+    selected / candidates since start as a percentage — how sparse the
+    attention ran — and the decode program's form (`masked`, or `gather`
+    once one is built)."""
+    dsa = (engine or {}).get("dsa")
+    if not dsa or not dsa.get("candidates"):
+        return None
+    form = ((((engine.get("startup") or {}).get("attention") or {})
+             .get("sparse") or {}).get("form") or {}).get("decode", "")
+    return (f"{100.0 * dsa['selected'] / dsa['candidates']:.0f}% "
+            f"{form.split(' ')[0]}").strip()
+
+
 def build_rows(name: str, fams: dict, prev: dict | None, now: float,
                engine: dict | None = None) -> list[dict[str, Any]]:
     """One provider-level row plus one sub-row per engine tier. `prev`
@@ -341,6 +356,7 @@ def build_rows(name: str, fams: dict, prev: dict | None, now: float,
         "state": None, "share": None,
         "target": target, "scale": scale_disp,
         "ahead": ahead_cell, "stalls": stall_cell, "tail": tail,
+        "dsa": read_dsa(engine),
         "_sample": {"t": now, "tok": tok, "shed": shed or 0.0,
                     "dec": decisions or 0.0},
     }]
@@ -406,6 +422,7 @@ def render_table(rows: list[dict[str, Any]]) -> str:
                  r["link"] or "-",
                  r.get("state") or "-", r.get("share") or "-",
                  r.get("hit"), r.get("target") or "-", r.get("scale"),
+                 r.get("dsa") or "-",
                  r.get("ahead") or "-", r.get("stalls") or "-",
                  r.get("tail"))
         out.append("  ".join(_fmt_cell(c, w)
