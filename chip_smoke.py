@@ -320,6 +320,12 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
             and not (sparse.get("form") or {}).get("decode")):
         failures.append(f"a model with learned sparse attention reported "
                         f"no sparse form: {attention}")
+    # its selection has a kernel (ops/sparse_attention.py dsa_select): the
+    # smoke's caches have the decode form's layout
+    if sparse and set((sparse.get("select") or {}).values()) != {
+            "dsa_select kernel"}:
+        failures.append(f"the selection did not run the dsa_select kernel "
+                        f"in both programs: {sparse.get('select')}")
     ssm = startup.get("ssm") or {}
     ssm_decode = ssm.get("decode")
     # Mamba-2's step has a kernel (ops/ssm_step.py); the Gated DeltaNet's

@@ -2100,7 +2100,8 @@ class InferenceEngine:
         """The attention implementation the served prefill and decode
         programs took (models/llama.py attention_paths: the routing
         itself, asked with this engine's geometry)."""
-        from symmetry_tpu.models.llama import attention_paths, sparse_forms
+        from symmetry_tpu.models.llama import (
+            attention_paths, sparse_forms, sparse_select)
 
         paths = attention_paths(
             self.config, self.max_seq_len, self.mesh,
@@ -2115,6 +2116,9 @@ class InferenceEngine:
                 "topk": self._sparse.topk,
                 "index_heads": self._sparse.index_heads,
                 "form": sparse_forms(paths),
+                "select": sparse_select(paths, self.max_seq_len,
+                                        self.max_slots,
+                                        self._sparse.index_head_dim),
                 "index_bytes_per_token": per_token,
                 "index_cache_bytes": (per_token * self.max_slots
                                       * self.max_seq_len)}

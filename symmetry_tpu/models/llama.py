@@ -707,6 +707,24 @@ def sparse_forms(paths: dict) -> dict:
             "decode": form(paths["decode"], "decode kernel")}
 
 
+def sparse_select(paths: dict, capacity: int, slots: int,
+                  index_dim: int) -> dict:
+    """What makes each program's SELECTION (index scores, threshold, tie
+    rule, mask): the `dsa_select` kernel — over a prompt's own index keys
+    on the flash route, over each slot's live cached ones at decode where
+    a cache of `slots` x `capacity` keys of `index_dim` has the kernel's
+    layout — or the `jnp` form through XLA (`_attention`'s routing, asked
+    again)."""
+    from symmetry_tpu.ops.sparse_attention import SELECT_NAME, decode_group
+
+    def by(kernel):
+        return f"{SELECT_NAME} kernel" if kernel else "xla"
+
+    return {"prefill": by(paths["prefill"] != "xla"),
+            "decode": by(decode_group(capacity, slots,
+                                      index_dim) is not None)}
+
+
 def sparse_refusals(*, mesh: bool = False, role: str = "unified",
                     prefix_cache: bool = False, speculative: bool = False,
                     prefill_chunk: int | None = None) -> list[str]:
@@ -852,12 +870,14 @@ def _attention(
         if sparse is not None:
             # each query's own keep-set: over this call's index keys on
             # the flash route ([B, S, S] int8), over the cached ones
-            # ([B, S, T] bool) on the two routes that read the cache
+            # ([B, S, T] bool) on the two routes that read the cache —
+            # the whole index cache and the layer, so that a decode step
+            # reads each slot's live keys where they lie
             keep, n_sel = (
                 sa.prefill_keep(qi, ki, wi, seq_lens, sparse.topk)
                 if flash_route else
-                sa.cache_keep(qi, at_layer(cache.idx), wi, positions,
-                              kv_valid, sparse.topk))
+                sa.cache_keep(qi, cache.idx, wi, positions, kv_valid,
+                              sparse.topk, layer=layer))
             if cache.expert_pairs is not None:
                 cache = cache._replace(
                     expert_pairs=sa.add_counts(cache.expert_pairs, n_sel))

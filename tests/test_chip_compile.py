@@ -391,3 +391,110 @@ def test_sparse_flash_kernel_compiles_at_the_cells_buckets(
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert sa.NAME in text
+
+
+@pytest.mark.parametrize("bucket", [6144, 14848])
+def test_select_kernel_compiles_at_the_cells_buckets(one_chip, no_cache,
+                                                     bucket):
+    """`dsa_select` (ops/sparse_attention.py) at keye-vl-2.0-30b-a3b's
+    indexer (16 heads of 64, topk 2,048): a tile's [256, S] keys, the
+    prompt's index keys and the mask block fit the kernel's VMEM, and what
+    XLA builds around the call is the operands' transposes alone — no
+    [256, 16, S] products, no [256, S] scores."""
+    from symmetry_tpu.ops import sparse_attention as sa
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda qi, ki, w, n: sa.prefill_keep(qi, ki, w, n, 2048,
+                                             interpret=False)
+    ).lower(shape((1, bucket, 16, 64), jnp.bfloat16),
+            shape((1, bucket, 64), jnp.bfloat16),
+            shape((1, bucket, 16), jnp.bfloat16),
+            shape((1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and sa.SELECT_NAME in text
+    assert not re.search(rf"f32\[[\d,]*{bucket}\]", text)
+    # the transposed index queries (bf16) and weights (f32), nothing S x S
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**20
+
+
+def test_select_decode_form_reads_the_index_cache_where_it_lies(
+        one_chip, no_cache):
+    """The decode form at the cell's cache (4 layers x 64 slots x 16,384
+    index keys of 64): XLA keeps that cache position-minor, so the swap the
+    kernel asks for is a bitcast, and layer, slot and the live groups are
+    DMA addressing — no layer's slice, no transpose, no copy of it."""
+    from symmetry_tpu.ops import sparse_attention as sa
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    B, T = 64, 16384
+    compiled = jax.jit(
+        lambda qi, idx, w, pos, kv, layer: sa.cache_keep(
+            qi, idx, w, pos, kv, 2048, layer=layer, interpret=False)
+    ).lower(shape((B, 1, 16, 64), jnp.bfloat16),
+            shape((4, B, T, 64), jnp.bfloat16),
+            shape((B, 1, 16), jnp.bfloat16), shape((B, 1), jnp.int32),
+            shape((B,), jnp.int32), shape((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and sa.SELECT_NAME in text
+    made = [line.strip()[:160] for line in text.splitlines()
+            if re.search(r"= bf16\[(4,)?64,(16384,64|64,16384)\]\S* "
+                         r"(?!bitcast|parameter)\w", line)]
+    assert not made, made[0]
+    # the [B, T] int32 mask and the work list: no score, no cache
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**20
+
+
+@pytest.mark.parametrize("program", ["prefill 6144", "decode 64 x 16384"])
+def test_keye_programs_select_in_the_kernel_alone(one_chip, no_cache,
+                                                  monkeypatch, program):
+    """keye-vl-2.0-30b-a3b's served programs, whole: the indexer and its
+    threshold are the `dsa_select` call — no float32 [.., 16 heads, S]
+    products, no [tile, S] / [slots, capacity] scores, and the index cache
+    is written in place and read where it lies."""
+    from symmetry_tpu.models import llama, moe
+    from symmetry_tpu.ops import sparse_attention as sa
+
+    for module in (llama, moe, sa):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    cfg = llama.preset("keye-vl-2.0-30b-a3b")
+    prefill = program.startswith("prefill")
+    rows, capacity, S = (1, 6144, 6144) if prefill else (64, 16384, 1)
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+        slice_above=1 << 40))
+    cache = shaped(lambda: llama.init_cache(cfg, rows, capacity,
+                                            jnp.bfloat16, quantized=True))
+    tok = jax.ShapeDtypeStruct((rows, S), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(
+            (lambda p, t, c, n: llama.forward_hidden(
+                p, cfg, t, c, n, prefill_flash=True)) if prefill else
+            (lambda p, t, c, n: llama.forward_hidden(p, cfg, t, c)),
+            donate_argnums=(2,)).lower(params, tok, cache, lens).compile(
+        ).as_text()
+    assert len(re.findall(rf"%{sa.SELECT_NAME}[.\d]* = ", text)) == 1
+    heads, T = cfg.sparse.index_heads, capacity
+    scored = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= f32\[[\d,]*{heads},{T}\]", line)
+              or (prefill and re.search(rf"= [fsu]32\[(1,)?256,{T}\]", line))]
+    assert not scored, scored[0]
+    # (a prefill selects over its own index keys and only writes its one-
+    # row scratch cache; the decode step reads the slots' cache)
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if not prefill and re.search(
+                 rf"= bf16\[(4,)?{rows},({T},64|64,{T})\]\S* "
+                 rf"(copy|transpose|dynamic-slice)\(", line)]
+    assert not moved, moved[0]

@@ -121,6 +121,204 @@ def test_counters_split_in_words_carry_and_read_back_exactly():
     assert int(vec[-1]) < 1 << sa.COUNT_BITS       # the low word carried
 
 
+# ------------------------------------------------- the dsa_select kernel
+#
+# Whole-number index queries, keys and weights: every score is exact in
+# float32 whatever the order of its sums, and ties are plentiful — the
+# kernel's set has to be `select`'s and `lax.top_k`'s position for position.
+
+def whole(key, shape, dtype, lo=-2, hi=3):
+    return jax.random.randint(jax.random.key(key), shape, lo, hi
+                              ).astype(dtype)
+
+
+def prompt_inputs(B, S, H, Di, dtype, key=0):
+    return (whole(key, (B, S, H, Di), dtype), whole(key + 1, (B, S, Di),
+                                                    dtype),
+            whole(key + 2, (B, S, H), dtype, -1, 3))
+
+
+def prompt_oracle(qi, ki, w, lens, topk):
+    """`select` over `index_scores`, every query against the whole prompt,
+    and its validity mask."""
+    B, S = qi.shape[:2]
+    t = jnp.arange(S, dtype=jnp.int32)
+    valid = (t[None, None, :] <= t[None, :, None]) & (
+        t[None, :, None] < lens[:, None, None])
+    keep = sa.select(sa.index_scores(qi, ki, w), valid, topk)
+    return np.asarray(keep), np.asarray(valid)
+
+
+SELECT_CASES = {
+    # B, S, index heads, index dim, topk, query tile, lengths, dtype
+    "ragged lengths in one batch": (3, 128, 4, 8, 16, 32, [128, 5, 77],
+                                    jnp.bfloat16),
+    "a tile straddles topk": (2, 128, 4, 8, 40, 32, [128, 100],
+                              jnp.float32),
+    "a tile wholly under topk": (1, 128, 4, 8, 64, 32, [128], jnp.float32),
+    "every query under topk": (2, 64, 4, 8, 64, 32, [64, 33], jnp.bfloat16),
+    "padded queries": (2, 128, 4, 8, 16, 64, [70, 1], jnp.float32),
+    "several key blocks, the cell's heads": (2, 512, 16, 64, 100, 256,
+                                            [512, 300], jnp.bfloat16),
+    "row groups inside a tile": (1, 512, 4, 8, 48, 256, [400], jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(SELECT_CASES))
+def test_the_select_kernel_picks_selects_and_top_ks_sets_exactly(case):
+    B, S, H, Di, topk, tile, lens, dtype = SELECT_CASES[case]
+    qi, ki, w = prompt_inputs(B, S, H, Di, dtype, key=len(case))
+    lens = jnp.asarray(lens, jnp.int32)
+    keep, n = sa.prefill_keep(qi, ki, w, lens, topk, tile=tile)
+    assert keep.dtype == jnp.int8 and keep.shape == (B, S, S)
+    got = np.asarray(keep) != 0
+    want, valid = prompt_oracle(qi, ki, w, lens, topk)
+    np.testing.assert_array_equal(got, want)
+    scores = np.asarray(sa.index_scores(qi, ki, w))
+    for b in range(B):
+        np.testing.assert_array_equal(
+            got[b], top_k_sets(scores[b], valid[b], topk))
+    # ties AT the threshold were there to be broken, and rows under topk
+    # (and padded ones) kept every candidate (none)
+    assert (got.sum(-1) == np.minimum(valid.sum(-1), topk)).all()
+    kth = np.sort(np.where(valid, scores, -np.inf), axis=-1)[..., -topk]
+    tied = ((scores == kth[..., None]) & valid).sum(-1)
+    assert case.startswith("every query") or (tied > 1).any()
+    # the four counters, from the lengths alone
+    np.testing.assert_array_equal(
+        np.asarray(n), np.asarray(sa.counts(want, valid, topk)))
+
+
+@pytest.mark.parametrize("S, tile, why", [
+    (384, 128, "key block"),      # 384 = 1.5 key blocks of 256
+    (96, 64, "query tile"),
+])
+def test_the_select_kernel_refuses_a_prompt_its_blocks_do_not_divide(
+        S, tile, why):
+    qi, ki, w = prompt_inputs(1, S, 4, 8, jnp.float32)
+    with pytest.raises(ValueError, match=why):
+        sa.prefill_keep(qi, ki, w, jnp.asarray([S]), 16, tile=tile)
+
+
+DECODE_CASES = {
+    # layers, capacity, index heads, index dim, topk, lengths, dtype
+    "a dead lane, a lane under topk": (2, 128, 4, 8, 16,
+                                       [0, 5, 128, 77, 17], jnp.float32),
+    "a lane exactly topk long": (2, 384, 4, 8, 16, [384, 16, 33, 0],
+                                 jnp.bfloat16),
+    "several groups a lane": (2, 6144, 16, 64, 100,
+                              [6144, 0, 1500, 99, 101, 4097], jnp.bfloat16),
+    "every lane under topk": (1, 256, 4, 8, 300, [256, 1, 0, 200],
+                              jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_the_select_kernels_decode_form_follows_each_lanes_length(case):
+    L, T, H, Di, topk, lens, dtype = DECODE_CASES[case]
+    B = len(lens)
+    qi = whole(1, (B, 1, H, Di), dtype)
+    idx = whole(2, (L, B, T, Di), dtype)
+    w = whole(3, (B, 1, H), dtype, -1, 3)
+    kv = jnp.asarray(lens, jnp.int32)
+    pos = jnp.maximum(kv - 1, 0)[:, None]
+    assert sa.decode_group(T, B, Di) is not None
+    step = jax.jit(lambda layer: sa.cache_keep(qi, idx, w, pos, kv, topk,
+                                               layer=layer))
+    for layer in range(L):
+        keep, n = step(jnp.int32(layer))
+        assert keep.dtype == jnp.bool_ and keep.shape == (B, 1, T)
+        want, n_want = sa._masks(qi, idx[layer], w, pos, kv, topk)
+        np.testing.assert_array_equal(np.asarray(keep), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(n), np.asarray(n_want))
+        scores = np.asarray(sa.index_scores(qi, idx[layer], w))[:, 0]
+        valid = np.arange(T)[None, :] < np.asarray(kv)[:, None]
+        np.testing.assert_array_equal(np.asarray(keep)[:, 0],
+                                      top_k_sets(scores, valid, topk))
+    # another layer's keys give another set: the layer is DMA addressing
+    assert case.startswith("every lane") or (
+        np.asarray(step(jnp.int32(0))[0]) != np.asarray(keep)).any()
+
+
+def test_a_cache_without_the_decode_layout_selects_through_xla():
+    """A capacity no group of keys divides (and any S > 1 over a cache)
+    keeps the `jnp` form: the same sets, no kernel."""
+    L, B, T, H, Di, topk = 2, 3, 100, 4, 8, 16
+    assert sa.decode_group(T, B, Di) is None
+    assert sa.decode_group(16384, 64, 64) == 2048
+    assert sa.decode_group(16384, 512, 64) is None   # over the kernel's VMEM
+    assert sa.decode_group(16384, 64, 128) is None   # a row-major cache
+    qi, idx = whole(1, (B, 1, H, Di), jnp.float32), whole(
+        2, (L, B, T, Di), jnp.float32)
+    w, kv = whole(3, (B, 1, H), jnp.float32), jnp.asarray([100, 0, 37])
+    pos = jnp.maximum(kv - 1, 0)[:, None]
+    jaxpr = str(jax.make_jaxpr(lambda: sa.cache_keep(
+        qi, idx, w, pos, kv, topk, layer=jnp.int32(1)))())
+    assert "pallas_call" not in jaxpr
+    keep, n = sa.cache_keep(qi, idx, w, pos, kv, topk, layer=jnp.int32(1))
+    want, n_want = sa._masks(qi, idx[1], w, pos, kv, topk)
+    np.testing.assert_array_equal(np.asarray(keep), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(n), np.asarray(n_want))
+    paths = {"prefill": "pallas", "decode": "pallas"}
+    assert llama.sparse_select(paths, 16384, 64, 64) == {
+        "prefill": "dsa_select kernel", "decode": "dsa_select kernel"}
+    assert llama.sparse_select({**paths, "prefill": "xla"}, 100, 3, 8) == {
+        "prefill": "xla", "decode": "xla"}
+
+
+@pytest.mark.parametrize("form", ["prefill", "decode"])
+def test_attention_under_the_kernels_mask_is_attention_under_selects(form):
+    """`flash_sparse` and `decode_attention(keep=)` fed the kernel's mask
+    give what they give under `select`'s."""
+    ks = jax.random.split(jax.random.key(9), 3)
+    if form == "prefill":
+        B, S, H, K, D, topk = 2, 128, 4, 2, 16, 24
+        qi, ki, w = prompt_inputs(B, S, 4, 8, jnp.float32, key=5)
+        lens = jnp.asarray([S, 90])
+        keep, _ = sa.prefill_keep(qi, ki, w, lens, topk, tile=32)
+        want, _ = prompt_oracle(qi, ki, w, lens, topk)
+        q, k, v = (jax.random.normal(ks[i], (B, S, n, D), jnp.float32)
+                   for i, n in enumerate((H, K, K)))
+        run = lambda m: sa.flash_sparse(  # noqa: E731
+            q, k, v, m, block_q=32, block_k=64, interpret=True)
+        got, ref_out = run(keep), run(jnp.asarray(want, jnp.int8))
+    else:
+        L, B, T, D, nq, n_kv, topk = 2, 4, 256, 128, 32, 4, 40
+        qi, idx = whole(1, (B, 1, 4, 8), jnp.float32), whole(
+            2, (L, B, T, 8), jnp.float32)
+        w, lens = whole(3, (B, 1, 4), jnp.float32), jnp.asarray(
+            [5, 130, 256, 77], jnp.int32)
+        pos = (lens - 1)[:, None]
+        keep, _ = sa.cache_keep(qi, idx, w, pos, lens, topk,
+                                layer=jnp.int32(1))
+        want, _ = sa._masks(qi, idx[1], w, pos, lens, topk)
+        kq, ksc = quantize_kv(jax.random.normal(ks[0], (L, B, T, n_kv, D)))
+        vq, vsc = quantize_kv(jax.random.normal(ks[1], (L, B, T, n_kv, D)))
+        ksc, vsc = (jnp.moveaxis(s, 2, 3) for s in (ksc, vsc))
+        q = jax.random.normal(ks[2], (B, nq, D), jnp.bfloat16)
+        run = lambda m: da.decode_attention(  # noqa: E731
+            q, kq, vq, jnp.int32(1), lens, ksc, vsc, m[:, 0],
+            interpret=True)
+        got, ref_out = run(keep), run(want)
+    np.testing.assert_array_equal(np.asarray(keep) != 0, np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(ref_out, np.float32))
+
+
+def test_length_counts_are_the_masks_counts_past_one_words_range():
+    """From the lengths alone, a row of the batch at a time: two prompts of
+    1,500 (2.25 M candidates) carry into the high words as `counts` does."""
+    n = jnp.broadcast_to(jnp.arange(1, 1501, dtype=jnp.int32), (2, 1500))
+    valid = jnp.broadcast_to(jnp.tril(jnp.ones((1500, 1500), bool)),
+                             (2, 1500, 1500))
+    got = sa.read_counts(np.asarray(sa.length_counts(n, 16)))
+    assert got == sa.read_counts(np.asarray(sa.counts(
+        sa.select(jnp.zeros((2, 1500, 1500)), valid, 16), valid, 16)))
+    assert got == {"queries": 3000, "dense_queries": 32,
+                   "candidates": 2 * 1500 * 1501 // 2,
+                   "selected": 2 * (16 * 17 // 2 + 1484 * 16)}
+
+
 # ------------------------------------------------- against the reference
 
 def program_logits(params, tokens, n_prompt, *, flash, cache_dtype=jnp.float32,
@@ -544,6 +742,10 @@ def test_the_engine_reports_the_sparse_form_and_the_index_cache(engine):
         "topk": TOPK, "index_heads": 4,
         "form": {"prefill": "masked (dsa_flash kernel)",
                  "decode": "masked (xla)"},
+        # (the selection is the kernel's in both: a cache of 128 positions,
+        # one lane tile, has its decode layout)
+        "select": {"prefill": "dsa_select kernel",
+                   "decode": "dsa_select kernel"},
         "index_bytes_per_token": 2 * 8 * 2,
         "index_cache_bytes": 2 * 8 * 2 * 4 * 128}
     # K (16) + V (16) int8 + two float32 scales, 2 KV heads, 2 layers, and
@@ -625,6 +827,12 @@ def test_symtop_shows_the_selected_share_and_the_form_beside_the_tail():
                   "form": {"decode": "masked (decode kernel)"}}}}}
     rows = symtop.build_rows("prov", {}, None, now=0.0, engine=engine)
     assert rows[0]["dsa"] == "31% masked"
+    engine["startup"]["attention"]["sparse"]["select"] = {
+        "prefill": "dsa_select kernel", "decode": "dsa_select kernel"}
+    assert symtop.read_dsa(engine) == "31% masked/kernel"
+    engine["startup"]["attention"]["sparse"]["select"]["decode"] = "xla"
+    assert symtop.read_dsa(engine) == "31% masked/xla"
+    del engine["startup"]["attention"]["sparse"]["select"]
     head, first = symtop.render_table(rows).splitlines()[:2]
     assert head.split()[-4] == "DSA" and "31% masked" in first
     for other in (None, {}, {"dsa": {"candidates": 0, "selected": 0}}):

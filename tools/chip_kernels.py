@@ -13,6 +13,15 @@ by the Mosaic compiler only.
                      LM head), through pack_quantized + w8a16_apply
                      vs tests/test_qmm.py _reference_qmatmul
 
+  dsa_select         keye-vl-2.0-30b-a3b's indexer (16 heads of 64, topk
+                     2,048): a prompt of 6,144 and of 14,848 tokens and a
+                     decode step of 64 slots x 16,384 with 25 live of
+                     ~8.6k; EXACT equality with the `jnp` form (`select`
+                     over `index_scores`) on integer-valued inputs, where
+                     every score is exact and ties are plentiful; then both
+                     forms timed on normal draws (`--only select` runs
+                     these rows alone)
+
 Then the one timing question later PRs lean on: does
 `jax.block_until_ready` on this chip wait for completion? One decode block
 of chip_smoke.py's engine is timed under it, under the fetch fence of
@@ -26,6 +35,7 @@ compile or disagreed. Needs a TPU: `python tools/chip_kernels.py`.
 
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import sys
@@ -39,7 +49,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from _bench_util import sync  # noqa: E402
+from _bench_util import sync, timeit  # noqa: E402
 from symmetry_tpu.ops.attention import gqa_attention  # noqa: E402
 from symmetry_tpu.ops.decode_attention import decode_attention  # noqa: E402
 from symmetry_tpu.ops.flash import flash_prefill  # noqa: E402
@@ -165,6 +175,119 @@ def matmul_cases() -> list[dict]:
     return rows
 
 
+def select_cases(prompts=(6144, 14848), slots=64, capacity=16384,
+                 live=25, topk=2048, repeats=30) -> list[dict]:
+    """`dsa_select` (ops/sparse_attention.py) against the `jnp` form it
+    replaced, at keye-vl-2.0-30b-a3b's indexer: equal sets, and the
+    milliseconds of each — a prompt's tiles (the `jnp` form scores all S
+    columns whichever tile, so its last tile x the tiles is its prompt) and
+    one layer's decode step."""
+    from symmetry_tpu.ops import sparse_attention as sa
+
+    H, Di, tile, layers = 16, 64, sa.QUERY_TILE, 2
+
+    def draw(key, shape, whole):
+        return (jax.random.randint(key, shape, -3, 4) if whole
+                else jax.random.normal(key, shape)).astype(jnp.bfloat16)
+
+    def ms(fn, *args):
+        # calls back to back and ONE fence (a fence a call would time the
+        # dispatch and the fetch: ~1.8 ms here, more than a decode step's
+        # selection)
+        return round(timeit(fn, *args, n=repeats), 3)
+
+    def equal(name, pairs):
+        pairs = [(np.asarray(g), np.asarray(w)) for g, w in pairs]
+        wrong = sum(int((g != w).sum()) for g, w in pairs)
+        return {"kernel": name, "ok": wrong == 0, "mismatched": wrong,
+                "selected": sum(int(w.sum()) for _, w in pairs)}
+
+    rows = []
+    for S in prompts:
+        lens = jnp.asarray([S - 77], jnp.int32)
+
+        @jax.jit
+        def kernel(qi, ki, w, lens=lens):
+            return sa.prefill_keep(qi, ki, w, lens, topk)[0]
+
+        @jax.jit
+        def jnp_tile(qi, ki, w, t0, lens=lens):
+            part = lambda a: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                a, t0, tile, 1)
+            return sa._masks(part(qi), ki, part(w),
+                             (t0 + jnp.arange(tile, dtype=jnp.int32))[None],
+                             lens, topk)[0]
+
+        def inputs(whole, S=S):
+            k = jax.random.split(jax.random.key(S + whole), 3)
+            return (draw(k[0], (1, S, H, Di), whole),
+                    draw(k[1], (1, S, Di), whole),
+                    draw(k[2], (1, S, H), whole))
+
+        try:
+            args = inputs(True)
+            got = np.asarray(kernel(*args)) != 0
+            # the tile under topk (no pass), the first over it, one
+            # mid-prompt, the last (its end padded)
+            row = equal(
+                f"dsa_select prefill S={S} (sets, whole-number inputs)",
+                [(got[:, t0:t0 + tile], jnp_tile(*args, jnp.int32(t0)))
+                 for t0 in (topk - tile, topk, S // 2 // tile * tile,
+                            S - tile)])
+            args = inputs(False)
+            t_kernel = ms(kernel, *args)
+            t_tile = ms(jnp_tile, *args, jnp.int32(S - tile))
+            row.update(kernel_prompt_ms=t_kernel,
+                       kernel_tile_ms=round(t_kernel * tile / S, 3),
+                       jnp_tile_ms=t_tile,
+                       jnp_prompt_ms=round(t_tile * S / tile, 3))
+        except Exception as exc:  # noqa: BLE001 — the refusal IS the finding
+            row = {"kernel": f"dsa_select prefill S={S}", "ok": False,
+                   "error": f"{type(exc).__name__}: {exc}"[:2000]}
+        rows.append(row)
+
+    # decode: the cell's cache, 25 live slots scattered over 64, lengths as
+    # the traffic's (4,096-14,336 and what was generated since)
+    rng = np.random.default_rng(41)
+    lengths = np.zeros(slots, np.int32)
+    lengths[rng.choice(slots, live, replace=False)] = rng.integers(
+        capacity // 4, capacity * 7 // 8 + 1, live) + rng.integers(
+            0, capacity // 32, live)
+    lengths[np.flatnonzero(lengths)[:2]] = (topk - 5, capacity)
+    kv = jnp.asarray(lengths)
+    pos = jnp.maximum(kv - 1, 0)[:, None]
+
+    @jax.jit
+    def kernel(qi, idx, w, layer):
+        return sa.cache_keep(qi, idx, w, pos, kv, topk, layer=layer)[0]
+
+    @jax.jit
+    def jnp_form(qi, idx, w, layer):
+        return sa._masks(qi, jax.lax.dynamic_index_in_dim(
+            idx, layer, 0, keepdims=False), w, pos, kv, topk)[0]
+
+    try:
+        for whole in (True, False):
+            k = jax.random.split(jax.random.key(7 + whole), 3)
+            args = (draw(k[0], (slots, 1, H, Di), whole),
+                    draw(k[1], (layers, slots, capacity, Di), whole),
+                    draw(k[2], (slots, 1, H), whole), jnp.int32(1))
+            if whole:
+                row = equal(
+                    f"dsa_select decode {slots} x {capacity}, {live} live "
+                    f"of mean {int(lengths[lengths > 0].mean())} (sets, "
+                    f"whole-number inputs)",
+                    [(kernel(*args), jnp_form(*args))])
+            else:
+                row.update(kernel_ms=ms(kernel, *args),
+                           jnp_ms=ms(jnp_form, *args))
+    except Exception as exc:  # noqa: BLE001
+        row = {"kernel": "dsa_select decode", "ok": False,
+               "error": f"{type(exc).__name__}: {exc}"[:2000]}
+    rows.append(row)
+    return rows
+
+
 def fence_timing() -> dict:
     """One decode block of chip_smoke.py's engine (mistral-7b int8+kv8,
     8 slots × 4096, block 16), 10 blocks per fence."""
@@ -206,6 +329,10 @@ def fence_timing() -> dict:
 
 
 def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--only", choices=["select"],
+                    help="these rows alone, no fence timing")
+    only = ap.parse_args().only
     if interpret_mode() or jax.default_backend() != "tpu":
         print(f"chip_kernels needs a TPU; JAX gave "
               f"{jax.default_backend()!r}", file=sys.stderr)
@@ -213,14 +340,18 @@ def main() -> int:
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": jax.device_count()}
-    rows = flash_cases() + decode_cases() + matmul_cases()
+    rows = select_cases()
+    if only is None:
+        rows = flash_cases() + decode_cases() + matmul_cases() + rows
     for r in rows:
         print(json.dumps(r))
-    out = {"device": device, "jax": jax.__version__, "kernels": rows,
-           "fence": fence_timing()}
-    print(json.dumps(out["fence"]))
+    out = {"device": device, "jax": jax.__version__, "kernels": rows}
+    if only is None:
+        out["fence"] = fence_timing()
+        print(json.dumps(out["fence"]))
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(REPO, "chiprun_out", "chip_kernels.json"),
+    with open(os.path.join(REPO, "chiprun_out",
+                           f"chip_kernels{'.' + only if only else ''}.json"),
               "w") as fh:
         json.dump(out, fh, indent=1)
     failed = [r["kernel"] for r in rows if not r["ok"]]

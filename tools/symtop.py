@@ -55,7 +55,7 @@ COLUMNS = ("PROVIDER", "TIER", "TOK/S", "TTFT p50", "TTFT p99",
            "LINK", "STATE", "SHARE", "HIT", "TARGET", "SCALE",
            "DSA", "AHEAD", "STALLS", "TAIL")
 WIDTHS = (22, 10, 9, 9, 9, 7, 6, 5, 5, 7, 7, 7, 7, 6, 7, 6, 7, 6,
-          9, 6, 6, 9, 6, 11, 11, 9, 6)
+          9, 6, 6, 9, 6, 18, 11, 9, 6)
 
 # sym_pool_member_state gauge encoding (engine/disagg/pool.py
 # STATE_CODES) rendered back to the membership lifecycle names.
@@ -264,15 +264,18 @@ def read_dsa(engine: dict | None) -> str | None:
     """Learned sparse attention (the stats reply's `engine.dsa` counters
     and `startup.attention.sparse`; None for any other model): DSA =
     selected / candidates since start as a percentage — how sparse the
-    attention ran — and the decode program's form (`masked`, or `gather`
-    once one is built)."""
+    attention ran — the decode program's form (`masked`, or `gather` once
+    one is built) and what makes its selection (`/kernel`: dsa_select;
+    `/xla`)."""
     dsa = (engine or {}).get("dsa")
     if not dsa or not dsa.get("candidates"):
         return None
-    form = ((((engine.get("startup") or {}).get("attention") or {})
-             .get("sparse") or {}).get("form") or {}).get("decode", "")
-    return (f"{100.0 * dsa['selected'] / dsa['candidates']:.0f}% "
-            f"{form.split(' ')[0]}").strip()
+    sparse = (((engine.get("startup") or {}).get("attention") or {})
+              .get("sparse") or {})
+    form = (sparse.get("form") or {}).get("decode", "").split(" ")[0]
+    select = (sparse.get("select") or {}).get("decode", "").split(" ")[-1]
+    return (f"{100.0 * dsa['selected'] / dsa['candidates']:.0f}% {form}"
+            + (f"/{select}" if select else "")).strip()
 
 
 def build_rows(name: str, fams: dict, prev: dict | None, now: float,
