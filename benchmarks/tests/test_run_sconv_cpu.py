@@ -1,0 +1,61 @@
+"""The lfm2 cell's shape rehearsed through `run.py` on the CPU: `tiny-sconv`
+(gated short convolutions among two attention layers, two dense layers, 8
+experts top 2 by sigmoid scores and a selection bias), int8 weights and int8
+KV, a closed loop, every metric file of the real cell. Every phase runs — the
+tails are installed, stepped and reused lane after lane by real traffic —
+every reader is walked, and then it REFUSES: non-zero exit, nothing on stdout, because the
+engine host's platform is not tpu."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+from conftest import BENCH, CHECKOUT, TESTS
+
+RUN = os.path.join(BENCH, "run.py")
+ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
+REAL_CELL = "lfm2-8b-a1b.batch-closed"
+CELL = "tiny-sconv.tiny-closed"
+
+
+def test_sconv_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
+    data = tmp_path / "data"
+    shutil.copytree(os.path.join(TESTS, "data"), data)
+    real = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
+    mine = [m for m in real["per_layer"]
+            if m.get("workloads") == [REAL_CELL]]
+    assert len(mine) == 13
+    m = json.load(open(data / "BENCHMARK.tiny.json"))
+    m["configs"].append({"name": "tiny-sconv", "source": "test preset",
+                         "file": "configs/tiny-sconv.json", "reduced": [],
+                         "why": "CPU rehearsal of the lfm2_moe model"})
+    m["workloads"].append({"name": CELL, "config": "tiny-sconv",
+                           "traffic": "tiny-closed", "chips": 1,
+                           "why": "rehearsal"})
+    m["per_layer"] += [dict(e, workloads=[CELL]) for e in mine]
+    json.dump(m, open(data / "BENCHMARK.tiny.json", "w"))
+    out = subprocess.run(
+        [sys.executable, RUN, "--workload", CELL, "--seed", "3000000042",
+         "--seconds", "3", "--trace", "1", "--manifest",
+         str(data / "BENCHMARK.tiny.json")], cwd=CHECKOUT, env=ENV,
+        capture_output=True, text=True, timeout=900)
+    assert out.returncode != 0 and out.stdout.strip() == "", out.stdout
+    assert "not tpu" in out.stderr, out.stderr[-3000:]
+    lines = [ln for ln in out.stderr.splitlines() if "rehearsal:" in ln]
+    assert lines, out.stderr[-3000:]
+    line = lines[-1]
+    assert "correct=True" in line and "failed=0" in line, line
+    # every reader that needs no device trace found something to read
+    for name in ("gap_p99_s", "setup_s", "sconv_prefill_tok_s",
+                 "sconv_installs_per_s", "moe_expert_imbalance.sconv",
+                 "wire_out_tok_s.sconv", "decode_step_ms.sconv",
+                 "sched_occupancy.sconv", "kv_fill.sconv", "wire_tpot_p50_ms",
+                 "admit_share"):
+        assert f"'{name}'" in line, line
+    # ... and the trace readers found no device plane (nor the CPU a
+    # memory limit), and said nothing
+    for name in ("sconv_decode_hbm_share", "sconv_prefill_mxu_share",
+                 "sconv_state_hbm_share", "hbm_used.sconv"):
+        assert f"'{name}'" not in line, line

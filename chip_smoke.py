@@ -54,6 +54,14 @@ contends for the chip its child needs.
         # key cache of its own; the report's `attention.sparse` carries
         # topk, the form of each program — the smoke fails without one —
         # and the index cache's bytes), 128 experts top 8 (`moe`)
+    python chip_smoke.py --preset lfm2-8b-a1b
+        # LFM2-8B-A1B whole on one chip (18 gated short convolutions whose
+        # state is a two-position tail, six GQA layers of 64-wide heads,
+        # two dense layers, 22 x 32 experts top 4 by sigmoid scores + a
+        # selection bias; `ssm.kind` short_conv, `moe.router.score`
+        # sigmoid, `moe.grouped_matmul` pallas): its heads of 64 are no
+        # lane tile, so decode attention is held to `xla` WITH its reason
+        # and prefill to the flash kernel
     JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny
         # CPU dry run of every phase; ends non-zero: "platform is cpu"
 """
@@ -81,6 +89,12 @@ DRAIN_TIMEOUT_S = 120.0
 # presets whose attention runs under a learned selection (by name: this
 # process may not import jax to ask)
 SPARSE_PRESETS = ("keye-vl-2.0-30b-a3b", "tiny-dsa")
+
+
+# presets whose heads are no lane tile (64): the decode-attention kernel has
+# no geometry for them, so decode takes the XLA path and must say why
+# (models/llama.py attention_paths; PERF.md section 4)
+XLA_DECODE_PRESETS = ("lfm2-8b-a1b",)
 
 
 class SmokeFailure(Exception):
@@ -311,7 +325,13 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
                         f"after a full start-up")
     # One chip or a mesh (8 x 4,096 is over the sharded trunk's floor):
     # both programs through compiled kernels.
-    if (attention.get("prefill"), attention.get("decode")) != (
+    if cfg["tpu"]["model_preset"] in XLA_DECODE_PRESETS:
+        if ((attention.get("prefill"), attention.get("decode")) != (
+                "pallas", "xla") or not attention.get("decode_why")):
+            failures.append(f"a head of 64 takes the flash kernel at "
+                            f"prefill and the XLA path, with its reason, at "
+                            f"decode: {attention}")
+    elif (attention.get("prefill"), attention.get("decode")) != (
             "pallas", "pallas"):
         failures.append(f"attention did not run compiled Pallas kernels "
                         f"in both programs: {attention}")

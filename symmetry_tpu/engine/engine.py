@@ -568,9 +568,11 @@ class InferenceEngine:
                    if self.kv_quant else {}),
                 # The whole recurrent state of the lane is overwritten:
                 # this copy is also the lane's reset (a length of 0 is not).
-                **({"ssm": place(state.cache.ssm, prefix.ssm),
-                    "conv": place(state.cache.conv, prefix.conv, axis=2)}
+                # (a short convolution's state is its tail: no `ssm` leaf)
+                **({"ssm": place(state.cache.ssm, prefix.ssm)}
                    if state.cache.ssm is not None else {}),
+                **({"conv": place(state.cache.conv, prefix.conv, axis=2)}
+                   if state.cache.conv is not None else {}),
                 # a sparse-attention row brings its index keys along
                 **({"idx": place(state.cache.idx, prefix.idx)}
                    if state.cache.idx is not None else {}),
@@ -1347,33 +1349,47 @@ class InferenceEngine:
         """`startup.ssm`: the state kept, each program's form; or None."""
         if not self._has_state:
             return None
-        from symmetry_tpu.models import gdn, mamba2
+        from symmetry_tpu.models import gdn, mamba2, sconv
         from symmetry_tpu.models.hybrid import state_bytes_per_slot
 
         c = self.config
+        cache = self.state.cache
         per_slot = state_bytes_per_slot(c, self.cache_dtype)
-        itemsize = self.state.cache.ssm.dtype.itemsize
+        shared = {
+            "attention_layers": len(c.layers_of(c.attention_kind)),
+            "state_bytes": sum(per_slot.values()) * self.max_slots,
+            "prefill_rows_max": self._state_rows_max(),
+            "scratch_rows_max": 2 * self._state_rows_max(),
+        }
+        if c.recurrent_kind == "conv":
+            # the tail IS the state: `state_*` count it, no `ssm` leaf
+            return {
+                "kind": "short_conv", "layers": len(c.layers_of("conv")),
+                "taps": c.conv_L_cache, **shared,
+                "state_bytes_per_slot": per_slot["conv"],
+                "state_dtype": str(cache.conv.dtype),
+                "prefill": {"form": "whole prompt, rows stop at their "
+                                    "lengths"},
+                "decode": sconv.step_form(c),
+            }
         if c.recurrent_kind == "mamba":
             kind = {"kind": "mamba2",
                     "mamba_layers": len(c.layers_of("mamba"))}
-            chunk, decode = c.mamba_chunk_size, mamba2.step_form(c, itemsize)
+            chunk, decode = c.mamba_chunk_size, mamba2.step_form(
+                c, cache.ssm.dtype.itemsize)
         else:
             kind = {"kind": "gated_deltanet",
                     "linear_attention_layers":
                         len(c.layers_of("linear_attention"))}
             chunk, decode = c.linear_chunk_size, gdn.step_form(c)
         return {
-            **kind,
-            "attention_layers": len(c.layers_of(c.attention_kind)),
+            **kind, **shared,
             "state_bytes_per_slot": per_slot["ssm"],
             "conv_bytes_per_slot": per_slot["conv"],
-            "state_bytes": sum(per_slot.values()) * self.max_slots,
-            "state_dtype": str(self.state.cache.ssm.dtype),
-            "conv_dtype": str(self.state.cache.conv.dtype),
+            "state_dtype": str(cache.ssm.dtype),
+            "conv_dtype": str(cache.conv.dtype),
             "prefill": {"form": "chunked (jnp)", "chunk": chunk},
             "decode": decode,
-            "prefill_rows_max": self._state_rows_max(),
-            "scratch_rows_max": 2 * self._state_rows_max(),
         }
 
     def extract_slot_kv(self, slot: int, p: int):
@@ -2075,6 +2091,14 @@ class InferenceEngine:
                 "matmul's operand, scales on the accumulator"
                 if isinstance(wg, QuantizedTensor) else "not quantized"),
         }
+        if getattr(c, "router_score", "softmax") == "sigmoid":
+            # lfm2_moe: the router's form, and which layers have experts
+            self._moe_report["router"] = {
+                "score": "sigmoid", "bias": bool(c.router_bias),
+                "norm_topk": True, "scale": float(c.routed_scaling_factor)}
+            self._moe_report["dense_layers"] = c.num_dense_layers
+            self._moe_report["expert_layers"] = (c.num_layers
+                                                 - c.num_dense_layers)
         if c.shared_intermediate_size:
             self._moe_report["shared_expert"] = {
                 "width": c.shared_intermediate_size,

@@ -489,7 +489,7 @@ class EngineHost:
                 m["role"] = self._role
                 m["startup"] = self._startup
                 if "ssm" in self._startup:
-                    # valid prompt tokens the mamba layers scanned and
+                    # valid prompt tokens the recurrent layers scanned and
                     # lanes whose state an insert overwrote, since start
                     m["ssm"] = dict(self._engine.ssm_counters)
                 m["compile"] = self._compile.stats()

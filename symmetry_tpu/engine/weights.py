@@ -55,16 +55,17 @@ def convert_hf_state_dict(
     tensors: dict[str, np.ndarray], config: ModelConfig
 ) -> dict:
     """Convert a full in-memory HF llama/mixtral state dict to our pytree
-    (a granitemoehybrid or qwen3_next one through models/hybrid.py's name
-    maps)."""
+    (a granitemoehybrid, qwen3_next or lfm2_moe one through
+    models/hybrid.py's name maps)."""
     if getattr(config, "layer_types", None):
         from symmetry_tpu.models import hybrid
 
         try:
             return hybrid.convert_hf_state_dict(tensors, config)
         except (KeyError, ValueError) as exc:
-            family = ("qwen3_next" if config.recurrent_kind
-                      == "linear_attention" else "granitemoehybrid")
+            family = {"linear_attention": "qwen3_next",
+                      "conv": "lfm2_moe"}.get(config.recurrent_kind,
+                                              "granitemoehybrid")
             raise CheckpointError(f"{family} checkpoint: {exc}")
     n_exp = getattr(config, "num_experts", 0)
     router_name, experts_module, expert_map = hf_moe_names(config)

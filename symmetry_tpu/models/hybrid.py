@@ -1,9 +1,10 @@
 """Decoder whose layers are of two mixer kinds: recurrent layers with a
-per-slot state — Mamba-2 (models/mamba2.py; granitemoehybrid family) or
-Gated DeltaNet (models/gdn.py; qwen3_next family, whose `layer_types` say
-"linear_attention" / "full_attention") — among attention layers
-(models/llama.py `_attention`), every one followed by the routed + shared
-expert FFN (models/moe.py).
+per-slot state — Mamba-2 (models/mamba2.py; granitemoehybrid family), Gated
+DeltaNet (models/gdn.py; qwen3_next family, whose `layer_types` say
+"linear_attention" / "full_attention") or a gated short convolution
+(models/sconv.py; lfm2_moe family: "conv" / "full_attention") — among
+attention layers (models/llama.py `_attention`), every one followed by the
+routed + shared expert FFN (models/moe.py).
 
     h = embed[tokens] * embedding_multiplier
     for layer i:   h = h + r * mixer_i(rms_norm(h))
@@ -41,6 +42,18 @@ stack is `layers.gdn`, its state rides the same two cache leaves — `ssm`
 follow the call's shape in the same way (models/gdn.py `step_at`,
 `chunked`).
 
+An lfm2_moe model is the same trunk with a third recurrent kind, whose
+state is a tail ALONE: its stack is `layers.sconv` (a gated short
+convolution, models/sconv.py), the cache has no `ssm` leaf (None) and `conv`
+[n_conv, K - 1, B, E] is all a slot keeps for those layers; its attention
+layers norm q and k per head and rotate every channel; and its FFN is of TWO
+kinds — the first `num_dense_layers` layers end in a dense SwiGLU (stack
+`layers.dense` [n_dense, ...]), the rest in routed experts (stack
+`layers.ffn` [num_layers - n_dense, ...], indexed by a layer's place among
+the expert layers) chosen by a sigmoid router with a selection bias
+(models/moe.py `route_top_k`). A run (`runs`) breaks where the mixer kind OR
+the FFN kind changes, so one scan body still holds one kind of each.
+
 One device only: there are no sharding rules for the state yet.
 """
 
@@ -51,12 +64,15 @@ import math
 import jax
 import jax.numpy as jnp
 
-from symmetry_tpu.models import gdn, llama, mamba2
+from symmetry_tpu.models import gdn, llama, mamba2, sconv
 from symmetry_tpu.models.moe import moe_mlp
 from symmetry_tpu.ops.norm import rms_norm
+from symmetry_tpu.ops.quant import qmatmul
 
 KIND_STACK = {"mamba": "mamba", "attention": "attn",
-              "linear_attention": "gdn", "full_attention": "attn"}
+              "linear_attention": "gdn", "full_attention": "attn",
+              "conv": "sconv"}
+RECURRENT = {"mamba": mamba2, "linear_attention": gdn, "conv": sconv}
 
 
 def stack_index(config, i: int) -> int:
@@ -64,10 +80,14 @@ def stack_index(config, i: int) -> int:
     return config.layers_of(config.layer_types[i]).index(i)
 
 
-def state_shapes(config, batch: int) -> tuple[tuple, tuple]:
+def state_shapes(config, batch: int) -> tuple[tuple | None, tuple]:
     """The shapes of the cache's `ssm` and `conv` leaves: a stack over the
-    recurrent layers of this model's kind."""
+    recurrent layers of this model's kind. A short convolution's state is
+    its tail alone: no `ssm` leaf (None)."""
     n = len(config.layers_of(config.recurrent_kind))
+    if config.recurrent_kind == "conv":
+        z = sconv.sizes(config)
+        return None, (n, z["K"] - 1, batch, z["conv"])
     if config.recurrent_kind == "mamba":
         z = mamba2.sizes(config)
         return ((n, batch, z["H"], z["P"], z["N"]),
@@ -80,7 +100,7 @@ def state_shapes(config, batch: int) -> tuple[tuple, tuple]:
 def state_bytes_per_slot(config, dtype=jnp.bfloat16) -> dict:
     """What one slot holds that is not a row per position."""
     ssm, conv = state_shapes(config, 1)
-    return {"ssm": math.prod(ssm) * 4,
+    return {"ssm": math.prod(ssm) * 4 if ssm else 0,
             "conv": math.prod(conv) * jnp.dtype(dtype).itemsize}
 
 
@@ -100,7 +120,7 @@ def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
         v_scale=jnp.zeros(scale_shape, jnp.float32) if quantized else None,
         expert_pairs=(jnp.zeros((config.num_experts,), jnp.int32)
                       if count_experts else None),
-        ssm=jnp.zeros(ssm, jnp.float32),
+        ssm=jnp.zeros(ssm, jnp.float32) if ssm else None,
         conv=jnp.zeros(conv, dtype),
     )
 
@@ -129,6 +149,8 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
 
     if c.recurrent_kind == "linear_attention":
         return _init_qwen3_next(c, keys, dense, dtype)
+    if c.recurrent_kind == "conv":
+        return _init_lfm2(c, keys, dense, dtype)
     L, E, F = c.num_layers, c.hidden_size, c.intermediate_size
     X, Fs = c.num_experts, c.shared_intermediate_size
     Lm, La = len(c.layers_of("mamba")), len(c.layers_of("attention"))
@@ -231,6 +253,61 @@ def _init_qwen3_next(c, keys, dense, dtype) -> dict:
     return params
 
 
+def _init_lfm2(c, keys, dense, dtype) -> dict:
+    """`init_params` for an lfm2_moe config. `expert_bias` is drawn uniform
+    in [-0.25, 0.25]: the published initial value is zero, under which
+    leaving the bias out of the selection changes nothing and no comparison
+    could tell (benchmarks/configs/lfm2-8b-a1b.json `weights`)."""
+    z = sconv.sizes(c)
+    E, F, X, D = c.hidden_size, c.intermediate_size, c.num_experts, \
+        c.dim_per_head
+    Lc, La = len(c.layers_of("conv")), len(c.layers_of("full_attention"))
+    Ld, Fd = c.num_dense_layers, c.dense_intermediate_size
+    Lx = c.num_layers - Ld
+    params = {
+        "embed": dense((c.vocab_size, E), scale=0.02),
+        "layers": {
+            "sconv": {
+                "norm": jnp.ones((Lc, E), dtype),
+                "in_proj": dense((Lc, E, z["proj"]), "in_proj"),
+                "conv_w": dense((Lc, z["K"], z["conv"]),
+                                scale=z["K"] ** -0.5),
+                "out_proj": dense((Lc, E, E), "out_proj"),
+            },
+            "attn": {
+                "norm": jnp.ones((La, E), dtype),
+                "wq": dense((La, E, c.q_dim), "wq"),
+                "wk": dense((La, E, c.kv_dim), "wk"),
+                "wv": dense((La, E, c.kv_dim), "wv"),
+                "wo": dense((La, c.q_dim, E), "wo"),
+                "q_norm": jnp.ones((La, D), dtype),
+                "k_norm": jnp.ones((La, D), dtype),
+            },
+            "ffn": {
+                "norm": jnp.ones((Lx, E), dtype),
+                "router": dense((Lx, E, X)),
+                "wg": dense((Lx, X, E, F), "wg"),
+                "wu": dense((Lx, X, E, F), "wu"),
+                "wd": dense((Lx, X, F, E), "wd"),
+            },
+        },
+        "final_norm": jnp.ones((E,), dtype),
+    }
+    if Ld:
+        params["layers"]["dense"] = {
+            "norm": jnp.ones((Ld, E), dtype),
+            "wg": dense((Ld, E, Fd), "wg"),
+            "wu": dense((Ld, E, Fd), "wu"),
+            "wd": dense((Ld, Fd, E), "wd"),
+        }
+    if c.router_bias:
+        params["layers"]["ffn"]["expert_bias"] = jax.random.uniform(
+            next(keys), (Lx, X), jnp.float32, -0.25, 0.25)
+    if not c.tie_embeddings:
+        params["lm_head"] = dense((E, c.vocab_size), "lm_head", scale=0.02)
+    return params
+
+
 def state_refusals(*, mesh: bool = False, role: str = "unified",
                    prefix_cache: bool = False, speculative: bool = False,
                    prefill_chunk: int | None = None) -> list[str]:
@@ -243,8 +320,8 @@ def state_refusals(*, mesh: bool = False, role: str = "unified",
     if prefix_cache:
         why.append(
             "tpu.prefix_cache_mb: a cached prefix holds K/V rows and no "
-            "recurrent state, so a hit would resume the mamba layers from "
-            "nothing — leave it unset for a model with recurrent layers")
+            "recurrent state, so a hit would resume the recurrent layers "
+            "from nothing — leave it unset for a model with recurrent layers")
     if speculative:
         why.append(
             "tpu.speculative: a rejected draft is rolled back by lengths "
@@ -269,10 +346,14 @@ def state_refusals(*, mesh: bool = False, role: str = "unified",
 
 
 def runs(config) -> list[tuple[str, int, int]]:
-    """The pattern as runs of one mixer kind: (kind, first layer, length)."""
+    """The pattern as runs of one mixer kind AND one FFN kind: (mixer kind,
+    first layer, length). A run breaks where either changes (lfm2_moe's
+    leading dense layers end at `num_dense_layers`), so one scan body holds
+    one kind of each; the run's FFN kind is `config.ffn_kind(first)`."""
     out: list[tuple[str, int, int]] = []
     for i, kind in enumerate(config.layer_types):
-        if out and out[-1][0] == kind:
+        if (out and out[-1][0] == kind
+                and config.ffn_kind(out[-1][1]) == config.ffn_kind(i)):
             out[-1] = (kind, out[-1][1], out[-1][2] + 1)
         else:
             out.append((kind, i, 1))
@@ -314,7 +395,8 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
     h = h * jnp.asarray(c.embedding_multiplier, h.dtype)
     r = jnp.asarray(c.residual_multiplier, h.dtype)
 
-    recurrent = gdn if c.recurrent_kind == "linear_attention" else mamba2
+    recurrent = RECURRENT[c.recurrent_kind]
+    n_dense = c.num_dense_layers
 
     def norm(h, w):
         return rms_norm(h, llama._norm_w(w, c), c.rms_eps)
@@ -329,12 +411,21 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
                                                conv, c)
             return out[:, None], cache._replace(
                 ssm=ssm, conv=cache.conv.at[j].set(conv))
+        if cache.ssm is None:  # a tail is the kind's whole state
+            if prefill_flash:
+                conv = jnp.zeros_like(conv)
+            out, _, conv = recurrent.chunked(x, lp, None, conv, seq_lens, c)
+            return out, cache._replace(conv=cache.conv.at[j].set(conv))
         ssm = _at(cache.ssm, j)
         if prefill_flash:  # from empty, whatever the buffer holds
             ssm, conv = jnp.zeros_like(ssm), jnp.zeros_like(conv)
         out, ssm, conv = recurrent.chunked(x, lp, ssm, conv, seq_lens, c)
         return out, cache._replace(ssm=cache.ssm.at[j].set(ssm),
                                    conv=cache.conv.at[j].set(conv))
+
+    def dense_ffn(x, lp):
+        return qmatmul(llama._act(qmatmul(x, lp["wg"]), c)
+                       * qmatmul(x, lp["wu"]), lp["wd"])
 
     for kind, first, length in runs(c):
         j0 = stack_index(c, first)
@@ -345,9 +436,14 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
             out, cache = mixer(kind, norm(h, lp["norm"]), lp, cache,
                                j0 + step)
             h = h + r * out
-            lp = _at(layers["ffn"], first + step)
+            if first < n_dense:  # a run is of one FFN kind (`runs`)
+                lp = _at(layers["dense"], first + step)
+                return (h + r * dense_ffn(norm(h, lp["norm"]), lp),
+                        cache), None
+            # the layer's index among the expert layers
+            lp = _at(layers["ffn"], first - n_dense + step)
             y, pairs = moe_mlp(norm(h, lp["norm"]), lp, c, seq_lens,
-                               stack=(layers["ffn"], first + step))
+                               stack=(layers["ffn"], first - n_dense + step))
             h = h + r * y
             if cache.expert_pairs is not None:
                 cache = cache._replace(
@@ -397,6 +493,28 @@ def hf_config(config) -> dict:
     reference — `benchmarks/reference/hybrid_decoder.py`, or
     `gdn_moe_decoder.py` for a qwen3_next config — is given)."""
     c = config
+    if c.recurrent_kind == "conv":
+        return {
+            "architectures": ["Lfm2MoeForCausalLM"],
+            "model_type": "lfm2_moe",
+            "vocab_size": c.vocab_size, "hidden_size": c.hidden_size,
+            "num_hidden_layers": c.num_layers,
+            "layer_types": list(c.layer_types),
+            "num_attention_heads": c.num_heads,
+            "num_key_value_heads": c.num_kv_heads,
+            "head_dim": c.dim_per_head,
+            "intermediate_size": c.dense_intermediate_size,
+            "moe_intermediate_size": c.intermediate_size,
+            "num_dense_layers": c.num_dense_layers,
+            "num_experts": c.num_experts,
+            "num_experts_per_tok": c.num_experts_per_tok,
+            "norm_topk_prob": True, "use_expert_bias": c.router_bias,
+            "routed_scaling_factor": c.routed_scaling_factor,
+            "conv_L_cache": c.conv_L_cache, "conv_bias": False,
+            "rope_theta": c.rope_theta, "norm_eps": c.rms_eps,
+            "tie_embedding": c.tie_embeddings,
+            "max_position_embeddings": c.max_position,
+        }
     if c.recurrent_kind == "linear_attention":
         return {
             "architectures": ["Qwen3NextForCausalLM"],
@@ -474,6 +592,8 @@ def convert_hf_state_dict(tensors: dict, config) -> dict:
 
     if config.recurrent_kind == "linear_attention":
         return _qwen3_next_from_hf(tensors, config)
+    if config.recurrent_kind == "conv":
+        return _lfm2_from_hf(tensors, config)
     known = set(HF_TOP)
     stacks: dict = {"mamba": {}, "attn": {}, "ffn": {}}
     for i, kind in enumerate(config.layer_types):
@@ -503,6 +623,8 @@ def to_hf_state_dict(params: dict, config) -> dict:
 
     if config.recurrent_kind == "linear_attention":
         return _qwen3_next_to_hf(params, config)
+    if config.recurrent_kind == "conv":
+        return _lfm2_to_hf(params, config)
 
     def arr(a):
         return np.asarray(a, np.float32)
@@ -672,4 +794,115 @@ def _qwen3_next_to_hf(params: dict, config) -> dict:
             for e in range(config.num_experts):
                 out[f"{prefix}mlp.experts.{e}.{hf}.weight"] = arr(
                     lay["ffn"][name][i][e]).T
+    return out
+
+
+# ---------------------------------------------------------------------------
+# HF `lfm2_moe` checkpoint names. A layer's two norms are `operator_norm` and
+# `ffn_norm`; the short convolution is `conv.in_proj` ([3E, E]: the rows of
+# B, then C, then x), `conv.conv` ([E, 1, K], a cross-correlation: tap K-1
+# meets the current position; ours [K, E]) and `conv.out_proj`; attention's
+# output projection is `out_proj` and its per-head norms `q_layernorm` /
+# `k_layernorm`; `feed_forward` is w1 / w3 / w2 (gate, up, down) in a dense
+# layer and `gate` (the router), `expert_bias` and `experts.{e}.w1|w3|w2` in
+# an expert layer; the final norm is `embedding_norm`, the head is tied.
+
+LFM2_MIXER = {
+    "conv": {"operator_norm.weight": "norm",
+             "conv.in_proj.weight": "in_proj",
+             "conv.conv.weight": "conv_w",
+             "conv.out_proj.weight": "out_proj"},
+    "full_attention": {
+        "operator_norm.weight": "norm",
+        "self_attn.q_proj.weight": "wq", "self_attn.k_proj.weight": "wk",
+        "self_attn.v_proj.weight": "wv", "self_attn.out_proj.weight": "wo",
+        "self_attn.q_layernorm.weight": "q_norm",
+        "self_attn.k_layernorm.weight": "k_norm"},
+}
+LFM2_FFN = {"dense": {"ffn_norm.weight": "norm",
+                      "feed_forward.w1.weight": "wg",
+                      "feed_forward.w3.weight": "wu",
+                      "feed_forward.w2.weight": "wd"},
+            "moe": {"ffn_norm.weight": "norm",
+                    "feed_forward.gate.weight": "router",
+                    "feed_forward.expert_bias": "expert_bias"}}
+LFM2_EXPERT = {"w1": "wg", "w3": "wu", "w2": "wd"}
+LFM2_TOP = {"model.embed_tokens.weight": "embed",
+            "model.embedding_norm.weight": "final_norm"}
+FFN_STACK = {"dense": "dense", "moe": "ffn"}
+
+
+def _lfm2_ffn_names(config) -> dict:
+    names = dict(LFM2_FFN["moe"])
+    if not config.router_bias:
+        del names["feed_forward.expert_bias"]
+    return {"dense": LFM2_FFN["dense"], "moe": names}
+
+
+def _lfm2_from_hf(tensors: dict, config) -> dict:
+    import numpy as np
+
+    def ours_of(name, a):
+        if name == "conv_w":
+            return np.ascontiguousarray(a[:, 0, :].T)           # [K, E]
+        return np.swapaxes(a, -1, -2) if a.ndim >= 2 else a
+
+    ffn_names = _lfm2_ffn_names(config)
+    known = set(LFM2_TOP)
+    stacks: dict = {"sconv": {}, "attn": {}, "dense": {}, "ffn": {}}
+    for i, kind in enumerate(config.layer_types):
+        prefix = f"model.layers.{i}."
+        for hf, name in LFM2_MIXER[kind].items():
+            known.add(prefix + hf)
+            stacks[KIND_STACK[kind]].setdefault(name, []).append(
+                ours_of(name, tensors[prefix + hf]))
+        ffn = config.ffn_kind(i)
+        for hf, name in ffn_names[ffn].items():
+            known.add(prefix + hf)
+            stacks[FFN_STACK[ffn]].setdefault(name, []).append(
+                ours_of(name, tensors[prefix + hf]))
+        if ffn == "moe":
+            for hf, name in LFM2_EXPERT.items():
+                names = [f"{prefix}feed_forward.experts.{e}.{hf}.weight"
+                         for e in range(config.num_experts)]
+                known.update(names)
+                stacks["ffn"].setdefault(name, []).append(
+                    np.stack([tensors[n].T for n in names]))
+    unmapped = sorted(set(tensors) - known - {"lm_head.weight"})
+    if unmapped:
+        raise ValueError(f"unmapped HF tensors: {unmapped[:4]}")
+    return {"embed": tensors["model.embed_tokens.weight"],
+            "final_norm": tensors["model.embedding_norm.weight"],
+            "layers": {stack: {k: np.stack(v) for k, v in leaves.items()}
+                       for stack, leaves in stacks.items() if leaves}}
+
+
+def _lfm2_to_hf(params: dict, config) -> dict:
+    import numpy as np
+
+    def arr(a):
+        return np.asarray(a, np.float32)
+
+    def hf_of(name, a):
+        if name == "conv_w":
+            return a.T[:, None, :]
+        return np.swapaxes(a, -1, -2) if a.ndim >= 2 else a
+
+    ffn_names = _lfm2_ffn_names(config)
+    lay = params["layers"]
+    out = {"model.embed_tokens.weight": arr(params["embed"]),
+           "model.embedding_norm.weight": arr(params["final_norm"])}
+    for i, kind in enumerate(config.layer_types):
+        prefix, j = f"model.layers.{i}.", stack_index(config, i)
+        for hf, name in LFM2_MIXER[kind].items():
+            out[prefix + hf] = hf_of(name, arr(lay[KIND_STACK[kind]][name][j]))
+        ffn = config.ffn_kind(i)
+        at = i if ffn == "dense" else i - config.num_dense_layers
+        for hf, name in ffn_names[ffn].items():
+            out[prefix + hf] = hf_of(name, arr(lay[FFN_STACK[ffn]][name][at]))
+        if ffn == "moe":
+            for hf, name in LFM2_EXPERT.items():
+                for e in range(config.num_experts):
+                    out[f"{prefix}feed_forward.experts.{e}.{hf}.weight"] = \
+                        arr(lay["ffn"][name][at][e]).T
     return out

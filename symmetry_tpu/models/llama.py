@@ -115,12 +115,14 @@ class MoEConfig(ModelConfig):
 
 @dataclass(frozen=True)
 class HybridConfig(MoEConfig):
-    """Layers of two mixer kinds in one model (granitemoehybrid and
-    qwen3_next families): `layer_types[i]` is "mamba" (a Mamba-2 mixer with
-    a per-slot recurrent state, models/mamba2.py) or "attention" (GQA, here
-    without rotary embedding) — or, qwen3_next's pair, "linear_attention"
-    (Gated DeltaNet, models/gdn.py) or "full_attention" — each followed by
-    the routed + shared expert FFN. The forward pass, parameters and cache
+    """Layers of two mixer kinds in one model (granitemoehybrid, qwen3_next
+    and lfm2_moe families): `layer_types[i]` is "mamba" (a Mamba-2 mixer
+    with a per-slot recurrent state, models/mamba2.py) or "attention" (GQA,
+    here without rotary embedding) — or, qwen3_next's pair,
+    "linear_attention" (Gated DeltaNet, models/gdn.py) or "full_attention"
+    — or, lfm2_moe's, "conv" (a gated short convolution, models/sconv.py) or
+    "full_attention" — each followed by the routed (+ shared) expert FFN,
+    lfm2_moe's first `num_dense_layers` by a dense SwiGLU instead. The forward pass, parameters and cache
     are models/hybrid.py's; every entry point of this module hands a config
     with `layer_types` over to it."""
 
@@ -150,16 +152,43 @@ class HybridConfig(MoEConfig):
     qk_norm: bool = False             # per-head RMSNorm on q and k
     attn_output_gate: bool = False    # wq is [E, 2 * q_dim]: (q | gate) a head
     shared_expert_gate: bool = False  # shared(x) * sigmoid(x . w_sg)
+    # lfm2_moe family, every field at "absent" for any other model:
+    # `layer_types[i]` is "conv" (a gated short convolution of `conv_L_cache`
+    # taps whose only state is its last taps - 1 inputs, models/sconv.py) or
+    # "full_attention" (GQA, q and k normed per head, full rotary, no gate);
+    # the first `num_dense_layers` layers end in a dense SwiGLU of
+    # `dense_intermediate_size` (stack `layers.dense`), the rest in experts
+    # chosen by `router_score` "sigmoid": scores sigmoid(logits), selection
+    # by score + `expert_bias` (`router_bias`), gates the selected's unbiased
+    # scores renormalised and times `routed_scaling_factor` (models/moe.py).
+    conv_L_cache: int = 0
+    num_dense_layers: int = 0
+    dense_intermediate_size: int = 0
+    router_score: str = "softmax"     # "softmax" | "sigmoid"
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
         kinds = set(self.layer_types)
         if (len(self.layer_types) != self.num_layers
                 or not (kinds <= {"mamba", "attention"}
-                        or kinds <= {"linear_attention", "full_attention"})):
+                        or kinds <= {"linear_attention", "full_attention"}
+                        or kinds <= {"conv", "full_attention"})):
             raise ValueError(
                 f"layer_types must name {self.num_layers} layers, each "
                 f"'mamba' or 'attention', or each 'linear_attention' or "
-                f"'full_attention'; got {self.layer_types!r}")
+                f"'full_attention', or each 'conv' or 'full_attention'; "
+                f"got {self.layer_types!r}")
+        if ("conv" in kinds) != bool(self.conv_L_cache):
+            raise ValueError(
+                f"'conv' layers and conv_L_cache go together; got "
+                f"conv_L_cache {self.conv_L_cache} with {self.layer_types!r}")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score must be 'softmax' or 'sigmoid'; "
+                             f"got {self.router_score!r}")
+        if bool(self.num_dense_layers) != bool(self.dense_intermediate_size):
+            raise ValueError("num_dense_layers and dense_intermediate_size "
+                             "go together")
 
     def layers_of(self, kind: str) -> tuple[int, ...]:
         return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
@@ -167,13 +196,20 @@ class HybridConfig(MoEConfig):
     @property
     def recurrent_kind(self) -> str:
         """The name `layer_types` gives this model's recurrent layers."""
+        if self.conv_L_cache:
+            return "conv"
         return ("linear_attention" if "linear_attention" in self.layer_types
                 or "full_attention" in self.layer_types else "mamba")
 
     @property
     def attention_kind(self) -> str:
-        return ("full_attention" if self.recurrent_kind == "linear_attention"
-                else "attention")
+        return "attention" if self.recurrent_kind == "mamba" \
+            else "full_attention"
+
+    def ffn_kind(self, i: int) -> str:
+        """What layer i ends in: "dense" (the leading SwiGLU layers) or
+        "moe"."""
+        return "dense" if i < self.num_dense_layers else "moe"
 
 
 # Named presets; sizes from the public HF configs of each model family.
@@ -323,6 +359,40 @@ PRESETS: dict[str, ModelConfig] = {
         linear_conv_kernel_dim=4, linear_chunk_size=64,
         partial_rotary_factor=0.25, qk_norm=True, attn_output_gate=True,
         shared_expert_gate=True,
+    ),
+    # the CPU's copy of LFM2-MoE's mechanisms with its IRREGULAR pattern: two
+    # leading dense layers, an attention layer after two conv layers and
+    # after three, a sigmoid router with a selection bias over 8 experts top
+    # 2, q/k norms, a tied head
+    "tiny-sconv": HybridConfig(
+        vocab_size=512, hidden_size=64, num_layers=8, num_heads=4,
+        num_kv_heads=2, intermediate_size=32, head_dim=16,
+        rope_theta=10000.0, rms_eps=1e-5, max_position=512,
+        tie_embeddings=True, num_experts=8, num_experts_per_tok=2,
+        qk_norm=True,
+        layer_types=("conv", "conv", "full_attention", "conv", "conv", "conv",
+                     "full_attention", "conv"),
+        conv_L_cache=3, num_dense_layers=2, dense_intermediate_size=128,
+        router_score="sigmoid", router_bias=True,
+    ),
+    # LFM2-8B-A1B WHOLE: all 24 layers (18 gated short convolutions, six GQA
+    # layers at 2, 6, 10, 14, 18, 21), both leading dense layers and all 32
+    # experts of the other 22, the whole vocabulary, every published width —
+    # 8.5 GB in int8, half of one 16 GB chip (benchmarks/configs/
+    # lfm2-8b-a1b.json). `intermediate_size` is the routed expert's width
+    # (`moe_intermediate_size`), `dense_intermediate_size` the published
+    # `intermediate_size` of layers 0 and 1. Heads of 64 = 2,048 / 32.
+    "lfm2-8b-a1b": HybridConfig(
+        vocab_size=65536, hidden_size=2048, num_layers=24, num_heads=32,
+        num_kv_heads=8, intermediate_size=1792, head_dim=64,
+        rope_theta=1000000.0, rms_eps=1e-5, max_position=128000,
+        tie_embeddings=True, num_experts=32, num_experts_per_tok=4,
+        qk_norm=True,
+        layer_types=("conv", "conv", "full_attention")
+        + ("conv", "conv", "conv", "full_attention") * 4
+        + ("conv", "conv", "full_attention", "conv", "conv"),
+        conv_L_cache=3, num_dense_layers=2, dense_intermediate_size=7168,
+        router_score="sigmoid", router_bias=True, routed_scaling_factor=1.0,
     ),
     "gemma-7b": ModelConfig(
         vocab_size=256000, hidden_size=3072, num_layers=28, num_heads=16,
@@ -674,7 +744,9 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
       - learned sparse attention (`config.sparse`): the decode kernel
         takes a selection only beside the scale planes of an int8 cache of
         interleaved heads (ops/decode_attention.py keep_supported); any
-        other cache decodes on the XLA path."""
+        other cache decodes on the XLA path;
+      - a head size that is no lane tile (lfm2-8b-a1b's 64): decode keeps
+        the XLA path and the reply says why (`decode_why`)."""
     from symmetry_tpu.ops import decode_attention as da
 
     kernel = "pallas-interpret" if interpret_mode() else "pallas"
@@ -685,6 +757,14 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
     tiles = da.geometry(batch // data if batch % data == 0 else batch,
                         capacity, config.num_kv_heads // model,
                         config.dim_per_head, kv_bytes)
+    if tiles is None and config.dim_per_head % da.LANES:
+        # the reason rides along where the SHAPE is what the kernel lacks
+        # (every configuration before lfm2-8b-a1b has heads of 128 or 256);
+        # the flash kernel lowers at [block, 64] (tests/test_chip_compile.py)
+        return {"prefill": kernel, "decode": "xla",
+                "decode_why": f"ops/decode_attention.py has no geometry for "
+                              f"a head of {config.dim_per_head}: no lane "
+                              f"tile of {da.LANES}"}
     if tiles is None or (tp_mesh is not None
                          and capacity < da.TP_MIN_CAPACITY):
         return {"prefill": kernel, "decode": "xla"}
@@ -1127,9 +1207,10 @@ def logits_from_hidden(params: dict, config: ModelConfig,
 
 # Weights eligible for int8 quantization (all the large matmuls; the
 # embedding stays dense — it is gathered, not contracted).
-# `in_proj` / `out_proj` are the recurrent mixer's (mamba's or the Gated
-# DeltaNet's q|k|v|z projection), `sg` / `su` / `sd` the shared expert's
-# (models/hybrid.py).
+# `in_proj` / `out_proj` are the recurrent mixer's (mamba's, the Gated
+# DeltaNet's q|k|v|z projection or the short convolution's B|C|x), `sg` /
+# `su` / `sd` the shared expert's (models/hybrid.py; lfm2_moe's leading
+# dense layers are `wg` / `wu` / `wd` of the stack `layers.dense`).
 # `wqi` / `wki` are the sparse attention's index projections.
 QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "in_proj",
               "out_proj", "sg", "su", "sd", "wqi", "wki", "lm_head")
@@ -1429,6 +1510,37 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
             linear_conv_kernel_dim=hf.get("linear_conv_kernel_dim", 4),
             partial_rotary_factor=hf.get("partial_rotary_factor", 1.0),
             qk_norm=True, attn_output_gate=True, shared_expert_gate=True,
+        )
+    if hf.get("model_type") == "lfm2_moe":
+        if hf.get("conv_bias"):
+            raise ValueError("lfm2_moe with conv_bias is not implemented")
+        if not hf.get("norm_topk_prob", True):
+            raise ValueError("lfm2_moe with norm_topk_prob false is not "
+                             "implemented")
+        return HybridConfig(
+            vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf["num_key_value_heads"],
+            # the routed expert's width; `intermediate_size` is the leading
+            # dense layers'
+            intermediate_size=hf["moe_intermediate_size"],
+            head_dim=hf.get("head_dim"),
+            rope_theta=float(hf.get("rope_theta", 1000000.0)),
+            rms_eps=hf.get("norm_eps", 1e-5),
+            tie_embeddings=hf.get("tie_embedding",
+                                  hf.get("tie_word_embeddings", True)),
+            max_position=hf.get("max_position_embeddings", 128000),
+            num_experts=hf["num_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            qk_norm=True, layer_types=tuple(hf["layer_types"]),
+            conv_L_cache=hf["conv_L_cache"],
+            num_dense_layers=hf.get("num_dense_layers", 0),
+            dense_intermediate_size=(hf["intermediate_size"]
+                                     if hf.get("num_dense_layers") else 0),
+            router_score="sigmoid",
+            router_bias=bool(hf.get("use_expert_bias", True)),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
         )
     if hf.get("model_type") == "KeyeVL2":
         # the LANGUAGE model of the family (the catalog row's keys): the

@@ -36,6 +36,12 @@ A config with `shared_intermediate_size` adds a shared expert (`sg`, `su`,
 `sd`: one dense gated FFN every token passes through) to the routed sum,
 weighted by sigmoid(x . sgate) where the layer has that column (qwen3_next).
 
+lfm2_moe's router (HF `Lfm2MoeSparseMoeBlock`) is another form:
+`route_top_k(score="sigmoid")` — sigmoid scores, selection by score + the
+layer's `expert_bias`, gates the selected's UNBIASED scores renormalised —
+chosen by the config (`routing_of`), the softmax form the default; both
+expert forms take their gates from it.
+
 qwen3_next's router (HF `Qwen3NextSparseMoeBlock`, `norm_topk_prob` true)
 takes the softmax over ALL the router logits, keeps the k largest and
 renormalises them to sum 1. That IS `route_top_k`'s softmax over the k
@@ -121,7 +127,17 @@ ROUTED_MIN_TOKENS = 1024
 # 6%; from 128 the kernel does. They cross between 64 and 128 and no
 # dispatch lies between: decode keeps the mixture, every prefill of the
 # long-document cell (1,024 tokens and up) is routed.
-ROUTED_FROM = {(72, 10): 256, (512, 10): 1, (128, 8): 128}
+# 32 top 4 at expert width 1,792 (lfm2-8b-a1b; `--shape 32,4,2048,1792`;
+# PERF.md, PR 42; floor 0.43 for all 32 experts): 16 tokens 1.41 / 0.47 /
+# 0.49, 32: 1.73 / 0.52 / 0.49, 64: 2.25 / 0.55 / 0.49, 128: 3.23 / 0.60 /
+# 0.58, 256: 3.35 / 0.68 / 1.00, 512: 3.71 / 0.87 / 2.12, 1,024: 4.43 /
+# 1.21 / 4.22, 2,048: 5.87 / 1.88 / 8.06. From 32 tokens every expert is
+# hit; the mixture's 8x FLOPs hide behind the weight stream up to 128
+# tokens (74-88% of the floor) and it wins there by 3-10%; at 256 its
+# matmuls are the longer pole. They cross between 128 and 256 and no
+# dispatch lies between: decode (128 slots) keeps the mixture, a prefill
+# dispatch of 256 tokens or more is routed.
+ROUTED_FROM = {(72, 10): 256, (512, 10): 1, (128, 8): 128, (32, 4): 256}
 
 
 def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
@@ -132,14 +148,37 @@ def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
     return "routed" if n_tokens >= least else "dense-mixture"
 
 
-def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int
-                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int, *,
+                score: str = "softmax", bias: jnp.ndarray | None = None,
+                scale: float = 1.0) -> tuple[jnp.ndarray, jnp.ndarray]:
     """[T, D] tokens -> (gates [T, k] float32, experts [T, k] int32).
     Logits accumulate and come out in float32; the softmax is over the k
-    selected logits (mixtral: normalise AFTER selection)."""
+    selected logits (mixtral: normalise AFTER selection).
+
+    `score` "sigmoid" is lfm2_moe's router (HF `Lfm2MoeSparseMoeBlock`):
+    scores sigmoid(logits); the k experts are those of the largest score +
+    `bias` (the layer's `expert_bias` [X] float32; ties toward the lower
+    index); the gates are the UNBIASED scores of the selected, divided by
+    their sum + 1e-6 (`norm_topk_prob`), times `scale`."""
     logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_idx = jax.lax.top_k(
+            scores if bias is None else scores + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(scores, top_idx, axis=-1)
+        gates = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-6)
+        return gates * scale, top_idx.astype(jnp.int32)
     top_vals, top_idx = jax.lax.top_k(logits, k)
     return jax.nn.softmax(top_vals, axis=-1), top_idx.astype(jnp.int32)
+
+
+def routing_of(config, lp: dict) -> dict:
+    """`route_top_k`'s keywords for this config and layer: empty (the
+    softmax form) for every family but lfm2_moe."""
+    if getattr(config, "router_score", "softmax") != "sigmoid":
+        return {}
+    return {"score": "sigmoid", "bias": lp.get("expert_bias"),
+            "scale": config.routed_scaling_factor}
 
 
 def grouped_matmul_form(w, n_rows: int, one_device: bool = True) -> dict:
@@ -184,15 +223,17 @@ def _grouped_matmul(rows: jnp.ndarray, w, group_sizes: jnp.ndarray,
                               preferred_element_type=jnp.float32)
 
 
-def _routed_ffn(x, valid, router, wg, wu, wd, k: int, at=None):
+def _routed_ffn(x, valid, router, wg, wu, wd, k: int, at=None,
+                routing=None):
     """x [T, D], valid [T] bool -> (y [T, D] float32, pairs [X] int32).
     Under shard_map this is one shard's program: wg/wu hold a slice of the
     FFN width, wd the matching rows, and y is that slice's partial sum.
     `at` = ({"wg", "wu", "wd"}: the whole stacks, this layer's index) on
-    one device (`_grouped_matmul`), else None."""
+    one device (`_grouped_matmul`), else None. `routing`: `route_top_k`'s
+    keywords (`routing_of`)."""
     T, _ = x.shape
     X = router.shape[-1]
-    gates, experts = route_top_k(x, router, k)            # [T, k]
+    gates, experts = route_top_k(x, router, k, **(routing or {}))  # [T, k]
     flat_expert = experts.reshape(-1)                     # [T*k]
     # Stable sort: pairs of one expert keep token order, so the result
     # does not depend on how the sort breaks ties.
@@ -248,10 +289,10 @@ def _experts_dot(x: jnp.ndarray, w) -> jnp.ndarray:
     return jnp.einsum("ta,xaf->txf", x, w)
 
 
-def _dense_mixture(x, valid, router, wg, wu, wd, k: int):
+def _dense_mixture(x, valid, router, wg, wu, wd, k: int, routing=None):
     """Same contract as `_routed_ffn`; every expert computes every token."""
     X = router.shape[-1]
-    gates, experts = route_top_k(x, router, k)            # [T, k]
+    gates, experts = route_top_k(x, router, k, **(routing or {}))  # [T, k]
     onehot = experts[..., None] == jnp.arange(X, dtype=jnp.int32)
     dense_gates = jnp.sum(jnp.where(onehot, gates[..., None], 0.0), axis=1)
     pairs = jnp.sum(onehot & valid[:, None, None], axis=(0, 1),
@@ -267,12 +308,13 @@ def _dense_mixture(x, valid, router, wg, wu, wd, k: int):
     return jnp.einsum("xtd,tx->td", y, dense_gates), pairs
 
 
-def _expert_ffn(x, valid, router, wg, wu, wd, k: int, at=None):
+def _expert_ffn(x, valid, router, wg, wu, wd, k: int, at=None,
+                routing=None):
     """x [T, D] -> (y [T, D] float32, valid pairs [X]) by the form this
-    token count takes; `at` as `_routed_ffn`'s."""
+    token count takes; `at` and `routing` as `_routed_ffn`'s."""
     if moe_route(x.shape[0], router.shape[-1], k) == "routed":
-        return _routed_ffn(x, valid, router, wg, wu, wd, k, at)
-    return _dense_mixture(x, valid, router, wg, wu, wd, k)
+        return _routed_ffn(x, valid, router, wg, wu, wd, k, at, routing)
+    return _dense_mixture(x, valid, router, wg, wu, wd, k, routing)
 
 
 def _model_shards(tp_mesh, ffn_width: int) -> int:
@@ -322,13 +364,17 @@ def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
     args = (xf, valid, lp["router"], lp["wg"], lp["wu"], lp["wd"])
 
     n = _model_shards(tp_mesh, config.intermediate_size)
+    routing = routing_of(config, lp)
+    if routing and tp_mesh is not None:
+        raise ValueError("the sigmoid router is traced on one device only")
     if tp_mesh is None:
         # one device: the kernel's operand is a stack — the caller's, or
         # this layer alone as a stack of one (a reshape)
         stacks, layer = stack if stack is not None else (
             jax.tree.map(lambda a: a[None],
                          {name: lp[name] for name in EXPERT_LEAVES}), 0)
-        y, pairs = _expert_ffn(*args, k, (stacks, jnp.int32(layer)))
+        y, pairs = _expert_ffn(*args, k, (stacks, jnp.int32(layer)),
+                               routing)
     elif n == 1:
         y, pairs = _expert_ffn(*args, k)
     else:

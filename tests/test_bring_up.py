@@ -113,8 +113,10 @@ class TestEngineHost:
             assert block["device"]["hbm"] == []  # CPU reports no HBM
             # tiny on CPU: flash runs interpreted; head size 16 is no
             # lane tile, so decode takes the XLA path and says so.
-            assert block["attention"] == {"prefill": "pallas-interpret",
-                                          "decode": "xla"}
+            assert block["attention"] == {
+                "prefill": "pallas-interpret", "decode": "xla",
+                "decode_why": "ops/decode_attention.py has no geometry for "
+                              "a head of 16: no lane tile of 128"}
             # tiny's 512 logits are 4 groups of 128, below the two-stage
             # selection's threshold (ops/sampling.py top_k_route).
             assert block["sampling"] == {"top_k": "direct"}

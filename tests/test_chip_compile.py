@@ -498,3 +498,100 @@ def test_keye_programs_select_in_the_kernel_alone(one_chip, no_cache,
                  rf"= bf16\[(4,)?{rows},({T},64|64,{T})\]\S* "
                  rf"(copy|transpose|dynamic-slice)\(", line)]
     assert not moved, moved[0]
+
+
+def _lfm2_shapes(one_chip, rows, capacity):
+    from symmetry_tpu.models import llama
+
+    cfg = llama.preset("lfm2-8b-a1b")
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+        slice_above=1 << 40))
+    cache = shaped(lambda: llama.init_cache(cfg, rows, capacity,
+                                            jnp.bfloat16, quantized=True))
+    return cfg, params, cache
+
+
+def test_lfm2_decode_step_moves_the_tails_in_place(one_chip, no_cache,
+                                                   monkeypatch):
+    """lfm2-8b-a1b's decode trunk at its cell (128 slots x 640), all 24
+    layers in 13 scans: it lowers for a v5e; the 18.9 MB stack of tails
+    (`bf16[18,2,128,2048]`: a short convolution's whole state) is donated
+    in, aliased out and never copied or relaid whole; heads of 64 have no
+    decode kernel (`attention_paths` says so), the step's experts are the
+    dense mixture (128 tokens are under `ROUTED_FROM[(32, 4)]`), so the
+    program holds no custom call at all."""
+    from symmetry_tpu.models import llama, moe
+
+    for module in (llama, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    B, T = 128, 640
+    cfg, params, cache = _lfm2_shapes(one_chip, B, T)
+    assert cache.ssm is None and cache.conv.shape == (18, 2, 128, 2048)
+    assert cache.k.shape == (6, 128, 640, 8, 64)
+    paths = llama.attention_paths(cfg, T, None, batch=B, kv_bytes=1)
+    assert (paths["prefill"], paths["decode"]) == ("pallas", "xla")
+    assert moe.moe_route(B, 32, 4) == "dense-mixture"
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
+            donate_argnums=(2,)).lower(params, tok, cache).compile()
+    memory = compiled.memory_analysis()
+    tails = 18 * 2 * 128 * 2048 * 2
+    kv = 2 * 6 * 128 * 640 * 8 * 64
+    assert memory.alias_size_in_bytes >= tails + kv
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 0
+    whole = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= bf16\[18,2,128,2048\]\S* (copy|transpose)\(",
+                          line)]
+    assert not whole, whole[0]
+    # the weights stream once: no expert stack is copied or relaid
+    stacks = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= s8\[22,32,\d+,\d+\]\S* (copy|transpose)\(",
+                           line)]
+    assert not stacks, stacks[0]
+
+
+@pytest.mark.parametrize("rows,bucket", [(2, 256), (8, 64)])
+def test_lfm2_prefill_lowers_flash_at_a_head_of_64_and_routes_its_experts(
+        one_chip, no_cache, monkeypatch, rows, bucket):
+    """A 512-token prefill dispatch of the lfm2 cell: the flash kernel
+    lowers at a `[block, 64]` tile (one call in each of the six attention
+    runs: what `startup.attention.prefill: pallas` promises), the 22
+    expert layers are routed (`moe_gmm`: three calls in each of the twelve
+    scans that hold expert layers; the run of the two dense layers holds
+    none) over the WHOLE `[22, 32, ...]` int8 stacks, indexed by the
+    layer's place among the expert layers."""
+    from symmetry_tpu.models import hybrid, llama, moe
+
+    for module in (llama, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    cfg, params, cache = _lfm2_shapes(one_chip, rows, bucket)
+    assert moe.moe_route(rows * bucket, 32, 4) == "routed"
+    tok = jax.ShapeDtypeStruct((rows, bucket), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(
+            lambda p, t, c, n: llama.forward_hidden(p, cfg, t, c, n,
+                                                    prefill_flash=True),
+            donate_argnums=(2,)).lower(params, tok, cache, lens).compile(
+        ).as_text()
+    expert_runs = [r for r in hybrid.runs(cfg) if cfg.ffn_kind(r[1]) == "moe"]
+    assert len(expert_runs) == 12 and len(hybrid.runs(cfg)) == 13
+    gmm_calls = len(re.findall(r"%moe_gmm[.\d]* = ", text))
+    assert gmm_calls == 3 * len(expert_runs)
+    assert text.count("tpu_custom_call") == gmm_calls + 6   # + flash
+    stack = r"s8\[22,32,(2048,1792|1792,2048)\]"
+    touched = [line.strip()[:160] for line in text.splitlines()
+               if re.search(rf"= {stack}\S* (copy|transpose|fusion|"
+                            rf"dynamic-slice)\(", line)]
+    assert not touched, touched[0]

@@ -105,3 +105,33 @@ def test_yaml_load_and_scaffold(tmp_path):
     # Round-trips through real YAML on disk.
     raw = yaml.safe_load(path.read_text())
     assert raw["serverKey"] == "ef" * 32
+
+
+LFM2_REFUSED = {
+    "prefix_cache_mb": {"prefix_cache_mb": 64},
+    "speculative": {"speculative": {"k_draft": 4}},
+    "prefill_chunk": {"prefill_chunk": 256},
+    "role": {"role": "disagg"},
+    "mesh": {"mesh": {"model": 4}},
+}
+
+
+@pytest.mark.parametrize("preset", ["tiny-sconv", "lfm2-8b-a1b"])
+@pytest.mark.parametrize("setting", sorted(LFM2_REFUSED))
+def test_a_short_conv_preset_refuses_what_cannot_carry_its_tail(setting,
+                                                                preset):
+    """A preset whose recurrent layers are short convolutions (a per-slot
+    tail beside the K/V rows) is refused under the prefix cache,
+    speculation, chunked prefill, a disagg role or a mesh before anything
+    is built, in `state_refusals`' sentences — which name "the recurrent
+    layers", no kind that is not there."""
+    def config(**tpu):
+        return {**BASE, "apiProvider": "tpu_native",
+                "tpu": {"model_preset": preset, "prefill_chunk": None,
+                        **tpu}}
+
+    ConfigManager(config=config())      # the plain configuration is fine
+    with pytest.raises(ConfigError, match=f"tpu.{setting}") as err:
+        ConfigManager(config=config(**LFM2_REFUSED[setting]))
+    assert "mamba" not in str(err.value)
+    assert "recurrent" in str(err.value)

@@ -185,3 +185,85 @@ def test_every_uncut_key_is_the_catalogs(name):
             assert c["published"][key] == value, key
         else:
             assert c[key] == value, key
+
+
+# ---------------------------------------------------------------------------
+# lfm2-8b-a1b is NOT cut (`reduced == []`: all 24 layers, every expert, the
+# whole vocabulary): held to the preset and to the catalog's row all the same
+# (`benchmarks/tests/test_manifest.py` cannot hold a file whose
+# `intermediate_size` is the dense layers' width and whose eps key is
+# `norm_eps`).
+
+LFM2 = "lfm2-8b-a1b.json"
+
+
+def test_the_uncut_lfm2_file_is_the_programs_preset():
+    from symmetry_tpu.models.llama import config_from_hf
+
+    c = load(LFM2)
+    p = preset(c["tpu"]["model_preset"])
+    assert c["reduced"] == [] and "published" not in c
+    assert LFM2 not in cut_files()
+    # every published key the program reads, through its own reader
+    assert config_from_hf(c) == p
+    assert (p.vocab_size, p.hidden_size, p.num_layers, p.num_heads,
+            p.num_kv_heads, p.dim_per_head) == (
+        c["vocab_size"], c["hidden_size"], c["num_hidden_layers"],
+        c["num_attention_heads"], c["num_key_value_heads"], c["head_dim"])
+    assert (p.intermediate_size, p.dense_intermediate_size,
+            p.num_dense_layers) == (
+        c["moe_intermediate_size"], c["intermediate_size"],
+        c["num_dense_layers"]) == (1792, 7168, 2)
+    assert (p.num_experts, p.num_experts_per_tok, p.router_score,
+            p.router_bias, p.routed_scaling_factor) == (
+        c["num_experts"], c["num_experts_per_tok"], "sigmoid",
+        c["use_expert_bias"], c["routed_scaling_factor"]) == (
+        32, 4, "sigmoid", True, 1)
+    assert list(p.layer_types) == c["layer_types"]
+    assert len(c["layer_types"]) == c["num_hidden_layers"] == 24
+    assert p.conv_L_cache == c["conv_L_cache"] == 3
+    assert c["conv_bias"] is False and c["norm_topk_prob"] is True
+    assert p.rope_theta == c["rope_theta"] and p.rms_eps == c["norm_eps"]
+    assert p.max_position == c["max_position_embeddings"] == 128000
+    assert p.tie_embeddings is c["tie_embedding"] is True
+    assert p.qk_norm and p.shared_intermediate_size == 0
+
+
+def test_the_lfm2_file_states_its_source_its_assumptions_and_its_run():
+    c = load(LFM2)
+    manifest = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
+    entry = next(e for e in manifest["configs"]
+                 if e["file"] == f"benchmarks/configs/{LFM2}")
+    assert entry["reduced"] == [] == c["reduced"]
+    assert entry["source"] == c["source"] and len(c["source"]) <= 200
+    text = " ".join(c["assumed"])
+    for word in ("head_dim 64", "q_layernorm", "tied", "bfloat16", "640",
+                 "byte tokenizer", "expert_bias", "[-0.25, 0.25]"):
+        assert word in text, word
+    assert "whole model on one v5e chip" in c["deployment"]
+    assert "nothing is cut" in c["deployment"]
+    assert "expert_bias uniform in [-0.25, 0.25]" in c["weights"]
+    assert c["reference"] == "benchmarks/reference/sconv_moe_decoder.py"
+    assert os.path.exists(os.path.join(CHECKOUT, c["reference"]))
+    tpu = c["tpu"]
+    assert (tpu["quantization"], tpu["kv_quantization"], tpu["dtype"]) == (
+        "int8", "int8", "bfloat16")
+    assert (tpu["max_batch_size"], tpu["max_seq_len"],
+            tpu["decode_block"]) == (128, 640, 16)
+    assert tpu["prefill_buckets"] == [64, 128, 256]
+    assert tpu["prefill_chunk"] is None and c["template_tokens"] == 19
+    cell = next(w for w in manifest["workloads"]
+                if w["config"] == "lfm2-8b-a1b")
+    assert (cell["name"], cell["traffic"], cell["chips"]) == (
+        "lfm2-8b-a1b.batch-closed", "batch-closed", 1)
+
+
+def test_every_lfm2_key_is_the_catalogs():
+    if not os.path.exists(CATALOG):
+        pytest.skip("the guide's catalog is not installed here")
+    c = load(LFM2)
+    rows = [json.loads(line) for line in open(CATALOG)]
+    row = next(r for r in rows if r["source_url"] == c["source"])
+    assert row["name"] == "LFM2-8B-A1B"
+    for key, value in row["config"].items():
+        assert c[key] == value, key

@@ -4,10 +4,10 @@ change what an existing configuration runs?".
     JAX_PLATFORMS=cpu python tools/lowered_programs.py OUT_DIR [preset ...]
 
 For each preset (default: mistral-7b, qwen2-7b, granite-4.0-h-small,
-qwen3-next-80b-a3b and keye-vl-2.0-30b-a3b at the closed cells' shape, 128
-slots x 640, int8 weights + int8 KV, decode_block 16; and tiny-moe8 — the
-stand-in for mixtral-8x7b's sharded programs — on a `model: 4` mesh of
-virtual CPU devices: eighteen programs) it writes the StableHLO of the
+qwen3-next-80b-a3b, keye-vl-2.0-30b-a3b and lfm2-8b-a1b at the closed cells'
+shape, 128 slots x 640, int8 weights + int8 KV, decode_block 16; and
+tiny-moe8 — the stand-in for mixtral-8x7b's sharded programs — on a
+`model: 4` mesh of virtual CPU devices: twenty-one programs) it writes the StableHLO of the
 engine's OWN jits — `decode_block`, `prefill` at (8, 256) and `insert_all`
 — lowered from shapes alone (nothing is built or run), as
 `OUT_DIR/<preset>.<program>.txt` and prints one sha256 a file. The text
@@ -16,7 +16,9 @@ the interpreter (no Mosaic bytecode with file paths in it), so the same
 programs give the same bytes on two commits: copy this file into the other
 checkout's `tools/`, run it from each, and `diff -r` the two directories.
 (PR 41, a kernel under the sparse path alone: 16 of the 18 files identical,
-keye's `prefill` and `decode_block` the two that differ.)
+keye's `prefill` and `decode_block` the two that differ. PR 42, a third
+recurrent kind: the eighteen older files identical; a parent that lacks a
+preset is given the names it has.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
@@ -87,7 +89,7 @@ def main() -> int:
     out_dir = sys.argv[1]
     names = sys.argv[2:] or ["mistral-7b", "qwen2-7b", "tiny-moe8",
                              "granite-4.0-h-small", "qwen3-next-80b-a3b",
-                             "keye-vl-2.0-30b-a3b"]
+                             "keye-vl-2.0-30b-a3b", "lfm2-8b-a1b"]
     os.makedirs(out_dir, exist_ok=True)
     for name in names:
         cfg = llama.preset(name)

@@ -53,9 +53,9 @@ COLUMNS = ("PROVIDER", "TIER", "TOK/S", "TTFT p50", "TTFT p99",
            "QUEUE", "INFL", "OCC", "DEPTH", "SHED", "RESUME",
            "WASTED", "REUSED", "DUMPS", "COST", "WASTE%", "GPUT",
            "LINK", "STATE", "SHARE", "HIT", "TARGET", "SCALE",
-           "DSA", "AHEAD", "STALLS", "TAIL")
+           "RECUR", "DSA", "AHEAD", "STALLS", "TAIL")
 WIDTHS = (22, 10, 9, 9, 9, 7, 6, 5, 5, 7, 7, 7, 7, 6, 7, 6, 7, 6,
-          9, 6, 6, 9, 6, 18, 11, 9, 6)
+          9, 6, 6, 9, 6, 20, 18, 11, 9, 6)
 
 # sym_pool_member_state gauge encoding (engine/disagg/pool.py
 # STATE_CODES) rendered back to the membership lifecycle names.
@@ -278,6 +278,16 @@ def read_dsa(engine: dict | None) -> str | None:
             + (f"/{select}" if select else "")).strip()
 
 
+def read_recurrent(engine: dict | None) -> str | None:
+    """A model with recurrent layers (the stats reply's `startup.ssm`; None
+    for any other): RECUR = the kind — mamba2, gated_deltanet, short_conv —
+    and what all slots' state holds, in MiB."""
+    ssm = ((engine or {}).get("startup") or {}).get("ssm") or {}
+    if not ssm.get("kind"):
+        return None
+    return f"{ssm['kind']} {ssm.get('state_bytes', 0) / 2 ** 20:.0f}M"
+
+
 def build_rows(name: str, fams: dict, prev: dict | None, now: float,
                engine: dict | None = None) -> list[dict[str, Any]]:
     """One provider-level row plus one sub-row per engine tier. `prev`
@@ -359,7 +369,7 @@ def build_rows(name: str, fams: dict, prev: dict | None, now: float,
         "state": None, "share": None,
         "target": target, "scale": scale_disp,
         "ahead": ahead_cell, "stalls": stall_cell, "tail": tail,
-        "dsa": read_dsa(engine),
+        "recur": read_recurrent(engine), "dsa": read_dsa(engine),
         "_sample": {"t": now, "tok": tok, "shed": shed or 0.0,
                     "dec": decisions or 0.0},
     }]
@@ -425,7 +435,7 @@ def render_table(rows: list[dict[str, Any]]) -> str:
                  r["link"] or "-",
                  r.get("state") or "-", r.get("share") or "-",
                  r.get("hit"), r.get("target") or "-", r.get("scale"),
-                 r.get("dsa") or "-",
+                 r.get("recur") or "-", r.get("dsa") or "-",
                  r.get("ahead") or "-", r.get("stalls") or "-",
                  r.get("tail"))
         out.append("  ".join(_fmt_cell(c, w)
