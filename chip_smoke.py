@@ -91,12 +91,6 @@ DRAIN_TIMEOUT_S = 120.0
 SPARSE_PRESETS = ("keye-vl-2.0-30b-a3b", "tiny-dsa")
 
 
-# presets whose heads are no lane tile (64): the decode-attention kernel has
-# no geometry for them, so decode takes the XLA path and must say why
-# (models/llama.py attention_paths; PERF.md section 4)
-XLA_DECODE_PRESETS = ("lfm2-8b-a1b",)
-
-
 class SmokeFailure(Exception):
     pass
 
@@ -324,14 +318,9 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
         failures.append(f"the compile cache at {cache} holds no entries "
                         f"after a full start-up")
     # One chip or a mesh (8 x 4,096 is over the sharded trunk's floor):
-    # both programs through compiled kernels.
-    if cfg["tpu"]["model_preset"] in XLA_DECODE_PRESETS:
-        if ((attention.get("prefill"), attention.get("decode")) != (
-                "pallas", "xla") or not attention.get("decode_why")):
-            failures.append(f"a head of 64 takes the flash kernel at "
-                            f"prefill and the XLA path, with its reason, at "
-                            f"decode: {attention}")
-    elif (attention.get("prefill"), attention.get("decode")) != (
+    # both programs through compiled kernels (lfm2-8b-a1b's heads of 64
+    # too: they lie in the cache in pairs, models/llama.py kv_row).
+    if (attention.get("prefill"), attention.get("decode")) != (
             "pallas", "pallas"):
         failures.append(f"attention did not run compiled Pallas kernels "
                         f"in both programs: {attention}")

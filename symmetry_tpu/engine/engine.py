@@ -38,6 +38,7 @@ from symmetry_tpu.models.llama import (
     forward_hidden,
     init_cache,
     init_params,
+    kv_row,
     logits_from_hidden,
     preset,
 )
@@ -1462,7 +1463,7 @@ class InferenceEngine:
                 f"with this engine ({self.kv_quant}) — tiers must share "
                 f"the cache layout")
         c = self.config
-        want = (c.num_layers, 1, bs, c.num_kv_heads, c.dim_per_head)
+        want = (c.num_layers, 1, bs, *kv_row(c))
         want_dtype = np.dtype(np.int8 if self.kv_quant
                               else self.cache_dtype)
         for j, planes in handoff.blocks.items():
@@ -1506,8 +1507,8 @@ class InferenceEngine:
         try:
             capacity = self.bucket_for(p_eff)
             m = plan.matched_len
-            k_row = np.zeros((c.num_layers, 1, capacity, c.num_kv_heads,
-                              c.dim_per_head), want_dtype)
+            k_row = np.zeros((c.num_layers, 1, capacity, *kv_row(c)),
+                             want_dtype)
             v_row = np.zeros_like(k_row)
             ks_row = vs_row = None
             if self.kv_quant:

@@ -109,8 +109,7 @@ def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
                ) -> llama.KVCache:
     n_attn = len(config.layers_of(config.attention_kind))
     ssm, conv = state_shapes(config, batch)
-    shape = (n_attn, batch, capacity, config.num_kv_heads,
-             config.dim_per_head)
+    shape = (n_attn, batch, capacity, *llama.kv_row(config))
     scale_shape = (n_attn, batch, config.num_kv_heads, capacity)
     return llama.KVCache(
         k=jnp.zeros(shape, jnp.int8 if quantized else dtype),

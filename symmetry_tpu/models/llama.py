@@ -431,8 +431,28 @@ def preset(name: str) -> ModelConfig:
     return PRESETS[name]
 
 
+def kv_row(config: ModelConfig) -> tuple[int, int]:
+    """(rows, width) of one position's K (or V) as the cache holds it:
+    (kv_heads, head_dim), but heads of 64 lie in PAIRS, (kv_heads / 2,
+    128) with heads 2p and 2p + 1 in the two halves of row p. A 64-wide
+    minor dimension is half a lane tile: compiled for a described v5e
+    `s8[6, 128, 640, 8, 64]` is `{4,3,2,1,0:T(8,128)(4,1)}` — the 64
+    padded to 128 lanes, 1,024 bytes a position for 512, bf16 / f32 alike
+    — and the attached chip's default layout of the shape is position-
+    minor (dense, a position's row strewn a capacity apart: PERF.md, PR
+    43); either way XLA slices and relays a whole layer to attend over it
+    and the decode kernel has no view of it (Mosaic refuses a 64-wide
+    slice of the padded rows). Folded, the bytes are dense where they
+    lie, a position's row contiguous; it is a reshape of the row, never
+    of what a head holds. `write_kv` folds, the XLA attention unfolds,
+    ops/decode_attention.py reads the pairs as they lie."""
+    K, D = config.num_kv_heads, config.dim_per_head
+    return (K // 2, 2 * D) if D == 64 and K % 2 == 0 else (K, D)
+
+
 class KVCache(NamedTuple):
-    """Static-shape KV cache: [layers, batch, capacity, kv_heads, head_dim].
+    """Static-shape KV cache: [layers, batch, capacity, kv_heads, head_dim]
+    ([..., kv_heads / 2, 128] for heads of 64: `kv_row`).
 
     With quantized=True at init, k/v hold int8 payloads and k_scale/v_scale
     hold the per-(layer, slot, kv_head, position) f32 dequant scales
@@ -486,8 +506,7 @@ def init_cache(
         return hybrid.init_cache(config, batch, capacity, dtype,
                                  quantized=quantized,
                                  count_experts=count_experts)
-    shape = (config.num_layers, batch, capacity, config.num_kv_heads,
-             config.dim_per_head)
+    shape = (config.num_layers, batch, capacity, *kv_row(config))
     pairs = (jnp.zeros((config.num_experts,), jnp.int32)
              if count_experts else None)
     extra = {}
@@ -528,6 +547,8 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     the capacity is dropped. Padded tail tokens write garbage past the
     slot's valid length — never read, overwritten later. A quantized cache
     takes the int8 payload plus the f32 scales (ops/quant.py quantize_kv).
+    Heads of 64 are written as the cache holds them, in pairs (`kv_row`);
+    their scales stay one a head.
     `idx` ([B, S, index_head_dim]; sparse attention) goes into `cache.idx`
     at the same rows.
 
@@ -539,7 +560,12 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     block (+4.3 GB a chip at 64 x 2048: it did not fit). Indexing the head
     too leaves a window of D alone, which either layout serves in place.
     """
-    B, S, nkv, _ = k.shape
+    B, S = k.shape[:2]
+    nkv = cache.k.shape[3]
+
+    def rows(x):
+        return x.reshape(B, S, *cache.k.shape[3:])
+
     b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
     l_idx = jnp.full((B, S), layer, jnp.int32)
     row = (l_idx, b_idx, positions)  # each write is one [K, D] row
@@ -551,16 +577,16 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
             jnp.arange(nkv, dtype=jnp.int32)[None, None, :],)
     if not cache.quantized:
         return cache._replace(
-            k=cache.k.at[row].set(k.astype(cache.k.dtype)),
-            v=cache.v.at[row].set(v.astype(cache.v.dtype)))
+            k=cache.k.at[row].set(rows(k).astype(cache.k.dtype)),
+            v=cache.v.at[row].set(rows(v).astype(cache.v.dtype)))
     kq, ks = quantize_kv(k)  # ks [B, S, K]
     vq, vs = quantize_kv(v)
     # Scale planes are [L, B, K, T] (position minor, see KVCache): the
     # mixed advanced/slice index puts the advanced dims (B, S) in front,
     # matching the [B, S, K] scale values.
     return cache._replace(
-        k=cache.k.at[row].set(kq),
-        v=cache.v.at[row].set(vq),
+        k=cache.k.at[row].set(rows(kq)),
+        v=cache.v.at[row].set(rows(vq)),
         k_scale=cache.k_scale.at[l_idx, b_idx, :, positions].set(ks),
         v_scale=cache.v_scale.at[l_idx, b_idx, :, positions].set(vs))
 
@@ -745,8 +771,11 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
         takes a selection only beside the scale planes of an int8 cache of
         interleaved heads (ops/decode_attention.py keep_supported); any
         other cache decodes on the XLA path;
-      - a head size that is no lane tile (lfm2-8b-a1b's 64): decode keeps
-        the XLA path and the reply says why (`decode_why`)."""
+      - a head of 64 (lfm2-8b-a1b) lies in the cache in pairs (`kv_row`)
+        and takes the kernel's pair form where the pairs are interleaved
+        rows; any other head size that is no lane tile (the tiny test
+        configurations' 16) keeps the XLA path and the reply says why
+        (`decode_why`)."""
     from symmetry_tpu.ops import decode_attention as da
 
     kernel = "pallas-interpret" if interpret_mode() else "pallas"
@@ -759,17 +788,20 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
                         config.dim_per_head, kv_bytes)
     if tiles is None and config.dim_per_head % da.LANES:
         # the reason rides along where the SHAPE is what the kernel lacks
-        # (every configuration before lfm2-8b-a1b has heads of 128 or 256);
-        # the flash kernel lowers at [block, 64] (tests/test_chip_compile.py)
-        return {"prefill": kernel, "decode": "xla",
-                "decode_why": f"ops/decode_attention.py has no geometry for "
-                              f"a head of {config.dim_per_head}: no lane "
-                              f"tile of {da.LANES}"}
+        # (the served configurations have heads of 64, 128 or 256); the
+        # flash kernel lowers at any [block, D] (tests/test_chip_compile.py)
+        why = (f"ops/decode_attention.py has no geometry for a head of "
+               f"{config.dim_per_head}: no lane tile of {da.LANES}")
+        if 2 * config.dim_per_head == da.LANES:
+            why += (f", and {config.num_kv_heads // model} KV heads a chip "
+                    f"are no interleaved pairs at this cache")
+        return {"prefill": kernel, "decode": "xla", "decode_why": why}
     if tiles is None or (tp_mesh is not None
                          and capacity < da.TP_MIN_CAPACITY):
         return {"prefill": kernel, "decode": "xla"}
     if getattr(config, "sparse", None) is not None and not da.keep_supported(
-            config.num_kv_heads, kv_bytes, quantized=kv_bytes == 1):
+            config.num_kv_heads, kv_bytes, kv_bytes == 1,
+            config.dim_per_head):
         return {"prefill": kernel, "decode": "xla"}
     return {"prefill": kernel, "decode": kernel,
             "decode_slot_tile": tiles[0], "decode_block_t": tiles[1]}
@@ -945,6 +977,9 @@ def _attention(
             return jax.lax.dynamic_index_in_dim(arr, layer, 0,
                                                 keepdims=False)
 
+        def heads_at_layer(arr):  # pair-folded rows (kv_row) as [K, D]
+            return at_layer(arr).reshape(*arr.shape[1:3], nkv, D)
+
         flash_route = prefill_flash and paths["prefill"] != "xla"
         keep = None
         if sparse is not None:
@@ -999,7 +1034,8 @@ def _attention(
                                                 **kw))[:, None]
         else:
             attn = gqa_attention(
-                q, at_layer(cache.k), at_layer(cache.v), positions, kv_valid,
+                q, heads_at_layer(cache.k), heads_at_layer(cache.v),
+                positions, kv_valid,
                 sliding_window=config.sliding_window,
                 k_scale=at_layer(cache.k_scale) if cache.quantized else None,
                 v_scale=at_layer(cache.v_scale) if cache.quantized else None,

@@ -19,6 +19,22 @@ kernel is the single-position attention of every one-chip decode program
     (slot, head) is a lane of its own, [T, D], with one KV head. The
     layer is a scalar-prefetch argument: layer and lane selection are
     DMA addressing, never a materialised slice.
+  - A head of 64 is half a lane tile, and `s8[L, B, T, 8, 64]` has no
+    dense layout with a position's heads in rows: compiled for a
+    described v5e it is one (8, 128) tile a position with lanes 64-127
+    empty, 1,024 bytes for 512, and Mosaic refuses a 64-wide slice of
+    it; the attached chip's own default for the shape is position-MINOR
+    (major to minor L, B, K, D, T: dense, but a position's 512 bytes lie
+    a capacity apart). So a model with such heads declares its K/V
+    leaves PAIR-FOLDED (models/llama.py kv_row: [L, B, T, K / 2, 128],
+    heads 2p and 2p + 1 in the two halves of row p), and the kernel is
+    its own K / 2 form at 128 lanes: a query row of head (pair p, half s)
+    carries its 64 values in lanes 64 s ... and zeros in the other half,
+    so the 128-lane contraction against row (t, p) is that head's score;
+    the output product yields the head's result in its own half (the
+    sibling's V weighted by this head's probabilities in the other,
+    which the wrapper drops). 2x redundant MXU lanes, as the interleave
+    already spends K-fold. The scale planes stay a row a HEAD.
   - Work is lists of (slot, block) items the kernel writes into SMEM at
     the top of each grid step: for each slot only the `block_t`-entry
     blocks under its length (and, with a sliding window, not below the
@@ -98,7 +114,9 @@ def _lanes(n_kv: int, kv_bytes: int) -> tuple[int, int] | None:
     .py holds it to that): 4, 8 or a multiple of 8 heads are row tiles of
     the array as written; 2 heads of int8 would fill a sixteenth of a
     tile and XLA keeps that cache head-major ([L, B, K, T, D] physically),
-    while 2 heads of bf16 or f32 get a 2-row tile and stay interleaved."""
+    while 2 heads of bf16 or f32 get a 2-row tile and stay interleaved.
+    Heads of 64 come here as the PAIRS the cache holds (`geometry`): a
+    pair is a row of 128 lanes like any head of 128."""
     if n_kv == 2 and kv_bytes == 1:
         return 2, 1
     if n_kv in (1, 2, 4) or (n_kv and n_kv % SUBLANES == 0):
@@ -125,16 +143,27 @@ def geometry(batch: int, capacity: int, n_kv: int, head_dim: int = LANES,
     divide (640 = 2.5 x 256) ends in a block that starts early and masks
     what the one before it covered. A capacity that is no multiple of 128
     has no block (the scale planes are position-minor and a partial lane
-    tile would be a masked copy), a head size that is no lane tile none
-    either, nor heads so many or wide that a lane tile of positions
-    overruns MAX_ITEM_BYTES (gemma-7b's 16 heads of 256 in bf16).
+    tile would be a masked copy), nor have heads so many or wide that a
+    lane tile of positions overruns MAX_ITEM_BYTES (gemma-7b's 16 heads
+    of 256 in bf16). A head of 64 (`head_dim`: the QUERY's) has the
+    geometry of its pair-folded cache — K / 2 rows of 128 a position —
+    where the pairs are interleaved rows of one lane (lfm2-8b-a1b's 8
+    heads: block_t 256, qwen2-7b's items); an odd head count, a single
+    pair or head-major pairs (4 int8 heads) have none, and no other head
+    size that is no lane tile has (the tiny test configurations' 16).
     slot_tile: the largest divisor of the batch whose lanes (slots x
     head-major heads) are at most MAX_TILE_LANES and whose blocks fit a
     work list of MAX_TILE_ITEMS (128 slots at 640, 16 at 8,192)."""
-    lanes = _lanes(n_kv, kv_bytes)
+    fold = 2 if 2 * head_dim == LANES else 1    # heads a cache row
+    if n_kv % fold:
+        return None
+    lanes = _lanes(n_kv // fold, kv_bytes)
+    head_dim *= fold
     if lanes is None or capacity % LANES or head_dim % LANES:
         return None
     heads, n_kv = lanes
+    if fold > 1 and (heads > 1 or n_kv == 1):
+        return None
     block_t = min(capacity, max(LANES, BLOCK_ROWS // n_kv // LANES * LANES))
     if block_t * n_kv * head_dim * kv_bytes > MAX_ITEM_BYTES:
         return None
@@ -144,12 +173,15 @@ def geometry(batch: int, capacity: int, n_kv: int, head_dim: int = LANES,
                 if batch % t == 0), block_t
 
 
-def keep_supported(n_kv: int, kv_bytes: int, quantized: bool) -> bool:
+def keep_supported(n_kv: int, kv_bytes: int, quantized: bool,
+                   head_dim: int = LANES) -> bool:
     """Whether `decode_attention(keep=)` has a form for this cache: the
     selection travels as a third scale plane, which only an int8 cache of
-    interleaved heads has."""
+    interleaved heads has (of whole lane tiles: no configuration selects
+    over pair-folded heads of 64)."""
     lanes = _lanes(n_kv, kv_bytes)
-    return quantized and lanes is not None and lanes[1] > 1
+    return (quantized and lanes is not None and lanes[1] > 1
+            and head_dim % LANES == 0)
 
 
 def _three_bf16(x):
@@ -163,7 +195,7 @@ def _three_bf16(x):
 
 def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             scale: float, block_t: int, capacity: int, heads: int,
-            n_kv: int, slab: int, ways: int, quantized: bool,
+            n_kv: int, fold: int, slab: int, ways: int, quantized: bool,
             window: int | None, compute_dtype, masked: bool):
     if quantized:
         planes_hbm, rest = rest[:2 + masked], rest[2 + masked:]
@@ -241,9 +273,10 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
     t_of_lane = lane // n_kv
     row = jax.lax.broadcasted_iota(jnp.int32, (nq, 1), 0)
-    # own-head: query row r (head r % K) against lane j (head j % K)
-    bias = jnp.where((row % n_kv) == (lane % n_kv), 0.0,
-                     NEG_INF).astype(jnp.float32)
+    # own-head: query row r (head r % K) against lane j (head j % K); of
+    # pair-folded heads row r is head r % 2K, in the lanes of pair r % 2K // 2
+    own = row % n_kv if fold == 1 else row % (n_kv * fold) // fold
+    bias = jnp.where(own == (lane % n_kv), 0.0, NEG_INF).astype(jnp.float32)
     if quantized and n_kv > 1:
         # spread[t, j] = 1 where lane j holds position t: one MXU product
         # lays a [slab, chunk] scale plane out in the scores' lane order.
@@ -312,11 +345,13 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                 planes = []
                 for c in range(block_t // chunk):
                     at = slice(c * chunk, (c + 1) * chunk)
-                    # K < 8 heads fill a slab by repeating their rows
+                    # K < 8 heads fill a slab by repeating their rows (a
+                    # row a head: 2K of them where the heads lie in pairs)
                     n_p = (2 + masked) * slab
                     e = jax.lax.dot_general(
                         _three_bf16(jnp.concatenate(
-                            sum(([scbuf[w, buf, p, :, at]] * (slab // n_kv)
+                            sum(([scbuf[w, buf, p, :, at]]
+                                 * (slab // (n_kv * fold))
                                  for p in range(2 + masked)), []), axis=0)),
                         spread[...], (((1,), (0,)), ((), ())),
                         precision=jax.lax.Precision.DEFAULT,
@@ -376,8 +411,8 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def decode_attention(
     q: jnp.ndarray,           # [B, n_q_heads, D] (single decode position)
-    k_cache: jnp.ndarray,     # [L, B, T, K, D] FULL cache (bf16/f32 or int8)
-    v_cache: jnp.ndarray,
+    k_cache: jnp.ndarray,     # [L, B, T, K, D] FULL cache (bf16/f32 or int8);
+    v_cache: jnp.ndarray,     # heads of 64 pair-folded: [L, B, T, K / 2, 128]
     layer: jnp.ndarray,       # scalar int32: which layer's cache to read
     kv_length: jnp.ndarray,   # [B] int32 valid entries (incl. current token)
     k_scale: jnp.ndarray | None = None,  # [L, B, K, T] f32 (int8 caches;
@@ -389,21 +424,22 @@ def decode_attention(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Returns [B, n_q_heads, D] in q's dtype."""
-    L, B, T, K, D = k_cache.shape
-    nq = q.shape[1]
-    group = nq // K
+    L, B, T, K, D = k_cache.shape     # K x D: a cache row as it lies
+    nq, head_dim = q.shape[1:]
+    fold = D // head_dim              # heads a lane row: 2 for heads of 64
+    group = nq // (K * fold)
     kv_bytes = k_cache.dtype.itemsize
-    tiles = geometry(B, T, K, D, kv_bytes)
+    tiles = geometry(B, T, K * fold, head_dim, kv_bytes)
     if tiles is None:
         raise ValueError(f"no decode-attention geometry for a {B} x {T} "
-                         f"cache of {K} KV heads of {D}")
+                         f"cache of {K * fold} KV heads of {head_dim}")
     slot_tile, block_t = tiles
     heads, n_kv = _lanes(K, kv_bytes)
     lanes, tile = B * heads, slot_tile * heads
-    slab = max(n_kv, SUBLANES)
+    slab = max(n_kv * fold, SUBLANES)
     quantized = k_scale is not None
     masked = keep is not None
-    if masked and not keep_supported(K, kv_bytes, quantized):
+    if masked and not keep_supported(K, kv_bytes, quantized, head_dim):
         raise ValueError("a selection rides with the scale planes of "
                          "interleaved int8 lanes alone")
     compute_dtype = (q.dtype if quantized
@@ -415,9 +451,16 @@ def decode_attention(
     # A lane's query rows ordered (group, head) and padded to whole slabs:
     # row r of the kernel's q and output belongs to the lane's head
     # r % n_kv.
-    nql = group * n_kv
+    nql = group * n_kv * fold
     nqp = -(-nql // slab) * slab
-    qk = jnp.swapaxes(q.reshape(lanes, n_kv, group, D), 1, 2)
+    qk = jnp.swapaxes(q.reshape(lanes, n_kv * fold, group, head_dim), 1, 2)
+    if fold > 1:
+        # head 2p + s of a pair: its values in half s of the lane row,
+        # zeros in the sibling's
+        half = jax.lax.broadcasted_iota(jnp.int32, (1, 1, fold, 1), 2)
+        qk = qk.reshape(lanes, nql // fold, fold, head_dim)
+        qk = jnp.concatenate([jnp.where(half == s, qk, 0)
+                              for s in range(fold)], axis=-1)
     qk = jnp.pad(qk.reshape(lanes, nql, D).astype(compute_dtype),
                  ((0, 0), (0, nqp - nql), (0, 0)))
     if heads > 1:  # head-major, as XLA lays such a cache out: a bitcast
@@ -442,7 +485,7 @@ def decode_attention(
             args += [jnp.broadcast_to(
                 keep.astype(jnp.float32)[:, None, :], (B, K, T))]
             in_specs += [hbm]
-        scratch += [pltpu.VMEM((ways, NBUF, 2 + masked, K, block_t),
+        scratch += [pltpu.VMEM((ways, NBUF, 2 + masked, K * fold, block_t),
                                jnp.float32)]
         if n_kv > 1:
             scratch += [pltpu.VMEM((chunk, chunk * n_kv), jnp.bfloat16)]
@@ -460,9 +503,9 @@ def decode_attention(
         args[3:] = [pltpu.with_memory_space_constraint(x, pltpu.HBM)
                     for x in args[3:]]
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=D ** -0.5, block_t=block_t,
-                          capacity=T, heads=heads, n_kv=n_kv, slab=slab,
-                          ways=ways,
+        functools.partial(_kernel, scale=head_dim ** -0.5, block_t=block_t,
+                          capacity=T, heads=heads, n_kv=n_kv, fold=fold,
+                          slab=slab, ways=ways,
                           quantized=quantized, window=window,
                           compute_dtype=compute_dtype, masked=masked),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -475,8 +518,14 @@ def decode_attention(
         out_shape=jax.ShapeDtypeStruct((lanes, nqp, D), q.dtype),
         interpret=interpret,
     )(*args)
-    out = out[:, :nql].reshape(lanes, group, n_kv, D)
-    return jnp.swapaxes(out, 1, 2).reshape(B, nq, D)
+    out = out[:, :nql]
+    if fold > 1:  # a head's result is in its own half of the row
+        out = out.reshape(lanes, nql // fold, fold, D)
+        out = sum(jnp.where(half == s,
+                            out[..., s * head_dim:(s + 1) * head_dim], 0)
+                  for s in range(fold))
+    out = out.reshape(lanes, group, n_kv * fold, head_dim)
+    return jnp.swapaxes(out, 1, 2).reshape(B, nq, head_dim)
 
 
 # A sharded trunk takes the kernel only from here up: the gate the old

@@ -67,6 +67,10 @@ CASES = {
     "gemma-2b: 1 KV head of 256": (18, 8, 8192, 1, 8, jnp.int8, None, 256),
     "gemma-7b: 16 KV heads of 256": (2, 8, 4096, 16, 16, jnp.int8, None,
                                      256),
+    # heads of 64 lie in the cache in pairs, [L, B, T, K / 2, 128]
+    # (models/llama.py kv_row): as written XLA pads the 64 to a lane tile
+    "lfm2-8b-a1b cell": (6, 128, 640, 8, 32, jnp.int8, None, 64),
+    "8 KV heads of 64 bf16": (6, 128, 640, 8, 32, jnp.bfloat16, None, 64),
 }
 
 
@@ -89,7 +93,8 @@ def test_kernel_compiles_at_served_shapes(one_chip, no_cache, case):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
 
     q = shape((B, nq, D), jnp.bfloat16)
-    kv = shape((L, B, T, K, D), dtype)
+    kv = shape((L, B, T, K * D // 128, 128) if D == 64 else (L, B, T, K, D),
+               dtype)
     scale = shape((L, B, K, T), jnp.float32) if dtype == jnp.int8 else None
     compiled = jax.jit(
         lambda q, k, v, layer, n, ks, vs: decode_attention(
@@ -98,6 +103,12 @@ def test_kernel_compiles_at_served_shapes(one_chip, no_cache, case):
             scale, scale).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
+    if D == 64:
+        # the leaves lie dense — four-row tiles of 128 lanes — and the
+        # kernel's [T * 4, 128] view of them is a bitcast
+        tile = "T(4,128)(4,1)" if dtype == jnp.int8 else "T(4,128)(2,1)"
+        assert f"[6,128,640,4,128]{{4,3,2,1,0:{tile}}} parameter(1)" in text
+        assert re.search(r"\[6,128,2560,128\]\S* bitcast\(%k\.", text)
     # the cache is the call's operand as it lies: viewed, never copied
     assert not re.search(r" copy\(%[kv]\.", text)
     assert not whole_cache_copies(text)
@@ -519,37 +530,52 @@ def _lfm2_shapes(one_chip, rows, capacity):
     return cfg, params, cache
 
 
-def test_lfm2_decode_step_moves_the_tails_in_place(one_chip, no_cache,
-                                                   monkeypatch):
+@pytest.fixture(scope="module")
+def lfm2_decode_step(one_chip):
     """lfm2-8b-a1b's decode trunk at its cell (128 slots x 640), all 24
-    layers in 13 scans: it lowers for a v5e; the 18.9 MB stack of tails
-    (`bf16[18,2,128,2048]`: a short convolution's whole state) is donated
-    in, aliased out and never copied or relaid whole; heads of 64 have no
-    decode kernel (`attention_paths` says so), the step's experts are the
-    dense mixture (128 tokens are under `ROUTED_FROM[(32, 4)]`), so the
-    program holds no custom call at all."""
+    layers in 13 scans, compiled once for a v5e: (compiled program, its
+    text)."""
+    from jax.experimental.compilation_cache import compilation_cache
     from symmetry_tpu.models import llama, moe
 
-    for module in (llama, moe):
-        monkeypatch.setattr(module, "interpret_mode", lambda: False)
     B, T = 128, 640
     cfg, params, cache = _lfm2_shapes(one_chip, B, T)
     assert cache.ssm is None and cache.conv.shape == (18, 2, 128, 2048)
-    assert cache.k.shape == (6, 128, 640, 8, 64)
-    paths = llama.attention_paths(cfg, T, None, batch=B, kv_bytes=1)
-    assert (paths["prefill"], paths["decode"]) == ("pallas", "xla")
-    assert moe.moe_route(B, 32, 4) == "dense-mixture"
+    assert cache.k.shape == (6, 128, 640, 4, 128)
     tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
-    with jax.default_matmul_precision("default"):
-        compiled = jax.jit(
-            lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
-            donate_argnums=(2,)).lower(params, tok, cache).compile()
+    before = jax.config.jax_enable_compilation_cache
+    with pytest.MonkeyPatch.context() as patch:
+        for module in (llama, moe):
+            patch.setattr(module, "interpret_mode", lambda: False)
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            paths = llama.attention_paths(cfg, T, None, batch=B, kv_bytes=1)
+            assert paths == {"prefill": "pallas", "decode": "pallas",
+                             "decode_slot_tile": 128, "decode_block_t": 256}
+            assert moe.moe_route(B, 32, 4) == "dense-mixture"
+            with jax.default_matmul_precision("default"):
+                compiled = jax.jit(
+                    lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
+                    donate_argnums=(2,)).lower(params, tok, cache).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", before)
+            compilation_cache.reset_cache()
+    return compiled, compiled.as_text()
+
+
+def test_lfm2_decode_step_moves_the_tails_in_place(lfm2_decode_step):
+    """The 18.9 MB stack of tails (`bf16[18,2,128,2048]`: a short
+    convolution's whole state) is donated in, aliased out and never copied
+    or relaid whole; the step's experts are the dense mixture (128 tokens
+    are under `ROUTED_FROM[(32, 4)]`), so the program's only custom calls
+    are the six attention layers' decode kernel."""
+    compiled, text = lfm2_decode_step
     memory = compiled.memory_analysis()
     tails = 18 * 2 * 128 * 2048 * 2
     kv = 2 * 6 * 128 * 640 * 8 * 64
     assert memory.alias_size_in_bytes >= tails + kv
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 0
+    assert text.count("tpu_custom_call") == 6
     whole = [line.strip()[:160] for line in text.splitlines()
              if re.search(r"= bf16\[18,2,128,2048\]\S* (copy|transpose)\(",
                           line)]
@@ -559,6 +585,31 @@ def test_lfm2_decode_step_moves_the_tails_in_place(one_chip, no_cache,
               if re.search(r"= s8\[22,32,\d+,\d+\]\S* (copy|transpose)\(",
                            line)]
     assert not stacks, stacks[0]
+
+
+def test_lfm2_decode_step_reads_the_kv_pairs_where_they_lie(lfm2_decode_step):
+    """Heads of 64 through the decode kernel: the K/V leaves lie as the
+    pair view assumes (`s8[6,128,640,4,128]` in four-row tiles of 128
+    lanes, dense: no padded half), each of the six calls takes the WHOLE
+    stack as a bitcast, and no layer's 42 MB of K or V — nor the stack —
+    is sliced, copied, relaid or staged in fast memory (the XLA path made
+    twelve such copies a step: 1 GB moved where 0.27 GB is live); the
+    stack's only writers are the in-place scatters of `write_kv`. The
+    step's temporaries are megabytes, not the 1.5 GB of staged layers."""
+    compiled, text = lfm2_decode_step
+    assert len(re.findall(r"%decode_attention[.\d]* = ", text)) == 6
+    leaf = r"s8\[6,128,640,4,128\]"
+    laid = set(re.findall(leaf + r"(\{[^}]*\})", text))
+    assert laid == {"{4,3,2,1,0:T(4,128)(4,1)}"}, laid
+    assert len(re.findall(r"= s8\[6,128,2560,128\]\S* bitcast\(", text)) == 12
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= s8\[\d+,128,(640,4|2560),128\]\S* (copy|"
+                          r"copy-start|transpose|slice|dynamic-slice)\(", line)
+             or re.search(r"= s8\[128,(640,4|2560),128\]", line)
+             or (re.search(rf"= {leaf}\S* fusion\(", line)
+                 and "scatter" not in line)]
+    assert not moved, moved[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 @pytest.mark.parametrize("rows,bucket", [(2, 256), (8, 64)])

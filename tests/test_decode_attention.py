@@ -48,6 +48,12 @@ def reference(q, k_layer, v_layer, lengths, k_scale=None, v_scale=None):
     return out[:, 0]
 
 
+def rows(x):
+    """K/V as the cache holds it (models/llama.py kv_row): heads of 64 in
+    pairs, [..., K / 2, 128]; any other head as it is."""
+    return x.reshape(*x.shape[:-2], -1, 128) if x.shape[-1] == 64 else x
+
+
 def to_minor(scale):
     """quantize_kv emits [L, B, T, K]; caches store position-minor [L, B, K, T]."""
     return jnp.moveaxis(scale, -1, -2)
@@ -108,6 +114,8 @@ class TestDecodeAttentionKernel:
         ("tiny-mha", 640, False),
         ("mixtral-8x7b", 2048, True),
         ("gemma-2b", 8192, True),        # one KV head of 256
+        ("lfm2-8b-a1b", 640, True),      # 8 heads of 64: four pairs a row
+        ("llama3.2-1b", 640, True),
     ])
     def test_supports_gate(self, name, capacity, want):
         """geometry() is the one gate: None is the XLA path."""
@@ -145,10 +153,20 @@ class TestDecodeAttentionKernel:
         (8, 4096, 3, None),              # heads that tile nothing
         (8, 4096, 12, None),
         (8, 4096, 16, (8, 128)),
+        # (heads, head size, bytes): heads of 64 lie in pairs of 128 lanes
+        (128, 640, (8, 64, 1), (128, 256)),   # lfm2-8b-a1b's cell: qwen2's
+        (128, 640, (8, 64, 2), (128, 256)),   # items; bf16 alike
+        (128, 640, (16, 64, 1), (128, 128)),
+        (8, 4096, (4, 64, 2), (8, 512)),      # two bf16 pairs interleave
+        (8, 4096, (4, 64, 1), None),     # two int8 pairs lie head-major
+        (128, 640, (7, 64, 1), None),    # an odd count makes no pairs
+        (128, 640, (2, 64, 1), None),    # one pair: a row a slab has no form
+        (8, 256, (2, 16, 1), None),      # the tiny configurations' head
+        (8, 256, (8, 32, 1), None),
     ])
     def test_geometry_follows_the_cache_shape(self, batch, capacity, n_kv,
                                               want):
-        assert geometry(batch, capacity, n_kv) == want
+        assert geometry(batch, capacity, *np.atleast_1d(n_kv)) == want
 
     def test_routes_by_shape_and_mesh(self):
         """One route per observable case, each named in the reply."""
@@ -192,18 +210,31 @@ class TestDecodeAttentionKernel:
             small = dataclasses.replace(preset(name), head_dim=128)
             assert paths(small, 4096, batch=8)["decode"] == \
                 "pallas-interpret"
+        # heads of 64 in interleaved pairs: the kernel, at qwen2's tiles
+        lfm2 = preset("lfm2-8b-a1b")
+        assert paths(lfm2, 640) == {
+            "prefill": "pallas-interpret", "decode": "pallas-interpret",
+            "decode_slot_tile": 128, "decode_block_t": 256}
+        assert paths(lfm2, 640, kv_bytes=2)["decode_block_t"] == 256
+        odd = paths(dataclasses.replace(lfm2, num_kv_heads=4), 640)
+        assert odd["decode"] == "xla" and "head of 64" in odd["decode_why"]
+        # no selection over pairs: the third plane is a row a whole head
+        assert da.keep_supported(8, 1, True)
+        assert not da.keep_supported(8, 1, True, head_dim=64)
 
 
 LENGTHS_640 = [0, 1, 127, 128, 129, 640]
 
 
-def case_640(K, G, quantized, dtype=jnp.bfloat16, seed=0, B=6, T=640):
+def case_640(K, G, quantized, dtype=jnp.bfloat16, seed=0, B=6, T=640,
+             D=128):
     """One layer pair of a 6-slot x 640 cache at the dense cells' head
-    shapes, lengths mixed in the batch; scales position-minor."""
+    shapes, lengths mixed in the batch; scales position-minor. K and V as
+    [.., K, D]: `rows` makes the cache's form of a head of 64."""
     ks = jax.random.split(jax.random.key(seed), 3)
-    q = jax.random.normal(ks[0], (B, K * G, 128), dtype)
-    k = jax.random.normal(ks[1], (2, B, T, K, 128), jnp.float32)
-    v = jax.random.normal(ks[2], (2, B, T, K, 128), jnp.float32)
+    q = jax.random.normal(ks[0], (B, K * G, D), dtype)
+    k = jax.random.normal(ks[1], (2, B, T, K, D), jnp.float32)
+    v = jax.random.normal(ks[2], (2, B, T, K, D), jnp.float32)
     if not quantized:
         return q, k.astype(dtype), v.astype(dtype), ()
     kq, ksc = quantize_kv(k)
@@ -212,19 +243,22 @@ def case_640(K, G, quantized, dtype=jnp.bfloat16, seed=0, B=6, T=640):
 
 
 class TestCellShapes:
-    """Capacity 640 with mistral-7b's (8 KV x 4) and qwen2-7b's (4 KV x 7)
-    heads: the shapes the benchmark's dense cells decode at."""
+    """Capacity 640 with mistral-7b's (8 KV x 4), qwen2-7b's (4 KV x 7)
+    and lfm2-8b-a1b's (8 KV of 64 x 4) heads: the shapes the benchmark's
+    one-chip cells decode at."""
 
     @pytest.mark.parametrize("slot_tile", [1, 2, 3, 6])
     @pytest.mark.parametrize("quantized", [False, True],
                              ids=["bf16", "int8"])
-    @pytest.mark.parametrize("K, G", [(8, 4), (4, 7)])
-    def test_matches_gqa_attention(self, tiled, K, G, quantized, slot_tile):
-        q, k, v, scales = case_640(K, G, quantized)
+    @pytest.mark.parametrize("K, G, D", [(8, 4, 128), (4, 7, 128),
+                                         (8, 4, 64), (16, 2, 64)])
+    def test_matches_gqa_attention(self, tiled, K, G, D, quantized,
+                                   slot_tile):
+        q, k, v, scales = case_640(K, G, quantized, D=D)
         lengths = jnp.asarray(LENGTHS_640, jnp.int32)
-        assert geometry(6, 640, K)[0] == 6
-        got = tiled(lanes=slot_tile)(q, k, v, jnp.int32(1), lengths,
-                                     *scales, interpret=True)
+        assert geometry(6, 640, K, D)[0] == 6
+        got = tiled(lanes=slot_tile)(q, rows(k), rows(v), jnp.int32(1),
+                                     lengths, *scales, interpret=True)
         assert got.shape == q.shape and got.dtype == q.dtype
         assert np.isfinite(np.asarray(got, np.float32)).all()
         want = reference(q, k[1], v[1], jnp.maximum(lengths, 1),
@@ -236,14 +270,16 @@ class TestCellShapes:
 
     @pytest.mark.parametrize("quantized", [False, True],
                              ids=["bf16", "int8"])
-    @pytest.mark.parametrize("K, G", [(8, 4), (4, 7), (2, 4)])
-    def test_a_slot_does_not_see_its_neighbours(self, tiled, K, G,
+    @pytest.mark.parametrize("K, G, D", [(8, 4, 128), (4, 7, 128),
+                                         (2, 4, 128), (8, 4, 64)])
+    def test_a_slot_does_not_see_its_neighbours(self, tiled, K, G, D,
                                                 quantized):
         """check_correct compares two identical greedy requests that land
         in different slots beside different neighbours: a slot's result
         must be bit-identical whatever the others' lengths, wherever the
         tile boundaries fall."""
-        q, k, v, scales = case_640(K, G, quantized, seed=2)
+        q, k, v, scales = case_640(K, G, quantized, seed=2, D=D)
+        k, v = rows(k), rows(v)
         mine, slot = 300, 2
         outs = []
         for others, slot_tile in (([640, 1, 0, 129, 513], 6),
@@ -279,14 +315,16 @@ class TestCellShapes:
                                    np.asarray(want, np.float32)[1:],
                                    rtol=2e-2, atol=2e-2)
 
+    @pytest.mark.parametrize("K, G, D", [(4, 7, 128), (8, 4, 64)])
     @pytest.mark.parametrize("block_t", [128, 256, 384, 640])
-    def test_every_block_size_reads_the_same(self, tiled, block_t):
+    def test_every_block_size_reads_the_same(self, tiled, block_t, K, G, D):
         """640 = 2.5 x 256: the last block starts early and masks what the
-        one before it covered; whatever the block, the same result."""
-        q, k, v, scales = case_640(4, 7, True, seed=5)
+        one before it covered; whatever the block, the same result (four
+        rows a position either way: 4 heads of 128, 4 pairs of 64)."""
+        q, k, v, scales = case_640(K, G, True, seed=5, D=D)
         lengths = jnp.asarray([640, 513, 512, 257, 256, 3], jnp.int32)
         got = tiled(block_rows=4 * block_t)(
-            q, k, v, jnp.int32(1), lengths, *scales, window=300,
+            q, rows(k), rows(v), jnp.int32(1), lengths, *scales, window=300,
             interpret=True)
         want = gqa_attention(q[:, None], k[1], v[1], (lengths - 1)[:, None],
                              lengths, sliding_window=300,
@@ -306,6 +344,31 @@ class TestCellShapes:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    rtol=2e-2, atol=2e-2)
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["f32", "int8"])
+    def test_a_head_never_reads_its_pairs_sibling(self, quantized):
+        """Heads 2p and 2p + 1 share a 128-lane row: with the odd heads'
+        V a thousand times the even heads' (and K unrelated), every head's
+        result is still its own to float32 rounding — the sibling's half
+        of the output product is dropped, its half of the contraction
+        meets the query's zeros."""
+        q, k, v, _ = case_640(8, 4, False, jnp.float32, seed=7, D=64)
+        v = v * jnp.where(jnp.arange(8) % 2, 1000.0, 1.0)[:, None]
+        scales = ()
+        if quantized:
+            (k, ksc), (v, vsc) = quantize_kv(k), quantize_kv(v)
+            scales = (to_minor(ksc), to_minor(vsc))
+        lengths = jnp.asarray([257, 1, 127, 128, 129, 640], jnp.int32)
+        got = decode_attention(q, rows(k), rows(v), jnp.int32(1), lengths,
+                               *scales, interpret=True)
+        want = reference(q, k[1], v[1], lengths, *(s[1] for s in scales))
+        got, want = (np.asarray(x).reshape(6, 8, 4, 64) for x in (got, want))
+        assert np.abs(want[:, 1::2]).mean() > 50 * np.abs(want[:, ::2]).mean()
+        np.testing.assert_allclose(got[:, ::2], want[:, ::2],
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(got[:, 1::2], want[:, 1::2],
+                                   rtol=2e-5, atol=2e-2)
 
     def test_heads_that_tile_no_sublanes_are_refused(self):
         assert geometry(2, 128, 3) is None
@@ -327,17 +390,32 @@ class TestCellShapes:
                                    rtol=2e-2, atol=2e-2)
 
 
+# (query heads, KV heads, head size): 2 heads of 128; 8 of 64, which the
+# cache holds in pairs (write_kv folds, the XLA path unfolds, the kernel
+# reads the pairs as they lie)
+HEADS = [(4, 2, 128), (8, 8, 64)]
+
+
 class TestModelIntegration:
-    def test_forward_decode_uses_kernel_and_matches(self, monkeypatch):
+    @pytest.mark.parametrize("heads", HEADS, ids=str)
+    def test_forward_decode_uses_kernel_and_matches(self, monkeypatch,
+                                                    heads):
         """Full model decode through the kernel (capacity 128: routed by
         shape, interpreted here) must reproduce the XLA path
         token-for-token."""
         import symmetry_tpu.ops.decode_attention as da
         from symmetry_tpu.models import ModelConfig, forward, init_cache, init_params
+        from symmetry_tpu.models.llama import attention_paths
 
+        nq, nkv, D = heads
         cfg = ModelConfig(vocab_size=256, hidden_size=128, num_layers=2,
-                          num_heads=4, num_kv_heads=2, intermediate_size=256,
-                          head_dim=128, rope_theta=10000.0, max_position=256)
+                          num_heads=nq, num_kv_heads=nkv,
+                          intermediate_size=256,
+                          head_dim=D, rope_theta=10000.0, max_position=256)
+        assert attention_paths(cfg, 128, batch=2, kv_bytes=4)[
+            "decode"] == "pallas-interpret"
+        assert init_cache(cfg, 2, 128, jnp.float32).k.shape[3:] == (
+            nkv * D // 128, 128)
         params = init_params(cfg, jax.random.key(0), jnp.float32)
         prompt = jnp.asarray(
             np.random.default_rng(0).integers(0, 256, (2, 8)), jnp.int32)
@@ -357,13 +435,16 @@ class TestModelIntegration:
 
         np.testing.assert_array_equal(decode(True), decode(False))
 
-    def test_forward_decode_kernel_quantized_cache(self, monkeypatch):
+    @pytest.mark.parametrize("heads", HEADS, ids=str)
+    def test_forward_decode_kernel_quantized_cache(self, monkeypatch, heads):
         import symmetry_tpu.ops.decode_attention as da
         from symmetry_tpu.models import ModelConfig, forward, init_cache, init_params
 
+        nq, nkv, D = heads
         cfg = ModelConfig(vocab_size=256, hidden_size=128, num_layers=2,
-                          num_heads=4, num_kv_heads=2, intermediate_size=256,
-                          head_dim=128, rope_theta=10000.0, max_position=256)
+                          num_heads=nq, num_kv_heads=nkv,
+                          intermediate_size=256,
+                          head_dim=D, rope_theta=10000.0, max_position=256)
         params = init_params(cfg, jax.random.key(1), jnp.float32)
         prompt = jnp.asarray(
             np.random.default_rng(1).integers(0, 256, (1, 6)), jnp.int32)

@@ -437,7 +437,9 @@ def test_the_published_shapes_by_eval_shape():
     cache = jax.eval_shape(lambda: llama.init_cache(
         FULL, 128, 640, jnp.bfloat16, quantized=True))
     assert cache.ssm is None and cache.conv.shape == (18, 2, 128, 2048)
-    assert cache.k.shape == (6, 128, 640, 8, 64)
+    # 8 heads of 64 a position, in four pairs of 128 lanes (kv_row)
+    assert cache.k.shape == (6, 128, 640, 4, 128)
+    assert cache.k_scale.shape == (6, 128, 8, 640)
     assert hybrid.state_bytes_per_slot(FULL) == {"ssm": 0, "conv": 147_456}
 
 
@@ -563,14 +565,18 @@ def test_the_engine_reports_the_kind_its_tails_the_router_and_the_routes(
     assert moe_report["dense_layers"] == 2
     assert moe_report["expert_layers"] == 6
     assert "shared_expert" not in moe_report
-    # heads of 16 here, of 64 at the published widths: no lane tile, so
-    # decode says `xla` and why; prefill takes the flash kernel
+    # heads of 16 here are no lane tile, so decode says `xla` and why;
+    # prefill takes the flash kernel. The published widths' heads of 64
+    # lie in the cache in pairs and take the decode kernel at qwen2-7b's
+    # tiles (four 128-lane rows a position)
     paths = engine.attention_paths()
     assert paths["prefill"] == "pallas-interpret" and paths["decode"] == "xla"
     assert "head of 16" in paths["decode_why"]
     full = llama.attention_paths(FULL, 640, None, batch=128, kv_bytes=1)
-    assert full["decode"] == "xla" and "head of 64" in full["decode_why"]
-    assert set(full) == {"prefill", "decode", "decode_why"}
+    assert full == {"prefill": "pallas-interpret",
+                    "decode": "pallas-interpret",
+                    "decode_slot_tile": 128, "decode_block_t": 256}
+    assert llama.kv_row(FULL) == (4, 128) and llama.kv_row(CFG) == (2, 16)
     # the published widths: 147 KB of tails a row, so the scratch bound
     # leaves the widest batch there is; 6,528 bytes of K/V a token
     whole = InferenceEngine.__new__(InferenceEngine)

@@ -22,6 +22,18 @@ by the Mosaic compiler only.
                      forms timed on normal draws (`--only select` runs
                      these rows alone)
 
+  decode64           lfm2-8b-a1b's cell: 6 attention layers x 128 slots x
+                     640, 8 int8 KV heads of 64 held in pairs
+                     ([6, 128, 640, 4, 128]: models/llama.py kv_row), 32
+                     query heads, the lengths of the cell's traffic (mean
+                     ~200 live positions); the kernel against
+                     gqa_attention on the chip, then a STEP's six layers
+                     timed three ways — the kernel, the XLA path over the
+                     pairs (what a cache the kernel has no form for falls
+                     back to) and the XLA path over `[6, 128, 640, 8, 64]`
+                     as written (PR 42's program: the padded leaves)
+                     (`--only decode64` runs these rows alone)
+
 Then the one timing question later PRs lean on: does
 `jax.block_until_ready` on this chip wait for completion? One decode block
 of chip_smoke.py's engine is timed under it, under the fetch fence of
@@ -288,6 +300,90 @@ def select_cases(prompts=(6144, 14848), slots=64, capacity=16384,
     return rows
 
 
+def decode64_cases(layers=6, slots=128, capacity=640, repeats=20
+                   ) -> list[dict]:
+    """The decode kernel at a head of 64 (ops/decode_attention.py, the
+    pair form) at lfm2-8b-a1b's cell: right on the chip, and what one
+    decode step's six attention layers cost through it and through the
+    XLA path it replaced."""
+    nq, K, d = 32, 8, 64
+    ks = jax.random.split(jax.random.key(64), 3)
+    q = jax.random.normal(ks[0], (slots, nq, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (layers, slots, capacity, K, d),
+                          jnp.bfloat16)
+    v = jax.random.normal(ks[2], (layers, slots, capacity, K, d),
+                          jnp.bfloat16)
+    kq, ksc = quantize_kv(k)
+    vq, vsc = quantize_kv(v)
+    ksc, vsc = jnp.moveaxis(ksc, -1, -2), jnp.moveaxis(vsc, -1, -2)
+    del k, v
+    # batch-closed: prompts of 51-179 tokens, 128-448 asked, a stream seen
+    # at a uniform point of its output; two lanes at the edges
+    rng = np.random.default_rng(64)
+    lengths = rng.integers(51, 180, slots) + (
+        rng.random(slots) * rng.integers(128, 449, slots)).astype(np.int64)
+    lengths[:2] = (1, capacity)
+    lengths = jnp.asarray(np.minimum(lengths, capacity), jnp.int32)
+
+    def pairs(x):  # as the cache holds heads of 64
+        return x.reshape(layers, slots, capacity, K // 2, 2 * d)
+
+    def xla(q, kc, vc, ksc, vsc, layer):
+        def at(x):
+            return jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
+        return gqa_attention(
+            q[:, None], at(kc).reshape(slots, capacity, K, d),
+            at(vc).reshape(slots, capacity, K, d), (lengths - 1)[:, None],
+            lengths, k_scale=at(ksc), v_scale=at(vsc))[:, 0]
+
+    def step(one_layer):
+        # a decode step's attention: every layer once, each fed the one
+        # before it so that none can be dropped or overlapped
+        @jax.jit
+        def run(q, kc, vc, ksc, vsc):
+            def body(layer, q):
+                return one_layer(q, kc, vc, ksc, vsc, layer)
+            return jax.lax.fori_loop(0, layers, body, q)
+        return run
+
+    kernel = step(lambda q, kc, vc, ksc, vsc, layer: decode_attention(
+        q, kc, vc, layer, lengths, ksc, vsc, interpret=interpret_mode()))
+    want = np.asarray(xla(q.astype(jnp.float32), kq, vq, ksc, vsc,
+                          jnp.int32(3)), np.float32)
+    row = check(
+        f"decode_attention {slots} x {capacity}, {K} int8 KV heads of {d} "
+        f"in pairs, mean length {int(np.asarray(lengths).mean())}",
+        lambda: decode_attention(q, pairs(kq), pairs(vq), jnp.int32(3),
+                                 lengths, ksc, vsc,
+                                 interpret=interpret_mode()),
+        want, BF16_TOL)
+    def lies(x):  # how the device holds an array, and in how many bytes
+        try:
+            return {"layout": str(x.format.layout),
+                    "bytes": int(x.on_device_size_in_bytes())}
+        except Exception as exc:  # noqa: BLE001 — a report, not a check
+            return {"error": f"{type(exc).__name__}: {exc}"[:200]}
+
+    row["as_written"] = {"shape": list(kq.shape), **lies(kq)}
+    row["in_pairs"] = {"shape": list(pairs(kq).shape), **lies(pairs(kq))}
+    if row["ok"]:
+        try:
+            # calls back to back under ONE fence (a fence a call costs
+            # more than the layers it would time)
+            for name, fn, kc, vc in (
+                    ("kernel_step_ms", kernel, pairs(kq), pairs(vq)),
+                    ("xla_pairs_step_ms", step(xla), pairs(kq), pairs(vq)),
+                    ("xla_as_written_step_ms", step(xla), kq, vq)):
+                row[name] = round(timeit(fn, q, kc, vc, ksc, vsc,
+                                         n=repeats), 3)
+                del kc, vc
+            row["layers_a_step"] = layers
+        except Exception as exc:  # noqa: BLE001
+            row.update(ok=False,
+                       error=f"{type(exc).__name__}: {exc}"[:2000])
+    return [row]
+
+
 def fence_timing() -> dict:
     """One decode block of chip_smoke.py's engine (mistral-7b int8+kv8,
     8 slots × 4096, block 16), 10 blocks per fence."""
@@ -330,7 +426,7 @@ def fence_timing() -> dict:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--only", choices=["select"],
+    ap.add_argument("--only", choices=["select", "decode64"],
                     help="these rows alone, no fence timing")
     only = ap.parse_args().only
     if interpret_mode() or jax.default_backend() != "tpu":
@@ -340,7 +436,8 @@ def main() -> int:
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": jax.device_count()}
-    rows = select_cases()
+    rows = ((select_cases() if only != "decode64" else [])
+            + (decode64_cases() if only != "select" else []))
     if only is None:
         rows = flash_cases() + decode_cases() + matmul_cases() + rows
     for r in rows:

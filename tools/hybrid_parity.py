@@ -464,6 +464,8 @@ def main() -> int:
         "device": {"platform": dev.platform, "kind": dev.device_kind,
                    "count": jax.device_count()},
         "preset": args.preset, "layers": cfg.num_layers,
+        "attention": llama.attention_paths(
+            cfg, args.capacity, None, batch=n, kv_bytes=1),
         "state_dtype": args.state_dtype, "dtype": args.dtype, "prompts": n,
         "prompt_lens": lens.tolist(), "bucket": args.bucket,
         "decode_steps": d, "tokens_compared": int(margins.size),
