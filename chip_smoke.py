@@ -46,8 +46,8 @@ contends for the chip its child needs.
     python chip_smoke.py --preset qwen3-next-80b-a3b
         # the one-chip hybrid models (a per-slot recurrent state beside an
         # attention layer; the report carries `ssm`: the kind, its state
-        # and each program's form — Mamba-2's decode step must read
-        # `pallas`, the Gated DeltaNet's is jnp so far — and `moe`, whose
+        # and each program's form — the decode step of either kind must
+        # read `pallas`: ops/ssm_step.py — and `moe`, whose
         # `grouped_matmul` must read `pallas` on one chip: ops/gmm.py)
     python chip_smoke.py --preset keye-vl-2.0-30b-a3b
         # learned sparse attention on one chip (a lightning indexer with a
@@ -337,9 +337,10 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
                         f"in both programs: {sparse.get('select')}")
     ssm = startup.get("ssm") or {}
     ssm_decode = ssm.get("decode")
-    # Mamba-2's step has a kernel (ops/ssm_step.py); the Gated DeltaNet's
-    # (`--preset qwen3-next-80b-a3b`) is jnp so far and says so
-    if (ssm_decode is not None and ssm.get("kind", "mamba2") == "mamba2"
+    # Mamba-2's step and the Gated DeltaNet's (`--preset qwen3-next-80b-a3b`)
+    # each have a kernel (ops/ssm_step.py); a short convolution's tail is
+    # two positions, stepped in jnp
+    if (ssm_decode is not None and ssm.get("kind", "mamba2") != "short_conv"
             and ssm_decode.get("form") != "pallas"):
         failures.append(f"the recurrent layers' decode step did not run "
                         f"the compiled Pallas kernel: {ssm_decode}")

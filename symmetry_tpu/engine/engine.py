@@ -1382,7 +1382,8 @@ class InferenceEngine:
             kind = {"kind": "gated_deltanet",
                     "linear_attention_layers":
                         len(c.layers_of("linear_attention"))}
-            chunk, decode = c.linear_chunk_size, gdn.step_form(c)
+            chunk, decode = c.linear_chunk_size, gdn.step_form(
+                c, cache.ssm.dtype.itemsize)
         return {
             **kind, **shared,
             "state_bytes_per_slot": per_slot["ssm"],

@@ -30,10 +30,16 @@ Two forms, one mathematics:
 
 - `step_at` (S == 1): the recurrence with both read-outs taken from the OLD
   state — `S^T k = a (S_{t-1}^T k)` and `S_t^T q = a (S_{t-1}^T q) +
-  (k . q) d_t` — so one pass reads the state for k and q together and the
-  update `a S + k (outer) d` is one elementwise pass over it. Plain jnp:
-  XLA decides how often the state crosses HBM (`step_form` says so; a
-  one-pass kernel as ops/ssm_step.py is for Mamba-2 is queued work).
+  (k . q) d_t` — so one copy of a tile of the state serves both read-outs
+  and the update `a S + k (outer) d`. Between the heads and the gate it is
+  ONE Pallas kernel over the whole [n_layers, B, Hv, Dk, Dv] stack
+  (ops/ssm_step.py `gdn_step`: each head's [Dk, Dv] tile read once, reduced
+  for k and q in VMEM, updated where it lies; the layer is DMA addressing)
+  wherever that kernel has a geometry for the state (`step_form`);
+  `recurrence` is the same algebra in jnp — what the tests hold the kernel
+  to, and what a state the kernel has no geometry for falls back to (XLA
+  makes it two fusions a layer, three crossings of the state: PERF.md,
+  PR 35 / PR 45).
 - `chunked` (S > 1; prefill): per chunk of `linear_chunk_size` positions,
   with G the running sum of g inside the chunk, the deltas solve a unit
   lower-triangular system
@@ -56,6 +62,8 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
+from symmetry_tpu.ops import ssm_step
+from symmetry_tpu.ops.interpret import interpret_mode
 from symmetry_tpu.ops.quant import qmatmul
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -71,11 +79,15 @@ def sizes(config) -> dict:
             "proj": 2 * hk * dk + 2 * hv * dv}
 
 
-def step_form(config) -> dict:
-    """What `startup.ssm.decode` reports for this kind: the jnp recurrence,
-    both read-outs from the old state and one update pass (XLA's schedule
-    of them over HBM is read from the trace, not promised here)."""
-    return {"form": "step (jnp), read-outs from the old state"}
+def step_form(config, itemsize: int = 4) -> dict:
+    """Which form the single-position recurrence takes for this config's
+    state (what `step_at` routes by and the engine reports): "pallas"
+    with its `head_tile` ("pallas-interpret": the same kernel on the CPU
+    backend), or the jnp recurrence where the kernel has no geometry."""
+    z = sizes(config)
+    return ssm_step.step_form(
+        z["Hv"], z["Dk"], z["Dv"], itemsize, interpret=interpret_mode(),
+        otherwise="step (jnp), read-outs from the old state")
 
 
 def _beta_decay(u: jnp.ndarray, lp: dict, z: dict):
@@ -142,11 +154,15 @@ def step_at(u: jnp.ndarray, lp: dict, state: jnp.ndarray, layer,
         [conv.astype(jnp.float32), qkv[None].astype(jnp.float32)], axis=0)
     q, k, v = _heads(jax.nn.silu(jnp.sum(
         window * lp["conv_w"].astype(jnp.float32)[:, None, :], axis=0)), z)
-    o, new = recurrence(
-        jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False
-                                     ).astype(jnp.float32),
-        jnp.exp(g), beta, q, k, v)
-    state = state.at[layer].set(new.astype(state.dtype))
+    if "head_tile" in step_form(config, state.dtype.itemsize):
+        o, state = ssm_step.gdn_step(state, layer, jnp.exp(g), beta, q, k, v,
+                                     interpret=interpret_mode())
+    else:
+        o, new = recurrence(
+            jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False
+                                         ).astype(jnp.float32),
+            jnp.exp(g), beta, q, k, v)
+        state = state.at[layer].set(new.astype(state.dtype))
     out = _gate_out(o, gate, lp, config.rms_eps, u.dtype)
     return out, state, window[1:].astype(conv.dtype)
 

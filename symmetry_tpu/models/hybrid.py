@@ -39,8 +39,9 @@ stack is `layers.gdn`, its state rides the same two cache leaves — `ssm`
 [n_linear, B, Hv, Dk, Dv] float32, a MATRIX a value head, and `conv`
 [n_linear, K - 1, B, 2 Hk Dk + Hv Dv] — its norms are zero-centred
 (`norm_plus_one`), its head untied, its shared expert gated; the forms
-follow the call's shape in the same way (models/gdn.py `step_at`,
-`chunked`).
+follow the call's shape in the same way (models/gdn.py `step_at` — the
+whole stack and the layer's index to the same file's other kernel,
+`gdn_step` — and `chunked`).
 
 An lfm2_moe model is the same trunk with a third recurrent kind, whose
 state is a tail ALONE: its stack is `layers.sconv` (a gated short

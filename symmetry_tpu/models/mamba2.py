@@ -101,12 +101,9 @@ def step_form(config, itemsize: int = 4) -> dict:
     with its `head_tile` ("pallas-interpret": the same kernel on the CPU
     backend), or the jnp recurrence where the kernel has no geometry."""
     z = sizes(config)
-    tile = ssm_step.head_tile(z["H"], z["P"], z["N"], itemsize,
-                              interpret=interpret_mode())
-    if tile is None:
-        return {"form": "step (jnp), two passes over the state"}
-    return {"form": "pallas-interpret" if interpret_mode() else "pallas",
-            "head_tile": tile}
+    return ssm_step.step_form(
+        z["H"], z["P"], z["N"], itemsize, interpret=interpret_mode(),
+        otherwise="step (jnp), two passes over the state")
 
 
 def recurrence(ssm, a, dx, b, c, skip):

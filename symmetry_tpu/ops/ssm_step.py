@@ -1,4 +1,5 @@
-"""Pallas Mamba-2 decode step: one pass over the recurrent state.
+"""Pallas decode steps of the recurrent layers (Mamba-2: `ssm_step`; Gated
+DeltaNet: `gdn_step`): one pass over the recurrent state.
 
 The single-position recurrence of a mamba layer (models/mamba2.py step),
 float32 throughout:
@@ -43,8 +44,38 @@ computed from that copy, and the new tile goes back to where it came from:
     broadcast it was 1.79 (the XLU, not the DMA, set the pace), at 16
     heads a step 1.90 (1,024 grid steps a layer).
 
-`head_tile` is the one shape gate: None where the kernel has no geometry
-(the caller keeps the jnp recurrence there and says so).
+`gdn_step` is the same pass for the gated delta rule of a Gated DeltaNet
+layer (models/gdn.py recurrence), whose state is a [Dk, Dv] MATRIX a value
+head (qwen3-next: 128 slots x 32 heads x 128 x 128, 268 MB a layer, which
+XLA's two fusions a layer cross three times):
+
+    r_k = S^T k,  r_q = S^T q          both read-outs of the OLD state
+    d   = beta * (v - a * r_k)
+    o   = a * r_q + (k . q) * d
+    S   = a * S + k (outer) d
+
+  - It shares the stack's addressing (`over_stack`: the whole [L, B, H, Dk,
+    Dv] stack aliased through the call, the layer a scalar-prefetch
+    argument, grid (B, H / head_tile)) and `head_tile`'s byte budget: 32
+    heads x 64 KB, a whole slot a grid step at qwen3-next's shape.
+  - Here the update DEPENDS on a reduction over the whole tile (`d` needs
+    `r_k`): a head's 16 vregs are reduced first and updated second, from
+    the same VMEM copy. Dv lies on lanes, so v, d, o are lane rows and the
+    reductions over Dk are sublane-direction adds on the VPU; k and q are
+    COLUMNS, [B, H / tile, Dk, tile] with a head a lane (two lane broadcasts
+    a vreg of state, and no lane reduction); a, beta and k . q are scalars
+    from SMEM. Every slice is static: the heads of a tile are unrolled.
+  - What it costs (tools/ssm_step_ab.py --kind gdn on a v5e, ms a layer of
+    268 MB; PERF.md, PR 45): XLA's form 1.233, a bare copy through the same
+    pipeline 0.841 (638 GB/s), the kernel 0.884 at 16 or 32 heads a step
+    (608 GB/s, 5% over the copy) and 0.911 at 8. The columns loaded once a
+    grid step, or a key head's two broadcasts shared by the two value heads
+    it serves (half the lane broadcasts), read the same within the noise of
+    two calls (0.871 / 0.868 beside 0.862–0.873): the XLU does not set the
+    pace here.
+
+`head_tile` is the one shape gate: None where a kernel has no geometry (the
+caller keeps the jnp recurrence there and says so: `step_form`).
 """
 
 from __future__ import annotations
@@ -60,7 +91,8 @@ LANES = 128
 SUBLANES = 8
 GROUP = 16             # heads unrolled between two lane rotations
 TILE_BYTES = 2**21     # of state a grid step moves each way
-NAME = "ssm_step"      # the op's name in a device trace
+NAME = "ssm_step"      # the ops' names in a device trace
+GDN_NAME = "gdn_step"
 
 
 def head_tile(n_heads: int, d_head: int, d_state: int, itemsize: int = 4,
@@ -74,6 +106,19 @@ def head_tile(n_heads: int, d_head: int, d_state: int, itemsize: int = 4,
     most = max(1, TILE_BYTES // (d_head * d_state * itemsize))
     return next(t for t in range(min(n_heads, most), 0, -1)
                 if n_heads % t == 0)
+
+
+def step_form(n_heads: int, d_head: int, d_state: int, itemsize: int, *,
+              interpret: bool, otherwise: str) -> dict:
+    """What a recurrent kind's `step_form` reports and its `step_at` routes
+    by: "pallas" with the `head_tile` ("pallas-interpret": the same kernel
+    on the CPU backend), or `otherwise` — the kind's name for its jnp
+    recurrence — where the kernel has no geometry for the state."""
+    tile = head_tile(n_heads, d_head, d_state, itemsize, interpret=interpret)
+    if tile is None:
+        return {"form": otherwise}
+    return {"form": "pallas-interpret" if interpret else "pallas",
+            "head_tile": tile}
 
 
 def _kernel(layer_ref, b_ref, c_ref, dx_ref, skip_ref, a_ref, s_ref,
@@ -109,6 +154,53 @@ def _kernel(layer_ref, b_ref, c_ref, dx_ref, skip_ref, a_ref, s_ref,
     y_ref[0] = jnp.where(mine, sc + skip_ref[0], y_ref[0])
 
 
+def _tile(stack, tile, interpret: bool, what: str) -> int:
+    """The head tile a call takes: the caller's, or `head_tile`'s."""
+    H, P, N = stack.shape[2:]
+    if tile is None:
+        tile = head_tile(H, P, N, stack.dtype.itemsize, interpret=interpret)
+    if tile is None or H % tile:
+        raise ValueError(f"no {what} geometry for a state of {H} heads "
+                         f"of {P} x {N} (tile {tile})")
+    return tile
+
+
+def address(layer) -> jnp.ndarray:
+    """The layer as the scalar-prefetch operand."""
+    return jnp.reshape(layer, (1,)).astype(jnp.int32)
+
+
+def over_stack(kernel, name: str, stack, layer, tile: int, in_specs,
+               operands, out_spec, out_shape, *, interpret: bool):
+    """`kernel` over layer `layer` of the WHOLE stack [L, B, H, P, N], a
+    [tile, P, N] block of one slot a grid step, aliased input to output:
+    the stack is the last operand and the last result, the layer (`address`
+    of it) the one scalar-prefetch argument of every index map. Returns
+    (the kernel's other result, the stack)."""
+    B, H, P, N = stack.shape[1:]
+    state = pl.BlockSpec((1, 1, tile, P, N),
+                         lambda i, t, lay: (lay[0], i, t, 0, 0))
+    block = 2 * tile * P * N * 4    # a tile in and a tile out, as float32
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # layer
+            grid=(B, H // tile),
+            in_specs=[*in_specs, state],
+            out_specs=[out_spec, state],
+        ),
+        out_shape=[out_shape, jax.ShapeDtypeStruct(stack.shape, stack.dtype)],
+        # the stack, counted with `layer`
+        input_output_aliases={len(in_specs) + 1: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            # double buffers of the tile each way, and room for the rest
+            vmem_limit_bytes=max(32 * 2**20, 3 * block)),
+        name=name,
+        interpret=interpret,
+    )(layer, *operands, stack)
+
+
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def ssm_step(
     ssm: jnp.ndarray,       # [L, B, H, P, N] the FULL stack
@@ -124,11 +216,7 @@ def ssm_step(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (y [B, H, P] f32, the stack with layer `layer` stepped)."""
     L, B, H, P, N = ssm.shape
-    if tile is None:
-        tile = head_tile(H, P, N, ssm.dtype.itemsize, interpret=interpret)
-    if tile is None or H % tile:
-        raise ValueError(f"no ssm-step geometry for a state of {H} heads "
-                         f"of {P} x {N} (tile {tile})")
+    tile = _tile(ssm, tile, interpret, "ssm-step")
     group = next(g for g in range(min(GROUP, tile), 0, -1) if tile % g == 0)
     lanes = -(-H // LANES) * LANES
 
@@ -141,27 +229,68 @@ def ssm_step(
     col = pl.BlockSpec((1, P, lanes), lambda i, t, lay: (i, 0, 0))
     decay = pl.BlockSpec((1, 1, H), lambda i, t, lay: (i, 0, 0),
                          memory_space=pltpu.SMEM)
-    state = pl.BlockSpec((1, 1, tile, P, N),
-                         lambda i, t, lay: (lay[0], i, t, 0, 0))
-    block = 2 * tile * P * N * 4    # a tile in and a tile out, as float32
-    y, ssm = pl.pallas_call(
-        functools.partial(_kernel, group=group),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,  # layer
-            grid=(B, H // tile),
-            in_specs=[row, row, col, col, decay, state],
-            out_specs=[col, state],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((B, P, lanes), jnp.float32),
-                   jax.ShapeDtypeStruct(ssm.shape, ssm.dtype)],
-        input_output_aliases={6: 1},    # the stack, counted with `layer`
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            # double buffers of the tile each way, and room for the rest
-            vmem_limit_bytes=max(32 * 2**20, 3 * block)),
-        name=NAME,
-        interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-      b[:, None].astype(jnp.float32), c[:, None].astype(jnp.float32),
-      columns(dx), columns(skip), a[:, None].astype(jnp.float32), ssm)
+    y, ssm = over_stack(
+        functools.partial(_kernel, group=group), NAME, ssm, address(layer),
+        tile, [row, row, col, col, decay],
+        (b[:, None].astype(jnp.float32), c[:, None].astype(jnp.float32),
+         columns(dx), columns(skip), a[:, None].astype(jnp.float32)),
+        col, jax.ShapeDtypeStruct((B, P, lanes), jnp.float32),
+        interpret=interpret)
     return jnp.swapaxes(y[:, :, :H], 1, 2), ssm
+
+
+def _gdn_kernel(layer_ref, k_ref, q_ref, v_ref, w_ref, s_ref,
+                o_ref, s_out_ref):
+    del layer_ref                                   # addressing only
+    tile = s_ref.shape[2]
+    first = pl.program_id(1) * tile                 # this step's first head
+    for j in range(tile):
+        a, beta, kq = (w_ref[0, n, first + j] for n in range(3))
+        s = s_ref[0, 0, j].astype(jnp.float32)                  # [Dk, Dv]
+        k = k_ref[0, 0, :, j:j + 1]                             # [Dk, 1]
+        # both read-outs of the OLD state, from the one copy of the tile
+        read_k = jnp.sum(s * k, axis=0, keepdims=True)          # [1, Dv]
+        read_q = jnp.sum(s * q_ref[0, 0, :, j:j + 1], axis=0, keepdims=True)
+        d = beta * (v_ref[0, 0, j:j + 1, :] - a * read_k)
+        o_ref[0, 0, j:j + 1, :] = a * read_q + kq * d
+        s_out_ref[0, 0, j] = (a * s + k * d).astype(s_out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def gdn_step(
+    state: jnp.ndarray,     # [L, B, H, Dk, Dv] the FULL stack
+    layer: jnp.ndarray,     # scalar int32: which layer's state steps
+    a: jnp.ndarray,         # [B, H] f32 decay of this position
+    beta: jnp.ndarray,      # [B, H] f32 write strength
+    q: jnp.ndarray,         # [B, H, Dk] f32
+    k: jnp.ndarray,         # [B, H, Dk] f32
+    v: jnp.ndarray,         # [B, H, Dv] f32
+    *,
+    tile: int | None = None,    # heads a grid step (None: `head_tile`)
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One position of the gated delta rule (models/gdn.py recurrence):
+    returns (o [B, H, Dv] f32, the stack with layer `layer` stepped)."""
+    L, B, H, Dk, Dv = state.shape
+    tile = _tile(state, tile, interpret, "gdn-step")
+    tiles = H // tile
+
+    def columns(x):     # [B, H, Dk] -> [B, tiles, Dk, tile]: a head a lane
+        return jnp.swapaxes(
+            x.astype(jnp.float32).reshape(B, tiles, tile, Dk), 2, 3)
+
+    # what is one number a head: the decay, the write strength, k . q
+    w = jnp.stack([a, beta, jnp.sum(k * q, axis=-1)],
+                  axis=1).astype(jnp.float32)                   # [B, 3, H]
+    col = pl.BlockSpec((1, 1, Dk, tile), lambda i, t, lay: (i, t, 0, 0))
+    row = pl.BlockSpec((1, 1, tile, Dv), lambda i, t, lay: (i, t, 0, 0))
+    scalars = pl.BlockSpec((1, 3, H), lambda i, t, lay: (i, 0, 0),
+                           memory_space=pltpu.SMEM)
+    o, state = over_stack(
+        _gdn_kernel, GDN_NAME, state, address(layer), tile,
+        [col, col, row, scalars],
+        (columns(k), columns(q),
+         v.astype(jnp.float32).reshape(B, tiles, tile, Dv), w),
+        row, jax.ShapeDtypeStruct((B, tiles, tile, Dv), jnp.float32),
+        interpret=interpret)
+    return o.reshape(B, H, Dv), state

@@ -245,14 +245,17 @@ def test_gdn_decode_step_updates_the_matrix_state_in_place(
     """qwen3-next-80b-a3b's decode trunk at its cell (128 slots x 640): the
     0.81 GB matrix state is donated in, aliased out and updated where it
     lies — no copy or relayout of the whole stack, temporaries under one
-    layer's state — by the jnp step's two fusions a layer (one pass that
-    reads the state for both read-outs, one in-place update), and the one
-    gated-attention layer (2 KV heads of 256) takes the decode kernel. At
-    512 experts top 10 the step's experts are the routed form (PR 36):
-    three `moe_gmm` calls in the body of each of the two runs' scans."""
-    from symmetry_tpu.models import hybrid, llama, mamba2, moe
+    layer's state — by ONE kernel call for the run of Gated DeltaNet layers
+    (ops/ssm_step.py gdn_step, the stack its operand) and no XLA fusion: the
+    two fusions a layer XLA made of the jnp step (a reduction that read the
+    state, then an update that read it again and wrote it: PR 35 – PR 44)
+    cannot come back unseen. The one gated-attention layer (2 KV heads of
+    256) takes the decode kernel. At 512 experts top 10 the step's experts
+    are the routed form (PR 36): three `moe_gmm` calls in the body of each
+    of the two runs' scans."""
+    from symmetry_tpu.models import gdn, hybrid, llama, moe
 
-    for module in (llama, mamba2, moe):
+    for module in (llama, gdn, moe):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
     cfg = llama.preset("qwen3-next-80b-a3b")
     B, T = 128, 640
@@ -282,16 +285,57 @@ def test_gdn_decode_step_updates_the_matrix_state_in_place(
     text = compiled.as_text()
     gmm_calls = len(re.findall(r"%moe_gmm[.\d]* = ", text))
     assert gmm_calls == 3 * len(hybrid.runs(cfg))
-    assert text.count("tpu_custom_call") == 1 + gmm_calls  # + attention
+    gdn_runs = sum(kind == "linear_attention"
+                   for kind, _, _ in hybrid.runs(cfg))
+    assert gdn_runs == 1
+    assert len(re.findall(r"%gdn_step[.\d]* = ", text)) == gdn_runs
+    # + the attention layer's decode kernel
+    assert text.count("tpu_custom_call") == 1 + gmm_calls + gdn_runs
+    shape = r"f32\[3,128,32,128,128\]"
     whole = [line.strip()[:160] for line in text.splitlines()
-             if re.search(r"= f32\[3,128,32,128,128\]\S* (copy|transpose)\(",
-                          line)]
+             if re.search(rf"= {shape}\S* (copy|transpose)\(", line)]
     assert not whole, whole[0]
-    # the stack's only writer is an in-place dynamic-update-slice
-    writers = [line for line in text.splitlines()
-               if re.search(r"= f32\[3,128,32,128,128\]\S* "
-                            r"dynamic-update-slice\(", line)]
-    assert writers
+    # the stack is an operand of the kernel call (and of the plumbing that
+    # carries it: tuples, loops, bitcasts) and of no fusion, in or out
+    stack = set(re.findall(rf"(%[\w.\-]+)(?: =|:) {shape}", text))
+    fused = [line.strip()[:160] for line in text.splitlines()
+             if " fusion(" in line and (
+                 re.search(shape, line.split(" fusion(")[0])
+                 or stack & set(re.findall(r"%[\w.\-]+",
+                                           line.split(" fusion(")[1])))]
+    assert stack and not fused, fused[:1]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gdn_step_compiles_at_the_cells_state(one_chip, no_cache, dtype):
+    """The one-pass delta-rule step alone at qwen3-next-80b-a3b's cell —
+    `[3, 128, 32, 128, 128]`, 128 slots, the gate's head tile (a whole slot,
+    2 MB of float32, a grid step: Mosaic refuses here what overruns VMEM) —
+    with the layer traced: the donated stack is aliased through the call,
+    and the program holds no copy of it and no temporary of a layer's
+    size."""
+    from symmetry_tpu.ops import ssm_step
+
+    L, B, H, Dk, Dv = 3, 128, 32, 128, 128
+    assert ssm_step.head_tile(H, Dk, Dv, jnp.dtype(dtype).itemsize) == 32
+
+    def arg(shape, of=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, of, sharding=one_chip)
+
+    compiled = jax.jit(ssm_step.gdn_step, donate_argnums=(0,)).lower(
+        arg((L, B, H, Dk, Dv), dtype), arg((), jnp.int32), arg((B, H)),
+        arg((B, H)), arg((B, H, Dk)), arg((B, H, Dk)), arg((B, H, Dv))
+    ).compile()
+    memory = compiled.memory_analysis()
+    state_bytes = L * B * H * Dk * Dv * jnp.dtype(dtype).itemsize
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // (8 * L)
+    text = compiled.as_text()
+    assert len(re.findall(r"%gdn_step[.\d]* = ", text)) == 1
+    whole = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= (f32|bf16)\[3,128,32,128,128\]\S* "
+                          r"(copy|transpose|fusion)\(", line)]
+    assert not whole, whole[0]
 
 
 @pytest.mark.parametrize("preset,rows,bucket,stack", [

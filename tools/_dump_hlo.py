@@ -18,7 +18,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
-from symmetry_tpu.models import llama, mamba2
+from symmetry_tpu.models import gdn, llama, mamba2
 from symmetry_tpu.ops import decode_attention as da
 
 args = [a for a in sys.argv[1:] if not a.startswith("--")]
@@ -34,6 +34,7 @@ else:
     # the layers ask the default backend whether kernels are interpreted;
     # this compile is for the chip
     llama.interpret_mode = mamba2.interpret_mode = lambda: False
+    gdn.interpret_mode = lambda: False
 if xla:
     da.geometry = lambda *a, **k: None
 one = SingleDeviceSharding(device)
