@@ -2159,8 +2159,8 @@ class InferenceEngine:
     def weight_stream_bytes(self) -> int:
         """Bytes of parameter data one decode step must stream from HBM:
         every matmul weight (int8 payload + f32 scales, or dense) is read
-        in full each step — the decode-floor denominator (BASELINE.md
-        convert-wall study). The input embedding is excluded unless tied:
+        in full each step — the stats reply's `weight_bytes_per_step`,
+        beside `decode_step_ms`. The input embedding is excluded unless tied:
         it is gathered (B rows), not contracted; tied models re-read it
         as the LM head. Metadata-only (nbytes), safe from any thread."""
         total = sum(leaf.nbytes for leaf in jax.tree.leaves(self.params))
@@ -2173,8 +2173,8 @@ class InferenceEngine:
         LOCAL shard size (sharding.shard_shape), so TP sharded leaves
         divide by the axis size while replicated leaves count in full on
         every device — the actual per-chip HBM stream one decode step
-        costs, and the denominator bench.py's per-device
-        weight_stream_gbs reports. Metadata-only, safe from any thread;
+        costs (tests/test_qmm_mesh.py holds it under the aggregate).
+        Metadata-only, safe from any thread;
         on a single device this equals weight_stream_bytes."""
 
         def local_nbytes(leaf) -> int:

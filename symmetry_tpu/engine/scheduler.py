@@ -4,8 +4,8 @@ The reference's hot loop pumped one HTTP response per peer with backpressure
 (reference: src/provider.ts:240-258). Here the equivalent loop is the decode
 step over a slot batch: requests are inserted the moment a slot frees
 (insert-on-arrival), every step advances all active slots one token, and
-slots are evicted on EOS / token budget / client cancellation — BASELINE
-config 3 (16 concurrent clients, continuous batching).
+slots are evicted on EOS / token budget / client cancellation: continuous
+batching, as every closed benchmark cell drives it (128 clients).
 
 Threading model: one dedicated engine thread owns all JAX calls (the engine
 is single-threaded by contract); asyncio callers talk to it through
@@ -688,8 +688,8 @@ class Scheduler:
         if self._dispatch_thread_hist.count:
             out["dispatch_thread_block_s"] = (
                 self._dispatch_thread_hist.to_dict())
-        # Decode-floor metrics (the convert-wall number, in EVERY driver
-        # bench capture instead of only the engine-only bench): per-step
+        # Decode-floor metrics (what the benchmark's `decode_step_ms.*`
+        # read from the stats reply): per-step
         # decode wall from the block-interval p50 (intervals spanning
         # admissions land in the upper percentiles, so p50 is the
         # steady-state estimate), and the weight bytes that step must
@@ -2307,7 +2307,7 @@ class Scheduler:
             # First event of the request: attach the per-stage admission
             # stamps (host recv → placement pick → first token). The host
             # adds its pipe-out stamp, the provider the relay stamp — the
-            # full TTFT chain then reads out per stage in bench.py.
+            # full TTFT chain then reads out per stage (stage_*_mean_s).
             # stages_sent is owned by whichever side runs the jobs
             # (exactly one; see _run_job).
             active.stages_sent = True

@@ -15,7 +15,7 @@ Design (TPU-first, not a port — the reference has no model code to port):
     (ops/attention.py), cache writes are scatters at per-sample positions,
     so a continuous batch of ragged requests runs at static shape.
 
-HF weight compatibility (BASELINE.json north star loads HF safetensors):
+HF weight compatibility (the engine loads HF safetensors directly):
 tensor layout/naming map in `HF_LAYER_MAP` + `convert_hf_params`
 (engine/weights.py does the streaming file IO).
 """
@@ -225,7 +225,7 @@ PRESETS: dict[str, ModelConfig] = {
         num_kv_heads=4, intermediate_size=128, rope_theta=10000.0,
         max_position=512,
     ),
-    # production targets (BASELINE.json configs 2-5)
+    # the first rounds' production targets (no benchmark cell serves them)
     "llama3-8b": ModelConfig(
         vocab_size=128256, hidden_size=4096, num_layers=32, num_heads=32,
         num_kv_heads=8, intermediate_size=14336, rope_theta=500000.0,

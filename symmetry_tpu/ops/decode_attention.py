@@ -131,7 +131,7 @@ def geometry(batch: int, capacity: int, n_kv: int, head_dim: int = LANES,
     None where it has none: the one gate — the caller keeps the XLA path
     there and says so. By shape alone, at every capacity and on either
     backend (the CPU interprets it); the >= 4,096 floor this replaced
-    priced the old one-slot-one-block grid (BASELINE.md rounds 3-4), not
+    priced the old one-slot-one-block grid (PERF.md §6, PR 29), not
     the idea.
 
     block_t: the positions that make BLOCK_ROWS rows of a lane — 128 at

@@ -1,7 +1,7 @@
 """W8A16 fused-dequant Pallas matmul (`tpu.fused_dequant`, off by default).
 
-The regime (measured on this v5e before the benchmark existed; BASELINE.md
-rounds 3-4): DECODE (M ≈ slot count, ~128 rows) is bandwidth-bound, and
+The regime (measured on a shared v5e before the benchmark existed, so no
+rate of record): DECODE (M ≈ slot count, ~128 rows) is bandwidth-bound, and
 the floor is the int8→bf16 CONVERT, not HBM — XLA's mixed dot
 materializes a full bf16 copy of every int8 weight before each dot
 (~480 GB/s effective vs the 740-860 a pure bf16 matmul streams).
@@ -20,7 +20,7 @@ in the epilogue — `(x @ q_bf16) * scale`, cast to the activation dtype.
 A W8A8 form (activations quantized per row, s8×s8 → s32 MXU tiles) was
 measured and never routed: ~50% slower than the mixed dot in the decode
 trunk (48.5 vs 32.1 ms) and no gain at prefill (165.3 vs 167.6 ms a
-group). The record is BASELINE.md rounds 3-4; the kernel left in PR 30.
+group), on that same shared chip; the kernel left in PR 30.
 """
 
 from __future__ import annotations
@@ -38,7 +38,7 @@ from symmetry_tpu.ops.interpret import interpret_mode
 # buffer depth lever (the pallas grid pipeline keeps the next (bk, bn)
 # tile's DMA in flight behind the current tile's MXU work). 512×512 int8
 # = 256 KiB per tile, two in flight, well inside VMEM next to the
-# activation block and f32 accumulator. tools/probe_w8a16.py sweeps this.
+# activation block and f32 accumulator. tools/chip_kernels.py: the shapes.
 W8A16_BLOCK_K = 512
 W8A16_BLOCK_N = 512
 # Row-block cap: x [bm, bk] + acc [bm, bn] f32 + out [bm, bn] must fit

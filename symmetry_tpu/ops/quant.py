@@ -1,4 +1,4 @@
-"""Int8 weight quantization (BASELINE config 5: llama3-70b int8 TP).
+"""Int8 weight quantization (every benchmark cell serves int8 weights).
 
 Symmetric per-output-channel int8: for w [.., in, out], each output column
 gets scale = max|column| / 127, q = round(w / scale). The matmul computes

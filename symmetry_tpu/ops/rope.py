@@ -2,7 +2,7 @@
 
 Uses the rotate-half layout (first half / second half pairing) so weights
 loaded from HF llama/mistral checkpoints produce identical activations —
-required because the north star loads HF safetensors directly (BASELINE.json).
+required because the engine loads HF safetensors directly (engine/weights.py).
 Cos/sin are computed in float32 regardless of activation dtype; bf16 RoPE
 phases drift noticeably past ~2k positions.
 """

@@ -1,6 +1,6 @@
 """tpu_native backend: the in-process JAX engine as an apiProvider.
 
-The flagship of the rebuild (BASELINE.json north star): where the reference
+The flagship of the rebuild (ROADMAP.md north star): where the reference
 could only proxy to an external GPU server (reference: src/provider.ts:
 210-214), this backend hosts the model itself — HF weights pjit-sharded over
 the provider's TPU slice, continuous batching across peers, tokens streamed

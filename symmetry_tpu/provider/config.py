@@ -5,7 +5,7 @@ Same `provider.yaml` surface as the reference's ConfigManager
 `apiHostname/apiPath/apiPort/apiProtocol/apiProvider/modelName/name/path/
 public/serverKey/dataCollectionEnabled/maxConnections/apiKey` and `-c` CLI
 override — extended with a `tpu` section for the native engine (mesh shape,
-dtype, KV budget, checkpoint path) per the BASELINE.json north star.
+dtype, KV budget, checkpoint path).
 
 Differences from the reference, on purpose:
   - `api*` fields are required only for HTTP-proxy backends; the flagship
@@ -53,8 +53,8 @@ class TpuConfig:
     # sharding decision, column-/row-parallel leaves run a shard_map'd
     # per-shard kernel, and a leaf whose shard loses tileability keeps
     # the mixed dot (counted in sym_qmm_fallback_total, never silent).
-    # Off by default pending the on-chip A/B (BASELINE.md decode-floor
-    # section; bench.py --fused-dequant / tools/probe_w8a16.py).
+    # Off by default: no chip verdict yet (ROADMAP Speed 3: a cell pair
+    # decides it; tools/chip_kernels.py holds the ten W8A16 shapes).
     fused_dequant: bool = False
     max_batch_size: int = 8            # decode slots (continuous batching)
     max_seq_len: int = 2048            # KV capacity per slot
@@ -143,10 +143,10 @@ class TpuConfig:
     max_queue: int | None = None
     # Request-scoped tracing (utils/trace.py): bounded span/counter rings
     # in the scheduler and host, read through the host-pipe `trace` op and
-    # exported as a Perfetto timeline (provider `trace` op, bench.py
-    # --trace-out). Cheap enough to leave on (a few ring appends per
-    # decode block); False empties the rings entirely — the bench A/B
-    # knob for proving the overhead stays under 1%.
+    # exported as a Perfetto timeline (provider `trace` op,
+    # tools/trace_smoke.py). Cheap enough to leave on (a few ring appends
+    # per decode block); False empties the rings entirely (every
+    # benchmark cell runs with it on).
     tracing: bool = True
     # symledger per-request cost attribution (engine/ledger.py): the
     # scheduler apportions every dispatch's measured wall to the
@@ -158,7 +158,7 @@ class TpuConfig:
     # (sym_goodput_tokens_per_device_second) and feeds the autoscaler's
     # SLO-attaining numerator. False disables: one guarded branch per
     # dispatch (same overhead contract as metrics.enabled and
-    # tpu.faults; BASELINE.md Round 20 pre-registers the ≤1% A/B).
+    # tpu.faults; every benchmark cell runs with it on).
     ledger: bool = True
     # TTFT-bounded admission: shed a new request when the provider's
     # ESTIMATED first-token wait (requests awaiting their first token ÷
@@ -203,7 +203,7 @@ class TpuConfig:
     #   inline: bool = false      backend self-hosts the PrefillNode
     #                             in-process and dials it at `peer` —
     #                             the full wire path in one provider
-    #                             (bench --disagg-transport, CI smoke)
+    #                             (tools/disagg_smoke.py, the CI smoke)
     #   chunk_kb: int = 1024      handoff chunk size on the link
     #   credit_mb: float = 64     receiver credit window (bounds
     #                             in-flight bytes; exhaustion throttles

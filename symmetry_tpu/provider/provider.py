@@ -134,8 +134,8 @@ class SymmetryProvider:
         # Metrics (SURVEY §5.5: tok/s, queue depth first-class). Latency
         # distributions live in this provider's Tracer (utils/trace.py):
         # spans feed the same log-bucketed histograms stats() reads, so
-        # there is exactly one aggregation path — p50/p99 TTFT is the
-        # BASELINE.json headline metric.
+        # there is exactly one aggregation path (the benchmark reads TTFT
+        # from it: provider_hop_mean_s).
         self.tracer = Tracer()
         self.metrics: dict[str, Any] = {
             "requests": 0, "tokens_out": 0, "errors": 0, "shed": 0,

@@ -22,8 +22,8 @@ class NoChipError(RuntimeError):
 def require_chip() -> None:
     """Refuse any platform but `tpu` — unless JAX was pinned to the CPU
     BY NAME (`jax_platforms == "cpu"`, which JAX fills from
-    `JAX_PLATFORMS`). That is what tests/conftest.py, the tools/ smokes
-    and `bench.py --smoke` do, so they keep running; a CPU that JAX fell
+    `JAX_PLATFORMS`). That is what tests/conftest.py and the tools/
+    smokes do (and a CPU rehearsal of chip_smoke.py); a CPU that JAX fell
     back to on its own never serves. Where JAX is pinned to the TPU and
     cannot get it, backend initialisation itself fails; that is the same
     refusal."""

@@ -9,8 +9,8 @@ byte counts on the peer object. Here the equivalents are first-class:
     one Tracer instance (provider/provider.py) whose histograms back its
     stats() snapshot.
   - Histogram: log-bucketed latency/throughput distributions with
-    percentile estimates — p50/p99 TTFT is the BASELINE.json north-star
-    metric, so it must be computable from a running provider, not from
+    percentile estimates — p50/p99 TTFT is what a provider's user feels
+    first, so it must be computable from a running provider, not from
     offline logs.
   - Tracer.phase: one timed section that is a ring record, a cumulative
     seconds-per-label counter and — only while a jax.profiler capture is

@@ -8,6 +8,9 @@ and it OVERRIDES the outer one: a builder's shell may point JAX at a chip.
 """
 
 import os
+import time
+
+import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
@@ -35,3 +38,23 @@ enable_compile_cache()
 # matmul precision truncates f32 operands to bf16 passes, which swamps the
 # tolerances. Production serving uses bf16 params, where this is a no-op.
 jax.config.update("jax_default_matmul_precision", "highest")
+
+
+@pytest.fixture
+def loop_ratio():
+    """`loop_ratio(site, n, bare=<an empty call>)`: wall time of `n` calls
+    of `site` over that of `n` calls of `bare`, each the best of five loops
+    run turn about in this process. The disabled-mode guards bound THIS,
+    never a number of seconds: a loaded machine slows both loops, the code
+    under test only the first."""
+    def loop(fn, n):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            fn()
+        return time.perf_counter() - t0
+
+    def ratio(site, n, bare=lambda: None):
+        runs = [(loop(site, n), loop(bare, n)) for _ in range(5)]
+        return min(s for s, _ in runs) / min(b for _, b in runs)
+
+    return ratio

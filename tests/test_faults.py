@@ -160,23 +160,25 @@ class TestActions:
 
 
 class TestNoopOverhead:
-    def test_unconfigured_injector_is_a_noop(self):
+    def test_unconfigured_injector_is_a_noop(self, monkeypatch, loop_ratio):
         """The contract instrumented hot paths rely on: with nothing
-        armed, a seam costs one attribute read + one early return. 200k
-        calls in well under half a second leaves an order of magnitude
-        of CI-machine headroom."""
+        armed, a seam costs one attribute read + one early return. It
+        never reaches `fire` (no lock, no hit recorded), and it costs a
+        small multiple of an empty call (1.2x guarded, 2x unguarded here),
+        whatever the machine's load."""
         inj = FaultInjector()
         assert inj.enabled is False
-        t0 = time.perf_counter()
-        for _ in range(200_000):
+        fired = []
+        monkeypatch.setattr(inj, "fire", fired.append)
+
+        def guarded():
             if inj.enabled and inj.point("host.pipe_write"):
                 pass
-        assert time.perf_counter() - t0 < 0.5
+
+        assert loop_ratio(guarded, 50_000) < 25
         # and point() itself stays cheap when called without the guard
-        t0 = time.perf_counter()
-        for _ in range(200_000):
-            inj.point("host.pipe_write")
-        assert time.perf_counter() - t0 < 0.5
+        assert loop_ratio(lambda: inj.point("host.pipe_write"), 50_000) < 25
+        assert fired == [] and inj.counters() == {}
 
     def test_global_injector_starts_disabled_without_env(self):
         # The autouse fixture cleared it; this is the state every

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import json
 import threading
-import time
 
 import pytest
 
@@ -115,25 +114,32 @@ class TestRegistry:
         assert s["count"] == n * threads
         assert s["buckets"][-1][1] == n * threads
 
-    def test_disabled_mode_is_inert_and_cheap(self):
+    def test_disabled_mode_is_inert_and_cheap(self, loop_ratio):
+        """The one-branch contract: a disabled registry's handles count
+        nothing, create no series and take no lock, and a site costs a
+        small multiple of an empty call (2-3x here), whatever the
+        machine's load."""
         r = MetricsRegistry(enabled=False)
         c = r.counter("t_off_total", "off")
         h = r.histogram("t_off_seconds", "off")
-        t0 = time.perf_counter()
-        for _ in range(200_000):
+
+        class Untouched:
+            def __enter__(self):
+                raise AssertionError("a disabled site took the lock")
+
+            def __exit__(self, *exc):
+                return None
+
+        lock, r._lock = r._lock, Untouched()
+
+        def site():
             c.inc()
             h.observe(0.1)
-        dt = time.perf_counter() - t0
-        assert c.value() == 0
-        (s,) = r.snapshot()["families"]["t_off_seconds"]["series"] \
-            if r.snapshot()["families"]["t_off_seconds"]["series"] else [None]
-        assert s is None or s["count"] == 0
-        # 400k guarded ops; the bound is generous (CI shares cores) but
-        # still pins the one-branch contract: ~100 ns/op measured, so a
-        # chunk's ~5 sites stay far under 1% of a 1 ms chunk budget.
-        assert dt < 1.0, f"disabled-mode: {dt:.3f}s for 400k guarded ops"
-        per_op = dt / 400_000
-        assert per_op * 5 < 0.01 * 1e-3
+
+        ratio = loop_ratio(site, 50_000)
+        r._lock = lock
+        assert c.value() == 0 and h._fam.series == {}  # no record allocated
+        assert ratio < 50, f"two disabled sites cost {ratio:.1f}x an empty call"
 
     def test_histogram_ring_is_bounded_time_series(self):
         r = MetricsRegistry()

@@ -211,6 +211,11 @@ class TestEngineHitPaths:
         assert engine.prefix_index.covers(prompt[:24])
         st = engine.prefix_index.stats()
         assert st["blocks_in_use"] == 4  # 2 (BASE) + 2 (new tail)
+        # ... so the NEXT turn of the session (this whole history plus a
+        # new message) hits at all of it, not at the first turn's blocks
+        turn3 = engine.prefix_lookup(prompt + list(b" and a reply"))
+        assert turn3.length == 24
+        turn3.release()
 
     def test_coalesced_hit_group_with_pad_rows(self, setup):
         """Several requests sharing one (node, matched_len) admit as ONE

@@ -6,8 +6,7 @@ naming error that cancels on the round-trip would pass. Here the
 checkpoint is authored by `transformers.LlamaForCausalLM.save_pretrained`
 and the logits are compared against transformers' own forward — the
 formats and semantics are pinned by an independent implementation
-(reference capability: the north star serves HF weights directly,
-BASELINE.json; loader: engine/weights.py).
+(the provider serves HF weights directly; loader: engine/weights.py).
 
 Everything runs on CPU with a tiny model; transformers is baked into the
 image and never touches the network.

@@ -1,6 +1,6 @@
 """70B int8 fit plan: per-device byte table + reduced-geometry dryrun.
 
-Two halves, matching the round-19 acceptance row (BASELINE.md):
+Two halves (PR 19's acceptance row):
 
 1. `--table`: jax.eval_shape the llama3-70b int8 param tree and the KV
    cache under a (dcn_data x ici_model) mesh and the default megatron
@@ -22,7 +22,8 @@ Two halves, matching the round-19 acceptance row (BASELINE.md):
    identical). Greedy decode must produce tokens and the packed-leaf
    count must be positive.
 
-Default (no flags) runs both and writes MULTICHIP_r06.json.
+Default (no flags) runs both and writes MULTICHIP_r06.json (git-ignored:
+an output, which nothing reads).
 """
 
 from __future__ import annotations

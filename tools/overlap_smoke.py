@@ -18,20 +18,12 @@ dispatch-thread vs offloaded wall split, the configured + live depth
 gauges, the emit-queue depth, and evidence the emit worker actually
 absorbed work (offloaded_s > 0, flushes > 0).
 
-Phase 3 — bench.py --pipeline-depth: the smoke-mode bench accepts the
-knob at depths 1 and 2 and stamps pipeline_depth +
-dispatch_thread_block_s into its capture; the two captures'
-config_fingerprints must DIFFER so benchdiff refuses a cross-depth diff
-unless --force'd (the deliberate A/B path).
-
 Run: python tools/overlap_smoke.py
 """
 
 from __future__ import annotations
 
-import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -209,40 +201,10 @@ def phase2_split(stats1, stats2) -> None:
         f"{stats2['offloaded_s']}s offloaded)")
 
 
-def phase3_bench_knob() -> None:
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    caps = {}
-    for depth in (1, 2):
-        out = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "--smoke",
-             "--pipeline-depth", str(depth)],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=900)
-        assert out.returncode == 0 and out.stdout.strip(), (
-            f"bench --smoke --pipeline-depth {depth} failed "
-            f"rc={out.returncode}:\n{out.stderr[-2000:]}")
-        cap = json.loads(out.stdout.strip().splitlines()[-1])
-        assert cap.get("pipeline_depth") == depth, cap.get("pipeline_depth")
-        dtb = cap.get("dispatch_thread_block_s") or {}
-        assert dtb.get("p50") is not None and dtb.get("p99") is not None, \
-            f"depth {depth}: capture has no dispatch_thread_block_s: {dtb}"
-        assert cap.get("config", {}).get("pipeline_depth") == depth
-        assert cap.get("config_fingerprint"), "capture is unstamped"
-        caps[depth] = cap
-    assert (caps[1]["config_fingerprint"]
-            != caps[2]["config_fingerprint"]), \
-        "depth 1 and 2 captures share a fingerprint — benchdiff would " \
-        "silently diff across the knob"
-    log(f"phase 3 OK: bench --pipeline-depth stamps depth + "
-        f"dispatch_thread_block_s (depth1 p50 "
-        f"{caps[1]['dispatch_thread_block_s']['p50']}s, depth2 p50 "
-        f"{caps[2]['dispatch_thread_block_s']['p50']}s)")
-
-
 def main() -> int:
     t0 = time.monotonic()
     stats1, stats2 = phase1_identity()
     phase2_split(stats1, stats2)
-    phase3_bench_knob()
     log(f"ALL PHASES OK in {time.monotonic() - t0:.1f}s")
     return 0
 

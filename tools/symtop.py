@@ -7,7 +7,7 @@ handoff-link health, and — on autoscaled pools — TARGET (the
 controller's desired M×N vs the live topology, from
 sym_autoscale_target_members) and SCALE (booked scaling decisions per
 minute) — the operator's answer to "is the fleet healthy RIGHT NOW",
-where bench.py answers "how fast was it over a run".
+where `benchmarks/run.py` answers "how fast was it over a run".
 
 Two poll paths, mixable in one invocation:
 
