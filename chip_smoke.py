@@ -63,6 +63,7 @@ contends for the chip its child needs.
         # lane tile, so decode attention is held to `xla` WITH its reason
         # and prefill to the flash kernel
     JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny
+    JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny-bd
         # CPU dry run of every phase; ends non-zero: "platform is cpu"
 """
 
@@ -89,6 +90,10 @@ DRAIN_TIMEOUT_S = 120.0
 # presets whose attention runs under a learned selection (by name: this
 # process may not import jax to ask)
 SPARSE_PRESETS = ("keye-vl-2.0-30b-a3b", "tiny-dsa")
+# presets that generate by diffusion over blocks: a decode forward carries a
+# block of queries a slot, which takes the XLA attention by design, and the
+# report carries `diffusion`
+DIFFUSION_PRESETS = ("sdar-30b-a3b-chat", "tiny-bd")
 
 
 class SmokeFailure(Exception):
@@ -320,7 +325,20 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
     # One chip or a mesh (8 x 4,096 is over the sharded trunk's floor):
     # both programs through compiled kernels (lfm2-8b-a1b's heads of 64
     # too: they lie in the cache in pairs, models/llama.py kv_row).
-    if (attention.get("prefill"), attention.get("decode")) != (
+    diffusion = cfg["tpu"]["model_preset"] in DIFFUSION_PRESETS
+    if diffusion:
+        bd = startup.get("diffusion") or {}
+        if (attention.get("prefill"), attention.get("decode")) != (
+                "pallas", "xla") or "decode_why" not in attention:
+            failures.append(f"a block-diffusion model's prefill did not run "
+                            f"the compiled flash kernel, or its decode "
+                            f"forwards did not say why they take the XLA "
+                            f"attention: {attention}")
+        if not bd.get("block") or bd.get("programs") != {
+                "prefill": "bd_prefill", "decode": "bd_decode_block"}:
+            failures.append(f"a block-diffusion model reported no "
+                            f"`diffusion` block: {bd}")
+    elif (attention.get("prefill"), attention.get("decode")) != (
             "pallas", "pallas"):
         failures.append(f"attention did not run compiled Pallas kernels "
                         f"in both programs: {attention}")
@@ -411,13 +429,15 @@ def main() -> int:
                          "logits against the float32 reference)")
     args = ap.parse_args()
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu" and args.preset != "tiny":
+    if os.environ.get("JAX_PLATFORMS") == "cpu" and args.preset not in (
+            "tiny", "tiny-bd"):
         # The engine host obeys a CPU pinned by name (utils/device.py), so
         # the verdict is known before anything starts — and a full-width
         # model is not built on a CPU to reach it.
         print(f"chip_smoke: FAIL: JAX_PLATFORMS=cpu pins the engine host "
               f"to the CPU: its platform is cpu, not tpu ({args.preset} is "
-              f"not built there; `--preset tiny` is the CPU dry run)",
+              f"not built there; `--preset tiny` and `--preset tiny-bd` are "
+              f"the CPU dry runs)",
               file=sys.stderr)
         return 1
 

@@ -45,7 +45,8 @@ from symmetry_tpu.models.llama import (
 
 
 from symmetry_tpu.ops.sampling import (
-    sample_tokens, top_k_route, verify_tokens)
+    RULE_DYNAMIC, RULE_STATIC, diffusion_candidates, diffusion_unmask,
+    sample_tokens, top_k_route, transfer_schedule, verify_tokens)
 from symmetry_tpu.parallel.mesh import MeshSpec, build_mesh
 from symmetry_tpu.parallel.sharding import shardings_for
 from symmetry_tpu.engine.prefix_cache import BlockPool, RadixHit, RadixIndex
@@ -70,6 +71,18 @@ def _stay_parked(before: jnp.ndarray, after: jnp.ndarray) -> jnp.ndarray:
     prompt) and stays at 0 — it decodes garbage like every idle lane, at
     one position, and never grows into context nobody reads."""
     return jnp.where(before == 0, 0, after)
+
+
+def _emptied(scratch: KVCache) -> KVCache:
+    """A reused prefill buffer under the empty-cache contract: lengths
+    position the writes and carry the previous use's values, and
+    insert_all adds a prefix's expert count to the decode state's, so a
+    reused buffer must not bring its last use's along."""
+    cache = scratch._replace(lengths=jnp.zeros_like(scratch.lengths))
+    if cache.expert_pairs is not None:
+        cache = cache._replace(
+            expert_pairs=jnp.zeros_like(cache.expert_pairs))
+    return cache
 
 
 class EngineError(RuntimeError):
@@ -186,6 +199,8 @@ class InferenceEngine:
         speculative: SpecConfig | None = None,
         fused_dequant: bool = False,
         role: str = "unified",
+        diffusion_steps: int | None = None,
+        diffusion_threshold: float | None = None,
     ) -> None:
         self.config = config
         self.params = params
@@ -247,6 +262,59 @@ class InferenceEngine:
                 prefill_chunk=prefill_chunk)
             if refused:
                 raise EngineError(refused[0])
+        # Generation by diffusion over blocks (models/llama.py
+        # BlockDiffusion): the same rule, and the two generation settings.
+        self._diffusion = getattr(config, "diffusion", None)
+        self.diffusion: dict | None = None
+        if self._diffusion is None:
+            if diffusion_steps is not None or diffusion_threshold is not None:
+                raise EngineError(
+                    "tpu.diffusion_steps / tpu.diffusion_threshold are the "
+                    "settings of a model that generates by diffusion over "
+                    "blocks: this model has no block length")
+        else:
+            from symmetry_tpu.models.llama import diffusion_refusals
+
+            refused = diffusion_refusals(
+                mesh=mesh is not None, role=role,
+                prefix_cache=prefix_cache_bytes > 0,
+                speculative=speculative is not None,
+                prefill_chunk=prefill_chunk)
+            if refused:
+                raise EngineError(refused[0])
+            block = self._diffusion.block
+            if decode_block % block:
+                raise EngineError(
+                    f"decode_block {decode_block} is no multiple of the "
+                    f"block length {block}: a dispatch denoises whole blocks")
+            steps = block if diffusion_steps is None else int(diffusion_steps)
+            try:
+                transfer_schedule(block, steps)
+            except ValueError as exc:
+                raise EngineError(f"tpu.diffusion_steps: {exc}") from None
+            if diffusion_threshold is not None and not (
+                    0.0 <= diffusion_threshold < 1.0):
+                raise EngineError(
+                    f"tpu.diffusion_threshold {diffusion_threshold} is no "
+                    f"probability in [0, 1)")
+            # a prompt's bucket also holds the opening block behind it
+            prefill_buckets = tuple(b for b in prefill_buckets
+                                    if b + block <= max_seq_len)
+            if any(b % block for b in prefill_buckets):
+                raise EngineError(
+                    f"every prefill bucket must be a multiple of the block "
+                    f"length {block}; got {prefill_buckets}")
+            self._bd_steps = steps
+            self._bd_threshold = (None if diffusion_threshold is None
+                                  else float(diffusion_threshold))
+            # since start (stats.engine.diffusion): the engine counts the
+            # forwards it dispatches, the scheduler what reached a stream
+            self.diffusion = {
+                "forwards": 0, "commit_forwards": 0, "admit_forwards": 0,
+                "live_slot_forwards": 0, "positions_unmasked": 0,
+                "tokens_committed": 0, "tokens_dropped": 0,
+                "opening_block_tokens": {str(n): 0
+                                         for n in range(1, block + 1)}}
         # since start, as of the last synced decode block (stats.engine.dsa)
         self.dsa = (None if self._sparse is None else
                     {"queries": 0, "candidates": 0, "selected": 0,
@@ -519,14 +587,7 @@ class InferenceEngine:
             is sound — EXCEPT lengths, which position the writes and
             carry the previous use's values: reset to the empty-cache
             contract first."""
-            cache = scratch._replace(
-                lengths=jnp.zeros_like(scratch.lengths))
-            if cache.expert_pairs is not None:
-                # insert_all adds a prefix's count to the decode state's:
-                # a reused buffer must not bring its last use's along.
-                cache = cache._replace(
-                    expert_pairs=jnp.zeros_like(cache.expert_pairs))
-            h, cache = forward_hidden(params, cfg, tokens, cache,
+            h, cache = forward_hidden(params, cfg, tokens, _emptied(scratch),
                                       seq_lens=true_len, prefill_flash=True,
                                       tp_mesh=mesh)
             # Project ONLY the last valid position through the LM head —
@@ -578,9 +639,13 @@ class InferenceEngine:
                 **({"idx": place(state.cache.idx, prefix.idx)}
                    if state.cache.idx is not None else {}),
             )
+            # (a block-diffusion admission hands its opening block over:
+            # nothing is fed on from it, the last token fills the field)
+            first = (first_token[row] if self._diffusion is None
+                     else first_token[row, -1])
             return DecodeState(
                 cache=cache,
-                last_token=state.last_token.at[slot].set(first_token[row]),
+                last_token=state.last_token.at[slot].set(first),
                 temperature=state.temperature.at[slot].set(temp[row]),
                 top_p=state.top_p.at[slot].set(top_p[row]),
                 top_k=state.top_k.at[slot].set(top_k[row]),
@@ -794,6 +859,9 @@ class InferenceEngine:
                 top_p=state.top_p, top_k=state.top_k, rng=rng,
             ), out.T, n_emit
 
+        if self._diffusion is not None:
+            prefill, decode_block = self._diffusion_programs()
+
         state_shard = self._state_shardings
         if self.mesh is not None:
             # Host-read outputs (sampled tokens) must be fully replicated —
@@ -895,6 +963,106 @@ class InferenceEngine:
             return pairs[:, 0], pairs[:, 1]
 
         self._derive_keys = jax.jit(derive_keys)
+
+    def _diffusion_programs(self):
+        """The admission and the decode program of a model that generates
+        by diffusion over blocks (models/llama.py BlockDiffusion), under
+        the names a device trace is cut by: `bd_prefill`, `bd_decode_block`.
+
+        Both denoise a block the same way (`denoise`): `steps` forwards over
+        the block's [rows, block] tokens through the continuation path —
+        the block's K/V written in place behind the committed context and
+        `cache.lengths` left where it was, so nothing is committed — each
+        followed by the candidates, their confidences and the choice of
+        which masked positions become known (ops/sampling.py); then ONE
+        more forward over the finished block, which moves `lengths` by the
+        block: the commit. Known positions are a plane of their own: a
+        sampled id equal to the mask token's is a token like any other."""
+        cfg, mesh = self.config, self.mesh
+        block, mask_id = self._diffusion.block, self._diffusion.mask_token_id
+        steps, threshold = self._bd_steps, self._bd_threshold
+        schedule = transfer_schedule(block, steps)
+
+        def denoise(params, cache, tokens, known, temp, top_p, top_k, rng):
+            """tokens / known [R, block] at each row's `cache.lengths` (a
+            block boundary) -> (cache with the block committed, its tokens
+            [R, block], rng)."""
+            base = cache.lengths
+            counts = jnp.asarray(schedule, jnp.int32)
+
+            def step(i, carry):
+                cache, tokens, known, rng = carry
+                h, cache = forward_hidden(params, cfg, tokens, cache,
+                                          tp_mesh=mesh)
+                cache = cache._replace(lengths=base)  # not committed
+                logits = logits_from_hidden(params, cfg, h)  # [R, block, V]
+                split = jax.vmap(lambda k: jax.random.split(k, 2))(rng)
+                cand, conf = diffusion_candidates(
+                    logits, split[:, 1], temp, top_p, top_k)
+                take = diffusion_unmask(conf, known, counts[i],
+                                        i == steps - 1, threshold)
+                return (cache, jnp.where(take, cand, tokens), known | take,
+                        split[:, 0])
+
+            cache, tokens, _, rng = jax.lax.fori_loop(
+                0, steps, step, (cache, tokens, known, rng))
+            _, cache = forward_hidden(params, cfg, tokens, cache,
+                                      tp_mesh=mesh)  # the commit
+            return cache, tokens, rng
+
+        def bd_prefill(params, tokens, true_len, temp, top_p, top_k, rng,
+                       scratch):
+            """tokens [N, Sb] padded; returns (opening blocks [N, block],
+            prefix KV). The prompt's whole blocks are prefilled under the
+            block mask (`prefill`'s contract for `scratch`, whose capacity
+            is the bucket plus one block); its `true_len % block` left-over
+            tokens open the first generated block as known positions, and
+            that block is denoised and committed HERE, so the lane enters
+            decode at a block boundary: row i's first tokens are
+            `out[i, true_len[i] % block:]`, between 1 and `block` of them."""
+            whole = true_len // block * block
+            _, cache = forward_hidden(params, cfg, tokens, _emptied(scratch),
+                                      seq_lens=whole, prefill_flash=True,
+                                      tp_mesh=mesh)
+            pos = whole[:, None] + jnp.arange(block, dtype=jnp.int32)[None]
+            known = pos < true_len[:, None]
+            left_over = jnp.take_along_axis(
+                tokens, jnp.minimum(pos, tokens.shape[1] - 1), axis=1)
+            opening = jnp.where(known, left_over, mask_id).astype(jnp.int32)
+            cache, out, _ = denoise(params, cache, opening, known, temp,
+                                    top_p, top_k, rng)
+            return out, cache
+
+        def bd_decode_block(params, state: DecodeState, park):
+            """`decode_block / block` blocks a slot in ONE dispatch, each
+            denoised from all-masked and committed; `decode_block`'s
+            contract otherwise: (state, tokens [K, B] in position order,
+            pairs)."""
+            rows = self.max_slots
+
+            def one(s: DecodeState, _):
+                before = s.cache.lengths
+                cache, toks, rng = denoise(
+                    params, s.cache,
+                    jnp.full((rows, block), mask_id, jnp.int32),
+                    jnp.zeros((rows, block), bool), s.temperature, s.top_p,
+                    s.top_k, s.rng)
+                cache = cache._replace(
+                    lengths=_stay_parked(before, cache.lengths))
+                return s._replace(cache=cache, last_token=toks[:, -1],
+                                  rng=rng), toks.T
+
+            state, toks = jax.lax.scan(
+                one, _park(state, park), None,
+                length=self.decode_block // block)
+            toks = toks.reshape(self.decode_block, rows)
+            pairs = state.cache.expert_pairs
+            if pairs is None:
+                return state, toks, jnp.zeros((0,), jnp.int32)
+            return state._replace(cache=state.cache._replace(
+                expert_pairs=jnp.zeros_like(pairs))), toks, pairs
+
+        return bd_prefill, bd_decode_block
 
     # ------------------------------------------------------------------
     # Host-side API (called by the scheduler's engine thread)
@@ -1102,6 +1270,11 @@ class InferenceEngine:
             self.params, jnp.asarray(padded), lens_arr, temps_arr,
             top_ps_arr, top_ks_arr, prefill_keys,
             self._prefill_scratch_for(batch, bucket))
+        if self._diffusion is not None:
+            # the lanes enter decode with their opening block committed
+            block = self._diffusion.block
+            lens_arr = jnp.asarray(lens // block * block + block)
+            self.diffusion["admit_forwards"] += self._bd_steps + 1
         # One dispatch installs every row; pad rows re-write the last
         # real slot with bit-identical data (same prompt AND keys above).
         self._insert(prefix, slots_arr, lens_arr, toks, temps_arr,
@@ -1691,7 +1864,10 @@ class InferenceEngine:
         key = (batch, bucket)
         scratch = self._prefill_scratch.pop(key, None)
         if scratch is None:
-            scratch = self._new_prefix_cache(bucket, batch)
+            # (a block-diffusion admission commits the opening block
+            # behind the prompt's bucket)
+            room = 0 if self._diffusion is None else self._diffusion.block
+            scratch = self._new_prefix_cache(bucket + room, batch)
         return scratch
 
     def _store_prefill_scratch(self, batch: int, bucket: int,
@@ -2023,6 +2199,10 @@ class InferenceEngine:
         device execution (SURVEY §7 hard-part 3: double-buffered token
         fetch)."""
         self.state, toks, pairs = self._dispatch_decode()
+        if self.diffusion is not None:
+            blocks = self.decode_block // self._diffusion.block
+            self.diffusion["forwards"] += blocks * (self._bd_steps + 1)
+            self.diffusion["commit_forwards"] += blocks
         if self._count_experts:
             # Start the [experts] count's copy to the host now: by the
             # time this block's tokens are synced it has arrived, and
@@ -2073,19 +2253,22 @@ class InferenceEngine:
         def route(tokens: int) -> str:
             return moe_route(tokens, c.num_experts, c.num_experts_per_tok)
 
-        # by tokens a dispatch: decode is one per slot; a prefill is
-        # batch x bucket for every shape warm-up compiles
+        # by tokens a forward: decode is one per slot (a block per slot
+        # under block diffusion); a prefill is batch x bucket for every
+        # shape warm-up compiles
+        decode_tokens = self.max_slots * (
+            1 if self._diffusion is None else self._diffusion.block)
         prefills = sorted({b * bucket for bucket in self.prefill_buckets
                            for b in self.prefill_batches_for(bucket)})
         self._moe_report = {
             "experts": c.num_experts, "top_k": c.num_experts_per_tok,
             "layout": moe_layout(self.mesh, c.intermediate_size),
-            "route": {"decode": route(self.max_slots),
+            "route": {"decode": route(decode_tokens),
                       "prefill": {str(t): route(t) for t in prefills}},
             # what the routed form's three matmuls run as (the row tile
             # of the smallest program's rows: the kernel's own from 64)
             "grouped_matmul": grouped_matmul_form(
-                wg, min(self.max_slots, *prefills) * c.num_experts_per_tok,
+                wg, min(decode_tokens, *prefills) * c.num_experts_per_tok,
                 one_device=self.mesh is None),
             "quantized_leaf_route": (
                 "expert_stack: int8 [L, X, K, N] stays flat (the packed "
@@ -2149,6 +2332,29 @@ class InferenceEngine:
                 "index_cache_bytes": (per_token * self.max_slots
                                       * self.max_seq_len)}
         return paths
+
+    def diffusion_report(self) -> dict | None:
+        """`startup.diffusion`: the block, the two generation settings and
+        what a dispatch is made of; None for any other model."""
+        if self._diffusion is None:
+            return None
+        block, steps = self._diffusion.block, self._bd_steps
+        blocks = self.decode_block // block
+        return {
+            "block": block, "mask_token_id": self._diffusion.mask_token_id,
+            "steps": steps,
+            "rule": RULE_STATIC if self._bd_threshold is None
+            else RULE_DYNAMIC,
+            "threshold": self._bd_threshold,
+            "transfer_schedule": list(transfer_schedule(block, steps)),
+            "blocks_per_dispatch": blocks,
+            "forwards_per_dispatch": blocks * (steps + 1),
+            "tokens_per_forward": self.max_slots * block,
+            "admission": "the admission program denoises and commits the "
+                         "opening block: block - (prompt % block) first "
+                         "tokens, lanes block-aligned from then on",
+            "programs": {"prefill": "bd_prefill",
+                         "decode": "bd_decode_block"}}
 
     def sampling_route(self) -> dict:
         """How every sampling call of the served programs selects its
@@ -2348,4 +2554,7 @@ class InferenceEngine:
             # decode host, each of which sees its own tier role here);
             # an engine can only be one tier or unified.
             role=getattr(tpu_cfg, "role", "unified") or "unified",
+            diffusion_steps=getattr(tpu_cfg, "diffusion_steps", None),
+            diffusion_threshold=getattr(tpu_cfg, "diffusion_threshold",
+                                        None),
         )

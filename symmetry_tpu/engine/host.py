@@ -421,6 +421,9 @@ class EngineHost:
         ssm = self._engine.ssm_report()
         if ssm is not None:
             self._startup["ssm"] = ssm
+        diffusion = self._engine.diffusion_report()
+        if diffusion is not None:
+            self._startup["diffusion"] = diffusion
         self._write({"op": HostOp.READY,
                      "model": self._config.model_name,
                      "role": self._role,

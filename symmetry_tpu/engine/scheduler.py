@@ -414,6 +414,13 @@ class Scheduler:
             self._drafter: NGramDrafter | None = NGramDrafter(spec)
         else:
             self._drafter = None
+        # A model that generates by diffusion over blocks: an admission
+        # yields its opening block (1 to block-length first tokens,
+        # _activate_block); None for every other model.
+        report = (engine.diffusion_report()
+                  if hasattr(engine, "diffusion_report") else None) or {}
+        self._bd_block: int | None = report.get("block")
+        self._bd_forwards = report.get("forwards_per_dispatch", 0)
         # KV writes one dispatch can land for a slot: a verify dispatch
         # touches 1 + k_draft positions where a plain block touches
         # decode_block — the capacity guards must fence the larger.
@@ -654,6 +661,17 @@ class Scheduler:
             counts = list(self.engine.expert_pairs)
             out["moe"] = {"pairs": sum(counts), "expert_pairs": counts,
                           "route": self.engine.moe_report()["route"]}
+        if self._bd_block is not None:
+            # generation by diffusion over blocks: the settings, the
+            # forwards dispatched and what of their tokens reached a
+            # stream, as of the last entry read
+            report = self.engine.diffusion_report()
+            out["diffusion"] = {
+                **{k: report[k] for k in ("block", "steps", "rule",
+                                          "threshold")},
+                **self.engine.diffusion,
+                "opening_block_tokens": dict(
+                    self.engine.diffusion["opening_block_tokens"])}
         if getattr(self.engine, "dsa", None) is not None:
             # learned sparse attention: queries, the positions they could
             # select from and those they selected, as of the same block
@@ -927,6 +945,14 @@ class Scheduler:
             return self._decorate(active, TokenEvent(
                 text=text, token_id=last_tok,
                 tokens_generated=gen, tokens_emitted=emitted))
+        if kind == "first_run":
+            _k, active, run, last_tok, gen, emitted, ttft = job
+            text = active.decoder.push_many(run.tolist())
+            if not text:
+                return None
+            return self._decorate(active, TokenEvent(
+                text=text, token_id=last_tok, tokens_generated=gen,
+                tokens_emitted=emitted, ttft_s=ttft))
         if kind == "finish":
             _k, active, run, tok, reason, ttft, gen, emitted, costs = job
             toks = run.tolist() if hasattr(run, "tolist") else list(run)
@@ -1304,10 +1330,11 @@ class Scheduler:
                 led_share = wall / n_live
             else:
                 self.ledger.book_unattributed(wall)
-        block_tokens = 0
+        block_tokens = live_lanes = 0
         for slot, active in snapshot.items():
             if self._slots.get(slot) is not active:
                 continue  # finished in an earlier block; lane is stale
+            live_lanes += 1
             if active.req.cancelled():
                 # Discard the whole block remainder past the cancel.
                 if active.req.ledger is not None:
@@ -1327,18 +1354,9 @@ class Scheduler:
             # toward tokens_generated but is never detokenized or counted
             # as emitted.
             v = K if n_valid is None else int(n_valid[slot])
-            budget = active.req.max_new_tokens - active.generated
-            r = max(1, min(v, budget))
-            hits = np.flatnonzero(eos_mask[:r, slot])
-            if hits.size:
-                e = int(hits[0])
-                n_push, consumed, finish = e, e + 1, "stop"
-            elif budget <= v:
-                n_push = consumed = r
-                finish = "length"
-            else:
-                n_push = consumed = v
-                finish = None
+            n_push, consumed, finish = self._cut_run(
+                eos_mask[:, slot], v,
+                active.req.max_new_tokens - active.generated)
             last_tok = int(toks[consumed - 1, slot])
             active.generated += consumed
             active.emitted += n_push
@@ -1387,6 +1405,31 @@ class Scheduler:
         self.metrics["tokens"] += block_tokens
         if block_tokens:
             self._m_tokens.inc(block_tokens)
+        if self._bd_block is not None:
+            # what the dispatch's forwards yielded and what of it reached a
+            # stream: the rest — past a budget or a stop token, and idle
+            # or stale lanes' blocks — was dropped on this side
+            bd, made = self.engine.diffusion, int(toks.size)
+            bd["live_slot_forwards"] += live_lanes * self._bd_forwards
+            bd["positions_unmasked"] += made
+            bd["tokens_committed"] += block_tokens
+            bd["tokens_dropped"] += made - block_tokens
+
+    @staticmethod
+    def _cut_run(eos: np.ndarray, v: int, budget: int
+                 ) -> tuple[int, int, str | None]:
+        """Where a run of `v` new tokens ends for a request with `budget`
+        left: (tokens to push, tokens consumed, finish reason or None).
+        `eos[i]` says token i is a stop token: the first inside the budget
+        finishes as "stop" (consumed, never pushed), checked before the
+        length bound."""
+        r = max(1, min(v, budget))
+        hits = np.flatnonzero(eos[:r])
+        if hits.size:
+            return int(hits[0]), int(hits[0]) + 1, "stop"
+        if budget <= v:
+            return r, r, "length"
+        return v, v, None
 
     def _spec_peek(self) -> bool:
         """Would any active slot propose a draft from its CURRENT
@@ -1990,7 +2033,11 @@ class Scheduler:
         t0 = time.perf_counter()
         with self._phase("sync", **read.attrs) as span:
             try:
-                firsts = np.asarray(adm.toks).reshape(-1)
+                firsts = np.asarray(adm.toks)
+                # one first token a row — or, from a model that generates
+                # by diffusion over blocks, the row's opening block
+                firsts = (firsts.reshape(-1) if self._bd_block is None
+                          else firsts.reshape(-1, self._bd_block))
             except Exception as exc:  # noqa: BLE001 — device errors → stream error
                 error = exc
             wait_s = time.perf_counter() - t0
@@ -2040,7 +2087,13 @@ class Scheduler:
                     req.ledger.waste_all_device("killed_prefill")
                 self._finish(slot, active, "cancelled", None, ())
                 continue
-            self._activate(slot, req, int(first), active)
+            if self._bd_block is None:
+                self._activate(slot, req, int(first), active)
+            else:
+                # the left-over prompt tokens opened the block: what
+                # follows them is the stream's first tokens
+                self._activate(slot, req, first[
+                    len(req.prompt_ids) % self._bd_block:], active)
 
     def _book_admission(self, adm: _Admission, device_s: float) -> None:
         """symledger: an admission's device seconds land on the requests
@@ -2152,9 +2205,10 @@ class Scheduler:
             if req.ledger is not None:
                 req.ledger.book_device("chunk", cost)
 
-    def _activate(self, slot: int, req: GenRequest, first: int,
+    def _activate(self, slot: int, req: GenRequest, first: int | np.ndarray,
                   active: _ActiveSlot | None) -> None:
-        """The lane's first token has been read: stamp TTFT, finish on
+        """The lane's first token (block diffusion: its first tokens,
+        `_activate_block`) has been read: stamp TTFT, finish on
         EOS / budget / capacity, else emit it. `active` is the lane as
         registered at dispatch (None on a prefill tier: hand off)."""
         if req.resume_offset > 0 and req.reused_tokens > 0:
@@ -2188,6 +2242,9 @@ class Scheduler:
                                active.first_token_at - picked,
                                request_id=req.id, trace_id=req.trace_id,
                                prompt_len=len(req.prompt_ids))
+        if self._bd_block is not None:
+            self._activate_block(slot, active, first)
+            return
         active.generated = 1
         if first in self.engine.tokenizer.eos_ids:
             self._finish(slot, active, "stop", first, ())
@@ -2213,6 +2270,36 @@ class Scheduler:
         if self._drafter is not None and req.speculative is not False:
             self._drafter.begin(slot, req.prompt_ids, first)
         self._submit_job(("first", active, first,
+                          active.first_token_at - req.enqueued_at))
+
+    def _activate_block(self, slot: int, active: _ActiveSlot,
+                        run: np.ndarray) -> None:
+        """`_activate`'s tail for a model that generates by diffusion over
+        blocks: the admission yielded the opening block's `run` of 1 to
+        block-length first tokens (engine bd_prefill), consumed like a
+        decode block's run — up to the first EOS or the budget, the
+        surplus dropped on this side of the counters — and sent as one
+        event that carries the TTFT."""
+        req, bd = active.req, self.engine.diffusion
+        bd["opening_block_tokens"][str(len(run))] += 1
+        bd["positions_unmasked"] += len(run)
+        n_push, consumed, finish = self._cut_run(
+            np.isin(run, self._eos_arr), len(run), req.max_new_tokens)
+        last_tok = int(run[consumed - 1])
+        active.generated, active.emitted = consumed, n_push
+        self.metrics["tokens"] += n_push
+        self._m_tokens.inc(n_push)
+        bd["tokens_committed"] += n_push
+        bd["tokens_dropped"] += len(run) - n_push
+        if finish is None and (
+                active.prompt_len + active.generated
+                + 2 * self._max_block_writes > self.engine.slot_capacity + 1):
+            finish = "length"
+        if finish is not None:
+            self._finish(slot, active, finish, last_tok, run[:n_push])
+            return
+        self._submit_job(("first_run", active, run[:n_push], last_tok,
+                          active.generated, active.emitted,
                           active.first_token_at - req.enqueued_at))
 
     def _handoff_request(self, slot: int, req: GenRequest,

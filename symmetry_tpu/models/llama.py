@@ -83,6 +83,27 @@ class SparseAttention:
 
 
 @dataclass(frozen=True)
+class BlockDiffusion:
+    """Generation by diffusion over blocks (the SDAR family): attention is
+    causal ACROSS blocks of `block` positions and bidirectional inside one
+    (`M[i, j] = 1 iff j // block <= i // block`), the logit row at a
+    position predicts the token AT it, and a block is generated by
+    denoising: its unknown positions enter as `mask_token_id` and are made
+    known by confidence, a few a forward (ops/sampling.py
+    diffusion_candidates / diffusion_unmask; engine/engine.py has the
+    programs)."""
+
+    block: int
+    mask_token_id: int
+
+
+# The SDAR family's generation settings where a config.json does not carry
+# them: the released chat models' block of four and their `<|MASK|>` id.
+SDAR_BLOCK_LENGTH = 4
+SDAR_MASK_TOKEN_ID = 151669
+
+
+@dataclass(frozen=True)
 class MoEConfig(ModelConfig):
     """Mixture-of-experts variant (mixtral family): the MLP becomes
     num_experts parallel FFNs with top-k routing (models/moe.py)."""
@@ -99,6 +120,9 @@ class MoEConfig(ModelConfig):
     qk_norm: bool = False
     mrope_section: tuple[int, ...] | None = None
     sparse: SparseAttention | None = None
+    # the SDAR family: the block mask and the mask token (None: a causal
+    # decoder that yields one token a stream a forward, every other model)
+    diffusion: BlockDiffusion | None = None
     # whose tensor names the expert block has in an HF checkpoint
     # (`hf_moe_names`): "mixtral" (`block_sparse_moe`) or "qwen3_moe"
     # (`mlp.gate`, `mlp.experts`)
@@ -283,6 +307,35 @@ PRESETS: dict[str, ModelConfig] = {
         mrope_section=(16, 24, 24),
         sparse=SparseAttention(topk=2048, index_heads=16,
                                index_head_dim=64),
+        hf_block="qwen3_moe", init_fan_in=True,
+    ),
+    # the CPU's copy of SDAR-MoE's mechanisms: blocks of four under the
+    # block mask, GQA with q/k norms, 8 experts top 2, an untied head; the
+    # mask token is the vocabulary's last id (outside the byte tokenizer's
+    # 0..257)
+    "tiny-bd": MoEConfig(
+        vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
+        num_kv_heads=2, intermediate_size=32, head_dim=16,
+        rope_theta=10000.0, rms_eps=1e-6, max_position=512,
+        num_experts=8, num_experts_per_tok=2, qk_norm=True,
+        diffusion=BlockDiffusion(block=4, mask_token_id=511),
+        hf_block="qwen3_moe", init_fan_in=True,
+    ),
+    # SDAR-30B-A3B-Chat CUT IN DEPTH to 12 of its 48 identical layers at
+    # every published width, with all 128 experts and the whole vocabulary:
+    # stage 1 of 4 of a pipeline over one four-chip host, what one 16 GB
+    # chip holds in int8 beside a 128 x 640 cache (benchmarks/configs/
+    # sdar-30b-a3b-chat.json has the cut). `intermediate_size` is the
+    # routed expert's width (`moe_intermediate_size`); the published dense
+    # width 6144 serves no layer. Block length and mask token are the
+    # released chat models' generation settings (`assumed` in that file).
+    "sdar-30b-a3b-chat": MoEConfig(
+        vocab_size=151936, hidden_size=2048, num_layers=12, num_heads=32,
+        num_kv_heads=4, intermediate_size=768, head_dim=128,
+        rope_theta=1000000.0, rms_eps=1e-6, max_position=32768,
+        num_experts=128, num_experts_per_tok=8, qk_norm=True,
+        diffusion=BlockDiffusion(block=SDAR_BLOCK_LENGTH,
+                                 mask_token_id=SDAR_MASK_TOKEN_ID),
         hf_block="qwen3_moe", init_fan_in=True,
     ),
     "mixtral-8x7b": MoEConfig(
@@ -775,13 +828,25 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
         and takes the kernel's pair form where the pairs are interleaved
         rows; any other head size that is no lane tile (the tiny test
         configurations' 16) keeps the XLA path and the reply says why
-        (`decode_why`)."""
+        (`decode_why`);
+      - generation by diffusion over blocks (`config.diffusion`): every
+        forward over the cache carries a block of queries a slot, which
+        the one-query decode kernel does not take — the XLA path, with
+        its `decode_why`."""
     from symmetry_tpu.ops import decode_attention as da
 
     kernel = "pallas-interpret" if interpret_mode() else "pallas"
     model = 1 if tp_mesh is None else dict(tp_mesh.shape).get("model", 1)
     if config.num_kv_heads % model:
         return {"prefill": "xla", "decode": "xla"}
+    diffusion = getattr(config, "diffusion", None)
+    if diffusion is not None:
+        # no program of such a model has one query a slot: a decode forward
+        # carries a block of them over the cache
+        return {"prefill": kernel, "decode": "xla", "decode_why": (
+            f"a decode forward carries {diffusion.block} positions a slot "
+            f"under the block mask: ops/decode_attention.py is a one-query "
+            f"kernel, so the forward takes gqa_attention over the cache")}
     data = 1 if tp_mesh is None else dict(tp_mesh.shape).get("data", 1)
     tiles = da.geometry(batch // data if batch % data == 0 else batch,
                         capacity, config.num_kv_heads // model,
@@ -875,6 +940,44 @@ def sparse_refusals(*, mesh: bool = False, role: str = "unified",
     return why
 
 
+def diffusion_refusals(*, mesh: bool = False, role: str = "unified",
+                       prefix_cache: bool = False, speculative: bool = False,
+                       prefill_chunk: int | None = None) -> list[str]:
+    """Why a model that generates by diffusion over blocks cannot be served
+    under these settings: one sentence a setting, empty when it can (the
+    engine and provider/config.py ask, as of `sparse_refusals`)."""
+    why = []
+    if prefix_cache:
+        why.append(
+            "tpu.prefix_cache_mb: a hit would have to end on a block "
+            "boundary of the NEW prompt too (the left-over tokens open the "
+            "first generated block), which the radix lookup does not know — "
+            "leave it unset for a model that generates by diffusion")
+    if speculative:
+        why.append(
+            "tpu.speculative: a denoise forward already yields several "
+            "positions a stream and nothing is drafted ahead of it — leave "
+            "it unset for a model that generates by diffusion")
+    if prefill_chunk is not None:
+        why.append(
+            f"tpu.prefill_chunk {prefill_chunk}: a chunk's last position "
+            f"yields no token here and the opening block is denoised by the "
+            f"admission program, which the chunk programs do not do — set "
+            f"prefill_chunk: null for a model that generates by diffusion "
+            f"(prompts prefill whole, up to the largest bucket)")
+    if role != "unified":
+        why.append(
+            f"tpu.role {role!r}: the handoff carries one first token, not "
+            f"an opening block — a model that generates by diffusion serves "
+            f"unified")
+    if mesh:
+        why.append(
+            "tpu.mesh: the block programs are not shown equal to the "
+            "reference on a sharded trunk — a model that generates by "
+            "diffusion runs on one device")
+    return why
+
+
 def _attention(
     x: jnp.ndarray,             # [B, S, E], already normed
     lp: dict,                   # one layer's params (leading L dim stripped)
@@ -932,6 +1035,9 @@ def _attention(
         q = q * jnp.asarray(scale * D ** 0.5, q.dtype)
 
     sparse = getattr(config, "sparse", None)
+    diffusion = getattr(config, "diffusion", None)
+    # (a keyword only where there is a block: no other model's call moves)
+    blocked = {} if diffusion is None else {"block_len": diffusion.block}
     if sparse is None:
         cache = write_kv(cache, layer, positions, k, v,
                          by_head=tp_mesh is not None)
@@ -1005,7 +1111,7 @@ def _attention(
             from symmetry_tpu.ops import flash
 
             kw = dict(window=config.sliding_window,
-                      interpret=interpret_mode())
+                      interpret=interpret_mode(), **blocked)
             if keep is not None:
                 # (the keep-set carries causality and the prompt's length)
                 attn = sa.flash_sparse(q, k, v, keep,
@@ -1039,7 +1145,7 @@ def _attention(
                 sliding_window=config.sliding_window,
                 k_scale=at_layer(cache.k_scale) if cache.quantized else None,
                 v_scale=at_layer(cache.v_scale) if cache.quantized else None,
-                keep=keep)
+                keep=keep, **blocked)
     attn = attn.reshape(B, S, nq * D)
     if gate is not None:
         attn = attn * jax.nn.sigmoid(gate)
@@ -1489,6 +1595,32 @@ def hf_config_sparse(config: MoEConfig) -> dict:
     }
 
 
+def hf_config_diffusion(config: MoEConfig) -> dict:
+    """A block-diffusion config as its published `config.json` keys (what
+    `config_from_hf` reads back, and what the plain reference —
+    `benchmarks/reference/block_diffusion_moe_decoder.py` — is given).
+    `block_length` and `mask_token_id` are not keys of the published file:
+    they are the generation settings, carried here so one dict says all."""
+    c = config
+    return {
+        "model_type": "sdar_moe", "vocab_size": c.vocab_size,
+        "hidden_size": c.hidden_size, "num_hidden_layers": c.num_layers,
+        "num_attention_heads": c.num_heads,
+        "num_key_value_heads": c.num_kv_heads, "head_dim": c.dim_per_head,
+        "moe_intermediate_size": c.intermediate_size,
+        "num_experts": c.num_experts,
+        "num_experts_per_tok": c.num_experts_per_tok,
+        "norm_topk_prob": True, "decoder_sparse_step": 1,
+        "mlp_only_layers": [], "rope_theta": c.rope_theta,
+        "rope_scaling": None, "rms_norm_eps": c.rms_eps,
+        "max_position_embeddings": c.max_position,
+        "tie_word_embeddings": c.tie_embeddings,
+        "attention_bias": c.attention_bias, "use_sliding_window": False,
+        "block_length": c.diffusion.block,
+        "mask_token_id": c.diffusion.mask_token_id,
+    }
+
+
 def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
     """Build a ModelConfig from an HF config.json dict (llama/mistral/
     qwen2/mixtral shapes; mixtral's num_local_experts selects MoEConfig)."""
@@ -1577,6 +1709,37 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
             router_score="sigmoid",
             router_bias=bool(hf.get("use_expert_bias", True)),
             routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        )
+    if hf.get("model_type") == "sdar_moe":
+        # the Qwen3-MoE block under a block mask, generated by diffusion
+        if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1:
+            raise ValueError("sdar_moe with dense-MLP layers (mlp_only_layers,"
+                             " decoder_sparse_step) is not implemented")
+        if not hf.get("norm_topk_prob", True):
+            raise ValueError("sdar_moe with norm_topk_prob false is not "
+                             "implemented")
+        if hf.get("rope_scaling"):
+            raise ValueError("sdar_moe with rope_scaling is not implemented")
+        return MoEConfig(
+            vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf["num_key_value_heads"],
+            intermediate_size=hf["moe_intermediate_size"],
+            head_dim=hf.get("head_dim"),
+            rope_theta=float(hf.get("rope_theta", 1000000.0)),
+            rms_eps=hf.get("rms_norm_eps", 1e-6),
+            tie_embeddings=hf.get("tie_word_embeddings", False),
+            sliding_window=sliding,
+            attention_bias=hf.get("attention_bias", False),
+            max_position=hf.get("max_position_embeddings", 32768),
+            num_experts=hf["num_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            qk_norm=True,
+            diffusion=BlockDiffusion(
+                block=hf.get("block_length", SDAR_BLOCK_LENGTH),
+                mask_token_id=hf.get("mask_token_id", SDAR_MASK_TOKEN_ID)),
+            hf_block="qwen3_moe", init_fan_in=True,
         )
     if hf.get("model_type") == "KeyeVL2":
         # the LANGUAGE model of the family (the catalog row's keys): the

@@ -36,7 +36,9 @@ def gqa_attention(
     k_scale: jnp.ndarray | None = None,  # [B, n_kv_heads, T] f32: int8 cache
     v_scale: jnp.ndarray | None = None,  # per-token-per-head dequant scales
     keep: jnp.ndarray | None = None,  # [B, S, T] bool: a learned selection
-) -> jnp.ndarray:                     # (ops/sparse_attention.py) to stay in
+                                      # (ops/sparse_attention.py) to stay in
+    block_len: int | None = None,  # block mask: causal ACROSS blocks only
+) -> jnp.ndarray:
     """Returns [B, S, n_q_heads, head_dim] in q's dtype. Softmax in f32.
 
     With k_scale/v_scale set, k_cache/v_cache hold int8 payloads
@@ -44,6 +46,11 @@ def gqa_attention(
     contractions — k's scale multiplies the scores (k = q·s distributes over
     the dot product), v's scale multiplies the probabilities — so no bf16
     copy of the cache is ever materialized and the HBM read stays int8-wide.
+
+    `block_len` (generation by diffusion over blocks) makes the mask causal
+    across blocks of that many ABSOLUTE positions and bidirectional inside
+    one: a query sees every written key up to the last position of its own
+    block.
     """
     B, S, n_q, D = q.shape
     T, n_kv = k_cache.shape[1], k_cache.shape[2]
@@ -66,8 +73,10 @@ def gqa_attention(
     scores = scores * scale
 
     kv_pos = jnp.arange(T, dtype=jnp.int32)
+    q_last = q_positions if block_len is None else (
+        q_positions // block_len * block_len + (block_len - 1))
     # key valid iff written (pos < kv_length) and causal (pos <= query pos)
-    mask = (kv_pos[None, None, :] <= q_positions[..., None]) & (
+    mask = (kv_pos[None, None, :] <= q_last[..., None]) & (
         kv_pos[None, None, :] < kv_length[:, None, None]
     )  # [B, S, T]
     if sliding_window is not None:

@@ -31,7 +31,8 @@ NEG_INF = -2.0**30
 
 
 def _flash_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
-                  block_q: int, block_k: int, window: int | None):
+                  block_q: int, block_k: int, window: int | None,
+                  block_len: int | None = None):
     qi = pl.program_id(2)
     seq_len = seqlen_ref[pl.program_id(0)]  # this batch row's true length
 
@@ -44,6 +45,11 @@ def _flash_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
 
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
+    # the last key a query sees: itself, or under a block mask (generation
+    # by diffusion over blocks of `block_len`) the last position of its
+    # own block — causal across blocks, bidirectional inside one
+    q_last = q_pos if block_len is None else (
+        q_pos // block_len * block_len + (block_len - 1))
 
     def body(j, carry):
         m, l, acc = carry
@@ -56,7 +62,7 @@ def _flash_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
         )  # [block_q, block_k]
         kv_pos = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        mask = (kv_pos <= q_pos) & (kv_pos < seq_len)
+        mask = (kv_pos <= q_last) & (kv_pos < seq_len)
         if window is not None:
             # mistral-style local attention: key within `window` of query
             mask &= kv_pos > q_pos - window
@@ -79,6 +85,8 @@ def _flash_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
     lo = 0
     if window is not None:
         lo = jnp.maximum(0, (qi * block_q - window + 1) // block_k)
+    # (a block mask ends a query tile's keys with the tile: flash_prefill
+    # holds block_len to a divisor of block_q, and block_k == block_q)
     m, l, acc = jax.lax.fori_loop(lo, qi + 1, body, (m0, l0, acc0))
     # Padded rows (q_pos >= seq_len) are fully masked: l == 0. Guard the
     # division; their output is garbage by contract, but must not be NaN.
@@ -87,7 +95,8 @@ def _flash_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "block_k", "window", "interpret"))
+    jax.jit, static_argnames=("block_q", "block_k", "window", "interpret",
+                              "block_len"))
 def flash_prefill(
     q: jnp.ndarray,         # [B, S, H, D]
     k: jnp.ndarray,         # [B, S, K, D]
@@ -98,6 +107,7 @@ def flash_prefill(
     block_k: int = 128,
     window: int | None = None,  # mistral-style sliding-window span
     interpret: bool = False,
+    block_len: int | None = None,  # block mask: causal ACROSS blocks only
 ) -> jnp.ndarray:
     """Causal self-attention over a fresh (cache-empty) padded prompt.
 
@@ -114,6 +124,11 @@ def flash_prefill(
     block_k = min(block_k, S)
     if S % block_q or S % block_k:
         raise ValueError(f"S={S} not a multiple of blocks {block_q}/{block_k}")
+    if block_len is not None and (block_q % block_len or block_q != block_k
+                                  or window is not None):
+        raise ValueError(
+            f"block_len {block_len} must divide the query tile {block_q}, "
+            f"with equal tiles ({block_k}) and no sliding window")
     scale = D ** -0.5
 
     # [B, S, H, D] -> [B, H, S, D]: trailing (S, D) dims are the TPU-tileable
@@ -123,9 +138,10 @@ def flash_prefill(
     vt = v.transpose(0, 2, 1, 3)
 
     grid = (B, H, S // block_q)
-    kernel = functools.partial(_flash_kernel, scale=scale,
-                               block_q=block_q, block_k=block_k,
-                               window=window)
+    kernel = functools.partial(
+        _flash_kernel, scale=scale, block_q=block_q, block_k=block_k,
+        window=window, **({} if block_len is None
+                          else {"block_len": block_len}))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

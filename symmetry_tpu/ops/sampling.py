@@ -224,3 +224,94 @@ def verify_tokens(
     out = jnp.where(lane < stop, draft_ext, 0)
     out = jnp.where(lane == stop, bonus[:, None], out)
     return out.astype(jnp.int32), (n_acc + 1).astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# Generation by diffusion over blocks (models/llama.py BlockDiffusion): a
+# forward yields logits for every position of a block; each still-masked
+# position draws a candidate, and the most confident ones become known.
+
+RULE_STATIC = "low_confidence_static"
+RULE_DYNAMIC = "low_confidence_dynamic"
+
+
+def transfer_schedule(block: int, steps: int) -> tuple[int, ...]:
+    """How many masked positions the static rule makes known at each of a
+    block's `steps` denoise forwards: `block // steps`, the first
+    `block % steps` forwards one more (the published schedule); the last
+    forward takes whatever is left whatever this says."""
+    if not 1 <= steps <= block:
+        raise ValueError(f"denoise steps must be in 1..{block} (the block "
+                         f"length); got {steps}")
+    return tuple(block // steps + (i < block % steps) for i in range(steps))
+
+
+def diffusion_candidates(
+    logits: jnp.ndarray,        # [R, S, V] float: a block's logit rows
+    key: jax.Array,             # [R] per-slot PRNG keys
+    temperature: jnp.ndarray,   # [R] float; 0 => greedy
+    top_p: jnp.ndarray,         # [R] float in (0, 1]
+    top_k: jnp.ndarray,         # [R] int32
+    cap: int = SAMPLING_TOP_CAP,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """A candidate token for every position of a block and its confidence
+    -> (candidates [R, S] int32, confidence [R, S] float32).
+
+    The candidate is drawn from exactly the distribution `sample_tokens`
+    draws from (temperature, top-k, top-p over the top-`cap` window; the
+    argmax under temperature 0). Its confidence is the probability the
+    model's own distribution gives it: softmax of the temperature-scaled
+    logits over the WHOLE vocabulary, before the top-k / top-p cut (greedy
+    scales by 1). The published script reads it after the cut, where a
+    greedy lane's every candidate has probability 1 and the choice of
+    position falls to the tie rule; before the cut a greedy lane unmasks
+    what the model is surest of. The plain reference does the same."""
+    R, S, V = logits.shape
+    cap = min(cap, V)
+    logits = logits.astype(jnp.float32)
+    masked, top_idx = _masked_top_logits(logits, temperature, top_p, top_k,
+                                         cap)            # [R, S, cap] x2
+    keys = jax.vmap(lambda k: jax.random.split(k, S))(key)     # [R, S]
+    rank = jax.vmap(jax.vmap(
+        lambda k, row: jax.random.categorical(k, row)))(keys, masked)
+    cand = jnp.take_along_axis(top_idx, rank[..., None], axis=-1)[..., 0]
+    # (a kept entry of `masked` is the scaled logit itself)
+    chosen = jnp.take_along_axis(masked, rank[..., None], axis=-1)[..., 0]
+    safe_t = jnp.where(temperature > 0, temperature, 1.0)[:, None, None]
+    total = jax.nn.logsumexp(logits / safe_t, axis=-1)
+    return cand.astype(jnp.int32), jnp.exp(chosen - total)
+
+
+def diffusion_unmask(
+    confidence: jnp.ndarray,    # [R, S] float32
+    known: jnp.ndarray,         # [R, S] bool: positions already decided
+    n_static: jnp.ndarray,      # scalar int32: this forward's static count
+    final: jnp.ndarray,         # scalar bool: the block's last forward
+    threshold: float | None = None,
+) -> jnp.ndarray:
+    """Which masked positions become known after this forward -> [R, S]
+    bool, never a known one.
+
+    `low_confidence_static` (threshold None): the `n_static` masked
+    positions of highest confidence, a tie going to the LOWER position;
+    all that are left at the last forward, or where fewer are left.
+    `low_confidence_dynamic`: every masked position whose confidence
+    exceeds `threshold`, when those are at least the static count; the
+    static choice otherwise."""
+    masked = ~known
+    S = confidence.shape[-1]
+    conf = jnp.where(masked, confidence, -jnp.inf)
+    pos = jnp.arange(S, dtype=jnp.int32)
+    # ahead[r, i, j]: position j is chosen before position i
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (pos[None, None, :] < pos[None, :, None]))
+    rank = jnp.sum(ahead & masked[:, None, :], axis=-1)
+    take = masked & (rank < n_static)
+    if threshold is not None:
+        high = masked & (confidence > threshold)
+        enough = (jnp.sum(high, axis=-1, keepdims=True)
+                  >= jnp.minimum(n_static, jnp.sum(masked, axis=-1,
+                                                   keepdims=True)))
+        take = jnp.where(enough, high, take)
+    return jnp.where(final, masked, take)

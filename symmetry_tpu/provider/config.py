@@ -117,6 +117,16 @@ class TpuConfig:
     # with the knob on or off; sampled lanes stay unbiased via rejection
     # sampling. Per-request opt-out: "speculative": false on the request.
     speculative: Any = None
+    # A model that generates by diffusion over blocks (models/llama.py
+    # BlockDiffusion) only; refused for any other. diffusion_steps: denoise
+    # forwards a block (None: the block length, one position a forward —
+    # the family's default; fewer trades quality for speed).
+    # diffusion_threshold: with a value, every masked position whose
+    # confidence exceeds it becomes known when those are at least the
+    # step's static count (`low_confidence_dynamic`); None is
+    # `low_confidence_static`.
+    diffusion_steps: int | None = None
+    diffusion_threshold: float | None = None
     # Decode steps per device dispatch. 16 measured throughput-equal to
     # 64 at the llama3-8b/128-slot point (double-buffered dispatch hides
     # the round-trips) with ~2x lower TTFT and inter-chunk latency.
@@ -345,11 +355,35 @@ class ConfigManager:
         from symmetry_tpu.models.llama import PRESETS
 
         preset = PRESETS.get(tpu.model_preset)
+        mesh = math.prod((tpu.mesh or {}).values()) > 1
+        diffusion = getattr(preset, "diffusion", None)
+        if diffusion is not None:
+            from symmetry_tpu.models.llama import diffusion_refusals
+
+            refused = diffusion_refusals(
+                mesh=mesh, role=tpu.role or "unified",
+                prefix_cache=bool(tpu.prefix_cache_mb),
+                speculative=bool(tpu.speculative),
+                prefill_chunk=tpu.prefill_chunk)
+            if tpu.decode_block % diffusion.block:
+                refused.append(
+                    f"tpu.decode_block {tpu.decode_block} is no multiple of "
+                    f"the block length {diffusion.block}")
+            if refused:
+                raise ConfigError(f"model_preset {tpu.model_preset!r}: "
+                                  + "; ".join(refused))
+        elif preset is not None and (tpu.diffusion_steps is not None
+                                     or tpu.diffusion_threshold is not None):
+            raise ConfigError(
+                f"model_preset {tpu.model_preset!r}: tpu.diffusion_steps / "
+                f"tpu.diffusion_threshold are the settings of a model that "
+                f"generates by diffusion over blocks; this preset has no "
+                f"block length")
         if getattr(preset, "sparse", None) is not None:
             from symmetry_tpu.models.llama import sparse_refusals
 
             refused = sparse_refusals(
-                mesh=math.prod((tpu.mesh or {}).values()) > 1,
+                mesh=mesh,
                 role=tpu.role or "unified",
                 prefix_cache=bool(tpu.prefix_cache_mb),
                 speculative=bool(tpu.speculative),
@@ -362,7 +396,7 @@ class ConfigManager:
         from symmetry_tpu.models.hybrid import state_refusals
 
         refused = state_refusals(
-            mesh=math.prod((tpu.mesh or {}).values()) > 1,
+            mesh=mesh,
             role=tpu.role or "unified",
             prefix_cache=bool(tpu.prefix_cache_mb),
             speculative=bool(tpu.speculative),

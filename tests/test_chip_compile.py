@@ -690,3 +690,66 @@ def test_lfm2_prefill_lowers_flash_at_a_head_of_64_and_routes_its_experts(
                if re.search(rf"= {stack}\S* (copy|transpose|fusion|"
                             rf"dynamic-slice)\(", line)]
     assert not touched, touched[0]
+
+
+def test_sdar_decode_dispatch_denoises_and_commits_in_place(
+        one_chip, no_cache, monkeypatch):
+    """sdar-30b-a3b-chat's decode dispatch, whole, for a described v5e: four
+    blocks a slot, each two denoise forwards and a commit over [128, 4]
+    positions (a scan of blocks around a loop of forwards around the scan
+    of layers). The 1 GB cache rides every loop's carry: no level of the
+    nesting may copy or relay it, the experts run the grouped-matmul kernel
+    (a forward routes 4,096 pairs), and no decode-attention kernel is in it
+    (a block of queries takes the XLA route)."""
+    import importlib.util
+
+    from symmetry_tpu.engine import engine as eng_mod
+    from symmetry_tpu.models import llama, moe
+
+    for module in (llama, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    spec = importlib.util.spec_from_file_location(
+        "lowered_programs", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "lowered_programs.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    cfg = llama.preset("sdar-30b-a3b-chat")
+    e = tool.bare_engine(cfg)
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+        slice_above=1 << 40))
+    state = shaped(lambda: eng_mod.DecodeState(
+        cache=llama.init_cache(cfg, 128, 640, jnp.bfloat16, quantized=True,
+                               count_experts=True),
+        last_token=jnp.zeros((128,), jnp.int32),
+        temperature=jnp.zeros((128,), jnp.float32),
+        top_p=jnp.ones((128,), jnp.float32),
+        top_k=jnp.zeros((128,), jnp.int32),
+        rng=jax.random.split(jax.random.key(0), 128)))
+    park = jax.ShapeDtypeStruct((128,), bool, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        compiled = e._decode.lower(params, state, park).compile()
+    text = compiled.as_text()
+    whole = r"= (s8\[12,128,640,4,128\]|f32\[12,128,4,640\])\S* "
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(whole + r"(copy|transpose)\(", line)
+             or (re.search(whole + r"fusion\(", line)
+                 and "kind=kCustom" not in line)]   # the in-place scatters
+    assert not moved, moved[0]
+    assert len(re.findall(whole + r"fusion\(", text)) == 8  # k, v, 2 scales
+    # (what the XLA route does read a layer: a [1, 128, 640, 4, 128] slice
+    # of K and of V, staged and relaid — the four-query kernel's to remove)
+    # two forwards' trunks (the denoise loop's and the commit's), three
+    # grouped matmuls a layer each, compiled once a scan body
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 6
+    assert "decode_attention" not in text
+    # weights 8.4 GB and the cache 1.04 GB are arguments; what the program
+    # adds (logits of [512, 151936] and the sampler's windows) stays small
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30

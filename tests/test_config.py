@@ -135,3 +135,31 @@ def test_a_short_conv_preset_refuses_what_cannot_carry_its_tail(setting,
         ConfigManager(config=config(**LFM2_REFUSED[setting]))
     assert "mamba" not in str(err.value)
     assert "recurrent" in str(err.value)
+
+
+@pytest.mark.parametrize("preset", ["tiny-bd", "sdar-30b-a3b-chat"])
+@pytest.mark.parametrize("setting", sorted(LFM2_REFUSED) + ["decode_block"])
+def test_a_block_diffusion_preset_refuses_what_is_not_shown(setting, preset):
+    """A preset that generates by diffusion over blocks is refused under the
+    prefix cache, speculation, chunked prefill, a disagg role, a mesh or a
+    decode_block that is no multiple of its block before anything is built
+    (`diffusion_refusals`); its two generation settings are taken."""
+    def config(**tpu):
+        return {**BASE, "apiProvider": "tpu_native",
+                "tpu": {"model_preset": preset, "prefill_chunk": None,
+                        **tpu}}
+
+    cfg = ConfigManager(config=config(diffusion_steps=2,
+                                      diffusion_threshold=0.9))
+    assert (cfg.tpu.diffusion_steps, cfg.tpu.diffusion_threshold) == (2, 0.9)
+    refused = {**LFM2_REFUSED, "decode_block": {"decode_block": 6}}[setting]
+    with pytest.raises(ConfigError, match=f"tpu.{setting}") as err:
+        ConfigManager(config=config(**refused))
+    assert "diffusion" in str(err.value) or setting == "decode_block"
+
+
+@pytest.mark.parametrize("key", ["diffusion_steps", "diffusion_threshold"])
+def test_diffusion_settings_refused_for_a_preset_without_a_block(key):
+    with pytest.raises(ConfigError, match="no block length"):
+        ConfigManager(config={**BASE, "apiProvider": "tpu_native",
+                              "tpu": {"model_preset": "tiny-moe", key: 1}})

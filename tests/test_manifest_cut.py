@@ -37,6 +37,7 @@ def test_there_is_a_cut_configuration_to_hold():
     assert "granite-4.0-h-small.json" in cut_files()
     assert "qwen3-next-80b-a3b.json" in cut_files()
     assert "keye-vl-2.0-30b-a3b.json" in cut_files()
+    assert "sdar-30b-a3b-chat.json" in cut_files()
 
 
 @pytest.mark.parametrize("name", cut_files())
@@ -74,6 +75,13 @@ def test_a_cut_keeps_a_whole_period_of_the_published_pattern(name):
         # 48 identical layers: a period is one layer, and four is the
         # floor for the layers kept
         assert c["num_hidden_layers"] == 4
+        assert c["published"]["num_hidden_layers"] == 48
+        assert c["mlp_only_layers"] == [] and c["decoder_sparse_step"] == 1
+        return
+    if c.get("model_type") == "sdar_moe":
+        # 48 identical layers: a period is one layer; twelve are kept, a
+        # quarter of the model (stage 1 of 4), over the floor of four
+        assert c["num_hidden_layers"] == 12
         assert c["published"]["num_hidden_layers"] == 48
         assert c["mlp_only_layers"] == [] and c["decoder_sparse_step"] == 1
         return
@@ -151,6 +159,39 @@ def test_a_cut_file_is_the_programs_preset(name):
                      "byte tokenizer", "6144"):
             assert word in text, word
         assert "stage 1 of 12" in c["deployment"]
+    if c.get("model_type") == "sdar_moe":
+        from symmetry_tpu.models.llama import config_from_hf
+
+        # every published key the program reads, through its own reader;
+        # the block and the mask token are the preset's (`assumed`)
+        assert config_from_hf(c) == p
+        assert (p.num_experts, p.num_experts_per_tok) == (
+            c["num_experts"], c["num_experts_per_tok"]) == (128, 8)
+        assert (p.diffusion.block, p.diffusion.mask_token_id) == (4, 151669)
+        assert p.diffusion.mask_token_id < c["vocab_size"]
+        assert p.qk_norm and p.sliding_window is None and p.sparse is None
+        assert p.max_position == c["max_position_embeddings"] == 32768
+        tpu = c["tpu"]
+        assert (tpu["max_batch_size"], tpu["max_seq_len"],
+                tpu["decode_block"]) == (128, 640, 16)
+        assert tpu["prefill_chunk"] is None
+        assert (tpu["diffusion_steps"], tpu["diffusion_threshold"]) == (
+            2, None)
+        assert tpu["decode_block"] % p.diffusion.block == 0
+        assert all(b % p.diffusion.block == 0
+                   and b + p.diffusion.block <= tpu["max_seq_len"]
+                   for b in tpu["prefill_buckets"])
+        assert (c["decode_program"], c["prefill_program"]) == (
+            "bd_decode_block", "bd_prefill")
+        assert c["reference"].endswith("block_diffusion_moe_decoder.py")
+        # every `assumed` item the issue lists is stated
+        text = " ".join(c["assumed"])
+        for word in ("block length 4", "151669", "unshifted", "RMSNorm",
+                     "low_confidence_static", "low_confidence_dynamic",
+                     "one position a step", "steps 2", "plane of their own",
+                     "640", "byte tokenizer", "6144", "fan_in"):
+            assert word in text, word
+        assert "stage 1 of 4" in c["deployment"]
     if "layer_types" in c:
         assert list(p.layer_types) == c["layer_types"]
         assert (p.num_experts, p.num_experts_per_tok,

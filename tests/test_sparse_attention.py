@@ -909,7 +909,8 @@ def test_only_the_new_presets_draw_at_the_fan_in():
     (whose cells' weights may not move) keep `layers ** -0.5`."""
     fan_in = {name for name, c in llama.PRESETS.items()
               if getattr(c, "init_fan_in", False)}
-    assert fan_in == {"tiny-dsa", "keye-vl-2.0-30b-a3b"}
+    assert fan_in == {"tiny-dsa", "keye-vl-2.0-30b-a3b",
+                      "tiny-bd", "sdar-30b-a3b-chat"}     # PR 47's pair
     p = llama.init_params(CFG, jax.random.key(0), jnp.float32)["layers"]
     for name, fan in (("wq", CFG.hidden_size), ("wd", CFG.intermediate_size),
                       ("router", CFG.hidden_size)):

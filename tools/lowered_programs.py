@@ -4,12 +4,13 @@ change what an existing configuration runs?".
     JAX_PLATFORMS=cpu python tools/lowered_programs.py OUT_DIR [preset ...]
 
 For each preset (default: mistral-7b, qwen2-7b, granite-4.0-h-small,
-qwen3-next-80b-a3b, keye-vl-2.0-30b-a3b and lfm2-8b-a1b at the closed cells'
-shape, 128 slots x 640, int8 weights + int8 KV, decode_block 16; and
-tiny-moe8 — the stand-in for mixtral-8x7b's sharded programs — on a
-`model: 4` mesh of virtual CPU devices: twenty-one programs) it writes the StableHLO of the
-engine's OWN jits — `decode_block`, `prefill` at (8, 256) and `insert_all`
-— lowered from shapes alone (nothing is built or run), as
+qwen3-next-80b-a3b, keye-vl-2.0-30b-a3b, lfm2-8b-a1b and sdar-30b-a3b-chat
+(its diffusion programs at 2 denoise steps a block, the static rule) at the
+closed cells' shape, 128 slots x 640, int8 weights + int8 KV, decode_block
+16; and tiny-moe8 — the stand-in for mixtral-8x7b's sharded programs — on a
+`model: 4` mesh of virtual CPU devices: twenty-four programs) it writes the
+StableHLO of the engine's OWN jits — `decode_block`, `prefill` at (8, 256)
+and `insert_all` — lowered from shapes alone (nothing is built or run), as
 `OUT_DIR/<preset>.<program>.txt` and prints one sha256 a file. The text
 carries no source locations, and on the CPU the Pallas kernels lower through
 the interpreter (no Mosaic bytecode with file paths in it), so the same
@@ -18,7 +19,8 @@ checkout's `tools/`, run it from each, and `diff -r` the two directories.
 (PR 41, a kernel under the sparse path alone: 16 of the 18 files identical,
 keye's `prefill` and `decode_block` the two that differ. PR 42, a third
 recurrent kind: the eighteen older files identical; a parent that lacks a
-preset is given the names it has.)
+preset is given the names it has. PR 47, generation by diffusion over
+blocks: the twenty-one older files identical.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
@@ -59,6 +61,9 @@ def bare_engine(cfg):
     e.max_slots, e.max_seq_len = SLOTS, CAPACITY
     e._state_shardings = e._cache_shardings = None
     e._count_experts = bool(getattr(cfg, "num_experts", 0))
+    # generation by diffusion over blocks: the sdar cell's two settings
+    e._diffusion = getattr(cfg, "diffusion", None)
+    e._bd_steps, e._bd_threshold = 2, None
     e._build_jits()
     return e
 
@@ -72,16 +77,20 @@ def programs(e, params, state):
         return jax.ShapeDtypeStruct((n,), dtype)
 
     keys = shapes(lambda: jax.random.split(jax.random.key(0), n))
+    # (a block-diffusion admission commits its opening block behind the
+    # bucket and hands the block over where the others hand one token)
+    block = getattr(getattr(cfg, "diffusion", None), "block", 0)
     scratch = shapes(lambda: llama.init_cache(
-        cfg, n, bucket, jnp.bfloat16, quantized=True,
+        cfg, n, bucket + block, jnp.bfloat16, quantized=True,
         count_experts=e._count_experts))
+    first = (jax.ShapeDtypeStruct((n, block), i32) if block else vec(i32))
     yield "decode_block", e._decode.lower(
         params, state, jax.ShapeDtypeStruct((e.max_slots,), bool))
     yield "prefill", e._prefill.lower(
         params, jax.ShapeDtypeStruct((n, bucket), i32), vec(i32), vec(f32),
         vec(f32), vec(i32), keys, scratch)
     yield "insert_all", e._insert_all.lower(
-        state, scratch, vec(i32), vec(i32), vec(i32), vec(f32), vec(f32),
+        state, scratch, vec(i32), vec(i32), first, vec(f32), vec(f32),
         vec(i32), keys)
 
 
@@ -89,7 +98,8 @@ def main() -> int:
     out_dir = sys.argv[1]
     names = sys.argv[2:] or ["mistral-7b", "qwen2-7b", "tiny-moe8",
                              "granite-4.0-h-small", "qwen3-next-80b-a3b",
-                             "keye-vl-2.0-30b-a3b", "lfm2-8b-a1b"]
+                             "keye-vl-2.0-30b-a3b", "lfm2-8b-a1b",
+                             "sdar-30b-a3b-chat"]
     os.makedirs(out_dir, exist_ok=True)
     for name in names:
         cfg = llama.preset(name)
