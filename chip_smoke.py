@@ -48,7 +48,8 @@ contends for the chip its child needs.
         # attention layer; the report carries `ssm`: the kind, its state
         # and each program's form — the decode step of either kind must
         # read `pallas`: ops/ssm_step.py — and `moe`, whose
-        # `grouped_matmul` must read `pallas` on one chip: ops/gmm.py)
+        # `grouped_matmul` must read `pallas` on one chip, its `operand`
+        # the layers' stack and not a stack of one: ops/gmm.py)
     python chip_smoke.py --preset keye-vl-2.0-30b-a3b
         # learned sparse attention on one chip (a lightning indexer with a
         # key cache of its own; the report's `attention.sparse` carries
@@ -362,13 +363,9 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
             and ssm_decode.get("form") != "pallas"):
         failures.append(f"the recurrent layers' decode step did not run "
                         f"the compiled Pallas kernel: {ssm_decode}")
-    # One chip's int8 expert stacks: the routed form over the compiled
-    # grouped-matmul kernel (ops/gmm.py); under a mesh `lax.ragged_dot`
-    gmm_form = (startup.get("moe") or {}).get("grouped_matmul")
-    if (gmm_form is not None and "mesh" not in cfg["tpu"]
-            and gmm_form.get("form") != "pallas"):
-        failures.append(f"the routed expert FFN's grouped matmul did not "
-                        f"run the compiled Pallas kernel: {gmm_form}")
+    failures += grouped_matmul_failures(
+        (startup.get("moe") or {}).get("grouped_matmul"),
+        meshed="mesh" in cfg["tpu"])
     if device.get("platform") != "tpu":
         failures.append(f"the engine host's platform is "
                         f"{device.get('platform')}, not tpu")
@@ -403,6 +400,24 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
                    "ttft_s": round(lone[1]["ttft_s"], 3)},
         "host_restarts": supervisor.get("restarts"),
     }
+
+
+def grouped_matmul_failures(gmm_form: dict | None, meshed: bool) -> list:
+    """What `startup.moe.grouped_matmul` may not read on one chip's int8
+    expert stacks: the routed form runs over the compiled grouped-matmul
+    kernel (ops/gmm.py; under a mesh `lax.ragged_dot`), whose weight
+    operand is the layers' stack as it lies (models/llama.py run_layers,
+    models/hybrid.py: `moe_mlp(stack=)`)."""
+    if gmm_form is None or meshed:
+        return []
+    if gmm_form.get("form") != "pallas":
+        return [f"the routed expert FFN's grouped matmul did not run the "
+                f"compiled Pallas kernel: {gmm_form}"]
+    if gmm_form.get("operand") == "stack of one":
+        return [f"the grouped-matmul kernel is given a layer's slice of "
+                f"the expert stacks, which XLA copies out before every "
+                f"call: {gmm_form}"]
+    return []
 
 
 def verdict(report: dict) -> dict:

@@ -2244,10 +2244,13 @@ class InferenceEngine:
         if self._moe_report is not None:
             return self._moe_report
         from symmetry_tpu.models.moe import (
-            grouped_matmul_form, moe_layout, moe_route)
+            grouped_matmul_form, moe_layout, moe_route, whole_stacks)
         from symmetry_tpu.ops.quant import QuantizedTensor
 
         layers = self.params["layers"]
+        # the stacks the trunk hands `moe_mlp` whole: the hybrid trunk its
+        # `ffn` stack always, the homogeneous one what `run_layers` does
+        whole = layers.get("ffn") or whole_stacks(layers, self.mesh)
         wg = layers.get("ffn", layers)["wg"]
 
         def route(tokens: int) -> str:
@@ -2260,6 +2263,15 @@ class InferenceEngine:
             1 if self._diffusion is None else self._diffusion.block)
         prefills = sorted({b * bucket for bucket in self.prefill_buckets
                            for b in self.prefill_batches_for(bucket)})
+        gmm_form = grouped_matmul_form(
+            wg, min(decode_tokens, *prefills) * c.num_experts_per_tok,
+            one_device=self.mesh is None)
+        if gmm_form["form"] != "ragged_dot":
+            # the kernel's weight operand: the layers' stack as it lies
+            # (it addresses layer and expert), or one layer's slice of it
+            # as a stack of one — which XLA copies out before each call
+            gmm_form["operand"] = ("stack of one" if whole is None else
+                                   f"layers' stack {list(wg.q.shape)}")
         self._moe_report = {
             "experts": c.num_experts, "top_k": c.num_experts_per_tok,
             "layout": moe_layout(self.mesh, c.intermediate_size),
@@ -2267,9 +2279,7 @@ class InferenceEngine:
                       "prefill": {str(t): route(t) for t in prefills}},
             # what the routed form's three matmuls run as (the row tile
             # of the smallest program's rows: the kernel's own from 64)
-            "grouped_matmul": grouped_matmul_form(
-                wg, min(decode_tokens, *prefills) * c.num_experts_per_tok,
-                one_device=self.mesh is None),
+            "grouped_matmul": gmm_form,
             "quantized_leaf_route": (
                 "expert_stack: int8 [L, X, K, N] stays flat (the packed "
                 "W8A16 layout has no expert grid dim) and is the grouped "

@@ -1166,9 +1166,11 @@ def _layer(
     sp_mode: str = "ring",
     tp_mesh=None,
     rope_positions=None,
+    stack=None,
 ) -> tuple[jnp.ndarray, KVCache]:
     """One decoder layer of the homogeneous stack: attention, then the FFN
-    (dense or routed experts); arguments as `_attention`'s."""
+    (dense or routed experts); arguments as `_attention`'s, and `stack` as
+    `moe_mlp`'s."""
     x = rms_norm(h, _norm_w(lp["attn_norm"], config), config.rms_eps)
     attn, cache = _attention(x, lp, cache, layer, positions, kv_valid,
                              seq_lens, config, prefill_flash,
@@ -1180,7 +1182,7 @@ def _layer(
     if "router" in lp:
         from symmetry_tpu.models.moe import moe_mlp
 
-        y, pairs = moe_mlp(x, lp, config, seq_lens, tp_mesh)
+        y, pairs = moe_mlp(x, lp, config, seq_lens, tp_mesh, stack)
         h = h + y
         if cache.expert_pairs is not None:
             # (a sparse-attention model's vector ends in its own counters)
@@ -1318,6 +1320,11 @@ def run_layers(
 ) -> tuple[jnp.ndarray, KVCache]:
     """Scan a stack of decoder layers over `h`; layer indices inside are
     local to the stack passed in, whose leading dim is the cache's."""
+    stacks = None
+    if "router" in layers_params:
+        from symmetry_tpu.models.moe import whole_stacks
+
+        stacks = whole_stacks(layers_params, tp_mesh)
 
     def body(carry, xs):
         # The cache rides the CARRY, scatter-updated in place: scan xs/ys
@@ -1328,7 +1335,8 @@ def run_layers(
         h, c = _layer(h, lp, c, l, positions, kv_valid,
                       seq_lens, config, use_flash, ring_mesh=use_ring,
                       sp_mode=sp_mode, tp_mesh=tp_mesh,
-                      rope_positions=rope_positions)
+                      rope_positions=rope_positions,
+                      stack=stacks and (stacks, l))
         return (h, c), None
 
     n_layers = jax.tree.leaves(layers_params)[0].shape[0]

@@ -345,14 +345,27 @@ def moe_layout(tp_mesh, ffn_width: int) -> str:
 EXPERT_LEAVES = ("wg", "wu", "wd")
 
 
+def whole_stacks(layers: dict, tp_mesh=None) -> dict | None:
+    """`moe_mlp`'s `stack` leaves for a scan over `layers` (models/llama.py
+    `run_layers`): the three expert leaves [L, X, A, F] as they lie, so that
+    the routed form's kernel addresses (layer, expert) in them and no layer's
+    experts are sliced out of the scan's operand first; None under a mesh,
+    where the expert FFN is partitioned and reads the layer's slice."""
+    if tp_mesh is not None:
+        return None
+    return {name: layers[name] for name in EXPERT_LEAVES}
+
+
 def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
             tp_mesh=None, stack=None) -> tuple[jnp.ndarray, jnp.ndarray]:
     """MoE FFN: [B, S, D] -> ([B, S, D], valid pairs per expert [X]).
     `seq_lens` [B] says how many of each row's S positions are real.
     `stack` = (the FFN layers' stacked leaves, this layer's index) where the
-    caller indexes its stacks itself (models/hybrid.py): the routed form's
-    kernel then reads an expert where it lies in the stack, and `lp`'s
-    slices of the three expert leaves are read by the mixture alone."""
+    caller holds its layers stacked (models/hybrid.py; models/llama.py
+    `run_layers` on one device): the routed form's kernel then reads an
+    expert where it lies in the stack, and `lp`'s slices of the three
+    expert leaves are read by the mixture alone (and by `ragged_dot`, for
+    a stack `grouped_matmul_form` gives no kernel)."""
     B, S, D = x.shape
     k = config.num_experts_per_tok
     if seq_lens is None:

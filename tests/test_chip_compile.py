@@ -341,10 +341,16 @@ def test_gdn_step_compiles_at_the_cells_state(one_chip, no_cache, dtype):
 @pytest.mark.parametrize("preset,rows,bucket,stack", [
     ("qwen3-next-80b-a3b", 2, 256, r"s8\[4,512,(2048,512|512,2048)\]"),
     ("granite-4.0-h-small", 4, 128, r"s8\[10,72,(4096,768|768,4096)\]"),
+    # the homogeneous trunk (models/llama.py run_layers; PR 49): one scan
+    # over all the layers, the stacks its constants and not its operands.
+    # sdar's 512 tokens are a DECODE forward's too (128 slots x a block)
+    ("sdar-30b-a3b-chat", 4, 128, r"s8\[12,128,(2048,768|768,2048)\]"),
+    ("keye-vl-2.0-30b-a3b", 1, 6144, r"s8\[4,128,(2048,768|768,2048)\]"),
 ])
 def test_prefill_reads_the_expert_stacks_where_they_lie(
         one_chip, no_cache, monkeypatch, preset, rows, bucket, stack):
-    """A 512-token prefill dispatch of each one-chip expert cell: the routed
+    """A routed prefill dispatch of each one-chip expert cell (512 tokens;
+    keye's smallest routed bucket): the routed
     form's three matmuls a layer are `moe_gmm` calls (ops/gmm.py: one call
     each in the body of a run's scan) whose weight operands are the WHOLE
     int8 stacks as they lie in HBM — no slice, copy or relayout of a stack
@@ -353,10 +359,14 @@ def test_prefill_reads_the_expert_stacks_where_they_lie(
     fusion, and no dense mixture left (its [tokens, experts, width]
     product)."""
     from symmetry_tpu.models import hybrid, llama, mamba2, moe
+    from symmetry_tpu.ops import sparse_attention
 
-    for module in (llama, mamba2, moe):
+    for module in (llama, mamba2, moe, sparse_attention):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
     cfg = llama.preset(preset)
+    # one scan a run of layers of one kind; the homogeneous trunk is one
+    n_runs = len(hybrid.runs(cfg)) if getattr(cfg, "layer_types",
+                                              None) else 1
     tokens = rows * bucket
     assert moe.moe_route(tokens, cfg.num_experts,
                          cfg.num_experts_per_tok) == "routed"
@@ -382,8 +392,7 @@ def test_prefill_reads_the_expert_stacks_where_they_lie(
                                                     prefill_flash=True),
             donate_argnums=(2,)).lower(params, tok, cache, lens).compile(
         ).as_text()
-    assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 3 * len(
-        hybrid.runs(cfg))
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 3 * n_runs
     names = set(re.findall(rf"(%[\w.\-]+)(?: =|:) {stack}", text))
     assert names
     touched = [line.strip()[:160] for line in text.splitlines()
@@ -392,7 +401,8 @@ def test_prefill_reads_the_expert_stacks_where_they_lie(
                or (" fusion(" in line and names & set(re.findall(
                    r"%[\w.\-]+", line.split(" fusion(")[1])))]
     assert not touched, touched[0]
-    layer = stack.replace(r"s8\[4,", r"s8\[").replace(r"s8\[10,", r"s8\[")
+    # one layer's leaf, with or without the stack axis kept
+    layer = re.sub(r"^s8\\\[\d+,", r"s8\\[(1,)?", stack)
     sliced = [line.strip()[:160] for line in text.splitlines()
               if re.search(rf"= {layer}", line)]
     assert not sliced, sliced[0]
@@ -749,6 +759,12 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
     # two forwards' trunks (the denoise loop's and the commit's), three
     # grouped matmuls a layer each, compiled once a scan body
     assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 6
+    # ... whose weight operands are the layers' stacks as they lie: nothing
+    # yields one layer's experts (sliced by the layer scan they were a
+    # 0.6 GB copy a layer, 40% of the device's time: PERF.md, PR 49)
+    sliced = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= s8\[(1,)?128,(2048,768|768,2048)\]", line)]
+    assert not sliced, sliced[0]
     assert "decode_attention" not in text
     # weights 8.4 GB and the cache 1.04 GB are arguments; what the program
     # adds (logits of [512, 151936] and the sampler's windows) stays small

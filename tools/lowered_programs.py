@@ -20,7 +20,14 @@ checkout's `tools/`, run it from each, and `diff -r` the two directories.
 keye's `prefill` and `decode_block` the two that differ. PR 42, a third
 recurrent kind: the eighteen older files identical; a parent that lacks a
 preset is given the names it has. PR 47, generation by diffusion over
-blocks: the twenty-one older files identical.)
+blocks: the twenty-one older files identical. PR 49, the homogeneous trunk
+hands the routed experts their stacks whole: 20 of the 24 files identical —
+every dense, hybrid and meshed program, and both `insert_all`s — and the
+four trunks of the two one-device homogeneous expert presets differ:
+sdar-30b-a3b-chat's `decode_block` and `prefill` and keye-vl-2.0-30b-a3b's
+`prefill` lose the layer's `[1, 128, ...]` slices before their kernels;
+keye's `decode_block`, a dense mixture, differs in the ORDER of its scan's
+operands alone — the stacks ride it as constants it never reads.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
