@@ -92,8 +92,9 @@ DRAIN_TIMEOUT_S = 120.0
 # process may not import jax to ask)
 SPARSE_PRESETS = ("keye-vl-2.0-30b-a3b", "tiny-dsa")
 # presets that generate by diffusion over blocks: a decode forward carries a
-# block of queries a slot, which takes the XLA attention by design, and the
-# report carries `diffusion`
+# block of queries a slot, which the decode kernel takes as its q tile where
+# the cache has a geometry (`decode_queries`; tiny-bd's head of 16 has none
+# and says why), and the report carries `diffusion`
 DIFFUSION_PRESETS = ("sdar-30b-a3b-chat", "tiny-bd")
 
 
@@ -329,12 +330,19 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
     diffusion = cfg["tpu"]["model_preset"] in DIFFUSION_PRESETS
     if diffusion:
         bd = startup.get("diffusion") or {}
-        if (attention.get("prefill"), attention.get("decode")) != (
-                "pallas", "xla") or "decode_why" not in attention:
+        block_tile = (attention.get("decode") == "pallas"
+                      and attention.get("decode_queries") == bd.get("block"))
+        tiny_head = (attention.get("decode") == "xla"
+                     and "head of 16" in attention.get("decode_why", ""))
+        if attention.get("prefill") != "pallas" or not (block_tile
+                                                        or tiny_head):
             failures.append(f"a block-diffusion model's prefill did not run "
                             f"the compiled flash kernel, or its decode "
-                            f"forwards did not say why they take the XLA "
-                            f"attention: {attention}")
+                            f"forwards did not take the decode kernel with "
+                            f"the block as its q tile: {attention}")
+        if "opening_block" not in attention:
+            failures.append(f"no route reported for the admission's opening "
+                            f"block: {attention}")
         if not bd.get("block") or bd.get("programs") != {
                 "prefill": "bd_prefill", "decode": "bd_decode_block"}:
             failures.append(f"a block-diffusion model reported no "

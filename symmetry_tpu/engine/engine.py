@@ -2322,11 +2322,26 @@ class InferenceEngine:
         from symmetry_tpu.models.llama import (
             attention_paths, sparse_forms, sparse_select)
 
+        kv_bytes = jnp.dtype(jnp.int8 if self.kv_quant
+                             else self.cache_dtype).itemsize
         paths = attention_paths(
             self.config, self.max_seq_len, self.mesh,
-            batch=self.max_slots,
-            kv_bytes=jnp.dtype(jnp.int8 if self.kv_quant
-                               else self.cache_dtype).itemsize)
+            batch=self.max_slots, kv_bytes=kv_bytes)
+        if self._diffusion is not None:
+            # the admission program denoises the opening block over its
+            # scratch of bucket + block positions: the same routing, asked
+            # with that shape
+            block = self._diffusion.block
+            routes = {attention_paths(self.config, bucket + block, self.mesh,
+                                      batch=1, kv_bytes=kv_bytes)["decode"]
+                      for bucket in self.prefill_buckets}
+            paths["opening_block"] = "/".join(sorted(routes))
+            if "xla" in routes:
+                paths["opening_block_why"] = (
+                    f"the admission's scratch holds bucket + {block} "
+                    f"positions: where that is no multiple of 128, or the "
+                    f"head is no lane tile, its forwards over it take "
+                    f"gqa_attention by shape")
         if self._sparse is not None:
             # the selection each program's attention runs under, and what
             # the indexer's own cache costs

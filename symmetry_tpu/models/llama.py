@@ -830,9 +830,15 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
         configurations' 16) keeps the XLA path and the reply says why
         (`decode_why`);
       - generation by diffusion over blocks (`config.diffusion`): every
-        forward over the cache carries a block of queries a slot, which
-        the one-query decode kernel does not take — the XLA path, with
-        its `decode_why`."""
+        forward over the cache carries a block of queries a slot, from a
+        block boundary (`cache.lengths`: the engine's contract), so under
+        the block mask all of a slot's rows see the same keys — the
+        kernel's mask — and the forward takes the kernel with the block
+        as its q tile (`decode_queries`) wherever it has a geometry for
+        that; a cache it has none for (the admission program's scratch of
+        bucket + block positions is no multiple of 128, a tiny head is no
+        lane tile) and a sliding window (a mask a position) keep the XLA
+        path, the served cache's with its `decode_why`."""
     from symmetry_tpu.ops import decode_attention as da
 
     kernel = "pallas-interpret" if interpret_mode() else "pallas"
@@ -840,17 +846,13 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
     if config.num_kv_heads % model:
         return {"prefill": "xla", "decode": "xla"}
     diffusion = getattr(config, "diffusion", None)
-    if diffusion is not None:
-        # no program of such a model has one query a slot: a decode forward
-        # carries a block of them over the cache
-        return {"prefill": kernel, "decode": "xla", "decode_why": (
-            f"a decode forward carries {diffusion.block} positions a slot "
-            f"under the block mask: ops/decode_attention.py is a one-query "
-            f"kernel, so the forward takes gqa_attention over the cache")}
+    # no program of a model that generates by diffusion has one query a
+    # slot: a decode forward carries a block of them over the cache
+    queries = 1 if diffusion is None else diffusion.block
     data = 1 if tp_mesh is None else dict(tp_mesh.shape).get("data", 1)
     tiles = da.geometry(batch // data if batch % data == 0 else batch,
                         capacity, config.num_kv_heads // model,
-                        config.dim_per_head, kv_bytes)
+                        config.dim_per_head, kv_bytes, queries)
     if tiles is None and config.dim_per_head % da.LANES:
         # the reason rides along where the SHAPE is what the kernel lacks
         # (the served configurations have heads of 64, 128 or 256); the
@@ -861,6 +863,15 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
             why += (f", and {config.num_kv_heads // model} KV heads a chip "
                     f"are no interleaved pairs at this cache")
         return {"prefill": kernel, "decode": "xla", "decode_why": why}
+    if queries > 1 and (tiles is None or tp_mesh is not None
+                        or config.sliding_window is not None):
+        lacks = (f"has no geometry for a {batch} x {capacity} cache"
+                 if tiles is None else
+                 "takes on one device and without a sliding window alone")
+        return {"prefill": kernel, "decode": "xla", "decode_why": (
+            f"a decode forward carries {queries} positions a slot under "
+            f"the block mask, a q tile that ops/decode_attention.py "
+            f"{lacks}: the forward takes gqa_attention over the cache")}
     if tiles is None or (tp_mesh is not None
                          and capacity < da.TP_MIN_CAPACITY):
         return {"prefill": kernel, "decode": "xla"}
@@ -869,6 +880,7 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
             config.dim_per_head):
         return {"prefill": kernel, "decode": "xla"}
     return {"prefill": kernel, "decode": kernel,
+            **({"decode_queries": queries} if queries > 1 else {}),
             "decode_slot_tile": tiles[0], "decode_block_t": tiles[1]}
 
 
@@ -1121,23 +1133,37 @@ def _attention(
             else:
                 attn = flash.flash_prefill_tp(q, k, v, seq_lens,
                                               mesh=tp_mesh, **kw)
-        elif S == 1 and paths["decode"] != "xla":
-            # Single-position decode: the Pallas kernel reads only each
-            # slot's occupied KV prefix; the full cache is its operand and
-            # stays where it lies, layer and slot are DMA addressing
+        elif paths["decode"] != "xla" and S == paths.get("decode_queries",
+                                                         1):
+            # Decode — one position a slot, or a block-diffusion forward's
+            # block of them from a block boundary, which all see the same
+            # keys: the Pallas kernel reads only each slot's occupied KV
+            # prefix; the full cache is its operand and stays where it
+            # lies, layer and slot are DMA addressing
             # (ops/decode_attention.py).
             from symmetry_tpu.ops import decode_attention as da
 
-            args = (q[:, 0], cache.k, cache.v, layer, kv_valid,
-                    cache.k_scale if cache.quantized else None,
-                    cache.v_scale if cache.quantized else None)
+            scales = ((cache.k_scale, cache.v_scale) if cache.quantized
+                      else (None, None))
+            if S > 1 and cache.quantized:
+                # a block's forwards hand the kernel this layer's scale
+                # planes (1.3 MB each at 128 x 640, read out of XLA's fast
+                # memory): the whole arrays are small for a model this
+                # shallow, XLA keeps them there across the layer loop for
+                # the in-place scatters, and the kernel's pin to HBM on
+                # them has it evict and refetch both every layer — at
+                # sdar's twelve layers the v5e compiler's repacker crashes
+                # on that program (tests/test_chip_compile.py compiles it)
+                scales = tuple(at_layer(x)[None] for x in scales)
+            args = (q[:, 0] if S == 1 else q, cache.k, cache.v, layer,
+                    kv_valid, *scales)
             if keep is not None:
                 args += (keep[:, 0],)
             kw = dict(window=config.sliding_window,
                       interpret=interpret_mode())
             attn = (da.decode_attention(*args, **kw) if tp_mesh is None
-                    else da.decode_attention_tp(*args, mesh=tp_mesh,
-                                                **kw))[:, None]
+                    else da.decode_attention_tp(*args, mesh=tp_mesh, **kw))
+            attn = attn[:, None] if S == 1 else attn
         else:
             attn = gqa_attention(
                 q, heads_at_layer(cache.k), heads_at_layer(cache.v),
@@ -1238,6 +1264,11 @@ def forward_hidden(
     Split from the LM head so prefill can project only the last valid
     position — at 128k vocab the head matmul over a full padded bucket would
     dominate prefill cost.
+
+    With `config.diffusion`, a forward of exactly one block over the cache
+    starts on a block boundary (`cache.lengths % block == 0`: the engine
+    commits whole blocks), so its rows share one key set and may take the
+    decode kernel (`attention_paths`).
 
     prefill_flash=True routes attention through the Pallas flash kernel.
     VALID ONLY when cache.lengths are all zero (engine prefill's case) —

@@ -1,11 +1,19 @@
 """Grouped-query attention over a static KV cache.
 
-One attention routine serves both phases of serving:
+One attention routine serves every phase of serving:
 
   - prefill: q covers S new positions, cache already holds them (written
     before the call), mask is causal-by-absolute-position;
   - decode:  q covers 1 new position per slot, attends to everything the
-    slot has written so far.
+    slot has written so far;
+  - a block (generation by diffusion, `block_len`): q covers one block a
+    slot or the prompt's whole blocks, each seeing its own block whole.
+
+(Where ops/decode_attention.py has a geometry for the cache it takes the
+decode case and a block of queries from a block boundary, and this routine
+keeps what has a mask a position or a cache the kernel has no tiles for:
+chunked continuations, speculative verify, the admission's opening block
+over its scratch — models/llama.py attention_paths.)
 
 Masking is driven entirely by absolute positions, so the same jitted
 computation handles ragged per-slot lengths in a continuous batch — the
@@ -50,7 +58,8 @@ def gqa_attention(
     `block_len` (generation by diffusion over blocks) makes the mask causal
     across blocks of that many ABSOLUTE positions and bidirectional inside
     one: a query sees every written key up to the last position of its own
-    block.
+    block. (One block a slot with `kv_length` on the block's end is the same
+    mask for all its rows, `pos < kv_length`: the decode kernel's.)
     """
     B, S, n_q, D = q.shape
     T, n_kv = k_cache.shape[1], k_cache.shape[2]

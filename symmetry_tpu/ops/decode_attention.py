@@ -7,8 +7,16 @@ The decode step is HBM-bound and the KV cache is its second-largest stream
 `[B, T, K, D]` slice per layer for K and again for V (a copy into the
 compiler's fast memory that the score / output fusions then read), and
 masking discards the dead positions' values but not their traffic. This
-kernel is the single-position attention of every one-chip decode program
-(and, a call a shard, of a sharded trunk from TP_MIN_CAPACITY up):
+kernel is the attention of every one-chip decode program (and, a call a
+shard, of a sharded trunk from TP_MIN_CAPACITY up): one query position a
+slot, or the S positions of a block that a model generating by diffusion
+denoises at once — S times the query rows of a slot against the same
+live blocks. The contract of S > 1: every row of a slot sees the slot's
+keys below `kv_length` and nothing else tells the rows apart (the block
+mask with `kv_length` on the block's end: ops/attention.py gqa_attention
+`block_len`). It is NOT a causal multi-position kernel: speculative
+verify and a chunked continuation have a mask a row and keep
+`gqa_attention`.
 
   - The FULL [L, B, T, K, D] cache stays in HBM (pinned there: left
     free, XLA stages small operands whole in its fast memory) and is
@@ -58,9 +66,11 @@ kernel is the single-position attention of every one-chip decode program
     selection. K-fold redundant MXU and VPU work (bf16 operands, one
     pass), which measured far cheaper than pulling each head's rows out
     of the interleave (PERF.md, PR 29).
-  - Query rows are ordered (group, head) inside the kernel, so rows
-    r, r + K, ... share a KV head and a `slab` of max(K, 8) rows is a
-    whole sublane tile with the same head pattern in every slab.
+  - Query rows are ordered (position, group, head) inside the kernel, so
+    rows r, r + K, ... share a KV head and a `slab` of max(K, 8) rows is
+    a whole sublane tile with the same head pattern in every slab: S
+    positions are S times the groups, and nothing in the kernel's body
+    knows which of the two a row is.
   - int8 caches (ops/quant.py quantize_kv): payload is read at 1 byte and
     widened in VMEM to the query's dtype (exact). The [K, block_t] scale
     planes are position-minor; the MXU lays them out in the scores'
@@ -75,7 +85,9 @@ kernel is the single-position attention of every one-chip decode program
     nothing but its own rows.
 
 Masking is by absolute position (kv_pos < kv_length), identical semantics
-to ops/attention.py gqa_attention at decode (q position == length - 1).
+to ops/attention.py gqa_attention at decode (q position == length - 1)
+and, with S positions a slot, under `block_len=S` with the block's last
+position at length - 1.
 
 A learned selection (ops/sparse_attention.py: `keep`, a 0/1 float32 plane
 [B, K, T], the slot's set repeated a KV head) rides with the int8 cache's
@@ -100,7 +112,8 @@ SUBLANES = 8
 BLOCK_ROWS = 1024    # (position, head) rows of one item: 128 KB of int8
 WAYS = 4             # independent lane lists walked side by side
 NBUF = 2             # buffers a way: one item computed, the next in flight
-MAX_TILE_LANES = 128  # q / output lanes resident in VMEM per grid step
+MAX_TILE_LANES = 128  # q / output lanes resident in VMEM per grid step, a
+                      # query position each: S positions a lane are S of them
 MAX_TILE_ITEMS = 1024  # a work list's entries in SMEM: 32 KB for them all
 MAX_ITEM_BYTES = 2**19  # of K (and of V), WAYS x NBUF buffers each: 8 MB of
                         # the 16 MB of VMEM a kernel may take
@@ -125,10 +138,11 @@ def _lanes(n_kv: int, kv_bytes: int) -> tuple[int, int] | None:
 
 
 def geometry(batch: int, capacity: int, n_kv: int, head_dim: int = LANES,
-             kv_bytes: int = 1) -> tuple[int, int] | None:
+             kv_bytes: int = 1, queries: int = 1) -> tuple[int, int] | None:
     """(slot_tile, block_t) the kernel compiles with at this cache shape
-    (`n_kv`: the KV heads on the chip; `kv_bytes`: of a cache entry), or
-    None where it has none: the one gate — the caller keeps the XLA path
+    (`n_kv`: the KV heads on the chip; `kv_bytes`: of a cache entry;
+    `queries`: the positions a slot's q carries), or None where it has
+    none: the one gate — the caller keeps the XLA path
     there and says so. By shape alone, at every capacity and on either
     backend (the CPU interprets it); the >= 4,096 floor this replaced
     priced the old one-slot-one-block grid (PERF.md §6, PR 29), not
@@ -152,8 +166,12 @@ def geometry(batch: int, capacity: int, n_kv: int, head_dim: int = LANES,
     pair or head-major pairs (4 int8 heads) have none, and no other head
     size that is no lane tile has (the tiny test configurations' 16).
     slot_tile: the largest divisor of the batch whose lanes (slots x
-    head-major heads) are at most MAX_TILE_LANES and whose blocks fit a
-    work list of MAX_TILE_ITEMS (128 slots at 640, 16 at 8,192)."""
+    head-major heads) x `queries` are at most MAX_TILE_LANES — the q and
+    output tiles are that many lanes' rows, double-buffered, so a block of
+    4 queries moves in tiles of 32 slots at the bytes one query moves 128
+    in (sdar-30b-a3b-chat: 32 x 128 rows x 128 in bf16, 1 MB a tile, 4 MB
+    of VMEM for q and the output) — and whose blocks fit a work list of
+    MAX_TILE_ITEMS (128 slots at 640, 16 at 8,192)."""
     fold = 2 if 2 * head_dim == LANES else 1    # heads a cache row
     if n_kv % fold:
         return None
@@ -167,7 +185,7 @@ def geometry(batch: int, capacity: int, n_kv: int, head_dim: int = LANES,
     block_t = min(capacity, max(LANES, BLOCK_ROWS // n_kv // LANES * LANES))
     if block_t * n_kv * head_dim * kv_bytes > MAX_ITEM_BYTES:
         return None
-    most = max(1, min(MAX_TILE_LANES, MAX_TILE_ITEMS
+    most = max(1, min(MAX_TILE_LANES // queries, MAX_TILE_ITEMS
                       // -(-capacity // block_t)) // heads)
     return next(t for t in range(min(batch, most), 0, -1)
                 if batch % t == 0), block_t
@@ -196,7 +214,8 @@ def _three_bf16(x):
 def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             scale: float, block_t: int, capacity: int, heads: int,
             n_kv: int, fold: int, slab: int, ways: int, quantized: bool,
-            window: int | None, compute_dtype, masked: bool):
+            window: int | None, compute_dtype, masked: bool,
+            sliced: bool):
     if quantized:
         planes_hbm, rest = rest[:2 + masked], rest[2 + masked:]
         o_ref, kbuf, vbuf, scbuf, *spread, islot, iblk, sem = rest
@@ -256,10 +275,12 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                                      vbuf.at[w, buf], sem.at[1, w, buf])]
         if quantized:
             for p, plane in enumerate(planes_hbm):
-                # the keep plane (p == 2) is one layer's: no layer axis
+                # the keep plane (p == 2) is one layer's: no layer axis;
+                # scale planes of one layer are the caller's slice of it
                 at_t = (b // heads, slice(None), pl.ds(t0, block_t))
                 out.append(pltpu.make_async_copy(
-                    plane.at[at_t if p == 2 else (layer,) + at_t],
+                    plane.at[at_t if p == 2 else
+                             (0 if sliced else layer,) + at_t],
                     scbuf.at[w, buf, p], sem.at[2 + p, w, buf]))
         return out
 
@@ -410,29 +431,38 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def decode_attention(
-    q: jnp.ndarray,           # [B, n_q_heads, D] (single decode position)
+    q: jnp.ndarray,           # [B, n_q_heads, D] (single decode position),
+                              # or [B, S, n_q_heads, D]: S that share keys
     k_cache: jnp.ndarray,     # [L, B, T, K, D] FULL cache (bf16/f32 or int8);
     v_cache: jnp.ndarray,     # heads of 64 pair-folded: [L, B, T, K / 2, 128]
     layer: jnp.ndarray,       # scalar int32: which layer's cache to read
     kv_length: jnp.ndarray,   # [B] int32 valid entries (incl. current token)
     k_scale: jnp.ndarray | None = None,  # [L, B, K, T] f32 (int8 caches;
-    v_scale: jnp.ndarray | None = None,  # position minor — tile-friendly)
+    v_scale: jnp.ndarray | None = None,  # position minor — tile-friendly),
+                                         # or [1, B, K, T]: `layer`'s own
     keep: jnp.ndarray | None = None,     # [B, T] bool: the positions each
     *,                                   # slot's query selected
     window: int | None = None,  # sliding-window span (mistral); bounds the
                                 # per-slot block range below AND above
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Returns [B, n_q_heads, D] in q's dtype."""
+    """Returns q's shape in q's dtype. Every one of a slot's S positions
+    attends to the slot's keys below `kv_length`, its own block's among
+    them (the caller wrote them): there is no mask a position, so no
+    `window` and no `keep` with S > 1."""
     L, B, T, K, D = k_cache.shape     # K x D: a cache row as it lies
-    nq, head_dim = q.shape[1:]
+    queries = 1 if q.ndim == 3 else q.shape[1]
+    nq, head_dim = q.shape[-2:]
     fold = D // head_dim              # heads a lane row: 2 for heads of 64
     group = nq // (K * fold)
     kv_bytes = k_cache.dtype.itemsize
-    tiles = geometry(B, T, K * fold, head_dim, kv_bytes)
+    tiles = geometry(B, T, K * fold, head_dim, kv_bytes, queries)
     if tiles is None:
         raise ValueError(f"no decode-attention geometry for a {B} x {T} "
                          f"cache of {K * fold} KV heads of {head_dim}")
+    if q.ndim == 4 and (window is not None or keep is not None):
+        raise ValueError("a window or a selection is a mask a position: "
+                         "a block of queries shares one key set")
     slot_tile, block_t = tiles
     heads, n_kv = _lanes(K, kv_bytes)
     lanes, tile = B * heads, slot_tile * heads
@@ -448,12 +478,18 @@ def decode_attention(
     rows = block_t * n_kv
     ways = min(WAYS, tile)
 
-    # A lane's query rows ordered (group, head) and padded to whole slabs:
-    # row r of the kernel's q and output belongs to the lane's head
-    # r % n_kv.
-    nql = group * n_kv * fold
+    # A lane's query rows ordered (position, group, head) and padded to
+    # whole slabs: row r of the kernel's q and output belongs to the
+    # lane's head r % n_kv.
+    nql = queries * group * n_kv * fold
     nqp = -(-nql // slab) * slab
-    qk = jnp.swapaxes(q.reshape(lanes, n_kv * fold, group, head_dim), 1, 2)
+    if q.ndim == 3:
+        qk = jnp.swapaxes(q.reshape(lanes, n_kv * fold, group, head_dim),
+                          1, 2)
+    else:
+        by_lane = (B, heads, queries, group, n_kv * fold, head_dim)
+        qk = jnp.transpose(q.reshape(B, queries, heads, n_kv * fold, group,
+                                     head_dim), (0, 2, 1, 4, 3, 5))
     if fold > 1:
         # head 2p + s of a pair: its values in half s of the lane row,
         # zeros in the sibling's
@@ -507,7 +543,8 @@ def decode_attention(
                           capacity=T, heads=heads, n_kv=n_kv, fold=fold,
                           slab=slab, ways=ways,
                           quantized=quantized, window=window,
-                          compute_dtype=compute_dtype, masked=masked),
+                          compute_dtype=compute_dtype, masked=masked,
+                          sliced=quantized and k_scale.shape[0] != L),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # kv_length, layer
             grid=(B // slot_tile,),
@@ -524,8 +561,11 @@ def decode_attention(
         out = sum(jnp.where(half == s,
                             out[..., s * head_dim:(s + 1) * head_dim], 0)
                   for s in range(fold))
-    out = out.reshape(lanes, group, n_kv * fold, head_dim)
-    return jnp.swapaxes(out, 1, 2).reshape(B, nq, head_dim)
+    if q.ndim == 3:
+        out = out.reshape(lanes, group, n_kv * fold, head_dim)
+        return jnp.swapaxes(out, 1, 2).reshape(B, nq, head_dim)
+    return jnp.transpose(out.reshape(by_lane),
+                         (0, 2, 1, 4, 3, 5)).reshape(q.shape)
 
 
 # A sharded trunk takes the kernel only from here up: the gate the old

@@ -159,6 +159,53 @@ def test_blocks_through_the_cache_match_reference(params):
     assert int(cache.lengths[0]) == 20
 
 
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_a_block_takes_the_decode_kernel_where_the_cache_has_a_geometry(
+        monkeypatch, quantized):
+    """The tiny preset with a head that is a lane tile, over 128 positions:
+    `attention_paths` reports the kernel with the block as its q tile, a
+    block's forwards over the cache take it (the prompt's 12 positions, no
+    block, do not), and the logits are the XLA route's."""
+    import dataclasses
+
+    from symmetry_tpu.ops import decode_attention as da
+
+    cfg = dataclasses.replace(CFG, head_dim=128)
+    params = llama.init_params(cfg, jax.random.key(5), jnp.float32)
+    paths = llama.attention_paths(cfg, 128, batch=2,
+                                  kv_bytes=1 if quantized else 4)
+    assert (paths["decode"], paths["decode_queries"]) == (
+        "pallas-interpret", BLOCK)
+    assert llama.attention_paths(cfg, 64 + BLOCK, batch=2, kv_bytes=4)[
+        "decode"] == "xla"   # an admission's scratch: no multiple of 128
+    taken = []
+    kernel = da.decode_attention
+    monkeypatch.setattr(
+        da, "decode_attention",
+        lambda q, *a, **kw: taken.append(q.shape) or kernel(q, *a, **kw))
+
+    def run():
+        cache = llama.init_cache(cfg, 2, 128, jnp.float32,
+                                 quantized=quantized)
+        _, cache = llama.forward(
+            params, cfg, jnp.asarray([ids_of(12), ids_of(12, key=1)]), cache)
+        out = []
+        for b in range(3):
+            block = jnp.asarray([ids_of(BLOCK, key=2 + b),
+                                 [MASK, 7, MASK, MASK]])
+            logits, cache = llama.forward(params, cfg, block, cache)
+            out.append(logits)
+        return jnp.stack(out)
+
+    got = run()
+    # (the layer scan traces its body once a forward)
+    assert taken == [(2, BLOCK, cfg.num_heads, 128)] * 3
+    monkeypatch.setattr(da, "geometry", lambda *a: None)
+    want = run()
+    assert len(taken) == 3    # the XLA route this time
+    np.testing.assert_allclose(got, want, atol=2e-4 if quantized else ATOL)
+
+
 # ---------------------------------------------------------------------------
 # candidates, confidence and the choice of positions
 
@@ -367,7 +414,10 @@ def test_one_position_a_step_is_the_default(params):
 def test_startup_reports(params, engines):
     engine = engines["static"]
     paths = engine.attention_paths()
-    assert paths["decode"] == "xla" and "one-query" in paths["decode_why"]
+    # a head of 16 is no lane tile: the block's forwards keep the XLA route
+    # by shape, over the slots' cache and the admission's scratch alike
+    assert paths["decode"] == "xla" and "head of 16" in paths["decode_why"]
+    assert paths["opening_block"] == "xla" and "opening_block_why" in paths
     report = engine.diffusion_report()
     assert report["forwards_per_dispatch"] == 6
     assert report["programs"] == {"prefill": "bd_prefill",

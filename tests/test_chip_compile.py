@@ -71,6 +71,10 @@ CASES = {
     # (models/llama.py kv_row): as written XLA pads the 64 to a lane tile
     "lfm2-8b-a1b cell": (6, 128, 640, 8, 32, jnp.int8, None, 64),
     "8 KV heads of 64 bf16": (6, 128, 640, 8, 32, jnp.bfloat16, None, 64),
+    # a block of 4 query positions a slot (a ninth entry): 128 rows a slot,
+    # q and the output in tiles of 32 slots
+    "sdar-30b-a3b-chat cell": (12, 128, 640, 4, 32, jnp.int8, None, 128, 4),
+    "4 queries at 8 x 4096": (12, 8, 4096, 4, 32, jnp.int8, None, 128, 4),
 }
 
 
@@ -87,12 +91,12 @@ def whole_cache_copies(text: str) -> list[str]:
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_kernel_compiles_at_served_shapes(one_chip, no_cache, case):
-    L, B, T, K, nq, dtype, window, D = CASES[case]
+    L, B, T, K, nq, dtype, window, D, *queries = CASES[case]
 
     def shape(dims, dt):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
 
-    q = shape((B, nq, D), jnp.bfloat16)
+    q = shape((B, *queries, nq, D), jnp.bfloat16)
     kv = shape((L, B, T, K * D // 128, 128) if D == 64 else (L, B, T, K, D),
                dtype)
     scale = shape((L, B, K, T), jnp.float32) if dtype == jnp.int8 else None
@@ -709,8 +713,9 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
     positions (a scan of blocks around a loop of forwards around the scan
     of layers). The 1 GB cache rides every loop's carry: no level of the
     nesting may copy or relay it, the experts run the grouped-matmul kernel
-    (a forward routes 4,096 pairs), and no decode-attention kernel is in it
-    (a block of queries takes the XLA route)."""
+    (a forward routes 4,096 pairs), and attention is the decode kernel with
+    the block of queries as its q tile: no layer's K or V is sliced out of
+    the cache, staged or relaid (the XLA route's 42 MB each a layer)."""
     import importlib.util
 
     from symmetry_tpu.engine import engine as eng_mod
@@ -754,8 +759,15 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
                  and "kind=kCustom" not in line)]   # the in-place scatters
     assert not moved, moved[0]
     assert len(re.findall(whole + r"fusion\(", text)) == 8  # k, v, 2 scales
-    # (what the XLA route does read a layer: a [1, 128, 640, 4, 128] slice
-    # of K and of V, staged and relaid — the four-query kernel's to remove)
+    # ... and nothing yields ONE layer's K or V (what the XLA route read: a
+    # [1, 128, 640, 4, 128] slice of each, staged and relaid, whatever the
+    # slots' fill): the kernel reads each slot's live blocks where they lie
+    layer = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= s8\[(1,)?128,640,4,128\]", line)]
+    assert not layer, layer[0]
+    # two forwards' trunks (the denoise loop's and the commit's), a call a
+    # scan body each
+    assert len(re.findall(r"%decode_attention[.\d]* = ", text)) == 2
     # two forwards' trunks (the denoise loop's and the commit's), three
     # grouped matmuls a layer each, compiled once a scan body
     assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 6
@@ -765,7 +777,6 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
     sliced = [line.strip()[:160] for line in text.splitlines()
               if re.search(r"= s8\[(1,)?128,(2048,768|768,2048)\]", line)]
     assert not sliced, sliced[0]
-    assert "decode_attention" not in text
     # weights 8.4 GB and the cache 1.04 GB are arguments; what the program
     # adds (logits of [512, 151936] and the sampler's windows) stays small
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30

@@ -48,6 +48,15 @@ def reference(q, k_layer, v_layer, lengths, k_scale=None, v_scale=None):
     return out[:, 0]
 
 
+def block_reference(q, k_layer, v_layer, lengths, k_scale=None, v_scale=None):
+    """q [B, S, nq, D] at each slot's block boundary `lengths - S`: the
+    XLA route under the block mask."""
+    S = q.shape[1]
+    positions = (lengths - S)[:, None] + jnp.arange(S)[None]
+    return gqa_attention(q, k_layer, v_layer, positions, lengths,
+                         k_scale=k_scale, v_scale=v_scale, block_len=S)
+
+
 def rows(x):
     """K/V as the cache holds it (models/llama.py kv_row): heads of 64 in
     pairs, [..., K / 2, 128]; any other head as it is."""
@@ -57,6 +66,35 @@ def rows(x):
 def to_minor(scale):
     """quantize_kv emits [L, B, T, K]; caches store position-minor [L, B, K, T]."""
     return jnp.moveaxis(scale, -1, -2)
+
+
+GEOMETRIES = [
+    (128, 640, 8, (128, 128)),       # mistral-7b's cell
+    (128, 640, 4, (128, 256)),       # qwen2-7b's: 2.5 blocks a slot
+    (64, 2048, 2, (64, 1024)),       # head-major lanes: 128 a grid step
+    (128, 640, 2, (64, 640)),
+    (8, 4096, 8, (8, 128)),
+    (8, 4096, 1, (8, 1024)),
+    (8, 256, 2, (8, 256)),           # never over the capacity
+    (192, 640, 16, (96, 128)),       # never under a lane tile;
+    (7, 1024, 8, (7, 128)),          # two grid steps of 96 slots
+    (128, 672, 8, None),
+    (128, 8192, 8, (16, 128)),       # the work lists stay in SMEM:
+    (128, 32768, 8, (4, 128)),       # 1,024 (slot, block) items a list
+    (8, 4096, 3, None),              # heads that tile nothing
+    (8, 4096, 12, None),
+    (8, 4096, 16, (8, 128)),
+    # (heads, head size, bytes): heads of 64 lie in pairs of 128 lanes
+    (128, 640, (8, 64, 1), (128, 256)),   # lfm2-8b-a1b's cell: qwen2's
+    (128, 640, (8, 64, 2), (128, 256)),   # items; bf16 alike
+    (128, 640, (16, 64, 1), (128, 128)),
+    (8, 4096, (4, 64, 2), (8, 512)),      # two bf16 pairs interleave
+    (8, 4096, (4, 64, 1), None),     # two int8 pairs lie head-major
+    (128, 640, (7, 64, 1), None),    # an odd count makes no pairs
+    (128, 640, (2, 64, 1), None),    # one pair: a row a slab has no form
+    (8, 256, (2, 16, 1), None),      # the tiny configurations' head
+    (8, 256, (8, 32, 1), None),
+]
 
 
 class TestDecodeAttentionKernel:
@@ -137,33 +175,7 @@ class TestDecodeAttentionKernel:
         assert geometry(8, 4096, 2, kv_bytes=2) == (8, 512)
         assert da._lanes(2, 1) == (2, 1) and da._lanes(2, 2) == (1, 2)
 
-    @pytest.mark.parametrize("batch, capacity, n_kv, want", [
-        (128, 640, 8, (128, 128)),       # mistral-7b's cell
-        (128, 640, 4, (128, 256)),       # qwen2-7b's: 2.5 blocks a slot
-        (64, 2048, 2, (64, 1024)),       # head-major lanes: 128 a grid step
-        (128, 640, 2, (64, 640)),
-        (8, 4096, 8, (8, 128)),
-        (8, 4096, 1, (8, 1024)),
-        (8, 256, 2, (8, 256)),           # never over the capacity
-        (192, 640, 16, (96, 128)),       # never under a lane tile;
-        (7, 1024, 8, (7, 128)),          # two grid steps of 96 slots
-        (128, 672, 8, None),
-        (128, 8192, 8, (16, 128)),       # the work lists stay in SMEM:
-        (128, 32768, 8, (4, 128)),       # 1,024 (slot, block) items a list
-        (8, 4096, 3, None),              # heads that tile nothing
-        (8, 4096, 12, None),
-        (8, 4096, 16, (8, 128)),
-        # (heads, head size, bytes): heads of 64 lie in pairs of 128 lanes
-        (128, 640, (8, 64, 1), (128, 256)),   # lfm2-8b-a1b's cell: qwen2's
-        (128, 640, (8, 64, 2), (128, 256)),   # items; bf16 alike
-        (128, 640, (16, 64, 1), (128, 128)),
-        (8, 4096, (4, 64, 2), (8, 512)),      # two bf16 pairs interleave
-        (8, 4096, (4, 64, 1), None),     # two int8 pairs lie head-major
-        (128, 640, (7, 64, 1), None),    # an odd count makes no pairs
-        (128, 640, (2, 64, 1), None),    # one pair: a row a slab has no form
-        (8, 256, (2, 16, 1), None),      # the tiny configurations' head
-        (8, 256, (8, 32, 1), None),
-    ])
+    @pytest.mark.parametrize("batch, capacity, n_kv, want", GEOMETRIES)
     def test_geometry_follows_the_cache_shape(self, batch, capacity, n_kv,
                                               want):
         assert geometry(batch, capacity, *np.atleast_1d(n_kv)) == want
@@ -227,12 +239,14 @@ LENGTHS_640 = [0, 1, 127, 128, 129, 640]
 
 
 def case_640(K, G, quantized, dtype=jnp.bfloat16, seed=0, B=6, T=640,
-             D=128):
+             D=128, S=None):
     """One layer pair of a 6-slot x 640 cache at the dense cells' head
     shapes, lengths mixed in the batch; scales position-minor. K and V as
-    [.., K, D]: `rows` makes the cache's form of a head of 64."""
+    [.., K, D]: `rows` makes the cache's form of a head of 64. `S`: a
+    block of that many query positions a slot."""
     ks = jax.random.split(jax.random.key(seed), 3)
-    q = jax.random.normal(ks[0], (B, K * G, D), dtype)
+    q = jax.random.normal(
+        ks[0], (B, K * G, D) if S is None else (B, S, K * G, D), dtype)
     k = jax.random.normal(ks[1], (2, B, T, K, D), jnp.float32)
     v = jax.random.normal(ks[2], (2, B, T, K, D), jnp.float32)
     if not quantized:
@@ -270,15 +284,16 @@ class TestCellShapes:
 
     @pytest.mark.parametrize("quantized", [False, True],
                              ids=["bf16", "int8"])
-    @pytest.mark.parametrize("K, G, D", [(8, 4, 128), (4, 7, 128),
-                                         (2, 4, 128), (8, 4, 64)])
-    def test_a_slot_does_not_see_its_neighbours(self, tiled, K, G, D,
+    @pytest.mark.parametrize("K, G, D, S", [
+        (8, 4, 128, None), (4, 7, 128, None), (2, 4, 128, None),
+        (8, 4, 64, None), (4, 8, 128, 4), (8, 4, 128, 4)])
+    def test_a_slot_does_not_see_its_neighbours(self, tiled, K, G, D, S,
                                                 quantized):
         """check_correct compares two identical greedy requests that land
         in different slots beside different neighbours: a slot's result
         must be bit-identical whatever the others' lengths, wherever the
-        tile boundaries fall."""
-        q, k, v, scales = case_640(K, G, quantized, seed=2, D=D)
+        tile boundaries fall — one query a slot or a block of four."""
+        q, k, v, scales = case_640(K, G, quantized, seed=2, D=D, S=S)
         k, v = rows(k), rows(v)
         mine, slot = 300, 2
         outs = []
@@ -287,7 +302,7 @@ class TestCellShapes:
                                   ([128, 640, 640, 640, 127], 3),
                                   ([5, 257, 384, 1, 640], 1)):
             lengths = others[:slot] + [mine] + others[slot:]
-            got = tiled(lanes=slot_tile)(
+            got = tiled(lanes=slot_tile * (S or 1))(
                 q, k, v, jnp.int32(0), jnp.asarray(lengths, jnp.int32),
                 *scales, interpret=True)
             outs.append(np.asarray(got[slot], np.float32))
@@ -388,6 +403,121 @@ class TestCellShapes:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    rtol=2e-2, atol=2e-2)
+
+
+# block boundaries a slot (the length is the block's END, base + 4): an
+# empty slot's first block, a block of 256 just filled, a capacity's last
+BASES_640 = [0, 4, 124, 252, 256, 636]
+
+
+class TestBlockOfQueries:
+    """S query positions a slot that share the slot's keys — a
+    block-diffusion forward over the cache from a block boundary — against
+    `gqa_attention(block_len=S)`: sdar-30b-a3b-chat's heads (4 KV x 8) and
+    mistral's (8 KV x 4) at the cells' capacity, which the block of 256
+    does not divide."""
+
+    @pytest.mark.parametrize("slot_tile", [1, 2, 6])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("K, G", [(4, 8), (8, 4)])
+    def test_matches_gqa_attention_under_the_block_mask(
+            self, tiled, K, G, quantized, slot_tile):
+        q, k, v, scales = case_640(K, G, quantized, seed=8, S=4)
+        lengths = jnp.asarray(BASES_640, jnp.int32) + 4
+        got = tiled(lanes=4 * slot_tile)(q, k, v, jnp.int32(1), lengths,
+                                         *scales, interpret=True)
+        assert got.shape == q.shape and got.dtype == q.dtype
+        want = block_reference(q, k[1], v[1], lengths,
+                               *(s[1] for s in scales))
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+    @pytest.mark.parametrize("K", [4, 8])
+    def test_float32_to_rounding_with_an_empty_slot(self, K):
+        """float32 end to end: the same mathematics to 2e-5. A slot with
+        nothing valid (length 0: a parked lane) is garbage by contract and
+        finite by construction; every row of a live slot is written."""
+        q, k, v, _ = case_640(K, 32 // K, False, jnp.float32, seed=9, S=4)
+        lengths = jnp.asarray([0, 4, 128, 256, 640, 0], jnp.int32)
+        got = decode_attention(q, k, v, jnp.int32(0), lengths,
+                               interpret=True)
+        assert np.isfinite(np.asarray(got)).all()
+        want = block_reference(q, k[0], v[0], jnp.maximum(lengths, 4))
+        np.testing.assert_allclose(np.asarray(got)[1:5],
+                                   np.asarray(want)[1:5],
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_one_layers_scale_planes(self):
+        """The planes may come as the caller's slice of `layer`
+        ([1, B, K, T]: models/llama.py hands a block's forwards that):
+        the same bits as reading them out of the whole arrays."""
+        q, k, v, scales = case_640(4, 8, True, seed=10, S=4)
+        lengths = jnp.asarray(BASES_640, jnp.int32) + 4
+        whole = decode_attention(q, k, v, jnp.int32(1), lengths, *scales,
+                                 interpret=True)
+        sliced = decode_attention(q, k, v, jnp.int32(1), lengths,
+                                  *(s[1:2] for s in scales), interpret=True)
+        np.testing.assert_array_equal(np.asarray(whole, np.float32),
+                                      np.asarray(sliced, np.float32))
+
+    def test_one_position_is_the_one_query_form(self):
+        """S = 1 through the four-axis q is today's program's result."""
+        q, k, v, scales = case_640(4, 7, True, seed=11)
+        lengths = jnp.asarray(LENGTHS_640, jnp.int32)
+        one = decode_attention(q, k, v, jnp.int32(0), lengths, *scales,
+                               interpret=True)
+        four_axes = decode_attention(q[:, None], k, v, jnp.int32(0), lengths,
+                                     *scales, interpret=True)
+        np.testing.assert_array_equal(np.asarray(one, np.float32),
+                                      np.asarray(four_axes[:, 0], np.float32))
+
+    def test_a_mask_a_position_is_refused(self):
+        """No causal multi-position kernel: a window or a selection tells
+        a slot's rows apart, and keeps `gqa_attention`."""
+        q, k, v, scales = case_640(8, 4, True, S=4)
+        lengths = jnp.full((6,), 64, jnp.int32)
+        with pytest.raises(ValueError, match="a mask a position"):
+            decode_attention(q, k, v, jnp.int32(0), lengths, *scales,
+                             window=32, interpret=True)
+        with pytest.raises(ValueError, match="a mask a position"):
+            decode_attention(q, k, v, jnp.int32(0), lengths, *scales,
+                             jnp.ones((6, 640), bool), interpret=True)
+
+    @pytest.mark.parametrize("batch, capacity, n_kv, want", [
+        (128, 640, 4, (32, 256)),        # sdar-30b-a3b-chat's cell
+        (128, 640, 8, (32, 128)),
+        (8, 4096, 4, (8, 256)),          # the smoke's shape
+        (128, 8192, 8, (16, 128)),       # the work lists bind first
+        (64, 2048, 2, (16, 1024)),       # head-major lanes: 32 a grid step
+        (6, 640, 4, (6, 256)),
+        (128, 672, 4, None),
+    ])
+    def test_geometry_of_four_queries(self, batch, capacity, n_kv, want):
+        assert geometry(batch, capacity, n_kv, queries=4) == want
+
+
+def test_one_query_takes_todays_tiles_and_four_fit_the_vmem():
+    """`queries=1` is the default at every shape the file lists, and the q
+    and output tiles of four queries (double-buffered, beside the WAYS x
+    NBUF item buffers of K and V) stay inside the 16 MB a kernel may take
+    at every one of them that has a geometry — 32 query heads a position,
+    the served models' most, in bfloat16."""
+    for batch, capacity, n_kv, want in GEOMETRIES:
+        shape = tuple(np.atleast_1d(n_kv))
+        assert geometry(batch, capacity, *shape, queries=1) == want
+        tiles = geometry(batch, capacity, *shape, queries=4)
+        assert (tiles is None) == (want is None)
+        if tiles is None:
+            continue
+        heads, head_dim, kv_bytes = (shape + (128, 1))[:3]
+        assert tiles[1] == want[1] and want[0] % tiles[0] == 0
+        tile_bytes = tiles[0] * 4 * 32 * max(head_dim, 128) * 2
+        items = 2 * da.WAYS * da.NBUF * min(
+            da.MAX_ITEM_BYTES,
+            tiles[1] * heads * max(head_dim, 128) * kv_bytes)
+        assert 2 * 2 * tile_bytes + items <= 2**24, (batch, capacity, n_kv)
 
 
 # (query heads, KV heads, head size): 2 heads of 128; 8 of 64, which the

@@ -10,7 +10,13 @@ once through ops/decode_attention.py as `_layer` routes it, once with the
 route forced to "xla" (the staged per-layer slice the kernel replaced).
 The forcing lives here, not in the program: the served path has no switch.
 
-Needs a TPU: `python tools/ab_ragged_640.py [preset ...]`. Writes
+A preset that generates by diffusion over blocks (sdar-30b-a3b-chat) is
+measured at what ITS forwards over the cache carry: a block of query
+positions a slot from a block boundary (lengths rounded down to one), the
+kernel against `gqa_attention(block_len=)`; `--tiles` also times the kernel
+at other WAYS / MAX_TILE_LANES (set here, for this process alone).
+
+Needs a TPU: `python tools/ab_ragged_640.py [--tiles] [preset ...]`. Writes
 chiprun_out/ab_ragged_640.json.
 """
 import json
@@ -31,6 +37,7 @@ from symmetry_tpu.ops.interpret import interpret_mode
 
 B, T = 128, 640
 OCCUPANCIES = (64, 173, 320, 620)
+TILES = (da.WAYS, da.MAX_TILE_LANES)
 
 
 def cell_mix() -> np.ndarray:
@@ -43,23 +50,36 @@ def cell_mix() -> np.ndarray:
     return lengths
 
 
+def block_of(cfg) -> int:
+    """Query positions a slot in a forward over the cache."""
+    diffusion = getattr(cfg, "diffusion", None)
+    return 1 if diffusion is None else diffusion.block
+
+
 def parity(cfg) -> dict:
     """Worst |kernel - gqa_attention| on one layer, lengths mixed."""
     K, nq, D = cfg.num_kv_heads, cfg.num_heads, cfg.dim_per_head
+    S = block_of(cfg)
     ks = jax.random.split(jax.random.key(1), 5)
-    q = jax.random.normal(ks[0], (B, nq, D), jnp.bfloat16)
+    q = jax.random.normal(ks[0], (B, S, nq, D), jnp.bfloat16)
     k = jax.random.randint(ks[1], (2, B, T, K, D), -127, 128, jnp.int8)
     v = jax.random.randint(ks[2], (2, B, T, K, D), -127, 128, jnp.int8)
     ksc = jax.random.uniform(ks[3], (2, B, K, T), jnp.float32, 0.005, 0.02)
     vsc = jax.random.uniform(ks[4], (2, B, K, T), jnp.float32, 0.005, 0.02)
-    lengths = jnp.asarray(np.resize([0, 1, 127, 128, 129, 640], B), jnp.int32)
-    got = da.decode_attention(q, k, v, jnp.int32(1), lengths, ksc, vsc,
-                              window=cfg.sliding_window,
+    lengths = np.resize([0, 1, 127, 128, 129, 640], B)
+    if S > 1:   # the end of a block that starts on a block boundary
+        lengths = np.where(lengths > 0, np.maximum(lengths // S * S, S), 0)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    got = da.decode_attention(q[:, 0] if S == 1 else q, k, v, jnp.int32(1),
+                              lengths, ksc, vsc, window=cfg.sliding_window,
                               interpret=interpret_mode())
-    want = gqa_attention(q[:, None], k[1], v[1],
-                         jnp.maximum(lengths - 1, 0)[:, None], lengths,
+    got = got.reshape(q.shape)
+    want = gqa_attention(q, k[1], v[1],
+                         jnp.maximum(lengths - S, 0)[:, None]
+                         + jnp.arange(S)[None], lengths,
                          sliding_window=cfg.sliding_window,
-                         k_scale=ksc[1], v_scale=vsc[1])[:, 0]
+                         k_scale=ksc[1], v_scale=vsc[1],
+                         **({"block_len": S} if S > 1 else {}))
     live = np.asarray(lengths) > 0
     err = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
     return {"max_abs_err": float(err[live].max()),
@@ -81,7 +101,7 @@ def make_trunk(cfg, params, use_kernel: bool):
 
         trunk = jax.jit(step, donate_argnums=(2,))
         cache = llama.init_cache(cfg, B, T, jnp.bfloat16, quantized=True)
-        tok = jnp.ones((B, 1), jnp.int32)
+        tok = jnp.ones((B, block_of(cfg)), jnp.int32)
         h, cache = trunk(params, tok, cache, jnp.zeros((B,), jnp.int32))
         sync(h)
     finally:
@@ -104,26 +124,36 @@ def make_trunk(cfg, params, use_kernel: bool):
 
 def main() -> None:
     report = {"device": jax.devices()[0].device_kind, "shape": [B, T]}
-    for name in sys.argv[1:] or ("mistral-7b", "qwen2-7b"):
+    names = [a for a in sys.argv[1:] if a != "--tiles"]
+    for name in names or ("mistral-7b", "qwen2-7b"):
         cfg = llama.preset(name)
-        row = {"geometry": da.geometry(B, T, cfg.num_kv_heads),
-               "parity": parity(cfg),
-               "trunk_ms": {}}
+        S = block_of(cfg)
+        row = {"geometry": da.geometry(B, T, cfg.num_kv_heads, queries=S),
+               "queries": S, "parity": parity(cfg), "trunk_ms": {}}
         print(name, "parity", row["parity"], flush=True)
         params = llama.init_params(cfg, jax.random.key(0), jnp.bfloat16,
                                    quantize=True)
-        cases = {str(o): np.full(B, o, np.int32) for o in OCCUPANCIES}
-        cases["cell-mix"] = cell_mix()
-        for route in ("xla", "kernel"):  # one cache on the chip at a time
-            timed = make_trunk(cfg, params, route == "kernel")
+        cases = {str(o): np.full(B, o // S * S, np.int32)
+                 for o in OCCUPANCIES}
+        cases["cell-mix"] = cell_mix() // S * S
+        # (route, WAYS, MAX_TILE_LANES): the served constants, then others
+        routes = [("xla",) + TILES, ("kernel",) + TILES]
+        if "--tiles" in sys.argv:
+            routes += [(f"kernel ways {w} lanes {n}", w, n)
+                       for w, n in ((2, 128), (1, 128), (4, 256), (2, 256))]
+        for route, da.WAYS, da.MAX_TILE_LANES in routes:
+            jax.clear_caches()   # the constants are read when traced
+            # one cache on the chip at a time
+            timed = make_trunk(cfg, params, route != "xla")
             for label, lengths in cases.items():
                 row["trunk_ms"].setdefault(label, {})[route] = round(
                     timed(lengths), 2)
             del timed
+        da.WAYS, da.MAX_TILE_LANES = TILES
         for label, ms in row["trunk_ms"].items():
-            print(f"{name} {label:>8} of 640: xla {ms['xla']:6.2f} ms  "
-                  f"kernel {ms['kernel']:6.2f} ms  "
-                  f"({ms['xla'] - ms['kernel']:+.2f})", flush=True)
+            print(f"{name} {label:>8} of 640: " + "  ".join(
+                f"{route} {t:6.2f} ms" for route, t in ms.items())
+                + f"  ({ms['xla'] - ms['kernel']:+.2f})", flush=True)
         report[name] = row
         del params
         os.makedirs("chiprun_out", exist_ok=True)

@@ -22,7 +22,9 @@ program's own choices are tapped where they are made, through `jax.debug.callbac
 and its final hidden states (`forward_hidden`), the logits, candidates and
 confidences (`diffusion_candidates`), the known plane and what was taken
 (`diffusion_unmask`), every layer's attention output over the cache
-(`gqa_attention`) and the prefill's (`flash_prefill`). Each tapped forward is
+(`decode_attention` with the block as its q tile where the cache has a
+geometry, `gqa_attention` elsewhere: the admission's scratch, a tiny head)
+and the prefill's (`flash_prefill`). Each tapped forward is
 TEACHER-FORCED into the reference: its full forward over [the lane's committed
 context || the block as it stood] under the block mask.
 
@@ -205,7 +207,7 @@ def main() -> int:
     from symmetry_tpu.engine import engine as eng_mod
     from symmetry_tpu.engine.tokenizer import get_tokenizer
     from symmetry_tpu.models import llama
-    from symmetry_tpu.ops import flash
+    from symmetry_tpu.ops import decode_attention, flash
     from symmetry_tpu.ops.quant import QuantizedTensor
 
     t0 = time.monotonic()
@@ -262,9 +264,15 @@ def main() -> int:
         return take
 
     orig_gqa, orig_flash = llama.gqa_attention, flash.flash_prefill
+    orig_decode = decode_attention.decode_attention
 
     def gqa(*a, **kw):
         out = orig_gqa(*a, **kw)
+        jax.debug.callback(record("gqa"), rows_of(out), ordered=True)
+        return out
+
+    def decode(*a, **kw):  # a block's forwards where the cache has tiles
+        out = orig_decode(*a, **kw)
         jax.debug.callback(record("gqa"), rows_of(out), ordered=True)
         return out
 
@@ -277,6 +285,7 @@ def main() -> int:
     eng_mod.diffusion_candidates = candidates
     eng_mod.diffusion_unmask = unmask
     llama.gqa_attention, flash.flash_prefill = gqa, flash_prefill
+    decode_attention.decode_attention = decode
 
     engine = eng_mod.InferenceEngine(
         cfg, params, get_tokenizer(None, vocab_size=cfg.vocab_size),

@@ -27,7 +27,9 @@ four trunks of the two one-device homogeneous expert presets differ:
 sdar-30b-a3b-chat's `decode_block` and `prefill` and keye-vl-2.0-30b-a3b's
 `prefill` lose the layer's `[1, 128, ...]` slices before their kernels;
 keye's `decode_block`, a dense mixture, differs in the ORDER of its scan's
-operands alone — the stacks ride it as constants it never reads.)
+operands alone — the stacks ride it as constants it never reads. PR 50, a
+block of queries a slot through the decode kernel: 23 of the 24 identical,
+sdar-30b-a3b-chat's `decode_block` the one that differs.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
