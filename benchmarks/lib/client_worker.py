@@ -1,6 +1,6 @@
 """One shard of the benchmark's client fleet, in a process of its own.
 
-A copy of the shape of `bench.py`'s `run_e2e_client_worker` (sharding the
+A shard is an OS process of its own, not a task in the parent (sharding the
 fleet over OS processes keeps the client event loop out of the measured
 tails), rebuilt for schedules: closed-loop clients that cycle through a
 request list, and an open-loop schedule multiplexed over a pool of sessions.

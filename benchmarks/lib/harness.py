@@ -1,7 +1,7 @@
 """Start the system under test, drive a cell's traffic at it, collect what
 the run left: client records, stats samples, the device trace.
 
-The served path is the one `bench.py run_e2e` and `chip_smoke.py` drive (an
+The served path is the one `chip_smoke.py` drives (an
 in-process SymmetryServer, `python -m symmetry_tpu.provider` as its own OS
 process whose engine host is the only process that touches JAX, clients over
 TCP loopback with Noise on); the provider-config builder and the process

@@ -16,6 +16,14 @@ def gap_percentile_s(ctx, p: float = 99.0) -> float | None:
     return window.percentile(window.window_gaps(ph.records, ph.w0, ph.w1), p)
 
 
+def gap_band_mean_s(ctx, lo: float = 80.0, hi: float = 98.0) -> float | None:
+    """Mean of the window's gaps from their `lo`th percentile up to their
+    `hi`th (`lib/window.py band_mean`): the judged tail, `gap_tail_s`."""
+    ph = ctx.phase
+    return window.band_mean(window.window_gaps(ph.records, ph.w0, ph.w1),
+                            lo, hi)
+
+
 def ttft_percentile_s(ctx, p: float = 50.0) -> float | None:
     """From DUE time, over requests due in the window. A missing request
     sorts as infinitely late, so it raises the percentile instead of

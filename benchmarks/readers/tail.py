@@ -9,7 +9,8 @@ An INTERVAL is decode-block read to decode-block read (`t` to `t`), ending
 inside the window, never across an idle boundary (the later block's
 `caused_by` is the earlier one's `seq`) and never across a record the
 samples missed. The TAIL is the longest 5% of the window's intervals, at
-least 3: what `gap_p99_s` is made of on the engine's side.
+least 3: what the clients' gap tail (`gap_tail_s`, and `wire_gap_p99_s`
+above it) is made of on the engine's side.
 
 A program without `reads` (before PR 37) reads as None everywhere.
 `tools/read_tail.py` prints a run's tail, record by record, from a

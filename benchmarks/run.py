@@ -6,8 +6,10 @@ A new process each time. It starts the in-process SymmetryServer and the
 provider as its own OS process (whose engine host is the only process that
 touches JAX), connects the clients, runs the warm traffic, measures for
 `--seconds`, prints ONE JSON line last on stdout (`correct`, `attempted`,
-`failed`, `metrics`, `device`, and `breakdown` when traced), tears everything
-down and leaves no process behind. The line before it is the `setup_s` split.
+`failed`, `metrics`, `device`, `breakdown` when traced, and last `compared`:
+every number `correct` rests on beside its limit, which are also the last
+lines on stderr), tears everything down and leaves no process behind. The
+line before it is the `setup_s` split.
 
 It exits non-zero and prints no result when the engine host found no TPU
 (or fewer chips than the cell asks for), when a phase failed, or when the
@@ -104,41 +106,68 @@ def device_block(startup: dict, trace: dict | None) -> dict:
     return out
 
 
-def check_correct(phase: harness.Phase, probe_texts: list[str],
-                  probe_tokens: int) -> list[str]:
-    """Everything a run can show about the outputs being right. Returns the
-    reasons it is not; empty means correct."""
-    why = []
+def compare(phase: harness.Phase, probe_texts: list[str],
+            probe_tokens: int) -> list[tuple[str, float, float, str]]:
+    """Every number a run compares, as (name, value, limit, what it means
+    when the value is over the limit). Conservation and self-consistency:
+    what a run can show about the outputs being right without a second
+    forward pass (PERF.md §7 says what that leaves out)."""
     done = [r for r in phase.records if not r.get("error")]
+    short, empty, first_short = 0, 0, None
     for r in done:
         exact = r["tokens"] == r["max_new"]
-        # a sampled EOS ends a stream early, and says so
+        # a sampled EOS ends a stream early, and says so — as the FIRST
+        # token it leaves a stream of 0 tokens, which a seed in some tens
+        # draws once; many of them are a program that stopped answering
         stopped = (r["finish"] == "stop"
-                   and 0 < (r["tokens"] or 0) <= r["max_new"])
+                   and 0 <= (r["tokens"] or 0) <= r["max_new"])
+        if stopped and not r["tokens"]:
+            empty += 1
         if not (exact or stopped):
-            why.append(f"a stream asked for {r['max_new']} tokens and "
-                       f"delivered {r['tokens']} (finish {r['finish']})")
-            break
-    if any(r.get("t_done") is None for r in phase.records):
-        why.append("a stream was left open")
+            short += 1
+            first_short = first_short or r
+    out = [("short_streams", short, 0,
+            first_short and f"a stream asked for {first_short['max_new']} "
+            f"tokens and delivered {first_short['tokens']} (finish "
+            f"{first_short['finish']})"),
+           ("empty_stop_streams", empty, max(0, len(done) - 1) // 100,
+            f"{empty} of {len(done)} completed streams ended `stop` with 0 "
+            f"tokens (limit: under 1%)"),
+           ("open_streams",
+            sum(r.get("t_done") is None for r in phase.records), 0,
+            "a stream was left open")]
     wire = sum(r["tokens"] or 0 for r in done) + probe_tokens
     engine = phase.stats_end.get("engine") or {}
-    errored = len(phase.records) - len(done)
-    if errored == 0 and engine.get("tokens") != wire:
-        why.append(f"the wire carried {wire} tokens, the host counted "
-                   f"{engine.get('tokens')}")
-    if errored == 0 and phase.stats_end.get("tokens_out") != wire:
-        why.append(f"the wire carried {wire} tokens, the provider counted "
-                   f"{phase.stats_end.get('tokens_out')}")
+    if len(done) == len(phase.records):
+        for name, who, counted in (
+                ("wire_host_token_gap", "host", engine.get("tokens")),
+                ("wire_provider_token_gap", "provider",
+                 phase.stats_end.get("tokens_out"))):
+            out.append((name, abs(wire - (counted or 0)), 0,
+                        f"the wire carried {wire} tokens, the {who} "
+                        f"counted {counted}"))
     sup = engine.get("supervisor") or {}
-    if sup.get("restarts") or sup.get("respawn_failures"):
-        why.append(f"the supervisor respawned the host: {sup}")
-    if len(set(probe_texts)) != 1 or not probe_texts[0]:
-        why.append(f"two identical greedy requests differ: {probe_texts!r}")
-    if phase.stats_end.get("in_flight"):
-        why.append(f"{phase.stats_end['in_flight']} requests still in "
-                   f"flight after the drain")
-    return why
+    out.append(("host_respawns", (sup.get("restarts") or 0)
+                + (sup.get("respawn_failures") or 0), 0,
+                f"the supervisor respawned the host: {sup}"))
+    out.append(("greedy_probe_mismatch",
+                int(len(set(probe_texts)) != 1 or not probe_texts[0]), 0,
+                f"two identical greedy requests differ: {probe_texts!r}"))
+    out.append(("in_flight_after_drain",
+                phase.stats_end.get("in_flight") or 0, 0,
+                f"{phase.stats_end.get('in_flight')} requests still in "
+                f"flight after the drain"))
+    return out
+
+
+def over_limit(compared: list[tuple[str, float, float, str]]) -> list[str]:
+    """The reasons a run is not correct; empty means correct."""
+    return [text for _, value, limit, text in compared if value > limit]
+
+
+def check_correct(phase: harness.Phase, probe_texts: list[str],
+                  probe_tokens: int) -> list[str]:
+    return over_limit(compare(phase, probe_texts, probe_tokens))
 
 
 def refusal(device: dict, cell: harness.Cell) -> str | None:
@@ -198,12 +227,14 @@ async def run(args) -> tuple[dict, dict]:
             raise BenchFailure("the trace shows no device operation")
         setup_s = phase.w0 - T_PROCESS_START
         ctx = RunContext(cell, phase, setup_s, device, trace)
-        why = check_correct(phase, probe_texts, probe_tokens)
-        if serving.provider_rc != 0:
-            why.append(f"the provider exited with code "
-                       f"{serving.provider_rc} on drain")
-        if serving.orphans:
-            why.append(f"children outlived the provider: {serving.orphans}")
+        compared = compare(phase, probe_texts, probe_tokens) + [
+            ("provider_exit_code", 255 if serving.provider_rc is None
+             else abs(serving.provider_rc), 0,
+             f"the provider exited with code {serving.provider_rc} on "
+             f"drain"),
+            ("orphan_processes", len(serving.orphans), 0,
+             f"children outlived the provider: {serving.orphans}")]
+        why = over_limit(compared)
         for reason in why:
             log(f"NOT CORRECT: {reason}")
         due = window.due_in_window(phase.records, phase.w0, phase.w1)
@@ -227,6 +258,9 @@ async def run(args) -> tuple[dict, dict]:
                 "device_ops": [[n.replace(" ", "_"), s]
                                for n, s in trace["ops"][:10]],
                 "idle_gaps": trace["idle_gaps"][:5]}
+        # last in the line: every number compared, beside its limit
+        result["compared"] = {name: [value, limit]
+                              for name, value, limit, _ in compared}
         split = {"setup_s": setup_s, **serving.timings, **phase.timings,
                  "build_s": startup.get("build_s"),
                  "warmup_s": startup.get("warmup_s"),
@@ -247,7 +281,9 @@ async def run(args) -> tuple[dict, dict]:
             with open(os.path.join(
                     args.dump, f"{args.workload}.{args.seed}.json"),
                     "w") as fh:
-                json.dump({"w0": phase.w0, "w1": phase.w1,
+                json.dump({"workload": args.workload, "seed": args.seed,
+                           "setup_s": phase.w0 - T_PROCESS_START,
+                           "w0": phase.w0, "w1": phase.w1,
                            "records": phase.records,
                            "samples": phase.samples}, fh)
         serving.cleanup()
@@ -263,7 +299,8 @@ def main() -> int:
                     help="another BENCHMARK.json (the tests' tiny cells)")
     ap.add_argument("--dump", default=None,
                     help="write the run's client records and stats samples "
-                         "into this directory (for looking at a run)")
+                         "into this directory (for looking at a run: "
+                         "tools/read_tail.py, benchmarks/admit.py)")
     args = ap.parse_args()
     if not os.path.isdir(os.path.join(CHECKOUT, "symmetry_tpu")):
         print("benchmarks/run.py: the program under test (symmetry_tpu/) is "
@@ -274,6 +311,9 @@ def main() -> int:
     except BenchFailure as exc:
         print(f"benchmarks/run.py: FAIL: {exc}", file=sys.stderr)
         return 1
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared: {name} {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps({"setup_split": split}))
     print(json.dumps(result), flush=True)
     return 0

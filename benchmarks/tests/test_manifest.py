@@ -1,12 +1,13 @@
 """BENCHMARK.json, the data files and the program's presets agree."""
 
+import functools
 import json
 import os
 import re
 
 import pytest
 
-from conftest import BENCH, CHECKOUT
+from conftest import BENCH, CHECKOUT, cell_entries
 
 MANIFEST = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -79,7 +80,7 @@ def test_every_metric_has_its_reader_file(group, folder):
     for m in MANIFEST[group]:
         spec = json.load(open(os.path.join(BENCH, folder,
                                            m["name"] + ".json")))
-        for key in ("unit", "better", "source", "layer", "moves"):
+        for key in ("name", "unit", "better", "source", "layer", "moves"):
             if key in m:
                 assert spec[key] == m[key], (m["name"], key)
         module, func = spec["reader"].rsplit(".", 1)
@@ -87,19 +88,109 @@ def test_every_metric_has_its_reader_file(group, folder):
             importlib.import_module(f"readers.{module}"), func))
 
 
+@functools.lru_cache(maxsize=None)
+def reading(name):
+    """What an entry reads: its file's reader and parameters."""
+    spec = json.load(open(os.path.join(BENCH, "layer_metrics",
+                                       name + ".json")))
+    return spec["reader"], json.dumps(spec.get("params") or {},
+                                      sort_keys=True)
+
+
+def read_twice(manifest):
+    """(cell, entry, entry) wherever two entries over the same `(reader,
+    params)` reach one cell."""
+    found = []
+    for w in manifest["workloads"]:
+        seen = {}
+        for m in cell_entries(manifest, w["name"]):
+            other = seen.setdefault(reading(m["name"]), m)
+            if other is not m:
+                found.append((w["name"], other["name"], m["name"]))
+    return found
+
+
+CAP = 128   # the most `per_layer` entries a benchmark may have
+
+
+@pytest.mark.parametrize("group,folder", [("end_to_end", "end_to_end"),
+                                          ("per_layer", "layer_metrics")])
+def test_one_entry_a_file_and_one_file_an_entry(group, folder):
+    """Every entry has exactly one file and every file one entry (alike in
+    every key they share: the test above)."""
+    files = sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, folder))
+                   if f.endswith(".json"))
+    assert files == sorted(m["name"] for m in MANIFEST[group])
+
+
+def test_one_entry_a_reading_and_room_under_the_cap():
+    """The fold's rule (PR 52): NO CELL is reached by two entries over the
+    same `(reader, params)` — a copy of an entry that has no `workloads` key
+    always breaks that, a copy that lists a new cell beside an entry that
+    lists others never does — and `per_layer` keeps room for the next
+    configuration's own entries."""
+    free = CAP - len(MANIFEST["per_layer"])
+    assert free >= 0, f"per_layer is {-free} entries over the cap of {CAP}"
+    assert read_twice(MANIFEST) == [], f"({free} entries are free)"
+
+
+def test_every_entry_moves_a_metric_its_cells_report():
+    """An entry's `moves` names an end-to-end metric, and every cell the
+    entry reaches reports that metric."""
+    e2e = {m["name"]: m for m in MANIFEST["end_to_end"]}
+    cells = [w["name"] for w in MANIFEST["workloads"]]
+    for m in MANIFEST["per_layer"]:
+        target = e2e[m["moves"]]
+        if "workloads" in target:
+            assert set(m.get("workloads", cells)) <= set(
+                target["workloads"]), m["name"]
+
+
+def test_the_rule_catches_a_copy_and_lets_a_listed_one_pass():
+    """Not vacuous: a second entry over `stats.decode_step_ms` for one cell
+    (what PRs 28–47 added eight times, under a suffix) reaches that cell
+    twice; a second entry over a reader whose first LISTS its cells,
+    for a cell that list lacks, does not."""
+    def with_copy_of(name, cell):
+        entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
+        copy = dict(entry, workloads=[cell])
+        return dict(MANIFEST, per_layer=MANIFEST["per_layer"] + [copy])
+
+    cell = "mistral-7b.chat-open"
+    assert read_twice(with_copy_of("decode_step_ms", cell)) == [
+        (cell, "decode_step_ms", "decode_step_ms")]
+    assert read_twice(with_copy_of("state_hbm_share", cell)) == []
+
+
 @pytest.mark.parametrize("name", sorted(
     f[:-5] for f in os.listdir(os.path.join(BENCH, "configs"))))
 def test_config_file_is_the_programs_preset(name):
-    from symmetry_tpu.models.llama import preset
+    """Every published key the program reads, through its own reader
+    (`config_from_hf`, as tier-1 `tests/test_manifest_cut.py` reads the cut
+    files): a file's `intermediate_size` may be a dense width no layer uses,
+    its eps and tie keys the family's own."""
+    import dataclasses
+
+    from symmetry_tpu.models.llama import config_from_hf, preset
 
     c = json.load(open(os.path.join(BENCH, "configs", name + ".json")))
     p = preset(c["tpu"]["model_preset"])
+    q = config_from_hf(c)
+    assert type(q) is type(p)
+    # the oldest presets leave `head_dim` to be derived and `max_position`
+    # at its default: the head's width is compared as the program uses it
+    for f in dataclasses.fields(p):
+        if f.name not in ("head_dim", "max_position"):
+            assert getattr(q, f.name) == getattr(p, f.name), f.name
+    assert q.dim_per_head == p.dim_per_head == c["head_dim"]
     assert (p.vocab_size, p.hidden_size, p.num_layers, p.num_heads,
-            p.num_kv_heads, p.intermediate_size, p.dim_per_head) == (
+            p.num_kv_heads) == (
         c["vocab_size"], c["hidden_size"], c["num_hidden_layers"],
-        c["num_attention_heads"], c["num_key_value_heads"],
-        c["intermediate_size"], c["head_dim"])
-    assert p.rope_theta == c["rope_theta"] and p.rms_eps == c["rms_norm_eps"]
-    assert p.attention_bias == bool(c.get("attention_bias", False))
-    assert p.tie_embeddings == c["tie_word_embeddings"]
-    assert len(c["source"]) <= 200 and c["reduced"] == []
+        c["num_attention_heads"], c["num_key_value_heads"])
+    # a cut is stated, in the file as in the manifest, with what was cut
+    assert len(c["source"]) <= 200
+    assert sorted(c.get("published", {})) == sorted(c["reduced"])
+    for entry in MANIFEST["configs"]:
+        if entry["file"] == f"benchmarks/configs/{name}.json":
+            assert entry["reduced"] == c["reduced"]
+            assert entry["source"] == c["source"]

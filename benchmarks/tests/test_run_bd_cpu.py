@@ -13,7 +13,7 @@ import shutil
 import subprocess
 import sys
 
-from conftest import BENCH, CHECKOUT, TESTS
+from conftest import BENCH, CHECKOUT, TESTS, rehome
 
 RUN = os.path.join(BENCH, "run.py")
 ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
@@ -25,17 +25,6 @@ def test_bd_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
     data = tmp_path / "data"
     shutil.copytree(os.path.join(TESTS, "data"), data)
     real = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
-    mine = [m for m in real["per_layer"]
-            if m.get("workloads") == [REAL_CELL]]
-    assert len(mine) == 16
-    for m in mine:      # each has its file, and the file its reader
-        assert m["moves"] == "gap_p99_s"
-        spec = json.load(open(os.path.join(
-            BENCH, "layer_metrics", m["name"] + ".json")))
-        assert {k: spec[k] for k in ("name", "unit", "better", "source",
-                                     "layer", "moves")} == {
-            k: m[k] for k in ("name", "unit", "better", "source", "layer",
-                              "moves")}
     m = json.load(open(data / "BENCHMARK.tiny.json"))
     m["configs"].append({"name": "tiny-bd", "source": "test preset",
                          "file": "configs/tiny-bd.json", "reduced": [],
@@ -43,7 +32,12 @@ def test_bd_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
     m["workloads"].append({"name": CELL, "config": "tiny-bd",
                            "traffic": "tiny-closed", "chips": 1,
                            "why": "rehearsal"})
-    m["per_layer"] += [dict(e, workloads=[CELL]) for e in mine]
+    reached = rehome(m, real, REAL_CELL, CELL)
+    # the cell's own metrics reach it, by name
+    own = {"bd_tokens_per_forward", "bd_dropped_share", "bd_forward_ms",
+           "bd_decode_hbm_share", "bd_forward_mxu_share",
+           "bd_prefill_mxu_share", "moe_expert_imbalance"}
+    assert own <= set(reached), own - set(reached)
     json.dump(m, open(data / "BENCHMARK.tiny.json", "w"))
     out = subprocess.run(
         [sys.executable, RUN, "--workload", CELL, "--seed", "3000000047",
@@ -57,15 +51,15 @@ def test_bd_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
     line = lines[-1]
     assert "correct=True" in line and "failed=0" in line, line
     # every reader that needs no device trace found something to read
-    for name in ("gap_p99_s", "setup_s", "bd_tokens_per_forward",
-                 "bd_commit_share", "bd_dropped_share",
-                 "moe_expert_imbalance.bd", "wire_out_tok_s.bd",
-                 "decode_step_ms.bd", "sched_occupancy.bd", "kv_fill.bd",
-                 "wire_ttft_p50_s.bd", "wire_tpot_p50_ms", "admit_share"):
+    for name in ("gap_tail_s", "tpot_p50_ms", "setup_s",
+                 "bd_tokens_per_forward", "bd_dropped_share",
+                 "moe_expert_imbalance", "wire_out_tok_s",
+                 "decode_step_ms", "sched_occupancy", "kv_fill",
+                 "wire_ttft_p50_s", "wire_gap_p99_s", "admit_share"):
         assert f"'{name}'" in line, line
     # ... and the trace readers found no device plane (nor the CPU a
     # memory limit), and said nothing
     for name in ("bd_forward_ms", "bd_decode_hbm_share",
                  "bd_forward_mxu_share", "bd_prefill_mxu_share",
-                 "hbm_used.bd"):
+                 "hbm_used"):
         assert f"'{name}'" not in line, line

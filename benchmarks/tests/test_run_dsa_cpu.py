@@ -12,7 +12,7 @@ import shutil
 import subprocess
 import sys
 
-from conftest import BENCH, CHECKOUT, TESTS
+from conftest import BENCH, CHECKOUT, TESTS, rehome
 
 RUN = os.path.join(BENCH, "run.py")
 ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
@@ -24,16 +24,6 @@ def test_dsa_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
     data = tmp_path / "data"
     shutil.copytree(os.path.join(TESTS, "data"), data)
     real = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
-    mine = [m for m in real["per_layer"]
-            if m.get("workloads") == [REAL_CELL]]
-    assert len(mine) == 17
-    for m in mine:      # each has its file, and the file its reader
-        spec = json.load(open(os.path.join(
-            BENCH, "layer_metrics", m["name"] + ".json")))
-        assert {k: spec[k] for k in ("name", "unit", "better", "source",
-                                     "layer", "moves")} == {
-            k: m[k] for k in ("name", "unit", "better", "source", "layer",
-                              "moves")}
     m = json.load(open(data / "BENCHMARK.tiny.json"))
     m["configs"].append({"name": "tiny-dsa", "source": "test preset",
                          "file": "configs/tiny-dsa.json", "reduced": [],
@@ -41,7 +31,12 @@ def test_dsa_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
     m["workloads"].append({"name": CELL, "config": "tiny-dsa",
                            "traffic": "tiny-closed", "chips": 1,
                            "why": "rehearsal"})
-    m["per_layer"] += [dict(e, workloads=[CELL]) for e in mine]
+    reached = rehome(m, real, REAL_CELL, CELL)
+    # the cell's own metrics reach it, by name
+    own = {"dsa_select_ratio", "dsa_decode_hbm_share",
+           "dsa_prefill_mxu_share", "dsa_index_hbm_share",
+           "dsa_flash_roofline", "moe_expert_imbalance"}
+    assert own <= set(reached), own - set(reached)
     json.dump(m, open(data / "BENCHMARK.tiny.json", "w"))
     out = subprocess.run(
         [sys.executable, RUN, "--workload", CELL, "--seed", "3000000040",
@@ -55,16 +50,17 @@ def test_dsa_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
     line = lines[-1]
     assert "correct=True" in line and "failed=0" in line, line
     # every reader that needs no device trace found something to read
-    for name in ("gap_p99_s", "setup_s", "dsa_select_ratio",
-                 "moe_expert_imbalance.dsa", "wire_out_tok_s.dsa",
-                 "decode_step_ms.dsa", "sched_occupancy.dsa", "kv_fill.dsa",
-                 "wire_ttft_p50_s.dsa", "wire_ttft_p95_s.dsa",
-                 "sched_queue_mean_s.dsa", "stage_prefill_mean_s.dsa",
-                 "wire_tpot_p50_ms", "admit_share"):
+    for name in ("gap_tail_s", "tpot_p50_ms", "setup_s",
+                 "dsa_select_ratio",
+                 "moe_expert_imbalance", "wire_out_tok_s",
+                 "decode_step_ms", "sched_occupancy", "kv_fill",
+                 "wire_ttft_p50_s", "wire_ttft_p95_s",
+                 "sched_queue_mean_s", "stage_prefill_mean_s",
+                 "wire_gap_p99_s", "admit_share"):
         assert f"'{name}'" in line, line
     # ... and the trace readers found no device plane (nor the CPU a
     # memory limit), and said nothing
     for name in ("dsa_decode_hbm_share", "dsa_prefill_mxu_share",
                  "dsa_flash_roofline", "dsa_index_hbm_share",
-                 "hbm_used.dsa"):
+                 "hbm_used"):
         assert f"'{name}'" not in line, line

@@ -12,7 +12,7 @@ import shutil
 import subprocess
 import sys
 
-from conftest import BENCH, CHECKOUT, TESTS
+from conftest import BENCH, CHECKOUT, TESTS, rehome
 
 RUN = os.path.join(BENCH, "run.py")
 ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
@@ -24,9 +24,6 @@ def test_gdn_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
     data = tmp_path / "data"
     shutil.copytree(os.path.join(TESTS, "data"), data)
     real = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
-    mine = [m for m in real["per_layer"]
-            if m.get("workloads") == [REAL_CELL]]
-    assert len(mine) == 12
     m = json.load(open(data / "BENCHMARK.tiny.json"))
     m["configs"].append({"name": "tiny-gdn", "source": "test preset",
                          "file": "configs/tiny-gdn.json", "reduced": [],
@@ -34,7 +31,12 @@ def test_gdn_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
     m["workloads"].append({"name": CELL, "config": "tiny-gdn",
                            "traffic": "tiny-closed", "chips": 1,
                            "why": "rehearsal"})
-    m["per_layer"] += [dict(e, workloads=[CELL]) for e in mine]
+    reached = rehome(m, real, REAL_CELL, CELL)
+    # the cell's own metrics reach it, by name
+    own = {"lin_decode_hbm_share", "lin_prefill_mxu_share",
+           "state_hbm_share", "state_prefill_tok_s",
+           "state_installs_per_s", "moe_expert_imbalance"}
+    assert own <= set(reached), own - set(reached)
     json.dump(m, open(data / "BENCHMARK.tiny.json", "w"))
     out = subprocess.run(
         [sys.executable, RUN, "--workload", CELL, "--seed", "3000000035",
@@ -48,13 +50,14 @@ def test_gdn_cell_on_the_cpu_refuses_but_walks_its_readers(tmp_path):
     line = lines[-1]
     assert "correct=True" in line and "failed=0" in line, line
     # every reader that needs no device trace found something to read
-    for name in ("gap_p99_s", "setup_s", "lin_prefill_tok_s",
-                 "lin_installs_per_s", "moe_expert_imbalance.lin",
-                 "wire_out_tok_s.lin", "decode_step_ms.lin",
-                 "sched_occupancy.lin", "wire_tpot_p50_ms", "admit_share"):
+    for name in ("gap_tail_s", "tpot_p50_ms", "setup_s",
+                 "state_prefill_tok_s",
+                 "state_installs_per_s", "moe_expert_imbalance",
+                 "wire_out_tok_s", "decode_step_ms",
+                 "sched_occupancy", "wire_gap_p99_s", "admit_share"):
         assert f"'{name}'" in line, line
     # ... and the trace readers found no device plane (nor the CPU a
     # memory limit), and said nothing
     for name in ("lin_decode_hbm_share", "lin_prefill_mxu_share",
-                 "lin_state_hbm_share", "hbm_used.lin"):
+                 "state_hbm_share", "hbm_used"):
         assert f"'{name}'" not in line, line

@@ -335,7 +335,7 @@ def test_the_manifest_lists_the_eleven_for_every_cell():
             if m["name"] in NAMES}
     assert sorted(mine) == sorted(NAMES)
     for name, entry in mine.items():
-        assert "workloads" not in entry and entry["moves"] == "gap_p99_s"
+        assert "workloads" not in entry and entry["moves"] == "gap_tail_s"
         spec = json.load(open(os.path.join(
             BENCH, "layer_metrics", name + ".json")))
         for key in ("unit", "better", "source", "layer", "moves"):
@@ -365,3 +365,49 @@ def test_the_eleven_are_walked_on_the_cpu(tmp_path):
     assert "correct=True" in lines[-1] and "failed=0" in lines[-1]
     for name in NAMES:
         assert f"'{name}'" in lines[-1], lines[-1]
+
+
+def closed_loop_records(n_streams, intervals, t0=100.0):
+    """Every live stream receives its chunk at the same read: `n_streams`
+    records whose stamps are the running sum of `intervals`."""
+    stamps, t = [], t0
+    for dt in intervals:
+        t += dt
+        stamps.append([t, 16])
+    return [{"stamps": list(stamps)} for _ in range(n_streams)], t0, t + 1.0
+
+
+@pytest.mark.parametrize("late,tail_share", [
+    # ONE block of 99 read late by 0.1 s, then by 2.3 s (a stall): it holds
+    # 1.01% of the gaps, so the 99th percentile IS that block, and the band
+    # up to the 98th holds none of its gaps
+    ({47: 0.1}, 0.0),
+    ({47: 2.3}, 0.0),
+    # two late blocks are 2.02% of the gaps: three of them reach the band
+    ({31: 0.1, 71: 0.1}, 3 / 2282),
+    # THREE are 3% of the gaps: one of them lies in the band, and the
+    # judged tail says so (131 of its 2,282 gaps)
+    ({15: 0.1, 31: 0.1, 71: 0.1}, 131 / 2282),
+])
+def test_one_interval_owns_the_p99_and_not_the_band(late, tail_share):
+    """`gap_tail_s` (`client.gap_band_mean_s` 80..98) on a synthetic closed
+    loop of 128 streams: 99 intervals of which every eighth holds an
+    admission (0.5 s, the plateau) and the rest are plain blocks (0.4 s)."""
+    from readers import client
+
+    def ctx(extra):
+        intervals = [(0.5 if i % 8 == 7 else 0.4) + extra.get(i, 0.0)
+                     for i in range(100)]   # the first stamp opens no gap
+        records, w0, w1 = closed_loop_records(128, intervals)
+        return SimpleNamespace(phase=SimpleNamespace(
+            records=records, w0=w0, w1=w1))
+
+    calm, odd = ctx({}), ctx(late)
+    by = max(late.values())
+    # the band holds 999 gaps of a plain block and 1,283 of the plateau
+    plain = (999 * 0.4 + 1283 * 0.5) / 2282
+    assert client.gap_percentile_s(calm, 99) == pytest.approx(0.5)
+    assert client.gap_band_mean_s(calm, 80, 98) == pytest.approx(plain)
+    assert client.gap_percentile_s(odd, 99) == pytest.approx(0.5 + by)
+    assert client.gap_band_mean_s(odd, 80, 98) == pytest.approx(
+        plain + tail_share * by)

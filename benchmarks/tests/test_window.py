@@ -54,6 +54,17 @@ def test_percentile_nearest_rank():
     assert window.percentile([], 50) is None
 
 
+def test_band_mean_is_the_ranks_between_two_percentiles():
+    xs = list(range(1, 101))
+    # nearest rank: the 90th percentile is 90, the 99th 99, both included
+    assert window.band_mean(xs, 90, 99) == sum(range(90, 100)) / 10
+    assert window.band_mean(xs, 80, 99) == sum(range(80, 100)) / 20
+    assert window.band_mean([3.0], 90, 99) == 3.0
+    assert window.band_mean([], 90, 99) is None
+    # order does not matter, and a band of one rank is that percentile
+    assert window.band_mean(xs[::-1], 99, 99) == window.percentile(xs, 99)
+
+
 def test_tpot_and_live():
     assert window.tpots(RECORDS, W0, W1) == [3.0 / 11, 2.0 / 15]
     streams, tokens = window.live_at(RECORDS, 11.75)
