@@ -65,6 +65,11 @@ contends for the chip its child needs.
         # and prefill to the flash kernel
     JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny
     JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny-bd
+    python chip_smoke.py --preset kanana-2-30b-a3b
+        # latent attention on one chip (one cached row of 576 values in 640
+        # lanes, no int8 form: `kv_quantization` null); the report's
+        # `attention.kind` reads `latent` and it carries `cache`
+    JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny-mla
         # CPU dry run of every phase; ends non-zero: "platform is cpu"
 """
 
@@ -96,6 +101,10 @@ SPARSE_PRESETS = ("keye-vl-2.0-30b-a3b", "tiny-dsa")
 # the cache has a geometry (`decode_queries`; tiny-bd's head of 16 has none
 # and says why), and the report carries `diffusion`
 DIFFUSION_PRESETS = ("sdar-30b-a3b-chat", "tiny-bd")
+# presets with latent attention: a cached position is one row (no int8 form:
+# `kv_quantization` null), the report carries `cache` and the decode step
+# runs absorbed through ops/mla_attention.py
+LATENT_PRESETS = ("kanana-2-30b-a3b", "tiny-mla")
 
 
 class SmokeFailure(Exception):
@@ -114,7 +123,9 @@ def provider_config(preset: str, mesh_model: int) -> dict:
                                        digest_size=32).hexdigest(),
         "tpu": {
             "model_preset": preset, "dtype": "bfloat16",
-            "quantization": "int8", "kv_quantization": "int8",
+            "quantization": "int8",
+            "kv_quantization": (None if preset in LATENT_PRESETS
+                                else "int8"),
             "max_batch_size": SLOTS, "max_seq_len": MAX_SEQ,
             "prefill_buckets": [BUCKET], "decode_block": BLOCK,
             **({"mesh": {"model": mesh_model}} if mesh_model > 1 else {}),
@@ -351,6 +362,11 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
             "pallas", "pallas"):
         failures.append(f"attention did not run compiled Pallas kernels "
                         f"in both programs: {attention}")
+    if cfg["tpu"]["model_preset"] in LATENT_PRESETS and (
+            attention.get("kind") != "latent"
+            or (startup.get("cache") or {}).get("kind") != "latent"):
+        failures.append(f"a model with latent attention reported no latent "
+                        f"cache: {attention} {startup.get('cache')}")
     sparse = attention.get("sparse") or {}
     if (cfg["tpu"]["model_preset"] in SPARSE_PRESETS
             and not (sparse.get("form") or {}).get("decode")):
@@ -393,6 +409,7 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
         "sampling": startup.get("sampling"),
         **({"moe": startup["moe"]} if startup.get("moe") else {}),
         **({"ssm": startup["ssm"]} if startup.get("ssm") else {}),
+        **({"cache": startup["cache"]} if startup.get("cache") else {}),
         "startup_s": round(startup_s, 1),
         "build_s": startup.get("build_s"),
         "warmup_s": startup.get("warmup_s"),
@@ -453,14 +470,14 @@ def main() -> int:
     args = ap.parse_args()
 
     if os.environ.get("JAX_PLATFORMS") == "cpu" and args.preset not in (
-            "tiny", "tiny-bd"):
+            "tiny", "tiny-bd", "tiny-mla"):
         # The engine host obeys a CPU pinned by name (utils/device.py), so
         # the verdict is known before anything starts — and a full-width
         # model is not built on a CPU to reach it.
         print(f"chip_smoke: FAIL: JAX_PLATFORMS=cpu pins the engine host "
               f"to the CPU: its platform is cpu, not tpu ({args.preset} is "
-              f"not built there; `--preset tiny` and `--preset tiny-bd` are "
-              f"the CPU dry runs)",
+              f"not built there; `--preset tiny`, `tiny-bd` and `tiny-mla` "
+              f"are the CPU dry runs)",
               file=sys.stderr)
         return 1
 

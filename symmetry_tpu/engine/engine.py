@@ -32,8 +32,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from symmetry_tpu.models.llama import (
+    LATENT_COUNTS,
     KVCache,
     ModelConfig,
+    absorb_latent,
     cache_logical_axes,
     forward_hidden,
     init_cache,
@@ -238,7 +240,10 @@ class InferenceEngine:
         # A model with recurrent layers keeps a state per slot that is not
         # a row per position (models/hybrid.py): what cannot carry it is
         # refused here, never served wrong.
-        self._has_state = bool(getattr(config, "layer_types", None))
+        # (a model of latent-attention layers alone rides the same trunk
+        # with no recurrent kind: it keeps a row a position and no state)
+        self._has_state = bool(getattr(config, "layer_types", None)) and (
+            config.recurrent_kind is not None)
         if self._has_state:
             from symmetry_tpu.models.hybrid import state_refusals
 
@@ -262,6 +267,26 @@ class InferenceEngine:
                 prefill_chunk=prefill_chunk)
             if refused:
                 raise EngineError(refused[0])
+        # Latent attention keeps ONE row a cached position and no K / V a
+        # head (models/llama.py LatentAttention): the same rule.
+        self._latent = getattr(config, "latent", None)
+        if self._latent is not None:
+            from symmetry_tpu.models.llama import latent_refusals
+
+            refused = latent_refusals(
+                mesh=mesh is not None, role=role,
+                prefix_cache=prefix_cache_bytes > 0,
+                speculative=speculative is not None,
+                prefill_chunk=prefill_chunk, kv_quant=kv_quant)
+            if refused:
+                raise EngineError(refused[0])
+        # since start (stats.engine.mla): decode forwards and the live rows
+        # they read as of the last synced decode block (counted on the
+        # device), prompt tokens prefilled through the expanded form as
+        # dispatched
+        self.mla = (None if self._latent is None else
+                    {**{name: 0 for name in LATENT_COUNTS},
+                     "prefill_tokens": 0})
         # Generation by diffusion over blocks (models/llama.py
         # BlockDiffusion): the same rule, and the two generation settings.
         self._diffusion = getattr(config, "diffusion", None)
@@ -621,7 +646,9 @@ class InferenceEngine:
 
             cache = state.cache._replace(
                 k=place(state.cache.k, prefix.k),
-                v=place(state.cache.v, prefix.v),
+                # (a latent cache has no `v` leaf: `k` holds the one row)
+                **({"v": place(state.cache.v, prefix.v)}
+                   if state.cache.v is not None else {}),
                 # The first sampled token's KV is not here yet: the next
                 # decode step writes it at position true_len.
                 lengths=state.cache.lengths.at[slot].set(true_len[row]),
@@ -1262,6 +1289,8 @@ class InferenceEngine:
 
         if self._has_state:
             self.ssm_counters["prefill_tokens"] += int(lens[:n_req].sum())
+        if self.mla is not None:
+            self.mla["prefill_tokens"] += int(lens[:n_req].sum())
         lens_arr = jnp.asarray(lens)
         temps_arr = jnp.asarray(temps)
         top_ps_arr = jnp.asarray(top_ps)
@@ -1487,6 +1516,12 @@ class InferenceEngine:
         plus scale planes when int8-quantized) — sizes handoff frames
         and the decode tier's adoption-budget floor."""
         c = self.config
+        latent = getattr(c, "latent", None)
+        if latent is not None:
+            # one row a layer and position, as it lies on the chip: in
+            # whole lane tiles (576 values in 640 lanes)
+            return (c.num_layers * latent.lanes
+                    * jnp.dtype(self.cache_dtype).itemsize)
         # a model with recurrent layers keeps K/V for its attention layers
         # alone (state_bytes_per_slot has the rest of a slot)
         n_layers = (len(c.layers_of(c.attention_kind)) if self._has_state
@@ -2233,6 +2268,10 @@ class InferenceEngine:
 
                 for name, n in read_counts(block[-N_COUNTS:]).items():
                     self.dsa[name] += n
+            if self.mla is not None:
+                for name, n in zip(LATENT_COUNTS,
+                                   block[-len(LATENT_COUNTS):]):
+                    self.mla[name] += int(n)
 
     def moe_report(self) -> dict | None:
         """`startup.moe`: where the expert weights live and which form
@@ -2357,6 +2396,33 @@ class InferenceEngine:
                 "index_cache_bytes": (per_token * self.max_slots
                                       * self.max_seq_len)}
         return paths
+
+    def cache_report(self) -> dict | None:
+        """`startup.cache`: what a cached position is, for a model whose
+        entry is not K and V a head (latent attention); None for any
+        other."""
+        la = self._latent
+        if la is None:
+            return None
+        item = jnp.dtype(self.cache_dtype).itemsize
+        per_token = self.kv_bytes_per_token()
+        return {
+            "kind": "latent", "rank": la.rank, "rope": la.rope,
+            "row": la.row, "lanes": la.lanes,
+            "layers": self.config.num_layers,
+            "dtype": str(jnp.dtype(self.cache_dtype)),
+            "row_bytes": la.row * item,
+            # as the chip holds it (the 576 values pad to 640 lanes)
+            "bytes_per_token": per_token,
+            "cache_bytes": per_token * self.max_slots * self.max_seq_len,
+            "expanded_bytes_per_token": (
+                self.config.num_layers * self.config.num_heads
+                * (la.nope + la.rope + la.v) * item),
+            "absorbed_factors": {
+                "dtype": str(self.params["layers"]["attn"]["wuk"].dtype),
+                "bytes": sum(
+                    int(self.params["layers"]["attn"][n].nbytes)
+                    for n in ("wuk", "wuv"))}}
 
     def diffusion_report(self) -> dict | None:
         """`startup.diffusion`: the block, the two generation settings and
@@ -2516,6 +2582,7 @@ class InferenceEngine:
                     from symmetry_tpu.models.llama import quantize_params
 
                     params = quantize_params(params)
+                params = absorb_latent(params, config, dtype)
                 if use_warm:
                     try:
                         save_warm_cache(tpu_cfg.checkpoint_path, params,

@@ -424,6 +424,9 @@ class EngineHost:
         diffusion = self._engine.diffusion_report()
         if diffusion is not None:
             self._startup["diffusion"] = diffusion
+        cache = self._engine.cache_report()
+        if cache is not None:
+            self._startup["cache"] = cache
         self._write({"op": HostOp.READY,
                      "model": self._config.model_name,
                      "role": self._role,

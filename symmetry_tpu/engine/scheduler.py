@@ -672,6 +672,10 @@ class Scheduler:
                 **self.engine.diffusion,
                 "opening_block_tokens": dict(
                     self.engine.diffusion["opening_block_tokens"])}
+        if getattr(self.engine, "mla", None) is not None:
+            # latent attention: decode forwards, the live rows they read
+            # and the tokens prefilled expanded, as of the same block
+            out["mla"] = dict(self.engine.mla)
         if getattr(self.engine, "dsa", None) is not None:
             # learned sparse attention: queries, the positions they could
             # select from and those they selected, as of the same block

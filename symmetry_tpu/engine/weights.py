@@ -63,9 +63,9 @@ def convert_hf_state_dict(
         try:
             return hybrid.convert_hf_state_dict(tensors, config)
         except (KeyError, ValueError) as exc:
-            family = {"linear_attention": "qwen3_next",
-                      "conv": "lfm2_moe"}.get(config.recurrent_kind,
-                                              "granitemoehybrid")
+            family = {"linear_attention": "qwen3_next", "conv": "lfm2_moe",
+                      None: "deepseek_v3"}.get(config.recurrent_kind,
+                                               "granitemoehybrid")
             raise CheckpointError(f"{family} checkpoint: {exc}")
     n_exp = getattr(config, "num_experts", 0)
     router_name, experts_module, expert_map = hf_moe_names(config)
@@ -239,6 +239,9 @@ def load_checkpoint(
         params = convert_hf_state_dict(tensors, config)
         abstract = jax.eval_shape(
             lambda: init_params(config, jax.random.key(0), dtype))
+        for derived in ("wuk", "wuv"):  # llama.absorb_latent's, of the
+            # finished (quantised) tree: no checkpoint holds them
+            abstract["layers"].get("attn", {}).pop(derived, None)
         return jax.tree.map(
             lambda a, like: jnp.asarray(a, like.dtype), params,
             abstract), config

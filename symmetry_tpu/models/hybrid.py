@@ -55,6 +55,14 @@ the expert layers) chosen by a sigmoid router with a selection bias
 (models/moe.py `route_top_k`). A run (`runs`) breaks where the mixer kind OR
 the FFN kind changes, so one scan body still holds one kind of each.
 
+A deepseek_v3 model (`config.latent`) is the same trunk with NO recurrent
+kind: every layer is "latent_attention" (models/llama.py
+`_latent_attention`; stack `layers.attn`), the cache is `k` alone — one row
+of rank + rope values a position in whole lane tiles, [num_layers, B, T,
+lanes], no `v`, no `ssm`, no `conv` — and the FFN kinds are lfm2_moe's (a
+leading dense layer, then experts by the sigmoid router) with granite's
+ungated shared expert beside the routed sum.
+
 One device only: there are no sharding rules for the state yet.
 """
 
@@ -72,7 +80,7 @@ from symmetry_tpu.ops.quant import qmatmul
 
 KIND_STACK = {"mamba": "mamba", "attention": "attn",
               "linear_attention": "gdn", "full_attention": "attn",
-              "conv": "sconv"}
+              "conv": "sconv", "latent_attention": "attn"}
 RECURRENT = {"mamba": mamba2, "linear_attention": gdn, "conv": sconv}
 
 
@@ -85,6 +93,8 @@ def state_shapes(config, batch: int) -> tuple[tuple | None, tuple]:
     """The shapes of the cache's `ssm` and `conv` leaves: a stack over the
     recurrent layers of this model's kind. A short convolution's state is
     its tail alone: no `ssm` leaf (None)."""
+    if config.recurrent_kind is None:  # latent attention alone: no state
+        return None, None
     n = len(config.layers_of(config.recurrent_kind))
     if config.recurrent_kind == "conv":
         z = sconv.sizes(config)
@@ -102,7 +112,8 @@ def state_bytes_per_slot(config, dtype=jnp.bfloat16) -> dict:
     """What one slot holds that is not a row per position."""
     ssm, conv = state_shapes(config, 1)
     return {"ssm": math.prod(ssm) * 4 if ssm else 0,
-            "conv": math.prod(conv) * jnp.dtype(dtype).itemsize}
+            "conv": (math.prod(conv) * jnp.dtype(dtype).itemsize
+                     if conv else 0)}
 
 
 def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
@@ -111,6 +122,17 @@ def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
     n_attn = len(config.layers_of(config.attention_kind))
     ssm, conv = state_shapes(config, batch)
     shape = (n_attn, batch, capacity, *llama.kv_row(config))
+    if config.latent is not None:
+        if quantized:
+            raise ValueError("a latent cache row has no int8 form")
+        # the row is all a position keeps; the expert counter ends in the
+        # latent counters (llama.LATENT_COUNTS), as a sparse model's does
+        return llama.KVCache(
+            k=jnp.zeros(shape, dtype), v=None,
+            lengths=jnp.zeros((batch,), jnp.int32),
+            expert_pairs=(jnp.zeros((config.num_experts
+                                     + len(llama.LATENT_COUNTS),), jnp.int32)
+                          if count_experts else None))
     scale_shape = (n_attn, batch, config.num_kv_heads, capacity)
     return llama.KVCache(
         k=jnp.zeros(shape, jnp.int8 if quantized else dtype),
@@ -147,6 +169,9 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
                 else make_leaf)
         return make(next(keys), shape, scale, dtype, quantized=quantized)
 
+    if c.latent is not None:
+        return llama.absorb_latent(_init_latent(c, keys, dense, dtype), c,
+                                   dtype)
     if c.recurrent_kind == "linear_attention":
         return _init_qwen3_next(c, keys, dense, dtype)
     if c.recurrent_kind == "conv":
@@ -308,6 +333,57 @@ def _init_lfm2(c, keys, dense, dtype) -> dict:
     return params
 
 
+def _init_latent(c, keys, dense, dtype) -> dict:
+    """`init_params` for a deepseek_v3 config (`absorb_latent` adds the
+    absorbed factors). `expert_bias` (HF `e_score_correction_bias`) is
+    drawn uniform in [-0.25, 0.25] as `_init_lfm2` draws it: at the
+    published initial zero no comparison could tell the bias left out."""
+    la = c.latent
+    E, F, X, H = c.hidden_size, c.intermediate_size, c.num_experts, \
+        c.num_heads
+    L, Ld, Fd, Fs = c.num_layers, c.num_dense_layers, \
+        c.dense_intermediate_size, c.shared_intermediate_size
+    Lx = L - Ld
+    params = {
+        "embed": dense((c.vocab_size, E), scale=0.02),
+        "layers": {
+            "attn": {
+                "norm": jnp.ones((L, E), dtype),
+                "wq": dense((L, E, H * (la.nope + la.rope)), "wq"),
+                "wkva": dense((L, E, la.row), "wkva"),
+                "kv_norm": jnp.ones((L, la.rank), dtype),
+                "wkvb": dense((L, la.rank, H * (la.nope + la.v)), "wkvb"),
+                "wo": dense((L, H * la.v, E), "wo"),
+            },
+            "ffn": {
+                "norm": jnp.ones((Lx, E), dtype),
+                "router": dense((Lx, E, X)),
+                "wg": dense((Lx, X, E, F), "wg"),
+                "wu": dense((Lx, X, E, F), "wu"),
+                "wd": dense((Lx, X, F, E), "wd"),
+            },
+        },
+        "final_norm": jnp.ones((E,), dtype),
+    }
+    if Fs:
+        params["layers"]["ffn"].update(
+            sg=dense((Lx, E, Fs), "sg"), su=dense((Lx, E, Fs), "su"),
+            sd=dense((Lx, Fs, E), "sd"))
+    if Ld:
+        params["layers"]["dense"] = {
+            "norm": jnp.ones((Ld, E), dtype),
+            "wg": dense((Ld, E, Fd), "wg"),
+            "wu": dense((Ld, E, Fd), "wu"),
+            "wd": dense((Ld, Fd, E), "wd"),
+        }
+    if c.router_bias:
+        params["layers"]["ffn"]["expert_bias"] = jax.random.uniform(
+            next(keys), (Lx, X), jnp.float32, -0.25, 0.25)
+    if not c.tie_embeddings:
+        params["lm_head"] = dense((E, c.vocab_size), "lm_head", scale=0.02)
+    return params
+
+
 def state_refusals(*, mesh: bool = False, role: str = "unified",
                    prefix_cache: bool = False, speculative: bool = False,
                    prefill_chunk: int | None = None) -> list[str]:
@@ -386,7 +462,7 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
                  + jnp.arange(S, dtype=jnp.int32)[None, :])
     kv_valid = cache.lengths + seq_lens
     layers = params["layers"]
-    for kind in (c.recurrent_kind, c.attention_kind):
+    for kind in filter(None, (c.recurrent_kind, c.attention_kind)):
         n = jax.tree.leaves(layers[KIND_STACK[kind]])[0].shape[0]
         if n != len(c.layers_of(kind)):
             raise ValueError(f"params carry {n} {kind} layers but "
@@ -395,7 +471,7 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
     h = h * jnp.asarray(c.embedding_multiplier, h.dtype)
     r = jnp.asarray(c.residual_multiplier, h.dtype)
 
-    recurrent = RECURRENT[c.recurrent_kind]
+    recurrent = RECURRENT.get(c.recurrent_kind)
     n_dense = c.num_dense_layers
 
     def norm(h, w):
@@ -445,14 +521,14 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
             y, pairs = moe_mlp(norm(h, lp["norm"]), lp, c, seq_lens,
                                stack=(layers["ffn"], first - n_dense + step))
             h = h + r * y
-            if cache.expert_pairs is not None:
-                cache = cache._replace(
-                    expert_pairs=cache.expert_pairs + pairs)
-            return (h, cache), None
+            return (h, llama.add_expert_pairs(cache, pairs)), None
 
         (h, cache), _ = jax.lax.scan(
             body, (h, cache), jnp.arange(length, dtype=jnp.int32))
     h = norm(h, params["final_norm"])
+    if c.latent is not None and S == 1 and cache.expert_pairs is not None:
+        cache = cache._replace(expert_pairs=llama.count_latent(
+            cache.expert_pairs, cache.lengths, kv_valid))
     return h, cache._replace(lengths=kv_valid)
 
 
@@ -493,6 +569,8 @@ def hf_config(config) -> dict:
     reference — `benchmarks/reference/hybrid_decoder.py`, or
     `gdn_moe_decoder.py` for a qwen3_next config — is given)."""
     c = config
+    if c.latent is not None:
+        return llama.hf_config_latent(c)
     if c.recurrent_kind == "conv":
         return {
             "architectures": ["Lfm2MoeForCausalLM"],
@@ -590,6 +668,8 @@ def convert_hf_state_dict(tensors: dict, config) -> dict:
     missing and ValueError for one that maps nowhere."""
     import numpy as np
 
+    if config.latent is not None:
+        return _deepseek_from_hf(tensors, config)
     if config.recurrent_kind == "linear_attention":
         return _qwen3_next_from_hf(tensors, config)
     if config.recurrent_kind == "conv":
@@ -621,6 +701,8 @@ def to_hf_state_dict(params: dict, config) -> dict:
     """The inverse of `convert_hf_state_dict` (numpy, float32)."""
     import numpy as np
 
+    if config.latent is not None:
+        return _deepseek_to_hf(params, config)
     if config.recurrent_kind == "linear_attention":
         return _qwen3_next_to_hf(params, config)
     if config.recurrent_kind == "conv":
@@ -905,4 +987,114 @@ def _lfm2_to_hf(params: dict, config) -> dict:
                 for e in range(config.num_experts):
                     out[f"{prefix}feed_forward.experts.{e}.{hf}.weight"] = \
                         arr(lay["ffn"][name][at][e]).T
+    return out
+
+
+# ---------------------------------------------------------------------------
+# HF `deepseek_v3` checkpoint names (no query latent). Attention is `q_proj`,
+# `kv_a_proj_with_mqa` ([rank + rope, E]: the latent's rows, then the shared
+# rotary key's), `kv_a_layernorm`, `kv_b_proj` ([H (nope + v), rank]: a head's
+# nope rows, then its v rows) and `o_proj`; the rope channels keep HF's
+# INTERLEAVED order in both — the forward pass reorders them as HF's does
+# (ops/rope.py `interleaved`). A dense layer's `mlp` is gate / up / down_proj;
+# an expert layer's is `gate.weight` (the router), `gate.
+# e_score_correction_bias`, `experts.{e}.*` and `shared_experts.*`. The
+# absorbed factors are ours alone: derived on the way in, left out on the way
+# back.
+
+DEEPSEEK_ATTN = {"input_layernorm.weight": "norm",
+                 "self_attn.q_proj.weight": "wq",
+                 "self_attn.kv_a_proj_with_mqa.weight": "wkva",
+                 "self_attn.kv_a_layernorm.weight": "kv_norm",
+                 "self_attn.kv_b_proj.weight": "wkvb",
+                 "self_attn.o_proj.weight": "wo"}
+DEEPSEEK_FFN = {"dense": {"post_attention_layernorm.weight": "norm",
+                          "mlp.gate_proj.weight": "wg",
+                          "mlp.up_proj.weight": "wu",
+                          "mlp.down_proj.weight": "wd"},
+                "moe": {"post_attention_layernorm.weight": "norm",
+                        "mlp.gate.weight": "router",
+                        "mlp.gate.e_score_correction_bias": "expert_bias",
+                        "mlp.shared_experts.gate_proj.weight": "sg",
+                        "mlp.shared_experts.up_proj.weight": "su",
+                        "mlp.shared_experts.down_proj.weight": "sd"}}
+DEEPSEEK_TOP = {"model.embed_tokens.weight": "embed",
+                "model.norm.weight": "final_norm",
+                "lm_head.weight": "lm_head"}
+
+
+def _deepseek_ffn_names(config) -> dict:
+    names = dict(DEEPSEEK_FFN["moe"])
+    if not config.shared_intermediate_size:
+        names = {hf: n for hf, n in names.items()
+                 if n not in ("sg", "su", "sd")}
+    return {"dense": DEEPSEEK_FFN["dense"], "moe": names}
+
+
+def _deepseek_from_hf(tensors: dict, config) -> dict:
+    import numpy as np
+
+    def ours_of(a):
+        return np.swapaxes(a, -1, -2) if a.ndim >= 2 else a
+
+    ffn_names = _deepseek_ffn_names(config)
+    known = set(DEEPSEEK_TOP)
+    stacks: dict = {"attn": {}, "dense": {}, "ffn": {}}
+    for i in range(config.num_layers):
+        prefix = f"model.layers.{i}."
+        for hf, name in DEEPSEEK_ATTN.items():
+            known.add(prefix + hf)
+            stacks["attn"].setdefault(name, []).append(
+                ours_of(tensors[prefix + hf]))
+        ffn = config.ffn_kind(i)
+        for hf, name in ffn_names[ffn].items():
+            known.add(prefix + hf)
+            stacks[FFN_STACK[ffn]].setdefault(name, []).append(
+                ours_of(tensors[prefix + hf]))
+        if ffn == "moe":
+            for hf, name in QWEN_EXPERT.items():
+                names = [f"{prefix}mlp.experts.{e}.{hf}.weight"
+                         for e in range(config.num_experts)]
+                known.update(names)
+                stacks["ffn"].setdefault(name, []).append(
+                    np.stack([tensors[n].T for n in names]))
+    unmapped = sorted(set(tensors) - known)
+    if unmapped:
+        raise ValueError(f"unmapped HF tensors: {unmapped[:4]}")
+    out = {"embed": tensors["model.embed_tokens.weight"],
+           "final_norm": tensors["model.norm.weight"],
+           "layers": {stack: {k: np.stack(v) for k, v in leaves.items()}
+                      for stack, leaves in stacks.items() if leaves}}
+    if not config.tie_embeddings:
+        out["lm_head"] = tensors["lm_head.weight"].T
+    return out
+
+
+def _deepseek_to_hf(params: dict, config) -> dict:
+    import numpy as np
+
+    def arr(a):
+        a = np.asarray(a, np.float32)
+        return np.swapaxes(a, -1, -2) if a.ndim >= 2 else a
+
+    ffn_names = _deepseek_ffn_names(config)
+    lay = params["layers"]
+    out = {"model.embed_tokens.weight": np.asarray(params["embed"],
+                                                   np.float32),
+           "model.norm.weight": arr(params["final_norm"])}
+    if not config.tie_embeddings:
+        out["lm_head.weight"] = arr(params["lm_head"])
+    for i in range(config.num_layers):
+        prefix = f"model.layers.{i}."
+        for hf, name in DEEPSEEK_ATTN.items():
+            out[prefix + hf] = arr(lay["attn"][name][i])
+        ffn = config.ffn_kind(i)
+        at = i if ffn == "dense" else i - config.num_dense_layers
+        for hf, name in ffn_names[ffn].items():
+            out[prefix + hf] = arr(lay[FFN_STACK[ffn]][name][at])
+        if ffn == "moe":
+            for hf, name in QWEN_EXPERT.items():
+                for e in range(config.num_experts):
+                    out[f"{prefix}mlp.experts.{e}.{hf}.weight"] = arr(
+                        lay["ffn"][name][at][e])
     return out

@@ -32,7 +32,7 @@ from symmetry_tpu.ops.attention import gqa_attention
 from symmetry_tpu.ops.interpret import interpret_mode
 from symmetry_tpu.ops.norm import rms_norm
 from symmetry_tpu.ops.quant import (
-    QuantizedTensor, qmatmul, quantize_kv, quantize_tree)
+    QuantizedTensor, dequantize, qmatmul, quantize_kv, quantize_tree)
 from symmetry_tpu.ops.rope import apply_rope
 
 
@@ -97,6 +97,38 @@ class BlockDiffusion:
     mask_token_id: int
 
 
+@dataclass(frozen=True)
+class LatentAttention:
+    """Multi-head latent attention (HF `DeepseekV3`, `q_lora_rank` null):
+    keys and values are up-projections of ONE normed latent of `rank` values
+    a position, beside one rotary key of `rope` values that all heads share,
+    so a cached position is a single row of `rank + rope` values and no K or
+    V a head (`KVCache.k` [L, B, T, rank + rope], no `v` leaf). A head's
+    query and key are `nope + rope` wide, its value `v` wide; attention runs
+    ABSORBED over the cached rows and EXPANDED over a prompt's own
+    (`_latent_attention`, ops/mla_attention.py)."""
+
+    rank: int                 # kv_lora_rank
+    rope: int                 # qk_rope_head_dim
+    nope: int                 # qk_nope_head_dim
+    v: int                    # v_head_dim
+    rope_interleave: bool = True   # frequency i turns channels (2i, 2i + 1)
+
+    @property
+    def row(self) -> int:
+        return self.rank + self.rope
+
+    @property
+    def lanes(self) -> int:
+        """The row as the cache leaf declares it: padded with zeros to
+        whole lane tiles of 128 (576 -> 640). The chip's tiled layout pads
+        a 576-wide minor dimension to 640 lanes whatever is declared, and
+        Mosaic refuses to slice such an array for the decode kernel's
+        copies ("must be aligned to tiling (128), but is 576"): declared,
+        the same bytes are a leaf the kernel can address."""
+        return -(-self.row // 128) * 128
+
+
 # The SDAR family's generation settings where a config.json does not carry
 # them: the released chat models' block of four and their `<|MASK|>` id.
 SDAR_BLOCK_LENGTH = 4
@@ -123,6 +155,10 @@ class MoEConfig(ModelConfig):
     # the SDAR family: the block mask and the mask token (None: a causal
     # decoder that yields one token a stream a forward, every other model)
     diffusion: BlockDiffusion | None = None
+    # the DeepseekV3 family: the latent cache entry's sizes (None: K and V
+    # a head, every other model); `head_dim` is then the published rotary
+    # width and sizes no matrix
+    latent: LatentAttention | None = None
     # whose tensor names the expert block has in an HF checkpoint
     # (`hf_moe_names`): "mixtral" (`block_sparse_moe`) or "qwen3_moe"
     # (`mlp.gate`, `mlp.experts`)
@@ -191,18 +227,29 @@ class HybridConfig(MoEConfig):
     router_score: str = "softmax"     # "softmax" | "sigmoid"
     router_bias: bool = False
     routed_scaling_factor: float = 1.0
+    # what the sigmoid router adds to the sum it divides the selected
+    # scores by: lfm2_moe's 1e-6, deepseek_v3's 1e-20
+    router_norm_eps: float = 1e-6
+    # deepseek_v3 family: every layer "latent_attention" (`latent` has the
+    # sizes; no recurrent kind, so no state a slot), the first
+    # `num_dense_layers` (`first_k_dense_replace`) dense, the rest experts
+    # by the sigmoid router plus an ungated shared expert
 
     def __post_init__(self):
         kinds = set(self.layer_types)
         if (len(self.layer_types) != self.num_layers
                 or not (kinds <= {"mamba", "attention"}
                         or kinds <= {"linear_attention", "full_attention"}
-                        or kinds <= {"conv", "full_attention"})):
+                        or kinds <= {"conv", "full_attention"}
+                        or kinds == {"latent_attention"})):
             raise ValueError(
                 f"layer_types must name {self.num_layers} layers, each "
                 f"'mamba' or 'attention', or each 'linear_attention' or "
-                f"'full_attention', or each 'conv' or 'full_attention'; "
-                f"got {self.layer_types!r}")
+                f"'full_attention', or each 'conv' or 'full_attention', or "
+                f"each 'latent_attention'; got {self.layer_types!r}")
+        if ("latent_attention" in kinds) != (self.latent is not None):
+            raise ValueError("'latent_attention' layers and the `latent` "
+                             "sizes go together")
         if ("conv" in kinds) != bool(self.conv_L_cache):
             raise ValueError(
                 f"'conv' layers and conv_L_cache go together; got "
@@ -218,8 +265,11 @@ class HybridConfig(MoEConfig):
         return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
 
     @property
-    def recurrent_kind(self) -> str:
-        """The name `layer_types` gives this model's recurrent layers."""
+    def recurrent_kind(self) -> str | None:
+        """The name `layer_types` gives this model's recurrent layers; None
+        for a model of latent-attention layers alone, which has none."""
+        if self.latent is not None:
+            return None
         if self.conv_L_cache:
             return "conv"
         return ("linear_attention" if "linear_attention" in self.layer_types
@@ -227,6 +277,8 @@ class HybridConfig(MoEConfig):
 
     @property
     def attention_kind(self) -> str:
+        if self.latent is not None:
+            return "latent_attention"
         return "attention" if self.recurrent_kind == "mamba" \
             else "full_attention"
 
@@ -447,6 +499,44 @@ PRESETS: dict[str, ModelConfig] = {
         conv_L_cache=3, num_dense_layers=2, dense_intermediate_size=7168,
         router_score="sigmoid", router_bias=True, routed_scaling_factor=1.0,
     ),
+    # the CPU's copy of Kanana-2's mechanisms: a latent cache row of 16 + 8
+    # values for 4 heads (nope 16, value 12: unequal key and value widths),
+    # a leading dense layer, a sigmoid router with a selection bias and a
+    # gate scale over 8 experts top 2, an ungated shared expert, an untied
+    # head
+    "tiny-mla": HybridConfig(
+        vocab_size=512, hidden_size=64, num_layers=3, num_heads=4,
+        num_kv_heads=4, intermediate_size=32, head_dim=8,
+        rope_theta=10000.0, rms_eps=1e-6, max_position=512,
+        num_experts=8, num_experts_per_tok=2, shared_intermediate_size=64,
+        latent=LatentAttention(rank=16, rope=8, nope=16, v=12),
+        layer_types=("latent_attention",) * 3,
+        num_dense_layers=1, dense_intermediate_size=128,
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=2.448, router_norm_eps=1e-20,
+    ),
+    # kanana-2-30b-a3b-instruct-2601 (HF `deepseek_v3`, no query latent)
+    # CUT IN DEPTH to 8 of its 48 layers — the leading dense layer and
+    # seven of the 47 expert layers — at every published width, with all
+    # 128 experts, both shared experts (one FFN of 2 x 768) and the whole
+    # vocabulary: stage 1 of 6 of a pipeline, what one 16 GB chip holds in
+    # int8 beside a 64 x 11,776 cache of 576-value latent rows
+    # (benchmarks/configs/kanana-2-30b-a3b.json has the cut). `head_dim` is
+    # the published key, the rotary width: it sizes no matrix (`latent` has
+    # a head's widths), `intermediate_size` the routed expert's width,
+    # `dense_intermediate_size` the published `intermediate_size` of layer 0.
+    "kanana-2-30b-a3b": HybridConfig(
+        vocab_size=128256, hidden_size=2048, num_layers=8, num_heads=32,
+        num_kv_heads=32, intermediate_size=768, head_dim=64,
+        rope_theta=1000000.0, rms_eps=1e-6, max_position=32768,
+        num_experts=128, num_experts_per_tok=6,
+        shared_intermediate_size=1536,
+        latent=LatentAttention(rank=512, rope=64, nope=128, v=128),
+        layer_types=("latent_attention",) * 8,
+        num_dense_layers=1, dense_intermediate_size=6144,
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=2.448, router_norm_eps=1e-20,
+    ),
     "gemma-7b": ModelConfig(
         vocab_size=256000, hidden_size=3072, num_layers=28, num_heads=16,
         num_kv_heads=16, intermediate_size=24576, head_dim=256,
@@ -499,6 +589,12 @@ def kv_row(config: ModelConfig) -> tuple[int, int]:
     lie, a position's row contiguous; it is a reshape of the row, never
     of what a head holds. `write_kv` folds, the XLA attention unfolds,
     ops/decode_attention.py reads the pairs as they lie."""
+    latent = getattr(config, "latent", None)
+    if latent is not None:
+        # ONE row a position, the latent and the shared rotary key (in
+        # whole lane tiles: `LatentAttention.lanes`): no head axis, and no
+        # `v` leaf beside it
+        return (latent.lanes,)
     K, D = config.num_kv_heads, config.dim_per_head
     return (K // 2, 2 * D) if D == 64 and K % 2 == 0 else (K, D)
 
@@ -517,7 +613,12 @@ class KVCache(NamedTuple):
     """
 
     k: jnp.ndarray
-    v: jnp.ndarray
+    # None for a latent-attention model (config.latent), whose `k` holds
+    # the one row a position there is — [layers, batch, capacity, lanes]:
+    # the normed latent, the shared rotary key, then zeros up to a whole
+    # lane tile (576 values in 640 lanes) — in the compute dtype, never
+    # quantized
+    v: jnp.ndarray | None
     lengths: jnp.ndarray  # [batch] int32: valid entries per slot
     k_scale: jnp.ndarray | None = None
     v_scale: jnp.ndarray | None = None
@@ -642,6 +743,40 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
         v=cache.v.at[row].set(rows(vq)),
         k_scale=cache.k_scale.at[l_idx, b_idx, :, positions].set(ks),
         v_scale=cache.v_scale.at[l_idx, b_idx, :, positions].set(vs))
+
+
+# What a latent-attention model's `expert_pairs` ends in (as a sparse
+# model's ends in its own counters: `KVCache.idx`): forwards of one position
+# a slot over the cache, and the live rows those read (each live slot's
+# length with the current position, once a forward — not a layer). The
+# vector is handed out and zeroed a decode block (engine/engine.py), so no
+# int32 wraps: a block of 16 steps over 64 x 11,776 rows counts 1.2e7.
+LATENT_COUNTS = ("decode_steps", "live_positions")
+
+
+def count_latent(vector: jnp.ndarray, lengths: jnp.ndarray,
+                 kv_valid: jnp.ndarray) -> jnp.ndarray:
+    """`vector` (an `expert_pairs` ending in LATENT_COUNTS) plus one decode
+    forward's counts. A parked lane (length 0) is no live row."""
+    new = jnp.stack([jnp.int32(1), jnp.sum(
+        jnp.where(lengths > 0, kv_valid, 0), dtype=jnp.int32)])
+    n = len(LATENT_COUNTS)
+    return jnp.concatenate([vector[:-n], vector[-n:] + new])
+
+
+def write_latent(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
+                 rows: jnp.ndarray) -> KVCache:
+    """`write_kv` for a latent cache: this call's rows ([B, S, rank + rope],
+    the normed latent | the roped shared key; padded here with zeros to the
+    leaf's lanes) scattered into `cache.k` at (layer, slot, position), in
+    place on the layer scan's carry."""
+    B, S = rows.shape[:2]
+    rows = jnp.pad(rows, ((0, 0), (0, 0),
+                          (0, cache.k.shape[-1] - rows.shape[-1])))
+    b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
+    l_idx = jnp.full((B, S), layer, jnp.int32)
+    return cache._replace(k=cache.k.at[l_idx, b_idx, positions].set(
+        rows.astype(cache.k.dtype)))
 
 
 # ---------------------------------------------------------------------------
@@ -842,6 +977,9 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
     from symmetry_tpu.ops import decode_attention as da
 
     kernel = "pallas-interpret" if interpret_mode() else "pallas"
+    latent = getattr(config, "latent", None)
+    if latent is not None:
+        return _latent_paths(latent, capacity, kernel, kv_bytes)
     model = 1 if tp_mesh is None else dict(tp_mesh.shape).get("model", 1)
     if config.num_kv_heads % model:
         return {"prefill": "xla", "decode": "xla"}
@@ -882,6 +1020,74 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
     return {"prefill": kernel, "decode": kernel,
             **({"decode_queries": queries} if queries > 1 else {}),
             "decode_slot_tile": tiles[0], "decode_block_t": tiles[1]}
+
+
+def _latent_paths(latent: LatentAttention, capacity: int, kernel: str,
+                  kv_bytes: int) -> dict:
+    """`attention_paths` of a latent-attention model."""
+    from symmetry_tpu.ops import flash, mla_attention as mla
+
+    block_t = mla.geometry(capacity, latent.lanes, kv_bytes)
+    out = {"kind": "latent", "prefill": kernel,
+           "prefill_form": (f"expanded: keys of {latent.nope + latent.rope}"
+                            f", values of {latent.v} a head (flash)"),
+           **({} if kernel == "xla" else {"prefill_tile": flash.WIDE_TILE}),
+           "decode_form": (f"absorbed: one row of {latent.row} values "
+                           f"({latent.lanes} lanes) a position for every "
+                           f"head")}
+    if block_t is None:
+        return {**out, "decode": "xla", "decode_why": (
+            f"ops/mla_attention.py has no block for a capacity of "
+            f"{capacity} (no multiple of {mla.LANES}): the absorbed form "
+            f"runs as two einsums over the layer's rows")}
+    return {**out, "decode": kernel, "decode_block_t": block_t}
+
+
+def latent_refusals(*, mesh: bool = False, role: str = "unified",
+                    prefix_cache: bool = False, speculative: bool = False,
+                    prefill_chunk: int | None = None,
+                    kv_quant: bool = False) -> list[str]:
+    """Why a model with latent attention (one row of rank + rope values a
+    cached position, no K or V a head) cannot be served under these
+    settings: one sentence a setting, empty when it can (the engine and
+    provider/config.py ask, as of `sparse_refusals`)."""
+    why = []
+    if prefill_chunk is not None:
+        why.append(
+            f"tpu.prefill_chunk {prefill_chunk}: a chunk's queries attend "
+            f"over cached latents, for which there is no flash kernel with "
+            f"a key offset that expands them block by block (the jnp form "
+            f"holds [chunk, capacity] float32 scores a head) — set "
+            f"prefill_chunk: null for a model with latent attention "
+            f"(prompts prefill whole, up to the largest bucket)")
+    if prefix_cache:
+        why.append(
+            "tpu.prefix_cache_mb: the block pool knows one entry shape (K, "
+            "V a head and their scales) and has no block of latent rows — "
+            "leave it unset for a model with latent attention")
+    if speculative:
+        why.append(
+            "tpu.speculative: the verify program over latent rows (several "
+            "positions a slot, absorbed) is not shown to pick what the "
+            "single-position kernel picks — leave it unset for a model "
+            "with latent attention")
+    if role != "unified":
+        why.append(
+            f"tpu.role {role!r}: the KV handoff frame carries K and V "
+            f"planes a head and has no plane for a latent row — a model "
+            f"with latent attention serves unified")
+    if mesh:
+        why.append(
+            "tpu.mesh: the latent row has no head axis to shard and the "
+            "absorbed factors have no sharding rules yet — a model with "
+            "latent attention runs on one device")
+    if kv_quant:
+        why.append(
+            "tpu.kv_quantization int8: a latent row has no head axis for "
+            "the scale planes and no int8 form of the absorbed kernel "
+            "exists — set kv_quantization: null for a model with latent "
+            "attention (the row is cached in the compute dtype)")
+    return why
 
 
 def sparse_forms(paths: dict) -> dict:
@@ -1007,6 +1213,12 @@ def _attention(
 ) -> tuple[jnp.ndarray, KVCache]:
     """The attention mixer: projections, the cache write, attention by the
     routed path, the output projection -> ([B, S, E], cache)."""
+    if getattr(config, "latent", None) is not None:
+        if ring_mesh is not None or tp_mesh is not None:
+            raise ValueError("a model with latent attention runs on one "
+                             "device: no ring_mesh, no tp_mesh")
+        return _latent_attention(x, lp, cache, layer, positions, kv_valid,
+                                 seq_lens, config, prefill_flash)
     B, S, E = x.shape
     D, nq, nkv = config.dim_per_head, config.num_heads, config.num_kv_heads
 
@@ -1178,6 +1390,113 @@ def _attention(
     return qmatmul(attn, lp["wo"]), cache
 
 
+def absorbed_factors(wkvb, latent: LatentAttention, num_heads: int,
+                     dtype=jnp.bfloat16) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`W_kvb` ([.., rank, H * (nope + v)], dense or int8 with column
+    scales) split a head into the absorbed form's factors, (`wuk` [.., H,
+    nope, rank], `wuv` [.., H, rank, v]) in `dtype`: what the decode step
+    multiplies the nope query and the latent-space output by instead of
+    expanding the cache. `absorb_latent` alone calls it for a tree that is
+    served, and holds the factors beside `wkvb`, which the expanded prefill
+    reads."""
+    w = dequantize(wkvb) if isinstance(
+        wkvb, QuantizedTensor) else wkvb.astype(jnp.float32)
+    w = w.reshape(*w.shape[:-1], num_heads, latent.nope + latent.v)
+    wuk = jnp.moveaxis(w[..., :latent.nope], -3, -1)      # [.., H, nope, R]
+    wuv = jnp.moveaxis(w[..., latent.nope:], -3, -2)      # [.., H, R, v]
+    return wuk.astype(dtype), wuv.astype(dtype)
+
+
+def absorb_latent(params: dict, config: ModelConfig,
+                  dtype=jnp.bfloat16) -> dict:
+    """`params` with the absorbed factors of every latent-attention layer
+    (`layers.attn.wuk` / `wuv`, `absorbed_factors` of `wkvb`) in place; a
+    model without latent attention is returned as it is. THE derivation,
+    made once on the finished tree — after quantisation, so that decode
+    multiplies by the same int8-rounded `W_kvb` the prefill expands
+    through: `init_params` ends with it (a preset's leaves are drawn
+    quantised), and a checkpoint's tree passes it after `quantize_params`
+    (engine.py `from_tpu_config`); no converter and no forward derives
+    them again."""
+    latent = getattr(config, "latent", None)
+    if latent is None:
+        return params
+    attn = params["layers"]["attn"]
+    attn["wuk"], attn["wuv"] = jax.jit(
+        lambda w: absorbed_factors(w, latent, config.num_heads, dtype))(
+            attn["wkvb"])
+    return params
+
+
+def _latent_attention(
+    x: jnp.ndarray,             # [B, S, E], already normed
+    lp: dict,
+    cache: KVCache,             # k: FULL [L, B, T, rank + rope]; no v
+    layer: jnp.ndarray,
+    positions: jnp.ndarray,     # [B, S]
+    kv_valid: jnp.ndarray,      # [B]
+    seq_lens: jnp.ndarray,      # [B]
+    config: ModelConfig,
+    prefill_flash: bool,
+) -> tuple[jnp.ndarray, KVCache]:
+    """`_attention` of a latent-attention layer (HF `DeepseekV3Attention`
+    without a query latent). The cache takes the row `c_n | k_r` alone. A
+    prompt from an empty cache attends EXPANDED over its own rows — K =
+    (c_n W_kvb's nope part | k_r for every head), V its v part — on the
+    flash route; every call over the cache attends ABSORBED
+    (ops/mla_attention.py): the decode step through the kernel, anything
+    else through the `jnp` form. Scores scale by (nope + rope) ** -0.5."""
+    from symmetry_tpu.ops import mla_attention as mla
+
+    la = config.latent
+    B, S, _ = x.shape
+    H, R = config.num_heads, la.rank
+    rope = dict(theta=config.rope_theta, interleaved=la.rope_interleave)
+
+    q = qmatmul(x, lp["wq"]).reshape(B, S, H, la.nope + la.rope)
+    q_nope = q[..., :la.nope]
+    q_pe = apply_rope(q[..., la.nope:], positions, **rope)
+    ckr = qmatmul(x, lp["wkva"])                          # [B, S, R + rope]
+    c_n = rms_norm(ckr[..., :R], _norm_w(lp["kv_norm"], config),
+                   config.rms_eps)
+    k_r = apply_rope(ckr[:, :, None, R:], positions, **rope)  # [B, S, 1, r]
+    cache = write_latent(cache, layer, positions,
+                         jnp.concatenate([c_n, k_r[:, :, 0]], axis=-1))
+    scale = (la.nope + la.rope) ** -0.5
+    paths = attention_paths(config, cache.k.shape[2], batch=cache.k.shape[1],
+                            kv_bytes=cache.k.dtype.itemsize)
+    if prefill_flash and paths["prefill"] != "xla":
+        from symmetry_tpu.ops import flash
+
+        kv = qmatmul(c_n, lp["wkvb"]).reshape(B, S, H, la.nope + la.v)
+        k = jnp.concatenate(
+            [kv[..., :la.nope], jnp.broadcast_to(k_r, (B, S, H, la.rope))],
+            axis=-1)
+        # (tiles of 512: 32 heads over 6-9k tokens in 128 x 128 tiles is
+        # bound by the walk's turns, 11% of the MXU's peak against 39-41%
+        # — tools/flash_tile_ab.py on the chip, PERF.md PR 54)
+        attn = flash.flash_prefill_wide(
+            jnp.concatenate([q_nope, q_pe], axis=-1), k, kv[..., la.nope:],
+            seq_lens, interpret=interpret_mode())
+    else:
+        # (zeros against the row's padding lanes)
+        q_abs = jnp.concatenate(
+            [jnp.einsum("bshd,hdr->bshr", q_nope, lp["wuk"]),
+             q_pe, jnp.zeros((B, S, H, la.lanes - la.row), x.dtype)],
+            axis=-1)                                      # [B, S, H, lanes]
+        if S == 1 and paths["decode"] != "xla":
+            o_lat = mla.mla_decode(q_abs[:, 0], cache.k, layer, kv_valid,
+                                   scale=scale, rank=R,
+                                   interpret=interpret_mode())[:, None]
+        else:
+            rows = jax.lax.dynamic_index_in_dim(cache.k, layer, 0,
+                                                keepdims=False)
+            o_lat = mla.absorbed_attention(q_abs, rows, positions, kv_valid,
+                                           scale, R)
+        attn = jnp.einsum("bshr,hrd->bshd", o_lat, lp["wuv"])
+    return qmatmul(attn.reshape(B, S, H * la.v), lp["wo"]), cache
+
+
 def _layer(
     h: jnp.ndarray,             # [B, S, E]
     lp: dict,
@@ -1210,15 +1529,22 @@ def _layer(
 
         y, pairs = moe_mlp(x, lp, config, seq_lens, tp_mesh, stack)
         h = h + y
-        if cache.expert_pairs is not None:
-            # (a sparse-attention model's vector ends in its own counters)
-            extra = cache.expert_pairs.shape[0] - pairs.shape[0]
-            cache = cache._replace(expert_pairs=cache.expert_pairs + (
-                jnp.pad(pairs, (0, extra)) if extra else pairs))
+        cache = add_expert_pairs(cache, pairs)
     else:
         h = h + qmatmul(_act(qmatmul(x, lp["wg"]), config)
                         * qmatmul(x, lp["wu"]), lp["wd"])
     return h, cache
+
+
+def add_expert_pairs(cache: KVCache, pairs: jnp.ndarray) -> KVCache:
+    """`cache` with one layer's valid (token, expert) pairs added to its
+    counter, where it carries one (a sparse- or latent-attention model's
+    vector ends in counters of its own, which `pairs` leaves alone)."""
+    if cache.expert_pairs is None:
+        return cache
+    extra = cache.expert_pairs.shape[0] - pairs.shape[0]
+    return cache._replace(expert_pairs=cache.expert_pairs + (
+        jnp.pad(pairs, (0, extra)) if extra else pairs))
 
 
 def _act(x: jnp.ndarray, config: ModelConfig) -> jnp.ndarray:
@@ -1392,9 +1718,12 @@ def logits_from_hidden(params: dict, config: ModelConfig,
 # DeltaNet's q|k|v|z projection or the short convolution's B|C|x), `sg` /
 # `su` / `sd` the shared expert's (models/hybrid.py; lfm2_moe's leading
 # dense layers are `wg` / `wu` / `wd` of the stack `layers.dense`).
-# `wqi` / `wki` are the sparse attention's index projections.
+# `wqi` / `wki` are the sparse attention's index projections, `wkva` /
+# `wkvb` the latent attention's down- and up-projection (its absorbed
+# factors `wuk` / `wuv` are derived from `wkvb` and held in bfloat16).
 QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "in_proj",
-              "out_proj", "sg", "su", "sd", "wqi", "wki", "lm_head")
+              "out_proj", "sg", "su", "sd", "wqi", "wki", "wkva", "wkvb",
+              "lm_head")
 # Those of them stacked along a leading layers axis.
 STACKED_KEYS = QUANT_KEYS[:-1]
 
@@ -1660,6 +1989,107 @@ def hf_config_diffusion(config: MoEConfig) -> dict:
     }
 
 
+def hf_config_latent(config: "HybridConfig") -> dict:
+    """A latent-attention config as its published `config.json` keys (HF
+    `deepseek_v3`: what `config_from_hf` reads back, and what the plain
+    reference — `benchmarks/reference/latent_moe_decoder.py` — is given)."""
+    c, la = config, config.latent
+    return {
+        "model_type": "deepseek_v3", "vocab_size": c.vocab_size,
+        "hidden_size": c.hidden_size, "num_hidden_layers": c.num_layers,
+        "num_attention_heads": c.num_heads,
+        "num_key_value_heads": c.num_kv_heads, "head_dim": la.rope,
+        "q_lora_rank": None, "kv_lora_rank": la.rank,
+        "qk_nope_head_dim": la.nope, "qk_rope_head_dim": la.rope,
+        "qk_head_dim": la.nope + la.rope, "v_head_dim": la.v,
+        "rope_interleave": la.rope_interleave,
+        "intermediate_size": c.dense_intermediate_size,
+        "moe_intermediate_size": c.intermediate_size,
+        "first_k_dense_replace": c.num_dense_layers, "moe_layer_freq": 1,
+        "n_routed_experts": c.num_experts,
+        "n_shared_experts": (c.shared_intermediate_size
+                             // c.intermediate_size),
+        "num_experts_per_tok": c.num_experts_per_tok,
+        "n_group": 1, "topk_group": 1, "topk_method": "noaux_tc",
+        "scoring_func": "sigmoid", "norm_topk_prob": True,
+        "routed_scaling_factor": c.routed_scaling_factor,
+        "hidden_act": "silu", "attention_bias": False,
+        "rope_theta": c.rope_theta, "rope_scaling": None,
+        "rms_norm_eps": c.rms_eps,
+        "max_position_embeddings": c.max_position,
+        "tie_word_embeddings": c.tie_embeddings,
+    }
+
+
+def _latent_config_from_hf(hf: dict[str, Any]) -> "HybridConfig":
+    """`config_from_hf` of HF `deepseek_v3`. What would change the layer
+    and is not built raises, in a sentence, rather than being ignored."""
+    if hf.get("q_lora_rank") is not None:
+        raise ValueError(
+            f"deepseek_v3 with q_lora_rank {hf['q_lora_rank']}: a query "
+            f"latent (q_a_proj, q_a_layernorm, q_b_proj) is not implemented")
+    if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+        raise ValueError(
+            f"deepseek_v3 with n_group {hf.get('n_group')} / topk_group "
+            f"{hf.get('topk_group')}: group-limited routing is not "
+            f"implemented (one group alone, where the limit is the identity)")
+    if hf.get("rope_scaling") is not None:
+        raise ValueError(
+            "deepseek_v3 with rope_scaling: rotary scaling by frequency "
+            "bands (and its mscale on the softmax scale) is not implemented")
+    if hf.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError(
+            f"deepseek_v3 with topk_method {hf['topk_method']!r}: only "
+            f"noaux_tc (selection by score + e_score_correction_bias) is "
+            f"implemented")
+    if hf.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(
+            f"deepseek_v3 with scoring_func {hf['scoring_func']!r}: only "
+            f"the sigmoid router is implemented")
+    if not hf.get("norm_topk_prob", True):
+        raise ValueError("deepseek_v3 with norm_topk_prob false is not "
+                         "implemented")
+    if hf.get("moe_layer_freq", 1) != 1:
+        raise ValueError("deepseek_v3 with moe_layer_freq other than 1 is "
+                         "not implemented")
+    if hf.get("attention_bias"):
+        raise ValueError("deepseek_v3 with attention_bias is not "
+                         "implemented")
+    n_layers, n_dense = hf["num_hidden_layers"], hf.get(
+        "first_k_dense_replace", 0)
+    nope, rope = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
+    return HybridConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_layers=n_layers, num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf.get("num_key_value_heads",
+                            hf["num_attention_heads"]),
+        # the routed expert's width; `intermediate_size` is the leading
+        # dense layers'
+        intermediate_size=hf["moe_intermediate_size"],
+        # the published key: the rotary width, which sizes no matrix here
+        # (a head's q and k are nope + rope wide: `latent`)
+        head_dim=hf.get("head_dim", rope),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        max_position=hf.get("max_position_embeddings", 4096),
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        shared_intermediate_size=(hf.get("n_shared_experts", 0)
+                                  * hf["moe_intermediate_size"]),
+        latent=LatentAttention(
+            rank=hf["kv_lora_rank"], rope=rope, nope=nope,
+            v=hf["v_head_dim"],
+            rope_interleave=bool(hf.get("rope_interleave", True))),
+        layer_types=("latent_attention",) * n_layers,
+        num_dense_layers=n_dense,
+        dense_intermediate_size=hf["intermediate_size"] if n_dense else 0,
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        router_norm_eps=1e-20,
+    )
+
+
 def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
     """Build a ModelConfig from an HF config.json dict (llama/mistral/
     qwen2/mixtral shapes; mixtral's num_local_experts selects MoEConfig)."""
@@ -1679,6 +2109,8 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
     sliding = hf.get("sliding_window")
     if hf.get("use_sliding_window") is False:
         sliding = None
+    if hf.get("model_type") == "deepseek_v3":
+        return _latent_config_from_hf(hf)
     if hf.get("model_type") == "qwen3_next":
         if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1:
             raise ValueError("qwen3_next with dense-MLP layers "

@@ -137,7 +137,20 @@ ROUTED_MIN_TOKENS = 1024
 # matmuls are the longer pole. They cross between 128 and 256 and no
 # dispatch lies between: decode (128 slots) keeps the mixture, a prefill
 # dispatch of 256 tokens or more is routed.
-ROUTED_FROM = {(72, 10): 256, (512, 10): 1, (128, 8): 128, (32, 4): 256}
+# 128 top 6 at expert width 768 (kanana-2-30b-a3b; `--shape 128,6,2048,768`;
+# PERF.md, PR 54; floor 0.74 for all 128 experts): 32 tokens 3.40 / 0.68 /
+# 0.83, 64: 4.01 / 0.84 / 0.83, 128: 4.71 / 0.93 / 1.00, 256: 6.44 / 1.01 /
+# 1.90, 512: 6.64 / 1.18 / 3.83, 2,048: 8.98 / 2.70 / 14.93, 8,192: 20.87 /
+# 8.64 / 59.38. At 32 tokens 192 pairs hit ~100 of the 128 experts and the
+# kernel, reading those alone, wins by 18%; at 64 (decode's 64 slots: 384
+# pairs hit ~122) the two tie within 1.2%, the mixture's 21x FLOPs still
+# behind the weight stream (89% of the floor); from 128 the kernel wins (7%,
+# then 1.9x at 256). One threshold cannot say "routed at 32, either at 64":
+# the tie keeps the mixture, as granite's did, and the crossing is the
+# first size from which routing wins at every larger one — decode keeps the
+# mixture, every prefill of the report cell (6,912 tokens and up) is routed.
+ROUTED_FROM = {(72, 10): 256, (512, 10): 1, (128, 8): 128, (32, 4): 256,
+               (128, 6): 128}
 
 
 def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
@@ -150,7 +163,8 @@ def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
 
 def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int, *,
                 score: str = "softmax", bias: jnp.ndarray | None = None,
-                scale: float = 1.0) -> tuple[jnp.ndarray, jnp.ndarray]:
+                scale: float = 1.0, eps: float = 1e-6
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """[T, D] tokens -> (gates [T, k] float32, experts [T, k] int32).
     Logits accumulate and come out in float32; the softmax is over the k
     selected logits (mixtral: normalise AFTER selection).
@@ -159,14 +173,17 @@ def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int, *,
     scores sigmoid(logits); the k experts are those of the largest score +
     `bias` (the layer's `expert_bias` [X] float32; ties toward the lower
     index); the gates are the UNBIASED scores of the selected, divided by
-    their sum + 1e-6 (`norm_topk_prob`), times `scale`."""
+    their sum + `eps` (`norm_topk_prob`), times `scale`. deepseek_v3's
+    router (HF `DeepseekV3TopkRouter` at one group, `noaux_tc`) is this
+    form with `bias` its `e_score_correction_bias`, `scale` its
+    `routed_scaling_factor` and `eps` 1e-20."""
     logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
     if score == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         _, top_idx = jax.lax.top_k(
             scores if bias is None else scores + bias.astype(jnp.float32), k)
         top = jnp.take_along_axis(scores, top_idx, axis=-1)
-        gates = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-6)
+        gates = top / (jnp.sum(top, axis=-1, keepdims=True) + eps)
         return gates * scale, top_idx.astype(jnp.int32)
     top_vals, top_idx = jax.lax.top_k(logits, k)
     return jax.nn.softmax(top_vals, axis=-1), top_idx.astype(jnp.int32)
@@ -174,11 +191,12 @@ def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int, *,
 
 def routing_of(config, lp: dict) -> dict:
     """`route_top_k`'s keywords for this config and layer: empty (the
-    softmax form) for every family but lfm2_moe."""
+    softmax form) for every family but lfm2_moe and deepseek_v3."""
     if getattr(config, "router_score", "softmax") != "sigmoid":
         return {}
     return {"score": "sigmoid", "bias": lp.get("expert_bias"),
-            "scale": config.routed_scaling_factor}
+            "scale": config.routed_scaling_factor,
+            "eps": config.router_norm_eps}
 
 
 def grouped_matmul_form(w, n_rows: int, one_device: bool = True) -> dict:

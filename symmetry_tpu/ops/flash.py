@@ -16,6 +16,12 @@ block qi, giving the ~2x FLOP saving of causal masking, with the partial
 diagonal block masked by element positions. Ragged prompt lengths
 (`seq_lens`, the padded-bucket contract of engine prefill) mask the same
 way; fully-masked padded rows get a sum-guard instead of NaNs.
+
+`flash_prefill_wide` is the same attention in tiles of 512 x 512 for long
+prompts of many heads (latent attention expanded: 32 heads of 192 / 128 over
+6-9k tokens), where the 128 x 128 walk is bound by the loop's own turns —
+691k of them a 9,344-token prompt, each two matmuls too small to fill the
+MXU's pipeline — and not by the FLOPs.
 """
 
 from __future__ import annotations
@@ -37,7 +43,8 @@ def _flash_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
     seq_len = seqlen_ref[pl.program_id(0)]  # this batch row's true length
 
     q = q_ref[:].astype(jnp.float32) * scale  # [block_q, D]
-    D = q.shape[-1]
+    D = v_ref.shape[-1]  # the value's width: the key's, or (latent
+    # attention expanded: keys of 192, values of 128) its own
 
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
@@ -100,7 +107,7 @@ def _flash_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
 def flash_prefill(
     q: jnp.ndarray,         # [B, S, H, D]
     k: jnp.ndarray,         # [B, S, K, D]
-    v: jnp.ndarray,         # [B, S, K, D]
+    v: jnp.ndarray,         # [B, S, K, Dv]: D, or a value width of its own
     seq_lens: jnp.ndarray,  # [B] int32 valid prompt lengths
     *,
     block_q: int = 128,
@@ -111,14 +118,15 @@ def flash_prefill(
 ) -> jnp.ndarray:
     """Causal self-attention over a fresh (cache-empty) padded prompt.
 
-    Returns [B, S, H, D] in q's dtype. Requires S % block == 0 (buckets are
+    Returns [B, S, H, Dv] in q's dtype (scores scale by D ** -0.5, the
+    key's width). Requires S % block == 0 (buckets are
     chosen that way); positions are 0..S-1 (prefill-from-empty contract of
     engine prefill, engine.py). `window` restricts attention to the last
     `window` keys (sliding-window models); blocks wholly outside the
     window are skipped, making long-prompt prefill O(S·window).
     """
     B, S, H, D = q.shape
-    K = k.shape[2]
+    K, Dv = k.shape[2], v.shape[3]
     group = H // K
     block_q = min(block_q, S)
     block_k = min(block_k, S)
@@ -152,16 +160,123 @@ def flash_prefill(
                              lambda b, h, qi, sl: (b, h, qi, 0)),
                 pl.BlockSpec((None, None, S, D),
                              lambda b, h, qi, sl: (b, h // group, 0, 0)),
-                pl.BlockSpec((None, None, S, D),
+                pl.BlockSpec((None, None, S, Dv),
                              lambda b, h, qi, sl: (b, h // group, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((None, None, block_q, D),
+            out_specs=pl.BlockSpec((None, None, block_q, Dv),
                                    lambda b, h, qi, sl: (b, h, qi, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         interpret=interpret,
     )(seq_lens, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
+
+
+WIDE_NAME = "flash_wide"
+# The wide walk's tile, of 256 / 512 / 1024 on the chip at 32 heads of 192 /
+# 128 (tools/flash_tile_ab.py; PERF.md, PR 54): a call over a 9,344 bucket
+# 15.5 / 10.5 / 10.9 ms against 36.7 in 128 x 128, over 6,912 8.3 / 5.5 / 6.1
+# against 20.4.
+WIDE_TILE = 512
+
+
+def _flash_wide_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *,
+                       scale: float, block: int):
+    """One tile of `block` queries of one head against the key tiles at or
+    under its diagonal. Beside `_flash_kernel`: the operands go to the MXU
+    as they are stored (bfloat16) and the scores scale in float32; only the
+    diagonal tile is masked (a real query sees no key past itself, so none
+    past the prompt's length either); a query tile that lies wholly in the
+    bucket's padding — the last tiles, the longest walks — writes zeros."""
+    qi = pl.program_id(2)
+    seq_len = seqlen_ref[pl.program_id(0)]
+    Dv = v_ref.shape[-1]
+
+    def tile(j, carry, diagonal=False):
+        m, l, acc = carry
+        k_blk = k_ref[pl.ds(j * block, block), :]
+        v_blk = v_ref[pl.ds(j * block, block), :]
+        s = jax.lax.dot_general(
+            q_ref[:], k_blk, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if diagonal:
+            row = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+            s = jnp.where(col <= row, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m - m_new)
+        l_new = l * correction + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * correction + jax.lax.dot_general(
+            p.astype(v_blk.dtype), v_blk,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    @pl.when(qi * block < seq_len)
+    def _():
+        carry = (jnp.full((block, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((block, 1), jnp.float32),
+                 jnp.zeros((block, Dv), jnp.float32))
+        carry = jax.lax.fori_loop(0, qi, tile, carry)
+        _, l, acc = tile(qi, carry, diagonal=True)
+        o_ref[:] = (acc / l).astype(o_ref.dtype)  # l >= 1: the diagonal
+
+    @pl.when(qi * block >= seq_len)
+    def _():
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def flash_prefill_wide(
+    q: jnp.ndarray,         # [B, S, H, D]
+    k: jnp.ndarray,         # [B, S, H, D]
+    v: jnp.ndarray,         # [B, S, H, Dv]
+    seq_lens: jnp.ndarray,  # [B] int32 valid prompt lengths
+    *,
+    block: int = WIDE_TILE,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """`flash_prefill` (causal, from an empty cache, one key head a query
+    head, no window) in tiles of `block` x `block`; S is padded up to a
+    multiple of `block` here (a bucket is a multiple of 128) and the rows
+    past a prompt's length come back as garbage or zeros, by the same
+    contract. Returns [B, S, H, Dv]."""
+    B, S, H, D = q.shape
+    Dv = v.shape[3]
+    block = min(block, S)
+    pad = -S % block
+    qt, kt, vt = (jnp.pad(a.transpose(0, 2, 1, 3),
+                          ((0, 0), (0, 0), (0, pad), (0, 0)))
+                  for a in (q, k, v))
+    Sp = S + pad
+    lanes = lambda d: -(-d // 128) * 128  # noqa: E731
+    # K and V of one head stay whole in VMEM, double-buffered, beside the
+    # tile's float32 scores and probabilities
+    vmem = (4 * Sp * (lanes(D) + lanes(Dv)) + 6 * 4 * block * block
+            + (8 << 20))
+    out = pl.pallas_call(
+        functools.partial(_flash_wide_kernel, scale=D ** -0.5, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # seq_lens
+            grid=(B, H, Sp // block),
+            in_specs=[
+                pl.BlockSpec((None, None, block, D),
+                             lambda b, h, qi, sl: (b, h, qi, 0)),
+                pl.BlockSpec((None, None, Sp, D),
+                             lambda b, h, qi, sl: (b, h, 0, 0)),
+                pl.BlockSpec((None, None, Sp, Dv),
+                             lambda b, h, qi, sl: (b, h, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, None, block, Dv),
+                                   lambda b, h, qi, sl: (b, h, qi, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sp, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+        name=WIDE_NAME,
+        interpret=interpret,
+    )(seq_lens, qt, kt, vt)
+    return out[:, :, :S].transpose(0, 2, 1, 3)
 
 
 def flash_prefill_tp(q, k, v, seq_lens, *, mesh, **kw) -> jnp.ndarray:

@@ -64,6 +64,7 @@ def apply_rope(
     theta: float = 500000.0,
     rotary_dim: int | None = None,
     mrope_section: tuple[int, ...] | None = None,
+    interleaved: bool = False,
 ) -> jnp.ndarray:
     """Rotate q or k by absolute position; returns x's dtype. With
     `rotary_dim` under the head size (partial rotary: HF
@@ -71,7 +72,15 @@ def apply_rope(
     head rotate, as a head of that size would; the rest pass through.
     3-D `positions` are the components of a multimodal rotary
     (`mrope_cos_sin`, by `mrope_section`); 2-D ones are three equal
-    components, which is the plain rotary below."""
+    components, which is the plain rotary below.
+    `interleaved` (HF `rope_interleave`, the DeepseekV3 family): frequency i
+    turns the channel PAIR (2i, 2i + 1), not (i, i + d / 2). As HF's
+    `apply_rotary_pos_emb_interleave` does, the channels are first put in
+    the order evens | odds and then rotated by halves, so the result is the
+    pairwise rotation IN THAT ORDER: q and k take the same permutation and
+    every q . k is the pairwise rotary's."""
+    if interleaved:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
         return jnp.concatenate(
             [apply_rope(x[..., :rotary_dim], positions, theta,

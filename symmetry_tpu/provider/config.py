@@ -349,7 +349,8 @@ class ConfigManager:
         refuses the same for a checkpoint, whose config it learns late).
         A preset with learned sparse attention keeps an index key a cached
         position: models/llama.py sparse_refusals, the same settings the
-        same way."""
+        same way; one with latent attention a single row a position:
+        latent_refusals, which also refuses an int8 cache."""
         import math
 
         from symmetry_tpu.models.llama import PRESETS
@@ -391,6 +392,20 @@ class ConfigManager:
             if refused:
                 raise ConfigError(f"model_preset {tpu.model_preset!r}: "
                                   + "; ".join(refused))
+        if getattr(preset, "latent", None) is not None:
+            from symmetry_tpu.models.llama import latent_refusals
+
+            refused = latent_refusals(
+                mesh=mesh,
+                role=tpu.role or "unified",
+                prefix_cache=bool(tpu.prefix_cache_mb),
+                speculative=bool(tpu.speculative),
+                prefill_chunk=tpu.prefill_chunk,
+                kv_quant=tpu.kv_quantization == "int8")
+            if refused:
+                raise ConfigError(f"model_preset {tpu.model_preset!r}: "
+                                  + "; ".join(refused))
+            return  # no recurrent kind: a row a position is all it keeps
         if not getattr(preset, "layer_types", None):
             return
         from symmetry_tpu.models.hybrid import state_refusals

@@ -780,3 +780,85 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
     # weights 8.4 GB and the cache 1.04 GB are arguments; what the program
     # adds (logits of [512, 151936] and the sampler's windows) stays small
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def _kanana_text(one_chip, monkeypatch, rows, capacity, S):
+    """kanana-2-30b-a3b's trunk (all 8 layers: the dense one and seven
+    expert layers) compiled for a described v5e at [rows, S] tokens over a
+    rows x capacity latent cache: (config, optimised HLO)."""
+    from symmetry_tpu.models import llama, moe
+
+    for module in (llama, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    cfg = llama.preset("kanana-2-30b-a3b")
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+        slice_above=1 << 40))
+    cache = shaped(lambda: llama.init_cache(cfg, rows, capacity,
+                                            jnp.bfloat16))
+    assert cache.k.shape == (8, rows, capacity, 640) and cache.v is None
+    tok = jax.ShapeDtypeStruct((rows, S), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    prefill = S > 1
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(
+            (lambda p, t, c, n: llama.forward_hidden(
+                p, cfg, t, c, n, prefill_flash=True)) if prefill else
+            (lambda p, t, c, n: llama.forward_hidden(p, cfg, t, c)),
+            donate_argnums=(2,)).lower(params, tok, cache, lens).compile(
+        ).as_text()
+    return cfg, text
+
+
+def test_kanana_decode_step_reads_the_latent_rows_where_they_lie(
+        one_chip, no_cache, monkeypatch):
+    """The decode step at the cell (64 slots x 11,776): attention is the
+    `mla_decode` call in each of the two scans (the dense layer's, the
+    expert layers'), the absorbed form — no [slots, capacity, 32 heads, ..]
+    expansion of the cache exists, and the cache's one leaf is neither
+    copied nor sliced a layer at a time."""
+    from symmetry_tpu.ops import mla_attention as mla
+
+    B, T = 64, 11776
+    cfg, text = _kanana_text(one_chip, monkeypatch, B, T, 1)
+    assert len(re.findall(rf"%{mla.DECODE_NAME}[.\d]* = ", text)) == 2
+    H = cfg.num_heads
+    expanded = [line.strip()[:160] for line in text.splitlines()
+                if re.search(rf"= \w+\[{B},{T},{H},\d+\]", line)
+                or re.search(rf"= \w+\[{B},{H},{T},\d+\]", line)
+                or re.search(rf"= \w+\[{B},{H},(1,)?{T}\]", line)]
+    assert not expanded, expanded[0]
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= bf16\[(\d+,)?{B},{T},640\]\S* "
+                          rf"(copy|transpose|dynamic-slice|fusion)\(", line)
+             and "dynamic-update-slice" not in line
+             and "scatter" not in line]
+    assert not moved, moved[0]
+
+
+def test_kanana_prefill_expands_through_flash_and_routes_its_experts(
+        one_chip, no_cache, monkeypatch):
+    """A 9,728-token prefill row: attention is the flash kernel at keys of
+    192 and values of 128 in its wide tiles (one call a scan: Mosaic takes
+    the 512 x 512 walk with a head's whole K and V in VMEM at this length),
+    the seven expert layers are
+    routed — three `moe_gmm` calls whose weight operands are the layers'
+    int8 stacks as they lie — and no layer's experts are sliced out."""
+    from symmetry_tpu.ops import flash, gmm
+
+    cfg, text = _kanana_text(one_chip, monkeypatch, 1, 9728, 9728)
+    assert len(re.findall(rf"%{flash.WIDE_NAME}[.\d]* = ", text)) == 2
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) >= 5
+    assert len(re.findall(rf"%{gmm.NAME}[.\d]* = ", text)) == 3
+    sliced = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= s8\[(1,)?128,(2048,768|768,2048)\]\S* "
+                           r"(copy|dynamic-slice|fusion)\(", line)]
+    assert not sliced, sliced[0]

@@ -38,6 +38,7 @@ def test_there_is_a_cut_configuration_to_hold():
     assert "qwen3-next-80b-a3b.json" in cut_files()
     assert "keye-vl-2.0-30b-a3b.json" in cut_files()
     assert "sdar-30b-a3b-chat.json" in cut_files()
+    assert "kanana-2-30b-a3b.json" in cut_files()
 
 
 @pytest.mark.parametrize("name", cut_files())
@@ -84,6 +85,15 @@ def test_a_cut_keeps_a_whole_period_of_the_published_pattern(name):
         assert c["num_hidden_layers"] == 12
         assert c["published"]["num_hidden_layers"] == 48
         assert c["mlp_only_layers"] == [] and c["decoder_sparse_step"] == 1
+        return
+    if c.get("model_type") == "deepseek_v3":
+        # a leading dense layer, then 47 identical expert layers: the dense
+        # layer is kept with seven expert layers after it (over the floor of
+        # four), a sixth of the model (stage 1 of 6)
+        assert c["num_hidden_layers"] == 8
+        assert c["published"] == {"num_hidden_layers": 48}
+        assert c["first_k_dense_replace"] == 1 and c["moe_layer_freq"] == 1
+        assert c["num_hidden_layers"] - c["first_k_dense_replace"] >= 4
         return
     if "layer_types" not in c["reduced"]:
         pytest.skip("no layer pattern was cut")
@@ -192,6 +202,58 @@ def test_a_cut_file_is_the_programs_preset(name):
                      "640", "byte tokenizer", "6144", "fan_in"):
             assert word in text, word
         assert "stage 1 of 4" in c["deployment"]
+    if c.get("model_type") == "deepseek_v3":
+        from symmetry_tpu.models.llama import LatentAttention, config_from_hf
+
+        # every published key the program reads, through its own reader: the
+        # file IS the preset, and no width was cut
+        assert config_from_hf(c) == p
+        assert c["reduced"] == ["num_hidden_layers"]
+        assert p.latent == LatentAttention(
+            rank=c["kv_lora_rank"], rope=c["qk_rope_head_dim"],
+            nope=c["qk_nope_head_dim"], v=c["v_head_dim"],
+            rope_interleave=c["rope_interleave"]) == LatentAttention(
+            rank=512, rope=64, nope=128, v=128, rope_interleave=True)
+        assert c["qk_head_dim"] == p.latent.nope + p.latent.rope == 192
+        assert c["q_lora_rank"] is None and c["rope_scaling"] is None
+        assert (p.num_experts, p.num_experts_per_tok) == (
+            c["n_routed_experts"], c["num_experts_per_tok"]) == (128, 6)
+        assert p.shared_intermediate_size == (
+            c["n_shared_experts"] * c["moe_intermediate_size"]) == 1536
+        assert (p.num_dense_layers, p.dense_intermediate_size) == (
+            c["first_k_dense_replace"], c["intermediate_size"]) == (1, 6144)
+        assert (p.router_score, p.router_bias, p.routed_scaling_factor,
+                p.router_norm_eps) == ("sigmoid", True, 2.448, 1e-20)
+        assert (c["n_group"], c["topk_group"], c["topk_method"]) == (
+            1, 1, "noaux_tc")
+        assert p.vocab_size == 128256
+        assert p.max_position == c["max_position_embeddings"] == 32768
+        assert set(p.layer_types) == {"latent_attention"}
+        tpu = c["tpu"]
+        assert (tpu["max_batch_size"], tpu["max_seq_len"],
+                tpu["decode_block"]) == (64, 11776, 16)
+        assert tpu["prefill_chunk"] is None
+        assert tpu["kv_quantization"] is None
+        assert tpu["quantization"] == "int8"
+        # every bucket a multiple of the flash kernel's block, the cell's
+        # longest prompt (9,216 + 19 of template) inside the largest, and
+        # room in a slot for the longest answer and the lookahead
+        assert all(b % 128 == 0 for b in tpu["prefill_buckets"])
+        assert max(tpu["prefill_buckets"]) >= 9216 + c["template_tokens"]
+        assert tpu["max_seq_len"] >= 9216 + 19 + 2048 + 2 * 16
+        assert tpu["max_seq_len"] % 512 == 0
+        assert (c["decode_program"], c["prefill_program"]) == (
+            "decode_block", "prefill")
+        assert c["reference"].endswith("latent_moe_decoder.py")
+        # every `assumed` item the issue lists is stated
+        text = " ".join(c["assumed"])
+        for word in ("192 ** -0.5", "mscale", "rope_interleave",
+                     "kv_a_layernorm", "1e-20", "bfloat16", "FP8", "11776",
+                     "byte tokenizer", "128256", "kv_a_proj_with_mqa",
+                     "e_score_correction_bias", "fan_in"):
+            assert word in text, word
+        assert "stage 1 of 6" in c["deployment"]
+        assert "bfloat16" in c["deployment"] and "W_UK" in c["deployment"]
     if "layer_types" in c:
         assert list(p.layer_types) == c["layer_types"]
         assert (p.num_experts, p.num_experts_per_tok,

@@ -265,12 +265,13 @@ def test_the_tail_is_kept_in_the_caches_dtype_and_read_as_it_was_written():
 
 # ------------------------------------------------------------ falsifications
 
-def biased_gates(x, router, k, *, score="softmax", bias=None, scale=1.0):
+def biased_gates(x, router, k, *, score="softmax", bias=None, scale=1.0,
+                 eps=1e-6):
     """`route_top_k` with the gates taken from the BIASED scores."""
     scores = jax.nn.sigmoid(jnp.dot(x, router,
                                     preferred_element_type=jnp.float32))
     top, idx = jax.lax.top_k(scores + bias, k)
-    return (top / (jnp.sum(top, -1, keepdims=True) + 1e-6) * scale,
+    return (top / (jnp.sum(top, -1, keepdims=True) + eps) * scale,
             idx.astype(jnp.int32))
 
 

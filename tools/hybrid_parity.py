@@ -186,14 +186,14 @@ def run_control(name: str, cfg, params, run_program):
                            params)
     true_route = moe.route_top_k
 
-    def biased_gates(x, router, k, *, score, bias, scale):
+    def biased_gates(x, router, k, *, score, bias, scale, eps):
         scores = jax.nn.sigmoid(jnp.dot(
             x, router, preferred_element_type=jnp.float32))
         top, idx = jax.lax.top_k(scores + bias, k)
-        return (top / (jnp.sum(top, -1, keepdims=True) + 1e-6) * scale,
+        return (top / (jnp.sum(top, -1, keepdims=True) + eps) * scale,
                 idx.astype(jnp.int32))
 
-    def bf16_router(x, router, k, *, score, bias, scale):
+    def bf16_router(x, router, k, *, score, bias, scale, eps):
         # an EXPLICIT rounding of the logits to bfloat16's 8 bits: a dot
         # with a bfloat16 result is not one — XLA keeps the float32
         # accumulator through the fusion (`xla_allow_excess_precision`) and
@@ -202,7 +202,7 @@ def run_control(name: str, cfg, params, run_program):
             x, router, preferred_element_type=jnp.float32), 8, 7))
         _, idx = jax.lax.top_k(scores + bias, k)
         top = jnp.take_along_axis(scores, idx, axis=-1)
-        return (top / (jnp.sum(top, -1, keepdims=True) + 1e-6) * scale,
+        return (top / (jnp.sum(top, -1, keepdims=True) + eps) * scale,
                 idx.astype(jnp.int32))
 
     moe.route_top_k = {"biased-gates": biased_gates,

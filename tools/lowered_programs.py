@@ -4,11 +4,12 @@ change what an existing configuration runs?".
     JAX_PLATFORMS=cpu python tools/lowered_programs.py OUT_DIR [preset ...]
 
 For each preset (default: mistral-7b, qwen2-7b, granite-4.0-h-small,
-qwen3-next-80b-a3b, keye-vl-2.0-30b-a3b, lfm2-8b-a1b and sdar-30b-a3b-chat
-(its diffusion programs at 2 denoise steps a block, the static rule) at the
-closed cells' shape, 128 slots x 640, int8 weights + int8 KV, decode_block
-16; and tiny-moe8 — the stand-in for mixtral-8x7b's sharded programs — on a
-`model: 4` mesh of virtual CPU devices: twenty-four programs) it writes the
+qwen3-next-80b-a3b, keye-vl-2.0-30b-a3b, lfm2-8b-a1b, sdar-30b-a3b-chat
+(its diffusion programs at 2 denoise steps a block, the static rule) and
+kanana-2-30b-a3b (its latent cache in bfloat16) at the closed cells' shape,
+128 slots x 640, int8 weights + int8 KV, decode_block 16; and tiny-moe8 —
+the stand-in for mixtral-8x7b's sharded programs — on a `model: 4` mesh of
+virtual CPU devices: twenty-seven programs) it writes the
 StableHLO of the engine's OWN jits — `decode_block`, `prefill` at (8, 256)
 and `insert_all` — lowered from shapes alone (nothing is built or run), as
 `OUT_DIR/<preset>.<program>.txt` and prints one sha256 a file. The text
@@ -29,7 +30,9 @@ sdar-30b-a3b-chat's `decode_block` and `prefill` and keye-vl-2.0-30b-a3b's
 keye's `decode_block`, a dense mixture, differs in the ORDER of its scan's
 operands alone — the stacks ride it as constants it never reads. PR 50, a
 block of queries a slot through the decode kernel: 23 of the 24 identical,
-sdar-30b-a3b-chat's `decode_block` the one that differs.)
+sdar-30b-a3b-chat's `decode_block` the one that differs. PR 54, latent
+attention as a mixer kind of the hybrid trunk: all 24 older files
+identical, three new ones.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
@@ -65,7 +68,9 @@ def shapes(fn):
 def bare_engine(cfg):
     """An engine whose jits exist and whose arrays do not."""
     e = object.__new__(eng_mod.InferenceEngine)
-    e.config, e.mesh, e.kv_quant, e.decode_block = cfg, None, True, BLOCK
+    # (a latent cache row has no int8 form: that preset's cache is bfloat16)
+    e.config, e.mesh, e.decode_block = cfg, None, BLOCK
+    e.kv_quant = getattr(cfg, "latent", None) is None
     e.spec, e.prefix_block, e.cache_dtype = None, 16, jnp.bfloat16
     e.max_slots, e.max_seq_len = SLOTS, CAPACITY
     e._state_shardings = e._cache_shardings = None
@@ -90,7 +95,7 @@ def programs(e, params, state):
     # bucket and hands the block over where the others hand one token)
     block = getattr(getattr(cfg, "diffusion", None), "block", 0)
     scratch = shapes(lambda: llama.init_cache(
-        cfg, n, bucket + block, jnp.bfloat16, quantized=True,
+        cfg, n, bucket + block, jnp.bfloat16, quantized=e.kv_quant,
         count_experts=e._count_experts))
     first = (jax.ShapeDtypeStruct((n, block), i32) if block else vec(i32))
     yield "decode_block", e._decode.lower(
@@ -108,7 +113,7 @@ def main() -> int:
     names = sys.argv[2:] or ["mistral-7b", "qwen2-7b", "tiny-moe8",
                              "granite-4.0-h-small", "qwen3-next-80b-a3b",
                              "keye-vl-2.0-30b-a3b", "lfm2-8b-a1b",
-                             "sdar-30b-a3b-chat"]
+                             "sdar-30b-a3b-chat", "kanana-2-30b-a3b"]
     os.makedirs(out_dir, exist_ok=True)
     for name in names:
         cfg = llama.preset(name)
@@ -135,7 +140,7 @@ def main() -> int:
                 cfg, jax.random.key(0), jnp.bfloat16, quantize=True))
             state = shapes(lambda: eng_mod.DecodeState(
                 cache=llama.init_cache(cfg, SLOTS, CAPACITY, jnp.bfloat16,
-                                       quantized=True,
+                                       quantized=e.kv_quant,
                                        count_experts=e._count_experts),
                 last_token=jnp.zeros((SLOTS,), jnp.int32),
                 temperature=jnp.zeros((SLOTS,), jnp.float32),
