@@ -49,16 +49,19 @@ Protocol: JSON lines.
             "device": {"platform", "device_kind", "device_count",
                        "hbm": [{"bytes_in_use", "bytes_limit"}, …]},
             "attention": {"prefill", "decode"},
-            "sampling": {"top_k", "groups"?, "width"?, "cap"?},
+            "sampling": {"top_k", "cap"?, "stages"?: [{"groups",
+                         "width"}, …], "ranked"?},
             "moe"?: {"experts", "top_k", "layout", "route": {"decode",
                      "prefill"}, "quantized_leaf_route"}}
             (after warmup. `moe` only for an expert model: engine.py
             moe_report. `device` is what JAX handed this process and
             its per-device memory_stats() once every program has
             compiled; `attention` is "pallas" | "pallas-interpret" |
-            "xla" per program; `sampling.top_k` is "grouped" (with its
-            geometry) | "direct", ops/sampling.py top_k_route. The
-            stats reply repeats all three.)
+            "xla" per program; `sampling.top_k` is "grouped" (with each
+            stage's groups and width — of the vocabulary, then of what
+            the stage before kept — and how many entries are `ranked`
+            last) | "direct", ops/sampling.py top_k_route. The stats
+            reply repeats all three.)
            {"op": "clock", "t0", "t": our monotonic at receipt}
            {"op": "trace", "clock", "components": [{name, spans,
             counters, clock_offset_s}, …]}   (host + scheduler rings,
@@ -436,8 +439,7 @@ class EngineHost:
         # Startup breakdown to stderr: a slow start must carry its own
         # explanation in the provider log (round-3 verdict #1).
         dev, attn = self._startup["device"], self._startup["attention"]
-        samp = ",".join(f"{k}:{v}"
-                        for k, v in self._startup["sampling"].items())
+        samp = json.dumps(self._startup["sampling"], separators=(",", ":"))
         hbm = " ".join(f"{h['bytes_in_use'] / 2**30:.2f}/"
                        f"{h['bytes_limit'] / 2**30:.2f}GiB"
                        for h in dev["hbm"]) or "n/a"

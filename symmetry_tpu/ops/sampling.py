@@ -13,16 +13,36 @@ time is proportional to V and to nothing else — 3.5 ms a decode step on
 [128, 152064], four times the LM head that produced the logits, 0.75 ms
 on [128, 32768] (PERF_LEDGER.jsonl PR 25; PERF.md §6, PR 26). So where the
 vocabulary is wide enough (`top_k_route`: from 16,257 entries at cap 64) the
-window is selected in two stages (`_top_k`): the maxima of groups of
+window is selected in stages (`_top_k`): the maxima of groups of
 TOP_K_GROUP_WIDTH consecutive entries choose the `cap` groups that can hold
-the top `cap`, and only those are ranked — 0.75 ms a step at 152,064. Exact,
-ties included: `lax.top_k` is stable (lowest index first), so an entry
-left out sits behind `cap` groups whose maxima outrank it — `cap` entries
-ahead of it; and the chosen groups are gathered in ascending order, so
-candidate order is vocabulary order and ties break as in the single call.
-Greedy and any top_k <= cap are exact; top-p loses only the probability
-mass beyond the top `cap` tokens (< 1e-3 for typical LM distributions at
-cap=64).
+the top `cap`; the maxima of sub-groups of TOP_K_SUBGROUP_WIDTH consecutive
+entries of those 8,192 choose `cap` sub-groups the same way; and only their
+2,048 entries are ranked. Exact, ties included, by one argument a stage:
+`lax.top_k` is stable (lowest index first), so an entry left out sits behind
+`cap` groups whose maxima outrank it — `cap` entries ahead of it; the chosen
+groups are gathered in ascending order, so the kept entries lie in
+vocabulary order, a stage's groups are runs of consecutive entries of the
+stage before, and ties break as in the single call. (Groups that are not
+contiguous — strided lanes — would break that rule.) Greedy and any
+top_k <= cap are exact; top-p loses only the probability mass beyond the top
+`cap` tokens (< 1e-3 for typical LM distributions at cap=64).
+
+On a v5e (my chip runs, PR 55: tools/top_k_ab.py, PERF.md §6): ranking the
+8,192 kept entries of one stage whole was 0.22 ms of a 0.90 ms selection at
+[128, 152064] but, as a sort over `[128, 4, 8192]`, 4.5 ms of 8.3 at sdar's
+[128, 4, 151936] — the largest op of that cell. The form here takes 0.74 ms
+and 2.75 ms at those shapes (0.21 at [128, 32768], 0.23 at [64, 128256]):
+1.4 ms of the 2.75 is the division by the temperature and the groups'
+maxima, passes over the logits that every form makes. Three things carry
+it, in the order of what they gave: what a stage ranks is reshaped to flat
+rows (`_rows`: a sort over `[128, 4, n]` costs 3.3x the same rows as
+`[512, n]`; reshaping the LOGITS to flat rows instead costs a 311 MB
+relayout copy, 2 ms), the second stage (sorts of 1,187 + 256 + 2,048 for one
+of 8,192), and the map from a kept position back to its group by comparison
+with the `cap` chosen numbers instead of an element gather (0.33 ms a stage
+at 512 rows, 0.002 so). Sub-groups of 16 and 32 read within 0.01 ms of each
+other at 64 and 128 rows and 32 wins by 0.47 ms at 512; 8, three stages
+(32 then 8) and first-stage groups of 256 lost everywhere.
 """
 
 from __future__ import annotations
@@ -33,43 +53,88 @@ import jax.numpy as jnp
 from symmetry_tpu.ops.attention import NEG_INF
 
 SAMPLING_TOP_CAP = 64
-# Width of a vocabulary group in the two-stage selection, and how many
-# groups per kept entry make it worth taking: fixed once from a sweep of
-# W in {128, 256, 512} at V = 152064 and 32768 on a v5e (PERF.md §6, PR 26).
+# Widths of the staged selection's groups — of the vocabulary, then of what
+# the first stage kept — and how many groups per kept entry make the stages
+# worth taking: the first fixed from a sweep of W in {128, 256, 512} at
+# V = 152064 and 32768 on a v5e (PERF.md §6, PR 26), the second from a sweep
+# of {8, 16, 32} (and 32 then 8) at every cell's shape (PERF.md §6, PR 55).
 TOP_K_GROUP_WIDTH = 128
+TOP_K_SUBGROUP_WIDTH = 32
 TOP_K_MIN_GROUPS_PER_CAP = 2
 
 
 def top_k_route(vocab: int, cap: int = SAMPLING_TOP_CAP) -> dict:
     """How `_top_k` selects the top `cap` of `vocab` logits — decided by
     the two static sizes alone, so every call of a served program takes
-    the same route and the engine can report it (startup.sampling)."""
+    the same route and the engine can report it (startup.sampling):
+    each stage's groups and width, and how many entries are ranked last."""
     cap = min(cap, vocab)
-    groups = -(-vocab // TOP_K_GROUP_WIDTH)
-    if groups < TOP_K_MIN_GROUPS_PER_CAP * cap:
+    stages, n = [], vocab
+    for width in (TOP_K_GROUP_WIDTH, TOP_K_SUBGROUP_WIDTH):
+        groups = -(-n // width)
+        if groups < TOP_K_MIN_GROUPS_PER_CAP * cap:
+            break
+        stages.append({"groups": groups, "width": width})
+        n = cap * width
+    if not stages:
         return {"top_k": "direct"}
-    return {"top_k": "grouped", "groups": groups,
-            "width": TOP_K_GROUP_WIDTH, "cap": cap}
+    return {"top_k": "grouped", "cap": cap, "stages": stages, "ranked": n}
 
 
-def _grouped_top_k(x: jnp.ndarray, cap: int,
-                   width: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """`lax.top_k(x, cap)` over the last axis, values and indices equal,
-    ranking groups + cap*width entries instead of all of them (module
-    docstring has why it is exact). Needs at least `cap` groups."""
-    *lead, vocab = x.shape
-    groups = -(-vocab // width)
-    pad = groups * width - vocab
+def _rows(x: jnp.ndarray) -> jnp.ndarray:
+    """The leading axes as ONE axis of rows, for what a stage ranks: on a
+    v5e a sort over `[128, 4, n]` takes a 4-row tile, 3.3x the time of the
+    same rows as `[512, n]` (PERF.md §6, PR 55). Only the few hundred values
+    a row that a stage ranks are reshaped, never the logits."""
+    return x.reshape(-1, x.shape[-1])
+
+
+def _keep_groups(x: jnp.ndarray, cap: int,
+                 width: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One stage: the `cap` groups of `width` consecutive entries that can
+    hold the top `cap` of the last axis -> (their entries [..., cap * width]
+    in index order, their group numbers [..., cap] ascending). Needs at
+    least `cap` groups."""
+    *lead, n = x.shape
+    groups = -(-n // width)
+    pad = groups * width - n
     if pad:  # -inf at the highest indices: behind every real entry
         x = jnp.pad(x, [(0, 0)] * len(lead) + [(0, pad)],
                     constant_values=-jnp.inf)
     grouped = x.reshape(*lead, groups, width)
-    _, chosen = jax.lax.top_k(grouped.max(-1), cap)
-    chosen = jnp.sort(chosen, axis=-1)  # candidates in vocabulary order
-    candidates = jnp.take_along_axis(grouped, chosen[..., None], axis=-2)
-    values, pos = jax.lax.top_k(candidates.reshape(*lead, cap * width), cap)
-    group = jnp.take_along_axis(chosen, pos // width, axis=-1)
-    return values, group * width + pos % width
+    _, chosen = jax.lax.top_k(_rows(grouped.max(-1)), cap)
+    # ascending: the kept entries lie in index order
+    chosen = jnp.sort(chosen, axis=-1).reshape(*lead, cap)
+    kept = jnp.take_along_axis(grouped, chosen[..., None], axis=-2)
+    return kept.reshape(*lead, cap * width), chosen
+
+
+def _grouped_top_k(x: jnp.ndarray, cap: int, widths: tuple[int, ...]
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`lax.top_k(x, cap)` over the last axis, values and indices equal,
+    in stages: each width of `widths` keeps `cap` groups of what the stage
+    before kept, and only the last stage's cap * widths[-1] entries are
+    ranked (module docstring has why it is exact). Values move by whole
+    groups (`take_along_axis` over the group axis): an element gather by
+    flat index read 11 ms and a one-hot product is inexact at -inf (PR 26);
+    only the whole-number map back to the vocabulary is a comparison."""
+    kept, stages = x, []
+    for width in widths:
+        kept, chosen = _keep_groups(kept, cap, width)
+        stages.append((chosen, width))
+    values, pos = jax.lax.top_k(_rows(kept), cap)
+    values = values.reshape(*x.shape[:-1], cap)
+    pos = pos.reshape(*x.shape[:-1], cap)
+    slot = jnp.arange(cap, dtype=pos.dtype)
+    for chosen, width in reversed(stages):
+        # A position among a stage's kept entries -> its position among the
+        # entries the stage chose from. The group's number is picked out of
+        # `chosen` by comparison (whole numbers: exact), which costs a
+        # hundredth of the element gather it replaces.
+        hit = (pos // width)[..., :, None] == slot
+        group = jnp.sum(jnp.where(hit, chosen[..., None, :], 0), axis=-1)
+        pos = group * width + pos % width
+    return values, pos
 
 
 def _top_k(x: jnp.ndarray, cap: int) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -78,7 +143,8 @@ def _top_k(x: jnp.ndarray, cap: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     route = top_k_route(x.shape[-1], cap)
     if route["top_k"] == "direct":
         return jax.lax.top_k(x, cap)
-    return _grouped_top_k(x, cap, route["width"])
+    return _grouped_top_k(x, cap,
+                          tuple(s["width"] for s in route["stages"]))
 
 
 def _masked_top_logits(

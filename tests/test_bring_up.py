@@ -97,7 +97,10 @@ class TestEngineHost:
         assert "platform cpu" in captured.err
         assert captured.out == ""  # no READY frame: nothing was built
 
-    def test_ready_and_stats_name_the_device(self, monkeypatch, capsys):
+    @staticmethod
+    def startup_blocks(monkeypatch, capsys):
+        """Serve a stats request and a shutdown: READY's block and the
+        stats reply's copy of it."""
         monkeypatch.setattr(sys, "stdin", io.StringIO(
             '{"op": "stats"}\n{"op": "shutdown"}\n'))
         host = host_mod.EngineHost(ConfigManager(config=HOST_CONFIG))
@@ -106,7 +109,10 @@ class TestEngineHost:
                   for line in capsys.readouterr().out.splitlines()]
         ready = next(f for f in frames if f["op"] == "ready")
         stats = next(f for f in frames if f["op"] == "stats")
-        for block in (ready, stats["startup"]):
+        return ready, stats["startup"]
+
+    def test_ready_and_stats_name_the_device(self, monkeypatch, capsys):
+        for block in self.startup_blocks(monkeypatch, capsys):
             assert block["device"]["platform"] == "cpu"
             assert block["device"]["device_kind"]
             assert block["device"]["device_count"] == jax.device_count()
@@ -117,11 +123,26 @@ class TestEngineHost:
                 "prefill": "pallas-interpret", "decode": "xla",
                 "decode_why": "ops/decode_attention.py has no geometry for "
                               "a head of 16: no lane tile of 128"}
-            # tiny's 512 logits are 4 groups of 128, below the two-stage
+            # tiny's 512 logits are 4 groups of 128, below the staged
             # selection's threshold (ops/sampling.py top_k_route).
             assert block["sampling"] == {"top_k": "direct"}
             assert block["compile_cache"] == compile_cache.cache_dir()
             assert block["build_s"] >= 0 and block["warmup_s"] >= 0
+
+    def test_ready_and_stats_carry_the_selection_stages(self, monkeypatch,
+                                                        capsys):
+        # tiny's 512 logits with the staged selection engaged (128 groups
+        # of 4, the 256 kept as 128 sub-groups of 2): READY and the stats
+        # reply say which stages every sampling call of the served programs
+        # takes, as they do for a cell's vocabulary at the shipped widths.
+        from symmetry_tpu.ops import sampling
+        monkeypatch.setattr(sampling, "TOP_K_GROUP_WIDTH", 4)
+        monkeypatch.setattr(sampling, "TOP_K_SUBGROUP_WIDTH", 2)
+        for block in self.startup_blocks(monkeypatch, capsys):
+            assert block["sampling"] == {
+                "top_k": "grouped", "cap": 64, "ranked": 128,
+                "stages": [{"groups": 128, "width": 4},
+                           {"groups": 128, "width": 2}]}
 
 
 class TestBackendSurfacesTheRefusal:

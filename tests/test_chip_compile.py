@@ -777,6 +777,27 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
     sliced = [line.strip()[:160] for line in text.splitlines()
               if re.search(r"= s8\[(1,)?128,(2048,768|768,2048)\]", line)]
     assert not sliced, sliced[0]
+    # the sampler's window over [128, 4, 151936] logits is selected in
+    # stages (ops/sampling.py top_k_route): no sort ranks more entries a row
+    # than the largest stage — the 8,192 candidates of the two-stage form
+    # were one `sort f32[128,4,8192]`, the capture's largest op (PERF.md,
+    # PR 55) — and a stage's rows are ranked flat, [512, n]
+    from symmetry_tpu.ops import sampling
+    route = sampling.top_k_route(cfg.vocab_size)
+    ranked = [s["groups"] for s in route["stages"]] + [route["ranked"]]
+    assert ranked == [1187, 256, 2048]
+    sorts = [(tuple(int(d) for d in m.group(1).split(",")), int(m.group(2)))
+             for m in re.finditer(
+                 r"= \(?\w+\[([\d,]+)\][^\n]*? sort\([^\n]*dimensions=\{(\d+)\}",
+                 text)]
+    assert sorts
+    # (the routed experts' sort of a forward's 4,096 pairs is one row)
+    wide = [dims for dims, axis in sorts
+            if len(dims) > 1 and dims[axis] > max(ranked)]
+    assert not wide, wide
+    for n in ranked:
+        assert ((512, n), 1) in sorts, (n, sorts)
+    assert not [dims for dims, _ in sorts if dims[:2] == (128, 4)], sorts
     # weights 8.4 GB and the cache 1.04 GB are arguments; what the program
     # adds (logits of [512, 151936] and the sampler's windows) stays small
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30

@@ -495,8 +495,9 @@ class TestSeededReproducibility:
     def test_grouped_top_k_programs_emit_the_same_tokens(self, setup, sp,
                                                          monkeypatch):
         """The served programs (prefill, chunk_final, decode) with the
-        sampler's two-stage selection engaged — tiny's 512 logits as 256
-        groups of 2 — emit the tokens the single lax.top_k gives."""
+        sampler's staged selection engaged — tiny's 512 logits as 128
+        groups of 4, the 256 kept as 128 sub-groups of 2 — emit the tokens
+        the single lax.top_k gives."""
         from symmetry_tpu.ops import sampling
         cfg, params = setup
         prompt = list(b"a prompt long enough to be chunked in two")
@@ -508,8 +509,12 @@ class TestSeededReproducibility:
             return toks + [int(engine.decode_step()[0]) for _ in range(8)]
 
         want = [generate(256), generate(16)]
-        monkeypatch.setattr(sampling, "TOP_K_GROUP_WIDTH", 2)
-        assert sampling.top_k_route(cfg.vocab_size)["top_k"] == "grouped"
+        monkeypatch.setattr(sampling, "TOP_K_GROUP_WIDTH", 4)
+        monkeypatch.setattr(sampling, "TOP_K_SUBGROUP_WIDTH", 2)
+        assert sampling.top_k_route(cfg.vocab_size) == {
+            "top_k": "grouped", "cap": 64, "ranked": 128,
+            "stages": [{"groups": 128, "width": 4},
+                       {"groups": 128, "width": 2}]}
         assert [generate(256), generate(16)] == want
 
 

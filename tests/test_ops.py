@@ -140,18 +140,50 @@ class TestSampling:
         assert 0 <= int(out[1]) < 3
 
 
-# Vocabularies of the two-stage selection's test: qwen2-7b, mistral-7b,
+# Vocabularies of the staged selection's test: qwen2-7b, mistral-7b,
 # OLMoE, one that TOP_K_GROUP_WIDTH does not divide (padded last group),
-# one small enough that the groups do not reach the threshold, and tiny's.
-TOP_K_VOCABS = (152064, 32768, 50304, 100000, 1000, 512)
+# one small enough that the groups do not reach the threshold, tiny's, and
+# (PR 55) the other cells': sdar / keye / qwen3-next, llama-3, lfm2,
+# granite / kanana, mixtral (a padded last group again).
+TOP_K_VOCABS = (152064, 32768, 50304, 100000, 1000, 512,
+                151936, 128256, 65536, 100352, 32000)
 
 
 def top_k_case(kind: str, vocab: int, cap: int = 64,
-               width: int = sampling.TOP_K_GROUP_WIDTH) -> np.ndarray:
-    """[3, vocab] float32 rows (or [2, 3, vocab]) built to stress one
-    property of the selection; seeded by (kind, vocab)."""
+               width: int = sampling.TOP_K_GROUP_WIDTH,
+               sub: int = sampling.TOP_K_SUBGROUP_WIDTH) -> np.ndarray:
+    """[3, vocab] float32 rows (or [2, 3, vocab], [2, 4, vocab]) built to
+    stress one property of the selection; seeded by (kind, vocab). `sub` is
+    the second stage's width: a group is `width // sub` sub-groups."""
     rng = np.random.default_rng([len(kind), vocab])
     groups = -(-vocab // width)
+    if kind == "one_subgroup":  # the window's head fills ONE sub-group,
+        x = rng.normal(size=(3, vocab)).astype(np.float32)  # whole
+        g = int(rng.integers(groups - 1))
+        at = g * width + sub * int(rng.integers(max(width // sub, 1)))
+        x[:, at:at + sub] += 100.0
+        return x
+    if kind == "one_per_subgroup":  # one winner in each of `cap` sub-groups
+        x = np.ones((3, vocab), np.float32)  # packed into cap * sub / width
+        per = max(width // sub, 1)           # groups; every other maximum
+        for row in x:                        # ties
+            chosen = rng.choice(groups - 1, replace=False,
+                                size=min(-(-cap // per), groups - 1))
+            at = (chosen[:, None] * width
+                  + np.arange(per)[None, :] * sub).reshape(-1)[:cap]
+            row[at + rng.integers(sub, size=at.size)] = 2.0
+        return x
+    if kind == "ties_straddle_subgroups":  # the window ends INSIDE a run of
+        x = rng.normal(size=(3, vocab)).astype(np.float32) * 0.1  # equals
+        for row in x:                      # that crosses sub-group borders,
+            g = rng.choice(groups - 1, size=2, replace=False)  # with a
+            for start in g * width + sub - 5:  # second such run elsewhere
+                row[start:start + cap // 2 + sub] = 3.0
+            row[rng.choice(vocab, size=cap // 4 + 7, replace=False)] = 5.0
+        return x
+    if kind == "block_of_four":  # diffusion_candidates' [R, 4, V]
+        x = jnp.asarray(rng.normal(size=(2, 4, vocab)), jnp.bfloat16)
+        return np.asarray(x.astype(jnp.float32) / 0.7)
     if kind == "gaussian":
         return rng.normal(size=(3, vocab)).astype(np.float32) * 3
     if kind == "bf16_ties":  # what the sampler sees: bf16 logits / 0.7
@@ -189,12 +221,15 @@ def top_k_case(kind: str, vocab: int, cap: int = 64,
 
 
 TOP_K_KINDS = ("gaussian", "bf16_ties", "one_group", "one_per_group",
-               "ties_across_groups", "constant", "neg_inf", "batch_seq")
+               "ties_across_groups", "constant", "neg_inf", "batch_seq",
+               "one_subgroup", "one_per_subgroup", "ties_straddle_subgroups",
+               "block_of_four")
 
 
 class TestTwoStageTopK:
-    """ops/sampling.py _top_k against the single lax.top_k it replaces:
-    values AND indices equal, ties included."""
+    """ops/sampling.py _top_k (the selection by stages; two of them until
+    PR 55, hence the name) against the single lax.top_k it replaces: values
+    AND indices equal, ties included."""
 
     @pytest.mark.parametrize("vocab", TOP_K_VOCABS)
     @pytest.mark.parametrize("kind", TOP_K_KINDS)
@@ -205,30 +240,55 @@ class TestTwoStageTopK:
         np.testing.assert_array_equal(got_v, want_v)
         np.testing.assert_array_equal(got_i, want_i)
 
+    @pytest.mark.parametrize("widths", [(8,), (13,), (13, 5), (8, 3)],
+                             ids=lambda w: "x".join(map(str, w)))
     @pytest.mark.parametrize("kind", TOP_K_KINDS)
-    def test_padded_last_group_at_any_width(self, kind):
-        # The grouped form itself, below the route's threshold: 1000 is
-        # 125 groups of 8 (divides) or 77 of 13 (a padded last group).
-        for width in (8, 13):
-            x = jnp.asarray(top_k_case(kind, 1000, width=width))
-            want_v, want_i = jax.lax.top_k(x, 64)
-            got_v, got_i = sampling._grouped_top_k(x, 64, width)
-            np.testing.assert_array_equal(got_v, want_v)
-            np.testing.assert_array_equal(got_i, want_i)
+    def test_padded_last_group_at_any_width(self, kind, widths):
+        # The staged form itself, below the route's threshold: 1000 is 125
+        # groups of 8 (divides) or 77 of 13 (a padded last group); the 832
+        # kept of 13 are 167 sub-groups of 5, the 512 kept of 8 are 171 of
+        # 3 (neither divides: a padded last sub-group).
+        x = jnp.asarray(top_k_case(kind, 1000, width=widths[0],
+                                   sub=widths[-1]))
+        want_v, want_i = jax.lax.top_k(x, 64)
+        got_v, got_i = sampling._grouped_top_k(x, 64, widths)
+        np.testing.assert_array_equal(got_v, want_v)
+        np.testing.assert_array_equal(got_i, want_i)
 
     @pytest.mark.parametrize("vocab,route", [
-        (152064, {"top_k": "grouped", "groups": 1188, "width": 128,
-                  "cap": 64}),
-        (32768, {"top_k": "grouped", "groups": 256, "width": 128,
-                 "cap": 64}),
-        (100000, {"top_k": "grouped", "groups": 782, "width": 128,
-                  "cap": 64}),
+        (152064, {"top_k": "grouped", "cap": 64, "ranked": 2048,
+                  "stages": [{"groups": 1188, "width": 128},
+                             {"groups": 256, "width": 32}]}),
+        (151936, {"top_k": "grouped", "cap": 64, "ranked": 2048,
+                  "stages": [{"groups": 1187, "width": 128},
+                             {"groups": 256, "width": 32}]}),
+        (32768, {"top_k": "grouped", "cap": 64, "ranked": 2048,
+                 "stages": [{"groups": 256, "width": 128},
+                            {"groups": 256, "width": 32}]}),
+        (32000, {"top_k": "grouped", "cap": 64, "ranked": 2048,
+                 "stages": [{"groups": 250, "width": 128},
+                            {"groups": 256, "width": 32}]}),
+        (100000, {"top_k": "grouped", "cap": 64, "ranked": 2048,
+                  "stages": [{"groups": 782, "width": 128},
+                             {"groups": 256, "width": 32}]}),
         (1000, {"top_k": "direct"}),
         (512, {"top_k": "direct"}),
         (32, {"top_k": "direct"}),  # cap clamps to the vocabulary
     ])
     def test_route_follows_the_shape(self, vocab, route):
         assert sampling.top_k_route(vocab) == route
+
+    def test_a_stage_is_taken_only_with_enough_groups(self, monkeypatch):
+        # two groups a kept entry, stage by stage: 8,192 kept entries are
+        # 64 sub-groups of 128 (too few: ranked whole, PR 26's form) or
+        # 128 of 64
+        monkeypatch.setattr(sampling, "TOP_K_SUBGROUP_WIDTH", 128)
+        assert sampling.top_k_route(32768) == {
+            "top_k": "grouped", "cap": 64, "ranked": 8192,
+            "stages": [{"groups": 256, "width": 128}]}
+        monkeypatch.setattr(sampling, "TOP_K_SUBGROUP_WIDTH", 64)
+        assert sampling.top_k_route(32768)["stages"][1] == {
+            "groups": 128, "width": 64}
 
     def test_benchmark_presets_take_the_grouped_route(self):
         from symmetry_tpu.models.llama import preset
@@ -252,6 +312,23 @@ class TestTwoStageTopK:
         got = sample_tokens(*args)
         monkeypatch.setattr(sampling, "_top_k", jax.lax.top_k)
         np.testing.assert_array_equal(got, sample_tokens(*args))
+
+    @pytest.mark.parametrize("vocab", (151936, 32768))
+    def test_diffusion_candidates_identical_to_single_call(self, vocab,
+                                                           monkeypatch):
+        R, S = 5, 4
+        logits = jnp.asarray(
+            np.random.default_rng(vocab + 2).normal(size=(R, S, vocab)),
+            jnp.bfloat16).astype(jnp.float32)
+        args = (logits, jax.random.split(jax.random.key(13), R),
+                jnp.asarray([0.0, 0.7, 0.7, 1.0, 1.3], jnp.float32),
+                jnp.asarray([1.0, 1.0, 0.9, 0.5, 1.0], jnp.float32),
+                jnp.asarray([0, 0, 0, 40, 5], jnp.int32))
+        got = sampling.diffusion_candidates(*args)
+        monkeypatch.setattr(sampling, "_top_k", jax.lax.top_k)
+        want = sampling.diffusion_candidates(*args)
+        np.testing.assert_array_equal(got[0], want[0])  # candidates
+        np.testing.assert_array_equal(got[1], want[1])  # confidences
 
     @pytest.mark.parametrize("vocab", (152064, 32768))
     def test_verify_tokens_identical_to_single_call(self, vocab,
