@@ -12,4 +12,11 @@ Three roles (reference: readme.md Architecture diagram):
   - client   (symmetry_tpu.client):   requests a provider, streams completions
 """
 
+import time
+
+# The first statement this program runs in a process: where its start-up
+# timeline begins when the kernel's own stamp cannot be used
+# (utils/trace.py process_start).
+T_FIRST_STATEMENT = time.monotonic()
+
 __version__ = "0.1.0"

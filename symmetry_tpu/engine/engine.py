@@ -22,6 +22,7 @@ concurrency in one event loop (SURVEY §5.2).
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import time
 from dataclasses import dataclass
@@ -54,6 +55,7 @@ from symmetry_tpu.parallel.sharding import shardings_for
 from symmetry_tpu.engine.prefix_cache import BlockPool, RadixHit, RadixIndex
 from symmetry_tpu.engine.spec import SpecConfig
 from symmetry_tpu.engine.tokenizer import Tokenizer, get_tokenizer
+from symmetry_tpu.utils.trace import Tracer
 
 
 def _park(state: "DecodeState", park: jnp.ndarray) -> "DecodeState":
@@ -203,11 +205,27 @@ class InferenceEngine:
         role: str = "unified",
         diffusion_steps: int | None = None,
         diffusion_threshold: float | None = None,
+        tracer: Tracer | None = None,
+        compile_watch: Any = None,
     ) -> None:
         self.config = config
         self.params = params
         self.tokenizer = tokenizer
         self.mesh = mesh
+        # The start-up's record (PERF.md §3): `from_tpu_config` and
+        # `warmup()` stamp their spans on `tracer` — the engine host hands
+        # in its own, so READY carries them — and warm-up books to each
+        # program what `compile_watch` (utils/devprof.py CompileWatch)
+        # counted inside it; without a watch a record's counts are zeros.
+        self.tracer = tracer if tracer is not None else Tracer()
+        self.compile_watch = compile_watch
+        self.warmup_programs: list[dict] = []
+        # Where `from_tpu_config`'s last span ended (None for an engine
+        # made without it), and where the next warm-up record begins: on
+        # that stamp for the warm-up behind the build, else (None) on its
+        # own clock read.
+        self.built_at: float | None = None
+        self._warm_t: float | None = None
         stages = 1 if mesh is None else dict(mesh.shape).get("stage", 1)
         if stages > 1:
             # The axis has no schedule behind it (it leaves parallel/
@@ -1968,6 +1986,63 @@ class InferenceEngine:
         self.state = self._insert_all(self.state, prefix,
                                       jnp.asarray(slots), *rows)
 
+    # What a warm-up record books from the compile watch, in this order
+    # behind `program`, `batch`, `bucket`, `t0`, `wall_s`.
+    WARM_COUNTS = ("trace_s", "lower_s", "backend_s", "retrieval_s",
+                   "cache_hits", "cache_misses")
+
+    @contextlib.contextmanager
+    def _warm(self, program: str, batch: int | None = None,
+              bucket: int | None = None, **what: Any):
+        """One record of the warm-up (`self.warmup_programs`): a
+        `start.warm` phase that begins on the stamp that ended the record
+        before it, with the compile watch's growth inside it booked to
+        `program`. Dispatch is asynchronous, so a program's record holds
+        its trace, lowering, compile (or cache read) and launch; the
+        device's run of it drains in the next `sync` record, which names
+        what it waited for (`waits`)."""
+        watch = self.compile_watch
+        mark = None if watch is None else watch.mark()
+        attrs = {"program": program, "batch": batch, "bucket": bucket,
+                 **what}
+        phase = self.tracer.phase(
+            "start.warm", t0=self._warm_t,
+            **{k: v for k, v in attrs.items() if v is not None})
+        try:
+            with phase:
+                yield
+        finally:
+            self._warm_t = phase.t1
+            grown = {} if watch is None else watch.since(mark)
+            self.warmup_programs.append({
+                **attrs, "t0": phase.t0, "wall_s": phase.t1 - phase.t0,
+                **{key: grown.get(key, 0) for key in self.WARM_COUNTS}})
+
+    def _warm_prefill(self, batch: int, bucket: int):
+        """Warm-up's prefill at (batch, bucket), over garbage: returns
+        (tokens, prefix)."""
+        toks, prefix = self._prefill(
+            self.params, jnp.zeros((batch, bucket), jnp.int32),
+            jnp.ones((batch,), jnp.int32),
+            jnp.zeros((batch,), jnp.float32),
+            jnp.ones((batch,), jnp.float32),
+            jnp.zeros((batch,), jnp.int32),
+            jax.random.split(jax.random.key(0), batch),
+            self._prefill_scratch_for(batch, bucket))
+        self._store_prefill_scratch(batch, bucket, prefix)
+        return toks, prefix
+
+    def _warm_insert(self, state, prefix, toks, batch: int):
+        """Warm-up's insert of such a prefix: slot 0 with true_len 0
+        leaves the state semantically untouched."""
+        return self._insert_all(
+            state, prefix, jnp.zeros((batch,), jnp.int32),
+            jnp.zeros((batch,), jnp.int32), toks,
+            jnp.zeros((batch,), jnp.float32),
+            jnp.ones((batch,), jnp.float32),
+            jnp.zeros((batch,), jnp.int32),
+            jax.random.split(jax.random.key(0), batch))
+
     def warmup(self) -> None:
         """Compile every serving program before traffic: decode, and the
         full (PREFILL_BATCHES × prefill_buckets) prefill/insert grid. A
@@ -1975,6 +2050,12 @@ class InferenceEngine:
         every active stream — the first coalesced burst must not pay it.
         Call before the first insert — warmup advances device state with
         garbage that is only harmless on an empty cache.
+
+        Every call it makes is inside one `_warm` record, so the records
+        tile the warm-up (from `built_at`, where the build's last span
+        ended, when this is the warm-up behind `from_tpu_config`) and
+        `self.warmup_programs` says which program a second of it, a
+        compile or a cache miss belongs to.
 
         Role gating (two-tier warmup is the structural win of disagg): a
         "prefill" engine never decodes, so the decode block, the
@@ -1985,38 +2066,32 @@ class InferenceEngine:
         full set ("decode" has the prefix store on by contract, so the
         adoption seed-copy shapes are always covered)."""
         decode_side = self.role != "prefill"
+        self.warmup_programs = []
+        prefill_name = self._prefill.__name__
+        decode_name = self._decode.__name__
         # The resume RNG fast-forward (scalar key program, one compile
         # covers every resume depth): warm it so the first mid-stream
         # recovery under load never pays a fresh XLA compile.
-        self._rng_resume(jax.random.key(0), 0)
+        with self._warm("rng_resume"):
+            self._rng_resume(jax.random.key(0), 0)
         for batch in self.PREFILL_BATCHES:  # the group-key program
-            self._derive_keys(self._base_key, np.zeros((batch,), np.int32),
-                              np.zeros((batch,), np.int32),
-                              np.zeros((batch,), bool))
+            with self._warm("derive_keys", batch):
+                self._derive_keys(
+                    self._base_key, np.zeros((batch,), np.int32),
+                    np.zeros((batch,), np.int32), np.zeros((batch,), bool))
         if decode_side:
-            self.state, _, _ = self._dispatch_decode()
+            with self._warm(decode_name):
+                self.state, _, _ = self._dispatch_decode()
         for bucket in self.prefill_buckets:
             for batch in self.prefill_batches_for(bucket):
                 if batch > self.max_slots:
                     continue
-                toks, prefix = self._prefill(
-                    self.params, jnp.zeros((batch, bucket), jnp.int32),
-                    jnp.ones((batch,), jnp.int32),
-                    jnp.zeros((batch,), jnp.float32),
-                    jnp.ones((batch,), jnp.float32),
-                    jnp.zeros((batch,), jnp.int32),
-                    jax.random.split(jax.random.key(0), batch),
-                    self._prefill_scratch_for(batch, bucket))
-                self._store_prefill_scratch(batch, bucket, prefix)
-                # insert_all compiles per (batch, bucket) too; slot 0
-                # with true_len 0 leaves the state semantically untouched.
-                self.state = self._insert_all(
-                    self.state, prefix, jnp.zeros((batch,), jnp.int32),
-                    jnp.zeros((batch,), jnp.int32), toks,
-                    jnp.zeros((batch,), jnp.float32),
-                    jnp.ones((batch,), jnp.float32),
-                    jnp.zeros((batch,), jnp.int32),
-                    jax.random.split(jax.random.key(0), batch))
+                with self._warm(prefill_name, batch, bucket):
+                    toks, prefix = self._warm_prefill(batch, bucket)
+                # insert_all compiles per (batch, bucket) too
+                with self._warm("insert_all", batch, bucket):
+                    self.state = self._warm_insert(self.state, prefix,
+                                                   toks, batch)
         # Exercise the CONCURRENT decode+prefill peak once PER BUCKET:
         # serving overlaps an in-flight decode block with a prefill
         # dispatch, and their workspaces coexist in HBM — a configuration
@@ -2033,24 +2108,20 @@ class InferenceEngine:
         for bucket in (self.prefill_buckets if decode_side else ()):
             widest = max(b for b in self.prefill_batches_for(bucket)
                          if b <= self.max_slots)
-            pending = self._dispatch_decode()
-            self.state = pending[0]
-            toks, prefix = self._prefill(
-                self.params,
-                jnp.zeros((widest, bucket), jnp.int32),
-                jnp.ones((widest,), jnp.int32),
-                jnp.zeros((widest,), jnp.float32),
-                jnp.ones((widest,), jnp.float32),
-                jnp.zeros((widest,), jnp.int32),
-                jax.random.split(jax.random.key(0), widest),
-                self._prefill_scratch_for(widest, bucket))
-            self._store_prefill_scratch(widest, bucket, prefix)
+            with self._warm("peak." + decode_name, bucket=bucket):
+                pending = self._dispatch_decode()
+                self.state = pending[0]
+            with self._warm("peak." + prefill_name, widest, bucket):
+                toks, _ = self._warm_prefill(widest, bucket)
             # Sync on the PREFILL output: the device queue is FIFO, so
             # its completion implies the decode's too — and JAX surfaces
             # async failures only on the poisoned output, so syncing the
             # decode alone would let a prefill OOM stay pending until
-            # first traffic.
-            np.asarray(toks)
+            # first traffic. The first of these syncs also drains every
+            # run the grid above dispatched.
+            with self._warm("sync", widest, bucket,
+                      waits="peak." + prefill_name):
+                np.asarray(toks)
 
         # Chunked-prefill programs: one (step, final) pair per bucket that
         # can hold a multi-chunk prompt. A mid-traffic compile would be the
@@ -2061,27 +2132,29 @@ class InferenceEngine:
             for bucket in self.prefill_buckets:
                 if bucket <= C:
                     continue
-                cache = self._new_prefix_cache(bucket)
-                cache = self._chunk_step(
-                    self.params, jnp.zeros((1, C), jnp.int32), cache, one)
-                toks, cache = self._chunk_final(
-                    self.params, jnp.zeros((1, C), jnp.int32), cache, one,
-                    jnp.zeros((1,), jnp.int32),
-                    jnp.zeros((1,), jnp.float32), jnp.ones((1,), jnp.float32),
-                    jnp.zeros((1,), jnp.int32),
-                    jax.random.split(jax.random.key(0), 1))
+                with self._warm("chunk_step", 1, bucket):
+                    cache = self._new_prefix_cache(bucket)
+                    cache = self._chunk_step(
+                        self.params, jnp.zeros((1, C), jnp.int32), cache,
+                        one)
+                with self._warm("chunk_final", 1, bucket):
+                    toks, cache = self._chunk_final(
+                        self.params, jnp.zeros((1, C), jnp.int32), cache,
+                        one, jnp.zeros((1,), jnp.int32),
+                        jnp.zeros((1,), jnp.float32),
+                        jnp.ones((1,), jnp.float32),
+                        jnp.zeros((1,), jnp.int32),
+                        jax.random.split(jax.random.key(0), 1))
                 # batch-1 insert at this bucket already compiled above
 
         # Speculative verify program (only when the knob is on — off keeps
         # warmup's compile set byte-identical): exactly ONE extra compile,
         # the fixed [B, 1+k_draft] verify shape. Zero drafts advance every
         # lane one garbage token — harmless on the pre-insert empty cache,
-        # same contract as the decode warmup above. The sync inside
-        # verify_step surfaces a marginal-HBM failure at startup.
+        # same contract as the decode warmup above. The sync surfaces a
+        # marginal-HBM failure at startup.
         if self.spec is not None and decode_side:
-            self.verify_step(
-                np.zeros((self.max_slots, self.spec.k_draft), np.int32),
-                np.zeros((self.max_slots,), np.int32))
+            self._warm_verify()
 
         if self.role == "prefill":
             # The handoff snapshot programs: the decode-state cache IS a
@@ -2091,8 +2164,12 @@ class InferenceEngine:
             # final sync doubles as the prefill-role startup-OOM probe
             # (the grid loop above dispatches without syncing).
             for bucket in self.prefill_buckets:
-                np.asarray(self.extract_slot_kv(0, min(
-                    bucket, self.max_seq_len)).lengths)
+                with self._warm("extract_slot_kv", bucket=bucket):
+                    row = self.extract_slot_kv(0, min(
+                        bucket, self.max_seq_len))
+                with self._warm("sync", bucket=bucket,
+                                waits="extract_slot_kv"):
+                    np.asarray(row.lengths)
 
         # Prefix-cache hit-path programs (only when the cache is on —
         # budget 0 keeps warmup exactly as before): per bucket the block
@@ -2109,30 +2186,37 @@ class InferenceEngine:
             for bucket in self.prefill_buckets:
                 # All lanes at the trash block: the scatter compiles and
                 # runs, and the garbage lands where nobody reads.
-                row = self._new_prefix_cache(bucket)
-                self._pool_kv = self._write_blocks(
-                    self._pool_kv, row, self._bucket_ids(bucket))
+                with self._warm("prefix.write_blocks", bucket=bucket):
+                    row = self._new_prefix_cache(bucket)
+                    self._pool_kv = self._write_blocks(
+                        self._pool_kv, row, self._bucket_ids(bucket))
             for bucket in self.prefill_buckets:
                 for batch in self.prefill_batches_for(bucket):
-                    scratch = self._prefill_scratch_for(batch, bucket)
-                    self._extract_prefix_row(scratch, jnp.int32(0),
-                                             jnp.int32(0))
-                    scratch = self._insert_from_blocks(
-                        scratch, self._pool_kv, self._bucket_ids(bucket),
-                        jnp.int32(0))
-                    toks, prefix = self._chunk_final(
-                        self.params, jnp.zeros((batch, A), jnp.int32),
-                        scratch, jnp.ones((batch,), jnp.int32),
-                        jnp.zeros((batch,), jnp.int32),
-                        jnp.zeros((batch,), jnp.float32),
-                        jnp.ones((batch,), jnp.float32),
-                        jnp.zeros((batch,), jnp.int32),
-                        jax.random.split(jax.random.key(0), batch))
-                    self._store_prefill_scratch(batch, bucket, prefix)
+                    with self._warm("prefix.extract_row", batch, bucket):
+                        scratch = self._prefill_scratch_for(batch, bucket)
+                        self._extract_prefix_row(scratch, jnp.int32(0),
+                                                 jnp.int32(0))
+                    with self._warm("prefix.insert_from_blocks", batch,
+                                    bucket):
+                        scratch = self._insert_from_blocks(
+                            scratch, self._pool_kv,
+                            self._bucket_ids(bucket), jnp.int32(0))
+                    with self._warm("prefix.suffix", batch, bucket):
+                        toks, prefix = self._chunk_final(
+                            self.params, jnp.zeros((batch, A), jnp.int32),
+                            scratch, jnp.ones((batch,), jnp.int32),
+                            jnp.zeros((batch,), jnp.int32),
+                            jnp.zeros((batch,), jnp.float32),
+                            jnp.ones((batch,), jnp.float32),
+                            jnp.zeros((batch,), jnp.int32),
+                            jax.random.split(jax.random.key(0), batch))
+                        self._store_prefill_scratch(batch, bucket, prefix)
                     # Sync so a marginal-HBM failure surfaces at startup,
                     # not at the first hit burst (same rationale as the
                     # concurrent-peak probe above).
-                    np.asarray(toks)
+                    with self._warm("sync", batch, bucket,
+                                    waits="prefix.suffix"):
+                        np.asarray(toks)
 
         # Dispatch-cache closure. Donation aliases output buffers to the
         # donated inputs, so a state array's PHYSICAL provenance (which
@@ -2151,49 +2235,72 @@ class InferenceEngine:
         # (one class per materializing executable), so this converges in
         # a couple of rounds; every dispatch hits an already-compiled
         # program, so the cost is a handful of device launches, not
-        # compiles.
+        # compiles. One record a round (`settle`): its seconds are
+        # launches, and waits on the device's queue where that is full.
         if decode_side:
             def _settle_insert(state, batch: int, bucket: int):
-                toks, prefix = self._prefill(
-                    self.params, jnp.zeros((batch, bucket), jnp.int32),
-                    jnp.ones((batch,), jnp.int32),
-                    jnp.zeros((batch,), jnp.float32),
-                    jnp.ones((batch,), jnp.float32),
-                    jnp.zeros((batch,), jnp.int32),
-                    jax.random.split(jax.random.key(0), batch),
-                    self._prefill_scratch_for(batch, bucket))
-                self._store_prefill_scratch(batch, bucket, prefix)
-                return self._insert_all(
-                    state, prefix, jnp.zeros((batch,), jnp.int32),
-                    jnp.zeros((batch,), jnp.int32), toks,
-                    jnp.zeros((batch,), jnp.float32),
-                    jnp.ones((batch,), jnp.float32),
-                    jnp.zeros((batch,), jnp.int32),
-                    jax.random.split(jax.random.key(0), batch))
+                toks, prefix = self._warm_prefill(batch, bucket)
+                return self._warm_insert(state, prefix, toks, batch)
 
-            for _ in range(6):
-                sizes = self.compile_cache_sizes()
-                for bucket in self.prefill_buckets:
-                    for batch in self.prefill_batches_for(bucket):
-                        if batch > self.max_slots:
-                            continue
-                        # burst admission: inserts back-to-back
-                        self.state = _settle_insert(self.state, batch,
-                                                    bucket)
-                        # steady decode between admissions
+            for settle_round in range(6):
+                with self._warm("settle", round=settle_round):
+                    sizes = self.compile_cache_sizes()
+                    for bucket in self.prefill_buckets:
+                        for batch in self.prefill_batches_for(bucket):
+                            if batch > self.max_slots:
+                                continue
+                            # burst admission: inserts back-to-back
+                            self.state = _settle_insert(self.state, batch,
+                                                        bucket)
+                            # steady decode between admissions
+                            self.state, _, _ = self._dispatch_decode()
+                            self.state = _settle_insert(self.state, batch,
+                                                        bucket)
+                        # consecutive decode blocks (no admission between)
                         self.state, _, _ = self._dispatch_decode()
-                        self.state = _settle_insert(self.state, batch,
-                                                    bucket)
-                    # consecutive decode blocks (no admission between)
-                    self.state, _, _ = self._dispatch_decode()
-                    self.state, _, _ = self._dispatch_decode()
-                if self.spec is not None:
-                    self.verify_step(
-                        np.zeros((self.max_slots, self.spec.k_draft),
-                                 np.int32),
-                        np.zeros((self.max_slots,), np.int32))
-                if self.compile_cache_sizes() == sizes:
+                        self.state, _, _ = self._dispatch_decode()
+                    if self.spec is not None:
+                        self.verify_step(
+                            np.zeros((self.max_slots, self.spec.k_draft),
+                                     np.int32),
+                            np.zeros((self.max_slots,), np.int32))
+                    settled = self.compile_cache_sizes() == sizes
+                if settled:
                     break
+        self._warm_t = None  # a later warm-up begins on its own clock read
+
+    def warmup_report(self) -> dict:
+        """The warm-up record's totals and its five slowest records: the
+        `startup.warmup` block of READY and of every stats reply (the whole
+        list, `warmup_programs`, rides READY and the host's log only).
+        `compile_s` is trace + lowering + compile-or-fetch seconds,
+        `retrieval_s` the part of it spent reading the persistent cache,
+        `run_s` the seconds inside `sync` records: the device's runs."""
+        records = self.warmup_programs
+
+        def total(*keys: str) -> float:
+            return sum(r[key] for r in records for key in keys)
+
+        return {
+            "programs": len(records),
+            "wall_s": total("wall_s"),
+            "compile_s": total("trace_s", "lower_s", "backend_s"),
+            "retrieval_s": total("retrieval_s"),
+            "run_s": sum(r["wall_s"] for r in records
+                         if r["program"] == "sync"),
+            "cache_hits": int(total("cache_hits")),
+            "cache_misses": int(total("cache_misses")),
+            "slowest": sorted(records, key=lambda r: -r["wall_s"])[:5]}
+
+    def _warm_verify(self) -> None:
+        name = self._verify.__name__
+        with self._warm(name):
+            out = self.verify_step_dispatch(
+                np.zeros((self.max_slots, self.spec.k_draft), np.int32),
+                np.zeros((self.max_slots,), np.int32))
+        with self._warm("sync", waits=name):
+            for array in out:
+                np.asarray(array)
 
     def verify_step_dispatch(self, draft: np.ndarray, n_draft: np.ndarray
                              ) -> tuple[jax.Array, jax.Array]:
@@ -2508,7 +2615,9 @@ class InferenceEngine:
     # ------------------------------------------------------------------
 
     @classmethod
-    def from_tpu_config(cls, tpu_cfg: Any) -> "InferenceEngine":
+    def from_tpu_config(cls, tpu_cfg: Any, *, tracer: Tracer | None = None,
+                        compile_watch: Any = None,
+                        t0: float | None = None) -> "InferenceEngine":
         """Build from a provider.yaml `tpu:` section (provider/config.py).
 
         With `tpu.multihost` set, joins the jax.distributed job first and
@@ -2518,23 +2627,82 @@ class InferenceEngine:
         Raises NoChipError (utils/device.py) before any weight is made
         when the devices JAX hands out are not TPUs and the CPU was not
         pinned by name.
+
+        The build is three spans of the start-up timeline on `tracer`,
+        the first begun at `t0` (the stamp that ended the caller's span
+        before it), each on the stamp that ended the last: `build.devices`
+        (the first touch of the backend: the device client's own init),
+        `build.params` (the weights made or loaded, quantised, absorbed,
+        placed) and `build.state` (the cache, the recurrent state and the
+        prefix pool allocated, the jitted callables made). `tracer` and
+        `compile_watch` stay with the engine for `warmup()`.
         """
         from symmetry_tpu.utils.device import require_chip
 
-        mesh_spec = MeshSpec.from_dict(tpu_cfg.mesh)
-        mh = tpu_cfg.multihost
-        if mh:
-            from symmetry_tpu.parallel.multihost import (
-                build_multihost_mesh, init_distributed)
+        tracer = tracer if tracer is not None else Tracer()
+        span = tracer.phase("start.build.devices", t0=t0, parent=None)
+        with span:
+            mesh_spec = MeshSpec.from_dict(tpu_cfg.mesh)
+            mh = tpu_cfg.multihost
+            if mh:
+                from symmetry_tpu.parallel.multihost import (
+                    build_multihost_mesh, init_distributed)
 
-            init_distributed(mh["coordinator"], mh["num_processes"],
-                             mh.get("process_id", 0))
-        require_chip()
-        if mh:
-            mesh = build_multihost_mesh(mesh_spec, mh.get("dcn_data", 1))
-        else:
-            mesh = build_mesh(mesh_spec) if mesh_spec.size > 1 else None
+                init_distributed(mh["coordinator"], mh["num_processes"],
+                                 mh.get("process_id", 0))
+            require_chip()
+            if mh:
+                mesh = build_multihost_mesh(mesh_spec,
+                                            mh.get("dcn_data", 1))
+            else:
+                mesh = build_mesh(mesh_spec) if mesh_spec.size > 1 else None
+        span = tracer.phase("start.build.params", t0=span.t1, parent=None)
+        with span:
+            params, config, dtype, tokenizer = cls._params_from_tpu_config(
+                tpu_cfg, mesh)
+        span = tracer.phase("start.build.state", t0=span.t1, parent=None)
+        with span:
+            engine = cls(
+                config, params, tokenizer, mesh=mesh,
+                max_slots=tpu_cfg.max_batch_size,
+                max_seq_len=tpu_cfg.max_seq_len,
+                prefill_buckets=tpu_cfg.prefill_buckets,
+                cache_dtype=dtype,
+                decode_block=getattr(tpu_cfg, "decode_block", 1),
+                kv_quant=tpu_cfg.kv_quantization == "int8",
+                prefill_chunk=getattr(tpu_cfg, "prefill_chunk", 256),
+                prefill_token_budget=getattr(tpu_cfg, "prefill_token_budget",
+                                             None),
+                prefix_cache_bytes=int(
+                    (getattr(tpu_cfg, "prefix_cache_mb", None) or 0) * 2**20),
+                prefix_block_tokens=int(
+                    getattr(tpu_cfg, "prefix_block_tokens", None) or 16),
+                prefix_gossip_blocks=int(
+                    getattr(tpu_cfg, "prefix_gossip_blocks", None) or 0),
+                # is-None, not falsy-or: an explicit 0.0 means "recompute
+                # on every heartbeat probe", not the default cadence
+                prefix_gossip_s=float(
+                    2.0 if getattr(tpu_cfg, "prefix_gossip_s", None) is None
+                    else tpu_cfg.prefix_gossip_s),
+                speculative=SpecConfig.from_knob(
+                    getattr(tpu_cfg, "speculative", None)),
+                fused_dequant=bool(getattr(tpu_cfg, "fused_dequant", False)),
+                # "disagg" is the BACKEND's role (it spawns a prefill and a
+                # decode host, each of which sees its own tier role here);
+                # an engine can only be one tier or unified.
+                role=getattr(tpu_cfg, "role", "unified") or "unified",
+                diffusion_steps=getattr(tpu_cfg, "diffusion_steps", None),
+                diffusion_threshold=getattr(tpu_cfg, "diffusion_threshold",
+                                            None),
+                tracer=tracer, compile_watch=compile_watch,
+            )
+        engine.built_at = engine._warm_t = span.t1
+        return engine
 
+    @staticmethod
+    def _params_from_tpu_config(tpu_cfg: Any, mesh: Any):
+        """The weights of `from_tpu_config`, on the device: (params,
+        config, dtype, tokenizer)."""
         dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
                   "float16": jnp.float16}
         if tpu_cfg.dtype not in dtypes:
@@ -2617,36 +2785,4 @@ class InferenceEngine:
         # the MODEL's vocab or sampled ids stream as silence (tokenizer.py).
         tokenizer = get_tokenizer(tpu_cfg.tokenizer_path,
                                   vocab_size=config.vocab_size)
-        return cls(
-            config, params, tokenizer, mesh=mesh,
-            max_slots=tpu_cfg.max_batch_size,
-            max_seq_len=tpu_cfg.max_seq_len,
-            prefill_buckets=tpu_cfg.prefill_buckets,
-            cache_dtype=dtype,
-            decode_block=getattr(tpu_cfg, "decode_block", 1),
-            kv_quant=tpu_cfg.kv_quantization == "int8",
-            prefill_chunk=getattr(tpu_cfg, "prefill_chunk", 256),
-            prefill_token_budget=getattr(tpu_cfg, "prefill_token_budget",
-                                         None),
-            prefix_cache_bytes=int(
-                (getattr(tpu_cfg, "prefix_cache_mb", None) or 0) * 2**20),
-            prefix_block_tokens=int(
-                getattr(tpu_cfg, "prefix_block_tokens", None) or 16),
-            prefix_gossip_blocks=int(
-                getattr(tpu_cfg, "prefix_gossip_blocks", None) or 0),
-            # is-None, not falsy-or: an explicit 0.0 means "recompute
-            # on every heartbeat probe", not the default cadence
-            prefix_gossip_s=float(
-                2.0 if getattr(tpu_cfg, "prefix_gossip_s", None) is None
-                else tpu_cfg.prefix_gossip_s),
-            speculative=SpecConfig.from_knob(
-                getattr(tpu_cfg, "speculative", None)),
-            fused_dequant=bool(getattr(tpu_cfg, "fused_dequant", False)),
-            # "disagg" is the BACKEND's role (it spawns a prefill and a
-            # decode host, each of which sees its own tier role here);
-            # an engine can only be one tier or unified.
-            role=getattr(tpu_cfg, "role", "unified") or "unified",
-            diffusion_steps=getattr(tpu_cfg, "diffusion_steps", None),
-            diffusion_threshold=getattr(tpu_cfg, "diffusion_threshold",
-                                        None),
-        )
+        return params, config, dtype, tokenizer

@@ -52,8 +52,25 @@ Protocol: JSON lines.
             "sampling": {"top_k", "cap"?, "stages"?: [{"groups",
                          "width"}, …], "ranked"?},
             "moe"?: {"experts", "top_k", "layout", "route": {"decode",
-                     "prefill"}, "quantized_leaf_route"}}
-            (after warmup. `moe` only for an expert model: engine.py
+                     "prefill"}, "quantized_leaf_route"},
+            "timeline": [[name, t0, t1, parent], …], "origin",
+            "warmup": {"programs", "wall_s", "compile_s", "retrieval_s",
+                       "run_s", "cache_hits", "cache_misses", "slowest"},
+            "warmup_programs": [{"program", "batch", "bucket", "t0",
+                                 "wall_s", "trace_s", "lower_s",
+                                 "backend_s", "retrieval_s", "cache_hits",
+                                 "cache_misses"}, …]}
+            (after warmup. `timeline` is this process's start-up in
+            CLOCK_MONOTONIC seconds — `host.process`, `host.config`,
+            `build.devices|params|state`, `warmup`, `host.scheduler`, the
+            stamp `ready` — each span begun on the stamp that ended the
+            one before; `origin` says where `host.process` begins
+            (`kernel` | `package`: utils/trace.py process_start).
+            `warmup_programs` is one record a program warm-up ran
+            (engine.py `_warm`), READY only; `warmup` its totals and five
+            slowest records. The stats reply's `startup` repeats
+            everything here but `warmup_programs`.
+            `moe` only for an expert model: engine.py
             moe_report. `device` is what JAX handed this process and
             its per-device memory_stats() once every program has
             compiled; `attention` is "pallas" | "pallas-interpret" |
@@ -157,8 +174,13 @@ HANDOFF_MAX_KV_BYTES = 384 * 1024 * 1024
 
 
 class EngineHost:
-    def __init__(self, config: ConfigManager) -> None:
+    def __init__(self, config: ConfigManager,
+                 t_main: float | None = None) -> None:
         self._config = config
+        # Where `main()` began (CLOCK_MONOTONIC): the start-up timeline's
+        # `host.process` ends and `host.config` begins there. A host made
+        # without a `main()` (tests) begins its timeline here.
+        self._t_main = time.monotonic() if t_main is None else t_main
         # Fault injection (utils/faults.py): env SYMMETRY_FAULTS is
         # inherited from the provider and already loaded at import; a
         # provider-config `faults:` mapping rides the config file here.
@@ -359,18 +381,28 @@ class EngineHost:
     # ------------------------------------------------------------- lifecycle
 
     def start(self) -> None:
-        import time
-
+        """Build, warm up, start the scheduler, write READY. Every step is
+        a `start.*` span of `self.tracer`, each begun on the stamp that
+        ended the one before it; they are frozen into `startup.timeline`
+        once the last has closed, just before READY is written (PERF.md §3
+        says which metric reads which row)."""
         from symmetry_tpu.utils.compile_cache import enable_compile_cache
 
-        # Persistent XLA compile cache: without it every host start
-        # recompiles the full serving grid; with it a config-identical
-        # restart compiles ~nothing.
-        cache_dir = enable_compile_cache(self._config.tpu)
-        self._compile.register()
-        t0 = time.perf_counter()
-        self._engine = InferenceEngine.from_tpu_config(self._config.tpu)
-        t_build = time.perf_counter() - t0
+        span = self.tracer.phase
+        # the interpreter and the imports, JAX among them
+        origin = self.tracer.process_span("start.host.process",
+                                          self._t_main)
+        step = span("start.host.config", t0=self._t_main, parent=None)
+        with step:
+            # Persistent XLA compile cache: without it every host start
+            # recompiles the full serving grid; with it a config-identical
+            # restart compiles ~nothing.
+            cache_dir = enable_compile_cache(self._config.tpu)
+            self._compile.register()
+        self._engine = InferenceEngine.from_tpu_config(
+            self._config.tpu, tracer=self.tracer,
+            compile_watch=self._compile, t0=step.t1)
+        t_build = self._engine.built_at - step.t1
         sched_engine = self._engine
         mh = self._config.tpu.multihost
         if mh and mh.get("num_processes", 1) > 1:
@@ -383,59 +415,79 @@ class EngineHost:
             self._command_loop = CommandLoop(self._engine,
                                              is_coordinator=True)
             sched_engine = MultihostEngine(self._command_loop)
-        t1 = time.perf_counter()
-        sched_engine.warmup()
-        t_warmup = time.perf_counter() - t1
-        self._compile.mark_ready()
-        self._scheduler = Scheduler(
-            sched_engine, emit_batch=self._emit_batch,
-            pipeline_depth=int(getattr(self._config.tpu,
-                                       "pipeline_depth", 2)),
-            handoff=(self._handoff_sink if self._role == "prefill"
-                     else None),
-            ledger_enabled=bool(getattr(self._config.tpu,
-                                        "ledger", True)),
-            compile_watch=self._compile)
-        # tpu.tracing=False empties every ring (the bench A/B knob); the
-        # default leaves the bounded always-on recorder running.
-        tracing = bool(getattr(self._config.tpu, "tracing", True))
+        step = span("start.warmup", t0=self._engine.built_at, parent=None)
+        with step:
+            sched_engine.warmup()
+            self._compile.mark_ready()
+        t_warmup = step.t1 - step.t0
+        step = span("start.host.scheduler", t0=step.t1, parent=None)
+        with step:
+            self._scheduler = Scheduler(
+                sched_engine, emit_batch=self._emit_batch,
+                pipeline_depth=int(getattr(self._config.tpu,
+                                           "pipeline_depth", 2)),
+                handoff=(self._handoff_sink if self._role == "prefill"
+                         else None),
+                ledger_enabled=bool(getattr(self._config.tpu,
+                                            "ledger", True)),
+                compile_watch=self._compile)
+            # tpu.tracing=False empties every ring (the bench A/B knob);
+            # the default leaves the bounded always-on recorder running.
+            # The host's own ring is emptied below, once the start-up's
+            # spans have been read out of it.
+            tracing = bool(getattr(self._config.tpu, "tracing", True))
+            self._scheduler.tracer.enabled = tracing
+            # Metrics registry gate (metrics.enabled: false → every
+            # registry op in this process is one branch) + the
+            # structured-log component tag for this process's records.
+            mcfg = self._config.get("metrics") or {}
+            METRICS.enabled = bool(mcfg.get("enabled", True))
+            set_component("host")
+            self._scheduler.start()
+            # What this process runs on, read once every program has
+            # compiled and the caches are allocated: READY, the log line
+            # and every stats reply carry the same block, so nobody
+            # downstream has to touch JAX (and take the chip) to learn it.
+            self._startup = {
+                "build_s": round(t_build, 1),
+                "warmup_s": round(t_warmup, 1),
+                "compile_cache": cache_dir,
+                "device": device_report(),
+                "attention": self._engine.attention_paths(),
+                "sampling": self._engine.sampling_route()}
+            moe = self._engine.moe_report()
+            if moe is not None:
+                self._startup["moe"] = moe
+            ssm = self._engine.ssm_report()
+            if ssm is not None:
+                self._startup["ssm"] = ssm
+            diffusion = self._engine.diffusion_report()
+            if diffusion is not None:
+                self._startup["diffusion"] = diffusion
+            cache = self._engine.cache_report()
+            if cache is not None:
+                self._startup["cache"] = cache
+            warm = self._engine.warmup_report()
+            self._startup["warmup"] = warm
+        # The timeline, frozen: the stamp `ready` is the one that ended
+        # `host.scheduler` — what follows is the frame's own write.
+        # `origin` says where `host.process` begins (utils/trace.py
+        # process_start: `kernel` or `package`).
+        self._startup["timeline"] = self.tracer.timeline() + [
+            ["ready", step.t1, step.t1, None]]
+        self._startup["origin"] = origin
         self.tracer.enabled = tracing
-        self._scheduler.tracer.enabled = tracing
-        # Metrics registry gate (metrics.enabled: false → every registry
-        # op in this process is one branch) + the structured-log
-        # component tag for this process's records.
-        mcfg = self._config.get("metrics") or {}
-        METRICS.enabled = bool(mcfg.get("enabled", True))
-        set_component("host")
-        self._scheduler.start()
-        # What this process runs on, read once every program has
-        # compiled and the caches are allocated: READY, the log line and
-        # every stats reply carry the same block, so nobody downstream
-        # has to touch JAX (and take the chip) to learn it.
-        self._startup = {
-            "build_s": round(t_build, 1), "warmup_s": round(t_warmup, 1),
-            "compile_cache": cache_dir,
-            "device": device_report(),
-            "attention": self._engine.attention_paths(),
-            "sampling": self._engine.sampling_route()}
-        moe = self._engine.moe_report()
-        if moe is not None:
-            self._startup["moe"] = moe
-        ssm = self._engine.ssm_report()
-        if ssm is not None:
-            self._startup["ssm"] = ssm
-        diffusion = self._engine.diffusion_report()
-        if diffusion is not None:
-            self._startup["diffusion"] = diffusion
-        cache = self._engine.cache_report()
-        if cache is not None:
-            self._startup["cache"] = cache
+        programs = self._engine.warmup_programs
         self._write({"op": HostOp.READY,
                      "model": self._config.model_name,
                      "role": self._role,
                      "slots": self._engine.max_slots,
                      "max_seq_len": self._engine.max_seq_len,
-                     **self._startup})
+                     **self._startup,
+                     # every record of the warm-up, here and in the log
+                     # only: `startup.warmup` (stats, every second) keeps
+                     # the totals and the five slowest
+                     "warmup_programs": programs})
         # Startup breakdown to stderr: a slow start must carry its own
         # explanation in the provider log (round-3 verdict #1).
         dev, attn = self._startup["device"], self._startup["attention"]
@@ -443,6 +495,21 @@ class EngineHost:
         hbm = " ".join(f"{h['bytes_in_use'] / 2**30:.2f}/"
                        f"{h['bytes_limit'] / 2**30:.2f}GiB"
                        for h in dev["hbm"]) or "n/a"
+        devices_s = next(t1 - t0 for name, t0, t1, _
+                         in self._startup["timeline"]
+                         if name == "build.devices")
+        slowest = " ".join(
+            "{}[{}]={:.1f}s".format(
+                r["program"], ",".join(str(r[k]) for k in ("batch", "bucket")
+                                       if r[k] is not None), r["wall_s"])
+            for r in warm["slowest"][:3])
+        # the whole record first, so the log's tail is the line a person
+        # reads
+        logger.info("engine host warm-up: " + json.dumps(
+            {"timeline": self._startup["timeline"], "origin": origin,
+             "programs": [{k: round(v, 6) if isinstance(v, float) else v
+                           for k, v in r.items()} for r in programs]},
+            separators=(",", ":")))
         logger.info(f"engine host ready: model={self._config.model_name} "
                     f"role={self._role} slots={self._engine.max_slots} "
                     f"platform={dev['platform']} "
@@ -451,7 +518,10 @@ class EngineHost:
                     f"attention={','.join(f'{k}:{v}' for k, v in attn.items())} "
                     f"sampling={samp} "
                     + (f"moe={json.dumps(moe)} " if moe else "") +
+                    f"devices={devices_s:.1f}s "
                     f"build={t_build:.1f}s warmup={t_warmup:.1f}s "
+                    f"compile={warm['compile_s']:.1f}s "
+                    f"misses={warm['cache_misses']} slowest: {slowest} "
                     f"compile_cache={cache_dir or 'off'}")
 
     def serve_forever(self) -> int:
@@ -995,11 +1065,12 @@ class EngineHost:
 
 
 def main() -> int:
+    t_main = time.monotonic()
     if len(sys.argv) != 2:
         print("usage: python -m symmetry_tpu.engine.host <config.yaml>",
               file=sys.stderr)
         return 2
-    host = EngineHost(ConfigManager(config_path=sys.argv[1]))
+    host = EngineHost(ConfigManager(config_path=sys.argv[1]), t_main)
     try:
         return host.serve_forever()
     except NoChipError as exc:

@@ -32,6 +32,7 @@ from symmetry_tpu.provider.backends.base import (
 )
 from symmetry_tpu.utils.faults import FAULTS
 from symmetry_tpu.utils.logging import logger as log
+from symmetry_tpu.utils.trace import Tracer
 
 DEFAULT_MAX_NEW_TOKENS = 512
 
@@ -235,6 +236,14 @@ class TpuNativeBackend(InferenceBackend):
         self._min_stable_s = float(sup.get("min_stable_s", 5.0))
         self._spawned_at: float | None = None
         self._device_count = 1  # chips the engine host reported at READY
+        # Every record of the engine host's warm-up, as its READY frame
+        # listed them (engine/engine.py `_warm`): the provider's flight
+        # dumps carry it. None from a host that lists none.
+        self.warmup_programs: list | None = None
+        # Where a host life's spawn, READY wait and clock handshake are
+        # stamped (`start.backend.*`, children of the provider's
+        # `provider.backend`): the provider hands in its own tracer.
+        self.start_tracer = Tracer()
         self._supervisor: asyncio.Task | None = None
         self._host_down: asyncio.Event | None = None  # set by reader EOF
         self._down_reason = "crash"
@@ -509,17 +518,27 @@ class TpuNativeBackend(InferenceBackend):
         compile cache makes it a warm start). In disagg mode a "life"
         is the PAIR: both processes are created first so their engine
         builds overlap, then each is brought to ready."""
+        span = self.start_tracer.phase
         self._host_dead = False
         self._engine_alive = True
-        self._proc = await self._spawn_one(self._cfg_path)
-        if self._local_pair:
-            self._prefill_proc = await self._spawn_one(
-                self._prefill_cfg_path)
-        ready = await self._await_ready(
-            self._proc, "decode host" if self._disagg else "engine host")
-        self._clock_offset = await self._clock_handshake(self._proc)
-        self._reader = asyncio.get_running_loop().create_task(
-            self._read_events())
+        step = span("start.backend.spawn", parent="provider.backend")
+        with step:
+            self._proc = await self._spawn_one(self._cfg_path)
+            if self._local_pair:
+                self._prefill_proc = await self._spawn_one(
+                    self._prefill_cfg_path)
+        step = span("start.backend.ready", t0=step.t1,
+                    parent="provider.backend")
+        with step:
+            ready = await self._await_ready(
+                self._proc,
+                "decode host" if self._disagg else "engine host")
+        self.warmup_programs = ready.get("warmup_programs")
+        with span("start.backend.clock", t0=step.t1,
+                  parent="provider.backend"):
+            self._clock_offset = await self._clock_handshake(self._proc)
+            self._reader = asyncio.get_running_loop().create_task(
+                self._read_events())
         if self._local_pair:
             await self._await_ready(self._prefill_proc, "prefill host")
             self._prefill_clock_offset = await self._clock_handshake(

@@ -152,6 +152,13 @@ class SymmetryProvider:
         self._unstarted = 0
         self._first_token_stamps: deque[float] = deque(maxlen=512)
         self._started_at = time.monotonic()
+        # The start-up timeline (`start.provider.*` spans of the tracer,
+        # frozen into this block when the server acknowledges the join —
+        # at the end of start() for a private provider): stats() and so
+        # every flight dump carry it. `_registering` is the span that is
+        # open from start()'s end until then.
+        self._startup: dict[str, Any] = {}
+        self._registering: Any = None
         # Always-on flight recorder (utils/trace.py): the span rings are
         # already recording; this owns the trigger — SLO breach, backend
         # error, or SIGUSR2 dumps the merged last-window timeline + a
@@ -291,7 +298,24 @@ class SymmetryProvider:
         return self._listener.address
 
     async def start(self, listen_address: str | None = None) -> None:
-        await self.backend.start()
+        """Bring the provider up. Each step is a `start.provider.*` span,
+        begun on the stamp that ended the one before: `process` (this
+        process's start → here), `backend` (its children
+        `backend.spawn|ready|clock`; the engine host's own timeline lies
+        inside `backend.ready`, on the same clock), `listen`, `dht` (the
+        announce and the rest of this function), `server` (→ the
+        server's JOIN_ACK: the dial, the handshake, the challenge) and
+        the stamp `registered`."""
+        span = self.tracer.phase
+        t_start = time.monotonic()
+        # the interpreter, the imports, the config, __init__
+        self._startup = {"origin": self.tracer.process_span(
+            "start.provider.process", t_start)}
+        if hasattr(self.backend, "start_tracer"):
+            self.backend.start_tracer = self.tracer
+        step = span("start.provider.backend", t0=t_start, parent=None)
+        with step:
+            await self.backend.start()
         if hasattr(self.backend, "on_host_restart"):
             # Supervised engine host (tpu_native process mode): every
             # crash/wedge the supervisor handles dumps the flight
@@ -299,24 +323,48 @@ class SymmetryProvider:
             self.backend.on_host_restart = self._on_backend_restart
         if hasattr(self.backend, "on_engine_stall"):
             self.backend.on_engine_stall = self._on_engine_stall
-        listen_address = listen_address or (
-            f"{self._transport.scheme}://"
-            f"{self.config.get('listenHost', '0.0.0.0')}"
-            f":{self.config.get('listenPort', 0)}"
-        )
-        self._listener = await self._transport.listen(listen_address, self._on_peer)
-        logger.info(
-            f"provider {self.config.name!r} listening on {self.address} "
-            f"key={self.identity.public_hex} model={self.config.model_name!r}"
-        )
+        step = span("start.provider.listen", t0=step.t1, parent=None)
+        with step:
+            listen_address = listen_address or (
+                f"{self._transport.scheme}://"
+                f"{self.config.get('listenHost', '0.0.0.0')}"
+                f":{self.config.get('listenPort', 0)}"
+            )
+            self._listener = await self._transport.listen(
+                listen_address, self._on_peer)
+            logger.info(
+                f"provider {self.config.name!r} listening on {self.address} "
+                f"key={self.identity.public_hex} "
+                f"model={self.config.model_name!r}"
+            )
+            if self.config.public:
+                self._spawn(self._server_loop())
+            self._spawn(self._health_loop())
+        step = span("start.provider.dht", t0=step.t1, parent=None)
+        with step:
+            await self._join_dht()
+            self._start_puncher()
+            self._install_sigusr2()
+            self._install_sigusr1()
+            self._start_metrics_server()
         if self.config.public:
-            self._spawn(self._server_loop())
-        self._spawn(self._health_loop())
-        await self._join_dht()
-        self._start_puncher()
-        self._install_sigusr2()
-        self._install_sigusr1()
-        self._start_metrics_server()
+            self._registering = span("start.provider.server", t0=step.t1,
+                                     parent=None)
+            self._registering.__enter__()
+        else:
+            self._freeze_startup()
+
+    def _freeze_startup(self) -> None:
+        """Close the span that waited for the server's acknowledgement, if
+        one is open, and read the start-up's spans out of the ring into
+        the `startup` block of stats()."""
+        timeline = []
+        if self._registering is not None:
+            self._registering.__exit__(None, None, None)
+            t = self._registering.t1
+            timeline = [["registered", t, t, None]]
+            self._registering = None
+        self._startup["timeline"] = self.tracer.timeline() + timeline
 
     def _start_metrics_server(self) -> None:
         """Prometheus exposition endpoint (`metrics.port`): a stdlib
@@ -648,6 +696,8 @@ class SymmetryProvider:
                 logger.debug("server signature verified")
             elif msg.key == MessageKey.JOIN_ACK:
                 logger.info("registered with server ✅")
+                if self._registering is not None:  # the first time only
+                    self._freeze_startup()
                 self._server_ready.set()
             elif msg.key == MessageKey.PING:
                 await peer.send(MessageKey.PONG)
@@ -749,6 +799,10 @@ class SymmetryProvider:
             "tok_s": round(self.metrics["tokens_out"] / uptime, 2),
             "ttft_s": self.tracer.histogram("ttft_s").to_dict(),
             "e2e_s": self.tracer.histogram("inference_s").to_dict(),
+            # this process's start-up timeline, once it is frozen (the
+            # engine host's own is `engine.startup.timeline`)
+            **({"startup": self._startup}
+               if "timeline" in self._startup else {}),
             # symledger headline: windowed SLO-goodput from the
             # per-request cost folds (absent until one arrives).
             **({"goodput": goodput} if goodput is not None else {}),
@@ -789,6 +843,11 @@ class SymmetryProvider:
         if engine_stats is not None:
             with contextlib.suppress(Exception):
                 stats["engine"] = await engine_stats()
+        programs = getattr(self.backend, "warmup_programs", None)
+        if programs:
+            # every record of the engine host's warm-up, as its READY
+            # frame listed them (stats keeps the totals only)
+            stats["warmup_programs"] = programs
         if self._cost_ring:
             # symledger tail: the last requests' attributed cost blocks
             # — the dump answers "what was the device doing" per

@@ -11,10 +11,13 @@ benchmark's `--trace 1` reduces that capture to `device_idle`,
 `idle_in.*`, `top_op_share` and `breakdown`.
 
 `CompileWatch` counts what JAX traces, lowers and compiles in the engine
-host, from `jax.monitoring` events — the stats op's `compile` block
-(`compile_share`, `lowerings_in_window`) — and names the events that
-overlap a span (the scheduler's stall record). `gc_seconds` is the wall the
-garbage collector has held the interpreter, from one `gc.callbacks` pair.
+host, and what the persistent cache gave or lacked, from `jax.monitoring`
+events — the stats op's `compile` block (`compile_share`,
+`lowerings_in_window`) — hands out the growth of its counts since a mark
+(the warm-up record books it to a program: engine/engine.py `_warm`) and
+names the events that overlap a span (the scheduler's stall record).
+`gc_seconds` is the wall the garbage collector has held the interpreter,
+from one `gc.callbacks` pair.
 """
 
 from __future__ import annotations
@@ -38,7 +41,12 @@ class CompileWatch:
     inside a window. Cumulative since `register()`; `mark_ready()` keeps
     the counts as they stood when warm-up ended, so growth past them is
     work the serving loop paid for. A listener body is an add and an
-    append under a lock."""
+    append under a lock.
+
+    `backend_s` is the wall of "compile or fetch": on a cache hit it is
+    mostly `retrieval_s` (reading and loading the executable), on a miss
+    the compiler's. `cache_misses` counts executables compiled AND written
+    to the persistent cache — 0 on a warm start, and with the cache off."""
 
     EVENTS = {
         "/jax/core/compile/jaxpr_trace_duration": ("traces", "trace_s"),
@@ -47,14 +55,17 @@ class CompileWatch:
         "/jax/core/compile/backend_compile_duration":
             ("backend_compiles", "backend_s"),
     }
-    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+    RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+    COUNTED = {"/jax/compilation_cache/cache_hits": "cache_hits",
+               "/jax/compilation_cache/cache_misses": "cache_misses"}
     RECENT = 32
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._counts: dict[str, float] = {
             key: 0 for pair in self.EVENTS.values() for key in pair}
-        self._counts["cache_hits"] = 0
+        self._counts.update({key: 0 for key in self.COUNTED.values()})
+        self._counts["retrieval_s"] = 0
         self._recent: deque[tuple[float, str, str, float]] = deque(
             maxlen=self.RECENT)
         self._at_ready: dict[str, float] | None = None
@@ -73,6 +84,10 @@ class CompileWatch:
 
     def _on_duration(self, event: str, duration: float,
                      **kwargs: Any) -> None:
+        if event == self.RETRIEVAL:  # one a cache hit: seconds only
+            with self._lock:
+                self._counts["retrieval_s"] += duration
+            return
         keys = self.EVENTS.get(event)
         if keys is None:
             return
@@ -83,9 +98,23 @@ class CompileWatch:
                                  str(kwargs.get("fun_name", "")), duration))
 
     def _on_event(self, event: str, **kwargs: Any) -> None:
-        if event == self.CACHE_HIT:
+        key = self.COUNTED.get(event)
+        if key is not None:
             with self._lock:
-                self._counts["cache_hits"] += 1
+                self._counts[key] += 1
+
+    def mark(self) -> dict[str, float]:
+        """The counts as they stand: hand it back to `since`."""
+        with self._lock:
+            return dict(self._counts)
+
+    def since(self, mark: dict[str, float]) -> dict[str, float]:
+        """How far each count has grown since `mark`. JAX compiles on the
+        calling thread, so between a mark and its reading on one thread
+        the growth is that thread's own work."""
+        with self._lock:
+            return {key: value - mark[key]
+                    for key, value in self._counts.items()}
 
     def _snapshot(self) -> dict[str, float]:
         out = dict(self._counts)

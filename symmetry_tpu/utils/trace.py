@@ -16,7 +16,14 @@ byte counts on the peer object. Here the equivalents are first-class:
     seconds-per-label counter and — only while a jax.profiler capture is
     active in this process (utils/devprof.capture_device_profile) — a
     `sym.<label>` TraceAnnotation, so the program's own spans sit on the
-    device trace's clock in every capture.
+    device trace's clock in every capture. It feeds no histogram (nothing
+    reads a phase's percentiles; `Tracer.record` keeps its own).
+  - The start-up timeline: every process stamps its start-up as
+    `start.<name>` phases, each begun on the stamp that ended the one
+    before (`phase(..., t0=prev.t1)`), the first on `process_start()`.
+    `Tracer.timeline()` freezes them into `[name, t0, t1, parent]` rows:
+    the `startup.timeline` of the host's READY frame and of every stats
+    reply (PERF.md §3 says which metric reads which row).
 
 Request-scoped distributed tracing (PR 5) builds on the same rings:
 
@@ -52,6 +59,12 @@ import uuid
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any
+
+
+# The kernel's stamp of a process's start is believed only this close before
+# the package's first statement (an interpreter starts in tens of ms; a
+# clock with another zero is off by the machine's suspended time).
+PROCESS_START_SLACK_S = 30.0
 
 
 def _log_buckets(lo: float, hi: float, per_decade: int = 5) -> list[float]:
@@ -153,6 +166,27 @@ class Histogram:
         }
 
 
+def process_start() -> tuple[float, str]:
+    """When this process began, on CLOCK_MONOTONIC, and where the stamp is
+    from: `kernel` — `/proc/self/stat` field 22, the fork itself in clock
+    ticks since boot, which is CLOCK_MONOTONIC's zero on a machine that has
+    not been suspended — where that lies at or before the package's first
+    statement (`symmetry_tpu.T_FIRST_STATEMENT`) by no more than an
+    interpreter's start can take; else `package`, that stamp itself (a
+    suspended machine, a time namespace, no /proc)."""
+    from symmetry_tpu import T_FIRST_STATEMENT as first_statement
+
+    try:
+        with open("/proc/self/stat") as fh:
+            ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+        born = ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return first_statement, "package"
+    if 0.0 <= first_statement - born <= PROCESS_START_SLACK_S:
+        return born, "kernel"
+    return first_statement, "package"
+
+
 def new_trace_id() -> str:
     """Mint a request trace id (carried client → provider → host →
     scheduler so every component's spans correlate)."""
@@ -215,27 +249,38 @@ def set_capture_active(active: bool) -> None:
 
 class _Phase:
     """One Tracer.phase section. Re-enterable: the scheduler suspends a
-    loop phase around a nested one by exiting and re-entering it."""
+    loop phase around a nested one by exiting and re-entering it. `t0` and
+    `t1` are its stamps once it has closed; a phase made with `t0=prev.t1`
+    begins on the stamp that ended `prev` (the start-up timeline: one
+    clock read ends a span and starts the next)."""
 
-    __slots__ = ("_tracer", "_label", "_ring", "_attrs", "_t0", "_ann")
+    __slots__ = ("_tracer", "_label", "_ring", "_attrs", "_given", "_ann",
+                 "t0", "t1")
 
     def __init__(self, tracer: "Tracer", label: str, ring: str,
-                 attrs: dict[str, Any]) -> None:
+                 attrs: dict[str, Any], t0: float | None = None,
+                 t1: float | None = None) -> None:
         self._tracer = tracer
         self._label = label
         self._ring = ring
         self._attrs = attrs
+        self._given = (t0, t1)
         self._ann = None
 
     def __enter__(self) -> dict[str, Any]:
         if _annotation is not None:
             self._ann = _annotation("sym." + self._label, **self._attrs)
             self._ann.__enter__()
-        self._t0 = time.monotonic()
+        t0 = self._given[0]
+        self.t0 = time.monotonic() if t0 is None else t0
         return self._attrs
 
     def __exit__(self, *exc: Any) -> None:
-        dt = time.monotonic() - self._t0
+        t1 = self._given[1]
+        dt = (time.monotonic() if t1 is None else t1) - self.t0
+        # the end as the ring reads it back (start + duration): the next
+        # span's start is this very number
+        self.t1 = self.t0 + dt
         if self._ann is not None:
             # Entered under a capture; exiting after stop_trace is a no-op
             # inside the profiler.
@@ -245,7 +290,8 @@ class _Phase:
         with tracer._lock:
             tracer.phase_s[self._label] = (
                 tracer.phase_s.get(self._label, 0.0) + dt)
-        tracer.record(self._ring, self._t0, dt, **self._attrs)
+        if tracer.enabled:
+            tracer._append(self._ring, self.t0, dt, **self._attrs)
 
 
 class Tracer:
@@ -270,7 +316,8 @@ class Tracer:
         self.phase_s: dict[str, float] = {}
         self._lock = threading.Lock()
 
-    def phase(self, label: str, ring: str | None = None,
+    def phase(self, label: str, ring: str | None = None, *,
+              t0: float | None = None, t1: float | None = None,
               **attrs: Any) -> _Phase:
         """A timed section named `<component>.<name>` (`sched.sync`,
         `engine.prefill`, `host.pipe_flush`). On exit it (a) records the
@@ -279,20 +326,52 @@ class Tracer:
         is active in this process, it ran inside
         `TraceAnnotation("sym.<label>", **attrs)` — the same interval on
         the device trace's clock. Yields the attrs dict, so the block can
-        annotate the span (e.g. token counts) before it closes."""
-        return _Phase(self, label, ring or label, attrs)
+        annotate the span (e.g. token counts) before it closes. `t0` /
+        `t1` give a stamp taken elsewhere in place of this span's own
+        clock read: the end of the span before it, or an interval that
+        closed before this tracer existed (the process's own start)."""
+        return _Phase(self, label, ring or label, attrs, t0, t1)
 
-    def record(self, name: str, start: float, duration_s: float,
-               request_id: str = "", trace_id: str = "",
-               **attrs: Any) -> None:
-        if not self.enabled:
-            return
+    def _append(self, name: str, start: float, duration_s: float,
+                request_id: str = "", trace_id: str = "",
+                **attrs: Any) -> None:
         with self._lock:
             self._spans.append(Span(name=name, start=start,
                                     duration_s=duration_s,
                                     request_id=request_id,
-                                    trace_id=trace_id, attrs=dict(attrs)))
+                                    trace_id=trace_id, attrs=attrs))
+
+    def record(self, name: str, start: float, duration_s: float,
+               request_id: str = "", trace_id: str = "",
+               **attrs: Any) -> None:
+        """A span stamped by the caller: a ring record and an observation
+        of the histogram `<name>_s` (`ttft_s`, `inference_s`: what
+        `stats()` and the provider's stats reply read)."""
+        if not self.enabled:
+            return
+        self._append(name, start, duration_s, request_id, trace_id, **attrs)
         self.histogram(f"{name}_s").observe(duration_s)
+
+    def process_span(self, label: str, until: float) -> str:
+        """The start-up timeline's first span: this process's own start →
+        `until`, a stamp of the caller's (its entry point). Returns where
+        the start is from (`process_start`: `kernel` or `package`)."""
+        born, origin = process_start()
+        with self.phase(label, t0=min(born, until), t1=until, parent=None):
+            pass
+        return origin
+
+    def timeline(self, prefix: str = "start.") -> list[list]:
+        """The ring's `<prefix><name>` spans that name a `parent` (None at
+        the top level), oldest first, as `[name, t0, t1, parent]` in
+        seconds on this process's CLOCK_MONOTONIC — the start-up timeline,
+        frozen by its owner once the last span has closed."""
+        with self._lock:
+            spans = [s for s in self._spans
+                     if s.name.startswith(prefix) and "parent" in s.attrs]
+        return [[s.name[len(prefix):], s.start, s.start + s.duration_s,
+                 s.attrs["parent"]]
+                for s in sorted(spans, key=lambda s: s.start)]
 
     def counter(self, name: str, value: float,
                 t: float | None = None) -> None:

@@ -466,6 +466,7 @@ class TestCompileWatch:
         assert reply["op"] == "stats" and reply["tokens"] == 0
         assert set(reply["compile"]) == {
             "traces", "trace_s", "lowerings", "lower_s", "backend_compiles",
-            "backend_s", "cache_hits", "host_s", "at_ready", "recent"}
+            "backend_s", "cache_hits", "cache_misses", "retrieval_s",
+            "host_s", "at_ready", "recent"}
         assert tuple(reply["loop_s"]) == LOOP_PHASES
         assert reply["loop_iters"] == 0

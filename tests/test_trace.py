@@ -59,8 +59,13 @@ class TestTracer:
         assert spans[0]["bucket"] == 128
         assert spans[0]["duration_s"] >= 0.01
         assert tr.export(request_id="r2")[0]["bucket"] == 512
-        assert tr.stats()["prefill_s"]["count"] == 2
+        # a phase is a ring record and seconds under its label; it feeds
+        # no histogram (Tracer.record keeps its own)
+        assert [s["name"] for s in spans].count("prefill") == 2
+        assert tr.stats() == {}
         assert tr.phase_s["prefill"] >= 0.01
+        tr.record("ttft", 0.0, 0.25)
+        assert tr.stats()["ttft_s"]["count"] == 1
 
     def test_disabled_is_noop(self):
         tr = Tracer()
