@@ -2407,11 +2407,17 @@ class InferenceEngine:
         # shape warm-up compiles
         decode_tokens = self.max_slots * (
             1 if self._diffusion is None else self._diffusion.block)
-        prefills = sorted({b * bucket for bucket in self.prefill_buckets
-                           for b in self.prefill_batches_for(bucket)})
+        admissions = {(b, bucket) for bucket in self.prefill_buckets
+                      for b in self.prefill_batches_for(bucket)}
+        prefills = sorted({b * bucket for b, bucket in admissions})
+        # ... and, under block diffusion, the opening block's forwards of
+        # an admission: batch x block (`diffusion.admit_forwards` counts
+        # them)
+        openings = [] if self._diffusion is None else sorted(
+            {b * self._diffusion.block for b, _ in admissions})
         gmm_form = grouped_matmul_form(
-            wg, min(decode_tokens, *prefills) * c.num_experts_per_tok,
-            one_device=self.mesh is None)
+            wg, min(decode_tokens, *prefills, *openings)
+            * c.num_experts_per_tok, one_device=self.mesh is None)
         if gmm_form["form"] != "ragged_dot":
             # the kernel's weight operand: the layers' stack as it lies
             # (it addresses layer and expert), or one layer's slice of it
@@ -2432,6 +2438,9 @@ class InferenceEngine:
                 "matmul's operand, scales on the accumulator"
                 if isinstance(wg, QuantizedTensor) else "not quantized"),
         }
+        if openings:
+            self._moe_report["route"]["opening"] = {
+                str(t): route(t) for t in openings}
         if getattr(c, "router_score", "softmax") == "sigmoid":
             # lfm2_moe: the router's form, and which layers have experts
             self._moe_report["router"] = {

@@ -52,7 +52,7 @@ Protocol: JSON lines.
             "sampling": {"top_k", "cap"?, "stages"?: [{"groups",
                          "width"}, …], "ranked"?},
             "moe"?: {"experts", "top_k", "layout", "route": {"decode",
-                     "prefill"}, "quantized_leaf_route"},
+                     "prefill", "opening"?}, "quantized_leaf_route"},
             "timeline": [[name, t0, t1, parent], …], "origin",
             "warmup": {"programs", "wall_s", "compile_s", "retrieval_s",
                        "run_s", "cache_hits", "cache_misses", "slowest"},

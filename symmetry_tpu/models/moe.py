@@ -8,9 +8,10 @@ experts per token, softmax over the k selected logits, and
 
 Neither form drops a (token, expert) pair, and there is no capacity:
 
-- ROUTED (`_routed_ffn`; programs of at least the routing shape's crossing:
-  `moe_route`). The T*k pairs are sorted by expert, so each expert's rows
-  are one contiguous group; the three expert matmuls run over the groups —
+- ROUTED (`_routed_ffn`; programs outside the band of token counts the
+  mixture keeps at this routing shape: `moe_route`). The T*k pairs are
+  sorted by expert, so each expert's rows are one contiguous group; the
+  three expert matmuls run over the groups —
   T*k rows whatever the routing: static shapes, FLOPs of k experts and not
   of all of them — the rows are un-sorted by a gather and combined with the
   gates in float32. The grouped matmul (`_grouped_matmul`) is
@@ -22,7 +23,8 @@ Neither form drops a (token, expert) pair, and there is no capacity:
     under a mesh (each shard of mixtral-8x7b on `model: 4`), for bf16 /
     float32 stacks, for a shape the kernel cannot tile. It is also the
     kernel's reference in the tests.
-- DENSE MIXTURE (`_dense_mixture`; programs under the crossing). Every
+- DENSE MIXTURE (`_dense_mixture`; programs inside the shape's band: under
+  the crossing, and at 128 experts top 8 from 64 tokens up). Every
   expert computes every token as one batched matmul and the gates, zero
   outside the top k, weight the combine: X/k times the FLOPs, the same
   weight bytes. A decode step of B slots x k pairs hits every expert of a
@@ -94,8 +96,14 @@ ROUTED_MIN_TOKENS = 1024
 # The one-chip routing shapes, each from its own reading of
 # tools/moe_decode_ab.py with the kernel under the routed form (PERF.md,
 # PR 36; a v5e; ms a layer: routed over `ragged_dot` / routed over the
-# kernel / dense mixture): (experts, k) -> the least tokens a dispatch at
-# which routing pays.
+# kernel / dense mixture): (experts, k) -> the BAND of tokens a dispatch
+# the dense mixture keeps, `(lo, hi)`: the mixture iff lo <= tokens < hi,
+# routed on either side of it. `hi` is the crossing each table below ends
+# in (the first size from which routing wins at every larger one); `lo` is
+# 0 — the mixture all the way down — for every shape whose low end has no
+# reading that says otherwise, and the first count from which the MIXTURE
+# wins at every larger one up to `hi` where it has ((128, 8), PR 57: few
+# tokens hit few experts, and the kernel reads the hit ones alone).
 # 72 top 10 at expert width 768 (granite-4.0-h-small; `--shape
 # 72,10,4096,768`; the weight stream's floor is 0.83): 16 tokens 4.03 /
 # 0.89 / 0.96, 64: 4.75 / 1.06 / 0.94, 128: 5.51 / 1.17 / 1.14, 256: 7.67 /
@@ -127,6 +135,18 @@ ROUTED_MIN_TOKENS = 1024
 # 6%; from 128 the kernel does. They cross between 64 and 128 and no
 # dispatch lies between: decode keeps the mixture, every prefill of the
 # long-document cell (1,024 tokens and up) is routed.
+# The same shape's LOW end (sdar-30b-a3b-chat's opening blocks: 1-16 rows x
+# 4 positions; PERF.md, PR 57; three repeats within 0.5%): 4 tokens 2.32 /
+# 0.24 / 0.82, 8: 2.67 / 0.37 / 0.82, 16: 3.32 / 0.59 / 0.82, 32: 4.28 /
+# 0.77 / 0.82, 64: 6.20 / 0.88 / 0.82, 128: 6.33 / 0.95 / 0.99. The mixture
+# streams all 128 experts whatever the tokens (0.82 ms: 90% of the floor);
+# the kernel reads the hit ones alone — 32 pairs hit ~29, three calls of
+# 0.066 ms at 84% of THEIR bytes' stream, and the sort, the gathers and the
+# combine are 0.03 ms a layer beside them — so it wins by 3.5x at 4 tokens,
+# 2.2x at 8, 29% at 16 and 6% at 32, and loses by 7% at 64: the mixture's
+# band is [64, 128). Under routing that is not uniform the kernel reads
+# fewer still (eight tokens that route alike, as an admission's pad rows
+# and masked positions do: 0.10 ms a layer).
 # 32 top 4 at expert width 1,792 (lfm2-8b-a1b; `--shape 32,4,2048,1792`;
 # PERF.md, PR 42; floor 0.43 for all 32 experts): 16 tokens 1.41 / 0.47 /
 # 0.49, 32: 1.73 / 0.52 / 0.49, 64: 2.25 / 0.55 / 0.49, 128: 3.23 / 0.60 /
@@ -149,16 +169,16 @@ ROUTED_MIN_TOKENS = 1024
 # the tie keeps the mixture, as granite's did, and the crossing is the
 # first size from which routing wins at every larger one — decode keeps the
 # mixture, every prefill of the report cell (6,912 tokens and up) is routed.
-ROUTED_FROM = {(72, 10): 256, (512, 10): 1, (128, 8): 128, (32, 4): 256,
-               (128, 6): 128}
+ROUTED_FROM = {(72, 10): (0, 256), (512, 10): (0, 1), (128, 8): (64, 128),
+               (32, 4): (0, 256), (128, 6): (0, 128)}
 
 
 def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
     """The form a program of `n_tokens` tokens takes, from the shape alone:
-    the measured crossing of this routing shape, or mixtral's where none
-    was measured."""
-    least = ROUTED_FROM.get((experts, k), ROUTED_MIN_TOKENS)
-    return "routed" if n_tokens >= least else "dense-mixture"
+    the dense mixture inside the measured band of this routing shape (or
+    under mixtral's crossing where none was measured), routed outside it."""
+    lo, hi = ROUTED_FROM.get((experts, k), (0, ROUTED_MIN_TOKENS))
+    return "dense-mixture" if lo <= n_tokens < hi else "routed"
 
 
 def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int, *,

@@ -427,6 +427,29 @@ def test_startup_reports(params, engines):
         engine.moe_report()["route"]["prefill"]["32"]
 
 
+@pytest.mark.parametrize("band,forms", [
+    (None, ["dense-mixture", "dense-mixture", "dense-mixture"]),
+    ((16, 64), ["routed", "routed", "dense-mixture"])])
+def test_startup_reports_the_opening_blocks_route(engines, monkeypatch, band,
+                                                  forms):
+    """`startup.moe.route.opening`: the form of the opening block's forwards
+    (batch x block tokens) for every admission batch warm-up compiles — 1, 2
+    and 4 rows of 4 positions here; times `diffusion.admit_forwards` it says
+    how many forwards took which form. The tiny shape (8 experts top 2) has
+    no band of its own: the mixture everywhere, until it is given one."""
+    from symmetry_tpu.models import moe
+
+    engine = engines["static"]
+    monkeypatch.setattr(engine, "_moe_report", None)    # built once
+    if band is not None:
+        monkeypatch.setitem(moe.ROUTED_FROM, (8, 2), band)
+    route = engine.moe_report()["route"]
+    assert route["opening"] == dict(zip(("4", "8", "16"), forms))
+    assert set(route) == {"decode", "prefill", "opening"}
+    # the decode forward's 16 tokens and the opening block's take one form
+    assert route["decode"] == route["opening"]["16"]
+
+
 # ---------------------------------------------------------------------------
 # what is refused
 

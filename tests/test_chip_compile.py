@@ -706,19 +706,14 @@ def test_lfm2_prefill_lowers_flash_at_a_head_of_64_and_routes_its_experts(
     assert not touched, touched[0]
 
 
-def test_sdar_decode_dispatch_denoises_and_commits_in_place(
-        one_chip, no_cache, monkeypatch):
-    """sdar-30b-a3b-chat's decode dispatch, whole, for a described v5e: four
-    blocks a slot, each two denoise forwards and a commit over [128, 4]
-    positions (a scan of blocks around a loop of forwards around the scan
-    of layers). The 1 GB cache rides every loop's carry: no level of the
-    nesting may copy or relay it, the experts run the grouped-matmul kernel
-    (a forward routes 4,096 pairs), and attention is the decode kernel with
-    the block of queries as its q tile: no layer's K or V is sliced out of
-    the cache, staged or relaid (the XLA route's 42 MB each a layer)."""
+def _sdar_engine(one_chip, monkeypatch):
+    """sdar-30b-a3b-chat's engine programs without its arrays
+    (`tools/lowered_programs.py bare_engine`: 128 slots x 640, two denoise
+    steps a block), the kernels compiled and not interpreted: (config,
+    engine, `shaped` — a function's output shapes on the described chip —
+    and the int8 parameters' shapes)."""
     import importlib.util
 
-    from symmetry_tpu.engine import engine as eng_mod
     from symmetry_tpu.models import llama, moe
 
     for module in (llama, moe):
@@ -729,7 +724,6 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
     cfg = llama.preset("sdar-30b-a3b-chat")
-    e = tool.bare_engine(cfg)
 
     def shaped(fn):
         return jax.tree.map(
@@ -740,6 +734,23 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
     params = shaped(lambda: llama.init_params(
         cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
         slice_above=1 << 40))
+    return cfg, tool.bare_engine(cfg), shaped, params
+
+
+def test_sdar_decode_dispatch_denoises_and_commits_in_place(
+        one_chip, no_cache, monkeypatch):
+    """sdar-30b-a3b-chat's decode dispatch, whole, for a described v5e: four
+    blocks a slot, each two denoise forwards and a commit over [128, 4]
+    positions (a scan of blocks around a loop of forwards around the scan
+    of layers). The 1 GB cache rides every loop's carry: no level of the
+    nesting may copy or relay it, the experts run the grouped-matmul kernel
+    (a forward routes 4,096 pairs), and attention is the decode kernel with
+    the block of queries as its q tile: no layer's K or V is sliced out of
+    the cache, staged or relaid (the XLA route's 42 MB each a layer)."""
+    from symmetry_tpu.engine import engine as eng_mod
+    from symmetry_tpu.models import llama
+
+    cfg, e, shaped, params = _sdar_engine(one_chip, monkeypatch)
     state = shaped(lambda: eng_mod.DecodeState(
         cache=llama.init_cache(cfg, 128, 640, jnp.bfloat16, quantized=True,
                                count_experts=True),
@@ -801,6 +812,58 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
     # weights 8.4 GB and the cache 1.04 GB are arguments; what the program
     # adds (logits of [512, 151936] and the sampler's windows) stays small
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+@pytest.mark.parametrize("rows,gmm_calls,mixture_tokens", [
+    # the prompt's forward: 1 x 64 tokens a mixture, 256 and 1,024 routed;
+    # the opening block's two trunks (the denoise loop's and the commit's):
+    # 4 and 16 tokens routed, three `moe_gmm` calls a scan body each
+    (1, 6, [64]), (4, 9, []),
+    # 16 rows x 4 positions = 64 tokens: inside (128, 8)'s band, the mixture
+    (16, 3, [64])])
+def test_sdar_admission_routes_its_small_opening_blocks(
+        one_chip, no_cache, monkeypatch, rows, gmm_calls, mixture_tokens):
+    """sdar-30b-a3b-chat's admission program (`bd_prefill`: the prompt's
+    forward, then two denoise forwards and the commit over [rows, 4]
+    positions), whole, at the 64 bucket for a described v5e. Under 64
+    tokens the opening block's forwards take the routed form over the
+    kernel — no `[tokens, 128, 768]` product of every expert (PR 57: 604 MB
+    a layer streamed for 32-256 pairs) — and from 64 the mixture, as
+    `models/moe.py moe_route` reads its band. (This model's scale planes
+    have crashed the v5e compiler's repacker once: PERF.md §7 (7).)"""
+    from symmetry_tpu.models import llama, moe
+
+    cfg, e, shaped, params = _sdar_engine(one_chip, monkeypatch)
+    block, bucket = cfg.diffusion.block, 64
+
+    def vec(dtype):
+        return jax.ShapeDtypeStruct((rows,), dtype, sharding=one_chip)
+
+    scratch = shaped(lambda: llama.init_cache(
+        cfg, rows, bucket + block, jnp.bfloat16, quantized=True,
+        count_experts=True))
+    keys = shaped(lambda: jax.random.split(jax.random.key(0), rows))
+    with jax.default_matmul_precision("default"):
+        text = e._prefill.lower(
+            params, jax.ShapeDtypeStruct((rows, bucket), jnp.int32,
+                                         sharding=one_chip),
+            vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
+            vec(jnp.int32), keys, scratch).compile().as_text()
+    opening = rows * block
+    assert moe.moe_route(opening, 128, 8) == (
+        "dense-mixture" if opening in mixture_tokens else "routed")
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == gmm_calls
+    for tokens in {opening, rows * bucket}:
+        product = re.findall(rf"(?:bf16|f32)\[{tokens},128,768\]", text)
+        assert bool(product) == (tokens in mixture_tokens), (tokens,
+                                                             product[:1])
+    if not mixture_tokens:
+        # every forward routed: nothing yields one layer's experts (the
+        # mixture's dots are what read a layer's slice of the stacks)
+        sliced = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= s8\[(1,)?128,(2048,768|768,2048)\]",
+                               line)]
+        assert not sliced, sliced[0]
 
 
 def _kanana_text(one_chip, monkeypatch, rows, capacity, S):

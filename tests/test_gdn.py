@@ -310,7 +310,7 @@ def test_moe_route_at_512_experts_is_a_measured_entry():
     """Over the grouped-matmul kernel the routed form wins at every size
     measured (16-2,048 tokens: it reads the hit experts alone, the mixture
     all 512), decode's 128 tokens among them (PR 36)."""
-    assert moe.ROUTED_FROM[(512, 10)] == 1
+    assert moe.ROUTED_FROM[(512, 10)] == (0, 1)     # the mixture: never
     for tokens in (1, 16, 64, 128, 512, 2048):
         assert moe.moe_route(tokens, 512, 10) == "routed"
     # the other shapes keep crossings of their own

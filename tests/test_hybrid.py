@@ -203,7 +203,7 @@ def test_72_way_routing_and_the_shared_expert_against_a_per_token_loop(
     cfg = dataclasses.replace(CFG, num_experts=X, num_experts_per_tok=k)
     # each form at 400 tokens, whichever side of the crossing that is
     monkeypatch.setitem(moe.ROUTED_FROM, (X, k),
-                        1 if form == "routed" else 401)
+                        (0, 1 if form == "routed" else 401))
     assert moe.moe_route(tokens, X, k) == form
     keys = jax.random.split(jax.random.key(17), 8)
     lp = {"router": jax.random.normal(keys[0], (D, X)),

@@ -242,6 +242,112 @@ def test_the_form_follows_the_token_count(weights, grouped):
         assert other not in jaxpr
 
 
+# What each routing shape's single "routed from" threshold was before an
+# entry became the band of counts the mixture keeps (PR 57); None: the shape
+# without a reading of its own, which takes mixtral's `ROUTED_MIN_TOKENS`.
+THRESHOLD_WAS = {(72, 10): 256, (512, 10): 1, (128, 8): 128, (32, 4): 256,
+                 (128, 6): 128, None: 1024}
+
+
+@pytest.mark.parametrize("shape", list(THRESHOLD_WAS), ids=str)
+def test_a_band_answers_as_its_threshold_did(shape):
+    """Every entry is `(lo, hi)`: the mixture iff lo <= tokens < hi. At
+    every count the old threshold decided — under a lower edge only (128, 8)
+    has one — the answer is the one it gave."""
+    assert set(moe.ROUTED_FROM) == set(THRESHOLD_WAS) - {None}
+    lo, hi = moe.ROUTED_FROM.get(shape, (0, moe.ROUTED_MIN_TOKENS))
+    assert hi == THRESHOLD_WAS[shape] and 0 <= lo < hi
+    assert (lo > 0) == (shape == (128, 8))
+    args = shape or (8, 2)
+    for tokens in (1, lo - 1, lo, hi - 1, hi, 4096):
+        if tokens < max(lo, 1):
+            continue                    # the band's new side, or no count
+        was = "routed" if tokens >= THRESHOLD_WAS[shape] else "dense-mixture"
+        assert moe.moe_route(tokens, *args) == was, tokens
+
+
+@pytest.mark.parametrize("tokens,form", [
+    (4, "routed"), (8, "routed"), (16, "routed"), (32, "routed"),
+    (63, "routed"), (64, "dense-mixture"), (127, "dense-mixture"),
+    (128, "routed"), (512, "routed")])
+def test_128_top_8_keeps_the_mixture_from_64_to_127_alone(tokens, form):
+    """sdar's opening blocks (batch 1-8 x 4 positions) are routed: the
+    kernel reads the hit experts, the mixture all 128 (`models/moe.py`'s
+    table). 64 (keye's decode, sdar's 16-row opening block and its 1 x 64
+    prompt forward) keeps the mixture; 128 and up were always routed."""
+    assert moe.moe_route(tokens, 128, 8) == form
+
+
+def small_dispatch(tokens: int, routing: str):
+    """One layer of 128 experts top 8 (int8 stacks [1, 128, 64, 32]) and
+    `tokens` rows as an admission's opening block holds them. `pad-rows`:
+    the last request's rows repeated, bit-identical, to fill the batch (the
+    scheduler's pad rows). `same-eight`: every token picks experts 0..7, so
+    eight groups hold all the rows and 120 are empty."""
+    X, k, D, F = 128, 8, 64, 32
+    keys = jax.random.split(jax.random.key(57), 5)
+    stacks = {"wg": make_leaf(keys[0], (1, X, D, F), D ** -0.5,
+                              jnp.float32, True),
+              "wu": make_leaf(keys[1], (1, X, D, F), D ** -0.5,
+                              jnp.float32, True),
+              "wd": make_leaf(keys[2], (1, X, F, D), F ** -0.5,
+                              jnp.float32, True)}
+    router = jax.random.normal(keys[3], (D, X), jnp.float32)
+    x = jax.random.normal(keys[4], (tokens, D), jnp.float32)
+    if routing == "pad-rows":
+        x = jnp.tile(x[:4], (tokens // 4, 1))
+    else:
+        u = jnp.ones((D,), jnp.float32) / np.sqrt(D)
+        bias = jnp.asarray([9.0 - 0.25 * e for e in range(k)]
+                           + [0.0] * (X - k))
+        router = 0.05 * router + jnp.outer(u, bias)
+        x = x + 4.0 * u
+    return stacks, router, x, k
+
+
+@pytest.mark.parametrize("routing", ["pad-rows", "same-eight"])
+@pytest.mark.parametrize("tokens", [4, 8, 16, 32])
+def test_a_small_dispatch_is_the_same_sum_in_both_forms(tokens, routing):
+    """What (128, 8)'s lower edge changes: 4-32 tokens through the routed
+    form over the kernel (32-256 rows; a row tile of 32 at 32 rows) against
+    the mixture they took before and against the float32 reference's loop
+    over experts. All three in float32 on the dequantised int8 weights,
+    another order of accumulation: atol 4e-6 of the largest output."""
+    from reference import block_diffusion_moe_decoder as bd_ref
+
+    stacks, router, x, k = small_dispatch(tokens, routing)
+    lp = {name: jax.tree.map(lambda a: a[0], w)
+          for name, w in stacks.items()}
+    valid = jnp.ones((tokens,), bool)
+    form = moe.grouped_matmul_form(stacks["wg"], tokens * k)
+    assert form == {"form": "pallas-interpret",
+                    "row_tile": min(64, tokens * k)}
+    routed, pairs = jax.jit(
+        lambda x: moe._routed_ffn(x, valid, router, lp["wg"], lp["wu"],
+                                  lp["wd"], k, (stacks, jnp.int32(0))))(x)
+    mixture, pairs_m = jax.jit(
+        lambda x: moe._dense_mixture(x, valid, router, lp["wg"], lp["wu"],
+                                     lp["wd"], k))(x)
+    with jax.default_matmul_precision("highest"):
+        want, margin = bd_ref.sparse_moe_block(
+            x, router, *(dequantize(lp[n]) for n in ("wg", "wu", "wd")), k)
+    assert float(np.asarray(margin).min()) >= 1e-3
+    scale = float(np.abs(np.asarray(want)).max())
+    assert scale > 0.05
+    for got in (routed, mixture):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=4e-6 * scale, rtol=0)
+    np.testing.assert_array_equal(np.asarray(pairs), np.asarray(pairs_m))
+    assert int(pairs.sum()) == tokens * k
+    if routing == "same-eight":
+        assert np.asarray(pairs).tolist() == [tokens] * k + [0] * (128 - k)
+    else:
+        # a pad row is the row it repeats, bit for bit, in the routed form
+        np.testing.assert_array_equal(
+            np.asarray(routed), np.tile(np.asarray(routed[:4]),
+                                        (tokens // 4, 1)))
+
+
 @pytest.mark.parametrize("kv", ["dense", "int8"])
 @pytest.mark.parametrize("weights", ["float32", "int8"])
 @pytest.mark.parametrize("form", FORMS, indirect=True)

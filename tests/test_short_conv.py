@@ -385,7 +385,8 @@ def test_both_expert_forms_take_the_sigmoid_gates(tokens, form):
 
 def test_moe_route_at_32_experts_top_4_is_a_measured_entry():
     assert (32, 4) in moe.ROUTED_FROM
-    least = moe.ROUTED_FROM[(32, 4)]
+    lo, least = moe.ROUTED_FROM[(32, 4)]    # the mixture's band
+    assert lo == 0                          # no measured lower edge
     assert moe.moe_route(least, 32, 4) == "routed"
     if least > 1:
         assert moe.moe_route(least - 1, 32, 4) == "dense-mixture"

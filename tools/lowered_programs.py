@@ -9,7 +9,8 @@ qwen3-next-80b-a3b, keye-vl-2.0-30b-a3b, lfm2-8b-a1b, sdar-30b-a3b-chat
 kanana-2-30b-a3b (its latent cache in bfloat16) at the closed cells' shape,
 128 slots x 640, int8 weights + int8 KV, decode_block 16; and tiny-moe8 —
 the stand-in for mixtral-8x7b's sharded programs — on a `model: 4` mesh of
-virtual CPU devices: twenty-seven programs) it writes the
+virtual CPU devices: twenty-eight programs, sdar's a second
+admission at (16, 64) among them) it writes the
 StableHLO of the engine's OWN jits — `decode_block`, `prefill` at (8, 256)
 and `insert_all` — lowered from shapes alone (nothing is built or run), as
 `OUT_DIR/<preset>.<program>.txt` and prints one sha256 a file. The text
@@ -32,7 +33,10 @@ operands alone — the stacks ride it as constants it never reads. PR 50, a
 block of queries a slot through the decode kernel: 23 of the 24 identical,
 sdar-30b-a3b-chat's `decode_block` the one that differs. PR 54, latent
 attention as a mixer kind of the hybrid trunk: all 24 older files
-identical, three new ones.)
+identical, three new ones. PR 57, (128, 8)'s band: 26 of the 27
+identical — sdar-30b-a3b-chat's `prefill` (8 rows: an opening block of 32
+tokens, routed now) the one that differs; its `prefill_16x64`, new in the
+tool, identical on parent and change.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
@@ -58,6 +62,7 @@ from symmetry_tpu.models import llama  # noqa: E402
 
 SLOTS, CAPACITY, BLOCK = 128, 640, 16
 PREFILL = (8, 256)
+PREFILL_WIDE = (16, 64)     # a diffusion preset's second admission shape
 
 
 def shapes(fn):
@@ -83,29 +88,41 @@ def bare_engine(cfg):
 
 
 def programs(e, params, state):
-    n, bucket = PREFILL
     cfg = e.config
     i32, f32 = jnp.int32, jnp.float32
-
-    def vec(dtype):
-        return jax.ShapeDtypeStruct((n,), dtype)
-
-    keys = shapes(lambda: jax.random.split(jax.random.key(0), n))
     # (a block-diffusion admission commits its opening block behind the
     # bucket and hands the block over where the others hand one token)
     block = getattr(getattr(cfg, "diffusion", None), "block", 0)
-    scratch = shapes(lambda: llama.init_cache(
-        cfg, n, bucket + block, jnp.bfloat16, quantized=e.kv_quant,
-        count_experts=e._count_experts))
-    first = (jax.ShapeDtypeStruct((n, block), i32) if block else vec(i32))
+
+    def vec(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype)
+
+    def admission(n, bucket):
+        """`prefill`'s arguments after `params`: tokens, true lengths, the
+        three sampling vectors, the keys and the scratch."""
+        keys = shapes(lambda: jax.random.split(jax.random.key(0), n))
+        scratch = shapes(lambda: llama.init_cache(
+            cfg, n, bucket + block, jnp.bfloat16, quantized=e.kv_quant,
+            count_experts=e._count_experts))
+        return (jax.ShapeDtypeStruct((n, bucket), i32), vec(n, i32),
+                vec(n, f32), vec(n, f32), vec(n, i32), keys, scratch)
+
+    n = PREFILL[0]
+    args = admission(*PREFILL)
+    keys, scratch = args[-2:]
+    first = jax.ShapeDtypeStruct((n, block), i32) if block else vec(n, i32)
     yield "decode_block", e._decode.lower(
         params, state, jax.ShapeDtypeStruct((e.max_slots,), bool))
-    yield "prefill", e._prefill.lower(
-        params, jax.ShapeDtypeStruct((n, bucket), i32), vec(i32), vec(f32),
-        vec(f32), vec(i32), keys, scratch)
+    yield "prefill", e._prefill.lower(params, *args)
     yield "insert_all", e._insert_all.lower(
-        state, scratch, vec(i32), vec(i32), first, vec(f32), vec(f32),
-        vec(i32), keys)
+        state, scratch, vec(n, i32), vec(n, i32), first, vec(n, f32),
+        vec(n, f32), vec(n, i32), keys)
+    if block:
+        # a second admission shape: 16 rows x 4 positions are an opening
+        # block of 64 tokens, inside (128, 8)'s band of the dense mixture,
+        # where the 8 rows above are under it and routed (PR 57)
+        yield ("prefill_%dx%d" % PREFILL_WIDE,
+               e._prefill.lower(params, *admission(*PREFILL_WIDE)))
 
 
 def main() -> int:
