@@ -69,7 +69,14 @@ contends for the chip its child needs.
         # latent attention on one chip (one cached row of 576 values in 640
         # lanes, no int8 form: `kv_quantization` null); the report's
         # `attention.kind` reads `latent` and it carries `cache`
+    python chip_smoke.py --preset smallthinker-21b-a3b
+        # window (RoPE, 4,096) and full (NoPE) attention layers in one
+        # cache: the report's `attention.kind` reads `window+full` with a
+        # route a kind — the decode of BOTH must read `pallas`, a window
+        # layer's over its ring of 4,096 rows — and it carries `cache`
+        # (`cache_bytes` against `uniform_cache_bytes`)
     JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny-mla
+    JAX_PLATFORMS=cpu python chip_smoke.py --preset tiny-swa
         # CPU dry run of every phase; ends non-zero: "platform is cpu"
 """
 
@@ -105,6 +112,9 @@ DIFFUSION_PRESETS = ("sdar-30b-a3b-chat", "tiny-bd")
 # `kv_quantization` null), the report carries `cache` and the decode step
 # runs absorbed through ops/mla_attention.py
 LATENT_PRESETS = ("kanana-2-30b-a3b", "tiny-mla")
+# presets with window AND full attention layers: a route a kind under
+# `attention.full` / `attention.window`, and `cache` with both leaves' bytes
+WINDOW_PRESETS = ("smallthinker-21b-a3b", "tiny-swa")
 
 
 class SmokeFailure(Exception):
@@ -367,6 +377,15 @@ async def serve_and_check(cfg: dict, log_path: str) -> dict:
             or (startup.get("cache") or {}).get("kind") != "latent"):
         failures.append(f"a model with latent attention reported no latent "
                         f"cache: {attention} {startup.get('cache')}")
+    if cfg["tpu"]["model_preset"] in WINDOW_PRESETS and (
+            attention.get("kind") != "window+full"
+            or {(attention.get(kind) or {}).get("decode")
+                for kind in ("full", "window")} != {"pallas"}
+            or (startup.get("cache") or {}).get("kind") != "window+full"):
+        failures.append(f"a model with window and full attention layers did "
+                        f"not decode both kinds through the kernel, or "
+                        f"reported no window+full cache: {attention} "
+                        f"{startup.get('cache')}")
     sparse = attention.get("sparse") or {}
     if (cfg["tpu"]["model_preset"] in SPARSE_PRESETS
             and not (sparse.get("form") or {}).get("decode")):
@@ -470,14 +489,14 @@ def main() -> int:
     args = ap.parse_args()
 
     if os.environ.get("JAX_PLATFORMS") == "cpu" and args.preset not in (
-            "tiny", "tiny-bd", "tiny-mla"):
+            "tiny", "tiny-bd", "tiny-mla", "tiny-swa"):
         # The engine host obeys a CPU pinned by name (utils/device.py), so
         # the verdict is known before anything starts — and a full-width
         # model is not built on a CPU to reach it.
         print(f"chip_smoke: FAIL: JAX_PLATFORMS=cpu pins the engine host "
               f"to the CPU: its platform is cpu, not tpu ({args.preset} is "
-              f"not built there; `--preset tiny`, `tiny-bd` and `tiny-mla` "
-              f"are the CPU dry runs)",
+              f"not built there; `--preset tiny`, `tiny-bd`, `tiny-mla` and "
+              f"`tiny-swa` are the CPU dry runs)",
               file=sys.stderr)
         return 1
 

@@ -34,6 +34,7 @@ import numpy as np
 
 from symmetry_tpu.models.llama import (
     LATENT_COUNTS,
+    WINDOW_COUNTS,
     KVCache,
     ModelConfig,
     absorb_latent,
@@ -298,6 +299,28 @@ class InferenceEngine:
                 prefill_chunk=prefill_chunk, kv_quant=kv_quant)
             if refused:
                 raise EngineError(refused[0])
+        # Window AND full attention layers keep a ring a window layer
+        # beside a full row a full layer (models/llama.py KVCache.kw): the
+        # same rule.
+        self._window = getattr(config, "window_kind", None)
+        if self._window is not None:
+            from symmetry_tpu.models.hybrid import window_refusals
+
+            refused = window_refusals(
+                mesh=mesh is not None, role=role,
+                prefix_cache=prefix_cache_bytes > 0,
+                speculative=speculative is not None,
+                prefill_chunk=prefill_chunk)
+            if refused:
+                raise EngineError(refused[0])
+        # since start (stats.engine.swa), as of the last synced decode
+        # block: decode forwards, the rows a full layer and a window layer
+        # read in them, the writes that came back to row 0 of a full ring
+        # (counted on the device, WINDOW_COUNTS); prompt tokens prefilled,
+        # as dispatched
+        self.swa = (None if self._window is None else
+                    {**{name: 0 for name in WINDOW_COUNTS},
+                     "prefill_tokens": 0})
         # since start (stats.engine.mla): decode forwards and the live rows
         # they read as of the last synced decode block (counted on the
         # device), prompt tokens prefilled through the expanded form as
@@ -475,7 +498,11 @@ class InferenceEngine:
             return DecodeState(
                 cache=init_cache(c, max_slots, max_seq_len, cache_dtype,
                                  quantized=kv_quant,
-                                 count_experts=self._count_experts),
+                                 count_experts=self._count_experts,
+                                 # (a window layer's ring: the window's
+                                 # rows, whatever the capacity)
+                                 **({} if self._window is None
+                                    else {"ring": c.sliding_window})),
                 last_token=jnp.zeros((max_slots,), jnp.int32),
                 temperature=jnp.zeros((max_slots,), jnp.float32),
                 top_p=jnp.ones((max_slots,), jnp.float32),
@@ -662,7 +689,43 @@ class InferenceEngine:
                 return jax.lax.dynamic_update_slice(
                     big, small.astype(big.dtype), start)
 
+            def place_ring(big, small_batch, axis=2):
+                # a window layer's ring [Lw,B,W,...] <- the prompt's LAST
+                # W rows of small_batch[:, row] [Lw,1,Sb,...], position p
+                # at row p mod W (`axis`: where positions lie; 3 in the
+                # scale planes). A bucket no longer than the ring lies as
+                # it is, from row 0; of a longer one the W rows that end
+                # at the prompt's length are taken, and rolling them by
+                # their first position mod W puts each at its row — two
+                # contiguous pieces, the ring's tail and its head.
+                W, Sb = big.shape[axis], small_batch.shape[axis]
+                if Sb <= W:
+                    return place(big, small_batch)
+                first = jnp.maximum(true_len[row] - W, 0)
+                sizes = tuple(1 if d == 1 else W if d == axis else n
+                              for d, n in enumerate(small_batch.shape))
+                src = tuple(row if d == 1 else first if d == axis else 0
+                            for d in range(small_batch.ndim))
+                rows = jnp.roll(
+                    jax.lax.dynamic_slice(small_batch, src, sizes),
+                    first % W, axis=axis)
+                start = tuple(slot if d == 1 else 0
+                              for d in range(big.ndim))
+                return jax.lax.dynamic_update_slice(
+                    big, rows.astype(big.dtype), start)
+
+            ring = {}
+            if state.cache.kw is not None:
+                ring = {"kw": place_ring(state.cache.kw, prefix.kw),
+                        "vw": place_ring(state.cache.vw, prefix.vw)}
+                if self.kv_quant:
+                    ring.update(
+                        kw_scale=place_ring(state.cache.kw_scale,
+                                            prefix.kw_scale, axis=3),
+                        vw_scale=place_ring(state.cache.vw_scale,
+                                            prefix.vw_scale, axis=3))
             cache = state.cache._replace(
+                **ring,
                 k=place(state.cache.k, prefix.k),
                 # (a latent cache has no `v` leaf: `k` holds the one row)
                 **({"v": place(state.cache.v, prefix.v)}
@@ -1309,6 +1372,8 @@ class InferenceEngine:
             self.ssm_counters["prefill_tokens"] += int(lens[:n_req].sum())
         if self.mla is not None:
             self.mla["prefill_tokens"] += int(lens[:n_req].sum())
+        if self.swa is not None:
+            self.swa["prefill_tokens"] += int(lens[:n_req].sum())
         lens_arr = jnp.asarray(lens)
         temps_arr = jnp.asarray(temps)
         top_ps_arr = jnp.asarray(top_ps)
@@ -1529,11 +1594,23 @@ class InferenceEngine:
     # Disaggregated prefill/decode (engine side; wire format and broker
     # in engine/disagg/)
 
-    def kv_bytes_per_token(self) -> int:
+    def kv_bytes_per_token(self, kind: str | None = None) -> int:
         """Bytes of KV cache one token position occupies (k + v payloads
         plus scale planes when int8-quantized) — sizes handoff frames
-        and the decode tier's adoption-budget floor."""
+        and the decode tier's adoption-budget floor. `kind` (a model with
+        window and full attention layers alone): "full" or "window", the
+        bytes a position takes in that kind's leaves — a full layer's for
+        as long as the slot lives, a window layer's for the window's span;
+        None: both."""
         c = self.config
+        if getattr(c, "window_kind", None) is not None:
+            kinds = {"full": (c.attention_kind,),
+                     "window": (c.window_kind,)}.get(kind, c.attention_kinds)
+            per_plane = c.num_kv_heads * sum(len(c.layers_of(name))
+                                             for name in kinds)
+            return 2 * per_plane * (
+                c.dim_per_head + 4 if self.kv_quant else
+                c.dim_per_head * jnp.dtype(self.cache_dtype).itemsize)
         latent = getattr(c, "latent", None)
         if latent is not None:
             # one row a layer and position, as it lies on the chip: in
@@ -1636,6 +1713,11 @@ class InferenceEngine:
         of any next admission)."""
         if not 0 <= slot < self.max_slots:
             raise EngineError(f"extract_slot_kv: slot {slot} out of range")
+        if self._window is not None:
+            raise EngineError(
+                "extract_slot_kv: the handoff row carries one K and one V "
+                "plane at one capacity and has no place for a window "
+                "layer's ring")
         row = self._extract_prefix_row(self.state.cache, jnp.int32(slot),
                                        jnp.int32(p))
         cap = self.bucket_for(max(int(p), 1))
@@ -2379,6 +2461,10 @@ class InferenceEngine:
                 for name, n in zip(LATENT_COUNTS,
                                    block[-len(LATENT_COUNTS):]):
                     self.mla[name] += int(n)
+            if self.swa is not None:
+                for name, n in zip(WINDOW_COUNTS,
+                                   block[-len(WINDOW_COUNTS):]):
+                    self.swa[name] += int(n)
 
     def moe_report(self) -> dict | None:
         """`startup.moe`: where the expert weights live and which form
@@ -2449,6 +2535,10 @@ class InferenceEngine:
             self._moe_report["dense_layers"] = c.num_dense_layers
             self._moe_report["expert_layers"] = (c.num_layers
                                                  - c.num_dense_layers)
+        if getattr(c, "router_input", "ffn_input") != "ffn_input":
+            # smallthinker: what the router reads, and the gated activation
+            self._moe_report["router_input"] = c.router_input
+            self._moe_report["activation"] = c.hidden_act
         if c.shared_intermediate_size:
             self._moe_report["shared_expert"] = {
                 "width": c.shared_intermediate_size,
@@ -2515,8 +2605,28 @@ class InferenceEngine:
 
     def cache_report(self) -> dict | None:
         """`startup.cache`: what a cached position is, for a model whose
-        entry is not K and V a head (latent attention); None for any
-        other."""
+        entry is not K and V a head at one capacity (latent attention; window
+        and full attention layers); None for any other."""
+        if self._window is not None:
+            c = self.config
+            full, window = (self.kv_bytes_per_token(kind)
+                            for kind in ("full", "window"))
+            ring = int(self.state.cache.kw.shape[2])
+            return {
+                "kind": "window+full",
+                "dtype": str(self.state.cache.k.dtype),
+                "full": {"layers": len(c.layers_of(c.attention_kind)),
+                         "rows": self.max_seq_len,
+                         "bytes_per_token": full},
+                "window": {"layers": len(c.layers_of(c.window_kind)),
+                           "rows": ring, "span": c.sliding_window,
+                           "bytes_per_token": window},
+                "bytes_per_slot": full * self.max_seq_len + window * ring,
+                "cache_bytes": self.max_slots * (full * self.max_seq_len
+                                                 + window * ring),
+                # what ONE capacity for every attention layer would hold
+                "uniform_cache_bytes": (self.max_slots * self.max_seq_len
+                                        * (full + window))}
         la = self._latent
         if la is None:
             return None

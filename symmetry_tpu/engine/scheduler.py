@@ -676,6 +676,11 @@ class Scheduler:
             # latent attention: decode forwards, the live rows they read
             # and the tokens prefilled expanded, as of the same block
             out["mla"] = dict(self.engine.mla)
+        if getattr(self.engine, "swa", None) is not None:
+            # window + full attention: decode forwards, the rows each kind
+            # read in them, ring wraps, tokens prefilled, as of the same
+            # block
+            out["swa"] = dict(self.engine.swa)
         if getattr(self.engine, "dsa", None) is not None:
             # learned sparse attention: queries, the positions they could
             # select from and those they selected, as of the same block

@@ -64,8 +64,9 @@ def convert_hf_state_dict(
             return hybrid.convert_hf_state_dict(tensors, config)
         except (KeyError, ValueError) as exc:
             family = {"linear_attention": "qwen3_next", "conv": "lfm2_moe",
-                      None: "deepseek_v3"}.get(config.recurrent_kind,
-                                               "granitemoehybrid")
+                      None: ("deepseek_v3" if config.latent is not None
+                             else "smallthinker")}.get(
+                config.recurrent_kind, "granitemoehybrid")
             raise CheckpointError(f"{family} checkpoint: {exc}")
     n_exp = getattr(config, "num_experts", 0)
     router_name, experts_module, expert_map = hf_moe_names(config)

@@ -63,6 +63,22 @@ lanes], no `v`, no `ssm`, no `conv` — and the FFN kinds are lfm2_moe's (a
 leading dense layer, then experts by the sigmoid router) with granite's
 ungated shared expert beside the routed sum.
 
+A smallthinker model (`config.window_kind`) is the same trunk with no
+recurrent kind and TWO ATTENTION kinds: "full_attention" layers (stack
+`layers.attn`; the cache's `k` / `v`, every position at the full capacity)
+and "sliding_attention" layers (stack `layers.swa`; the cache's `kw` / `vw`,
+a RING of exactly the window's rows a slot, position p at row p mod window:
+models/llama.py `write_kv(ring_valid=)`, `ring_view`), a rotary choice a
+layer (`rope_layout`: the published layout ropes the window layers and gives
+the full ones no positional embedding), and a router that reads the
+residual stream as it ENTERS the layer (`router_input` "layer_input":
+`moe_mlp(route_from=)`) over ReGLU experts. A run breaks where the kind or
+the rotary choice changes. A prefill from empty (`prefill_flash`) writes a
+window layer's rows PLAIN, position p at row p of a scratch whose window
+leaves are as long as the bucket (the engine's insert rolls the last
+`window` rows into the ring); every other call treats the window leaf as a
+ring of its capacity.
+
 One device only: there are no sharding rules for the state yet.
 """
 
@@ -80,7 +96,8 @@ from symmetry_tpu.ops.quant import qmatmul
 
 KIND_STACK = {"mamba": "mamba", "attention": "attn",
               "linear_attention": "gdn", "full_attention": "attn",
-              "conv": "sconv", "latent_attention": "attn"}
+              "conv": "sconv", "latent_attention": "attn",
+              "sliding_attention": "swa"}
 RECURRENT = {"mamba": mamba2, "linear_attention": gdn, "conv": sconv}
 
 
@@ -117,8 +134,11 @@ def state_bytes_per_slot(config, dtype=jnp.bfloat16) -> dict:
 
 
 def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
-               quantized: bool = False, count_experts: bool = False
-               ) -> llama.KVCache:
+               quantized: bool = False, count_experts: bool = False,
+               ring: int | None = None) -> llama.KVCache:
+    """`ring` (a window / full model alone): the rows of the window layers'
+    leaves — the window for a served cache, None for a prefill scratch,
+    whose window leaves are `capacity` long like its full ones."""
     n_attn = len(config.layers_of(config.attention_kind))
     ssm, conv = state_shapes(config, batch)
     shape = (n_attn, batch, capacity, *llama.kv_row(config))
@@ -134,16 +154,32 @@ def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
                                      + len(llama.LATENT_COUNTS),), jnp.int32)
                           if count_experts else None))
     scale_shape = (n_attn, batch, config.num_kv_heads, capacity)
+    kv_dtype = jnp.int8 if quantized else dtype
+    window, counts = {}, 0
+    if config.window_kind is not None:
+        # the window layers' leaves beside the full layers': a ring of the
+        # window's rows — or, for a prefill scratch (`ring` None), as many
+        # rows as the full leaves have, written plain
+        n_win = len(config.layers_of(config.window_kind))
+        rows = capacity if ring is None else ring
+        wshape = (n_win, batch, rows, *llama.kv_row(config))
+        wscale = (n_win, batch, config.num_kv_heads, rows)
+        window = dict(
+            kw=jnp.zeros(wshape, kv_dtype), vw=jnp.zeros(wshape, kv_dtype),
+            kw_scale=jnp.zeros(wscale, jnp.float32) if quantized else None,
+            vw_scale=jnp.zeros(wscale, jnp.float32) if quantized else None)
+        counts = len(llama.WINDOW_COUNTS)  # the expert counter's tail
     return llama.KVCache(
-        k=jnp.zeros(shape, jnp.int8 if quantized else dtype),
-        v=jnp.zeros(shape, jnp.int8 if quantized else dtype),
+        k=jnp.zeros(shape, kv_dtype),
+        v=jnp.zeros(shape, kv_dtype),
         lengths=jnp.zeros((batch,), jnp.int32),
         k_scale=jnp.zeros(scale_shape, jnp.float32) if quantized else None,
         v_scale=jnp.zeros(scale_shape, jnp.float32) if quantized else None,
-        expert_pairs=(jnp.zeros((config.num_experts,), jnp.int32)
+        expert_pairs=(jnp.zeros((config.num_experts + counts,), jnp.int32)
                       if count_experts else None),
         ssm=jnp.zeros(ssm, jnp.float32) if ssm else None,
-        conv=jnp.zeros(conv, dtype),
+        conv=jnp.zeros(conv, dtype) if conv else None,
+        **window,
     )
 
 
@@ -172,6 +208,8 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
     if c.latent is not None:
         return llama.absorb_latent(_init_latent(c, keys, dense, dtype), c,
                                    dtype)
+    if c.recurrent_kind is None:
+        return _init_window(c, keys, dense, dtype)
     if c.recurrent_kind == "linear_attention":
         return _init_qwen3_next(c, keys, dense, dtype)
     if c.recurrent_kind == "conv":
@@ -384,6 +422,81 @@ def _init_latent(c, keys, dense, dtype) -> dict:
     return params
 
 
+def _init_window(c, keys, dense, dtype) -> dict:
+    """`init_params` for a smallthinker config: an attention stack a kind
+    (`attn` the full layers, `swa` the window layers; the same leaves), one
+    expert FFN a layer, an untied head."""
+    E, F, X, L = c.hidden_size, c.intermediate_size, c.num_experts, \
+        c.num_layers
+
+    def attention(n):
+        return {"norm": jnp.ones((n, E), dtype),
+                "wq": dense((n, E, c.q_dim), "wq"),
+                "wk": dense((n, E, c.kv_dim), "wk"),
+                "wv": dense((n, E, c.kv_dim), "wv"),
+                "wo": dense((n, c.q_dim, E), "wo")}
+
+    params = {
+        "embed": dense((c.vocab_size, E), scale=0.02),
+        "layers": {
+            **{KIND_STACK[kind]: attention(len(c.layers_of(kind)))
+               for kind in c.attention_kinds},
+            "ffn": {
+                "norm": jnp.ones((L, E), dtype),
+                "router": dense((L, E, X)),
+                "wg": dense((L, X, E, F), "wg"),
+                "wu": dense((L, X, E, F), "wu"),
+                "wd": dense((L, X, F, E), "wd"),
+            },
+        },
+        "final_norm": jnp.ones((E,), dtype),
+    }
+    if not c.tie_embeddings:
+        params["lm_head"] = dense((E, c.vocab_size), "lm_head", scale=0.02)
+    return params
+
+
+def window_refusals(*, mesh: bool = False, role: str = "unified",
+                    prefix_cache: bool = False, speculative: bool = False,
+                    prefill_chunk: int | None = None) -> list[str]:
+    """Why a model with window AND full attention layers (a ring of the
+    window's rows a window layer beside a full row a full layer) cannot be
+    served under these settings: one sentence a setting, empty when it can
+    (the engine and provider/config.py ask, as of `state_refusals`)."""
+    why = []
+    if prefix_cache:
+        why.append(
+            "tpu.prefix_cache_mb: the block pool knows one entry shape at "
+            "one capacity, and a window layer's ring holds a slot's last "
+            "positions alone, so a stored prefix would come back without "
+            "the rows its window layers need — leave it unset for a model "
+            "with window and full attention layers")
+    if speculative:
+        why.append(
+            "tpu.speculative: a ring row that a rejected draft overwrote "
+            "held a key still inside the window, and rolling the lengths "
+            "back does not bring it back — leave it unset for a model with "
+            "window and full attention layers")
+    if prefill_chunk is not None:
+        why.append(
+            f"tpu.prefill_chunk {prefill_chunk}: a chunk's later positions "
+            f"overwrite ring rows its earlier queries still need (a ring of "
+            f"the window's rows has no room for a chunk beside it) — set "
+            f"prefill_chunk: null for a model with window and full "
+            f"attention layers (prompts prefill whole, up to the largest "
+            f"bucket)")
+    if role != "unified":
+        why.append(
+            f"tpu.role {role!r}: the KV handoff frame carries one K and one "
+            f"V plane at one capacity and has no place for the rings — a "
+            f"model with window and full attention layers serves unified")
+    if mesh:
+        why.append(
+            "tpu.mesh: the ring leaves have no sharding rules yet — a model "
+            "with window and full attention layers runs on one device")
+    return why
+
+
 def state_refusals(*, mesh: bool = False, role: str = "unified",
                    prefix_cache: bool = False, speculative: bool = False,
                    prefill_chunk: int | None = None) -> list[str]:
@@ -424,12 +537,16 @@ def state_refusals(*, mesh: bool = False, role: str = "unified",
 def runs(config) -> list[tuple[str, int, int]]:
     """The pattern as runs of one mixer kind AND one FFN kind: (mixer kind,
     first layer, length). A run breaks where either changes (lfm2_moe's
-    leading dense layers end at `num_dense_layers`), so one scan body holds
-    one kind of each; the run's FFN kind is `config.ffn_kind(first)`."""
+    leading dense layers end at `num_dense_layers`) — or the rotary choice
+    (`rope_layout`; it changes with the kind in smallthinker's published
+    layout: 26 runs at its full depth, 6 at three periods) — so one scan
+    body holds one kind of each; the run's FFN kind is
+    `config.ffn_kind(first)`, its rotary choice `config.layer_rope(first)`."""
     out: list[tuple[str, int, int]] = []
     for i, kind in enumerate(config.layer_types):
         if (out and out[-1][0] == kind
-                and config.ffn_kind(out[-1][1]) == config.ffn_kind(i)):
+                and config.ffn_kind(out[-1][1]) == config.ffn_kind(i)
+                and config.layer_rope(out[-1][1]) == config.layer_rope(i)):
             out[-1] = (kind, out[-1][1], out[-1][2] + 1)
         else:
             out.append((kind, i, 1))
@@ -462,7 +579,7 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
                  + jnp.arange(S, dtype=jnp.int32)[None, :])
     kv_valid = cache.lengths + seq_lens
     layers = params["layers"]
-    for kind in filter(None, (c.recurrent_kind, c.attention_kind)):
+    for kind in filter(None, (c.recurrent_kind, *c.attention_kinds)):
         n = jax.tree.leaves(layers[KIND_STACK[kind]])[0].shape[0]
         if n != len(c.layers_of(kind)):
             raise ValueError(f"params carry {n} {kind} layers but "
@@ -477,10 +594,19 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
     def norm(h, w):
         return rms_norm(h, llama._norm_w(w, c), c.rms_eps)
 
-    def mixer(kind, x, lp, cache, j):
+    def mixer(kind, x, lp, cache, j, rope):
+        if kind == c.window_kind:
+            # the ring leaves in the places of k / v; plain rows in a
+            # prefill's scratch, whose insert makes the ring
+            out, view = llama._attention(
+                x, lp, llama.ring_view(cache), j, positions, kv_valid,
+                seq_lens, c, prefill_flash and S > 1, rope=rope,
+                window=c.sliding_window, ring=not prefill_flash)
+            return out, llama.ring_restore(cache, view)
         if kind == c.attention_kind:
             return llama._attention(x, lp, cache, j, positions, kv_valid,
-                                    seq_lens, c, prefill_flash and S > 1)
+                                    seq_lens, c, prefill_flash and S > 1,
+                                    rope=rope)
         conv = _at(cache.conv, j)
         if S == 1:  # the stack as it lies: layer j is the step's address
             out, ssm, conv = recurrent.step_at(x[:, 0], lp, cache.ssm, j,
@@ -508,9 +634,10 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
 
         def body(carry, step, kind=kind, first=first, j0=j0):
             h, cache = carry
+            entered = h  # what a "layer_input" router reads
             lp = _at(layers[KIND_STACK[kind]], j0 + step)
             out, cache = mixer(kind, norm(h, lp["norm"]), lp, cache,
-                               j0 + step)
+                               j0 + step, c.layer_rope(first))
             h = h + r * out
             if first < n_dense:  # a run is of one FFN kind (`runs`)
                 lp = _at(layers["dense"], first + step)
@@ -518,8 +645,11 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
                         cache), None
             # the layer's index among the expert layers
             lp = _at(layers["ffn"], first - n_dense + step)
-            y, pairs = moe_mlp(norm(h, lp["norm"]), lp, c, seq_lens,
-                               stack=(layers["ffn"], first - n_dense + step))
+            y, pairs = moe_mlp(
+                norm(h, lp["norm"]), lp, c, seq_lens,
+                stack=(layers["ffn"], first - n_dense + step),
+                **({"route_from": entered}
+                   if c.router_input == "layer_input" else {}))
             h = h + r * y
             return (h, llama.add_expert_pairs(cache, pairs)), None
 
@@ -529,6 +659,10 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
     if c.latent is not None and S == 1 and cache.expert_pairs is not None:
         cache = cache._replace(expert_pairs=llama.count_latent(
             cache.expert_pairs, cache.lengths, kv_valid))
+    if (c.window_kind is not None and S == 1
+            and cache.expert_pairs is not None):
+        cache = cache._replace(expert_pairs=llama.count_window(
+            cache.expert_pairs, cache.lengths, kv_valid, cache.kw.shape[2]))
     return h, cache._replace(lengths=kv_valid)
 
 
@@ -571,6 +705,8 @@ def hf_config(config) -> dict:
     c = config
     if c.latent is not None:
         return llama.hf_config_latent(c)
+    if c.recurrent_kind is None:
+        return llama.hf_config_window(c)
     if c.recurrent_kind == "conv":
         return {
             "architectures": ["Lfm2MoeForCausalLM"],
@@ -670,6 +806,8 @@ def convert_hf_state_dict(tensors: dict, config) -> dict:
 
     if config.latent is not None:
         return _deepseek_from_hf(tensors, config)
+    if config.recurrent_kind is None:
+        return _smallthinker_from_hf(tensors, config)
     if config.recurrent_kind == "linear_attention":
         return _qwen3_next_from_hf(tensors, config)
     if config.recurrent_kind == "conv":
@@ -703,6 +841,8 @@ def to_hf_state_dict(params: dict, config) -> dict:
 
     if config.latent is not None:
         return _deepseek_to_hf(params, config)
+    if config.recurrent_kind is None:
+        return _smallthinker_to_hf(params, config)
     if config.recurrent_kind == "linear_attention":
         return _qwen3_next_to_hf(params, config)
     if config.recurrent_kind == "conv":
@@ -1097,4 +1237,84 @@ def _deepseek_to_hf(params: dict, config) -> dict:
                 for e in range(config.num_experts):
                     out[f"{prefix}mlp.experts.{e}.{hf}.weight"] = arr(
                         lay["ffn"][name][at][e])
+    return out
+
+
+# ---------------------------------------------------------------------------
+# HF `smallthinker` checkpoint names (PowerInfer's modeling_smallthinker.py;
+# the checkpoint is not in the sandbox). A layer of either attention kind has
+# the same names — `self_attn.{q,k,v,o}_proj`, `input_layernorm`,
+# `post_attention_layernorm` — and its kind decides the stack (`attn` /
+# `swa`); the expert block is `block_sparse_moe.primary_router` (it reads the
+# layer's input) and `block_sparse_moe.experts.{e}.{gate,up,down}`.
+
+SMALLTHINKER_ATTN = {"input_layernorm.weight": "norm",
+                     "self_attn.q_proj.weight": "wq",
+                     "self_attn.k_proj.weight": "wk",
+                     "self_attn.v_proj.weight": "wv",
+                     "self_attn.o_proj.weight": "wo"}
+SMALLTHINKER_FFN = {"post_attention_layernorm.weight": "norm",
+                    "block_sparse_moe.primary_router.weight": "router"}
+SMALLTHINKER_EXPERT = {"gate": "wg", "up": "wu", "down": "wd"}
+
+
+def _smallthinker_from_hf(tensors: dict, config) -> dict:
+    import numpy as np
+
+    def ours_of(a):
+        return np.swapaxes(a, -1, -2) if a.ndim >= 2 else a
+
+    known = set(QWEN_TOP)
+    stacks: dict = {"attn": {}, "swa": {}, "ffn": {}}
+    for i, kind in enumerate(config.layer_types):
+        prefix = f"model.layers.{i}."
+        for hf, name in SMALLTHINKER_ATTN.items():
+            known.add(prefix + hf)
+            stacks[KIND_STACK[kind]].setdefault(name, []).append(
+                ours_of(tensors[prefix + hf]))
+        for hf, name in SMALLTHINKER_FFN.items():
+            known.add(prefix + hf)
+            stacks["ffn"].setdefault(name, []).append(
+                ours_of(tensors[prefix + hf]))
+        for hf, name in SMALLTHINKER_EXPERT.items():
+            names = [f"{prefix}block_sparse_moe.experts.{e}.{hf}.weight"
+                     for e in range(config.num_experts)]
+            known.update(names)
+            stacks["ffn"].setdefault(name, []).append(
+                np.stack([tensors[n].T for n in names]))
+    unmapped = sorted(set(tensors) - known)
+    if unmapped:
+        raise ValueError(f"unmapped HF tensors: {unmapped[:4]}")
+    out = {"embed": tensors["model.embed_tokens.weight"],
+           "final_norm": tensors["model.norm.weight"],
+           "layers": {stack: {k: np.stack(v) for k, v in leaves.items()}
+                      for stack, leaves in stacks.items() if leaves}}
+    if not config.tie_embeddings:
+        out["lm_head"] = tensors["lm_head.weight"].T
+    return out
+
+
+def _smallthinker_to_hf(params: dict, config) -> dict:
+    import numpy as np
+
+    def arr(a):
+        a = np.asarray(a, np.float32)
+        return np.swapaxes(a, -1, -2) if a.ndim >= 2 else a
+
+    lay = params["layers"]
+    out = {"model.embed_tokens.weight": np.asarray(params["embed"],
+                                                   np.float32),
+           "model.norm.weight": arr(params["final_norm"])}
+    if not config.tie_embeddings:
+        out["lm_head.weight"] = arr(params["lm_head"])
+    for i, kind in enumerate(config.layer_types):
+        prefix, j = f"model.layers.{i}.", stack_index(config, i)
+        for hf, name in SMALLTHINKER_ATTN.items():
+            out[prefix + hf] = arr(lay[KIND_STACK[kind]][name][j])
+        for hf, name in SMALLTHINKER_FFN.items():
+            out[prefix + hf] = arr(lay["ffn"][name][i])
+        for hf, name in SMALLTHINKER_EXPERT.items():
+            for e in range(config.num_experts):
+                out[f"{prefix}block_sparse_moe.experts.{e}.{hf}.weight"] = \
+                    arr(lay["ffn"][name][i][e])
     return out

@@ -48,12 +48,21 @@ class ModelConfig:
     rope_theta: float = 500000.0
     rms_eps: float = 1e-5
     tie_embeddings: bool = False
-    sliding_window: int | None = None    # mistral-v0.1 style local attention
+    # local attention's span: a key s is visible to a query t iff
+    # t - sliding_window < s <= t. For a model WITHOUT `layer_types` it is
+    # model-wide (mistral-v0.1 style): every layer masks by it over the one
+    # cache of full capacity (flash's block bounds, the decode kernel's
+    # `window=`, gqa_attention's mask). For a HybridConfig whose
+    # `layer_types` name "sliding_attention" layers it is THAT KIND's span
+    # alone: those layers cache their K/V in a ring of exactly this many
+    # rows (`KVCache.kw` / `vw`), the "full_attention" layers beside them
+    # see every position
+    sliding_window: int | None = None
     attention_bias: bool = False         # qwen2-style QKV projection biases
     max_position: int = 8192
     # gemma family: gelu-tanh GeGLU, RMSNorm scale stored as (weight - 1),
     # and embeddings multiplied by sqrt(hidden) at lookup
-    hidden_act: str = "silu"             # "silu" | "gelu_tanh"
+    hidden_act: str = "silu"             # "silu" | "gelu_tanh" | "relu"
     norm_plus_one: bool = False
     scale_embed: bool = False
 
@@ -234,6 +243,19 @@ class HybridConfig(MoEConfig):
     # sizes; no recurrent kind, so no state a slot), the first
     # `num_dense_layers` (`first_k_dense_replace`) dense, the rest experts
     # by the sigmoid router plus an ungated shared expert
+    # smallthinker family, both fields at "absent" for any other model:
+    # every layer is attention, of TWO KINDS — "sliding_attention" (a window
+    # of `sliding_window` positions, cached in a ring of that many rows:
+    # `KVCache.kw` / `vw`) or "full_attention" (every position, cached in
+    # `k` / `v`) — so there is no recurrent kind and no state a slot;
+    # `rope_layout[i]` says whether layer i rotates q and k (1) or has no
+    # positional embedding (0) — a rotary choice a LAYER, the published
+    # layout's a kind (None: `rope` for every layer) — and `router_input`
+    # "layer_input" feeds the router the residual stream as it ENTERS the
+    # layer, before the input norm and attention ("ffn_input": the normed
+    # tensor the experts read, every other model)
+    rope_layout: tuple[int, ...] | None = None
+    router_input: str = "ffn_input"   # "ffn_input" | "layer_input"
 
     def __post_init__(self):
         kinds = set(self.layer_types)
@@ -241,12 +263,27 @@ class HybridConfig(MoEConfig):
                 or not (kinds <= {"mamba", "attention"}
                         or kinds <= {"linear_attention", "full_attention"}
                         or kinds <= {"conv", "full_attention"}
+                        or kinds <= {"sliding_attention", "full_attention"}
                         or kinds == {"latent_attention"})):
             raise ValueError(
                 f"layer_types must name {self.num_layers} layers, each "
                 f"'mamba' or 'attention', or each 'linear_attention' or "
                 f"'full_attention', or each 'conv' or 'full_attention', or "
+                f"each 'sliding_attention' or 'full_attention', or "
                 f"each 'latent_attention'; got {self.layer_types!r}")
+        if ("sliding_attention" in kinds) != (
+                self.sliding_window is not None):
+            raise ValueError(
+                f"'sliding_attention' layers and sliding_window (their "
+                f"span) go together; got sliding_window "
+                f"{self.sliding_window} with {self.layer_types!r}")
+        if self.rope_layout is not None and (
+                len(self.rope_layout) != self.num_layers):
+            raise ValueError(f"rope_layout must have {self.num_layers} "
+                             f"entries; got {self.rope_layout!r}")
+        if self.router_input not in ("ffn_input", "layer_input"):
+            raise ValueError(f"router_input must be 'ffn_input' or "
+                             f"'layer_input'; got {self.router_input!r}")
         if ("latent_attention" in kinds) != (self.latent is not None):
             raise ValueError("'latent_attention' layers and the `latent` "
                              "sizes go together")
@@ -267,8 +304,11 @@ class HybridConfig(MoEConfig):
     @property
     def recurrent_kind(self) -> str | None:
         """The name `layer_types` gives this model's recurrent layers; None
-        for a model of latent-attention layers alone, which has none."""
-        if self.latent is not None:
+        for a model of attention layers alone — latent attention, or the
+        window / full pair (which always carries a `rope_layout`) — which
+        has none."""
+        if (self.latent is not None or self.rope_layout is not None
+                or "sliding_attention" in self.layer_types):
             return None
         if self.conv_L_cache:
             return "conv"
@@ -277,10 +317,29 @@ class HybridConfig(MoEConfig):
 
     @property
     def attention_kind(self) -> str:
+        """The kind whose layers ride the cache's `k` / `v` leaves at the
+        full capacity."""
         if self.latent is not None:
             return "latent_attention"
         return "attention" if self.recurrent_kind == "mamba" \
             else "full_attention"
+
+    @property
+    def window_kind(self) -> str | None:
+        """The SECOND attention kind of a window / full model, whose layers
+        ride the ring leaves `kw` / `vw`; None for every other model."""
+        return ("sliding_attention" if "sliding_attention" in self.layer_types
+                else None)
+
+    @property
+    def attention_kinds(self) -> tuple[str, ...]:
+        return tuple(filter(None, (self.attention_kind, self.window_kind)))
+
+    def layer_rope(self, i: int) -> bool:
+        """Whether layer i rotates q and k: its `rope_layout` entry, or the
+        model-wide `rope`."""
+        return bool(self.rope if self.rope_layout is None
+                    else self.rope_layout[i])
 
     def ffn_kind(self, i: int) -> str:
         """What layer i ends in: "dense" (the leading SwiGLU layers) or
@@ -537,6 +596,39 @@ PRESETS: dict[str, ModelConfig] = {
         router_score="sigmoid", router_bias=True,
         routed_scaling_factor=2.448, router_norm_eps=1e-20,
     ),
+    # the CPU's copy of SmallThinker's mechanisms: two periods of (full,
+    # window, window, window), a window of 8 — shorter than the test
+    # prompts, so a ring wraps in a few steps — rope on the window layers
+    # alone, the router on the layer's input, 8 ReGLU experts top 2, an
+    # untied head
+    "tiny-swa": HybridConfig(
+        vocab_size=512, hidden_size=64, num_layers=8, num_heads=4,
+        num_kv_heads=2, intermediate_size=32, head_dim=16,
+        rope_theta=10000.0, rms_eps=1e-6, max_position=512,
+        sliding_window=8, hidden_act="relu",
+        num_experts=8, num_experts_per_tok=2,
+        layer_types=("full_attention",) + ("sliding_attention",) * 3
+        + ("full_attention",) + ("sliding_attention",) * 3,
+        rope_layout=(0, 1, 1, 1) * 2, router_input="layer_input",
+    ),
+    # SmallThinker-21BA3B-Instruct CUT IN DEPTH to 12 of its 52 layers —
+    # three whole periods of (full NoPE, window, window, window): 3 full and
+    # 9 window layers, the published 1 : 3 — at every published width, with
+    # all 64 experts and the whole vocabulary: one of the three 12-layer
+    # stages of a (12, 12, 12, 16) pipeline over one four-chip host, what
+    # one 16 GB chip holds in int8 beside a 64-slot cache of 11,776-row full
+    # leaves and 4,096-row rings (benchmarks/configs/smallthinker-21b-a3b
+    # .json has the cut). `intermediate_size` is the expert's width
+    # (`moe_ffn_hidden_size`); there is no dense FFN and no shared expert.
+    "smallthinker-21b-a3b": HybridConfig(
+        vocab_size=151936, hidden_size=2560, num_layers=12, num_heads=28,
+        num_kv_heads=4, intermediate_size=768, head_dim=128,
+        rope_theta=1500000.0, rms_eps=1e-6, max_position=16384,
+        sliding_window=4096, hidden_act="relu",
+        num_experts=64, num_experts_per_tok=6,
+        layer_types=(("full_attention",) + ("sliding_attention",) * 3) * 3,
+        rope_layout=(0, 1, 1, 1) * 3, router_input="layer_input",
+    ),
     "gemma-7b": ModelConfig(
         vocab_size=256000, hidden_size=3072, num_layers=28, num_heads=16,
         num_kv_heads=16, intermediate_size=24576, head_dim=256,
@@ -644,22 +736,59 @@ class KVCache(NamedTuple):
     # ride that vector instead of a leaf that would twin each site; the
     # engine alone splits them off (`collect_expert_pairs`).
     idx: jnp.ndarray | None = None
+    # Models with window AND full attention layers only (a HybridConfig
+    # with "sliding_attention" layers; None, so no leaf, for every other):
+    # the window layers' K/V, kw / vw [n_window, B, ring, K, D] with their
+    # scale planes [n_window, B, K, ring] where int8 — the served cache's
+    # `ring` is exactly the window, and position p lies at row p mod ring
+    # (`write_kv(ring_valid=)`), so a ring holds a slot's last `ring`
+    # positions and no other; a prefill scratch's is the bucket and holds
+    # position p at row p (the engine's insert rolls the last `ring` rows
+    # into place). `k` / `v` then hold the FULL attention layers alone, at
+    # the full capacity. Such a model's `expert_pairs` ends in
+    # WINDOW_COUNTS.
+    kw: jnp.ndarray | None = None
+    vw: jnp.ndarray | None = None
+    kw_scale: jnp.ndarray | None = None
+    vw_scale: jnp.ndarray | None = None
 
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
 
 
+def ring_view(cache: KVCache) -> KVCache:
+    """The cache as a window layer's `_attention` sees it: the ring leaves
+    in the places of `k` / `v` and their scales (a rebinding of the pytree,
+    no copy)."""
+    return cache._replace(k=cache.kw, v=cache.vw, k_scale=cache.kw_scale,
+                          v_scale=cache.vw_scale, kw=None, vw=None,
+                          kw_scale=None, vw_scale=None)
+
+
+def ring_restore(cache: KVCache, view: KVCache) -> KVCache:
+    """`ring_view`'s inverse: `view`'s leaves back into the ring's places
+    of `cache` (whose full leaves a window layer never touches), with what
+    else the layer updated."""
+    return view._replace(k=cache.k, v=cache.v, k_scale=cache.k_scale,
+                         v_scale=cache.v_scale, kw=view.k, vw=view.v,
+                         kw_scale=view.k_scale, vw_scale=view.v_scale)
+
+
 def init_cache(
     config: ModelConfig, batch: int, capacity: int, dtype=jnp.bfloat16,
     *, quantized: bool = False, count_experts: bool = False,
+    ring: int | None = None,
 ) -> KVCache:
+    """`ring`: the rows of a window / full model's window leaves (models/
+    hybrid.py init_cache; no other model takes it)."""
     if getattr(config, "layer_types", None):
         from symmetry_tpu.models import hybrid
 
         return hybrid.init_cache(config, batch, capacity, dtype,
                                  quantized=quantized,
-                                 count_experts=count_experts)
+                                 count_experts=count_experts,
+                                 **({} if ring is None else {"ring": ring}))
     shape = (config.num_layers, batch, capacity, *kv_row(config))
     pairs = (jnp.zeros((config.num_experts,), jnp.int32)
              if count_experts else None)
@@ -693,7 +822,8 @@ def init_cache(
 
 def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
              k: jnp.ndarray, v: jnp.ndarray, *, by_head: bool,
-             idx: jnp.ndarray | None = None) -> KVCache:
+             idx: jnp.ndarray | None = None,
+             ring_valid: jnp.ndarray | None = None) -> KVCache:
     """Scatter this call's K/V ([B, S, K, D], roped) straight into the full
     cache at (layer, slot, position) — an in-place row write on the layer
     scan's carry; a per-layer slice-out/slice-in would stream the whole
@@ -705,6 +835,12 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     their scales stay one a head.
     `idx` ([B, S, index_head_dim]; sparse attention) goes into `cache.idx`
     at the same rows.
+    `ring_valid` ([B]: each slot's length AFTER this call; a window layer's
+    leaves, `ring_view`) makes the leaf a RING of its capacity M: position p
+    is written at row p mod M, and only a slot's last M positions under its
+    length are written at all — an older one would land on a newer one's
+    row and a padded tail's garbage on a live row, so both are dropped (a
+    scatter's order among equal rows is not defined).
 
     `by_head` (the trunk is sharded over a mesh): a cache sharded by KV
     head may hold 2 heads a chip, and XLA then keeps it in HBM as
@@ -716,6 +852,11 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     """
     B, S = k.shape[:2]
     nkv = cache.k.shape[3]
+    if ring_valid is not None:
+        M = cache.k.shape[2]
+        live = ((positions >= ring_valid[:, None] - M)
+                & (positions < ring_valid[:, None]))
+        positions = jnp.where(live, positions % M, M)  # M: dropped
 
     def rows(x):
         return x.reshape(B, S, *cache.k.shape[3:])
@@ -761,6 +902,33 @@ def count_latent(vector: jnp.ndarray, lengths: jnp.ndarray,
     new = jnp.stack([jnp.int32(1), jnp.sum(
         jnp.where(lengths > 0, kv_valid, 0), dtype=jnp.int32)])
     n = len(LATENT_COUNTS)
+    return jnp.concatenate([vector[:-n], vector[-n:] + new])
+
+
+# What a window / full model's `expert_pairs` ends in, as a latent model's
+# ends in LATENT_COUNTS and by the same rule (handed out and zeroed a decode
+# block, so no int32 wraps): forwards of one position a slot over the cache;
+# the rows a FULL layer reads in them (each live slot's length with the
+# current position) and the rows a WINDOW layer reads (the same, capped at
+# the ring), once a forward — not a layer; and the steps whose write came
+# back to row 0 of a full ring.
+WINDOW_COUNTS = ("decode_steps", "full_rows", "ring_rows", "ring_wraps")
+
+
+def count_window(vector: jnp.ndarray, lengths: jnp.ndarray,
+                 kv_valid: jnp.ndarray, ring: int) -> jnp.ndarray:
+    """`vector` (an `expert_pairs` ending in WINDOW_COUNTS) plus one decode
+    forward's counts. A parked lane (length 0) is no live row."""
+    live = lengths > 0
+
+    def total(x):
+        return jnp.sum(jnp.where(live, x, 0), dtype=jnp.int32)
+
+    new = jnp.stack([
+        jnp.int32(1), total(kv_valid), total(jnp.minimum(kv_valid, ring)),
+        total(((kv_valid > ring) & ((kv_valid - 1) % ring == 0))
+              .astype(jnp.int32))])
+    n = len(WINDOW_COUNTS)
     return jnp.concatenate([vector[:-n], vector[-n:] + new])
 
 
@@ -935,7 +1103,8 @@ def cache_logical_axes(*, quantized: bool = False,
 
 
 def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
-                    batch: int, kv_bytes: int) -> dict:
+                    batch: int, kv_bytes: int, kind: str | None = None
+                    ) -> dict:
     """Which attention implementation the prefill-from-empty and the
     single-position decode programs take at this cache shape (`batch`
     slots of `capacity` entries of `kv_bytes`): "pallas" (ops/flash.py,
@@ -973,13 +1142,31 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
         that; a cache it has none for (the admission program's scratch of
         bucket + block positions is no multiple of 128, a tiny head is no
         lane tile) and a sliding window (a mask a position) keep the XLA
-        path, the served cache's with its `decode_why`."""
+        path, the served cache's with its `decode_why`;
+      - a MODEL-WIDE window (`sliding_window` on a config without
+        `layer_types`): one cache of full capacity, the kernels masked by
+        the window (flash skips the blocks wholly older than it, the
+        decode kernel takes `window=`);
+      - a window A KIND (a HybridConfig with "sliding_attention" layers):
+        the answer is a kind's — `kind` "full" (the `k` / `v` leaves at
+        `capacity`, no window) or "window" (the ring leaves, `capacity`
+        their rows) — and, asked without one, both side by side
+        (`_window_paths`: `startup.attention`). A window layer's decode is
+        the kernel over its ring with `kv_length = min(length, ring)` and
+        NO `window=`: keys are roped when written and a softmax does not
+        care in which row a key lies, so a ring of exactly the window's
+        rows needs no start offset; a leaf of any other size (a uniform
+        cache in a test) keeps the XLA path, which masks by each row's
+        own position."""
     from symmetry_tpu.ops import decode_attention as da
 
     kernel = "pallas-interpret" if interpret_mode() else "pallas"
     latent = getattr(config, "latent", None)
     if latent is not None:
         return _latent_paths(latent, capacity, kernel, kv_bytes)
+    if getattr(config, "window_kind", None) is not None and kind is None:
+        return _window_paths(config, capacity, tp_mesh, batch=batch,
+                             kv_bytes=kv_bytes)
     model = 1 if tp_mesh is None else dict(tp_mesh.shape).get("model", 1)
     if config.num_kv_heads % model:
         return {"prefill": "xla", "decode": "xla"}
@@ -1017,9 +1204,53 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
             config.num_kv_heads, kv_bytes, kv_bytes == 1,
             config.dim_per_head):
         return {"prefill": kernel, "decode": "xla"}
+    if kind == "window" and capacity != config.sliding_window:
+        return {"prefill": kernel, "decode": "xla", "decode_why": (
+            f"a window layer's leaf of {capacity} rows is no ring of the "
+            f"window's {config.sliding_window}: its keys need a start "
+            f"offset that ops/decode_attention.py's items lack, so the "
+            f"step masks by each row's own position in gqa_attention")}
     return {"prefill": kernel, "decode": kernel,
             **({"decode_queries": queries} if queries > 1 else {}),
             "decode_slot_tile": tiles[0], "decode_block_t": tiles[1]}
+
+
+def _window_paths(config: "HybridConfig", capacity: int, tp_mesh, *,
+                  batch: int, kv_bytes: int) -> dict:
+    """`attention_paths` of a window / full model asked without a kind: the
+    route of each kind's leaf beside the other's (`startup.attention`). The
+    served ring is exactly the window."""
+    from symmetry_tpu.ops import flash
+
+    c, ring = config, config.sliding_window
+
+    def of(kind, rows, name):
+        paths = attention_paths(c, rows, tp_mesh, batch=batch,
+                                kv_bytes=kv_bytes, kind=kind)
+        layers = c.layers_of(name)
+        roped = {c.layer_rope(i) for i in layers}
+        prefill = paths.pop("prefill")
+        return prefill, {
+            "layers": len(layers),
+            "rope": roped.pop() if len(roped) == 1 else "mixed", **paths}
+
+    prefill, full = of("full", capacity, c.attention_kind)
+    _, window = of("window", ring, c.window_kind)
+    routes = {full["decode"], window["decode"]}
+    out = {"kind": "window+full", "prefill": prefill,
+           "decode": routes.pop() if len(routes) == 1 else "mixed",
+           "full": {**full, "capacity": capacity},
+           "window": {**window, "span": ring, "ring": ring}}
+    if prefill != "xla":
+        out["prefill_form"] = (
+            f"{flash.WIDE_NAME}: tiles of {flash.WIDE_TILE}, {c.num_heads} "
+            f"query heads over {c.num_kv_heads} KV heads; a window layer "
+            f"walks the tiles its window leaves"
+            if flash.wide_takes(ring) else
+            f"{flash.WIDE_NAME} on the full layers; flash_prefill in tiles "
+            f"of 128 on the window layers (a window of {ring} is no "
+            f"multiple of {flash.WIDE_TILE})")
+    return out
 
 
 def _latent_paths(latent: LatentAttention, capacity: int, kernel: str,
@@ -1210,9 +1441,30 @@ def _attention(
     sp_mode: str = "ring",      # static: "ring" | "ulysses" (SURVEY §5.7)
     tp_mesh=None,               # static: Mesh the trunk is GSPMD-sharded over
     rope_positions=None,        # [3, B, S] multimodal rotary components
+    rope: bool | None = None,   # static: this LAYER's rotary choice (None:
+                                # the config's)
+    window: int | None = None,  # static: this layer's window, of a model
+                                # with a window a KIND (any other model's
+                                # is `config.sliding_window`, model-wide)
+    ring: bool = False,         # static: `cache` is a window layer's
+                                # `ring_view` and its leaf a ring
 ) -> tuple[jnp.ndarray, KVCache]:
     """The attention mixer: projections, the cache write, attention by the
-    routed path, the output projection -> ([B, S, E], cache)."""
+    routed path, the output projection -> ([B, S, E], cache).
+
+    A window layer over a RING (`ring`) of M rows: the write goes to
+    position mod M, a decode step attends over rows [0, min(length, M)) —
+    by the kernel where M is the window, each row's own position masked in
+    gqa_attention otherwise. Queries of S > 1 over a ring are right where
+    no key a query needs was overwritten by this call's own later
+    positions: the slot's length stays within M, or M >= window + S - 1
+    (the engine refuses the programs that could break it: chunks,
+    speculative verify)."""
+    windowed = getattr(config, "window_kind", None) is not None
+    if not windowed:
+        window = config.sliding_window
+    if rope is None:
+        rope = getattr(config, "rope", True)
     if getattr(config, "latent", None) is not None:
         if ring_mesh is not None or tp_mesh is not None:
             raise ValueError("a model with latent attention runs on one "
@@ -1240,7 +1492,7 @@ def _attention(
     if getattr(config, "qk_norm", False):  # per head, over its D channels
         q = rms_norm(q, _norm_w(lp["q_norm"], config), config.rms_eps)
         k = rms_norm(k, _norm_w(lp["k_norm"], config), config.rms_eps)
-    if getattr(config, "rope", True) and rope_positions is not None:
+    if rope and rope_positions is not None:
         # a multimodal rotary's three components (equal ones, the served
         # text path, are `positions`: the branch below)
         section = config.mrope_section
@@ -1248,7 +1500,7 @@ def _attention(
                        mrope_section=section)
         k = apply_rope(k, rope_positions, config.rope_theta,
                        mrope_section=section)
-    elif getattr(config, "rope", True):
+    elif rope:
         rot = int(D * getattr(config, "partial_rotary_factor", 1.0))
         q = apply_rope(q, positions, config.rope_theta, rot)
         k = apply_rope(k, positions, config.rope_theta, rot)
@@ -1262,7 +1514,10 @@ def _attention(
     diffusion = getattr(config, "diffusion", None)
     # (a keyword only where there is a block: no other model's call moves)
     blocked = {} if diffusion is None else {"block_len": diffusion.block}
-    if sparse is None:
+    if ring:
+        cache = write_kv(cache, layer, positions, k, v, by_head=False,
+                         ring_valid=kv_valid)
+    elif sparse is None:
         cache = write_kv(cache, layer, positions, k, v,
                          by_head=tp_mesh is not None)
     else:
@@ -1299,9 +1554,10 @@ def _attention(
 
             attn = ring_attention(q, k, v, seq_lens, ring_mesh)
     else:
-        paths = attention_paths(config, cache.k.shape[2], tp_mesh,
-                                batch=cache.k.shape[1],
-                                kv_bytes=cache.k.dtype.itemsize)
+        paths = attention_paths(
+            config, cache.k.shape[2], tp_mesh, batch=cache.k.shape[1],
+            kv_bytes=cache.k.dtype.itemsize,
+            **({"kind": "window" if window else "full"} if windowed else {}))
 
         def at_layer(arr):
             return jax.lax.dynamic_index_in_dim(arr, layer, 0,
@@ -1334,12 +1590,19 @@ def _attention(
             # kernel's block range to the window.
             from symmetry_tpu.ops import flash
 
-            kw = dict(window=config.sliding_window,
+            kw = dict(window=window,
                       interpret=interpret_mode(), **blocked)
             if keep is not None:
                 # (the keep-set carries causality and the prompt's length)
                 attn = sa.flash_sparse(q, k, v, keep,
                                        interpret=interpret_mode())
+            elif windowed and flash.wide_takes(window):
+                # prompts of 5-8k tokens: the 512-tile walk, its K/V of one
+                # KV head shared by the head's group, a window layer's
+                # tiles bounded by the window (PERF.md, PR 58)
+                attn = flash.flash_prefill_wide(
+                    q, k, v, seq_lens, window=window,
+                    interpret=interpret_mode())
             elif tp_mesh is None:
                 attn = flash.flash_prefill(q, k, v, seq_lens, **kw)
             else:
@@ -1368,11 +1631,17 @@ def _attention(
                 # on that program (tests/test_chip_compile.py compiles it)
                 scales = tuple(at_layer(x)[None] for x in scales)
             args = (q[:, 0] if S == 1 else q, cache.k, cache.v, layer,
-                    kv_valid, *scales)
+                    # a ring holds the window's keys and no other: every
+                    # written row is live, whichever position it holds
+                    jnp.minimum(kv_valid, cache.k.shape[2]) if ring
+                    else kv_valid, *scales)
             if keep is not None:
                 args += (keep[:, 0],)
-            kw = dict(window=config.sliding_window,
-                      interpret=interpret_mode())
+            kw = dict(window=None if ring else window,
+                      interpret=interpret_mode(),
+                      # (named in a trace for this model alone: no other
+                      # model's call moves)
+                      **({"name": da.WINDOW_NAME} if windowed else {}))
             attn = (da.decode_attention(*args, **kw) if tp_mesh is None
                     else da.decode_attention_tp(*args, mesh=tp_mesh, **kw))
             attn = attn[:, None] if S == 1 else attn
@@ -1380,14 +1649,26 @@ def _attention(
             attn = gqa_attention(
                 q, heads_at_layer(cache.k), heads_at_layer(cache.v),
                 positions, kv_valid,
-                sliding_window=config.sliding_window,
+                sliding_window=window,
                 k_scale=at_layer(cache.k_scale) if cache.quantized else None,
                 v_scale=at_layer(cache.v_scale) if cache.quantized else None,
-                keep=keep, **blocked)
+                keep=keep, **blocked,
+                **({"kv_positions": ring_positions(kv_valid,
+                                                   cache.k.shape[2])}
+                   if ring else {}))
     attn = attn.reshape(B, S, nq * D)
     if gate is not None:
         attn = attn * jax.nn.sigmoid(gate)
     return qmatmul(attn, lp["wo"]), cache
+
+
+def ring_positions(kv_valid: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """[B, rows] int32: the position each row of a ring holds for a slot of
+    `kv_valid` written positions — the newest p = r mod rows under the
+    length — or -1 for a row not written yet."""
+    r = jnp.arange(rows, dtype=jnp.int32)[None, :]
+    n = kv_valid[:, None]
+    return jnp.where(r < n, r + rows * ((n - 1 - r) // rows), -1)
 
 
 def absorbed_factors(wkvb, latent: LatentAttention, num_heads: int,
@@ -1548,10 +1829,12 @@ def add_expert_pairs(cache: KVCache, pairs: jnp.ndarray) -> KVCache:
 
 
 def _act(x: jnp.ndarray, config: ModelConfig) -> jnp.ndarray:
-    """Gated-MLP activation: silu (llama/mistral/qwen) or tanh-approx gelu
-    (gemma's GeGLU)."""
+    """Gated-MLP activation: silu (llama/mistral/qwen), tanh-approx gelu
+    (gemma's GeGLU) or relu (smallthinker's ReGLU)."""
     if config.hidden_act == "gelu_tanh":
         return jax.nn.gelu(x, approximate=True)
+    if config.hidden_act == "relu":
+        return jax.nn.relu(x)
     return jax.nn.silu(x)
 
 
@@ -2090,6 +2373,80 @@ def _latent_config_from_hf(hf: dict[str, Any]) -> "HybridConfig":
     )
 
 
+def hf_config_window(config: "HybridConfig") -> dict:
+    """A window / full attention config as its published `config.json` keys
+    (PowerInfer `smallthinker`: what `config_from_hf` reads back, and what
+    the plain reference — `benchmarks/reference/swa_moe_decoder.py` — is
+    given)."""
+    c = config
+    return {
+        "model_type": "smallthinker", "vocab_size": c.vocab_size,
+        "hidden_size": c.hidden_size, "num_hidden_layers": c.num_layers,
+        "num_attention_heads": c.num_heads,
+        "num_key_value_heads": c.num_kv_heads, "head_dim": c.dim_per_head,
+        "moe_ffn_hidden_size": c.intermediate_size,
+        "moe_num_primary_experts": c.num_experts,
+        "moe_num_active_primary_experts": c.num_experts_per_tok,
+        "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
+        "sliding_window_size": c.sliding_window,
+        "sliding_window_layout": [int(t == "sliding_attention")
+                                  for t in c.layer_types],
+        "rope_layout": [int(c.layer_rope(i)) for i in range(c.num_layers)],
+        "rope_theta": c.rope_theta, "rope_scaling": None,
+        "rms_norm_eps": c.rms_eps,
+        "max_position_embeddings": c.max_position,
+        "tie_word_embeddings": c.tie_embeddings,
+    }
+
+
+def _window_config_from_hf(hf: dict[str, Any]) -> "HybridConfig":
+    """`config_from_hf` of PowerInfer `smallthinker`. The two layouts are
+    taken as given — any pattern of window / full layers, a rotary choice a
+    layer that need not follow it; what would change the layer and is not
+    built raises, in a sentence, rather than being ignored."""
+    if hf.get("rope_scaling") is not None:
+        raise ValueError("smallthinker with rope_scaling is not implemented")
+    if not hf.get("moe_primary_router_apply_softmax", True):
+        raise ValueError(
+            "smallthinker with moe_primary_router_apply_softmax false (gates "
+            "by sigmoid of the selected logits) is not implemented")
+    if not hf.get("norm_topk_prob", True):
+        raise ValueError("smallthinker with norm_topk_prob false is not "
+                         "implemented")
+    if hf.get("moe_num_secondary_experts") or hf.get(
+            "moe_enable_secondary_experts"):
+        raise ValueError("smallthinker with secondary experts is not "
+                         "implemented (an expert is one dense ReGLU FFN)")
+    n_layers = hf["num_hidden_layers"]
+    sliding = [int(bool(x)) for x in (hf.get("sliding_window_layout")
+                                      or [0] * n_layers)]
+    rope = [int(bool(x)) for x in (hf.get("rope_layout") or sliding)]
+    if len(sliding) != n_layers or len(rope) != n_layers:
+        raise ValueError(
+            f"smallthinker: sliding_window_layout ({len(sliding)}) and "
+            f"rope_layout ({len(rope)}) must name {n_layers} layers each")
+    return HybridConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_layers=n_layers, num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf.get("num_key_value_heads",
+                            hf["num_attention_heads"]),
+        # the expert's width: there is no dense FFN
+        intermediate_size=hf["moe_ffn_hidden_size"],
+        head_dim=hf.get("head_dim"),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        max_position=hf.get("max_position_embeddings", 16384),
+        sliding_window=(hf["sliding_window_size"] if any(sliding) else None),
+        hidden_act="relu",
+        num_experts=hf["moe_num_primary_experts"],
+        num_experts_per_tok=hf["moe_num_active_primary_experts"],
+        layer_types=tuple("sliding_attention" if w else "full_attention"
+                          for w in sliding),
+        rope_layout=tuple(rope), router_input="layer_input",
+    )
+
+
 def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
     """Build a ModelConfig from an HF config.json dict (llama/mistral/
     qwen2/mixtral shapes; mixtral's num_local_experts selects MoEConfig)."""
@@ -2111,6 +2468,9 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
         sliding = None
     if hf.get("model_type") == "deepseek_v3":
         return _latent_config_from_hf(hf)
+    if (hf.get("model_type") == "smallthinker"
+            or str(hf.get("model_name", "")).startswith("smallthinker")):
+        return _window_config_from_hf(hf)
     if hf.get("model_type") == "qwen3_next":
         if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1:
             raise ValueError("qwen3_next with dense-MLP layers "

@@ -169,8 +169,20 @@ ROUTED_MIN_TOKENS = 1024
 # the tie keeps the mixture, as granite's did, and the crossing is the
 # first size from which routing wins at every larger one — decode keeps the
 # mixture, every prefill of the report cell (6,912 tokens and up) is routed.
+# 64 top 6 at expert width 768 (smallthinker-21b-a3b; `--shape
+# 64,6,2560,768`; PERF.md, PR 58; floor 0.46 for all 64 experts): 16 tokens
+# 2.19 / 0.49 / 0.53, 32: 2.40 / 0.56 / 0.53, 64: 2.60 / 0.59 / 0.54, 128:
+# 3.01 / 0.633 / 0.633, 256: 4.10 / 0.73 / 1.10, 512: 4.35 / 0.92 / 2.25,
+# 1,024: 5.32 / 1.29 / 4.65, 2,048: 7.82 / 2.38 / 8.98, 8,192: 21.16 /
+# 10.96 / 35.79. At 16 tokens 96 pairs hit ~50 of the 64 experts and the
+# kernel, reading those alone, wins by 7%; from 32 every expert is hit and
+# the mixture's 10.7x FLOPs hide behind the weight stream (86% of the
+# floor): it wins by 4% at 32 and by 10% at 64 (decode's 64 slots), ties at
+# 128 (the tie keeps the mixture, as granite's and kanana's did) and loses
+# by 1.5x at 256: the band is [32, 256) — decode keeps the mixture, every
+# prefill of the draft cell (1,024 tokens and up) is routed.
 ROUTED_FROM = {(72, 10): (0, 256), (512, 10): (0, 1), (128, 8): (64, 128),
-               (32, 4): (0, 256), (128, 6): (0, 128)}
+               (32, 4): (0, 256), (128, 6): (0, 128), (64, 6): (32, 256)}
 
 
 def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
@@ -209,14 +221,39 @@ def route_top_k(x: jnp.ndarray, router: jnp.ndarray, k: int, *,
     return jax.nn.softmax(top_vals, axis=-1), top_idx.astype(jnp.int32)
 
 
-def routing_of(config, lp: dict) -> dict:
-    """`route_top_k`'s keywords for this config and layer: empty (the
-    softmax form) for every family but lfm2_moe and deepseek_v3."""
-    if getattr(config, "router_score", "softmax") != "sigmoid":
-        return {}
-    return {"score": "sigmoid", "bias": lp.get("expert_bias"),
-            "scale": config.routed_scaling_factor,
-            "eps": config.router_norm_eps}
+def routing_of(config, lp: dict, route_from=None) -> dict:
+    """How this config and layer route and activate, for both expert forms:
+    `route_top_k`'s keywords (lfm2_moe's and deepseek_v3's sigmoid router),
+    `route_from` ([T, D]: the tensor the router reads where it is not the
+    FFN's input — smallthinker's `router_input` "layer_input") and `act`
+    (the gated activation where it is not silu: smallthinker's relu). Empty
+    — the softmax router on the FFN's input under silu — for every other
+    family."""
+    out = {}
+    if getattr(config, "router_score", "softmax") == "sigmoid":
+        out = {"score": "sigmoid", "bias": lp.get("expert_bias"),
+               "scale": config.routed_scaling_factor,
+               "eps": config.router_norm_eps}
+    if route_from is not None:
+        out["route_from"] = route_from
+    if config.hidden_act != "silu":
+        out["act"] = config.hidden_act
+    return out
+
+
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+        "gelu_tanh": lambda x: jax.nn.gelu(x, approximate=True)}
+
+
+def _route(x, router, k: int, routing) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`route_top_k` under `routing_of`'s answer: the router reads
+    `route_from` where the layer gives one, else the FFN's input `x`."""
+    kw = {name: v for name, v in (routing or {}).items() if name != "act"}
+    return route_top_k(kw.pop("route_from", x), router, k, **kw)
+
+
+def _act(routing):
+    return ACTS[(routing or {}).get("act", "silu")]
 
 
 def grouped_matmul_form(w, n_rows: int, one_device: bool = True) -> dict:
@@ -271,7 +308,7 @@ def _routed_ffn(x, valid, router, wg, wu, wd, k: int, at=None,
     keywords (`routing_of`)."""
     T, _ = x.shape
     X = router.shape[-1]
-    gates, experts = route_top_k(x, router, k, **(routing or {}))  # [T, k]
+    gates, experts = _route(x, router, k, routing)        # [T, k]
     flat_expert = experts.reshape(-1)                     # [T*k]
     # Stable sort: pairs of one expert keep token order, so the result
     # does not depend on how the sort breaks ties.
@@ -287,7 +324,7 @@ def _routed_ffn(x, valid, router, wg, wu, wd, k: int, at=None,
                                at and (at[0][name], at[1]))
 
     rows = jnp.take(x, order // k, axis=0)                # [T*k, D]
-    h = (jax.nn.silu(grouped(rows, wg, "wg")) * grouped(rows, wu, "wu"))
+    h = (_act(routing)(grouped(rows, wg, "wg")) * grouped(rows, wu, "wu"))
     y = grouped(h.astype(x.dtype), wd, "wd")
 
     # Un-sort by a gather (the inverse permutation), then the gated sum
@@ -330,12 +367,12 @@ def _experts_dot(x: jnp.ndarray, w) -> jnp.ndarray:
 def _dense_mixture(x, valid, router, wg, wu, wd, k: int, routing=None):
     """Same contract as `_routed_ffn`; every expert computes every token."""
     X = router.shape[-1]
-    gates, experts = route_top_k(x, router, k, **(routing or {}))  # [T, k]
+    gates, experts = _route(x, router, k, routing)        # [T, k]
     onehot = experts[..., None] == jnp.arange(X, dtype=jnp.int32)
     dense_gates = jnp.sum(jnp.where(onehot, gates[..., None], 0.0), axis=1)
     pairs = jnp.sum(onehot & valid[:, None, None], axis=(0, 1),
                     dtype=jnp.int32)
-    h = jax.nn.silu(_experts_dot(x, wg)) * _experts_dot(x, wu)  # [T, X, F]
+    h = _act(routing)(_experts_dot(x, wg)) * _experts_dot(x, wu)  # [T, X, F]
     if isinstance(wd, QuantizedTensor):
         y = jax.lax.dot_general(h, wd.q, (((2,), (1,)), ((1,), (0,))),
                                 preferred_element_type=jnp.float32)
@@ -395,7 +432,8 @@ def whole_stacks(layers: dict, tp_mesh=None) -> dict | None:
 
 
 def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
-            tp_mesh=None, stack=None) -> tuple[jnp.ndarray, jnp.ndarray]:
+            tp_mesh=None, stack=None, route_from=None
+            ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """MoE FFN: [B, S, D] -> ([B, S, D], valid pairs per expert [X]).
     `seq_lens` [B] says how many of each row's S positions are real.
     `stack` = (the FFN layers' stacked leaves, this layer's index) where the
@@ -403,7 +441,8 @@ def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
     `run_layers` on one device): the routed form's kernel then reads an
     expert where it lies in the stack, and `lp`'s slices of the three
     expert leaves are read by the mixture alone (and by `ragged_dot`, for
-    a stack `grouped_matmul_form` gives no kernel)."""
+    a stack `grouped_matmul_form` gives no kernel). `route_from` [B, S, D]:
+    the tensor the router reads where it is not `x` (`routing_of`)."""
     B, S, D = x.shape
     k = config.num_experts_per_tok
     if seq_lens is None:
@@ -415,9 +454,12 @@ def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
     args = (xf, valid, lp["router"], lp["wg"], lp["wu"], lp["wd"])
 
     n = _model_shards(tp_mesh, config.intermediate_size)
-    routing = routing_of(config, lp)
+    routing = routing_of(config, lp, None if route_from is None
+                         else route_from.reshape(B * S, D))
     if routing and tp_mesh is not None:
-        raise ValueError("the sigmoid router is traced on one device only")
+        raise ValueError("the sigmoid router, a router off the FFN's input "
+                         "and an activation other than silu are traced on "
+                         "one device only")
     if tp_mesh is None:
         # one device: the kernel's operand is a stack — the caller's, or
         # this layer alone as a stack of one (a reshape)

@@ -46,6 +46,9 @@ def gqa_attention(
     keep: jnp.ndarray | None = None,  # [B, S, T] bool: a learned selection
                                       # (ops/sparse_attention.py) to stay in
     block_len: int | None = None,  # block mask: causal ACROSS blocks only
+    kv_positions: jnp.ndarray | None = None,  # [B, T] int32: the position
+                                      # each cache row holds (a ring: -1
+                                      # where none); None: row t holds t
 ) -> jnp.ndarray:
     """Returns [B, S, n_q_heads, head_dim] in q's dtype. Softmax in f32.
 
@@ -60,6 +63,10 @@ def gqa_attention(
     one: a query sees every written key up to the last position of its own
     block. (One block a slot with `kv_length` on the block's end is the same
     mask for all its rows, `pos < kv_length`: the decode kernel's.)
+
+    `kv_positions` (a window layer's ring, models/llama.py ring_positions)
+    masks by the position each row HOLDS instead of its index: causal and
+    windowed against that, and live where it is not -1.
     """
     B, S, n_q, D = q.shape
     T, n_kv = k_cache.shape[1], k_cache.shape[2]
@@ -84,10 +91,18 @@ def gqa_attention(
     kv_pos = jnp.arange(T, dtype=jnp.int32)
     q_last = q_positions if block_len is None else (
         q_positions // block_len * block_len + (block_len - 1))
-    # key valid iff written (pos < kv_length) and causal (pos <= query pos)
-    mask = (kv_pos[None, None, :] <= q_last[..., None]) & (
-        kv_pos[None, None, :] < kv_length[:, None, None]
-    )  # [B, S, T]
+    if kv_positions is not None:
+        held = kv_positions[:, None, :]
+        mask = (held >= 0) & (held <= q_last[..., None])  # [B, S, T]
+        if sliding_window is not None:
+            mask &= held > q_positions[..., None] - sliding_window
+        sliding_window = None
+    else:
+        # key valid iff written (pos < kv_length) and causal (pos <= query
+        # pos)
+        mask = (kv_pos[None, None, :] <= q_last[..., None]) & (
+            kv_pos[None, None, :] < kv_length[:, None, None]
+        )  # [B, S, T]
     if sliding_window is not None:
         mask &= kv_pos[None, None, :] > q_positions[..., None] - sliding_window
     if keep is not None:

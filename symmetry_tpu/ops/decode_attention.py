@@ -107,6 +107,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0**30
+WINDOW_NAME = "swa_decode"  # the kernel's calls of a window / full model
 LANES = 128          # scale planes are position-minor: blocks are lane tiles
 SUBLANES = 8
 BLOCK_ROWS = 1024    # (position, head) rows of one item: 128 KB of int8
@@ -429,7 +430,7 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                jnp.zeros((nq, D), jnp.float32)) for _ in range(ways)))
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "interpret", "name"))
 def decode_attention(
     q: jnp.ndarray,           # [B, n_q_heads, D] (single decode position),
                               # or [B, S, n_q_heads, D]: S that share keys
@@ -445,11 +446,18 @@ def decode_attention(
     window: int | None = None,  # sliding-window span (mistral); bounds the
                                 # per-slot block range below AND above
     interpret: bool = False,
+    name: str | None = None,  # the call's name in a device trace (a model
+                              # with window and full layers: WINDOW_NAME)
 ) -> jnp.ndarray:
     """Returns q's shape in q's dtype. Every one of a slot's S positions
     attends to the slot's keys below `kv_length`, its own block's among
     them (the caller wrote them): there is no mask a position, so no
-    `window` and no `keep` with S > 1."""
+    `window` and no `keep` with S > 1.
+
+    A window layer's RING (models/llama.py KVCache.kw: position p at row p
+    mod T, T the window) is this kernel with `kv_length = min(length, T)`
+    and no `window`: the keys were roped when written and a softmax does
+    not care in which row a key lies, so items start at row 0 as ever."""
     L, B, T, K, D = k_cache.shape     # K x D: a cache row as it lies
     queries = 1 if q.ndim == 3 else q.shape[1]
     nq, head_dim = q.shape[-2:]
@@ -554,6 +562,7 @@ def decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((lanes, nqp, D), q.dtype),
         interpret=interpret,
+        **({} if name is None else {"name": name}),
     )(*args)
     out = out[:, :nql]
     if fold > 1:  # a head's result is in its own half of the row

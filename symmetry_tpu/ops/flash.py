@@ -180,29 +180,41 @@ WIDE_NAME = "flash_wide"
 WIDE_TILE = 512
 
 
+def wide_takes(window: int | None) -> bool:
+    """Whether `flash_prefill_wide` walks a layer of this window: one whose
+    edge is a tile's (a multiple of WIDE_TILE), or none."""
+    return window is None or window % WIDE_TILE == 0
+
+
 def _flash_wide_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *,
-                       scale: float, block: int):
+                       scale: float, block: int, window_tiles: int = 0):
     """One tile of `block` queries of one head against the key tiles at or
     under its diagonal. Beside `_flash_kernel`: the operands go to the MXU
     as they are stored (bfloat16) and the scores scale in float32; only the
     diagonal tile is masked (a real query sees no key past itself, so none
     past the prompt's length either); a query tile that lies wholly in the
-    bucket's padding — the last tiles, the longest walks — writes zeros."""
+    bucket's padding — the last tiles, the longest walks — writes zeros.
+
+    `window_tiles` w > 0 is a sliding window of w whole tiles (key s
+    visible to query t iff t - w * block < s <= t): the walk starts at tile
+    qi - w, and that tile alone is cut by the window's far edge — row r
+    sees its columns past r, the diagonal's complement — the tiles between
+    it and the diagonal are whole."""
     qi = pl.program_id(2)
     seq_len = seqlen_ref[pl.program_id(0)]
     Dv = v_ref.shape[-1]
 
-    def tile(j, carry, diagonal=False):
+    def tile(j, carry, diagonal=False, edge=False):
         m, l, acc = carry
         k_blk = k_ref[pl.ds(j * block, block), :]
         v_blk = v_ref[pl.ds(j * block, block), :]
         s = jax.lax.dot_general(
             q_ref[:], k_blk, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        if diagonal:
+        if diagonal or edge:
             row = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
             col = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-            s = jnp.where(col <= row, s, NEG_INF)
+            s = jnp.where(col <= row if diagonal else col > row, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         correction = jnp.exp(m - m_new)
@@ -218,7 +230,20 @@ def _flash_wide_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *,
         carry = (jnp.full((block, 1), NEG_INF, jnp.float32),
                  jnp.zeros((block, 1), jnp.float32),
                  jnp.zeros((block, Dv), jnp.float32))
-        carry = jax.lax.fori_loop(0, qi, tile, carry)
+        if window_tiles:
+            # (the edge tile's last row sees none of it: its running
+            # maximum stays NEG_INF, its weights are exp(0) and its sum is
+            # not 0 — until the next tile's correction exp(NEG_INF - m)
+            # wipes them; the diagonal, where every row sees its own key,
+            # always follows)
+            lo = qi - window_tiles
+            carry = jax.lax.cond(
+                lo >= 0, lambda c: tile(lo, c, edge=True), lambda c: c,
+                carry)
+            carry = jax.lax.fori_loop(jnp.maximum(lo + 1, 0), qi, tile,
+                                      carry)
+        else:
+            carry = jax.lax.fori_loop(0, qi, tile, carry)
         _, l, acc = tile(qi, carry, diagonal=True)
         o_ref[:] = (acc / l).astype(o_ref.dtype)  # l >= 1: the diagonal
 
@@ -227,24 +252,38 @@ def _flash_wide_kernel(seqlen_ref, q_ref, k_ref, v_ref, o_ref, *,
         o_ref[:] = jnp.zeros_like(o_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "window"))
 def flash_prefill_wide(
     q: jnp.ndarray,         # [B, S, H, D]
-    k: jnp.ndarray,         # [B, S, H, D]
-    v: jnp.ndarray,         # [B, S, H, Dv]
+    k: jnp.ndarray,         # [B, S, K, D]: K == H, or H a multiple of it
+    v: jnp.ndarray,         # [B, S, K, Dv]
     seq_lens: jnp.ndarray,  # [B] int32 valid prompt lengths
     *,
     block: int = WIDE_TILE,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
-    """`flash_prefill` (causal, from an empty cache, one key head a query
-    head, no window) in tiles of `block` x `block`; S is padded up to a
-    multiple of `block` here (a bucket is a multiple of 128) and the rows
-    past a prompt's length come back as garbage or zeros, by the same
-    contract. Returns [B, S, H, Dv]."""
+    """`flash_prefill` (causal, from an empty cache) in tiles of `block` x
+    `block`; S is padded up to a multiple of `block` here (a bucket is a
+    multiple of 128) and the rows past a prompt's length come back as
+    garbage or zeros, by the same contract. Returns [B, S, H, Dv].
+
+    One key head a query head (latent attention expanded: the form PR 54
+    built, whose program this leaves as it was), or grouped-query heads: a
+    KV head's K and V stay in VMEM while its group's query heads walk them
+    (the grid's head axis is the query's; consecutive heads of a group map
+    to one block, which is fetched once). `window`: a sliding window of
+    whole tiles (`wide_takes`); one no shorter than the padded prompt masks
+    nothing and is dropped."""
     B, S, H, D = q.shape
-    Dv = v.shape[3]
+    K, Dv = k.shape[2], v.shape[3]
+    group = H // K
     block = min(block, S)
+    if window is not None and window >= S + (-S % block):
+        window = None
+    if window is not None and window % block:
+        raise ValueError(f"window {window} is no multiple of the tile "
+                         f"{block}: flash_prefill takes it")
     pad = -S % block
     qt, kt, vt = (jnp.pad(a.transpose(0, 2, 1, 3),
                           ((0, 0), (0, 0), (0, pad), (0, 0)))
@@ -255,18 +294,20 @@ def flash_prefill_wide(
     # tile's float32 scores and probabilities
     vmem = (4 * Sp * (lanes(D) + lanes(Dv)) + 6 * 4 * block * block
             + (8 << 20))
+    kv_head = ((lambda b, h, qi, sl: (b, h, 0, 0)) if group == 1 else
+               (lambda b, h, qi, sl: (b, h // group, 0, 0)))
     out = pl.pallas_call(
-        functools.partial(_flash_wide_kernel, scale=D ** -0.5, block=block),
+        functools.partial(
+            _flash_wide_kernel, scale=D ** -0.5, block=block,
+            **({} if window is None else {"window_tiles": window // block})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,  # seq_lens
             grid=(B, H, Sp // block),
             in_specs=[
                 pl.BlockSpec((None, None, block, D),
                              lambda b, h, qi, sl: (b, h, qi, 0)),
-                pl.BlockSpec((None, None, Sp, D),
-                             lambda b, h, qi, sl: (b, h, 0, 0)),
-                pl.BlockSpec((None, None, Sp, Dv),
-                             lambda b, h, qi, sl: (b, h, 0, 0)),
+                pl.BlockSpec((None, None, Sp, D), kv_head),
+                pl.BlockSpec((None, None, Sp, Dv), kv_head),
             ],
             out_specs=pl.BlockSpec((None, None, block, Dv),
                                    lambda b, h, qi, sl: (b, h, qi, 0)),

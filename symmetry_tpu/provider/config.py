@@ -350,7 +350,9 @@ class ConfigManager:
         A preset with learned sparse attention keeps an index key a cached
         position: models/llama.py sparse_refusals, the same settings the
         same way; one with latent attention a single row a position:
-        latent_refusals, which also refuses an int8 cache."""
+        latent_refusals, which also refuses an int8 cache; one with window
+        and full attention layers a ring beside a full row: models/
+        hybrid.py window_refusals."""
         import math
 
         from symmetry_tpu.models.llama import PRESETS
@@ -408,6 +410,19 @@ class ConfigManager:
             return  # no recurrent kind: a row a position is all it keeps
         if not getattr(preset, "layer_types", None):
             return
+        if preset.window_kind is not None:
+            from symmetry_tpu.models.hybrid import window_refusals
+
+            refused = window_refusals(
+                mesh=mesh,
+                role=tpu.role or "unified",
+                prefix_cache=bool(tpu.prefix_cache_mb),
+                speculative=bool(tpu.speculative),
+                prefill_chunk=tpu.prefill_chunk)
+            if refused:
+                raise ConfigError(f"model_preset {tpu.model_preset!r}: "
+                                  + "; ".join(refused))
+            return  # no recurrent kind: rows a position are all it keeps
         from symmetry_tpu.models.hybrid import state_refusals
 
         refused = state_refusals(

@@ -946,3 +946,81 @@ def test_kanana_prefill_expands_through_flash_and_routes_its_experts(
               if re.search(r"= s8\[(1,)?128,(2048,768|768,2048)\]\S* "
                            r"(copy|dynamic-slice|fusion)\(", line)]
     assert not sliced, sliced[0]
+
+
+def _smallthinker_text(one_chip, monkeypatch, rows, capacity, S):
+    """smallthinker-21b-a3b's trunk (all 12 layers: six runs of one
+    attention kind) compiled for a described v5e at [rows, S] tokens over
+    int8 leaves — a served cache's (full leaves of `capacity` rows, rings
+    of the window's 4,096) for a decode step, a prefill scratch's (both
+    `capacity` long) for S > 1: (config, optimised HLO)."""
+    from symmetry_tpu.models import llama, moe
+
+    for module in (llama, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    cfg = llama.preset("smallthinker-21b-a3b")
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    prefill = S > 1
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+        slice_above=1 << 40))
+    cache = shaped(lambda: llama.init_cache(
+        cfg, rows, capacity, jnp.bfloat16, quantized=True,
+        count_experts=True, ring=None if prefill else cfg.sliding_window))
+    tok = jax.ShapeDtypeStruct((rows, S), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(
+            (lambda p, t, c, n: llama.forward_hidden(
+                p, cfg, t, c, n, prefill_flash=True)) if prefill else
+            (lambda p, t, c, n: llama.forward_hidden(p, cfg, t, c)),
+            donate_argnums=(2,)).lower(params, tok, cache, lens).compile(
+        ).as_text()
+    return cfg, cache, text
+
+
+def test_smallthinker_decode_step_reads_both_leaves_where_they_lie(
+        one_chip, no_cache, monkeypatch):
+    """The decode step at the cell (64 slots: full leaves of 11,776 rows,
+    rings of 4,096): attention is one `swa_decode` call in each of the six
+    scans, over the full leaves in three and over the rings in three, and
+    neither leaf is copied or sliced a layer at a time."""
+    from symmetry_tpu.ops import decode_attention as da
+
+    B, T = 64, 11776
+    cfg, cache, text = _smallthinker_text(one_chip, monkeypatch, B, T, 1)
+    assert cache.k.shape == (3, B, T, 4, 128)
+    assert cache.kw.shape == (9, B, 4096, 4, 128)
+    assert len(re.findall(rf"%{da.WINDOW_NAME}[.\d]* = ", text)) == 6
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= s8\[(\d+,)?{B},({T}|4096),4,128\]\S* "
+                          rf"(copy|transpose|dynamic-slice|fusion)\(", line)
+             and "dynamic-update-slice" not in line
+             and "scatter" not in line]
+    assert not moved, moved[0]
+
+
+def test_smallthinker_prefill_walks_wide_tiles_and_routes_its_experts(
+        one_chip, no_cache, monkeypatch):
+    """An 8,320-token prefill row over its scratch: attention is the wide
+    flash walk in every one of the six scans (28 query heads over 4 KV
+    heads; a window layer's call bounded by its window), the experts are
+    routed — three `moe_gmm` calls a scan whose weight operands are the
+    layers' int8 stacks as they lie."""
+    from symmetry_tpu.ops import flash, gmm
+
+    cfg, cache, text = _smallthinker_text(one_chip, monkeypatch, 1, 8320,
+                                          8320)
+    assert cache.kw.shape == (9, 1, 8320, 4, 128)
+    assert len(re.findall(rf"%{flash.WIDE_NAME}[.\d]* = ", text)) == 6
+    assert len(re.findall(rf"%{gmm.NAME}[.\d]* = ", text)) == 18
+    sliced = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= s8\[(1,)?64,(2560,768|768,2560)\]\S* "
+                           r"(copy|dynamic-slice|fusion)\(", line)]
+    assert not sliced, sliced[0]

@@ -39,6 +39,7 @@ def test_there_is_a_cut_configuration_to_hold():
     assert "keye-vl-2.0-30b-a3b.json" in cut_files()
     assert "sdar-30b-a3b-chat.json" in cut_files()
     assert "kanana-2-30b-a3b.json" in cut_files()
+    assert "smallthinker-21b-a3b.json" in cut_files()
 
 
 @pytest.mark.parametrize("name", cut_files())
@@ -95,6 +96,18 @@ def test_a_cut_keeps_a_whole_period_of_the_published_pattern(name):
         assert c["first_k_dense_replace"] == 1 and c["moe_layer_freq"] == 1
         assert c["num_hidden_layers"] - c["first_k_dense_replace"] >= 4
         return
+    if str(c.get("model_name", "")).startswith("smallthinker"):
+        # the pattern is the two layouts' (full, window, window, window):
+        # three whole periods of the published thirteen, both layouts cut
+        # alike and in the published order
+        assert c["num_hidden_layers"] == 12
+        for key in ("sliding_window_layout", "rope_layout"):
+            full, kept = c["published"][key], c[key]
+            assert len(kept) == 12 and len(full) == 52
+            assert full[:12] == kept == [0, 1, 1, 1] * 3
+            assert full == [0, 1, 1, 1] * 13
+        assert c["published"]["num_hidden_layers"] == 52
+        return
     if "layer_types" not in c["reduced"]:
         pytest.skip("no layer pattern was cut")
     full, kept = c["published"]["layer_types"], c["layer_types"]
@@ -116,7 +129,8 @@ def test_a_cut_file_is_the_programs_preset(name):
     p = preset(c["tpu"]["model_preset"])
     # a qwen3_next file's `intermediate_size` is the dense width no layer
     # uses; the preset's is the routed expert's
-    width = c.get("moe_intermediate_size", c["intermediate_size"])
+    width = c.get("moe_intermediate_size", c.get(
+        "moe_ffn_hidden_size", c.get("intermediate_size")))
     assert (p.vocab_size, p.hidden_size, p.num_layers, p.num_heads,
             p.num_kv_heads, p.intermediate_size, p.dim_per_head) == (
         c["vocab_size"], c["hidden_size"], c["num_hidden_layers"],
@@ -202,6 +216,53 @@ def test_a_cut_file_is_the_programs_preset(name):
                      "640", "byte tokenizer", "6144", "fan_in"):
             assert word in text, word
         assert "stage 1 of 4" in c["deployment"]
+    if str(c.get("model_name", "")).startswith("smallthinker"):
+        from symmetry_tpu.models.llama import config_from_hf
+
+        # every published key the program reads, through its own reader: the
+        # file IS the preset, and no width was cut
+        assert config_from_hf(c) == p
+        assert c["reduced"] == ["num_hidden_layers", "sliding_window_layout",
+                                "rope_layout"]
+        assert (p.num_experts, p.num_experts_per_tok) == (
+            c["moe_num_primary_experts"],
+            c["moe_num_active_primary_experts"]) == (64, 6)
+        assert p.sliding_window == c["sliding_window_size"] == 4096
+        assert [int(t == "sliding_attention") for t in p.layer_types] == \
+            c["sliding_window_layout"]
+        assert list(p.rope_layout) == c["rope_layout"]
+        assert (p.hidden_act, p.router_input, p.shared_intermediate_size
+                ) == ("relu", "layer_input", 0)
+        assert p.recurrent_kind is None and p.attention_kinds == (
+            "full_attention", "sliding_attention")
+        assert p.vocab_size == 151936 and c["rope_scaling"] is None
+        assert p.max_position == c["max_position_embeddings"] == 16384
+        tpu = c["tpu"]
+        assert (tpu["max_batch_size"], tpu["max_seq_len"],
+                tpu["decode_block"]) == (64, 11776, 16)
+        assert tpu["prefill_chunk"] is None
+        assert (tpu["quantization"], tpu["kv_quantization"]) == (
+            "int8", "int8")
+        # every bucket a multiple of the flash kernel's least tile, the
+        # cell's longest prompt (8,192 + 19 of template) inside the largest,
+        # room in a slot for the longest answer and the lookahead, and a
+        # capacity the decode kernel's 256-position block divides
+        assert all(b % 128 == 0 for b in tpu["prefill_buckets"])
+        assert max(tpu["prefill_buckets"]) >= 8192 + c["template_tokens"]
+        assert tpu["max_seq_len"] >= 8192 + 19 + 3072 + 2 * 16
+        assert tpu["max_seq_len"] % 256 == 0
+        assert (c["decode_program"], c["prefill_program"]) == (
+            "decode_block", "prefill")
+        assert c["reference"].endswith("swa_moe_decoder.py")
+        assert os.path.exists(os.path.join(CHECKOUT, c["reference"]))
+        # every `assumed` item the issue lists is stated
+        text = " ".join(c["assumed"])
+        for word in ("before input_layernorm", "kv_pos > q_pos", "ReGLU",
+                     "primary keys only", "11776", "16384",
+                     "byte tokenizer", "primary_router", "fan_in"):
+            assert word in text, word
+        assert "(12, 12, 12, 16" in c["deployment"]
+        assert "9.550 GB" in c["deployment"]
     if c.get("model_type") == "deepseek_v3":
         from symmetry_tpu.models.llama import LatentAttention, config_from_hf
 

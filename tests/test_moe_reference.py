@@ -254,7 +254,8 @@ def test_a_band_answers_as_its_threshold_did(shape):
     """Every entry is `(lo, hi)`: the mixture iff lo <= tokens < hi. At
     every count the old threshold decided — under a lower edge only (128, 8)
     has one — the answer is the one it gave."""
-    assert set(moe.ROUTED_FROM) == set(THRESHOLD_WAS) - {None}
+    # (an entry newer than the bands had no threshold to answer as)
+    assert set(moe.ROUTED_FROM) == set(THRESHOLD_WAS) - {None} | {(64, 6)}
     lo, hi = moe.ROUTED_FROM.get(shape, (0, moe.ROUTED_MIN_TOKENS))
     assert hi == THRESHOLD_WAS[shape] and 0 <= lo < hi
     assert (lo > 0) == (shape == (128, 8))
@@ -264,6 +265,18 @@ def test_a_band_answers_as_its_threshold_did(shape):
             continue                    # the band's new side, or no count
         was = "routed" if tokens >= THRESHOLD_WAS[shape] else "dense-mixture"
         assert moe.moe_route(tokens, *args) == was, tokens
+
+
+@pytest.mark.parametrize("tokens,form", [
+    (1, "routed"), (16, "routed"), (31, "routed"), (32, "dense-mixture"),
+    (64, "dense-mixture"), (128, "dense-mixture"), (255, "dense-mixture"),
+    (256, "routed"), (1024, "routed"), (8320, "routed")])
+def test_64_top_6_keeps_the_mixture_from_32_to_255(tokens, form):
+    """smallthinker's shape, from its own reading (PR 58; `models/moe.py`'s
+    table): the kernel under 32 tokens (few experts hit), the mixture
+    through decode's 64 slots and the tie at 128, routed from 256 — every
+    prefill of its cell."""
+    assert moe.moe_route(tokens, 64, 6) == form
 
 
 @pytest.mark.parametrize("tokens,form", [

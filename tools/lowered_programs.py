@@ -5,11 +5,13 @@ change what an existing configuration runs?".
 
 For each preset (default: mistral-7b, qwen2-7b, granite-4.0-h-small,
 qwen3-next-80b-a3b, keye-vl-2.0-30b-a3b, lfm2-8b-a1b, sdar-30b-a3b-chat
-(its diffusion programs at 2 denoise steps a block, the static rule) and
-kanana-2-30b-a3b (its latent cache in bfloat16) at the closed cells' shape,
+(its diffusion programs at 2 denoise steps a block, the static rule),
+kanana-2-30b-a3b (its latent cache in bfloat16) and smallthinker-21b-a3b
+(its window layers' rings of 4,096 rows beside full leaves of 640) at the
+closed cells' shape,
 128 slots x 640, int8 weights + int8 KV, decode_block 16; and tiny-moe8 —
 the stand-in for mixtral-8x7b's sharded programs — on a `model: 4` mesh of
-virtual CPU devices: twenty-eight programs, sdar's a second
+virtual CPU devices: thirty-one programs, sdar's a second
 admission at (16, 64) among them) it writes the
 StableHLO of the engine's OWN jits — `decode_block`, `prefill` at (8, 256)
 and `insert_all` — lowered from shapes alone (nothing is built or run), as
@@ -36,7 +38,8 @@ attention as a mixer kind of the hybrid trunk: all 24 older files
 identical, three new ones. PR 57, (128, 8)'s band: 26 of the 27
 identical — sdar-30b-a3b-chat's `prefill` (8 rows: an opening block of 32
 tokens, routed now) the one that differs; its `prefill_16x64`, new in the
-tool, identical on parent and change.)
+tool, identical on parent and change. PR 58, a second attention kind with a
+ring of its own: all 28 older files identical, three new ones.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
@@ -130,7 +133,8 @@ def main() -> int:
     names = sys.argv[2:] or ["mistral-7b", "qwen2-7b", "tiny-moe8",
                              "granite-4.0-h-small", "qwen3-next-80b-a3b",
                              "keye-vl-2.0-30b-a3b", "lfm2-8b-a1b",
-                             "sdar-30b-a3b-chat", "kanana-2-30b-a3b"]
+                             "sdar-30b-a3b-chat", "kanana-2-30b-a3b",
+                             "smallthinker-21b-a3b"]
     os.makedirs(out_dir, exist_ok=True)
     for name in names:
         cfg = llama.preset(name)
@@ -156,9 +160,12 @@ def main() -> int:
             params = shapes(lambda: llama.init_params(
                 cfg, jax.random.key(0), jnp.bfloat16, quantize=True))
             state = shapes(lambda: eng_mod.DecodeState(
-                cache=llama.init_cache(cfg, SLOTS, CAPACITY, jnp.bfloat16,
-                                       quantized=e.kv_quant,
-                                       count_experts=e._count_experts),
+                cache=llama.init_cache(
+                    cfg, SLOTS, CAPACITY, jnp.bfloat16, quantized=e.kv_quant,
+                    count_experts=e._count_experts,
+                    # (a window layer's ring: the window's rows)
+                    **({"ring": cfg.sliding_window}
+                       if getattr(cfg, "window_kind", None) else {})),
                 last_token=jnp.zeros((SLOTS,), jnp.int32),
                 temperature=jnp.zeros((SLOTS,), jnp.float32),
                 top_p=jnp.ones((SLOTS,), jnp.float32),
