@@ -76,7 +76,15 @@ verify and a chunked continuation have a mask a row and keep
     planes are position-minor; the MXU lays them out in the scores'
     (position, head) lane order — a product with a 0/1 `spread` matrix,
     exact because the f32 scales go in as three bf16 terms and every
-    output has one non-zero addend. k scales multiply the scores, v
+    output has one non-zero addend. The planes of the WAYS items a loop
+    step computes share ONE product a chunk, a row a head: what the
+    layout costs is the rows that stream through the MXU and come back
+    [rows] wide, far more than the loads of `spread` (PR 59, one v5e, us
+    an item at mistral's 128 x 640 of 8 heads | smallthinker's full
+    rings of 4: a product a way over rows repeated to whole slabs 0.527
+    | 0.521; one product for the ways 0.502 | 0.499; each distinct row
+    once 0.502 | 0.466; no layout at all, wrong numbers, 0.449 | 0.441;
+    the copies alone 0.389 | 0.380). k scales multiply the scores, v
     scales the probabilities, the probabilities are cast to the query's
     dtype before the output product — `gqa_attention`'s algebra.
   - Online softmax in f32; running max / sum / accumulator are the loop
@@ -212,6 +220,20 @@ def _three_bf16(x):
     return jnp.concatenate([a, b, c], axis=0)
 
 
+def _lay_out(x, spread):
+    """f32 planes x [n, chunk], position-minor, in the scores' lane order:
+    [n, chunk * n_kv], column j the plane's value at position j // n_kv.
+    One MXU product against the 0/1 `spread` [chunk, chunk * n_kv]: exact,
+    since x goes in as three bf16 terms and an output has one non-zero
+    addend a term."""
+    n = x.shape[0]
+    e = jax.lax.dot_general(
+        _three_bf16(x), spread, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+    return e[:n] + e[n:2 * n] + e[2 * n:]
+
+
 def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             scale: float, block_t: int, capacity: int, heads: int,
             n_kv: int, fold: int, slab: int, ways: int, quantized: bool,
@@ -321,10 +343,10 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         # the ways' arithmetic below is one straight line the scheduler
         # can interleave: the chains are independent.
         meta = []
+        buf = step % NBUF
         for w in range(ways):
             live = step < counts[w]
             item = jnp.minimum(step, counts[w] - 1)
-            buf = step % NBUF
             i = islot[w, item]
             first = (item == 0) | (islot[w, jnp.maximum(item - 1, 0)] != i)
 
@@ -338,11 +360,25 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                 for c in copies(w, item, buf):
                     c.wait()
 
-            meta.append((live, i, iblk[w, item], buf, first))
+            meta.append((live, i, iblk[w, item], first))
+
+        if quantized and n_kv > 1:
+            # The scale planes of ALL the ways' items through one product a
+            # chunk, a row a head (2K rows where the heads lie in pairs):
+            # what the layout costs is the rows that stream through the
+            # MXU and come back as [rows]-wide results, so each distinct
+            # row goes in once — [ways * (2 + masked) * n_h, chunk].
+            n_h = n_kv * fold
+            laid = jnp.concatenate([
+                _lay_out(jnp.concatenate(
+                    [scbuf[w, buf, p, :, c:c + chunk]
+                     for w in range(ways) for p in range(2 + masked)],
+                    axis=0), spread[...])
+                for c in range(0, block_t, chunk)], axis=1)
 
         out = []
         for w in range(ways):
-            live, i, blk, buf, first = meta[w]
+            live, i, blk, first = meta[w]
             m_in, l_in, acc_in = carry[w]
             length = len_ref[(base + i) // heads]
             m_old = jnp.where(first, NEG_INF, m_in)
@@ -364,30 +400,18 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                     for p in range(2))
                 s = per_slab(s, k_plane)
             elif quantized:
-                planes = []
-                for c in range(block_t // chunk):
-                    at = slice(c * chunk, (c + 1) * chunk)
-                    # K < 8 heads fill a slab by repeating their rows (a
-                    # row a head: 2K of them where the heads lie in pairs)
-                    n_p = (2 + masked) * slab
-                    e = jax.lax.dot_general(
-                        _three_bf16(jnp.concatenate(
-                            sum(([scbuf[w, buf, p, :, at]]
-                                 * (slab // (n_kv * fold))
-                                 for p in range(2 + masked)), []), axis=0)),
-                        spread[...], (((1,), (0,)), ((), ())),
-                        precision=jax.lax.Precision.DEFAULT,
-                        preferred_element_type=jnp.float32)
-                    planes.append(e[:n_p] + e[n_p:2 * n_p] + e[2 * n_p:])
-                planes = jnp.concatenate(planes, axis=1)  # [n_p, rows]
-                k_plane, v_plane = planes[:slab], planes[slab:2 * slab]
+                # K < 8 heads fill a slab by repeating their rows
+                k_plane, v_plane, *kept = (
+                    jnp.concatenate(
+                        [laid[r * n_h:(r + 1) * n_h]] * (slab // n_h), axis=0)
+                    for r in range(w * (2 + masked), (w + 1) * (2 + masked)))
                 s = per_slab(s, k_plane)
             s = s * scale + bias
             if masked:
                 # 0 on a selected position, -inf (as the own-head bias's)
                 # on one the query left out
                 s = (s.reshape(nq // slab, slab, rows)
-                     + ((planes[2 * slab:] - 1.0) * -NEG_INF)[None]
+                     + ((kept[0] - 1.0) * -NEG_INF)[None]
                      ).reshape(nq, rows)
             pos = t_of_lane + block_start(blk)
             keep = (pos < length) & (pos >= blk * block_t)

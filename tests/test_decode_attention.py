@@ -17,15 +17,19 @@ def tiled(monkeypatch):
     """decode_attention with the router's constants made small, so a
     handful of slots on the CPU walk every tiling the chip sees at 128
     and more: `tiled(lanes=2)` holds a grid step to 2 lanes,
-    `tiled(block_rows=512)` a block to 512 rows. Its own jit each time:
-    the constants are read when a shape is first traced."""
+    `tiled(block_rows=512)` a block to 512 rows. Its own jit of its own
+    function each time: the constants are read when a shape is first
+    traced, and jits of ONE function share their traces."""
     def make(lanes=None, block_rows=None):
         if lanes is not None:
             monkeypatch.setattr(da, "MAX_TILE_LANES", lanes)
         if block_rows is not None:
             monkeypatch.setattr(da, "BLOCK_ROWS", block_rows)
-        return jax.jit(decode_attention.__wrapped__,
-                       static_argnames=("window", "interpret"))
+
+        def fresh(*args, **kw):
+            return decode_attention.__wrapped__(*args, **kw)
+
+        return jax.jit(fresh, static_argnames=("window", "interpret"))
     return make
 
 
@@ -518,6 +522,70 @@ def test_one_query_takes_todays_tiles_and_four_fit_the_vmem():
             da.MAX_ITEM_BYTES,
             tiles[1] * heads * max(head_dim, 128) * kv_bytes)
         assert 2 * 2 * tile_bytes + items <= 2**24, (batch, capacity, n_kv)
+
+
+def plain_layout(x, spread):
+    """`_lay_out` as `jnp` says it: every position's value once a KV head."""
+    return jnp.repeat(x, spread.shape[1] // spread.shape[0], axis=-1)
+
+
+# id -> (KV heads, groups, head size, queries a slot, selection, lengths)
+LAYOUTS = {
+    "8 heads": (8, 4, 128, None, False, LENGTHS_640),
+    "4 heads": (4, 7, 128, None, False, LENGTHS_640),
+    "pairs of 64": (8, 4, 64, None, False, LENGTHS_640),
+    "keep plane": (4, 8, 128, None, True, [640, 0, 257, 129, 300, 1]),
+    "four queries": (4, 8, 128, 4, False, [b + 4 for b in BASES_640]),
+    # a window layer's ring once it is full: kv_length = min(length, T)
+    "full ring": (4, 7, 128, None, False, [640] * 6),
+    # the ways' lists of unequal lengths: five items against one or two
+    "one long slot": (8, 4, 128, None, False, [640, 0, 0, 0, 0, 0]),
+}
+
+
+class TestScalePlanesOfAStep:
+    """The scale planes of the WAYS items a loop step computes go through
+    ONE `spread` product a chunk (`_lay_out` over the ways' stacked rows):
+    the result is, bit for bit, that of one way with the planes laid out by
+    `jnp.repeat` — three bf16 terms and a 0/1 matrix lose nothing, and a
+    row of the stacked operand reaches its own way alone — and, to
+    rounding, `gqa_attention`'s."""
+
+    @pytest.mark.parametrize("ways, lanes", [(1, 1), (2, 2), (4, 6)])
+    @pytest.mark.parametrize("case", list(LAYOUTS))
+    def test_one_product_a_chunk_is_the_plain_layout(self, tiled, monkeypatch,
+                                                     case, ways, lanes):
+        K, G, D, S, selected, lengths = LAYOUTS[case]
+        q, k, v, scales = case_640(K, G, True, seed=12, D=D, S=S)
+        keep = (jax.random.bernoulli(jax.random.key(13), 0.5, (6, 640))
+                .at[:, 0].set(True) if selected else None)
+        if case == "one long slot":
+            # what a way that has run out still holds — here an empty
+            # slot's planes, not even finite — shares the product with the
+            # long slot's rows and must not reach them
+            scales = tuple(s.at[:, 1:].set(jnp.nan) for s in scales)
+        args = (rows(k), rows(v), jnp.int32(1),
+                jnp.asarray(lengths, jnp.int32), *scales, keep)
+        assert min(da.WAYS, lanes) == ways
+        got = tiled(lanes=lanes * (S or 1))(q, *args, interpret=True)
+        monkeypatch.setattr(da, "_lay_out", plain_layout)
+        want = tiled(lanes=S or 1)(q, *args, interpret=True)
+        live = np.asarray(lengths) > 0   # "one long slot": slot 0 alone
+        assert np.isfinite(np.asarray(got, np.float32)[live]).all()
+        np.testing.assert_array_equal(np.asarray(got, np.float32)[live],
+                                      np.asarray(want, np.float32)[live])
+        # both sides slice the product's rows alike, so hold them to
+        # `gqa_attention` too: a plane or a head taken for another's is
+        # the same bits at every `ways`, and not the XLA form's numbers
+        kv_length = jnp.asarray(lengths, jnp.int32)
+        positions = kv_length[:, None] - (S or 1) + jnp.arange(S or 1)[None]
+        ref = gqa_attention(
+            q if S else q[:, None], k[1], v[1], positions, kv_length,
+            k_scale=scales[0][1], v_scale=scales[1][1], block_len=S,
+            keep=keep[:, None] if selected else None).reshape(q.shape)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[live],
+            np.asarray(ref, np.float32)[live], rtol=2e-2, atol=2e-2)
 
 
 # (query heads, KV heads, head size): 2 heads of 128; 8 of 64, which the

@@ -16,7 +16,18 @@ positions a slot from a block boundary (lengths rounded down to one), the
 kernel against `gqa_attention(block_len=)`; `--tiles` also times the kernel
 at other WAYS / MAX_TILE_LANES (set here, for this process alone).
 
-Needs a TPU: `python tools/ab_ragged_640.py [--tiles] [preset ...]`. Writes
+`--kernel` times the kernel ALONE instead of the trunks, at the int8 shapes
+whose cells it weighs most in — mistral-7b's and qwen2-7b's 128 x 640 (8
+and 4 KV heads) and smallthinker-21b-a3b's two leaves, 64 rings of 4,096
+rows all full and 64 full rows of 11,776 at the cell's lengths (5k-10k,
+~7.6k a slot), 4 KV heads under 28 query heads: ms a call (a call a layer,
+back to back under one fence), us an item (a slot's block of BLOCK_ROWS
+rows: 128 KB of K and of V) and GB/s of live rows. (PR 59 also read them
+with the scale planes' layout product replaced by ones, wrong numbers: an
+item 15% cheaper, 0.527 -> 0.449 us at mistral's shape; PERF.md section 6.)
+
+Needs a TPU: `python tools/ab_ragged_640.py [--tiles] [preset ...]`, or
+`python tools/ab_ragged_640.py --kernel`. Writes
 chiprun_out/ab_ragged_640.json.
 """
 import json
@@ -29,7 +40,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _bench_util import sync
+from _bench_util import sync, timeit
 from symmetry_tpu.models import llama
 from symmetry_tpu.ops import decode_attention as da
 from symmetry_tpu.ops.attention import gqa_attention
@@ -48,6 +59,57 @@ def cell_mix() -> np.ndarray:
     live = rng.permutation(B)[:107]
     lengths[live] = rng.integers(32, 600, size=live.size)
     return lengths
+
+
+def kernel_shapes() -> dict:
+    """label -> (capacity, KV heads, query heads, layers, calls, lengths):
+    the cache a kernel-only row reads, `calls` calls a timed program."""
+    rng = np.random.default_rng(0)
+    return {
+        "mistral-7b 128 x 640, cell mix": (T, 8, 32, 4, 8, cell_mix()),
+        "mistral-7b 128 x 640, at 620": (T, 8, 32, 4, 8,
+                                         np.full(B, 620, np.int32)),
+        "qwen2-7b 128 x 640, at 620": (T, 4, 28, 4, 8,
+                                       np.full(B, 620, np.int32)),
+        "smallthinker ring 64 x 4,096, full": (
+            4096, 4, 28, 9, 9, np.full(64, 4096, np.int32)),
+        "smallthinker full 64 x 11,776, 5k-10k": (
+            11776, 4, 28, 3, 6, rng.integers(5120, 10240, 64, np.int32)),
+    }
+
+
+def kernel_rows() -> dict:
+    """The kernel alone over a random int8 cache, a call a layer."""
+    out = {}
+    for label, (cap, K, nq, L, calls, lengths) in kernel_shapes().items():
+        slots, D = lengths.size, 128
+        ks = jax.random.split(jax.random.key(2), 5)
+        q = jax.random.normal(ks[0], (slots, nq, D), jnp.bfloat16)
+        k, v = (jax.random.randint(key, (L, slots, cap, K, D), -127, 128,
+                                   jnp.int8) for key in ks[1:3])
+        ksc, vsc = (jax.random.uniform(key, (L, slots, K, cap), jnp.float32,
+                                       0.005, 0.02) for key in ks[3:])
+        block_t = da.geometry(slots, cap, K)[1]
+
+        @jax.jit
+        def step(q, k, v, ksc, vsc, n_):
+            # a q a call: identical calls would be one call
+            return sum(da.decode_attention(
+                q * (1 + c), k, v, jnp.int32(c % L), n_, ksc, vsc,
+                interpret=interpret_mode()) for c in range(calls))
+
+        ms = timeit(step, q, k, v, ksc, vsc,
+                    jnp.asarray(lengths, jnp.int32)) / calls
+        items = int(np.maximum(-(-lengths // block_t), 1).sum())
+        live = int(lengths.sum()) * K * (2 * D + 8)   # K, V and two scales
+        out[label] = {"ms_a_call": round(ms, 4), "items": items,
+                      "us_an_item": round(ms * 1e3 / items, 4),
+                      "live_gb_s": round(live / ms / 1e6, 1)}
+        print(f"kernel {label}: {ms:.4f} ms a call, {items} items, "
+              f"{ms * 1e3 / items:.4f} us an item, "
+              f"{live / ms / 1e6:.1f} GB/s of live rows", flush=True)
+        del q, k, v, ksc, vsc
+    return out
 
 
 def block_of(cfg) -> int:
@@ -122,9 +184,19 @@ def make_trunk(cfg, params, use_kernel: bool):
     return timed
 
 
+def save(report: dict) -> None:
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/ab_ragged_640.json", "w") as fh:
+        json.dump(report, fh, indent=1)
+
+
 def main() -> None:
     report = {"device": jax.devices()[0].device_kind, "shape": [B, T]}
-    names = [a for a in sys.argv[1:] if a != "--tiles"]
+    names = [a for a in sys.argv[1:] if not a.startswith("--")]
+    if "--kernel" in sys.argv:
+        report["kernel"] = kernel_rows()
+        save(report)
+        return
     for name in names or ("mistral-7b", "qwen2-7b"):
         cfg = llama.preset(name)
         S = block_of(cfg)
@@ -156,9 +228,7 @@ def main() -> None:
                 + f"  ({ms['xla'] - ms['kernel']:+.2f})", flush=True)
         report[name] = row
         del params
-        os.makedirs("chiprun_out", exist_ok=True)
-        with open("chiprun_out/ab_ragged_640.json", "w") as fh:
-            json.dump(report, fh, indent=1)
+        save(report)
 
 
 if __name__ == "__main__":
