@@ -470,6 +470,8 @@ class InferenceEngine:
         # llama.py KVCache).
         self._count_experts = bool(getattr(c, "num_experts", 0))
         self.expert_pairs = [0] * getattr(c, "num_experts", 0)
+        # (a share of the experts: held experts hit, a layer and a forward)
+        self.expert_hits = 0
         self._pairs_pending: collections.deque = collections.deque()
         self._moe_report: dict | None = None
 
@@ -1679,6 +1681,10 @@ class InferenceEngine:
         if c.recurrent_kind == "mamba":
             kind = {"kind": "mamba2",
                     "mamba_layers": len(c.layers_of("mamba"))}
+            if c.mamba_n_groups > 1:
+                # groups of B and C the heads read (the norm's groups too)
+                kind["groups"] = c.mamba_n_groups
+                kind["heads_per_group"] = c.mamba_n_heads // c.mamba_n_groups
             chunk, decode = c.mamba_chunk_size, mamba2.step_form(
                 c, cache.ssm.dtype.itemsize)
         else:
@@ -1694,6 +1700,7 @@ class InferenceEngine:
             "state_dtype": str(cache.ssm.dtype),
             "conv_dtype": str(cache.conv.dtype),
             "prefill": {"form": "chunked (jnp)", "chunk": chunk},
+            # one form serves every state layer: the stack is one operand
             "decode": decode,
         }
 
@@ -2465,6 +2472,28 @@ class InferenceEngine:
                 for name, n in zip(WINDOW_COUNTS,
                                    block[-len(WINDOW_COUNTS):]):
                     self.swa[name] += int(n)
+            if getattr(self.config, "experts_held", None) is not None:
+                self.expert_hits += int(block[-1])      # HELD_COUNTS
+
+    def moe_counts(self) -> dict:
+        """`stats.engine.moe`'s counters: valid (token, expert) pairs
+        computed since start, in all and per expert, as of the last synced
+        decode block. Where this chip holds a SHARE of the experts the
+        router scores, `pairs` counts every pair the router made,
+        `expert_pairs` is of the HELD experts alone (what this chip
+        computed, and what its imbalance is over), `held_pairs` /
+        `absent_pairs` split the total, and `expert_hits` counts the held
+        experts a valid pair fell on, a layer and a forward: what the
+        routed form has to read, an expert's matrices a hit."""
+        counts = list(self.expert_pairs)
+        out = {"pairs": sum(counts), "expert_pairs": counts}
+        held = getattr(self.config, "experts_held", None)
+        if held is not None:
+            mine = counts[held[0]:held[0] + held[1]]
+            out.update(expert_pairs=mine, held_pairs=sum(mine),
+                       absent_pairs=sum(counts) - sum(mine),
+                       expert_hits=self.expert_hits)
+        return out
 
     def moe_report(self) -> dict | None:
         """`startup.moe`: where the expert weights live and which form
@@ -2483,10 +2512,15 @@ class InferenceEngine:
         # the stacks the trunk hands `moe_mlp` whole: the hybrid trunk its
         # `ffn` stack always, the homogeneous one what `run_layers` does
         whole = layers.get("ffn") or whole_stacks(layers, self.mesh)
-        wg = layers.get("ffn", layers)["wg"]
+        # (an ungated expert has no gate matrix: its up-projection's stack
+        # is the same shape)
+        ffn = layers.get("ffn", layers)
+        wg = ffn["wg"] if "wg" in ffn else ffn["wu"]
+        held = getattr(c, "experts_held", None)
 
         def route(tokens: int) -> str:
-            return moe_route(tokens, c.num_experts, c.num_experts_per_tok)
+            return moe_route(tokens, c.num_experts, c.num_experts_per_tok,
+                             held and held[1])
 
         # by tokens a forward: decode is one per slot (a block per slot
         # under block diffusion); a prefill is batch x bucket for every
@@ -2533,19 +2567,34 @@ class InferenceEngine:
                 "score": "sigmoid", "bias": bool(c.router_bias),
                 "norm_topk": True, "scale": float(c.routed_scaling_factor)}
             self._moe_report["dense_layers"] = c.num_dense_layers
-            self._moe_report["expert_layers"] = (c.num_layers
-                                                 - c.num_dense_layers)
+            self._moe_report["expert_layers"] = len(
+                c.layers_ending_in("moe"))
         if getattr(c, "router_input", "ffn_input") != "ffn_input":
             # smallthinker: what the router reads, and the gated activation
             self._moe_report["router_input"] = c.router_input
             self._moe_report["activation"] = c.hidden_act
+        if held is not None:
+            # a chip's share: the router scores `experts`, the leaves hold
+            # `count` of them from `first`; pairs on the others are dropped
+            # before the sort (routed) or masked (mixture), gates as routed
+            self._moe_report["held"] = {
+                "first": held[0], "count": held[1],
+                "routed_over": c.num_experts,
+                "absent": "dropped before the sort, gates not renormalised"}
+            self._moe_report["layers_without_ffn"] = len(
+                c.layers_ending_in("none"))
+        if not getattr(c, "gated_ffn", True):
+            self._moe_report["expert_form"] = (
+                f"ungated: {c.hidden_act}(x W_up) W_down, two matrices")
+            self._moe_report["activation"] = c.hidden_act
         if c.shared_intermediate_size:
+            gated = "gated" if getattr(c, "gated_ffn", True) else "ungated"
             self._moe_report["shared_expert"] = {
                 "width": c.shared_intermediate_size,
-                "form": ("dense gated FFN, every token, weight "
+                "form": (f"dense {gated} FFN, every token, weight "
                          "sigmoid(x . sgate)"
                          if getattr(c, "shared_expert_gate", False)
-                         else "dense gated FFN, every token, weight 1")}
+                         else f"dense {gated} FFN, every token, weight 1")}
         return self._moe_report
 
     def decode_step(self) -> np.ndarray:

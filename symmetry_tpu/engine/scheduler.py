@@ -658,8 +658,7 @@ class Scheduler:
             # Valid (token, expert) pairs computed since start, per
             # expert and in all, as of the last synced decode block; the
             # form each program kind's expert FFN takes (models/moe.py).
-            counts = list(self.engine.expert_pairs)
-            out["moe"] = {"pairs": sum(counts), "expert_pairs": counts,
+            out["moe"] = {**self.engine.moe_counts(),
                           "route": self.engine.moe_report()["route"]}
         if self._bd_block is not None:
             # generation by diffusion over blocks: the settings, the

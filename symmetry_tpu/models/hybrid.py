@@ -79,6 +79,22 @@ leaves are as long as the bucket (the engine's insert rolls the last
 `window` rows into the ring); every other call treats the window leaf as a
 ring of its capacity.
 
+A nemotron_h model (`config.ffn_layout`) is granite's trunk — Mamba-2
+among NoPE GQA layers, the cache's `ssm` / `conv` / `k` / `v` as above — whose
+published blocks are ONE sub-layer each: `M` a mixer alone, `*` attention
+alone, `E` experts alone, every one `h + f(norm(h))`. Two consecutive blocks
+"mixer, then E" ARE this trunk's (mixer, FFN) layer, exactly, so the 52
+published blocks run as 29 layers (models/llama.py `pair_blocks`) whose FFN
+kind is the LAYER's (`ffn_kind(i)`: "moe", or "none" — the body returns after
+the mixer — for the mamba block before an attention block); a run breaks
+where the FFN kind changes, and `layers.ffn` stacks the layers that END in
+experts alone, indexed by `ffn_index`. Its Mamba-2 has G groups of B and C
+(models/mamba2.py; the decode kernel indexes them by the head group of the
+tile it holds), its experts are ungated relu2 — `wu` and `wd`, no `wg`; the
+shared expert `su` / `sd` — stored at `expert_columns` of their width, and
+the chip may hold a SHARE of the experts its router scores (`experts_held`;
+models/moe.py), its head untied, no multipliers.
+
 One device only: there are no sharding rules for the state yet.
 """
 
@@ -169,6 +185,8 @@ def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
             kw_scale=jnp.zeros(wscale, jnp.float32) if quantized else None,
             vw_scale=jnp.zeros(wscale, jnp.float32) if quantized else None)
         counts = len(llama.WINDOW_COUNTS)  # the expert counter's tail
+    if config.experts_held is not None:
+        counts = len(llama.HELD_COUNTS)     # a share counts its hits
     return llama.KVCache(
         k=jnp.zeros(shape, kv_dtype),
         v=jnp.zeros(shape, kv_dtype),
@@ -214,8 +232,11 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
         return _init_qwen3_next(c, keys, dense, dtype)
     if c.recurrent_kind == "conv":
         return _init_lfm2(c, keys, dense, dtype)
-    L, E, F = c.num_layers, c.hidden_size, c.intermediate_size
-    X, Fs = c.num_experts, c.shared_intermediate_size
+    E, F = c.hidden_size, c.intermediate_size
+    # the expert layers, and the experts whose weights lie HERE (all the
+    # router scores, or this chip's share of them)
+    L, X, Fs = (len(c.layers_ending_in("moe")), c.experts_here,
+                c.shared_intermediate_size)
     Lm, La = len(c.layers_of("mamba")), len(c.layers_of("attention"))
     z = mamba2.sizes(c)
     H = z["H"]
@@ -224,7 +245,7 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
     dt = jnp.exp(jax.random.uniform(next(keys), (Lm, H), jnp.float32,
                                     math.log(1e-3), math.log(1e-1)))
     a = jax.random.uniform(next(keys), (Lm, H), jnp.float32, 1.0, 16.0)
-    return {
+    params = {
         "embed": dense((c.vocab_size, E), scale=0.02),
         "layers": {
             "mamba": {
@@ -245,19 +266,66 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
                 "wv": dense((La, E, c.kv_dim), "wv"),
                 "wo": dense((La, c.q_dim, E), "wo"),
             },
-            "ffn": {
-                "norm": jnp.ones((L, E), dtype),
-                "router": dense((L, E, X)),
-                "wg": dense((L, X, E, F), "wg"),
-                "wu": dense((L, X, E, F), "wu"),
-                "wd": dense((L, X, F, E), "wd"),
-                "sg": dense((L, E, Fs), "sg"),
-                "su": dense((L, E, Fs), "su"),
-                "sd": dense((L, Fs, E), "sd"),
-            },
         },
         "final_norm": jnp.ones((E,), dtype),
     }
+    ffn = {"norm": jnp.ones((L, E), dtype),
+           "router": dense((L, E, c.num_experts))}
+    if c.gated_ffn:     # granitemoehybrid: three matrices an expert
+        ffn.update(wg=dense((L, X, E, F), "wg"), wu=dense((L, X, E, F), "wu"),
+                   wd=dense((L, X, F, E), "wd"), sg=dense((L, E, Fs), "sg"),
+                   su=dense((L, E, Fs), "su"), sd=dense((L, Fs, E), "sd"))
+    else:
+        # nemotron_h: two, stored at `expert_columns` of their width
+        Fp = expert_columns(F)
+        ffn.update(
+            wu=_zero_past(dense((L, X, E, Fp), "wu"), F, -1),
+            wd=_zero_past(dense((L, X, Fp, E), "wd", scale=F ** -0.5), F, -2),
+            su=dense((L, E, Fs), "su"), sd=dense((L, Fs, E), "sd"))
+    params["layers"]["ffn"] = ffn
+    if c.router_bias:
+        # (HF `e_score_correction_bias`, drawn as `_init_lfm2` draws it: at
+        # the published initial zero no comparison could tell it left out)
+        ffn["expert_bias"] = jax.random.uniform(
+            next(keys), (L, c.num_experts), jnp.float32, -0.25, 0.25)
+    if not c.tie_embeddings:
+        params["lm_head"] = dense((E, c.vocab_size), "lm_head", scale=0.02)
+    return params
+
+
+LANES = 128
+
+
+def expert_columns(width: int) -> int:
+    """The width an ungated expert's two matrices are STORED at: the
+    published width rounded up to whole lane tiles, the columns of `wu` and
+    the rows of `wd` past it zero (relu2(0) = 0 meets a zero row: exact).
+    nemotron_h's 1,856 is 14.5 tiles: left as it is, the chip lays an
+    [.., 2688, 1856] int8 leaf out with 2,688 minor to save the padding, and
+    hands a kernel that wants rows of 1,856 a 3.5 GB copy of the stack a
+    call (compiled for a described v5e: tests/test_chip_compile.py) — so the
+    layout pads, 1,920, and no width of the model changes: the config, the
+    reference and the benchmark's byte counts are of 1,856."""
+    return -(-width // LANES) * LANES
+
+
+def _zero_past(leaf, n: int, axis: int):
+    """`leaf` with everything from index `n` on along `axis` (-1 or -2)
+    zeroed where it lies — an int8 leaf's payload; a zero times any scale
+    is zero."""
+    from symmetry_tpu.ops.quant import QuantizedTensor
+
+    def zero(a):
+        if a.shape[axis] == n:
+            return a
+        keep = jnp.arange(a.shape[axis]) < n
+        keep = keep if axis == -1 else keep[:, None]
+        return jax.jit(lambda a: jnp.where(keep, a, jnp.zeros((), a.dtype)),
+                       donate_argnums=0)(a)
+
+    if isinstance(leaf, QuantizedTensor):
+        return QuantizedTensor(q=zero(leaf.q), scale=leaf.scale)
+    return zero(leaf)
 
 
 def _init_qwen3_next(c, keys, dense, dtype) -> dict:
@@ -589,7 +657,6 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
     r = jnp.asarray(c.residual_multiplier, h.dtype)
 
     recurrent = RECURRENT.get(c.recurrent_kind)
-    n_dense = c.num_dense_layers
 
     def norm(h, w):
         return rms_norm(h, llama._norm_w(w, c), c.rms_eps)
@@ -631,23 +698,29 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
 
     for kind, first, length in runs(c):
         j0 = stack_index(c, first)
+        # a run is of one FFN kind (`runs`): the run's first layer's, and
+        # its index in that kind's stack
+        ffn, f0 = c.ffn_kind(first), c.ffn_index(first)
 
-        def body(carry, step, kind=kind, first=first, j0=j0):
+        def body(carry, step, kind=kind, first=first, j0=j0, ffn=ffn,
+                 f0=f0):
             h, cache = carry
             entered = h  # what a "layer_input" router reads
             lp = _at(layers[KIND_STACK[kind]], j0 + step)
             out, cache = mixer(kind, norm(h, lp["norm"]), lp, cache,
                                j0 + step, c.layer_rope(first))
             h = h + r * out
-            if first < n_dense:  # a run is of one FFN kind (`runs`)
-                lp = _at(layers["dense"], first + step)
+            if ffn == "none":  # the block is the mixer alone
+                return (h, cache), None
+            if ffn == "dense":
+                lp = _at(layers["dense"], f0 + step)
                 return (h + r * dense_ffn(norm(h, lp["norm"]), lp),
                         cache), None
             # the layer's index among the expert layers
-            lp = _at(layers["ffn"], first - n_dense + step)
+            lp = _at(layers["ffn"], f0 + step)
             y, pairs = moe_mlp(
                 norm(h, lp["norm"]), lp, c, seq_lens,
-                stack=(layers["ffn"], first - n_dense + step),
+                stack=(layers["ffn"], f0 + step),
                 **({"route_from": entered}
                    if c.router_input == "layer_input" else {}))
             h = h + r * y
@@ -707,6 +780,8 @@ def hf_config(config) -> dict:
         return llama.hf_config_latent(c)
     if c.recurrent_kind is None:
         return llama.hf_config_window(c)
+    if c.ffn_layout is not None:
+        return llama.hf_config_nemotron_h(c)
     if c.recurrent_kind == "conv":
         return {
             "architectures": ["Lfm2MoeForCausalLM"],
@@ -771,7 +846,8 @@ def hf_config(config) -> dict:
         "num_experts_per_tok": c.num_experts_per_tok,
         "mamba_n_heads": c.mamba_n_heads, "mamba_d_head": c.mamba_d_head,
         "mamba_d_state": c.mamba_d_state, "mamba_d_conv": c.mamba_d_conv,
-        "mamba_n_groups": 1, "mamba_chunk_size": c.mamba_chunk_size,
+        "mamba_n_groups": c.mamba_n_groups,
+        "mamba_chunk_size": c.mamba_chunk_size,
         "embedding_multiplier": c.embedding_multiplier,
         "residual_multiplier": c.residual_multiplier,
         "attention_multiplier": c.attention_multiplier,
@@ -781,6 +857,16 @@ def hf_config(config) -> dict:
         "tie_word_embeddings": c.tie_embeddings,
         "max_position_embeddings": c.max_position,
     }
+
+
+def _no_name_map(config) -> None:
+    """A nemotron_h checkpoint's tensor names are not mapped: no checkpoint
+    of the family has been in the repository to hold a map to (the served
+    weights are random, from the seed)."""
+    if config.ffn_layout is not None:
+        raise ValueError(
+            "a checkpoint of a model whose blocks are one sub-layer each "
+            "(ffn_layout; HF nemotron_h) has no tensor-name map yet")
 
 
 def _from_hf(ours: str, arr):
@@ -812,6 +898,7 @@ def convert_hf_state_dict(tensors: dict, config) -> dict:
         return _qwen3_next_from_hf(tensors, config)
     if config.recurrent_kind == "conv":
         return _lfm2_from_hf(tensors, config)
+    _no_name_map(config)
     known = set(HF_TOP)
     stacks: dict = {"mamba": {}, "attn": {}, "ffn": {}}
     for i, kind in enumerate(config.layer_types):
@@ -847,6 +934,7 @@ def to_hf_state_dict(params: dict, config) -> dict:
         return _qwen3_next_to_hf(params, config)
     if config.recurrent_kind == "conv":
         return _lfm2_to_hf(params, config)
+    _no_name_map(config)
 
     def arr(a):
         return np.asarray(a, np.float32)

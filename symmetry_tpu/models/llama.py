@@ -62,7 +62,7 @@ class ModelConfig:
     max_position: int = 8192
     # gemma family: gelu-tanh GeGLU, RMSNorm scale stored as (weight - 1),
     # and embeddings multiplied by sqrt(hidden) at lookup
-    hidden_act: str = "silu"             # "silu" | "gelu_tanh" | "relu"
+    hidden_act: str = "silu"    # "silu" | "gelu_tanh" | "relu" | "relu2"
     norm_plus_one: bool = False
     scale_embed: bool = False
 
@@ -191,8 +191,10 @@ class HybridConfig(MoEConfig):
     "linear_attention" (Gated DeltaNet, models/gdn.py) or "full_attention"
     — or, lfm2_moe's, "conv" (a gated short convolution, models/sconv.py) or
     "full_attention" — each followed by the routed (+ shared) expert FFN,
-    lfm2_moe's first `num_dense_layers` by a dense SwiGLU instead. The forward pass, parameters and cache
-    are models/hybrid.py's; every entry point of this module hands a config
+    lfm2_moe's first `num_dense_layers` by a dense SwiGLU instead — or,
+    nemotron_h's, "mamba" or "attention" again, each followed by what its
+    `ffn_layout` entry says: experts, or nothing. The forward pass,
+    parameters and cache are models/hybrid.py's; every entry point of this module hands a config
     with `layer_types` over to it."""
 
     layer_types: tuple[str, ...] = ()
@@ -256,10 +258,36 @@ class HybridConfig(MoEConfig):
     # tensor the experts read, every other model)
     rope_layout: tuple[int, ...] | None = None
     router_input: str = "ffn_input"   # "ffn_input" | "layer_input"
+    # nemotron_h family, every field at "absent" for any other model: a
+    # published block is ONE sub-layer — a mixer alone or an FFN alone, each
+    # under its own norm and residual — so the FFN kind is a property of the
+    # LAYER: `ffn_layout[i]` says what trunk layer i (the mixer
+    # `layer_types[i]`) ends in, "moe", "dense" or "none" (None: the leading
+    # `num_dense_layers` "dense", the rest "moe"), and `num_layers` may count
+    # the published blocks (mixers + FFNs) instead of the trunk's layers.
+    # `mamba_n_groups` groups of B and C a position (head h reads group
+    # h // (heads / groups); the gated norm is over each group's channels).
+    # `experts_held` = (first, count): the routed experts THIS chip holds of
+    # the `num_experts` the router scores (None: all of them) — routing is
+    # over all, the held experts' part of the sum is computed and the rest
+    # left out, gates as routed (models/moe.py). `gated_ffn` False: an
+    # expert (and the shared one) is act(x W_up) W_down, two matrices, no
+    # gate matrix; `hidden_act` "relu2" is relu(x)^2.
+    ffn_layout: tuple[str, ...] | None = None
+    mamba_n_groups: int = 1
+    experts_held: tuple[int, int] | None = None
+    gated_ffn: bool = True
 
     def __post_init__(self):
         kinds = set(self.layer_types)
-        if (len(self.layer_types) != self.num_layers
+        if self.ffn_layout is not None and (
+                len(self.ffn_layout) != len(self.layer_types)
+                or not set(self.ffn_layout) <= {"moe", "dense", "none"}):
+            raise ValueError(
+                f"ffn_layout must name 'moe', 'dense' or 'none' for each of "
+                f"the {len(self.layer_types)} layers; got "
+                f"{self.ffn_layout!r}")
+        if (self.num_layers not in (len(self.layer_types), self.num_blocks)
                 or not (kinds <= {"mamba", "attention"}
                         or kinds <= {"linear_attention", "full_attention"}
                         or kinds <= {"conv", "full_attention"}
@@ -297,6 +325,34 @@ class HybridConfig(MoEConfig):
         if bool(self.num_dense_layers) != bool(self.dense_intermediate_size):
             raise ValueError("num_dense_layers and dense_intermediate_size "
                              "go together")
+        if self.mamba_n_groups < 1 or (
+                self.mamba_n_heads % self.mamba_n_groups):
+            raise ValueError(
+                f"mamba_n_groups {self.mamba_n_groups} must divide "
+                f"mamba_n_heads {self.mamba_n_heads}")
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if not (0 <= first and 0 < count
+                    and first + count <= self.num_experts):
+                raise ValueError(
+                    f"experts_held (first, count) = {self.experts_held!r} "
+                    f"must lie inside the {self.num_experts} experts routed "
+                    f"over")
+
+    @property
+    def num_blocks(self) -> int:
+        """Mixers and FFNs counted singly — what a family whose published
+        block is ONE sub-layer (`ffn_layout`) calls its number of layers;
+        the trunk's layers for every other."""
+        return len(self.layer_types) + (
+            0 if self.ffn_layout is None
+            else sum(k != "none" for k in self.ffn_layout))
+
+    @property
+    def experts_here(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return (self.num_experts if self.experts_held is None
+                else self.experts_held[1])
 
     def layers_of(self, kind: str) -> tuple[int, ...]:
         return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
@@ -342,9 +398,56 @@ class HybridConfig(MoEConfig):
                     else self.rope_layout[i])
 
     def ffn_kind(self, i: int) -> str:
-        """What layer i ends in: "dense" (the leading SwiGLU layers) or
-        "moe"."""
+        """What layer i ends in: its `ffn_layout` entry ("moe", "dense" or
+        "none": the mixer alone), or, without a layout, "dense" for the
+        leading SwiGLU layers and "moe" for the rest."""
+        if self.ffn_layout is not None:
+            return self.ffn_layout[i]
         return "dense" if i < self.num_dense_layers else "moe"
+
+    def ffn_index(self, i: int) -> int:
+        """Layer i's index in the stack of its FFN kind."""
+        kind = self.ffn_kind(i)
+        return sum(self.ffn_kind(j) == kind for j in range(i))
+
+    def layers_ending_in(self, kind: str) -> tuple[int, ...]:
+        return tuple(i for i in range(len(self.layer_types))
+                     if self.ffn_kind(i) == kind)
+
+
+BLOCK_MIXERS = {"M": "mamba", "*": "attention"}
+
+
+def pair_blocks(pattern: str) -> tuple[tuple[str, ...], tuple[str, ...]]:
+    """A nemotron_h `hybrid_override_pattern` (one letter a block: `M` a
+    Mamba-2 mixer, `*` attention, `E` experts; each block `h + f(norm(h))`)
+    as the trunk's (layer_types, ffn_layout): two consecutive pre-norm
+    residual blocks "mixer, then E" ARE one (mixer, FFN) layer, exactly, and
+    a mixer that no `E` follows is a layer that ends in "none". Refuses a
+    pattern it cannot pair: an `E` with no mixer before it, and `-` (a
+    dense MLP block, which no served model of the family has)."""
+    kinds, ffns = [], []
+    for at, letter in enumerate(pattern):
+        if letter in BLOCK_MIXERS:
+            kinds.append(BLOCK_MIXERS[letter])
+            ffns.append("none")
+        elif letter == "E" and ffns and ffns[-1] == "none" \
+                and pattern[at - 1] in BLOCK_MIXERS:
+            ffns[-1] = "moe"
+        else:
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r}: block {at} ({letter!r}"
+                f") cannot be paired — an expert block needs a mixer block "
+                f"before it, and only M, * and E blocks are implemented")
+    return tuple(kinds), tuple(ffns)
+
+
+def blocks_of(config: "HybridConfig") -> str:
+    """The inverse of `pair_blocks`."""
+    letters = {v: k for k, v in BLOCK_MIXERS.items()}
+    return "".join(letters[kind] + ("E" if ffn == "moe" else "")
+                   for kind, ffn in zip(config.layer_types,
+                                        config.ffn_layout))
 
 
 # Named presets; sizes from the public HF configs of each model family.
@@ -628,6 +731,52 @@ PRESETS: dict[str, ModelConfig] = {
         num_experts=64, num_experts_per_tok=6,
         layer_types=(("full_attention",) + ("sliding_attention",) * 3) * 3,
         rope_layout=(0, 1, 1, 1) * 3, router_input="layer_input",
+    ),
+    # the CPU's copy of Nemotron-H's mechanisms: blocks of one sub-layer
+    # ("MEM*EMEM*EME": every pairing — a mamba block before experts, before
+    # attention, attention before experts — as 7 trunk layers of 12 blocks),
+    # 2 groups of 4 mamba heads, a chunk shorter than the test prompts, 4
+    # query heads on ONE K/V head with no rotary, 8 ungated relu2 experts
+    # top 2 by the sigmoid router of which this chip holds the SECOND four,
+    # an ungated shared expert, an untied head
+    "tiny-nh": HybridConfig(
+        vocab_size=512, hidden_size=64, num_layers=12, num_heads=4,
+        num_kv_heads=1, intermediate_size=24, head_dim=16,
+        rope_theta=10000.0, rms_eps=1e-5, max_position=512,
+        hidden_act="relu2", gated_ffn=False,
+        num_experts=8, num_experts_per_tok=2, experts_held=(4, 4),
+        shared_intermediate_size=40,
+        layer_types=("mamba", "mamba", "attention", "mamba", "mamba",
+                     "attention", "mamba"),
+        ffn_layout=("moe", "none", "moe", "moe", "none", "moe", "moe"),
+        mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16,
+        mamba_chunk_size=16, mamba_n_groups=2, rope=False,
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=2.5, router_norm_eps=1e-20,
+    ),
+    # NVIDIA-Nemotron-3-Nano-30B-A3B (HF `nemotron_h`) at its WHOLE depth,
+    # all 52 blocks — 23 Mamba-2 (8 groups), 6 GQA (32 Q on 2 K/V heads, no
+    # rotary), 23 expert blocks — as 29 trunk layers: 23 (mixer, experts)
+    # and 6 (mamba, none), every width as published, the whole vocabulary.
+    # The router scores all 128 experts; this chip HOLDS 32 of them — one
+    # chip's share of a four-chip host whose chips divide each block's
+    # experts four ways and replicate the rest (benchmarks/configs/
+    # nemotron-3-nano-30b-a3b.json has the cut). `intermediate_size` is the
+    # routed expert's width (`moe_intermediate_size`); rope_theta is the
+    # published key and no layer uses it (rope=False).
+    "nemotron-3-nano-30b-a3b": HybridConfig(
+        vocab_size=131072, hidden_size=2688, num_layers=52, num_heads=32,
+        num_kv_heads=2, intermediate_size=1856, head_dim=128,
+        rope_theta=10000.0, rms_eps=1e-5, max_position=262144,
+        hidden_act="relu2", gated_ffn=False,
+        num_experts=128, num_experts_per_tok=6, experts_held=(0, 32),
+        shared_intermediate_size=3712,
+        **dict(zip(("layer_types", "ffn_layout"), pair_blocks(
+            "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"))),
+        mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+        mamba_d_conv=4, mamba_chunk_size=128, mamba_n_groups=8, rope=False,
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=2.5, router_norm_eps=1e-20,
     ),
     "gemma-7b": ModelConfig(
         vocab_size=256000, hidden_size=3072, num_layers=28, num_heads=16,
@@ -913,6 +1062,11 @@ def count_latent(vector: jnp.ndarray, lengths: jnp.ndarray,
 # the ring), once a forward — not a layer; and the steps whose write came
 # back to row 0 of a full ring.
 WINDOW_COUNTS = ("decode_steps", "full_rows", "ring_rows", "ring_wraps")
+# What a model that holds a SHARE of its experts counts behind the per-expert
+# pairs: (expert layer, held expert) hits — a held expert that some VALID
+# pair of a forward fell on — which is what the routed form's kernel must
+# read, an expert's matrices a hit (models/moe.py; `stats.engine.moe`).
+HELD_COUNTS = ("expert_hits",)
 
 
 def count_window(vector: jnp.ndarray, lengths: jnp.ndarray,
@@ -1835,6 +1989,8 @@ def _act(x: jnp.ndarray, config: ModelConfig) -> jnp.ndarray:
         return jax.nn.gelu(x, approximate=True)
     if config.hidden_act == "relu":
         return jax.nn.relu(x)
+    if config.hidden_act == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.silu(x)
 
 
@@ -2447,6 +2603,120 @@ def _window_config_from_hf(hf: dict[str, Any]) -> "HybridConfig":
     )
 
 
+def hf_config_nemotron_h(config: "HybridConfig") -> dict:
+    """The config as nemotron_h's published `config.json` keys (what
+    `_nemotron_h_config_from_hf` reads back, and what the plain reference,
+    benchmarks/reference/nemotron_h_decoder.py, is given). Where a share of
+    the experts is held, `n_routed_experts` is the HELD count — the
+    benchmark configuration file's convention — beside `experts_routed_over`
+    and `experts_held`, keys of ours that a published config.json lacks."""
+    c = config
+    out = {
+        "architectures": ["NemotronHForCausalLM"],
+        "model_type": "nemotron_h",
+        "vocab_size": c.vocab_size, "hidden_size": c.hidden_size,
+        "num_hidden_layers": c.num_blocks,
+        "hybrid_override_pattern": blocks_of(c),
+        "num_attention_heads": c.num_heads,
+        "num_key_value_heads": c.num_kv_heads, "head_dim": c.dim_per_head,
+        "intermediate_size": c.intermediate_size,
+        "moe_intermediate_size": c.intermediate_size,
+        "moe_shared_expert_intermediate_size": c.shared_intermediate_size,
+        "n_routed_experts": c.experts_here, "n_shared_experts": 1,
+        "num_experts_per_tok": c.num_experts_per_tok,
+        "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": c.routed_scaling_factor,
+        "mlp_hidden_act": c.hidden_act, "mamba_hidden_act": "silu",
+        "mamba_num_heads": c.mamba_n_heads,
+        "mamba_head_dim": c.mamba_d_head,
+        "ssm_state_size": c.mamba_d_state, "conv_kernel": c.mamba_d_conv,
+        "n_groups": c.mamba_n_groups, "chunk_size": c.mamba_chunk_size,
+        "use_conv_bias": True, "mamba_proj_bias": False,
+        "attention_bias": False, "mlp_bias": False, "use_bias": False,
+        "rope_theta": c.rope_theta, "norm_eps": c.rms_eps,
+        "layer_norm_epsilon": c.rms_eps,
+        "tie_word_embeddings": c.tie_embeddings,
+        "max_position_embeddings": c.max_position,
+    }
+    if c.experts_held is not None:
+        out["experts_routed_over"] = c.num_experts
+        out["experts_held"] = list(c.experts_held)
+    return out
+
+
+def _nemotron_h_config_from_hf(hf: dict[str, Any]) -> "HybridConfig":
+    """HF `nemotron_h` (NVIDIA-Nemotron-3-Nano): blocks of one sub-layer
+    each, paired into trunk layers by `pair_blocks`; Mamba-2 with
+    `n_groups` groups; GQA without rotary (`rope_theta` is carried and
+    unused); ungated relu2 experts chosen by deepseek_v3's sigmoid router
+    at one group, plus an ungated shared expert. `experts_routed_over` and
+    `experts_held`, where the file has them (a benchmark configuration's
+    share of a deployment; no published config.json does), say that
+    `n_routed_experts` counts the experts held HERE."""
+    for key, want in (("mlp_hidden_act", "relu2"), ("mamba_hidden_act",
+                                                    "silu")):
+        if hf.get(key, want) != want:
+            raise ValueError(f"nemotron_h with {key} {hf[key]!r} is not "
+                             f"implemented")
+    for key in ("attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias"):
+        if hf.get(key):
+            raise ValueError(f"nemotron_h with {key} is not implemented")
+    if not hf.get("use_conv_bias", True):
+        raise ValueError("nemotron_h without use_conv_bias is not "
+                         "implemented")
+    if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+        raise ValueError("nemotron_h with group-limited routing (n_group, "
+                         "topk_group) is not implemented")
+    if not hf.get("norm_topk_prob", True):
+        raise ValueError("nemotron_h with norm_topk_prob false is not "
+                         "implemented")
+    if hf.get("n_shared_experts", 1) != 1:
+        raise ValueError("nemotron_h with other than one shared expert is "
+                         "not implemented")
+    if hf.get("time_step_limit"):
+        raise ValueError("nemotron_h with a time_step_limit is not "
+                         "implemented")
+    pattern = hf["hybrid_override_pattern"]
+    if len(pattern) != hf["num_hidden_layers"]:
+        raise ValueError(f"hybrid_override_pattern names {len(pattern)} "
+                         f"blocks, num_hidden_layers "
+                         f"{hf['num_hidden_layers']}")
+    types, ffns = pair_blocks(pattern)
+    held = hf.get("experts_held")
+    routed_over = hf.get("experts_routed_over", hf["n_routed_experts"])
+    if held is not None and held[1] != hf["n_routed_experts"]:
+        raise ValueError(f"experts_held {held!r} and n_routed_experts "
+                         f"{hf['n_routed_experts']} (the count held) differ")
+    return HybridConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        # the routed expert's width
+        intermediate_size=hf["moe_intermediate_size"],
+        head_dim=hf.get("head_dim"),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_eps=hf.get("norm_eps", hf.get("layer_norm_epsilon", 1e-5)),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        max_position=hf.get("max_position_embeddings", 8192),
+        hidden_act="relu2", gated_ffn=False,
+        num_experts=routed_over,
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        experts_held=None if held is None else tuple(held),
+        shared_intermediate_size=hf["moe_shared_expert_intermediate_size"],
+        layer_types=types, ffn_layout=ffns,
+        mamba_n_heads=hf["mamba_num_heads"],
+        mamba_d_head=hf["mamba_head_dim"],
+        mamba_d_state=hf["ssm_state_size"],
+        mamba_d_conv=hf.get("conv_kernel", 4),
+        mamba_chunk_size=hf.get("chunk_size", 128),
+        mamba_n_groups=hf.get("n_groups", 1), rope=False,
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        router_norm_eps=1e-20,
+    )
+
+
 def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
     """Build a ModelConfig from an HF config.json dict (llama/mistral/
     qwen2/mixtral shapes; mixtral's num_local_experts selects MoEConfig)."""
@@ -2609,10 +2879,10 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
                                    index_head_dim=sa["indexer_head_dim"]),
             hf_block="qwen3_moe", init_fan_in=True,
         )
+    if hf.get("model_type") == "nemotron_h":
+        return _nemotron_h_config_from_hf(hf)
     if hf.get("model_type") == "granitemoehybrid":
         types = tuple(hf["layer_types"])
-        if hf.get("mamba_n_groups", 1) != 1:
-            raise ValueError("mamba_n_groups other than 1 is not implemented")
         return HybridConfig(
             vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
             num_layers=hf["num_hidden_layers"],
@@ -2634,6 +2904,7 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
             mamba_d_state=hf["mamba_d_state"],
             mamba_d_conv=hf.get("mamba_d_conv", 4),
             mamba_chunk_size=hf.get("mamba_chunk_size", 256),
+            mamba_n_groups=hf.get("mamba_n_groups", 1),
             embedding_multiplier=hf.get("embedding_multiplier", 1.0),
             residual_multiplier=hf.get("residual_multiplier", 1.0),
             attention_multiplier=hf.get("attention_multiplier"),

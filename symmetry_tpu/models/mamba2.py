@@ -1,16 +1,23 @@
 """Mamba-2 mixer (state-space duality layer): one step for decode, a chunked
 form for prefill, both reading and writing a per-slot recurrent state.
 
-The layer (HF `GraniteMoeHybridMambaLayer` / `Mamba2Mixer`, one group):
+The layer (HF `GraniteMoeHybridMambaLayer` / `Mamba2Mixer`; G groups of B
+and C — 1 for granitemoehybrid, `n_groups` for nemotron_h — head h reading
+group g(h) = h // (H / G)):
 
-    [z | xBC | dt] = u @ in_proj           widths H*P | H*P + 2N | H
+    [z | xBC | dt] = u @ in_proj           widths H*P | H*P + 2GN | H
     xBC = silu(causal depthwise conv over K taps, with bias)
-    [x | B | C] = xBC                      x as [H, P]
+    [x | B | C] = xBC                      x as [H, P], B and C as [G, N]
     D_t = softplus(dt + dt_bias);  a_t = exp(-D_t * exp(A_log))     per head
-    S_t = a_t * S_{t-1} + D_t * x_t (outer) B_t                     [H, P, N]
-    y_t = S_t C_t + D * x_t
-    y = rms_norm(y * silu(z)) * gate_norm  over all H*P channels, gate first
+    S_t[h] = a_t S_{t-1}[h] + D_t x_t[h] (outer) B_t[g(h)]          [H, P, N]
+    y_t[h] = S_t[h] C_t[g(h)] + D x_t[h]
+    y = rms_norm(y * silu(z)) * gate_norm  gate first; the mean square over
+                                           EACH GROUP's H*P/G channels
     out = y @ out_proj
+
+At one group every line below is the one-group form it was (granite's
+lowered programs do not change); the grouped forms view the heads as
+[G, H / G] and carry the group through every product.
 
 What a slot keeps between calls (models/llama.py KVCache): `ssm` [B, H, P, N]
 float32 — the state S after the slot's last valid token — and `conv`
@@ -59,9 +66,10 @@ HIGHEST = jax.lax.Precision.HIGHEST
 def sizes(config) -> dict:
     h, p, n = (config.mamba_n_heads, config.mamba_d_head,
                config.mamba_d_state)
-    return {"H": h, "P": p, "N": n, "K": config.mamba_d_conv,
-            "inner": h * p, "conv": h * p + 2 * n,
-            "proj": 2 * h * p + 2 * n + h}
+    g = getattr(config, "mamba_n_groups", 1)
+    return {"H": h, "P": p, "N": n, "K": config.mamba_d_conv, "G": g,
+            "inner": h * p, "conv": h * p + 2 * g * n,
+            "proj": 2 * h * p + 2 * g * n + h}
 
 
 def _in_proj(u: jnp.ndarray, w) -> jnp.ndarray:
@@ -87,12 +95,31 @@ def _decay(dt: jnp.ndarray, lp: dict):
 
 
 def _gate_out(y: jnp.ndarray, gate: jnp.ndarray, lp: dict, eps: float,
-              dtype) -> jnp.ndarray:
-    """y, gate [..., inner] float32 -> the layer's output in `dtype`."""
+              dtype, groups: int = 1) -> jnp.ndarray:
+    """y, gate [..., inner] float32 -> the layer's output in `dtype`; the
+    norm's mean square is over each of the `groups` groups' channels."""
     y = y * jax.nn.silu(gate)
-    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
-                          + eps) * lp["gate_norm"].astype(jnp.float32)
+    if groups == 1:
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                              + eps) * lp["gate_norm"].astype(jnp.float32)
+    else:
+        per = y.reshape(*y.shape[:-1], groups, y.shape[-1] // groups)
+        per = per * jax.lax.rsqrt(
+            jnp.mean(jnp.square(per), axis=-1, keepdims=True) + eps)
+        y = per.reshape(y.shape) * lp["gate_norm"].astype(jnp.float32)
     return qmatmul(y.astype(dtype), lp["out_proj"])
+
+
+def _split_bc(xbc: jnp.ndarray, z: dict):
+    """The convolved [.., conv] -> (x [.., H, P], B, C): B and C [.., N]
+    at one group, [.., G, N] at more."""
+    inner, gn = z["inner"], z["G"] * z["N"]
+    x = xbc[..., :inner].reshape(*xbc.shape[:-1], z["H"], z["P"])
+    b, c = xbc[..., inner:inner + gn], xbc[..., inner + gn:]
+    if z["G"] > 1:
+        b = b.reshape(*b.shape[:-1], z["G"], z["N"])
+        c = c.reshape(*c.shape[:-1], z["G"], z["N"])
+    return x, b, c
 
 
 def step_form(config, itemsize: int = 4) -> dict:
@@ -103,12 +130,26 @@ def step_form(config, itemsize: int = 4) -> dict:
     z = sizes(config)
     return ssm_step.step_form(
         z["H"], z["P"], z["N"], itemsize, interpret=interpret_mode(),
-        otherwise="step (jnp), two passes over the state")
+        otherwise="step (jnp), two passes over the state",
+        **({"groups": z["G"]} if z["G"] > 1 else {}))
 
 
 def recurrence(ssm, a, dx, b, c, skip):
     """One position of the recurrence in jnp: ssm [B, H, P, N], a [B, H],
-    dx / skip [B, H, P], b / c [B, N], float32 -> (y [B, H, P], ssm)."""
+    dx / skip [B, H, P], b / c [B, N] (or [B, G, N]: a group of H / G
+    heads reads its own), float32 -> (y [B, H, P], ssm)."""
+    if b.ndim == 3:
+        B, H, P, N = ssm.shape
+        G = b.shape[1]
+        s = ssm.reshape(B, G, H // G, P, N)
+        y = (a.reshape(B, G, -1)[..., None] * jnp.einsum(
+            "bgrpn,bgn->bgrp", s, c, precision=HIGHEST)
+             + dx.reshape(B, G, -1, P)
+             * jnp.sum(b * c, axis=-1)[:, :, None, None]).reshape(B, H, P)
+        new = (a.reshape(B, G, -1)[..., None, None] * s
+               + dx.reshape(B, G, -1, P)[..., None]
+               * b[:, :, None, None, :])
+        return y + skip, new.reshape(ssm.shape).astype(ssm.dtype)
     # y_t = S_t C = a (S_{t-1} C) + (dt x) (B . C): reads the OLD state
     y = (a[..., None] * jnp.einsum("bhpn,bn->bhp", ssm, c,
                                    precision=HIGHEST)
@@ -130,9 +171,7 @@ def step_at(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, layer,
     xbc = jax.nn.silu(
         jnp.sum(window * lp["conv_w"].astype(jnp.float32)[:, None, :],
                 axis=0) + lp["conv_b"].astype(jnp.float32))
-    x = xbc[:, :z["inner"]].reshape(B, z["H"], z["P"])
-    b = xbc[:, z["inner"]:z["inner"] + z["N"]]
-    c = xbc[:, z["inner"] + z["N"]:]
+    x, b, c = _split_bc(xbc, z)                                 # x [B, H, P]
     delta, log_a = _decay(dt, lp)                               # [B, H]
     a = jnp.exp(log_a)
     dx = delta[..., None] * x                                   # [B, H, P]
@@ -146,7 +185,7 @@ def step_at(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, layer,
             a, dx, b, c, skip)
         ssm = ssm.at[layer].set(new)
     out = _gate_out(y.reshape(B, z["inner"]), gate, lp, config.rms_eps,
-                    u.dtype)
+                    u.dtype, z["G"])
     return out, ssm, window[1:].astype(conv.dtype)
 
 
@@ -161,6 +200,8 @@ def step(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, conv: jnp.ndarray,
 def _chunk(x, delta, log_a, b, c, state):
     """One chunk of the dual form. x [B, Q, H, P], delta / log_a [B, Q, H],
     b / c [B, Q, N], state [B, H, P, N] -> (y [B, Q, H, P], state)."""
+    if b.ndim == 4:
+        return _chunk_grouped(x, delta, log_a, b, c, state)
     Q = x.shape[1]
     cum = jnp.cumsum(log_a, axis=1)                             # inclusive
     dx = delta[..., None] * x                                   # [B, Q, H, P]
@@ -184,6 +225,30 @@ def _chunk(x, delta, log_a, b, c, state):
     return y, state
 
 
+def _chunk_grouped(x, delta, log_a, b, c, state):
+    """`_chunk` with b / c [B, Q, G, N]: the same three products with the
+    heads viewed as [G, H / G] and the group carried through."""
+    B, Q, H, P = x.shape
+    G, N = b.shape[2:]
+    cum = jnp.cumsum(log_a, axis=1)                             # inclusive
+    dx = (delta[..., None] * x).reshape(B, Q, G, H // G, P)
+    scores = jnp.einsum("btgn,bsgn->bgts", c, b, precision=HIGHEST)
+    cum_h = jnp.moveaxis(cum, 1, 2)                             # [B, H, Q]
+    seg = cum_h[..., :, None] - cum_h[..., None, :]             # [B, H, t, s]
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), seg,
+                              -jnp.inf)).reshape(B, G, H // G, Q, Q)
+    y = jnp.einsum("bgrts,bsgrp->btgrp", scores[:, :, None] * decay, dx,
+                   precision=HIGHEST)
+    s = state.reshape(B, G, H // G, P, N)
+    y = y + jnp.exp(cum).reshape(B, Q, G, -1)[..., None] * jnp.einsum(
+        "bgrpn,btgn->btgrp", s, c, precision=HIGHEST)
+    to_end = jnp.exp(cum[:, -1:, :] - cum).reshape(B, Q, G, -1)
+    s = (jnp.exp(cum[:, -1, :]).reshape(B, G, -1)[..., None, None] * s
+         + jnp.einsum("bsgrp,bsgn->bgrpn", to_end[..., None] * dx, b,
+                      precision=HIGHEST))
+    return y.reshape(B, Q, H, P), s.reshape(state.shape)
+
+
 def chunked(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, conv: jnp.ndarray,
             seq_lens: jnp.ndarray, config
             ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -203,10 +268,7 @@ def chunked(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, conv: jnp.ndarray,
     # the row's last K-1 valid inputs: padded[seq_len .. seq_len + K-2]
     tail_at = seq_lens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
     tail = jnp.take_along_axis(padded, tail_at[..., None], axis=1)
-    xbc = jax.nn.silu(conv_out)
-    x = xbc[..., :z["inner"]].reshape(B, S, z["H"], z["P"])
-    b = xbc[..., z["inner"]:z["inner"] + z["N"]]
-    c = xbc[..., z["inner"] + z["N"]:]
+    x, b, c = _split_bc(jax.nn.silu(conv_out), z)               # [B, S, H, P]
     delta, log_a = _decay(dt, lp)                               # [B, S, H]
     valid = (jnp.arange(S, dtype=jnp.int32)[None, :]
              < seq_lens[:, None])[..., None]
@@ -233,6 +295,6 @@ def chunked(u: jnp.ndarray, lp: dict, ssm: jnp.ndarray, conv: jnp.ndarray,
         y = jnp.moveaxis(y, 0, 1).reshape(B, S + pad, z["H"], z["P"])[:, :S]
     y = y + lp["D"].astype(jnp.float32)[:, None] * x
     out = _gate_out(y.reshape(B, S, z["inner"]), gate, lp, config.rms_eps,
-                    u.dtype)
+                    u.dtype, z["G"])
     return (out, state.astype(ssm.dtype),
             jnp.moveaxis(tail, 1, 0).astype(conv.dtype))

@@ -66,8 +66,26 @@ uneven. Without `tp_mesh`, or with an `expert` axis > 1, the same function
 is traced bare and GSPMD partitions it from the shardings (exact; not
 measured on a chip — PERF.md).
 
+A chip's share of the experts (`config.experts_held` = (first, count);
+nemotron_h's cell: 32 of 128, one chip of a four-chip host that divides each
+layer's experts four ways): the router keeps its width and its top k, and
+the expert leaves hold the `count` held experts alone. A pair whose expert
+is not held is dropped BEFORE the routed form's sort — it is given the
+group past the last, no group size counts it, and the grouped matmul never
+visits its row — and masked out of the mixture's gates; the gates are those
+of the routing over ALL experts, never renormalised over the held ones, so
+the parts the shares compute add up to the uncut layer
+(tests/test_nemotron_h.py); a token with no held expert gets the shared
+expert alone. Nothing stands in for the absent chips or their exchange.
+Every other model holds all it routes over and traces what it traced.
+
+Ungated experts (`config.gated_ffn` False; nemotron_h's relu2): an expert —
+and the shared one — is act(x W_up) W_down: the `wg` / `sg` leaves are
+absent and both forms skip the gate product.
+
 Counting: `moe_mlp` also returns the per-expert number of VALID pairs it
-computed ([experts] int32; padded prompt positions are left out), which
+computed ([experts] int32; padded prompt positions are left out; a share
+appends the number of held experts hit: `_count_pairs`), which
 `models/llama.py _layer` adds to `KVCache.expert_pairs` where the cache
 carries that counter (the engine's does; `stats.engine.moe`).
 """
@@ -181,15 +199,41 @@ ROUTED_MIN_TOKENS = 1024
 # 128 (the tie keeps the mixture, as granite's and kanana's did) and loses
 # by 1.5x at 256: the band is [32, 256) — decode keeps the mixture, every
 # prefill of the draft cell (1,024 tokens and up) is routed.
+# A SHARE of the experts routed over is keyed by what is HELD and measured,
+# (held, k, routed over) — `moe_route(held=)`: with a share, "experts" alone
+# would not say which. 32 held of 128, top 6, at expert width 1,856 stored
+# as 1,920, TWO matrices (nemotron-3-nano-30b-a3b; `--shape 128,6,2688,1920
+# --held 32 --ungated relu2`; PERF.md, PR 61; floor 0.40 for the 32 held
+# experts' 330 MB): 16 tokens 3.82 / 0.31 / 0.52, 32: 5.21 / 0.44 / 0.52,
+# 64: 7.06 / 0.55 / 0.52, 128: 7.53 / 0.59 / 0.55, 256: 8.42 / 0.67 / 0.96,
+# 512: 8.79 / 0.82 / 1.94, 1,024: 9.92 / 1.21 / 4.48, 2,048: 12.84 / 2.46 /
+# 7.72, 8,192: 30.67 / 11.76 / 30.79. A quarter of the pairs fall on held
+# experts (24 of 96 at 16 tokens: ~17 of the 32 hit, and the kernel reads
+# those alone; 96 of 384 at decode's 64 slots: ~30 hit), so the kernel wins
+# under 64 tokens and from 256; between, the tool has the mixture ahead by
+# 5-6% (0.52 against 0.55 at 64). IN THE TRUNK that reading does not hold:
+# there XLA copies each layer's two [32, 2688, 1920] slices OUT of the
+# layers' stack before the mixed dot (375 MB of temporaries in the decode
+# program compiled for a v5e; the cell's first traced run read 46.5 ms a
+# decode step, ~1.4 ms an expert layer, where the tool's scan of two layers
+# read 0.52), and the kernel reads the stack where it lies. So no band: the
+# share is routed at every size (as (512, 10) is), and the mixture's slice
+# copy is the cell's first `perf_opt` question, not this entry's.
 ROUTED_FROM = {(72, 10): (0, 256), (512, 10): (0, 1), (128, 8): (64, 128),
-               (32, 4): (0, 256), (128, 6): (0, 128), (64, 6): (32, 256)}
+               (32, 4): (0, 256), (128, 6): (0, 128), (64, 6): (32, 256),
+               (32, 6, 128): (0, 1)}
 
 
-def moe_route(n_tokens: int, experts: int = 8, k: int = 2) -> str:
+def moe_route(n_tokens: int, experts: int = 8, k: int = 2,
+              held: int | None = None) -> str:
     """The form a program of `n_tokens` tokens takes, from the shape alone:
     the dense mixture inside the measured band of this routing shape (or
-    under mixtral's crossing where none was measured), routed outside it."""
-    lo, hi = ROUTED_FROM.get((experts, k), (0, ROUTED_MIN_TOKENS))
+    under mixtral's crossing where none was measured), routed outside it.
+    `held`: the experts this chip holds where that is a share of the
+    `experts` routed over — the key is then (held, k, experts): what is
+    held and measured."""
+    key = (experts, k) if held in (None, experts) else (held, k, experts)
+    lo, hi = ROUTED_FROM.get(key, (0, ROUTED_MIN_TOKENS))
     return "dense-mixture" if lo <= n_tokens < hi else "routed"
 
 
@@ -238,18 +282,27 @@ def routing_of(config, lp: dict, route_from=None) -> dict:
         out["route_from"] = route_from
     if config.hidden_act != "silu":
         out["act"] = config.hidden_act
+    if getattr(config, "experts_held", None) is not None:
+        out["held"] = tuple(config.experts_held)
     return out
 
 
 ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+        "relu2": lambda x: jnp.square(jax.nn.relu(x)),
         "gelu_tanh": lambda x: jax.nn.gelu(x, approximate=True)}
 
 
 def _route(x, router, k: int, routing) -> tuple[jnp.ndarray, jnp.ndarray]:
     """`route_top_k` under `routing_of`'s answer: the router reads
     `route_from` where the layer gives one, else the FFN's input `x`."""
-    kw = {name: v for name, v in (routing or {}).items() if name != "act"}
+    kw = {name: v for name, v in (routing or {}).items()
+          if name not in ("act", "held")}
     return route_top_k(kw.pop("route_from", x), router, k, **kw)
+
+
+def _held(routing) -> tuple[int, int] | None:
+    """(first, count) of the experts held where that is a share."""
+    return (routing or {}).get("held")
 
 
 def _act(routing):
@@ -310,22 +363,45 @@ def _routed_ffn(x, valid, router, wg, wu, wd, k: int, at=None,
     X = router.shape[-1]
     gates, experts = _route(x, router, k, routing)        # [T, k]
     flat_expert = experts.reshape(-1)                     # [T*k]
+    held = _held(routing)
+    if held is not None:
+        # a share: the held experts are groups 0 .. count - 1 of the local
+        # stacks; a pair on an absent expert takes the group past the last,
+        # sorts behind every held pair and is in no group size — dropped
+        # here, before the sort — and its gate is zero in the combine
+        first, X = held
+        all_pairs = _count_pairs(experts, valid, router.shape[-1], held)
+        mine = (experts >= first) & (experts < first + X)
+        gates = jnp.where(mine, gates, 0.0)
+        flat_expert = jnp.where(mine, experts - first, X).reshape(-1)
     # Stable sort: pairs of one expert keep token order, so the result
     # does not depend on how the sort breaks ties.
     order = jnp.argsort(flat_expert, stable=True)         # sorted -> pair
     row_expert = jnp.take(flat_expert, order)
     onehot = flat_expert[:, None] == jnp.arange(X, dtype=jnp.int32)
     group_sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)
-    pairs = jnp.sum(onehot & jnp.repeat(valid, k)[:, None], axis=0,
-                    dtype=jnp.int32)
+    if held is None:
+        pairs = jnp.sum(onehot & jnp.repeat(valid, k)[:, None], axis=0,
+                        dtype=jnp.int32)
+    else:   # counted over all the router scores; the scale gather in range
+        pairs, row_expert = all_pairs, jnp.minimum(row_expert, X - 1)
 
     def grouped(rows, w, name):
         return _grouped_matmul(rows, w, group_sizes, row_expert,
                                at and (at[0][name], at[1]))
 
     rows = jnp.take(x, order // k, axis=0)                # [T*k, D]
-    h = (_act(routing)(grouped(rows, wg, "wg")) * grouped(rows, wu, "wu"))
+    if wg is None:          # ungated: act(x W_up) W_down
+        h = _act(routing)(grouped(rows, wu, "wu"))
+    else:
+        h = (_act(routing)(grouped(rows, wg, "wg"))
+             * grouped(rows, wu, "wu"))
     y = grouped(h.astype(x.dtype), wd, "wd")
+    if held is not None:
+        # rows past the held pairs were never written (ops/gmm.py): what
+        # they hold is no number, and 0 x that is none either
+        y = jnp.where((jnp.arange(T * k, dtype=jnp.int32)
+                       < jnp.sum(group_sizes))[:, None], y, 0.0)
 
     # Un-sort by a gather (the inverse permutation), then the gated sum
     # over each token's k rows in float32: no scatter-add, so the sum has
@@ -355,6 +431,19 @@ def _routed_ffn(x, valid, router, wg, wu, wd, k: int, at=None,
         0, k, add, jnp.zeros((T, y.shape[-1]), jnp.float32)), pairs
 
 
+def _count_pairs(experts, valid, n_experts: int, held) -> jnp.ndarray:
+    """What a SHARE counts: the valid (token, expert) pairs per expert of
+    the `n_experts` the router scores, then (models/llama.py HELD_COUNTS)
+    how many of the held experts a valid pair fell on — the experts this
+    forward has to read: experts [T, k], valid [T] -> [n_experts + 1]
+    int32."""
+    onehot = experts[..., None] == jnp.arange(n_experts, dtype=jnp.int32)
+    pairs = jnp.sum(onehot & valid[:, None, None], axis=(0, 1),
+                    dtype=jnp.int32)
+    hits = jnp.sum(pairs[held[0]:held[0] + held[1]] > 0, dtype=jnp.int32)
+    return jnp.concatenate([pairs, hits[None]])
+
+
 def _experts_dot(x: jnp.ndarray, w) -> jnp.ndarray:
     """[T, A] @ per-expert [X, A, F] -> [T, X, F], every expert at once."""
     if isinstance(w, QuantizedTensor):
@@ -368,11 +457,22 @@ def _dense_mixture(x, valid, router, wg, wu, wd, k: int, routing=None):
     """Same contract as `_routed_ffn`; every expert computes every token."""
     X = router.shape[-1]
     gates, experts = _route(x, router, k, routing)        # [T, k]
-    onehot = experts[..., None] == jnp.arange(X, dtype=jnp.int32)
+    held = _held(routing)
+    if held is None:
+        onehot = experts[..., None] == jnp.arange(X, dtype=jnp.int32)
+    else:   # a share: the gates of the held experts' columns alone
+        onehot = experts[..., None] == held[0] + jnp.arange(
+            held[1], dtype=jnp.int32)
     dense_gates = jnp.sum(jnp.where(onehot, gates[..., None], 0.0), axis=1)
-    pairs = jnp.sum(onehot & valid[:, None, None], axis=(0, 1),
-                    dtype=jnp.int32)
-    h = _act(routing)(_experts_dot(x, wg)) * _experts_dot(x, wu)  # [T, X, F]
+    if held is None:
+        pairs = jnp.sum(onehot & valid[:, None, None], axis=(0, 1),
+                        dtype=jnp.int32)
+    else:
+        pairs = _count_pairs(experts, valid, X, held)
+    if wg is None:          # ungated: act(x W_up) W_down
+        h = _act(routing)(_experts_dot(x, wu))                    # [T, X, F]
+    else:
+        h = _act(routing)(_experts_dot(x, wg)) * _experts_dot(x, wu)
     if isinstance(wd, QuantizedTensor):
         y = jax.lax.dot_general(h, wd.q, (((2,), (1,)), ((1,), (0,))),
                                 preferred_element_type=jnp.float32)
@@ -387,7 +487,9 @@ def _expert_ffn(x, valid, router, wg, wu, wd, k: int, at=None,
                 routing=None):
     """x [T, D] -> (y [T, D] float32, valid pairs [X]) by the form this
     token count takes; `at` and `routing` as `_routed_ffn`'s."""
-    if moe_route(x.shape[0], router.shape[-1], k) == "routed":
+    held = _held(routing)
+    if moe_route(x.shape[0], router.shape[-1], k,
+                 held and held[1]) == "routed":
         return _routed_ffn(x, valid, router, wg, wu, wd, k, at, routing)
     return _dense_mixture(x, valid, router, wg, wu, wd, k, routing)
 
@@ -420,6 +522,12 @@ def moe_layout(tp_mesh, ffn_width: int) -> str:
 EXPERT_LEAVES = ("wg", "wu", "wd")
 
 
+def expert_leaves(lp: dict) -> tuple[str, ...]:
+    """The expert leaves a layer (or a stack of layers) has: all three, or
+    `wu` and `wd` alone where the experts are ungated."""
+    return tuple(name for name in EXPERT_LEAVES if name in lp)
+
+
 def whole_stacks(layers: dict, tp_mesh=None) -> dict | None:
     """`moe_mlp`'s `stack` leaves for a scan over `layers` (models/llama.py
     `run_layers`): the three expert leaves [L, X, A, F] as they lie, so that
@@ -428,7 +536,7 @@ def whole_stacks(layers: dict, tp_mesh=None) -> dict | None:
     where the expert FFN is partitioned and reads the layer's slice."""
     if tp_mesh is not None:
         return None
-    return {name: layers[name] for name in EXPERT_LEAVES}
+    return {name: layers[name] for name in expert_leaves(layers)}
 
 
 def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
@@ -451,7 +559,7 @@ def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
         valid = (jnp.arange(S, dtype=jnp.int32)[None, :]
                  < seq_lens[:, None]).reshape(B * S)
     xf = x.reshape(B * S, D)
-    args = (xf, valid, lp["router"], lp["wg"], lp["wu"], lp["wd"])
+    args = (xf, valid, lp["router"], lp.get("wg"), lp["wu"], lp["wd"])
 
     n = _model_shards(tp_mesh, config.intermediate_size)
     routing = routing_of(config, lp, None if route_from is None
@@ -465,7 +573,7 @@ def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
         # this layer alone as a stack of one (a reshape)
         stacks, layer = stack if stack is not None else (
             jax.tree.map(lambda a: a[None],
-                         {name: lp[name] for name in EXPERT_LEAVES}), 0)
+                         {name: lp[name] for name in expert_leaves(lp)}), 0)
         y, pairs = _expert_ffn(*args, k, (stacks, jnp.int32(layer)),
                                routing)
     elif n == 1:
@@ -505,4 +613,8 @@ def moe_mlp(x: jnp.ndarray, lp: dict, config, seq_lens=None,
             shared = shared * jax.nn.sigmoid(jnp.dot(
                 xf, lp["sgate"], preferred_element_type=jnp.float32))
         y = y + shared
+    elif "su" in lp:
+        # the UNGATED shared expert: act(x W_up) W_down, every token,
+        # weight 1 — computed on every chip alike, whatever experts it holds
+        y = y + qmatmul(_act(routing)(qmatmul(xf, lp["su"])), lp["sd"])
     return y.astype(x.dtype).reshape(B, S, D), pairs

@@ -96,38 +96,48 @@ GDN_NAME = "gdn_step"
 
 
 def head_tile(n_heads: int, d_head: int, d_state: int, itemsize: int = 4,
-              *, interpret: bool = False) -> int | None:
+              *, interpret: bool = False, groups: int = 1) -> int | None:
     """Heads of one slot a grid step moves at this state shape, or None
     where the kernel has none: a [P, N] plane of a head has to be whole
     (8, 128) tiles for Mosaic (any shape interprets). The largest divisor
-    of the head count whose tile is at most TILE_BYTES."""
+    of the head count whose tile is at most TILE_BYTES — and, where the
+    heads read `groups` groups of B and C, is whole groups or lies inside
+    one (at one group every divisor does)."""
     if not interpret and (d_state % LANES or d_head % SUBLANES):
         return None
     most = max(1, TILE_BYTES // (d_head * d_state * itemsize))
+    per = n_heads // groups
     return next(t for t in range(min(n_heads, most), 0, -1)
-                if n_heads % t == 0)
+                if n_heads % t == 0 and (t % per == 0 or per % t == 0))
 
 
 def step_form(n_heads: int, d_head: int, d_state: int, itemsize: int, *,
-              interpret: bool, otherwise: str) -> dict:
+              interpret: bool, otherwise: str, groups: int = 1) -> dict:
     """What a recurrent kind's `step_form` reports and its `step_at` routes
     by: "pallas" with the `head_tile` ("pallas-interpret": the same kernel
-    on the CPU backend), or `otherwise` — the kind's name for its jnp
+    on the CPU backend) and, where there is more than one, the `groups` of
+    B and C the heads read, or `otherwise` — the kind's name for its jnp
     recurrence — where the kernel has no geometry for the state."""
-    tile = head_tile(n_heads, d_head, d_state, itemsize, interpret=interpret)
+    tile = head_tile(n_heads, d_head, d_state, itemsize, interpret=interpret,
+                     groups=groups)
     if tile is None:
         return {"form": otherwise}
     return {"form": "pallas-interpret" if interpret else "pallas",
-            "head_tile": tile}
+            "head_tile": tile, **({"groups": groups} if groups > 1 else {})}
 
 
 def _kernel(layer_ref, b_ref, c_ref, dx_ref, skip_ref, a_ref, s_ref,
-            y_ref, s_out_ref, *, group: int):
+            y_ref, s_out_ref, *, group: int, per: int | None = None):
+    """`per` (None: one group of B and C, every head reads row 0): heads a
+    group of B and C serves — head h reads row h // per of the [G, N]
+    blocks. The unrolled `group` of heads is whole groups or inside one
+    (`ssm_step`), so the row of head h0 + j is h0 // per + j // per, the
+    second term static."""
     del layer_ref                                   # addressing only
     tile, P, N = s_ref.shape[2:]
     lanes = dx_ref.shape[-1]
     first = pl.program_id(1) * tile                 # this step's first head
-    b_row, c_row = b_ref[0], c_ref[0]               # [1, N]
+    whole = (b_ref[0], c_ref[0]) if per is None else None   # [1, N] each
     dx_all = dx_ref[0]                              # [P, lanes]
     lane = jax.lax.broadcasted_iota(jnp.int32, (P, lanes), 1)
 
@@ -135,9 +145,13 @@ def _kernel(layer_ref, b_ref, c_ref, dx_ref, skip_ref, a_ref, s_ref,
         h0 = first + g * group
         # bring heads h0 .. h0 + group - 1 to lanes 0 .. group - 1
         dx_g = pltpu.roll(dx_all, (lanes - h0) % lanes, 1)
+        b_row, c_row = whole or (None, None)
         for j in range(group):
             at = g * group + j
             a = a_ref[0, 0, h0 + j]
+            if per is not None and j % per == 0:    # a new group's rows
+                row = pl.ds(h0 // per + j // per, 1)
+                b_row, c_row = b_ref[0, row, :], c_ref[0, row, :]
             s = s_ref[0, 0, at].astype(jnp.float32)             # [P, N]
             read = jnp.sum(s * c_row, axis=-1, keepdims=True)   # [P, 1]
             s_out_ref[0, 0, at] = (
@@ -207,14 +221,18 @@ def ssm_step(
     layer: jnp.ndarray,     # scalar int32: which layer's state steps
     a: jnp.ndarray,         # [B, H] f32 decay of this position
     dx: jnp.ndarray,        # [B, H, P] f32 dt * x
-    b: jnp.ndarray,         # [B, N] f32
-    c: jnp.ndarray,         # [B, N] f32
+    b: jnp.ndarray,         # [B, N] f32, or [B, G, N]: a row a group
+    c: jnp.ndarray,         # [B, N] f32, or [B, G, N]
     skip: jnp.ndarray,      # [B, H, P] f32 D * x
     *,
     tile: int | None = None,    # heads a grid step (None: `head_tile`)
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (y [B, H, P] f32, the stack with layer `layer` stepped)."""
+    """Returns (y [B, H, P] f32, the stack with layer `layer` stepped).
+    With b and c [B, G, N], head h reads row h // (H / G) of each."""
+    if b.ndim == 3:
+        return _ssm_step_grouped(ssm, layer, a, dx, b, c, skip, tile,
+                                 interpret)
     L, B, H, P, N = ssm.shape
     tile = _tile(ssm, tile, interpret, "ssm-step")
     group = next(g for g in range(min(GROUP, tile), 0, -1) if tile % g == 0)
@@ -234,6 +252,48 @@ def ssm_step(
         tile, [row, row, col, col, decay],
         (b[:, None].astype(jnp.float32), c[:, None].astype(jnp.float32),
          columns(dx), columns(skip), a[:, None].astype(jnp.float32)),
+        col, jax.ShapeDtypeStruct((B, P, lanes), jnp.float32),
+        interpret=interpret)
+    return jnp.swapaxes(y[:, :, :H], 1, 2), ssm
+
+
+def _columns(v, lanes: int):
+    """[B, H, P] -> [B, P, lanes] float32: heads on lanes."""
+    return jnp.pad(jnp.swapaxes(v.astype(jnp.float32), 1, 2),
+                   ((0, 0), (0, 0), (0, lanes - v.shape[1])))
+
+
+def _ssm_step_grouped(ssm, layer, a, dx, b, c, skip, tile, interpret):
+    """`ssm_step` with G rows of B and C a slot: the same pass and the same
+    addressing, the [G, N] blocks of a slot resident beside its columns; a
+    head tile and the unrolled group of heads are each whole groups of
+    H / G heads or inside one (at 64 heads in 8 groups: a tile of 64, 16
+    heads unrolled over two groups)."""
+    L, B, H, P, N = ssm.shape
+    G = b.shape[1]
+    per = H // G
+    if tile is None:
+        tile = head_tile(H, P, N, ssm.dtype.itemsize, interpret=interpret,
+                         groups=G)
+    if tile is None or H % tile or (tile % per and per % tile):
+        raise ValueError(f"no ssm-step geometry for a state of {H} heads "
+                         f"of {P} x {N} in {G} groups (tile {tile})")
+    group = next(g for g in range(min(GROUP, tile), 0, -1)
+                 if tile % g == 0 and (g % per == 0 or per % g == 0))
+    lanes = -(-H // LANES) * LANES
+
+    bc = jnp.repeat(jnp.sum(b * c, axis=-1), per, axis=1)       # [B, H]
+    skip = skip + dx * bc[:, :, None]
+    rows = pl.BlockSpec((1, G, N), lambda i, t, lay: (i, 0, 0))
+    col = pl.BlockSpec((1, P, lanes), lambda i, t, lay: (i, 0, 0))
+    decay = pl.BlockSpec((1, 1, H), lambda i, t, lay: (i, 0, 0),
+                         memory_space=pltpu.SMEM)
+    y, ssm = over_stack(
+        functools.partial(_kernel, group=group, per=per), NAME, ssm,
+        address(layer), tile, [rows, rows, col, col, decay],
+        (b.astype(jnp.float32), c.astype(jnp.float32),
+         _columns(dx, lanes), _columns(skip, lanes),
+         a[:, None].astype(jnp.float32)),
         col, jax.ShapeDtypeStruct((B, P, lanes), jnp.float32),
         interpret=interpret)
     return jnp.swapaxes(y[:, :, :H], 1, 2), ssm

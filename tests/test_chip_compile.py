@@ -1024,3 +1024,139 @@ def test_smallthinker_prefill_walks_wide_tiles_and_routes_its_experts(
               if re.search(r"= s8\[(1,)?64,(2560,768|768,2560)\]\S* "
                            r"(copy|dynamic-slice|fusion)\(", line)]
     assert not sliced, sliced[0]
+
+
+def _nemotron_shapes(one_chip, rows, capacity):
+    from symmetry_tpu.models import llama
+
+    cfg = llama.preset("nemotron-3-nano-30b-a3b")
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
+        slice_above=1 << 40))
+    cache = shaped(lambda: llama.init_cache(cfg, rows, capacity,
+                                            jnp.bfloat16, quantized=True))
+    return cfg, params, cache
+
+
+def test_nemotron_decode_step_steps_the_grouped_state_in_place(
+        one_chip, no_cache, monkeypatch):
+    """nemotron-3-nano-30b-a3b's decode trunk at its cell (64 slots x 640,
+    all 52 blocks as 29 trunk layers in 19 runs): the 3.09 GB recurrent
+    state of 23 mamba blocks is donated in, aliased out and stepped where it
+    lies by ONE grouped `ssm_step` call a run of mamba layers (8 rows of B
+    and C a slot, 16 heads unrolled over two groups) and no XLA fusion; the
+    attention blocks (16 queries a K/V head, a row of 256 values) take the
+    decode kernel; the expert layers are ROUTED — two `moe_gmm` calls a run
+    over the 32 HELD experts' stacks where they lie: the mixture had XLA
+    copy each layer's two [32, 2688, 1920] slices out of the stack first
+    (375 MB of temporaries; 46.5 ms a step on the chip, PERF.md PR 61) —
+    and the whole program fits the chip."""
+    from symmetry_tpu.models import hybrid, llama, mamba2, moe
+
+    for module in (llama, mamba2, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    cfg, params, cache = _nemotron_shapes(one_chip, 64, 640)
+    assert cache.ssm.shape == (23, 64, 64, 64, 128)
+    assert cache.k.shape == (6, 64, 640, 2, 128)
+    assert mamba2.step_form(cfg) == {"form": "pallas", "head_tile": 64,
+                                     "groups": 8}
+    assert moe.moe_route(64, 128, 6, 32) == "routed"
+    tok = jax.ShapeDtypeStruct((64, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
+        donate_argnums=(2,)).lower(params, tok, cache).compile()
+    memory = compiled.memory_analysis()
+    state_bytes = 23 * 64 * 64 * 64 * 128 * 4
+    assert memory.alias_size_in_bytes >= state_bytes
+    # the temporaries are NOT the state's: XLA gives this trunk's K/V leaves
+    # (2 int8 heads a position: s8[6, 64, 640, 2, 128]) a (4, 128) tile and
+    # relays 63 MB copies of them out to the head-major view the decode
+    # kernel takes and back, a layer (PERF.md section 7, PR 61: the cell's
+    # first `perf_opt` item; mistral's shards of 2 heads stay head-major)
+    assert memory.temp_size_in_bytes < 3 * state_bytes // 23
+    # weights 9.92 GB + state and K/V 3.27 GB + temporaries: inside 16 GB
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 14.5e9)
+    text = compiled.as_text()
+    runs = hybrid.runs(cfg)
+    mamba_runs = sum(kind == "mamba" for kind, _, _ in runs)
+    attn_runs = sum(kind == "attention" for kind, _, _ in runs)
+    assert (len(runs), mamba_runs, attn_runs) == (19, 13, 6)
+    assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == mamba_runs
+    expert_runs = sum(cfg.ffn_kind(first) == "moe" for _, first, _ in runs)
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 2 * expert_runs
+    assert text.count("tpu_custom_call") == (attn_runs + mamba_runs
+                                             + 2 * expert_runs)
+    shape = r"f32\[23,64,64,64,128\]"
+    whole = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= {shape}\S* (copy|transpose)\(", line)]
+    assert not whole, whole[0]
+    stack = set(re.findall(rf"(%[\w.\-]+)(?: =|:) {shape}", text))
+    fused = [line.strip()[:160] for line in text.splitlines()
+             if " fusion(" in line and (
+                 re.search(shape, line.split(" fusion(")[0])
+                 or stack & set(re.findall(r"%[\w.\-]+",
+                                           line.split(" fusion(")[1])))]
+    assert stack and not fused, fused[:1]
+    # no layer's slice of an expert stack is copied out, no mixture is left
+    assert not re.search(r"= s8\[(1,)?32,(2688,1920|1920,2688)\]", text)
+    assert not re.search(r"\[64,(32|128),\d+\]", text)
+
+
+def test_nemotron_routed_prefill_reads_the_held_stacks_where_they_lie(
+        one_chip, no_cache, monkeypatch):
+    """The cell's widest admission (two rows of bucket 256: 512 tokens, the
+    one dispatch its band routes): the ungated experts are TWO `moe_gmm`
+    calls a run that ends in experts, over the held experts' int8 stacks
+    as they lie — [23, 32, 2688, 1920] / [23, 32, 1920, 2688]: the
+    published 1,856 stored in whole lane tiles (models/hybrid.py
+    `expert_columns`). At 1,856 the chip laid the up-projection's stack
+    out with 2,688 minor and this program held TWO 3.5 GB copies of it for
+    the kernel (16.15 GB of the chip's 15.75: it did not compile). No
+    slice, copy or fusion of a stack, and no mixture left."""
+    from symmetry_tpu.models import hybrid, llama, mamba2, moe
+    from symmetry_tpu.ops import gmm
+
+    for module in (llama, mamba2, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    assert hybrid.expert_columns(1856) == 1920
+    assert hybrid.expert_columns(768) == 768
+    assert gmm.geometry(3072, 2688, 1856) is None       # 14.5 lane tiles
+    assert gmm.geometry(3072, 2688, 1920) == (64, 640)
+    assert gmm.geometry(3072, 1920, 2688) == (64, 896)
+    cfg, params, cache = _nemotron_shapes(one_chip, 2, 256)
+    assert moe.moe_route(512, 128, 6, 32) == "routed"
+    tok = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(
+            lambda p, t, c, n: llama.forward_hidden(p, cfg, t, c, n,
+                                                    prefill_flash=True),
+            donate_argnums=(2,)).lower(params, tok, cache, lens).compile(
+        ).as_text()
+    expert_runs = sum(cfg.ffn_kind(first) == "moe"
+                      for _, first, _ in hybrid.runs(cfg))
+    assert expert_runs == 13
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 2 * expert_runs
+    stack = r"s8\[23,32,(2688,1920|1920,2688)\]"
+    names = set(re.findall(rf"(%[\w.\-]+)(?: =|:) {stack}", text))
+    assert names
+    touched = [line.strip()[:160] for line in text.splitlines()
+               if re.search(rf"= {stack}\S* (copy|transpose|fusion|"
+                            rf"dynamic-slice)\(", line)
+               or (" fusion(" in line and names & set(re.findall(
+                   r"%[\w.\-]+", line.split(" fusion(")[1])))]
+    assert not touched, touched[0]
+    sliced = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= s8\[(1,)?32,(2688,1920|1920,2688)\]", line)]
+    assert not sliced, sliced[0]
+    mixture = [line.strip()[:160] for line in text.splitlines()
+               if re.search(r"\[512,(32|128),\d+\]", line)]
+    assert not mixture, mixture[0]

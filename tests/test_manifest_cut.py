@@ -40,6 +40,7 @@ def test_there_is_a_cut_configuration_to_hold():
     assert "sdar-30b-a3b-chat.json" in cut_files()
     assert "kanana-2-30b-a3b.json" in cut_files()
     assert "smallthinker-21b-a3b.json" in cut_files()
+    assert "nemotron-3-nano-30b-a3b.json" in cut_files()
 
 
 @pytest.mark.parametrize("name", cut_files())
@@ -108,6 +109,16 @@ def test_a_cut_keeps_a_whole_period_of_the_published_pattern(name):
             assert full == [0, 1, 1, 1] * 13
         assert c["published"]["num_hidden_layers"] == 52
         return
+    if c.get("model_type") == "nemotron_h":
+        # NOT cut in depth: all 52 published blocks, the pattern as
+        # published; the cut is the chip's share of each expert block
+        assert c["reduced"] == ["n_routed_experts"]
+        assert len(c["hybrid_override_pattern"]) == c["num_hidden_layers"] \
+            == 52
+        assert (c["n_routed_experts"], c["published"]) == (
+            32, {"n_routed_experts": 128})
+        assert c["n_routed_experts"] >= 8   # the guide's floor for a share
+        return
     if "layer_types" not in c["reduced"]:
         pytest.skip("no layer pattern was cut")
     full, kept = c["published"]["layer_types"], c["layer_types"]
@@ -136,8 +147,65 @@ def test_a_cut_file_is_the_programs_preset(name):
         c["vocab_size"], c["hidden_size"], c["num_hidden_layers"],
         c["num_attention_heads"], c["num_key_value_heads"],
         width, c["head_dim"])
-    assert p.rope_theta == c["rope_theta"] and p.rms_eps == c["rms_norm_eps"]
+    # (the eps key is the family's own: nemotron_h's is `norm_eps`)
+    assert p.rope_theta == c["rope_theta"]
+    assert p.rms_eps == c.get("rms_norm_eps", c.get("norm_eps"))
     assert p.tie_embeddings == c["tie_word_embeddings"]
+    if c.get("model_type") == "nemotron_h":
+        from symmetry_tpu.models.llama import (
+            blocks_of, config_from_hf, pair_blocks)
+
+        # every published key the program reads, through its own reader: the
+        # file IS the preset — every width as published, the depth whole, and
+        # the share stated by keys of the file's own
+        assert config_from_hf(c) == p
+        assert c["reduced"] == ["n_routed_experts"]
+        assert p.num_layers == p.num_blocks == c["num_hidden_layers"] == 52
+        assert blocks_of(p) == c["hybrid_override_pattern"]
+        assert (p.layer_types, p.ffn_layout) == pair_blocks(
+            c["hybrid_override_pattern"])
+        assert len(p.layer_types) == 29 and p.ffn_layout.count("none") == 6
+        assert (p.num_experts, p.experts_held, p.num_experts_per_tok) == (
+            c["experts_routed_over"], tuple(c["experts_held"]),
+            c["num_experts_per_tok"]) == (128, (0, 32), 6)
+        assert c["n_routed_experts"] == c["experts_held"][1] == 32
+        assert c["published"] == {"n_routed_experts": 128}
+        assert (p.intermediate_size, p.shared_intermediate_size) == (
+            c["moe_intermediate_size"],
+            c["moe_shared_expert_intermediate_size"]) == (1856, 3712)
+        assert (p.hidden_act, p.gated_ffn) == (c["mlp_hidden_act"], False)
+        assert (p.mamba_n_heads, p.mamba_d_head, p.mamba_d_state,
+                p.mamba_d_conv, p.mamba_chunk_size, p.mamba_n_groups) == (
+            c["mamba_num_heads"], c["mamba_head_dim"], c["ssm_state_size"],
+            c["conv_kernel"], c["chunk_size"], c["n_groups"]) == (
+            64, 64, 128, 4, 128, 8)
+        assert (p.router_score, p.router_bias, p.routed_scaling_factor,
+                p.router_norm_eps) == ("sigmoid", True, 2.5, 1e-20)
+        assert (c["n_group"], c["topk_group"], c["norm_topk_prob"]) == (
+            1, 1, True)
+        assert p.rope is False and p.vocab_size == 131072
+        assert p.max_position == c["max_position_embeddings"] == 262144
+        tpu = c["tpu"]
+        assert (tpu["max_batch_size"], tpu["max_seq_len"],
+                tpu["decode_block"]) == (64, 640, 16)
+        assert tpu["prefill_buckets"] == [64, 128, 256]
+        assert tpu["prefill_chunk"] is None
+        assert (tpu["quantization"], tpu["kv_quantization"],
+                tpu["dtype"]) == ("int8", "int8", "bfloat16")
+        assert (c["decode_program"], c["prefill_program"]) == (
+            "decode_block", "prefill")
+        assert c["reference"].endswith("nemotron_h_decoder.py")
+        assert os.path.exists(os.path.join(CHECKOUT, c["reference"]))
+        # every `assumed` item the issue lists is stated
+        text = " ".join(c["assumed"])
+        for word in ("float32", "bfloat16", "no rotary", "unclamped",
+                     "NOT renormalised", "512 channels", "relu(x W_up)^2",
+                     "640", "int8", "byte tokenizer", "experts_held",
+                     "e_score_correction_bias", "fan_in"):
+            assert word in text, word
+        for word in ("four-chip", "32 a chip", "replicated", "9.924 GB",
+                     "3.141 GB", "78.0%", "quarter of the rows"):
+            assert word in c["deployment"], word
     if c.get("model_type") == "qwen3_next":
         from symmetry_tpu.models.llama import config_from_hf
 

@@ -254,8 +254,10 @@ def test_a_band_answers_as_its_threshold_did(shape):
     """Every entry is `(lo, hi)`: the mixture iff lo <= tokens < hi. At
     every count the old threshold decided — under a lower edge only (128, 8)
     has one — the answer is the one it gave."""
-    # (an entry newer than the bands had no threshold to answer as)
-    assert set(moe.ROUTED_FROM) == set(THRESHOLD_WAS) - {None} | {(64, 6)}
+    # (an entry newer than the bands had no threshold to answer as; a
+    # three-part key is a SHARE's: held, k, routed over — PR 61)
+    assert set(moe.ROUTED_FROM) == set(THRESHOLD_WAS) - {None} | {
+        (64, 6), (32, 6, 128)}
     lo, hi = moe.ROUTED_FROM.get(shape, (0, moe.ROUTED_MIN_TOKENS))
     assert hi == THRESHOLD_WAS[shape] and 0 <= lo < hi
     assert (lo > 0) == (shape == (128, 8))

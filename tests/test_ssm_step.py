@@ -265,3 +265,100 @@ def test_the_roofline_reader_counts_the_state_once_each_way(
     else:
         ctx = reader_ctx(config={"hidden_size": 4096})
         assert reader.step_roofline(ctx, op="ssm_step") is None
+
+
+# ---------------------------------------------------------------------------
+# groups of B and C (nemotron_h): head h reads row h // (heads / groups)
+
+GROUPED = {
+    # layers, slots, heads, d_head, d_state, groups, head tile (None: the
+    # gate's)
+    "tiny-nh": (3, 4, 8, 16, 16, 2, None),
+    "tiny-nh, a tile inside a group": (3, 4, 8, 16, 16, 2, 2),
+    "a tile of one whole group": (2, 2, 8, 16, 16, 2, 4),
+    "the served shape's slot: 64 heads in 8 groups, 16 unrolled over two": (
+        2, 1, 64, 8, 128, 8, 64),
+    "two tiles a slot of four groups each": (2, 2, 64, 8, 128, 8, 32),
+    "a group a head": (2, 2, 8, 8, 128, 8, None),
+    "groups of 3 heads, 12 unrolled": (2, 2, 24, 8, 128, 8, 12),
+}
+
+
+def grouped_inputs(B, H, P, N, G, seed=0):
+    a, dx, _, _, skip = inputs(B, H, P, N, seed)
+    k = jax.random.split(jax.random.key(seed + 100), 2)
+    return (a, dx, jax.random.normal(k[0], (B, G, N), jnp.float32),
+            jax.random.normal(k[1], (B, G, N), jnp.float32), skip)
+
+
+def by_head(ssm, a, dx, b, c, skip):
+    """The recurrence with each head's own rows spelled out."""
+    H, G = ssm.shape[1], b.shape[1]
+    bh, ch = (jnp.repeat(t, H // G, axis=1) for t in (b, c))
+    new = a[..., None, None] * ssm + dx[..., None] * bh[:, :, None, :]
+    return jnp.einsum("bhpn,bhn->bhp", new, ch,
+                      precision=jax.lax.Precision.HIGHEST) + skip, new
+
+
+@pytest.mark.parametrize("case", list(GROUPED))
+def test_the_grouped_kernel_is_the_grouped_recurrence(case):
+    L, B, H, P, N, G, tile = GROUPED[case]
+    stack, xs = stack_of(L, B, H, P, N), grouped_inputs(B, H, P, N, G)
+    y, new = kernel(stack, L - 1, xs, tile)
+    want_y, want = by_head(stack[L - 1], *xs)
+    close(y, want_y, 2e-6)
+    close(new[L - 1], want)
+    assert np.array_equal(np.asarray(new[:L - 1]), np.asarray(stack[:L - 1]))
+    jnp_y, jnp_new = mamba2.recurrence(stack[L - 1], *xs)
+    close(jnp_y, want_y, 2e-6)
+    close(jnp_new, want)
+
+
+def test_a_group_of_the_kernel_reads_no_other_groups_rows():
+    L, B, H, P, N, G = 2, 2, 8, 16, 16, 2
+    stack = stack_of(L, B, H, P, N)
+    a, dx, b, c, skip = grouped_inputs(B, H, P, N, G)
+    y0, s0 = kernel(stack, 0, (a, dx, b, c, skip))
+    y1, s1 = kernel(stack, 0, (a, dx, b.at[:, 1].add(2.0),
+                               c.at[:, 1].multiply(-1.0), skip))
+    assert np.array_equal(np.asarray(y0[:, :4]), np.asarray(y1[:, :4]))
+    assert np.array_equal(np.asarray(s0[0, :, :4]), np.asarray(s1[0, :, :4]))
+    assert not np.allclose(np.asarray(y0[:, 4:]), np.asarray(y1[:, 4:]))
+    # one group IS the one-row form
+    y_one, s_one = kernel(stack, 0, (a, dx, b[:, :1], c[:, :1], skip))
+    y_row, s_row = kernel(stack, 0, (a, dx, b[:, 0], c[:, 0], skip))
+    close(y_one, y_row)
+    close(s_one, s_row)
+
+
+def test_the_gate_keeps_a_tile_whole_groups_or_inside_one():
+    # nemotron-3-nano-30b-a3b: a slot's 2 MB is one tile of all 8 groups
+    assert op.head_tile(64, 64, 128, groups=8) == 64
+    assert op.step_form(64, 64, 128, 4, interpret=False, otherwise="jnp",
+                        groups=8) == {"form": "pallas", "head_tile": 64,
+                                      "groups": 8}
+    # 24 heads in 8 groups of 3 at 16 KB a head: 24 fits; at a budget of 16
+    # heads the divisors 12 (whole groups) and 8 (neither) — 12
+    assert op.head_tile(24, 8, 128, groups=8) == 24
+    assert op.head_tile(128, 64, 128, groups=16) == 64
+    # one group: every divisor, as it was (granite-4.0-h-small's 64 of 128)
+    assert op.head_tile(128, 64, 128) == 64
+    assert "groups" not in op.step_form(128, 64, 128, 4, interpret=False,
+                                        otherwise="jnp")
+    with pytest.raises(ValueError, match="in 8 groups"):
+        kernel(stack_of(1, 1, 24, 8, 128), 0,
+               grouped_inputs(1, 24, 8, 128, 8), tile=8)
+    nh = llama.preset("nemotron-3-nano-30b-a3b")
+    assert mamba2.sizes(nh)["G"] == 8
+    assert mamba2.sizes(TINY)["G"] == 1
+
+
+def test_the_grouped_trunk_takes_one_kernel_call_a_run_of_mamba_layers():
+    cfg = llama.preset("tiny-nh")
+    params = llama.init_params(cfg, jax.random.key(0), jnp.float32)
+    cache = llama.init_cache(cfg, 2, 32, jnp.float32)
+    tokens = jnp.zeros((2, 1), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda t, c: llama.forward_hidden(params, cfg, t, c))(tokens, cache)
+    runs = sum(kind == "mamba" for kind, _, _ in hybrid.runs(cfg))
+    assert runs == 5 and count_kernels(jaxpr.jaxpr) == runs

@@ -34,6 +34,12 @@ by the Mosaic compiler only.
                      as written (PR 42's program: the padded leaves)
                      (`--only decode64` runs these rows alone)
 
+  gqa16              nemotron-3-nano-30b-a3b's attention blocks: 32 query
+                     heads on TWO K/V heads of 128 (16 queries a K/V head)
+                     — the flash kernel at a bucket of 256 and the decode
+                     kernel over 64 slots x 640 in bf16 and int8 — against
+                     the plain attention (`--only gqa16`)
+
 Then the one timing question later PRs lean on: does
 `jax.block_until_ready` on this chip wait for completion? One decode block
 of chip_smoke.py's engine is timed under it, under the fetch fence of
@@ -95,15 +101,16 @@ def check(name: str, fn, want: np.ndarray, tol: float) -> dict:
     return row
 
 
-def flash_cases(lengths=(128, 2048)) -> list[dict]:
+def flash_cases(lengths=(128, 2048), nkv: int = NKV) -> list[dict]:
     rows = []
+    tag = "" if nkv == NKV else f" {NQ // nkv} queries a KV head"
     for S in lengths:
         # B 2 keeps naive_attention's per-row numpy loops affordable.
         B = 2
         rng = np.random.default_rng(S)
         q = rng.normal(size=(B, S, NQ, D)).astype(np.float32)
-        k = rng.normal(size=(B, S, NKV, D)).astype(np.float32)
-        v = rng.normal(size=(B, S, NKV, D)).astype(np.float32)
+        k = rng.normal(size=(B, S, nkv, D)).astype(np.float32)
+        v = rng.normal(size=(B, S, nkv, D)).astype(np.float32)
         seq_lens = np.array([S, S // 2 + 3], np.int32)
         to = lambda a: jnp.asarray(a, jnp.bfloat16)  # noqa: E731
         # The reference sees the same bf16-rounded inputs, in float32. It
@@ -122,22 +129,27 @@ def flash_cases(lengths=(128, 2048)) -> list[dict]:
             got = np.asarray(out, np.float32)[:, rows_q]
             return np.where(valid[..., None, None], got, 0.0)
 
-        rows.append(check(f"flash_prefill S={S} bf16", run,
+        rows.append(check(f"flash_prefill S={S} bf16{tag}", run,
                           np.where(valid[..., None, None], want, 0.0),
                           BF16_TOL))
     return rows
 
 
-def decode_cases(capacities=(4096, 8192)) -> list[dict]:
+def decode_cases(capacities=(4096, 8192), nkv: int = NKV,
+                 slots: int = 8) -> list[dict]:
     rows = []
+    tag = "" if nkv == NKV else (f" {NQ // nkv} queries a KV head, "
+                                 f"{slots} slots")
     for T in capacities:
-        L, B, layer = 2, 8, 1
+        L, B, layer = 2, slots, 1
         ks = jax.random.split(jax.random.key(T), 3)
         q = jax.random.normal(ks[0], (B, NQ, D), jnp.bfloat16)
-        k = jax.random.normal(ks[1], (L, B, T, NKV, D), jnp.bfloat16)
-        v = jax.random.normal(ks[2], (L, B, T, NKV, D), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (L, B, T, nkv, D), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (L, B, T, nkv, D), jnp.bfloat16)
+        edge = [T - 3, 5, T // 2, 1, 513, 1024, T, 700]
         lengths = jnp.asarray(
-            [T - 3, 5, T // 2, 1, 513, 1024, T, 700][:B], jnp.int32)
+            [min(n, T) for n in edge][:B] + np.random.default_rng(T).integers(
+                1, T + 1, max(0, B - len(edge))).tolist(), jnp.int32)
 
         def ref(kl, vl, ksc=None, vsc=None):
             with jax.default_matmul_precision("highest"):
@@ -148,7 +160,7 @@ def decode_cases(capacities=(4096, 8192)) -> list[dict]:
             return np.asarray(out[:, 0], np.float32)
 
         rows.append(check(
-            f"decode_attention T={T} bf16",
+            f"decode_attention T={T} bf16{tag}",
             lambda: decode_attention(q, k, v, jnp.int32(layer), lengths,
                                      interpret=interpret_mode()),
             ref(k[layer].astype(jnp.float32), v[layer].astype(jnp.float32)),
@@ -157,7 +169,7 @@ def decode_cases(capacities=(4096, 8192)) -> list[dict]:
         vq, vsc = quantize_kv(v)
         ksc, vsc = jnp.moveaxis(ksc, -1, -2), jnp.moveaxis(vsc, -1, -2)
         rows.append(check(
-            f"decode_attention T={T} int8 KV",
+            f"decode_attention T={T} int8 KV{tag}",
             lambda: decode_attention(q, kq, vq, jnp.int32(layer), lengths,
                                      k_scale=ksc, v_scale=vsc,
                                      interpret=interpret_mode()),
@@ -426,7 +438,7 @@ def fence_timing() -> dict:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--only", choices=["select", "decode64"],
+    ap.add_argument("--only", choices=["select", "decode64", "gqa16"],
                     help="these rows alone, no fence timing")
     only = ap.parse_args().only
     if interpret_mode() or jax.default_backend() != "tpu":
@@ -436,7 +448,11 @@ def main() -> int:
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": jax.device_count()}
-    rows = ((select_cases() if only != "decode64" else [])
+    # gqa16: 32 query heads on TWO K/V heads at a 64 x 640 cache (PR 61)
+    rows = (flash_cases((256,), nkv=2) + decode_cases((640,), nkv=2,
+                                                      slots=64)
+            if only == "gqa16" else
+            (select_cases() if only != "decode64" else [])
             + (decode64_cases() if only != "select" else []))
     if only is None:
         rows = flash_cases() + decode_cases() + matmul_cases() + rows

@@ -6,8 +6,10 @@ change what an existing configuration runs?".
 For each preset (default: mistral-7b, qwen2-7b, granite-4.0-h-small,
 qwen3-next-80b-a3b, keye-vl-2.0-30b-a3b, lfm2-8b-a1b, sdar-30b-a3b-chat
 (its diffusion programs at 2 denoise steps a block, the static rule),
-kanana-2-30b-a3b (its latent cache in bfloat16) and smallthinker-21b-a3b
-(its window layers' rings of 4,096 rows beside full leaves of 640) at the
+kanana-2-30b-a3b (its latent cache in bfloat16), smallthinker-21b-a3b
+(its window layers' rings of 4,096 rows beside full leaves of 640) and
+nemotron-3-nano-30b-a3b (at ITS cell's 64 slots, and its widest admission,
+two rows of 256: the dispatch its band routes) at the
 closed cells' shape,
 128 slots x 640, int8 weights + int8 KV, decode_block 16; and tiny-moe8 —
 the stand-in for mixtral-8x7b's sharded programs — on a `model: 4` mesh of
@@ -39,7 +41,9 @@ identical, three new ones. PR 57, (128, 8)'s band: 26 of the 27
 identical — sdar-30b-a3b-chat's `prefill` (8 rows: an opening block of 32
 tokens, routed now) the one that differs; its `prefill_16x64`, new in the
 tool, identical on parent and change. PR 58, a second attention kind with a
-ring of its own: all 28 older files identical, three new ones.)
+ring of its own: all 28 older files identical, three new ones. PR 61,
+blocks of one sub-layer, groups of B and C, a share of ungated experts: all
+31 older files identical, three new ones.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
@@ -66,6 +70,10 @@ from symmetry_tpu.models import llama  # noqa: E402
 SLOTS, CAPACITY, BLOCK = 128, 640, 16
 PREFILL = (8, 256)
 PREFILL_WIDE = (16, 64)     # a diffusion preset's second admission shape
+# a preset whose cell is not 128 slots, and whose widest admission (a
+# prefill buffer carries a slot's whole recurrent state a row) is not 8 rows
+SLOTS_OF = {"nemotron-3-nano-30b-a3b": 64}
+PREFILL_OF = {"nemotron-3-nano-30b-a3b": (2, 256)}
 
 
 def shapes(fn):
@@ -73,14 +81,14 @@ def shapes(fn):
                         jax.eval_shape(fn))
 
 
-def bare_engine(cfg):
+def bare_engine(cfg, slots: int = SLOTS):
     """An engine whose jits exist and whose arrays do not."""
     e = object.__new__(eng_mod.InferenceEngine)
     # (a latent cache row has no int8 form: that preset's cache is bfloat16)
     e.config, e.mesh, e.decode_block = cfg, None, BLOCK
     e.kv_quant = getattr(cfg, "latent", None) is None
     e.spec, e.prefix_block, e.cache_dtype = None, 16, jnp.bfloat16
-    e.max_slots, e.max_seq_len = SLOTS, CAPACITY
+    e.max_slots, e.max_seq_len = slots, CAPACITY
     e._state_shardings = e._cache_shardings = None
     e._count_experts = bool(getattr(cfg, "num_experts", 0))
     # generation by diffusion over blocks: the sdar cell's two settings
@@ -90,7 +98,7 @@ def bare_engine(cfg):
     return e
 
 
-def programs(e, params, state):
+def programs(e, params, state, prefill=PREFILL):
     cfg = e.config
     i32, f32 = jnp.int32, jnp.float32
     # (a block-diffusion admission commits its opening block behind the
@@ -110,8 +118,8 @@ def programs(e, params, state):
         return (jax.ShapeDtypeStruct((n, bucket), i32), vec(n, i32),
                 vec(n, f32), vec(n, f32), vec(n, i32), keys, scratch)
 
-    n = PREFILL[0]
-    args = admission(*PREFILL)
+    n = prefill[0]
+    args = admission(*prefill)
     keys, scratch = args[-2:]
     first = jax.ShapeDtypeStruct((n, block), i32) if block else vec(n, i32)
     yield "decode_block", e._decode.lower(
@@ -134,7 +142,8 @@ def main() -> int:
                              "granite-4.0-h-small", "qwen3-next-80b-a3b",
                              "keye-vl-2.0-30b-a3b", "lfm2-8b-a1b",
                              "sdar-30b-a3b-chat", "kanana-2-30b-a3b",
-                             "smallthinker-21b-a3b"]
+                             "smallthinker-21b-a3b",
+                             "nemotron-3-nano-30b-a3b"]
     os.makedirs(out_dir, exist_ok=True)
     for name in names:
         cfg = llama.preset(name)
@@ -156,22 +165,24 @@ def main() -> int:
                 kv_quant=True, prefill_chunk=None)
             params, state = e.params, e.state
         else:
-            e = bare_engine(cfg)
+            slots = SLOTS_OF.get(name, SLOTS)
+            e = bare_engine(cfg, slots)
             params = shapes(lambda: llama.init_params(
                 cfg, jax.random.key(0), jnp.bfloat16, quantize=True))
             state = shapes(lambda: eng_mod.DecodeState(
                 cache=llama.init_cache(
-                    cfg, SLOTS, CAPACITY, jnp.bfloat16, quantized=e.kv_quant,
+                    cfg, slots, CAPACITY, jnp.bfloat16, quantized=e.kv_quant,
                     count_experts=e._count_experts,
                     # (a window layer's ring: the window's rows)
                     **({"ring": cfg.sliding_window}
                        if getattr(cfg, "window_kind", None) else {})),
-                last_token=jnp.zeros((SLOTS,), jnp.int32),
-                temperature=jnp.zeros((SLOTS,), jnp.float32),
-                top_p=jnp.ones((SLOTS,), jnp.float32),
-                top_k=jnp.zeros((SLOTS,), jnp.int32),
-                rng=jax.random.split(jax.random.key(0), SLOTS)))
-        for prog, lowered in programs(e, params, state):
+                last_token=jnp.zeros((slots,), jnp.int32),
+                temperature=jnp.zeros((slots,), jnp.float32),
+                top_p=jnp.ones((slots,), jnp.float32),
+                top_k=jnp.zeros((slots,), jnp.int32),
+                rng=jax.random.split(jax.random.key(0), slots)))
+        for prog, lowered in programs(e, params, state,
+                                      PREFILL_OF.get(name, PREFILL)):
             text = lowered.as_text()
             path = os.path.join(out_dir, f"{name}.{prog}.txt")
             with open(path, "w") as fh:

@@ -23,7 +23,16 @@ kernel at other row tiles than `ops/gmm.py geometry` chooses.
         --tokens 128,256,512,1024,2048       # granite-4.0-h-small's layer
     python tools/moe_decode_ab.py --shape 512,10,2048,512 --layers 2 \
         --row-tiles 32,64,256                # qwen3-next-80b-a3b's
+    python tools/moe_decode_ab.py --shape 128,6,2688,1856 --held 32 \
+        --ungated relu2 --layers 2 \
+        --tokens 16,32,64,128,256,512,1024,2048,8192
+                                             # nemotron-3-nano-30b-a3b's:
+                                             # 32 of 128 held, two matrices
     JAX_PLATFORMS=cpu python tools/moe_decode_ab.py --tiny
+
+`--held N` holds the first N of the experts routed over (a chip's share: the
+stacks carry N experts, the router its full width, pairs on the others are
+dropped); `--ungated ACT` times two-matrix experts, ACT(x W_up) W_down.
 """
 
 from __future__ import annotations
@@ -49,6 +58,11 @@ def main() -> int:
     ap.add_argument("--shape", default="8,2,4096,3584",
                     help="experts,top_k,hidden,ffn_width held here; "
                          "granite-4.0-h-small whole is 72,10,4096,768")
+    ap.add_argument("--held", type=int, default=0,
+                    help="experts held of those routed over (0: all)")
+    ap.add_argument("--ungated", default="",
+                    help="two-matrix experts under this activation "
+                         "(e.g. relu2); default: gated, silu")
     args = ap.parse_args()
 
     import jax
@@ -63,28 +77,42 @@ def main() -> int:
     if args.tiny:
         D, F = 64, 32
     L = args.layers
+    held = args.held or X       # the stacks carry the held experts alone
+    routing = {}
+    if args.held:
+        routing["held"] = (0, held)
+    if args.ungated:
+        routing["act"] = args.ungated
+    routing = routing or None
     keys = jax.random.split(jax.random.key(0), 4)
-    wg = make_leaf(keys[0], (L, X, D, F), D ** -0.5, jnp.bfloat16, True)
-    wu = make_leaf(keys[1], (L, X, D, F), D ** -0.5, jnp.bfloat16, True)
-    wd = make_leaf(keys[2], (L, X, F, D), F ** -0.5, jnp.bfloat16, True)
+    wg = (None if args.ungated else
+          make_leaf(keys[0], (L, held, D, F), D ** -0.5, jnp.bfloat16, True))
+    wu = make_leaf(keys[1], (L, held, D, F), D ** -0.5, jnp.bfloat16, True)
+    wd = make_leaf(keys[2], (L, held, F, D), F ** -0.5, jnp.bfloat16, True)
     router = make_leaf(keys[3], (L, D, X), D ** -0.5, jnp.bfloat16)
+    names = ("wu", "wd") if args.ungated else ("wg", "wu", "wd")
 
     def valid(x):
         return jnp.ones((x.shape[0],), bool)
 
+    def leaves(layer):      # (router, wg or None, wu, wd) of one layer
+        router, *rest = layer[1:]
+        return (router, *((None,) if args.ungated else ()), *rest)
+
     def dense_mixture(x, layer, stacks):
-        return _dense_mixture(x, valid(x), *layer[1:], k)[0]
+        return _dense_mixture(x, valid(x), *leaves(layer), k, routing)[0]
 
     def routed(x, layer, stacks):
-        return _routed_ffn(x, valid(x), *layer[1:], k)[0]
+        return _routed_ffn(x, valid(x), *leaves(layer), k, None, routing)[0]
 
     def routed_kernel(x, layer, stacks):
         # as models/hybrid.py calls it: the stacks whole, the layer's index
-        return _routed_ffn(x, valid(x), *layer[1:], k, (stacks, layer[0]))[0]
+        return _routed_ffn(x, valid(x), *leaves(layer), k,
+                           (stacks, layer[0]), routing)[0]
 
     def trunk(form):
         def run(x, layers):  # the weights are arguments, never constants
-            stacks = dict(zip(("wg", "wu", "wd"), layers[2:]))
+            stacks = dict(zip(names, layers[2:]))
 
             def body(h, layer):
                 return h + form(h, layer, stacks).astype(h.dtype), None
@@ -100,26 +128,36 @@ def main() -> int:
         y.block_until_ready()
         return round(1e3 * (time.perf_counter() - t0) / args.repeats / L, 4)
 
-    layers = (jnp.arange(L, dtype=jnp.int32), router, wg, wu, wd)
+    layers = (jnp.arange(L, dtype=jnp.int32), router,
+              *(() if args.ungated else (wg,)), wu, wd)
     forms = {"routed": routed, "routed_kernel": routed_kernel,
              "dense_mixture": dense_mixture}
-    weight_bytes = 3 * X * D * F
+    weight_bytes = len(names) * held * D * F
     floor = None if args.tiny else round(1e3 * weight_bytes / 819e9, 4)
 
     out = {"device": jax.devices()[0].device_kind, "layers": L,
-           "shape": {"experts": X, "top_k": k, "hidden": D, "ffn_slice": F},
+           "shape": {"experts": X, "top_k": k, "hidden": D, "ffn_slice": F,
+                     "held": held, "matrices": len(names)},
            "weight_stream_floor_ms_per_layer": floor, "ms_per_layer": {}}
     for T in [int(t) for t in args.tokens.split(",")]:
         x = jax.random.normal(jax.random.key(T), (T, D), jnp.bfloat16)
         row = {name: ms_a_layer(form, x) for name, form in forms.items()}
-        row["grouped_matmul"] = grouped_matmul_form(wg, T * k)
+        # the three forms are one mathematics: each against the routed
+        # form over `ragged_dot`, as a share of its largest value
+        want = trunk(routed)(x, layers).astype(jnp.float32)
+        row["max_rel_diff"] = {
+            name: round(float(jnp.max(jnp.abs(
+                trunk(form)(x, layers).astype(jnp.float32) - want))
+                / jnp.max(jnp.abs(want))), 5)
+            for name, form in forms.items() if name != "routed"}
+        row["grouped_matmul"] = grouped_matmul_form(wu, T * k)
         for tile in [int(t) for t in args.row_tiles.split(",") if t]:
             with _row_tile(gmm, tile):
                 row[f"routed_kernel@{tile}"] = ms_a_layer(routed_kernel, x)
         if floor:
             row["floor_share"] = {name: round(floor / row[name], 3)
                                   for name in forms}
-        row["route"] = moe_route(T, X, k)
+        row["route"] = moe_route(T, X, k, args.held or None)
         out["ms_per_layer"][str(T)] = row
     print(json.dumps(out), flush=True)
     return 0

@@ -18,6 +18,11 @@ Prints one JSON line: ms a layer and GB/s of state for each form, the floor
 against the jnp form on the same inputs — the reading
 `ops/ssm_step.py TILE_BYTES` is set from.
 
+`--groups G` (mamba): B and C are G rows a slot and head h reads row
+h // (heads / G) — nemotron-3-nano-30b-a3b's cell is `--shape 64,64,64,128
+--groups 8 --layers 4 --tiles 16,32,64`: 64 slots x 64 heads x 64 x 128
+float32 = 134 MB a layer.
+
     python tools/ssm_step_ab.py [--kind gdn]            # on the chip
     JAX_PLATFORMS=cpu python tools/ssm_step_ab.py [--kind gdn] --tiny
 """
@@ -46,6 +51,8 @@ def main() -> int:
     ap.add_argument("--shape", help="slots,heads,d_head,d_state (mamba) or "
                                     "slots,value heads,d_key,d_value (gdn)")
     ap.add_argument("--tiles")
+    ap.add_argument("--groups", type=int, default=1,
+                    help="groups of B and C a slot (mamba)")
     ap.add_argument("--repeats", type=int, default=20)
     ap.add_argument("--tiny", action="store_true")
     args = ap.parse_args()
@@ -68,12 +75,16 @@ def main() -> int:
         (B, H, P, N), L, tiles = (2, 8, 16, 16), 2, [2, 8]
     keys = jax.random.split(jax.random.key(34), 6)
     decay = jax.random.uniform(keys[0], (L, B, H), jnp.float32, 0.2, 0.999)
+    G = args.groups
+    if args.tiny and G > 1:
+        tiles = [t for t in tiles if t % (H // G) == 0 or (H // G) % t == 0]
     if args.kind == "mamba":
         recurrence, step = mamba2.recurrence, op.ssm_step
+        rows = (L, B, N) if G == 1 else (L, B, G, N)
         xs = (decay,
               jax.random.normal(keys[1], (L, B, H, P), jnp.float32),
-              jax.random.normal(keys[2], (L, B, N), jnp.float32),
-              jax.random.normal(keys[3], (L, B, N), jnp.float32),
+              jax.random.normal(keys[2], rows, jnp.float32),
+              jax.random.normal(keys[3], rows, jnp.float32),
               jax.random.normal(keys[4], (L, B, H, P), jnp.float32))
     else:
         recurrence, step = gdn.recurrence, op.gdn_step
@@ -129,7 +140,7 @@ def main() -> int:
     want_state, want_y = (np.asarray(v) for v in trunk(xla)(fresh(), xs))
     out = {"device": jax.devices()[0].device_kind, "kind": args.kind,
            "layers": L,
-           "state": {"slots": B, "heads": H, "plane": [P, N],
+           "state": {"slots": B, "heads": H, "plane": [P, N], "groups": G,
                      "bytes_a_layer": layer_bytes // 2},
            "floor_ms_per_layer": round(1e3 * layer_bytes / 819e9, 4),
            "forms": {}}
