@@ -840,9 +840,36 @@ def kv_row(config: ModelConfig) -> tuple[int, int]:
     return (K // 2, 2 * D) if D == 64 and K % 2 == 0 else (K, D)
 
 
+def kv_head_major(row: tuple[int, ...], kv_bytes: int) -> bool:
+    """Whether a K/V leaf whose position holds `row` (`kv_row`: what follows
+    [L, B, T] in the leaf's shape) entries of `kv_bytes` LIES HEAD-MAJOR on
+    one device: 2 one-byte rows of whole lane tiles (nemotron-3-nano-30b-
+    a3b's 2 int8 heads of 128, qwen3-next-80b-a3b's of 256). Such a pair
+    fills a sixteenth of an (8, 128)(4, 1) tile, and the chip's own layout
+    of `s8[L, B, T, 2, D]` — an argument, a donated state — is [L, B, 2, T,
+    D] physically (`{4,2,3,1,0:T(8,128)(4,1)}`). A program keeps it there
+    only while nothing asks for a position's two rows side by side: the
+    scatter whose window is the [2, D] row does (`write_kv`), and XLA then
+    relays the WHOLE leaf to a (4, 128) tile around the scatter and back to
+    head-major around ops/decode_attention.py's view of it, K and V, once
+    a layer (PERF.md PR 62: 36 copies of 63 MB in nemotron's decode step,
+    a third of it). So this is what every site that would fix a layout
+    asks — `write_kv` indexes the head too and writes a decode step's
+    scales by a select (`_put_scales`: the planes of 2 rows are the same
+    case), `attention_paths` names it — by shape alone; a wider or fuller
+    row (4 heads, 2 of bfloat16) is tiled as written and interleaved."""
+    return (len(row) == 2 and row[0] == 2 and kv_bytes == 1
+            and row[1] % 128 == 0)
+
+
 class KVCache(NamedTuple):
     """Static-shape KV cache: [layers, batch, capacity, kv_heads, head_dim]
-    ([..., kv_heads / 2, 128] for heads of 64: `kv_row`).
+    ([..., kv_heads / 2, 128] for heads of 64: `kv_row`). That is the
+    LOGICAL shape of every model's leaves; where they lie in HBM is the
+    compiler's: as a rule a position's heads are rows of one tile, but 2
+    int8 heads lie head-major ([layers, batch, 2, capacity, head_dim]
+    physically) and stay so while every write indexes the head
+    (`kv_head_major`, `write_kv`).
 
     With quantized=True at init, k/v hold int8 payloads and k_scale/v_scale
     hold the per-(layer, slot, kv_head, position) f32 dequant scales
@@ -991,13 +1018,19 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     row and a padded tail's garbage on a live row, so both are dropped (a
     scatter's order among equal rows is not defined).
 
-    `by_head` (the trunk is sharded over a mesh): a cache sharded by KV
-    head may hold 2 heads a chip, and XLA then keeps it in HBM as
-    [L, B, K, T, D] (a second-minor dim of 2 would pad 2x). A scatter
+    `by_head` (the trunk is sharded over a mesh) indexes the head too: a
+    cache sharded by KV head may hold 2 int8 heads a chip, which lie in
+    HBM as [L, B, K, T, D] (a second-minor dim of 2 would pad). A scatter
     whose window is the [K, D] row needs K next to D, so the program
     copied the whole cache into the padded layout around every decode
-    block (+4.3 GB a chip at 64 x 2048: it did not fit). Indexing the head
-    too leaves a window of D alone, which either layout serves in place.
+    block (+4.3 GB a chip at 64 x 2048: it did not fit). A window of D
+    alone either layout serves in place. It is the SCATTER that keeps such
+    a leaf head-major, not the mesh: on one device the same 2 int8 heads
+    under the row-window scatter were relaid whole around every layer's
+    write and read, so a leaf that `kv_head_major` names takes the
+    head-indexed scatter whatever the caller says — and its scale planes,
+    at a decode step (S = 1), a select in place of a scatter
+    (`_put_scales`: 2-row planes have the same quarrel with a scatter).
     """
     B, S = k.shape[:2]
     nkv = cache.k.shape[3]
@@ -1016,7 +1049,8 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     if idx is not None:
         cache = cache._replace(
             idx=cache.idx.at[row].set(idx.astype(cache.idx.dtype)))
-    if by_head:
+    head_major = kv_head_major(cache.k.shape[3:], cache.k.dtype.itemsize)
+    if by_head or head_major:
         row = tuple(i[..., None] for i in row) + (
             jnp.arange(nkv, dtype=jnp.int32)[None, None, :],)
     if not cache.quantized:
@@ -1028,11 +1062,36 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     # Scale planes are [L, B, K, T] (position minor, see KVCache): the
     # mixed advanced/slice index puts the advanced dims (B, S) in front,
     # matching the [B, S, K] scale values.
+    def put(plane, scales):
+        if S == 1 and head_major:
+            return _put_scales(plane, scales, layer, positions[:, 0])
+        return plane.at[l_idx, b_idx, :, positions].set(scales)
+
     return cache._replace(
         k=cache.k.at[row].set(rows(kq)),
         v=cache.v.at[row].set(rows(vq)),
-        k_scale=cache.k_scale.at[l_idx, b_idx, :, positions].set(ks),
-        v_scale=cache.v_scale.at[l_idx, b_idx, :, positions].set(vs))
+        k_scale=put(cache.k_scale, ks), v_scale=put(cache.v_scale, vs))
+
+
+def _put_scales(plane: jnp.ndarray, scales: jnp.ndarray, layer,
+                positions: jnp.ndarray) -> jnp.ndarray:
+    """`plane` [L, B, K, T] with one position's `scales` [B, 1, K] a slot at
+    [layer, b, :, positions[b]] (a position at or past T is dropped) — a
+    decode step's write into the planes of a HEAD-MAJOR leaf
+    (`kv_head_major`), as a select over the layer's slice updated in place.
+    A scatter there costs the step a relayout of the whole plane a layer:
+    planes of 2 rows lie in (2, 128) tiles, which is what
+    ops/decode_attention.py copies blocks out of, and XLA's scatter takes
+    no 2-row tile — it relaid `f32[6,64,2,640]` to a padded (8, 128) tile
+    for each write and back for each kernel call, K and V, 0.19 ms a pass
+    and 5.1 ms of nemotron's 26 ms step (PERF.md, PR 62). An elementwise
+    select takes the tile as it lies."""
+    T = plane.shape[3]
+    at = jax.lax.dynamic_index_in_dim(plane, layer, 0, keepdims=True)
+    hit = (positions[None, :, None, None]
+           == jnp.arange(T, dtype=positions.dtype)[None, None, None, :])
+    new = jnp.where(hit, scales[None, :, 0, :, None].astype(plane.dtype), at)
+    return jax.lax.dynamic_update_slice_in_dim(plane, new, layer, 0)
 
 
 # What a latent-attention model's `expert_pairs` ends in (as a sparse
@@ -1278,6 +1337,13 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
         TP_MIN_CAPACITY up: below it a sharded trunk stays on the XLA
         path (2 KV heads a chip lie head-major there and no slice is
         staged: PERF.md PR 28) until a four-chip A/B says otherwise;
+      - ONE device and 2 int8 KV heads of whole lane tiles a position
+        (`kv_head_major`): the leaves lie head-major, [L, B, 2, T, D]
+        physically, in every program — `write_kv` indexes the head too,
+        so nothing asks for the two rows side by side, and a decode step
+        writes the 2-row scale planes by a select in the (2, 128) tiles
+        the kernel reads — and the decode kernel's lane a (slot, head) is
+        a bitcast of them; the reply says so (`kv_layout`), there alone;
       - learned sparse attention (`config.sparse`): the decode kernel
         takes a selection only beside the scale planes of an int8 cache of
         interleaved heads (ops/decode_attention.py keep_supported); any
@@ -1366,7 +1432,14 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
             f"step masks by each row's own position in gqa_attention")}
     return {"prefill": kernel, "decode": kernel,
             **({"decode_queries": queries} if queries > 1 else {}),
-            "decode_slot_tile": tiles[0], "decode_block_t": tiles[1]}
+            "decode_slot_tile": tiles[0], "decode_block_t": tiles[1],
+            # (a key only where the leaves lie so: no other model's reply
+            # moves)
+            **({"kv_layout": "head-major [L, B, K, T, D]: head-indexed "
+                             "writes (a decode step's scales by a select)"
+                             ", a lane a (slot, head)"}
+               if tp_mesh is None and kv_head_major(kv_row(config), kv_bytes)
+               else {})}
 
 
 def _window_paths(config: "HybridConfig", capacity: int, tp_mesh, *,

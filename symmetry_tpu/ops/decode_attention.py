@@ -23,10 +23,16 @@ verify and a chunked continuation have a mask a row and keep
     seen as it lies there, so the view is a bitcast (`_lanes`): as a
     rule the K heads of a position are rows of one memory tile and a
     slot is one lane, [T * K, D]; 2 int8 heads (a shard of 8 over
-    model: 4) XLA keeps head-major, [L, B, K, T, D] physically, and every
-    (slot, head) is a lane of its own, [T, D], with one KV head. The
-    layer is a scalar-prefetch argument: layer and lane selection are
-    DMA addressing, never a materialised slice.
+    model: 4; nemotron-3-nano-30b-a3b's and qwen3-next-80b-a3b's own 2 on
+    one chip) lie head-major, [L, B, K, T, D] physically, and every
+    (slot, head) is a lane of its own, [T, D], with one KV head — as
+    long as the program's WRITES index the head too (models/llama.py
+    write_kv, kv_head_major): a scatter of [K, D] rows has XLA relay the
+    whole leaf to a (4, 128) tile for it and back for this view, a layer
+    (PERF.md, PR 62; the scale planes of 2 rows likewise: a decode step
+    writes them by a select in the (2, 128) tiles this kernel copies
+    blocks out of). The layer is a scalar-prefetch argument: layer
+    and lane selection are DMA addressing, never a materialised slice.
   - A head of 64 is half a lane tile, and `s8[L, B, T, 8, 64]` has no
     dense layout with a position's heads in rows: compiled for a
     described v5e it is one (8, 128) tile a position with lanes 64-127
@@ -135,8 +141,13 @@ def _lanes(n_kv: int, kv_bytes: int) -> tuple[int, int] | None:
     [L, B, T, K, D] cache of a program decides it (tests/test_chip_compile
     .py holds it to that): 4, 8 or a multiple of 8 heads are row tiles of
     the array as written; 2 heads of int8 would fill a sixteenth of a
-    tile and XLA keeps that cache head-major ([L, B, K, T, D] physically),
-    while 2 heads of bf16 or f32 get a 2-row tile and stay interleaved.
+    tile and the chip's layout of that leaf is head-major ([L, B, K, T, D]
+    physically) — which a program keeps under the head-indexed scatter
+    alone (models/llama.py write_kv: the row-window scatter of 2 int8
+    heads has XLA copy the whole leaf to an interleaved (4, 128) tile and
+    back around every call of this kernel, so `write_kv` never takes it
+    for such a leaf) — while 2 heads of bf16 or f32 get a 2-row tile and
+    stay interleaved.
     Heads of 64 come here as the PAIRS the cache holds (`geometry`): a
     pair is a row of 128 lanes like any head of 128."""
     if n_kv == 2 and kv_bytes == 1:
@@ -531,7 +542,8 @@ def decode_attention(
                               for s in range(fold)], axis=-1)
     qk = jnp.pad(qk.reshape(lanes, nql, D).astype(compute_dtype),
                  ((0, 0), (0, nqp - nql), (0, 0)))
-    if heads > 1:  # head-major, as XLA lays such a cache out: a bitcast
+    if heads > 1:  # head-major, as such a leaf lies under head-indexed
+        # writes (models/llama.py kv_head_major): a bitcast
         k_cache, v_cache = (jnp.swapaxes(x, 2, 3) for x in (k_cache, v_cache))
 
     tile_spec = pl.BlockSpec((tile, nqp, D),

@@ -1,7 +1,10 @@
 """The KV-cache write every program runs (models/llama.py write_kv) against
 a NumPy reference: the row-window scatter of the one-chip trunk and the
 head-indexed scatter of a sharded one, bf16 and int8 caches, decode (S = 1)
-and prefill (S > 1) shapes, ragged positions across slots.
+and prefill (S > 1) shapes, ragged positions across slots — over a narrow
+leaf (heads of 8) and over the leaf of 2 heads of a whole lane tile (128),
+which as int8 lies head-major on one chip and takes the head-indexed scatter
+whatever the caller asks (`kv_head_major`).
 
 The inputs are built so the int8 quantiser has one right answer whatever
 the compiler does with its division: every (token, head) vector is a vector
@@ -16,14 +19,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from symmetry_tpu.models.llama import KVCache, write_kv
+from symmetry_tpu.models.llama import KVCache, kv_head_major, write_kv
 
-L, B, T, K, D = 3, 4, 16, 2, 8
+L, B, T, K = 3, 4, 16, 2
+WIDTHS = pytest.mark.parametrize("D", [8, 128], ids=["heads-of-8",
+                                                     "heads-of-128"])
 # slot b's first position: ragged, and slot 3 ends on the last cache row
 STARTS = {1: (0, 7, 3, T - 1), 5: (0, 7, 3, T - 5)}
 
 
-def dirty_cache(quantized: bool, seed: int = 0) -> KVCache:
+def dirty_cache(quantized: bool, seed: int = 0, D: int = 8) -> KVCache:
     """A cache full of recognisable garbage, so an untouched entry that
     changed — or a touched one that did not — shows."""
     rng = np.random.default_rng(seed)
@@ -39,7 +44,7 @@ def dirty_cache(quantized: bool, seed: int = 0) -> KVCache:
     return KVCache(k=k, v=v, lengths=lengths, k_scale=ks, v_scale=vs)
 
 
-def new_rows(S: int, seed: int):
+def new_rows(S: int, seed: int, D: int = 8):
     """(values [B, S, K, D] f32, integers [B, S, K, D], scales [B, S, K]):
     values = integers * scale, exactly, in bf16 as well as f32."""
     rng = np.random.default_rng(seed)
@@ -113,14 +118,17 @@ def untouched_scales_are_bit_identical(got: KVCache, before: KVCache,
             np.asarray(getattr(before, name))[mask])
 
 
+@WIDTHS
 @pytest.mark.parametrize("layer", [0, L - 1])
 @pytest.mark.parametrize("by_head", [False, True],
                          ids=["row-window", "head-indexed"])
 @pytest.mark.parametrize("S", [1, 5], ids=["decode", "prefill"])
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-def test_write_lands_where_the_reference_says(quantized, S, by_head, layer):
-    cache = dirty_cache(quantized, seed=layer)
-    k_rows, v_rows = new_rows(S, seed=10 + S), new_rows(S, seed=20 + S)
+def test_write_lands_where_the_reference_says(quantized, S, by_head, layer,
+                                              D):
+    cache = dirty_cache(quantized, seed=layer, D=D)
+    k_rows, v_rows = (new_rows(S, seed=10 + S, D=D),
+                      new_rows(S, seed=20 + S, D=D))
     positions = positions_for(S, STARTS[S])
     got = written(cache, jnp.int32(layer), jnp.asarray(positions),
                   jnp.asarray(k_rows[0], jnp.bfloat16),
@@ -134,16 +142,18 @@ def test_write_lands_where_the_reference_says(quantized, S, by_head, layer):
                                   np.asarray(cache.lengths))
 
 
+@WIDTHS
+@pytest.mark.parametrize("S", [3, 1], ids=["prefill", "decode"])
 @pytest.mark.parametrize("by_head", [False, True],
                          ids=["row-window", "head-indexed"])
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-def test_a_position_past_the_capacity_is_dropped(quantized, by_head):
+def test_a_position_past_the_capacity_is_dropped(quantized, by_head, S, D):
     """A slot at its capacity (a stale lane; a padded prefill tail) writes
     nothing — it does not wrap, clamp onto the last row, or touch a
-    neighbour."""
-    S = 3
-    cache = dirty_cache(quantized, seed=5)
-    k_rows, v_rows = new_rows(S, seed=31), new_rows(S, seed=32)
+    neighbour. (A decode step of the head-major leaf writes its scale
+    planes by a select, not a scatter: the same rule.)"""
+    cache = dirty_cache(quantized, seed=5, D=D)
+    k_rows, v_rows = new_rows(S, seed=31, D=D), new_rows(S, seed=32, D=D)
     # slot 0 runs off the end after one token, slot 1 starts past it
     positions = positions_for(S, (T - 1, T + 4, 2, 9))
     got = written(cache, jnp.int32(1), jnp.asarray(positions),
@@ -173,3 +183,29 @@ def test_both_forms_write_the_same_cache():
     for a, b in zip(written(*args, False), written(*args, True)):
         if a is not None:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("quantized, D, head_major", [
+    (True, 128, True),     # nemotron-3-nano-30b-a3b's position: 2 x 128
+    (True, 256, True),     # qwen3-next-80b-a3b's: 2 x 256
+    (True, 8, False),      # no lane tile: the tiny presets' programs stand
+    (False, 128, False),   # 2 bf16 heads get a 2-row tile and interleave
+], ids=["int8-128", "int8-256", "int8-8", "bf16-128"])
+def test_two_int8_heads_take_the_head_indexed_scatter_unasked(quantized, D,
+                                                             head_major):
+    """A leaf of 2 one-byte heads of whole lane tiles lies head-major on
+    one chip, and the row-window scatter is what has XLA relay it whole
+    (PERF.md, PR 62): asked for the row window, `write_kv` traces the
+    head-indexed scatter for such a leaf — the very program a sharded trunk
+    traces — and for no other."""
+    cache = dirty_cache(quantized, seed=3, D=D)
+    assert kv_head_major(cache.k.shape[3:], cache.k.dtype.itemsize) \
+        is head_major
+    k_rows, v_rows = new_rows(1, seed=51, D=D), new_rows(1, seed=52, D=D)
+    args = (cache, jnp.int32(1), jnp.asarray(positions_for(1, STARTS[1])),
+            jnp.asarray(k_rows[0], jnp.bfloat16),
+            jnp.asarray(v_rows[0], jnp.bfloat16))
+    asked, indexed = (str(jax.make_jaxpr(
+        lambda *a: write_kv(*a, by_head=by_head))(*args))
+        for by_head in (False, True))
+    assert (asked == indexed) is head_major

@@ -89,6 +89,40 @@ def whole_cache_copies(text: str) -> list[str]:
                          r"(copy|transpose|reshape|fusion)\(", line)]
 
 
+def leaf_moves(text: str, L: int, B: int, T: int,
+               D: int) -> tuple[list[str], list[str]]:
+    """(moved, written): the lines of a compiled program whose result is a
+    K/V leaf of 2 int8 heads in either order — `s8[L,B,T,2,D]` as declared
+    or `s8[L,B,2,T,D]` as the decode kernel views it — made by a `copy`, a
+    `transpose` or a fusion that is no write in place: each one is the
+    whole leaf through HBM. And the writes in place themselves (a scatter
+    is a `kCustom` fusion whose result IS its operand's buffer, the
+    insert's placement a dynamic-update-slice fusion), so that a test can
+    count them."""
+    shape = rf"= s8\[{L},{B},({T},2|2,{T}),{D}\]\S* "
+    moved, written = [], []
+    for line in text.splitlines():
+        if re.search(shape + r"(copy|transpose)\(", line):
+            moved.append(line.strip()[:160])
+        elif re.search(shape + r"fusion\(", line):
+            in_place = ("kind=kCustom" in line
+                        or "dynamic-update-slice" in line.split(" = ")[0])
+            (written if in_place else moved).append(line.strip()[:160])
+    return moved, written
+
+
+def plane_moves(text: str, L: int, B: int, T: int) -> list[str]:
+    """Lines of a compiled program that copy or relay a scale plane of 2
+    heads, `f32[L,B,2,T]`: the planes lie in (2, 128) tiles, the decode
+    kernel copies blocks out of them as they lie, and a scatter into them
+    has XLA relay the whole plane to a padded (8, 128) tile and back, a
+    layer (a decode step of a head-major leaf writes them by a select over
+    the layer's slice instead: models/llama.py _put_scales)."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= f32\[{L},{B},2,{T}\]\S* "
+                         rf"(copy|copy-start|transpose|scatter)\(", line)]
+
+
 @pytest.mark.parametrize("case", list(CASES))
 def test_kernel_compiles_at_served_shapes(one_chip, no_cache, case):
     L, B, T, K, nq, dtype, window, D, *queries = CASES[case]
@@ -287,6 +321,14 @@ def test_gdn_decode_step_updates_the_matrix_state_in_place(
     assert memory.alias_size_in_bytes >= state_bytes
     assert memory.temp_size_in_bytes < state_bytes // 3    # under one layer
     text = compiled.as_text()
+    # the attention layer's K/V (2 int8 heads of 256 a position) lies
+    # head-major where it is written and read: until PR 62 the row-window
+    # scatter had the step relay both 42 MB leaves (`copy.460` / `.461
+    # s8[1,128,2,640,256]`, 2.4% of the cell's busy time)
+    moved, _ = leaf_moves(text, 1, 128, 640, 256)
+    assert not moved, moved[0]
+    moved = plane_moves(text, 1, 128, 640)      # nor its scale planes
+    assert not moved, moved[0]
     gmm_calls = len(re.findall(r"%moe_gmm[.\d]* = ", text))
     assert gmm_calls == 3 * len(hybrid.runs(cfg))
     gdn_runs = sum(kind == "linear_attention"
@@ -706,24 +748,18 @@ def test_lfm2_prefill_lowers_flash_at_a_head_of_64_and_routes_its_experts(
     assert not touched, touched[0]
 
 
-def _sdar_engine(one_chip, monkeypatch):
-    """sdar-30b-a3b-chat's engine programs without its arrays
-    (`tools/lowered_programs.py bare_engine`: 128 slots x 640, two denoise
-    steps a block), the kernels compiled and not interpreted: (config,
-    engine, `shaped` — a function's output shapes on the described chip —
-    and the int8 parameters' shapes)."""
+def _lowered_programs_tool(one_chip, monkeypatch):
+    """`tools/lowered_programs.py` (the engine's own jits without its
+    arrays: `bare_engine`, `decode_state`, `programs`) with every shape it
+    makes placed on the described chip, and that `shaped` itself: a
+    function's output shapes there."""
     import importlib.util
 
-    from symmetry_tpu.models import llama, moe
-
-    for module in (llama, moe):
-        monkeypatch.setattr(module, "interpret_mode", lambda: False)
     spec = importlib.util.spec_from_file_location(
         "lowered_programs", os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "tools", "lowered_programs.py"))
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
-    cfg = llama.preset("sdar-30b-a3b-chat")
 
     def shaped(fn):
         return jax.tree.map(
@@ -731,6 +767,22 @@ def _sdar_engine(one_chip, monkeypatch):
                                            sharding=one_chip),
             jax.eval_shape(fn))
 
+    monkeypatch.setattr(tool, "shapes", shaped)
+    return tool, shaped
+
+
+def _sdar_engine(one_chip, monkeypatch):
+    """sdar-30b-a3b-chat's engine programs without its arrays
+    (`tools/lowered_programs.py bare_engine`: 128 slots x 640, two denoise
+    steps a block), the kernels compiled and not interpreted: (config,
+    engine, `shaped` — a function's output shapes on the described chip —
+    and the int8 parameters' shapes)."""
+    from symmetry_tpu.models import llama, moe
+
+    for module in (llama, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    tool, shaped = _lowered_programs_tool(one_chip, monkeypatch)
+    cfg = llama.preset("sdar-30b-a3b-chat")
     params = shaped(lambda: llama.init_params(
         cfg, jax.random.key(0), jnp.bfloat16, quantize=True,
         slice_above=1 << 40))
@@ -1053,7 +1105,8 @@ def test_nemotron_decode_step_steps_the_grouped_state_in_place(
     lies by ONE grouped `ssm_step` call a run of mamba layers (8 rows of B
     and C a slot, 16 heads unrolled over two groups) and no XLA fusion; the
     attention blocks (16 queries a K/V head, a row of 256 values) take the
-    decode kernel; the expert layers are ROUTED — two `moe_gmm` calls a run
+    decode kernel over K/V leaves that no op copies or relays (PR 62); the
+    expert layers are ROUTED — two `moe_gmm` calls a run
     over the 32 HELD experts' stacks where they lie: the mixture had XLA
     copy each layer's two [32, 2688, 1920] slices out of the stack first
     (375 MB of temporaries; 46.5 ms a step on the chip, PERF.md PR 61) —
@@ -1075,16 +1128,29 @@ def test_nemotron_decode_step_steps_the_grouped_state_in_place(
     memory = compiled.memory_analysis()
     state_bytes = 23 * 64 * 64 * 64 * 128 * 4
     assert memory.alias_size_in_bytes >= state_bytes
-    # the temporaries are NOT the state's: XLA gives this trunk's K/V leaves
-    # (2 int8 heads a position: s8[6, 64, 640, 2, 128]) a (4, 128) tile and
-    # relays 63 MB copies of them out to the head-major view the decode
-    # kernel takes and back, a layer (PERF.md section 7, PR 61: the cell's
-    # first `perf_opt` item; mistral's shards of 2 heads stay head-major)
-    assert memory.temp_size_in_bytes < 3 * state_bytes // 23
+    # the K/V leaves (2 int8 heads a position: s8[6, 64, 640, 2, 128]) lie
+    # head-major — the chip's own layout of the donated argument,
+    # `{4,2,3,1,0:T(8,128)(4,1)}` — through the whole step: the six layers'
+    # head-indexed scatters write them in place (`write_kv` under
+    # `kv_head_major`) and the decode kernel's [L, B * 2, T, D] view is a
+    # bitcast. Under the row-window scatter this program held 36 copies of
+    # a 63 MB leaf (to a (4, 128) tile for the scatter, back, and out to
+    # the kernel's view, K and V, a layer: two fifths of the cell's step,
+    # PERF.md PR 62) and 374.5 MiB of temporaries
+    text = compiled.as_text()
+    moved, written = leaf_moves(text, 6, 64, 640, 128)
+    assert not moved, moved[0]
+    assert len(written) == 2 * 6, written
+    # ... and their scale planes `f32[6,64,2,640]` in the (2, 128) tiles the
+    # kernel reads, written by a select over the layer's slice: a scatter
+    # had the step relay each plane to a padded tile and back, a layer (22
+    # + 12 copies of 2 MB at 0.19 ms a pass, 5.1 ms of the 26 ms step left)
+    moved = plane_moves(text, 6, 64, 640)
+    assert not moved, moved[0]
+    assert memory.temp_size_in_bytes < 64 * 2**20        # 38.8 MiB
     # weights 9.92 GB + state and K/V 3.27 GB + temporaries: inside 16 GB
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 14.5e9)
-    text = compiled.as_text()
     runs = hybrid.runs(cfg)
     mamba_runs = sum(kind == "mamba" for kind, _, _ in runs)
     attn_runs = sum(kind == "attention" for kind, _, _ in runs)
@@ -1160,3 +1226,58 @@ def test_nemotron_routed_prefill_reads_the_held_stacks_where_they_lie(
     mixture = [line.strip()[:160] for line in text.splitlines()
                if re.search(r"\[512,(32|128),\d+\]", line)]
     assert not mixture, mixture[0]
+
+
+@pytest.mark.parametrize("program", ["decode_block", "insert_all",
+                                     "prefill"])
+def test_nemotron_programs_keep_the_kv_leaf_head_major(
+        one_chip, no_cache, monkeypatch, program):
+    """The three programs that share the cell's cache — the engine's own
+    jits at 64 slots x 640 (`tools/lowered_programs.py`): the decode block
+    of 16 steps, the insert of an admission's rows into their slots, and
+    the widest admission (two rows of bucket 256) into its scratch — must
+    agree on where the K/V leaves lie, or one of them relays the whole leaf
+    for the others: each holds the leaf in the chip's own head-major layout
+    alone, writes it in place (scatters by head; the insert's placement)
+    and never copies, transposes or fuses it into another (PR 62; the
+    parent's decode block held 32 such copies, its prefill relaid the
+    scratch around twelve scatters)."""
+    from symmetry_tpu.models import llama, mamba2, moe
+
+    for module in (llama, mamba2, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    tool, _ = _lowered_programs_tool(one_chip, monkeypatch)
+    name = "nemotron-3-nano-30b-a3b"
+    slots, (rows, bucket) = tool.SLOTS_OF[name], tool.PREFILL_OF[name]
+    cfg, params, _ = _nemotron_shapes(one_chip, slots, tool.CAPACITY)
+    e = tool.bare_engine(cfg, slots)
+    state = tool.decode_state(e, cfg, slots)
+    assert state.cache.k.shape == (6, 64, 640, 2, 128)
+    with jax.default_matmul_precision("default"):  # the served setting,
+        lowered = next(low for prog, low in tool.programs(  # as traced
+            e, params, state, (rows, bucket)) if prog == program)
+        text = lowered.compile().as_text()
+    # the leaf as each program holds it: the served cache, or the scratch
+    B, T = (rows, bucket) if program == "prefill" else (slots, 640)
+    moved, written = leaf_moves(text, 6, B, T, 128)
+    assert not moved, moved[0]
+    if program == "prefill":
+        # the scratch (0.8 MB) is staged in fast memory and scattered
+        # there through a bitcast to the rows as they lie, [L * B * 2 * T,
+        # 128]: twelve scatters, K and V of six attention layers
+        written = re.findall(rf"= s8\[{6 * B * 2 * T},128\]\S* scatter\(",
+                             text)
+    # K and V: of six layers, or placed once a leaf (a re-materialised
+    # scatter may be listed twice)
+    assert len(written) >= (2 if program == "insert_all" else 2 * 6), written
+
+    def layouts(b, t):
+        return set(re.findall(rf"s8\[6,{b},{t},2,128\]\{{([\d,]+):", text))
+
+    assert layouts(B, T) == {"4,2,3,1,0"}       # [L, B, 2, T, D] in HBM
+    if program == "decode_block":
+        moved = plane_moves(text, 6, slots, 640)
+        assert not moved, moved[0]
+    if program == "insert_all":
+        # ... and the scratch it places lies as the prefill left it
+        assert layouts(rows, bucket) == {"4,2,3,1,0"}

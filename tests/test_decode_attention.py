@@ -261,15 +261,23 @@ def case_640(K, G, quantized, dtype=jnp.bfloat16, seed=0, B=6, T=640,
 
 
 class TestCellShapes:
-    """Capacity 640 with mistral-7b's (8 KV x 4), qwen2-7b's (4 KV x 7)
-    and lfm2-8b-a1b's (8 KV of 64 x 4) heads: the shapes the benchmark's
-    one-chip cells decode at."""
+    """Capacity 640 with mistral-7b's (8 KV x 4), qwen2-7b's (4 KV x 7),
+    lfm2-8b-a1b's (8 KV of 64 x 4), nemotron-3-nano-30b-a3b's (2 KV x 16)
+    and qwen3-next-80b-a3b's (2 KV of 256 x 8) heads: the shapes the
+    benchmark's one-chip cells decode at."""
 
     @pytest.mark.parametrize("slot_tile", [1, 2, 3, 6])
     @pytest.mark.parametrize("quantized", [False, True],
                              ids=["bf16", "int8"])
     @pytest.mark.parametrize("K, G, D", [(8, 4, 128), (4, 7, 128),
-                                         (8, 4, 64), (16, 2, 64)])
+                                         (8, 4, 64), (16, 2, 64),
+                                         # 2 heads: as int8 a lane a (slot,
+                                         # head) of the head-major leaf,
+                                         # 16 query rows a lane (nemotron-
+                                         # 3-nano-30b-a3b) or 8 of 256
+                                         # (qwen3-next-80b-a3b); as bf16
+                                         # interleaved rows of one lane
+                                         (2, 16, 128), (2, 8, 256)])
     def test_matches_gqa_attention(self, tiled, K, G, D, quantized,
                                    slot_tile):
         q, k, v, scales = case_640(K, G, quantized, D=D)
@@ -662,6 +670,55 @@ class TestModelIntegration:
 
         np.testing.assert_allclose(decode(True), decode(False),
                                    rtol=2e-4, atol=2e-4)
+
+
+    @pytest.mark.parametrize("heads, head_major", [
+        ((4, 2, 128), True),    # 2 int8 heads of a lane tile: head-major
+        ((8, 2, 256), True),    # ... of two (qwen3-next-80b-a3b's)
+        ((8, 4, 128), False),   # 4 heads are rows of one tile as written
+    ], ids=str)
+    def test_engine_decodes_an_int8_cache_as_the_xla_route_does(
+            self, monkeypatch, heads, head_major):
+        """The engine's own programs over an int8 cache — prefill into a
+        scratch, insert into a slot, two decode blocks through the
+        interpreted kernel — yield the XLA route's tokens. Two int8 heads
+        of whole lane tiles are the leaf that lies head-major on one chip:
+        every program writes it by head (models/llama.py write_kv), the
+        insert places the scratch's rows as they are, and the reply says
+        so (`kv_layout`) — for that leaf alone."""
+        from symmetry_tpu.engine.engine import InferenceEngine, SamplingParams
+        from symmetry_tpu.engine.tokenizer import ByteTokenizer
+        from symmetry_tpu.models import ModelConfig, init_params
+
+        nq, nkv, D = heads
+        cfg = ModelConfig(vocab_size=512, hidden_size=128, num_layers=2,
+                          num_heads=nq, num_kv_heads=nkv,
+                          intermediate_size=128, head_dim=D,
+                          rope_theta=10000.0, max_position=256)
+        params = init_params(cfg, jax.random.key(2), jnp.float32)
+
+        def served():
+            engine = InferenceEngine(
+                cfg, params, ByteTokenizer(), max_slots=4, max_seq_len=128,
+                prefill_buckets=(16,), cache_dtype=jnp.float32,
+                kv_quant=True, decode_block=4, prefill_chunk=None)
+            assert engine.state.cache.k.dtype == jnp.int8
+            first = [engine.prefill_and_insert(slot, list(prompt),
+                                               SamplingParams())
+                     for slot, prompt in ((2, b"a first prompt"),
+                                          (0, b"another"))]
+            blocks = np.concatenate([engine.decode_steps()
+                                     for _ in range(2)])
+            return engine.attention_paths(), first, blocks[:, [2, 0]]
+
+        paths, first, toks = served()
+        assert paths["decode"] == "pallas-interpret"
+        assert ("kv_layout" in paths) is head_major
+        monkeypatch.setattr(da, "geometry", lambda *a: None)
+        xla_paths, xla_first, xla_toks = served()
+        assert xla_paths["decode"] == "xla" and "kv_layout" not in xla_paths
+        assert first == xla_first
+        np.testing.assert_array_equal(toks, xla_toks)
 
 
 class TestSlidingWindow:

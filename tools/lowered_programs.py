@@ -43,7 +43,11 @@ tokens, routed now) the one that differs; its `prefill_16x64`, new in the
 tool, identical on parent and change. PR 58, a second attention kind with a
 ring of its own: all 28 older files identical, three new ones. PR 61,
 blocks of one sub-layer, groups of B and C, a share of ungated experts: all
-31 older files identical, three new ones.)
+31 older files identical, three new ones. PR 62, the head-indexed K/V
+scatter for a leaf of 2 int8 heads of whole lane tiles: 30 of the 34
+identical — every `insert_all` and all three programs of the ten other
+presets — nemotron-3-nano-30b-a3b's and qwen3-next-80b-a3b's `decode_block`
+and `prefill` the four that differ.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
@@ -96,6 +100,22 @@ def bare_engine(cfg, slots: int = SLOTS):
     e._bd_steps, e._bd_threshold = 2, None
     e._build_jits()
     return e
+
+
+def decode_state(e, cfg, slots: int):
+    """The shapes of the engine's served state at `slots` x CAPACITY."""
+    return shapes(lambda: eng_mod.DecodeState(
+        cache=llama.init_cache(
+            cfg, slots, CAPACITY, jnp.bfloat16, quantized=e.kv_quant,
+            count_experts=e._count_experts,
+            # (a window layer's ring: the window's rows)
+            **({"ring": cfg.sliding_window}
+               if getattr(cfg, "window_kind", None) else {})),
+        last_token=jnp.zeros((slots,), jnp.int32),
+        temperature=jnp.zeros((slots,), jnp.float32),
+        top_p=jnp.ones((slots,), jnp.float32),
+        top_k=jnp.zeros((slots,), jnp.int32),
+        rng=jax.random.split(jax.random.key(0), slots)))
 
 
 def programs(e, params, state, prefill=PREFILL):
@@ -169,18 +189,7 @@ def main() -> int:
             e = bare_engine(cfg, slots)
             params = shapes(lambda: llama.init_params(
                 cfg, jax.random.key(0), jnp.bfloat16, quantize=True))
-            state = shapes(lambda: eng_mod.DecodeState(
-                cache=llama.init_cache(
-                    cfg, slots, CAPACITY, jnp.bfloat16, quantized=e.kv_quant,
-                    count_experts=e._count_experts,
-                    # (a window layer's ring: the window's rows)
-                    **({"ring": cfg.sliding_window}
-                       if getattr(cfg, "window_kind", None) else {})),
-                last_token=jnp.zeros((slots,), jnp.int32),
-                temperature=jnp.zeros((slots,), jnp.float32),
-                top_p=jnp.ones((slots,), jnp.float32),
-                top_k=jnp.zeros((slots,), jnp.int32),
-                rng=jax.random.split(jax.random.key(0), slots)))
+            state = decode_state(e, cfg, slots)
         for prog, lowered in programs(e, params, state,
                                       PREFILL_OF.get(name, PREFILL)):
             text = lowered.as_text()
