@@ -142,7 +142,7 @@ def provider_config(preset: str, mesh_model: int) -> dict:
             # No prompt of the smoke is chunked (one bucket of 128 under
             # the default chunk of 256), so say so: chunked prefill is
             # REFUSED for a model with recurrent layers (`--preset
-            # granite-4.0-h-small`; models/hybrid.py state_refusals), and
+            # granite-4.0-h-small`; models/residents.py refusals), and
             # this process may not import jax to ask which preset is one.
             "prefill_chunk": None,
         },

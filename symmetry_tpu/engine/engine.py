@@ -32,9 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from symmetry_tpu.models import residents
 from symmetry_tpu.models.llama import (
-    LATENT_COUNTS,
-    WINDOW_COUNTS,
     KVCache,
     ModelConfig,
     absorb_latent,
@@ -256,81 +255,37 @@ class InferenceEngine:
                 f"(KV handoff snapshots the cache host-side); drop the "
                 f"role or the mesh")
         self.role = role
-        # A model with recurrent layers keeps a state per slot that is not
-        # a row per position (models/hybrid.py): what cannot carry it is
-        # refused here, never served wrong.
-        # (a model of latent-attention layers alone rides the same trunk
-        # with no recurrent kind: it keeps a row a position and no state)
-        self._has_state = bool(getattr(config, "layer_types", None)) and (
-            config.recurrent_kind is not None)
-        if self._has_state:
-            from symmetry_tpu.models.hybrid import state_refusals
-
-            refused = state_refusals(
-                mesh=mesh is not None, role=role,
-                prefix_cache=prefix_cache_bytes > 0,
-                speculative=speculative is not None,
-                prefill_chunk=prefill_chunk)
-            if refused:
-                raise EngineError(refused[0])
-        # Learned sparse attention keeps an index key a cached position
-        # (models/llama.py KVCache.idx): the same rule.
-        self._sparse = getattr(config, "sparse", None)
-        if self._sparse is not None:
-            from symmetry_tpu.models.llama import sparse_refusals
-
-            refused = sparse_refusals(
-                mesh=mesh is not None, role=role,
-                prefix_cache=prefix_cache_bytes > 0,
-                speculative=speculative is not None,
-                prefill_chunk=prefill_chunk)
-            if refused:
-                raise EngineError(refused[0])
-        # Latent attention keeps ONE row a cached position and no K / V a
-        # head (models/llama.py LatentAttention): the same rule.
-        self._latent = getattr(config, "latent", None)
-        if self._latent is not None:
-            from symmetry_tpu.models.llama import latent_refusals
-
-            refused = latent_refusals(
-                mesh=mesh is not None, role=role,
-                prefix_cache=prefix_cache_bytes > 0,
-                speculative=speculative is not None,
-                prefill_chunk=prefill_chunk, kv_quant=kv_quant)
-            if refused:
-                raise EngineError(refused[0])
-        # Window AND full attention layers keep a ring a window layer
-        # beside a full row a full layer (models/llama.py KVCache.kw): the
-        # same rule.
-        self._window = getattr(config, "window_kind", None)
-        if self._window is not None:
-            from symmetry_tpu.models.hybrid import window_refusals
-
-            refused = window_refusals(
-                mesh=mesh is not None, role=role,
-                prefix_cache=prefix_cache_bytes > 0,
-                speculative=speculative is not None,
-                prefill_chunk=prefill_chunk)
-            if refused:
-                raise EngineError(refused[0])
-        # since start (stats.engine.swa), as of the last synced decode
-        # block: decode forwards, the rows a full layer and a window layer
-        # read in them, the writes that came back to row 0 of a full ring
-        # (counted on the device, WINDOW_COUNTS); prompt tokens prefilled,
-        # as dispatched
-        self.swa = (None if self._window is None else
-                    {**{name: 0 for name in WINDOW_COUNTS},
-                     "prefill_tokens": 0})
-        # since start (stats.engine.mla): decode forwards and the live rows
-        # they read as of the last synced decode block (counted on the
-        # device), prompt tokens prefilled through the expanded form as
-        # dispatched
-        self.mla = (None if self._latent is None else
-                    {**{name: 0 for name in LATENT_COUNTS},
-                     "prefill_tokens": 0})
+        # What a slot of this model keeps beyond K and V a head at one
+        # capacity (models/residents.py: a recurrent state, index keys, a
+        # latent row, a ring, a diffusion block, a share of the experts),
+        # asked ONCE: what cannot carry it is refused here, never served
+        # wrong, and each row's counters get their block.
+        rows = residents.kept(config)
+        refused = residents.refusals(
+            config, mesh=mesh is not None, role=role,
+            prefix_cache=prefix_cache_bytes > 0,
+            speculative=speculative is not None,
+            prefill_chunk=prefill_chunk, kv_quant=kv_quant)
+        if refused:
+            raise EngineError(refused[0])
+        # what the PROGRAMS below ask: the state install, the index keys'
+        # and the latent row's sizes, the ring's rows, the block length
+        found = {row.name: row.of(config) for row in rows}
+        self._has_state = "recurrent state" in found
+        self._sparse = found.get("index keys")
+        self._latent = found.get("latent row")
+        self._window = found.get("window ring")
+        self._diffusion = found.get("diffusion block")
+        # since start, a block a row (`stats_blocks`): what the device
+        # counted as of the last synced decode block (the words the row
+        # appends to `expert_pairs`, `collect_expert_pairs`) and what the
+        # host counts as dispatched
+        self.counters: dict[str, dict[str, int]] = {
+            row.block: dict.fromkeys(names, 0)
+            for row in rows if (names := row.counters())}
+        self._tail = next((row for row in rows if row.words), None)
         # Generation by diffusion over blocks (models/llama.py
-        # BlockDiffusion): the same rule, and the two generation settings.
-        self._diffusion = getattr(config, "diffusion", None)
+        # BlockDiffusion): the two generation settings.
         self.diffusion: dict | None = None
         if self._diffusion is None:
             if diffusion_steps is not None or diffusion_threshold is not None:
@@ -339,15 +294,6 @@ class InferenceEngine:
                     "settings of a model that generates by diffusion over "
                     "blocks: this model has no block length")
         else:
-            from symmetry_tpu.models.llama import diffusion_refusals
-
-            refused = diffusion_refusals(
-                mesh=mesh is not None, role=role,
-                prefix_cache=prefix_cache_bytes > 0,
-                speculative=speculative is not None,
-                prefill_chunk=prefill_chunk)
-            if refused:
-                raise EngineError(refused[0])
             block = self._diffusion.block
             if decode_block % block:
                 raise EngineError(
@@ -381,11 +327,6 @@ class InferenceEngine:
                 "tokens_committed": 0, "tokens_dropped": 0,
                 "opening_block_tokens": {str(n): 0
                                          for n in range(1, block + 1)}}
-        # since start, as of the last synced decode block (stats.engine.dsa)
-        self.dsa = (None if self._sparse is None else
-                    {"queries": 0, "candidates": 0, "selected": 0,
-                     "dense_queries": 0})
-        self.ssm_counters = {"prefill_tokens": 0, "state_installs": 0}
         # W8A16 fused-dequant routing (tpu.fused_dequant): pack the int8
         # weight leaves into the Pallas kernel's tile layout ONCE, here —
         # the layout is the routing (qmatmul dispatches on the leaf
@@ -470,8 +411,6 @@ class InferenceEngine:
         # llama.py KVCache).
         self._count_experts = bool(getattr(c, "num_experts", 0))
         self.expert_pairs = [0] * getattr(c, "num_experts", 0)
-        # (a share of the experts: held experts hit, a layer and a forward)
-        self.expert_hits = 0
         self._pairs_pending: collections.deque = collections.deque()
         self._moe_report: dict | None = None
 
@@ -1370,12 +1309,9 @@ class InferenceEngine:
         prefill_keys, decode_keys_arr = self._group_keys(
             [sampling for _, _, sampling in assignments], batch)
 
-        if self._has_state:
-            self.ssm_counters["prefill_tokens"] += int(lens[:n_req].sum())
-        if self.mla is not None:
-            self.mla["prefill_tokens"] += int(lens[:n_req].sum())
-        if self.swa is not None:
-            self.swa["prefill_tokens"] += int(lens[:n_req].sum())
+        for block in self.counters.values():
+            if "prefill_tokens" in block:
+                block["prefill_tokens"] += int(lens[:n_req].sum())
         lens_arr = jnp.asarray(lens)
         temps_arr = jnp.asarray(temps)
         top_ps_arr = jnp.asarray(top_ps)
@@ -2070,7 +2006,7 @@ class InferenceEngine:
         before the next decode program is no longer one to park)."""
         self._park[np.asarray(slots)] = False
         if self._has_state:
-            self.ssm_counters["state_installs"] += len(set(
+            self.counters["ssm"]["state_installs"] += len(set(
                 np.asarray(slots).tolist()))
         self.state = self._insert_all(self.state, prefix,
                                       jnp.asarray(slots), *rows)
@@ -2458,22 +2394,15 @@ class InferenceEngine:
             block = np.asarray(self._pairs_pending.popleft())
             self.expert_pairs = [a + int(b) for a, b in
                                  zip(self.expert_pairs, block)]
-            if self._sparse is not None:
-                from symmetry_tpu.ops.sparse_attention import (
-                    N_COUNTS, read_counts)
-
-                for name, n in read_counts(block[-N_COUNTS:]).items():
-                    self.dsa[name] += n
-            if self.mla is not None:
-                for name, n in zip(LATENT_COUNTS,
-                                   block[-len(LATENT_COUNTS):]):
-                    self.mla[name] += int(n)
-            if self.swa is not None:
-                for name, n in zip(WINDOW_COUNTS,
-                                   block[-len(WINDOW_COUNTS):]):
-                    self.swa[name] += int(n)
-            if getattr(self.config, "experts_held", None) is not None:
-                self.expert_hits += int(block[-1])      # HELD_COUNTS
+            row = self._tail
+            if row is not None:
+                # the vector's last words are the one row's that has any
+                # (`residents.tail_words`, which sized it)
+                tail = block[-len(row.words):]
+                counts = self.counters[row.block]
+                for name, n in (zip(row.words, tail) if row.decode is None
+                                else row.decode(tail).items()):
+                    counts[name] += int(n)
 
     def moe_counts(self) -> dict:
         """`stats.engine.moe`'s counters: valid (token, expert) pairs
@@ -2492,7 +2421,7 @@ class InferenceEngine:
             mine = counts[held[0]:held[0] + held[1]]
             out.update(expert_pairs=mine, held_pairs=sum(mine),
                        absent_pairs=sum(counts) - sum(mine),
-                       expert_hits=self.expert_hits)
+                       expert_hits=self.counters["moe"]["expert_hits"])
         return out
 
     def moe_report(self) -> dict | None:
@@ -2721,6 +2650,40 @@ class InferenceEngine:
                          "tokens, lanes block-aligned from then on",
             "programs": {"prefill": "bd_prefill",
                          "decode": "bd_decode_block"}}
+
+    def stats_blocks(self) -> dict[str, dict]:
+        """Every block of `stats.engine` that this model brings, by name:
+        a row's counters (`self.counters`: `ssm`, `dsa`, `mla`, `swa`), and
+        `moe` and `diffusion` with what their reports say beside the
+        counts. The scheduler's stats reply takes them whole."""
+        out = {name: dict(block) for name, block in self.counters.items()}
+        if self.expert_pairs:
+            # valid (token, expert) pairs computed since start, per expert
+            # and in all, as of the last synced decode block; the form each
+            # program kind's expert FFN takes (models/moe.py)
+            out["moe"] = {**self.moe_counts(),
+                          "route": self.moe_report()["route"]}
+        if self.diffusion is not None:
+            # the settings, the forwards dispatched and what of their
+            # tokens reached a stream (the scheduler counts those), as of
+            # the last entry read
+            report = self.diffusion_report()
+            out["diffusion"] = {
+                **{k: report[k] for k in ("block", "steps", "rule",
+                                          "threshold")},
+                **self.diffusion,
+                "opening_block_tokens": dict(
+                    self.diffusion["opening_block_tokens"])}
+        return out
+
+    def startup_reports(self) -> dict[str, dict]:
+        """Every block of `startup` that this model brings, by name: built
+        once the programs have compiled and the caches are allocated."""
+        reports = {"moe": self.moe_report(), "ssm": self.ssm_report(),
+                   "diffusion": self.diffusion_report(),
+                   "cache": self.cache_report()}
+        return {name: report for name, report in reports.items()
+                if report is not None}
 
     def sampling_route(self) -> dict:
         """How every sampling call of the served programs selects its

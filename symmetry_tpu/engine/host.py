@@ -454,19 +454,10 @@ class EngineHost:
                 "compile_cache": cache_dir,
                 "device": device_report(),
                 "attention": self._engine.attention_paths(),
-                "sampling": self._engine.sampling_route()}
-            moe = self._engine.moe_report()
-            if moe is not None:
-                self._startup["moe"] = moe
-            ssm = self._engine.ssm_report()
-            if ssm is not None:
-                self._startup["ssm"] = ssm
-            diffusion = self._engine.diffusion_report()
-            if diffusion is not None:
-                self._startup["diffusion"] = diffusion
-            cache = self._engine.cache_report()
-            if cache is not None:
-                self._startup["cache"] = cache
+                "sampling": self._engine.sampling_route(),
+                # what this model brings of its own (`moe`, `ssm`,
+                # `diffusion`, `cache`)
+                **self._engine.startup_reports()}
             warm = self._engine.warmup_report()
             self._startup["warmup"] = warm
         # The timeline, frozen: the stamp `ready` is the one that ended
@@ -491,6 +482,7 @@ class EngineHost:
         # Startup breakdown to stderr: a slow start must carry its own
         # explanation in the provider log (round-3 verdict #1).
         dev, attn = self._startup["device"], self._startup["attention"]
+        moe = self._startup.get("moe")
         samp = json.dumps(self._startup["sampling"], separators=(",", ":"))
         hbm = " ".join(f"{h['bytes_in_use'] / 2**30:.2f}/"
                        f"{h['bytes_limit'] / 2**30:.2f}GiB"
@@ -566,10 +558,6 @@ class EngineHost:
                 m["emit"] = dict(self.emit_stats)
                 m["role"] = self._role
                 m["startup"] = self._startup
-                if "ssm" in self._startup:
-                    # valid prompt tokens the recurrent layers scanned and
-                    # lanes whose state an insert overwrote, since start
-                    m["ssm"] = dict(self._engine.ssm_counters)
                 m["compile"] = self._compile.stats()
                 # Per-request emitted-token journal rider: the tokens
                 # each live stream has had WRITTEN to the pipe. The

@@ -654,36 +654,11 @@ class Scheduler:
                          "recent": list(self._stalls_recent)}
         if self._adopt_hist.count:
             out["adopt_dispatch_s"] = self._adopt_hist.to_dict()
-        if getattr(self.engine, "expert_pairs", None):
-            # Valid (token, expert) pairs computed since start, per
-            # expert and in all, as of the last synced decode block; the
-            # form each program kind's expert FFN takes (models/moe.py).
-            out["moe"] = {**self.engine.moe_counts(),
-                          "route": self.engine.moe_report()["route"]}
-        if self._bd_block is not None:
-            # generation by diffusion over blocks: the settings, the
-            # forwards dispatched and what of their tokens reached a
-            # stream, as of the last entry read
-            report = self.engine.diffusion_report()
-            out["diffusion"] = {
-                **{k: report[k] for k in ("block", "steps", "rule",
-                                          "threshold")},
-                **self.engine.diffusion,
-                "opening_block_tokens": dict(
-                    self.engine.diffusion["opening_block_tokens"])}
-        if getattr(self.engine, "mla", None) is not None:
-            # latent attention: decode forwards, the live rows they read
-            # and the tokens prefilled expanded, as of the same block
-            out["mla"] = dict(self.engine.mla)
-        if getattr(self.engine, "swa", None) is not None:
-            # window + full attention: decode forwards, the rows each kind
-            # read in them, ring wraps, tokens prefilled, as of the same
-            # block
-            out["swa"] = dict(self.engine.swa)
-        if getattr(self.engine, "dsa", None) is not None:
-            # learned sparse attention: queries, the positions they could
-            # select from and those they selected, as of the same block
-            out["dsa"] = dict(self.engine.dsa)
+        blocks = getattr(self.engine, "stats_blocks", None)
+        if blocks is not None:
+            # what the model's slots keep and count (engine.stats_blocks:
+            # a block a row of models/residents.py, `moe`, `diffusion`)
+            out.update(blocks())
         # Gauges for the two admission backlogs that were invisible in
         # host→provider stats: the budget-deferred deque and the
         # chunked-prefill jobs still building their prefixes.

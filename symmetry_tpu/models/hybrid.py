@@ -158,20 +158,17 @@ def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
     n_attn = len(config.layers_of(config.attention_kind))
     ssm, conv = state_shapes(config, batch)
     shape = (n_attn, batch, capacity, *llama.kv_row(config))
+    pairs = llama.init_expert_pairs(config) if count_experts else None
     if config.latent is not None:
         if quantized:
             raise ValueError("a latent cache row has no int8 form")
-        # the row is all a position keeps; the expert counter ends in the
-        # latent counters (llama.LATENT_COUNTS), as a sparse model's does
+        # the row is all a position keeps
         return llama.KVCache(
             k=jnp.zeros(shape, dtype), v=None,
-            lengths=jnp.zeros((batch,), jnp.int32),
-            expert_pairs=(jnp.zeros((config.num_experts
-                                     + len(llama.LATENT_COUNTS),), jnp.int32)
-                          if count_experts else None))
+            lengths=jnp.zeros((batch,), jnp.int32), expert_pairs=pairs)
     scale_shape = (n_attn, batch, config.num_kv_heads, capacity)
     kv_dtype = jnp.int8 if quantized else dtype
-    window, counts = {}, 0
+    window = {}
     if config.window_kind is not None:
         # the window layers' leaves beside the full layers': a ring of the
         # window's rows — or, for a prefill scratch (`ring` None), as many
@@ -184,17 +181,13 @@ def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
             kw=jnp.zeros(wshape, kv_dtype), vw=jnp.zeros(wshape, kv_dtype),
             kw_scale=jnp.zeros(wscale, jnp.float32) if quantized else None,
             vw_scale=jnp.zeros(wscale, jnp.float32) if quantized else None)
-        counts = len(llama.WINDOW_COUNTS)  # the expert counter's tail
-    if config.experts_held is not None:
-        counts = len(llama.HELD_COUNTS)     # a share counts its hits
     return llama.KVCache(
         k=jnp.zeros(shape, kv_dtype),
         v=jnp.zeros(shape, kv_dtype),
         lengths=jnp.zeros((batch,), jnp.int32),
         k_scale=jnp.zeros(scale_shape, jnp.float32) if quantized else None,
         v_scale=jnp.zeros(scale_shape, jnp.float32) if quantized else None,
-        expert_pairs=(jnp.zeros((config.num_experts + counts,), jnp.int32)
-                      if count_experts else None),
+        expert_pairs=pairs,
         ssm=jnp.zeros(ssm, jnp.float32) if ssm else None,
         conv=jnp.zeros(conv, dtype) if conv else None,
         **window,
@@ -522,84 +515,6 @@ def _init_window(c, keys, dense, dtype) -> dict:
     if not c.tie_embeddings:
         params["lm_head"] = dense((E, c.vocab_size), "lm_head", scale=0.02)
     return params
-
-
-def window_refusals(*, mesh: bool = False, role: str = "unified",
-                    prefix_cache: bool = False, speculative: bool = False,
-                    prefill_chunk: int | None = None) -> list[str]:
-    """Why a model with window AND full attention layers (a ring of the
-    window's rows a window layer beside a full row a full layer) cannot be
-    served under these settings: one sentence a setting, empty when it can
-    (the engine and provider/config.py ask, as of `state_refusals`)."""
-    why = []
-    if prefix_cache:
-        why.append(
-            "tpu.prefix_cache_mb: the block pool knows one entry shape at "
-            "one capacity, and a window layer's ring holds a slot's last "
-            "positions alone, so a stored prefix would come back without "
-            "the rows its window layers need — leave it unset for a model "
-            "with window and full attention layers")
-    if speculative:
-        why.append(
-            "tpu.speculative: a ring row that a rejected draft overwrote "
-            "held a key still inside the window, and rolling the lengths "
-            "back does not bring it back — leave it unset for a model with "
-            "window and full attention layers")
-    if prefill_chunk is not None:
-        why.append(
-            f"tpu.prefill_chunk {prefill_chunk}: a chunk's later positions "
-            f"overwrite ring rows its earlier queries still need (a ring of "
-            f"the window's rows has no room for a chunk beside it) — set "
-            f"prefill_chunk: null for a model with window and full "
-            f"attention layers (prompts prefill whole, up to the largest "
-            f"bucket)")
-    if role != "unified":
-        why.append(
-            f"tpu.role {role!r}: the KV handoff frame carries one K and one "
-            f"V plane at one capacity and has no place for the rings — a "
-            f"model with window and full attention layers serves unified")
-    if mesh:
-        why.append(
-            "tpu.mesh: the ring leaves have no sharding rules yet — a model "
-            "with window and full attention layers runs on one device")
-    return why
-
-
-def state_refusals(*, mesh: bool = False, role: str = "unified",
-                   prefix_cache: bool = False, speculative: bool = False,
-                   prefill_chunk: int | None = None) -> list[str]:
-    """Why a model with recurrent layers (a per-slot state beside the K/V
-    rows) cannot be served under these settings: one sentence a setting,
-    empty when it can. The engine raises the first as an EngineError;
-    provider/config.py asks the same of a preset before anything is built
-    and raises it as a ConfigError."""
-    why = []
-    if prefix_cache:
-        why.append(
-            "tpu.prefix_cache_mb: a cached prefix holds K/V rows and no "
-            "recurrent state, so a hit would resume the recurrent layers "
-            "from nothing — leave it unset for a model with recurrent layers")
-    if speculative:
-        why.append(
-            "tpu.speculative: a rejected draft is rolled back by lengths "
-            "alone, and the recurrent state has already advanced past it — "
-            "leave it unset for a model with recurrent layers")
-    if prefill_chunk is not None:
-        why.append(
-            f"tpu.prefill_chunk {prefill_chunk}: the chunk programs are "
-            f"not shown to carry the recurrent state from chunk to chunk — "
-            f"set prefill_chunk: null for a model with recurrent layers "
-            f"(prompts prefill whole, up to the largest bucket)")
-    if role != "unified":
-        why.append(
-            f"tpu.role {role!r}: the KV handoff frame has no place for the "
-            f"recurrent state — a model with recurrent layers serves "
-            f"unified")
-    if mesh:
-        why.append(
-            "tpu.mesh: the recurrent state has no sharding rules yet — a "
-            "model with recurrent layers runs on one device")
-    return why
 
 
 def runs(config) -> list[tuple[str, int, int]]:

@@ -951,6 +951,16 @@ def ring_restore(cache: KVCache, view: KVCache) -> KVCache:
                          kw_scale=view.k_scale, vw_scale=view.v_scale)
 
 
+def init_expert_pairs(config) -> jnp.ndarray:
+    """A zeroed `KVCache.expert_pairs`: a count an expert the router scores,
+    then the words of whatever else the model counts there
+    (models/residents.py tail_words: the ONE definition of the tail)."""
+    from symmetry_tpu.models.residents import tail_words
+
+    return jnp.zeros((config.num_experts + len(tail_words(config)),),
+                     jnp.int32)
+
+
 def init_cache(
     config: ModelConfig, batch: int, capacity: int, dtype=jnp.bfloat16,
     *, quantized: bool = False, count_experts: bool = False,
@@ -966,17 +976,12 @@ def init_cache(
                                  count_experts=count_experts,
                                  **({} if ring is None else {"ring": ring}))
     shape = (config.num_layers, batch, capacity, *kv_row(config))
-    pairs = (jnp.zeros((config.num_experts,), jnp.int32)
-             if count_experts else None)
+    pairs = init_expert_pairs(config) if count_experts else None
     extra = {}
     sparse = getattr(config, "sparse", None)
     if sparse is not None:
-        from symmetry_tpu.ops.sparse_attention import N_COUNTS
-
         extra["idx"] = jnp.zeros((config.num_layers, batch, capacity,
                                   sparse.index_head_dim), dtype)
-        if count_experts:
-            pairs = jnp.zeros((config.num_experts + N_COUNTS,), jnp.int32)
     if quantized:
         scale_shape = (config.num_layers, batch, config.num_kv_heads,
                        capacity)
@@ -1501,53 +1506,6 @@ def _latent_paths(latent: LatentAttention, capacity: int, kernel: str,
     return {**out, "decode": kernel, "decode_block_t": block_t}
 
 
-def latent_refusals(*, mesh: bool = False, role: str = "unified",
-                    prefix_cache: bool = False, speculative: bool = False,
-                    prefill_chunk: int | None = None,
-                    kv_quant: bool = False) -> list[str]:
-    """Why a model with latent attention (one row of rank + rope values a
-    cached position, no K or V a head) cannot be served under these
-    settings: one sentence a setting, empty when it can (the engine and
-    provider/config.py ask, as of `sparse_refusals`)."""
-    why = []
-    if prefill_chunk is not None:
-        why.append(
-            f"tpu.prefill_chunk {prefill_chunk}: a chunk's queries attend "
-            f"over cached latents, for which there is no flash kernel with "
-            f"a key offset that expands them block by block (the jnp form "
-            f"holds [chunk, capacity] float32 scores a head) — set "
-            f"prefill_chunk: null for a model with latent attention "
-            f"(prompts prefill whole, up to the largest bucket)")
-    if prefix_cache:
-        why.append(
-            "tpu.prefix_cache_mb: the block pool knows one entry shape (K, "
-            "V a head and their scales) and has no block of latent rows — "
-            "leave it unset for a model with latent attention")
-    if speculative:
-        why.append(
-            "tpu.speculative: the verify program over latent rows (several "
-            "positions a slot, absorbed) is not shown to pick what the "
-            "single-position kernel picks — leave it unset for a model "
-            "with latent attention")
-    if role != "unified":
-        why.append(
-            f"tpu.role {role!r}: the KV handoff frame carries K and V "
-            f"planes a head and has no plane for a latent row — a model "
-            f"with latent attention serves unified")
-    if mesh:
-        why.append(
-            "tpu.mesh: the latent row has no head axis to shard and the "
-            "absorbed factors have no sharding rules yet — a model with "
-            "latent attention runs on one device")
-    if kv_quant:
-        why.append(
-            "tpu.kv_quantization int8: a latent row has no head axis for "
-            "the scale planes and no int8 form of the absorbed kernel "
-            "exists — set kv_quantization: null for a model with latent "
-            "attention (the row is cached in the compute dtype)")
-    return why
-
-
 def sparse_forms(paths: dict) -> dict:
     """What each program's attention is under a learned selection, from
     `attention_paths`' routes — every one the lossless MASKED form (each
@@ -1578,82 +1536,6 @@ def sparse_select(paths: dict, capacity: int, slots: int,
                                       index_dim) is not None)}
 
 
-def sparse_refusals(*, mesh: bool = False, role: str = "unified",
-                    prefix_cache: bool = False, speculative: bool = False,
-                    prefill_chunk: int | None = None) -> list[str]:
-    """Why a model with learned sparse attention (an index key a cached
-    position beside its K/V row) cannot be served under these settings:
-    one sentence a setting, empty when it can. The engine raises the first
-    as an EngineError; provider/config.py asks the same of a preset before
-    anything is built and raises it as a ConfigError."""
-    why = []
-    if prefix_cache:
-        why.append(
-            "tpu.prefix_cache_mb: the block pool knows one entry shape (K, "
-            "V and their scales) and would hand a hit back without its "
-            "index keys — leave it unset for a model with sparse attention")
-    if speculative:
-        why.append(
-            "tpu.speculative: the verify program over a selection is not "
-            "shown to pick what the single-position steps pick — leave it "
-            "unset for a model with sparse attention")
-    if prefill_chunk is not None:
-        why.append(
-            f"tpu.prefill_chunk {prefill_chunk}: a chunk over a non-empty "
-            f"cache selects on the XLA path alone ([B, S, T] scores and the "
-            f"threshold's passes through HBM), which no chip run has driven "
-            f"at long contexts — set prefill_chunk: null for a model with "
-            f"sparse attention (prompts prefill whole, up to the largest "
-            f"bucket)")
-    if role != "unified":
-        why.append(
-            f"tpu.role {role!r}: the KV handoff frame has no place for the "
-            f"index keys — a model with sparse attention serves unified")
-    if mesh:
-        why.append(
-            "tpu.mesh: the index cache and the selection have no sharding "
-            "rules yet — a model with sparse attention runs on one device")
-    return why
-
-
-def diffusion_refusals(*, mesh: bool = False, role: str = "unified",
-                       prefix_cache: bool = False, speculative: bool = False,
-                       prefill_chunk: int | None = None) -> list[str]:
-    """Why a model that generates by diffusion over blocks cannot be served
-    under these settings: one sentence a setting, empty when it can (the
-    engine and provider/config.py ask, as of `sparse_refusals`)."""
-    why = []
-    if prefix_cache:
-        why.append(
-            "tpu.prefix_cache_mb: a hit would have to end on a block "
-            "boundary of the NEW prompt too (the left-over tokens open the "
-            "first generated block), which the radix lookup does not know — "
-            "leave it unset for a model that generates by diffusion")
-    if speculative:
-        why.append(
-            "tpu.speculative: a denoise forward already yields several "
-            "positions a stream and nothing is drafted ahead of it — leave "
-            "it unset for a model that generates by diffusion")
-    if prefill_chunk is not None:
-        why.append(
-            f"tpu.prefill_chunk {prefill_chunk}: a chunk's last position "
-            f"yields no token here and the opening block is denoised by the "
-            f"admission program, which the chunk programs do not do — set "
-            f"prefill_chunk: null for a model that generates by diffusion "
-            f"(prompts prefill whole, up to the largest bucket)")
-    if role != "unified":
-        why.append(
-            f"tpu.role {role!r}: the handoff carries one first token, not "
-            f"an opening block — a model that generates by diffusion serves "
-            f"unified")
-    if mesh:
-        why.append(
-            "tpu.mesh: the block programs are not shown equal to the "
-            "reference on a sharded trunk — a model that generates by "
-            "diffusion runs on one device")
-    return why
-
-
 def _attention(
     x: jnp.ndarray,             # [B, S, E], already normed
     lp: dict,                   # one layer's params (leading L dim stripped)
@@ -1664,8 +1546,6 @@ def _attention(
     seq_lens: jnp.ndarray,      # [B] valid tokens in this call's input
     config: ModelConfig,
     prefill_flash: bool,        # static: flash self-attention (fresh cache)
-    ring_mesh=None,             # static: Mesh => sequence-parallel prefill
-    sp_mode: str = "ring",      # static: "ring" | "ulysses" (SURVEY §5.7)
     tp_mesh=None,               # static: Mesh the trunk is GSPMD-sharded over
     rope_positions=None,        # [3, B, S] multimodal rotary components
     rope: bool | None = None,   # static: this LAYER's rotary choice (None:
@@ -1693,9 +1573,9 @@ def _attention(
     if rope is None:
         rope = getattr(config, "rope", True)
     if getattr(config, "latent", None) is not None:
-        if ring_mesh is not None or tp_mesh is not None:
+        if tp_mesh is not None:
             raise ValueError("a model with latent attention runs on one "
-                             "device: no ring_mesh, no tp_mesh")
+                             "device: no tp_mesh")
         return _latent_attention(x, lp, cache, layer, positions, kv_valid,
                                  seq_lens, config, prefill_flash)
     B, S, E = x.shape
@@ -1753,9 +1633,9 @@ def _attention(
         # normed input; plain rotary by the temporal component
         from symmetry_tpu.ops import sparse_attention as sa
 
-        if ring_mesh is not None or tp_mesh is not None:
+        if tp_mesh is not None:
             raise ValueError("a model with learned sparse attention runs "
-                             "on one device: no ring_mesh, no tp_mesh")
+                             "on one device: no tp_mesh")
         t_pos = positions if rope_positions is None else rope_positions[0]
         qi = apply_rope(
             qmatmul(x, lp["wqi"]).reshape(B, S, sparse.index_heads,
@@ -1767,122 +1647,108 @@ def _attention(
         cache = write_kv(cache, layer, positions, k, v, by_head=False,
                          idx=ki)
 
-    if ring_mesh is not None:
-        # Long-context prefill: sequence sharded over the `context` mesh
-        # axis — K/V blocks rotating on ICI (parallel/ring.py), or one
-        # all-to-all head scatter when heads divide the shard count
-        # (parallel/ulysses.py).
-        if sp_mode == "ulysses":
-            from symmetry_tpu.parallel.ulysses import ulysses_attention
+    paths = attention_paths(
+        config, cache.k.shape[2], tp_mesh, batch=cache.k.shape[1],
+        kv_bytes=cache.k.dtype.itemsize,
+        **({"kind": "window" if window else "full"} if windowed else {}))
 
-            attn = ulysses_attention(q, k, v, seq_lens, ring_mesh)
+    def at_layer(arr):
+        return jax.lax.dynamic_index_in_dim(arr, layer, 0,
+                                            keepdims=False)
+
+    def heads_at_layer(arr):  # pair-folded rows (kv_row) as [K, D]
+        return at_layer(arr).reshape(*arr.shape[1:3], nkv, D)
+
+    flash_route = prefill_flash and paths["prefill"] != "xla"
+    keep = None
+    if sparse is not None:
+        # each query's own keep-set: over this call's index keys on
+        # the flash route ([B, S, S] int8), over the cached ones
+        # ([B, S, T] bool) on the two routes that read the cache —
+        # the whole index cache and the layer, so that a decode step
+        # reads each slot's live keys where they lie
+        keep, n_sel = (
+            sa.prefill_keep(qi, ki, wi, seq_lens, sparse.topk)
+            if flash_route else
+            sa.cache_keep(qi, cache.idx, wi, positions, kv_valid,
+                          sparse.topk, layer=layer))
+        if cache.expert_pairs is not None:
+            cache = cache._replace(
+                expert_pairs=sa.add_counts(cache.expert_pairs, n_sel))
+    if flash_route:
+        # Prefill-from-empty: attention is over this call's own K/V —
+        # the Pallas kernel streams K/V blocks through VMEM instead of
+        # materializing [H, S, S] scores (ops/flash.py); the cache
+        # slice is never read back. Sliding-window models restrict the
+        # kernel's block range to the window.
+        from symmetry_tpu.ops import flash
+
+        kw = dict(window=window,
+                  interpret=interpret_mode(), **blocked)
+        if keep is not None:
+            # (the keep-set carries causality and the prompt's length)
+            attn = sa.flash_sparse(q, k, v, keep,
+                                   interpret=interpret_mode())
+        elif windowed and flash.wide_takes(window):
+            # prompts of 5-8k tokens: the 512-tile walk, its K/V of one
+            # KV head shared by the head's group, a window layer's
+            # tiles bounded by the window (PERF.md, PR 58)
+            attn = flash.flash_prefill_wide(
+                q, k, v, seq_lens, window=window,
+                interpret=interpret_mode())
+        elif tp_mesh is None:
+            attn = flash.flash_prefill(q, k, v, seq_lens, **kw)
         else:
-            from symmetry_tpu.parallel.ring import ring_attention
+            attn = flash.flash_prefill_tp(q, k, v, seq_lens,
+                                          mesh=tp_mesh, **kw)
+    elif paths["decode"] != "xla" and S == paths.get("decode_queries",
+                                                     1):
+        # Decode — one position a slot, or a block-diffusion forward's
+        # block of them from a block boundary, which all see the same
+        # keys: the Pallas kernel reads only each slot's occupied KV
+        # prefix; the full cache is its operand and stays where it
+        # lies, layer and slot are DMA addressing
+        # (ops/decode_attention.py).
+        from symmetry_tpu.ops import decode_attention as da
 
-            attn = ring_attention(q, k, v, seq_lens, ring_mesh)
+        scales = ((cache.k_scale, cache.v_scale) if cache.quantized
+                  else (None, None))
+        if S > 1 and cache.quantized:
+            # a block's forwards hand the kernel this layer's scale
+            # planes (1.3 MB each at 128 x 640, read out of XLA's fast
+            # memory): the whole arrays are small for a model this
+            # shallow, XLA keeps them there across the layer loop for
+            # the in-place scatters, and the kernel's pin to HBM on
+            # them has it evict and refetch both every layer — at
+            # sdar's twelve layers the v5e compiler's repacker crashes
+            # on that program (tests/test_chip_compile.py compiles it)
+            scales = tuple(at_layer(x)[None] for x in scales)
+        args = (q[:, 0] if S == 1 else q, cache.k, cache.v, layer,
+                # a ring holds the window's keys and no other: every
+                # written row is live, whichever position it holds
+                jnp.minimum(kv_valid, cache.k.shape[2]) if ring
+                else kv_valid, *scales)
+        if keep is not None:
+            args += (keep[:, 0],)
+        kw = dict(window=None if ring else window,
+                  interpret=interpret_mode(),
+                  # (named in a trace for this model alone: no other
+                  # model's call moves)
+                  **({"name": da.WINDOW_NAME} if windowed else {}))
+        attn = (da.decode_attention(*args, **kw) if tp_mesh is None
+                else da.decode_attention_tp(*args, mesh=tp_mesh, **kw))
+        attn = attn[:, None] if S == 1 else attn
     else:
-        paths = attention_paths(
-            config, cache.k.shape[2], tp_mesh, batch=cache.k.shape[1],
-            kv_bytes=cache.k.dtype.itemsize,
-            **({"kind": "window" if window else "full"} if windowed else {}))
-
-        def at_layer(arr):
-            return jax.lax.dynamic_index_in_dim(arr, layer, 0,
-                                                keepdims=False)
-
-        def heads_at_layer(arr):  # pair-folded rows (kv_row) as [K, D]
-            return at_layer(arr).reshape(*arr.shape[1:3], nkv, D)
-
-        flash_route = prefill_flash and paths["prefill"] != "xla"
-        keep = None
-        if sparse is not None:
-            # each query's own keep-set: over this call's index keys on
-            # the flash route ([B, S, S] int8), over the cached ones
-            # ([B, S, T] bool) on the two routes that read the cache —
-            # the whole index cache and the layer, so that a decode step
-            # reads each slot's live keys where they lie
-            keep, n_sel = (
-                sa.prefill_keep(qi, ki, wi, seq_lens, sparse.topk)
-                if flash_route else
-                sa.cache_keep(qi, cache.idx, wi, positions, kv_valid,
-                              sparse.topk, layer=layer))
-            if cache.expert_pairs is not None:
-                cache = cache._replace(
-                    expert_pairs=sa.add_counts(cache.expert_pairs, n_sel))
-        if flash_route:
-            # Prefill-from-empty: attention is over this call's own K/V —
-            # the Pallas kernel streams K/V blocks through VMEM instead of
-            # materializing [H, S, S] scores (ops/flash.py); the cache
-            # slice is never read back. Sliding-window models restrict the
-            # kernel's block range to the window.
-            from symmetry_tpu.ops import flash
-
-            kw = dict(window=window,
-                      interpret=interpret_mode(), **blocked)
-            if keep is not None:
-                # (the keep-set carries causality and the prompt's length)
-                attn = sa.flash_sparse(q, k, v, keep,
-                                       interpret=interpret_mode())
-            elif windowed and flash.wide_takes(window):
-                # prompts of 5-8k tokens: the 512-tile walk, its K/V of one
-                # KV head shared by the head's group, a window layer's
-                # tiles bounded by the window (PERF.md, PR 58)
-                attn = flash.flash_prefill_wide(
-                    q, k, v, seq_lens, window=window,
-                    interpret=interpret_mode())
-            elif tp_mesh is None:
-                attn = flash.flash_prefill(q, k, v, seq_lens, **kw)
-            else:
-                attn = flash.flash_prefill_tp(q, k, v, seq_lens,
-                                              mesh=tp_mesh, **kw)
-        elif paths["decode"] != "xla" and S == paths.get("decode_queries",
-                                                         1):
-            # Decode — one position a slot, or a block-diffusion forward's
-            # block of them from a block boundary, which all see the same
-            # keys: the Pallas kernel reads only each slot's occupied KV
-            # prefix; the full cache is its operand and stays where it
-            # lies, layer and slot are DMA addressing
-            # (ops/decode_attention.py).
-            from symmetry_tpu.ops import decode_attention as da
-
-            scales = ((cache.k_scale, cache.v_scale) if cache.quantized
-                      else (None, None))
-            if S > 1 and cache.quantized:
-                # a block's forwards hand the kernel this layer's scale
-                # planes (1.3 MB each at 128 x 640, read out of XLA's fast
-                # memory): the whole arrays are small for a model this
-                # shallow, XLA keeps them there across the layer loop for
-                # the in-place scatters, and the kernel's pin to HBM on
-                # them has it evict and refetch both every layer — at
-                # sdar's twelve layers the v5e compiler's repacker crashes
-                # on that program (tests/test_chip_compile.py compiles it)
-                scales = tuple(at_layer(x)[None] for x in scales)
-            args = (q[:, 0] if S == 1 else q, cache.k, cache.v, layer,
-                    # a ring holds the window's keys and no other: every
-                    # written row is live, whichever position it holds
-                    jnp.minimum(kv_valid, cache.k.shape[2]) if ring
-                    else kv_valid, *scales)
-            if keep is not None:
-                args += (keep[:, 0],)
-            kw = dict(window=None if ring else window,
-                      interpret=interpret_mode(),
-                      # (named in a trace for this model alone: no other
-                      # model's call moves)
-                      **({"name": da.WINDOW_NAME} if windowed else {}))
-            attn = (da.decode_attention(*args, **kw) if tp_mesh is None
-                    else da.decode_attention_tp(*args, mesh=tp_mesh, **kw))
-            attn = attn[:, None] if S == 1 else attn
-        else:
-            attn = gqa_attention(
-                q, heads_at_layer(cache.k), heads_at_layer(cache.v),
-                positions, kv_valid,
-                sliding_window=window,
-                k_scale=at_layer(cache.k_scale) if cache.quantized else None,
-                v_scale=at_layer(cache.v_scale) if cache.quantized else None,
-                keep=keep, **blocked,
-                **({"kv_positions": ring_positions(kv_valid,
-                                                   cache.k.shape[2])}
-                   if ring else {}))
+        attn = gqa_attention(
+            q, heads_at_layer(cache.k), heads_at_layer(cache.v),
+            positions, kv_valid,
+            sliding_window=window,
+            k_scale=at_layer(cache.k_scale) if cache.quantized else None,
+            v_scale=at_layer(cache.v_scale) if cache.quantized else None,
+            keep=keep, **blocked,
+            **({"kv_positions": ring_positions(kv_valid,
+                                               cache.k.shape[2])}
+               if ring else {}))
     attn = attn.reshape(B, S, nq * D)
     if gate is not None:
         attn = attn * jax.nn.sigmoid(gate)
@@ -2015,8 +1881,6 @@ def _layer(
     seq_lens: jnp.ndarray,
     config: ModelConfig,
     prefill_flash: bool,
-    ring_mesh=None,
-    sp_mode: str = "ring",
     tp_mesh=None,
     rope_positions=None,
     stack=None,
@@ -2027,7 +1891,6 @@ def _layer(
     x = rms_norm(h, _norm_w(lp["attn_norm"], config), config.rms_eps)
     attn, cache = _attention(x, lp, cache, layer, positions, kv_valid,
                              seq_lens, config, prefill_flash,
-                             ring_mesh=ring_mesh, sp_mode=sp_mode,
                              tp_mesh=tp_mesh, rope_positions=rope_positions)
     h = h + attn
 
@@ -2086,8 +1949,6 @@ def forward_hidden(
     seq_lens: jnp.ndarray | None = None,  # [B] valid tokens in `tokens`; None = all S
     *,
     prefill_flash: bool = False,  # static: caller guarantees cache is empty
-    ring_mesh=None,               # static: context-parallel prefill mesh
-    sp_mode: str = "ring",        # static: "ring" | "ulysses"
     tp_mesh=None,                 # static: Mesh the arrays are sharded over
     rope_positions: jnp.ndarray | None = None,  # [3, B, S] (mrope models)
 ) -> tuple[jnp.ndarray, KVCache]:
@@ -2110,24 +1971,16 @@ def forward_hidden(
 
     prefill_flash=True routes attention through the Pallas flash kernel.
     VALID ONLY when cache.lengths are all zero (engine prefill's case) —
-    both fast paths attend to this call's own K/V, not the cache.
-    ring_mesh additionally shards the sequence over the mesh's `context`
-    axis; it requires prefill_flash's empty-cache contract and S divisible
-    by the shard count. sp_mode picks the scheme: "ring" rotates K/V
-    blocks (parallel/ring.py, any head count), "ulysses" head-scatters via
-    one all-to-all (parallel/ulysses.py, needs kv_heads % shards == 0).
+    the kernel attends to this call's own K/V, not the cache.
     Sliding-window models (mistral-v0.1) use the window-bounded flash
-    kernel for prefill. The ring/ulysses schemes do not support windows:
-    with ring_mesh set, a sliding-window model runs the (non-sequence-
-    parallel) flash kernel instead — callers needing SP for windowed
-    models must shard some other way.
+    kernel for prefill.
     """
     if getattr(config, "layer_types", None):
         from symmetry_tpu.models import hybrid
 
-        if ring_mesh is not None or tp_mesh is not None:
+        if tp_mesh is not None:
             raise ValueError("a model with recurrent layers runs on one "
-                             "device: no ring_mesh, no tp_mesh")
+                             "device: no tp_mesh")
         return hybrid.forward_hidden(params, config, tokens, cache, seq_lens,
                                      prefill_flash=prefill_flash)
     B, S = tokens.shape
@@ -2135,17 +1988,9 @@ def forward_hidden(
         seq_lens = jnp.full((B,), S, jnp.int32)
     positions = cache.lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     kv_valid = cache.lengths + seq_lens
-    if ring_mesh is not None and not prefill_flash:
-        # Ring q/kv positions start at 0 and ignore cached entries — only
-        # the prefill-from-empty contract makes that correct. Fail loudly
-        # rather than silently mis-attend on a continuation call.
-        raise ValueError("ring_mesh requires prefill_flash=True "
-                         "(prefill-from-empty contract)")
-    use_ring = ring_mesh if (ring_mesh is not None and S > 1
-                             and config.sliding_window is None) else None
-    # Flash prefill handles sliding windows natively (window-bounded block
-    # range); only the ring path still requires global attention.
-    use_flash = prefill_flash and use_ring is None and S > 1
+    # (flash prefill handles sliding windows natively: a window-bounded
+    # block range)
+    use_flash = prefill_flash and S > 1
 
     n_stacked = jax.tree.leaves(params["layers"])[0].shape[0]
     if n_stacked != config.num_layers:
@@ -2165,8 +2010,7 @@ def forward_hidden(
                          "config has no mrope_section")
     h, new_cache = run_layers(params["layers"], h, cache, positions,
                               kv_valid, seq_lens, config,
-                              use_flash=use_flash, use_ring=use_ring,
-                              sp_mode=sp_mode, tp_mesh=tp_mesh,
+                              use_flash=use_flash, tp_mesh=tp_mesh,
                               rope_positions=rope_positions)
     h = rms_norm(h, _norm_w(params["final_norm"], config), config.rms_eps)
     return h, new_cache._replace(lengths=kv_valid)
@@ -2182,8 +2026,6 @@ def run_layers(
     config: ModelConfig,
     *,
     use_flash: bool = False,
-    use_ring=None,
-    sp_mode: str = "ring",
     tp_mesh=None,
     rope_positions=None,
 ) -> tuple[jnp.ndarray, KVCache]:
@@ -2202,8 +2044,7 @@ def run_layers(
         h, c = carry
         lp, l = xs
         h, c = _layer(h, lp, c, l, positions, kv_valid,
-                      seq_lens, config, use_flash, ring_mesh=use_ring,
-                      sp_mode=sp_mode, tp_mesh=tp_mesh,
+                      seq_lens, config, use_flash, tp_mesh=tp_mesh,
                       rope_positions=rope_positions,
                       stack=stacks and (stacks, l))
         return (h, c), None

@@ -1,0 +1,263 @@
+"""What a slot keeps beyond K and V a head at one capacity: ONE table.
+
+The engine, the scheduler, the host and provider/config.py know nothing of
+recurrent states, index keys, latent rows, rings or diffusion blocks by
+name. They ask this table three things: which settings a model that keeps
+such a thing cannot be served under and why (`refusals`), what its
+`KVCache.expert_pairs` ends in (`tail_words`), and which rows a config has
+(`kept`). A new mechanism adds a ROW here beside its model code; a
+subsystem that learns to carry a row's thing deletes that row's reason.
+
+The rows are data. A row's `reasons` are the useful part of a refusal —
+why THIS thing cannot ride the block pool, a rolled-back draft, a chunk, the
+handoff frame, a mesh — and `SETTINGS` wraps each in the words every row
+shares. Importing this file builds the table and nothing else (the
+provider asks it before a host is spawned).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, NamedTuple
+
+from symmetry_tpu.models.llama import (
+    HELD_COUNTS, LATENT_COUNTS, WINDOW_COUNTS)
+
+
+class Resident(NamedTuple):
+    name: str                       # what the slot keeps
+    of: Callable[[Any], Any]        # config -> what it says of it, or None
+    phrase: str | None              # what the sentences call such a model
+    reasons: dict[str, str]         # setting -> why it cannot carry this
+    block: str                      # `stats.engine.<block>` takes its counts
+    # the words it appends to `expert_pairs` (the device function that
+    # writes them adds to "the last n words"), and what reads them back
+    # where they are not plain counts
+    words: tuple[str, ...] = ()
+    decode: Callable[[Any], dict[str, int]] | None = None
+    host_counts: tuple[str, ...] = ()   # what the engine counts on the host
+
+    def counters(self) -> tuple[str, ...]:
+        """The names of this row's block, device-counted then host-counted."""
+        device = (self.words if self.decode is None
+                  else tuple(self.decode((0,) * len(self.words))))
+        return device + self.host_counts
+
+
+def _index_counts(tail) -> dict[str, int]:
+    # imported where asked: the module brings Pallas with it, which a
+    # provider process that validates a config never needs
+    from symmetry_tpu.ops.sparse_attention import read_counts
+
+    return read_counts(tail)
+
+
+# setting -> (what its sentence opens with, what it tells a {phrase} to do);
+# in the order the sentences of one row come out. (An int8 cache is refused
+# by the latent row alone: its advice is that row's.)
+SETTINGS = {
+    "prefix_cache": ("tpu.prefix_cache_mb", "leave it unset for {phrase}"),
+    "speculative": ("tpu.speculative", "leave it unset for {phrase}"),
+    "prefill_chunk": (
+        "tpu.prefill_chunk {value}",
+        "set prefill_chunk: null for {phrase} (prompts prefill whole, up "
+        "to the largest bucket)"),
+    "role": ("tpu.role {value!r}", "{phrase} serves unified"),
+    "mesh": ("tpu.mesh", "{phrase} runs on one device"),
+    "kv_quant": (
+        "tpu.kv_quantization int8",
+        "set kv_quantization: null for {phrase} (the row is cached in the "
+        "compute dtype)"),
+}
+
+RESIDENTS = (
+    # a per-slot state that is no row a position (models/hybrid.py: the
+    # `ssm` / `conv` leaves), whatever the recurrent kind
+    Resident(
+        name="recurrent state",
+        of=lambda c: (c.recurrent_kind
+                      if getattr(c, "layer_types", None) else None),
+        phrase="a model with recurrent layers",
+        reasons={
+            "prefix_cache": (
+                "a cached prefix holds K/V rows and no recurrent state, so "
+                "a hit would resume the recurrent layers from nothing"),
+            "speculative": (
+                "a rejected draft is rolled back by lengths alone, and the "
+                "recurrent state has already advanced past it"),
+            "prefill_chunk": (
+                "the chunk programs are not shown to carry the recurrent "
+                "state from chunk to chunk"),
+            "role": (
+                "the KV handoff frame has no place for the recurrent state"),
+            "mesh": "the recurrent state has no sharding rules yet",
+        },
+        block="ssm",
+        # valid prompt tokens the recurrent layers scanned, and lanes whose
+        # state an insert overwrote
+        host_counts=("prefill_tokens", "state_installs")),
+    # an index key a cached position beside its K/V row (`KVCache.idx`)
+    Resident(
+        name="index keys",
+        of=lambda c: getattr(c, "sparse", None),
+        phrase="a model with sparse attention",
+        reasons={
+            "prefix_cache": (
+                "the block pool knows one entry shape (K, V and their "
+                "scales) and would hand a hit back without its index keys"),
+            "speculative": (
+                "the verify program over a selection is not shown to pick "
+                "what the single-position steps pick"),
+            "prefill_chunk": (
+                "a chunk over a non-empty cache selects on the XLA path "
+                "alone ([B, S, T] scores and the threshold's passes through "
+                "HBM), which no chip run has driven at long contexts"),
+            "role": "the KV handoff frame has no place for the index keys",
+            "mesh": (
+                "the index cache and the selection have no sharding rules "
+                "yet"),
+        },
+        block="dsa",
+        # ops/sparse_attention.py N_COUNTS words: queries, dense queries,
+        # then candidates and selected each as a high and a low word
+        words=("queries", "dense_queries", "candidates_high",
+               "candidates_low", "selected_high", "selected_low"),
+        decode=_index_counts),
+    # ONE row of rank + rope values a cached position, no K or V a head
+    Resident(
+        name="latent row",
+        of=lambda c: getattr(c, "latent", None),
+        phrase="a model with latent attention",
+        reasons={
+            "prefix_cache": (
+                "the block pool knows one entry shape (K, V a head and "
+                "their scales) and has no block of latent rows"),
+            "speculative": (
+                "the verify program over latent rows (several positions a "
+                "slot, absorbed) is not shown to pick what the "
+                "single-position kernel picks"),
+            "prefill_chunk": (
+                "a chunk's queries attend over cached latents, for which "
+                "there is no flash kernel with a key offset that expands "
+                "them block by block (the jnp form holds [chunk, capacity] "
+                "float32 scores a head)"),
+            "role": (
+                "the KV handoff frame carries K and V planes a head and has "
+                "no plane for a latent row"),
+            "mesh": (
+                "the latent row has no head axis to shard and the absorbed "
+                "factors have no sharding rules yet"),
+            "kv_quant": (
+                "a latent row has no head axis for the scale planes and no "
+                "int8 form of the absorbed kernel exists"),
+        },
+        block="mla",
+        words=LATENT_COUNTS,
+        # prompt tokens prefilled through the expanded form, as dispatched
+        host_counts=("prefill_tokens",)),
+    # a ring of the window's rows a window layer beside a full row a full
+    # layer (`KVCache.kw` / `vw`)
+    Resident(
+        name="window ring",
+        of=lambda c: getattr(c, "window_kind", None),
+        phrase="a model with window and full attention layers",
+        reasons={
+            "prefix_cache": (
+                "the block pool knows one entry shape at one capacity, and "
+                "a window layer's ring holds a slot's last positions alone, "
+                "so a stored prefix would come back without the rows its "
+                "window layers need"),
+            "speculative": (
+                "a ring row that a rejected draft overwrote held a key "
+                "still inside the window, and rolling the lengths back does "
+                "not bring it back"),
+            "prefill_chunk": (
+                "a chunk's later positions overwrite ring rows its earlier "
+                "queries still need (a ring of the window's rows has no "
+                "room for a chunk beside it)"),
+            "role": (
+                "the KV handoff frame carries one K and one V plane at one "
+                "capacity and has no place for the rings"),
+            "mesh": "the ring leaves have no sharding rules yet",
+        },
+        block="swa",
+        words=WINDOW_COUNTS,
+        host_counts=("prefill_tokens",)),        # as dispatched
+    # a block of positions denoised together (models/llama.py
+    # BlockDiffusion); its block's counts are the engine's and the
+    # scheduler's own (`InferenceEngine.diffusion`)
+    Resident(
+        name="diffusion block",
+        of=lambda c: getattr(c, "diffusion", None),
+        phrase="a model that generates by diffusion",
+        reasons={
+            "prefix_cache": (
+                "a hit would have to end on a block boundary of the NEW "
+                "prompt too (the left-over tokens open the first generated "
+                "block), which the radix lookup does not know"),
+            "speculative": (
+                "a denoise forward already yields several positions a "
+                "stream and nothing is drafted ahead of it"),
+            "prefill_chunk": (
+                "a chunk's last position yields no token here and the "
+                "opening block is denoised by the admission program, which "
+                "the chunk programs do not do"),
+            "role": (
+                "the handoff carries one first token, not an opening block"),
+            "mesh": (
+                "the block programs are not shown equal to the reference on "
+                "a sharded trunk"),
+        },
+        block="diffusion"),
+    # a SHARE of the experts the router scores (`experts_held`): no leaf of
+    # its own, so it refuses nothing — it counts its hits
+    Resident(
+        name="held share",
+        of=lambda c: getattr(c, "experts_held", None),
+        phrase=None, reasons={},
+        block="moe",
+        words=HELD_COUNTS),
+)
+
+
+def kept(config) -> tuple[Resident, ...]:
+    """The rows `config`'s model has, in the table's order (`row.of(config)`
+    is what the config says of each)."""
+    return tuple(row for row in RESIDENTS if row.of(config) is not None)
+
+
+def refusals(config, *, mesh: bool = False, role: str = "unified",
+             prefix_cache: bool = False, speculative: bool = False,
+             prefill_chunk: int | None = None,
+             kv_quant: bool = False) -> list[str]:
+    """Why `config`'s model cannot be served under these settings: for each
+    row it has, in the table's order, one sentence a setting that is on
+    (SETTINGS' order) and that the row cannot carry; empty when it can be.
+    The engine raises the first as an EngineError; provider/config.py asks
+    the same of a preset before anything is built and raises them all as a
+    ConfigError."""
+    on = {"prefix_cache": prefix_cache or None,
+          "speculative": speculative or None,
+          "prefill_chunk": prefill_chunk,
+          "role": None if role == "unified" else role,
+          "mesh": mesh or None,
+          "kv_quant": kv_quant or None}
+    return [f"{head.format(value=on[setting])}: {row.reasons[setting]} — "
+            f"{advice.format(phrase=row.phrase)}"
+            for row in kept(config)
+            for setting, (head, advice) in SETTINGS.items()
+            if on[setting] is not None and setting in row.reasons]
+
+
+def tail_words(config) -> tuple[str, ...]:
+    """What `config`'s `KVCache.expert_pairs` ends in, behind the
+    `num_experts` pair counts: the words of the one row that has any. Each
+    device function adds to "the last n words", so two such rows in one
+    model have no layout and are refused."""
+    rows = [row for row in kept(config) if row.words]
+    if len(rows) > 1:
+        raise ValueError(
+            "expert_pairs has one tail, and this model has "
+            + " and ".join(f"{row.name} ({', '.join(row.words)})"
+                           for row in rows)
+            + ": each is written as the vector's last words")
+    return rows[0].words if rows else ()

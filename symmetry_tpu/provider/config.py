@@ -342,95 +342,41 @@ class ConfigManager:
 
     @staticmethod
     def _validate_state_model(tpu: TpuConfig) -> None:
-        """A preset with recurrent layers keeps a per-slot state that the
-        prefix cache, speculation, chunked prefill, the disagg handoff and
-        a mesh cannot carry: refuse those settings here, by name, before a
-        host is spawned (models/hybrid.py state_refusals; the engine
-        refuses the same for a checkpoint, whose config it learns late).
-        A preset with learned sparse attention keeps an index key a cached
-        position: models/llama.py sparse_refusals, the same settings the
-        same way; one with latent attention a single row a position:
-        latent_refusals, which also refuses an int8 cache; one with window
-        and full attention layers a ring beside a full row: models/
-        hybrid.py window_refusals."""
+        """A preset whose slots keep more than K and V a head at one
+        capacity cannot be served under every setting: the prefix cache,
+        speculation, chunked prefill, a disagg role, a mesh (and, for one
+        row of the table, an int8 cache) are refused here, by name and with
+        the reason, before a host is spawned — models/residents.py is the
+        table of what is kept and what cannot carry it, and the engine asks
+        it the same for a checkpoint, whose config it learns late. Beside
+        it, the two checks that need a generation setting's VALUE."""
         import math
 
         from symmetry_tpu.models.llama import PRESETS
+        from symmetry_tpu.models.residents import refusals
 
         preset = PRESETS.get(tpu.model_preset)
-        mesh = math.prod((tpu.mesh or {}).values()) > 1
-        diffusion = getattr(preset, "diffusion", None)
-        if diffusion is not None:
-            from symmetry_tpu.models.llama import diffusion_refusals
-
-            refused = diffusion_refusals(
-                mesh=mesh, role=tpu.role or "unified",
-                prefix_cache=bool(tpu.prefix_cache_mb),
-                speculative=bool(tpu.speculative),
-                prefill_chunk=tpu.prefill_chunk)
-            if tpu.decode_block % diffusion.block:
-                refused.append(
-                    f"tpu.decode_block {tpu.decode_block} is no multiple of "
-                    f"the block length {diffusion.block}")
-            if refused:
-                raise ConfigError(f"model_preset {tpu.model_preset!r}: "
-                                  + "; ".join(refused))
-        elif preset is not None and (tpu.diffusion_steps is not None
-                                     or tpu.diffusion_threshold is not None):
-            raise ConfigError(
-                f"model_preset {tpu.model_preset!r}: tpu.diffusion_steps / "
-                f"tpu.diffusion_threshold are the settings of a model that "
-                f"generates by diffusion over blocks; this preset has no "
-                f"block length")
-        if getattr(preset, "sparse", None) is not None:
-            from symmetry_tpu.models.llama import sparse_refusals
-
-            refused = sparse_refusals(
-                mesh=mesh,
-                role=tpu.role or "unified",
-                prefix_cache=bool(tpu.prefix_cache_mb),
-                speculative=bool(tpu.speculative),
-                prefill_chunk=tpu.prefill_chunk)
-            if refused:
-                raise ConfigError(f"model_preset {tpu.model_preset!r}: "
-                                  + "; ".join(refused))
-        if getattr(preset, "latent", None) is not None:
-            from symmetry_tpu.models.llama import latent_refusals
-
-            refused = latent_refusals(
-                mesh=mesh,
-                role=tpu.role or "unified",
-                prefix_cache=bool(tpu.prefix_cache_mb),
-                speculative=bool(tpu.speculative),
-                prefill_chunk=tpu.prefill_chunk,
-                kv_quant=tpu.kv_quantization == "int8")
-            if refused:
-                raise ConfigError(f"model_preset {tpu.model_preset!r}: "
-                                  + "; ".join(refused))
-            return  # no recurrent kind: a row a position is all it keeps
-        if not getattr(preset, "layer_types", None):
+        if preset is None:
             return
-        if preset.window_kind is not None:
-            from symmetry_tpu.models.hybrid import window_refusals
-
-            refused = window_refusals(
-                mesh=mesh,
-                role=tpu.role or "unified",
-                prefix_cache=bool(tpu.prefix_cache_mb),
-                speculative=bool(tpu.speculative),
-                prefill_chunk=tpu.prefill_chunk)
-            if refused:
-                raise ConfigError(f"model_preset {tpu.model_preset!r}: "
-                                  + "; ".join(refused))
-            return  # no recurrent kind: rows a position are all it keeps
-        from symmetry_tpu.models.hybrid import state_refusals
-
-        refused = state_refusals(
-            mesh=mesh,
+        refused = refusals(
+            preset, mesh=math.prod((tpu.mesh or {}).values()) > 1,
             role=tpu.role or "unified",
             prefix_cache=bool(tpu.prefix_cache_mb),
             speculative=bool(tpu.speculative),
-            prefill_chunk=tpu.prefill_chunk)
+            prefill_chunk=tpu.prefill_chunk,
+            kv_quant=tpu.kv_quantization == "int8")
+        diffusion = getattr(preset, "diffusion", None)
+        if diffusion is None:
+            if (tpu.diffusion_steps is not None
+                    or tpu.diffusion_threshold is not None):
+                refused.append(
+                    "tpu.diffusion_steps / tpu.diffusion_threshold are the "
+                    "settings of a model that generates by diffusion over "
+                    "blocks; this preset has no block length")
+        elif tpu.decode_block % diffusion.block:
+            refused.append(
+                f"tpu.decode_block {tpu.decode_block} is no multiple of "
+                f"the block length {diffusion.block}")
         if refused:
             raise ConfigError(f"model_preset {tpu.model_preset!r}: "
                               + "; ".join(refused))

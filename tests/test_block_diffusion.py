@@ -486,14 +486,6 @@ def test_settings_refused_for_a_model_without_a_block():
                         diffusion_steps=2)
 
 
-def test_refusals_name_each_setting():
-    why = llama.diffusion_refusals(mesh=True, role="decode",
-                                   prefix_cache=True, speculative=True,
-                                   prefill_chunk=256)
-    assert len(why) == 5
-    assert llama.diffusion_refusals() == []
-
-
 def test_config_round_trip():
     assert llama.config_from_hf(MODEL) == CFG
     full = llama.preset("sdar-30b-a3b-chat")

@@ -403,13 +403,13 @@ def test_a_coalesced_prefill_of_unequal_lengths_matches_single_prefills(
 def test_serving_compiles_nothing_after_warmup_and_counts_what_it_did(
         engine):
     before = engine.compile_cache_sizes()
-    counted = dict(engine.ssm_counters)
+    counted = dict(engine.counters["ssm"])
     stream(engine, 0, PROMPT_B)
     engine.release_slot(0)
     assert engine.compile_cache_sizes() == before
-    assert engine.ssm_counters["prefill_tokens"] == (
+    assert engine.counters["ssm"]["prefill_tokens"] == (
         counted["prefill_tokens"] + len(PROMPT_B))
-    assert engine.ssm_counters["state_installs"] == (
+    assert engine.counters["ssm"]["state_installs"] == (
         counted["state_installs"] + 1)
 
 
@@ -486,13 +486,6 @@ def test_each_refused_setting_is_a_config_error_before_anything_is_built(
     ConfigManager(config=config())      # the plain configuration is fine
     with pytest.raises(ConfigError, match=f"tpu.{setting}"):
         ConfigManager(config=config(**CONFIG_REFUSED[setting]))
-
-
-def test_state_refusals_are_the_same_for_either_recurrent_kind():
-    every = hybrid.state_refusals(mesh=True, role="prefill",
-                                  prefix_cache=True, speculative=True,
-                                  prefill_chunk=64)
-    assert len(every) == 5 and hybrid.state_refusals() == []
 
 
 def test_layer_types_come_in_one_familys_names():

@@ -316,13 +316,13 @@ def test_a_coalesced_prefill_of_unequal_lengths_matches_single_prefills(
 def test_serving_compiles_nothing_after_warmup_and_counts_what_it_did(
         engine):
     before = engine.compile_cache_sizes()
-    counted = dict(engine.ssm_counters)
+    counted = dict(engine.counters["ssm"])
     stream(engine, 0, PROMPT_B)
     engine.release_slot(0)
     assert engine.compile_cache_sizes() == before
-    assert engine.ssm_counters["prefill_tokens"] == (
+    assert engine.counters["ssm"]["prefill_tokens"] == (
         counted["prefill_tokens"] + len(PROMPT_B))
-    assert engine.ssm_counters["state_installs"] == (
+    assert engine.counters["ssm"]["state_installs"] == (
         counted["state_installs"] + 1)
 
 

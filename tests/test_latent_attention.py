@@ -407,16 +407,6 @@ def test_latent_layers_and_their_sizes_go_together():
             layer_types=("latent_attention",))
 
 
-def test_latent_refusals_name_every_setting():
-    every = llama.latent_refusals(mesh=True, role="prefill",
-                                  prefix_cache=True, speculative=True,
-                                  prefill_chunk=256, kv_quant=True)
-    assert len(every) == 6 and llama.latent_refusals() == []
-    for setting in ("prefill_chunk", "prefix_cache_mb", "speculative",
-                    "role", "mesh", "kv_quantization"):
-        assert sum(f"tpu.{setting}" in why for why in every) == 1
-
-
 def make_engine(**kw):
     params = llama.init_params(CFG, jax.random.key(0), jnp.float32)
     args = dict(max_slots=4, max_seq_len=128, prefill_buckets=(32, 64),
@@ -570,7 +560,7 @@ def test_engine_and_scheduler_stream_the_references_tokens(engine):
     requests = [(ids_of(20 + 5 * r, key=10 + r), 9 + r) for r in range(3)]
     requests.append((ids_of(40, key=20), 6))
     before = engine.compile_cache_sizes()
-    counted = dict(engine.mla)
+    counted = dict(engine.counters["mla"])
     got = {i: [] for i in range(len(requests))}
     done = {i: threading.Event() for i in range(len(requests))}
 
@@ -605,9 +595,10 @@ def test_engine_and_scheduler_stream_the_references_tokens(engine):
             dec.push_many(want) + dec.flush(), i
         assert last.tokens_emitted == max_new
     assert engine.compile_cache_sizes() == before
-    grew = {k: engine.mla[k] - counted[k] for k in counted}
+    grew = {k: engine.counters["mla"][k] - counted[k] for k in counted}
     assert grew["prefill_tokens"] == sum(len(ids) for ids, _ in requests)
     assert grew["decode_steps"] >= 12 and grew["decode_steps"] % 4 == 0
     assert grew["live_positions"] > grew["decode_steps"] * 20
-    assert stats["mla"].keys() == engine.mla.keys()     # (a block behind)
+    # (a block behind)
+    assert stats["mla"].keys() == engine.counters["mla"].keys()
     assert len(engine.expert_pairs) == CFG.num_experts

@@ -530,12 +530,13 @@ def test_the_counters_count_held_and_absent_pairs_and_no_ffnless_layer(
     two layers that end in nothing are in no expert counter."""
     before = list(engine.expert_pairs)
     engine.decode_steps()
-    hits = engine.expert_hits
+    hits = engine.counters["moe"]["expert_hits"]
     engine.decode_steps()
     grew = [b - a for a, b in zip(before, engine.expert_pairs)]
     assert len(grew) == 8 and sum(grew) == 2 * 4 * 4 * 5 * 2
     # a held expert hit, a layer and a step: at most 4 of them a call
-    assert 0 < engine.expert_hits - hits <= 2 * 4 * 5 * 4
+    assert 0 < (engine.counters["moe"]["expert_hits"] - hits
+                ) <= 2 * 4 * 5 * 4
     counts = engine.moe_counts()
     total = sum(engine.expert_pairs)
     assert counts["pairs"] == total
@@ -543,7 +544,7 @@ def test_the_counters_count_held_and_absent_pairs_and_no_ffnless_layer(
     assert counts["held_pairs"] == sum(engine.expert_pairs[4:8])
     assert counts["held_pairs"] + counts["absent_pairs"] == total
     assert 0 < counts["held_pairs"] < total
-    assert counts["expert_hits"] == engine.expert_hits
+    assert counts["expert_hits"] == engine.counters["moe"]["expert_hits"]
     # an engine that holds all it routes over counts as it did
     plain = InferenceEngine.__new__(InferenceEngine)
     plain.config, plain.expert_pairs = llama.preset("tiny-moe"), [3, 1, 0, 2]

@@ -530,14 +530,14 @@ def test_a_coalesced_prefill_of_unequal_lengths_matches_single_prefills(
 def test_serving_compiles_nothing_after_warmup_and_counts_what_it_did(
         engine):
     before = engine.compile_cache_sizes()
-    counted = dict(engine.ssm_counters)
+    counted = dict(engine.counters["ssm"])
     pairs = sum(engine.expert_pairs)
     stream(engine, 0, PROMPT_B)
     engine.release_slot(0)
     assert engine.compile_cache_sizes() == before
-    assert engine.ssm_counters["prefill_tokens"] == (
+    assert engine.counters["ssm"]["prefill_tokens"] == (
         counted["prefill_tokens"] + len(PROMPT_B))
-    assert engine.ssm_counters["state_installs"] == (
+    assert engine.counters["ssm"]["state_installs"] == (
         counted["state_installs"] + 1)
     # pairs are counted in the six expert layers alone: top 2 a token (the
     # prompt's valid positions, then 12 steps of all four lanes: idle lanes
@@ -630,15 +630,6 @@ REFUSED = {
 def test_the_engine_refuses_what_cannot_carry_a_tail(setting):
     with pytest.raises(EngineError, match=f"tpu.{setting}"):
         make_engine(**REFUSED[setting])
-
-
-def test_the_refusals_name_no_kind_that_is_not_there():
-    every = hybrid.state_refusals(mesh=True, role="prefill",
-                                  prefix_cache=True, speculative=True,
-                                  prefill_chunk=64)
-    assert len(every) == 5 and hybrid.state_refusals() == []
-    assert not any("mamba" in why for why in every)
-    assert "the recurrent layers" in every[0]
 
 
 def test_layer_types_come_in_one_familys_names_with_their_fields():

@@ -722,11 +722,11 @@ def test_a_coalesced_prefill_of_unequal_lengths_matches_single_prefills(
 def test_serving_compiles_nothing_after_warmup_and_counts_what_it_selected(
         engine):
     before = engine.compile_cache_sizes()
-    counted = dict(engine.dsa)
+    counted = dict(engine.counters["dsa"])
     stream(engine, 0, PROMPT_B, blocks=2)
     engine.release_slot(0)
     assert engine.compile_cache_sizes() == before
-    grew = {k: engine.dsa[k] - counted[k] for k in counted}
+    grew = {k: engine.counters["dsa"][k] - counted[k] for k in counted}
     # the prompt's 60 queries in each of 2 layers, then 8 steps of 4 lanes
     # (an idle lane's query is counted too: its stale rows are candidates)
     t = np.arange(60) + 1
@@ -759,7 +759,8 @@ def test_the_engine_reports_the_sparse_form_and_the_index_cache(engine):
         llama.init_params(llama.preset("tiny"), jax.random.key(0)),
         get_tokenizer(None, vocab_size=512), max_slots=2, max_seq_len=64,
         prefill_buckets=(32,))
-    assert "sparse" not in plain.attention_paths() and plain.dsa is None
+    assert "sparse" not in plain.attention_paths()
+    assert "dsa" not in plain.counters
     assert plain.index_bytes_per_token() == 0
 
 
@@ -800,13 +801,6 @@ def test_each_refused_setting_is_a_config_error_before_anything_is_built(
     ConfigManager(config=config())      # the plain configuration is fine
     with pytest.raises(ConfigError, match=f"tpu.{setting}"):
         ConfigManager(config=config(**CONFIG_REFUSED[setting]))
-
-
-def test_sparse_refusals_name_every_setting():
-    every = llama.sparse_refusals(mesh=True, role="prefill",
-                                  prefix_cache=True, speculative=True,
-                                  prefill_chunk=256)
-    assert len(every) == 5 and llama.sparse_refusals() == []
 
 
 def test_the_default_chunk_is_refused_with_the_setting_that_serves():

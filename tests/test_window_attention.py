@@ -407,16 +407,6 @@ def test_the_config_holds_its_fields_to_each_other(change, says):
         dataclasses.replace(CFG, **change)
 
 
-def test_window_refusals_name_every_setting():
-    every = hybrid.window_refusals(mesh=True, role="prefill",
-                                   prefix_cache=True, speculative=True,
-                                   prefill_chunk=256)
-    assert len(every) == 5 and hybrid.window_refusals() == []
-    for setting in ("prefill_chunk", "prefix_cache_mb", "speculative",
-                    "role", "mesh"):
-        assert sum(f"tpu.{setting}" in why for why in every) == 1
-
-
 def make_engine(**kw):
     params = llama.init_params(CFG, jax.random.key(0), jnp.float32)
     args = dict(max_slots=4, max_seq_len=64, prefill_buckets=(16, 32),
@@ -616,7 +606,7 @@ def test_engine_and_scheduler_stream_the_references_tokens(engine):
     requests = [(ids_of(5 + 8 * r, key=10 + r), 14 + r) for r in range(3)]
     requests.append((ids_of(30, key=20), 12))
     before = engine.compile_cache_sizes()
-    counted = dict(engine.swa)
+    counted = dict(engine.counters["swa"])
     got = {i: [] for i in range(len(requests))}
     done = {i: threading.Event() for i in range(len(requests))}
 
@@ -651,10 +641,11 @@ def test_engine_and_scheduler_stream_the_references_tokens(engine):
             dec.push_many(want) + dec.flush(), i
         assert last.tokens_emitted == max_new
     assert engine.compile_cache_sizes() == before
-    grew = {k: engine.swa[k] - counted[k] for k in counted}
+    grew = {k: engine.counters["swa"][k] - counted[k] for k in counted}
     assert grew["prefill_tokens"] == sum(len(ids) for ids, _ in requests)
     assert grew["decode_steps"] >= 12 and grew["decode_steps"] % 4 == 0
     assert grew["full_rows"] > grew["ring_rows"] > grew["decode_steps"] * 8
     assert grew["ring_wraps"] >= 4
-    assert stats["swa"].keys() == engine.swa.keys()     # (a block behind)
+    # (a block behind)
+    assert stats["swa"].keys() == engine.counters["swa"].keys()
     assert len(engine.expert_pairs) == CFG.num_experts

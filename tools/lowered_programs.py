@@ -47,7 +47,9 @@ blocks of one sub-layer, groups of B and C, a share of ungated experts: all
 scatter for a leaf of 2 int8 heads of whole lane tiles: 30 of the 34
 identical — every `insert_all` and all three programs of the ten other
 presets — nemotron-3-nano-30b-a3b's and qwen3-next-80b-a3b's `decode_block`
-and `prefill` the four that differ.)
+and `prefill` the four that differ. PR 63, what a slot keeps as one table
+(models/residents.py sizes `expert_pairs`) and the sequence-parallel
+keywords out of the trunk: all 34 identical.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never

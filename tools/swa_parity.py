@@ -221,7 +221,7 @@ def run_seed(args, seed: int) -> dict:
         jax.effects_barrier()
         decode_taps = taken()
         engine.collect_expert_pairs()
-        counters = dict(engine.swa)
+        counters = dict(engine.counters["swa"])
         program_s = time.monotonic() - t0
         paths, cache = engine.attention_paths(), engine.cache_report()
     finally:
