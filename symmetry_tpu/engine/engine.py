@@ -2478,8 +2478,9 @@ class InferenceEngine:
             "layout": moe_layout(self.mesh, c.intermediate_size),
             "route": {"decode": route(decode_tokens),
                       "prefill": {str(t): route(t) for t in prefills}},
-            # what the routed form's three matmuls run as (the row tile
-            # of the smallest program's rows: the kernel's own from 64)
+            # what the routed form's matmuls run as — under the kernel a
+            # gated layer's gate and up are one call (the row tile of the
+            # smallest program's rows: the kernel's own from 64)
             "grouped_matmul": gmm_form,
             "quantized_leaf_route": (
                 "expert_stack: int8 [L, X, K, N] stays flat (the packed "

@@ -18,7 +18,9 @@ Neither form drops a (token, expert) pair, and there is no capacity:
   * the Pallas kernel `moe_gmm` (ops/gmm.py) for an int8 stack where the
     expert FFN is traced on one device: it reads each HIT expert's int8
     tile once, where it lies in the layers' stack, widens it in VMEM and
-    multiplies only the row tiles the expert's group touches;
+    multiplies only the row tiles the expert's group touches — two calls a
+    gated layer: gate and up share one (a visit copies both tiles and
+    writes act(g) * u), down is the other;
   * `jax.lax.ragged_dot` (XLA:TPU's native grouped matmul) everywhere else:
     under a mesh (each shard of mixtral-8x7b on `model: 4`), for bf16 /
     float32 stacks, for a shape the kernel cannot tile. It is also the
@@ -391,11 +393,22 @@ def _routed_ffn(x, valid, router, wg, wu, wd, k: int, at=None,
                                at and (at[0][name], at[1]))
 
     rows = jnp.take(x, order // k, axis=0)                # [T*k, D]
+    act = _act(routing)
     if wg is None:          # ungated: act(x W_up) W_down
-        h = _act(routing)(grouped(rows, wu, "wu"))
+        h = act(grouped(rows, wu, "wu"))
+    elif at is not None and grouped_matmul_form(
+            at[0]["wg"], T * k)["form"] != "ragged_dot":
+        # gate and up (one shape, so one form) in ONE kernel call: a visit
+        # copies the expert's gate tile AND its up tile, multiplies the
+        # row tile it holds by both and writes act(g) * u once, formed in
+        # float32 and rounded to x.dtype — half the visits of two calls,
+        # and neither [T*k, F] float32 product goes to HBM and back
+        gate, up = at[0]["wg"], at[0]["wu"]
+        h = gmm.grouped_matmul(
+            rows, (gate.q, up.q), (gate.scale, up.scale), group_sizes,
+            at[1], act=act, interpret=interpret_mode())
     else:
-        h = (_act(routing)(grouped(rows, wg, "wg"))
-             * grouped(rows, wu, "wu"))
+        h = act(grouped(rows, wg, "wg")) * grouped(rows, wu, "wu")
     y = grouped(h.astype(x.dtype), wd, "wd")
     if held is not None:
         # rows past the held pairs were never written (ops/gmm.py): what

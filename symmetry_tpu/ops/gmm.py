@@ -3,6 +3,10 @@ three matmuls (models/moe.py `_routed_ffn`) on one device.
 
     out[r, :] = (rows[r, :] @ q[layer, e(r)]) * scale[layer, e(r), :]
 
+(down, and an ungated expert's up), or for a gated expert's gate and up
+
+    out[r, :] = act(gate's product) * up's product      in `rows.dtype`
+
 `rows [R, A]` lie sorted by expert, so expert e's rows are the contiguous
 group `offsets[e] .. offsets[e + 1]`; `q [L, X, A, F]` is the int8 stack of
 every layer's experts and `scale [L, X, F]` its per-(expert, column) scales.
@@ -31,6 +35,18 @@ What the kernel is for is what it does NOT read and does not compute:
     output block still in VMEM; it goes back to HBM when the walk leaves it.
   - The first axis tiles F where an expert's [A, F] is too large for VMEM
     (`COLUMN_TILE_BYTES`); at the served shapes an expert is one tile.
+
+A gated expert's first two matmuls are ONE call (PR 64): `q` and `scale`
+a (gate, up) pair of stacks of one shape, `act` the activation. The walk,
+the row tile and the maps are the same; a visit copies the expert's gate
+tile AND its up tile, multiplies the row tile it holds by each into a
+float32 accumulator, scales each, and writes `act(g) * u` — formed in
+float32, rounded once, to the activations' dtype: the float32 ops and
+their order are those of two calls and the fusion between them
+(tests/test_gmm.py holds the two to the last bit), with half the visits,
+and neither [R, F] float32 product goes to HBM and back. The column tile
+is `geometry`'s, per leaf; the VMEM the call asks for is what doubles
+(granite's [4096, 768] pair 51 MB, lfm2's [2048, 1792] 60 of a v5e's 128).
 
 `megablox` (jax.experimental.pallas.ops.tpu) is the structure; it refuses an
 int8 operand and takes one layer. Rows beyond `sum(group_sizes)` are left
@@ -110,8 +126,11 @@ def visits(group_sizes: jnp.ndarray, n_rows: int, tm: int):
 
 
 def _kernel(layer_ref, offsets_ref, group_ref, tile_ref, count_ref,
-            rows_ref, q_ref, scale_ref, out_ref):
+            rows_ref, *refs, act=None):
+    """`refs`: (q, scale, out) or, gated, (gate q, gate scale, up q, up
+    scale, out)."""
     del layer_ref                                   # addressing only
+    *leaves, out_ref = refs
     v = pl.program_id(1)
 
     @pl.when(v < count_ref[0])
@@ -122,36 +141,51 @@ def _kernel(layer_ref, offsets_ref, group_ref, tile_ref, count_ref,
             jnp.int32, (tm, 1), 0)
         mine = (row >= offsets_ref[g]) & (row < offsets_ref[g + 1])
         rows = rows_ref[...]
-        # The tile is widened where it is multiplied (module docstring).
-        # One MXU pass is exact for bfloat16 operands and all Mosaic takes
-        # for them: said here, a process whose default precision is
-        # "highest" (the CPU tests) is not refused the kernel.
-        acc = jax.lax.dot_general(
-            rows, q_ref[...].astype(rows.dtype), (((1,), (0,)), ((), ())),
-            precision=(jax.lax.Precision.DEFAULT
-                       if rows.dtype == jnp.bfloat16 else None),
-            preferred_element_type=jnp.float32)
+
+        def product(q_ref, scale_ref):
+            # The tile is widened where it is multiplied (module
+            # docstring). One MXU pass is exact for bfloat16 operands and
+            # all Mosaic takes for them: said here, a process whose default
+            # precision is "highest" (the CPU tests) is not refused the
+            # kernel.
+            acc = jax.lax.dot_general(
+                rows, q_ref[...].astype(rows.dtype),
+                (((1,), (0,)), ((), ())),
+                precision=(jax.lax.Precision.DEFAULT
+                           if rows.dtype == jnp.bfloat16 else None),
+                preferred_element_type=jnp.float32)
+            return acc * scale_ref[...]
+
+        y = product(*leaves[:2])
+        if len(leaves) == 4:
+            # the float32 product first, the rounding after it: the order
+            # of `act(g) * u` followed by the cast between two calls
+            y = (act(y) * product(*leaves[2:])).astype(out_ref.dtype)
         # rows of the tile's other groups keep what their visit wrote (or
         # will write: what the block holds until then is never read as a
         # number)
-        out_ref[...] = jnp.where(mine, acc * scale_ref[...], out_ref[...])
+        out_ref[...] = jnp.where(mine, y, out_ref[...])
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("row_tile", "interpret"))
+                   static_argnames=("row_tile", "interpret", "act"))
 def grouped_matmul(
     rows: jnp.ndarray,          # [R, A] activations, sorted by expert
-    q: jnp.ndarray,             # [L, X, A, F] int8, the FULL stack
-    scale: jnp.ndarray,         # [L, X, F] f32
+    q,                          # [L, X, A, F] int8, the FULL stack —
+    scale,                      # [L, X, F] f32 — or (gate, up) of each
     group_sizes: jnp.ndarray,   # [X] int32, summing to R
     layer: jnp.ndarray,         # scalar int32: which layer's experts
     *,
+    act=None,                   # the gated pair's activation
     row_tile: int | None = None,    # None: `geometry`'s
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Returns [R, F] float32."""
+    """Returns [R, F] float32; for a (gate, up) pair of stacks
+    `act(gate product) * up product`, formed in float32 and rounded once,
+    in `rows.dtype`."""
+    qs, scales = (q, scale) if isinstance(q, tuple) else ((q,), (scale,))
     R, A = rows.shape
-    _, X, _, F = q.shape
+    _, X, _, F = qs[0].shape
     tiling = geometry(R, A, F, rows.dtype.itemsize, interpret=interpret)
     if tiling is None:
         raise ValueError(f"no grouped-matmul geometry for rows [{R}, {A}] "
@@ -164,9 +198,9 @@ def grouped_matmul(
     layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
     # the layer's scales alone (1 MB at most), a row an expert: the stack
     # of them reshaped whole would be a relayout of every layer's a call
-    scales = jax.lax.dynamic_index_in_dim(
-        scale.astype(jnp.float32), layer[0], 0, keepdims=False
-    ).reshape(X, 1, F)
+    scales = [jax.lax.dynamic_index_in_dim(
+        s.astype(jnp.float32), layer[0], 0, keepdims=False
+    ).reshape(X, 1, F) for s in scales]
 
     def at_tile(n, v, lay, offsets, group, tile, count):
         return tile[v], 0
@@ -180,23 +214,26 @@ def grouped_matmul(
     def at_out(n, v, lay, offsets, group, tile, count):
         return tile[v], n
 
-    # rows and out double-buffered, the int8 tile too, and its widened copy
-    block = (2 * tm * A * rows.dtype.itemsize + 2 * tm * tn * 4
-             + A * tn * (2 + rows.dtype.itemsize) + tm * tn * 4)
+    out_dtype = rows.dtype if len(qs) == 2 else jnp.float32
+    # rows and out double-buffered, each leaf's int8 tile too, and its
+    # widened copy and float32 product
+    block = (2 * tm * A * rows.dtype.itemsize
+             + 2 * tm * tn * jnp.dtype(out_dtype).itemsize
+             + len(qs) * (A * tn * (2 + rows.dtype.itemsize) + tm * tn * 4))
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,  # layer, offsets, group, tile, count
             grid=(F // tn, n_visits),
             in_specs=[pl.BlockSpec((tm, A), at_tile),
-                      pl.BlockSpec((None, None, A, tn), at_expert),
-                      pl.BlockSpec((None, 1, tn), at_scale)],
+                      *[pl.BlockSpec((None, None, A, tn), at_expert),
+                        pl.BlockSpec((None, 1, tn), at_scale)] * len(qs)],
             out_specs=pl.BlockSpec((tm, tn), at_out),
         ),
-        out_shape=jax.ShapeDtypeStruct((R, F), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R, F), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=max(32 * 2**20, 2 * block)),
         name=NAME,
         interpret=interpret,
-    )(layer, *walk, rows, q, scales)
+    )(layer, *walk, rows, *[a for pair in zip(qs, scales) for a in pair])

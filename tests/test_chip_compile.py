@@ -111,6 +111,16 @@ def leaf_moves(text: str, L: int, B: int, T: int,
     return moved, written
 
 
+def gmm_results(text: str) -> list[str]:
+    """What each `moe_gmm` call of a compiled program returns, sorted: a
+    routed GATED layer is two calls (PR 64) — the (gate, up) pair's, whose
+    one visit copies both tiles and writes `act(g) * u` in the activations'
+    dtype, `bf16[rows,F]`, and down's `f32[rows,D]` — where it was three,
+    with two `f32[rows,F]` results and a fusion between them; an ungated
+    layer's two are up's and down's, both float32."""
+    return sorted(re.findall(r"%moe_gmm[.\d]* = (\w+\[[\d,]+\])", text))
+
+
 def plane_moves(text: str, L: int, B: int, T: int) -> list[str]:
     """Lines of a compiled program that copy or relay a scale plane of 2
     heads, `f32[L,B,2,T]`: the planes lie in (2, 128) tiles, the decode
@@ -289,8 +299,9 @@ def test_gdn_decode_step_updates_the_matrix_state_in_place(
     state, then an update that read it again and wrote it: PR 35 – PR 44)
     cannot come back unseen. The one gated-attention layer (2 KV heads of
     256) takes the decode kernel. At 512 experts top 10 the step's experts
-    are the routed form (PR 36): three `moe_gmm` calls in the body of each
-    of the two runs' scans."""
+    are the routed form (PR 36): two `moe_gmm` calls in the body of each of
+    the two runs' scans (PR 64: gate and up are ONE call, and no
+    `f32[1280,512]` product of either goes to HBM between the calls)."""
     from symmetry_tpu.models import gdn, hybrid, llama, moe
 
     for module in (llama, gdn, moe):
@@ -330,7 +341,10 @@ def test_gdn_decode_step_updates_the_matrix_state_in_place(
     moved = plane_moves(text, 1, 128, 640)      # nor its scale planes
     assert not moved, moved[0]
     gmm_calls = len(re.findall(r"%moe_gmm[.\d]* = ", text))
-    assert gmm_calls == 3 * len(hybrid.runs(cfg))
+    assert gmm_calls == 2 * len(hybrid.runs(cfg))
+    assert gmm_results(text) == (["bf16[1280,512]"] * 2
+                                 + ["f32[1280,2048]"] * 2)
+    assert "f32[1280,512]" not in text
     gdn_runs = sum(kind == "linear_attention"
                    for kind, _, _ in hybrid.runs(cfg))
     assert gdn_runs == 1
@@ -397,8 +411,9 @@ def test_prefill_reads_the_expert_stacks_where_they_lie(
         one_chip, no_cache, monkeypatch, preset, rows, bucket, stack):
     """A routed prefill dispatch of each one-chip expert cell (512 tokens;
     keye's smallest routed bucket): the routed
-    form's three matmuls a layer are `moe_gmm` calls (ops/gmm.py: one call
-    each in the body of a run's scan) whose weight operands are the WHOLE
+    form's three matmuls a layer are TWO `moe_gmm` calls (ops/gmm.py: the
+    (gate, up) pair's and down's, in the body of a run's scan: the pair
+    fits VMEM at every cell's shape) whose weight operands are the WHOLE
     int8 stacks as they lie in HBM — no slice, copy or relayout of a stack
     or of a layer of it beside a call (PR 29's lesson: a slice around a
     kernel is a copy; here 0.5 GB a matmul), no stack an operand of an XLA
@@ -438,7 +453,10 @@ def test_prefill_reads_the_expert_stacks_where_they_lie(
                                                     prefill_flash=True),
             donate_argnums=(2,)).lower(params, tok, cache, lens).compile(
         ).as_text()
-    assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 3 * n_runs
+    pairs, (A, F) = tokens * cfg.num_experts_per_tok, re.search(
+        r"\((\d+),(\d+)\|", stack).groups()
+    assert gmm_results(text) == ([f"bf16[{pairs},{F}]"] * n_runs
+                                 + [f"f32[{pairs},{A}]"] * n_runs)
     names = set(re.findall(rf"(%[\w.\-]+)(?: =|:) {stack}", text))
     assert names
     touched = [line.strip()[:160] for line in text.splitlines()
@@ -718,7 +736,8 @@ def test_lfm2_prefill_lowers_flash_at_a_head_of_64_and_routes_its_experts(
     """A 512-token prefill dispatch of the lfm2 cell: the flash kernel
     lowers at a `[block, 64]` tile (one call in each of the six attention
     runs: what `startup.attention.prefill: pallas` promises), the 22
-    expert layers are routed (`moe_gmm`: three calls in each of the twelve
+    expert layers are routed (`moe_gmm`: two calls — the (gate, up) pair's
+    and down's — in each of the twelve
     scans that hold expert layers; the run of the two dense layers holds
     none) over the WHOLE `[22, 32, ...]` int8 stacks, indexed by the
     layer's place among the expert layers."""
@@ -739,7 +758,9 @@ def test_lfm2_prefill_lowers_flash_at_a_head_of_64_and_routes_its_experts(
     expert_runs = [r for r in hybrid.runs(cfg) if cfg.ffn_kind(r[1]) == "moe"]
     assert len(expert_runs) == 12 and len(hybrid.runs(cfg)) == 13
     gmm_calls = len(re.findall(r"%moe_gmm[.\d]* = ", text))
-    assert gmm_calls == 3 * len(expert_runs)
+    assert gmm_calls == 2 * len(expert_runs)
+    assert gmm_results(text) == (["bf16[2048,1792]"] * 12
+                                 + ["f32[2048,2048]"] * 12)
     assert text.count("tpu_custom_call") == gmm_calls + 6   # + flash
     stack = r"s8\[22,32,(2048,1792|1792,2048)\]"
     touched = [line.strip()[:160] for line in text.splitlines()
@@ -831,9 +852,13 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
     # two forwards' trunks (the denoise loop's and the commit's), a call a
     # scan body each
     assert len(re.findall(r"%decode_attention[.\d]* = ", text)) == 2
-    # two forwards' trunks (the denoise loop's and the commit's), three
-    # grouped matmuls a layer each, compiled once a scan body
-    assert len(re.findall(r"%moe_gmm[.\d]* = ", text)) == 6
+    # two forwards' trunks (the denoise loop's and the commit's), a layer's
+    # (gate, up) pair call and its down call each, compiled once a scan
+    # body: [128 slots x 4 positions x top 8, ...] and no float32
+    # [4096, 768] product between them
+    assert gmm_results(text) == (["bf16[4096,768]"] * 2
+                                 + ["f32[4096,2048]"] * 2)
+    assert "f32[4096,768]" not in text
     # ... whose weight operands are the layers' stacks as they lie: nothing
     # yields one layer's experts (sliced by the layer scan they were a
     # 0.6 GB copy a layer, 40% of the device's time: PERF.md, PR 49)
@@ -869,10 +894,11 @@ def test_sdar_decode_dispatch_denoises_and_commits_in_place(
 @pytest.mark.parametrize("rows,gmm_calls,mixture_tokens", [
     # the prompt's forward: 1 x 64 tokens a mixture, 256 and 1,024 routed;
     # the opening block's two trunks (the denoise loop's and the commit's):
-    # 4 and 16 tokens routed, three `moe_gmm` calls a scan body each
-    (1, 6, [64]), (4, 9, []),
+    # 4 and 16 tokens routed, two `moe_gmm` calls a scan body each (the
+    # (gate, up) pair's and down's)
+    (1, 4, [64]), (4, 6, []),
     # 16 rows x 4 positions = 64 tokens: inside (128, 8)'s band, the mixture
-    (16, 3, [64])])
+    (16, 2, [64])])
 def test_sdar_admission_routes_its_small_opening_blocks(
         one_chip, no_cache, monkeypatch, rows, gmm_calls, mixture_tokens):
     """sdar-30b-a3b-chat's admission program (`bd_prefill`: the prompt's
@@ -985,15 +1011,16 @@ def test_kanana_prefill_expands_through_flash_and_routes_its_experts(
     192 and values of 128 in its wide tiles (one call a scan: Mosaic takes
     the 512 x 512 walk with a head's whole K and V in VMEM at this length),
     the seven expert layers are
-    routed — three `moe_gmm` calls whose weight operands are the layers'
-    int8 stacks as they lie — and no layer's experts are sliced out."""
+    routed — two `moe_gmm` calls, the (gate, up) pair's and down's, whose
+    weight operands are the layers' int8 stacks as they lie — and no
+    layer's experts are sliced out."""
     from symmetry_tpu.ops import flash, gmm
 
     cfg, text = _kanana_text(one_chip, monkeypatch, 1, 9728, 9728)
     assert len(re.findall(rf"%{flash.WIDE_NAME}[.\d]* = ", text)) == 2
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
-                          text)) >= 5
-    assert len(re.findall(rf"%{gmm.NAME}[.\d]* = ", text)) == 3
+                          text)) >= 4
+    assert len(re.findall(rf"%{gmm.NAME}[.\d]* = ", text)) == 2
     sliced = [line.strip()[:160] for line in text.splitlines()
               if re.search(r"= s8\[(1,)?128,(2048,768|768,2048)\]\S* "
                            r"(copy|dynamic-slice|fusion)\(", line)]
@@ -1063,15 +1090,15 @@ def test_smallthinker_prefill_walks_wide_tiles_and_routes_its_experts(
     """An 8,320-token prefill row over its scratch: attention is the wide
     flash walk in every one of the six scans (28 query heads over 4 KV
     heads; a window layer's call bounded by its window), the experts are
-    routed — three `moe_gmm` calls a scan whose weight operands are the
-    layers' int8 stacks as they lie."""
+    routed — two `moe_gmm` calls a scan, the (gate, up) pair's and down's,
+    whose weight operands are the layers' int8 stacks as they lie."""
     from symmetry_tpu.ops import flash, gmm
 
     cfg, cache, text = _smallthinker_text(one_chip, monkeypatch, 1, 8320,
                                           8320)
     assert cache.kw.shape == (9, 1, 8320, 4, 128)
     assert len(re.findall(rf"%{flash.WIDE_NAME}[.\d]* = ", text)) == 6
-    assert len(re.findall(rf"%{gmm.NAME}[.\d]* = ", text)) == 18
+    assert len(re.findall(rf"%{gmm.NAME}[.\d]* = ", text)) == 12
     sliced = [line.strip()[:160] for line in text.splitlines()
               if re.search(r"= s8\[(1,)?64,(2560,768|768,2560)\]\S* "
                            r"(copy|dynamic-slice|fusion)\(", line)]

@@ -38,7 +38,8 @@ def kernel_stacks(monkeypatch):
     inner = gmm.grouped_matmul
 
     def spy(rows, q, *a, **kw):
-        seen.append(q.shape[0])
+        # a gated layer's gate and up arrive as one call's pair
+        seen.extend(s.shape[0] for s in (q if isinstance(q, tuple) else (q,)))
         return inner(rows, q, *a, **kw)
 
     monkeypatch.setattr(gmm, "grouped_matmul", spy)
@@ -57,8 +58,8 @@ def test_whole_stacks_equal_the_stack_of_one(monkeypatch, kernel_stacks,
                          cfg.num_experts_per_tok) == form
     params, dtype = make_params(cfg, weights)
     logits, pairs = forward(cfg, params, dtype)
-    # the kernel's operand: all the layers' experts, three matmuls, traced
-    # once in the scan's body — or no kernel at all: the mixture, and a
+    # the kernel's operands: all the layers' experts, three stacks over two
+    # calls, traced once in the scan's body — or no kernel at all: the mixture, and a
     # bf16 stack's `ragged_dot`, read the layer's slice
     kernel = form == "routed" and weights == "int8"
     assert kernel_stacks == ([cfg.num_layers] * 3 if kernel else [])

@@ -64,7 +64,8 @@ def test_the_routed_form_over_the_kernel_is_the_dense_mixture(X, k, tokens):
                           {n: lp[n] for n in moe.EXPERT_LEAVES})
     jaxpr = str(jax.make_jaxpr(
         lambda: moe._routed_ffn(*args, (stacks, jnp.int32(0))))())
-    assert jaxpr.count("moe_gmm") >= 3 and "ragged_dot" not in jaxpr
+    # gate and up in one call, down in another
+    assert jaxpr.count("moe_gmm") == 2 and "ragged_dot" not in jaxpr
     with jax.default_matmul_precision("highest"):
         kernel, pairs = moe._routed_ffn(*args, (stacks, jnp.int32(0)))
         ragged, pairs_ragged = moe._routed_ffn(*args)
@@ -74,6 +75,40 @@ def test_the_routed_form_over_the_kernel_is_the_dense_mixture(X, k, tokens):
     np.testing.assert_array_equal(pairs, pairs_dense)
     np.testing.assert_array_equal(pairs, pairs_ragged)
     assert int(pairs.sum()) == (tokens - 5) * k
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+@pytest.mark.parametrize("held", [None, (2, 4)], ids=["all", "a share"])
+def test_a_share_and_an_ungated_expert_take_the_kernel_as_the_mixture(
+        gated, held):
+    """Two kernel calls a layer either way: (gate, up) in one and down, or
+    — ungated, act(x W_up) W_down — up and down, the form that has no pair
+    to join; a chip's share leaves rows past its groups unwritten in
+    both."""
+    X, k, D, F_, tokens = 8, 2, 32, 16, 40
+    lp = int8_layer(X, k, D, F_, jax.random.key(3))
+    x = jax.random.normal(jax.random.key(4), (tokens, D))
+    routing = {"act": "silu" if gated else "relu2"}
+    names = moe.EXPERT_LEAVES if gated else ("wu", "wd")
+    if held is not None:
+        routing["held"] = held
+        lp.update({n: jax.tree.map(
+            lambda a: a[held[0]:held[0] + held[1]], lp[n]) for n in names})
+    args = (x, jnp.ones((tokens,), bool), lp["router"],
+            lp["wg"] if gated else None, lp["wu"], lp["wd"], k)
+    at = (jax.tree.map(lambda a: a[None], {n: lp[n] for n in names}),
+          jnp.int32(0))
+    jaxpr = str(jax.make_jaxpr(
+        lambda: moe._routed_ffn(*args, at, routing))())
+    assert jaxpr.count("moe_gmm") == 2 and "ragged_dot" not in jaxpr
+    with jax.default_matmul_precision("highest"):
+        kernel, pairs = moe._routed_ffn(*args, at, routing)
+        ragged, _ = moe._routed_ffn(*args, None, routing)
+        dense, pairs_dense = moe._dense_mixture(*args, routing)
+    np.testing.assert_allclose(kernel, ragged, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(kernel, dense, rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(pairs, pairs_dense)
+    assert np.abs(np.asarray(kernel)).max() > 0
 
 
 class TestMoEForward:

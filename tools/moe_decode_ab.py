@@ -16,7 +16,11 @@ weight stream's floor (every expert read once at 819 GB/s) and each form's
 share of it (the kernel reads the HIT experts alone, so it can read over 1
 where few tokens hit few experts) — the reading `models/moe.py
 ROUTED_MIN_TOKENS` and `ROUTED_FROM` are set from. `--row-tiles` times the
-kernel at other row tiles than `ops/gmm.py geometry` chooses.
+kernel at other row tiles than `ops/gmm.py geometry` chooses. `--calls`
+times a gated layer's gate + up products as kernel calls ALONE (`calls`: two
+calls and the product between them, the pair call that serves them since
+PR 64, and — the price of a visit by removal — the two calls against ONE
+call over a [D, 2F] concatenation of the two stacks).
 
     python tools/moe_decode_ab.py            # on the chip
     python tools/moe_decode_ab.py --shape 72,10,4096,768 --layers 2 \
@@ -60,6 +64,8 @@ def main() -> int:
                          "granite-4.0-h-small whole is 72,10,4096,768")
     ap.add_argument("--held", type=int, default=0,
                     help="experts held of those routed over (0: all)")
+    ap.add_argument("--calls", action="store_true",
+                    help="also time the gate + up kernel calls alone")
     ap.add_argument("--ungated", default="",
                     help="two-matrix experts under this activation "
                          "(e.g. relu2); default: gated, silu")
@@ -71,6 +77,7 @@ def main() -> int:
     from symmetry_tpu.models.moe import (
         _dense_mixture, _routed_ffn, grouped_matmul_form, moe_route)
     from symmetry_tpu.ops import gmm
+    from symmetry_tpu.ops.interpret import interpret_mode
     from symmetry_tpu.ops.quant import make_leaf
 
     X, k, D, F = (int(v) for v in args.shape.split(","))
@@ -119,14 +126,52 @@ def main() -> int:
             return jax.lax.scan(body, x, layers)[0]
         return jax.jit(run)
 
-    def ms_a_layer(form, x):
-        fn = trunk(form)
-        fn(x, layers).block_until_ready()
+    def timed(fn, *operands):      # ms a layer, compiled before the clock
+        fn(*operands).block_until_ready()
         t0 = time.perf_counter()
         for _ in range(args.repeats):
-            y = fn(x, layers)
+            y = fn(*operands)
         y.block_until_ready()
         return round(1e3 * (time.perf_counter() - t0) / args.repeats / L, 4)
+
+    def ms_a_layer(form, x):
+        return timed(trunk(form), x, layers)
+
+    def calls(T):
+        """ms a layer of gate + up over T tokens routed uniformly, the
+        kernel calls alone; each form scanned over the layers, the stacks
+        its arguments."""
+        sizes = jnp.bincount(jax.lax.top_k(jax.random.normal(
+            jax.random.key(T), (T, held)), k)[1].reshape(-1), length=held)
+        rows = jax.random.normal(jax.random.key(T + 1), (T * k, D),
+                                 jnp.bfloat16)
+        both = jax.tree.map(lambda g, u: jnp.concatenate([g, u], -1), wg, wu)
+        act, interpret = jax.nn.silu, interpret_mode()
+
+        def call(q, scale, i, **kw):
+            return gmm.grouped_matmul(rows, q, scale, sizes, i,
+                                      interpret=interpret, **kw)
+
+        def whole(out):     # read every element, keep one number
+            return jnp.sum(out.astype(jnp.float32))
+
+        forms = {
+            "two_calls+product": ((wg, wu), lambda g, u, i: whole(
+                (act(call(*g, i)) * call(*u, i)).astype(rows.dtype))),
+            "pair_call": ((wg, wu), lambda g, u, i: whole(
+                call((g.q, u.q), (g.scale, u.scale), i, act=act))),
+            # the calls' own time: eight rows of each result are read
+            "two_calls": ((wg, wu), lambda g, u, i: whole(
+                call(*g, i)[:8] + call(*u, i)[:8])),
+            "one_call_over_[D,2F]": ((both,), lambda b, i: whole(
+                call(*b, i)[:8]))}
+        out = {"hit_experts": int((sizes > 0).sum())}
+        for name, (stacks, form) in forms.items():
+            fn = jax.jit(lambda *a, form=form: jax.lax.scan(
+                lambda acc, i: (acc + form(*a, i), None), jnp.float32(0),
+                jnp.arange(L, dtype=jnp.int32))[0])
+            out[name] = timed(fn, *stacks)
+        return out
 
     layers = (jnp.arange(L, dtype=jnp.int32), router,
               *(() if args.ungated else (wg,)), wu, wd)
@@ -157,6 +202,8 @@ def main() -> int:
         if floor:
             row["floor_share"] = {name: round(floor / row[name], 3)
                                   for name in forms}
+        if args.calls and not args.ungated:
+            row["calls"] = calls(T)
         row["route"] = moe_route(T, X, k, args.held or None)
         out["ms_per_layer"][str(T)] = row
     print(json.dumps(out), flush=True)
