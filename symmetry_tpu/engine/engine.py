@@ -44,7 +44,10 @@ from symmetry_tpu.models.llama import (
     kv_row,
     logits_from_hidden,
     preset,
+    MTP_COUNTS,
+    tail_add,
 )
+from symmetry_tpu.models.hybrid import mtp_forward
 
 
 from symmetry_tpu.ops.sampling import (
@@ -93,6 +96,11 @@ class EngineError(RuntimeError):
     pass
 
 
+# `DecodeState.draft` of a lane that drafts nothing (any negative value is
+# "no draft this step"; this one is also kept from step to step)
+DRAFT_OFF = -2
+
+
 class DecodeState(NamedTuple):
     """Everything the decode step needs, all static-shape device arrays."""
 
@@ -105,6 +113,11 @@ class DecodeState(NamedTuple):
                               # at insert: a seeded request reproduces its
                               # whole completion and no slot's sampling is
                               # perturbed by other traffic
+    # Under `tpu.speculative: mtp` alone (None, so no leaf, otherwise):
+    # [B] int32, the token the model's multi-token-prediction module
+    # drafted to FOLLOW `last_token` — or DRAFT_OFF for a lane whose
+    # request opted out, which every step then advances one token
+    draft: jnp.ndarray | None = None
 
 
 @dataclass(frozen=True)
@@ -264,7 +277,8 @@ class InferenceEngine:
         refused = residents.refusals(
             config, mesh=mesh is not None, role=role,
             prefix_cache=prefix_cache_bytes > 0,
-            speculative=speculative is not None,
+            speculative=(False if speculative is None
+                         else getattr(speculative, "drafter", True)),
             prefill_chunk=prefill_chunk, kv_quant=kv_quant)
         if refused:
             raise EngineError(refused[0])
@@ -276,6 +290,19 @@ class InferenceEngine:
         self._latent = found.get("latent row")
         self._window = found.get("window ring")
         self._diffusion = found.get("diffusion block")
+        # the model's own module drafts inside the decode block
+        # (`tpu.speculative: mtp`); its rows are written by every prefill
+        # and step only then — served without it, the module is dead weight
+        self._mtp = getattr(speculative, "drafter", None) == residents.MTP
+        # a window layer's ring: the window's rows, and a draft's where
+        # drafts are verified (models/residents.py ring_rows)
+        self._ring = residents.ring_rows(
+            config, 0 if speculative is None else speculative.k_draft)
+        # A parity tool's or a test's window into the programs the module
+        # drafts in (None: nothing is traced in): called on the host with
+        # ("prefill" | "trunk" | "module", lengths before, logits, ...) from
+        # inside the program — set it, then `_build_jits()` again
+        self.tap = None
         # since start, a block a row (`stats_blocks`): what the device
         # counted as of the last synced decode block (the words the row
         # appends to `expert_pairs`, `collect_expert_pairs`) and what the
@@ -283,7 +310,8 @@ class InferenceEngine:
         self.counters: dict[str, dict[str, int]] = {
             row.block: dict.fromkeys(names, 0)
             for row in rows if (names := row.counters())}
-        self._tail = next((row for row in rows if row.words), None)
+        self._tails = [(row, residents.tail_at(config, row.words))
+                       for row in rows if row.words]
         # Generation by diffusion over blocks (models/llama.py
         # BlockDiffusion): the two generation settings.
         self.diffusion: dict | None = None
@@ -440,15 +468,17 @@ class InferenceEngine:
                 cache=init_cache(c, max_slots, max_seq_len, cache_dtype,
                                  quantized=kv_quant,
                                  count_experts=self._count_experts,
-                                 # (a window layer's ring: the window's
-                                 # rows, whatever the capacity)
+                                 # (a window layer's ring, whatever
+                                 # the capacity)
                                  **({} if self._window is None
-                                    else {"ring": c.sliding_window})),
+                                    else {"ring": self._ring})),
                 last_token=jnp.zeros((max_slots,), jnp.int32),
                 temperature=jnp.zeros((max_slots,), jnp.float32),
                 top_p=jnp.ones((max_slots,), jnp.float32),
                 top_k=jnp.zeros((max_slots,), jnp.int32),
                 rng=jax.random.split(jax.random.key(0), max_slots),
+                draft=(jnp.full((max_slots,), DRAFT_OFF, jnp.int32)
+                       if self._mtp else None),
             )
 
         if self._state_shardings is not None:
@@ -609,6 +639,24 @@ class InferenceEngine:
                 axis=1)  # [N, 1, E]
             last = logits_from_hidden(params, cfg, h_last)[:, 0]  # [N, V]
             toks = sample_tokens(last, rng, temp, top_p, top_k)  # [N] keys
+            if self._mtp:
+                # The module over the whole prompt, its rows into its own
+                # cache layer: position t reads the trunk's hidden state
+                # there and token t + 1 — the sampled first token at the
+                # prompt's last position, whose output is the first draft.
+                # Returned beside the first token: [N, 2].
+                rows = jnp.arange(tokens.shape[0])
+                after = jnp.roll(tokens, -1, axis=1).at[
+                    rows, true_len - 1].set(toks)
+                first, cache = self._module_logits(
+                    params, h, after,
+                    cache._replace(lengths=jnp.zeros_like(cache.lengths)),
+                    true_len, prefill_flash=True)
+                draft = jnp.argmax(first, axis=-1)
+                if self.tap is not None:
+                    jax.debug.callback(self.tap, "prefill", true_len, last,
+                                       first, ordered=True)
+                toks = jnp.stack([toks, draft.astype(toks.dtype)], axis=1)
             return toks, cache
 
         def insert(state: DecodeState, prefix: KVCache, row, slot, true_len,
@@ -692,7 +740,12 @@ class InferenceEngine:
             # nothing is fed on from it, the last token fills the field)
             first = (first_token[row] if self._diffusion is None
                      else first_token[row, -1])
+            draft = None
+            if self._mtp:   # the prefill's [N, 2]: first token, first draft
+                first = first_token[row, 0]
+                draft = state.draft.at[slot].set(first_token[row, 1])
             return DecodeState(
+                draft=draft,
                 cache=cache,
                 last_token=state.last_token.at[slot].set(first),
                 temperature=state.temperature.at[slot].set(temp[row]),
@@ -867,6 +920,78 @@ class InferenceEngine:
             return state._replace(cache=state.cache._replace(
                 expert_pairs=jnp.zeros_like(pairs))), toks, pairs
 
+        def mtp_one(state: DecodeState, params):
+            """One step of a lane that carries a draft: [last_token,
+            draft] through the trunk (the continuation path verify_block
+            uses; a window layer's ring has a row for the drafted position
+            beside the window's), the draft scored by `verify_tokens`
+            unchanged — the module's argmax is a deterministic proposer —
+            so 1 or 2 tokens come out; the lengths roll back over a
+            rejected position; then the module over the positions that
+            stayed (their hidden states, the tokens that came out), whose
+            last output is the next draft. A lane without a draft
+            (`draft` < 0) advances one token like a plain step."""
+            n_draft = (state.draft >= 0).astype(jnp.int32)
+            draft = jnp.maximum(state.draft, 0)
+            old = state.cache.lengths
+            h, cache = forward_hidden(
+                params, cfg, jnp.stack([state.last_token, draft], axis=1),
+                state.cache, seq_lens=1 + n_draft, tp_mesh=mesh)
+            logits = logits_from_hidden(params, cfg, h)       # [B, 2, V]
+            split = jax.vmap(lambda q: jax.random.split(q, 2))(state.rng)
+            rng, step_key = split[:, 0], split[:, 1]
+            out, n_emit = verify_tokens(
+                logits, draft[:, None], n_draft, step_key,
+                state.temperature, state.top_p, state.top_k)
+            last = jnp.take_along_axis(out, (n_emit - 1)[:, None],
+                                       axis=1)[:, 0]
+            if self.tap is not None:
+                jax.debug.callback(self.tap, "trunk", old, logits,
+                                   state.draft, out, n_emit, ordered=True)
+            proposed, cache = self.device_drafter(
+                params, h, out, n_emit, cache._replace(lengths=old))
+            live = old > 0
+            if cache.expert_pairs is not None:
+                def total(x):
+                    return jnp.sum(jnp.where(live, x, 0), dtype=jnp.int32)
+
+                cache = cache._replace(expert_pairs=tail_add(
+                    cache.expert_pairs, cfg, MTP_COUNTS, jnp.stack([
+                        total(n_draft), total(n_emit - 1), total(n_emit),
+                        total(1)])))
+            cache = cache._replace(
+                lengths=_stay_parked(old, old + n_emit))
+            return state._replace(
+                cache=cache, last_token=last, rng=rng,
+                draft=jnp.where(state.draft == DRAFT_OFF, DRAFT_OFF,
+                                proposed.astype(jnp.int32))), (out, n_emit)
+
+        def mtp_decode_block(params, state: DecodeState, park):
+            """`decode_block` where the module drafts: K steps of 1 or 2
+            tokens a lane in ONE dispatch. Returns (state, tokens
+            [2K + 1, B], pairs): a lane's tokens packed from row 0 in the
+            order they came out, and in the LAST row how many they are."""
+            state, (outs, n) = jax.lax.scan(
+                lambda s, _: mtp_one(s, params), _park(state, park), None,
+                length=self.decode_block)          # [K, B, 2], [K, B]
+            K, B = n.shape
+            flat = outs.transpose(0, 2, 1).reshape(2 * K, B)
+            valid = (jnp.arange(2 * K, dtype=jnp.int32)[:, None] % 2
+                     < jnp.repeat(n, 2, axis=0))
+            order = jnp.argsort(~valid, axis=0, stable=True)
+            toks = jnp.concatenate(
+                [jnp.take_along_axis(flat, order, axis=0),
+                 jnp.sum(n, axis=0, dtype=jnp.int32)[None]], axis=0)
+            pairs = state.cache.expert_pairs
+            return state._replace(cache=state.cache._replace(
+                expert_pairs=jnp.zeros_like(pairs))), toks, pairs
+
+        def lanes_draft_off(state: DecodeState, slots):
+            """The lanes `slots` draft nothing from here on (a request's
+            `"speculative": false`)."""
+            return state._replace(
+                draft=state.draft.at[slots].set(DRAFT_OFF))
+
         def verify_block(params, state: DecodeState, draft, n_draft, park):
             """Speculative verify: ONE batched forward over [B, 1+k_draft]
             positions — the pending last_token plus every slot's drafted
@@ -910,6 +1035,9 @@ class InferenceEngine:
 
         if self._diffusion is not None:
             prefill, decode_block = self._diffusion_programs()
+        if self._mtp:
+            decode_block = mtp_decode_block
+            self._draft_off = jax.jit(lanes_draft_off, donate_argnums=(0,))
 
         state_shard = self._state_shardings
         if self.mesh is not None:
@@ -941,7 +1069,7 @@ class InferenceEngine:
                                     out_shardings=(rep, prefix_shard))
             self._decode = jax.jit(decode_block, donate_argnums=(1,),
                                    out_shardings=(state_shard, rep, rep))
-            if self.spec is not None:
+            if self.spec is not None and not self._mtp:
                 self._verify = jax.jit(
                     verify_block, donate_argnums=(1,),
                     out_shardings=(state_shard, rep, rep))
@@ -960,7 +1088,7 @@ class InferenceEngine:
         else:
             self._prefill = jax.jit(prefill, donate_argnums=(7,))
             self._decode = jax.jit(decode_block, donate_argnums=(1,))
-            if self.spec is not None:
+            if self.spec is not None and not self._mtp:
                 self._verify = jax.jit(verify_block, donate_argnums=(1,))
             self._chunk_step = jax.jit(chunk_step, donate_argnums=(2,))
             self._chunk_final = jax.jit(chunk_final, donate_argnums=(2,))
@@ -1264,6 +1392,8 @@ class InferenceEngine:
         for start in range(0, len(assignments), cap):
             part = assignments[start:start + cap]
             toks = np.asarray(self.prefill_and_insert_many_dispatch(part))
+            if self._mtp:       # [N, 2]: the first token, the first draft
+                toks = toks[:, 0]
             firsts.extend(int(tok) for tok in toks[:len(part)])
         return firsts
 
@@ -1339,7 +1469,48 @@ class InferenceEngine:
         # the next same-shape prefill the moment the insert executes —
         # device-order sequencing makes immediate reuse safe.
         self._store_prefill_scratch(batch, bucket, prefix)
+        # (where the module drafts this is the prefill's [N, 2] — the first
+        # token and the first draft, which the insert took on the device —
+        # unread: a slice here would be a program of its own a batch size,
+        # compiled in the middle of traffic. The reader takes column 0.)
         return toks
+
+    def device_drafter(self, params, h, out, n_emit, cache):
+        """The seam a drafter that runs INSIDE the decode program fills
+        (engine/spec/: the host-side n-gram drafter's counterpart), traced
+        into every step of `mtp_decode_block`: `h` [B, 2, E] the trunk's
+        hidden states at the step's two positions, `out` [B, 2] the tokens
+        that came out, of which `n_emit` [B] stay, `cache` at the lengths
+        BEFORE the step. Returns (the token each lane drafts to follow its
+        last one [B], the cache with whatever rows the drafter keeps
+        written — the engine sets the lengths). Here: the model's
+        multi-token-prediction module over the positions that stayed, its
+        argmax at the last of them."""
+        logits, cache = self._module_logits(params, h, out, cache, n_emit)
+        if self.tap is not None:
+            jax.debug.callback(self.tap, "module", cache.lengths - n_emit,
+                               logits, n_emit, ordered=True)
+        return jnp.argmax(logits, axis=-1), cache
+
+    def _module_logits(self, params, h, after, cache, n, *,
+                       prefill_flash: bool = False):
+        """The module over `n` [B] positions a lane from `cache.lengths`
+        (`h` the trunk's hidden states there, `after` the token after
+        each): (its logits at the LAST of them [B, V], the cache with its
+        rows written and the lengths advanced)."""
+        hm, cache = mtp_forward(params, self.config, h, after, cache,
+                                seq_lens=n, prefill_flash=prefill_flash)
+        hm_last = jnp.take_along_axis(
+            hm, (n - 1)[:, None, None].astype(jnp.int32), axis=1)
+        return logits_from_hidden(params, self.config, hm_last)[:, 0], cache
+
+    def draft_off(self, slot: int) -> None:
+        """Lane `slot`'s request opted out of drafting (`"speculative":
+        false`): its steps advance one token. Nothing to do unless the
+        model's own module drafts."""
+        if self._mtp:
+            self.state = self._draft_off(
+                self.state, jnp.asarray([slot], jnp.int32))
 
     # ------------------------------------------------------------------
     # Shared-prefix KV cache (engine side; bookkeeping in prefix_cache.py)
@@ -1544,8 +1715,11 @@ class InferenceEngine:
         if getattr(c, "window_kind", None) is not None:
             kinds = {"full": (c.attention_kind,),
                      "window": (c.window_kind,)}.get(kind, c.attention_kinds)
-            per_plane = c.num_kv_heads * sum(len(c.layers_of(name))
-                                             for name in kinds)
+            # (a multi-token-prediction module's rows are one more full
+            # layer's, written whenever the module drafts)
+            per_plane = c.num_kv_heads * (
+                sum(len(c.layers_of(name)) for name in kinds)
+                + (c.mtp_layers if kind != "window" else 0))
             return 2 * per_plane * (
                 c.dim_per_head + 4 if self.kv_quant else
                 c.dim_per_head * jnp.dtype(self.cache_dtype).itemsize)
@@ -2178,7 +2352,7 @@ class InferenceEngine:
         # lane one garbage token — harmless on the pre-insert empty cache,
         # same contract as the decode warmup above. The sync surfaces a
         # marginal-HBM failure at startup.
-        if self.spec is not None and decode_side:
+        if self.spec is not None and not self._mtp and decode_side:
             self._warm_verify()
 
         if self.role == "prefill":
@@ -2284,7 +2458,7 @@ class InferenceEngine:
                         # consecutive decode blocks (no admission between)
                         self.state, _, _ = self._dispatch_decode()
                         self.state, _, _ = self._dispatch_decode()
-                    if self.spec is not None:
+                    if self.spec is not None and not self._mtp:
                         self.verify_step(
                             np.zeros((self.max_slots, self.spec.k_draft),
                                      np.int32),
@@ -2339,8 +2513,9 @@ class InferenceEngine:
         decode block (pre-pipeline, the same-iteration sync ate the
         overlap). The next PROPOSAL still waits for the sync: drafts are
         built from this dispatch's output."""
-        if self.spec is None:
-            raise EngineError("speculative decoding is not enabled")
+        if self.spec is None or self._mtp:
+            raise EngineError("speculative decoding by a host drafter is "
+                              "not enabled")
         k = self.spec.k_draft
         if draft.shape != (self.max_slots, k):
             raise EngineError(
@@ -2394,11 +2569,10 @@ class InferenceEngine:
             block = np.asarray(self._pairs_pending.popleft())
             self.expert_pairs = [a + int(b) for a, b in
                                  zip(self.expert_pairs, block)]
-            row = self._tail
-            if row is not None:
-                # the vector's last words are the one row's that has any
-                # (`residents.tail_words`, which sized it)
-                tail = block[-len(row.words):]
+            for row, (lo, hi) in self._tails:
+                # each row's words where the table lays them
+                # (`residents.tail_at`, which `tail_words` sized)
+                tail = block[lo:hi]
                 counts = self.counters[row.block]
                 for name, n in (zip(row.words, tail) if row.decode is None
                                 else row.decode(tail).items()):
@@ -2550,7 +2724,19 @@ class InferenceEngine:
                              else self.cache_dtype).itemsize
         paths = attention_paths(
             self.config, self.max_seq_len, self.mesh,
-            batch=self.max_slots, kv_bytes=kv_bytes)
+            batch=self.max_slots, kv_bytes=kv_bytes,
+            # (a ring that carries a draft has more rows than the window)
+            **({"ring": self._ring} if self._window is not None else {}))
+        if self._mtp:
+            # every decode step carries the pending token and the module's
+            # draft: two positions a slot, each over the rows up to its own
+            paths["verify"] = {
+                "positions": 2,
+                "full": ("the decode kernel once a position"
+                         if paths["full"]["decode"] != "xla"
+                         else "gqa_attention over the leaf"),
+                "window": "gqa_attention, each ring row masked by the "
+                          "position it holds"}
         if self._diffusion is not None:
             # the admission program denoises the opening block over its
             # scratch of bucket + block positions: the same routing, asked
@@ -2595,6 +2781,8 @@ class InferenceEngine:
                 "kind": "window+full",
                 "dtype": str(self.state.cache.k.dtype),
                 "full": {"layers": len(c.layers_of(c.attention_kind)),
+                         **({"module_layers": c.mtp_layers}
+                            if c.mtp_layers else {}),
                          "rows": self.max_seq_len,
                          "bytes_per_token": full},
                 "window": {"layers": len(c.layers_of(c.window_kind)),

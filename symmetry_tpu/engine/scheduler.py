@@ -408,7 +408,11 @@ class Scheduler:
         # blocks. Engine spec None => self._drafter None => every code
         # path below is byte-identical to the non-speculative scheduler.
         spec = getattr(engine, "spec", None)
-        if spec is not None:
+        # (the model's own module drafts ON THE DEVICE, inside the decode
+        # block: no host drafter, no verify dispatch — a block's lanes
+        # come back with 1 or 2 tokens a step, `_sync_and_process`)
+        self._device_drafts = spec is not None and spec.drafter == "mtp"
+        if spec is not None and not self._device_drafts:
             from symmetry_tpu.engine.spec import NGramDrafter
 
             self._drafter: NGramDrafter | None = NGramDrafter(spec)
@@ -425,7 +429,7 @@ class Scheduler:
         # touches 1 + k_draft positions where a plain block touches
         # decode_block — the capacity guards must fence the larger.
         self._max_block_writes = max(
-            engine.decode_block,
+            engine.decode_block * (2 if self._device_drafts else 1),
             (1 + spec.k_draft) if spec is not None else 0)
         self.metrics = {"requests": 0, "tokens": 0, "evictions": 0,
                         "steps": 0,
@@ -1246,6 +1250,11 @@ class Scheduler:
         with self._phase("sync", **read.attrs) as span:
             t0 = time.perf_counter()
             toks = np.asarray(device_toks)  # blocks on THIS block only
+            if self._device_drafts and kind == "decode_block":
+                # the module drafted inside the block: a lane's tokens lie
+                # packed from row 0 and the LAST row counts them (engine
+                # mtp_decode_block) — the ragged form a verify takes
+                toks, n_valid = toks[:-1], toks[-1]
             # MoE: the block's per-expert pair counts came out of the
             # same program, so they are ready — a read, not a wait.
             collect = getattr(self.engine, "collect_expert_pairs", None)
@@ -1796,10 +1805,17 @@ class Scheduler:
             return cached(group, hit)
         many = getattr(engine, "prefill_and_insert_many_dispatch", None)
         if many is not None:
-            return many(group)
-        if len(group) > 1:
-            return engine.prefill_and_insert_many(group)
-        return [engine.prefill_and_insert(*group[0])]
+            toks = many(group)
+        elif len(group) > 1:
+            toks = engine.prefill_and_insert_many(group)
+        else:
+            toks = [engine.prefill_and_insert(*group[0])]
+        if self._device_drafts:
+            # a request's opt-out, before the next block is dispatched
+            for slot, req in sub:
+                if req.speculative is False:
+                    engine.draft_off(slot)
+        return toks
 
     def _charge(self, shape: tuple, dt: float, materialised: bool) -> float:
         """Charge one admission dispatch to the block's budget; returns
@@ -2019,6 +2035,10 @@ class Scheduler:
                 firsts = np.asarray(adm.toks)
                 # one first token a row — or, from a model that generates
                 # by diffusion over blocks, the row's opening block
+                if self._device_drafts and firsts.ndim == 2:
+                    # [N, 2]: the first token, and the module's first
+                    # draft, which went to the insert on the device
+                    firsts = firsts[:, 0]
                 firsts = (firsts.reshape(-1) if self._bd_block is None
                           else firsts.reshape(-1, self._bd_block))
             except Exception as exc:  # noqa: BLE001 — device errors → stream error

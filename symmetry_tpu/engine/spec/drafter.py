@@ -39,6 +39,11 @@ class SpecConfig:
     (draft tokens per slot per dispatch); the n-gram bounds trade match
     precision (longer = fewer, better matches) against coverage."""
 
+    # who drafts: "ngram" (the host-side prompt-lookup drafter below) or
+    # "mtp" — the model's own multi-token-prediction module, which lives in
+    # the parameters and drafts ONE token a step inside the decode block
+    # (engine/engine.py mtp_one); the n-gram fields then mean nothing
+    drafter: str = "ngram"
     k_draft: int = 8
     ngram_max: int = 3
     ngram_min: int = 1
@@ -50,6 +55,12 @@ class SpecConfig:
     max_index_tokens: int = 4096
 
     def __post_init__(self) -> None:
+        if self.drafter not in ("ngram", "mtp"):
+            raise ValueError(f"speculative drafter must be 'ngram' or "
+                             f"'mtp', got {self.drafter!r}")
+        if self.drafter == "mtp" and self.k_draft != 1:
+            raise ValueError("the multi-token-prediction module drafts one "
+                             "token a step: k_draft is 1")
         if self.k_draft < 1:
             raise ValueError("speculative k_draft must be >= 1")
         if not 1 <= self.ngram_min <= self.ngram_max:
@@ -60,11 +71,14 @@ class SpecConfig:
     @classmethod
     def from_knob(cls, knob: Any) -> "SpecConfig | None":
         """Parse the `tpu.speculative` config value: falsy disables;
-        True = defaults; an int = k_draft; a mapping = field overrides."""
+        True = defaults; an int = k_draft; a mapping = field overrides;
+        "mtp" = the model's own multi-token-prediction module drafts."""
         if not knob:
             return None
         if knob is True:
             return cls()
+        if knob == "mtp":
+            return cls(drafter="mtp", k_draft=1)
         if isinstance(knob, int):
             return cls(k_draft=knob)
         if isinstance(knob, dict):
@@ -75,7 +89,7 @@ class SpecConfig:
                     f"unknown tpu.speculative keys: {sorted(unknown)}")
             return cls(**{k: int(v) for k, v in knob.items()})
         raise ValueError(
-            f"tpu.speculative must be a bool, int, or mapping, "
+            f"tpu.speculative must be a bool, int, mapping or 'mtp', "
             f"got {type(knob).__name__}")
 
 

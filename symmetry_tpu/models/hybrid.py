@@ -115,6 +115,8 @@ KIND_STACK = {"mamba": "mamba", "attention": "attn",
               "conv": "sconv", "latent_attention": "attn",
               "sliding_attention": "swa"}
 RECURRENT = {"mamba": mamba2, "linear_attention": gdn, "conv": sconv}
+# the scope a multi-token-prediction module's ops carry in a device trace
+MTP_SCOPE = "mtp_module"
 
 
 def stack_index(config, i: int) -> int:
@@ -155,7 +157,10 @@ def init_cache(config, batch: int, capacity: int, dtype=jnp.bfloat16, *,
     """`ring` (a window / full model alone): the rows of the window layers'
     leaves — the window for a served cache, None for a prefill scratch,
     whose window leaves are `capacity` long like its full ones."""
-    n_attn = len(config.layers_of(config.attention_kind))
+    # (a multi-token-prediction module's block keeps its rows in a layer of
+    # its own BEHIND the trunk's full layers: `mtp_forward`)
+    n_attn = (len(config.layers_of(config.attention_kind))
+              + config.mtp_layers)
     ssm, conv = state_shapes(config, batch)
     shape = (n_attn, batch, capacity, *llama.kv_row(config))
     pairs = llama.init_expert_pairs(config) if count_experts else None
@@ -206,7 +211,7 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
     c = config
     if slice_above is None:
         slice_above = default_leaf_limit()
-    keys = iter(jax.random.split(key, 24))
+    keys = iter(jax.random.split(key, 48 if c.mtp_layers else 24))
 
     def dense(shape, name=None, scale=None):
         scale = shape[-2] ** -0.5 if scale is None else scale
@@ -219,6 +224,8 @@ def init_params(config, key: jax.Array, dtype=jnp.bfloat16, *,
     if c.latent is not None:
         return llama.absorb_latent(_init_latent(c, keys, dense, dtype), c,
                                    dtype)
+    if c.recurrent_kind is None and c.router_input == "ffn_input":
+        return _init_exaone(c, keys, dense, dtype)
     if c.recurrent_kind is None:
         return _init_window(c, keys, dense, dtype)
     if c.recurrent_kind == "linear_attention":
@@ -517,6 +524,115 @@ def _init_window(c, keys, dense, dtype) -> dict:
     return params
 
 
+def _init_exaone(c, keys, dense, dtype) -> dict:
+    """`init_params` for an exaone_moe config: an attention stack a kind
+    with q/k norms (`attn` the full layers, `swa` the window layers), the
+    leading dense layers, then experts (the HELD ones' weights alone) under
+    deepseek_v3's router beside a shared expert, an untied head — and,
+    under `mtp`, each multi-token-prediction module as a stack of its own
+    (leading axis: the module): the two input norms, the projection of
+    [hidden ; embedding] (`in_proj`, [2E, E]), one full-attention block
+    with an expert FFN like the trunk's last, and its output norm.
+    `expert_bias` drawn as `_init_latent` draws it."""
+    E, F, X, D = c.hidden_size, c.intermediate_size, c.experts_here, \
+        c.dim_per_head
+    Ld, Fd, Fs = c.num_dense_layers, c.dense_intermediate_size, \
+        c.shared_intermediate_size
+
+    def attention(n):
+        return {"norm": jnp.ones((n, E), dtype),
+                "wq": dense((n, E, c.q_dim), "wq"),
+                "wk": dense((n, E, c.kv_dim), "wk"),
+                "wv": dense((n, E, c.kv_dim), "wv"),
+                "wo": dense((n, c.q_dim, E), "wo"),
+                "q_norm": jnp.ones((n, D), dtype),
+                "k_norm": jnp.ones((n, D), dtype)}
+
+    def experts(n):
+        return {"norm": jnp.ones((n, E), dtype),
+                "router": dense((n, E, c.num_experts)),
+                "wg": dense((n, X, E, F), "wg"),
+                "wu": dense((n, X, E, F), "wu"),
+                "wd": dense((n, X, F, E), "wd"),
+                "sg": dense((n, E, Fs), "sg"),
+                "su": dense((n, E, Fs), "su"),
+                "sd": dense((n, Fs, E), "sd"),
+                "expert_bias": jax.random.uniform(
+                    next(keys), (n, c.num_experts), jnp.float32, -0.25,
+                    0.25)}
+
+    params = {
+        "embed": dense((c.vocab_size, E), scale=0.02),
+        "layers": {
+            **{KIND_STACK[kind]: attention(len(c.layers_of(kind)))
+               for kind in c.attention_kinds},
+            "dense": {"norm": jnp.ones((Ld, E), dtype),
+                      "wg": dense((Ld, E, Fd), "wg"),
+                      "wu": dense((Ld, E, Fd), "wu"),
+                      "wd": dense((Ld, Fd, E), "wd")},
+            "ffn": experts(c.num_layers - Ld),
+        },
+        "final_norm": jnp.ones((E,), dtype),
+    }
+    if not c.tie_embeddings:
+        params["lm_head"] = dense((E, c.vocab_size), "lm_head", scale=0.02)
+    if c.mtp_layers:
+        M = c.mtp_layers
+        params["mtp"] = {
+            "hnorm": jnp.ones((M, E), dtype),
+            "enorm": jnp.ones((M, E), dtype),
+            "in_proj": dense((M, 2 * E, E), "in_proj"),
+            "attn": attention(M), "ffn": experts(M),
+            "final_norm": jnp.ones((M, E), dtype)}
+    return params
+
+
+def mtp_forward(params: dict, config, h: jnp.ndarray, tokens: jnp.ndarray,
+                cache: llama.KVCache, seq_lens: jnp.ndarray | None = None,
+                *, prefill_flash: bool = False
+                ) -> tuple[jnp.ndarray, llama.KVCache]:
+    """The multi-token-prediction module (DeepSeek-V3's form, arXiv:
+    2412.19437 s2.2) over S positions a slot from `cache.lengths`: `h`
+    [B, S, E] the trunk's hidden state there AS THE HEAD READS IT (after the
+    final norm), `tokens` [B, S] the token AFTER each position. x' =
+    in_proj [norm(h) ; norm(embed(token))], one pre-norm block — full NoPE
+    attention over the module's own rows, which lie in the `k` / `v`
+    leaves' layer behind the trunk's full layers and share the slot's
+    length, then the expert FFN — and the module's own norm: what
+    `logits_from_hidden` turns into logits for the token after `tokens`.
+    Returns (that hidden state [B, S, E], the cache with the rows written,
+    the module's expert pairs counted and `lengths` advanced)."""
+    c = config
+    B, S = tokens.shape
+    if seq_lens is None:
+        seq_lens = jnp.full((B,), S, jnp.int32)
+    positions = (cache.lengths[:, None]
+                 + jnp.arange(S, dtype=jnp.int32)[None, :])
+    kv_valid = cache.lengths + seq_lens
+    mp = _at(params["mtp"], 0)
+    layer = len(c.layers_of(c.attention_kind))
+
+    def norm(x, w):
+        return rms_norm(x, llama._norm_w(w, c), c.rms_eps)
+
+    with jax.named_scope(MTP_SCOPE):
+        e = jnp.take(params["embed"], tokens, axis=0)
+        x = qmatmul(jnp.concatenate(
+            [norm(h, mp["hnorm"]), norm(e, mp["enorm"])], axis=-1),
+            mp["in_proj"])
+        lp = mp["attn"]
+        out, cache = llama._attention(
+            norm(x, lp["norm"]), lp, cache, jnp.int32(layer), positions,
+            kv_valid, seq_lens, c, prefill_flash and S > 1, rope=False)
+        x = x + out
+        lp = mp["ffn"]
+        y, pairs = moe_mlp(norm(x, lp["norm"]), lp, c, seq_lens,
+                           stack=(params["mtp"]["ffn"], 0))
+        x = norm(x + y, mp["final_norm"])
+    return x, llama.add_expert_pairs(cache, pairs, c)._replace(
+        lengths=kv_valid)
+
+
 def runs(config) -> list[tuple[str, int, int]]:
     """The pattern as runs of one mixer kind AND one FFN kind: (mixer kind,
     first layer, length). A run breaks where either changes (lfm2_moe's
@@ -639,18 +755,22 @@ def forward_hidden(params: dict, config, tokens: jnp.ndarray,
                 **({"route_from": entered}
                    if c.router_input == "layer_input" else {}))
             h = h + r * y
-            return (h, llama.add_expert_pairs(cache, pairs)), None
+            return (h, llama.add_expert_pairs(cache, pairs, c)), None
 
         (h, cache), _ = jax.lax.scan(
             body, (h, cache), jnp.arange(length, dtype=jnp.int32))
     h = norm(h, params["final_norm"])
     if c.latent is not None and S == 1 and cache.expert_pairs is not None:
         cache = cache._replace(expert_pairs=llama.count_latent(
-            cache.expert_pairs, cache.lengths, kv_valid))
-    if (c.window_kind is not None and S == 1
+            cache.expert_pairs, cache.lengths, kv_valid, c))
+    if (c.window_kind is not None and not prefill_flash
             and cache.expert_pairs is not None):
+        # a decode step, or a verify of a drafted position or more (no
+        # other forward over a served ring: chunks are refused) — the rows
+        # its LAST position reads, each counted once
         cache = cache._replace(expert_pairs=llama.count_window(
-            cache.expert_pairs, cache.lengths, kv_valid, cache.kw.shape[2]))
+            cache.expert_pairs, cache.lengths, kv_valid,
+            min(cache.kw.shape[2], c.sliding_window), c))
     return h, cache._replace(lengths=kv_valid)
 
 
@@ -693,6 +813,8 @@ def hf_config(config) -> dict:
     c = config
     if c.latent is not None:
         return llama.hf_config_latent(c)
+    if c.recurrent_kind is None and c.router_input == "ffn_input":
+        return llama.hf_config_exaone(c)
     if c.recurrent_kind is None:
         return llama.hf_config_window(c)
     if c.ffn_layout is not None:
@@ -775,13 +897,18 @@ def hf_config(config) -> dict:
 
 
 def _no_name_map(config) -> None:
-    """A nemotron_h checkpoint's tensor names are not mapped: no checkpoint
-    of the family has been in the repository to hold a map to (the served
-    weights are random, from the seed)."""
+    """A nemotron_h or exaone_moe checkpoint's tensor names are not mapped:
+    no checkpoint of either family has been in the repository to hold a map
+    to (the served weights are random, from the seed)."""
     if config.ffn_layout is not None:
         raise ValueError(
             "a checkpoint of a model whose blocks are one sub-layer each "
             "(ffn_layout; HF nemotron_h) has no tensor-name map yet")
+    if config.recurrent_kind is None and config.latent is None and (
+            config.router_input == "ffn_input"):
+        raise ValueError(
+            "a checkpoint of a window / full model under deepseek_v3's "
+            "router (HF exaone_moe) has no tensor-name map yet")
 
 
 def _from_hf(ours: str, arr):

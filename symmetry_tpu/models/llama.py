@@ -22,6 +22,7 @@ tensor layout/naming map in `HF_LAYER_MAP` + `convert_hf_params`
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any, NamedTuple
 
@@ -277,6 +278,16 @@ class HybridConfig(MoEConfig):
     mamba_n_groups: int = 1
     experts_held: tuple[int, int] | None = None
     gated_ffn: bool = True
+    # exaone_moe family (K-EXAONE): smallthinker's window / full pair under
+    # lfm2_moe's q/k norms and leading dense layers and deepseek_v3's router
+    # and shared expert, every one a field above — and `mtp_layers`
+    # (`num_nextn_predict_layers`, 0 for every other model): multi-token-
+    # prediction modules behind the trunk (models/hybrid.py mtp_forward),
+    # each a full-attention NoPE block with a cache layer of its own in the
+    # `k` / `v` leaves BEHIND the trunk's full layers, that drafts the
+    # token after the next one from the trunk's hidden state and the next
+    # token's embedding (engine/engine.py: the on-device drafter)
+    mtp_layers: int = 0
 
     def __post_init__(self):
         kinds = set(self.layer_types)
@@ -330,6 +341,11 @@ class HybridConfig(MoEConfig):
             raise ValueError(
                 f"mamba_n_groups {self.mamba_n_groups} must divide "
                 f"mamba_n_heads {self.mamba_n_heads}")
+        if self.mtp_layers not in (0, 1) or (
+                self.mtp_layers and "sliding_attention" not in kinds):
+            raise ValueError(
+                f"mtp_layers {self.mtp_layers}: one multi-token-prediction "
+                f"module behind a window / full trunk is what is built")
         if self.experts_held is not None:
             first, count = self.experts_held
             if not (0 <= first and 0 < count
@@ -732,6 +748,48 @@ PRESETS: dict[str, ModelConfig] = {
         layer_types=(("full_attention",) + ("sliding_attention",) * 3) * 3,
         rope_layout=(0, 1, 1, 1) * 3, router_input="layer_input",
     ),
+    # the exaone_moe test size: two periods of (window, window, window,
+    # full NoPE) with a window of 8, one leading dense layer, 8 experts top 2
+    # by the sigmoid router of which this chip holds the first four, a shared
+    # expert, q/k norms, one multi-token-prediction module — and a
+    # vocabulary of 32, small enough that a random module's drafts land
+    "tiny-xm": HybridConfig(
+        vocab_size=32, hidden_size=64, num_layers=8, num_heads=4,
+        num_kv_heads=2, intermediate_size=32, head_dim=16,
+        rope_theta=10000.0, rms_eps=1e-5, max_position=512,
+        sliding_window=8, qk_norm=True,
+        num_experts=8, num_experts_per_tok=2, experts_held=(0, 4),
+        shared_intermediate_size=32,
+        num_dense_layers=1, dense_intermediate_size=96,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 2,
+        rope_layout=(1, 1, 1, 0) * 2,
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=2.5, router_norm_eps=1e-20, mtp_layers=1,
+    ),
+    # K-EXAONE-236B-A23B (HF `exaone_moe`) CUT IN DEPTH to 12 of its 48
+    # layers — three whole periods of (window, window, window, full NoPE),
+    # the leading dense layer and 11 sparse ones — plus its multi-token-
+    # prediction module, at every published width. The router scores all
+    # 128 experts; this chip HOLDS 16 of them and rows 0-19,199 of the
+    # vocabulary — one chip's share of eight that divide each layer's
+    # experts and the vocabulary eight ways and replicate the rest
+    # (benchmarks/configs/k-exaone-236b-a23b.json has the cut).
+    # `intermediate_size` is the routed expert's width
+    # (`moe_intermediate_size`), `dense_intermediate_size` the published
+    # `intermediate_size`.
+    "k-exaone-236b-a23b": HybridConfig(
+        vocab_size=19200, hidden_size=6144, num_layers=12, num_heads=64,
+        num_kv_heads=8, intermediate_size=2048, head_dim=128,
+        rope_theta=1000000.0, rms_eps=1e-5, max_position=262144,
+        sliding_window=128, qk_norm=True,
+        num_experts=128, num_experts_per_tok=8, experts_held=(0, 16),
+        shared_intermediate_size=2048,
+        num_dense_layers=1, dense_intermediate_size=18432,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 3,
+        rope_layout=(1, 1, 1, 0) * 3,
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=2.5, router_norm_eps=1e-20, mtp_layers=1,
+    ),
     # the CPU's copy of Nemotron-H's mechanisms: blocks of one sub-layer
     # ("MEM*EMEM*EME": every pairing — a mamba block before experts, before
     # attention, attention before experts — as 7 trunk layers of 12 blocks),
@@ -807,6 +865,13 @@ PRESETS: dict[str, ModelConfig] = {
         max_position=512, attention_bias=True,
     ),
 }
+
+
+# tiny-xm at a vocabulary the byte tokenizer fits (ids 0-257): what the
+# benchmark's CPU rehearsal serves (benchmarks/tests/test_run_xm_cpu.py);
+# its drafts land as rarely as the real cell's
+PRESETS["tiny-xm-bytes"] = dataclasses.replace(PRESETS["tiny-xm"],
+                                               vocab_size=512)
 
 
 def preset(name: str) -> ModelConfig:
@@ -1108,14 +1173,27 @@ def _put_scales(plane: jnp.ndarray, scales: jnp.ndarray, layer,
 LATENT_COUNTS = ("decode_steps", "live_positions")
 
 
+def tail_add(vector: jnp.ndarray, config, words: tuple[str, ...],
+             new: jnp.ndarray) -> jnp.ndarray:
+    """`vector` (an `expert_pairs`) with `new` added to the words of the
+    row that counts `words`, where the table says they lie
+    (models/residents.py tail_at)."""
+    from symmetry_tpu.models.residents import tail_at
+
+    lo, hi = tail_at(config, words)
+    # (a row that ends the vector lowers as it did while each model had one
+    # row: tools/lowered_programs.py holds the older programs byte for byte)
+    return jnp.concatenate([vector[:lo], vector[lo:hi] + new]
+                           + ([vector[hi:]] if hi < vector.shape[0] else []))
+
+
 def count_latent(vector: jnp.ndarray, lengths: jnp.ndarray,
-                 kv_valid: jnp.ndarray) -> jnp.ndarray:
-    """`vector` (an `expert_pairs` ending in LATENT_COUNTS) plus one decode
-    forward's counts. A parked lane (length 0) is no live row."""
+                 kv_valid: jnp.ndarray, config) -> jnp.ndarray:
+    """`vector` (an `expert_pairs` with LATENT_COUNTS in its tail) plus one
+    decode forward's counts. A parked lane (length 0) is no live row."""
     new = jnp.stack([jnp.int32(1), jnp.sum(
         jnp.where(lengths > 0, kv_valid, 0), dtype=jnp.int32)])
-    n = len(LATENT_COUNTS)
-    return jnp.concatenate([vector[:-n], vector[-n:] + new])
+    return tail_add(vector, config, LATENT_COUNTS, new)
 
 
 # What a window / full model's `expert_pairs` ends in, as a latent model's
@@ -1131,12 +1209,18 @@ WINDOW_COUNTS = ("decode_steps", "full_rows", "ring_rows", "ring_wraps")
 # pair of a forward fell on — which is what the routed form's kernel must
 # read, an expert's matrices a hit (models/moe.py; `stats.engine.moe`).
 HELD_COUNTS = ("expert_hits",)
+# What a model with a multi-token-prediction module counts a decode step the
+# module drafts for (engine/engine.py; `stats.engine.mtp`), live lanes
+# alone: drafts scored, drafts accepted, tokens yielded, (slot, step) pairs.
+MTP_COUNTS = ("drafted", "accepted", "emitted", "steps")
 
 
 def count_window(vector: jnp.ndarray, lengths: jnp.ndarray,
-                 kv_valid: jnp.ndarray, ring: int) -> jnp.ndarray:
-    """`vector` (an `expert_pairs` ending in WINDOW_COUNTS) plus one decode
-    forward's counts. A parked lane (length 0) is no live row."""
+                 kv_valid: jnp.ndarray, ring: int, config) -> jnp.ndarray:
+    """`vector` (an `expert_pairs` with WINDOW_COUNTS in its tail) plus one
+    decode forward's counts — of one position a slot, or of a verify's
+    last one (`kv_valid`: the rows with it). A parked lane (length 0) is
+    no live row."""
     live = lengths > 0
 
     def total(x):
@@ -1146,8 +1230,7 @@ def count_window(vector: jnp.ndarray, lengths: jnp.ndarray,
         jnp.int32(1), total(kv_valid), total(jnp.minimum(kv_valid, ring)),
         total(((kv_valid > ring) & ((kv_valid - 1) % ring == 0))
               .astype(jnp.int32))])
-    n = len(WINDOW_COUNTS)
-    return jnp.concatenate([vector[:-n], vector[-n:] + new])
+    return tail_add(vector, config, WINDOW_COUNTS, new)
 
 
 def write_latent(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
@@ -1321,8 +1404,8 @@ def cache_logical_axes(*, quantized: bool = False,
 
 
 def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
-                    batch: int, kv_bytes: int, kind: str | None = None
-                    ) -> dict:
+                    batch: int, kv_bytes: int, kind: str | None = None,
+                    ring: int | None = None) -> dict:
     """Which attention implementation the prefill-from-empty and the
     single-position decode programs take at this cache shape (`batch`
     slots of `capacity` entries of `kv_bytes`): "pallas" (ops/flash.py,
@@ -1391,7 +1474,7 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
         return _latent_paths(latent, capacity, kernel, kv_bytes)
     if getattr(config, "window_kind", None) is not None and kind is None:
         return _window_paths(config, capacity, tp_mesh, batch=batch,
-                             kv_bytes=kv_bytes)
+                             kv_bytes=kv_bytes, ring=ring)
     model = 1 if tp_mesh is None else dict(tp_mesh.shape).get("model", 1)
     if config.num_kv_heads % model:
         return {"prefill": "xla", "decode": "xla"}
@@ -1448,13 +1531,16 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
 
 
 def _window_paths(config: "HybridConfig", capacity: int, tp_mesh, *,
-                  batch: int, kv_bytes: int) -> dict:
+                  batch: int, kv_bytes: int, ring: int | None = None
+                  ) -> dict:
     """`attention_paths` of a window / full model asked without a kind: the
     route of each kind's leaf beside the other's (`startup.attention`). The
-    served ring is exactly the window."""
+    served ring is exactly the window — or `ring` rows where it carries a
+    draft beside it (models/residents.py ring_rows)."""
     from symmetry_tpu.ops import flash
 
-    c, ring = config, config.sliding_window
+    c, span = config, config.sliding_window
+    ring = span if ring is None else ring
 
     def of(kind, rows, name):
         paths = attention_paths(c, rows, tp_mesh, batch=batch,
@@ -1472,15 +1558,15 @@ def _window_paths(config: "HybridConfig", capacity: int, tp_mesh, *,
     out = {"kind": "window+full", "prefill": prefill,
            "decode": routes.pop() if len(routes) == 1 else "mixed",
            "full": {**full, "capacity": capacity},
-           "window": {**window, "span": ring, "ring": ring}}
+           "window": {**window, "span": span, "ring": ring}}
     if prefill != "xla":
         out["prefill_form"] = (
             f"{flash.WIDE_NAME}: tiles of {flash.WIDE_TILE}, {c.num_heads} "
             f"query heads over {c.num_kv_heads} KV heads; a window layer "
             f"walks the tiles its window leaves"
-            if flash.wide_takes(ring) else
+            if flash.wide_takes(span) else
             f"{flash.WIDE_NAME} on the full layers; flash_prefill in tiles "
-            f"of 128 on the window layers (a window of {ring} is no "
+            f"of 128 on the window layers (a window of {span} is no "
             f"multiple of {flash.WIDE_TILE})")
     return out
 
@@ -1738,6 +1824,26 @@ def _attention(
         attn = (da.decode_attention(*args, **kw) if tp_mesh is None
                 else da.decode_attention_tp(*args, mesh=tp_mesh, **kw))
         attn = attn[:, None] if S == 1 else attn
+    elif (windowed and not ring and not prefill_flash and S == 2
+          and paths["decode"] != "xla" and tp_mesh is None):
+        # A verify of ONE drafted position over a full leaf of a window /
+        # full model (no such model could be verified before the rings
+        # carried a draft): the decode kernel once a position, each over
+        # the rows up to its own — twice the live rows, where
+        # gqa_attention would read the leaf's whole capacity and
+        # dequantise it beside itself. (A position past `seq_lens` reads
+        # a row of garbage more for an output nobody takes.)
+        from symmetry_tpu.ops import decode_attention as da
+
+        scales = ((cache.k_scale, cache.v_scale) if cache.quantized
+                  else (None, None))
+        T = cache.k.shape[2]
+        attn = jnp.stack([
+            da.decode_attention(
+                q[:, i], cache.k, cache.v, layer,
+                jnp.minimum(positions[:, i] + 1, T), *scales,
+                interpret=interpret_mode(), name=da.WINDOW_NAME)
+            for i in range(S)], axis=1)
     else:
         attn = gqa_attention(
             q, heads_at_layer(cache.k), heads_at_layer(cache.v),
@@ -1900,21 +2006,29 @@ def _layer(
 
         y, pairs = moe_mlp(x, lp, config, seq_lens, tp_mesh, stack)
         h = h + y
-        cache = add_expert_pairs(cache, pairs)
+        cache = add_expert_pairs(cache, pairs, config)
     else:
         h = h + qmatmul(_act(qmatmul(x, lp["wg"]), config)
                         * qmatmul(x, lp["wu"]), lp["wd"])
     return h, cache
 
 
-def add_expert_pairs(cache: KVCache, pairs: jnp.ndarray) -> KVCache:
+def add_expert_pairs(cache: KVCache, pairs: jnp.ndarray,
+                     config) -> KVCache:
     """`cache` with one layer's valid (token, expert) pairs added to its
-    counter, where it carries one (a sparse- or latent-attention model's
-    vector ends in counters of its own, which `pairs` leaves alone)."""
+    counter, where it carries one: a count an expert routed over, and —
+    from a model that holds a share (models/moe.py _count_pairs) — the
+    held experts hit behind them, which goes to HELD_COUNTS' word in the
+    tail (the other rows' words are left alone)."""
     if cache.expert_pairs is None:
         return cache
-    extra = cache.expert_pairs.shape[0] - pairs.shape[0]
-    return cache._replace(expert_pairs=cache.expert_pairs + (
+    vector, n = cache.expert_pairs, config.num_experts
+    extra = vector.shape[0] - pairs.shape[0]
+    if pairs.shape[0] > n and extra:
+        # the hits' word does not end the vector: each part to its place
+        return cache._replace(expert_pairs=tail_add(
+            vector.at[:n].add(pairs[:n]), config, HELD_COUNTS, pairs[n:]))
+    return cache._replace(expert_pairs=vector + (
         jnp.pad(pairs, (0, extra)) if extra else pairs))
 
 
@@ -2517,6 +2631,127 @@ def _window_config_from_hf(hf: dict[str, Any]) -> "HybridConfig":
     )
 
 
+def hf_config_exaone(config: "HybridConfig") -> dict:
+    """A K-EXAONE config as its published `config.json` keys (HF
+    `exaone_moe`: what `_exaone_config_from_hf` reads back, and what the
+    plain reference — benchmarks/reference/exaone_moe_decoder.py — is
+    given). Where a share of the experts is held, `num_experts` is the
+    HELD count — the benchmark configuration file's convention — beside
+    `experts_routed_over` and `experts_held`, keys of ours that a published
+    config.json lacks."""
+    c = config
+    window = [c.sliding_window if t == "sliding_attention" else 0
+              for t in c.layer_types]
+    out = {
+        "model_type": "exaone_moe", "vocab_size": c.vocab_size,
+        "hidden_size": c.hidden_size, "num_hidden_layers": c.num_layers,
+        "num_attention_heads": c.num_heads,
+        "num_key_value_heads": c.num_kv_heads, "head_dim": c.dim_per_head,
+        "hidden_act": c.hidden_act,
+        "intermediate_size": c.dense_intermediate_size,
+        "moe_intermediate_size": c.intermediate_size,
+        "first_k_dense_replace": c.num_dense_layers,
+        "mlp_layer_types": ["dense" if c.ffn_kind(i) == "dense" else "sparse"
+                            for i in range(c.num_layers)],
+        "layer_types": list(c.layer_types),
+        "sliding_window": c.sliding_window, "sliding_windows": window,
+        "num_experts": c.experts_here,
+        "num_experts_per_tok": c.num_experts_per_tok,
+        "num_shared_experts": 1, "n_group": 1, "topk_group": 1,
+        "norm_topk_prob": True, "scoring_func": c.router_score,
+        "routed_scaling_factor": c.routed_scaling_factor,
+        "num_nextn_predict_layers": c.mtp_layers,
+        "mtp_layer_types": ["full_attention"] * c.mtp_layers,
+        "mtp_sliding_windows": [0] * c.mtp_layers,
+        "rope_parameters": {"rope_theta": c.rope_theta,
+                            "rope_type": "default"},
+        "rms_norm_eps": c.rms_eps,
+        "max_position_embeddings": c.max_position,
+        "tie_word_embeddings": c.tie_embeddings,
+    }
+    if c.experts_held is not None:
+        out["experts_routed_over"] = c.num_experts
+        out["experts_held"] = list(c.experts_held)
+    return out
+
+
+def _exaone_config_from_hf(hf: dict[str, Any]) -> "HybridConfig":
+    """HF `exaone_moe` (K-EXAONE-236B-A23B): window (rotary) and full
+    (NoPE) attention layers by `layer_types`, q and k normed per head,
+    `first_k_dense_replace` leading dense layers, then experts chosen by
+    deepseek_v3's sigmoid router at one group plus one shared expert of
+    the expert's width, and `num_nextn_predict_layers` multi-token-
+    prediction modules. What would change the layer and is not built
+    raises, in a sentence. `experts_routed_over` / `experts_held` as
+    nemotron_h's (a benchmark configuration's share of a deployment)."""
+    n_layers = hf["num_hidden_layers"]
+    types = tuple(hf["layer_types"])
+    ffns = list(hf.get("mlp_layer_types")
+                or ["dense"] * hf.get("first_k_dense_replace", 0)
+                + ["sparse"] * (n_layers - hf.get("first_k_dense_replace",
+                                                  0)))
+    n_dense = ffns.count("dense")
+    if ffns != ["dense"] * n_dense + ["sparse"] * (n_layers - n_dense):
+        raise ValueError("exaone_moe with dense layers that do not lead "
+                         f"is not implemented; got {ffns!r}")
+    for key, want in (("scoring_func", "sigmoid"), ("hidden_act", "silu"),
+                      ("n_group", 1), ("topk_group", 1),
+                      ("num_shared_experts", 1), ("norm_topk_prob", True)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"exaone_moe with {key} {hf[key]!r} is not "
+                             f"implemented")
+    rope = hf.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default" or hf.get(
+            "rope_scaling"):
+        raise ValueError("exaone_moe with a scaled rotary is not "
+                         "implemented")
+    mtp = hf.get("num_nextn_predict_layers", 0)
+    if list(hf.get("mtp_layer_types") or ["full_attention"] * mtp) != [
+            "full_attention"] * mtp:
+        raise ValueError("exaone_moe with a multi-token-prediction module "
+                         "that is no full-attention block is not "
+                         "implemented")
+    window = hf.get("sliding_window")
+    windows = hf.get("sliding_windows")
+    if windows is not None and list(windows) != [
+            window if t == "sliding_attention" else 0 for t in types]:
+        raise ValueError("exaone_moe with windows of several sizes is not "
+                         "implemented")
+    held = hf.get("experts_held")
+    routed_over = hf.get("experts_routed_over", hf["num_experts"])
+    if held is not None and held[1] != hf["num_experts"]:
+        raise ValueError(f"experts_held {held!r} and num_experts "
+                         f"{hf['num_experts']} (the count held) differ")
+    return HybridConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_layers=n_layers, num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        # the routed (and the shared) expert's width
+        intermediate_size=hf["moe_intermediate_size"],
+        head_dim=hf.get("head_dim"),
+        rope_theta=float(rope.get("rope_theta",
+                                  hf.get("rope_theta", 10000.0))),
+        rms_eps=hf.get("rms_norm_eps", 1e-5),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        max_position=hf.get("max_position_embeddings", 8192),
+        sliding_window=window if "sliding_attention" in types else None,
+        qk_norm=True,
+        num_experts=routed_over,
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        experts_held=None if held is None else tuple(held),
+        shared_intermediate_size=hf["moe_intermediate_size"],
+        num_dense_layers=n_dense,
+        dense_intermediate_size=hf["intermediate_size"] if n_dense else 0,
+        layer_types=types,
+        # rotary on the window layers alone (the EXAONE-4 family's hybrid
+        # attention): a full layer has no positional embedding
+        rope_layout=tuple(int(t == "sliding_attention") for t in types),
+        router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        router_norm_eps=1e-20, mtp_layers=mtp,
+    )
+
+
 def hf_config_nemotron_h(config: "HybridConfig") -> dict:
     """The config as nemotron_h's published `config.json` keys (what
     `_nemotron_h_config_from_hf` reads back, and what the plain reference,
@@ -2652,6 +2887,8 @@ def config_from_hf(hf: dict[str, Any]) -> ModelConfig:
         sliding = None
     if hf.get("model_type") == "deepseek_v3":
         return _latent_config_from_hf(hf)
+    if hf.get("model_type") == "exaone_moe":
+        return _exaone_config_from_hf(hf)
     if (hf.get("model_type") == "smallthinker"
             or str(hf.get("model_name", "")).startswith("smallthinker")):
         return _window_config_from_hf(hf)

@@ -221,9 +221,16 @@ ROUTED_MIN_TOKENS = 1024
 # read 0.52), and the kernel reads the stack where it lies. So no band: the
 # share is routed at every size (as (512, 10) is), and the mixture's slice
 # copy is the cell's first `perf_opt` question, not this entry's.
+# 16 held of 128, top 8, at expert width 2,048, three matrices
+# (k-exaone-236b-a23b; PR 65): NOT measured by the tool — the share takes
+# nemotron's finding as it stands (in the trunk the mixture copies each
+# layer's [16, 6144, 2048] slices out of the stack before its mixed dots,
+# 0.6 GB a layer, and computes 16x the FLOPs of the 8 pairs a held expert
+# sees a step; the kernel reads the stack where it lies), so no band: routed
+# at every size. An A/B at this shape is an open question of PERF.md.
 ROUTED_FROM = {(72, 10): (0, 256), (512, 10): (0, 1), (128, 8): (64, 128),
                (32, 4): (0, 256), (128, 6): (0, 128), (64, 6): (32, 256),
-               (32, 6, 128): (0, 1)}
+               (32, 6, 128): (0, 1), (16, 8, 128): (0, 1)}
 
 
 def moe_route(n_tokens: int, experts: int = 8, k: int = 2,

@@ -20,7 +20,7 @@ from __future__ import annotations
 from typing import Any, Callable, NamedTuple
 
 from symmetry_tpu.models.llama import (
-    HELD_COUNTS, LATENT_COUNTS, WINDOW_COUNTS)
+    HELD_COUNTS, LATENT_COUNTS, MTP_COUNTS, WINDOW_COUNTS)
 
 
 class Resident(NamedTuple):
@@ -155,7 +155,10 @@ RESIDENTS = (
         # prompt tokens prefilled through the expanded form, as dispatched
         host_counts=("prefill_tokens",)),
     # a ring of the window's rows a window layer beside a full row a full
-    # layer (`KVCache.kw` / `vw`)
+    # layer (`KVCache.kw` / `vw`). A draft is carried: under
+    # `tpu.speculative` the ring has the window's rows AND the drafted
+    # positions' (`ring_rows`), every row masked by the position it holds,
+    # so a rejected position overwrote no key a later query needs
     Resident(
         name="window ring",
         of=lambda c: getattr(c, "window_kind", None),
@@ -166,14 +169,10 @@ RESIDENTS = (
                 "a window layer's ring holds a slot's last positions alone, "
                 "so a stored prefix would come back without the rows its "
                 "window layers need"),
-            "speculative": (
-                "a ring row that a rejected draft overwrote held a key "
-                "still inside the window, and rolling the lengths back does "
-                "not bring it back"),
             "prefill_chunk": (
                 "a chunk's later positions overwrite ring rows its earlier "
-                "queries still need (a ring of the window's rows has no "
-                "room for a chunk beside it)"),
+                "queries still need (a ring of the window's rows and a "
+                "draft's has no room for a chunk beside it)"),
             "role": (
                 "the KV handoff frame carries one K and one V plane at one "
                 "capacity and has no place for the rings"),
@@ -216,7 +215,50 @@ RESIDENTS = (
         phrase=None, reasons={},
         block="moe",
         words=HELD_COUNTS),
+    # a multi-token-prediction module behind the trunk (`mtp_layers`;
+    # models/hybrid.py mtp_forward): its block's rows are one more layer of
+    # the `k` / `v` leaves, written by the prefill and by every decode
+    # step and rolled back with the trunk's by the one length — the
+    # engine's on-device drafter (`tpu.speculative: mtp`)
+    Resident(
+        name="drafting module",
+        of=lambda c: getattr(c, "mtp_layers", 0) or None,
+        phrase="a model with a multi-token-prediction module",
+        reasons={
+            "prefix_cache": (
+                "a stored prefix would come back without the first draft, "
+                "which the module makes from the prompt's last hidden state"),
+            "prefill_chunk": (
+                "the chunk programs run the trunk alone and would leave "
+                "the module's rows of a chunked prompt unwritten"),
+            "role": (
+                "the handoff carries one first token and no draft to open "
+                "the first decode step with"),
+            "mesh": "the module's parameters have no sharding rules yet",
+        },
+        block="mtp",
+        # decode steps' drafts scored, drafts accepted, tokens yielded and
+        # (slot, step) pairs run, live lanes alone
+        words=MTP_COUNTS,
+        # prompt tokens the module ran over, as dispatched
+        host_counts=("prefill_tokens",)),
 )
+
+# the value of `tpu.speculative` that selects the model's own module
+MTP = "mtp"
+
+
+def ring_rows(config, k_draft: int = 0) -> int | None:
+    """The rows of a window layer's served ring: the window's, or — where
+    drafts of up to `k_draft` positions are verified — the window's and the
+    drafts' in whole lane tiles, so that no position a forward writes
+    lands on a key its own earlier queries need (`_attention` masks each
+    row by the position it holds wherever the ring is not exactly the
+    window). None for a model without window layers."""
+    window = getattr(config, "window_kind", None) and config.sliding_window
+    if not window or not k_draft:
+        return window or None
+    return -(-(window + k_draft) // 128) * 128
 
 
 def kept(config) -> tuple[Resident, ...]:
@@ -226,7 +268,7 @@ def kept(config) -> tuple[Resident, ...]:
 
 
 def refusals(config, *, mesh: bool = False, role: str = "unified",
-             prefix_cache: bool = False, speculative: bool = False,
+             prefix_cache: bool = False, speculative: bool | str = False,
              prefill_chunk: int | None = None,
              kv_quant: bool = False) -> list[str]:
     """Why `config`'s model cannot be served under these settings: for each
@@ -235,29 +277,43 @@ def refusals(config, *, mesh: bool = False, role: str = "unified",
     The engine raises the first as an EngineError; provider/config.py asks
     the same of a preset before anything is built and raises them all as a
     ConfigError."""
+    rows = kept(config)
+    no_module = []
+    if speculative == MTP and not any(r.block == MTP for r in rows):
+        no_module = [
+            f"tpu.speculative {MTP}: that drafter is a multi-token-"
+            f"prediction module among the model's own parameters "
+            f"(num_nextn_predict_layers), and this model has none — leave "
+            f"it unset, or name the n-gram drafter (true, a number of "
+            f"draft tokens or its mapping)"]
     on = {"prefix_cache": prefix_cache or None,
-          "speculative": speculative or None,
+          "speculative": bool(speculative) or None,
           "prefill_chunk": prefill_chunk,
           "role": None if role == "unified" else role,
           "mesh": mesh or None,
           "kv_quant": kv_quant or None}
-    return [f"{head.format(value=on[setting])}: {row.reasons[setting]} — "
-            f"{advice.format(phrase=row.phrase)}"
-            for row in kept(config)
-            for setting, (head, advice) in SETTINGS.items()
-            if on[setting] is not None and setting in row.reasons]
+    return no_module + [
+        f"{head.format(value=on[setting])}: {row.reasons[setting]} — "
+        f"{advice.format(phrase=row.phrase)}"
+        for row in rows
+        for setting, (head, advice) in SETTINGS.items()
+        if on[setting] is not None and setting in row.reasons]
 
 
 def tail_words(config) -> tuple[str, ...]:
     """What `config`'s `KVCache.expert_pairs` ends in, behind the
-    `num_experts` pair counts: the words of the one row that has any. Each
-    device function adds to "the last n words", so two such rows in one
-    model have no layout and are refused."""
-    rows = [row for row in kept(config) if row.words]
-    if len(rows) > 1:
-        raise ValueError(
-            "expert_pairs has one tail, and this model has "
-            + " and ".join(f"{row.name} ({', '.join(row.words)})"
-                           for row in rows)
-            + ": each is written as the vector's last words")
-    return rows[0].words if rows else ()
+    `num_experts` pair counts: the words of every row that has any, in the
+    table's order. A device function adds to ITS row's words (`tail_at`),
+    wherever in the tail they lie."""
+    return tuple(word for row in kept(config) for word in row.words)
+
+
+def tail_at(config, words: tuple[str, ...]) -> tuple[int, int]:
+    """Where the row whose words are `words` lies in `config`'s
+    `expert_pairs`: (start, stop) counted from the vector's START."""
+    at = getattr(config, "num_experts", 0)
+    for row in kept(config):
+        if row.words == tuple(words):
+            return at, at + len(words)
+        at += len(row.words)
+    raise ValueError(f"this model has no row that counts {words!r}")

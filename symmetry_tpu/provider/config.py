@@ -109,9 +109,11 @@ class TpuConfig:
     # the decode path and warmup compile set are then byte-identical to a
     # build without the feature. True enables defaults; an int sets
     # k_draft (draft tokens per slot per verify dispatch); a mapping may
-    # set {k_draft, ngram_max, ngram_min, max_index_tokens}. Helps
-    # workloads whose output
-    # repeats spans of their own context (code edits, RAG quoting,
+    # set {k_draft, ngram_max, ngram_min, max_index_tokens}; "mtp" hands
+    # the drafting to the model's own multi-token-prediction module, on
+    # the device inside the decode block (refused for a model without
+    # one). The n-gram drafter helps workloads whose output repeats
+    # spans of their own context (code edits, RAG quoting,
     # extractive answers); hurts incompressible chat — watch the
     # acceptance_rate counter in stats. Greedy output is token-identical
     # with the knob on or off; sampled lanes stay unbiased via rejection
@@ -362,7 +364,8 @@ class ConfigManager:
             preset, mesh=math.prod((tpu.mesh or {}).values()) > 1,
             role=tpu.role or "unified",
             prefix_cache=bool(tpu.prefix_cache_mb),
-            speculative=bool(tpu.speculative),
+            # (the value itself: "mtp" asks for the model's own module)
+            speculative=tpu.speculative or False,
             prefill_chunk=tpu.prefill_chunk,
             kv_quant=tpu.kv_quantization == "int8")
         diffusion = getattr(preset, "diffusion", None)

@@ -1308,3 +1308,72 @@ def test_nemotron_programs_keep_the_kv_leaf_head_major(
     if program == "insert_all":
         # ... and the scratch it places lies as the prefill left it
         assert layouts(rows, bucket) == {"4,2,3,1,0"}
+
+
+def test_k_exaone_drafting_step_verifies_through_the_kernels_in_place(
+        one_chip, no_cache, monkeypatch):
+    """k-exaone-236b-a23b's drafting step at its cell (64 slots: four full
+    leaves of 5,376 rows — the trunk's three and the module's — and rings
+    of 256): the trunk over [pending, draft] then the module over the same
+    two positions, compiled for a described v5e. A full layer's attention
+    is the decode kernel ONCE A POSITION (two `swa_decode` calls in each of
+    the three full scans, two in the module), the rings go through
+    `gqa_attention` (no kernel: 256 rows are no ring of the window's 128);
+    the held share is ROUTED — two `moe_gmm` calls in each of the six
+    sparse scans and two in the module, over the 16 held experts' stacks as
+    they lie; no op copies or slices a full cache leaf; and the program fits
+    the chip beside the 13.2 GB it is handed."""
+    from symmetry_tpu.models import hybrid, llama, moe, residents
+    from symmetry_tpu.ops import decode_attention as da, gmm
+
+    for module in (llama, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    cfg = llama.preset("k-exaone-236b-a23b")
+    B, T = 64, 5376
+    ring = residents.ring_rows(cfg, 1)
+
+    def shaped(fn):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True))
+    cache = shaped(lambda: llama.init_cache(
+        cfg, B, T, jnp.bfloat16, quantized=True, count_experts=True,
+        ring=ring))
+    assert cache.k.shape == (4, B, T, 8, 128)
+    assert cache.kw.shape == (9, B, 256, 8, 128)
+    assert moe.moe_route(2 * B, 128, 8, 16) == "routed"
+    tok = jax.ShapeDtypeStruct((B, 2), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+
+    def step(p, t, c, n):
+        h, after = llama.forward_hidden(p, cfg, t, c, seq_lens=n)
+        hm, after = hybrid.mtp_forward(
+            p, cfg, h, t, after._replace(lengths=c.lengths), seq_lens=n)
+        return llama.logits_from_hidden(p, cfg, h), hm, after
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        params, tok, cache, lens).compile()
+    text = compiled.as_text()
+    assert len(re.findall(rf"%{da.WINDOW_NAME}[.\d]* = ", text)) == 8
+    assert len(re.findall(rf"%{gmm.NAME}[.\d]* = ", text)) == 14
+    # (a FULL leaf: a ring's layer, 16.8 MB of K and of V, is what
+    # `gqa_attention` slices out and relays a window layer — the price of
+    # masking by position until a kernel takes a start offset)
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= s8\[(\d+,)?{B},{T},8,128\]\S* "
+                          rf"(copy|transpose|dynamic-slice|fusion)\(", line)
+             and "dynamic-update-slice" not in line
+             and "scatter" not in line]
+    assert not moved, moved[0]
+    sliced = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= s8\[(1,)?16,(6144,2048|2048,6144)\]\S* "
+                           r"(copy|dynamic-slice|fusion)\(", line)]
+    assert not sliced, sliced[0]
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 600 << 20
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.0e9)

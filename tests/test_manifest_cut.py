@@ -41,6 +41,7 @@ def test_there_is_a_cut_configuration_to_hold():
     assert "kanana-2-30b-a3b.json" in cut_files()
     assert "smallthinker-21b-a3b.json" in cut_files()
     assert "nemotron-3-nano-30b-a3b.json" in cut_files()
+    assert "k-exaone-236b-a23b.json" in cut_files()
 
 
 @pytest.mark.parametrize("name", cut_files())
@@ -50,7 +51,9 @@ def test_reduced_lists_exactly_the_keys_that_differ_from_published(name):
     assert sorted(published) == sorted(c["reduced"])
     for key in c["reduced"]:
         assert c[key] != published[key], key
-        assert not key.endswith(WIDTH_ENDS), key
+        # (`vocab_size` counts rows, as `num_experts` counts experts: a
+        # chip's slice of the vocabulary is a share, not a width)
+        assert key == "vocab_size" or not key.endswith(WIDTH_ENDS), key
     manifest = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
     entry = next(e for e in manifest["configs"]
                  if e["file"] == f"benchmarks/configs/{name}")
@@ -148,7 +151,9 @@ def test_a_cut_file_is_the_programs_preset(name):
         c["num_attention_heads"], c["num_key_value_heads"],
         width, c["head_dim"])
     # (the eps key is the family's own: nemotron_h's is `norm_eps`)
-    assert p.rope_theta == c["rope_theta"]
+    # (exaone_moe nests its rotary keys: `rope_parameters`)
+    assert p.rope_theta == c.get(
+        "rope_theta", (c.get("rope_parameters") or {}).get("rope_theta"))
     assert p.rms_eps == c.get("rms_norm_eps", c.get("norm_eps"))
     assert p.tie_embeddings == c["tie_word_embeddings"]
     if c.get("model_type") == "nemotron_h":
@@ -205,6 +210,64 @@ def test_a_cut_file_is_the_programs_preset(name):
             assert word in text, word
         for word in ("four-chip", "32 a chip", "replicated", "9.924 GB",
                      "3.141 GB", "78.0%", "quarter of the rows"):
+            assert word in c["deployment"], word
+    if c.get("model_type") == "exaone_moe":
+        from symmetry_tpu.models.llama import config_from_hf
+
+        # every published key the program reads, through its own reader:
+        # every width as published; the cuts are the depth (three whole
+        # periods, the leading dense layer among them), this chip's share
+        # of the experts and its slice of the vocabulary
+        assert config_from_hf(c) == p
+        assert c["reduced"] == [
+            "num_hidden_layers", "layer_types", "mlp_layer_types",
+            "sliding_windows", "num_experts", "vocab_size"]
+        assert (c["published"]["num_hidden_layers"],
+                c["published"]["num_experts"],
+                c["published"]["vocab_size"]) == (48, 128, 153600)
+        assert c["layer_types"] == (["sliding_attention"] * 3
+                                    + ["full_attention"]) * 3
+        assert c["mlp_layer_types"] == ["dense"] + ["sparse"] * 11
+        assert c["sliding_windows"] == [128, 128, 128, 0] * 3
+        assert (p.num_experts, p.experts_held, p.num_experts_per_tok) == (
+            c["experts_routed_over"], tuple(c["experts_held"]),
+            c["num_experts_per_tok"]) == (128, (0, 16), 8)
+        assert c["num_experts"] == c["experts_held"][1] == 16 >= 8
+        assert c["published"]["vocab_size"] == 8 * c["vocab_size"]
+        assert (p.intermediate_size, p.shared_intermediate_size,
+                p.dense_intermediate_size, p.num_dense_layers) == (
+            c["moe_intermediate_size"], c["moe_intermediate_size"],
+            c["intermediate_size"], c["first_k_dense_replace"]) == (
+            2048, 2048, 18432, 1)
+        assert (p.sliding_window, p.qk_norm, p.mtp_layers) == (
+            c["sliding_window"], True, c["num_nextn_predict_layers"]) == (
+            128, True, 1)
+        assert p.rope_layout == (1, 1, 1, 0) * 3
+        assert (p.router_score, p.router_bias, p.routed_scaling_factor,
+                p.router_norm_eps) == ("sigmoid", True, 2.5, 1e-20)
+        assert p.max_position == c["max_position_embeddings"] == 262144
+        tpu = c["tpu"]
+        assert (tpu["max_batch_size"], tpu["max_seq_len"],
+                tpu["decode_block"], tpu["speculative"]) == (
+            64, 5376, 16, "mtp")
+        assert tpu["prefill_buckets"] == [128, 384, 512, 768, 1152]
+        assert tpu["prefill_chunk"] is None
+        assert (tpu["quantization"], tpu["kv_quantization"],
+                tpu["dtype"]) == ("int8", "int8", "bfloat16")
+        assert c["reference"].endswith("exaone_moe_decoder.py")
+        assert os.path.exists(os.path.join(CHECKOUT, c["reference"]))
+        # every `assumed` item the issue lists is stated
+        text = " ".join(c["assumed"])
+        for word in ("pre-norm", "RMS-normed per head", "WINDOW layers alone",
+                     "e_score_correction_bias", "NOT renormalised",
+                     "hidden state FIRST", "AFTER its final norm",
+                     "sparse layer like layer 47", "about zero", "5376",
+                     "int8", "byte tokenizer", "experts_held", "fan_in",
+                     "256 rows"):
+            assert word in text, word
+        for word in ("eight v5e chips", "16 a chip", "replicated",
+                     "9.971 GB", "2.907 GB", "78.0%",
+                     "an eighth of the rows", "1/13", "1/49"):
             assert word in c["deployment"], word
     if c.get("model_type") == "qwen3_next":
         from symmetry_tpu.models.llama import config_from_hf
@@ -385,6 +448,7 @@ def test_a_cut_file_is_the_programs_preset(name):
         assert "bfloat16" in c["deployment"] and "W_UK" in c["deployment"]
     if "layer_types" in c:
         assert list(p.layer_types) == c["layer_types"]
+    if "layer_types" in c and "num_local_experts" in c:
         assert (p.num_experts, p.num_experts_per_tok,
                 p.shared_intermediate_size) == (
             c["num_local_experts"], c["num_experts_per_tok"],

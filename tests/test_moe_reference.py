@@ -255,9 +255,11 @@ def test_a_band_answers_as_its_threshold_did(shape):
     every count the old threshold decided — under a lower edge only (128, 8)
     has one — the answer is the one it gave."""
     # (an entry newer than the bands had no threshold to answer as; a
-    # three-part key is a SHARE's: held, k, routed over — PR 61)
+    # three-part key is a SHARE's: held, k, routed over — PR 61, PR 65)
     assert set(moe.ROUTED_FROM) == set(THRESHOLD_WAS) - {None} | {
-        (64, 6), (32, 6, 128)}
+        (64, 6), (32, 6, 128), (16, 8, 128)}
+    assert moe.ROUTED_FROM[(16, 8, 128)] == (0, 1)      # routed at any size
+    assert moe.moe_route(128, 128, 8, held=16) == "routed"
     lo, hi = moe.ROUTED_FROM.get(shape, (0, moe.ROUTED_MIN_TOKENS))
     assert hi == THRESHOLD_WAS[shape] and 0 <= lo < hi
     assert (lo > 0) == (shape == (128, 8))

@@ -49,7 +49,11 @@ identical — every `insert_all` and all three programs of the ten other
 presets — nemotron-3-nano-30b-a3b's and qwen3-next-80b-a3b's `decode_block`
 and `prefill` the four that differ. PR 63, what a slot keeps as one table
 (models/residents.py sizes `expert_pairs`) and the sequence-parallel
-keywords out of the trunk: all 34 identical.)
+keywords out of the trunk: all 34 identical. PR 65, a drafting module
+behind a window / full trunk, rings that carry a draft and a tail of several
+rows: the 34 older files against the parent are in CHANGES.md's line; three
+new ones, k-exaone-236b-a23b's, whose `decode_block` is the drafting block
+at 64 slots and whose `prefill` is two rows of 512.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
@@ -78,8 +82,12 @@ PREFILL = (8, 256)
 PREFILL_WIDE = (16, 64)     # a diffusion preset's second admission shape
 # a preset whose cell is not 128 slots, and whose widest admission (a
 # prefill buffer carries a slot's whole recurrent state a row) is not 8 rows
-SLOTS_OF = {"nemotron-3-nano-30b-a3b": 64}
-PREFILL_OF = {"nemotron-3-nano-30b-a3b": (2, 256)}
+SLOTS_OF = {"nemotron-3-nano-30b-a3b": 64, "k-exaone-236b-a23b": 64}
+PREFILL_OF = {"nemotron-3-nano-30b-a3b": (2, 256),
+              "k-exaone-236b-a23b": (2, 512)}
+# a preset served with its own multi-token-prediction module drafting
+# (`tpu.speculative: mtp`): `decode_block` is then the drafting block
+DRAFTING = {"k-exaone-236b-a23b"}
 
 
 def shapes(fn):
@@ -87,13 +95,19 @@ def shapes(fn):
                         jax.eval_shape(fn))
 
 
-def bare_engine(cfg, slots: int = SLOTS):
+def bare_engine(cfg, slots: int = SLOTS, drafting: bool = False):
     """An engine whose jits exist and whose arrays do not."""
+    from symmetry_tpu.engine.spec import SpecConfig
+    from symmetry_tpu.models import residents
+
     e = object.__new__(eng_mod.InferenceEngine)
+    e._mtp, e.tap = drafting, None
+    e._ring = residents.ring_rows(cfg, int(drafting))
     # (a latent cache row has no int8 form: that preset's cache is bfloat16)
     e.config, e.mesh, e.decode_block = cfg, None, BLOCK
     e.kv_quant = getattr(cfg, "latent", None) is None
-    e.spec, e.prefix_block, e.cache_dtype = None, 16, jnp.bfloat16
+    e.spec = SpecConfig.from_knob("mtp") if drafting else None
+    e.prefix_block, e.cache_dtype = 16, jnp.bfloat16
     e.max_slots, e.max_seq_len = slots, CAPACITY
     e._state_shardings = e._cache_shardings = None
     e._count_experts = bool(getattr(cfg, "num_experts", 0))
@@ -110,14 +124,15 @@ def decode_state(e, cfg, slots: int):
         cache=llama.init_cache(
             cfg, slots, CAPACITY, jnp.bfloat16, quantized=e.kv_quant,
             count_experts=e._count_experts,
-            # (a window layer's ring: the window's rows)
-            **({"ring": cfg.sliding_window}
+            # (a window layer's ring: the window's rows, and a draft's)
+            **({"ring": e._ring}
                if getattr(cfg, "window_kind", None) else {})),
         last_token=jnp.zeros((slots,), jnp.int32),
         temperature=jnp.zeros((slots,), jnp.float32),
         top_p=jnp.ones((slots,), jnp.float32),
         top_k=jnp.zeros((slots,), jnp.int32),
-        rng=jax.random.split(jax.random.key(0), slots)))
+        rng=jax.random.split(jax.random.key(0), slots),
+        draft=jnp.zeros((slots,), jnp.int32) if e._mtp else None))
 
 
 def programs(e, params, state, prefill=PREFILL):
@@ -144,6 +159,8 @@ def programs(e, params, state, prefill=PREFILL):
     args = admission(*prefill)
     keys, scratch = args[-2:]
     first = jax.ShapeDtypeStruct((n, block), i32) if block else vec(n, i32)
+    if e._mtp:      # the first token and the first draft
+        first = jax.ShapeDtypeStruct((n, 2), i32)
     yield "decode_block", e._decode.lower(
         params, state, jax.ShapeDtypeStruct((e.max_slots,), bool))
     yield "prefill", e._prefill.lower(params, *args)
@@ -165,7 +182,8 @@ def main() -> int:
                              "keye-vl-2.0-30b-a3b", "lfm2-8b-a1b",
                              "sdar-30b-a3b-chat", "kanana-2-30b-a3b",
                              "smallthinker-21b-a3b",
-                             "nemotron-3-nano-30b-a3b"]
+                             "nemotron-3-nano-30b-a3b",
+                             "k-exaone-236b-a23b"]
     os.makedirs(out_dir, exist_ok=True)
     for name in names:
         cfg = llama.preset(name)
@@ -188,7 +206,7 @@ def main() -> int:
             params, state = e.params, e.state
         else:
             slots = SLOTS_OF.get(name, SLOTS)
-            e = bare_engine(cfg, slots)
+            e = bare_engine(cfg, slots, name in DRAFTING)
             params = shapes(lambda: llama.init_params(
                 cfg, jax.random.key(0), jnp.bfloat16, quantize=True))
             state = decode_state(e, cfg, slots)
