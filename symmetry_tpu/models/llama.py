@@ -1073,7 +1073,12 @@ def write_kv(cache: KVCache, layer: jnp.ndarray, positions: jnp.ndarray,
     """Scatter this call's K/V ([B, S, K, D], roped) straight into the full
     cache at (layer, slot, position) — an in-place row write on the layer
     scan's carry; a per-layer slice-out/slice-in would stream the whole
-    layer slice through HBM. `positions` is [B, S]; a position at or past
+    layer slice through HBM. Every forward of S > 1 positions writes here,
+    and every decode step but the homogeneous one-chip trunk's through the
+    kernel, which appends ONCE behind its layer scan (`append_step`,
+    `attention_paths`: `kv_append`) — four scatters a layer (K, V and an
+    int8 cache's two planes) cost a decode step ~16 us each whatever they
+    move. `positions` is [B, S]; a position at or past
     the capacity is dropped. Padded tail tokens write garbage past the
     slot's valid length — never read, overwritten later. A quantized cache
     takes the int8 payload plus the f32 scales (ops/quant.py quantize_kv).
@@ -1162,6 +1167,34 @@ def _put_scales(plane: jnp.ndarray, scales: jnp.ndarray, layer,
            == jnp.arange(T, dtype=positions.dtype)[None, None, None, :])
     new = jnp.where(hit, scales[None, :, 0, :, None].astype(plane.dtype), at)
     return jax.lax.dynamic_update_slice_in_dim(plane, new, layer, 0)
+
+
+def append_step(cache: KVCache, positions: jnp.ndarray,
+                rows: tuple) -> KVCache:
+    """A decode step's ONE cache write (`run_layers`): every layer's row of
+    slot b — `rows` as `_attention(own_row=True)` handed them out of the
+    layer scan, stacked: K and V [L, B, *kv_row] in the cache's dtype, and
+    of an int8 cache the scales [L, B, K] beside them — at position
+    `positions[b]`, all layers at once: a scatter a K/V leaf, its window a
+    slot's [L, 1, 1, *kv_row] rows, and one kernel call for the scale
+    planes (ops/scale_append.py: no scatter writes a plane's [L, 1, K, 1]
+    window where it lies), where `write_kv` a layer is four scatters a
+    LAYER — each ~16 us on a v5e whatever it moves, 128 updates of 1 KB or
+    of 8 floats alike: 1.7-1.8 ms of the dense cells' 22-24 ms step
+    (PERF.md, PR 66). The same bytes at the same places as `write_kv` called
+    a layer; a position at or past the capacity is dropped."""
+    k, v, k_scale, v_scale = rows
+    b = jnp.arange(positions.shape[0], dtype=jnp.int32)
+    cache = cache._replace(k=cache.k.at[:, b, positions].set(k),
+                           v=cache.v.at[:, b, positions].set(v))
+    if not cache.quantized:
+        return cache
+    from symmetry_tpu.ops.scale_append import append_scales
+
+    planes = append_scales((cache.k_scale, cache.v_scale),
+                           (k_scale, v_scale), positions,
+                           interpret=interpret_mode())
+    return cache._replace(k_scale=planes[0], v_scale=planes[1])
 
 
 # What a latent-attention model's `expert_pairs` ends in (as a sparse
@@ -1518,6 +1551,7 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
             f"window's {config.sliding_window}: its keys need a start "
             f"offset that ops/decode_attention.py's items lack, so the "
             f"step masks by each row's own position in gqa_attention")}
+    head_major = tp_mesh is None and kv_head_major(kv_row(config), kv_bytes)
     return {"prefill": kernel, "decode": kernel,
             **({"decode_queries": queries} if queries > 1 else {}),
             "decode_slot_tile": tiles[0], "decode_block_t": tiles[1],
@@ -1526,8 +1560,12 @@ def attention_paths(config: ModelConfig, capacity: int, tp_mesh=None, *,
             **({"kv_layout": "head-major [L, B, K, T, D]: head-indexed "
                              "writes (a decode step's scales by a select)"
                              ", a lane a (slot, head)"}
-               if tp_mesh is None and kv_head_major(kv_row(config), kv_bytes)
-               else {})}
+               if head_major else {}),
+            # (likewise: the homogeneous trunk's one-query programs alone)
+            **({"kv_append": "step"}
+               if tp_mesh is None and queries == 1 and not head_major
+               and getattr(config, "sparse", None) is None
+               and not getattr(config, "layer_types", None) else {})}
 
 
 def _window_paths(config: "HybridConfig", capacity: int, tp_mesh, *,
@@ -1641,9 +1679,20 @@ def _attention(
                                 # is `config.sliding_window`, model-wide)
     ring: bool = False,         # static: `cache` is a window layer's
                                 # `ring_view` and its leaf a ring
-) -> tuple[jnp.ndarray, KVCache]:
+    own_row: bool = False,      # static: the caller appends this position's
+                                # row itself (`run_layers`, where
+                                # `attention_paths` says `kv_append`)
+) -> tuple:
     """The attention mixer: projections, the cache write, attention by the
     routed path, the output projection -> ([B, S, E], cache).
+
+    With `own_row` (one position a slot through the decode kernel) NOTHING
+    is written: the position's K/V row, quantised as the cache will hold
+    it, is an operand of attention — the kernel walks the rows below it
+    and starts each slot's softmax from the row (ops/decode_attention.py
+    `own`) — and comes back BESIDE the cache, -> ([B, 1, E], cache, (k, v,
+    k_scale, v_scale) a slot) as `append_step` takes them stacked over the
+    layers: the cache is read, never written, here.
 
     A window layer over a RING (`ring`) of M rows: the write goes to
     position mod M, a decode step attends over rows [0, min(length, M)) —
@@ -1707,7 +1756,26 @@ def _attention(
     diffusion = getattr(config, "diffusion", None)
     # (a keyword only where there is a block: no other model's call moves)
     blocked = {} if diffusion is None else {"block_len": diffusion.block}
-    if ring:
+    paths = attention_paths(
+        config, cache.k.shape[2], tp_mesh, batch=cache.k.shape[1],
+        kv_bytes=cache.k.dtype.itemsize,
+        **({"kind": "window" if window else "full"} if windowed else {}))
+    if own_row:
+        if S != 1 or paths.get("kv_append") != "step" or ring:
+            raise ValueError("own_row: this program writes its rows a "
+                             "layer (attention_paths has no kv_append)")
+        # the position's row as the leaves will hold it (`kv_row`: heads of
+        # 64 in pairs), and an int8 cache's scales a head
+        fold = (B, *cache.k.shape[3:])
+        if cache.quantized:
+            (own_k, k_scale), (own_v, v_scale) = (quantize_kv(k[:, 0]),
+                                                  quantize_kv(v[:, 0]))
+            own = (own_k.reshape(fold), own_v.reshape(fold), k_scale,
+                   v_scale)
+        else:
+            own = (k[:, 0].astype(cache.k.dtype).reshape(fold),
+                   v[:, 0].astype(cache.v.dtype).reshape(fold), None, None)
+    elif ring:
         cache = write_kv(cache, layer, positions, k, v, by_head=False,
                          ring_valid=kv_valid)
     elif sparse is None:
@@ -1732,11 +1800,6 @@ def _attention(
         wi = qmatmul(x, lp["wwi"])
         cache = write_kv(cache, layer, positions, k, v, by_head=False,
                          idx=ki)
-
-    paths = attention_paths(
-        config, cache.k.shape[2], tp_mesh, batch=cache.k.shape[1],
-        kv_bytes=cache.k.dtype.itemsize,
-        **({"kind": "window" if window else "full"} if windowed else {}))
 
     def at_layer(arr):
         return jax.lax.dynamic_index_in_dim(arr, layer, 0,
@@ -1813,6 +1876,8 @@ def _attention(
                 # a ring holds the window's keys and no other: every
                 # written row is live, whichever position it holds
                 jnp.minimum(kv_valid, cache.k.shape[2]) if ring
+                # the cached rows alone: the own row is an operand
+                else jnp.maximum(kv_valid - 1, 0) if own_row
                 else kv_valid, *scales)
         if keep is not None:
             args += (keep[:, 0],)
@@ -1821,8 +1886,15 @@ def _attention(
                   # (named in a trace for this model alone: no other
                   # model's call moves)
                   **({"name": da.WINDOW_NAME} if windowed else {}))
+        if own_row:
+            kw["own"] = own
         attn = (da.decode_attention(*args, **kw) if tp_mesh is None
                 else da.decode_attention_tp(*args, mesh=tp_mesh, **kw))
+        if own_row and cache.quantized:
+            # (the planes, untouched, through the call: no constants of the
+            # layer loop for XLA to stage whole around it)
+            attn, *planes = attn
+            cache = cache._replace(k_scale=planes[0], v_scale=planes[1])
         attn = attn[:, None] if S == 1 else attn
     elif (windowed and not ring and not prefill_flash and S == 2
           and paths["decode"] != "xla" and tp_mesh is None):
@@ -1858,6 +1930,8 @@ def _attention(
     attn = attn.reshape(B, S, nq * D)
     if gate is not None:
         attn = attn * jax.nn.sigmoid(gate)
+    if own_row:
+        return qmatmul(attn, lp["wo"]), cache, own
     return qmatmul(attn, lp["wo"]), cache
 
 
@@ -1990,14 +2064,18 @@ def _layer(
     tp_mesh=None,
     rope_positions=None,
     stack=None,
-) -> tuple[jnp.ndarray, KVCache]:
+    own_row: bool = False,
+) -> tuple[jnp.ndarray, KVCache, tuple | None]:
     """One decoder layer of the homogeneous stack: attention, then the FFN
     (dense or routed experts); arguments as `_attention`'s, and `stack` as
-    `moe_mlp`'s."""
+    `moe_mlp`'s -> (h, cache, the position's K/V row where the caller
+    appends it: `own_row`)."""
     x = rms_norm(h, _norm_w(lp["attn_norm"], config), config.rms_eps)
-    attn, cache = _attention(x, lp, cache, layer, positions, kv_valid,
-                             seq_lens, config, prefill_flash,
-                             tp_mesh=tp_mesh, rope_positions=rope_positions)
+    attn, cache, *row = _attention(x, lp, cache, layer, positions, kv_valid,
+                                   seq_lens, config, prefill_flash,
+                                   tp_mesh=tp_mesh,
+                                   rope_positions=rope_positions,
+                                   own_row=own_row)
     h = h + attn
 
     x = rms_norm(h, _norm_w(lp["mlp_norm"], config), config.rms_eps)
@@ -2010,7 +2088,7 @@ def _layer(
     else:
         h = h + qmatmul(_act(qmatmul(x, lp["wg"]), config)
                         * qmatmul(x, lp["wu"]), lp["wd"])
-    return h, cache
+    return h, cache, row[0] if row else None
 
 
 def add_expert_pairs(cache: KVCache, pairs: jnp.ndarray,
@@ -2144,29 +2222,51 @@ def run_layers(
     rope_positions=None,
 ) -> tuple[jnp.ndarray, KVCache]:
     """Scan a stack of decoder layers over `h`; layer indices inside are
-    local to the stack passed in, whose leading dim is the cache's."""
+    local to the stack passed in, whose leading dim is the cache's.
+
+    Where `attention_paths` says `kv_append: "step"`, a forward of one
+    position a slot WRITES NO CACHE inside the scan: the K/V leaves are its
+    constants, the scale planes ride the carry untouched, each layer's row
+    leaves as the scan's `ys` (4 MB a step at mistral-7b's cell) and
+    `append_step` writes all of them behind it, once."""
     stacks = None
     if "router" in layers_params:
         from symmetry_tpu.models.moe import whole_stacks
 
         stacks = whole_stacks(layers_params, tp_mesh)
+    by_step = h.shape[1] == 1 and attention_paths(
+        config, cache.k.shape[2], tp_mesh, batch=cache.k.shape[1],
+        kv_bytes=cache.k.dtype.itemsize).get("kv_append") == "step"
+
+    def bare(c):
+        # by step the scan's carry is the cache WITHOUT its K/V leaves: the
+        # layers read those as the scan's constants (the scale planes stay,
+        # handed through each kernel call: an operand XLA's fast memory
+        # could hold must not be a constant of the loop)
+        return c._replace(k=None, v=None) if by_step else c
+
+    def whole(c):
+        return c._replace(k=cache.k, v=cache.v) if by_step else c
 
     def body(carry, xs):
-        # The cache rides the CARRY, scatter-updated in place: scan xs/ys
-        # would stream the full [L, B, T, K, D] arrays through HBM every
-        # forward — at decode that re-writes ~0.5 GB per token.
+        # Every other forward's cache rides the CARRY, scatter-updated in
+        # place a layer (`write_kv`): scan xs/ys would stream the full
+        # [L, B, T, K, D] arrays through HBM every forward — at decode that
+        # re-writes ~0.5 GB per token.
         h, c = carry
         lp, l = xs
-        h, c = _layer(h, lp, c, l, positions, kv_valid,
-                      seq_lens, config, use_flash, tp_mesh=tp_mesh,
-                      rope_positions=rope_positions,
-                      stack=stacks and (stacks, l))
-        return (h, c), None
+        h, c, row = _layer(h, lp, whole(c), l, positions, kv_valid,
+                           seq_lens, config, use_flash, tp_mesh=tp_mesh,
+                           rope_positions=rope_positions,
+                           stack=stacks and (stacks, l), own_row=by_step)
+        return (h, bare(c)), row
 
     n_layers = jax.tree.leaves(layers_params)[0].shape[0]
-    (h, new_cache), _ = jax.lax.scan(
-        body, (h, cache),
+    (h, new_cache), rows = jax.lax.scan(
+        body, (h, bare(cache)),
         (layers_params, jnp.arange(n_layers, dtype=jnp.int32)))
+    if by_step:
+        new_cache = append_step(whole(new_cache), positions[:, 0], rows)
     return h, new_cache
 
 

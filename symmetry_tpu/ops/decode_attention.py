@@ -98,6 +98,19 @@ verify and a chunked continuation have a mask a row and keep
     contributes exp(-inf - m) = 0 exactly, so a slot's result depends on
     nothing but its own rows.
 
+  - A decode step of the homogeneous trunk appends to the cache once,
+    BEHIND the layer loop (models/llama.py append_step), so inside it the
+    position's own K/V row is not in the cache: it comes as an operand
+    (`own`), `kv_length` counts the cached rows alone, and the row is
+    where each way's online softmax STARTS — a slot's first item takes
+    (own score, 1, own V) for its carry where any other call takes
+    (-2^30, 0, 0). Dequantised as the kernel would have read it back:
+    payload widened (exact), the k scale on the score, the v scale on
+    the probability, which is cast to the query's dtype. The score and
+    the weighted V are the wrapper's (two small XLA fusions over whole
+    tiles a call); the kernel loads them. (A merge in jnp BEHIND a call
+    that also returned its log-sum-exps was a dozen small relayouts and
+    fusions a layer, half of what the append saved: PERF.md, PR 66.)
 Masking is by absolute position (kv_pos < kv_length), identical semantics
 to ops/attention.py gqa_attention at decode (q position == length - 1)
 and, with S positions a slot, under `block_len=S` with the block's last
@@ -249,9 +262,14 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             scale: float, block_t: int, capacity: int, heads: int,
             n_kv: int, fold: int, slab: int, ways: int, quantized: bool,
             window: int | None, compute_dtype, masked: bool,
-            sliced: bool):
+            sliced: bool, with_own: bool):
     if quantized:
         planes_hbm, rest = rest[:2 + masked], rest[2 + masked:]
+    if with_own:
+        s0_ref, v0_ref, rest = rest[0], rest[1], rest[2:]
+    if with_own and quantized:   # the planes again, aliased: never touched
+        rest = rest[:1] + rest[3:]
+    if quantized:
         o_ref, kbuf, vbuf, scbuf, *spread, islot, iblk, sem = rest
     else:
         o_ref, kbuf, vbuf, islot, iblk, sem = rest
@@ -392,9 +410,23 @@ def _kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             live, i, blk, first = meta[w]
             m_in, l_in, acc_in = carry[w]
             length = len_ref[(base + i) // heads]
-            m_old = jnp.where(first, NEG_INF, m_in)
-            l_old = jnp.where(first, 0.0, l_in)
-            acc = jnp.where(first, 0.0, acc_in)
+            start = (NEG_INF, 0.0, 0.0)
+            if with_own:
+                # the position's own row, which the cache does not hold
+                # yet, is where the slot's softmax starts: its score (the
+                # wrapper's: every lane of a query row's holds it, and the
+                # MAX over them is how a column leaves a tile in the
+                # carry's layout — a lane slice, or a block one lane wide,
+                # is a relayout an item: +19% of the kernel, PERF.md PR
+                # 66), a weight of exp(0), its V a slab of rows (row j the
+                # lane's head j % n_h)
+                start = (jnp.max(s0_ref[i], axis=-1, keepdims=True), 1.0,
+                         jnp.broadcast_to(v0_ref[i][None],
+                                          (nq // slab, slab, D)
+                                          ).reshape(nq, D))
+            m_old = jnp.where(first, start[0], m_in)
+            l_old = jnp.where(first, start[1], l_in)
+            acc = jnp.where(first, start[2], acc_in)
 
             s = jax.lax.dot_general(
                 q_ref[i], kbuf[w, buf].astype(compute_dtype),
@@ -472,12 +504,19 @@ def decode_attention(
     k_cache: jnp.ndarray,     # [L, B, T, K, D] FULL cache (bf16/f32 or int8);
     v_cache: jnp.ndarray,     # heads of 64 pair-folded: [L, B, T, K / 2, 128]
     layer: jnp.ndarray,       # scalar int32: which layer's cache to read
-    kv_length: jnp.ndarray,   # [B] int32 valid entries (incl. current token)
+    kv_length: jnp.ndarray,   # [B] int32: the cache rows of each slot the
+                              # queries see — WITH the current token where
+                              # the caller wrote its row (every S > 1, the
+                              # hybrid trunk), WITHOUT it where the row
+                              # comes as `own`
     k_scale: jnp.ndarray | None = None,  # [L, B, K, T] f32 (int8 caches;
     v_scale: jnp.ndarray | None = None,  # position minor — tile-friendly),
                                          # or [1, B, K, T]: `layer`'s own
     keep: jnp.ndarray | None = None,     # [B, T] bool: the positions each
-    *,                                   # slot's query selected
+                                         # slot's query selected
+    own: tuple | None = None,  # the position's OWN row, not in the cache yet:
+                               # (k, v, k_scale, v_scale): [B, K, D] as the
+    *,                         # cache will hold them, [B, K] (None: no int8)
     window: int | None = None,  # sliding-window span (mistral); bounds the
                                 # per-slot block range below AND above
     interpret: bool = False,
@@ -488,6 +527,26 @@ def decode_attention(
     attends to the slot's keys below `kv_length`, its own block's among
     them (the caller wrote them): there is no mask a position, so no
     `window` and no `keep` with S > 1.
+
+    With `own` (one query a slot, no selection) the query attends to the
+    `kv_length` cached rows AND the row handed in, as if the cache held it
+    at position `kv_length` (a `window` counts it): the same result as
+    the call over a cache that holds the row but for the order of one
+    float32 sum a query row; a slot with no cached row returns the own
+    row's V. Heads of 64 come pair-folded as the cache holds them, their
+    scales a HEAD. Over an int8 cache such a call returns (that, k_scale,
+    v_scale): the planes ALIASED through the call, untouched. Its caller
+    writes no cache in its layer loop (the append is behind it), so the
+    planes would be constants of the loop, and XLA stages a loop-invariant
+    operand that fits its fast memory whole around every call, the pin to
+    HBM notwithstanding — qwen2-7b's 37 MB `k_scale` a LAYER, 1 GB a step
+    beside the 8 GB it must read (PERF.md, PR 66). An output the
+    loop carries is no constant; the leaves (1.2 GB) fit nowhere and stay
+    operands alone. The planes keep their pin to HBM as OUTPUTS, so on the
+    chip they have to flow through — donated in (or a loop's carry) and
+    results of the program, as the trunk has them: a program that feeds
+    them as plain arguments or drops them aborts the v5e compiler's memory
+    assignment (tools/chip_kernels.py, tools/ab_ragged_640.py donate).
 
     A window layer's RING (models/llama.py KVCache.kw: position p at row p
     mod T, T the window) is this kernel with `kv_length = min(length, T)`
@@ -503,9 +562,13 @@ def decode_attention(
     if tiles is None:
         raise ValueError(f"no decode-attention geometry for a {B} x {T} "
                          f"cache of {K * fold} KV heads of {head_dim}")
-    if q.ndim == 4 and (window is not None or keep is not None):
-        raise ValueError("a window or a selection is a mask a position: "
-                         "a block of queries shares one key set")
+    if q.ndim == 4 and (window is not None or keep is not None
+                        or own is not None):
+        raise ValueError("a window, a selection or a row of its own is a "
+                         "mask a position: a block of queries shares one "
+                         "key set")
+    if own is not None and keep is not None:
+        raise ValueError("the own row rides no selection")
     slot_tile, block_t = tiles
     heads, n_kv = _lanes(K, kv_bytes)
     lanes, tile = B * heads, slot_tile * heads
@@ -573,7 +636,38 @@ def decode_attention(
     scratch += [pltpu.SMEM((ways, tile * n_t), jnp.int32),
                 pltpu.SMEM((ways, tile * n_t), jnp.int32),
                 pltpu.SemaphoreType.DMA((n_sem, ways, NBUF))]
+    start = []
+    if own is not None:
+        # The own row's score and weighted V, in the kernel's row order
+        # (row r of a lane the head r % n_h) and its algebra: the payload
+        # widened to the products' dtype, products summed in float32, the
+        # k scale on the score, the v scale — cast to the products' dtype
+        # as a probability is — on V. Made here: over whole (slab, 128)
+        # tiles XLA needs two small fusions a call, where the kernel would
+        # reduce over lanes an item.
+        n_h = n_kv * fold
+        f32 = jnp.float32
 
+        def a_slab(x):  # [lanes, n_h, ...] -> a slab of rows, head j % n_h
+            return jnp.tile(x.astype(f32), (1, slab // n_h)
+                            + (1,) * (x.ndim - 2))
+
+        def payload(x):  # a row a HEAD: a pair's row for both its heads
+            x = x.astype(compute_dtype).reshape(lanes, n_kv, D)
+            return a_slab(jnp.repeat(x, fold, axis=1) if fold > 1 else x)
+
+        s0 = jnp.sum(qk.astype(f32).reshape(lanes, nqp // slab, slab, D)
+                     * payload(own[0])[:, None], axis=-1)
+        v0 = payload(own[1])
+        if quantized:
+            s0 = s0 * a_slab(own[2].reshape(lanes, n_h))[:, None]
+            v0 = a_slab(own[3].reshape(lanes, n_h)).astype(
+                compute_dtype).astype(f32)[..., None] * v0
+        start = [jnp.broadcast_to(
+            (s0 * head_dim ** -0.5).reshape(lanes, nqp, 1),
+            (lanes, nqp, LANES)), v0]
+        if window is not None:
+            window -= 1   # of the cached rows: the own row takes a place
     # Left free, XLA's memory-space assignment stages a small enough
     # operand WHOLE in its fast memory around every call (qwen2-7b's 9 MB
     # scale arrays: three 9 MB copies a layer, for blocks the kernel
@@ -582,24 +676,43 @@ def decode_attention(
     if not interpret:
         args[3:] = [pltpu.with_memory_space_constraint(x, pltpu.HBM)
                     for x in args[3:]]
+    for x in start:   # a lane's start moves with its q, in tiles
+        args += [x]
+        in_specs += [pl.BlockSpec((tile, *x.shape[1:]),
+                                  lambda i, lens, lay: (i, 0, 0))]
+    out_specs = tile_spec
+    out_shape = jax.ShapeDtypeStruct((lanes, nqp, D), q.dtype)
+    through = {}
+    if own is not None and quantized:
+        # (operands 5 and 6, counted with the two prefetched scalars; an
+        # aliased operand's pin is its output's)
+        through = {5: 1, 6: 2}
+        out_specs = [tile_spec, hbm, hbm]
+        out_shape = [out_shape] + [
+            (jax.ShapeDtypeStruct if interpret else pltpu.HBM)(
+                x.shape, x.dtype) for x in (k_scale, v_scale)]
     out = pl.pallas_call(
         functools.partial(_kernel, scale=head_dim ** -0.5, block_t=block_t,
                           capacity=T, heads=heads, n_kv=n_kv, fold=fold,
                           slab=slab, ways=ways,
                           quantized=quantized, window=window,
                           compute_dtype=compute_dtype, masked=masked,
-                          sliced=quantized and k_scale.shape[0] != L),
+                          sliced=quantized and k_scale.shape[0] != L,
+                          with_own=own is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # kv_length, layer
             grid=(B // slot_tile,),
             in_specs=in_specs,
-            out_specs=tile_spec,
+            out_specs=out_specs,
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((lanes, nqp, D), q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=through,
         interpret=interpret,
         **({} if name is None else {"name": name}),
     )(*args)
+    if through:
+        out, *planes = out
     out = out[:, :nql]
     if fold > 1:  # a head's result is in its own half of the row
         out = out.reshape(lanes, nql // fold, fold, D)
@@ -608,7 +721,8 @@ def decode_attention(
                   for s in range(fold))
     if q.ndim == 3:
         out = out.reshape(lanes, group, n_kv * fold, head_dim)
-        return jnp.swapaxes(out, 1, 2).reshape(B, nq, head_dim)
+        out = jnp.swapaxes(out, 1, 2).reshape(B, nq, head_dim)
+        return (out, *planes) if through else out
     return jnp.transpose(out.reshape(by_lane),
                          (0, 2, 1, 4, 3, 5)).reshape(q.shape)
 

@@ -374,7 +374,9 @@ class TestAttentionKernelsUnderTensorParallelism:
             assert attention_paths(preset(name), 640, None, batch=128,
                                    kv_bytes=1) == {
                 "prefill": "pallas-interpret", "decode": "pallas-interpret",
-                "decode_slot_tile": 128, "decode_block_t": block_t}
+                "decode_slot_tile": 128, "decode_block_t": block_t,
+                # one chip's homogeneous trunk appends once a step (PR 66)
+                "kv_append": "step"}
         # mixtral-8x7b.rag-closed: 64 slots x 2,048 over model: 4
         sharded = preset("mistral-7b")  # mixtral's attention, head for head
         assert attention_paths(sharded, 2048, mesh, batch=64,
@@ -414,7 +416,8 @@ class TestAttentionKernelsUnderTensorParallelism:
             cache_dtype=jnp.float32)
         assert engine.attention_paths() == {
             "prefill": "pallas-interpret", "decode": "pallas-interpret",
-            "decode_slot_tile": 4, "decode_block_t": 128}
+            "decode_slot_tile": 4, "decode_block_t": 128,
+            "kv_append": "step"}
         # ... and decodes through it: two identical greedy prompts in
         # different lanes, beside different neighbours, agree
         from symmetry_tpu.engine.engine import SamplingParams

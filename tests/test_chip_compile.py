@@ -197,10 +197,11 @@ def test_sharded_kernel_reads_each_shard_in_place(host, no_cache, dtype):
 
 def test_trunk_reads_the_cache_in_place(one_chip, no_cache, monkeypatch):
     """The whole decode trunk at qwen2-7b's cell: one kernel call in the
-    layer loop, no per-layer slice of the int8 cache, and none of the
-    cache's arrays staged whole in XLA's fast memory around the call (its
-    9 MB scale arrays were: three copies a layer until the operands were
-    pinned to HBM)."""
+    layer loop (and the step's one `scale_append` behind it: PR 66), no
+    per-layer slice of the int8 cache, and none of the cache's arrays
+    staged whole in XLA's fast memory around the call (its 9 MB scale
+    arrays were: three copies a layer until the operands were pinned to
+    HBM)."""
     from symmetry_tpu.models import llama
 
     monkeypatch.setattr(llama, "interpret_mode", lambda: False)
@@ -221,11 +222,103 @@ def test_trunk_reads_the_cache_in_place(one_chip, no_cache, monkeypatch):
     text = jax.jit(
         lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
         donate_argnums=(2,)).lower(params, tok, cache).compile().as_text()
-    assert text.count("tpu_custom_call") == 1
+    assert text.count("tpu_custom_call") == 2
+    assert len(re.findall(r"%decode_attention[.\d]* = ", text)) == 1
     assert "s8[1,128,640,4,128]" not in text
     staged = [line for line in text.splitlines()
               if "copy-start" in line and "[28,128," in line]
     assert not staged, staged[0][:200]
+
+
+def computations(text: str) -> dict[str, list[str]]:
+    """A compiled program's computations, name -> lines."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.]+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+@pytest.mark.parametrize("program", ["step", "block"])
+@pytest.mark.parametrize("preset", ["mistral-7b", "qwen2-7b"])
+def test_dense_decode_appends_to_the_cache_once_a_step(
+        one_chip, no_cache, monkeypatch, preset, program):
+    """The dense cells' decode step (`forward_hidden`, one position a slot)
+    and the engine's decode block of 16 (`tools/lowered_programs.py`), 128
+    slots x 640, for a described v5e (PR 66): the layer loop — the
+    computation that calls the decode kernel — WRITES no cache-shaped array
+    (no scatter, no dynamic-update-slice, no fusion, no copy: the parent's
+    held four scatters); it reads the K/V leaves as constants and hands the
+    two scale planes THROUGH the kernel call, aliased and untouched (as
+    constants of the loop XLA staged qwen2-7b's 37 MB `k_scale` whole
+    around every call: ops/decode_attention.py). The step behind the loop
+    writes each K/V leaf by ONE scatter in place and both planes by ONE
+    `scale_append` call aliased onto them, and nowhere is a leaf or a plane
+    copied, staged, transposed or relaid (a window scatter of the planes
+    is: ops/scale_append.py). No second cache: the program's temporaries
+    stay under a tenth of one leaf."""
+    from symmetry_tpu.models import llama
+
+    monkeypatch.setattr(llama, "interpret_mode", lambda: False)
+    tool, shaped = _lowered_programs_tool(one_chip, monkeypatch)
+    cfg = llama.preset(preset)
+    L, K, B, T = cfg.num_layers, cfg.num_kv_heads, tool.SLOTS, tool.CAPACITY
+    params = shaped(lambda: llama.init_params(
+        cfg, jax.random.key(0), jnp.bfloat16, quantize=True))
+    if program == "block":
+        e = tool.bare_engine(cfg)
+        assert llama.attention_paths(
+            cfg, T, None, batch=B, kv_bytes=1)["kv_append"] == "step"
+        with jax.default_matmul_precision("default"):  # as served
+            lowered = next(low for prog, low in tool.programs(
+                e, params, tool.decode_state(e, cfg, B))
+                if prog == "decode_block")
+    else:
+        cache = shaped(lambda: llama.init_cache(cfg, B, T, jnp.bfloat16,
+                                                quantized=True))
+        tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+        lowered = jax.jit(
+            lambda p, t, c: llama.forward_hidden(p, cfg, t, c),
+            donate_argnums=(2,)).lower(params, tok, cache)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaf, plane = rf"s8\[{L},{B},{T},{K},128\]", rf"f32\[{L},{B},{K},{T}\]"
+    made = rf"= \(?({leaf}|{plane})\S*(, ({leaf}|{plane})\S*)*\)? " \
+           rf"(fusion|scatter|dynamic-update-slice|copy|copy-start|" \
+           rf"transpose|custom-call)\("
+    bodies = computations(text)
+    loop, = (n for n, lines in bodies.items()
+             if any("%decode_attention" in ln.split(" = ")[0]
+                    for ln in lines))
+    step, = (n for n, lines in bodies.items()
+             if any("%scale_append" in ln.split(" = ")[0] for ln in lines))
+    assert loop != step
+    through = rf"= \(bf16\[{B},\d+,128\]\S*, {plane}\S*, {plane}\S*\) " \
+              rf"custom-call\("
+    in_loop = [ln.strip()[:160] for ln in bodies[loop]
+               if re.search(made, ln) and not re.search(through, ln)]
+    assert not in_loop, in_loop[0]
+    assert sum(bool(re.search(through, ln)) for ln in bodies[loop]) == 1
+    writes = [ln.strip() for n, lines in bodies.items() for ln in lines
+              if re.search(made, ln) and not n.startswith("%fused")
+              and not re.search(through, ln)]
+    scatters = [ln for ln in writes if re.search(
+        rf"= {leaf}\S* fusion\(", ln) and "kind=kCustom" in ln]
+    appends = [ln for ln in writes if re.search(
+        rf"= \({plane}\S*, {plane}\S*\) custom-call\(", ln)
+        and "%scale_append" in ln.split(" = ")[0]]
+    assert len(scatters) == 2 and len(appends) == 1, writes
+    assert sorted(writes) == sorted(scatters + appends), writes
+    behind = [ln.strip() for ln in bodies[step]]
+    assert all(ln in behind for ln in writes)
+    moved = [ln.strip()[:160] for ln in text.splitlines() if re.search(
+        rf"= ({leaf}|{plane})\S* (copy|copy-start|transpose)\(", ln)]
+    assert not moved, moved[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < L * B * T * K * 13
 
 
 def test_hybrid_decode_step_updates_the_recurrent_state_in_place(

@@ -201,7 +201,8 @@ class TestDecodeAttentionKernel:
         cfg = preset("mistral-7b")
         assert paths(cfg, 640) == {
             "prefill": "pallas-interpret", "decode": "pallas-interpret",
-            "decode_slot_tile": 128, "decode_block_t": 128}
+            "decode_slot_tile": 128, "decode_block_t": 128,
+            "kv_append": "step"}
         assert paths(preset("qwen2-7b"), 640)["decode_block_t"] == 256
         assert paths(cfg, 672) == {
             "prefill": "pallas-interpret", "decode": "xla"}
@@ -761,3 +762,137 @@ class TestSlidingWindow:
         want = reference(q, k[0], v[0], lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
+
+
+# a slot of length 1 (no cached row), 2, a block's edge at 8 heads (129:
+# the cached rows are one block of 128, the row's item is dropped) and at 4
+# (257), mid-block, and a full slot — lengths WITH the own row
+OWN_ROW_LENGTHS = [1, 2, 129, 257, 300, 640]
+
+
+def own_rows(k, v, scales, layer, lengths):
+    """Each slot's row at `lengths - 1` of `layer` as
+    models/llama.py _attention hands it to `decode_attention(own=)`: K and
+    V as the cache holds them ([B, K, D]; heads of 64 in their pairs), and
+    of an int8 cache the scales a head [B, K]."""
+    b = jnp.arange(lengths.shape[0])
+    own = [rows(x)[layer, b, lengths - 1] for x in (k, v)]
+    return tuple(own + [s[layer, b, :, lengths - 1] for s in scales]
+                 + [None] * (2 - len(scales)))
+
+
+def over_both(q, k, v, scales, layer, lengths, **kw):
+    """`decode_attention` over the rows below each slot's position with the
+    position's own row as an operand (an int8 cache's planes come back
+    beside the result)."""
+    got = decode_attention(
+        q, rows(k), rows(v), jnp.int32(layer), lengths - 1, *scales,
+        own=own_rows(k, v, scales, layer, lengths), interpret=True, **kw)
+    if not scales:
+        return got
+    for plane, same in zip(got[1:], scales):
+        np.testing.assert_array_equal(np.asarray(plane), np.asarray(same))
+    return got[0]
+
+
+class TestOwnRow:
+    """A decode step of the homogeneous trunk (PR 66) appends to the cache
+    behind its layer loop, so inside it the kernel walks the rows BELOW each
+    slot's position and takes the position's own row — which the cache does
+    not hold yet — as an operand: where the slot's softmax starts."""
+
+    @pytest.mark.parametrize("slot_tile", [2, 6])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("K, G, D", [(8, 4, 128), (4, 7, 128),
+                                         (8, 4, 64), (16, 2, 64),
+                                         (2, 16, 128)])
+    def test_cached_rows_and_the_own_row_are_the_kernel_over_both(
+            self, tiled, K, G, D, quantized, slot_tile):
+        """The kernel over `length - 1` rows + the own row == the kernel
+        over a cache that holds the row (what every step ran before), at
+        the dense cells' heads, lfm2's pairs of 64 and 2 heads (head-major
+        lanes as int8): a slot of length 1 reads the own row's V alone;
+        129 / 257 are a block's edge, where the cached rows need an item
+        less."""
+        q, k, v, scales = case_640(K, G, quantized, D=D, seed=5)
+        lengths = jnp.asarray(OWN_ROW_LENGTHS, jnp.int32)
+        want = decode_attention(q, rows(k), rows(v), jnp.int32(1), lengths,
+                                *scales, interpret=True)
+        fresh = tiled(lanes=slot_tile)
+        got = fresh(q, rows(k), rows(v), jnp.int32(1), lengths - 1, *scales,
+                    own=own_rows(k, v, scales, 1, lengths), interpret=True)
+        got = got[0] if quantized else got
+        assert got.shape == q.shape and got.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+        # length 1: softmax over one key is that key's V, dequantised
+        alone = v[1, 0, 0].astype(jnp.float32)
+        if quantized:
+            alone = alone * scales[1][1, 0, :, 0][:, None]
+        np.testing.assert_allclose(
+            np.asarray(got[0], np.float32).reshape(K, G, D),
+            np.broadcast_to(np.asarray(alone)[:, None], (K, G, D)),
+            rtol=2e-2, atol=2e-2)
+
+    def test_the_own_row_is_the_online_softmaxs_first_step_in_float32(self):
+        """float32 end to end: the result is the kernel's over a cache that
+        holds the row to 2e-5 — one sum a query row in another order."""
+        q, k, v, _ = make_case(B=3, T=384, K=4, G=2, seed=7)
+        lengths = jnp.asarray([1, 257, 384], jnp.int32)
+        want = decode_attention(q, k, v, jnp.int32(0), lengths,
+                                interpret=True)
+        got = over_both(q, k, v, (), 0, lengths)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("window", [1, 2, 128, 200])
+    def test_a_model_wide_window_counts_the_own_row(self, window):
+        """`window` is the model's, the query's own position in it: over
+        the cached rows the floor is the same — block edges (length -
+        window = 128) and a window of the own row alone included."""
+        q, k, v, scales = case_640(8, 4, True, seed=9)
+        lengths = jnp.asarray([1, 100, 128, 129, 256, 640], jnp.int32)
+        want = decode_attention(q, k, v, jnp.int32(0), lengths, *scales,
+                                window=window, interpret=True)
+        got = over_both(q, k, v, scales, 0, lengths, window=window)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+    def test_a_slots_result_does_not_see_its_neighbours(self):
+        """check_correct's rule holds with the own row: bit-identical
+        whatever the other slots hold."""
+        q, k, v, scales = case_640(4, 7, True, seed=11)
+        outs = []
+        for others in ([640, 1, 129, 513, 2], [1, 1, 1, 1, 1]):
+            lengths = jnp.asarray(others[:2] + [300] + others[2:],
+                                  jnp.int32)
+            outs.append(np.asarray(over_both(q, k, v, scales, 1, lengths)[2],
+                                   np.float32))
+        np.testing.assert_array_equal(*outs)
+
+    def test_a_caller_without_an_own_row_lowers_as_before(self):
+        """Every other program's call (each S > 1, the hybrid trunk's
+        layers, a sharded trunk) has one result and nothing aliased; a
+        block of queries, and a selection, cannot take an own row."""
+        q, k, v, scales = case_640(8, 4, True)
+        lengths = jnp.asarray(LENGTHS_640, jnp.int32)
+        args = (q, k, v, jnp.int32(0), lengths, *scales)
+        own = own_rows(k, v, scales, 0, jnp.maximum(lengths, 1))
+
+        def lowered(**kw):
+            return jax.jit(lambda *a: decode_attention.__wrapped__(
+                *a, interpret=True, **kw)).lower(*args).as_text()
+
+        plain = lowered()
+        assert plain == lowered(own=None) and plain != lowered(own=own)
+        assert isinstance(decode_attention(*args, interpret=True),
+                          jax.Array)
+        with pytest.raises(ValueError, match="mask a position"):
+            decode_attention(jnp.stack([q] * 4, axis=1), *args[1:],
+                             own=own, interpret=True)
+        with pytest.raises(ValueError, match="no selection"):
+            decode_attention(*args, jnp.ones((6, 640), bool), own,
+                             interpret=True)

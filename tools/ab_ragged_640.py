@@ -25,11 +25,15 @@ back to back under one fence), us an item (a slot's block of BLOCK_ROWS
 rows: 128 KB of K and of V) and GB/s of live rows. (PR 59 also read them
 with the scale planes' layout product replaced by ones, wrong numbers: an
 item 15% cheaper, 0.527 -> 0.449 us at mistral's shape; PERF.md section 6.)
+`--kernel --own` times each shape a second time as a decode step of the
+homogeneous trunk calls it (PR 66): the position's own row an operand, a
+slot's softmax started from it.
 
 Needs a TPU: `python tools/ab_ragged_640.py [--tiles] [preset ...]`, or
-`python tools/ab_ragged_640.py --kernel`. Writes
+`python tools/ab_ragged_640.py --kernel [--own]`. Writes
 chiprun_out/ab_ragged_640.json.
 """
+import functools
 import json
 import os
 import sys
@@ -40,7 +44,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _bench_util import sync, timeit
+from _bench_util import sync
 from symmetry_tpu.models import llama
 from symmetry_tpu.ops import decode_attention as da
 from symmetry_tpu.ops.attention import gqa_attention
@@ -80,7 +84,7 @@ def kernel_shapes() -> dict:
 
 def kernel_rows() -> dict:
     """The kernel alone over a random int8 cache, a call a layer."""
-    out = {}
+    out_ = {}
     for label, (cap, K, nq, L, calls, lengths) in kernel_shapes().items():
         slots, D = lengths.size, 128
         ks = jax.random.split(jax.random.key(2), 5)
@@ -90,26 +94,42 @@ def kernel_rows() -> dict:
         ksc, vsc = (jax.random.uniform(key, (L, slots, K, cap), jnp.float32,
                                        0.005, 0.02) for key in ks[3:])
         block_t = da.geometry(slots, cap, K)[1]
-
-        @jax.jit
-        def step(q, k, v, ksc, vsc, n_):
-            # a q a call: identical calls would be one call
-            return sum(da.decode_attention(
-                q * (1 + c), k, v, jnp.int32(c % L), n_, ksc, vsc,
-                interpret=interpret_mode()) for c in range(calls))
-
-        ms = timeit(step, q, k, v, ksc, vsc,
-                    jnp.asarray(lengths, jnp.int32)) / calls
         items = int(np.maximum(-(-lengths // block_t), 1).sum())
         live = int(lengths.sum()) * K * (2 * D + 8)   # K, V and two scales
-        out[label] = {"ms_a_call": round(ms, 4), "items": items,
-                      "us_an_item": round(ms * 1e3 / items, 4),
-                      "live_gb_s": round(live / ms / 1e6, 1)}
-        print(f"kernel {label}: {ms:.4f} ms a call, {items} items, "
-              f"{ms * 1e3 / items:.4f} us an item, "
-              f"{live / ms / 1e6:.1f} GB/s of live rows", flush=True)
+        row = (k[0, :, 0], v[0, :, 0], ksc[0, :, :, 0], vsc[0, :, :, 0])
+        n_ = jnp.asarray(lengths, jnp.int32)
+        for own in (False, True) if "--own" in sys.argv else (False,):
+            # (with its own row the call hands the scale planes through,
+            # aliased: they are donated in and come back, as in the trunk)
+            @functools.partial(jax.jit, donate_argnums=(3, 4))
+            def step(q, k, v, ksc, vsc, n_):
+                total = 0   # a q a call: identical calls would be one call
+                for c in range(calls):
+                    got = da.decode_attention(
+                        q * (1 + c), k, v, jnp.int32(c % L), n_, ksc, vsc,
+                        interpret=interpret_mode(),
+                        **({"own": row} if own else {}))
+                    if own:
+                        got, ksc, vsc = got
+                    total = total + got
+                return total, ksc, vsc
+
+            for i in range(3 + 20):   # 3 to warm up, 20 timed
+                if i == 3:
+                    sync(out)
+                    t0 = time.perf_counter()
+                out, ksc, vsc = step(q, k, v, ksc, vsc, n_)
+            sync(out)
+            ms = (time.perf_counter() - t0) / 20 * 1e3 / calls
+            name = label + (", own row" if own else "")
+            out_[name] = {"ms_a_call": round(ms, 4), "items": items,
+                          "us_an_item": round(ms * 1e3 / items, 4),
+                          "live_gb_s": round(live / ms / 1e6, 1)}
+            print(f"kernel {name}: {ms:.4f} ms a call, {items} items, "
+                  f"{ms * 1e3 / items:.4f} us an item, "
+                  f"{live / ms / 1e6:.1f} GB/s of live rows", flush=True)
         del q, k, v, ksc, vsc
-    return out
+    return out_
 
 
 def block_of(cfg) -> int:

@@ -40,6 +40,17 @@ by the Mosaic compiler only.
                      kernel over 64 slots x 640 in bf16 and int8 — against
                      the plain attention (`--only gqa16`)
 
+  append             a decode step of the homogeneous trunk (PR 66) at the
+                     dense cells' 128 slots x 640, mistral-7b's 8 and
+                     qwen2-7b's 4 int8 KV heads: the kernel over the rows
+                     BELOW each slot's position with the position's own
+                     row as an operand (`own`) against gqa_attention over
+                     a cache that holds the row; and `append_step` — a scatter a
+                     K/V leaf, ops/scale_append.py for the planes — EXACT
+                     against `write_kv` called a layer, a slot at the
+                     capacity (dropped) and a slot at position 0 among
+                     them (`--only append`)
+
 Then the one timing question later PRs lean on: does
 `jax.block_until_ready` on this chip wait for completion? One decode block
 of chip_smoke.py's engine is timed under it, under the fetch fence of
@@ -174,6 +185,75 @@ def decode_cases(capacities=(4096, 8192), nkv: int = NKV,
                                      k_scale=ksc, v_scale=vsc,
                                      interpret=interpret_mode()),
             ref(kq[layer], vq[layer], ksc[layer], vsc[layer]), BF16_TOL))
+    return rows
+
+
+def append_cases(layers=4, slots=128, capacity=640) -> list[dict]:
+    """`append`: the per-step cache append and the attention that goes
+    with it, at the dense cells' cache."""
+    from symmetry_tpu.models.llama import KVCache, append_step, write_kv
+
+    rows = []
+    L, B, T = layers, slots, capacity
+    for nkv, nq in ((8, 32), (4, 28)):
+        ks = jax.random.split(jax.random.key(nkv), 5)
+        q = jax.random.normal(ks[0], (B, nq, D), jnp.bfloat16)
+        kq, ksc = quantize_kv(jax.random.normal(ks[1], (L, B, T, nkv, D)))
+        vq, vsc = quantize_kv(jax.random.normal(ks[2], (L, B, T, nkv, D)))
+        ksc, vsc = jnp.moveaxis(ksc, -1, -2), jnp.moveaxis(vsc, -1, -2)
+        # each slot's position: ragged, with the first row, the last row
+        # and a slot already at its capacity (its write is dropped)
+        pos = jnp.asarray(np.random.default_rng(nkv).integers(
+            1, T, B), jnp.int32).at[:3].set(jnp.asarray([0, T - 1, T]))
+        new_k, new_v = (jax.random.normal(key, (L, B, 1, nkv, D),
+                                          jnp.bfloat16) for key in ks[3:])
+        cache = KVCache(k=kq, v=vq, lengths=pos, k_scale=ksc, v_scale=vsc)
+
+        def by_layer(cache):
+            for l in range(L):
+                cache = write_kv(cache, jnp.int32(l), pos[:, None],
+                                 new_k[l], new_v[l], by_head=False)
+            return cache
+
+        def by_step(cache):
+            own = [quantize_kv(x[:, :, 0]) for x in (new_k, new_v)]
+            return append_step(cache, pos, (own[0][0], own[1][0],
+                                            own[0][1], own[1][1]))
+
+        want = jax.jit(by_layer)(cache)
+        row = {"kernel": f"append_step {nkv} KV heads == write_kv a layer"}
+        try:
+            got = jax.jit(by_step)(cache)
+            row["ok"] = all(bool(jnp.array_equal(getattr(got, f),
+                                                 getattr(want, f)))
+                            for f in ("k", "v", "k_scale", "v_scale"))
+        except Exception as exc:  # noqa: BLE001 — the refusal IS the finding
+            row.update(ok=False, error=f"{type(exc).__name__}: {exc}"[:2000])
+        rows.append(row)
+
+        # attention of layer 1 over the cache WITH the row (the reference,
+        # and what every step ran before PR 66) against the kernel over the
+        # rows below it + the own row as an operand
+        layer, live = 1, np.asarray(pos) < T
+        with jax.default_matmul_precision("highest"):
+            ref = np.asarray(gqa_attention(
+                q.astype(jnp.float32)[:, None], want.k[layer], want.v[layer],
+                pos[:, None], pos + 1, k_scale=want.k_scale[layer],
+                v_scale=want.v_scale[layer])[:, 0], np.float32)
+
+        def with_own():
+            own_k, own_ks = quantize_kv(new_k[layer, :, 0])
+            own_v, own_vs = quantize_kv(new_v[layer, :, 0])
+            # the planes are handed through the call, aliased: donated in
+            # (copies: `want` is read below) and results, as in the trunk
+            return jax.jit(lambda ks_, vs_: decode_attention(
+                q, kq, vq, jnp.int32(layer), pos, ks_, vs_,
+                own=(own_k, own_v, own_ks, own_vs),
+                interpret=interpret_mode()), donate_argnums=(0, 1))(
+                    ksc + 0, vsc + 0)[0][live]
+
+        rows.append(check(f"decode_attention + own row, {nkv} KV heads",
+                          with_own, ref[live], BF16_TOL))
     return rows
 
 
@@ -438,7 +518,8 @@ def fence_timing() -> dict:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--only", choices=["select", "decode64", "gqa16"],
+    ap.add_argument("--only", choices=["select", "decode64", "gqa16",
+                                       "append"],
                     help="these rows alone, no fence timing")
     only = ap.parse_args().only
     if interpret_mode() or jax.default_backend() != "tpu":
@@ -451,11 +532,12 @@ def main() -> int:
     # gqa16: 32 query heads on TWO K/V heads at a 64 x 640 cache (PR 61)
     rows = (flash_cases((256,), nkv=2) + decode_cases((640,), nkv=2,
                                                       slots=64)
-            if only == "gqa16" else
+            if only == "gqa16" else append_cases() if only == "append" else
             (select_cases() if only != "decode64" else [])
             + (decode64_cases() if only != "select" else []))
     if only is None:
-        rows = flash_cases() + decode_cases() + matmul_cases() + rows
+        rows = (flash_cases() + decode_cases() + append_cases()
+                + matmul_cases() + rows)
     for r in rows:
         print(json.dumps(r))
     out = {"device": device, "jax": jax.__version__, "kernels": rows}

@@ -53,7 +53,10 @@ keywords out of the trunk: all 34 identical. PR 65, a drafting module
 behind a window / full trunk, rings that carry a draft and a tail of several
 rows: the 34 older files against the parent are in CHANGES.md's line; three
 new ones, k-exaone-236b-a23b's, whose `decode_block` is the drafting block
-at 64 slots and whose `prefill` is two rows of 512.)
+at 64 slots and whose `prefill` is two rows of 512. PR 66, the homogeneous
+one-chip trunk appends to the cache once a decode step: 35 of the 37
+identical — mistral-7b's and qwen2-7b's `decode_block` the two that
+differ.)
 
 It reaches into `InferenceEngine` (an instance made without `__init__`, with
 the attributes `_build_jits` reads) so that a 7B model's state is never
